@@ -1,0 +1,497 @@
+#!/usr/bin/env python3
+"""chip_smoke.py — the quickest proof that the system still starts on the chip.
+
+One process that owns the chip(s) drives the main path once through the
+entry points a user calls, at the full width of the models below, with
+random seeded weights:
+
+* **train** — BERT-base (12 layers, hidden 768, 12 heads, FFN 3072, vocab
+  30522) at sequence 512, the recipe ``bench.py`` builds (bf16 AMP white
+  list, Adam + global-norm clip, masked-gather MLM head), built under
+  ``program_guard``, initialised with ``Executor(TPUPlace()).run(startup)``
+  and stepped through ``Executor.run`` on
+  ``CompiledProgram(main).with_data_parallel(...)`` over every local chip.
+  First the packed Pallas kernels run forward + backward at the phase's own
+  shape against the einsum formulation; then ~10 steps on one fixed batch
+  at a constant learning rate must give finite, falling losses, and on one
+  chip the compiled step itself must contain the Mosaic custom call.
+* **serve** — ``serve(ServingEngine(...))`` with a paged
+  ``GenerationEngine`` attached, answering HTTP ``/predict``, ``/generate``
+  (prompts in different prefill buckets, one streamed), ``/healthz`` and
+  ``/metrics``, then ``close()``.  The generation model is the repo's one
+  decoder block at real widths — a smoke shape, NOT a supported
+  configuration: hidden 2048, 16 heads of 128, SwiGLU FFN 8192, vocab
+  50304, 16 layers, 8 slots x 2048 positions, float32.
+
+Any failed check raises: the exit code is non-zero and no result line is
+printed.  Without a TPU backend the script refuses to run (exit 2).  The
+last line of stdout is one JSON object
+``{"ok": true, "device": {"platform", "kind", "count"}}``.
+
+Nothing here is a measurement: the times printed are set-up and phase
+wall times (compilation included), there to show the compile cache working
+and the 1200 s limit being met — not rates.
+"""
+from __future__ import annotations
+
+import gc
+import json
+import sys
+import threading
+import time
+import urllib.request
+
+import numpy as np
+
+TRAIN = dict(seq=512, hidden=768, layers=12, heads=12, ffn=3072,
+             vocab=30522, global_batch=16, steps=10, lr=1e-4)
+SERVE = dict(hidden=2048, heads=16, ffn=8192, vocab=50304, layers=16,
+             slots=8, max_seq=2048, page_tokens=16,
+             # three prefill programs instead of the nine-rung default
+             # ladder: a sub-128 bucket, a mid one, and the cache width
+             prefill_buckets=(64, 512, 2048),
+             prompt_lens=(40, 300, 1500), new_tokens=8,
+             mlp=dict(feat=256, hidden=2048, depth=4, classes=16))
+
+# One tolerance, as a share of the reference's largest magnitude.  bf16
+# carries 8 mantissa bits, and the Pallas kernels feed the MXU at default
+# precision, which rounds their f32 operands (scaled q, probabilities, ds)
+# to bf16; under AMP the kernel's inputs and outputs are bf16 as well.
+# Each rounding is <= 2^-8 relative; four of them bound forward and
+# backward with room for the accumulation order.  Measured on a v5e
+# (PR 21): kernels 0.0056, generation logits 0.0024 (the prefill kernel
+# dominates), the XLA-only /predict MLP 1.3e-7.
+TOL = 4 * 2.0 ** -8
+
+
+def say(msg):
+    print(f"[chip_smoke] {msg}", flush=True)
+
+
+def check(cond, what):
+    if not cond:
+        raise AssertionError(f"chip_smoke: {what}")
+
+
+def attention_paths():
+    from paddle_tpu.monitor import stat_get
+
+    return {p: stat_get(f"attention_lowered_{p}")
+            for p in ("pallas", "blockwise", "ring", "xla")}
+
+
+def paths_since(before):
+    return {k: v - before[k] for k, v in attention_paths().items()
+            if v - before[k]}
+
+
+# ---------------------------------------------------------------------------
+# train
+# ---------------------------------------------------------------------------
+
+def check_packed_kernels(batch, seq, hidden, heads, interpret=False):
+    """flash_attention_packed / _packed_bias, forward + backward, against
+    the einsum formulation at "highest" matmul precision."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops.pallas.flash_attention import (
+        DEFAULT_BLOCK_K, DEFAULT_BLOCK_Q, flash_attention_packed,
+        flash_attention_packed_bias)
+
+    kq, kg = jax.random.split(jax.random.key(0))
+    qkv = jax.random.normal(kq, (batch, seq, 3 * hidden),
+                            jnp.float32).astype(jnp.bfloat16)
+    g = jax.random.normal(kg, (batch, seq, hidden),
+                          jnp.float32).astype(jnp.bfloat16)
+    # the last three positions are padding, as an attention mask makes them
+    bias = jnp.where(jnp.arange(seq)[None, :] < seq - 3, 0.0, -1e4) \
+        * jnp.ones((batch, 1), jnp.float32)
+
+    def reference(qkv, bias):
+        x = qkv.astype(jnp.float32).reshape(
+            batch, seq, 3, heads, hidden // heads)
+        q, k, v = (jnp.moveaxis(x[:, :, i], 1, 2) for i in range(3))
+        s = jnp.einsum("bhqd,bhkd->bhqk", q, k) / np.sqrt(hidden // heads)
+        if bias is not None:
+            s = s + bias[:, None, None, :]
+        o = jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, axis=-1), v)
+        return jnp.moveaxis(o, 1, 2).reshape(batch, seq, hidden)
+
+    worst = 0.0
+    for name, b in (("flash_attention_packed", None),
+                    ("flash_attention_packed_bias", bias)):
+        def kernel(qkv, b=b):
+            tail = (heads, False, None, DEFAULT_BLOCK_Q, DEFAULT_BLOCK_K,
+                    interpret)
+            if b is None:
+                return flash_attention_packed(qkv, *tail)
+            return flash_attention_packed_bias(qkv, b, *tail)
+
+        def fwd_bwd(f):
+            def loss(qkv):
+                out = f(qkv)
+                return (out.astype(jnp.float32)
+                        * g.astype(jnp.float32)).sum(), out
+            (_, out), grad = jax.jit(
+                jax.value_and_grad(loss, has_aux=True))(qkv)
+            return out, grad
+
+        out_k, grad_k = fwd_bwd(kernel)
+        with jax.default_matmul_precision("highest"):
+            out_r, grad_r = fwd_bwd(lambda x, b=b: reference(x, b))
+        for what, got, ref in (("forward", out_k, out_r),
+                               ("backward", grad_k, grad_r)):
+            got = np.asarray(got.astype(jnp.float32))
+            ref = np.asarray(ref.astype(jnp.float32))
+            check(np.isfinite(got).all(), f"{name} {what} not finite")
+            rel = float(np.abs(got - ref).max() / np.abs(ref).max())
+            worst = max(worst, rel)
+            check(rel <= TOL,
+                  f"{name} {what} off the einsum reference by {rel:.4g} "
+                  f"of its range (tolerance {TOL:.4g})")
+    return worst
+
+
+def train_phase(cfg=TRAIN, on_chip=True):
+    import jax
+
+    import bench
+    import paddle_tpu as pt
+
+    devices = jax.devices()
+    n = len(devices)
+    B, S = cfg["global_batch"], cfg["seq"]
+    check(B % n == 0, f"global batch {B} does not split over {n} devices")
+    paths0 = attention_paths()
+
+    t_phase = t0 = time.perf_counter()
+    worst = check_packed_kernels(B // n, S, cfg["hidden"], cfg["heads"],
+                                 interpret=not on_chip)
+    say(f"train: packed kernels fwd+bwd at [{B // n}, {S}, "
+        f"3x{cfg['hidden']}] bf16 within {worst:.4g} of the einsum "
+        f"reference's range (tolerance {TOL:.4g}) "
+        f"[{time.perf_counter() - t0:.1f} s]")
+
+    max_pred = max(1, int(round(0.15 * S)))
+    main_p, startup, feed_names, loss, _ = bench.build_bert_train_programs(
+        dict(batch_size=B, seq_len=S, vocab_size=cfg["vocab"],
+             hidden=cfg["hidden"], num_layers=cfg["layers"],
+             num_heads=cfg["heads"], intermediate=cfg["ffn"],
+             max_predictions=max_pred, use_flash=True, dropout=0.1),
+        learning_rate=cfg["lr"])
+    scope = pt.Scope()
+    exe = pt.Executor(pt.TPUPlace())
+    exe.run(startup, scope=scope)
+    comp = pt.CompiledProgram(main_p).with_data_parallel(
+        loss_name=loss.name)
+
+    rng = np.random.RandomState(0)
+    batch = {
+        "input_ids": rng.randint(0, cfg["vocab"], (B, S)).astype("int64"),
+        "token_type_ids": np.zeros((B, S), "int64"),
+        "attn_mask": np.ones((B, S), "float32"),
+        "mlm_positions": np.sort(np.stack(
+            [rng.choice(S, max_pred, replace=False) for _ in range(B)]),
+            axis=1).astype("int64"),
+        "mlm_labels": rng.randint(0, cfg["vocab"],
+                                  (B, max_pred)).astype("int64"),
+        "mlm_weights": np.ones((B, max_pred), "float32"),
+    }
+    losses, t_first = [], None
+    t0 = time.perf_counter()
+    for _ in range(cfg["steps"]):
+        out, = exe.run(comp, feed=batch, fetch_list=[loss], scope=scope)
+        losses.append(float(np.asarray(out).reshape(-1)[0]))
+        if t_first is None:
+            t_first = time.perf_counter() - t0
+            setup_s = time.perf_counter() - t_phase
+    say(f"train: first step (compile included) {t_first:.1f} s, "
+        f"{cfg['steps']} steps {time.perf_counter() - t0:.1f} s")
+    say("train: losses " + " ".join(f"{x:.4f}" for x in losses))
+    check(all(np.isfinite(losses)), f"non-finite loss in {losses}")
+    check(losses[-1] < losses[0],
+          f"loss did not fall: {losses[0]:.4f} -> {losses[-1]:.4f}")
+
+    # what XLA compiled, not the flag that asked for it
+    executable = comp.executable
+    feed_sh = executable.input_shardings[0][0][0]
+    shard = feed_sh.shard_shape((B, S))
+    check(shard[0] * n == B,
+          f"batch not split over dp: per-device shard {shard} of {(B, S)}")
+    mosaic = "tpu_custom_call" in executable.as_text()
+    paths = paths_since(paths0)
+    if on_chip and n == 1:
+        check(mosaic and paths.get("pallas"),
+              f"the compiled training step holds no Mosaic custom call: "
+              f"the Pallas kernels were not lowered (paths {paths})")
+    say(f"train: {n} device(s), per-device batch shard {shard}, Mosaic "
+        f"custom call in the compiled step: {mosaic}, attention lowered "
+        f"as {paths}")
+    if n > 1:
+        say("train: on more than one device attention is the blockwise "
+            "reference, not the Pallas kernels (pallas_call pins the "
+            "layout the partitioner needs free)")
+        check(paths.get("blockwise") and not mosaic,
+              "expected the blockwise reference under a multi-device mesh")
+
+    mem = [d.memory_stats() for d in devices]
+    if all(mem):
+        in_use = [m["bytes_in_use"] for m in mem]
+        say("train: bytes_in_use per device after the steps: "
+            + " ".join(str(b) for b in in_use))
+        params = sum(int(np.prod(v.shape)) * v.dtype.itemsize
+                     for v in (scope.find_var(k)
+                               for k in scope.local_var_names())
+                     if hasattr(v, "shape"))
+        check(min(in_use) >= params // 2,
+              f"state is not on every device: {in_use} against "
+              f"{params} bytes of replicated state")
+    return {"losses": losses, "paths": paths, "mosaic": mosaic,
+            "devices": n, "setup_s": setup_s}
+
+
+# ---------------------------------------------------------------------------
+# serve
+# ---------------------------------------------------------------------------
+
+def _http(url, doc=None, timeout=600):
+    data = None if doc is None else json.dumps(doc).encode()
+    req = urllib.request.Request(
+        url, data=data, headers={"Content-Type": "application/json"})
+    with urllib.request.urlopen(req, timeout=timeout) as r:
+        return r.status, r.read()
+
+
+def _mlp_predictor(feat, hidden, depth, classes):
+    import paddle_tpu as pt
+    from paddle_tpu import layers
+    from paddle_tpu.inference import Predictor
+
+    main, startup = pt.Program(), pt.Program()
+    startup._is_startup = True
+    startup.random_seed = main.random_seed = 0
+    with pt.program_guard(main, startup):
+        h = layers.data("x", [feat])
+        names = [f"smoke_fc{i}" for i in range(depth)] + ["smoke_head"]
+        for name in names:
+            last = name == names[-1]
+            h = layers.fc(h, classes if last else hidden,
+                          act=None if last else "relu",
+                          param_attr=f"{name}.w", bias_attr=f"{name}.b")
+    scope = pt.Scope()
+    pt.Executor(pt.TPUPlace()).run(startup, scope=scope)
+
+    def reference(x):
+        h = x.astype("float64")
+        for name in names:
+            h = h @ np.asarray(scope.find_var(f"{name}.w"), "float64") \
+                + np.asarray(scope.find_var(f"{name}.b"), "float64")
+            if name != names[-1]:
+                h = np.maximum(h, 0.0)
+        return h
+
+    return Predictor(main, ["x"], [h], scope=scope), reference
+
+
+def _forward_logits(gen, model, token_ids, seq):
+    """Uncached causal forward over the engine's own weights through the
+    einsum attention at "highest" precision: [len(token_ids), V]."""
+    import jax
+
+    import paddle_tpu as pt
+    from paddle_tpu.models.llama import build_llama_forward
+
+    main, startup = pt.Program(), pt.Program()
+    startup._is_startup = True
+    with pt.program_guard(main, startup):
+        _feeds, fetches = build_llama_forward(
+            1, seq, name=gen.name, attn_impl="xla", **model)
+    padded = np.zeros((1, seq), "int64")
+    padded[0, :len(token_ids)] = token_ids
+    with jax.default_matmul_precision("highest"):
+        out, = pt.Executor(pt.TPUPlace()).run(
+            main, feed={"input_ids": padded},
+            fetch_list=[fetches["logits"]], scope=gen.scope)
+    return np.asarray(out)[0, :len(token_ids)]
+
+
+def serve_phase(cfg=SERVE, on_chip=True):
+    from paddle_tpu import promtext
+    from paddle_tpu.serving import GenerationEngine, ServingEngine, serve
+
+    paths0 = attention_paths()
+    model = dict(vocab_size=cfg["vocab"], hidden=cfg["hidden"],
+                 num_layers=cfg["layers"], num_heads=cfg["heads"],
+                 num_kv_heads=cfg["heads"], intermediate=cfg["ffn"])
+    new = cfg["new_tokens"]
+    rng = np.random.RandomState(1)
+    prompts = [rng.randint(1, cfg["vocab"], (n,)).tolist()
+               for n in cfg["prompt_lens"]]
+
+    t0 = time.perf_counter()
+    predictor, mlp_reference = _mlp_predictor(**cfg["mlp"])
+    gen = GenerationEngine(
+        model, num_slots=cfg["slots"], max_seq_len=cfg["max_seq"],
+        prefill_buckets=cfg["prefill_buckets"], max_new_tokens=new,
+        paged=True, page_tokens=cfg["page_tokens"], prefill_chunk=0,
+        prefix_reuse=False, attn_impl="auto", keep_logits=True, seed=0,
+        deadline_ms=600000.0)
+    engine = ServingEngine(predictor, workers=1, max_batch=8,
+                           max_delay_ms=2.0, deadline_ms=600000.0,
+                           warmup_shapes={"x": (cfg["mlp"]["feat"],)})
+    engine.attach_generator(gen)
+    server = serve(engine)
+    try:
+        compiled = gen.warmup()
+        setup_s = time.perf_counter() - t0
+        say(f"serve: engines up, {compiled} generation programs + the "
+            f"/predict buckets compiled; KV pool "
+            f"{gen.kv_cache_bytes / 2 ** 30:.2f} GiB "
+            f"[{setup_s:.1f} s set-up]")
+
+        # /predict against a float64 numpy forward of the same weights
+        x = rng.rand(3, cfg["mlp"]["feat"]).astype("float32")
+        status, body = _http(server.url + "/predict",
+                             {"inputs": {"x": x.tolist()}})
+        got = np.asarray(json.loads(body)["outputs"][0], "float64")
+        ref = mlp_reference(x)
+        check(status == 200 and got.shape == ref.shape,
+              f"/predict answered {status} with shape {got.shape}")
+        rel = float(np.abs(got - ref).max() / np.abs(ref).max())
+        check(np.isfinite(got).all() and rel <= TOL,
+              f"/predict off the numpy reference by {rel:.4g} of its range")
+        say(f"serve: /predict {got.shape} within {rel:.4g} of the float64 "
+            f"reference's range (tolerance {TOL})")
+
+        # /generate: one prompt per prefill bucket, the middle one
+        # streamed, each against the in-process engine call
+        buckets = set()
+        for i, prompt in enumerate(prompts):
+            ref = gen.generate(prompt, new)
+            stream = i == 1
+            status, body = _http(
+                server.url + "/generate",
+                {"prompt": prompt, "max_new_tokens": new,
+                 "stream": stream})
+            check(status == 200, f"/generate answered {status}")
+            if stream:
+                lines = [json.loads(ln) for ln in body.splitlines() if ln]
+                tokens = [ln["token"] for ln in lines if "token" in ln]
+                done = lines[-1]
+                check(done.get("done") and done["tokens"] == tokens,
+                      "streamed tokens disagree with the summary line")
+            else:
+                tokens = json.loads(body)["tokens"]
+            check(len(tokens) == new and tokens == ref["tokens"],
+                  f"/generate tokens {tokens} != in-process "
+                  f"{ref['tokens']} (prompt of {len(prompt)})")
+            logits = np.stack(ref["logits"])
+            check(logits.shape == (new, cfg["vocab"])
+                  and np.isfinite(logits).all(),
+                  f"generation logits {logits.shape} not finite")
+            buckets.add(min(b for b in gen.prefill_buckets
+                            if b >= len(prompt)))
+            say(f"serve: /generate prompt {len(prompt):4d} -> {tokens}"
+                + (" (streamed)" if stream else ""))
+        check(len(buckets) >= 2, f"prompts landed in one bucket {buckets}")
+
+        # the paged prefill (Pallas) + cached decode against the uncached
+        # einsum forward, on the short prompt
+        ref = gen.generate(prompts[0], new)
+        ids = prompts[0] + ref["tokens"]
+        forward = _forward_logits(gen, model, ids, cfg["prefill_buckets"][0])
+        want = forward[len(prompts[0]) - 1:len(ids) - 1]
+        got = np.stack(ref["logits"])
+        rel = float(np.abs(got - want).max() / np.abs(want).max())
+        check(rel <= TOL,
+              f"cached generation logits off the uncached forward by "
+              f"{rel:.4g} of its range")
+        say(f"serve: {new} steps of paged prefill + cached decode within "
+            f"{rel:.4g} of the uncached einsum forward's range "
+            f"(tolerance {TOL})")
+
+        status, body = _http(server.url + "/healthz")
+        health = json.loads(body)
+        check(status == 200 and health.get("ready")
+              and health["generation"]["counters"]["served"] >= 2 * len(
+                  prompts), f"/healthz {status}: {health}")
+        status, body = _http(server.url + "/metrics")
+        text = body.decode()
+        errors = promtext.validate_lines(text)
+        check(status == 200 and not errors
+              and "serving_generate_requests" in text,
+              f"/metrics {status}: {errors[:3]}")
+        say(f"serve: /healthz ready, /metrics {len(text.splitlines())} "
+            f"valid exposition lines")
+        paths = paths_since(paths0)
+        say(f"serve: attention lowered as {paths}")
+        if on_chip:
+            # the engine holds one device whatever the host has
+            check(paths.get("pallas") and not paths.get("blockwise"),
+                  f"prefill did not lower to the Pallas kernel: {paths}")
+    finally:
+        server.close()
+    deadline = time.monotonic() + 30.0
+    while threading.active_count() > 1 and time.monotonic() < deadline:
+        time.sleep(0.1)
+    alive = [t.name for t in threading.enumerate()
+             if t is not threading.main_thread()]
+    check(not alive, f"threads alive after close(): {alive}")
+    say("serve: closed, only the main thread alive")
+    return {"paths": paths, "setup_s": setup_s}
+
+
+# ---------------------------------------------------------------------------
+
+def main():
+    t_start = time.perf_counter()
+    # the program first: in a directory that holds only this file the
+    # script must fail before it prints anything
+    import bench  # noqa: F401
+    import jax
+    from paddle_tpu.compile_cache import ensure_compile_cache
+    from paddle_tpu.monitor import stat_get
+
+    devices = jax.devices()
+    d0 = devices[0]
+    if d0.platform != "tpu":
+        print(f"chip_smoke: refusing to run: jax.devices()[0].platform is "
+              f"{d0.platform!r}, not 'tpu'.  This script proves the main "
+              f"path on the chip; on the CPU run the tests.",
+              file=sys.stderr)
+        return 2
+    import jaxlib
+
+    try:
+        import libtpu
+        libtpu_version = libtpu.__version__
+    except ImportError:
+        libtpu_version = "not importable"
+    say(f"device: platform {d0.platform}, kind {d0.device_kind}, count "
+        f"{len(devices)}; jax {jax.__version__}, jaxlib "
+        f"{jaxlib.__version__}, libtpu {libtpu_version}")
+
+    say(f"compile cache: {ensure_compile_cache()}")
+
+    t0 = time.perf_counter()
+    train = train_phase()
+    say(f"train phase done [{time.perf_counter() - t0:.1f} s]")
+    gc.collect()  # the trainer's state leaves HBM before the server loads
+
+    t0 = time.perf_counter()
+    serve = serve_phase()
+    say(f"serve phase done [{time.perf_counter() - t0:.1f} s]")
+
+    say(f"set-up (compile-dominated: kernel check + first train step + "
+        f"serving warm-up) {train['setup_s'] + serve['setup_s']:.1f} s, "
+        f"compile_cache_hits {stat_get('compile_cache_hits')}, total "
+        f"{time.perf_counter() - t_start:.1f} s")
+    print(json.dumps({"ok": True, "device": {
+        "platform": d0.platform, "kind": d0.device_kind,
+        "count": len(devices)}}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

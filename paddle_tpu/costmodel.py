@@ -12,20 +12,19 @@ does this compiled program actually cost".  Three layers use it:
   behind "why is this signature slow / big".
 * **Peak table** — :func:`device_peaks` maps ``device_kind`` → peak
   bf16 FLOP/s and HBM bytes/s (one table; ``FLAGS_device_peak_flops``
-  / ``FLAGS_device_peak_bw`` override, and the bench's historical
-  ``PEAK_TFLOPS`` env still wins for back-compat).  ``bench.py``'s two
-  previously independent MFU formulas both route through here now.
+  / ``FLAGS_device_peak_bw`` override).  A ``device_kind`` the table
+  does not know has no peak, so nothing computed against it reports a
+  utilization.  ``bench.py``'s MFU routes through here.
 * **Achieved efficiency** — :func:`mfu` / :func:`bw_util` /
   :func:`publish_achieved` turn (manifest, steps/sec) into live
   ``device_mfu`` / ``device_bw_util`` gauges on every training step.
 
 Everything degrades to ``None`` instead of raising: a backend without
-cost analysis (or an older jax) must not take down the step.
+cost analysis must not take down the step.
 """
 from __future__ import annotations
 
 import logging
-import os
 import threading
 from typing import Any, Dict, Optional
 
@@ -40,7 +39,7 @@ logger = logging.getLogger("paddle_tpu.costmodel")
 # device_kind substring -> (peak bf16 TFLOP/s, peak HBM GB/s) per chip.
 # Sources: published TPU specs (v5e 197 TF / 819 GB/s, v5p 459 / 2765,
 # v6e 918 / 1640, v4 275 / 1228, v3 123 / 900, v2 45 / 700).  First
-# match wins; unknown kinds assume v4 (the repo's historical default).
+# match wins; an unknown kind (the CPU included) has no peak.
 PEAK_TABLE = (
     ("v5 lite", 197.0, 819.0),
     ("v5e", 197.0, 819.0),
@@ -51,8 +50,6 @@ PEAK_TABLE = (
     ("v3", 123.0, 900.0),
     ("v2", 45.0, 700.0),
 )
-DEFAULT_PEAK_TFLOPS = 275.0
-DEFAULT_PEAK_GBPS = 1228.0
 
 
 def _kind_of(device) -> str:
@@ -68,10 +65,9 @@ def device_peaks(device=None) -> Dict[str, Any]:
     string, or None = the current backend's first device).
 
     Returns ``{"device_kind", "peak_flops" (FLOP/s), "peak_bw"
-    (bytes/s), "source"}`` where source records which override (env,
-    flag, table, default) produced the numbers — an operator reading
-    an MFU off ``/statusz`` needs to know whether the denominator was
-    measured config or a guess."""
+    (bytes/s), "source"}`` where source records what produced the
+    numbers (``table``, ``FLAGS_device_peak_flops``, or ``unknown`` —
+    both peaks None — for a device_kind the table does not list)."""
     if device is None:
         import sys
         jax = sys.modules.get("jax")
@@ -81,50 +77,48 @@ def device_peaks(device=None) -> Dict[str, Any]:
             except Exception as e:  # backend not initialized yet
                 logger.debug("device_peaks: no jax device: %s", e)
     kind = _kind_of(device)
-    tflops, gbps, source = None, None, "table"
+    tflops, gbps, source = None, None, "unknown"
     for key, tf, gb in PEAK_TABLE:
         if key in kind.lower():
-            tflops, gbps = tf, gb
+            tflops, gbps, source = tf, gb, "table"
             break
-    if tflops is None:
-        tflops, gbps, source = DEFAULT_PEAK_TFLOPS, DEFAULT_PEAK_GBPS, \
-            "default(v4)"
-    # overrides, strongest last: flag beats table, env beats flag (the
-    # bench's historical PEAK_TFLOPS contract)
     f = flag_value("FLAGS_device_peak_flops")
     if f:
         tflops, source = float(f), "FLAGS_device_peak_flops"
     b = flag_value("FLAGS_device_peak_bw")
     if b:
         gbps = float(b)
-    if "PEAK_TFLOPS" in os.environ:
-        tflops, source = float(os.environ["PEAK_TFLOPS"]), "PEAK_TFLOPS"
-    return {"device_kind": kind, "peak_flops": tflops * 1e12,
-            "peak_bw": gbps * 1e9, "source": source}
+    return {"device_kind": kind,
+            "peak_flops": None if tflops is None else tflops * 1e12,
+            "peak_bw": None if gbps is None else gbps * 1e9,
+            "source": source}
 
 
-def peak_flops(device=None) -> float:
-    """Per-chip peak FLOP/s (see :func:`device_peaks` for overrides)."""
+def peak_flops(device=None) -> Optional[float]:
+    """Per-chip peak FLOP/s (see :func:`device_peaks` for overrides);
+    None for an unknown device."""
     return device_peaks(device)["peak_flops"]
 
 
-def peak_bw(device=None) -> float:
-    """Per-chip peak HBM bytes/s."""
+def peak_bw(device=None) -> Optional[float]:
+    """Per-chip peak HBM bytes/s; None for an unknown device."""
     return device_peaks(device)["peak_bw"]
 
 
 def mfu(flops_per_sec: float, device=None,
-        peak: Optional[float] = None) -> float:
-    """Model FLOPs utilization: achieved FLOP/s over the chip peak."""
+        peak: Optional[float] = None) -> Optional[float]:
+    """Model FLOPs utilization: achieved FLOP/s over the chip peak;
+    None when the device has no known peak."""
     peak = peak if peak is not None else peak_flops(device)
-    return flops_per_sec / peak if peak > 0 else 0.0
+    return flops_per_sec / peak if peak else None
 
 
 def bw_util(bytes_per_sec: float, device=None,
-            peak: Optional[float] = None) -> float:
-    """HBM-bandwidth utilization: achieved bytes/s over the chip peak."""
+            peak: Optional[float] = None) -> Optional[float]:
+    """HBM-bandwidth utilization: achieved bytes/s over the chip peak;
+    None when the device has no known peak."""
     peak = peak if peak is not None else peak_bw(device)
-    return bytes_per_sec / peak if peak > 0 else 0.0
+    return bytes_per_sec / peak if peak else None
 
 
 # ---------------------------------------------------------------------------
@@ -215,8 +209,7 @@ def _cached_peaks() -> Dict[str, Any]:
     unless an override flag changes (the flags are read each call, so a
     changed override invalidates the cache)."""
     key = (flag_value("FLAGS_device_peak_flops"),
-           flag_value("FLAGS_device_peak_bw"),
-           os.environ.get("PEAK_TFLOPS"))
+           flag_value("FLAGS_device_peak_bw"))
     with _peaks_lock:
         if _peaks_cache.get("key") != key:
             _peaks_cache["key"] = key
@@ -239,12 +232,12 @@ def publish_achieved(manifest: Optional[dict], execs_per_sec: float,
     peaks = _cached_peaks()
     out = {}
     flops = manifest.get("flops")
-    if flops:
+    if flops and peaks["peak_flops"]:
         out["mfu"] = mfu(flops * execs_per_sec / max(n_devices, 1),
                          peak=peaks["peak_flops"])
         telemetry.gauge_set("device_mfu", out["mfu"])
     ba = manifest.get("bytes_accessed")
-    if ba:
+    if ba and peaks["peak_bw"]:
         out["bw_util"] = bw_util(ba * execs_per_sec / max(n_devices, 1),
                                  peak=peaks["peak_bw"])
         telemetry.gauge_set("device_bw_util", out["bw_util"])

@@ -98,11 +98,6 @@ register_flag("FLAGS_guard_resolve_interval", 64,
               "else (a fetch read, a checkpoint, close) forces it; "
               "1 restores the synchronous per-step host check, 0 defers "
               "indefinitely (fetch/checkpoint/close only)")
-register_flag("FLAGS_compile_cache_dir", "",
-              "persistent XLA compilation cache directory (jax "
-              "compilation cache; hits feed the compile_cache_hits "
-              "stat via jax's monitoring events); empty disables. Lets "
-              "TrainGuard auto-restarts skip recompilation")
 register_flag("FLAGS_feed_double_buffer", True,
               "stage numpy Executor.run feeds onto the device through a "
               "2-deep device_put ring so the H2D copy of step N+1 "
@@ -259,8 +254,7 @@ register_flag("FLAGS_histogram_buckets", "",
 register_flag("FLAGS_device_peak_flops", 0.0,
               "per-chip peak TFLOP/s override for the costmodel peak "
               "table (paddle_tpu/costmodel.py); 0 = auto from "
-              "device_kind.  The bench's PEAK_TFLOPS env var, when "
-              "set, wins over both (historical contract)")
+              "device_kind (an unknown device_kind has no peak)")
 register_flag("FLAGS_device_peak_bw", 0.0,
               "per-chip peak HBM GB/s override for the costmodel peak "
               "table; 0 = auto from device_kind")

@@ -68,7 +68,7 @@ class CompiledProgram:
         self._loss_name = None
         self._exec_strategy = None
         self._places = None
-        # (sig, fn, mut_in, const_in, mesh, mode, batch_axes)
+        # (sig, executable, mut_in, const_in, mesh, mode, batch_axes)
         self._compiled = None
 
     def with_data_parallel(self, loss_name=None, build_strategy=None,
@@ -82,8 +82,18 @@ class CompiledProgram:
         self._places = places
         return self
 
+    @property
+    def executable(self):
+        """The data-parallel step as XLA compiled it for the last feed
+        signature (a ``jax.stages.Compiled``: ``as_text()``,
+        ``input_shardings``, ``memory_analysis()``); None before the
+        first run."""
+        return self._compiled[1] if self._compiled else None
+
     # Executor.run delegates here (framework/executor.py)
     def _compile_and_run(self, exe, feed, fetch_list, scope, return_numpy):
+        import jax
+
         from ..framework.executor import _fetch_names, _prepare_feed
         if not self._is_data_parallel:
             return exe.run(self._program, feed, fetch_list, scope,
@@ -98,11 +108,6 @@ class CompiledProgram:
                     for n, a in sorted(feed_arrays.items()))
         key = (sig, tuple(fetch_names))
 
-        if self._compiled is None or self._compiled[0] != key:
-            self._compiled = (key,) + self._build(list(feed_arrays),
-                                                  fetch_names)
-        _, fn, mut_in, const_in, mesh, mode, batch_axes = self._compiled
-
         def _val(n):
             v = scope.find_var(n)
             if v is None:
@@ -110,17 +115,38 @@ class CompiledProgram:
                                    f"run the startup program first")
             return v
 
-        mut_vals = tuple(_val(n) for n in mut_in)
-        const_vals = tuple(_val(n) for n in const_in)
+        feed_vals = tuple(feed_arrays.values())
         exe._step += 1
-        if mode == "gspmd":
-            from ..parallel.sharded import shard_batch
-            feed_vals = tuple(shard_batch(mesh, list(feed_arrays.values()),
-                                          batch_axes=batch_axes))
-        else:
-            feed_vals = tuple(feed_arrays.values())
-        fetches, new_mut, _extra = fn(feed_vals, mut_vals, const_vals,
-                                      np.int32(exe._step))
+        step = np.int32(exe._step)
+        if self._compiled is None or self._compiled[0] != key:
+            fn, mut_in, const_in, *layout = self._build(
+                list(feed_arrays), fetch_names)
+            # one XLA compile, kept: the executable is the step from
+            # here on and can be inspected (``executable``)
+            executable = fn.lower(
+                feed_vals, tuple(_val(n) for n in mut_in),
+                tuple(_val(n) for n in const_in), step).compile()
+            self._compiled = (key, executable, mut_in, const_in, *layout)
+        _, executable, mut_in, const_in = self._compiled[:4]
+
+        # the executable takes its arguments where it was compiled to
+        # find them: the batch split over the mesh, and state moved to
+        # its mesh placement once (the step hands it back placed)
+        feed_sh, mut_sh, const_sh, _ = executable.input_shardings[0]
+
+        def _placed(names, shardings):
+            vals = []
+            for n, sh in zip(names, shardings):
+                v = _val(n)
+                if getattr(v, "sharding", None) != sh:
+                    v = jax.device_put(v, sh)
+                    scope.set_var(n, v)
+                vals.append(v)
+            return tuple(vals)
+
+        fetches, new_mut, _extra = executable(
+            jax.device_put(feed_vals, feed_sh), _placed(mut_in, mut_sh),
+            _placed(const_in, const_sh), step)
         for n, v in zip(mut_in, new_mut):
             scope.set_var(n, v)
         exe._last_dispatch = new_mut
@@ -157,7 +183,7 @@ class CompiledProgram:
                 for op in blk.ops)
 
         if _has_collective(self._program.global_block()):
-            fn, mut_in, const_in, extra = build_spmd_step(
+            fn, mut_in, const_in, _extra = build_spmd_step(
                 self._program, feed_names, fetch_names, mesh)
             return fn, mut_in, const_in, mesh, "spmd", batch_axes
         rules = None
@@ -167,7 +193,7 @@ class CompiledProgram:
                 import zero_mesh, zero_sharding_rules
             mesh, batch_axes = zero_mesh(n, zs.get("degree", n))
             rules = zero_sharding_rules(mesh)
-        fn, mut_in, const_in, extra = build_sharded_step(
+        fn, mut_in, const_in, _extra = build_sharded_step(
             self._program, feed_names, fetch_names, mesh, rules=rules,
             batch_axes=batch_axes)
         return fn, mut_in, const_in, mesh, "gspmd", batch_axes

@@ -189,11 +189,13 @@ class Predictor:
     def _compiled_for(self, sig, feed_arrays):
         import jax
 
+        from .compile_cache import ensure_compile_cache
         from .costmodel import executable_manifest
 
         with self._lock:
             entry = self._cache.get(sig)
             if entry is None:
+                ensure_compile_cache()
                 fn, state_vals = self._fn_and_state()
                 jitted = jax.jit(fn)
                 # AOT: compile now, at this signature.  Compiling under

@@ -10,9 +10,9 @@ device→host fence the executor pays (block_until_ready / fetch asarray /
 guard resolution; an async 50-step run should book O(1), not O(steps));
 ``guard_resolutions`` — batched resolutions of the deferred non-finite
 guard's pending verdict ring; ``compile_cache_hits`` — XLA binaries
-served from the FLAGS_compile_cache_dir persistent cache (jax's
-cache_hits monitoring event, i.e. a TrainGuard restart skipping a
-rebuild; counted process-wide).
+served from the persistent compilation cache (paddle_tpu/compile_cache.py;
+jax's cache_hits monitoring event, i.e. a restart skipping a rebuild;
+counted process-wide).
 
 program_to_dot / save_program_dot: render a Program's op/var dataflow as
 graphviz DOT — the reference attaches graph_viz_pass to pass pipelines;
@@ -144,7 +144,7 @@ def stat_add_per_device(name: str, n_devices: int, n: int = 1):
 
     An SPMD program emits each collective once at trace time but every
     device in the group executes it, so multichip attribution (e.g. the
-    MULTICHIP_r05 legs, per-shard ``/statusz`` health) needs the
+    ``dryrun_multichip`` legs, per-shard ``/statusz`` health) needs the
     per-device series.  Device-suffixed names are dynamic and therefore
     exempt from the README stat-catalog lint; the ``_dev<i>``
     convention itself is documented there."""

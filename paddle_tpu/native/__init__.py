@@ -2,52 +2,59 @@
 toolchain and loaded via ctypes.
 
 Reference analog: the C++ runtime around the compute path — here the
-DataFeed record parser (framework/data_feed.cc).  Build products are
-cached next to the sources keyed by source mtime; any build failure
-falls back to the pure-Python implementations silently (the framework
-stays functional on toolchain-less machines).
+DataFeed record parser (framework/data_feed.cc).  Build products sit
+next to the sources (git-ignored) with a stamp file holding the hash of
+the sources and flags they were built from: an artefact whose stamp
+does not match — one that travelled with a copied tree, or predates an
+edit — is rebuilt, never loaded.  A machine without ``g++`` gets None
+(callers fall back to pure Python); a build that fails where ``g++``
+exists raises with the compiler's output.
 """
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
+import shutil
 import subprocess
-from typing import Optional
+from typing import Optional, Sequence
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _LIB = None
 _TRIED = False
 
 
-def _build(src: str, out: str) -> bool:
-    try:
-        subprocess.run(["g++", "-O2", "-shared", "-fPIC", "-o", out, src],
-                       check=True, capture_output=True, timeout=120)
-        return True
-    except Exception:
-        return False
-
-
-def _build_embedded(src_name: str, out_name: str, extra_flags):
-    """mtime-cached g++ build of an embedded-python artifact; returns
-    the output path or None (no toolchain / libpython). Staleness keys
-    on the source AND the C API header it may include."""
-    src = os.path.join(_DIR, src_name)
+def _build(out_name: str, src_name: str, flags: Sequence[str],
+           link_flags: Sequence[str] = (), deps: Sequence[str] = (),
+           timeout: int = 180) -> Optional[str]:
+    """Build ``out_name`` from ``src_name`` unless its stamp says it was
+    built from exactly this source, these ``deps`` (headers) and flags.
+    Returns the artefact path, or None when there is no ``g++``."""
     out = os.path.join(_DIR, out_name)
-    header = os.path.join(_DIR, "paddle_tpu_c_api.h")
-    newest_dep = max(os.path.getmtime(src),
-                     os.path.getmtime(header)
-                     if os.path.exists(header) else 0)
-    if os.path.exists(out) and os.path.getmtime(out) >= newest_dep:
-        return out
-    cflags, ldflags = _python_flags()
-    try:
-        subprocess.run(["g++", "-O2"] + extra_flags + ["-o", out, src]
-                       + cflags + ldflags,
-                       check=True, capture_output=True, timeout=180)
-        return out
-    except Exception:
+    stamp_path = out + ".stamp"
+    h = hashlib.sha256(" ".join([*flags, *link_flags]).encode())
+    for name in (src_name, *deps):
+        with open(os.path.join(_DIR, name), "rb") as f:
+            h.update(f.read())
+    stamp = h.hexdigest()
+    if os.path.exists(out) and os.path.exists(stamp_path):
+        with open(stamp_path, encoding="utf-8") as f:
+            if f.read().strip() == stamp:
+                return out
+    if shutil.which("g++") is None:
         return None
+    # libraries follow the source on the link line
+    cmd = ["g++", "-O2", *flags, "-o", out,
+           os.path.join(_DIR, src_name), *link_flags]
+    proc = subprocess.run(cmd, capture_output=True, text=True,
+                          timeout=timeout)
+    if proc.returncode != 0:
+        raise RuntimeError(
+            f"native build of {out_name} failed "
+            f"(rc={proc.returncode}):\n{proc.stderr[-2000:]}")
+    with open(stamp_path, "w", encoding="utf-8") as f:
+        f.write(stamp + "\n")
+    return out
 
 
 def _python_flags():
@@ -58,19 +65,26 @@ def _python_flags():
     libdir = sysconfig.get_config_var("LIBDIR")
     ver = sysconfig.get_config_var("LDVERSION") or \
         sysconfig.get_config_var("VERSION")
-    return ([f"-I{inc}"],
-            [f"-L{libdir}", f"-Wl,-rpath,{libdir}", f"-lpython{ver}"])
+    return [f"-I{inc}", f"-L{libdir}", f"-Wl,-rpath,{libdir}",
+            f"-lpython{ver}"]
+
+
+def _build_embedded(out_name: str, src_name: str, flags):
+    """An artefact that embeds this interpreter and may include the C
+    API header."""
+    return _build(out_name, src_name, flags, link_flags=_python_flags(),
+                  deps=["paddle_tpu_c_api.h"])
 
 
 def build_train_demo() -> Optional[str]:
     """Compile the C++ train entry (train_demo.cc); returns the binary
-    path or None when the toolchain/libpython is unavailable."""
-    return _build_embedded("train_demo.cc", "train_demo", [])
+    path or None when there is no toolchain."""
+    return _build_embedded("train_demo", "train_demo.cc", [])
 
 
 def build_c_api() -> Optional[str]:
     """Compile the C inference ABI (capi.cc) into a shared library."""
-    return _build_embedded("capi.cc", "libpaddle_tpu_c.so",
+    return _build_embedded("libpaddle_tpu_c.so", "capi.cc",
                            ["-shared", "-fPIC"])
 
 
@@ -80,21 +94,16 @@ def datafeed_lib() -> Optional[ctypes.CDLL]:
     if _LIB is not None or _TRIED:
         return _LIB
     _TRIED = True
-    src = os.path.join(_DIR, "datafeed.cc")
-    out = os.path.join(_DIR, "libdatafeed.so")
-    if (not os.path.exists(out)
-            or os.path.getmtime(out) < os.path.getmtime(src)):
-        if not _build(src, out):
-            return None
-    try:
-        lib = ctypes.CDLL(out)
-        lib.parse_records.restype = ctypes.c_long
-        lib.parse_records.argtypes = [
-            ctypes.c_char_p, ctypes.c_long,
-            ctypes.POINTER(ctypes.c_long), ctypes.c_long,
-            ctypes.POINTER(ctypes.POINTER(ctypes.c_double)),
-            ctypes.c_long]
-        _LIB = lib
-    except OSError:
-        _LIB = None
+    out = _build("libdatafeed.so", "datafeed.cc", ["-shared", "-fPIC"],
+                 timeout=120)
+    if out is None:
+        return None
+    lib = ctypes.CDLL(out)
+    lib.parse_records.restype = ctypes.c_long
+    lib.parse_records.argtypes = [
+        ctypes.c_char_p, ctypes.c_long,
+        ctypes.POINTER(ctypes.c_long), ctypes.c_long,
+        ctypes.POINTER(ctypes.POINTER(ctypes.c_double)),
+        ctypes.c_long]
+    _LIB = lib
     return _LIB

@@ -9,8 +9,35 @@ execution per context:
 """
 from __future__ import annotations
 
+import logging
+
+from ..monitor import monitor as _monitor
 from ..parallel.mesh import SP_AXIS
 from .registry import in_var, register_op, set_out
+
+logger = logging.getLogger("paddle_tpu.ops.attention")
+
+# which implementation each attention op lowered to, counted at trace
+# time (per program build, like the collective_* stats)
+_LOWERED = {
+    "pallas": _monitor.get("attention_lowered_pallas"),
+    "blockwise": _monitor.get("attention_lowered_blockwise"),
+    "ring": _monitor.get("attention_lowered_ring"),
+    "xla": _monitor.get("attention_lowered_xla"),
+}
+_downgrades_logged = set()
+
+
+def _lowered(path, downgrade_reason=None):
+    """Book the path taken.  On a TPU backend the blockwise reference
+    is a downgrade from the Pallas kernels, not an equivalent: say so,
+    once per reason."""
+    _LOWERED[path].increase()
+    if downgrade_reason and downgrade_reason not in _downgrades_logged:
+        _downgrades_logged.add(downgrade_reason)
+        logger.warning("attention lowered to the blockwise reference on "
+                       "a TPU backend, not the Pallas kernels: %s",
+                       downgrade_reason)
 
 
 def _attn_infer(op, block):
@@ -72,12 +99,13 @@ def _flash_attention(ctx, op):
         eo = ("bhqk,bkhd->bqhd" if layout == "bshd"
               else "bhqk,bhkd->bhqd")
         out = jnp.einsum(eo, p, v)
+        _lowered("xla")
         ctx.set_output(op, "Out", out)
         return
 
     axes = getattr(ctx, "axis_names", ()) or ()
-    mesh = ctx.mesh
-    multi_device = mesh is not None and mesh.devices.size > 1
+    on_tpu = jax.default_backend() == "tpu"
+    n_mesh = ctx.mesh.devices.size if ctx.mesh is not None else 1
     if SP_AXIS in axes:
         if bias is not None:
             raise NotImplementedError(
@@ -85,16 +113,21 @@ def _flash_attention(ctx, op):
                 "not supported yet — pad-free bucketing or causal only")
         fn = ring_attention if mode == "ring" else ulysses_attention
         out = fn(q, k, v, SP_AXIS, causal=causal, sm_scale=sm_scale)
-    elif jax.default_backend() == "tpu" and not multi_device:
+        _lowered("ring")
+    elif on_tpu and n_mesh == 1:
         if bias is not None:
             out = flash_attention_bias(q, k, v, bias, causal, sm_scale)
         else:
             out = flash_attention(q, k, v, causal, sm_scale)
+        _lowered("pallas")
     else:
         # multi-device GSPMD: the einsum formulation lets the partitioner
         # shard batch/head/seq dims freely (pallas_call pins the layout)
         out, _ = blockwise_attention(q, k, v, causal=causal,
                                      sm_scale=sm_scale, bias=bias)
+        _lowered("blockwise",
+                 f"flash_attention under a {n_mesh}-device mesh"
+                 if on_tpu else None)
     ctx.set_output(op, "Out", out)
 
 
@@ -139,16 +172,16 @@ def _flash_attention_qkv(ctx, op):
     H = threeH // 3
     D = H // nh
 
-    mesh = ctx.mesh
-    multi_device = mesh is not None and mesh.devices.size > 1
-    use_kernel = (jax.default_backend() == "tpu" and not multi_device
-                  and H % 128 == 0 and D in (64, 128))
-    if use_kernel:
+    on_tpu = jax.default_backend() == "tpu"
+    n_mesh = ctx.mesh.devices.size if ctx.mesh is not None else 1
+    packable = H % 128 == 0 and D in (64, 128)
+    if on_tpu and n_mesh == 1 and packable:
         if bias is not None:
             out = flash_attention_packed_bias(qkv, bias, nh, causal,
                                               sm_scale)
         else:
             out = flash_attention_packed(qkv, nh, causal, sm_scale)
+        _lowered("pallas")
     else:
         # fallback (CPU / GSPMD meshes): blockwise online-softmax — keeps
         # O(S) attention memory so long-sequence mesh training doesn't
@@ -163,6 +196,14 @@ def _flash_attention_qkv(ctx, op):
         o, _ = blockwise_attention(q, k, v, causal=causal,
                                    sm_scale=sm_scale, bias=bias)
         out = jnp.moveaxis(o, 1, 2).reshape(B, S, H).astype(qkv.dtype)
+        reason = None
+        if on_tpu:
+            reason = (f"flash_attention_qkv under a {n_mesh}-device mesh"
+                      if n_mesh > 1 else
+                      f"flash_attention_qkv with hidden {H} / head_dim "
+                      f"{D} (kernel needs hidden % 128 == 0 and head_dim "
+                      f"64 or 128)")
+        _lowered("blockwise", reason)
     ctx.set_output(op, "Out", out)
 
 

@@ -423,12 +423,9 @@ def _conv2d_lower(ctx: LowerContext, op: Operator):
                                         ("NHWC", "OIHW", "NHWC"))
     pads = _resolve_padding(op, list(spatial),
                             [jnp.shape(w)[2], jnp.shape(w)[3]], strides, dils)
-    # no preferred_element_type=f32 here: the result was rounded straight
+    # no preferred_element_type=f32 here: the result is rounded straight
     # back to x.dtype anyway (numerically identical — XLA's TPU conv
-    # accumulates low-precision operands in f32 internally), and jax
-    # 0.4.x's conv transpose rule can't mix an f32 cotangent with bf16
-    # primals (lax.conv requires same dtypes), which broke conv2d_grad
-    # under bf16 AMP
+    # accumulates low-precision operands in f32 internally)
     out = lax.conv_general_dilated(
         x, w, window_strides=strides, padding=pads, rhs_dilation=dils,
         dimension_numbers=dn, feature_group_count=groups,

@@ -37,11 +37,30 @@ DEFAULT_BLOCK_K = 512
 NEG_INF = -1e30
 
 
-def _fit_block(block, size):
+def _fit_block(block, size, compiled=False):
+    """Largest halving of `block` that divides `size`.  For a compiled
+    (non-interpret) kernel several blocks must each span whole 128-lane
+    tiles: the kernels slice lse/delta/bias rows at `i * block` on the
+    lane dim, which Mosaic only accepts when provably 128-aligned."""
     b = min(block, size)
     while size % b:
         b //= 2
+    if compiled and b < size and b % 128:
+        raise ValueError(
+            f"flash attention: sequence length {size} has no block of "
+            f"whole 128-lane tiles dividing it (best {b}); pad the "
+            f"sequence to a multiple of 128 or keep it within one "
+            f"{block}-row block")
     return b
+
+
+def _block_loop(n_blocks, lower, upper, body, init):
+    """`fori_loop` over blocks.  A single block runs at the static index
+    0, so a block narrower than a lane tile (short prefill buckets)
+    never needs a dynamic lane offset."""
+    if n_blocks == 1:
+        return body(0, init)
+    return jax.lax.fori_loop(lower, upper, body, init)
 
 
 # ---------------------------------------------------------------------------
@@ -165,7 +184,7 @@ def _fa_fwd_kernel(q_ref, k_ref, v_ref, *rest, block_k, causal, scale,
         upper = jnp.minimum(nk, ((qi + 1) * bq + block_k - 1) // block_k)
     else:
         upper = nk
-    m, l, acc = jax.lax.fori_loop(0, upper, body, (m0, l0, acc0))
+    m, l, acc = _block_loop(nk, 0, upper, body, (m0, l0, acc0))
     l_safe = jnp.maximum(l, 1e-30)
     o_ref[0] = (acc / l_safe).astype(o_ref.dtype)
     lse_ref[0, 0] = (m + jnp.log(l_safe))[:, 0]
@@ -180,8 +199,8 @@ def _flash_forward(q, k, v, causal, sm_scale, block_q, block_k, interpret,
     B, H, Sq, D = q.shape
     Sk = k.shape[2]
     scale = sm_scale if sm_scale is not None else 1.0 / np.sqrt(D)
-    bq = _fit_block(block_q, Sq)
-    bk = _fit_block(block_k, Sk)
+    bq = _fit_block(block_q, Sq, compiled=not interpret)
+    bk = _fit_block(block_k, Sk, compiled=not interpret)
 
     qr = q.reshape(B * H, Sq, D)
     kr = k.reshape(B * H, Sk, D)
@@ -282,7 +301,7 @@ def _fa_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, *rest,
     dk0 = jnp.zeros((bk, d), jnp.float32)
     dv0 = jnp.zeros((bk, d), jnp.float32)
     db0 = jnp.zeros((bk,), jnp.float32)
-    dk, dv, db = jax.lax.fori_loop(lower, nq, body, (dk0, dv0, db0))
+    dk, dv, db = _block_loop(nq, lower, nq, body, (dk0, dv0, db0))
     dk_ref[0] = dk.astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
     if has_bias:
@@ -335,7 +354,7 @@ def _fa_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, *rest,
     else:
         upper = nk
     acc0 = jnp.zeros((bq, d), jnp.float32)
-    acc = jax.lax.fori_loop(0, upper, body, acc0)
+    acc = _block_loop(nk, 0, upper, body, acc0)
     dq_ref[0] = (acc * scale).astype(dq_ref.dtype)
 
 
@@ -348,8 +367,8 @@ def _flash_backward(q, k, v, out, lse, g, causal, sm_scale, block_q,
     B, H, Sq, D = q.shape
     Sk = k.shape[2]
     scale = sm_scale if sm_scale is not None else 1.0 / np.sqrt(D)
-    bq = _fit_block(block_q, Sq)
-    bk = _fit_block(block_k, Sk)
+    bq = _fit_block(block_q, Sq, compiled=not interpret)
+    bk = _fit_block(block_k, Sk, compiled=not interpret)
 
     # delta = rowsum(dO ⊙ O) — cheap fused XLA reduce
     delta = (g.astype(jnp.float32) * out.astype(jnp.float32)).sum(-1)
@@ -562,7 +581,7 @@ def _fp_fwd_kernel(q_ref, k_ref, v_ref, *rest, block_k, causal, scale,
                 nk, ((qi + 1) * q.shape[0] + block_k - 1) // block_k)
         else:
             upper = nk
-        m, l, acc = jax.lax.fori_loop(0, upper, body, (m0, l0, acc0))
+        m, l, acc = _block_loop(nk, 0, upper, body, (m0, l0, acc0))
         l_safe = jnp.maximum(l, 1e-30)
         outs.append(acc / l_safe)
         lse_ref[0, 0, h] = (m + jnp.log(l_safe))[:, 0]
@@ -577,8 +596,8 @@ def _packed_forward(qkv, num_heads, causal, sm_scale, block_q, block_k,
 
     B, S, H, D, HP, hpc = _packed_dims(qkv.shape, num_heads)
     scale = sm_scale if sm_scale is not None else 1.0 / np.sqrt(D)
-    bq = _fit_block(block_q, S)
-    bk = _fit_block(block_k, S)
+    bq = _fit_block(block_q, S, compiled=not interpret)
+    bk = _fit_block(block_k, S, compiled=not interpret)
 
     kernel = functools.partial(
         _fp_fwd_kernel, block_k=bk, causal=causal, scale=scale, seq_k=S,
@@ -670,7 +689,7 @@ def _fp_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, *rest,
         dk0 = jnp.zeros((bk, head_dim), jnp.float32)
         dv0 = jnp.zeros((bk, head_dim), jnp.float32)
         db0 = jnp.zeros((bk,), jnp.float32)
-        dk, dv, db = jax.lax.fori_loop(lower, nq, body, (dk0, dv0, db0))
+        dk, dv, db = _block_loop(nq, lower, nq, body, (dk0, dv0, db0))
         dk_parts.append(dk)
         dv_parts.append(dv)
         db_acc = db if db_acc is None else db_acc + db
@@ -737,7 +756,7 @@ def _fp_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, *rest,
         else:
             upper = nk
         acc0 = jnp.zeros((bq, head_dim), jnp.float32)
-        acc = jax.lax.fori_loop(0, upper, body, acc0)
+        acc = _block_loop(nk, 0, upper, body, acc0)
         dq_parts.append(acc * scale)
     dq_ref[0] = jnp.concatenate(dq_parts, axis=1).astype(dq_ref.dtype)
 
@@ -750,8 +769,8 @@ def _packed_backward(qkv, num_heads, out, lse, g, causal, sm_scale,
 
     B, S, H, D, HP, hpc = _packed_dims(qkv.shape, num_heads)
     scale = sm_scale if sm_scale is not None else 1.0 / np.sqrt(D)
-    bq = _fit_block(block_q, S)
-    bk = _fit_block(block_k, S)
+    bq = _fit_block(block_q, S, compiled=not interpret)
+    bk = _fit_block(block_k, S, compiled=not interpret)
     has_bias = bias is not None
 
     # delta = rowsum(dO ⊙ O) per head, laid out to match lse

@@ -15,24 +15,12 @@ import numpy as np
 
 
 def shard_map_compat(f, mesh, in_specs, out_specs, check=False):
-    """`shard_map` across jax versions: new jax exposes `jax.shard_map`
-    with `check_vma=`, 0.4.x has `jax.experimental.shard_map.shard_map`
-    with `check_rep=`.  `check=False` disables the replication/VMA
-    checker either way (our bodies mix collectives the checker can't
-    type)."""
-    import inspect
+    """`jax.shard_map` with the VMA checker off by default (our bodies
+    mix collectives the checker can't type)."""
+    import jax
 
-    try:
-        from jax import shard_map as sm
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as sm
-    params = inspect.signature(sm).parameters
-    kw = {}
-    if "check_vma" in params:
-        kw["check_vma"] = check
-    elif "check_rep" in params:
-        kw["check_rep"] = check
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check)
 
 
 # Canonical axis names used across the framework.

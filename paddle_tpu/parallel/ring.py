@@ -30,16 +30,6 @@ from ..ops.pallas.flash_attention import (NEG_INF, blockwise_attention)
 
 
 
-def _axis_size(axis_name):
-    """lax.axis_size across jax versions (0.4.x lacks it; psum of a
-    constant 1 constant-folds to the mesh axis size at trace time)."""
-    import jax.lax as lax
-
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis_name)
-    return lax.psum(1, axis_name)
-
-
 def ring_attention(q, k, v, axis_name: str, causal: bool = False,
                    sm_scale=None):
     """Attention over a sequence sharded on `axis_name` (inside
@@ -49,7 +39,7 @@ def ring_attention(q, k, v, axis_name: str, causal: bool = False,
     import jax.lax as lax
     import jax.numpy as jnp
 
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     B, H, Sl, D = q.shape
     scale = sm_scale if sm_scale is not None else 1.0 / np.sqrt(D)
@@ -86,11 +76,9 @@ def ring_attention(q, k, v, axis_name: str, causal: bool = False,
     l0 = jnp.zeros((B, H, Sl), jnp.float32)
     acc0 = jnp.zeros((B, H, Sl, D), jnp.float32)
     # mark the device-constant initializers as varying over the ring axis
-    # so the scan carry type matches the per-device accumulation (pvary
-    # is the new-jax VMA annotation; 0.4.x has no VMA typing to satisfy)
-    if hasattr(lax, "pvary"):
-        m0, l0, acc0 = (lax.pvary(x, (axis_name,))
-                        for x in (m0, l0, acc0))
+    # so the scan carry type matches the per-device accumulation
+    m0, l0, acc0 = (lax.pcast(x, (axis_name,), to="varying")
+                    for x in (m0, l0, acc0))
     (m, l, acc, _, _), _ = jax.lax.scan(
         step, (m0, l0, acc0, k, v), jnp.arange(n))
     out = acc / jnp.maximum(l, 1e-30)[..., None]
@@ -104,7 +92,7 @@ def ulysses_attention(q, k, v, axis_name: str, causal: bool = False,
     scatter back."""
     import jax.lax as lax
 
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     B, H, Sl, D = q.shape
     if H % n:
         raise ValueError(f"ulysses: heads {H} not divisible by group {n}")

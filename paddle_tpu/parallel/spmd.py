@@ -17,6 +17,7 @@ from typing import Dict, Optional, Sequence
 
 import numpy as np
 
+from ..compile_cache import ensure_compile_cache
 from ..framework.core import Program
 from ..framework.executor import analyze_block
 from ..ops.registry import LowerContext, lower_op
@@ -99,5 +100,6 @@ def build_spmd_step(program: Program, feed_names: Sequence[str],
         out_specs=(tuple(P(batch_axis) for _ in fetch_names), mut_spec,
                    tuple(P() for _ in extra_out)))
 
+    ensure_compile_cache()
     fn = jax.jit(mapped, donate_argnums=(1,) if donate_state else ())
     return fn, mut_in, const_in, extra_out

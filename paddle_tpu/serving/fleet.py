@@ -55,6 +55,15 @@ own port, metrics dir, and ``PADDLE_TPU_REPLICA_ID`` env.
   same port, re-swap the fresh process — so a rollout converges even
   when a replica's live state has drifted.
 
+* **One chip per replica.** On a TPU host every replica is a JAX
+  process that needs a chip, and a chip belongs to one process.
+  :meth:`start` counts the host's chips without touching JAX
+  (:func:`local_tpu_chips`), refuses more replicas than chips and a
+  supervisor process that has itself initialised JAX (it would hold
+  the chips), and pins replica ``i`` to chip ``i`` for every life
+  through its environment.  On a CPU host (``JAX_PLATFORMS=cpu``)
+  none of this applies.
+
 * **Postmortem pipeline.** Every replica death is harvested for the
   flight-recorder artifacts its life left in
   ``<metrics_dir>/postmortem/`` (self-dumps, the rolling dump, the
@@ -73,6 +82,7 @@ Stats (README catalog): counters ``fleet_restarts``,
 """
 from __future__ import annotations
 
+import glob
 import json
 import logging
 import os
@@ -90,7 +100,7 @@ from ..distributed.launch import spawn_process
 from ..flags import flag_value
 from ..monitor import stat_add
 
-__all__ = ["FleetSupervisor"]
+__all__ = ["FleetSupervisor", "local_tpu_chips"]
 
 logger = logging.getLogger("paddle_tpu.serving.fleet")
 
@@ -113,6 +123,28 @@ def _healthz(url: str, timeout: float = 2.0) -> Optional[dict]:
             return json.loads(r.read())
     except (OSError, TimeoutError, ValueError):
         return None
+
+
+def local_tpu_chips(env: Optional[Dict[str, str]] = None) -> int:
+    """TPU chips a JAX process started with ``env`` (default: this
+    process's environment) would find on this host — 0 when
+    ``JAX_PLATFORMS`` keeps it on the CPU.  Counted from the device
+    nodes libtpu itself enumerates, never through JAX: a process that
+    initialises JAX takes the chips."""
+    env = os.environ if env is None else env
+    platforms = env.get("JAX_PLATFORMS", "")
+    if platforms and "tpu" not in platforms.split(","):
+        return 0
+    return len(glob.glob("/dev/accel[0-9]*")
+               or glob.glob("/dev/vfio/[0-9]*"))
+
+
+def _chip_env(idx: int) -> Dict[str, str]:
+    """Environment that shows a replica exactly one chip of the host
+    (libtpu's single-host multi-process settings)."""
+    return {"TPU_VISIBLE_CHIPS": str(idx),
+            "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+            "TPU_PROCESS_BOUNDS": "1,1,1"}
 
 
 class _Replica:
@@ -209,6 +241,7 @@ class FleetSupervisor:
         self._closing = threading.Event()
         self._monitor: Optional[threading.Thread] = None
         self._liveness: Optional[threading.Thread] = None
+        self._chips = 0   # TPU chips replicas are pinned to (start())
         self._started = time.time()
         if autostart:
             self.start()
@@ -236,6 +269,8 @@ class FleetSupervisor:
             "PADDLE_TPU_REPLICA_ID": str(rep.idx),
             "FLAGS_metrics_dir": rep.metrics_dir,
         })
+        if self._chips:
+            env.update(_chip_env(rep.idx))
         rep.proc = spawn_process(cmd, env, rep.log_path,
                                  restart_count=rep.lives)
         rep.lives += 1
@@ -247,7 +282,29 @@ class FleetSupervisor:
                     rep.port or "ephemeral")
         self._publish_live()
 
+    def _claim_chips(self) -> int:
+        """Chips the replicas will be pinned to (0 on a CPU host);
+        raises when the host cannot give every replica its own."""
+        chips = local_tpu_chips({**os.environ, **self.env})
+        if not chips:
+            return 0
+        if self.n > chips:
+            raise RuntimeError(
+                f"FleetSupervisor: {self.n} replicas on a host with "
+                f"{chips} TPU chip(s); a chip serves one process, so "
+                f"the extra replicas could never become ready")
+        from jax._src import xla_bridge
+
+        if xla_bridge.backends_are_initialized():
+            raise RuntimeError(
+                "FleetSupervisor: this process has initialised JAX and "
+                "holds the host's TPU chips; replicas could not get "
+                "one.  Start the fleet from a process that stays off "
+                "JAX")
+        return chips
+
     def start(self):
+        self._chips = self._claim_chips()
         for rep in self._replicas:
             if rep.proc is None:
                 self._spawn(rep)

@@ -412,8 +412,10 @@ class GenerationEngine:
                  prefix_reuse=None, role=None, speculate=None,
                  spec_tokens=None, spec_ngram=None):
         import paddle_tpu as pt
+        from ..compile_cache import ensure_compile_cache
         from ..models.llama import build_llama_decode, build_llama_prefill
 
+        ensure_compile_cache()
         self.model = dict(model)
         self.name = name
         self.attn_impl = attn_impl

@@ -273,11 +273,13 @@ class ShardedPredictor(Predictor):
         import jax
         from jax.sharding import NamedSharding, PartitionSpec as P
 
+        from ..compile_cache import ensure_compile_cache
         from ..costmodel import executable_manifest
 
         with self._lock:
             entry = self._cache.get(sig)
             if entry is None:
+                ensure_compile_cache()
                 fn, state_vals = self._fn_and_state()
                 feed_sh = tuple(self._feed_sharding(a)
                                 for a in feed_arrays)

@@ -17,7 +17,6 @@ from paddle_tpu.train_guard import TrainGuard
 def _default_flags():
     yield
     pt.set_flags({"FLAGS_guard_resolve_interval": 64,
-                  "FLAGS_compile_cache_dir": "",
                   "FLAGS_feed_double_buffer": True})
 
 
@@ -196,22 +195,47 @@ def test_feed_double_buffer_stages_device_arrays():
 # persistent compile cache
 # ---------------------------------------------------------------------------
 
-def test_compile_cache_hits_across_executors(tmp_path):
-    pt.set_flags({"FLAGS_compile_cache_dir": str(tmp_path)})
-    loss = _net()
-    feed = _feed()
-    exe = _startup()
-    exe.run(feed=feed, fetch_list=[loss])
-    assert os.listdir(str(tmp_path)), "no persistent cache entries written"
+def test_compile_cache_hits_across_executors(tmp_path, monkeypatch):
+    """A cache placed from outside (JAX_COMPILATION_CACHE_DIR): the
+    program sets no directory of its own, the cache lands there, and a
+    restarted executor's hit feeds the stat."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache as cc
 
-    # a "restarted" executor (fresh jit cache, same program): jax serves
-    # the XLA binary from disk and its cache_hits monitoring event feeds
-    # the stat
-    h0 = stat_get("compile_cache_hits")
-    exe2 = pt.Executor()
-    exe2.run(pt.default_startup_program())
-    exe2.run(feed=feed, fetch_list=[loss])
-    assert stat_get("compile_cache_hits") >= h0 + 1
+    from paddle_tpu import compile_cache
+
+    # jax reads the variable at import; mid-process, set what it would
+    # have read.  jax persists only programs that took about a second to
+    # compile; this one is tiny, so drop its thresholds for the test.
+    knobs = {"jax_compilation_cache_dir": str(tmp_path),
+             "jax_persistent_cache_min_compile_time_secs": 0.0,
+             "jax_persistent_cache_min_entry_size_bytes": -1}
+    old = {k: getattr(jax.config, k) for k in knobs}
+    for k, v in knobs.items():
+        jax.config.update(k, v)
+    cc.reset_cache()
+    monkeypatch.setenv(compile_cache.ENV_VAR, str(tmp_path))
+    try:
+        loss = _net()
+        feed = _feed()
+        exe = _startup()
+        exe.run(feed=feed, fetch_list=[loss])
+        assert jax.config.jax_compilation_cache_dir == str(tmp_path)
+        assert os.listdir(str(tmp_path)), \
+            "no persistent cache entries written"
+
+        # a "restarted" executor (fresh jit cache, same program): jax
+        # serves the XLA binary from disk and its cache_hits monitoring
+        # event feeds the stat
+        h0 = stat_get("compile_cache_hits")
+        exe2 = pt.Executor()
+        exe2.run(pt.default_startup_program())
+        exe2.run(feed=feed, fetch_list=[loss])
+        assert stat_get("compile_cache_hits") >= h0 + 1
+    finally:
+        for k, v in old.items():
+            jax.config.update(k, v)
+        cc.reset_cache()
 
 
 # ---------------------------------------------------------------------------

@@ -138,10 +138,10 @@ def test_stat_catalog_lint_catches_undocumented_name(tmp_path):
     assert r.returncode == 0, r.stdout
 
 
-def test_perf_gate_smoke_on_committed_fixtures():
+def test_perf_gate_smoke():
     """tools/perf_gate.py --smoke: the perf-regression gate's pass/fail
-    logic validated against the checked-in BENCH_r0*.json and
-    op_bench_baseline.json fixtures — no benchmark run.  This keeps the
+    logic validated against synthetic reports and the
+    op_bench_baseline.json fixture — no benchmark run.  This keeps the
     gate itself load-bearing: a gate that silently stopped failing on
     regressions is worse than no gate."""
     r = subprocess.run(

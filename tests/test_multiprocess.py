@@ -9,16 +9,10 @@ import subprocess
 import sys
 
 import numpy as np
-import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-@pytest.mark.xfail(
-    reason="this image's jax 0.4.37 XLA CPU backend raises "
-           "'Multiprocess computations aren't implemented on the CPU "
-           "backend' for cross-process collectives (works on real "
-           "TPU/GPU backends)", strict=False)
 def test_launch_two_process_ring(tmp_path):
     script = os.path.join(os.path.dirname(__file__), "dist_worker.py")
     env = dict(os.environ)

@@ -74,22 +74,19 @@ def test_peak_table_and_overrides():
     p = costmodel.device_peaks("TPU v5 lite")
     assert p["peak_flops"] == 197.0e12 and p["peak_bw"] == 819.0e9
     assert costmodel.device_peaks("TPU v5p")["peak_flops"] == 459.0e12
+    # a device the table does not list has no peak and no utilization
+    # (it used to be handed a v4's)
     unknown = costmodel.device_peaks("mystery chip")
-    assert unknown["source"] == "default(v4)"
+    assert unknown["source"] == "unknown"
+    assert unknown["peak_flops"] is None and unknown["peak_bw"] is None
+    assert costmodel.mfu(1e12, "mystery chip") is None
+    assert costmodel.bw_util(1e9, "cpu") is None
     pt.set_flags({"FLAGS_device_peak_flops": 100.0,
                   "FLAGS_device_peak_bw": 500.0})
     try:
         p = costmodel.device_peaks("TPU v5 lite")
         assert p["peak_flops"] == 100.0e12 and p["peak_bw"] == 500.0e9
         assert p["source"] == "FLAGS_device_peak_flops"
-        # the bench's historical env contract wins over the flag
-        os.environ["PEAK_TFLOPS"] = "42"
-        try:
-            p = costmodel.device_peaks("TPU v5 lite")
-            assert p["peak_flops"] == 42.0e12
-            assert p["source"] == "PEAK_TFLOPS"
-        finally:
-            del os.environ["PEAK_TFLOPS"]
     finally:
         pt.set_flags({"FLAGS_device_peak_flops": 0.0,
                       "FLAGS_device_peak_bw": 0.0})
@@ -101,9 +98,19 @@ def test_executor_entry_carries_manifest_and_feeds_gauges():
     loss = _net()
     exe = pt.Executor()
     exe.run(pt.default_startup_program())
-    for i in range(3):
-        exe.run(pt.default_main_program(), feed=_feed(i),
-                fetch_list=[loss])
+    # the CPU has no entry in the peak table: without a declared peak
+    # a step publishes no utilization at all
+    assert costmodel.publish_achieved(
+        {"flops": 1e9, "bytes_accessed": 1e6}, 10.0) is None
+    pt.set_flags({"FLAGS_device_peak_flops": 1.0,
+                  "FLAGS_device_peak_bw": 1.0})
+    try:
+        for i in range(3):
+            exe.run(pt.default_main_program(), feed=_feed(i),
+                    fetch_list=[loss])
+    finally:
+        pt.set_flags({"FLAGS_device_peak_flops": 0.0,
+                      "FLAGS_device_peak_bw": 0.0})
     info = exe.cache_info()
     assert info["compiled"] >= 2  # startup + train step
     step_entries = [e for e in info["entries"]
@@ -332,7 +339,9 @@ def test_profilez_endpoint_contract(tmp_path):
                                     timeout=30) as r:
             statusz = json.loads(r.read())
         dev = statusz["device"]
-        assert dev["peaks"]["peak_flops"] > 0
+        # served from the CPU: a device the peak table does not know
+        assert dev["peaks"]["source"] == "unknown"
+        assert dev["peaks"]["peak_flops"] is None
         assert dev["hbm"]["live_bytes"] is None \
             or dev["hbm"]["live_bytes"] >= 0
         # manifests ride the executable inventory
@@ -471,12 +480,14 @@ def test_perf_gate_pass_fail_matrix():
         os.unlink(f.name)
 
 
-def test_perf_gate_cli_against_committed_baseline():
-    """The acceptance check: BENCH_r05 vs itself passes; a degraded
+def test_perf_gate_cli_against_a_baseline_file(tmp_path):
+    """The acceptance check: a report vs itself passes; a degraded
     copy fails with exit 1."""
     pg = _load_tool("perf_gate")
     gate = os.path.join(REPO, "tools", "perf_gate.py")
-    base = os.path.join(REPO, "BENCH_r05.json")
+    base = str(tmp_path / "base.json")
+    with open(base, "w") as f:
+        json.dump(pg.smoke_trajectory()[-1], f)
     r = subprocess.run(
         [sys.executable, gate, "--report", base, "--baseline", base],
         capture_output=True, text=True, timeout=60)

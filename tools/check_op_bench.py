@@ -3,8 +3,8 @@ tools/check_op_benchmark_result.py).
 
 Compares an op_bench.py results JSON against the committed baseline and
 fails (exit 1) when any op regressed by more than --threshold (default
-50% — the shared v5e chip drifts +-10% between runs with byte-identical
-programs, so a tight gate would flap; 1.5x catches real lowering
+50% — runs of byte-identical programs were seen to drift +-10%, so a
+tight gate would flap; 1.5x catches real lowering
 regressions like a fusion break or an accidental f32 fallback).
 
 Usage:

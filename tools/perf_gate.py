@@ -3,14 +3,14 @@
 trajectory, with noise-aware tolerances.
 
 Makes the numbers load-bearing (ROADMAP item 5): a perf PR runs the
-bench, then this gate compares the fresh report against the checked-in
-``BENCH_r*.json`` baselines (and optionally an ``op_bench.py`` report
-against ``tools/op_bench_baseline.json``) and **exits nonzero on
-regression** — a capacity or step-time regression fails loudly instead
-of shipping silently.
+bench, then this gate compares the fresh report against baseline
+``bench.py`` reports (and optionally an ``op_bench.py`` report against
+``tools/op_bench_baseline.json``) and **exits nonzero on regression** —
+a capacity or step-time regression fails loudly instead of shipping
+silently.
 
-Noise model: the shared chip drifts ±10% between runs with
-byte-identical programs (bench.py module docstring), and every bench
+Noise model: runs of byte-identical programs were seen to drift ±10%
+(bench.py module docstring), and every bench
 leg records its own window spread as ``stats.p10``/``stats.p90``.  The
 per-leg tolerance is therefore::
 
@@ -26,18 +26,17 @@ gate anything).
 
 Usage::
 
-    python tools/perf_gate.py --report fresh.json --baseline BENCH_r05.json
-        [--baseline BENCH_r04.json ...]     # trajectory: last match wins
+    python tools/perf_gate.py --report fresh.json --baseline base.json
+        [--baseline older.json ...]         # trajectory: last match wins
         [--op-report ops.json [--op-baseline tools/op_bench_baseline.json]]
         [--floor-tol 0.10] [--op-threshold 1.5]
-    python tools/perf_gate.py --smoke       # self-test on committed
-        fixtures (no benchmark run) — wired into tier-1 via
+    python tools/perf_gate.py --smoke       # self-test on synthetic
+        reports (no benchmark run) — wired into tier-1 via
         tests/test_lint.py
 """
 from __future__ import annotations
 
 import argparse
-import glob
 import json
 import os
 import sys
@@ -472,7 +471,7 @@ def compare_leg(name: str, new: dict, base: dict,
                threshold=round(threshold, 2))
     if new.get("anomaly"):
         # an anomalous fresh number can't prove health — but it also
-        # must not fail the gate on chip contention; surface it loudly
+        # must not fail the gate on a noisy window; surface it loudly
         res.update(status="skipped",
                    reason=f"fresh run flagged anomalous: "
                           f"{new['anomaly']}")
@@ -688,15 +687,32 @@ def _degrade(doc: dict, factor: float) -> dict:
     return out
 
 
+def smoke_trajectory() -> List[dict]:
+    """Two synthetic reports in the shape ``bench.py`` prints (a
+    flagship plus its seq512 leg), older first.  Made-up values on a
+    made-up device: the repo holds no capture of this installation."""
+    def leg(metric, median):
+        return {"metric": metric, "value": median, "unit": "samples/sec",
+                "device_kind": "smoke-device", "anomaly": None,
+                "stats": {"windows": 6, "steps_per_window": 5,
+                          "median": median, "p10": median * 0.99,
+                          "p90": median * 1.01, "min": median * 0.985,
+                          "max": median * 1.015}}
+
+    docs = []
+    for scale in (0.95, 1.0):
+        doc = leg("smoke_flagship", 1000.0 * scale)
+        doc["legs"] = {"seq512": leg("smoke_seq512", 300.0 * scale)}
+        docs.append(doc)
+    return docs
+
+
 def run_smoke() -> int:
-    """Assert the gate's pass/fail behavior against the checked-in
-    BENCH_r0*.json + op_bench_baseline.json fixtures.  Returns 0 when
-    every assertion holds (tier-1 wires this via tests/test_lint.py)."""
-    fixtures = sorted(glob.glob(os.path.join(REPO, "BENCH_r0*.json")))
-    if not fixtures:
-        print("smoke: no BENCH_r0*.json fixtures found")
-        return 1
-    docs = [load_report(p) for p in fixtures]
+    """Assert the gate's pass/fail behavior against synthetic reports
+    (:func:`smoke_trajectory`) + the op_bench_baseline.json fixture.
+    Returns 0 when every assertion holds (tier-1 wires this via
+    tests/test_lint.py)."""
+    docs = smoke_trajectory()
     latest = docs[-1]
     checks = []
 
