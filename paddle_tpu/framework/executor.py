@@ -19,9 +19,13 @@ Randomness is stateless: a per-run step counter is folded into a base key
 derived from program.random_seed (replaces cuRAND generator state).
 
 Telemetry (paddle_tpu/telemetry.py; all opt-out via ``FLAGS_telemetry=0``):
-every compiled run opens an ``executor/step`` span with
-``executor/compile`` (jit build), ``executor/dispatch`` (the compiled
-call), and ``executor/fetch`` (blocking host reads) children; the host
+every compiled run opens an ``executor/step`` span whose children name
+the run's host phases in order: ``executor/prepare`` (feed conversion,
+signature, cache lookup), ``executor/compile`` (jit build, on a miss),
+``executor/gather_state`` (scope reads), ``executor/stage_feed`` (H2D
+staging), ``executor/dispatch`` (the compiled call),
+``executor/commit_state`` (scope writes, efficiency gauges) and
+``executor/fetch`` (blocking host reads); the host
 wall time per run feeds the ``executor_step_host_ms`` histogram and the
 ``examples_per_sec`` gauge / heartbeat via ``telemetry.note_step``, the
 feed double-buffer depth feeds the ``feed_ring_occupancy`` gauge, and
@@ -424,6 +428,9 @@ class Executor:
         the throughput gauge without re-inspecting the feed."""
         import jax
 
+        # phase spans: a raise inside one is unwound by run()'s
+        # span_end(executor/step), which closes everything above it
+        phase = _telemetry.span_begin("executor/prepare")
         block = program.global_block()
         feed_arrays = _prepare_feed(block, feed)
         # .dtype directly: np.asarray on a device array would round-trip
@@ -446,6 +453,7 @@ class Executor:
                guard_loss)
 
         entry = self._cache.get(key) if use_program_cache else None
+        _telemetry.span_end(phase)
         if entry is None:
             _JIT_STAT.increase()
             ensure_compile_cache()
@@ -468,9 +476,14 @@ class Executor:
                     f"the startup program first?")
             return val
 
+        phase = _telemetry.span_begin("executor/gather_state",
+                                      vars=len(mut_in) + len(const_in))
         mut_vals = tuple(_val(n) for n in mut_in)
         const_vals = tuple(_val(n) for n in const_in)
+        _telemetry.span_end(phase)
+        phase = _telemetry.span_begin("executor/stage_feed")
         feed_vals = self._stage_feed(feed_arrays)
+        _telemetry.span_end(phase)
 
         self._step += 1
         _STEP_STAT.increase()
@@ -513,6 +526,8 @@ class Executor:
             fetches, new_state = out_vals
             ok = None
         _telemetry.span_end(dspan)
+        phase = _telemetry.span_begin("executor/commit_state",
+                                      vars=len(state_out))
         self._publish_efficiency(entry, new_state or fetches)
         if bench:
             t_dispatch = time.perf_counter() - t0
@@ -523,6 +538,7 @@ class Executor:
                   f"(host dispatch {t_dispatch * 1e3:.3f} ms)")
         for name, val in zip(state_out, new_state):
             scope.set_var(name, val)
+        _telemetry.span_end(phase)
         self._last_dispatch = new_state if new_state else fetches
         if guarded:
             # deferred verdict: keep the on-device scalar; the host learns

@@ -4,7 +4,9 @@ The host-side telemetry plane (spans, metrics, traces) sees dispatches;
 this module watches the **device**:
 
 * **HBM timeline** — :class:`HbmSampler`, a daemon thread sampling
-  jax's live-buffer bytes every ``FLAGS_hbm_sample_interval`` seconds:
+  the devices' bytes in use (the allocator's own count on a chip; the
+  sum over ``jax.live_arrays()`` on a backend that keeps none) every
+  ``FLAGS_hbm_sample_interval`` seconds:
   feeds the ``hbm_live_bytes`` gauge, the ``hbm_peak_bytes`` high
   watermark (``Gauge.set_max`` — the spike a poll misses), per-device
   ``hbm_live_bytes_dev<i>`` gauges on multichip meshes, and a Perfetto
@@ -35,6 +37,7 @@ from typing import Dict, Optional
 
 from . import telemetry
 from .flags import flag_value
+from .memory import memory_stats
 from .monitor import stat_add
 
 __all__ = ["device_live_bytes", "HbmSampler", "start_hbm_sampler",
@@ -52,38 +55,53 @@ MAX_CAPTURE_SEC = 60.0
 # ---------------------------------------------------------------------------
 
 def device_live_bytes() -> Optional[Dict[str, int]]:
-    """Live jax buffer bytes, total and per device index:
+    """Live device bytes, total and per device index:
     ``{"total": N, "per_device": {0: n0, 1: n1, ...}}``.
 
-    Sharded arrays attribute each addressable shard to its own device;
-    unsharded ones land on their single device.  Returns None when jax
-    is not imported yet (must not force a backend init) or the probe
-    fails."""
+    Where the backend keeps allocator statistics (TPU does) this is
+    each local device's ``memory_stats()["bytes_in_use"]``: one runtime
+    call per device, no Python object touched.  Backends without them
+    (CPU) fall back to walking ``jax.live_arrays()``, which holds the
+    GIL for every live buffer.  Returns None when jax is not imported
+    yet (must not force a backend init) or the probe fails."""
     import sys
     jax = sys.modules.get("jax")
     if jax is None:
         return None
     try:
         per: Dict[int, int] = {}
-        total = 0
-        for a in jax.live_arrays():
-            nbytes = int(getattr(a, "nbytes", 0) or 0)
-            total += nbytes
-            try:
-                shards = a.addressable_shards
-            except Exception:
-                shards = None
-            if shards:
-                for s in shards:
-                    di = int(getattr(s.device, "id", 0))
-                    per[di] = per.get(di, 0) + int(
-                        getattr(s.data, "nbytes", 0) or 0)
-            else:
-                per[0] = per.get(0, 0) + nbytes
-        return {"total": total, "per_device": per}
+        for d in jax.local_devices():
+            stats = memory_stats(d)     # {} where the backend keeps none
+            if "bytes_in_use" not in stats:
+                return _live_array_bytes(jax)
+            per[int(d.id)] = int(stats["bytes_in_use"])
+        return {"total": sum(per.values()), "per_device": per}
     except Exception as e:
         logger.debug("live-buffer probe failed: %s", e)
         return None
+
+
+def _live_array_bytes(jax) -> Dict[str, int]:
+    """The fallback of :func:`device_live_bytes`: sharded arrays
+    attribute each addressable shard to its own device; unsharded ones
+    land on their single device."""
+    per: Dict[int, int] = {}
+    total = 0
+    for a in jax.live_arrays():
+        nbytes = int(getattr(a, "nbytes", 0) or 0)
+        total += nbytes
+        try:
+            shards = a.addressable_shards
+        except Exception:
+            shards = None
+        if shards:
+            for s in shards:
+                di = int(getattr(s.device, "id", 0))
+                per[di] = per.get(di, 0) + int(
+                    getattr(s.data, "nbytes", 0) or 0)
+        else:
+            per[0] = per.get(0, 0) + nbytes
+    return {"total": total, "per_device": per}
 
 
 class HbmSampler:

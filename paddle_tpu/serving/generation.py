@@ -118,12 +118,12 @@ each fails only the then-active requests),
 ``serving_spec_tokens_proposed``, ``serving_spec_tokens_accepted``,
 ``serving_spec_rollbacks``; gauges
 ``serving_spec_acceptance_rate``,
-``serving_slot_occupancy``, ``serving_prefill_decode_ratio``,
+``serving_slot_occupancy``,
 ``serving_kv_cache_bytes`` (allocated cache capacity — the page pool
 in paged mode, the dense reservation otherwise),
 ``serving_kv_live_bytes`` (bytes of pages actually referenced by live
 sequences or the prefix index), ``serving_kv_pages_free``,
-``serving_kv_pages_live``, ``serving_decode_mfu``; histograms
+``serving_kv_pages_live``; histograms
 ``serving_generate_ms``, ``serving_prefill_ms``,
 ``serving_decode_step_ms``, ``serving_spec_verify_ms``,
 ``serving_ttft_ms``, ``serving_inter_token_ms``.
@@ -152,11 +152,6 @@ __all__ = ["GenerationEngine", "GenRequest", "PagePool", "PrefixIndex",
            "PoolExhausted", "ngram_draft"]
 
 logger = logging.getLogger("paddle_tpu.serving.generation")
-
-# decode-MFU gauge refresh cadence (steps) — cheap, but no need to pay
-# a costmodel lookup every token
-_MFU_EVERY = 16
-
 
 class GenRequest:
     """One queued generation request."""
@@ -607,6 +602,9 @@ class GenerationEngine:
         self._tail_keep = max(0, int(
             flag_value("FLAGS_trace_tail_keep") or 8))
         self._occ_vec: Optional[tuple] = None  # last slot-track sample
+        # seconds this scheduler iteration blocked on the device
+        # (scheduler thread only: _end_device_wait)
+        self._iter_wait_s = 0.0
 
         if autostart:
             self.start()
@@ -1415,6 +1413,7 @@ class GenerationEngine:
             # immediately when no swap is pending)
             self._apply_pending_swap()
             with self._cv:
+                waited = None
                 while True:
                     if self._queue and self._can_claim_locked():
                         break
@@ -1423,71 +1422,119 @@ class GenerationEngine:
                     if self._pending_swap is not None:
                         break  # an idle grid must still commit swaps
                     if self._draining and not self._queue:
+                        telemetry.span_end(waited)
                         return
+                    if waited is None:
+                        waited = telemetry.span_begin(
+                            "generation/wait_work",
+                            queued=len(self._queue))
                     self._cv.wait(0.02)
+                telemetry.span_end(waited)
+                # one iteration = one trace of its own (the stack is
+                # empty here); every phase below hangs under it on
+                # this thread's stack
+                it = telemetry.span_begin("generation/iteration")
+                cpu0 = time.thread_time()
+                claim = telemetry.span_begin("generation/claim")
                 claimed = self._claim_locked()
-            for slot, req in claimed:
-                try:
-                    self._begin(slot, req)
-                except PoolExhausted as e:
-                    # segment adoption allocates its pages at claim
-                    # time: exhaustion is the SAME transient the
-                    # prefill path sees — evictions already ran, so
-                    # requeue behind live sequences (or fail when the
-                    # pool can never hold it)
-                    self._requeue_or_fail(slot, e)
-                except Exception as e:  # noqa: BLE001 — a prefill/adopt
-                    # failure must not kill the scheduler: exactly this
-                    # request errors, the grid keeps decoding
-                    self._fail_request(slot, req,
-                                       "adopt" if req.segment is not None
-                                       else "prefill", e)
-            if claimed:
+                queued = len(self._queue)
+            active = None
+            try:
+                active = self._iteration(claimed, claim)
+            finally:
+                if it is not None:
+                    it.attrs.update(
+                        active=active, claimed=len(claimed), queued=queued,
+                        cpu_ms=round((time.thread_time() - cpu0) * 1e3, 3))
+                    # unwinds whatever phase a raise left open
+                    telemetry.span_end(it)
+                    telemetry.histogram_observe(
+                        "serving_iteration_host_ms",
+                        (it.end - it.start - self._iter_wait_s) * 1e3)
+                self._iter_wait_s = 0.0
+
+    def _end_device_wait(self, span):
+        """Close a span that blocked on the device and keep its time out
+        of the iteration's host time (``serving_iteration_host_ms``).
+        Only the scheduler's own waits count: warm-up runs the same
+        programs from its caller's thread."""
+        telemetry.span_end(span)
+        if span is not None and threading.current_thread() is self._thread:
+            self._iter_wait_s += span.end - span.start
+
+    def _iteration(self, claimed: List[tuple], claim) -> int:
+        """One scheduler pass after the claim: admit the claimed
+        requests, advance one prefill slice, speculate, step the decode
+        grid, publish.  ``claim`` is the open ``generation/claim``
+        span (None with telemetry off).  Returns the slots still
+        active at its end."""
+        for slot, req in claimed:
+            try:
+                self._begin(slot, req)
+            except PoolExhausted as e:
+                # segment adoption allocates its pages at claim
+                # time: exhaustion is the SAME transient the
+                # prefill path sees — evictions already ran, so
+                # requeue behind live sequences (or fail when the
+                # pool can never hold it)
+                self._requeue_or_fail(slot, e)
+            except Exception as e:  # noqa: BLE001 — a prefill/adopt
+                # failure must not kill the scheduler: exactly this
+                # request errors, the grid keeps decoding
+                self._fail_request(slot, req,
+                                   "adopt" if req.segment is not None
+                                   else "prefill", e)
+        if claim is not None:
+            claim.attrs["claimed"] = len(claimed)
+            telemetry.span_end(claim)
+        if claimed:
+            with telemetry.trace_span("generation/publish"):
                 self._sample_slot_track()
-            # chunked prefill: advance ONE pending slice per iteration
-            # (round-robin over prefilling slots), so a long prompt
-            # pays out between decode steps instead of stalling the
-            # grid — the dense path never leaves slots prefilling
-            pending = self._prefilling_slots()
-            if pending:
-                slot = pending[self._prefill_rr % len(pending)]
-                self._prefill_rr += 1
-                try:
-                    self._prefill_advance(slot)
-                except PoolExhausted as e:
-                    # transient saturation, not a broken request: live
-                    # sequences will free pages as they finish, so put
-                    # the request back at the queue head (its own
-                    # deadline still bounds the wait).  Only a pool
-                    # that cannot serve the prompt even with every
-                    # other slot idle is a hard failure
-                    self._requeue_or_fail(slot, e)
-                except Exception as e:  # noqa: BLE001 — same isolation
-                    # as a dense prefill failure: this request only
-                    self._fail_request(slot, slot.req, "prefill", e)
-            # speculative round first: slots whose draft verified this
-            # iteration already advanced (often several tokens) and are
-            # skipped by the grid step; the rest ride it unchanged —
-            # mixed grids per iteration
-            served = frozenset()
-            if self.speculate and self._decoding_slots():
-                try:
-                    served = self._speculate_round()
-                except Exception as e:  # noqa: BLE001 — a verify crash
-                    # is a decode-grid crash: it donated the same pool
-                    # buffers, so the active slots' cache state is
-                    # unknowable (same containment as the grid step)
-                    self._decode_failed(e)
-            if self._decoding_slots():
-                try:
-                    self._decode_step(skip=served)
-                except Exception as e:  # noqa: BLE001 — a decode-step
-                    # failure fails the ACTIVE requests (after a
-                    # mid-step crash their cache state is unknowable)
-                    # but never the scheduler: the next queued request
-                    # prefills into a clean slot and serving continues
-                    self._decode_failed(e)
-            self._publish_gauges()
+        # chunked prefill: advance ONE pending slice per iteration
+        # (round-robin over prefilling slots), so a long prompt
+        # pays out between decode steps instead of stalling the
+        # grid — the dense path never leaves slots prefilling
+        pending = self._prefilling_slots()
+        if pending:
+            slot = pending[self._prefill_rr % len(pending)]
+            self._prefill_rr += 1
+            try:
+                self._prefill_advance(slot)
+            except PoolExhausted as e:
+                # transient saturation, not a broken request: live
+                # sequences will free pages as they finish, so put
+                # the request back at the queue head (its own
+                # deadline still bounds the wait).  Only a pool
+                # that cannot serve the prompt even with every
+                # other slot idle is a hard failure
+                self._requeue_or_fail(slot, e)
+            except Exception as e:  # noqa: BLE001 — same isolation
+                # as a dense prefill failure: this request only
+                self._fail_request(slot, slot.req, "prefill", e)
+        # speculative round first: slots whose draft verified this
+        # iteration already advanced (often several tokens) and are
+        # skipped by the grid step; the rest ride it unchanged —
+        # mixed grids per iteration
+        served = frozenset()
+        if self.speculate and self._decoding_slots():
+            try:
+                served = self._speculate_round()
+            except Exception as e:  # noqa: BLE001 — a verify crash
+                # is a decode-grid crash: it donated the same pool
+                # buffers, so the active slots' cache state is
+                # unknowable (same containment as the grid step)
+                self._decode_failed(e)
+        if self._decoding_slots():
+            try:
+                self._decode_step(skip=served)
+            except Exception as e:  # noqa: BLE001 — a decode-step
+                # failure fails the ACTIVE requests (after a
+                # mid-step crash their cache state is unknowable)
+                # but never the scheduler: the next queued request
+                # prefills into a clean slot and serving continues
+                self._decode_failed(e)
+        with telemetry.trace_span("generation/publish"):
+            return self._publish_gauges()
 
     def _begin(self, slot: _Slot, req: GenRequest):
         """Post-claim admission work.  Dense: the whole prefill, here
@@ -1497,11 +1544,19 @@ class GenerationEngine:
         # the per-sequence timeline span: trace-linked root bracketing
         # claim→finish under the request's trace id, the prefill /
         # chunk / decode spans hang under it
+        queue_wait_ms = (req.t_claimed - req.t_submit) * 1e3
+        # (a root of the REQUEST's trace, not a child of the scheduler's
+        # generation/claim that happens to be open on this thread: a
+        # parent context with no span says exactly that)
         slot.span = telemetry.span_begin(
             "generation/sequence", detached=True,
-            trace_id=req.trace_id, slot=slot.idx,
+            parent=telemetry.SpanContext(req.trace_id, None),
+            slot=slot.idx,
             prompt_len=int(req.prompt.size),
-            adopted=req.segment is not None)
+            adopted=req.segment is not None,
+            queue_wait_ms=round(queue_wait_ms, 3))
+        telemetry.histogram_observe("serving_generate_queue_wait_ms",
+                                    queue_wait_ms, trace_id=req.trace_id)
         # page-second attribution arms here (None keeps every mark a
         # single attribute check — the FLAGS_usage=0 zero-work path)
         slot.page_tenant = req.tenant
@@ -1798,23 +1853,23 @@ class GenerationEngine:
 
     def _prefill(self, slot: _Slot, req: GenRequest):
         t0 = time.monotonic()
-        kind = fault.fire("prefill")
-        fault.maybe_delay(kind)
-        if kind == "fail":
-            raise fault.InjectedFault("injected prefill failure")
-        self._poison_check(req.prompt)
+        parent = slot.span.context() if slot.span is not None else None
         bucket = batcher.prompt_bucket_for(req.prompt.size,
                                            self.prefill_buckets)
-        with telemetry.trace_span("generation/prefill",
-                                  parent=slot.span.context()
-                                  if slot.span is not None else None,
+        with telemetry.trace_span("generation/prefill_prepare",
+                                  parent=parent, slot=slot.idx,
+                                  bucket=bucket):
+            kind = fault.fire("prefill")
+            fault.maybe_delay(kind)
+            if kind == "fail":
+                raise fault.InjectedFault("injected prefill failure")
+            self._poison_check(req.prompt)
+        with telemetry.trace_span("generation/prefill", parent=parent,
                                   tokens=int(req.prompt.size),
                                   bucket=bucket, slot=slot.idx):
             outs = self._run_prefill_program(req.prompt, bucket,
                                              slot.idx)
-            first = int(np.asarray(outs[0].numpy())[0])
-            slot.logits = [np.asarray(outs[1].numpy())[0]] \
-                if self.keep_logits else []
+            first = self._fetch_first_token(slot, outs, parent)
         now = time.monotonic()
         ms = (now - t0) * 1e3
         req.prefill_ms = ms
@@ -1943,30 +1998,28 @@ class GenerationEngine:
         prompt = req.prompt
         t0 = time.monotonic()
         n_prompt = int(prompt.size)
+        parent = slot.span.context() if slot.span is not None else None
         if slot.prefill_pos == 0 and self.prefill_chunk <= 0:
             bucket = batcher.prompt_bucket_for(n_prompt,
                                                self.prefill_buckets)
-            self._ensure_pages(slot, n_prompt)
-            prog, fetches = self._paged_prefill_prog_for(bucket)
-            fetch = [fetches["next_token"]]
-            if self.keep_logits:
-                fetch.append(fetches["logits"])
-            with telemetry.trace_span("generation/prefill",
-                                      parent=slot.span.context()
-                                      if slot.span is not None else None,
+            with telemetry.trace_span("generation/prefill_prepare",
+                                      parent=parent, slot=slot.idx,
+                                      bucket=bucket):
+                self._ensure_pages(slot, n_prompt)
+                prog, fetches = self._paged_prefill_prog_for(bucket)
+                fetch = [fetches["next_token"]]
+                if self.keep_logits:
+                    fetch.append(fetches["logits"])
+                feed = {"input_ids":
+                        batcher.pad_prompt(prompt, bucket)[None],
+                        "last_pos": np.asarray([n_prompt - 1], "int64"),
+                        "block_table": self._slot_block_table(slot)[None],
+                        "prompt_len": np.asarray([n_prompt], "int32")}
+            with telemetry.trace_span("generation/prefill", parent=parent,
                                       tokens=n_prompt, bucket=bucket,
                                       slot=slot.idx, paged=True):
                 outs = self._prefill_exe.run(
-                    prog,
-                    feed={"input_ids":
-                          batcher.pad_prompt(prompt, bucket)[None],
-                          "last_pos": np.asarray([n_prompt - 1],
-                                                 "int64"),
-                          "block_table":
-                          self._slot_block_table(slot)[None],
-                          "prompt_len": np.asarray([n_prompt],
-                                                   "int32")},
-                    fetch_list=fetch, scope=self.scope,
+                    prog, feed=feed, fetch_list=fetch, scope=self.scope,
                     return_numpy=False)
             req.prefill_ms += (time.monotonic() - t0) * 1e3
             if req.tenant is not None:
@@ -1981,27 +2034,28 @@ class GenerationEngine:
             slot.prefill_pos, n_prompt, self.prefill_chunk)[0]
         n = end - start
         bucket = batcher.prompt_bucket_for(n, self.prefill_buckets)
-        self._ensure_pages(slot, start + n)
-        prog, fetches = self._chunk_prog_for(bucket)
+        with telemetry.trace_span("generation/prefill_prepare",
+                                  parent=parent, slot=slot.idx,
+                                  bucket=bucket):
+            self._ensure_pages(slot, start + n)
+            prog, fetches = self._chunk_prog_for(bucket)
+            fetch = [fetches["next_token"]]
+            if self.keep_logits:
+                fetch.append(fetches["logits"])
+            chunk = np.zeros((bucket,), "int64")
+            chunk[:n] = prompt[start:start + n]
+            feed = {"chunk_ids": chunk[None],
+                    "base": np.asarray([start], "int32"),
+                    "block_table": self._slot_block_table(slot)[None],
+                    "chunk_len": np.asarray([n], "int32"),
+                    "last_off": np.asarray([n - 1], "int64")}
         last = start + n >= n_prompt
-        fetch = [fetches["next_token"]]
-        if self.keep_logits:
-            fetch.append(fetches["logits"])
-        chunk = np.zeros((bucket,), "int64")
-        chunk[:n] = prompt[start:start + n]
         with telemetry.trace_span("generation/prefill_chunk",
-                                  parent=slot.span.context()
-                                  if slot.span is not None else None,
-                                  tokens=n, base=start, bucket=bucket,
-                                  slot=slot.idx):
+                                  parent=parent, tokens=n, base=start,
+                                  bucket=bucket, slot=slot.idx):
             outs = self._prefill_exe.run(
-                prog,
-                feed={"chunk_ids": chunk[None],
-                      "base": np.asarray([start], "int32"),
-                      "block_table": self._slot_block_table(slot)[None],
-                      "chunk_len": np.asarray([n], "int32"),
-                      "last_off": np.asarray([n - 1], "int64")},
-                fetch_list=fetch, scope=self.scope, return_numpy=False)
+                prog, feed=feed, fetch_list=fetch, scope=self.scope,
+                return_numpy=False)
         self._count("prefill_chunks")
         stat_add("serving_prefill_chunks")
         if req.tenant is not None:
@@ -2014,13 +2068,26 @@ class GenerationEngine:
         if last:
             self._complete_prefill(slot, req, outs)
 
+    def _fetch_first_token(self, slot: _Slot, outs, parent) -> int:
+        """Block on a prefill's outputs: the first generated token (and
+        the logits row when kept), under ``generation/prefill_fetch``."""
+        span = telemetry.span_begin("generation/prefill_fetch",
+                                    parent=parent, slot=slot.idx)
+        try:
+            first = int(np.asarray(outs[0].numpy())[0])
+            slot.logits = [np.asarray(outs[1].numpy())[0]] \
+                if self.keep_logits else []
+        finally:
+            self._end_device_wait(span)
+        return first
+
     def _complete_prefill(self, slot: _Slot, req: GenRequest, outs):
         """Shared tail of every paged prefill path: book the first
         generated token, publish the prompt's fully-covered pages to
         the prefix index, and enter the decode grid."""
-        first = int(np.asarray(outs[0].numpy())[0])
-        slot.logits = [np.asarray(outs[1].numpy())[0]] \
-            if self.keep_logits else []
+        first = self._fetch_first_token(
+            slot, outs, slot.span.context() if slot.span is not None
+            else None)
         n_prompt = int(req.prompt.size)
         self._t_prefill_total += req.prefill_ms
         self._h_prefill.observe(req.prefill_ms, trace_id=req.trace_id)
@@ -2158,11 +2225,17 @@ class GenerationEngine:
         fetch = [self._decode_fetches["next_token"]]
         if self.keep_logits:
             fetch.append(self._decode_fetches["logits"])
-        outs = self._decode_exe.run(
-            self._decode_prog, feed=feed, fetch_list=fetch,
-            scope=self.scope, return_numpy=False)
-        next_tokens = np.asarray(outs[0].numpy())
-        logits = np.asarray(outs[1].numpy()) if self.keep_logits else None
+        with telemetry.trace_span("generation/decode_dispatch"):
+            outs = self._decode_exe.run(
+                self._decode_prog, feed=feed, fetch_list=fetch,
+                scope=self.scope, return_numpy=False)
+        span = telemetry.span_begin("generation/token_fetch")
+        try:
+            next_tokens = np.asarray(outs[0].numpy())
+            logits = np.asarray(outs[1].numpy()) \
+                if self.keep_logits else None
+        finally:
+            self._end_device_wait(span)
         return next_tokens, logits
 
     def _speculate_round(self) -> frozenset:
@@ -2284,6 +2357,39 @@ class GenerationEngine:
 
     def _decode_step(self, skip: frozenset = frozenset()):
         t0 = time.monotonic()
+        span = telemetry.span_begin("generation/decode_feeds")
+        try:
+            active, feeds = self._build_decode_feeds(skip)
+            # the grid step serves N sequences at once: link their
+            # sequence-span contexts, the fan-in convention batch spans
+            # use
+            links = tuple(s.span.context() for s in active
+                          if s.span is not None)
+            if span is not None:
+                span.attrs["active"] = len(active)
+                span.links = links
+        finally:
+            telemetry.span_end(span)
+        if not active:
+            return
+        with telemetry.trace_span("generation/decode_step",
+                                  links=links, active=len(active)):
+            next_tokens, logits = self._run_decode_program(*feeds)
+        t1 = time.monotonic()
+        span = telemetry.span_begin("generation/book_tokens", links=links,
+                                    tokens=len(active))
+        try:
+            self._book_step(active, next_tokens, logits, t0, t1)
+            if span is not None:
+                span.attrs["finished"] = sum(s.req is None
+                                             for s in active)
+        finally:
+            telemetry.span_end(span)
+
+    def _build_decode_feeds(self, skip: frozenset):
+        """The host half of a grid step before its dispatch: the page
+        guard, then the slots that ride the step and the program's
+        feeds ``(tokens, positions, block_tables, live)``."""
         kind = fault.fire("decode_step")
         fault.maybe_delay(kind)
         if kind == "fail":
@@ -2306,28 +2412,23 @@ class GenerationEngine:
         positions = np.zeros((self.num_slots,), "int32")
         active = [s for s in self._decoding_slots()
                   if s.idx not in skip]
-        if not active:
-            return
         for s in active:
             tokens[s.idx, 0] = s.tokens[-1]
             positions[s.idx] = s.position
         bt = live = None
-        if self.paged:
+        if self.paged and active:
             bt = np.zeros((self.num_slots, self.pages_per_slot),
                           "int32")
             live = np.zeros((self.num_slots,), "int32")
             for s in active:
                 bt[s.idx] = self._slot_block_table(s)
                 live[s.idx] = 1
-        # the grid step serves N sequences at once: link their
-        # sequence-span contexts, the fan-in convention batch spans use
-        links = [s.span.context() for s in active
-                 if s.span is not None] or None
-        with telemetry.trace_span("generation/decode_step",
-                                  links=links, active=len(active)):
-            next_tokens, logits = self._run_decode_program(
-                tokens, positions, bt, live)
-        t1 = time.monotonic()
+        return active, (tokens, positions, bt, live)
+
+    def _book_step(self, active, next_tokens, logits, t0: float,
+                   t1: float):
+        """The host half of a grid step after its token fetch: step
+        accounting, then one booked token per riding slot."""
         ms = (t1 - t0) * 1e3
         self._t_decode_total += ms
         self._h_step.observe(ms)
@@ -2550,14 +2651,16 @@ class GenerationEngine:
                 "ttft_exemplars": self._h_ttft.exemplars(),
                 "inter_token_exemplars": self._h_itl.exemplars()}
 
-    def _publish_gauges(self):
+    def _publish_gauges(self) -> int:
+        """Publish the per-iteration gauges; returns the active slot
+        count it took them from."""
         active = len(self._active())
         if active > self._peak_active:
             # peak concurrency feeds the paged bench's sequences-per-GB
             # headline, so it is tracked even with telemetry off
             self._peak_active = active
         if not telemetry.enabled():
-            return
+            return active
         telemetry.gauge_set("serving_slot_occupancy",
                             active / self.num_slots)
         if self.speculate:
@@ -2567,16 +2670,7 @@ class GenerationEngine:
             if prop:
                 telemetry.gauge_set("serving_spec_acceptance_rate",
                                     acc / prop)
-        if self._t_decode_total > 0:
-            telemetry.gauge_set(
-                "serving_prefill_decode_ratio",
-                self._t_prefill_total / self._t_decode_total)
-        with self._n_lock:
-            steps = self._n["decode_steps"]
-        if steps and steps % _MFU_EVERY == 0:
-            mfu = self.decode_mfu()
-            if mfu is not None:
-                telemetry.gauge_set("serving_decode_mfu", mfu)
+        return active
 
     def decode_manifest(self) -> Optional[dict]:
         """The decode-step executable's cost/memory manifest (flops,

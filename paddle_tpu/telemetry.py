@@ -7,6 +7,10 @@ was step N slow" and "is the job alive" without print statements:
   manager with a thread-local parent stack, monotonic-clock durations,
   and a bounded ring of completed spans exportable as chrome://tracing /
   Perfetto JSON (:func:`export_chrome_trace`, ``tools/trace_export.py``).
+  Every span on the thread-local stack is also a
+  ``jax.profiler.TraceAnnotation`` of the same name once jax is
+  imported (this module never imports it), so a device trace taken by
+  any means carries the program's spans on the profiler's own clock.
 * **Span context** — every span carries a ``trace_id`` (inherited from
   its parent; minted fresh at a root), :func:`current_span` exposes the
   innermost open span as a handoff-able :class:`SpanContext`, and
@@ -47,6 +51,7 @@ import json
 import logging
 import math
 import os
+import sys
 import threading
 import time
 from collections import deque
@@ -141,7 +146,7 @@ class Span:
     requests it serves)."""
 
     __slots__ = ("name", "attrs", "span_id", "parent_id", "trace_id",
-                 "links", "tid", "start", "end")
+                 "links", "tid", "start", "end", "_annotation")
     _next_id = [1]
     _id_lock = threading.Lock()
 
@@ -156,6 +161,7 @@ class Span:
         self.trace_id = trace_id or new_trace_id()
         self.links: Tuple[SpanContext, ...] = tuple(links or ())
         self.tid = tid
+        self._annotation = None
         self.start = time.monotonic()
         self.end: Optional[float] = None
 
@@ -281,7 +287,27 @@ def span_begin(name: str, parent: Optional[SpanContext] = None,
                 trace_id=trace_id, links=links)
     if not detached:
         _stack().append(span)
+        # one clock with the device trace: a stacked span is also a host
+        # event of the same name on the profiler's timeline (a detached
+        # span may end on another thread, so it gets none).  Only when
+        # jax is already imported; the annotation tests one atomic and
+        # does nothing while no trace is being taken.
+        profiler = getattr(sys.modules.get("jax"), "profiler", None)
+        if profiler is not None:
+            span._annotation = profiler.TraceAnnotation(name)
+            span._annotation.__enter__()
     return span
+
+
+def _close(span: Span, now: float, ring: deque):
+    """Stamp the end, exit the span's profiler annotation (if it has
+    one) and record the span in the ring."""
+    span.end = now
+    if span._annotation is not None:
+        span._annotation.__exit__(None, None, None)
+        span._annotation = None
+    with _ring_lock:
+        ring.append(span)
 
 
 def span_end(span: Optional[Span]):
@@ -297,21 +323,16 @@ def span_end(span: Optional[Span]):
         # detached span, or a stack span being ended from another
         # thread (the queue/thread-hop half of trace propagation)
         if span.end is None:
-            span.end = time.monotonic()
-            ring = _get_ring()  # before the lock: _get_ring takes it
-            with _ring_lock:
-                ring.append(span)
+            _close(span, time.monotonic(), _get_ring())
         return
     now = time.monotonic()
-    ring = _get_ring()
+    ring = _get_ring()  # before the lock: _get_ring takes it
     while stack:
         top = stack.pop()
         # a span another thread already ended keeps its recorded
         # duration and must not be appended to the ring twice
         if top.end is None:
-            top.end = now
-            with _ring_lock:
-                ring.append(top)
+            _close(top, now, ring)
         if top is span:
             break
 
