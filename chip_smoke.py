@@ -77,7 +77,8 @@ def attention_paths():
     from paddle_tpu.monitor import stat_get
 
     return {p: stat_get(f"attention_lowered_{p}")
-            for p in ("pallas", "blockwise", "ring", "xla")}
+            for p in ("pallas", "blockwise", "ring", "xla", "paged_decode",
+                      "paged_decode_reference")}
 
 
 def paths_since(before):
@@ -430,6 +431,10 @@ def serve_phase(cfg=SERVE, on_chip=True):
             # the engine holds one device whatever the host has
             check(paths.get("pallas") and not paths.get("blockwise"),
                   f"prefill did not lower to the Pallas kernel: {paths}")
+            check(paths.get("paged_decode")
+                  and not paths.get("paged_decode_reference"),
+                  f"the decode step did not lower to the paged-attention "
+                  f"kernel: {paths}")
     finally:
         server.close()
     deadline = time.monotonic() + 30.0

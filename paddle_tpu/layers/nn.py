@@ -23,6 +23,7 @@ __all__ = [
     "adaptive_pool2d", "flash_attention", "flash_attention_qkv",
     "rms_norm", "rope", "kv_cache_write", "kv_cache_insert",
     "cached_attention", "kv_pool_write", "kv_pool_gather",
+    "paged_decode_attention",
     "linear_chain_crf", "crf_decoding", "warpctc",
     "nce", "hsigmoid", "conv3d", "pool3d", "lrn", "row_conv",
     "shuffle_channel", "temporal_shift", "multiplex",
@@ -696,6 +697,28 @@ def cached_attention(q, cache_k, cache_v, positions, scale=None,
         attrs["scale"] = float(scale)
     helper.append_op("cached_attention",
                      inputs={"Q": [q], "K": [cache_k], "V": [cache_v],
+                             "Positions": [positions]},
+                     outputs={"Out": [out]}, attrs=attrs)
+    return out
+
+
+def paged_decode_attention(q, pool_k, pool_v, block_table, positions,
+                           scale=None, name=None):
+    """The paged decode step's attention: ``q`` [B, H, 1, D] (one new
+    token per slot) attends pools ``pool_k``/``pool_v`` [P, Hkv, pt, D]
+    through ``block_table`` [B, NP] at columns ``j <= positions[b]``.
+    A TPU backend reads the live pages in place (Pallas kernel); any
+    other runs :func:`kv_pool_gather` + :func:`cached_attention`'s
+    formulation, bit for bit.  Returns [B, H, 1, D]."""
+    helper = LayerHelper("paged_decode_attention", name=name)
+    out = helper.create_variable_for_type_inference(q.dtype)
+    attrs = {}
+    if scale is not None:
+        attrs["scale"] = float(scale)
+    helper.append_op("paged_decode_attention",
+                     inputs={"Q": [q], "PoolK": [pool_k],
+                             "PoolV": [pool_v],
+                             "BlockTable": [block_table],
                              "Positions": [positions]},
                      outputs={"Out": [out]}, attrs=attrs)
     return out

@@ -56,12 +56,16 @@ def llama_block(x, hidden, num_heads, num_kv_heads, seq_len, head_dim,
         cached decode: returns x with the caches updated in place.
         With ``block_table`` [B, NP] + ``kv_lengths`` [B] the caches
         are block-paged pools [P, n_kv, page_tokens, D]: the step's
-        K/V scatter into the slots' current pages (``kv_pool_write``)
-        and attention runs over the gathered logical view
-        (``kv_pool_gather`` -> ``cached_attention``, the identical
-        einsum the dense path runs — bit-exact).  ``seq_len`` > 1 in
-        this mode is a *prefill chunk*: S new tokens starting at
-        ``positions[b]`` attend the cache plus themselves causally.
+        K/V scatter into the slots' current pages (``kv_pool_write``).
+        With ``seq_len`` 1 (the decode step) the new token attends its
+        slot's live pages in place (``paged_decode_attention``: a
+        Pallas kernel on a TPU, held to the reference at a tolerance;
+        the gather + einsum formulation, bit-exact against dense,
+        anywhere else).  ``seq_len`` > 1 is a *prefill chunk*: S new
+        tokens starting at ``positions[b]`` attend the gathered logical
+        view plus themselves causally (``kv_pool_gather`` ->
+        ``cached_attention``, the identical einsum the dense path
+        runs — bit-exact).  The program's shape picks the path.
       * ``collect_kv=True`` — prefill: returns ``(x, k, v)`` where
         k/v are the post-RoPE [B, n_kv, S, D] cache rows.
     """
@@ -92,17 +96,23 @@ def llama_block(x, hidden, num_heads, num_kv_heads, seq_len, head_dim,
         # GQA expansion happens inside cached_attention
         cache_k, cache_v = kv_cache
         if block_table is not None:
-            # paged: scatter into the slots' pages, then attend the
-            # gathered logical view — write-before-gather makes the
-            # fresh rows visible (mask admits j <= positions[b] + t,
-            # which includes this step's own columns)
+            # paged: scatter into the slots' pages, then attend —
+            # write-before-read makes the fresh rows visible (mask
+            # admits j <= positions[b] + t, which includes this step's
+            # own columns)
             cache_k = layers.kv_pool_write(cache_k, k, positions,
                                            block_table, kv_lengths)
             cache_v = layers.kv_pool_write(cache_v, v, positions,
                                            block_table, kv_lengths)
-            gk = layers.kv_pool_gather(cache_k, block_table)
-            gv = layers.kv_pool_gather(cache_v, block_table)
-            attn = layers.cached_attention(q, gk, gv, positions)
+            if seq_len == 1:
+                # the decode step: live pages in place
+                attn = layers.paged_decode_attention(
+                    q, cache_k, cache_v, block_table, positions)
+            else:
+                # a chunk of query rows: the gathered logical view
+                gk = layers.kv_pool_gather(cache_k, block_table)
+                gv = layers.kv_pool_gather(cache_v, block_table)
+                attn = layers.cached_attention(q, gk, gv, positions)
         else:
             cache_k = layers.kv_cache_write(cache_k, k, positions)
             cache_v = layers.kv_cache_write(cache_v, v, positions)

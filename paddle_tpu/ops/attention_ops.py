@@ -24,20 +24,26 @@ _LOWERED = {
     "blockwise": _monitor.get("attention_lowered_blockwise"),
     "ring": _monitor.get("attention_lowered_ring"),
     "xla": _monitor.get("attention_lowered_xla"),
+    # the paged one-token decode step (ops/decode_ops.py): the Pallas
+    # kernel over live pages, or the gather + einsum formulation
+    "paged_decode": _monitor.get("attention_lowered_paged_decode"),
+    "paged_decode_reference":
+        _monitor.get("attention_lowered_paged_decode_reference"),
 }
 _downgrades_logged = set()
 
 
 def _lowered(path, downgrade_reason=None):
-    """Book the path taken.  On a TPU backend the blockwise reference
-    is a downgrade from the Pallas kernels, not an equivalent: say so,
-    once per reason."""
+    """Book the path taken.  On a TPU backend a reference formulation
+    (blockwise, or the paged decode step's gather + einsum) is a
+    downgrade from the Pallas kernels, not an equivalent: say so, once
+    per reason."""
     _LOWERED[path].increase()
     if downgrade_reason and downgrade_reason not in _downgrades_logged:
         _downgrades_logged.add(downgrade_reason)
-        logger.warning("attention lowered to the blockwise reference on "
-                       "a TPU backend, not the Pallas kernels: %s",
-                       downgrade_reason)
+        logger.warning("attention lowered to its reference formulation "
+                       "(%s) on a TPU backend, not the Pallas kernels: %s",
+                       path, downgrade_reason)
 
 
 def _attn_infer(op, block):

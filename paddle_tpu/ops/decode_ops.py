@@ -30,8 +30,19 @@ index -> physical page.  ``kv_pool_gather`` reconstructs a slot's
 logical ``[B, n_kv, NP*page_tokens, D]`` cache view from its pages, so
 ``cached_attention`` runs the *identical* einsum at the *identical*
 contraction length as the dense path — which is what keeps paged
-decode bit-exact against dense (columns beyond the live length differ
-only in garbage the ``-1e30`` mask turns into exact zeros either way).
+prefill chunks, verification and the CPU lowering bit-exact against
+dense (columns beyond the live length differ only in garbage the
+``-1e30`` mask turns into exact zeros either way).
+
+``paged_decode_attention`` is the paged decode step's attention (one
+query token per slot).  Its contract has two halves.  On a TPU backend
+it is a Pallas kernel (``ops/pallas/paged_attention.py``) that reads
+each slot's **live** pages in place through the block table — no dense
+view, no GQA expansion, no contraction over dead columns — and is held
+to the plain float32 reference at a stated tolerance (ROADMAP D1), not
+to the einsum's bits.  Everywhere else it runs the gather + einsum
+formulation above through the very same functions, so on the CPU the
+paged decode step stays bit-identical to the dense one.
 Physical page 0 is the reserved **trash page**: rows a write must
 discard (idle slots, pad-tail rows of a chunk) are redirected there
 instead of branching, so the scatter stays a single fused op.
@@ -102,9 +113,10 @@ def _kv_pool_write(ctx, op):
     ``block_table[b, (positions[b]+t) // pt]`` at in-page offset
     ``(positions[b]+t) % pt``.  Rows with ``t >= lengths[b]`` (idle
     slots, the pad tail of a bucketed prefill chunk) are redirected to
-    the reserved trash page 0 — one scatter, no branches.  The output
-    aliases the pool variable name, so the executor donates the buffer
-    exactly like the dense ``kv_cache_write`` (in-place HBM update)."""
+    the reserved trash page 0 — one scatter, no branch on data.  The
+    output aliases the pool variable name, so the executor donates the
+    buffer exactly like the dense ``kv_cache_write`` (in-place HBM
+    update)."""
     import jax.numpy as jnp
 
     pool = ctx.get_input(op, "Pool")
@@ -127,8 +139,25 @@ def _kv_pool_write(ctx, op):
     phys = jnp.where(valid, phys, 0)
     off = jnp.where(valid, off, 0)
     rows = jnp.transpose(new, (0, 2, 1, 3)).reshape(B * T, Hkv, D)
-    out = pool.at[phys.reshape(-1), :, off.reshape(-1), :].set(
-        rows.astype(pool.dtype))
+    rows = rows.astype(pool.dtype)
+    phys, off = phys.reshape(-1), off.reshape(-1)
+    if T == 1:
+        # the decode step: every (row, head) is its own index, so the
+        # scatter's window is the D lanes alone and the pool keeps its
+        # row-major layout.  With the [Hkv, D] window below XLA:TPU lays
+        # the pool out {D, Hkv, pt, P} for the scatter and copies every
+        # pool in and out of that layout, each layer, each step (32 pool
+        # copies, 18 of a 29 ms step at 32 slots x 1408; PERF.md PR 25)
+        # — in front of a kernel that reads the pool in place.  Same
+        # values either way.
+        head = jnp.arange(Hkv, dtype=jnp.int32)[None, :]
+        out = pool.at[phys[:, None], head, off[:, None], :].set(rows)
+    else:
+        # a chunk of rows: one [Hkv, D] window per row.  B * T * Hkv
+        # single-lane-row updates were slower at the long prefill rungs
+        # on the chip (543 against 523 ms at 3712 tokens), faster at the
+        # short ones (70 against 85 ms at 1024): left as it was
+        out = pool.at[phys, :, off, :].set(rows)
     ctx.set_output(op, "Out", out)
 
 
@@ -138,6 +167,18 @@ def _kv_pool_gather_infer(op, block):
     P, hkv, pt, d = pool.shape
     b, np_ = bt.shape
     set_out(op, block, "Out", (b, hkv, np_ * pt, d), pool.dtype)
+
+
+def _gather_pages(pool, bt):
+    """Pool [P, Hkv, pt, D] through BlockTable [B, NP] -> the dense
+    logical view [B, Hkv, NP*pt, D]."""
+    import jax.numpy as jnp
+
+    P, Hkv, pt, D = pool.shape
+    B, NP = bt.shape
+    pages = jnp.take(pool, bt.reshape(-1), axis=0, mode="clip")
+    return jnp.transpose(pages.reshape(B, NP, Hkv, pt, D),
+                         (0, 2, 1, 3, 4)).reshape(B, Hkv, NP * pt, D)
 
 
 @register_op("kv_pool_gather", infer=_kv_pool_gather_infer, grad=None)
@@ -154,12 +195,7 @@ def _kv_pool_gather(ctx, op):
 
     pool = ctx.get_input(op, "Pool")
     bt = ctx.get_input(op, "BlockTable").astype(jnp.int32)
-    P, Hkv, pt, D = pool.shape
-    B, NP = bt.shape
-    pages = jnp.take(pool, bt.reshape(-1), axis=0, mode="clip")
-    out = jnp.transpose(pages.reshape(B, NP, Hkv, pt, D),
-                        (0, 2, 1, 3, 4)).reshape(B, Hkv, NP * pt, D)
-    ctx.set_output(op, "Out", out)
+    ctx.set_output(op, "Out", _gather_pages(pool, bt))
 
 
 def _cached_attn_infer(op, block):
@@ -167,21 +203,12 @@ def _cached_attn_infer(op, block):
     set_out(op, block, "Out", q.shape, q.dtype)
 
 
-@register_op("cached_attention", infer=_cached_attn_infer, grad=None)
-def _cached_attention(ctx, op):
-    """Q [B, H, T, D] over caches K/V [B, Hkv, S_max, D]; Positions [B]
-    is the pre-step sequence length (row b's query t sits at absolute
-    position ``positions[b] + t`` and attends columns ``j`` with
-    ``j <= positions[b] + t``).  GQA caches (Hkv < H) expand
-    repeat-interleave style, matching the uncached block's ``expand_kv``
-    values exactly."""
+def _attend_cache(q, k, v, pos, scale=None):
+    """Q [B, H, T, D] over dense caches K/V [B, Hkv, S, D] with the
+    validity rule ``j <= pos[b] + t``: the einsum formulation."""
     import jax
     import jax.numpy as jnp
 
-    q = ctx.get_input(op, "Q")
-    k = ctx.get_input(op, "K")
-    v = ctx.get_input(op, "V")
-    pos = ctx.get_input(op, "Positions").astype(jnp.int32)
     B, H, T, D = q.shape
     Hkv, S = k.shape[1], k.shape[2]
     if Hkv != H:
@@ -190,7 +217,6 @@ def _cached_attention(ctx, op):
         # to kv head g//rep (same convention as llama_block's expand_kv)
         k = jnp.repeat(k, rep, axis=1)
         v = jnp.repeat(v, rep, axis=1)
-    scale = op.attr("scale", None)
     scale = scale if scale is not None else 1.0 / (D ** 0.5)
     if T == 1:
         # a Q=1 scores dot lowers to a GEMV-style rewrite whose
@@ -212,5 +238,71 @@ def _cached_attention(ctx, op):
     limit = pos[:, None, None, None] + t
     s = jnp.where(j <= limit, s, jnp.asarray(-1e30, s.dtype))
     p = jax.nn.softmax(s, axis=-1)
-    out = jnp.einsum("bhqk,bhkd->bhqd", p, v)
-    ctx.set_output(op, "Out", out.astype(q.dtype))
+    return jnp.einsum("bhqk,bhkd->bhqd", p, v).astype(q.dtype)
+
+
+@register_op("cached_attention", infer=_cached_attn_infer, grad=None)
+def _cached_attention(ctx, op):
+    """Q [B, H, T, D] over caches K/V [B, Hkv, S_max, D]; Positions [B]
+    is the pre-step sequence length (row b's query t sits at absolute
+    position ``positions[b] + t`` and attends columns ``j`` with
+    ``j <= positions[b] + t``).  GQA caches (Hkv < H) expand
+    repeat-interleave style, matching the uncached block's ``expand_kv``
+    values exactly."""
+    import jax.numpy as jnp
+
+    q = ctx.get_input(op, "Q")
+    k = ctx.get_input(op, "K")
+    v = ctx.get_input(op, "V")
+    pos = ctx.get_input(op, "Positions").astype(jnp.int32)
+    ctx.set_output(op, "Out",
+                   _attend_cache(q, k, v, pos, op.attr("scale", None)))
+
+
+@register_op("paged_decode_attention", infer=_cached_attn_infer, grad=None)
+def _paged_decode_attention(ctx, op):
+    """The paged decode step's attention, one query token per slot: Q
+    [B, H, 1, D] over the pools PoolK/PoolV [P, Hkv, pt, D] through
+    BlockTable [B, NP]; Positions [B] as in ``cached_attention`` (the
+    column ``positions[b]`` this step's ``kv_pool_write`` filled is
+    attended: the pool inputs are that op's outputs).
+
+    On a TPU backend, one device, at a shape the kernel takes (``D`` a
+    multiple of 128, ``pt`` of 8) this is the Pallas kernel of
+    ``ops/pallas/paged_attention.py``: live pages read in place, no
+    dense view, no GQA expansion, online softmax; it matches the einsum
+    formulation to float32 rounding, not bit for bit.  Anywhere else it
+    is exactly ``kv_pool_gather`` x 2 + ``cached_attention`` — the same
+    code — so the CPU lowering stays bit-identical to the dense path."""
+    import jax
+    import jax.numpy as jnp
+
+    from .attention_ops import _lowered
+    from .pallas import paged_attention
+
+    q = ctx.get_input(op, "Q")
+    pool_k = ctx.get_input(op, "PoolK")
+    pool_v = ctx.get_input(op, "PoolV")
+    bt = ctx.get_input(op, "BlockTable").astype(jnp.int32)
+    pos = ctx.get_input(op, "Positions").astype(jnp.int32)
+    scale = op.attr("scale", None)
+
+    on_tpu = jax.default_backend() == "tpu"
+    n_mesh = ctx.mesh.devices.size if ctx.mesh is not None else 1
+    fits = paged_attention.supported(q.shape, pool_k.shape)
+    if on_tpu and n_mesh == 1 and fits:
+        out = paged_attention.paged_decode_attention(
+            q, pool_k, pool_v, bt, pos, scale=scale)
+        _lowered("paged_decode")
+    else:
+        out = _attend_cache(q, _gather_pages(pool_k, bt),
+                            _gather_pages(pool_v, bt), pos, scale)
+        reason = None
+        if on_tpu:
+            reason = (f"paged_decode_attention under a {n_mesh}-device "
+                      f"mesh" if n_mesh > 1 else
+                      f"paged_decode_attention with Q {q.shape} over "
+                      f"pages {pool_k.shape[1:]} (kernel needs one query "
+                      f"token, head_dim % 128 == 0, page_tokens % 8 == 0)")
+        _lowered("paged_decode_reference", reason)
+    ctx.set_output(op, "Out", out)

@@ -1,0 +1,207 @@
+"""Paged decode attention for TPU — one query token per slot attends its
+live KV pages in place.
+
+The paged cache is a per-layer pool ``[P, Hkv, pt, D]`` with a block
+table ``[B, NP]`` (logical page -> physical page) per slot
+(``ops/decode_ops.py``).  The reference formulation gathers every slot's
+whole block-table row into a dense ``[B, Hkv, NP*pt, D]`` view, expands
+it to the query heads and contracts over all of ``max_seq``; its cost
+follows ``slots x max_seq`` whatever is live.  This kernel reads, per
+step, each **live** page of K and of V once, straight from the pool in
+HBM through the block table, and nothing else but Q and the output:
+
+* grid ``(B,)``, one slot per cell; the block table and the positions are
+  scalar-prefetch operands in SMEM, the pools stay in HBM;
+* a slot's live pages come in granules of ``G`` pages (``G * pt``
+  positions, 128 by default): one async copy per page (a page is
+  ``Hkv * pt * D`` contiguous elements) into a double-buffered VMEM
+  scratch laid out ``[Hkv, G*pt, D]``, so the next granule — or the next
+  slot's first one — is in flight while this one is contracted.  The
+  loop runs ``ceil(live_pages / G)`` times: nothing beyond the live
+  length is fetched, an idle slot (position 0) costs one page;
+* the ``rep`` query heads of a KV head share that head's K/V (query head
+  ``g`` reads KV head ``g // rep``, the ``cached_attention`` convention):
+  one batched ``[Hkv, rep, D] x [Hkv, T, D]`` contraction, no expansion;
+* online softmax with float32 state and accumulators, the same ``-1e30``
+  mask constant and ``j <= positions[b]`` validity rule as
+  ``cached_attention``.  Rows of the V buffer beyond the live length are
+  zeroed in a slot's last granule, so stale VMEM or a recycled page's
+  garbage (NaN included) cannot reach the output through ``0 * x``.
+
+Shapes the compiled kernel takes: ``D`` a multiple of 128 (lanes) and
+``pt`` a multiple of 8 (float32 sublanes); ``supported()`` says so and
+the op lowers anything else to the reference formulation.
+"""
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from .flash_attention import NEG_INF  # the -1e30 mask constant
+
+# positions fetched and contracted per loop turn.  On a TPU v5e at Hkv 8,
+# D 128, float32, page 16 (PERF.md §6, PR 25): 128 / 256 / 512 take 137 /
+# 150 / 186 us a layer over 32 short chat contexts and 243 / 235 / 239 us
+# over 8 long ones; a smaller granule wastes less on a slot's masked tail
+GRANULE_POSITIONS = 128
+# float32 operands go through the MXU whole (Mosaic's fp32 contraction),
+# not rounded to bf16 as at default precision: 6e-7 of the range against
+# a "highest" reference where default reads 4e-3 to 7e-3 (the einsum
+# formulation 2e-3 to 5e-3), for 2% (long contexts) to 12% (short) of
+# the kernel's time, which the HBM bounds either way (same runs)
+PRECISION = jax.lax.Precision.HIGHEST
+
+
+def supported(q_shape, pool_shape):
+    """Whether the compiled kernel takes these shapes: one query token,
+    whole lane tiles of ``D`` and whole float32 sublane tiles of ``pt``."""
+    _, H, T, D = q_shape
+    _, Hkv, pt, _ = pool_shape
+    return T == 1 and D % 128 == 0 and pt % 8 == 0 and H % Hkv == 0
+
+
+def _kernel(bt_ref, pos_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem,
+            turn_ref, *, scale, pt, G, NP):
+    b = pl.program_id(0)
+    nb = pl.num_programs(0)
+    T = G * pt
+
+    def live_pages(bb):
+        return pos_ref[bb] // pt + 1
+
+    def granules(bb):
+        return (live_pages(bb) + G - 1) // G
+
+    def copies(bb, g, buf):
+        """The granule's page copies, each under the condition it is
+        live: started and waited for under the same rule."""
+        n = live_pages(bb)
+        for i in range(G):
+            page = g * G + i
+            phys = bt_ref[bb * NP + jnp.minimum(page, NP - 1)]
+            dst = (buf, slice(None), pl.ds(i * pt, pt), slice(None))
+            yield page < n, (
+                pltpu.make_async_copy(k_hbm.at[phys], kbuf.at[dst],
+                                      sem.at[buf, 0]),
+                pltpu.make_async_copy(v_hbm.at[phys], vbuf.at[dst],
+                                      sem.at[buf, 1]))
+
+    def each_live(act, bb, g, buf):
+        for live, pair in copies(bb, g, buf):
+            @pl.when(live)
+            def _(pair=pair):
+                for c in pair:
+                    act(c)
+
+    def start(bb, g, buf):
+        each_live(lambda c: c.start(), bb, g, buf)
+
+    def wait(bb, g, buf):
+        each_live(lambda c: c.wait(), bb, g, buf)
+
+    @pl.when(b == 0)
+    def _():
+        turn_ref[0] = 0
+        start(0, 0, 0)
+
+    pos = pos_ref[b]
+    n_g = granules(b)
+    q = q_ref[0]                                     # [Hkv, R, D]
+    Hkv, R, D = q.shape
+
+    def body(g, carry):
+        m, l, acc = carry
+        buf = turn_ref[0] % 2
+        turn_ref[0] = turn_ref[0] + 1
+        # next in flight: this slot's next granule, else the next
+        # slot's first
+        more = g + 1 < n_g
+        nxt_b = jnp.where(more, b, b + 1)
+        nxt_g = jnp.where(more, g + 1, 0)
+
+        @pl.when(nxt_b < nb)
+        def _():
+            start(nxt_b, nxt_g, 1 - buf)
+
+        wait(b, g, buf)
+
+        @pl.when(jnp.logical_not(more))
+        def _():
+            row = g * T + jax.lax.broadcasted_iota(jnp.int32, (1, T, 1), 1)
+            v_ = vbuf[buf]
+            vbuf[buf] = jnp.where(row <= pos, v_, jnp.zeros_like(v_))
+
+        k = kbuf[buf]                                # [Hkv, T, D]
+        v = vbuf[buf]
+        s = jnp.einsum("hrd,hkd->hrk", q, k,
+                       preferred_element_type=jnp.float32,
+                       precision=PRECISION) * scale
+        col = g * T + jax.lax.broadcasted_iota(jnp.int32, (1, 1, T), 2)
+        s = jnp.where(col <= pos, s, NEG_INF)
+        m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
+        alpha = jnp.exp(m - m_new)
+        p = jnp.exp(s - m_new)
+        l = alpha * l + p.sum(axis=-1, keepdims=True)
+        acc = alpha * acc + jnp.einsum(
+            "hrk,hkd->hrd", p, v, preferred_element_type=jnp.float32,
+            precision=PRECISION)
+        return m_new, l, acc
+
+    init = (jnp.full((Hkv, R, 1), -jnp.inf, jnp.float32),
+            jnp.zeros((Hkv, R, 1), jnp.float32),
+            jnp.zeros((Hkv, R, D), jnp.float32))
+    _, l, acc = jax.lax.fori_loop(0, n_g, body, init)
+    o_ref[0] = (acc / l).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("scale", "interpret", "granule"))
+def paged_decode_attention(q, pool_k, pool_v, block_table, positions,
+                           scale=None, interpret=False,
+                           granule=GRANULE_POSITIONS):
+    """``q`` [B, H, 1, D] over pools ``[P, Hkv, pt, D]`` through
+    ``block_table`` [B, NP] int32; ``positions`` [B] int32 is each slot's
+    pre-step length, and the query attends columns ``j <= positions[b]``
+    (the column this step wrote included).  ``granule`` is the number
+    of positions fetched and contracted per loop turn, rounded to whole
+    pages.  Returns [B, H, 1, D]."""
+    B, H, _, D = q.shape
+    P, Hkv, pt, _ = pool_k.shape
+    NP = block_table.shape[1]
+    rep = H // Hkv
+    R = -(-rep // 8) * 8                             # whole sublane tiles
+    G = max(1, min(granule // pt, NP))
+    scale = scale if scale is not None else 1.0 / (D ** 0.5)
+
+    qg = q.reshape(B, Hkv, rep, D)
+    if R != rep:
+        qg = jnp.pad(qg, ((0, 0), (0, 0), (0, R - rep), (0, 0)))
+    kernel = functools.partial(_kernel, scale=scale, pt=pt, G=G, NP=NP)
+    blk = pl.BlockSpec((1, Hkv, R, D), lambda b, *_: (b, 0, 0, 0))
+    out = pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct((B, Hkv, R, D), q.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(B,),
+            in_specs=[blk,
+                      pl.BlockSpec(memory_space=pl.ANY),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=blk,
+            scratch_shapes=[
+                pltpu.VMEM((2, Hkv, G * pt, D), pool_k.dtype),
+                pltpu.VMEM((2, Hkv, G * pt, D), pool_v.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.SMEM((1,), jnp.int32),
+            ]),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name="paged_decode_attention",
+    )(block_table.reshape(-1).astype(jnp.int32),
+      positions.astype(jnp.int32), qg, pool_k, pool_v)
+    return out[:, :, :rep].reshape(B, H, 1, D)

@@ -1,0 +1,367 @@
+"""Paged decode attention: the Pallas kernel against a plain reference,
+and the op's two lowerings.
+
+* **Kernel** (``ops/pallas/paged_attention.py``, ``interpret=True`` on the
+  CPU) against a float32 ``jax.numpy`` reference at "highest" matmul
+  precision that gathers each slot's live columns and nothing else.
+  Tolerance ``TOL`` of the reference's range: both sides are float32
+  throughout and differ in the order of accumulation only (online softmax
+  over granules against one softmax row).
+* **Nothing beyond the live length is read**: every unmapped page, the
+  trash page beyond its first position and the tail of each slot's last
+  live page hold NaN; the output must be finite and equal the reference.
+* **Op** ``paged_decode_attention``: on a non-TPU backend it books
+  ``attention_lowered_paged_decode_reference`` and equals the
+  ``kv_pool_gather`` x 2 + ``cached_attention`` triple bit for bit, alone
+  and inside ``build_llama_decode``.
+* **For the chip, without one**: the kernel compiles for a described TPU
+  v5e at both serving cells' shapes (skipped where no topology can be
+  described; the topology is described inside a fixture of this one file,
+  because one process at a time may load the TPU's library).
+"""
+import os
+
+import numpy as np
+import pytest
+
+import paddle_tpu as pt
+from paddle_tpu import layers
+from paddle_tpu.monitor import stat_get
+
+# float32 on both sides, another order of accumulation: measured 5e-7 of
+# the range at these sizes; eight times that
+TOL = 4e-6
+
+
+def _case(rng, lengths, H, Hkv, D=128, pt_=8, NP=12, nan=True):
+    """Pools, a permuted block table and positions for slots attending
+    ``lengths`` columns (0 = an idle slot: position 0 on the trash page)."""
+    import jax.numpy as jnp
+
+    B = len(lengths)
+    P = B * NP + 1
+    pk = rng.standard_normal((P, Hkv, pt_, D)).astype(np.float32)
+    pv = rng.standard_normal((P, Hkv, pt_, D)).astype(np.float32)
+    q = (2.0 * rng.standard_normal((B, H, 1, D))).astype(np.float32)
+    bt = rng.permutation(np.arange(1, P)).reshape(B, NP).astype(np.int32)
+    pos = np.array([max(n, 1) - 1 for n in lengths], np.int32)
+    for b, n in enumerate(lengths):
+        live = 0 if n == 0 else -(-n // pt_)
+        if nan:
+            for i in range(live, NP):
+                pk[bt[b, i]] = pv[bt[b, i]] = np.nan
+            if live and n % pt_:
+                pk[bt[b, live - 1], :, n % pt_:] = np.nan
+                pv[bt[b, live - 1], :, n % pt_:] = np.nan
+        bt[b, live:] = 0
+    if nan:
+        # an idle slot attends column 0 of the trash page, and only that
+        pk[0, :, 1:] = pv[0, :, 1:] = np.nan
+    return tuple(jnp.asarray(a) for a in (q, pk, pv, bt, pos))
+
+
+def _reference(q, pk, pv, bt, pos, scale=None):
+    """One softmax row per slot and head over the slot's live columns."""
+    import jax
+    import jax.numpy as jnp
+
+    B, H, _, D = q.shape
+    _, Hkv, pt_, _ = pk.shape
+    scale = scale if scale is not None else 1.0 / D ** 0.5
+    out = []
+    for b in range(B):
+        n = int(pos[b]) + 1
+        pages = bt[b, :-(-n // pt_)]
+        k = jnp.moveaxis(pk[pages], 1, 0).reshape(Hkv, -1, D)[:, :n]
+        v = jnp.moveaxis(pv[pages], 1, 0).reshape(Hkv, -1, D)[:, :n]
+        k, v = (jnp.repeat(t, H // Hkv, axis=0) for t in (k, v))
+        s = jnp.einsum("hd,hkd->hk", q[b, :, 0], k,
+                       precision="highest") * scale
+        out.append(jnp.einsum("hk,hkd->hd", jax.nn.softmax(s, axis=-1), v,
+                              precision="highest"))
+    return np.asarray(jnp.stack(out))[:, :, None, :]
+
+
+def _check(args, **kw):
+    from paddle_tpu.ops.pallas.paged_attention import paged_decode_attention
+
+    got = np.asarray(paged_decode_attention(*args, interpret=True, **kw))
+    want = _reference(*args, scale=kw.get("scale"))
+    assert np.isfinite(got).all(), "something beyond the live length was read"
+    assert np.abs(got - want).max() <= TOL * np.abs(want).max()
+
+
+# page 8, 12 pages a slot: max_seq 96
+@pytest.mark.parametrize("rep", [4, 1])
+@pytest.mark.parametrize("granule", [8, 32, 1024])
+def test_kernel_matches_reference_over_ragged_lengths(rep, granule):
+    """Lengths 1, k*pt, k*pt + 1, max_seq, an idle slot first, in the
+    middle and last; one page, four pages and a whole slot per granule."""
+    lengths = [0, 1, 8, 9, 0, 32, 33, 57, 96, 0]
+    args = _case(np.random.default_rng(granule + rep), lengths,
+                 H=2 * rep, Hkv=2)
+    _check(args, granule=granule)
+
+
+def test_kernel_default_granule_page_16():
+    """The serving cells' page size and the default granule."""
+    args = _case(np.random.default_rng(5), [16, 17, 1, 128, 129, 160],
+                 H=8, Hkv=2, pt_=16, NP=10)
+    _check(args)
+
+
+def test_kernel_takes_a_scale_and_a_wider_head():
+    args = _case(np.random.default_rng(6), [5, 40, 17], H=4, Hkv=2, D=256)
+    _check(args, granule=16, scale=0.05)
+
+
+def test_kernel_pads_a_group_that_is_no_whole_sublane_tile():
+    """12 query heads a KV head: padded to 16 rows inside, sliced off."""
+    args = _case(np.random.default_rng(7), [24, 3], H=12, Hkv=1)
+    _check(args, granule=16)
+
+
+def test_kernel_single_slot_single_page():
+    args = _case(np.random.default_rng(8), [3], H=4, Hkv=4, NP=1)
+    _check(args)
+
+
+@pytest.mark.parametrize("q_shape,pool_shape,ok", [
+    ((32, 32, 1, 128), (2817, 8, 16, 128), True),
+    ((8, 32, 1, 128), (1857, 8, 16, 128), True),
+    ((3, 4, 1, 8), (19, 2, 16, 8), False),      # head 8: no lane tile
+    ((3, 4, 1, 128), (19, 2, 4, 128), False),   # page 4: no sublane tile
+    ((1, 4, 5, 128), (19, 2, 16, 128), False),  # a chunk of query rows
+])
+def test_supported_shapes(q_shape, pool_shape, ok):
+    from paddle_tpu.ops.pallas.paged_attention import supported
+
+    assert supported(q_shape, pool_shape) is ok
+
+
+# ---------------------------------------------------------------------------
+# the op: reference lowering off the TPU, bit for bit the old op triple
+# ---------------------------------------------------------------------------
+
+def _attention_program(B, H, Hkv, D, pt_, NP, fused):
+    main, startup = pt.Program(), pt.Program()
+    startup._is_startup = True
+    with pt.program_guard(main, startup):
+        def data(name, shape, dtype="float32"):
+            return layers.data(name, shape, dtype=dtype,
+                               append_batch_size=False)
+
+        q = data("q", [B, H, 1, D])
+        pk = data("pk", [B * NP + 1, Hkv, pt_, D])
+        pv = data("pv", [B * NP + 1, Hkv, pt_, D])
+        bt = data("bt", [B, NP], "int32")
+        pos = data("pos", [B], "int32")
+        if fused:
+            out = layers.paged_decode_attention(q, pk, pv, bt, pos)
+        else:
+            out = layers.cached_attention(q, layers.kv_pool_gather(pk, bt),
+                                          layers.kv_pool_gather(pv, bt), pos)
+    return main, out
+
+
+@pytest.mark.parametrize("D,pt_", [(128, 8), (16, 4)])
+def test_op_reference_lowering_is_the_old_triple_bit_for_bit(D, pt_):
+    """Off the TPU the op is the gather + einsum code itself, at a shape
+    the kernel takes and at one it does not."""
+    B, H, Hkv, NP = 3, 4, 2, 6
+    args = _case(np.random.default_rng(11), [1, pt_ * 3 + 1, pt_ * NP],
+                 H=H, Hkv=Hkv, D=D, pt_=pt_, NP=NP, nan=False)
+    feed = dict(zip(("q", "pk", "pv", "bt", "pos"), map(np.asarray, args)))
+    before = stat_get("attention_lowered_paged_decode_reference")
+    got = {}
+    for fused in (True, False):
+        main, out = _attention_program(B, H, Hkv, D, pt_, NP, fused)
+        got[fused] = pt.Executor().run(main, feed=feed, fetch_list=[out])[0]
+    assert stat_get("attention_lowered_paged_decode_reference") == before + 1
+    assert np.array_equal(got[True], got[False])
+    want = _reference(*args)
+    assert np.abs(got[True] - want).max() <= 1e-5 * np.abs(want).max()
+
+
+def test_decode_program_books_the_reference_and_keeps_its_bits(monkeypatch):
+    """``build_llama_decode`` (paged) calls the new op once a layer; on
+    this backend that is the reference lowering, and the step's logits
+    equal those of the same program built with the old op triple."""
+    from paddle_tpu.models.llama import build_llama_decode
+    from paddle_tpu.ops import attention_ops
+
+    model = dict(vocab_size=61, hidden=32, num_layers=3, num_heads=4,
+                 num_kv_heads=2, intermediate=64)
+    slots, max_seq, page = 3, 32, 8
+    pages = slots * (max_seq // page) + 1
+
+    def build():
+        main, startup = pt.Program(), pt.Program()
+        startup._is_startup = True
+        with pt.program_guard(main, startup):
+            feeds, fetches, caches = build_llama_decode(
+                slots, max_seq, name="pda", paged=True, num_pages=pages,
+                page_tokens=page, **model)
+        return main, startup, fetches, caches
+
+    def triple(q, pool_k, pool_v, block_table, positions):
+        return layers.cached_attention(
+            q, layers.kv_pool_gather(pool_k, block_table),
+            layers.kv_pool_gather(pool_v, block_table), positions)
+
+    new = build()
+    types = [op.type for op in new[0].global_block().ops]
+    assert types.count("paged_decode_attention") == model["num_layers"]
+    assert "kv_pool_gather" not in types and "cached_attention" not in types
+    monkeypatch.setattr(layers, "paged_decode_attention", triple)
+    old = build()
+    types = [op.type for op in old[0].global_block().ops]
+    assert types.count("cached_attention") == model["num_layers"]
+
+    rng = np.random.default_rng(3)
+    exe = pt.Executor()
+    scope = pt.Scope()
+    exe.run(new[1], scope=scope)
+    pools = {n: rng.standard_normal(
+        (pages, model["num_kv_heads"], page, 8)).astype("float32")
+        for n in new[3]}
+    bt = rng.permutation(np.arange(1, pages)).reshape(slots, -1)
+    feed = {"tokens": rng.integers(0, 61, (slots, 1)).astype("int64"),
+            "positions": np.array([0, 9, 31], "int32"),
+            "block_tables": bt.astype("int32"),
+            "live": np.array([0, 1, 1], "int32")}
+    counts = {k: stat_get(f"attention_lowered_{k}")
+              for k in attention_ops._LOWERED}
+    logits = []
+    for main, _, fetches, _ in (new, old):
+        for n, a in pools.items():
+            scope.set_var(n, a.copy())
+        logits.append(exe.run(main, feed=feed, scope=scope,
+                              fetch_list=[fetches["logits"]])[0])
+    moved = {k: stat_get(f"attention_lowered_{k}") - v
+             for k, v in counts.items()}
+    assert moved.pop("paged_decode_reference") == model["num_layers"]
+    assert not any(moved.values()), moved
+    assert np.isfinite(logits[0]).all()
+    assert np.array_equal(logits[0], logits[1])
+
+
+def test_reference_path_on_a_tpu_backend_is_logged_once(caplog):
+    from paddle_tpu.ops import attention_ops
+
+    before = stat_get("attention_lowered_paged_decode_reference")
+    with caplog.at_level("WARNING", logger="paddle_tpu.ops.attention"):
+        for _ in range(2):
+            attention_ops._lowered("paged_decode_reference",
+                                   "test: page of 4 tokens")
+    assert stat_get("attention_lowered_paged_decode_reference") == before + 2
+    said = [r.getMessage() for r in caplog.records
+            if "test: page of 4 tokens" in r.getMessage()]
+    assert len(said) == 1 and "paged_decode_reference" in said[0]
+
+
+# ---------------------------------------------------------------------------
+# for the chip, without one
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def chip():
+    """One device of a described TPU v5e: nothing is attached."""
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure means no compiler
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return topo.devices[0]
+
+
+@pytest.mark.parametrize("slots,max_seq", [(32, 1408), (8, 3712)])
+def test_kernel_compiles_for_a_described_v5e(chip, slots, max_seq):
+    """Mistral-7B widths (32 query heads over 8 KV heads of 128, float32,
+    page 16) at the two serving cells' slot grids: Mosaic takes the
+    kernel, and the pools are operands of the custom call, not of a
+    gather before it.  Nothing runs: a compile is not a chip run."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from paddle_tpu.ops.pallas.paged_attention import paged_decode_attention
+
+    page, np_slot = 16, max_seq // 16
+    pages = slots * np_slot + 1
+    one_chip = SingleDeviceSharding(chip)
+
+    def spec(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(paged_decode_attention).lower(
+        spec((slots, 32, 1, 128)), spec((pages, 8, page, 128)),
+        spec((pages, 8, page, 128)), spec((slots, np_slot), jnp.int32),
+        spec((slots,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert "gather" not in text
+    # no dense view of a pool among the temporaries: Q, the output and
+    # the padded group rows only
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20
+
+
+def test_decode_step_for_a_described_v5e_reads_the_pools_in_place(
+        chip, monkeypatch):
+    """The whole paged decode step, small but at a head of 128, compiled
+    for the described chip with the backend answered for (the op asks
+    ``jax.default_backend()``): one Mosaic call a layer, and no copy of a
+    pool anywhere — the step's ``kv_pool_write`` must leave the pool in
+    the layout the kernel reads (a ``[Hkv, D]`` scatter window made XLA
+    re-lay every pool in and out, each layer, each step)."""
+    import re
+
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from paddle_tpu import compile_cache
+    from paddle_tpu.models.llama import build_llama_decode
+    from paddle_tpu.parallel import build_sharded_step, dp_mesh
+    from paddle_tpu.parallel import sharded
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(compile_cache, "ensure_compile_cache", lambda: None)
+    monkeypatch.setattr(sharded, "ensure_compile_cache", lambda: None)
+    layers_, slots, max_seq, page = 2, 4, 64, 16
+    pages = slots * (max_seq // page) + 1
+    main, startup = pt.Program(), pt.Program()
+    startup._is_startup = True
+    with pt.program_guard(main, startup):
+        feeds, fetches, _ = build_llama_decode(
+            slots, max_seq, name="pdc", paged=True, num_pages=pages,
+            page_tokens=page, vocab_size=61, hidden=512, num_layers=layers_,
+            num_heads=4, num_kv_heads=2, intermediate=128)
+    before = stat_get("attention_lowered_paged_decode")
+    mesh = dp_mesh(1, devices=[chip])
+    fn, mut_in, const_in, _ = build_sharded_step(
+        main, feeds, [fetches["next_token"].name], mesh)
+    rep = NamedSharding(mesh, P())
+    block = main.global_block()
+
+    def spec(shape, dtype):
+        dtype = {"int64": "int32"}.get(str(dtype), str(dtype))
+        return jax.ShapeDtypeStruct(tuple(shape), np.dtype(dtype),
+                                    sharding=rep)
+
+    def state(names):
+        return tuple(spec(v.shape, v.dtype) for v in
+                     map(block._find_var_recursive, names))
+
+    shapes = {"tokens": ((slots, 1), "int32"),
+              "positions": ((slots,), "int32"),
+              "block_tables": ((slots, max_seq // page), "int32"),
+              "live": ((slots,), "int32")}
+    text = fn.lower(tuple(spec(*shapes[n]) for n in feeds), state(mut_in),
+                    state(const_in), spec((), "int32")).compile().as_text()
+    assert stat_get("attention_lowered_paged_decode") == before + layers_
+    assert text.count('custom_call_target="tpu_custom_call"') == layers_
+    pool = rf"f32\[{pages},2,{page},128\]"
+    assert re.search(pool, text), "no pool in the step's text"
+    assert not re.findall(pool + r"\{[^}]*\} copy\(", text)
