@@ -369,3 +369,10 @@ def quantile(values, q: float) -> float:
     hi = min(lo + 1, len(xs) - 1)
     return xs[lo] + (xs[hi] - xs[lo]) * (pos - lo)
 
+
+def share_over(values, times: float) -> float:
+    """Share, in percent, of a non-empty list that lies above ``times``
+    its own median: of token gaps at 2, the gaps that carried a stall
+    (a prefill) rather than a plain decode step."""
+    limit = times * quantile(values, 0.5)
+    return 100.0 * sum(v > limit for v in values) / len(values)

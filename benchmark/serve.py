@@ -15,7 +15,7 @@ import time
 import numpy as np
 
 import traffic
-from harness import HERE, quantile, seeded_weights
+from harness import HERE, quantile, seeded_weights, share_over
 
 CHECK_NEW_TOKENS = 9      # one from the prefill, eight cached decode steps
 
@@ -298,6 +298,34 @@ class Served:
         return st
 
 
+LADDER = tuple(range(90, 99)) + (98.5, 99, 99.5)
+GATE = 99                 # the percentile ``itl_p99_ms`` reads
+EDGE_NEAR = 0.03          # half a point either side within 3% of it
+STALLED_TIMES = 2.0       # a gap over twice the median carried a stall
+STALLED_MIN_PCT = 8.0
+
+
+def gap_ladder(gaps) -> dict:
+    """Where the gate sits among the token gaps: the 90th to 99.5th
+    percentiles point by point (and the 98.5th), the largest gap, the share of gaps over
+    twice their median (those that carried a prefill), and whether the
+    gate is off an edge.  Prefill lengths are padded to rungs, so the
+    stalled gaps form plateaus, one a rung; a percentile on the border
+    between two jumps with the seed, one inside a plateau reads a device
+    time.  The 99th lies inside the widest rung's plateau while that
+    holds a point and a half of the gaps or more."""
+    gaps = sorted(gaps)       # once: ``quantile`` sorts what it is given
+    rungs = {p: quantile(gaps, p / 100.0) for p in LADDER}
+    stalled = share_over(gaps, STALLED_TIMES)
+    near = max(abs(rungs[p] / rungs[GATE] - 1.0)
+               for p in (GATE - 0.5, GATE + 0.5))
+    return {"ladder_ms": {f"p{p}": round(v, 3) for p, v in rungs.items()},
+            "max_ms": round(gaps[-1], 3),
+            "stalled_gap_share_pct": round(stalled, 3),
+            "half_point_off_gate": round(near, 5),
+            "off_edge": stalled >= STALLED_MIN_PCT and near <= EDGE_NEAR}
+
+
 def summary(st: dict) -> dict:
     """The readings a window gives, by name."""
     if not st["ttft"] or not st["gaps"]:
@@ -308,6 +336,7 @@ def summary(st: dict) -> dict:
         "ttft_p95_ms": quantile(st["ttft"], 0.95),
         "itl_p50_ms": quantile(st["gaps"], 0.5),
         "itl_p95_ms": quantile(st["gaps"], 0.95),
+        "itl_p99_ms": quantile(st["gaps"], 0.99),
         "served_tokens_per_s": st["served_tokens"] / st["window_s"],
         "late_p99_ms": quantile(st["late"], 0.99),
         "slot_occupancy": sum(occ) / len(occ) if occ else float("nan"),
@@ -336,6 +365,7 @@ def run_cell(run) -> int:
             f"token gaps; " + ", ".join(
                 f"{k} {v:.3f}" for k, v in sm.items())
             + f", compiles in window {st['compiles']}")
+    run.say("token gaps " + json.dumps(gap_ladder(st["gaps"])))
     spans = st["spans"]
     ctx = {
         "run": run, "cfg": cell.cfg, "mix": cell.mix, "trace": run.trace,

@@ -88,7 +88,8 @@ def test_interval_arithmetic():
 @pytest.mark.parametrize("mix_name", ["chat-steady", "longprompt-pool"])
 def test_traffic_is_the_same_multiset_under_every_seed(mix_name):
     """Same lengths and gaps under three seeds, in another order; in a
-    closed loop every block of the stream is the same multiset."""
+    closed loop, and in an open loop cut into blocks, every block of the
+    stream is the same multiset."""
     mix = traffic.load_mix(mix_name)
     seeds = (0, 7, 3000000019)
 
@@ -120,6 +121,15 @@ def test_traffic_is_the_same_multiset_under_every_seed(mix_name):
                                for a, b in zip(win, win[1:])))
         # all gaps but the last (which closes the window) are offered
         assert [len(g) for g in gaps] == [len(gaps[0])] * 3
+        if mix.get("block_s"):
+            # every block of the window is the same work, under every seed
+            w0, bs = mix["warm_s"], mix["block_s"]
+            blocks = {tuple(sorted(
+                (r["prompt_len"] for r in run
+                 if w0 + k * bs <= r["due"] < w0 + (k + 1) * bs)))
+                for run in runs for k in range(int(40 // bs))}
+            assert len(blocks) == 1
+            assert len(blocks.pop()) == round(mix["rate_rps"] * bs)
         e = mix["engine"]
         assert max(r["prompt_len"] + r["max_new_tokens"]
                    for r in runs[0]) <= e["max_seq_len"]
@@ -129,6 +139,52 @@ def test_traffic_is_the_same_multiset_under_every_seed(mix_name):
         <= max(mix["engine"]["prefill_buckets"])
     assert traffic.token_ids(5, 1, 9, 100) == traffic.token_ids(5, 1, 9, 100)
     assert traffic.token_ids(5, 1, 9, 100) != traffic.token_ids(6, 1, 9, 100)
+
+
+def test_open_loop_mixes_offer_seven_to_eight_tenths_of_their_knee():
+    """``rate_rps`` and ``knee_rps`` are written by hand after a sweep
+    (``sweep.py``): they may not drift apart."""
+    open_mixes = []
+    for name in sorted(os.listdir(os.path.join(BENCH, "traffic"))):
+        mix = traffic.load_mix(os.path.splitext(name)[0])
+        if mix.get("loop") == "open":
+            open_mixes.append(name)
+            share = mix["rate_rps"] / mix["knee_rps"]
+            assert 0.7 <= share <= 0.8, (name, share)
+            assert mix["knee_note"]
+    assert "chat-steady.json" in open_mixes
+
+
+def test_stalled_share_and_gap_ladder():
+    """1000 gaps: 890 plain ones of 19-20 ms and 110 that carried a
+    prefill, in the four plateaus the rungs make (22 / 33 / 34 / 21 of
+    them at 40 / 70 / 104 / 166 ms).  Twice the median is 39 ms, so
+    11% are stalled; the widest rung's plateau holds the top 2.1%, so
+    the 98.5th to 99.5th percentiles lie in it, off an edge.  With a
+    third as many stalled gaps it holds 0.7%, the 99th falls on the
+    border below it and the test says so."""
+    import harness
+    import serve
+    stalled = [40.0] * 22 + [70.0] * 33 + [104.0] * 34 + [166.0] * 21
+    gaps = [19.0 + 0.001 * i for i in range(890)] + stalled
+    assert harness.share_over(gaps, 2.0) == pytest.approx(11.0)
+    reader = harness.load_module("readers", "client_share_over")
+    with open(os.path.join(BENCH, "metrics",
+                           "stalled_gap_share_pct.chat.json")) as f:
+        args = json.load(f)["args"]
+    assert reader.read({"clients": {"itl": gaps}}, **args) \
+        == pytest.approx(11.0)
+    assert reader.read({"clients": {}}, **args) is None
+    got = serve.gap_ladder(gaps)
+    assert got["ladder_ms"]["p95"] == 104.0
+    assert got["ladder_ms"]["p99"] == got["ladder_ms"]["p99.5"] == 166.0
+    assert got["max_ms"] == 166.0 and got["half_point_off_gate"] == 0.0
+    assert got["stalled_gap_share_pct"] == 11.0 and got["off_edge"]
+    few = stalled[::3]
+    edge = serve.gap_ladder(
+        [19.0 + 0.001 * i for i in range(1000 - len(few))] + few)
+    assert edge["stalled_gap_share_pct"] == pytest.approx(3.7)
+    assert edge["half_point_off_gate"] > 0.03 and not edge["off_edge"]
 
 
 def test_train_batches_depend_on_the_seed_only():

@@ -4,8 +4,9 @@ A mix (``benchmark/traffic/<name>.json``) fixes distributions and a rate or
 a worker count.  The generator never samples: it takes the evenly spaced
 quantiles of each distribution, so every run of a cell offers the same
 multiset of prompt lengths, output lengths and inter-arrival gaps.  The
-seed permutes their order and pairing (in a closed loop inside each block
-of the stream) and draws the token ids; it never changes the work.
+seed permutes their order and pairing (inside each block of the stream,
+where the mix cuts it into blocks) and draws the token ids; it never
+changes the work.
 
 Imports numpy only, so the tests and the rehearsal need no accelerator.
 """
@@ -61,9 +62,9 @@ def lengths(dist: dict, n: int) -> np.ndarray:
     return np.clip(x, int(dist.get("min", 1)), int(dist.get("max", x.max())))
 
 
-def _rng(seed: int, salt: int) -> np.random.Generator:
+def _rng(seed: int, *salt: int) -> np.random.Generator:
     # SeedSequence takes any non-negative whole number, 2**31 and beyond
-    return np.random.default_rng([int(seed), salt])
+    return np.random.default_rng([int(seed), *salt])
 
 
 def _phase(mix: dict, n: int, span_s: float, rng) -> list:
@@ -83,19 +84,27 @@ def open_schedule(mix: dict, seed: int, seconds: float,
                   tail_s: float = 0.0) -> dict:
     """Warm phase, window and (for a traced run) a tail, each its own
     stratified set, so the window holds exactly ``rate * seconds``
-    requests of the same lengths in every run.  ``due`` is in seconds from
-    the start of the warm phase."""
+    requests of the same lengths in every run.  With ``block_s`` in the
+    mix a phase is cut into blocks of that many seconds, each block its
+    own stratified set in its own seed-drawn order: every block then
+    offers the same work, and what the seed can still move (which long
+    answers are alive when the window opens and closes, how many slots
+    are live when a long prompt arrives) is held to one block, not spread
+    over the window.  ``due`` is in seconds from the start of the warm
+    phase."""
     rate, warm_s = float(mix["rate_rps"]), float(mix["warm_s"])
     reqs, t = [], 0.0
     for salt, span in ((1, warm_s), (2, float(seconds)), (5, tail_s)):
-        if span <= 0:
-            continue
-        phase = _phase(mix, max(1, round(rate * span)), span,
-                       _rng(seed, salt))
-        for r in phase:
-            r["due"] += t
-        reqs += phase
-        t += span
+        end, k = t + span, 0
+        while end - t > 1e-9:
+            part = min(float(mix.get("block_s", span)), end - t)
+            block = _phase(mix, max(1, round(rate * part)), part,
+                           _rng(seed, salt, k))
+            for r in block:
+                r["due"] += t
+            reqs += block
+            t, k = t + part, k + 1
+        t = end
     return {"loop": "open", "warm_s": warm_s, "seconds": float(seconds),
             "requests": reqs}
 
