@@ -449,6 +449,78 @@ def serve_phase(cfg=SERVE, on_chip=True):
 
 # ---------------------------------------------------------------------------
 
+SPARSE = dict(heads=28, kv_heads=4, head_dim=128, window=4096,
+              page_tokens=16, contexts=(100, 4096, 4097, 8000),
+              hidden=2560, experts=64, top_k=6, expert_width=768, slots=32)
+
+
+def sparse_window_phase(cfg=SPARSE):
+    """The two kernels a sparse-expert, windowed decoder adds to a decode
+    step, at that family's published shapes (28 query over 4 KV heads of
+    128, window 4096; 64 experts, top 6, width 768 on hidden 2560): the
+    windowed paged-decode kernel against the gather + einsum formulation,
+    and one routed expert step against a loop over all experts, both at
+    "highest" precision."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops.decode_ops import _attend_cache, _gather_pages
+    from paddle_tpu.ops.pallas.paged_attention import \
+        paged_decode_attention
+    from paddle_tpu.parallel.moe import moe_routed_tokens
+
+    key = jax.random.key(28)
+    pt_, w, ctx = cfg["page_tokens"], cfg["window"], cfg["contexts"]
+    B, NP = len(ctx), -(-max(ctx) // cfg["page_tokens"])
+    shape = (B * NP + 1, cfg["kv_heads"], pt_, cfg["head_dim"])
+    pk = jax.random.normal(jax.random.fold_in(key, 0), shape)
+    pv = jax.random.normal(jax.random.fold_in(key, 1), shape)
+    q = jax.random.normal(jax.random.fold_in(key, 2),
+                          (B, cfg["heads"], 1, cfg["head_dim"]))
+    bt = np.arange(1, B * NP + 1, dtype=np.int32).reshape(B, NP)
+    pos = np.asarray([n - 1 for n in ctx], np.int32)
+    for b, p in enumerate(pos):
+        bt[b, :max(0, p - w + 1) // pt_] = 0   # slid out: the trash page
+        bt[b, p // pt_ + 1:] = 0
+    bt, pos = jnp.asarray(bt), jnp.asarray(pos)
+    got = paged_decode_attention(q, pk, pv, bt, pos, window=w)
+    with jax.default_matmul_precision("highest"):
+        want = _attend_cache(q, _gather_pages(pk, bt),
+                             _gather_pages(pv, bt), pos, None, w)
+    rel = float(jnp.abs(got - want).max() / jnp.abs(want).max())
+    check(bool(jnp.isfinite(got).all()) and rel <= TOL,
+          f"windowed paged decode off the gather formulation by {rel:.4g}")
+    say(f"sparse: windowed paged decode, contexts {ctx} under window {w}, "
+        f"within {rel:.4g} of the gather formulation (tolerance {TOL})")
+
+    H, E, I, k = (cfg[n] for n in ("hidden", "experts", "expert_width",
+                                   "top_k"))
+    x, rx = (jax.random.normal(jax.random.fold_in(key, i),
+                               (cfg["slots"], H)) for i in (3, 4))
+    rw = jax.random.normal(jax.random.fold_in(key, 5), (H, E)) * 0.02
+    gu = jax.random.normal(jax.random.fold_in(key, 6), (E, H, 2 * I)) * 0.02
+    dn = jax.random.normal(jax.random.fold_in(key, 7), (E, I, H)) * 0.02
+    hi = jax.lax.Precision.HIGHEST
+    got, counts, logits = jax.jit(
+        lambda *a: moe_routed_tokens(*a, top_k=k, precision=hi))(
+            x, rx, rw, gu, dn)
+    with jax.default_matmul_precision("highest"):
+        top = jax.lax.top_k(logits, k)[1]
+        chosen = jax.nn.one_hot(top, E, dtype=bool).any(1)
+        wts = jax.nn.softmax(jnp.where(chosen, logits, -jnp.inf), -1)
+        h = jnp.einsum("nh,ehf->enf", x, gu)
+        y = jnp.einsum("eni,eih->enh",
+                       jnp.maximum(h[..., :I], 0) * h[..., I:], dn)
+        want = jnp.einsum("ne,enh->nh", jnp.where(chosen, wts, 0.0), y)
+    rel = float(jnp.abs(got - want).max() / jnp.abs(want).max())
+    check(int(counts.sum()) == cfg["slots"] * k and rel <= TOL,
+          f"routed expert step off the loop over all experts by {rel:.4g}, "
+          f"{int(counts.sum())} pairs placed")
+    say(f"sparse: {cfg['slots']} tokens x top-{k} of {E} experts, "
+        f"{int((counts > 0).sum())} experts touched, nothing dropped, "
+        f"within {rel:.4g} of the loop over all experts")
+
+
 def main():
     t_start = time.perf_counter()
     # the program first: in a directory that holds only this file the
@@ -487,6 +559,12 @@ def main():
     t0 = time.perf_counter()
     serve = serve_phase()
     say(f"serve phase done [{time.perf_counter() - t0:.1f} s]")
+    gc.collect()
+
+    t0 = time.perf_counter()
+    sparse_window_phase()
+    say(f"sparse and windowed kernels done "
+        f"[{time.perf_counter() - t0:.1f} s]")
 
     say(f"set-up (compile-dominated: kernel check + first train step + "
         f"serving warm-up) {train['setup_s'] + serve['setup_s']:.1f} s, "

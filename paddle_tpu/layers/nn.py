@@ -33,7 +33,7 @@ __all__ = [
     "resize_bilinear", "resize_nearest", "pixel_shuffle",
     "cos_sim", "pad2d", "expand_as", "crop_tensor", "crop",
     "pad_constant_like", "image_resize", "space_to_depth", "norm",
-    "dist", "py_func", "moe_ffn",
+    "dist", "py_func", "moe_ffn", "moe_routed_ffn",
 ]
 
 
@@ -537,7 +537,8 @@ def pad(x, paddings, pad_value=0.0, name=None):
 
 def flash_attention(q, k, v, bias=None, causal=False, scale=None,
                     seq_parallel_mode="ring", impl="auto", layout="bhsd",
-                    dropout_prob=0.0, is_test=False, name=None):
+                    dropout_prob=0.0, is_test=False, name=None,
+                    window=None):
     """Fused multi-head attention; q/k/v: [B, H, S, D] (layout "bhsd")
     or [B, S, H, D] (layout "bshd", impl="xla" only).
 
@@ -548,6 +549,9 @@ def flash_attention(q, k, v, bias=None, causal=False, scale=None,
     fastest at short/moderate S on v5e).
     bias: optional additive score bias [B, S] (or [B,1,1,S]) — the padding
     mask, 0 = attend / -1e4 = pad.
+    window: with ``causal``, query i attends keys j with
+    ``i - window < j <= i`` (the window counts the token itself); None
+    leaves the op exactly as it was.
     """
     helper = LayerHelper("flash_attention", name=name)
     out = helper.create_variable_for_type_inference(q.dtype)
@@ -556,6 +560,8 @@ def flash_attention(q, k, v, bias=None, causal=False, scale=None,
              "dropout_prob": float(dropout_prob), "is_test": is_test}
     if scale is not None:
         attrs["scale"] = float(scale)
+    if window is not None:
+        attrs["window"] = int(window)
     inputs = {"Q": [q], "K": [k], "V": [v]}
     if bias is not None:
         inputs["Bias"] = [bias]
@@ -684,17 +690,20 @@ def kv_pool_gather(pool, block_table, name=None):
 
 
 def cached_attention(q, cache_k, cache_v, positions, scale=None,
-                     name=None):
+                     name=None, window=None):
     """Decode-step attention over a KV cache: ``q`` [B, H, T, D]
     attends ``cache_k``/``cache_v`` [B, Hkv, S_max, D] with per-row
     validity ``j <= positions[b] + t`` (``positions`` [B] = pre-step
     sequence length).  GQA caches expand repeat-interleave style inside
-    the op.  Returns [B, H, T, D]."""
+    the op.  ``window`` adds the lower bound ``j > positions[b] + t -
+    window``.  Returns [B, H, T, D]."""
     helper = LayerHelper("cached_attention", name=name)
     out = helper.create_variable_for_type_inference(q.dtype)
     attrs = {}
     if scale is not None:
         attrs["scale"] = float(scale)
+    if window is not None:
+        attrs["window"] = int(window)
     helper.append_op("cached_attention",
                      inputs={"Q": [q], "K": [cache_k], "V": [cache_v],
                              "Positions": [positions]},
@@ -703,18 +712,23 @@ def cached_attention(q, cache_k, cache_v, positions, scale=None,
 
 
 def paged_decode_attention(q, pool_k, pool_v, block_table, positions,
-                           scale=None, name=None):
+                           scale=None, name=None, window=None):
     """The paged decode step's attention: ``q`` [B, H, 1, D] (one new
     token per slot) attends pools ``pool_k``/``pool_v`` [P, Hkv, pt, D]
     through ``block_table`` [B, NP] at columns ``j <= positions[b]``.
     A TPU backend reads the live pages in place (Pallas kernel); any
     other runs :func:`kv_pool_gather` + :func:`cached_attention`'s
-    formulation, bit for bit.  Returns [B, H, 1, D]."""
+    formulation, bit for bit.  ``window`` bounds the columns below too
+    (``j > positions[b] - window``): pages left of the window are never
+    read, and their block-table entries may point at the trash page.
+    Returns [B, H, 1, D]."""
     helper = LayerHelper("paged_decode_attention", name=name)
     out = helper.create_variable_for_type_inference(q.dtype)
     attrs = {}
     if scale is not None:
         attrs["scale"] = float(scale)
+    if window is not None:
+        attrs["window"] = int(window)
     helper.append_op("paged_decode_attention",
                      inputs={"Q": [q], "PoolK": [pool_k],
                              "PoolV": [pool_v],
@@ -1101,6 +1115,48 @@ def py_func(func, x, out, backward_func=None,
         attrs={"forward_callable_id": fid,
                "backward_callable_id": bid})
     return out
+
+
+def moe_routed_ffn(x, router_x, num_experts, top_k, d_ff,
+                   activation="relu", valid=None, name=None,
+                   keep_router_logits=False):
+    """Dropless top-k mixture of gated experts without bias
+    (ops/moe_ops.py ``moe_routed_ffn``): each token of ``x`` [B, S, H]
+    goes to the ``top_k`` experts its row of ``router_x`` [B, S, H]
+    scores highest (float32 logits, softmax over the selected), through
+    ``act(x W_gate) * (x W_up)`` then ``W_down``; no capacity, nothing
+    dropped.  ``valid`` [B] int: real rows per batch row, for the count.
+    ``name`` prefixes the parameters ``.router.w`` [H, E], ``.gate_up.w``
+    [E, H, 2 d_ff] and ``.down.w`` [E, d_ff, H].  Returns ``(out,
+    expert_count [E] int32, router_logits or None)``."""
+    from ..framework.initializer import XavierInitializer
+
+    helper = LayerHelper("moe_routed_ffn", name=name)
+    h, e, i = int(x.shape[-1]), int(num_experts), int(d_ff)
+    p = (lambda s: f"{name}.{s}") if name else (lambda s: None)
+    router_w = helper.create_parameter(p("router.w"), [h, e], x.dtype)
+    # per-expert matrices: Glorot over one expert's fan, not the stack's
+    gate_up = helper.create_parameter(
+        p("gate_up.w"), [e, h, 2 * i], x.dtype,
+        default_initializer=XavierInitializer(fan_in=h, fan_out=2 * i))
+    down = helper.create_parameter(
+        p("down.w"), [e, i, h], x.dtype,
+        default_initializer=XavierInitializer(fan_in=i, fan_out=h))
+    out = helper.create_variable_for_type_inference(x.dtype)
+    counts = helper.create_variable_for_type_inference("int32")
+    inputs = {"X": [x], "RouterX": [router_x], "RouterW": [router_w],
+              "GateUpW": [gate_up], "DownW": [down]}
+    if valid is not None:
+        inputs["Valid"] = [valid]
+    outputs = {"Out": [out], "ExpertCount": [counts]}
+    logits = None
+    if keep_router_logits:
+        logits = helper.create_variable_for_type_inference("float32")
+        outputs["RouterLogits"] = [logits]
+    helper.append_op("moe_routed_ffn", inputs=inputs, outputs=outputs,
+                     attrs={"top_k": int(top_k),
+                            "activation": activation})
+    return out, counts, logits
 
 
 def moe_ffn(x, num_experts, d_ff, capacity_factor=1.25,

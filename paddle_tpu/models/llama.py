@@ -35,17 +35,91 @@ from __future__ import annotations
 
 from .. import layers
 
+# One layer of the per-layer pattern.  A model's ``layer_pattern`` is a
+# list of such dicts (keys left out take these values) that tiles over
+# the depth: layer i runs ``layer_pattern[i % len(layer_pattern)]``.
+#   window: None (every earlier token) or W: token i attends j with
+#           i - W < j <= i (the window counts the token itself)
+#   rope:   rotary embeddings on q and k, or none at all (NoPE)
+#   ffn:    "dense" (SwiGLU of width ``intermediate``) or a dict
+#           {"experts": E, "top_k": k, "width": I, "activation": "relu"}:
+#           dropless top-k gated experts routed from the layer's RAW
+#           input, before its first norm and its attention
+DEFAULT_LAYER = {"window": None, "rope": True, "ffn": "dense"}
+
+
+def layer_spec(layer_pattern, i):
+    """Layer ``i``'s entry of the pattern, defaults filled in."""
+    if not layer_pattern:
+        return DEFAULT_LAYER
+    return dict(DEFAULT_LAYER, **layer_pattern[i % len(layer_pattern)])
+
+
+def window_layers(layer_pattern, num_layers):
+    """Indices of the layers whose attention is a sliding window."""
+    return [i for i in range(num_layers)
+            if layer_spec(layer_pattern, i)["window"] is not None]
+
+
+def expert_layers(layer_pattern, num_layers):
+    """Indices of the layers whose FFN is routed experts."""
+    return [i for i in range(num_layers)
+            if layer_spec(layer_pattern, i)["ffn"] != "dense"]
+
 
 def _linear(x, size, pname=None, name=None):
     return layers.fc(x, size, num_flatten_dims=2, bias_attr=False,
                      param_attr=pname, name=name)
 
 
+def _taps_fetches(taps):
+    """What a program's expert layers recorded, as fetches:
+    ``expert_counts`` [L_moe, E] int32 (tokens each expert got, valid
+    rows only) and, where kept, ``router_logits`` [B, L_moe, E]."""
+    out = {}
+    if taps.get("counts"):
+        out["expert_counts"] = layers.stack(taps["counts"], axis=0)
+    if taps.get("logits"):
+        out["router_logits"] = layers.stack(taps["logits"], axis=1)
+    return out
+
+
+def _kv_vars(block, name, i, shape, paged=True):
+    """Layer ``i``'s persistable K and V pools (``paged``) or dense
+    caches."""
+    stem = "pool" if paged else "cache"
+    return tuple(block.create_var(
+        name=f"{name}.{stem}_{kind}_{i}", persistable=True, shape=shape,
+        dtype="float32", stop_gradient=True) for kind in ("k", "v"))
+
+
+def _head_on_rows(x, rows_idx, vocab_size, name, eps):
+    """Final norm and LM head on one gathered row per batch row: x
+    [B, S, H], ``rows_idx`` [B] int64 -> logits [B, V].  The head then
+    costs V x H per request, not S x V x H (5 GB of float32 logits at
+    8192 x 151936)."""
+    batch = x.shape[0]
+    rows = layers.range(0, batch, 1, dtype="int64")
+    coords = layers.stack([rows, rows_idx], axis=1)          # [B, 2]
+    x = layers.unsqueeze(layers.gather_nd(x, coords), [1])   # [B, 1, H]
+    x = layers.rms_norm(x, epsilon=eps, param_attr=f"{name}.ln_f")
+    return layers.squeeze(
+        _linear(x, vocab_size, pname=f"{name}.head.w"), [1])
+
+
 def llama_block(x, hidden, num_heads, num_kv_heads, seq_len, head_dim,
                 intermediate, name=None, attn_impl="auto",
                 kv_cache=None, positions=None, collect_kv=False,
-                block_table=None, kv_lengths=None):
+                block_table=None, kv_lengths=None, rms_norm_eps=1e-6,
+                rope_base=10000.0, layer=None, valid=None, taps=None):
     """One decoder layer. x: [B, S, H].
+
+    ``layer`` is the layer's entry of the model's pattern
+    (:data:`DEFAULT_LAYER`; None is the default: full causal attention,
+    RoPE, dense SwiGLU).  With routed experts ``valid`` [B] int is the
+    number of real rows per batch row (for the expert counts) and
+    ``taps`` a dict the layer appends its ``counts`` (and, when
+    ``taps["keep_logits"]``, its router ``logits``) to.
 
     ``name`` prefixes every parameter deterministically (required when
     several programs must share one scope).  ``attn_impl`` feeds the
@@ -69,10 +143,15 @@ def llama_block(x, hidden, num_heads, num_kv_heads, seq_len, head_dim,
       * ``collect_kv=True`` — prefill: returns ``(x, k, v)`` where
         k/v are the post-RoPE [B, n_kv, S, D] cache rows.
     """
+    layer = layer or DEFAULT_LAYER
+    # a layer without a window calls the attention layers exactly as
+    # before the pattern existed
+    win = {} if layer["window"] is None else {"window": layer["window"]}
     q_size = num_heads * head_dim
     kv_size = num_kv_heads * head_dim
     p = (lambda s: f"{name}.{s}") if name else (lambda s: None)
-    h = layers.rms_norm(x, param_attr=p("ln1"))
+    x_in = x          # routed experts read the raw layer input
+    h = layers.rms_norm(x, epsilon=rms_norm_eps, param_attr=p("ln1"))
     qkv = _linear(h, q_size + 2 * kv_size, pname=p("qkv.w"))
     q = layers.slice(qkv, axes=[2], starts=[0], ends=[q_size])
     k = layers.slice(qkv, axes=[2], starts=[q_size],
@@ -86,9 +165,10 @@ def llama_block(x, hidden, num_heads, num_kv_heads, seq_len, head_dim,
 
     q, k, v = heads(q, num_heads), heads(k, num_kv_heads), \
         heads(v, num_kv_heads)
-    offset = positions if kv_cache is not None else None
-    q = layers.rope(q, offset=offset)
-    k = layers.rope(k, offset=offset)
+    if layer["rope"]:
+        offset = positions if kv_cache is not None else None
+        q = layers.rope(q, base=rope_base, offset=offset)
+        k = layers.rope(k, base=rope_base, offset=offset)
 
     if kv_cache is not None:
         # cached decode: write this step's K/V at each slot's position,
@@ -107,17 +187,18 @@ def llama_block(x, hidden, num_heads, num_kv_heads, seq_len, head_dim,
             if seq_len == 1:
                 # the decode step: live pages in place
                 attn = layers.paged_decode_attention(
-                    q, cache_k, cache_v, block_table, positions)
+                    q, cache_k, cache_v, block_table, positions, **win)
             else:
                 # a chunk of query rows: the gathered logical view
                 gk = layers.kv_pool_gather(cache_k, block_table)
                 gv = layers.kv_pool_gather(cache_v, block_table)
-                attn = layers.cached_attention(q, gk, gv, positions)
+                attn = layers.cached_attention(q, gk, gv, positions,
+                                               **win)
         else:
             cache_k = layers.kv_cache_write(cache_k, k, positions)
             cache_v = layers.kv_cache_write(cache_v, v, positions)
             attn = layers.cached_attention(q, cache_k, cache_v,
-                                           positions)
+                                           positions, **win)
     else:
         cache_k = cache_v = None
         new_k, new_v = k, v  # pre-expansion rows are what a cache stores
@@ -137,20 +218,32 @@ def llama_block(x, hidden, num_heads, num_kv_heads, seq_len, head_dim,
 
             k, v = expand_kv(k), expand_kv(v)
         attn = layers.flash_attention(q, k, v, causal=True,
-                                      impl=attn_impl)
+                                      impl=attn_impl, **win)
     attn = layers.transpose(attn, [0, 2, 1, 3])
     attn = layers.reshape(attn, [0, seq_len, q_size])
     x = layers.elementwise_add(x, _linear(attn, hidden,
                                           pname=p("attn_out.w")))
 
-    h = layers.rms_norm(x, param_attr=p("ln2"))
-    gate_up = _linear(h, 2 * intermediate, pname=p("gate_up.w"))
-    gate = layers.slice(gate_up, axes=[2], starts=[0], ends=[intermediate])
-    up = layers.slice(gate_up, axes=[2], starts=[intermediate],
-                      ends=[2 * intermediate])
-    ffn = layers.elementwise_mul(layers.silu(gate), up)
-    out = layers.elementwise_add(x, _linear(ffn, hidden,
-                                            pname=p("ffn_out.w")))
+    h = layers.rms_norm(x, epsilon=rms_norm_eps, param_attr=p("ln2"))
+    ffn = layer["ffn"]
+    if ffn == "dense":
+        gate_up = _linear(h, 2 * intermediate, pname=p("gate_up.w"))
+        gate = layers.slice(gate_up, axes=[2], starts=[0],
+                            ends=[intermediate])
+        up = layers.slice(gate_up, axes=[2], starts=[intermediate],
+                          ends=[2 * intermediate])
+        y = _linear(layers.elementwise_mul(layers.silu(gate), up), hidden,
+                    pname=p("ffn_out.w"))
+    else:
+        taps = taps if taps is not None else {}
+        y, counts, logits = layers.moe_routed_ffn(
+            h, x_in, ffn["experts"], ffn["top_k"], ffn["width"],
+            activation=ffn.get("activation", "relu"), valid=valid,
+            name=p("moe"), keep_router_logits=bool(taps.get("keep_logits")))
+        taps.setdefault("counts", []).append(counts)
+        if logits is not None:
+            taps.setdefault("logits", []).append(logits)
+    out = layers.elementwise_add(x, y)
     if collect_kv:
         return out, new_k, new_v
     return out
@@ -158,10 +251,16 @@ def llama_block(x, hidden, num_heads, num_kv_heads, seq_len, head_dim,
 
 def llama(input_ids, vocab_size=32000, hidden=4096, num_layers=32,
           num_heads=32, num_kv_heads=None, intermediate=11008,
-          seq_len=2048, name=None, attn_impl="auto"):
-    """Returns logits [B, S, V]. input_ids: [B, S] int64."""
+          seq_len=2048, name=None, attn_impl="auto", head_dim=None,
+          rms_norm_eps=1e-6, rope_base=10000.0, layer_pattern=None):
+    """Returns logits [B, S, V]. input_ids: [B, S] int64.
+
+    ``head_dim`` defaults to ``hidden // num_heads`` (a model may
+    publish another: q is then ``num_heads * head_dim`` wide);
+    ``layer_pattern`` is described at :data:`DEFAULT_LAYER`.  The
+    defaults build exactly the program they always did."""
     num_kv_heads = num_kv_heads or num_heads
-    head_dim = hidden // num_heads
+    head_dim = head_dim or hidden // num_heads
     p = (lambda s: f"{name}.{s}") if name else (lambda s: None)
     x = layers.embedding(input_ids, size=[vocab_size, hidden],
                          param_attr=p("embed"))
@@ -169,22 +268,27 @@ def llama(input_ids, vocab_size=32000, hidden=4096, num_layers=32,
         x = llama_block(x, hidden, num_heads, num_kv_heads, seq_len,
                         head_dim, intermediate,
                         name=f"{name}.blk{i}" if name else None,
-                        attn_impl=attn_impl)
-    x = layers.rms_norm(x, param_attr=p("ln_f"))
+                        attn_impl=attn_impl, rms_norm_eps=rms_norm_eps,
+                        rope_base=rope_base,
+                        layer=layer_spec(layer_pattern, i))
+    x = layers.rms_norm(x, epsilon=rms_norm_eps, param_attr=p("ln_f"))
     return _linear(x, vocab_size, pname=p("head.w"))
 
 
 def build_llama_train(batch_size=None, seq_len=2048, vocab_size=32000,
                       hidden=4096, num_layers=32, num_heads=32,
-                      num_kv_heads=None, intermediate=11008):
-    """Causal-LM training graph: feeds input_ids + labels [B, S]."""
+                      num_kv_heads=None, intermediate=11008, **arch):
+    """Causal-LM training graph: feeds input_ids + labels [B, S].
+    ``arch``: :func:`llama`'s ``head_dim`` / ``rms_norm_eps`` /
+    ``rope_base`` / ``layer_pattern`` (dense FFNs only: the routed
+    experts are inference-only)."""
     b = -1 if batch_size is None else batch_size
     input_ids = layers.data("input_ids", [b, seq_len], dtype="int64",
                             append_batch_size=False)
     labels = layers.data("labels", [b, seq_len], dtype="int64",
                          append_batch_size=False)
     logits = llama(input_ids, vocab_size, hidden, num_layers, num_heads,
-                   num_kv_heads, intermediate, seq_len)
+                   num_kv_heads, intermediate, seq_len, **arch)
     loss = layers.softmax_with_cross_entropy(
         logits, layers.unsqueeze(labels, [2]))
     mean_loss = layers.mean(layers.squeeze(loss, [2]))
@@ -198,7 +302,7 @@ def build_llama_train(batch_size=None, seq_len=2048, vocab_size=32000,
 def build_llama_forward(batch_size, seq_len, vocab_size=32000,
                         hidden=4096, num_layers=32, num_heads=32,
                         num_kv_heads=None, intermediate=11008,
-                        name="llama", attn_impl="auto"):
+                        name="llama", attn_impl="auto", **arch):
     """Uncached full forward: feeds input_ids [B, S], fetches logits
     [B, S, V] (causal — row i depends only on tokens ≤ i, so one run
     yields every decode step's reference logits)."""
@@ -206,7 +310,7 @@ def build_llama_forward(batch_size, seq_len, vocab_size=32000,
                             dtype="int64", append_batch_size=False)
     logits = llama(input_ids, vocab_size, hidden, num_layers, num_heads,
                    num_kv_heads, intermediate, seq_len, name=name,
-                   attn_impl=attn_impl)
+                   attn_impl=attn_impl, **arch)
     return ["input_ids"], {"logits": logits}
 
 
@@ -215,9 +319,22 @@ def build_llama_prefill(batch_size, seq_len, vocab_size=32000,
                         num_kv_heads=None, intermediate=11008,
                         name="llama", attn_impl="auto",
                         cache_slots=None, max_seq_len=None,
-                        paged=False, num_pages=None, page_tokens=None):
+                        paged=False, num_pages=None, page_tokens=None,
+                        head_dim=None, rms_norm_eps=1e-6,
+                        rope_base=10000.0, layer_pattern=None,
+                        num_window_pages=None, keep_router_logits=False):
     """Prefill entry point: one causal forward over the (padded) prompt
     that populates a decode cache in one shot.
+
+    Sliding-window layers of a paged model keep their pages in pools of
+    their own (``num_window_pages`` pages each) behind a second feed
+    ``block_table_window`` [1, NP]: the engine maps only the pages the
+    window still covers after the prompt, and rows of earlier pages
+    follow their zero entries to the trash page.  The final norm and
+    the head run on the gathered ``last_pos`` row alone.
+    Routed-expert layers add the fetch ``expert_counts`` [L_moe, E]
+    and, with ``keep_router_logits``, ``router_logits`` [B, L_moe, E]
+    at ``last_pos``.
 
     Feeds: ``input_ids`` [B, S] int64 (right-padded to the bucket) and
     ``last_pos`` [B] int64 (index of the last real token).  Fetches:
@@ -248,13 +365,14 @@ def build_llama_prefill(batch_size, seq_len, vocab_size=32000,
     from ..framework.core import default_main_program
 
     num_kv_heads = num_kv_heads or num_heads
-    head_dim = hidden // num_heads
+    head_dim = head_dim or hidden // num_heads
     input_ids = layers.data("input_ids", [batch_size, seq_len],
                             dtype="int64", append_batch_size=False)
     last_pos = layers.data("last_pos", [batch_size], dtype="int64",
                            append_batch_size=False)
     feeds = ["input_ids", "last_pos"]
-    slot = block_table = prompt_len = zero_pos = None
+    slot = block_table = bt_window = prompt_len = zero_pos = None
+    windowed = window_layers(layer_pattern, num_layers)
     if cache_slots is not None:
         if batch_size != 1:
             raise ValueError("in-graph cache insert prefills one "
@@ -273,6 +391,14 @@ def build_llama_prefill(batch_size, seq_len, vocab_size=32000,
             prompt_len = layers.data("prompt_len", [1], dtype="int32",
                                      append_batch_size=False)
             feeds += ["block_table", "prompt_len"]
+            if windowed:
+                if not num_window_pages:
+                    raise ValueError("paged prefill with sliding-window "
+                                     "layers needs num_window_pages")
+                bt_window = layers.data("block_table_window",
+                                        [1, np_slot], dtype="int32",
+                                        append_batch_size=False)
+                feeds.append("block_table_window")
             zero_pos = layers.fill_constant([1], "int32", 0)
         else:
             slot = layers.data("slot", [1], dtype="int32",
@@ -281,59 +407,79 @@ def build_llama_prefill(batch_size, seq_len, vocab_size=32000,
     x = layers.embedding(input_ids, size=[vocab_size, hidden],
                          param_attr=f"{name}.embed")
     kvs = []
+    taps = {"keep_logits": keep_router_logits}
+    # the expert layers count real rows only: the paged path feeds their
+    # number, the others have it as last_pos + 1
+    valid = prompt_len
+    if valid is None and expert_layers(layer_pattern, num_layers):
+        valid = layers.cast(last_pos + 1, "int32")
     block = default_main_program().global_block()
     for i in range(num_layers):
         x, k, v = llama_block(x, hidden, num_heads, num_kv_heads,
                               seq_len, head_dim, intermediate,
                               name=f"{name}.blk{i}", attn_impl=attn_impl,
-                              collect_kv=True)
+                              collect_kv=True, rms_norm_eps=rms_norm_eps,
+                              rope_base=rope_base,
+                              layer=layer_spec(layer_pattern, i),
+                              valid=valid, taps=taps)
         if block_table is not None:
             # paged: the prompt's K/V scatter across the slot's pages
             # from logical position 0; pad-tail rows (>= prompt_len)
-            # go to the trash page
-            for kind, t in (("k", k), ("v", v)):
-                pool = block.create_var(
-                    name=f"{name}.pool_{kind}_{i}", persistable=True,
-                    shape=[num_pages, num_kv_heads, page_tokens,
-                           head_dim],
-                    dtype="float32", stop_gradient=True)
-                layers.kv_pool_write(pool, t, zero_pos, block_table,
-                                     prompt_len)
+            # go to the trash page, and so do a window layer's rows
+            # whose page the window no longer covers
+            in_window = i in windowed
+            pools = _kv_vars(block, name, i, [
+                num_window_pages if in_window else num_pages,
+                num_kv_heads, page_tokens, head_dim])
+            for pool, t in zip(pools, (k, v)):
+                layers.kv_pool_write(
+                    pool, t, zero_pos,
+                    bt_window if in_window else block_table, prompt_len)
         elif slot is not None:
-            for kind, t in (("k", k), ("v", v)):
-                cache = block.create_var(
-                    name=f"{name}.cache_{kind}_{i}", persistable=True,
-                    shape=[cache_slots, num_kv_heads, max_seq_len,
-                           head_dim],
-                    dtype="float32", stop_gradient=True)
+            caches = _kv_vars(block, name, i, [
+                cache_slots, num_kv_heads, max_seq_len, head_dim],
+                paged=False)
+            for cache, t in zip(caches, (k, v)):
                 layers.kv_cache_insert(cache, t, slot)
         else:
             kvs.append((k, v))
-    x = layers.rms_norm(x, param_attr=f"{name}.ln_f")
-    # LM head over ALL rows, then gather each row's last real position.
-    # Gathering the hidden state first and projecting only that row
-    # would save (S-1)·V head FLOPs, but XLA fuses the gather into the
-    # projection and the fused contraction's accumulation order drifts
-    # ~5e-8 from the full-forward GEMM — breaking the bit-exactness
-    # contract (cached decode ≡ uncached forward, tolerance 0).
-    all_logits = _linear(x, vocab_size, pname=f"{name}.head.w")
-    rows = layers.range(0, batch_size, 1, dtype="int64")     # [B]
-    coords = layers.stack([rows, last_pos], axis=1)          # [B, 2]
-    logits = layers.gather_nd(all_logits, coords)            # [B, V]
-    next_token = layers.argmax(logits, axis=-1)              # [B] int64
-    fetches = {"logits": logits, "next_token": next_token}
+    logits = _head_on_rows(x, last_pos, vocab_size, name, rms_norm_eps)
+    return feeds, _prefill_fetches(logits, kvs, taps, last_pos)
+
+
+def _prefill_fetches(logits, kvs, taps, last_pos):
+    """A prefill's fetches from its gathered logits [B, V]: the greedy
+    token, uncached K/V rows, and what the expert layers recorded (the
+    router logits at ``last_pos``)."""
+    fetches = {"logits": logits,
+               "next_token": layers.argmax(logits, axis=-1)}  # [B] int64
     for i, (k, v) in enumerate(kvs):
         fetches[f"k_{i}"] = k
         fetches[f"v_{i}"] = v
-    return feeds, fetches
+    if taps.get("logits"):
+        rows = layers.range(0, int(logits.shape[0]), 1, dtype="int64")
+        coords = layers.stack([rows, last_pos], axis=1)
+        taps["logits"] = [layers.gather_nd(t, coords)         # [B, E]
+                          for t in taps["logits"]]
+    fetches.update(_taps_fetches(taps))
+    return fetches
 
 
 def build_llama_decode(num_slots, max_seq_len, vocab_size=32000,
                        hidden=4096, num_layers=32, num_heads=32,
                        num_kv_heads=None, intermediate=11008,
                        name="llama", paged=False, num_pages=None,
-                       page_tokens=None):
+                       page_tokens=None, head_dim=None, rms_norm_eps=1e-6,
+                       rope_base=10000.0, layer_pattern=None,
+                       num_window_pages=None, keep_router_logits=False):
     """Cached decode step over a fixed slot grid.
+
+    A paged model's sliding-window layers read pools of
+    ``num_window_pages`` pages through a feed of their own,
+    ``block_tables_window`` [slots, NP] (entries left of a slot's window
+    are the trash page).  Routed-expert layers add the fetch
+    ``expert_counts`` [L_moe, E] over the live slots and, with
+    ``keep_router_logits``, ``router_logits`` [slots, L_moe, E].
 
     Feeds: ``tokens`` [slots, 1] int64 (each slot's current token) and
     ``positions`` [slots] int32 (each slot's pre-step sequence length =
@@ -348,19 +494,22 @@ def build_llama_decode(num_slots, max_seq_len, vocab_size=32000,
     and adds feeds ``block_tables`` [slots, NP] int32 (NP =
     max_seq_len // page_tokens) and ``live`` [slots] int32 (1 = the
     slot decodes this step, 0 = idle — its garbage write is redirected
-    to the trash page instead of landing in a live page).
+    to the trash page instead of landing in a live page).  A model with
+    routed experts takes ``live`` over dense caches too: its expert
+    layers count the live slots' tokens only.
 
     Returns ``(feed_names, fetches, cache_names)``."""
     from ..framework.core import default_main_program
 
     num_kv_heads = num_kv_heads or num_heads
-    head_dim = hidden // num_heads
+    head_dim = head_dim or hidden // num_heads
     tokens = layers.data("tokens", [num_slots, 1], dtype="int64",
                          append_batch_size=False)
     positions = layers.data("positions", [num_slots], dtype="int32",
                             append_batch_size=False)
     feeds = ["tokens", "positions"]
-    block_tables = live = None
+    block_tables = bt_window = live = None
+    windowed = window_layers(layer_pattern, num_layers)
     if paged:
         if not num_pages or not page_tokens:
             raise ValueError("paged decode needs num_pages and "
@@ -372,42 +521,110 @@ def build_llama_decode(num_slots, max_seq_len, vocab_size=32000,
         live = layers.data("live", [num_slots], dtype="int32",
                            append_batch_size=False)
         feeds += ["block_tables", "live"]
+        if windowed:
+            if not num_window_pages:
+                raise ValueError("paged decode with sliding-window "
+                                 "layers needs num_window_pages")
+            bt_window = layers.data("block_tables_window",
+                                    [num_slots, np_slot], dtype="int32",
+                                    append_batch_size=False)
+            feeds.append("block_tables_window")
+    elif expert_layers(layer_pattern, num_layers):
+        # dense caches need no live mask, the experts' counts do
+        live = layers.data("live", [num_slots], dtype="int32",
+                           append_batch_size=False)
+        feeds.append("live")
     block = default_main_program().global_block()
     cache_names = []
     caches = []
     for i in range(num_layers):
         if paged:
-            shape = [num_pages, num_kv_heads, page_tokens, head_dim]
-            knm, vnm = f"{name}.pool_k_{i}", f"{name}.pool_v_{i}"
+            shape = [num_window_pages if i in windowed else num_pages,
+                     num_kv_heads, page_tokens, head_dim]
         else:
             shape = [num_slots, num_kv_heads, max_seq_len, head_dim]
-            knm, vnm = f"{name}.cache_k_{i}", f"{name}.cache_v_{i}"
-        ck = block.create_var(name=knm, persistable=True, shape=shape,
-                              dtype="float32", stop_gradient=True)
-        cv = block.create_var(name=vnm, persistable=True, shape=shape,
-                              dtype="float32", stop_gradient=True)
+        ck, cv = _kv_vars(block, name, i, shape, paged=paged)
         caches.append((ck, cv))
         cache_names += [ck.name, cv.name]
     x = layers.embedding(tokens, size=[vocab_size, hidden],
                          param_attr=f"{name}.embed")
+    taps = {"keep_logits": keep_router_logits}
     for i, (ck, cv) in enumerate(caches):
         x = llama_block(x, hidden, num_heads, num_kv_heads, 1, head_dim,
                         intermediate, name=f"{name}.blk{i}",
                         kv_cache=(ck, cv), positions=positions,
-                        block_table=block_tables, kv_lengths=live)
-    x = layers.rms_norm(x, param_attr=f"{name}.ln_f")
+                        block_table=bt_window if i in windowed
+                        else block_tables, kv_lengths=live,
+                        rms_norm_eps=rms_norm_eps, rope_base=rope_base,
+                        layer=layer_spec(layer_pattern, i), valid=live,
+                        taps=taps)
+    x = layers.rms_norm(x, epsilon=rms_norm_eps,
+                        param_attr=f"{name}.ln_f")
     logits = _linear(x, vocab_size, pname=f"{name}.head.w")  # [slots,1,V]
     logits = layers.squeeze(logits, [1])                     # [slots, V]
     next_token = layers.argmax(logits, axis=-1)              # [slots]
-    return feeds, \
-        {"logits": logits, "next_token": next_token}, cache_names
+    fetches = {"logits": logits, "next_token": next_token}
+    if taps.get("logits"):
+        taps["logits"] = [layers.squeeze(t, [1]) for t in taps["logits"]]
+    fetches.update(_taps_fetches(taps))
+    return feeds, fetches, cache_names
+
+
+def _chunk_forward(chunk_len, max_seq_len, num_pages, page_tokens,
+                   vocab_size, hidden, num_layers, num_heads, num_kv_heads,
+                   intermediate, name, head_dim=None, rms_norm_eps=1e-6,
+                   rope_base=10000.0, layer_pattern=None):
+    """The forward that the chunk and the verify programs share: C new
+    tokens at ``base`` attend the slot's pages plus themselves causally.
+    Returns ``(feed_names, x [1, C, H] before the final norm,
+    cache_names, taps)``.  One block table serves every layer, so a
+    model with sliding-window layers (two page kinds) has no such
+    program."""
+    from ..framework.core import default_main_program
+
+    if window_layers(layer_pattern, num_layers):
+        raise ValueError(
+            "prefill continuation (chunked prefill, prefix reuse, "
+            "speculative verify) is not built for a model with "
+            "sliding-window layers: their pages live in a second pool")
+    num_kv_heads = num_kv_heads or num_heads
+    head_dim = head_dim or hidden // num_heads
+    np_slot = max_seq_len // page_tokens
+    chunk_ids = layers.data("chunk_ids", [1, chunk_len], dtype="int64",
+                            append_batch_size=False)
+    base = layers.data("base", [1], dtype="int32",
+                       append_batch_size=False)
+    block_table = layers.data("block_table", [1, np_slot],
+                              dtype="int32", append_batch_size=False)
+    ck_len = layers.data("chunk_len", [1], dtype="int32",
+                         append_batch_size=False)
+    block = default_main_program().global_block()
+    cache_names = []
+    x = layers.embedding(chunk_ids, size=[vocab_size, hidden],
+                         param_attr=f"{name}.embed")
+    taps = {}
+    for i in range(num_layers):
+        ck, cv = _kv_vars(block, name, i, [num_pages, num_kv_heads,
+                                            page_tokens, head_dim])
+        cache_names += [ck.name, cv.name]
+        # rope offset = base per row; cached_attention's validity mask
+        # (j <= base + t) is exactly causal-over-prefix-plus-chunk
+        x = llama_block(x, hidden, num_heads, num_kv_heads, chunk_len,
+                        head_dim, intermediate, name=f"{name}.blk{i}",
+                        kv_cache=(ck, cv), positions=base,
+                        block_table=block_table, kv_lengths=ck_len,
+                        rms_norm_eps=rms_norm_eps, rope_base=rope_base,
+                        layer=layer_spec(layer_pattern, i), valid=ck_len,
+                        taps=taps)
+    return ["chunk_ids", "base", "block_table", "chunk_len"], x, \
+        cache_names, taps
 
 
 def build_llama_prefill_chunk(chunk_len, max_seq_len, num_pages,
                               page_tokens, vocab_size=32000,
                               hidden=4096, num_layers=32, num_heads=32,
                               num_kv_heads=None, intermediate=11008,
-                              name="llama"):
+                              name="llama", **arch):
     """Paged prefill *continuation*: one slice of a prompt attends the
     slot's already-populated pages plus itself causally — the program
     behind both **chunked prefill** (a long prompt feeds in
@@ -422,62 +639,28 @@ def build_llama_prefill_chunk(chunk_len, max_seq_len, num_pages,
     writes to the trash page), ``last_off`` [1] int64 (index of the
     last real token within the chunk).  Fetches: ``logits`` [1, V] at
     ``last_off`` and greedy ``next_token`` [1] — meaningful only for
-    a prompt's final chunk.
+    a prompt's final chunk.  ``arch``: ``head_dim``, ``rms_norm_eps``,
+    ``rope_base``, ``layer_pattern`` as :func:`llama` takes them.
 
     Returns ``(feed_names, fetches, cache_names)``."""
-    from ..framework.core import default_main_program
-
-    num_kv_heads = num_kv_heads or num_heads
-    head_dim = hidden // num_heads
-    np_slot = max_seq_len // page_tokens
-    chunk_ids = layers.data("chunk_ids", [1, chunk_len], dtype="int64",
-                            append_batch_size=False)
-    base = layers.data("base", [1], dtype="int32",
-                       append_batch_size=False)
-    block_table = layers.data("block_table", [1, np_slot],
-                              dtype="int32", append_batch_size=False)
-    ck_len = layers.data("chunk_len", [1], dtype="int32",
-                         append_batch_size=False)
+    feeds, x, cache_names, taps = _chunk_forward(
+        chunk_len, max_seq_len, num_pages, page_tokens, vocab_size,
+        hidden, num_layers, num_heads, num_kv_heads, intermediate, name,
+        **arch)
     last_off = layers.data("last_off", [1], dtype="int64",
                            append_batch_size=False)
-    block = default_main_program().global_block()
-    cache_names = []
-    caches = []
-    for i in range(num_layers):
-        ck = block.create_var(
-            name=f"{name}.pool_k_{i}", persistable=True,
-            shape=[num_pages, num_kv_heads, page_tokens, head_dim],
-            dtype="float32", stop_gradient=True)
-        cv = block.create_var(
-            name=f"{name}.pool_v_{i}", persistable=True,
-            shape=[num_pages, num_kv_heads, page_tokens, head_dim],
-            dtype="float32", stop_gradient=True)
-        caches.append((ck, cv))
-        cache_names += [ck.name, cv.name]
-    x = layers.embedding(chunk_ids, size=[vocab_size, hidden],
-                         param_attr=f"{name}.embed")
-    for i, (ck, cv) in enumerate(caches):
-        # rope offset = base per row; cached_attention's validity mask
-        # (j <= base + t) is exactly causal-over-prefix-plus-chunk
-        x = llama_block(x, hidden, num_heads, num_kv_heads, chunk_len,
-                        head_dim, intermediate, name=f"{name}.blk{i}",
-                        kv_cache=(ck, cv), positions=base,
-                        block_table=block_table, kv_lengths=ck_len)
-    x = layers.rms_norm(x, param_attr=f"{name}.ln_f")
-    all_logits = _linear(x, vocab_size, pname=f"{name}.head.w")
-    rows = layers.range(0, 1, 1, dtype="int64")              # [1]
-    coords = layers.stack([rows, last_off], axis=1)          # [1, 2]
-    logits = layers.gather_nd(all_logits, coords)            # [1, V]
+    logits = _head_on_rows(x, last_off, vocab_size, name,
+                           arch.get("rms_norm_eps", 1e-6))
     next_token = layers.argmax(logits, axis=-1)              # [1] int64
-    return ["chunk_ids", "base", "block_table", "chunk_len",
-            "last_off"], \
-        {"logits": logits, "next_token": next_token}, cache_names
+    fetches = {"logits": logits, "next_token": next_token}
+    fetches.update(_taps_fetches(taps))
+    return feeds + ["last_off"], fetches, cache_names
 
 
 def build_llama_verify(chunk_len, max_seq_len, num_pages, page_tokens,
                        vocab_size=32000, hidden=4096, num_layers=32,
                        num_heads=32, num_kv_heads=None,
-                       intermediate=11008, name="llama"):
+                       intermediate=11008, name="llama", **arch):
     """Speculative-decode verifier: the prefill-continuation forward
     (:func:`build_llama_prefill_chunk`) fetching EVERY row's greedy
     argmax + logits instead of one gathered row.
@@ -504,42 +687,12 @@ def build_llama_verify(chunk_len, max_seq_len, num_pages, page_tokens,
     the acceptance contract (see :func:`build_llama_prefill`).
 
     Returns ``(feed_names, fetches, cache_names)``."""
-    from ..framework.core import default_main_program
-
-    num_kv_heads = num_kv_heads or num_heads
-    head_dim = hidden // num_heads
-    np_slot = max_seq_len // page_tokens
-    chunk_ids = layers.data("chunk_ids", [1, chunk_len], dtype="int64",
-                            append_batch_size=False)
-    base = layers.data("base", [1], dtype="int32",
-                       append_batch_size=False)
-    block_table = layers.data("block_table", [1, np_slot],
-                              dtype="int32", append_batch_size=False)
-    ck_len = layers.data("chunk_len", [1], dtype="int32",
-                         append_batch_size=False)
-    block = default_main_program().global_block()
-    cache_names = []
-    caches = []
-    for i in range(num_layers):
-        ck = block.create_var(
-            name=f"{name}.pool_k_{i}", persistable=True,
-            shape=[num_pages, num_kv_heads, page_tokens, head_dim],
-            dtype="float32", stop_gradient=True)
-        cv = block.create_var(
-            name=f"{name}.pool_v_{i}", persistable=True,
-            shape=[num_pages, num_kv_heads, page_tokens, head_dim],
-            dtype="float32", stop_gradient=True)
-        caches.append((ck, cv))
-        cache_names += [ck.name, cv.name]
-    x = layers.embedding(chunk_ids, size=[vocab_size, hidden],
-                         param_attr=f"{name}.embed")
-    for i, (ck, cv) in enumerate(caches):
-        x = llama_block(x, hidden, num_heads, num_kv_heads, chunk_len,
-                        head_dim, intermediate, name=f"{name}.blk{i}",
-                        kv_cache=(ck, cv), positions=base,
-                        block_table=block_table, kv_lengths=ck_len)
-    x = layers.rms_norm(x, param_attr=f"{name}.ln_f")
+    feeds, x, cache_names, _taps = _chunk_forward(
+        chunk_len, max_seq_len, num_pages, page_tokens, vocab_size,
+        hidden, num_layers, num_heads, num_kv_heads, intermediate, name,
+        **arch)
+    x = layers.rms_norm(x, epsilon=arch.get("rms_norm_eps", 1e-6),
+                        param_attr=f"{name}.ln_f")
     all_logits = _linear(x, vocab_size, pname=f"{name}.head.w")
     tokens = layers.argmax(all_logits, axis=-1)              # [1, C]
-    return ["chunk_ids", "base", "block_table", "chunk_len"], \
-        {"logits": all_logits, "tokens": tokens}, cache_names
+    return feeds, {"logits": all_logits, "tokens": tokens}, cache_names

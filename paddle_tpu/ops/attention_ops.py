@@ -29,16 +29,28 @@ _LOWERED = {
     "paged_decode": _monitor.get("attention_lowered_paged_decode"),
     "paged_decode_reference":
         _monitor.get("attention_lowered_paged_decode_reference"),
+    # the same ops under a sliding window (``window`` attr set): booked
+    # beside the plain counters, which they also raise
+    "pallas_window": _monitor.get("attention_lowered_pallas_window"),
+    "xla_window": _monitor.get("attention_lowered_xla_window"),
+    "blockwise_window":
+        _monitor.get("attention_lowered_blockwise_window"),
+    "paged_decode_window":
+        _monitor.get("attention_lowered_paged_decode_window"),
+    "paged_decode_reference_window":
+        _monitor.get("attention_lowered_paged_decode_reference_window"),
 }
 _downgrades_logged = set()
 
 
-def _lowered(path, downgrade_reason=None):
+def _lowered(path, downgrade_reason=None, window=None):
     """Book the path taken.  On a TPU backend a reference formulation
     (blockwise, or the paged decode step's gather + einsum) is a
     downgrade from the Pallas kernels, not an equivalent: say so, once
     per reason."""
     _LOWERED[path].increase()
+    if window is not None:
+        _LOWERED[path + "_window"].increase()
     if downgrade_reason and downgrade_reason not in _downgrades_logged:
         _downgrades_logged.add(downgrade_reason)
         logger.warning("attention lowered to its reference formulation "
@@ -70,6 +82,11 @@ def _flash_attention(ctx, op):
     causal = op.attr("causal", False)
     sm_scale = op.attr("scale", None)
     mode = op.attr("seq_parallel_mode", "ring")
+    window = op.attr("window", None)
+    if window is not None and (not causal or bias is not None):
+        raise NotImplementedError(
+            "flash_attention: a sliding window needs causal=True and "
+            "no padding bias")
 
     if op.attr("impl", "auto") == "xla":
         if SP_AXIS in (getattr(ctx, "axis_names", ()) or ()):
@@ -95,8 +112,13 @@ def _flash_attention(ctx, op):
             s = s + bias[:, None, None, :].astype(s.dtype)
         if causal:
             S = s.shape[-1]
-            s = jnp.where(jnp.tril(jnp.ones((S, S), bool))[None, None],
-                          s, jnp.asarray(-1e30, s.dtype))
+            keep = jnp.tril(jnp.ones((S, S), bool))
+            if window is not None:
+                # i - window < j <= i: the window counts the token itself
+                keep = keep & ~jnp.tril(jnp.ones((S, S), bool),
+                                        -int(window))
+            s = jnp.where(keep[None, None], s,
+                          jnp.asarray(-1e30, s.dtype))
         p = jax.nn.softmax(s, axis=-1)
         prob = op.attr("dropout_prob", 0.0)
         if prob and not (ctx.is_test or op.attr("is_test", False)):
@@ -105,7 +127,7 @@ def _flash_attention(ctx, op):
         eo = ("bhqk,bkhd->bqhd" if layout == "bshd"
               else "bhqk,bhkd->bhqd")
         out = jnp.einsum(eo, p, v)
-        _lowered("xla")
+        _lowered("xla", window=window)
         ctx.set_output(op, "Out", out)
         return
 
@@ -113,27 +135,32 @@ def _flash_attention(ctx, op):
     on_tpu = jax.default_backend() == "tpu"
     n_mesh = ctx.mesh.devices.size if ctx.mesh is not None else 1
     if SP_AXIS in axes:
-        if bias is not None:
+        if bias is not None or window is not None:
             raise NotImplementedError(
-                "flash_attention: padding bias under sequence parallelism "
-                "not supported yet — pad-free bucketing or causal only")
+                "flash_attention: padding bias or a sliding window under "
+                "sequence parallelism not supported yet — pad-free "
+                "bucketing or causal only")
         fn = ring_attention if mode == "ring" else ulysses_attention
         out = fn(q, k, v, SP_AXIS, causal=causal, sm_scale=sm_scale)
         _lowered("ring")
     elif on_tpu and n_mesh == 1:
         if bias is not None:
             out = flash_attention_bias(q, k, v, bias, causal, sm_scale)
+        elif window is not None:
+            out = flash_attention(q, k, v, causal, sm_scale,
+                                  window=int(window))
         else:
             out = flash_attention(q, k, v, causal, sm_scale)
-        _lowered("pallas")
+        _lowered("pallas", window=window)
     else:
         # multi-device GSPMD: the einsum formulation lets the partitioner
         # shard batch/head/seq dims freely (pallas_call pins the layout)
         out, _ = blockwise_attention(q, k, v, causal=causal,
-                                     sm_scale=sm_scale, bias=bias)
+                                     sm_scale=sm_scale, bias=bias,
+                                     window=window)
         _lowered("blockwise",
                  f"flash_attention under a {n_mesh}-device mesh"
-                 if on_tpu else None)
+                 if on_tpu else None, window=window)
     ctx.set_output(op, "Out", out)
 
 

@@ -203,9 +203,10 @@ def _cached_attn_infer(op, block):
     set_out(op, block, "Out", q.shape, q.dtype)
 
 
-def _attend_cache(q, k, v, pos, scale=None):
+def _attend_cache(q, k, v, pos, scale=None, window=None):
     """Q [B, H, T, D] over dense caches K/V [B, Hkv, S, D] with the
-    validity rule ``j <= pos[b] + t``: the einsum formulation."""
+    validity rule ``j <= pos[b] + t`` (and, under a sliding ``window``,
+    ``j > pos[b] + t - window``): the einsum formulation."""
     import jax
     import jax.numpy as jnp
 
@@ -236,7 +237,10 @@ def _attend_cache(q, k, v, pos, scale=None):
     j = jnp.arange(S, dtype=jnp.int32)[None, None, None, :]
     t = jnp.arange(T, dtype=jnp.int32)[None, None, :, None]
     limit = pos[:, None, None, None] + t
-    s = jnp.where(j <= limit, s, jnp.asarray(-1e30, s.dtype))
+    keep = j <= limit
+    if window is not None:
+        keep = keep & (j > limit - int(window))
+    s = jnp.where(keep, s, jnp.asarray(-1e30, s.dtype))
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhqk,bhkd->bhqd", p, v).astype(q.dtype)
 
@@ -256,7 +260,8 @@ def _cached_attention(ctx, op):
     v = ctx.get_input(op, "V")
     pos = ctx.get_input(op, "Positions").astype(jnp.int32)
     ctx.set_output(op, "Out",
-                   _attend_cache(q, k, v, pos, op.attr("scale", None)))
+                   _attend_cache(q, k, v, pos, op.attr("scale", None),
+                                 op.attr("window", None)))
 
 
 @register_op("paged_decode_attention", infer=_cached_attn_infer, grad=None)
@@ -286,17 +291,23 @@ def _paged_decode_attention(ctx, op):
     bt = ctx.get_input(op, "BlockTable").astype(jnp.int32)
     pos = ctx.get_input(op, "Positions").astype(jnp.int32)
     scale = op.attr("scale", None)
+    # a sliding window: columns j > positions[b] - window only; the
+    # kernel starts at the window's first page, and block-table entries
+    # left of it may point at the trash page (masked in both lowerings)
+    window = op.attr("window", None)
 
     on_tpu = jax.default_backend() == "tpu"
     n_mesh = ctx.mesh.devices.size if ctx.mesh is not None else 1
     fits = paged_attention.supported(q.shape, pool_k.shape)
     if on_tpu and n_mesh == 1 and fits:
+        kw = {} if window is None else {"window": int(window)}
         out = paged_attention.paged_decode_attention(
-            q, pool_k, pool_v, bt, pos, scale=scale)
-        _lowered("paged_decode")
+            q, pool_k, pool_v, bt, pos, scale=scale, **kw)
+        _lowered("paged_decode", window=window)
     else:
         out = _attend_cache(q, _gather_pages(pool_k, bt),
-                            _gather_pages(pool_v, bt), pos, scale)
+                            _gather_pages(pool_v, bt), pos, scale,
+                            window)
         reason = None
         if on_tpu:
             reason = (f"paged_decode_attention under a {n_mesh}-device "
@@ -304,5 +315,5 @@ def _paged_decode_attention(ctx, op):
                       f"paged_decode_attention with Q {q.shape} over "
                       f"pages {pool_k.shape[1:]} (kernel needs one query "
                       f"token, head_dim % 128 == 0, page_tokens % 8 == 0)")
-        _lowered("paged_decode_reference", reason)
+        _lowered("paged_decode_reference", reason, window=window)
     ctx.set_output(op, "Out", out)

@@ -45,3 +45,47 @@ def _moe_ffn(ctx, op):
     ctx.set_output(op, "AuxLoss", aux)
     if op.output("ExpertCount"):
         ctx.set_output(op, "ExpertCount", counts)
+
+
+def _moe_routed_infer(op, block):
+    x = in_var(op, block, "X")
+    e = in_var(op, block, "RouterW").shape[1]
+    set_out(op, block, "Out", x.shape, x.dtype)
+    set_out(op, block, "ExpertCount", (e,), "int32")
+    if op.output("RouterLogits"):
+        set_out(op, block, "RouterLogits", tuple(x.shape[:-1]) + (e,),
+                "float32")
+
+
+@register_op("moe_routed_ffn", infer=_moe_routed_infer, grad=None)
+def _moe_routed_ffn(ctx, op):
+    """Dropless top-k mixture of gated experts (``parallel/moe.py``
+    ``moe_routed_tokens``): X [B, S, H] is the experts' input, RouterX
+    [B, S, H] what the router reads (an architecture may route from the
+    layer's raw input, before attention), Valid [B] int the number of
+    real rows of each batch row (optional: all).  Inference only."""
+    import jax.numpy as jnp
+
+    from ..parallel.moe import moe_routed_tokens
+    from .math_ops import _mm_precision
+
+    x = ctx.get_input(op, "X")
+    shape = x.shape
+    n_valid = ctx.get_input(op, "Valid") if op.single_input("Valid") \
+        else None
+    valid = None
+    if n_valid is not None:
+        t = jnp.arange(shape[1], dtype=jnp.int32)[None, :]
+        valid = (t < n_valid.astype(jnp.int32)[:, None]).reshape(-1)
+    out, counts, logits = moe_routed_tokens(
+        x.reshape(-1, shape[-1]),
+        ctx.get_input(op, "RouterX").reshape(-1, shape[-1]),
+        ctx.get_input(op, "RouterW"), ctx.get_input(op, "GateUpW"),
+        ctx.get_input(op, "DownW"), top_k=int(op.attr("top_k")),
+        activation=op.attr("activation", "relu"), valid=valid,
+        precision=_mm_precision(x.dtype))
+    ctx.set_output(op, "Out", out.reshape(shape))
+    ctx.set_output(op, "ExpertCount", counts)
+    if op.output("RouterLogits"):
+        ctx.set_output(op, "RouterLogits",
+                       logits.reshape(shape[:-1] + (logits.shape[-1],)))

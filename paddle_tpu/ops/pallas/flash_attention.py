@@ -68,13 +68,17 @@ def _block_loop(n_blocks, lower, upper, body, init):
 # ---------------------------------------------------------------------------
 
 def blockwise_attention(q, k, v, causal=False, sm_scale=None,
-                        block_k=DEFAULT_BLOCK_K, kv_offset=0, bias=None):
+                        block_k=DEFAULT_BLOCK_K, kv_offset=0, bias=None,
+                        window=None):
     """Online-softmax attention, scanning kv blocks.
 
     q: [B, H, Sq, D], k/v: [B, H, Sk, D]. kv_offset shifts the global kv
     position for causal masking (ring attention passes the rotating
     shard's offset). bias: optional [B, Sk] additive score bias
     (padding mask: 0 attend / -1e4 pad), broadcast over heads and q.
+    window: with ``causal``, query i attends keys j with
+    ``i - window < j <= i`` (a sliding window that counts the token
+    itself); None is plain causal.
     Returns (out, (m, l)): out [B,H,Sq,D], m/l the softmax running stats
     [B,H,Sq] (used by ring accumulation).
     """
@@ -110,6 +114,8 @@ def blockwise_attention(q, k, v, causal=False, sm_scale=None,
         if causal:
             k_pos = j * bk + jnp.arange(bk)[None, :] + kv_offset
             mask = q_pos >= k_pos
+            if window is not None:
+                mask = mask & (q_pos - k_pos < window)
             s = jnp.where(mask[None, None], s, NEG_INF)
         m_new = jnp.maximum(m, s.max(-1))
         # guards: a fully-masked block/row keeps m at NEG_INF — exp(0)=1
@@ -137,7 +143,7 @@ def blockwise_attention(q, k, v, causal=False, sm_scale=None,
 # ---------------------------------------------------------------------------
 
 def _fa_fwd_kernel(q_ref, k_ref, v_ref, *rest, block_k, causal, scale,
-                   seq_k, has_bias=False):
+                   seq_k, has_bias=False, window=None):
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -167,7 +173,10 @@ def _fa_fwd_kernel(q_ref, k_ref, v_ref, *rest, block_k, causal, scale,
                 jnp.int32, (bq, block_k), 0)
             k_pos = j * block_k + jax.lax.broadcasted_iota(
                 jnp.int32, (bq, block_k), 1)
-            s = jnp.where(q_pos >= k_pos, s, NEG_INF)
+            keep = q_pos >= k_pos
+            if window is not None:
+                keep = keep & (q_pos - k_pos < window)
+            s = jnp.where(keep, s, NEG_INF)
         m_new = jnp.maximum(m, s.max(-1, keepdims=True))
         p = jnp.exp(s - m_new)
         corr = jnp.exp(m - m_new)
@@ -179,19 +188,33 @@ def _fa_fwd_kernel(q_ref, k_ref, v_ref, *rest, block_k, causal, scale,
     m0 = jnp.full((bq, 1), NEG_INF, jnp.float32)
     l0 = jnp.zeros((bq, 1), jnp.float32)
     acc0 = jnp.zeros((bq, d), jnp.float32)
+    lower = 0
     if causal:
         # kv blocks past this q block's last row are fully masked
         upper = jnp.minimum(nk, ((qi + 1) * bq + block_k - 1) // block_k)
+        if window is not None:
+            # and so are those wholly left of the band of its first
+            # row.  A later row of the block may find the first block
+            # it visits fully masked: its m stays NEG_INF there, and the
+            # first block that holds one of its keys (its own diagonal
+            # at the latest) rescales that contribution by exp(-1e30)=0
+            lower = jnp.maximum(0, qi * bq - window + 1) // block_k
     else:
         upper = nk
-    m, l, acc = _block_loop(nk, 0, upper, body, (m0, l0, acc0))
+    m, l, acc = _block_loop(nk, lower, upper, body, (m0, l0, acc0))
     l_safe = jnp.maximum(l, 1e-30)
     o_ref[0] = (acc / l_safe).astype(o_ref.dtype)
     lse_ref[0, 0] = (m + jnp.log(l_safe))[:, 0]
 
 
+# K and V of one head sit whole in VMEM, double-buffered; past this many
+# bytes the kernel asks for a larger scoped limit than Mosaic's default
+# (16 MiB on a v5e, of 128 MiB): a 8192-key float32 head is 16 MiB alone
+_VMEM_ASK_OVER = 10 << 20
+
+
 def _flash_forward(q, k, v, causal, sm_scale, block_q, block_k, interpret,
-                   bias=None):
+                   bias=None, window=None):
     """Returns (out [B,H,Sq,D], lse [B,H,Sq] f32)."""
     import jax
     from jax.experimental import pallas as pl
@@ -206,9 +229,20 @@ def _flash_forward(q, k, v, causal, sm_scale, block_q, block_k, interpret,
     kr = k.reshape(B * H, Sk, D)
     vr = v.reshape(B * H, Sk, D)
 
+    if window is not None and not causal:
+        raise ValueError("flash attention: a sliding window needs "
+                         "causal=True")
+    kw = {} if window is None else {"window": int(window)}
     kernel = functools.partial(_fa_fwd_kernel, block_k=bk, causal=causal,
                                scale=scale, seq_k=Sk,
-                               has_bias=bias is not None)
+                               has_bias=bias is not None, **kw)
+    resident = 4 * Sk * D * k.dtype.itemsize      # K, V, two buffers each
+    call_kw = {}
+    if resident > _VMEM_ASK_OVER and not interpret:
+        from jax.experimental.pallas import tpu as pltpu
+
+        call_kw["compiler_params"] = pltpu.CompilerParams(
+            vmem_limit_bytes=min(resident + (24 << 20), 100 << 20))
     in_specs = [
         pl.BlockSpec((1, bq, D), lambda b, i: (b, i, 0)),
         pl.BlockSpec((1, Sk, D), lambda b, i: (b, 0, 0)),
@@ -233,7 +267,7 @@ def _flash_forward(q, k, v, causal, sm_scale, block_q, block_k, interpret,
                    pl.BlockSpec((1, 1, bq), lambda b, i: (b, 0, i))],
         out_shape=[jax.ShapeDtypeStruct((B * H, Sq, D), q.dtype),
                    jax.ShapeDtypeStruct((B * H, 1, Sq), np.float32)],
-        interpret=interpret,
+        interpret=interpret, **call_kw,
     )(*args)
     return out.reshape(B, H, Sq, D), lse.reshape(B, H, Sq)
 
@@ -452,22 +486,30 @@ def _flash_backward(q, k, v, out, lse, g, causal, sm_scale, block_q,
 # public entries: pallas forward + pallas backward via custom_vjp
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
 def flash_attention(q, k, v, causal=False, sm_scale=None,
                     block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K,
-                    interpret=False):
-    """Multi-head attention, q/k/v: [B, H, S, D] -> [B, H, Sq, D]."""
+                    interpret=False, window=None):
+    """Multi-head attention, q/k/v: [B, H, S, D] -> [B, H, Sq, D].
+    ``window`` (with ``causal``): query i attends keys j with
+    ``i - window < j <= i``; key blocks wholly left of the band are
+    skipped as those above the diagonal are.  Forward only."""
     return _flash_forward(q, k, v, causal, sm_scale, block_q, block_k,
-                          interpret)[0]
+                          interpret, window=window)[0]
 
 
-def _fa_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret):
+def _fa_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret,
+            window):
     out, lse = _flash_forward(q, k, v, causal, sm_scale, block_q, block_k,
-                              interpret)
+                              interpret, window=window)
     return out, (q, k, v, out, lse)
 
 
-def _fa_bwd(causal, sm_scale, block_q, block_k, interpret, res, g):
+def _fa_bwd(causal, sm_scale, block_q, block_k, interpret, window, res, g):
+    if window is not None:
+        raise NotImplementedError(
+            "flash attention: the sliding window has no backward kernel "
+            "(the serving path is forward only)")
     q, k, v, out, lse = res
     dq, dk, dv, _ = _flash_backward(q, k, v, out, lse, g, causal, sm_scale,
                                     block_q, block_k, interpret)

@@ -65,7 +65,7 @@ def supported(q_shape, pool_shape):
 
 
 def _kernel(bt_ref, pos_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem,
-            turn_ref, *, scale, pt, G, NP):
+            turn_ref, *, scale, pt, G, NP, window=None):
     b = pl.program_id(0)
     nb = pl.num_programs(0)
     T = G * pt
@@ -76,15 +76,30 @@ def _kernel(bt_ref, pos_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem,
     def granules(bb):
         return (live_pages(bb) + G - 1) // G
 
+    def first_page(bb):
+        """The page holding the window's first column ``pos - window +
+        1``; 0 without a window.  Pages left of it are neither fetched
+        nor contracted (their block-table entries may be the trash
+        page)."""
+        if window is None:
+            return 0
+        return jnp.maximum(pos_ref[bb] - window + 1, 0) // pt
+
+    def first_granule(bb):
+        return first_page(bb) // G
+
     def copies(bb, g, buf):
         """The granule's page copies, each under the condition it is
         live: started and waited for under the same rule."""
         n = live_pages(bb)
+        p0 = first_page(bb)
         for i in range(G):
             page = g * G + i
             phys = bt_ref[bb * NP + jnp.minimum(page, NP - 1)]
             dst = (buf, slice(None), pl.ds(i * pt, pt), slice(None))
-            yield page < n, (
+            live = page < n if window is None \
+                else jnp.logical_and(page < n, page >= p0)
+            yield live, (
                 pltpu.make_async_copy(k_hbm.at[phys], kbuf.at[dst],
                                       sem.at[buf, 0]),
                 pltpu.make_async_copy(v_hbm.at[phys], vbuf.at[dst],
@@ -106,7 +121,7 @@ def _kernel(bt_ref, pos_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem,
     @pl.when(b == 0)
     def _():
         turn_ref[0] = 0
-        start(0, 0, 0)
+        start(0, first_granule(0), 0)
 
     pos = pos_ref[b]
     n_g = granules(b)
@@ -121,7 +136,10 @@ def _kernel(bt_ref, pos_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem,
         # slot's first
         more = g + 1 < n_g
         nxt_b = jnp.where(more, b, b + 1)
-        nxt_g = jnp.where(more, g + 1, 0)
+        # (the clamp only keeps the scalar read in bounds on the last
+        # slot, where nothing is started)
+        nxt_g = jnp.where(more, g + 1,
+                          first_granule(jnp.minimum(b + 1, nb - 1)))
 
         @pl.when(nxt_b < nb)
         def _():
@@ -129,11 +147,22 @@ def _kernel(bt_ref, pos_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem,
 
         wait(b, g, buf)
 
-        @pl.when(jnp.logical_not(more))
-        def _():
+        if window is None:
+            @pl.when(jnp.logical_not(more))
+            def _():
+                row = g * T + jax.lax.broadcasted_iota(
+                    jnp.int32, (1, T, 1), 1)
+                v_ = vbuf[buf]
+                vbuf[buf] = jnp.where(row <= pos, v_, jnp.zeros_like(v_))
+        else:
+            # both ends of the live range can fall inside a granule:
+            # rows left of the window were never fetched (stale VMEM),
+            # rows right of the position are a page's unwritten tail
             row = g * T + jax.lax.broadcasted_iota(jnp.int32, (1, T, 1), 1)
             v_ = vbuf[buf]
-            vbuf[buf] = jnp.where(row <= pos, v_, jnp.zeros_like(v_))
+            vbuf[buf] = jnp.where(
+                jnp.logical_and(row <= pos, row > pos - window), v_,
+                jnp.zeros_like(v_))
 
         k = kbuf[buf]                                # [Hkv, T, D]
         v = vbuf[buf]
@@ -141,7 +170,10 @@ def _kernel(bt_ref, pos_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem,
                        preferred_element_type=jnp.float32,
                        precision=PRECISION) * scale
         col = g * T + jax.lax.broadcasted_iota(jnp.int32, (1, 1, T), 2)
-        s = jnp.where(col <= pos, s, NEG_INF)
+        keep = col <= pos
+        if window is not None:
+            keep = jnp.logical_and(keep, col > pos - window)
+        s = jnp.where(keep, s, NEG_INF)
         m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
         alpha = jnp.exp(m - m_new)
         p = jnp.exp(s - m_new)
@@ -154,21 +186,24 @@ def _kernel(bt_ref, pos_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem,
     init = (jnp.full((Hkv, R, 1), -jnp.inf, jnp.float32),
             jnp.zeros((Hkv, R, 1), jnp.float32),
             jnp.zeros((Hkv, R, D), jnp.float32))
-    _, l, acc = jax.lax.fori_loop(0, n_g, body, init)
+    _, l, acc = jax.lax.fori_loop(first_granule(b), n_g, body, init)
     o_ref[0] = (acc / l).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("scale", "interpret", "granule"))
+                   static_argnames=("scale", "interpret", "granule",
+                                    "window"))
 def paged_decode_attention(q, pool_k, pool_v, block_table, positions,
                            scale=None, interpret=False,
-                           granule=GRANULE_POSITIONS):
+                           granule=GRANULE_POSITIONS, window=None):
     """``q`` [B, H, 1, D] over pools ``[P, Hkv, pt, D]`` through
     ``block_table`` [B, NP] int32; ``positions`` [B] int32 is each slot's
     pre-step length, and the query attends columns ``j <= positions[b]``
     (the column this step wrote included).  ``granule`` is the number
     of positions fetched and contracted per loop turn, rounded to whole
-    pages.  Returns [B, H, 1, D]."""
+    pages.  ``window`` adds the lower bound ``j > positions[b] -
+    window``: the granule loop starts at the window's first page and
+    nothing left of it is fetched.  Returns [B, H, 1, D]."""
     B, H, _, D = q.shape
     P, Hkv, pt, _ = pool_k.shape
     NP = block_table.shape[1]
@@ -180,7 +215,9 @@ def paged_decode_attention(q, pool_k, pool_v, block_table, positions,
     qg = q.reshape(B, Hkv, rep, D)
     if R != rep:
         qg = jnp.pad(qg, ((0, 0), (0, 0), (0, R - rep), (0, 0)))
-    kernel = functools.partial(_kernel, scale=scale, pt=pt, G=G, NP=NP)
+    kw = {} if window is None else {"window": int(window)}
+    kernel = functools.partial(_kernel, scale=scale, pt=pt, G=G, NP=NP,
+                               **kw)
     blk = pl.BlockSpec((1, Hkv, R, D), lambda b, *_: (b, 0, 0, 0))
     out = pl.pallas_call(
         kernel,

@@ -129,3 +129,102 @@ def moe_rules(mesh, axis: str = EP_AXIS, inner=None):
         return inner_fn(name, shape)
 
     return ShardingRules(fn)
+
+
+# ---------------------------------------------------------------------------
+# dropless top-k routing with gated experts (the serving path's expert FFN)
+# ---------------------------------------------------------------------------
+
+def route_top_k(router_x, router_w, top_k: int):
+    """Router logits and the top-k choice per token.
+
+    router_x [N, H] (whatever the architecture routes from — it need not
+    be the experts' input), router_w [H, E].  Logits are float32 at
+    "highest" precision: routing is discrete, and a rounded logit flips
+    a choice.  Returns ``(logits [N, E], experts [N, k] int32, weights
+    [N, k])`` with the weights a softmax over the k selected logits,
+    which equals softmax over all E, select, renormalise."""
+    import jax
+    import jax.numpy as jnp
+
+    logits = jnp.dot(router_x.astype(jnp.float32),
+                     router_w.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    top, experts = jax.lax.top_k(logits, top_k)
+    return logits, experts.astype(jnp.int32), jax.nn.softmax(top, axis=-1)
+
+
+ROW_TILE = 64     # the row tile XLA:TPU's ragged-dot kernel picks here
+
+
+def grouped_matmul(rows, weights, group_sizes, precision=None):
+    """``rows`` [M, K], sorted by group, times ``weights`` [G, K, N]:
+    row ``r`` of group ``g`` is multiplied by ``weights[g]``.  One
+    formulation per shape, chosen on the chip (README "Routed experts"):
+    ``jax.lax.ragged_dot`` for both the decode step's few rows and a
+    prefill's many.  XLA:TPU lowers it to a grouped Mosaic kernel that
+    visits only the (row tile, group) pairs that hold rows, so the
+    weights of a group without rows are never read; the CPU lowering is
+    a masked dense product."""
+    import jax
+    import jax.numpy as jnp
+
+    # whole row tiles: on a TPU v5e the kernel gave garbage for 12 rows
+    # (two slots x top-6: the benchmark's check engine) and the right
+    # product for 18, 192 and 3072 (my chip run, PR 28).  Rows past
+    # ``group_sizes.sum()`` belong to no group and are cut off again
+    m = rows.shape[0]
+    pad = -m % ROW_TILE
+    if pad:
+        rows = jnp.pad(rows, ((0, pad), (0, 0)))
+    out = jax.lax.ragged_dot(rows, weights, group_sizes,
+                             precision=precision,
+                             preferred_element_type=rows.dtype)
+    return out[:m] if pad else out
+
+
+def moe_routed_tokens(x, router_x, router_w, w_gate_up, w_down, *,
+                      top_k: int, activation: str = "relu", valid=None,
+                      precision=None):
+    """Dropless top-k mixture of gated experts over flat tokens.
+
+    x [N, H] is the experts' input, router_x [N, H] what the router
+    reads; router_w [H, E]; w_gate_up [E, H, 2I] (gate columns first);
+    w_down [E, I, H]; ``valid`` [N] bool marks the rows that are real
+    tokens (pad-tail rows and idle slots still compute, but are not
+    counted).  Every token goes to its k experts: there is no capacity
+    and nothing is dropped.  Tokens are sorted by expert, the two
+    grouped matmuls read only experts that got rows, and the k results
+    per token are summed under the routing weights.
+
+    Returns ``(out [N, H], counts [E] int32, logits [N, E])``; ``counts``
+    are the group sizes the grouped matmul ran with, restricted to valid
+    rows, so ``counts.sum() == valid.sum() * k`` proves no token was
+    dropped."""
+    import jax.numpy as jnp
+
+    N, H = x.shape
+    E = router_w.shape[1]
+    inter = w_down.shape[1]
+    logits, experts, weights = route_top_k(router_x, router_w, top_k)
+    flat = experts.reshape(-1)                          # [N*k]
+    order = jnp.argsort(flat, stable=True)              # rows by expert
+    group_sizes = jnp.bincount(flat, length=E).astype(jnp.int32)
+    rows = jnp.take(x, order // top_k, axis=0)          # [N*k, H]
+    h = grouped_matmul(rows, w_gate_up.astype(x.dtype), group_sizes,
+                       precision)
+    gate, up = h[:, :inter], h[:, inter:]
+    if activation != "relu":
+        raise ValueError(f"unknown expert activation {activation!r}")
+    y = grouped_matmul(jnp.maximum(gate, 0) * up, w_down.astype(x.dtype), group_sizes,
+                       precision)                       # [N*k, H]
+    y = y * jnp.take(weights.reshape(-1), order)[:, None].astype(y.dtype)
+    # back to token order: row r of the sorted list is pair order[r]
+    y = jnp.zeros_like(y).at[order].set(y)
+    out = y.reshape(N, top_k, H).sum(axis=1)
+    if valid is None:
+        counts = group_sizes
+    else:
+        pair_valid = jnp.repeat(valid.astype(jnp.int32), top_k)
+        counts = jnp.zeros((E,), jnp.int32).at[flat].add(pair_valid)
+    return out.astype(x.dtype), counts, logits

@@ -116,14 +116,18 @@ each fails only the then-active requests),
 ``serving_prefill_chunks``, ``serving_kv_page_evictions``,
 ``serving_kv_pool_stalls``, ``serving_spec_drafts``,
 ``serving_spec_tokens_proposed``, ``serving_spec_tokens_accepted``,
-``serving_spec_rollbacks``; gauges
+``serving_spec_rollbacks``,
+``serving_kv_window_pages_released``, ``moe_tokens_routed``,
+``moe_tokens_dropped`` (must read 0); gauges
 ``serving_spec_acceptance_rate``,
 ``serving_slot_occupancy``,
 ``serving_kv_cache_bytes`` (allocated cache capacity — the page pool
 in paged mode, the dense reservation otherwise),
 ``serving_kv_live_bytes`` (bytes of pages actually referenced by live
 sequences or the prefix index), ``serving_kv_pages_free``,
-``serving_kv_pages_live``; histograms
+``serving_kv_pages_live`` (with sliding-window layers also
+``serving_kv_pages_live_full`` / ``serving_kv_pages_live_window``),
+``moe_experts_touched``, ``moe_expert_load_max_over_mean``; histograms
 ``serving_generate_ms``, ``serving_prefill_ms``,
 ``serving_decode_step_ms``, ``serving_spec_verify_ms``,
 ``serving_ttft_ms``, ``serving_inter_token_ms``.
@@ -351,7 +355,8 @@ class _Slot:
     """Per-slot decode state: cache offset, step count, deadline."""
 
     __slots__ = ("idx", "req", "position", "steps", "tokens", "t_start",
-                 "logits", "pages", "prefill_pos", "hit_tokens",
+                 "logits", "pages", "wpages", "router_logits",
+                 "prefill_pos", "hit_tokens",
                  "decoding", "span", "page_us", "page_t", "page_tenant")
 
     def __init__(self, idx: int):
@@ -364,6 +369,10 @@ class _Slot:
         self.t_start = 0.0
         self.logits: List[np.ndarray] = []  # keep_logits only
         self.pages: List[int] = []   # paged: block table, logical order
+        # paged, sliding-window layers: their block table, logical
+        # order too; a page the window slid past is 0 (the trash page)
+        self.wpages: List[int] = []
+        self.router_logits: List[np.ndarray] = []  # keep_logits, experts
         self.prefill_pos = 0         # paged: next position to prefill
         self.hit_tokens = 0          # paged: tokens served by the index
         self.decoding = False        # prefill complete, in the grid
@@ -405,10 +414,10 @@ class GenerationEngine:
                  mesh=None, shard_rules=None, paged=None,
                  page_tokens=None, num_pages=None, prefill_chunk=None,
                  prefix_reuse=None, role=None, speculate=None,
-                 spec_tokens=None, spec_ngram=None):
+                 spec_tokens=None, spec_ngram=None, num_window_pages=None):
         import paddle_tpu as pt
         from ..compile_cache import ensure_compile_cache
-        from ..models.llama import build_llama_decode, build_llama_prefill
+        from ..models.llama import build_llama_prefill, layer_spec
 
         ensure_compile_cache()
         self.model = dict(model)
@@ -418,7 +427,10 @@ class GenerationEngine:
         # keep_logits: fetch and retain every step's next-token logits
         # on the result record — the bit-exactness tests compare them
         # against the uncached full forward; costs one extra [slots, V]
-        # fetch per step, so serve-path default is off
+        # fetch per step, so serve-path default is off.  A model with
+        # routed experts also gets ``router_logits`` on the record, one
+        # [L_moe, E] array per generated token: routing is discrete, and
+        # a reference check needs them to tell a near tie from a fault
         self.keep_logits = bool(keep_logits)
         self.eos_id = int(eos_id)
         self.num_slots = int(num_slots if num_slots is not None
@@ -447,7 +459,22 @@ class GenerationEngine:
 
         heads = self.model["num_heads"]
         self._n_kv = self.model.get("num_kv_heads") or heads
-        self._head_dim = self.model["hidden"] // heads
+        self._head_dim = (self.model.get("head_dim")
+                          or self.model["hidden"] // heads)
+        # the per-layer pattern (models/llama.py DEFAULT_LAYER): which
+        # layers attend a sliding window, which route to experts
+        n_layers = self.model["num_layers"]
+        specs = [layer_spec(self.model.get("layer_pattern"), i)
+                 for i in range(n_layers)]
+        self._window_layers = [i for i, sp in enumerate(specs)
+                               if sp["window"] is not None]
+        widths = {specs[i]["window"] for i in self._window_layers}
+        if len(widths) > 1:
+            raise ValueError(f"sliding-window layers of one model share "
+                             f"one window, got {sorted(widths)}")
+        self.window = widths.pop() if widths else None
+        routed = [sp["ffn"] for sp in specs if sp["ffn"] != "dense"]
+        self._moe_top_k = routed[0]["top_k"] if routed else 0
         self._build_fn_prefill = build_llama_prefill
         self._seed = seed
 
@@ -461,6 +488,11 @@ class GenerationEngine:
         self.prefix_reuse = False
         self._pool: Optional[PagePool] = None
         self._prefix: Optional[PrefixIndex] = None
+        # sliding-window layers keep a second pool: a slot needs at most
+        # window / page_tokens + 1 of its pages however long it grows
+        self.num_window_pages = 0
+        self.window_pages_per_slot = 0
+        self._wpool: Optional[PagePool] = None
         if self.paged:
             pt_ = int(page_tokens if page_tokens is not None
                       else flag_value("FLAGS_serving_kv_page_tokens"))
@@ -489,6 +521,17 @@ class GenerationEngine:
             self._pool = PagePool(self.num_pages)
             if self.prefix_reuse:
                 self._prefix = PrefixIndex(self._pool, pt_)
+            if self._window_layers:
+                if self.window % pt_:
+                    raise ValueError(
+                        f"sliding window {self.window} is not a multiple "
+                        f"of page_tokens {pt_}")
+                self.window_pages_per_slot = min(
+                    self.pages_per_slot, self.window // pt_ + 1)
+                self.num_window_pages = int(
+                    num_window_pages if num_window_pages is not None
+                    else self.num_slots * self.window_pages_per_slot + 1)
+                self._wpool = PagePool(self.num_window_pages)
         # disaggregated serving role: "both" (colocated, the default)
         # runs prefill AND the decode grid; "prefill" exports each
         # prompt's populated pages as a KVSegment instead of decoding;
@@ -527,6 +570,26 @@ class GenerationEngine:
             if self.spec_ngram < 1:
                 raise ValueError(f"spec_ngram must be >= 1, got "
                                  f"{self.spec_ngram}")
+        if self._wpool is None:
+            # one pool: a dense cache takes the window as a mask, and
+            # nothing below treats any layer apart
+            self._window_layers = []
+        else:
+            # two page kinds: what walks ONE block table per slot is no
+            # part of this engine yet (PERF.md section 7)
+            refused = [what for what, on in (
+                ("prefix_reuse", self.prefix_reuse),
+                ("speculate", self.speculate),
+                ("prefill_chunk > 0", self.prefill_chunk > 0),
+                (f"role={self.role!r} (the disagg segment codec)",
+                 self.role != "both")) if on]
+            if refused:
+                raise ValueError(
+                    f"a paged model with sliding-window layers keeps two "
+                    f"page pools (full and window) and does not support "
+                    f"{', '.join(refused)}: prefix reuse, chunked "
+                    f"prefill, speculation and KV-segment handoff walk "
+                    f"one block table per slot")
         self._fingerprint: Optional[str] = None
         self._paged_prefill_progs: Dict[int, tuple] = {}
         self._chunk_progs: Dict[int, tuple] = {}
@@ -578,7 +641,9 @@ class GenerationEngine:
                    "segments_exported": 0, "segments_adopted": 0,
                    "adopt_rejects": 0, "spec_drafts": 0,
                    "spec_tokens_proposed": 0,
-                   "spec_tokens_accepted": 0, "spec_rollbacks": 0}
+                   "spec_tokens_accepted": 0, "spec_rollbacks": 0,
+                   "window_pages_released": 0, "moe_tokens_routed": 0,
+                   "moe_tokens_dropped": 0}
         self._n_lock = threading.Lock()
         # per-bucket manifest-flops cache for usage attribution: the
         # executor cache walk is paid once per bucket, not per dispatch
@@ -605,6 +670,7 @@ class GenerationEngine:
         # seconds this scheduler iteration blocked on the device
         # (scheduler thread only: _end_device_wait)
         self._iter_wait_s = 0.0
+        self._released_in_feeds = 0  # window pages the last feeds freed
 
         if autostart:
             self.start()
@@ -621,7 +687,9 @@ class GenerationEngine:
             feeds, fetches, cache_names = build_llama_decode(
                 self.num_slots, self.max_seq_len, name=self.name,
                 paged=self.paged, num_pages=self.num_pages or None,
-                page_tokens=self.page_tokens or None, **self.model)
+                page_tokens=self.page_tokens or None,
+                num_window_pages=self.num_window_pages or None,
+                keep_router_logits=self.keep_logits, **self.model)
         self._decode_prog = main
         self._decode_feeds = feeds
         self._decode_fetches = fetches
@@ -668,28 +736,35 @@ class GenerationEngine:
         else:
             shape = (self.num_slots, self._n_kv, self.max_seq_len,
                      self._head_dim)
+        # a window layer's pools are the window kind's size (cache_names
+        # holds K then V, layer by layer)
+        wshape = (self.num_window_pages,) + shape[1:]
         cache_sh = None
         self.kv_shard_axis = None
         if self.mesh is not None:
             cache_sh, self.kv_shard_axis = self._cache_sharding()
         total = 0
-        for n in self.cache_names:
+        for j, n in enumerate(self.cache_names):
             # one DISTINCT zero buffer per cache: the decode step and
             # the prefill insert donate all caches in one call, and XLA
             # rejects donating the same buffer twice (device_put also
             # allocates a fresh buffer per call)
-            zeros = jnp.zeros(shape, jnp.float32)
+            shp = wshape if j // 2 in self._window_layers else shape
+            zeros = jnp.zeros(shp, jnp.float32)
             self.scope.set_var(
                 n, jax.device_put(zeros, cache_sh)
                 if cache_sh is not None else zeros.copy())
-            total += int(np.prod(shape)) * 4
+            total += int(np.prod(shp)) * 4
         # capacity actually ALLOCATED (pool in paged mode, dense
         # reservation otherwise) — not the dense worst case
         self.kv_cache_bytes = total
-        # bytes one page costs across every layer's K+V pool
-        self.page_bytes = (len(self.cache_names) * self._n_kv
-                           * self.page_tokens * self._head_dim * 4) \
-            if self.paged else 0
+        # bytes one page costs across every layer's K+V pool (of its
+        # kind: a window page spans the window layers only)
+        layer_page = 2 * self._n_kv * self.page_tokens * self._head_dim * 4
+        n_window = len(self._window_layers)
+        self.page_bytes = (len(self.cache_names) // 2 - n_window) \
+            * layer_page if self.paged else 0
+        self.window_page_bytes = n_window * layer_page
         telemetry.gauge_set("serving_kv_cache_bytes", total)
         self._publish_pool_gauges()
 
@@ -700,8 +775,12 @@ class GenerationEngine:
                             self._pool.free_pages)
         telemetry.gauge_set("serving_kv_pages_live",
                             self._pool.live_pages)
-        telemetry.gauge_set("serving_kv_live_bytes",
-                            self._pool.live_pages * self.page_bytes)
+        telemetry.gauge_set("serving_kv_live_bytes", self.kv_live_bytes)
+        if self._wpool is not None:
+            telemetry.gauge_set("serving_kv_pages_live_full",
+                                self._pool.live_pages)
+            telemetry.gauge_set("serving_kv_pages_live_window",
+                                self._wpool.live_pages)
 
     @property
     def kv_live_bytes(self) -> int:
@@ -710,7 +789,10 @@ class GenerationEngine:
         cache, whose reservation is always fully held)."""
         if self._pool is None:
             return self.kv_cache_bytes
-        return self._pool.live_pages * self.page_bytes
+        live = self._pool.live_pages * self.page_bytes
+        if self._wpool is not None:
+            live += self._wpool.live_pages * self.window_page_bytes
+        return live
 
     def _prefill_prog_for(self, bucket: int):
         import paddle_tpu as pt
@@ -724,9 +806,31 @@ class GenerationEngine:
                 _feeds, fetches = self._build_fn_prefill(
                     1, bucket, name=self.name, attn_impl=self.attn_impl,
                     cache_slots=self.num_slots,
-                    max_seq_len=self.max_seq_len, **self.model)
+                    max_seq_len=self.max_seq_len,
+                    keep_router_logits=self.keep_logits, **self.model)
             entry = self._prefill_progs[bucket] = (main, fetches)
         return entry
+
+    def _fetch_names(self, fetches) -> List[str]:
+        """The fetches every run of a program takes, warm-up included
+        (another list would be another compilation): the greedy token,
+        what the expert layers counted, and with ``keep_logits`` the
+        logits and the router's."""
+        names = ["next_token"]
+        if "expert_counts" in fetches:
+            names.append("expert_counts")
+        if self.keep_logits:
+            names.append("logits")
+            if "router_logits" in fetches:
+                names.append("router_logits")
+        return names
+
+    def _run_fetching(self, exe, prog, fetches, feed) -> dict:
+        names = self._fetch_names(fetches)
+        outs = exe.run(prog, feed=feed,
+                       fetch_list=[fetches[n] for n in names],
+                       scope=self.scope, return_numpy=False)
+        return dict(zip(names, outs))
 
     def _paged_prefill_prog_for(self, bucket: int):
         """Whole-prompt paged prefill: the dense prefill forward with
@@ -745,7 +849,9 @@ class GenerationEngine:
                     cache_slots=self.num_slots,
                     max_seq_len=self.max_seq_len, paged=True,
                     num_pages=self.num_pages,
-                    page_tokens=self.page_tokens, **self.model)
+                    page_tokens=self.page_tokens,
+                    num_window_pages=self.num_window_pages or None,
+                    keep_router_logits=self.keep_logits, **self.model)
             entry = self._paged_prefill_progs[bucket] = (main, fetches)
         return entry
 
@@ -764,7 +870,8 @@ class GenerationEngine:
             with pt.program_guard(main, startup):
                 _feeds, fetches, _names = build_llama_prefill_chunk(
                     bucket, self.max_seq_len, self.num_pages,
-                    self.page_tokens, name=self.name, **self.model)
+                    self.page_tokens, name=self.name,
+                    **self.model)
             entry = self._chunk_progs[bucket] = (main, fetches)
         return entry
 
@@ -854,15 +961,15 @@ class GenerationEngine:
             for b in self.prefill_buckets:
                 if b not in self._paged_prefill_progs:
                     prog, fetches = self._paged_prefill_prog_for(b)
-                    self._prefill_exe.run(
-                        prog,
-                        feed={"input_ids": np.zeros((1, b), "int64"),
-                              "last_pos": np.zeros((1,), "int64"),
-                              "block_table": np.zeros((1, np_slot),
-                                                      "int32"),
-                              "prompt_len": np.zeros((1,), "int32")},
-                        fetch_list=[fetches["next_token"]],
-                        scope=self.scope, return_numpy=False)
+                    feed = {"input_ids": np.zeros((1, b), "int64"),
+                            "last_pos": np.zeros((1,), "int64"),
+                            "block_table": np.zeros((1, np_slot), "int32"),
+                            "prompt_len": np.zeros((1,), "int32")}
+                    if self._wpool is not None:
+                        feed["block_table_window"] = np.zeros(
+                            (1, np_slot), "int32")
+                    self._run_fetching(self._prefill_exe, prog, fetches,
+                                       feed)
                     compiled += 1
         if self.prefill_chunk > 0 or self.prefix_reuse:
             for b in self._chunk_buckets():
@@ -1375,6 +1482,7 @@ class GenerationEngine:
             slot.tokens = []
             slot.t_start = now
             slot.pages = []
+            slot.wpages = []
             slot.prefill_pos = 0
             slot.hit_tokens = 0
             slot.decoding = False
@@ -1490,14 +1598,19 @@ class GenerationEngine:
         if claimed:
             with telemetry.trace_span("generation/publish"):
                 self._sample_slot_track()
-        # chunked prefill: advance ONE pending slice per iteration
-        # (round-robin over prefilling slots), so a long prompt
+        # advance ONE pending slice per iteration, so a long prompt
         # pays out between decode steps instead of stalling the
-        # grid — the dense path never leaves slots prefilling
+        # grid — the dense path never leaves slots prefilling.
+        # Chunked: round-robin over the prefilling slots.  Unchunked a
+        # slice is a whole prompt, so slots claimed together prefill
+        # first come, first served
         pending = self._prefilling_slots()
         if pending:
-            slot = pending[self._prefill_rr % len(pending)]
-            self._prefill_rr += 1
+            if self.prefill_chunk > 0:
+                slot = pending[self._prefill_rr % len(pending)]
+                self._prefill_rr += 1
+            else:
+                slot = min(pending, key=lambda s: s.req.t_submit)
             try:
                 self._prefill_advance(slot)
             except PoolExhausted as e:
@@ -1792,17 +1905,11 @@ class GenerationEngine:
         the same HBM-in-place contract as the decode step)."""
         prog, fetches = self._prefill_prog_for(bucket)
         padded = batcher.pad_prompt(ids, bucket)
-        fetch = [fetches["next_token"]]
-        if self.keep_logits:
-            fetch.append(fetches["logits"])
-        outs = self._prefill_exe.run(
-            prog,
-            feed={"input_ids": padded[None],
-                  "last_pos": np.asarray([ids.size - 1], "int64"),
-                  "slot": np.asarray([slot], "int32")},
-            fetch_list=fetch,
-            scope=self.scope, return_numpy=False)
-        return outs
+        return self._run_fetching(
+            self._prefill_exe, prog, fetches,
+            {"input_ids": padded[None],
+             "last_pos": np.asarray([ids.size - 1], "int64"),
+             "slot": np.asarray([slot], "int32")})
 
     def _poison_check(self, prompt: np.ndarray):
         """The generation half of the poison-input model: a prompt
@@ -1869,7 +1976,8 @@ class GenerationEngine:
                                   bucket=bucket, slot=slot.idx):
             outs = self._run_prefill_program(req.prompt, bucket,
                                              slot.idx)
-            first = self._fetch_first_token(slot, outs, parent)
+            first = self._fetch_first_token(slot, outs, parent,
+                                            int(req.prompt.size))
         now = time.monotonic()
         ms = (now - t0) * 1e3
         req.prefill_ms = ms
@@ -1910,9 +2018,12 @@ class GenerationEngine:
         page-seconds to its tenant — this is the single exit every
         hold path (finish, fail, requeue, export, decode crash)
         funnels through."""
-        if self._pool is not None and slot.pages:
+        if self._pool is not None and (slot.pages or slot.wpages):
             self._mark_pages(slot)
             self._pool.decref(slot.pages)
+            if slot.wpages:
+                self._wpool.decref([p for p in slot.wpages if p])
+                slot.wpages = []
             self._publish_pool_gauges()
         if slot.page_tenant is not None:
             if slot.page_us:
@@ -1946,11 +2057,46 @@ class GenerationEngine:
                     f"/{self.num_pages - 1} pages live, nothing "
                     f"evictable)")
             slot.pages.append(p)
+        if self._wpool is not None:
+            self._slide_window_pages(slot, int(n_tokens), needed)
         self._publish_pool_gauges()
 
-    def _slot_block_table(self, slot: _Slot) -> np.ndarray:
+    def _slide_window_pages(self, slot: _Slot, n_tokens: int,
+                            needed: int):
+        """The window kind's half of :meth:`_ensure_pages`: the last of
+        ``n_tokens`` positions attends ``j >= n_tokens - window``, so
+        logical pages left of that column's page are released (their
+        entries become the trash page 0) and pages up to ``needed`` are
+        mapped from the window pool.  A slot so holds at most ``window
+        / page_tokens + 1`` window pages, and a single-shot prefill
+        maps only the last window of a long prompt: rows of earlier
+        pages follow their zero entries to the trash page."""
+        first = max(0, n_tokens - self.window) // self.page_tokens
+        wp = slot.wpages
+        gone = [p for p in wp[:first] if p]
+        if gone:
+            self._wpool.decref(gone)
+            wp[:first] = [0] * min(first, len(wp))
+            self._released_in_feeds += len(gone)
+            self._count("window_pages_released", len(gone))
+            stat_add("serving_kv_window_pages_released", len(gone))
+        while len(wp) < needed:
+            if len(wp) < first:
+                wp.append(0)
+                continue
+            p = self._wpool.alloc()
+            if p is None:
+                raise PoolExhausted(
+                    f"kv window page pool exhausted ("
+                    f"{self._wpool.live_pages}/"
+                    f"{self.num_window_pages - 1} pages live)")
+            wp.append(p)
+
+    def _slot_block_table(self, slot: _Slot,
+                          window: bool = False) -> np.ndarray:
+        pages = slot.wpages if window else slot.pages
         bt = np.zeros((self.pages_per_slot,), "int32")
-        bt[:len(slot.pages)] = slot.pages
+        bt[:len(pages)] = pages
         return bt
 
     def _acquire_draft_pages(self, slot: _Slot, n_tokens: int) -> int:
@@ -2007,25 +2153,24 @@ class GenerationEngine:
                                       bucket=bucket):
                 self._ensure_pages(slot, n_prompt)
                 prog, fetches = self._paged_prefill_prog_for(bucket)
-                fetch = [fetches["next_token"]]
-                if self.keep_logits:
-                    fetch.append(fetches["logits"])
                 feed = {"input_ids":
                         batcher.pad_prompt(prompt, bucket)[None],
                         "last_pos": np.asarray([n_prompt - 1], "int64"),
                         "block_table": self._slot_block_table(slot)[None],
                         "prompt_len": np.asarray([n_prompt], "int32")}
+                if self._wpool is not None:
+                    feed["block_table_window"] = \
+                        self._slot_block_table(slot, window=True)[None]
             with telemetry.trace_span("generation/prefill", parent=parent,
                                       tokens=n_prompt, bucket=bucket,
                                       slot=slot.idx, paged=True):
-                outs = self._prefill_exe.run(
-                    prog, feed=feed, fetch_list=fetch, scope=self.scope,
-                    return_numpy=False)
+                outs = self._run_fetching(self._prefill_exe, prog,
+                                          fetches, feed)
             req.prefill_ms += (time.monotonic() - t0) * 1e3
             if req.tenant is not None:
                 usage.ledger().book(req.tenant,
                                     flops=self._exe_flops(bucket))
-            self._complete_prefill(slot, req, outs)
+            self._complete_prefill(slot, req, outs, n_prompt)
             return
         # chunk continuation (chunked prefill and/or prefix-hit tail):
         # this iteration runs the FIRST remaining span; later spans
@@ -2039,9 +2184,6 @@ class GenerationEngine:
                                   bucket=bucket):
             self._ensure_pages(slot, start + n)
             prog, fetches = self._chunk_prog_for(bucket)
-            fetch = [fetches["next_token"]]
-            if self.keep_logits:
-                fetch.append(fetches["logits"])
             chunk = np.zeros((bucket,), "int64")
             chunk[:n] = prompt[start:start + n]
             feed = {"chunk_ids": chunk[None],
@@ -2053,9 +2195,8 @@ class GenerationEngine:
         with telemetry.trace_span("generation/prefill_chunk",
                                   parent=parent, tokens=n, base=start,
                                   bucket=bucket, slot=slot.idx):
-            outs = self._prefill_exe.run(
-                prog, feed=feed, fetch_list=fetch, scope=self.scope,
-                return_numpy=False)
+            outs = self._run_fetching(self._prefill_exe, prog, fetches,
+                                      feed)
         self._count("prefill_chunks")
         stat_add("serving_prefill_chunks")
         if req.tenant is not None:
@@ -2066,28 +2207,64 @@ class GenerationEngine:
         req.note("chunk", now, {"base": start, "tokens": n})
         slot.prefill_pos = start + n
         if last:
-            self._complete_prefill(slot, req, outs)
+            self._complete_prefill(slot, req, outs, n)
 
-    def _fetch_first_token(self, slot: _Slot, outs, parent) -> int:
-        """Block on a prefill's outputs: the first generated token (and
-        the logits row when kept), under ``generation/prefill_fetch``."""
+    def _fetch_first_token(self, slot: _Slot, outs, parent,
+                           n_tokens: int) -> int:
+        """Block on a prefill's outputs: the first generated token (the
+        logits row when kept, and the expert layers' counts over the
+        program's ``n_tokens`` real rows), under
+        ``generation/prefill_fetch``."""
         span = telemetry.span_begin("generation/prefill_fetch",
                                     parent=parent, slot=slot.idx)
         try:
-            first = int(np.asarray(outs[0].numpy())[0])
-            slot.logits = [np.asarray(outs[1].numpy())[0]] \
+            first = int(np.asarray(outs["next_token"].numpy())[0])
+            slot.logits = [np.asarray(outs["logits"].numpy())[0]] \
                 if self.keep_logits else []
+            slot.router_logits = \
+                [np.asarray(outs["router_logits"].numpy())[0]] \
+                if "router_logits" in outs else []
+            if "expert_counts" in outs:
+                self._book_experts(
+                    np.asarray(outs["expert_counts"].numpy()), n_tokens)
         finally:
             self._end_device_wait(span)
         return first
 
-    def _complete_prefill(self, slot: _Slot, req: GenRequest, outs):
+    def _book_experts(self, counts: np.ndarray, n_tokens: int) -> dict:
+        """Book what a program's expert layers counted: ``counts``
+        [L_moe, E] tokens per expert over the ``n_tokens`` valid rows.
+        Routing is dropless, so every layer must have placed ``n_tokens
+        * top_k`` pairs; the shortfall is ``moe_tokens_dropped`` and
+        must read 0.  Returns the load figures of the step."""
+        routed = int(counts.sum())
+        dropped = counts.shape[0] * n_tokens * self._moe_top_k - routed
+        self._count("moe_tokens_routed", routed)
+        stat_add("moe_tokens_routed", routed)
+        if dropped:
+            self._count("moe_tokens_dropped", dropped)
+            stat_add("moe_tokens_dropped", dropped)
+            logger.error("expert routing lost %d token-expert pairs",
+                         dropped)
+        touched = float((counts > 0).sum(axis=1).mean())
+        mean = counts.mean(axis=1)
+        load = float((counts.max(axis=1) / np.maximum(mean, 1e-9)).mean())
+        if telemetry.enabled():
+            telemetry.gauge_set("moe_experts_touched", touched)
+            telemetry.gauge_set("moe_expert_load_max_over_mean", load)
+        return {"experts_touched": round(touched, 3),
+                "expert_load_max_over_mean": round(load, 4)}
+
+    def _complete_prefill(self, slot: _Slot, req: GenRequest, outs,
+                          n_rows: int):
         """Shared tail of every paged prefill path: book the first
         generated token, publish the prompt's fully-covered pages to
-        the prefix index, and enter the decode grid."""
+        the prefix index, and enter the decode grid.  ``n_rows``: real
+        rows of the program that produced ``outs`` (the whole prompt, or
+        its last chunk: only that one's expert counts are fetched)."""
         first = self._fetch_first_token(
             slot, outs, slot.span.context() if slot.span is not None
-            else None)
+            else None, n_rows)
         n_prompt = int(req.prompt.size)
         self._t_prefill_total += req.prefill_ms
         self._h_prefill.observe(req.prefill_ms, trace_id=req.trace_id)
@@ -2212,31 +2389,38 @@ class GenerationEngine:
     def _run_decode_program(self, tokens: np.ndarray,
                             positions: np.ndarray,
                             block_tables: Optional[np.ndarray] = None,
-                            live: Optional[np.ndarray] = None):
+                            live: Optional[np.ndarray] = None,
+                            block_tables_window:
+                            Optional[np.ndarray] = None) -> dict:
+        """One grid step.  Returns its fetches as arrays, by name:
+        ``next_token`` and, where the program has them, ``logits``,
+        ``expert_counts``, ``router_logits`` (the counts ride the token
+        fetch: one wait for the one program)."""
         feed = {"tokens": tokens, "positions": positions}
         if self.paged:
+            empty = (self.num_slots, self.pages_per_slot)
             if block_tables is None:
-                block_tables = np.zeros(
-                    (self.num_slots, self.pages_per_slot), "int32")
+                block_tables = np.zeros(empty, "int32")
             if live is None:
                 live = np.zeros((self.num_slots,), "int32")
             feed["block_tables"] = block_tables
             feed["live"] = live
-        fetch = [self._decode_fetches["next_token"]]
-        if self.keep_logits:
-            fetch.append(self._decode_fetches["logits"])
+            if self._wpool is not None:
+                feed["block_tables_window"] = block_tables_window \
+                    if block_tables_window is not None \
+                    else np.zeros(empty, "int32")
+        elif "live" in self._decode_feeds:
+            # dense caches under routed experts: the counts' live mask
+            feed["live"] = live if live is not None \
+                else np.zeros((self.num_slots,), "int32")
         with telemetry.trace_span("generation/decode_dispatch"):
-            outs = self._decode_exe.run(
-                self._decode_prog, feed=feed, fetch_list=fetch,
-                scope=self.scope, return_numpy=False)
+            outs = self._run_fetching(self._decode_exe, self._decode_prog,
+                                      self._decode_fetches, feed)
         span = telemetry.span_begin("generation/token_fetch")
         try:
-            next_tokens = np.asarray(outs[0].numpy())
-            logits = np.asarray(outs[1].numpy()) \
-                if self.keep_logits else None
+            return {n: np.asarray(o.numpy()) for n, o in outs.items()}
         finally:
             self._end_device_wait(span)
-        return next_tokens, logits
 
     def _speculate_round(self) -> frozenset:
         """One speculative draft/verify per eligible decoding slot.
@@ -2372,14 +2556,34 @@ class GenerationEngine:
             telemetry.span_end(span)
         if not active:
             return
-        with telemetry.trace_span("generation/decode_step",
-                                  links=links, active=len(active)):
-            next_tokens, logits = self._run_decode_program(*feeds)
+        step = telemetry.span_begin("generation/decode_step", links=links,
+                                    active=len(active))
+        try:
+            outs = self._run_decode_program(*feeds)
+            if "expert_counts" in outs:
+                load = self._book_experts(outs["expert_counts"],
+                                          len(active))
+                if step is not None:
+                    step.attrs.update(load)
+            if step is not None and self._wpool is not None:
+                # the pages this step's feeds let go, and what both
+                # kinds hold now
+                step.attrs.update(
+                    window_pages_released=self._released_in_feeds,
+                    pages_live_full=self._pool.live_pages,
+                    pages_live_window=self._wpool.live_pages,
+                    live_positions=int(sum(s.position + 1
+                                           for s in active)),
+                    live_positions_window=int(sum(
+                        min(s.position + 1, self.window)
+                        for s in active)))
+        finally:
+            telemetry.span_end(step)
         t1 = time.monotonic()
         span = telemetry.span_begin("generation/book_tokens", links=links,
                                     tokens=len(active))
         try:
-            self._book_step(active, next_tokens, logits, t0, t1)
+            self._book_step(active, outs, t0, t1)
             if span is not None:
                 span.attrs["finished"] = sum(s.req is None
                                              for s in active)
@@ -2394,6 +2598,7 @@ class GenerationEngine:
         fault.maybe_delay(kind)
         if kind == "fail":
             raise fault.InjectedFault("injected decode_step failure")
+        self._released_in_feeds = 0
         if self.paged:
             # pool-exhaustion guard: a slot about to cross into an
             # unmapped page must get one BEFORE the step (the write
@@ -2415,20 +2620,28 @@ class GenerationEngine:
         for s in active:
             tokens[s.idx, 0] = s.tokens[-1]
             positions[s.idx] = s.position
-        bt = live = None
+        bt = live = btw = None
         if self.paged and active:
             bt = np.zeros((self.num_slots, self.pages_per_slot),
                           "int32")
             live = np.zeros((self.num_slots,), "int32")
+            if self._wpool is not None:
+                btw = np.zeros_like(bt)
             for s in active:
                 bt[s.idx] = self._slot_block_table(s)
                 live[s.idx] = 1
-        return active, (tokens, positions, bt, live)
+                if btw is not None:
+                    btw[s.idx] = self._slot_block_table(s, window=True)
+        elif active and "live" in self._decode_feeds:
+            live = np.zeros((self.num_slots,), "int32")
+            live[[s.idx for s in active]] = 1
+        return active, (tokens, positions, bt, live, btw)
 
-    def _book_step(self, active, next_tokens, logits, t0: float,
-                   t1: float):
+    def _book_step(self, active, outs: dict, t0: float, t1: float):
         """The host half of a grid step after its token fetch: step
         accounting, then one booked token per riding slot."""
+        next_tokens, logits = outs["next_token"], outs.get("logits")
+        router = outs.get("router_logits")
         ms = (t1 - t0) * 1e3
         self._t_decode_total += ms
         self._h_step.observe(ms)
@@ -2458,6 +2671,8 @@ class GenerationEngine:
             s.tokens.append(tok)
             if logits is not None:
                 s.logits.append(logits[s.idx])
+            if router is not None:
+                s.router_logits.append(router[s.idx])
             # one timestamp for the whole grid step: per-token
             # bookkeeping adds no extra clock reads to the step
             self._book_token(s, tok, t1)
@@ -2549,6 +2764,10 @@ class GenerationEngine:
         if self.keep_logits:
             result["logits"] = slot.logits
             slot.logits = []
+            if slot.router_logits:
+                # [L_moe, E] per generated token, for a reference check
+                result["router_logits"] = slot.router_logits
+                slot.router_logits = []
         if slot.hit_tokens:
             result["prefix_hit_tokens"] = slot.hit_tokens
         if req.record_timeline:
@@ -2717,6 +2936,15 @@ class GenerationEngine:
                 "pages_free": self._pool.free_pages,
                 "pages_live": self._pool.live_pages,
                 "page_bytes": self.page_bytes,
+                "window": None if self._wpool is None else {
+                    "window": self.window,
+                    "num_pages": self.num_window_pages,
+                    "pages_per_slot": self.window_pages_per_slot,
+                    "pages_free": self._wpool.free_pages,
+                    "pages_live": self._wpool.live_pages,
+                    "page_bytes": self.window_page_bytes,
+                    "pages_released": n["window_pages_released"],
+                },
                 "prefill_chunk": self.prefill_chunk,
                 "prefix_reuse": self.prefix_reuse,
                 "prefix_index_entries":

@@ -335,6 +335,22 @@ def test_chunked_prefill_interleaves_decode(dense_ref):
 # pool exhaustion: cache_full exactness + recovery
 # ---------------------------------------------------------------------------
 
+def test_whole_prompt_prefills_first_come_first_served(dense_ref):
+    """Unchunked, slots claimed in one pass prefill in the order their
+    requests arrived: one whole prompt an iteration, oldest first (the
+    round-robin cursor is for slices of chunked prompts)."""
+    eng = _paged(dense_ref, autostart=False)
+    try:
+        rng = np.random.default_rng(3)
+        futures = [eng.submit(rng.integers(1, 61, 20).tolist(), 2)
+                   for _ in range(3)]
+        eng.start()                      # all three claimed at once
+        ttft = [f.result(300)["ttft_ms"] for f in futures]
+        assert ttft == sorted(ttft)
+    finally:
+        eng.close()
+
+
 def test_pool_exhaustion_cache_full(dense_ref):
     """A budget beyond the pool finishes cache_full with EXACTLY
     usable_pages * page_tokens - prompt_len + 1 tokens (every page
