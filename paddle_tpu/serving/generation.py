@@ -65,6 +65,21 @@ identified.  This module is the repo's answer:
   causally masked and overwritten by the next real write).  Slots
   with no usable draft, or ``submit(speculate=False)``, take the
   unchanged one-token grid step — mixed grids per iteration.
+* **One decode step in flight** — the scheduler hands grid step n+1
+  to the device before it fetches and books step n, so building feeds,
+  booking tokens and publishing run while the device works.  Step n+1
+  takes step n's tokens as the device holds them; its positions and
+  pages are the booked ones plus the step in flight, and a sequence
+  the host knows will end at step n (budget or cache one token from
+  full) stays out of it.  A sequence that ends on EOS at the settle of
+  step n has already ridden n+1: that row is discarded (a row is
+  booked only if its slot still serves the request that rode; its K/V
+  write lies past every committed position and a later owner of the
+  page writes before it reads).  After a joiner (a finished prefill,
+  an adopted segment), before a speculative round or a weight swap,
+  and with no page left for the position ahead, the settle comes first
+  and the step is built from the host's tokens.  Token streams are the
+  same either way.
 * **Admission control** — bounded queue reusing the serving
   :class:`~paddle_tpu.serving.engine.OverloadedError` semantics:
   ``queue_full`` at submit, ``deadline`` when a request outlives
@@ -108,6 +123,9 @@ extra work.
 Stats (README catalog): counters ``serving_generate_requests``,
 ``serving_generate_shed``, ``requests_shed_deadline``,
 ``serving_prefills``, ``serving_decode_steps``,
+``serving_decode_steps_ahead`` (of those, dispatched before the step
+before them was fetched), ``serving_decode_rows_discarded`` (rows of
+such a step whose sequence had ended at the settle it overtook),
 ``serving_decode_failures`` (decode-grid iterations that raised —
 each fails only the then-active requests),
 ``serving_generated_tokens``,
@@ -391,6 +409,26 @@ class _Slot:
         return self.req is not None
 
 
+class _StepInFlight:
+    """A decode grid step the device has been handed and the host has
+    not read yet.  ``riders`` are the ``(slot, request)`` pairs of its
+    live rows: a row is booked at the settle only if its slot still
+    serves that request.  ``outs`` are the step's fetch handles by
+    name, ``t0`` the moment the device could begin it (its feeds'
+    start, or the settle of the step before when it was dispatched
+    ahead of that), ``released`` the window pages its feeds let go."""
+
+    __slots__ = ("riders", "outs", "links", "t0", "ahead", "released")
+
+    def __init__(self, riders, outs, links, t0, ahead, released):
+        self.riders = riders
+        self.outs = outs
+        self.links = links
+        self.t0 = t0
+        self.ahead = ahead
+        self.released = released
+
+
 class GenerationEngine:
     """KV-cached generation over a fixed decode-slot grid.
 
@@ -633,7 +671,8 @@ class GenerationEngine:
         self.weights_version = 1
 
         self._n = {"requests": 0, "shed": 0, "served": 0, "prefills": 0,
-                   "decode_steps": 0, "generated_tokens": 0,
+                   "decode_steps": 0, "decode_steps_ahead": 0,
+                   "decode_rows_discarded": 0, "generated_tokens": 0,
                    "prefill_tokens": 0, "slot_reclaims": 0,
                    "failed": 0, "prefix_hits": 0,
                    "prefix_tokens_saved": 0, "prefill_chunks": 0,
@@ -671,6 +710,8 @@ class GenerationEngine:
         # (scheduler thread only: _end_device_wait)
         self._iter_wait_s = 0.0
         self._released_in_feeds = 0  # window pages the last feeds freed
+        # the one decode grid step dispatched and not yet fetched
+        self._inflight: Optional[_StepInFlight] = None
 
         if autostart:
             self.start()
@@ -928,9 +969,7 @@ class GenerationEngine:
                     self._run_prefill_program(
                         np.zeros((b,), "int64"), b, slot=0)
                     compiled += 1
-            self._run_decode_program(
-                np.zeros((self.num_slots, 1), "int64"),
-                np.zeros((self.num_slots,), "int32"))
+            self._warm_decode()
             return compiled + 1
         np_slot = self.pages_per_slot
         if self.role == "decode":
@@ -953,9 +992,7 @@ class GenerationEngine:
                             fetch_list=[fetches["tokens"]],
                             scope=self.scope, return_numpy=False)
                         compiled += 1
-            self._run_decode_program(
-                np.zeros((self.num_slots, 1), "int64"),
-                np.zeros((self.num_slots,), "int32"))
+            self._warm_decode()
             return compiled + 1
         if self.prefill_chunk <= 0:
             for b in self.prefill_buckets:
@@ -1003,9 +1040,20 @@ class GenerationEngine:
                         fetch_list=[fetches["tokens"]],
                         scope=self.scope, return_numpy=False)
                     compiled += 1
-        self._run_decode_program(np.zeros((self.num_slots, 1), "int64"),
-                                 np.zeros((self.num_slots,), "int32"))
+        self._warm_decode()
         return compiled + 1
+
+    def _warm_decode(self):
+        """Two grid steps over idle rows: the decode program, then the
+        same program fed the first one's tokens as the device holds
+        them, which compiles the reshape that carries a step's tokens
+        into the step dispatched ahead of its settle."""
+        positions = np.zeros((self.num_slots,), "int32")
+        outs = self._dispatch_decode(
+            self._host_tokens(np.zeros((self.num_slots,), "int32")),
+            positions)
+        outs = self._dispatch_decode(self._carried_tokens(outs), positions)
+        self._fetch_decode(outs)
 
     # -- lifecycle ----------------------------------------------------------
     def start(self):
@@ -1237,6 +1285,13 @@ class GenerationEngine:
         if pending is None:
             return
         arrays, ev, box = pending
+        try:
+            # the step in flight ran under the old weights: book it
+            # before anything flips
+            self._settle_inflight()
+        except Exception as e:  # noqa: BLE001 — a decode-grid crash,
+            # contained as in _iteration; the swap itself goes ahead
+            self._decode_failed(e)
         try:
             box["result"] = self._commit_swap(arrays)
         except BaseException as e:  # noqa: BLE001 — hand the caller
@@ -1631,6 +1686,8 @@ class GenerationEngine:
         served = frozenset()
         if self.speculate and self._decoding_slots():
             try:
+                # a draft continues the booked history
+                self._settle_inflight()
                 served = self._speculate_round()
             except Exception as e:  # noqa: BLE001 — a verify crash
                 # is a decode-grid crash: it donated the same pool
@@ -1868,6 +1925,7 @@ class GenerationEngine:
         # chunked prefill writes into, so after a mid-step crash no
         # slot's cache state is knowable
         active = self._active()
+        self._inflight = None  # its rows die with their requests
         self._count("failed", len(active))
         stat_add("serving_decode_failures")
         logger.warning("decode step failed; failing %d active "
@@ -2386,16 +2444,30 @@ class GenerationEngine:
         req.future._resolve(outputs=result)
 
     # -- decode -------------------------------------------------------------
-    def _run_decode_program(self, tokens: np.ndarray,
-                            positions: np.ndarray,
-                            block_tables: Optional[np.ndarray] = None,
-                            live: Optional[np.ndarray] = None,
-                            block_tables_window:
-                            Optional[np.ndarray] = None) -> dict:
-        """One grid step.  Returns its fetches as arrays, by name:
-        ``next_token`` and, where the program has them, ``logits``,
-        ``expert_counts``, ``router_logits`` (the counts ride the token
-        fetch: one wait for the one program)."""
+    def _host_tokens(self, tokens: np.ndarray):
+        """``tokens`` [slots] int32 as the decode program's ``tokens`` feed,
+        on the device: the same array type and width as the tokens one
+        step carries into the next (:meth:`_carried_tokens`), so both
+        bind the one compiled step."""
+        import jax
+
+        return jax.device_put(tokens.reshape(-1, 1))
+
+    def _carried_tokens(self, outs: dict):
+        """The ``tokens`` feed of the step after the one that returned
+        ``outs``: its greedy tokens as the device holds them, never
+        brought to the host."""
+        return outs["next_token"].value.reshape(self.num_slots, 1)
+
+    def _dispatch_decode(self, tokens, positions: np.ndarray,
+                         block_tables: Optional[np.ndarray] = None,
+                         live: Optional[np.ndarray] = None,
+                         block_tables_window:
+                         Optional[np.ndarray] = None) -> dict:
+        """Hand one grid step to the device.  Returns its fetch handles
+        by name, unread: ``next_token`` and, where the program has
+        them, ``logits``, ``expert_counts``, ``router_logits`` (the
+        counts ride the token fetch: one wait for the one program)."""
         feed = {"tokens": tokens, "positions": positions}
         if self.paged:
             empty = (self.num_slots, self.pages_per_slot)
@@ -2414,8 +2486,11 @@ class GenerationEngine:
             feed["live"] = live if live is not None \
                 else np.zeros((self.num_slots,), "int32")
         with telemetry.trace_span("generation/decode_dispatch"):
-            outs = self._run_fetching(self._decode_exe, self._decode_prog,
+            return self._run_fetching(self._decode_exe, self._decode_prog,
                                       self._decode_fetches, feed)
+
+    def _fetch_decode(self, outs: dict) -> dict:
+        """Block on a dispatched grid step: its fetches as arrays."""
         span = telemetry.span_begin("generation/token_fetch")
         try:
             return {n: np.asarray(o.numpy()) for n, o in outs.items()}
@@ -2540,10 +2615,150 @@ class GenerationEngine:
         return frozenset(served)
 
     def _decode_step(self, skip: frozenset = frozenset()):
+        """One pass of the decode grid with one step kept in flight:
+        dispatch the next step, then settle (fetch and book) the one
+        dispatched a pass earlier, so the host's half of a step runs
+        while the device works on the next.  That order needs the next
+        step built without the last one's tokens on the host: every
+        sequence due to ride it rode the step in flight, whose tokens
+        it takes as the device holds them.  After a joiner (a finished
+        prefill, an adopted segment) or with no page left for the
+        position ahead, the settle comes first and the step goes out
+        from the host's tokens: the same pass, the settle placed
+        before the dispatch."""
+        kind = fault.fire("decode_step")
+        fault.maybe_delay(kind)
+        if kind == "fail":
+            raise fault.InjectedFault("injected decode_step failure")
+        lead = self._inflight if self._rides_on() else None
+        built = None
+        if lead is not None:
+            try:
+                built = self._decode_feeds_for(skip, lead)
+            except PoolExhausted:
+                # no page for a rider's position ahead: it may end at
+                # the settle, so that comes first
+                lead = None
+        if built is None:
+            self._settle_inflight()
+            built = self._decode_feeds_for(skip, None)
+        riders, feeds, links, t0 = built
+        if not riders:
+            # nothing rides on: all that is left is the step in flight
+            self._settle_inflight()
+            return
+        step = telemetry.span_begin("generation/decode_step", links=links,
+                                    active=len(riders),
+                                    ahead=int(lead is not None))
+        try:
+            self._inflight = _StepInFlight(
+                riders, self._dispatch_decode(*feeds), links, t0,
+                lead is not None, self._released_in_feeds)
+            if lead is not None:
+                outs = self._fetch_inflight(lead, step)
+        finally:
+            telemetry.span_end(step)
+        if lead is None:
+            return
+        self._book_inflight(lead, outs)
+        if not any(s.req is r for s, r in riders):
+            # every sequence of the step ahead ended at this settle: no
+            # row of it will be booked, and nothing waits for it
+            self._inflight = None
+            self._discard_rows(len(riders))
+
+    def _discard_rows(self, n: int):
+        """Count ``n`` rows of a step dispatched ahead that no sequence
+        is left to take."""
+        if n:
+            self._count("decode_rows_discarded", n)
+            stat_add("serving_decode_rows_discarded", n)
+
+    def _rides_on(self) -> bool:
+        """Whether the next grid step can go out ahead of the settle of
+        the one in flight: there is one, and every sequence decoding
+        now rode it (no joiner since its dispatch)."""
+        fl = self._inflight
+        if fl is None:
+            return False
+        rode = {s.idx: r for s, r in fl.riders}
+        return all(rode.get(s.idx) is s.req
+                   for s in self._decoding_slots())
+
+    def _settle_inflight(self):
+        """Fetch and book the step in flight, if there is one: what
+        comes before anything that reads or ends the sequences' booked
+        state (a step built from the host's tokens, a speculative
+        draft, a weight swap)."""
+        fl, self._inflight = self._inflight, None
+        if fl is None:
+            return
+        step = telemetry.span_begin("generation/decode_step",
+                                    links=fl.links, active=len(fl.riders))
+        try:
+            outs = self._fetch_inflight(fl, step)
+        finally:
+            telemetry.span_end(step)
+        self._book_inflight(fl, outs)
+
+    def _fetch_inflight(self, fl: _StepInFlight, step) -> dict:
+        """Wait for ``fl``'s fetches and write what the step did onto
+        the open ``generation/decode_step`` span."""
+        outs = self._fetch_decode(fl.outs)
+        attrs = {}
+        if "expert_counts" in outs:
+            # the counts cover every live row of the step, the rows the
+            # settle discards too
+            attrs.update(self._book_experts(outs["expert_counts"],
+                                            len(fl.riders)))
+        if self._wpool is not None:
+            # the pages this step's feeds let go, what both kinds hold
+            # now, the positions its rows attended
+            rows = [s for s, r in fl.riders if s.req is r]
+            attrs.update(
+                window_pages_released=fl.released,
+                pages_live_full=self._pool.live_pages,
+                pages_live_window=self._wpool.live_pages,
+                live_positions=int(sum(s.position + 1 for s in rows)),
+                live_positions_window=int(sum(
+                    min(s.position + 1, self.window) for s in rows)))
+        if step is not None:
+            step.attrs.update(attrs)
+        return outs
+
+    def _book_inflight(self, fl: _StepInFlight, outs: dict):
+        """Book ``fl``'s fetched tokens under ``generation/book_tokens``
+        to the riders whose slot still serves the request that rode."""
+        t1 = time.monotonic()
+        rows = [s for s, r in fl.riders if s.req is r]
+        span = telemetry.span_begin("generation/book_tokens",
+                                    links=fl.links, tokens=len(rows))
+        try:
+            self._book_step(rows, outs, fl.t0, t1)
+            if fl.ahead:
+                self._count("decode_steps_ahead")
+                stat_add("serving_decode_steps_ahead")
+            self._discard_rows(len(fl.riders) - len(rows))
+            if self._inflight is not None:
+                # dispatched ahead of this settle: the device begins it
+                # now
+                self._inflight.t0 = t1
+            if span is not None:
+                span.attrs["finished"] = sum(s.req is None for s in rows)
+        finally:
+            telemetry.span_end(span)
+
+    def _decode_feeds_for(self, skip: frozenset,
+                          lead: Optional[_StepInFlight]):
+        """:meth:`_build_decode_feeds` under ``generation/decode_feeds``.
+        Returns ``(riders, feeds, links, t0)``.  Raises
+        :class:`PoolExhausted` when the step was to go out ahead of
+        ``lead``'s settle and a rider has no page for the position
+        ahead."""
         t0 = time.monotonic()
         span = telemetry.span_begin("generation/decode_feeds")
         try:
-            active, feeds = self._build_decode_feeds(skip)
+            active, feeds = self._build_decode_feeds(skip, lead)
             # the grid step serves N sequences at once: link their
             # sequence-span contexts, the fan-in convention batch spans
             # use
@@ -2554,50 +2769,24 @@ class GenerationEngine:
                 span.links = links
         finally:
             telemetry.span_end(span)
-        if not active:
-            return
-        step = telemetry.span_begin("generation/decode_step", links=links,
-                                    active=len(active))
-        try:
-            outs = self._run_decode_program(*feeds)
-            if "expert_counts" in outs:
-                load = self._book_experts(outs["expert_counts"],
-                                          len(active))
-                if step is not None:
-                    step.attrs.update(load)
-            if step is not None and self._wpool is not None:
-                # the pages this step's feeds let go, and what both
-                # kinds hold now
-                step.attrs.update(
-                    window_pages_released=self._released_in_feeds,
-                    pages_live_full=self._pool.live_pages,
-                    pages_live_window=self._wpool.live_pages,
-                    live_positions=int(sum(s.position + 1
-                                           for s in active)),
-                    live_positions_window=int(sum(
-                        min(s.position + 1, self.window)
-                        for s in active)))
-        finally:
-            telemetry.span_end(step)
-        t1 = time.monotonic()
-        span = telemetry.span_begin("generation/book_tokens", links=links,
-                                    tokens=len(active))
-        try:
-            self._book_step(active, outs, t0, t1)
-            if span is not None:
-                span.attrs["finished"] = sum(s.req is None
-                                             for s in active)
-        finally:
-            telemetry.span_end(span)
+        return [(s, s.req) for s in active], feeds, links, t0
 
-    def _build_decode_feeds(self, skip: frozenset):
+    def _build_decode_feeds(self, skip: frozenset,
+                            lead: Optional[_StepInFlight] = None):
         """The host half of a grid step before its dispatch: the page
         guard, then the slots that ride the step and the program's
-        feeds ``(tokens, positions, block_tables, live)``."""
-        kind = fault.fire("decode_step")
-        fault.maybe_delay(kind)
-        if kind == "fail":
-            raise fault.InjectedFault("injected decode_step failure")
+        feeds ``(tokens, positions, block_tables, live)``.  With
+        ``lead`` the step goes out ahead of ``lead``'s settle: each
+        rider sits one position past its booked one, its token is
+        ``lead``'s on the device, and a sequence the host knows will
+        end at ``lead`` (its budget or its cache is one token from
+        full) stays out."""
+        ahead = int(lead is not None)
+        riding = [s for s in self._decoding_slots()
+                  if s.idx not in skip
+                  and not (ahead and (
+                      len(s.tokens) + 1 >= s.req.max_new_tokens
+                      or s.position + 1 >= self.max_seq_len))]
         self._released_in_feeds = 0
         if self.paged:
             # pool-exhaustion guard: a slot about to cross into an
@@ -2605,23 +2794,30 @@ class GenerationEngine:
             # would land on the trash page and corrupt nothing, but
             # the token would be attention-blind to itself); a slot
             # the pool cannot serve even after eviction finishes
-            # cache_full with everything it generated so far
-            for s in list(self._decoding_slots()):
-                if s.idx in skip:
-                    continue
+            # cache_full with everything it generated so far (ahead of
+            # a settle it has more to come: the caller settles first)
+            for s in riding:
                 try:
-                    self._ensure_pages(s, s.position + 1)
+                    self._ensure_pages(s, s.position + ahead + 1)
                 except PoolExhausted:
+                    if ahead:
+                        raise
                     self._finish(s, "cache_full")
-        tokens = np.zeros((self.num_slots, 1), "int64")
+        active = [s for s in riding if s.req is not None]
+        if not active:
+            return active, None
         positions = np.zeros((self.num_slots,), "int32")
-        active = [s for s in self._decoding_slots()
-                  if s.idx not in skip]
         for s in active:
-            tokens[s.idx, 0] = s.tokens[-1]
-            positions[s.idx] = s.position
+            positions[s.idx] = s.position + ahead
+        if ahead:
+            tokens = self._carried_tokens(lead.outs)
+        else:
+            last = np.zeros((self.num_slots,), "int32")
+            for s in active:
+                last[s.idx] = s.tokens[-1]
+            tokens = self._host_tokens(last)
         bt = live = btw = None
-        if self.paged and active:
+        if self.paged:
             bt = np.zeros((self.num_slots, self.pages_per_slot),
                           "int32")
             live = np.zeros((self.num_slots,), "int32")
@@ -2632,7 +2828,7 @@ class GenerationEngine:
                 live[s.idx] = 1
                 if btw is not None:
                     btw[s.idx] = self._slot_block_table(s, window=True)
-        elif active and "live" in self._decode_feeds:
+        elif "live" in self._decode_feeds:
             live = np.zeros((self.num_slots,), "int32")
             live[[s.idx for s in active]] = 1
         return active, (tokens, positions, bt, live, btw)

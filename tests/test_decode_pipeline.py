@@ -1,0 +1,505 @@
+"""One decode step in flight (PR 29): the scheduler hands grid step n+1
+to the device before it fetches and books step n.
+
+What must hold whatever the order of dispatch and settle: every
+sequence's token stream is the one it has when it runs alone; a row of
+a step that overtook its sequence's end is discarded and never booked
+to the slot's next owner; a length-bounded answer costs no extra step;
+pages are mapped before the dispatch that writes them and all return to
+their pools; a fault, a weight swap and a drain meet the step in flight
+and leave nothing behind; per-tenant sums stay equal to the counters.
+
+Toy widths on the CPU: every number here is a count or an ordering.
+"""
+import threading
+import time
+
+import numpy as np
+import pytest
+
+import paddle_tpu as pt
+from paddle_tpu import fault, telemetry
+from paddle_tpu.models.llama import build_llama_forward
+from paddle_tpu.serving import GenerationEngine, RequestFailed, usage
+
+MODEL = dict(vocab_size=61, hidden=32, num_layers=2, num_heads=4,
+             num_kv_heads=2, intermediate=64)
+MOE = {"experts": 8, "top_k": 3, "width": 32, "activation": "relu"}
+# one full layer without positions, then window-16 RoPE layers: two page
+# kinds, pages released while decoding
+WINDOW_MODEL = dict(
+    vocab_size=97, hidden=64, num_layers=4, num_heads=4, num_kv_heads=2,
+    intermediate=0, head_dim=32, rope_base=1.5e6,
+    layer_pattern=[{"window": None, "rope": False, "ffn": MOE}]
+    + [{"window": 16, "rope": True, "ffn": MOE}] * 3)
+PROMPTS = [[3, 17, 5, 40, 8, 22], [9, 1, 33, 7], [12, 50, 2, 2, 31, 6, 19],
+           [44, 13, 27], [21, 4, 60, 35, 11]]
+
+
+def _engine(kind, **kw):
+    if kind == "window":
+        return GenerationEngine(
+            WINDOW_MODEL, num_slots=3, max_seq_len=64, paged=True,
+            page_tokens=8, prefill_chunk=0, prefix_reuse=False,
+            speculate=False, attn_impl="xla", seed=0, **kw)
+    return GenerationEngine(
+        MODEL, num_slots=3, max_seq_len=64, attn_impl="xla", seed=0,
+        paged=kind == "paged", **(dict(page_tokens=8, prefix_reuse=False,
+                                       prefill_chunk=0, speculate=False)
+                                  if kind == "paged" else {}), **kw)
+
+
+@pytest.fixture(scope="module", params=["paged", "dense"])
+def eng(request):
+    e = _engine(request.param)
+    e.warmup()
+    yield e
+    e.close()
+
+
+@pytest.fixture(scope="module")
+def paged():
+    e = _engine("paged")
+    e.warmup()
+    yield e
+    e.close()
+
+
+@pytest.fixture(scope="module")
+def solo(eng):
+    """Each prompt's 16-token stream when it decodes alone."""
+    return [eng.generate(p, 16, timeout=120)["tokens"] for p in PROMPTS]
+
+
+def _counters(e):
+    return dict(e.stats()["counters"])
+
+
+def _quiet(e, timeout=10.0):
+    """Wait for the scheduler to go idle: a future resolves inside the
+    pass that books its last token, a moment before that pass ends."""
+    deadline = time.monotonic() + timeout
+    while time.monotonic() < deadline:
+        if not e.stats()["slots_active"] and e._inflight is None:
+            time.sleep(0.02)
+            return
+        time.sleep(0.005)
+    raise AssertionError("scheduler did not go idle")
+
+
+def _after_tokens(n):
+    """``(on_token, event)``: the event fires at the n-th token."""
+    ev, seen = threading.Event(), []
+
+    def on_token(tok, ts):
+        seen.append(tok)
+        if len(seen) >= n:
+            ev.set()
+    return on_token, ev
+
+
+def _pages_live(e):
+    p = e.stats()["paged"]
+    if p is None:
+        return 0
+    return p["pages_live"] + (p["window"]["pages_live"]
+                              if p["window"] else 0)
+
+
+# -- (1) streams -------------------------------------------------------------
+def test_streams_equal_solo_streams_with_joiners_midstream(eng, solo):
+    before = _counters(eng)
+    on_a, a_running = _after_tokens(4)
+    fa = eng.submit(PROMPTS[0], 16, on_token=on_a)
+    assert a_running.wait(60)
+    on_b, b_running = _after_tokens(3)
+    fb = eng.submit(PROMPTS[1], 12, on_token=on_b)   # joins a's grid
+    assert b_running.wait(60)
+    fc = eng.submit(PROMPTS[2], 9)                   # joins both
+    fd = eng.submit(PROMPTS[3], 7)                   # waits for a slot
+    got = [f.result(120) for f in (fa, fb, fc, fd)]
+    for res, want, n in zip(got, solo, (16, 12, 9, 7)):
+        assert res["tokens"] == want[:n] and res["finish"] == "length"
+        assert res["steps"] == n - 1
+    _quiet(eng)
+    after = _counters(eng)
+    steps = after["decode_steps"] - before["decode_steps"]
+    ahead = after["decode_steps_ahead"] - before["decode_steps_ahead"]
+    # every step but the first and those right after a joiner went out
+    # ahead of the settle before it
+    assert 0 < ahead < steps
+    assert after["decode_rows_discarded"] == before["decode_rows_discarded"]
+    assert _pages_live(eng) == 0
+    # tokens from the host and tokens carried on the device bind the one
+    # decode executable warm-up compiled
+    assert eng._decode_exe.cache_info()["compiled"] == 1
+
+
+@pytest.mark.parametrize("kind", ["paged", "dense", "window"])
+def test_logits_under_ahead_dispatch_are_the_uncached_forwards(kind):
+    """The step dispatched ahead reads its tokens from the device: its
+    logits are those of a plain forward over prompt + stream, at every
+    position, on all three cache layouts (the window engine crosses its
+    window and releases pages while it decodes)."""
+    model = WINDOW_MODEL if kind == "window" else MODEL
+    e = _engine(kind, keep_logits=True)
+    try:
+        prompt = PROMPTS[2] + PROMPTS[4][:3]              # 10 tokens
+        res = e.generate(prompt, 26, timeout=300)
+        seq = prompt + res["tokens"]
+        main, startup = pt.Program(), pt.Program()
+        startup._is_startup = True
+        with pt.program_guard(main, startup):
+            _, fetches = build_llama_forward(
+                1, e.max_seq_len, name=e.name, attn_impl="xla", **model)
+        padded = np.zeros((e.max_seq_len,), "int64")
+        padded[:len(seq)] = seq
+        ref = pt.Executor().run(main, feed={"input_ids": padded[None]},
+                                fetch_list=[fetches["logits"]],
+                                scope=e.scope)[0][0]
+        got = np.stack(res["logits"])
+        want = ref[len(prompt) - 1:len(prompt) - 1 + len(got)]
+        assert np.abs(got - want).max() <= 1e-4 * np.ptp(want)
+        assert res["tokens"] == [int(t) for t in want.argmax(-1)]
+        c = _counters(e)
+        assert c["decode_steps_ahead"] == c["decode_steps"] - 1 == 24
+        if kind == "window":
+            assert c["window_pages_released"] > 0
+            assert c["moe_tokens_dropped"] == 0
+        _quiet(e)
+        assert _pages_live(e) == 0
+    finally:
+        e.close()
+
+
+# -- (2) spans ---------------------------------------------------------------
+def _named(spans, name):
+    return [s for s in spans if s.name == name]
+
+
+def _within(spans, outer):
+    return sorted((s for s in spans if s.tid == outer.tid and s is not outer
+                   and outer.start <= s.start and s.end <= outer.end),
+                  key=lambda s: s.start)
+
+
+def test_dispatch_precedes_fetch_and_ahead_marks_it(paged):
+    pt.set_flags({"FLAGS_telemetry": True})
+    telemetry.clear_spans()
+    on_a, a_running = _after_tokens(5)
+    fa = paged.submit(PROMPTS[0], 14, on_token=on_a)
+    assert a_running.wait(60)
+    fb = paged.submit(PROMPTS[1], 4)
+    fa.result(120), fb.result(120)
+    _quiet(paged)
+    spans = telemetry.get_spans()
+    steps = sorted(_named(spans, "generation/decode_step"),
+                   key=lambda s: s.start)
+    shapes = []
+    for st in steps:
+        inner = [k.name.split("/")[1] for k in _within(spans, st)
+                 if k.name in ("generation/decode_dispatch",
+                               "generation/token_fetch")]
+        shapes.append((tuple(inner), st.attrs.get("ahead")))
+    steady = (("decode_dispatch", "token_fetch"), 1)
+    first = (("decode_dispatch",), 0)
+    settle = (("token_fetch",), None)
+    # a alone: its first step has nothing to overtake, the next ones go
+    # out before the step before them is fetched ...
+    assert shapes[0] == first and shapes[1] == steady
+    # ... b's finished prefill is a joiner: the step in flight is
+    # settled first, the next goes out from the host's tokens (ahead 0),
+    # and the one after it is ahead again
+    prefill_b = sorted(_named(spans, "generation/prefill_fetch"),
+                       key=lambda s: s.start)[1]
+    i = next(i for i, st in enumerate(steps) if st.start > prefill_b.end)
+    assert shapes[i] == settle and shapes[i + 1] == first
+    assert shapes[i + 2] == steady
+    # no other pass settles first, and the last only settles
+    assert [s for s in shapes[:-1] if s == settle] == [settle]
+    assert shapes[-1] == settle
+    assert shapes.count(first) == 2
+    assert all(s in (steady, first, settle) for s in shapes)
+    # spans of the scheduler thread never overlap without nesting
+    for st in steps:
+        for k in _within(spans, st):
+            assert st.start <= k.start and k.end <= st.end
+
+
+# -- (3) EOS at the settle ---------------------------------------------------
+def _pick_eos(streams, rider, others):
+    """An index k >= 3 of ``streams[rider]`` whose token appears nowhere
+    before it there and nowhere in the ``others``' streams."""
+    s = streams[rider]
+    for k in range(3, len(s) - 1):
+        if s[k] not in s[:k] and all(s[k] not in streams[o]
+                                     for o in others):
+            return k
+    return None
+
+
+@pytest.mark.parametrize("kind", ["paged", "dense", "window"])
+def test_eos_at_settle_discards_the_row_that_overtook_it(kind):
+    e = _engine(kind)
+    e.warmup()
+    try:
+        prompts = PROMPTS + [[7, 7, 30], [58, 2, 46, 9]]
+        streams = [e.generate(p, 16, timeout=120)["tokens"]
+                   for p in prompts]
+        pick = next(((p, k, [o for o in range(len(prompts)) if o != p])
+                     for p in range(len(prompts))
+                     for k in [_pick_eos(streams, p,
+                                         [o for o in range(len(prompts))
+                                          if o != p])]
+                     if k is not None), None)
+        assert pick is not None, streams
+        p, k, others = pick
+        r, q = others[0], others[1]
+        e.eos_id = streams[p][k]
+        before = _counters(e)
+        # two of three slots: r decodes throughout, p ends on EOS with a
+        # step in flight, q claims a slot and joins while r's row of
+        # that step is still unread
+        on_r, r_running = _after_tokens(2)
+        fr = e.submit(prompts[r], 16, on_token=on_r)
+        assert r_running.wait(60)
+        fp = e.submit(prompts[p], 16)
+        fq = e.submit(prompts[q], 10)
+        rp, rr, rq = fp.result(120), fr.result(120), fq.result(120)
+        assert rp["finish"] == "eos" and rp["tokens"] == streams[p][:k + 1]
+        assert rr["tokens"] == streams[r] and rq["tokens"] == streams[q][:10]
+        _quiet(e)
+        after = _counters(e)
+        assert after["decode_rows_discarded"] \
+            - before["decode_rows_discarded"] == 1
+        assert after["generated_tokens"] - before["generated_tokens"] \
+            == k + 1 + 16 + 10
+        assert _pages_live(e) == 0
+        # the slot p left is claimed again before its discarded row is
+        # read: with one slot free at a time the next owner is booked
+        # nothing but its own tokens
+        e.eos_id = streams[p][k]
+        futs = [e.submit(prompts[i], 16 if i == p else 12)
+                for i in (p, r, q, others[2])]
+        for f, i in zip(futs, (p, r, q, others[2])):
+            want = streams[i][:k + 1] if i == p else streams[i][:12]
+            assert f.result(120)["tokens"] == want
+        _quiet(e)
+        assert _pages_live(e) == 0
+    finally:
+        e.eos_id = -1
+        e.close()
+
+
+# -- (4) a length-bounded answer costs no extra step --------------------------
+def test_no_step_is_dispatched_past_a_budget_or_a_full_cache(eng):
+    pt.set_flags({"FLAGS_telemetry": True})
+    for prompt, budget, tokens, finish in (
+            (PROMPTS[0], 7, 7, "length"), (PROMPTS[1], 1, 1, "length"),
+            (PROMPTS[1], 2, 2, "length"),
+            # 6 prompt positions, 64 in the cache: 59 tokens fill it
+            (PROMPTS[0], 200, eng.max_seq_len - 6 + 1, "cache_full")):
+        _quiet(eng)
+        telemetry.clear_spans()
+        before = _counters(eng)
+        res = eng.generate(prompt, budget, timeout=120)
+        _quiet(eng)
+        assert (len(res["tokens"]), res["finish"]) == (tokens, finish)
+        after = _counters(eng)
+        dispatched = len(_named(telemetry.get_spans(),
+                                "generation/decode_dispatch"))
+        assert dispatched == tokens - 1 == res["steps"]
+        assert after["decode_steps"] - before["decode_steps"] == tokens - 1
+        assert after["decode_rows_discarded"] \
+            == before["decode_rows_discarded"]
+
+
+# -- (5) pages are mapped before the dispatch that writes them ---------------
+@pytest.mark.parametrize("kind", ["paged", "window"])
+def test_page_of_the_ahead_position_is_mapped_before_the_dispatch(kind):
+    e = _engine(kind)
+    e.warmup()
+    seen = []
+    dispatch = e._dispatch_decode
+
+    def spy(tokens, positions, bt=None, live=None, btw=None):
+        ahead = e._inflight is not None
+        for row in np.flatnonzero(live):
+            page = int(positions[row]) // e.page_tokens
+            seen.append((ahead, int(positions[row]), int(bt[row, page]),
+                         None if btw is None else int(btw[row, page])))
+        return dispatch(tokens, positions, bt, live, btw)
+
+    e._dispatch_decode = spy
+    try:
+        # prompt of 6, page of 8, window of 16: the answers cross three
+        # page edges and, on the window engine, the window's edge
+        ra = e.submit(PROMPTS[0], 26)
+        rb = e.submit(PROMPTS[2], 20)
+        ra.result(120), rb.result(120)
+        # page 0 is the trash page: a live row never writes there
+        assert all(full > 0 and window != 0
+                   for _, _, full, window in seen)
+        crossed = [pos for ahead, pos, _, _ in seen
+                   if ahead and pos % e.page_tokens == 0]
+        assert len(crossed) >= 4
+        if kind == "window":
+            assert max(pos for _, pos, _, _ in seen) >= e.window + 8
+            assert _counters(e)["window_pages_released"] > 0
+        _quiet(e)
+        assert _pages_live(e) == 0
+    finally:
+        e.close()
+
+
+def test_no_page_for_the_ahead_position_settles_first_and_finishes():
+    """Three pages, two sequences: the one that needs a fourth page
+    finishes ``cache_full`` with all it generated, at the settle, and
+    the other keeps its stream."""
+    e = GenerationEngine(MODEL, num_slots=2, max_seq_len=64,
+                         attn_impl="xla", seed=0, paged=True,
+                         page_tokens=8, num_pages=4, prefix_reuse=False,
+                         prefill_chunk=0, speculate=False)
+    try:
+        want_a = e.generate(PROMPTS[0], 10, timeout=120)["tokens"]
+        want_b = e.generate(PROMPTS[1], 20, timeout=120)["tokens"]
+        on_b, b_running = _after_tokens(2)
+        fb = e.submit(PROMPTS[1], 20, on_token=on_b)   # 4 + 20: 3 pages
+        assert b_running.wait(60)
+        fa = e.submit(PROMPTS[0], 10)                  # 6 + 10: 2 pages
+        a, b = fa.result(120), fb.result(120)
+        full = [r for r in (a, b) if r["finish"] == "cache_full"]
+        assert len(full) == 1 and e.stats()["counters"]["failed"] == 0
+        for res, want in ((a, want_a), (b, want_b)):
+            assert res["tokens"] == want[:len(res["tokens"])]
+            assert res["finish"] == "cache_full" \
+                or len(res["tokens"]) == len(want)
+        _quiet(e)
+        assert _pages_live(e) == 0
+    finally:
+        e.close()
+
+
+# -- (6) a fault with a step in flight ---------------------------------------
+def test_decode_fault_with_a_step_in_flight_fails_active_serves_next(
+        eng, solo):
+    _quiet(eng)
+    failed = _counters(eng)["failed"]
+    # the third pass raises at its head: the second's step is in flight
+    fault.configure("decode_step:fail@3")
+    try:
+        fa = eng.submit(PROMPTS[0], 12)
+        fb = eng.submit(PROMPTS[1], 12)
+        for f in (fa, fb):
+            with pytest.raises(RequestFailed, match="decode step failed"):
+                f.result(120)
+    finally:
+        fault.configure("")
+    assert eng._inflight is None
+    assert _counters(eng)["failed"] - failed == 2
+    # the slots are claimed again: nothing of the dropped step is booked
+    got = [eng.submit(PROMPTS[i], 9) for i in (2, 3, 0)]
+    for f, i in zip(got, (2, 3, 0)):
+        assert f.result(120)["tokens"] == solo[i][:9]
+    _quiet(eng)
+    assert _pages_live(eng) == 0
+
+
+# -- (7) a weight swap and a drain settle the step in flight first -----------
+def test_weight_swap_settles_the_step_in_flight_first(paged):
+    donor = GenerationEngine(MODEL, num_slots=3, max_seq_len=64,
+                             attn_impl="xla", seed=7, paged=True,
+                             page_tokens=8, prefix_reuse=False,
+                             prefill_chunk=0, speculate=False,
+                             name="donor")
+    try:
+        new = {n.replace("donor", paged.name, 1):
+               np.asarray(donor.scope.find_var(n))
+               for n in donor._weight_names()}
+        want_new = donor.generate(PROMPTS[3], 8, timeout=120)["tokens"]
+    finally:
+        donor.close()
+    old = {n: np.asarray(paged.scope.find_var(n))
+           for n in paged._weight_names()}
+    want_old = paged.generate(PROMPTS[0], 40, timeout=120)["tokens"]
+    seen = {}
+    commit = paged._commit_swap
+
+    def spy(arrays):
+        slot = next(s for s in paged._slots if s.active)
+        seen.update(inflight=paged._inflight, booked=len(slot.tokens),
+                    steps=_counters(paged)["decode_steps"])
+        return commit(arrays)
+
+    paged._commit_swap = spy
+    try:
+        on_a, a_running = _after_tokens(6)
+        fa = paged.submit(PROMPTS[0], 40, on_token=on_a)
+        assert a_running.wait(60)
+        steps0 = _counters(paged)["decode_steps"]
+        assert paged.swap_weights(new)["weights_version"] >= 2
+        res = fa.result(120)
+        # nothing was in flight when the weights flipped, every token
+        # booked by then is the old weights', and all 40 arrived
+        assert seen["inflight"] is None and seen["steps"] >= steps0
+        assert 6 <= seen["booked"] < 40 and len(res["tokens"]) == 40
+        assert res["tokens"][:seen["booked"]] == want_old[:seen["booked"]]
+        assert paged.generate(PROMPTS[3], 8, timeout=120)["tokens"] \
+            == want_new
+    finally:
+        paged._commit_swap = commit
+        paged.swap_weights(old)
+    assert paged.generate(PROMPTS[0], 40, timeout=120)["tokens"] == want_old
+
+
+@pytest.mark.parametrize("kind", ["paged", "dense"])
+def test_close_with_drain_settles_the_step_in_flight(kind):
+    e = _engine(kind)
+    want = [e.generate(p, 12, timeout=120)["tokens"] for p in PROMPTS[:2]]
+    on_a, a_running = _after_tokens(3)
+    fa = e.submit(PROMPTS[0], 12, on_token=on_a)
+    fb = e.submit(PROMPTS[1], 12)
+    assert a_running.wait(60)
+    e.close(drain=True, timeout=120)
+    assert not e._thread.is_alive() and e._inflight is None
+    assert [fa.result(1)["tokens"], fb.result(1)["tokens"]] == want
+    assert e.stats()["slots_active"] == 0 and _pages_live(e) == 0
+
+
+# -- (8) per-tenant sums equal the global counters ----------------------------
+def test_tenant_sums_equal_the_counters_with_a_discarded_row(eng, solo):
+    k = _pick_eos(solo, 0, [1, 2])
+    assert k is not None, solo
+    pt.set_flags({"FLAGS_usage": True})
+    usage.reset_ledger()
+    _quiet(eng)
+    before = _counters(eng)
+    eng.eos_id = solo[0][k]
+    try:
+        on_b, b_running = _after_tokens(2)
+        fb = eng.submit(PROMPTS[1], 16, tenant="umbrella", on_token=on_b)
+        assert b_running.wait(60)
+        fa = eng.submit(PROMPTS[0], 16, tenant="acme")    # ends on EOS
+        fc = eng.submit(PROMPTS[2], 11, tenant="acme")
+        results = [f.result(120) for f in (fa, fb, fc)]
+        _quiet(eng)
+    finally:
+        eng.eos_id = -1
+    after = _counters(eng)
+    assert results[0]["finish"] == "eos"
+    assert after["decode_rows_discarded"] \
+        - before["decode_rows_discarded"] == 1
+    led = usage.ledger()
+    snap = led.snapshot()
+    assert all(v["delta"] == 0 for v in led.conservation().values())
+    assert snap["totals"]["tokens_out"] \
+        == after["generated_tokens"] - before["generated_tokens"] \
+        == sum(len(r["tokens"]) for r in results)
+    # a step books one unit to each sequence it booked a token to: the
+    # discarded row bills no one
+    assert snap["totals"]["decode_steps"] \
+        == sum(r["steps"] for r in results)
+    assert snap["tenants"]["acme"]["tokens_out"] \
+        == len(results[0]["tokens"]) + len(results[2]["tokens"])
+    assert snap["tenants"]["umbrella"]["decode_steps"] == results[1]["steps"]
+    usage.reset_ledger()

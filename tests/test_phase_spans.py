@@ -45,7 +45,8 @@ def traced(engine):
     pt.set_flags({"FLAGS_telemetry": True})
     telemetry.clear_spans()
     res = engine.generate(PROMPT, 5, timeout=120)
-    return res, _spans_after(res["steps"])
+    # one pass more than decode steps: the last only settles (PR 29)
+    return res, _spans_after(res["steps"] + 1)
 
 
 def _spans_after(iterations: int):
@@ -77,36 +78,46 @@ def test_decode_iteration_span_tree(traced):
     """Every scheduler pass is a root ``generation/iteration`` whose
     children, in order, are the phases of the table in README "Serving
     observability"; the decode dispatch reaches down to the executor's
-    own phases."""
+    own phases.  One decode step is kept in flight (PR 29): the first
+    pass only dispatches, each pass after it dispatches the next step
+    and then fetches and books the one before, the last only settles."""
     res, spans = traced
     iters = _named(spans, "generation/iteration")
     decoding = [it for it in iters
                 if _named(_children(spans, it), "generation/decode_step")]
-    assert len(decoding) == res["steps"] == 4
+    assert res["steps"] == 4 and len(decoding) == res["steps"] + 1
     for it in iters:
         assert it.parent_id is None
         assert it.trace_id != res["trace_id"]
         assert set(it.attrs) == {"active", "claimed", "queued", "cpu_ms"}
-    for it in decoding:
+    for i, it in enumerate(decoding):
+        first, last = i == 0, i == len(decoding) - 1
         kids = sorted(_children(spans, it), key=lambda s: s.start)
         assert [k.name for k in kids if k.name != "generation/publish"] \
             == ["generation/claim", "generation/decode_feeds",
-                "generation/decode_step", "generation/book_tokens"]
+                "generation/decode_step"] \
+            + ([] if first else ["generation/book_tokens"])
         assert kids[-1].name == "generation/publish"
         assert all(k.tid == it.tid and _inside(k, it) for k in kids)
         step = _named(kids, "generation/decode_step")[0]
-        dispatch, fetch = sorted(_children(spans, step),
-                                 key=lambda s: s.start)
-        assert (dispatch.name, fetch.name) == (
-            "generation/decode_dispatch", "generation/token_fetch")
-        exe_step, = _children(spans, dispatch)
+        inner = sorted(_children(spans, step), key=lambda s: s.start)
+        assert [k.name for k in inner] == \
+            ([] if last else ["generation/decode_dispatch"]) \
+            + ([] if first else ["generation/token_fetch"])
+        # `ahead`: the dispatch went out before the step before it was
+        # fetched; the first has none before it, the last no dispatch
+        assert step.attrs == ({"active": 1} if last else
+                              {"active": 1, "ahead": int(not first)})
+        if last:
+            continue
+        exe_step, = _children(spans, inner[0])
         assert exe_step.name == "executor/step"
         assert [k.name for k in sorted(_children(spans, exe_step),
                                        key=lambda s: s.start)] \
             == EXECUTOR_PHASES
-    feeds = _named(spans, "generation/decode_feeds")[0]
+    feeds = _named(spans, "generation/decode_feeds")
     book = _named(spans, "generation/book_tokens")
-    assert feeds.attrs == {"active": 1}
+    assert [f.attrs for f in feeds] == [{"active": 1}] * 4 + [{"active": 0}]
     assert book[0].attrs == {"tokens": 1, "finished": 0}
     assert book[-1].attrs == {"tokens": 1, "finished": 1}
     assert _named(spans, "generation/claim")[0].attrs == {"claimed": 1}
@@ -149,15 +160,21 @@ def test_existing_spans_keep_start_end_and_attributes(traced):
     assert prepare.end <= prefill.start and prefill.end <= fetch.start
     exe_step, = _children(spans, prefill)
     assert exe_step.name == "executor/step"
-    for step in _named(spans, "generation/decode_step"):
-        assert step.attrs == {"active": 1}
-        kids = _children(spans, step)
-        assert len(kids) == 2 and all(_inside(k, step) for k in kids)
-    feeds = _named(spans, "generation/decode_feeds")
     steps = _named(spans, "generation/decode_step")
+    for step in steps:
+        assert set(step.attrs) <= {"active", "ahead"}
+        assert step.attrs["active"] == 1
+        kids = _children(spans, step)
+        assert 1 <= len(kids) <= 2 and all(_inside(k, step) for k in kids)
+    feeds = _named(spans, "generation/decode_feeds")
     books = _named(spans, "generation/book_tokens")
-    for f, s, b in zip(feeds, steps, books):
-        assert f.end <= s.start and s.end <= b.start
+    # a pass builds its feeds, then talks to the device, then books the
+    # step it fetched: the first pass has nothing to book yet
+    for f, s in zip(feeds, steps):
+        assert f.end <= s.start
+    for s, b in zip(steps[1:], books):
+        assert s.end <= b.start and _named(_children(spans, s),
+                                           "generation/token_fetch")
 
 
 def test_dense_prefill_span_still_holds_its_fetch():
@@ -187,14 +204,16 @@ def test_dense_prefill_span_still_holds_its_fetch():
 def test_wait_work_span_only_when_the_loop_waited(engine):
     pt.set_flags({"FLAGS_telemetry": True})
     engine.generate(PROMPT, 2, timeout=120)     # leaves the loop idle
+    time.sleep(0.1)     # ... once the pass that resolved it has closed
     telemetry.clear_spans()
     engine.generate(PROMPT, 3, timeout=120)
-    spans = _spans_after(2)
+    spans = _spans_after(3)
     waits = _named(spans, "generation/wait_work")
     iters = _named(spans, "generation/iteration")
     # the idle stretch before the request is one span, however many
-    # 20 ms polls it took; back-to-back iterations record none
-    assert len(waits) == 1 and len(iters) == 2
+    # 20 ms polls it took; back-to-back iterations record none (two
+    # decode steps are three passes: dispatch, dispatch + settle, settle)
+    assert len(waits) == 1 and len(iters) == 3
     assert waits[0].attrs == {"queued": 0}
     assert waits[0].parent_id is None
     assert waits[0].end <= min(it.start for it in iters)
@@ -235,7 +254,7 @@ def test_iteration_host_ms_is_the_iteration_less_its_device_waits(engine):
     before = hist.summary()
     telemetry.clear_spans()
     engine.generate(PROMPT, 4, timeout=120)
-    spans = _spans_after(3)
+    spans = _spans_after(4)
     after = hist.summary()
     iters = _named(spans, "generation/iteration")
     assert after["count"] - before["count"] == len(iters)
