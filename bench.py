@@ -72,7 +72,7 @@ Config via env: BENCH_SEQ (128|512), BENCH_BATCH (per-chip),
 BENCH_ATTN (unfused|xla|pallas), BENCH_LEGS=0 to skip the seq-512 leg,
 BENCH_DROPOUT, BENCH_DISPATCH.
 Serving-tier legs each gate on their own env switch (BENCH_SERVING,
-BENCH_RECSYS, BENCH_SHARDED, BENCH_ROUTER, BENCH_DECODE, BENCH_PAGED,
+BENCH_RECSYS, BENCH_SHARDED, BENCH_ROUTER, BENCH_DECODE,
 BENCH_SPEC, BENCH_DISAGG, BENCH_CHAOS, BENCH_ROLLOUT — 0 skips).
 The fleet legs (router, chaos, rollout) spawn replica processes that
 each need a chip, so they run first, before this process touches JAX;
@@ -1380,177 +1380,9 @@ def run_decode():
     }
 
 
-# ---------------------------------------------------------------------------
-# Paged-decode leg: block-paged KV cache vs dense on a shared-prompt chat
-# workload — concurrent sequences per GB of pool, tokens/sec, inter-token p99
-# ---------------------------------------------------------------------------
-
-def run_paged_decode():
-    """Paged-vs-dense A/B (`legs.llama_paged_decode`) on the
-    shared-system-prompt chat workload (every prompt = one fixed
-    header + a random tail, chat-style bimodal output lengths).
-
-    Both engines run the SAME model and an (approximately) EQUAL KV
-    byte budget; the dense engine's concurrency is capped by
-    ``bytes / (max_seq worst case)`` while the paged engine's is
-    capped by LIVE tokens, so the headline ratio is **concurrent
-    sequences per GB of KV pool** (peak concurrently-active sequences
-    over allocated cache bytes, paged / dense — the ISSUE 11 >= 2x
-    bar).  Also published: tokens/sec (value), p99 inter-token latency
-    (the decode-step histogram p99 — each grid step emits one token
-    per active sequence), and the prefix-index hit rate on the shared
-    header (floor gated in tools/perf_gate.py: the reuse machinery
-    must actually fire on the workload built to exercise it).  Sized
-    by BENCH_PAGED_{VOCAB,HIDDEN,LAYERS,HEADS,KV_HEADS,INTER,SLOTS,
-    DENSE_SLOTS,MAX_SEQ,PAGE_TOKENS,PAGES,CHUNK,PREFIX,TAIL_MAX,
-    REQUESTS,OUT_MEAN,OUT_MAX,ROUNDS,HIT_FLOOR}."""
-    from paddle_tpu.serving import GenerationEngine
-
-    lg = _load_serving_loadgen()
-    env = os.environ.get
-    vocab = int(env("BENCH_PAGED_VOCAB", "256"))
-    hidden = int(env("BENCH_PAGED_HIDDEN", "64"))
-    layers_n = int(env("BENCH_PAGED_LAYERS", "2"))
-    heads = int(env("BENCH_PAGED_HEADS", "4"))
-    kv_heads = int(env("BENCH_PAGED_KV_HEADS", str(heads)))
-    inter = int(env("BENCH_PAGED_INTER", str(2 * hidden)))
-    # equal-byte A/B: dense reserves slots*max_seq token rows; the
-    # paged pool gets the same row count (+1 trash page) but 4x the
-    # slots — short chat turns only occupy their live pages, so the
-    # same bytes hold ~4x the concurrent sequences
-    dense_slots = int(env("BENCH_PAGED_DENSE_SLOTS", "4"))
-    paged_slots = int(env("BENCH_PAGED_SLOTS", "16"))
-    max_seq = int(env("BENCH_PAGED_MAX_SEQ", "256"))
-    page_tokens = int(env("BENCH_PAGED_PAGE_TOKENS", "16"))
-    num_pages = int(env("BENCH_PAGED_PAGES",
-                        str(dense_slots * max_seq // page_tokens + 1)))
-    chunk = int(env("BENCH_PAGED_CHUNK", "32"))
-    prefix_tokens = int(env("BENCH_PAGED_PREFIX", "64"))
-    tail_max = int(env("BENCH_PAGED_TAIL_MAX", "8"))
-    n_req = int(env("BENCH_PAGED_REQUESTS", "48"))
-    out_mean = float(env("BENCH_PAGED_OUT_MEAN", "16"))
-    out_max = int(env("BENCH_PAGED_OUT_MAX", "48"))
-    rounds = int(env("BENCH_PAGED_ROUNDS", "3"))
-    hit_floor = float(env("BENCH_PAGED_HIT_FLOOR", "0.3"))
-    model = dict(vocab_size=vocab, hidden=hidden, num_layers=layers_n,
-                 num_heads=heads, num_kv_heads=kv_heads,
-                 intermediate=inter)
-    make_prompt = lg.prompt_maker(vocab, 4, tail_max, out_mean,
-                                  out_max, dist="bimodal",
-                                  prompt_dist="shared-prefix",
-                                  prefix_tokens=prefix_tokens)
-
-    def one_mode(paged):
-        kw = {}
-        slots = dense_slots
-        if paged:
-            slots = paged_slots
-            kw = dict(paged=True, page_tokens=page_tokens,
-                      num_pages=num_pages, prefill_chunk=chunk,
-                      prefix_reuse=True)
-        eng = GenerationEngine(model, num_slots=slots,
-                               max_seq_len=max_seq,
-                               max_new_tokens=out_max,
-                               queue_cap=4 * n_req,
-                               deadline_ms=600000.0, **kw)
-        eng.warmup()
-        try:
-            reps = [lg.run_closed_loop_generate(eng, make_prompt,
-                                                n_req,
-                                                concurrency=2 * slots)
-                    for _ in range(rounds)]
-            st = eng.stats()
-            extras = {
-                "kv_cache_bytes": eng.kv_cache_bytes,
-                "peak_active": st["peak_active_slots"],
-                "p99_step_ms": st["decode_step_ms"].get("p99"),
-                "prefill_ms_mean": st["prefill_ms"].get("mean"),
-                "prefix_hit_rate":
-                    (st["paged"] or {}).get("prefix_hit_rate")
-                    if paged else None,
-                "prefill_chunks": st["counters"]["prefill_chunks"],
-                "prefix_tokens_saved":
-                    st["counters"]["prefix_tokens_saved"],
-            }
-        finally:
-            eng.close()
-        return reps, extras
-
-    import jax
-
-    device = jax.devices()[0]
-    dense_reps, dense_x = one_mode(False)
-    paged_reps, paged_x = one_mode(True)
-    rates = [r["tokens_per_sec"] for r in paged_reps]
-    dense_rates = [r["tokens_per_sec"] for r in dense_reps]
-    tps = float(np.median(rates))
-    tps_dense = float(np.median(dense_rates))
-    gib = 1024.0 ** 3
-
-    def seq_per_gb(x):
-        return x["peak_active"] / (x["kv_cache_bytes"] / gib)
-
-    spg_paged, spg_dense = seq_per_gb(paged_x), seq_per_gb(dense_x)
-    paged_rep = paged_reps[
-        rates.index(sorted(rates)[len(rates) // 2])]
-    dense_rep = dense_reps[
-        dense_rates.index(sorted(dense_rates)[len(dense_rates) // 2])]
-    return {
-        "metric": chip_name(
-            "llama_paged_decode_tokens_per_sec_per_chip", device),
-        "value": round(tps, 2),
-        "unit": chip_name("tokens/sec/chip", device),
-        "device_kind": getattr(device, "device_kind", str(device)),
-        "stats": {
-            "rounds": rounds,
-            "median": round(tps, 2),
-            "p10": round(float(np.percentile(rates, 10)), 2),
-            "p90": round(float(np.percentile(rates, 90)), 2),
-            "min": round(min(rates), 2),
-            "max": round(max(rates), 2),
-        },
-        "dense_tokens_per_sec": round(tps_dense, 2),
-        "paged_vs_dense_tokens": round(tps / max(tps_dense, 1e-9), 3),
-        "seq_per_gb": round(spg_paged, 1),
-        "dense_seq_per_gb": round(spg_dense, 1),
-        "seq_per_gb_vs_dense": round(
-            spg_paged / max(spg_dense, 1e-9), 3),
-        "prefix_hit_rate": paged_x["prefix_hit_rate"],
-        "prefix_hit_floor": hit_floor,
-        "prefix_tokens_saved": paged_x["prefix_tokens_saved"],
-        "prefill_chunks": paged_x["prefill_chunks"],
-        "p99_intertoken_ms": paged_x["p99_step_ms"],
-        "dense_p99_intertoken_ms": dense_x["p99_step_ms"],
-        # the prefix-reuse win in its purest form: mean per-request
-        # prefill wall time — a hit replaces the header's causal pass
-        # with a page-table mapping, so paged << dense here even on a
-        # compute-saturated CPU host where tokens/sec stays near parity
-        "prefill_ms_mean": paged_x["prefill_ms_mean"],
-        "dense_prefill_ms_mean": dense_x["prefill_ms_mean"],
-        "p99_ms": paged_rep["latency_ms"].get("p99"),
-        "dense_p99_ms": dense_rep["latency_ms"].get("p99"),
-        "kv_pool_bytes": paged_x["kv_cache_bytes"],
-        "dense_kv_bytes": dense_x["kv_cache_bytes"],
-        "peak_active": paged_x["peak_active"],
-        "dense_peak_active": dense_x["peak_active"],
-        "closed": paged_rep,
-        "dense": dense_rep,
-        "config": {"vocab": vocab, "hidden": hidden,
-                   "layers": layers_n, "heads": heads,
-                   "kv_heads": kv_heads, "inter": inter,
-                   "dense_slots": dense_slots,
-                   "paged_slots": paged_slots, "max_seq": max_seq,
-                   "page_tokens": page_tokens, "num_pages": num_pages,
-                   "chunk": chunk, "prefix_tokens": prefix_tokens,
-                   "tail_max": tail_max, "requests": n_req,
-                   "out_mean": out_mean, "out_max": out_max,
-                   "rounds": rounds},
-    }
-
-
 def run_spec_decode():
     """Speculative-vs-plain decode A/B (`legs.llama_spec_decode`):
-    the SAME paged engine config (slots/pages/prefix reuse all equal)
+    the SAME engine config (slots/pages/prefix reuse all equal)
     run twice per workload, differing only in ``speculate`` — the
     n-gram self-drafter + one-chunk verifier vs the one-token grid
     step.  Greedy argmax acceptance is bit-exact, so this leg gates
@@ -1618,8 +1450,8 @@ def run_spec_decode():
     }
 
     def one_mode(speculate, make_prompt):
-        kw = dict(paged=True, page_tokens=page_tokens,
-                  num_pages=num_pages, prefix_reuse=True)
+        kw = dict(page_tokens=page_tokens, num_pages=num_pages,
+                  prefix_reuse=True)
         if speculate:
             kw.update(speculate=True, spec_tokens=spec_tokens,
                       spec_ngram=spec_ngram)
@@ -1810,8 +1642,7 @@ def run_disagg():
                                   long_tokens=long_tokens)
     kw = dict(num_slots=slots, max_seq_len=max_seq,
               max_new_tokens=out_max, queue_cap=4 * n_req,
-              deadline_ms=600000.0, paged=True,
-              page_tokens=page_tokens, prefill_chunk=chunk,
+              deadline_ms=600000.0, page_tokens=page_tokens, prefill_chunk=chunk,
               prefix_reuse=False)
 
     def build(role):
@@ -2293,12 +2124,8 @@ def main():
     # BASELINE config
     if wanted("BENCH_DECODE"):
         legs["llama_decode"] = run_decode()
-    # paged-decode leg: block-paged KV cache vs dense on the
-    # shared-system-prompt chat workload
-    if wanted("BENCH_PAGED"):
-        legs["llama_paged_decode"] = run_paged_decode()
     # speculative-decode leg: n-gram self-drafts + one-chunk
-    # verification vs plain paged decode
+    # verification vs plain decode
     if wanted("BENCH_SPEC"):
         legs["llama_spec_decode"] = run_spec_decode()
     # disaggregated prefill/decode A/B on the mixed workload

@@ -335,7 +335,7 @@ def serve_phase(cfg=SERVE, on_chip=True):
     gen = GenerationEngine(
         model, num_slots=cfg["slots"], max_seq_len=cfg["max_seq"],
         prefill_buckets=cfg["prefill_buckets"], max_new_tokens=new,
-        paged=True, page_tokens=cfg["page_tokens"], prefill_chunk=0,
+        page_tokens=cfg["page_tokens"], prefill_chunk=0,
         prefix_reuse=False, attn_impl="auto", keep_logits=True, seed=0,
         deadline_ms=600000.0)
     engine = ServingEngine(predictor, workers=1, max_batch=8,

@@ -157,22 +157,16 @@ register_flag("FLAGS_serving_max_new_tokens", 64,
               "tokens (a request's own max_new_tokens wins; a budget "
               "beyond the cache capacity left after the prompt decodes "
               "until the slot cache fills and finishes 'cache_full')")
-register_flag("FLAGS_serving_paged", False,
-              "generation engine: block-paged KV cache (vLLM-style "
-              "fixed-size pages + per-slot block tables) instead of the "
-              "dense per-slot [slots, n_kv, max_seq_len, D] reservation "
-              "— concurrency is bounded by LIVE tokens, not worst-case "
-              "sequence length; paged decode is bit-exact vs dense "
-              "(paddle_tpu/serving/generation.py).  0 keeps the dense "
-              "cache (the measured fallback)")
 register_flag("FLAGS_serving_kv_page_tokens", 16,
-              "paged KV cache: tokens per page (power of two dividing "
+              "generation engine's block-paged KV cache (fixed-size "
+              "pages + per-slot block tables, so concurrency is bounded "
+              "by LIVE tokens): tokens per page (power of two dividing "
               "FLAGS_serving_max_seq_len); smaller pages waste less on "
               "short sequences but deepen the per-slot block table")
 register_flag("FLAGS_serving_kv_pages", 0,
               "paged KV cache: physical pages in the per-layer pool "
               "(page 0 is the reserved trash page garbage writes are "
-              "redirected to); 0 = auto-size to the dense capacity "
+              "redirected to); 0 = auto-size to every slot's worst case "
               "(slots * max_seq_len / page_tokens + 1) — the pool HBM "
               "footprint is pages * layers * 2 * n_kv_heads * "
               "page_tokens * head_dim * 4 bytes")
@@ -182,7 +176,7 @@ register_flag("FLAGS_serving_prefill_chunk", 0,
               "interleaved with decode steps (SarathiServe-style "
               "chunked prefill), so a long prompt no longer stalls the "
               "whole grid's inter-token latency; 0 = whole-prompt "
-              "prefill (the bit-exact-vs-dense path)")
+              "prefill")
 register_flag("FLAGS_serving_prefix_reuse", True,
               "paged generation: hash page-aligned prompt-prefix chunks "
               "(system prompts, few-shot headers) and map index hits "
@@ -200,7 +194,7 @@ register_flag("FLAGS_serving_speculate", False,
               "accepted — bit-exact vs plain greedy decode, token-for-"
               "token and logit-for-logit.  Rejected draft tokens roll "
               "their provisionally-written KV pages back through the "
-              "refcounted pool.  Requires FLAGS_serving_paged=1")
+              "refcounted pool")
 register_flag("FLAGS_serving_spec_tokens", 4,
               "speculative decoding: maximum draft tokens proposed per "
               "slot per verify (the verify chunk scores draft+1 rows); "
@@ -218,8 +212,7 @@ register_flag("FLAGS_serving_role", "both",
               "'prefill' (runs paged prefill and exports each prompt's "
               "populated pages as a KVSegment, never occupies a decode "
               "slot), 'decode' (accepts segments via adopt()/POST "
-              "/adopt and runs only the decode grid).  Non-'both' "
-              "roles require FLAGS_serving_paged=1")
+              "/adopt and runs only the decode grid)")
 register_flag("FLAGS_disagg_reprefill", False,
               "disaggregated routing: when the cache-holding decode "
               "replica dies mid-generation the router fails the "

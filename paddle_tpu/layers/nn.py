@@ -21,7 +21,7 @@ __all__ = [
     "one_hot", "topk", "flatten", "l2_normalize", "label_smooth", "maxout",
     "soft_relu", "log_loss", "clip", "clip_by_norm", "mean", "pad",
     "adaptive_pool2d", "flash_attention", "flash_attention_qkv",
-    "rms_norm", "rope", "kv_cache_write", "kv_cache_insert",
+    "rms_norm", "rope",
     "cached_attention", "kv_pool_write", "kv_pool_gather",
     "paged_decode_attention",
     "linear_chain_crf", "crf_decoding", "warpctc",
@@ -626,45 +626,16 @@ def rope(x, base=10000.0, position_offset=0, offset=None, name=None):
     return out
 
 
-def kv_cache_write(cache, new, positions, name=None):
-    """Write the step's fresh K/V rows into a persistent decode cache
-    **in place**: ``cache`` [B, Hkv, S_max, D] gets ``new`` [B, Hkv, T,
-    D] at per-row seq offset ``positions`` [B].  The op's output is the
-    cache variable itself, so the executor classifies the cache as
-    mutated persistable state → donated buffer (HBM reused, no copy).
-    Returns the cache Variable (now carrying the updated value in the
-    lowered graph)."""
-    helper = LayerHelper("kv_cache_write", name=name)
-    helper.append_op("kv_cache_write",
-                     inputs={"Cache": [cache], "New": [new],
-                             "Positions": [positions]},
-                     outputs={"Out": [cache]})
-    return cache
-
-
-def kv_cache_insert(cache, new, slot, name=None):
-    """Prefill-time cache population, in place: ``cache`` [slots, Hkv,
-    S_max, D] gets ``new`` [1, Hkv, S_b, D] at slot index ``slot``
-    ([1] int32 Variable), seq offset 0.  Like :func:`kv_cache_write`,
-    the output aliases the cache variable so the executor donates the
-    buffer.  Returns the cache Variable."""
-    helper = LayerHelper("kv_cache_insert", name=name)
-    helper.append_op("kv_cache_insert",
-                     inputs={"Cache": [cache], "New": [new],
-                             "Slot": [slot]},
-                     outputs={"Out": [cache]})
-    return cache
-
-
 def kv_pool_write(pool, new, positions, block_table, lengths,
                   name=None):
     """Paged-cache write, in place: ``pool`` [P, Hkv, pt, D] gets row
     (b, t) of ``new`` [B, Hkv, T, D] at logical position
     ``positions[b] + t`` of slot b, routed through ``block_table``
     [B, NP] to a physical page; rows with ``t >= lengths[b]`` go to
-    the reserved trash page 0.  Like :func:`kv_cache_write`, the
-    output aliases the pool variable so the executor donates the
-    buffer.  Returns the pool Variable."""
+    the reserved trash page 0.  The op's output is the pool variable
+    itself, so the executor classifies the pool as mutated persistable
+    state → donated buffer (HBM reused, no copy).  Returns the pool
+    Variable (now carrying the updated value in the lowered graph)."""
     helper = LayerHelper("kv_pool_write", name=name)
     helper.append_op("kv_pool_write",
                      inputs={"Pool": [pool], "New": [new],
@@ -676,10 +647,10 @@ def kv_pool_write(pool, new, positions, block_table, lengths,
 
 
 def kv_pool_gather(pool, block_table, name=None):
-    """Gather a slot's pages back into the dense logical cache layout:
+    """Gather a slot's pages back into the logical cache layout:
     ``pool`` [P, Hkv, pt, D] through ``block_table`` [B, NP] ->
     [B, Hkv, NP*pt, D] (column j = logical position j, exactly what
-    :func:`cached_attention` expects from a dense cache)."""
+    :func:`cached_attention` contracts over)."""
     helper = LayerHelper("kv_pool_gather", name=name)
     out = helper.create_variable_for_type_inference(pool.dtype)
     helper.append_op("kv_pool_gather",

@@ -17,13 +17,14 @@ also runs in two KV-cache modes —
   * ``collect_kv=True`` (prefill): the post-RoPE, pre-GQA-expansion
     K/V of the whole prompt come back as extra outputs, so one forward
     populates a decode cache in one shot;
-  * ``kv_cache=(cache_k, cache_v)`` + ``positions`` (cached decode):
-    the block consumes persistent per-slot cache Variables, writes the
-    step's fresh K/V at per-row dynamic offsets (``kv_cache_write`` —
-    the op's output aliases the cache var, so the executor donates the
-    buffer and XLA updates it in place in HBM) and attends the single
-    new token over the cache (``cached_attention``) — O(1) work per
-    token instead of O(n²) over the prefix.
+  * ``kv_cache=(pool_k, pool_v)`` + ``positions`` + ``block_table``
+    (cached decode): the block consumes persistent page-pool Variables,
+    scatters the step's fresh K/V into the pages the block table names
+    (``kv_pool_write`` — the op's output aliases the pool var, so the
+    executor donates the buffer and XLA updates it in place in HBM) and
+    attends the new token over the slot's pages
+    (``paged_decode_attention``) — O(1) work per token instead of
+    O(n²) over the prefix.
 
 With an explicit ``name`` prefix every parameter gets a deterministic
 name, so the train/full-forward, prefill, and decode programs built in
@@ -84,12 +85,10 @@ def _taps_fetches(taps):
     return out
 
 
-def _kv_vars(block, name, i, shape, paged=True):
-    """Layer ``i``'s persistable K and V pools (``paged``) or dense
-    caches."""
-    stem = "pool" if paged else "cache"
+def _kv_vars(block, name, i, shape):
+    """Layer ``i``'s persistable K and V page pools."""
     return tuple(block.create_var(
-        name=f"{name}.{stem}_{kind}_{i}", persistable=True, shape=shape,
+        name=f"{name}.pool_{kind}_{i}", persistable=True, shape=shape,
         dtype="float32", stop_gradient=True) for kind in ("k", "v"))
 
 
@@ -126,20 +125,19 @@ def llama_block(x, hidden, num_heads, num_kv_heads, seq_len, head_dim,
     flash_attention op's impl switch ("auto" | "xla" | pallas bools).
 
     Cache modes (mutually exclusive):
-      * ``kv_cache=(cache_k, cache_v)`` with ``positions`` [B] int32 —
-        cached decode: returns x with the caches updated in place.
-        With ``block_table`` [B, NP] + ``kv_lengths`` [B] the caches
-        are block-paged pools [P, n_kv, page_tokens, D]: the step's
-        K/V scatter into the slots' current pages (``kv_pool_write``).
+      * ``kv_cache=(pool_k, pool_v)`` with ``positions`` [B] int32,
+        ``block_table`` [B, NP] and ``kv_lengths`` [B] — cached decode
+        over block-paged pools [P, n_kv, page_tokens, D]: the step's
+        K/V scatter into the slots' current pages (``kv_pool_write``)
+        and x comes back with the pools updated in place.
         With ``seq_len`` 1 (the decode step) the new token attends its
         slot's live pages in place (``paged_decode_attention``: a
         Pallas kernel on a TPU, held to the reference at a tolerance;
-        the gather + einsum formulation, bit-exact against dense,
-        anywhere else).  ``seq_len`` > 1 is a *prefill chunk*: S new
-        tokens starting at ``positions[b]`` attend the gathered logical
-        view plus themselves causally (``kv_pool_gather`` ->
-        ``cached_attention``, the identical einsum the dense path
-        runs — bit-exact).  The program's shape picks the path.
+        the gather + einsum formulation anywhere else).  ``seq_len`` > 1
+        is a *prefill chunk*: S new tokens starting at ``positions[b]``
+        attend the gathered logical view plus themselves causally
+        (``kv_pool_gather`` -> ``cached_attention``).  The program's
+        shape picks the path.
       * ``collect_kv=True`` — prefill: returns ``(x, k, v)`` where
         k/v are the post-RoPE [B, n_kv, S, D] cache rows.
     """
@@ -171,34 +169,25 @@ def llama_block(x, hidden, num_heads, num_kv_heads, seq_len, head_dim,
         k = layers.rope(k, base=rope_base, offset=offset)
 
     if kv_cache is not None:
-        # cached decode: write this step's K/V at each slot's position,
-        # then attend the new token(s) over the whole (updated) cache —
-        # GQA expansion happens inside cached_attention
+        # cached decode: scatter this step's K/V into the slots' pages,
+        # then attend the new token(s) over the (updated) pools — GQA
+        # expansion happens inside the attention ops.  Write-before-
+        # read makes the fresh rows visible (the mask admits
+        # j <= positions[b] + t, which includes this step's own columns)
         cache_k, cache_v = kv_cache
-        if block_table is not None:
-            # paged: scatter into the slots' pages, then attend —
-            # write-before-read makes the fresh rows visible (mask
-            # admits j <= positions[b] + t, which includes this step's
-            # own columns)
-            cache_k = layers.kv_pool_write(cache_k, k, positions,
-                                           block_table, kv_lengths)
-            cache_v = layers.kv_pool_write(cache_v, v, positions,
-                                           block_table, kv_lengths)
-            if seq_len == 1:
-                # the decode step: live pages in place
-                attn = layers.paged_decode_attention(
-                    q, cache_k, cache_v, block_table, positions, **win)
-            else:
-                # a chunk of query rows: the gathered logical view
-                gk = layers.kv_pool_gather(cache_k, block_table)
-                gv = layers.kv_pool_gather(cache_v, block_table)
-                attn = layers.cached_attention(q, gk, gv, positions,
-                                               **win)
+        cache_k = layers.kv_pool_write(cache_k, k, positions,
+                                       block_table, kv_lengths)
+        cache_v = layers.kv_pool_write(cache_v, v, positions,
+                                       block_table, kv_lengths)
+        if seq_len == 1:
+            # the decode step: live pages in place
+            attn = layers.paged_decode_attention(
+                q, cache_k, cache_v, block_table, positions, **win)
         else:
-            cache_k = layers.kv_cache_write(cache_k, k, positions)
-            cache_v = layers.kv_cache_write(cache_v, v, positions)
-            attn = layers.cached_attention(q, cache_k, cache_v,
-                                           positions, **win)
+            # a chunk of query rows: the gathered logical view
+            gk = layers.kv_pool_gather(cache_k, block_table)
+            gv = layers.kv_pool_gather(cache_v, block_table)
+            attn = layers.cached_attention(q, gk, gv, positions, **win)
     else:
         cache_k = cache_v = None
         new_k, new_v = k, v  # pre-expansion rows are what a cache stores
@@ -319,14 +308,14 @@ def build_llama_prefill(batch_size, seq_len, vocab_size=32000,
                         num_kv_heads=None, intermediate=11008,
                         name="llama", attn_impl="auto",
                         cache_slots=None, max_seq_len=None,
-                        paged=False, num_pages=None, page_tokens=None,
+                        paged=None, num_pages=None, page_tokens=None,
                         head_dim=None, rms_norm_eps=1e-6,
                         rope_base=10000.0, layer_pattern=None,
                         num_window_pages=None, keep_router_logits=False):
     """Prefill entry point: one causal forward over the (padded) prompt
     that populates a decode cache in one shot.
 
-    Sliding-window layers of a paged model keep their pages in pools of
+    Sliding-window layers keep their pages in pools of
     their own (``num_window_pages`` pages each) behind a second feed
     ``block_table_window`` [1, NP]: the engine maps only the pages the
     window still covers after the prompt, and rows of earlier pages
@@ -344,18 +333,15 @@ def build_llama_prefill(batch_size, seq_len, vocab_size=32000,
     Cache handling, two modes:
 
     * ``cache_slots``/``max_seq_len`` given (the serving engine's
-      path; requires ``batch_size == 1``): the per-layer post-RoPE K/V
-      are written **in-graph** into the shared decode cache Variables
-      ``<name>.cache_{k,v}_<i>`` at slot index feed ``slot`` [1] int32
-      — the caches are mutated persistable state, so the prefill step
-      donates them exactly like the decode step (no K/V fetch, no
-      host-side reinsert).  With ``paged=True`` the caches are the
-      block-paged pools ``<name>.pool_{k,v}_<i>`` instead and the
-      slot feed is replaced by ``block_table`` [1, NP] int32 +
-      ``prompt_len`` [1] int32 (rows past the real prompt length are
-      redirected to the trash page).  The forward itself is the SAME
-      graph either way, so paged prefill logits are bit-exact vs
-      dense.
+      path; requires ``batch_size == 1``, ``num_pages`` and
+      ``page_tokens``): the per-layer post-RoPE K/V are scattered
+      **in-graph** into the decode step's block-paged pools
+      ``<name>.pool_{k,v}_<i>`` through the feeds ``block_table``
+      [1, NP] int32 + ``prompt_len`` [1] int32 (rows past the real
+      prompt length are redirected to the trash page) — the pools are
+      mutated persistable state, so the prefill step donates them
+      exactly like the decode step (no K/V fetch, no host-side
+      reinsert).  The forward itself is the graph of the other mode.
     * omitted: per-layer ``k_i``/``v_i`` [B, n_kv, S, D] rows come
       back as extra fetches for the caller to place.
 
@@ -364,6 +350,8 @@ def build_llama_prefill(batch_size, seq_len, vocab_size=32000,
     via per-slot positions."""
     from ..framework.core import default_main_program
 
+    if paged not in (None, True):
+        raise ValueError("the dense KV cache was removed at PR 30")
     num_kv_heads = num_kv_heads or num_heads
     head_dim = head_dim or hidden // num_heads
     input_ids = layers.data("input_ids", [batch_size, seq_len],
@@ -371,7 +359,7 @@ def build_llama_prefill(batch_size, seq_len, vocab_size=32000,
     last_pos = layers.data("last_pos", [batch_size], dtype="int64",
                            append_batch_size=False)
     feeds = ["input_ids", "last_pos"]
-    slot = block_table = bt_window = prompt_len = zero_pos = None
+    block_table = bt_window = prompt_len = zero_pos = None
     windowed = window_layers(layer_pattern, num_layers)
     if cache_slots is not None:
         if batch_size != 1:
@@ -380,30 +368,24 @@ def build_llama_prefill(batch_size, seq_len, vocab_size=32000,
         if max_seq_len is None or seq_len > max_seq_len:
             raise ValueError(f"prefill bucket {seq_len} exceeds cache "
                              f"max_seq_len {max_seq_len}")
-        if paged:
-            if not num_pages or not page_tokens:
-                raise ValueError("paged prefill needs num_pages and "
-                                 "page_tokens")
-            np_slot = max_seq_len // page_tokens
-            block_table = layers.data("block_table", [1, np_slot],
-                                      dtype="int32",
-                                      append_batch_size=False)
-            prompt_len = layers.data("prompt_len", [1], dtype="int32",
-                                     append_batch_size=False)
-            feeds += ["block_table", "prompt_len"]
-            if windowed:
-                if not num_window_pages:
-                    raise ValueError("paged prefill with sliding-window "
-                                     "layers needs num_window_pages")
-                bt_window = layers.data("block_table_window",
-                                        [1, np_slot], dtype="int32",
-                                        append_batch_size=False)
-                feeds.append("block_table_window")
-            zero_pos = layers.fill_constant([1], "int32", 0)
-        else:
-            slot = layers.data("slot", [1], dtype="int32",
-                               append_batch_size=False)
-            feeds.append("slot")
+        if not num_pages or not page_tokens:
+            raise ValueError("paged prefill needs num_pages and "
+                             "page_tokens")
+        np_slot = max_seq_len // page_tokens
+        block_table = layers.data("block_table", [1, np_slot],
+                                  dtype="int32", append_batch_size=False)
+        prompt_len = layers.data("prompt_len", [1], dtype="int32",
+                                 append_batch_size=False)
+        feeds += ["block_table", "prompt_len"]
+        if windowed:
+            if not num_window_pages:
+                raise ValueError("paged prefill with sliding-window "
+                                 "layers needs num_window_pages")
+            bt_window = layers.data("block_table_window", [1, np_slot],
+                                    dtype="int32",
+                                    append_batch_size=False)
+            feeds.append("block_table_window")
+        zero_pos = layers.fill_constant([1], "int32", 0)
     x = layers.embedding(input_ids, size=[vocab_size, hidden],
                          param_attr=f"{name}.embed")
     kvs = []
@@ -435,12 +417,6 @@ def build_llama_prefill(batch_size, seq_len, vocab_size=32000,
                 layers.kv_pool_write(
                     pool, t, zero_pos,
                     bt_window if in_window else block_table, prompt_len)
-        elif slot is not None:
-            caches = _kv_vars(block, name, i, [
-                cache_slots, num_kv_heads, max_seq_len, head_dim],
-                paged=False)
-            for cache, t in zip(caches, (k, v)):
-                layers.kv_cache_insert(cache, t, slot)
         else:
             kvs.append((k, v))
     logits = _head_on_rows(x, last_pos, vocab_size, name, rms_norm_eps)
@@ -468,13 +444,13 @@ def _prefill_fetches(logits, kvs, taps, last_pos):
 def build_llama_decode(num_slots, max_seq_len, vocab_size=32000,
                        hidden=4096, num_layers=32, num_heads=32,
                        num_kv_heads=None, intermediate=11008,
-                       name="llama", paged=False, num_pages=None,
+                       name="llama", paged=None, num_pages=None,
                        page_tokens=None, head_dim=None, rms_norm_eps=1e-6,
                        rope_base=10000.0, layer_pattern=None,
                        num_window_pages=None, keep_router_logits=False):
     """Cached decode step over a fixed slot grid.
 
-    A paged model's sliding-window layers read pools of
+    Sliding-window layers read pools of
     ``num_window_pages`` pages through a feed of their own,
     ``block_tables_window`` [slots, NP] (entries left of a slot's window
     are the trash page).  Routed-expert layers add the fetch
@@ -483,67 +459,53 @@ def build_llama_decode(num_slots, max_seq_len, vocab_size=32000,
 
     Feeds: ``tokens`` [slots, 1] int64 (each slot's current token) and
     ``positions`` [slots] int32 (each slot's pre-step sequence length =
-    the cache offset this step writes at).  Per-layer cache Variables
-    ``<name>.cache_k_<i>`` / ``.cache_v_<i>`` [slots, n_kv, S_max, D]
-    are persistable read+written state — the executor donates them, so
-    every step updates the caches in place in HBM.  Fetches: ``logits``
-    [slots, V] and greedy ``next_token`` [slots] int64.
-
-    ``paged=True`` swaps the per-slot reservation for the block-paged
-    pools ``<name>.pool_{k,v}_<i>`` [num_pages, n_kv, page_tokens, D]
-    and adds feeds ``block_tables`` [slots, NP] int32 (NP =
-    max_seq_len // page_tokens) and ``live`` [slots] int32 (1 = the
-    slot decodes this step, 0 = idle — its garbage write is redirected
-    to the trash page instead of landing in a live page).  A model with
-    routed experts takes ``live`` over dense caches too: its expert
-    layers count the live slots' tokens only.
+    the logical offset this step writes at), ``block_tables``
+    [slots, NP] int32 (NP = max_seq_len // page_tokens) and ``live``
+    [slots] int32 (1 = the slot decodes this step, 0 = idle — its
+    garbage write is redirected to the trash page instead of landing in
+    a live page, and the expert layers leave it out of their counts).
+    The per-layer block-paged pools ``<name>.pool_{k,v}_<i>``
+    [num_pages, n_kv, page_tokens, D] are persistable read+written
+    state — the executor donates them, so every step updates them in
+    place in HBM.  Fetches: ``logits`` [slots, V] and greedy
+    ``next_token`` [slots] int64.
 
     Returns ``(feed_names, fetches, cache_names)``."""
     from ..framework.core import default_main_program
 
+    if paged not in (None, True):
+        raise ValueError("the dense KV cache was removed at PR 30")
+    if not num_pages or not page_tokens:
+        raise ValueError("paged decode needs num_pages and page_tokens")
     num_kv_heads = num_kv_heads or num_heads
     head_dim = head_dim or hidden // num_heads
     tokens = layers.data("tokens", [num_slots, 1], dtype="int64",
                          append_batch_size=False)
     positions = layers.data("positions", [num_slots], dtype="int32",
                             append_batch_size=False)
-    feeds = ["tokens", "positions"]
-    block_tables = bt_window = live = None
+    np_slot = max_seq_len // page_tokens
+    block_tables = layers.data("block_tables", [num_slots, np_slot],
+                               dtype="int32", append_batch_size=False)
+    live = layers.data("live", [num_slots], dtype="int32",
+                       append_batch_size=False)
+    feeds = ["tokens", "positions", "block_tables", "live"]
+    bt_window = None
     windowed = window_layers(layer_pattern, num_layers)
-    if paged:
-        if not num_pages or not page_tokens:
-            raise ValueError("paged decode needs num_pages and "
-                             "page_tokens")
-        np_slot = max_seq_len // page_tokens
-        block_tables = layers.data("block_tables", [num_slots, np_slot],
-                                   dtype="int32",
-                                   append_batch_size=False)
-        live = layers.data("live", [num_slots], dtype="int32",
-                           append_batch_size=False)
-        feeds += ["block_tables", "live"]
-        if windowed:
-            if not num_window_pages:
-                raise ValueError("paged decode with sliding-window "
-                                 "layers needs num_window_pages")
-            bt_window = layers.data("block_tables_window",
-                                    [num_slots, np_slot], dtype="int32",
-                                    append_batch_size=False)
-            feeds.append("block_tables_window")
-    elif expert_layers(layer_pattern, num_layers):
-        # dense caches need no live mask, the experts' counts do
-        live = layers.data("live", [num_slots], dtype="int32",
-                           append_batch_size=False)
-        feeds.append("live")
+    if windowed:
+        if not num_window_pages:
+            raise ValueError("paged decode with sliding-window "
+                             "layers needs num_window_pages")
+        bt_window = layers.data("block_tables_window",
+                                [num_slots, np_slot], dtype="int32",
+                                append_batch_size=False)
+        feeds.append("block_tables_window")
     block = default_main_program().global_block()
     cache_names = []
     caches = []
     for i in range(num_layers):
-        if paged:
-            shape = [num_window_pages if i in windowed else num_pages,
-                     num_kv_heads, page_tokens, head_dim]
-        else:
-            shape = [num_slots, num_kv_heads, max_seq_len, head_dim]
-        ck, cv = _kv_vars(block, name, i, shape, paged=paged)
+        ck, cv = _kv_vars(block, name, i, [
+            num_window_pages if i in windowed else num_pages,
+            num_kv_heads, page_tokens, head_dim])
         caches.append((ck, cv))
         cache_names += [ck.name, cv.name]
     x = layers.embedding(tokens, size=[vocab_size, hidden],

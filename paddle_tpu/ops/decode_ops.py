@@ -1,40 +1,31 @@
-"""Autoregressive-decode ops: per-slot KV-cache write + cached attention.
+"""Autoregressive-decode ops: block-paged KV-cache write + cached attention.
 
 New capability for the generation serving path (no reference analog —
-the reference vintage predates KV-cached LLM serving).  Two ops that
-make a decoder block's attention O(1) per step instead of O(n²) over
-the prefix:
+the reference vintage predates KV-cached LLM serving).  They make a
+decoder block's attention O(1) per step instead of O(n²) over the
+prefix, over a block-paged cache (PagedAttention, Kwon et al., SOSP
+'23): a flat per-layer pool ``[num_pages, n_kv, page_tokens, D]`` and a
+per-slot block table that maps logical page index -> physical page.
 
-* ``kv_cache_write`` — scatter the step's fresh K/V rows into a
-  persistent per-slot cache at per-row dynamic offsets
-  (``jax.lax.dynamic_update_slice`` vmapped over the slot dim).  The
-  output aliases the cache *variable name*, so the executor classifies
-  the cache as mutated persistable state → donated buffer → XLA updates
-  it in place in HBM (no [slots, H, S_max, D] copy per token).
-* ``cached_attention`` — one query step attends over the full cache
+* ``kv_pool_write`` — scatter the step's fresh K/V rows into the pages
+  the block table names.  The output aliases the pool *variable name*,
+  so the executor classifies the pool as mutated persistable state →
+  donated buffer → XLA updates it in place in HBM (no pool copy per
+  token).
+* ``kv_pool_gather`` — reconstruct a slot's logical
+  ``[B, n_kv, NP*page_tokens, D]`` cache view from its pages.
+* ``cached_attention`` — a chunk of query rows attends over that view
   with a per-row validity mask (``j <= position[b] + t``).  The
   formulation mirrors ``flash_attention impl='xla'`` exactly (same
   einsum contractions, same ``-1e30`` mask constant, same
-  ``jax.nn.softmax``), which is what makes cached decode logits
-  **bit-exact** against the uncached full forward on CPU — masked cache
-  columns contribute exact zeros, and reduction prefixes are preserved
-  across lengths (asserted in ``tests/test_generation.py``).
+  ``jax.nn.softmax``): masked columns contribute exact zeros whatever
+  garbage they hold, and cached decode logits match the uncached full
+  forward on the CPU to the accumulation order of one matmul (asserted
+  in ``tests/test_generation.py``).
 
-Both are inference-only (``grad=None``): the decode path never trains.
+All are inference-only (``grad=None``): the decode path never trains.
 
-Paged variants (``kv_pool_write`` / ``kv_pool_gather``) back the
-block-paged cache (PagedAttention, Kwon et al., SOSP '23): a flat
-per-layer pool ``[num_pages, n_kv, page_tokens, D]`` replaces the dense
-per-slot reservation, and a per-slot block table maps logical page
-index -> physical page.  ``kv_pool_gather`` reconstructs a slot's
-logical ``[B, n_kv, NP*page_tokens, D]`` cache view from its pages, so
-``cached_attention`` runs the *identical* einsum at the *identical*
-contraction length as the dense path — which is what keeps paged
-prefill chunks, verification and the CPU lowering bit-exact against
-dense (columns beyond the live length differ only in garbage the
-``-1e30`` mask turns into exact zeros either way).
-
-``paged_decode_attention`` is the paged decode step's attention (one
+``paged_decode_attention`` is the decode step's attention (one
 query token per slot).  Its contract has two halves.  On a TPU backend
 it is a Pallas kernel (``ops/pallas/paged_attention.py``) that reads
 each slot's **live** pages in place through the block table — no dense
@@ -42,7 +33,8 @@ view, no GQA expansion, no contraction over dead columns — and is held
 to the plain float32 reference at a stated tolerance (ROADMAP D1), not
 to the einsum's bits.  Everywhere else it runs the gather + einsum
 formulation above through the very same functions, so on the CPU the
-paged decode step stays bit-identical to the dense one.
+decode step, the prefill chunks and the verify program share one
+attention.
 Physical page 0 is the reserved **trash page**: rows a write must
 discard (idle slots, pad-tail rows of a chunk) are redirected there
 instead of branching, so the scatter stays a single fused op.
@@ -50,51 +42,6 @@ instead of branching, so the scatter stays a single fused op.
 from __future__ import annotations
 
 from .registry import in_var, register_op, set_out
-
-
-def _kv_write_infer(op, block):
-    c = in_var(op, block, "Cache")
-    set_out(op, block, "Out", c.shape, c.dtype)
-
-
-@register_op("kv_cache_write", infer=_kv_write_infer, grad=None,
-             stateful_outputs=("Out",))
-def _kv_cache_write(ctx, op):
-    """Cache [B, Hkv, S_max, D], New [B, Hkv, T, D], Positions [B] int —
-    write row b's T fresh rows at seq offset ``positions[b]``."""
-    import jax
-    import jax.numpy as jnp
-
-    cache = ctx.get_input(op, "Cache")
-    new = ctx.get_input(op, "New")
-    pos = ctx.get_input(op, "Positions")
-
-    def write_row(c, n, p):
-        return jax.lax.dynamic_update_slice(
-            c, n.astype(c.dtype), (jnp.int32(0), p, jnp.int32(0)))
-
-    out = jax.vmap(write_row)(cache, new, pos.astype(jnp.int32))
-    ctx.set_output(op, "Out", out)
-
-
-@register_op("kv_cache_insert", infer=_kv_write_infer, grad=None,
-             stateful_outputs=("Out",))
-def _kv_cache_insert(ctx, op):
-    """Prefill insert: Cache [slots, Hkv, S_max, D] gets New
-    [1, Hkv, S_b, D] at slot ``Slot[0]`` (seq offset 0) — the one-shot
-    cache population after a prompt's causal forward, in-graph so the
-    prefill step donates the cache buffer like the decode step does
-    (no per-layer K/V fetch + host-side reinsert)."""
-    import jax
-    import jax.numpy as jnp
-
-    cache = ctx.get_input(op, "Cache")
-    new = ctx.get_input(op, "New")
-    slot = ctx.get_input(op, "Slot").astype(jnp.int32)
-    z = jnp.int32(0)
-    out = jax.lax.dynamic_update_slice(
-        cache, new.astype(cache.dtype), (slot.reshape(()), z, z, z))
-    ctx.set_output(op, "Out", out)
 
 
 def _kv_pool_write_infer(op, block):
@@ -115,8 +62,7 @@ def _kv_pool_write(ctx, op):
     slots, the pad tail of a bucketed prefill chunk) are redirected to
     the reserved trash page 0 — one scatter, no branch on data.  The
     output aliases the pool variable name, so the executor donates the
-    buffer exactly like the dense ``kv_cache_write`` (in-place HBM
-    update)."""
+    buffer (in-place HBM update)."""
     import jax.numpy as jnp
 
     pool = ctx.get_input(op, "Pool")
@@ -186,9 +132,8 @@ def _kv_pool_gather(ctx, op):
     """Reassemble a slot's logical cache view from its pages: Pool
     [P, Hkv, pt, D] gathered through BlockTable [B, NP] ->
     [B, Hkv, NP*pt, D].  Column j of the output is logical position j
-    of slot b — the exact dense-cache layout, so the downstream
-    ``cached_attention`` einsum (and therefore its XLA reduction
-    tiling) is byte-identical to the dense path's.  Unmapped block-
+    of slot b, which is what ``cached_attention`` contracts over.
+    Unmapped block-
     table entries read the trash page; those columns sit beyond the
     slot's validity limit and mask to exact zeros."""
     import jax.numpy as jnp
@@ -204,7 +149,7 @@ def _cached_attn_infer(op, block):
 
 
 def _attend_cache(q, k, v, pos, scale=None, window=None):
-    """Q [B, H, T, D] over dense caches K/V [B, Hkv, S, D] with the
+    """Q [B, H, T, D] over the logical cache view K/V [B, Hkv, S, D] with the
     validity rule ``j <= pos[b] + t`` (and, under a sliding ``window``,
     ``j > pos[b] + t - window``): the einsum formulation."""
     import jax
@@ -278,7 +223,8 @@ def _paged_decode_attention(ctx, op):
     dense view, no GQA expansion, online softmax; it matches the einsum
     formulation to float32 rounding, not bit for bit.  Anywhere else it
     is exactly ``kv_pool_gather`` x 2 + ``cached_attention`` — the same
-    code — so the CPU lowering stays bit-identical to the dense path."""
+    code — so on the CPU the decode step and a one-row chunk agree
+    bit for bit."""
     import jax
     import jax.numpy as jnp
 

@@ -10,12 +10,12 @@ al., OSDI '22) and vLLM's KV-cache management (Kwon et al., SOSP '23)
 identified.  This module is the repo's answer:
 
 * **Fixed slot grid** — ``num_slots`` decode slots share per-layer KV
-  caches ``[slots, n_kv, max_seq_len, D]`` held as persistable
-  executor state.  The decode program writes each slot's fresh K/V at
-  its own offset and the executor *donates* the cache buffers
-  (``jax.jit donate_argnums`` via mutated-persistable classification),
-  so every step updates the caches in place in HBM — no per-token
-  cache copy, one compiled executable for the whole grid.
+  page pools ``[num_pages, n_kv, page_tokens, D]`` held as persistable
+  executor state.  The decode program writes each slot's fresh K/V
+  into the page its block table names and the executor *donates* the
+  pool buffers (``jax.jit donate_argnums`` via mutated-persistable
+  classification), so every step updates the pools in place in HBM —
+  no per-token cache copy, one compiled executable for the whole grid.
 * **Prefill/decode split** — prompts compile against shape buckets
   (powers of two, like the one-shot batcher); decode steps run the
   whole slot grid every iteration.  Idle slots compute garbage rows
@@ -27,17 +27,18 @@ identified.  This module is the repo's answer:
   slots keep generating.  ``continuous=False`` restores FIFO head-run
   static batching (claim only when every slot is idle, i.e. batch
   drain) — the measured baseline the bench leg compares against.
-* **Paged KV cache** (``FLAGS_serving_paged``, PagedAttention-style) —
-  the dense per-slot reservation strands a worst-case sequence's HBM
-  per short chat turn; paged mode swaps it for a flat per-layer pool
-  ``[num_pages, n_kv, page_tokens, D]`` plus per-slot block tables, so
-  concurrency is bounded by LIVE tokens.  :class:`PagePool` allocates
-  physical pages on demand (page 0 is the reserved trash page);
-  running out finishes the starved slot ``cache_full`` after trying to
-  evict idle prefix-index pages.  Paged decode is **bit-exact vs
-  dense** token-for-token AND logit-for-logit (``kv_pool_gather``
-  reconstructs the dense logical layout, so ``cached_attention`` runs
-  the identical einsum; asserted in ``tests/test_paged_generation.py``).
+* **Paged KV cache** (PagedAttention-style, the engine's only cache)
+  — a flat per-layer pool plus per-slot block tables, so concurrency
+  is bounded by LIVE tokens and not by a worst-case sequence per slot.
+  :class:`PagePool` allocates physical pages on demand (page 0 is the
+  reserved trash page); running out finishes the starved slot
+  ``cache_full`` after trying to evict idle prefix-index pages.  On a
+  TPU the decode step attends the live pages in place
+  (``paged_decode_attention``); on the CPU ``kv_pool_gather`` rebuilds
+  the slot's logical ``[n_kv, max_seq_len, D]`` view and
+  ``cached_attention`` contracts over it.  The plain engine answers to
+  the uncached forward (``tests/test_generation.py``,
+  ``tests/test_paged_generation.py``).
 * **Shared-prefix reuse** — :class:`PrefixIndex` hashes page-aligned
   prompt-prefix chunks (system prompts, few-shot headers); a hit maps
   the shared pages into the new slot copy-on-write (refcounted,
@@ -50,7 +51,7 @@ identified.  This module is the repo's answer:
   tail prefill rides the same chunk program with ``base`` set past the
   shared pages.
 * **Speculative decoding** (``FLAGS_serving_speculate``) — self-
-  speculation over the paged cache: a prompt-lookup drafter
+  speculation over the slot's pages: a prompt-lookup drafter
   (:func:`ngram_draft` — longest n-gram suffix match over the
   sequence's OWN prompt+generated history, no second model) proposes
   up to ``FLAGS_serving_spec_tokens`` tokens per slot per scheduler
@@ -139,8 +140,7 @@ each fails only the then-active requests),
 ``moe_tokens_dropped`` (must read 0); gauges
 ``serving_spec_acceptance_rate``,
 ``serving_slot_occupancy``,
-``serving_kv_cache_bytes`` (allocated cache capacity — the page pool
-in paged mode, the dense reservation otherwise),
+``serving_kv_cache_bytes`` (allocated cache capacity: the page pools),
 ``serving_kv_live_bytes`` (bytes of pages actually referenced by live
 sequences or the prefix index), ``serving_kv_pages_free``,
 ``serving_kv_pages_live`` (with sliding-window layers also
@@ -386,13 +386,13 @@ class _Slot:
         self.tokens: List[int] = []
         self.t_start = 0.0
         self.logits: List[np.ndarray] = []  # keep_logits only
-        self.pages: List[int] = []   # paged: block table, logical order
-        # paged, sliding-window layers: their block table, logical
+        self.pages: List[int] = []   # block table, logical order
+        # sliding-window layers: their block table, logical
         # order too; a page the window slid past is 0 (the trash page)
         self.wpages: List[int] = []
         self.router_logits: List[np.ndarray] = []  # keep_logits, experts
-        self.prefill_pos = 0         # paged: next position to prefill
-        self.hit_tokens = 0          # paged: tokens served by the index
+        self.prefill_pos = 0         # next position to prefill
+        self.hit_tokens = 0          # tokens served by the index
         self.decoding = False        # prefill complete, in the grid
         # KV page-second integration (usage ledger): page_us
         # accumulates held-pages-×-wall-time in µs, marked forward at
@@ -516,60 +516,52 @@ class GenerationEngine:
         self._build_fn_prefill = build_llama_prefill
         self._seed = seed
 
-        # paged KV cache config (None kwargs fall back to flags)
-        self.paged = bool(flag_value("FLAGS_serving_paged")
-                          if paged is None else paged)
-        self.page_tokens = 0
-        self.num_pages = 0
-        self.pages_per_slot = 0
-        self.prefill_chunk = 0
-        self.prefix_reuse = False
-        self._pool: Optional[PagePool] = None
-        self._prefix: Optional[PrefixIndex] = None
+        # page configuration (None kwargs fall back to flags)
+        if paged not in (None, True):
+            raise ValueError("the dense KV cache was removed at PR 30")
+        pt_ = int(page_tokens if page_tokens is not None
+                  else flag_value("FLAGS_serving_kv_page_tokens"))
+        if pt_ < 1 or (pt_ & (pt_ - 1)):
+            raise ValueError(f"FLAGS_serving_kv_page_tokens must be "
+                             f"a power of two, got {pt_}")
+        if self.max_seq_len % pt_:
+            # the gathered logical view is exactly max_seq_len columns
+            # wide (the contraction length of the CPU lowering and of
+            # the chunk and verify programs) — no ragged last page
+            raise ValueError(
+                f"max_seq_len {self.max_seq_len} is not a multiple "
+                f"of page_tokens {pt_}")
+        self.page_tokens = pt_
+        self.pages_per_slot = self.max_seq_len // pt_
+        auto = self.num_slots * self.pages_per_slot + 1
+        self.num_pages = int(
+            num_pages if num_pages is not None
+            else (flag_value("FLAGS_serving_kv_pages") or auto))
+        self.prefill_chunk = int(
+            prefill_chunk if prefill_chunk is not None
+            else flag_value("FLAGS_serving_prefill_chunk"))
+        self.prefix_reuse = bool(
+            prefix_reuse if prefix_reuse is not None
+            else flag_value("FLAGS_serving_prefix_reuse"))
+        self._pool = PagePool(self.num_pages)
+        self._prefix: Optional[PrefixIndex] = (
+            PrefixIndex(self._pool, pt_) if self.prefix_reuse else None)
         # sliding-window layers keep a second pool: a slot needs at most
         # window / page_tokens + 1 of its pages however long it grows
         self.num_window_pages = 0
         self.window_pages_per_slot = 0
         self._wpool: Optional[PagePool] = None
-        if self.paged:
-            pt_ = int(page_tokens if page_tokens is not None
-                      else flag_value("FLAGS_serving_kv_page_tokens"))
-            if pt_ < 1 or (pt_ & (pt_ - 1)):
-                raise ValueError(f"FLAGS_serving_kv_page_tokens must be "
-                                 f"a power of two, got {pt_}")
-            if self.max_seq_len % pt_:
-                # bit-exactness requires the gathered logical view to
-                # be exactly max_seq_len columns wide (the dense
-                # contraction length) — no ragged last page
+        if self._window_layers:
+            if self.window % pt_:
                 raise ValueError(
-                    f"max_seq_len {self.max_seq_len} is not a multiple "
+                    f"sliding window {self.window} is not a multiple "
                     f"of page_tokens {pt_}")
-            self.page_tokens = pt_
-            self.pages_per_slot = self.max_seq_len // pt_
-            auto = self.num_slots * self.pages_per_slot + 1
-            self.num_pages = int(
-                num_pages if num_pages is not None
-                else (flag_value("FLAGS_serving_kv_pages") or auto))
-            self.prefill_chunk = int(
-                prefill_chunk if prefill_chunk is not None
-                else flag_value("FLAGS_serving_prefill_chunk"))
-            self.prefix_reuse = bool(
-                prefix_reuse if prefix_reuse is not None
-                else flag_value("FLAGS_serving_prefix_reuse"))
-            self._pool = PagePool(self.num_pages)
-            if self.prefix_reuse:
-                self._prefix = PrefixIndex(self._pool, pt_)
-            if self._window_layers:
-                if self.window % pt_:
-                    raise ValueError(
-                        f"sliding window {self.window} is not a multiple "
-                        f"of page_tokens {pt_}")
-                self.window_pages_per_slot = min(
-                    self.pages_per_slot, self.window // pt_ + 1)
-                self.num_window_pages = int(
-                    num_window_pages if num_window_pages is not None
-                    else self.num_slots * self.window_pages_per_slot + 1)
-                self._wpool = PagePool(self.num_window_pages)
+            self.window_pages_per_slot = min(
+                self.pages_per_slot, self.window // pt_ + 1)
+            self.num_window_pages = int(
+                num_window_pages if num_window_pages is not None
+                else self.num_slots * self.window_pages_per_slot + 1)
+            self._wpool = PagePool(self.num_window_pages)
         # disaggregated serving role: "both" (colocated, the default)
         # runs prefill AND the decode grid; "prefill" exports each
         # prompt's populated pages as a KVSegment instead of decoding;
@@ -579,14 +571,9 @@ class GenerationEngine:
         if self.role not in ("both", "prefill", "decode"):
             raise ValueError(f"role must be both|prefill|decode, got "
                              f"{self.role!r}")
-        if self.role != "both" and not self.paged:
-            raise ValueError(
-                f"role={self.role!r} requires the paged KV cache "
-                f"(paged=True / FLAGS_serving_paged=1): the KV-segment "
-                f"handoff is page-block-based")
-        # speculative decoding (self-speculation; paged-only — the
-        # verify program scores the draft against the slot's pages and
-        # the rollback discipline IS page accounting)
+        # speculative decoding (self-speculation: the verify program
+        # scores the draft against the slot's pages and the rollback
+        # discipline IS page accounting)
         self.speculate = bool(flag_value("FLAGS_serving_speculate")
                               if speculate is None else speculate)
         self.spec_tokens = int(
@@ -596,23 +583,13 @@ class GenerationEngine:
             spec_ngram if spec_ngram is not None
             else flag_value("FLAGS_serving_spec_ngram"))
         if self.speculate:
-            if not self.paged:
-                raise ValueError(
-                    "speculate=True requires the paged KV cache "
-                    "(paged=True / FLAGS_serving_paged=1): the verify "
-                    "chunk scores drafts against the slot's pages and "
-                    "rejected tokens roll back through the page pool")
             if self.spec_tokens < 1:
                 raise ValueError(f"spec_tokens must be >= 1, got "
                                  f"{self.spec_tokens}")
             if self.spec_ngram < 1:
                 raise ValueError(f"spec_ngram must be >= 1, got "
                                  f"{self.spec_ngram}")
-        if self._wpool is None:
-            # one pool: a dense cache takes the window as a mask, and
-            # nothing below treats any layer apart
-            self._window_layers = []
-        else:
+        if self._wpool is not None:
             # two page kinds: what walks ONE block table per slot is no
             # part of this engine yet (PERF.md section 7)
             refused = [what for what, on in (
@@ -623,18 +600,16 @@ class GenerationEngine:
                  self.role != "both")) if on]
             if refused:
                 raise ValueError(
-                    f"a paged model with sliding-window layers keeps two "
+                    f"a model with sliding-window layers keeps two "
                     f"page pools (full and window) and does not support "
                     f"{', '.join(refused)}: prefix reuse, chunked "
                     f"prefill, speculation and KV-segment handoff walk "
                     f"one block table per slot")
         self._fingerprint: Optional[str] = None
-        self._paged_prefill_progs: Dict[int, tuple] = {}
         self._chunk_progs: Dict[int, tuple] = {}
         self._verify_progs: Dict[int, tuple] = {}
         self._adopt_scatter = None  # donated jit, built on first adopt
         self._prefill_rr = 0  # chunked-prefill round-robin cursor
-        self._peak_active = 0
 
         # programs + executors: decode gets its own executor so its
         # compile-cache entry (and cost/memory manifest) is isolated —
@@ -645,7 +620,7 @@ class GenerationEngine:
         self.scope = scope if scope is not None else pt.Scope()
         # mesh-partitioned decode: weights shard per `shard_rules`
         # (default serving_shard_rules — mp/ep last-dim splits) and the
-        # per-slot KV caches shard over mp on the kv-head dim.  The
+        # KV page pools shard over mp on the kv-head dim.  The
         # executor needs no mesh plumbing: committed NamedSharding
         # placements on the scope arrays drive GSPMD at jit time, and
         # the donated cache buffers stay sharded in place across steps.
@@ -727,8 +702,7 @@ class GenerationEngine:
         with pt.program_guard(main, startup):
             feeds, fetches, cache_names = build_llama_decode(
                 self.num_slots, self.max_seq_len, name=self.name,
-                paged=self.paged, num_pages=self.num_pages or None,
-                page_tokens=self.page_tokens or None,
+                num_pages=self.num_pages, page_tokens=self.page_tokens,
                 num_window_pages=self.num_window_pages or None,
                 keep_router_logits=self.keep_logits, **self.model)
         self._decode_prog = main
@@ -754,10 +728,10 @@ class GenerationEngine:
                           self._shard_rules, skip=self.cache_names)
 
     def _cache_sharding(self):
-        """KV caches [slots, n_kv, S_max, D] shard the kv-head dim over
-        ``mp`` when it divides (each device holds its heads' cache —
-        attention is per-head independent, so the contraction never
-        crosses devices); otherwise replicate."""
+        """KV page pools [pages, n_kv, page_tokens, D] shard the kv-head
+        dim over ``mp`` when it divides (each device holds its heads'
+        pages — attention is per-head independent, so the contraction
+        never crosses devices); otherwise replicate."""
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         from ..parallel.mesh import MP_AXIS, axis_size
@@ -771,12 +745,8 @@ class GenerationEngine:
         import jax
         import jax.numpy as jnp
 
-        if self.paged:
-            shape = (self.num_pages, self._n_kv, self.page_tokens,
-                     self._head_dim)
-        else:
-            shape = (self.num_slots, self._n_kv, self.max_seq_len,
-                     self._head_dim)
+        shape = (self.num_pages, self._n_kv, self.page_tokens,
+                 self._head_dim)
         # a window layer's pools are the window kind's size (cache_names
         # holds K then V, layer by layer)
         wshape = (self.num_window_pages,) + shape[1:]
@@ -786,8 +756,8 @@ class GenerationEngine:
             cache_sh, self.kv_shard_axis = self._cache_sharding()
         total = 0
         for j, n in enumerate(self.cache_names):
-            # one DISTINCT zero buffer per cache: the decode step and
-            # the prefill insert donate all caches in one call, and XLA
+            # one DISTINCT zero buffer per pool: the decode step and
+            # the prefill scatter donate all pools in one call, and XLA
             # rejects donating the same buffer twice (device_put also
             # allocates a fresh buffer per call)
             shp = wshape if j // 2 in self._window_layers else shape
@@ -796,22 +766,19 @@ class GenerationEngine:
                 n, jax.device_put(zeros, cache_sh)
                 if cache_sh is not None else zeros.copy())
             total += int(np.prod(shp)) * 4
-        # capacity actually ALLOCATED (pool in paged mode, dense
-        # reservation otherwise) — not the dense worst case
+        # capacity actually ALLOCATED (the pools, trash pages included)
         self.kv_cache_bytes = total
         # bytes one page costs across every layer's K+V pool (of its
         # kind: a window page spans the window layers only)
         layer_page = 2 * self._n_kv * self.page_tokens * self._head_dim * 4
         n_window = len(self._window_layers)
         self.page_bytes = (len(self.cache_names) // 2 - n_window) \
-            * layer_page if self.paged else 0
+            * layer_page
         self.window_page_bytes = n_window * layer_page
         telemetry.gauge_set("serving_kv_cache_bytes", total)
         self._publish_pool_gauges()
 
     def _publish_pool_gauges(self):
-        if self._pool is None:
-            return
         telemetry.gauge_set("serving_kv_pages_free",
                             self._pool.free_pages)
         telemetry.gauge_set("serving_kv_pages_live",
@@ -826,31 +793,11 @@ class GenerationEngine:
     @property
     def kv_live_bytes(self) -> int:
         """Bytes of pool pages referenced by live sequences or the
-        prefix index right now (== kv_cache_bytes for the dense
-        cache, whose reservation is always fully held)."""
-        if self._pool is None:
-            return self.kv_cache_bytes
+        prefix index right now."""
         live = self._pool.live_pages * self.page_bytes
         if self._wpool is not None:
             live += self._wpool.live_pages * self.window_page_bytes
         return live
-
-    def _prefill_prog_for(self, bucket: int):
-        import paddle_tpu as pt
-
-        entry = self._prefill_progs.get(bucket)
-        if entry is None:
-            main, startup = pt.Program(), pt.Program()
-            startup._is_startup = True
-            startup.random_seed = main.random_seed = self._seed
-            with pt.program_guard(main, startup):
-                _feeds, fetches = self._build_fn_prefill(
-                    1, bucket, name=self.name, attn_impl=self.attn_impl,
-                    cache_slots=self.num_slots,
-                    max_seq_len=self.max_seq_len,
-                    keep_router_logits=self.keep_logits, **self.model)
-            entry = self._prefill_progs[bucket] = (main, fetches)
-        return entry
 
     def _fetch_names(self, fetches) -> List[str]:
         """The fetches every run of a program takes, warm-up included
@@ -873,13 +820,12 @@ class GenerationEngine:
                        scope=self.scope, return_numpy=False)
         return dict(zip(names, outs))
 
-    def _paged_prefill_prog_for(self, bucket: int):
-        """Whole-prompt paged prefill: the dense prefill forward with
-        the K/V scattered into pages instead of a dense slot — logits
-        (and therefore token streams) bit-exact vs dense."""
+    def _prefill_prog_for(self, bucket: int):
+        """Whole-prompt prefill: the causal forward over the prompt with
+        each layer's K/V scattered into the slot's pages."""
         import paddle_tpu as pt
 
-        entry = self._paged_prefill_progs.get(bucket)
+        entry = self._prefill_progs.get(bucket)
         if entry is None:
             main, startup = pt.Program(), pt.Program()
             startup._is_startup = True
@@ -888,12 +834,12 @@ class GenerationEngine:
                 _feeds, fetches = self._build_fn_prefill(
                     1, bucket, name=self.name, attn_impl=self.attn_impl,
                     cache_slots=self.num_slots,
-                    max_seq_len=self.max_seq_len, paged=True,
+                    max_seq_len=self.max_seq_len,
                     num_pages=self.num_pages,
                     page_tokens=self.page_tokens,
                     num_window_pages=self.num_window_pages or None,
                     keep_router_logits=self.keep_logits, **self.model)
-            entry = self._paged_prefill_progs[bucket] = (main, fetches)
+            entry = self._prefill_progs[bucket] = (main, fetches)
         return entry
 
     def _chunk_prog_for(self, bucket: int):
@@ -960,17 +906,9 @@ class GenerationEngine:
     def warmup(self) -> int:
         """Compile every prefill bucket + the decode step now (off the
         request path).  Returns the number of programs compiled.
-        Paged warmup dispatches run with all-zero block tables and
-        zero valid lengths, so every write lands on the trash page."""
+        Warmup dispatches run with all-zero block tables and zero
+        valid lengths, so every write lands on the trash page."""
         compiled = 0
-        if not self.paged:
-            for b in self.prefill_buckets:
-                if b not in self._prefill_progs:
-                    self._run_prefill_program(
-                        np.zeros((b,), "int64"), b, slot=0)
-                    compiled += 1
-            self._warm_decode()
-            return compiled + 1
         np_slot = self.pages_per_slot
         if self.role == "decode":
             # a decode-role engine never prefills: the decode step
@@ -996,8 +934,8 @@ class GenerationEngine:
             return compiled + 1
         if self.prefill_chunk <= 0:
             for b in self.prefill_buckets:
-                if b not in self._paged_prefill_progs:
-                    prog, fetches = self._paged_prefill_prog_for(b)
+                if b not in self._prefill_progs:
+                    prog, fetches = self._prefill_prog_for(b)
                     feed = {"input_ids": np.zeros((1, b), "int64"),
                             "last_pos": np.zeros((1,), "int64"),
                             "block_table": np.zeros((1, np_slot), "int32"),
@@ -1424,8 +1362,6 @@ class GenerationEngine:
         if self.role == "prefill":
             raise ValueError("prefill-role engine cannot adopt "
                              "segments (it has no decode grid)")
-        if not self.paged:
-            raise ValueError("adopt() requires the paged KV cache")
         self._check_segment(segment)
         mnt = max(1, int(max_new_tokens if max_new_tokens is not None
                          else self.max_new_tokens))
@@ -1654,8 +1590,7 @@ class GenerationEngine:
             with telemetry.trace_span("generation/publish"):
                 self._sample_slot_track()
         # advance ONE pending slice per iteration, so a long prompt
-        # pays out between decode steps instead of stalling the
-        # grid — the dense path never leaves slots prefilling.
+        # pays out between decode steps instead of stalling the grid.
         # Chunked: round-robin over the prefilling slots.  Unchunked a
         # slice is a whole prompt, so slots claimed together prefill
         # first come, first served
@@ -1676,8 +1611,8 @@ class GenerationEngine:
                 # that cannot serve the prompt even with every
                 # other slot idle is a hard failure
                 self._requeue_or_fail(slot, e)
-            except Exception as e:  # noqa: BLE001 — same isolation
-                # as a dense prefill failure: this request only
+            except Exception as e:  # noqa: BLE001 — a prefill
+                # failure fails this request only
                 self._fail_request(slot, slot.req, "prefill", e)
         # speculative round first: slots whose draft verified this
         # iteration already advanced (often several tokens) and are
@@ -1707,9 +1642,8 @@ class GenerationEngine:
             return self._publish_gauges()
 
     def _begin(self, slot: _Slot, req: GenRequest):
-        """Post-claim admission work.  Dense: the whole prefill, here
-        and now.  Paged: poison/fault checks + the prefix-index
-        mapping only — the prompt itself pays out via
+        """Post-claim admission work: poison/fault checks + the
+        prefix-index mapping only — the prompt itself pays out via
         :meth:`_prefill_advance` (one slice per scheduler iteration)."""
         # the per-sequence timeline span: trace-linked root bracketing
         # claim→finish under the request's trace id, the prefill /
@@ -1732,12 +1666,6 @@ class GenerationEngine:
         slot.page_tenant = req.tenant
         if req.segment is not None:
             self._adopt_begin(slot, req)
-            return
-        if not self.paged:
-            self._prefill(slot, req)
-            slot.decoding = True
-            if req.bb is not None:
-                blackbox.request_phase(req.bb, "decoding")
             return
         kind = fault.fire("prefill")
         fault.maybe_delay(kind)
@@ -1956,19 +1884,6 @@ class GenerationEngine:
             self._publish_pool_gauges()
 
     # -- prefill ------------------------------------------------------------
-    def _run_prefill_program(self, ids: np.ndarray, bucket: int,
-                             slot: int):
-        """One causal pass over the padded prompt; the per-layer K/V
-        land in the slot's caches in-graph (donated executor state —
-        the same HBM-in-place contract as the decode step)."""
-        prog, fetches = self._prefill_prog_for(bucket)
-        padded = batcher.pad_prompt(ids, bucket)
-        return self._run_fetching(
-            self._prefill_exe, prog, fetches,
-            {"input_ids": padded[None],
-             "last_pos": np.asarray([ids.size - 1], "int64"),
-             "slot": np.asarray([slot], "int32")})
-
     def _poison_check(self, prompt: np.ndarray):
         """The generation half of the poison-input model: a prompt
         carrying the ``FLAGS_serving_poison_value`` sentinel token
@@ -2016,46 +1931,7 @@ class GenerationEngine:
         self._usage_flops[-1] = fl
         return fl
 
-    def _prefill(self, slot: _Slot, req: GenRequest):
-        t0 = time.monotonic()
-        parent = slot.span.context() if slot.span is not None else None
-        bucket = batcher.prompt_bucket_for(req.prompt.size,
-                                           self.prefill_buckets)
-        with telemetry.trace_span("generation/prefill_prepare",
-                                  parent=parent, slot=slot.idx,
-                                  bucket=bucket):
-            kind = fault.fire("prefill")
-            fault.maybe_delay(kind)
-            if kind == "fail":
-                raise fault.InjectedFault("injected prefill failure")
-            self._poison_check(req.prompt)
-        with telemetry.trace_span("generation/prefill", parent=parent,
-                                  tokens=int(req.prompt.size),
-                                  bucket=bucket, slot=slot.idx):
-            outs = self._run_prefill_program(req.prompt, bucket,
-                                             slot.idx)
-            first = self._fetch_first_token(slot, outs, parent,
-                                            int(req.prompt.size))
-        now = time.monotonic()
-        ms = (now - t0) * 1e3
-        req.prefill_ms = ms
-        req.note("prefill", now, {"tokens": int(req.prompt.size)})
-        self._t_prefill_total += ms
-        self._h_prefill.observe(ms, trace_id=req.trace_id)
-        telemetry.histogram_observe("serving_prefill_ms", ms,
-                                    trace_id=req.trace_id)
-        self._count("prefills")
-        self._count("prefill_tokens", int(req.prompt.size))
-        stat_add("serving_prefills")
-        stat_add("serving_prefill_tokens", int(req.prompt.size))
-        if req.tenant is not None:
-            usage.ledger().book(req.tenant, prefill_steps=1,
-                                flops=self._exe_flops(bucket))
-        slot.position = int(req.prompt.size)
-        slot.tokens = [first]
-        self._book_token(slot, first, now)
-
-    # -- paged prefill ------------------------------------------------------
+    # -- pages and prefill slices -------------------------------------------
     def _mark_pages(self, slot: _Slot, now: Optional[float] = None):
         """Advance the slot's KV page-second integral (µs × pages
         held) up to ``now`` — called before EVERY block-table change
@@ -2076,7 +1952,7 @@ class GenerationEngine:
         page-seconds to its tenant — this is the single exit every
         hold path (finish, fail, requeue, export, decode crash)
         funnels through."""
-        if self._pool is not None and (slot.pages or slot.wpages):
+        if slot.pages or slot.wpages:
             self._mark_pages(slot)
             self._pool.decref(slot.pages)
             if slot.wpages:
@@ -2192,12 +2068,12 @@ class GenerationEngine:
         return len(dropped)
 
     def _prefill_advance(self, slot: _Slot):
-        """One prefill slice for one paged slot: either the whole
-        prompt through the paged full-prefill program (chunking off,
-        no prefix hit — the path that is bit-exact vs dense), or the
-        next ``prefill_chunk`` tokens (or the whole prefix-hit tail)
-        through the chunk program.  The final slice yields the first
-        generated token and flips the slot into the decode grid."""
+        """One prefill slice for one slot: either the whole prompt
+        through the full-prefill program (chunking off, no prefix
+        hit), or the next ``prefill_chunk`` tokens (or the whole
+        prefix-hit tail) through the chunk program.  The final slice
+        yields the first generated token and flips the slot into the
+        decode grid."""
         req = slot.req
         prompt = req.prompt
         t0 = time.monotonic()
@@ -2210,7 +2086,7 @@ class GenerationEngine:
                                       parent=parent, slot=slot.idx,
                                       bucket=bucket):
                 self._ensure_pages(slot, n_prompt)
-                prog, fetches = self._paged_prefill_prog_for(bucket)
+                prog, fetches = self._prefill_prog_for(bucket)
                 feed = {"input_ids":
                         batcher.pad_prompt(prompt, bucket)[None],
                         "last_pos": np.asarray([n_prompt - 1], "int64"),
@@ -2221,7 +2097,7 @@ class GenerationEngine:
                         self._slot_block_table(slot, window=True)[None]
             with telemetry.trace_span("generation/prefill", parent=parent,
                                       tokens=n_prompt, bucket=bucket,
-                                      slot=slot.idx, paged=True):
+                                      slot=slot.idx):
                 outs = self._run_fetching(self._prefill_exe, prog,
                                           fetches, feed)
             req.prefill_ms += (time.monotonic() - t0) * 1e3
@@ -2315,7 +2191,7 @@ class GenerationEngine:
 
     def _complete_prefill(self, slot: _Slot, req: GenRequest, outs,
                           n_rows: int):
-        """Shared tail of every paged prefill path: book the first
+        """Shared tail of every prefill path: book the first
         generated token, publish the prompt's fully-covered pages to
         the prefix index, and enter the decode grid.  ``n_rows``: real
         rows of the program that produced ``outs`` (the whole prompt, or
@@ -2468,23 +2344,16 @@ class GenerationEngine:
         by name, unread: ``next_token`` and, where the program has
         them, ``logits``, ``expert_counts``, ``router_logits`` (the
         counts ride the token fetch: one wait for the one program)."""
-        feed = {"tokens": tokens, "positions": positions}
-        if self.paged:
-            empty = (self.num_slots, self.pages_per_slot)
-            if block_tables is None:
-                block_tables = np.zeros(empty, "int32")
-            if live is None:
-                live = np.zeros((self.num_slots,), "int32")
-            feed["block_tables"] = block_tables
-            feed["live"] = live
-            if self._wpool is not None:
-                feed["block_tables_window"] = block_tables_window \
-                    if block_tables_window is not None \
-                    else np.zeros(empty, "int32")
-        elif "live" in self._decode_feeds:
-            # dense caches under routed experts: the counts' live mask
-            feed["live"] = live if live is not None \
-                else np.zeros((self.num_slots,), "int32")
+        empty = (self.num_slots, self.pages_per_slot)
+        feed = {"tokens": tokens, "positions": positions,
+                "block_tables": block_tables if block_tables is not None
+                else np.zeros(empty, "int32"),
+                "live": live if live is not None
+                else np.zeros((self.num_slots,), "int32")}
+        if self._wpool is not None:
+            feed["block_tables_window"] = block_tables_window \
+                if block_tables_window is not None \
+                else np.zeros(empty, "int32")
         with telemetry.trace_span("generation/decode_dispatch"):
             return self._run_fetching(self._decode_exe, self._decode_prog,
                                       self._decode_fetches, feed)
@@ -2788,21 +2657,20 @@ class GenerationEngine:
                       len(s.tokens) + 1 >= s.req.max_new_tokens
                       or s.position + 1 >= self.max_seq_len))]
         self._released_in_feeds = 0
-        if self.paged:
-            # pool-exhaustion guard: a slot about to cross into an
-            # unmapped page must get one BEFORE the step (the write
-            # would land on the trash page and corrupt nothing, but
-            # the token would be attention-blind to itself); a slot
-            # the pool cannot serve even after eviction finishes
-            # cache_full with everything it generated so far (ahead of
-            # a settle it has more to come: the caller settles first)
-            for s in riding:
-                try:
-                    self._ensure_pages(s, s.position + ahead + 1)
-                except PoolExhausted:
-                    if ahead:
-                        raise
-                    self._finish(s, "cache_full")
+        # pool-exhaustion guard: a slot about to cross into an
+        # unmapped page must get one BEFORE the step (the write
+        # would land on the trash page and corrupt nothing, but
+        # the token would be attention-blind to itself); a slot
+        # the pool cannot serve even after eviction finishes
+        # cache_full with everything it generated so far (ahead of
+        # a settle it has more to come: the caller settles first)
+        for s in riding:
+            try:
+                self._ensure_pages(s, s.position + ahead + 1)
+            except PoolExhausted:
+                if ahead:
+                    raise
+                self._finish(s, "cache_full")
         active = [s for s in riding if s.req is not None]
         if not active:
             return active, None
@@ -2816,21 +2684,14 @@ class GenerationEngine:
             for s in active:
                 last[s.idx] = s.tokens[-1]
             tokens = self._host_tokens(last)
-        bt = live = btw = None
-        if self.paged:
-            bt = np.zeros((self.num_slots, self.pages_per_slot),
-                          "int32")
-            live = np.zeros((self.num_slots,), "int32")
-            if self._wpool is not None:
-                btw = np.zeros_like(bt)
-            for s in active:
-                bt[s.idx] = self._slot_block_table(s)
-                live[s.idx] = 1
-                if btw is not None:
-                    btw[s.idx] = self._slot_block_table(s, window=True)
-        elif "live" in self._decode_feeds:
-            live = np.zeros((self.num_slots,), "int32")
-            live[[s.idx for s in active]] = 1
+        bt = np.zeros((self.num_slots, self.pages_per_slot), "int32")
+        live = np.zeros((self.num_slots,), "int32")
+        btw = np.zeros_like(bt) if self._wpool is not None else None
+        for s in active:
+            bt[s.idx] = self._slot_block_table(s)
+            live[s.idx] = 1
+            if btw is not None:
+                btw[s.idx] = self._slot_block_table(s, window=True)
         return active, (tokens, positions, bt, live, btw)
 
     def _book_step(self, active, outs: dict, t0: float, t1: float):
@@ -3070,10 +2931,6 @@ class GenerationEngine:
         """Publish the per-iteration gauges; returns the active slot
         count it took them from."""
         active = len(self._active())
-        if active > self._peak_active:
-            # peak concurrency feeds the paged bench's sequences-per-GB
-            # headline, so it is tracked even with telemetry off
-            self._peak_active = active
         if not telemetry.enabled():
             return active
         telemetry.gauge_set("serving_slot_occupancy",
@@ -3124,8 +2981,7 @@ class GenerationEngine:
             "prefill_buckets": list(self.prefill_buckets),
             "kv_cache_bytes": self.kv_cache_bytes,
             "kv_live_bytes": self.kv_live_bytes,
-            "peak_active_slots": self._peak_active,
-            "paged": None if not self.paged else {
+            "paged": {
                 "page_tokens": self.page_tokens,
                 "num_pages": self.num_pages,
                 "pages_per_slot": self.pages_per_slot,

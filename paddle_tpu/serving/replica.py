@@ -204,19 +204,14 @@ def main(argv=None) -> int:
                          "'Disaggregated serving'): 'prefill' exports "
                          "KV segments from /generate, 'decode' adopts "
                          "them via POST /adopt; default follows "
-                         "FLAGS_serving_role.  Non-'both' roles force "
-                         "the paged KV cache on")
-    ap.add_argument("--gen-paged", action="store_true",
-                    help="build the generator with the paged KV cache "
-                         "(implied by --role prefill|decode)")
+                         "FLAGS_serving_role")
     ap.add_argument("--gen-page-tokens", type=int, default=None)
     ap.add_argument("--gen-pages", type=int, default=None)
     ap.add_argument("--gen-speculate", action="store_true",
                     help="enable speculative decoding on the generator "
                          "(n-gram self-drafts verified in one chunk "
-                         "call — bit-exact vs plain decode; implies "
-                         "the paged KV cache; see README 'Speculative "
-                         "decoding').  Per-request opt-out rides the "
+                         "call — bit-exact vs plain decode; see README "
+                         "'Speculative decoding').  Per-request opt-out rides the "
                          "/generate body's 'speculate' field")
     ap.add_argument("--gen-spec-tokens", type=int, default=None,
                     help="max draft tokens per verify (default "
@@ -286,11 +281,6 @@ def main(argv=None) -> int:
         from .generation import GenerationEngine
         role = args.role or str(flag_value("FLAGS_serving_role")
                                 or "both")
-        # specialized roles (and speculation's verify-against-pages
-        # contract) are page-block-based by definition: force the
-        # paged cache on even without --gen-paged
-        paged = True if (args.gen_paged or args.gen_speculate
-                         or role != "both") else None
         gen = GenerationEngine(
             dict(vocab_size=args.gen_vocab, hidden=args.gen_hidden,
                  num_layers=args.gen_layers, num_heads=args.gen_heads,
@@ -299,7 +289,7 @@ def main(argv=None) -> int:
             num_slots=args.gen_slots, max_seq_len=args.gen_max_seq,
             max_new_tokens=args.gen_max_new,
             queue_cap=args.queue_cap,
-            deadline_ms=args.deadline_ms, role=role, paged=paged,
+            deadline_ms=args.deadline_ms, role=role,
             page_tokens=args.gen_page_tokens, num_pages=args.gen_pages,
             speculate=True if args.gen_speculate else None,
             spec_tokens=args.gen_spec_tokens)

@@ -267,9 +267,9 @@ class _Replica:
         and 'decode' hops accept a specialized replica OR a 'both'
         one; None = any replica (the /predict path is role-blind).
         A 'decode' hop additionally requires the replica to be
-        adopt-capable (paged generation engine) — a dense 'both'
-        replica would 404 the /adopt, turning a valid request into a
-        client-visible error.  Capability steering is symmetric: an
+        adopt-capable (its health carries a generation engine's page
+        pool) — a 'both' replica without one would 404 the /adopt,
+        turning a valid request into a client-visible error.  Capability steering is symmetric: an
         'embedding' hop (a /predict body carrying sparse_ids) requires
         the capability — a dense replica has no sparse_ids feed and
         would 400 it — and a 'dense' hop excludes embedding replicas,

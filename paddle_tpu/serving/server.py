@@ -653,19 +653,17 @@ class _Handler(_JsonHandler):
         """One ``POST /adopt`` — body is a serialized
         :class:`~paddle_tpu.serving.disagg.KVSegment`; query args
         ``max_new_tokens`` and ``stream``.  404 when no decode-capable
-        paged generator is attached, 400 on a corrupt segment, **409**
+        generator is attached, 400 on a corrupt segment, **409**
         on a fingerprint/geometry mismatch (the router surfaces it
         verbatim — adopting would decode garbage), 503 on overload
         sheds, 500 on a decode failure.  200 (or the NDJSON stream)
         carries the same result record as ``/generate`` — ``tokens``
         is the full sequence, the segment's tokens replayed first."""
         gen = getattr(self.engine, "generator", None)
-        if gen is None or getattr(gen, "role", "both") == "prefill" \
-                or not getattr(gen, "paged", False):
+        if gen is None or getattr(gen, "role", "both") == "prefill":
             return 404, {"error": "not found",
-                         "detail": "no adopt-capable (decode-role "
-                                   "paged) generation engine "
-                                   "attached"}, None
+                         "detail": "no adopt-capable (decode-role) "
+                                   "generation engine attached"}, None
         from .disagg import KVSegment, SegmentMismatch
 
         try:

@@ -47,6 +47,57 @@ def pytest_configure(config):
         "subprocess-heavy or long-wall-clock tests")
 
 
+def assert_logits_match(got, want, what=""):
+    """``|got - want| <= 1e-5 x (max - min of want)``, elementwise: the
+    tolerance of every logits comparison that was written as tolerance
+    zero (ROADMAP D1).
+
+    XLA:CPU orders a matmul's accumulation by the batch shape, so the
+    same row computed in two batch shapes (a decode grid and a padded
+    forward, two buckets of one predictor, a batch and its bisected
+    half) differs in the last bits: the largest drift seen is 8.3e-7 on
+    a row of range 4, fifty times under this limit.  What the
+    comparisons are there to catch stays far over it: a wrong page,
+    mask or position is off by 1e-1 of the range, bfloat16 by 4e-3.
+    Token streams, page accounting, refcounts and usage sums are not
+    logits and stay exact."""
+    got, want = np.asarray(got), np.asarray(want)
+    assert got.shape == want.shape, f"{what}: {got.shape} != {want.shape}"
+    limit = 1e-5 * float(want.max() - want.min())
+    worst = float(np.abs(got - want).max()) if want.size else 0.0
+    assert worst <= limit, \
+        f"{what}: off by {worst:.3g}, over the limit {limit:.3g}"
+
+
+def uncached_logits(eng, token_ids):
+    """What the plain generation engine answers to: the uncached full
+    causal forward over ``token_ids`` on the engine's scope weights;
+    returns [S, V] logits (rows past ``len(token_ids)`` are pad
+    garbage).  It has no cache, no pages and no block tables.
+
+    The forward runs right-padded at the engine's fixed
+    ``max_seq_len`` — causality makes the pad tail inert, and the
+    fixed contraction length is the width of the decode path's
+    gathered view, which keeps the two within a matmul's accumulation
+    order of each other (:func:`assert_logits_match`)."""
+    import paddle_tpu as pt
+    from paddle_tpu.models.llama import build_llama_forward
+
+    S = eng.max_seq_len
+    assert len(token_ids) <= S
+    main, startup = pt.Program(), pt.Program()
+    startup._is_startup = True
+    with pt.program_guard(main, startup):
+        _feeds, fetches = build_llama_forward(
+            1, S, name=eng.name, attn_impl="xla", **eng.model)
+    padded = np.zeros((S,), "int64")
+    padded[:len(token_ids)] = token_ids
+    out = pt.Executor().run(
+        main, feed={"input_ids": padded[None]},
+        fetch_list=[fetches["logits"]], scope=eng.scope)
+    return out[0][0]
+
+
 def retry_flaky(retries: int = 1, delay_s: float = 2.0):
     """Bounded single-retry for tests DOCUMENTED as in-suite flakes on
     core-bound CI hosts (they pass reliably in isolation and on the

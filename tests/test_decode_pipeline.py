@@ -16,10 +16,10 @@ import time
 
 import numpy as np
 import pytest
+from conftest import uncached_logits
 
 import paddle_tpu as pt
 from paddle_tpu import fault, telemetry
-from paddle_tpu.models.llama import build_llama_forward
 from paddle_tpu.serving import GenerationEngine, RequestFailed, usage
 
 MODEL = dict(vocab_size=61, hidden=32, num_layers=2, num_heads=4,
@@ -37,19 +37,13 @@ PROMPTS = [[3, 17, 5, 40, 8, 22], [9, 1, 33, 7], [12, 50, 2, 2, 31, 6, 19],
 
 
 def _engine(kind, **kw):
-    if kind == "window":
-        return GenerationEngine(
-            WINDOW_MODEL, num_slots=3, max_seq_len=64, paged=True,
-            page_tokens=8, prefill_chunk=0, prefix_reuse=False,
-            speculate=False, attn_impl="xla", seed=0, **kw)
     return GenerationEngine(
-        MODEL, num_slots=3, max_seq_len=64, attn_impl="xla", seed=0,
-        paged=kind == "paged", **(dict(page_tokens=8, prefix_reuse=False,
-                                       prefill_chunk=0, speculate=False)
-                                  if kind == "paged" else {}), **kw)
+        WINDOW_MODEL if kind == "window" else MODEL, num_slots=3,
+        max_seq_len=64, page_tokens=8, prefill_chunk=0,
+        prefix_reuse=False, speculate=False, attn_impl="xla", seed=0, **kw)
 
 
-@pytest.fixture(scope="module", params=["paged", "dense"])
+@pytest.fixture(scope="module", params=["paged"])
 def eng(request):
     e = _engine(request.param)
     e.warmup()
@@ -100,8 +94,6 @@ def _after_tokens(n):
 
 def _pages_live(e):
     p = e.stats()["paged"]
-    if p is None:
-        return 0
     return p["pages_live"] + (p["window"]["pages_live"]
                               if p["window"] else 0)
 
@@ -135,28 +127,17 @@ def test_streams_equal_solo_streams_with_joiners_midstream(eng, solo):
     assert eng._decode_exe.cache_info()["compiled"] == 1
 
 
-@pytest.mark.parametrize("kind", ["paged", "dense", "window"])
+@pytest.mark.parametrize("kind", ["paged", "window"])
 def test_logits_under_ahead_dispatch_are_the_uncached_forwards(kind):
     """The step dispatched ahead reads its tokens from the device: its
     logits are those of a plain forward over prompt + stream, at every
-    position, on all three cache layouts (the window engine crosses its
+    position, with one page pool and with two (the window engine crosses its
     window and releases pages while it decodes)."""
-    model = WINDOW_MODEL if kind == "window" else MODEL
     e = _engine(kind, keep_logits=True)
     try:
         prompt = PROMPTS[2] + PROMPTS[4][:3]              # 10 tokens
         res = e.generate(prompt, 26, timeout=300)
-        seq = prompt + res["tokens"]
-        main, startup = pt.Program(), pt.Program()
-        startup._is_startup = True
-        with pt.program_guard(main, startup):
-            _, fetches = build_llama_forward(
-                1, e.max_seq_len, name=e.name, attn_impl="xla", **model)
-        padded = np.zeros((e.max_seq_len,), "int64")
-        padded[:len(seq)] = seq
-        ref = pt.Executor().run(main, feed={"input_ids": padded[None]},
-                                fetch_list=[fetches["logits"]],
-                                scope=e.scope)[0][0]
+        ref = uncached_logits(e, prompt + res["tokens"])
         got = np.stack(res["logits"])
         want = ref[len(prompt) - 1:len(prompt) - 1 + len(got)]
         assert np.abs(got - want).max() <= 1e-4 * np.ptp(want)
@@ -238,7 +219,7 @@ def _pick_eos(streams, rider, others):
     return None
 
 
-@pytest.mark.parametrize("kind", ["paged", "dense", "window"])
+@pytest.mark.parametrize("kind", ["paged", "window"])
 def test_eos_at_settle_discards_the_row_that_overtook_it(kind):
     e = _engine(kind)
     e.warmup()
@@ -357,7 +338,7 @@ def test_no_page_for_the_ahead_position_settles_first_and_finishes():
     finishes ``cache_full`` with all it generated, at the settle, and
     the other keeps its stream."""
     e = GenerationEngine(MODEL, num_slots=2, max_seq_len=64,
-                         attn_impl="xla", seed=0, paged=True,
+                         attn_impl="xla", seed=0,
                          page_tokens=8, num_pages=4, prefix_reuse=False,
                          prefill_chunk=0, speculate=False)
     try:
@@ -408,7 +389,7 @@ def test_decode_fault_with_a_step_in_flight_fails_active_serves_next(
 # -- (7) a weight swap and a drain settle the step in flight first -----------
 def test_weight_swap_settles_the_step_in_flight_first(paged):
     donor = GenerationEngine(MODEL, num_slots=3, max_seq_len=64,
-                             attn_impl="xla", seed=7, paged=True,
+                             attn_impl="xla", seed=7,
                              page_tokens=8, prefix_reuse=False,
                              prefill_chunk=0, speculate=False,
                              name="donor")
@@ -452,7 +433,7 @@ def test_weight_swap_settles_the_step_in_flight_first(paged):
     assert paged.generate(PROMPTS[0], 40, timeout=120)["tokens"] == want_old
 
 
-@pytest.mark.parametrize("kind", ["paged", "dense"])
+@pytest.mark.parametrize("kind", ["paged"])
 def test_close_with_drain_settles_the_step_in_flight(kind):
     e = _engine(kind)
     want = [e.generate(p, 12, timeout=120)["tokens"] for p in PROMPTS[:2]]

@@ -42,7 +42,7 @@ MODEL = dict(vocab_size=64, hidden=32, num_layers=2, num_heads=4,
              num_kv_heads=2, intermediate=64)
 KW = dict(num_slots=2, max_seq_len=32, max_new_tokens=8,
           attn_impl="xla", seed=0, queue_cap=64, deadline_ms=600000.0,
-          paged=True, page_tokens=8, prefill_chunk=0,
+          page_tokens=8, prefill_chunk=0,
           prefix_reuse=False)
 
 
@@ -230,9 +230,6 @@ def test_role_guards_and_pool_too_small():
     finally:
         tiny.close()
         pre.close()
-    # specialized roles require the paged cache
-    with pytest.raises(ValueError, match="paged"):
-        _build("prefill", paged=False)
 
 
 # ---------------------------------------------------------------------------
@@ -453,7 +450,8 @@ def test_loadgen_mixed_prompt_dist():
 
 
 def test_decode_hop_requires_adopt_capability():
-    """A dense 'both' replica must never win the adopt hop: its
+    """A 'both' replica whose health advertises no page pool (a build
+    from before the dense cache went) must never win the adopt hop: its
     /adopt answers 404, which would turn a valid /generate into a
     client-visible error (pick() filters on the paged generation
     block, not the role alone)."""

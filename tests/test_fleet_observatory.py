@@ -555,7 +555,7 @@ def test_ttft_spans_admit_to_first_token_through_chunked_prefill():
     span — claim, each chunk, and any interleaved decode work."""
     eng = GenerationEngine(TINY_LLAMA, num_slots=2, max_seq_len=64,
                            max_new_tokens=6, attn_impl="xla", seed=0,
-                           paged=True, page_tokens=8, prefill_chunk=8,
+                           page_tokens=8, prefill_chunk=8,
                            prefix_reuse=False)
     try:
         prompt = np.arange(1, 25)  # 24 tokens = 3 chunks of 8

@@ -378,8 +378,8 @@ def test_donation_use_after_alias_detected_and_clean_twin(tmp_path):
     bad = """
     from paddle_tpu import layers
 
-    def block(cache_k, k, positions):
-        layers.kv_cache_write(cache_k, k, positions)
+    def block(cache_k, k, positions, table, lens):
+        layers.kv_pool_write(cache_k, k, positions, table, lens)
         return layers.matmul(cache_k, k)   # reads the donated buffer
     """
     r = run_on(tmp_path, bad, ["donation-safety"])
@@ -389,17 +389,17 @@ def test_donation_use_after_alias_detected_and_clean_twin(tmp_path):
     good = """
     from paddle_tpu import layers
 
-    def block(cache_k, k, positions):
-        cache_k = layers.kv_cache_write(cache_k, k, positions)
+    def block(cache_k, k, positions, table, lens):
+        cache_k = layers.kv_pool_write(cache_k, k, positions, table, lens)
         return layers.matmul(cache_k, k)   # rebound: the op's output
 
-    def last_use(cache_k, k, positions):
-        out = layers.kv_cache_write(cache_k, k, positions)
+    def last_use(cache_k, k, positions, table, lens):
+        out = layers.kv_pool_write(cache_k, k, positions, table, lens)
         return out                          # donated name never read
 
-    def tuple_rebind(cache_k, cache_v, k, v, pos):
-        cache_k, cache_v = (layers.kv_cache_write(cache_k, k, pos),
-                            layers.kv_cache_write(cache_v, v, pos))
+    def tuple_rebind(cache_k, cache_v, k, v, pos, bt, ln):
+        cache_k, cache_v = (layers.kv_pool_write(cache_k, k, pos, bt, ln),
+                            layers.kv_pool_write(cache_v, v, pos, bt, ln))
         return layers.matmul(cache_k, cache_v)
     """
     r = run_on(tmp_path, good, ["donation-safety"])

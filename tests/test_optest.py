@@ -1123,13 +1123,12 @@ SKIP = {
                   "sequence_reverse", "sequence_expand_as",
                   "write_to_array", "read_from_array", "lstm_rnn",
                   "gru_rnn"]},
-    **{op: "tests/test_generation.py (kv_cache_write ragged-offset "
-       "unit; all three via cached-decode bit-exactness vs the "
-       "uncached forward, tolerance 0)" for op in [
-           "kv_cache_write", "kv_cache_insert", "cached_attention"]},
+    "cached_attention":
+        "tests/test_generation.py (cached decode vs the uncached "
+        "forward: conftest.assert_logits_match)",
     **{op: "tests/test_paged_generation.py (scatter/gather round trip "
-       "+ trash-page redirect unit; both via paged-decode "
-       "bit-exactness vs the dense cache, tolerance 0)" for op in [
+       "+ trash-page redirect unit; both via paged decode vs the "
+       "uncached forward: conftest.assert_logits_match)" for op in [
            "kv_pool_write", "kv_pool_gather"]},
     "paged_decode_attention":
         "tests/test_paged_decode_attention.py (op == the gather + "

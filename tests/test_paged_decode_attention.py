@@ -200,7 +200,7 @@ def test_decode_program_books_the_reference_and_keeps_its_bits(monkeypatch):
         startup._is_startup = True
         with pt.program_guard(main, startup):
             feeds, fetches, caches = build_llama_decode(
-                slots, max_seq, name="pda", paged=True, num_pages=pages,
+                slots, max_seq, name="pda", num_pages=pages,
                 page_tokens=page, **model)
         return main, startup, fetches, caches
 
@@ -335,7 +335,7 @@ def test_decode_step_for_a_described_v5e_reads_the_pools_in_place(
     startup._is_startup = True
     with pt.program_guard(main, startup):
         feeds, fetches, _ = build_llama_decode(
-            slots, max_seq, name="pdc", paged=True, num_pages=pages,
+            slots, max_seq, name="pdc", num_pages=pages,
             page_tokens=page, vocab_size=61, hidden=512, num_layers=layers_,
             num_heads=4, num_kv_heads=2, intermediate=128)
     before = stat_get("attention_lowered_paged_decode")

@@ -1,17 +1,22 @@
-"""Paged KV cache tests: block-paged decode bit-exactness vs the dense
-cache, shared-prefix copy-on-write reuse, page refcount lifecycle,
+"""Paged KV cache tests: block-paged decode against the uncached
+forward, shared-prefix copy-on-write reuse, page refcount lifecycle,
 chunked-prefill interleaving, and pool-exhaustion ``cache_full``.
+
+The reference roles: the plain engine (``plain_ref``: no prefix reuse,
+no chunking, no speculation) answers to the uncached forward
+(``conftest.uncached_logits``), which has no pages; every feature
+engine here answers to the plain engine on the same weights (a child
+of its scope: shared weights, pools of its own).
 
 The load-bearing contracts (ISSUE 11 acceptance):
 
-* **Bit-exact vs dense** — with chunking and prefix reuse off, the
-  paged engine's token streams AND per-step logits equal the dense
-  engine's at tolerance 0 (``np.array_equal``) on ragged concurrent
-  prompts spanning page boundaries (len = page-1 / page / page+1).
-  The mechanism: paged prefill runs the *same* forward graph as dense
-  (only the cache-insert op differs), and ``kv_pool_gather``
-  reconstructs the dense logical cache layout so ``cached_attention``
-  is the identical einsum at the identical contraction length.
+* **Routing through block tables** — the engine's token streams AND
+  per-step logits are those of the uncached forward
+  (``conftest.assert_logits_match``; tokens exactly) on ragged
+  concurrent prompts spanning page boundaries (len = page-1 / page /
+  page+1).  The mechanism: prefill scatters the forward's own K/V into
+  the slot's pages, and ``kv_pool_gather`` rebuilds the logical cache
+  layout for ``cached_attention`` at the forward's contraction length.
 * **COW isolation** — pages a prefix-index hit maps into a slot are
   never written by that slot (decode and tail-prefill writes target
   pages past the shared prefix; idle/pad writes redirect to the trash
@@ -30,6 +35,7 @@ import time
 
 import numpy as np
 import pytest
+from conftest import assert_logits_match, uncached_logits
 
 import paddle_tpu as pt
 from paddle_tpu import layers
@@ -43,34 +49,31 @@ MODEL = dict(vocab_size=61, hidden=32, num_layers=2, num_heads=4,
 PAGE = 16
 
 
+def _paged(scope=None, **kw):
+    base = dict(num_slots=3, max_seq_len=96, max_new_tokens=8,
+                keep_logits=True, attn_impl="xla", seed=0,
+                queue_cap=64, deadline_ms=600000.0,
+                page_tokens=PAGE, prefill_chunk=0, prefix_reuse=False)
+    base.update(kw)
+    return GenerationEngine(MODEL, scope=scope, **base)
+
+
 @pytest.fixture(scope="module")
-def dense_ref():
-    """Dense-cache reference engine; paged engines share its scope so
-    both sides bind identical weights."""
-    eng = GenerationEngine(MODEL, num_slots=3, max_seq_len=96,
-                           max_new_tokens=8, keep_logits=True,
-                           attn_impl="xla", seed=0, queue_cap=64,
-                           deadline_ms=600000.0, paged=False)
+def plain_ref():
+    """The plain engine every feature engine answers to.  They take a
+    child of its scope: the same weights, page pools of their own."""
+    eng = _paged()
     yield eng
     eng.close()
 
 
-def _paged(dense, **kw):
-    base = dict(num_slots=3, max_seq_len=96, max_new_tokens=8,
-                keep_logits=True, attn_impl="xla", seed=0,
-                queue_cap=64, deadline_ms=600000.0, paged=True,
-                page_tokens=PAGE, prefill_chunk=0, prefix_reuse=False)
-    base.update(kw)
-    return GenerationEngine(MODEL, scope=dense.scope, **base)
-
-
 @pytest.fixture(scope="module")
-def paged_ref(dense_ref):
-    """Module-shared paged engine (prefix reuse ON, chunking off) —
+def paged_ref(plain_ref):
+    """Module-shared engine with prefix reuse ON (chunking off) —
     one program-build cost for the bit-exactness / COW / refcount
     tests; tests needing deterministic pool counts drain the prefix
     index first via :func:`_drain_index`."""
-    eng = _paged(dense_ref, prefix_reuse=True)
+    eng = _paged(plain_ref.scope.new_scope(), prefix_reuse=True)
     yield eng
     eng.close()
 
@@ -88,7 +91,7 @@ def _drain_index(eng):
 def test_kv_pool_write_gather_roundtrip():
     """Rows land in the block-table-routed pages at the right in-page
     offsets; rows beyond Lengths redirect to the trash page; gather
-    reassembles the dense logical layout."""
+    reassembles the logical layout."""
     main, startup = pt.Program(), pt.Program()
     startup._is_startup = True
     with pt.program_guard(main, startup):
@@ -192,31 +195,30 @@ def test_prefix_index_lookup_register_evict():
 
 
 # ---------------------------------------------------------------------------
-# bit-exactness: paged == dense, tolerance 0, across page boundaries
+# bit-exactness: paged == the uncached forward, across page boundaries
 # ---------------------------------------------------------------------------
 
-def test_paged_bitexact_concurrent_ragged(dense_ref, paged_ref):
+def test_paged_bitexact_concurrent_ragged(paged_ref):
     """Prompts of page-1 / page / page+1 tokens decode CONCURRENTLY in
     the paged grid; every request's token stream and per-step logits
-    are bit-equal to the dense engine's.  (The prompts are distinct
-    randoms — no prefix hits — so this exercises the pure paged path;
-    registration alone cannot perturb streams.)"""
+    are those of its own uncached forward, which knows no pages.  (The
+    prompts are distinct randoms — no prefix hits — so this exercises
+    the pure paged path; registration alone cannot perturb streams.)"""
     eng = paged_ref
     rng = np.random.RandomState(7)
     prompts = [rng.randint(1, MODEL["vocab_size"], size=n).tolist()
                for n in (PAGE - 1, PAGE, PAGE + 1)]
     steps = [6, 5, 7]
-    fd = [dense_ref.submit(p, n) for p, n in zip(prompts, steps)]
-    rd = [f.result(120) for f in fd]
-    fp = [eng.submit(p, n) for p, n in zip(prompts, steps)]
-    rp = [f.result(120) for f in fp]
-    for a, b in zip(rd, rp):
-        assert a["tokens"] == b["tokens"]
-        assert a["finish"] == b["finish"] == "length"
-        for i, (la, lb) in enumerate(zip(a["logits"], b["logits"])):
-            assert np.array_equal(np.asarray(la), np.asarray(lb)), \
-                f"step {i}: paged logits drifted (max |d|=" \
-                f"{np.abs(np.asarray(la) - np.asarray(lb)).max()})"
+    futs = [eng.submit(p, n) for p, n in zip(prompts, steps)]
+    for prompt, n, res in zip(prompts, steps,
+                              [f.result(120) for f in futs]):
+        assert res["finish"] == "length" and len(res["tokens"]) == n
+        ref = uncached_logits(eng, prompt + res["tokens"][:-1])
+        want = ref[len(prompt) - 1:len(prompt) - 1 + n]
+        assert res["tokens"] == [int(t) for t in want.argmax(-1)]
+        for i, got in enumerate(res["logits"]):
+            assert_logits_match(got, want[i],
+                                f"step {i}: paged vs the uncached forward")
     # every slot-held page was returned: only index-registered full
     # prefix pages stay live
     st = eng.stats()["paged"]
@@ -228,10 +230,10 @@ def test_paged_bitexact_concurrent_ragged(dense_ref, paged_ref):
 # shared-prefix reuse: hits skip prefill, COW isolation holds
 # ---------------------------------------------------------------------------
 
-def test_prefix_reuse_cow_isolation(dense_ref, paged_ref):
+def test_prefix_reuse_cow_isolation(plain_ref, paged_ref):
     """Requests sharing a page-aligned system header reuse its pages:
     the borrowers skip the header's prefill (counters prove it), their
-    token streams stay bit-exact vs dense, concurrent borrowers don't
+    token streams are the plain engine's, concurrent borrowers don't
     corrupt each other, and the shared pages' raw bytes are untouched
     by the borrowers' decode writes (the COW contract)."""
     eng = paged_ref
@@ -244,7 +246,7 @@ def test_prefix_reuse_cow_isolation(dense_ref, paged_ref):
              for _ in range(3)]
     # donor run registers the header's 2 pages
     ra = eng.generate(header + tails[0], 6)
-    refs = [dense_ref.generate(header + t, 6) for t in tails]
+    refs = [plain_ref.generate(header + t, 6) for t in tails]
     assert ra["tokens"] == refs[0]["tokens"]
     assert eng.stats()["counters"]["prefix_hits"] == hits0
     # shared-page bytes before the borrowers run
@@ -270,7 +272,7 @@ def test_prefix_reuse_cow_isolation(dense_ref, paged_ref):
         "a borrower's write leaked into a shared prefix page"
 
 
-def test_refcount_release_on_reclaim(dense_ref, paged_ref):
+def test_refcount_release_on_reclaim(paged_ref):
     """Finished slots return every private page; only the prefix
     index's refs persist, and eviction releases those too."""
     eng = paged_ref
@@ -295,12 +297,13 @@ def test_refcount_release_on_reclaim(dense_ref, paged_ref):
 # chunked prefill: long prompts interleave with decode steps
 # ---------------------------------------------------------------------------
 
-def test_chunked_prefill_interleaves_decode(dense_ref):
+def test_chunked_prefill_interleaves_decode(plain_ref):
     """A long prompt pays out in chunks while a rider keeps decoding:
     decode steps advance BETWEEN chunks (one chunk per scheduler
     iteration — the inter-token-latency bound), and both streams stay
     correct."""
-    eng = _paged(dense_ref, prefill_chunk=8, max_new_tokens=64)
+    eng = _paged(plain_ref.scope.new_scope(), prefill_chunk=8,
+                 max_new_tokens=64)
     try:
         rng = np.random.RandomState(17)
         rider_prompt = rng.randint(1, MODEL["vocab_size"],
@@ -322,8 +325,8 @@ def test_chunked_prefill_interleaves_decode(dense_ref):
         # grid step, per iteration)
         assert s1["decode_steps"] - s0["decode_steps"] >= chunks - 1
         rider_res = rider_fut.result(120)
-        ref_long = dense_ref.generate(long_prompt, 4)
-        rider_ref = dense_ref.generate(rider_prompt, 36)
+        ref_long = plain_ref.generate(long_prompt, 4)
+        rider_ref = plain_ref.generate(rider_prompt, 36)
         assert long_res["tokens"] == ref_long["tokens"]
         assert rider_res["tokens"] == rider_ref["tokens"], \
             "rider stream corrupted by interleaved chunk prefill"
@@ -335,11 +338,11 @@ def test_chunked_prefill_interleaves_decode(dense_ref):
 # pool exhaustion: cache_full exactness + recovery
 # ---------------------------------------------------------------------------
 
-def test_whole_prompt_prefills_first_come_first_served(dense_ref):
+def test_whole_prompt_prefills_first_come_first_served(plain_ref):
     """Unchunked, slots claimed in one pass prefill in the order their
     requests arrived: one whole prompt an iteration, oldest first (the
     round-robin cursor is for slices of chunked prompts)."""
-    eng = _paged(dense_ref, autostart=False)
+    eng = _paged(plain_ref.scope.new_scope(), autostart=False)
     try:
         rng = np.random.default_rng(3)
         futures = [eng.submit(rng.integers(1, 61, 20).tolist(), 2)
@@ -351,16 +354,16 @@ def test_whole_prompt_prefills_first_come_first_served(dense_ref):
         eng.close()
 
 
-def test_pool_exhaustion_cache_full(dense_ref):
+def test_pool_exhaustion_cache_full(plain_ref):
     """A budget beyond the pool finishes cache_full with EXACTLY
     usable_pages * page_tokens - prompt_len + 1 tokens (every page
     filled, the +1 is the prefill's token which costs no cache row
     until the step after), and the freed pages serve the next
     request."""
-    eng = GenerationEngine(MODEL, scope=dense_ref.scope, num_slots=1,
-                           max_seq_len=96, attn_impl="xla", seed=0,
-                           queue_cap=64, deadline_ms=600000.0,
-                           paged=True, page_tokens=8, num_pages=5,
+    eng = GenerationEngine(MODEL, scope=plain_ref.scope.new_scope(),
+                           num_slots=1, max_seq_len=96, attn_impl="xla",
+                           seed=0, queue_cap=64, deadline_ms=600000.0,
+                           page_tokens=8, num_pages=5,
                            prefill_chunk=0, prefix_reuse=False)
     try:
         prompt = list(range(1, 11))          # 10 tokens
@@ -409,15 +412,15 @@ def test_loadgen_shared_prefix_prompts():
                         prompt_dist="shared-prefix", prefix_tokens=0)
 
 
-def test_pool_stall_requeues_until_pages_free(dense_ref):
+def test_pool_stall_requeues_until_pages_free(plain_ref):
     """Pool exhaustion during PREFILL while other sequences hold the
     pages is transient saturation, not a broken request: the prefill
     requeues at the queue head (`serving_kv_pool_stalls`) and succeeds
     once the live sequence finishes — zero failed requests."""
-    eng = GenerationEngine(MODEL, scope=dense_ref.scope, num_slots=2,
-                           max_seq_len=64, attn_impl="xla", seed=0,
-                           queue_cap=64, deadline_ms=600000.0,
-                           paged=True, page_tokens=8, num_pages=6,
+    eng = GenerationEngine(MODEL, scope=plain_ref.scope.new_scope(),
+                           num_slots=2, max_seq_len=64, attn_impl="xla",
+                           seed=0, queue_cap=64, deadline_ms=600000.0,
+                           page_tokens=8, num_pages=6,
                            prefill_chunk=0, prefix_reuse=False,
                            autostart=False)
     try:
@@ -433,7 +436,7 @@ def test_pool_stall_requeues_until_pages_free(dense_ref):
         eng.start()
         ra, rb = fa.result(120), fb.result(120)
         assert ra["finish"] == "length" and rb["finish"] == "length"
-        ref = dense_ref.generate(b_prompt, 4)
+        ref = plain_ref.generate(b_prompt, 4)
         assert rb["tokens"] == ref["tokens"]
         n = eng.stats()["counters"]
         assert n["pool_stalls"] >= 1
@@ -445,7 +448,12 @@ def test_pool_stall_requeues_until_pages_free(dense_ref):
 def test_paged_config_validation():
     with pytest.raises(ValueError):  # not a power of two
         GenerationEngine(MODEL, num_slots=1, max_seq_len=96,
-                         autostart=False, paged=True, page_tokens=12)
+                         autostart=False, page_tokens=12)
     with pytest.raises(ValueError):  # does not divide max_seq_len
         GenerationEngine(MODEL, num_slots=1, max_seq_len=100,
-                         autostart=False, paged=True, page_tokens=16)
+                         autostart=False, page_tokens=16)
+    # the keyword the benchmark builders still pass refuses the cache
+    # that is gone
+    with pytest.raises(ValueError, match="removed at PR 30"):
+        GenerationEngine(MODEL, num_slots=1, max_seq_len=96,
+                         autostart=False, paged=False)

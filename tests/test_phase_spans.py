@@ -31,7 +31,7 @@ EXECUTOR_PHASES = ["executor/prepare", "executor/gather_state",
 @pytest.fixture(scope="module")
 def engine():
     eng = GenerationEngine(MODEL, num_slots=2, max_seq_len=64,
-                           attn_impl="xla", seed=0, paged=True,
+                           attn_impl="xla", seed=0,
                            page_tokens=8, prefix_reuse=False)
     eng.warmup()
     yield eng
@@ -153,9 +153,9 @@ def test_existing_spans_keep_start_end_and_attributes(traced):
     prefill, = _named(spans, "generation/prefill")
     fetch, = _named(spans, "generation/prefill_fetch")
     assert prefill.attrs == {"tokens": len(PROMPT), "bucket": 16,
-                             "slot": prefill.attrs["slot"], "paged": True}
+                             "slot": prefill.attrs["slot"]}
     assert prepare.attrs == {"slot": prefill.attrs["slot"], "bucket": 16}
-    # the paged prefill span opens at the executor call and closes at
+    # the prefill span opens at the executor call and closes at
     # dispatch; the blocking read of its token is the fetch span's
     assert prepare.end <= prefill.start and prefill.end <= fetch.start
     exe_step, = _children(spans, prefill)
@@ -175,30 +175,6 @@ def test_existing_spans_keep_start_end_and_attributes(traced):
     for s, b in zip(steps[1:], books):
         assert s.end <= b.start and _named(_children(spans, s),
                                            "generation/token_fetch")
-
-
-def test_dense_prefill_span_still_holds_its_fetch():
-    """The dense engine's ``generation/prefill`` always ended after the
-    token came back; the fetch is now a named child interval of it."""
-    pt.set_flags({"FLAGS_telemetry": True})
-    eng = GenerationEngine(MODEL, num_slots=2, max_seq_len=64,
-                           attn_impl="xla", seed=0)
-    try:
-        eng.warmup()
-        telemetry.clear_spans()
-        res = eng.generate(PROMPT, 3, timeout=120)
-        spans = _spans_after(res["steps"])
-    finally:
-        eng.close()
-    prefill, = _named(spans, "generation/prefill")
-    fetch, = _named(spans, "generation/prefill_fetch")
-    prepare, = _named(spans, "generation/prefill_prepare")
-    assert _inside(fetch, prefill) and prepare.end <= prefill.start
-    assert set(prefill.attrs) == {"tokens", "bucket", "slot"}
-    # a dense prefill runs inside the claim, by time and thread
-    claim = [c for c in _named(spans, "generation/claim")
-             if c.attrs["claimed"] == 1]
-    assert len(claim) == 1 and _inside(prefill, claim[0])
 
 
 def test_wait_work_span_only_when_the_loop_waited(engine):
@@ -281,7 +257,7 @@ def test_new_histograms_on_live_metrics_pass_strict_validator():
     eng = ServingEngine(Predictor(main, ["x"], [y], scope=scope),
                         workers=1, warmup_shapes={"x": (8,)})
     gen = GenerationEngine(MODEL, num_slots=2, max_seq_len=64,
-                           attn_impl="xla", seed=0, paged=True,
+                           attn_impl="xla", seed=0,
                            page_tokens=8, prefix_reuse=False)
     eng.attach_generator(gen)
     gen.warmup()

@@ -30,6 +30,7 @@ import urllib.request
 
 import numpy as np
 import pytest
+from conftest import assert_logits_match
 
 import paddle_tpu as pt
 from paddle_tpu import fault, layers
@@ -220,10 +221,11 @@ def test_http_swap_versions_and_refusals(tmp_path):
 
 
 def test_engine_swap_under_load_never_torn(tmp_path):
-    """Swap while requests stream through: every answer must be
-    bit-exact under exactly ONE version — the pre-swap function or the
-    post-swap function, never a mix (and the engine must not shed:
-    a swap pauses, it never drops)."""
+    """Swap while requests stream through: every answer must be the
+    answer of exactly ONE version — the pre-swap function or the
+    post-swap function (``conftest.assert_logits_match``: the other
+    version's answer is 0.1 away), never a mix (and the engine must
+    not shed: a swap pauses, it never drops)."""
     eng = ServingEngine(_build_replica_predictor(seed=0), workers=2,
                         max_batch=4, max_delay_ms=1.0,
                         deadline_ms=60000.0)
@@ -239,14 +241,21 @@ def test_engine_swap_under_load_never_torn(tmp_path):
         res = eng.swap_weights(ck, timeout_s=30.0)
         futs += [eng.submit({"x": x}) for _ in range(16)]
         assert res["weights_version"] == 2
+        def answers(got, want):
+            try:
+                assert_logits_match(got, want)
+            except AssertionError:
+                return False
+            return True
+
+        assert not answers(old, new), "the swap must change the function"
         for f in futs:
             got = np.asarray(f.result(30.0)[0])
-            assert (np.array_equal(got, old)
-                    or np.array_equal(got, new)), \
+            assert answers(got, old) or answers(got, new), \
                 "torn or corrupted response across the swap boundary"
         # post-swap requests all serve the new function
         got = np.asarray(eng.submit({"x": x}).result(30.0)[0])
-        np.testing.assert_array_equal(got, new)
+        assert_logits_match(got, new, "after the swap")
     finally:
         eng.close()
 
@@ -476,7 +485,7 @@ def test_fleet_hot_swap_converges(tmp_path):
         assert [r["weights_version"] for r in rep["replicas"]] == [2, 2]
         assert all(r["swap_status"] == 200 and not r.get("fallback")
                    for r in rep["replicas"])
-        # every replica answers under the new version, bit-exactly
+        # every replica answers under the new version
         from paddle_tpu import io
         params = io._read(os.path.join(ck, "__params__"))
         x = np.linspace(-1.0, 1.0, 4, dtype="float32").reshape(1, 4)
@@ -484,8 +493,7 @@ def test_fleet_hot_swap_converges(tmp_path):
             code, doc, hdr = _post(url + "/predict",
                                    {"inputs": {"x": x.tolist()}})
             assert code == 200 and hdr[VERSION_HEADER] == "2"
-            np.testing.assert_allclose(np.asarray(doc["outputs"][0]),
-                                       _mlp_reference(params, x),
-                                       rtol=0, atol=0)
+            assert_logits_match(doc["outputs"][0],
+                                _mlp_reference(params, x), url)
     finally:
         sup.close()

@@ -64,14 +64,17 @@ def _train(is_sparse, opt_factory, steps=24, lazy=False, vocab=13, dim=4):
     lambda: pt.optimizer.AdagradOptimizer(0.1),
 ], ids=["sgd", "momentum", "adam", "adagrad"])
 def test_sparse_dense_trajectory_parity(opt):
-    dense_losses, dense_w = _train(False, opt)
-    sparse_losses, sparse_w = _train(True, opt)
+    dense_losses, dense_w = _train(False, opt, steps=64)
+    sparse_losses, sparse_w = _train(True, opt, steps=64)
     np.testing.assert_allclose(sparse_losses, dense_losses, rtol=2e-5,
                                atol=1e-6)
     np.testing.assert_allclose(sparse_w, dense_w, rtol=2e-5, atol=1e-6)
-    # window means: single-batch first-vs-last is a coin flip (each batch
-    # samples different rows of the target table)
-    assert np.mean(dense_losses[-3:]) < np.mean(dense_losses[:3])
+    # it actually trains.  Window means over a run long enough to show
+    # it: single-batch first-vs-last is a coin flip (each batch samples
+    # different rows of the target table), and at 24 steps momentum's
+    # three-step means were still level (0.0765 against 0.0853); at 64
+    # every optimizer's eight-step mean is under half of its first
+    assert np.mean(dense_losses[-8:]) < 0.5 * np.mean(dense_losses[:8])
 
 
 def test_grad_var_is_selected_rows_type():

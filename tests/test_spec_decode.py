@@ -4,8 +4,10 @@ verification, bit-exact acceptance, and rejected-draft page rollback.
 The load-bearing contracts (ISSUE 17 acceptance):
 
 * **Bit-exact vs non-speculative decode** — a speculating engine's
-  token streams AND per-step logits equal the plain engine's at
-  tolerance 0 (``np.array_equal``): verify row 0 writes exactly what
+  token streams (exactly) AND per-step logits
+  (``conftest.assert_logits_match``: a verify chunk and a grid step are
+  two batch shapes of one matmul) equal the plain engine's: verify row
+  0 writes exactly what
   the plain step writes, accepted rows replay the same argmax chain,
   and rejected rows' garbage K/V is causally masked and overwritten.
   Holds at page-boundary ±1 prompt lengths, with concurrent MIXED
@@ -20,11 +22,10 @@ The load-bearing contracts (ISSUE 17 acceptance):
 * **Opt-out** — ``submit(..., speculate=False)`` (and the HTTP
   ``"speculate"`` field) bypasses drafting per-request.
 
-All engines share the dense reference's scope: weight init depends on
-global state, so only shared-scope engines bind identical weights
-(the ``tests/test_paged_generation.py`` pattern).  Two paged engines
-sharing a scope share pool buffers — they run SEQUENTIALLY, never
-concurrently.
+All engines read the plain reference's weights: weight init depends on
+global state, so only engines on one scope bind identical weights
+(the ``tests/test_paged_generation.py`` pattern).  Each takes a child
+of that scope, so its page pools are its own.
 """
 import json
 import urllib.error
@@ -32,6 +33,7 @@ import urllib.request
 
 import numpy as np
 import pytest
+from conftest import assert_logits_match
 
 import paddle_tpu as pt
 from paddle_tpu import layers
@@ -43,26 +45,25 @@ MODEL = dict(vocab_size=61, hidden=32, num_layers=2, num_heads=4,
 PAGE = 8
 
 
-@pytest.fixture(scope="module")
-def dense_ref():
-    """Dense-cache non-speculative reference; spec engines share its
-    scope so both sides bind identical weights."""
-    eng = GenerationEngine(MODEL, num_slots=3, max_seq_len=96,
-                           max_new_tokens=8, keep_logits=True,
-                           attn_impl="xla", seed=0, queue_cap=64,
-                           deadline_ms=600000.0, paged=False)
-    yield eng
-    eng.close()
-
-
-def _spec(dense, **kw):
+def _spec(plain, **kw):
     base = dict(num_slots=3, max_seq_len=96, max_new_tokens=8,
                 keep_logits=True, attn_impl="xla", seed=0,
-                queue_cap=64, deadline_ms=600000.0, paged=True,
+                queue_cap=64, deadline_ms=600000.0,
                 page_tokens=PAGE, prefill_chunk=0, prefix_reuse=False,
                 speculate=True, spec_tokens=4, spec_ngram=3)
     base.update(kw)
-    return GenerationEngine(MODEL, scope=dense.scope, **base)
+    return GenerationEngine(
+        MODEL, scope=plain.scope.new_scope() if plain else None, **base)
+
+
+@pytest.fixture(scope="module")
+def plain_ref():
+    """The plain non-speculative reference (it answers to the uncached
+    forward in ``tests/test_generation.py``); spec engines read its
+    weights through a child of its scope."""
+    eng = _spec(None, speculate=False)
+    yield eng
+    eng.close()
 
 
 def _repetitive(rng, n, period=4):
@@ -110,7 +111,7 @@ def test_ngram_draft_degenerate_repetition():
 
 
 # ---------------------------------------------------------------------------
-# bit-exactness: speculating == plain, tolerance 0
+# bit-exactness: speculating == plain
 # ---------------------------------------------------------------------------
 
 def _assert_streams_equal(ref_results, got_results):
@@ -118,22 +119,21 @@ def _assert_streams_equal(ref_results, got_results):
         assert a["tokens"] == b["tokens"]
         assert a["finish"] == b["finish"]
         for i, (la, lb) in enumerate(zip(a["logits"], b["logits"])):
-            assert np.array_equal(np.asarray(la), np.asarray(lb)), \
-                f"step {i}: speculative logits drifted (max |d|=" \
-                f"{np.abs(np.asarray(la) - np.asarray(lb)).max()})"
+            assert_logits_match(lb, la,
+                                f"step {i}: speculative vs plain")
 
 
-def test_spec_bitexact_concurrent_ragged(dense_ref):
+def test_spec_bitexact_concurrent_ragged(plain_ref):
     """Repetitive prompts of page-1 / page / page+1 tokens decode
     concurrently with speculation on; every stream and per-step logit
-    vector is bit-equal to the dense non-speculative engine's, and the
+    vector is the plain non-speculative engine's, and the
     drafter demonstrably fired (otherwise the test is vacuous)."""
     rng = np.random.RandomState(11)
     prompts = [_repetitive(rng, n) for n in (PAGE - 1, PAGE, PAGE + 1)]
     steps = [6, 5, 7]
     rd = [f.result(120) for f in
-          [dense_ref.submit(p, n) for p, n in zip(prompts, steps)]]
-    eng = _spec(dense_ref)
+          [plain_ref.submit(p, n) for p, n in zip(prompts, steps)]]
+    eng = _spec(plain_ref)
     try:
         rs = [f.result(120) for f in
               [eng.submit(p, n) for p, n in zip(prompts, steps)]]
@@ -146,18 +146,18 @@ def test_spec_bitexact_concurrent_ragged(dense_ref):
         eng.close()
 
 
-def test_spec_bitexact_mixed_slots(dense_ref):
+def test_spec_bitexact_mixed_slots(plain_ref):
     """Speculating and per-request-opted-out slots decode CONCURRENTLY
     in one grid (the mixed-grid path: ``_decode_step(skip=...)``);
-    every stream matches dense regardless of which side of the fence
+    every stream matches plain regardless of which side of the fence
     it decoded on."""
     rng = np.random.RandomState(13)
     prompts = [_repetitive(rng, n) for n in (PAGE - 1, PAGE + 1, 12)]
     steps = [7, 6, 7]
     flags = [None, False, None]  # slot 1 opts out mid-grid
     rd = [f.result(120) for f in
-          [dense_ref.submit(p, n) for p, n in zip(prompts, steps)]]
-    eng = _spec(dense_ref)
+          [plain_ref.submit(p, n) for p, n in zip(prompts, steps)]]
+    eng = _spec(plain_ref)
     try:
         fs = [eng.submit(p, n, speculate=sp)
               for p, n, sp in zip(prompts, steps, flags)]
@@ -168,7 +168,7 @@ def test_spec_bitexact_mixed_slots(dense_ref):
         eng.close()
 
 
-def test_spec_bitexact_prefix_hits(dense_ref):
+def test_spec_bitexact_prefix_hits(plain_ref):
     """Streams riding prefix-index hits (borrowed COW pages, tail-only
     prefill) speculate bit-exactly: a plain paged engine and a
     speculating one see the same submission order, take the same index
@@ -179,7 +179,7 @@ def test_spec_bitexact_prefix_hits(dense_ref):
     steps = [6, 6, 6]
 
     def run(speculate):
-        eng = _spec(dense_ref, prefix_reuse=True, speculate=speculate)
+        eng = _spec(plain_ref, prefix_reuse=True, speculate=speculate)
         try:
             out = [eng.submit(p, n).result(120)
                    for p, n in zip(prompts, steps)]
@@ -188,8 +188,6 @@ def test_spec_bitexact_prefix_hits(dense_ref):
         finally:
             eng.close()
 
-    # sequential, never concurrent: the two paged engines share pool
-    # buffer names in the common scope
     plain, st_plain = run(False)
     spec, st_spec = run(True)
     _assert_streams_equal(plain, spec)
@@ -202,13 +200,13 @@ def test_spec_bitexact_prefix_hits(dense_ref):
 # rollback accounting
 # ---------------------------------------------------------------------------
 
-def test_spec_rollback_refcount_balance(dense_ref):
+def test_spec_rollback_refcount_balance(plain_ref):
     """Rejected drafts roll their provisional pages back: rollbacks
     fire (the tiny random model rarely follows the prompt's period),
     accepted <= proposed, and the pool drains to ZERO live pages once
     every request finishes."""
     rng = np.random.RandomState(19)
-    eng = _spec(dense_ref)
+    eng = _spec(plain_ref)
     try:
         for n in (PAGE - 1, PAGE, PAGE + 1, 12):
             eng.generate(_repetitive(rng, n), 8)
@@ -223,16 +221,16 @@ def test_spec_rollback_refcount_balance(dense_ref):
         eng.close()
 
 
-def test_spec_pool_exhaustion_mid_draft(dense_ref):
+def test_spec_pool_exhaustion_mid_draft(plain_ref):
     """A draft that cannot get pages falls through to the plain step,
     which finishes the sequence ``cache_full`` at EXACTLY the plain
     engine's truncation point with the plain engine's tokens — then
     the freed pages serve the next request (full recovery)."""
     def run(speculate):
-        eng = GenerationEngine(MODEL, scope=dense_ref.scope,
+        eng = GenerationEngine(MODEL, scope=plain_ref.scope.new_scope(),
                                num_slots=1, max_seq_len=96,
                                attn_impl="xla", seed=0, queue_cap=64,
-                               deadline_ms=600000.0, paged=True,
+                               deadline_ms=600000.0,
                                page_tokens=PAGE, num_pages=5,
                                prefill_chunk=0, prefix_reuse=False,
                                speculate=speculate, spec_tokens=4,
@@ -263,13 +261,13 @@ def test_spec_pool_exhaustion_mid_draft(dense_ref):
 # opt-out
 # ---------------------------------------------------------------------------
 
-def test_spec_per_request_opt_out(dense_ref):
+def test_spec_per_request_opt_out(plain_ref):
     """speculate=False per request on a speculating engine: zero
-    drafts, stream identical to dense."""
+    drafts, stream identical to plain."""
     rng = np.random.RandomState(29)
     prompt = _repetitive(rng, PAGE + 2)
-    ref = dense_ref.generate(prompt, 7)
-    eng = _spec(dense_ref)
+    ref = plain_ref.generate(prompt, 7)
+    eng = _spec(plain_ref)
     try:
         res = eng.submit(prompt, 7, speculate=False).result(120)
         assert res["tokens"] == ref["tokens"]
@@ -303,12 +301,12 @@ def _tiny_predictor():
     return Predictor(main, ["x"], [out], scope=scope)
 
 
-def test_http_generate_speculate(dense_ref):
+def test_http_generate_speculate(plain_ref):
     """POST /generate carries the per-request ``"speculate"`` field
     end-to-end, /statusz exposes the speculate stats block (the
     loadgen acceptance-rate embed reads it), and a non-bool value is a
     400, not a crash."""
-    gen = _spec(dense_ref)
+    gen = _spec(plain_ref)
     eng = ServingEngine(_tiny_predictor(), workers=1, max_batch=2,
                         max_delay_ms=1.0, deadline_ms=60000)
     eng.attach_generator(gen)
@@ -316,7 +314,7 @@ def test_http_generate_speculate(dense_ref):
     try:
         rng = np.random.RandomState(31)
         prompt = _repetitive(rng, PAGE + 1)
-        ref = dense_ref.generate(prompt, 6)
+        ref = plain_ref.generate(prompt, 6)
 
         code, doc = _post(srv.url + "/generate",
                           {"prompt": prompt, "max_new_tokens": 6})

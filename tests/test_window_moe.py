@@ -12,8 +12,8 @@ the CPU.
   the gather formulation) with contexts shorter than, equal to and longer
   than the window, pages left of the window on the NaN-filled trash page.
 * **Engine**: a model with ``head_dim != hidden // num_heads``, NoPE
-  full layers beside RoPE window layers and routed experts, served paged
-  (and dense) across the window boundary, against the uncached full
+  full layers beside RoPE window layers and routed experts, served
+  across the window boundary, against the uncached full
   forward and against the benchmark's plain reference; the window pool's
   bound, release and reuse of pages, admission by both pools; the guards.
 
@@ -51,7 +51,7 @@ def _engine(model=MODEL, **kw):
     from paddle_tpu.serving import GenerationEngine
 
     args = dict(num_slots=3, max_seq_len=64, prefill_buckets=[16, 32, 48],
-                paged=True, page_tokens=PAGE, attn_impl="xla",
+                page_tokens=PAGE, attn_impl="xla",
                 keep_logits=True, prefill_chunk=0, prefix_reuse=False,
                 speculate=False, eos_id=-1, deadline_ms=600000)
     args.update(kw)
@@ -473,37 +473,6 @@ def test_one_kind_model_keeps_one_pool():
                                      "live"]
         assert eng.page_bytes == 8 * 2 * PAGE * 32 * 4
         assert eng.stats()["paged"]["window"] is None
-    finally:
-        eng.close()
-
-
-def test_dense_cache_engine_serves_the_window_model():
-    """Without pages the window is a mask over the dense cache."""
-    eng = _engine(paged=False, prefill_chunk=None, prefix_reuse=None,
-                  speculate=None, page_tokens=None)
-    try:
-        prompt = np.random.default_rng(5).integers(1, 97, 12).tolist()
-        res = eng.generate(prompt, 20, timeout=300)
-        want = _full_forward(eng.scope, MODEL, prompt + res["tokens"])
-        assert _rel(np.stack(res["logits"]),
-                    want[11:11 + len(res["tokens"])]) < TOL_LOGITS
-    finally:
-        eng.close()
-
-
-def test_dense_cache_engine_counts_real_rows_only():
-    """Off the paged path too the expert layers count the prompt's real
-    rows and the live slots, not the bucket's or the grid's: nothing
-    reads as dropped (or as more than was routed)."""
-    eng = _engine(paged=False, prefill_chunk=None, prefix_reuse=None,
-                  speculate=None, page_tokens=None, num_slots=2)
-    try:
-        prompt = np.random.default_rng(6).integers(1, 97, 10).tolist()
-        eng.generate(prompt, 5, timeout=300)
-        n = eng.stats()["counters"]
-        assert n.get("moe_tokens_dropped", 0) == 0
-        # ten prompt rows and four decode steps, four layers, top 3
-        assert n["moe_tokens_routed"] == (10 + 4) * 4 * 3
     finally:
         eng.close()
 

@@ -604,7 +604,7 @@ def _scenario_poison_paged(cfg: dict) -> dict:
                  num_kv_heads=2, intermediate=64)
     eng_kw = dict(num_slots=4, max_seq_len=64, max_new_tokens=8,
                   attn_impl="xla", seed=0, queue_cap=256,
-                  deadline_ms=600000.0, paged=True, page_tokens=8,
+                  deadline_ms=600000.0, page_tokens=8,
                   prefill_chunk=0, prefix_reuse=True)
     poison_every = max(2, int(cfg.get("poison_every", 5)))
     rng = np.random.RandomState(5)
@@ -731,7 +731,7 @@ def _scenario_spec_storm(cfg: dict) -> dict:
                  num_kv_heads=2, intermediate=64)
     eng_kw = dict(num_slots=4, max_seq_len=64, max_new_tokens=8,
                   attn_impl="xla", seed=0, queue_cap=256,
-                  deadline_ms=600000.0, paged=True, page_tokens=8,
+                  deadline_ms=600000.0, page_tokens=8,
                   prefill_chunk=0, prefix_reuse=True,
                   speculate=True, spec_tokens=4, spec_ngram=3)
     poison_every = max(2, int(cfg.get("poison_every", 5)))

@@ -1,15 +1,15 @@
 """Donation-safety checker for buffer-aliasing ops.
 
-The decode ops whose output aliases an input variable
-(``kv_cache_write`` / ``kv_cache_insert`` / ``kv_pool_write`` — the
-mutated-persistable contract in ``ops/decode_ops.py``) make the
+The decode op whose output aliases an input variable
+(``kv_pool_write`` — the mutated-persistable contract in
+``ops/decode_ops.py``) makes the
 executor *donate* the input buffer to XLA: after the call, the
 Python-side variable the caller passed in refers to a buffer XLA has
 already overwritten (or freed).  The only safe patterns are
 
 * rebinding in the same statement::
 
-      cache_k = layers.kv_cache_write(cache_k, k, positions)
+      pool_k = layers.kv_pool_write(pool_k, k, positions, table, lens)
 
 * never touching the donated name again.
 
@@ -45,8 +45,6 @@ from .resource_pairing import _functions, _own_nodes, _recv_repr
 
 # op name -> index of the donated positional argument / keyword name
 ALIAS_OPS: Dict[str, tuple] = {
-    "kv_cache_write": (0, "cache"),
-    "kv_cache_insert": (0, "cache"),
     "kv_pool_write": (0, "pool"),
 }
 
@@ -96,8 +94,8 @@ def _donating_callables(sf: SourceFile) -> Dict[str, Set[int]]:
 
 @register_pass(
     "donation-safety", ("donation-use-after-alias",),
-    doc="a variable donated to an output-aliasing op (kv_cache_write "
-        "et al.) or through a jax.jit(donate_argnums=...) callable "
+    doc="a variable donated to an output-aliasing op (kv_pool_write) "
+        "or through a jax.jit(donate_argnums=...) callable "
         "must be rebound or never read again")
 def run(files: List[SourceFile]) -> List[Violation]:
     out: List[Violation] = []

@@ -417,9 +417,9 @@ def compare_leg(name: str, new: dict, base: dict,
     # lookup is a correctness break (a gather failed mid-leg), and a
     # present-but-None count is a vacuous window — core contention
     # can slow lookups, never degrade them.  The hot-row hit-rate
-    # floor rides the leg (like prefix_hit_floor): under it the cache
-    # is dead (hashing/eviction broke) even when throughput keeps up,
-    # and no anomaly flag shields either rule
+    # floor rides the leg: under it the cache is dead
+    # (hashing/eviction broke) even when throughput keeps up, and no
+    # anomaly flag shields either rule
     if "degraded_lookups" in new:
         dl = new.get("degraded_lookups")
         if dl is None:
@@ -532,49 +532,11 @@ def compare_leg(name: str, new: dict, base: dict,
         res.update(status="regression",
                    reason=f"availability {new_med}% under the "
                           f"{floor}% chaos budget")
-    # paged-decode extras: the paged cache's reason to exist is
-    # holding >= 2x the concurrent sequences per GB of KV pool (ISSUE
-    # 11 acceptance bar) — raw tokens/sec can track the baseline while
-    # the memory win quietly collapses (e.g. pages leak and the pool
-    # saturates), so the ratio gates explicitly when the baseline
-    # proved it on this device kind
-    spg_new = new.get("seq_per_gb_vs_dense")
-    spg_base = base.get("seq_per_gb_vs_dense")
-    if res["status"] == "ok" and spg_new is not None \
-            and spg_base is not None and spg_new < 2.0 <= spg_base:
-        res.update(status="regression",
-                   reason=f"seq_per_gb_vs_dense fell to {spg_new} "
-                          f"(< 2x paged memory contract; baseline "
-                          f"{spg_base})")
-    # ...and a paged tokens/sec win, once proven on a device kind,
-    # must not collapse below the dense fallback (compute-saturated
-    # CPU smoke hosts capture < 1.0 honestly — the rule arms only
-    # where the baseline had the win, like the other speedup rules)
-    pvd_new = new.get("paged_vs_dense_tokens")
-    pvd_base = base.get("paged_vs_dense_tokens")
-    if res["status"] == "ok" and pvd_new is not None \
-            and pvd_base is not None and pvd_new < 1.0 <= pvd_base:
-        res.update(status="regression",
-                   reason=f"paged_vs_dense_tokens collapsed to "
-                          f"{pvd_new} (baseline {pvd_base}: paged "
-                          f"beat dense)")
-    # ...and on the shared-system-prompt workload the prefix index
-    # must actually fire: a hit rate under the committed floor means
-    # the reuse machinery is dead (hashing broke, registration
-    # stopped, eviction runs wild) even if throughput looks fine
-    phr = new.get("prefix_hit_rate")
-    phr_floor = new.get("prefix_hit_floor")
-    if res["status"] == "ok" and phr is not None \
-            and phr_floor is not None and phr < float(phr_floor):
-        res.update(status="regression",
-                   reason=f"prefix hit rate {phr} under the "
-                          f"{phr_floor} floor on the shared-prompt "
-                          f"workload")
     # spec-decode extra: once a baseline proved speculative decode
     # beats the plain grid step on a device kind, a fresh ratio under
     # 1.0 means the speedup collapsed (verify got slower than the K+1
     # steps it replaces) even when raw tokens/sec keeps up — arms only
-    # where the baseline had the win, like paged_vs_dense_tokens
+    # where the baseline had the win, like the other speedup rules
     # (core-bound CPU smoke captures honestly sit under 1.0)
     svp_new = new.get("spec_vs_plain_tokens")
     svp_base = base.get("spec_vs_plain_tokens")
@@ -770,61 +732,6 @@ def run_smoke() -> int:
     r = compare_bench(collapsed, docs + [with_decode])
     check("decode speedup-collapse fails", not r["ok"] and any(
         x["status"] == "regression" and "speedup" in x.get("reason", "")
-        for x in r["legs"]))
-
-    # paged-decode leg (synthetic until a BENCH_r* capture carries
-    # it): generic noise gate + the seq-per-GB memory contract + the
-    # paged-vs-dense tokens collapse rule + the prefix-hit-rate floor
-    paged_leg = {
-        "metric": "llama_paged_decode_tokens_per_sec_per_chip",
-        "value": 2100.0, "unit": "tokens/sec/chip",
-        "device_kind": "cpu",
-        "stats": {"rounds": 3, "median": 2100.0, "p10": 1950.0,
-                  "p90": 2250.0, "min": 1900.0, "max": 2300.0},
-        "dense_tokens_per_sec": 1800.0,
-        "paged_vs_dense_tokens": 1.17,
-        "seq_per_gb": 16000.0, "dense_seq_per_gb": 4100.0,
-        "seq_per_gb_vs_dense": 3.9,
-        "prefix_hit_rate": 0.75, "prefix_hit_floor": 0.3,
-    }
-    with_paged = json.loads(json.dumps(latest))
-    with_paged.setdefault("legs", {})["llama_paged_decode"] = paged_leg
-    r = compare_bench(with_paged, docs + [with_paged])
-    check("paged self-compare passes", r["ok"],
-          json.dumps([x for x in r["legs"]
-                      if x["status"] == "regression"]))
-    r = compare_bench(_degrade(with_paged, 0.70), docs + [with_paged])
-    check("paged 30%-degraded fails", not r["ok"])
-    mem_collapse = json.loads(json.dumps(with_paged))
-    mem_collapse["legs"]["llama_paged_decode"]["seq_per_gb_vs_dense"] \
-        = 1.4
-    r = compare_bench(mem_collapse, docs + [with_paged])
-    check("paged seq-per-GB collapse fails", not r["ok"] and any(
-        x["status"] == "regression"
-        and "seq_per_gb" in x.get("reason", "") for x in r["legs"]))
-    tok_collapse = json.loads(json.dumps(with_paged))
-    tok_collapse["legs"]["llama_paged_decode"]["paged_vs_dense_tokens"] \
-        = 0.8
-    r = compare_bench(tok_collapse, docs + [with_paged])
-    check("paged tokens-collapse fails", not r["ok"] and any(
-        x["status"] == "regression"
-        and "paged_vs_dense_tokens" in x.get("reason", "")
-        for x in r["legs"]))
-    # ...but a sub-1.0 ratio must NOT flap when the baseline never
-    # proved the win (compute-saturated CPU smoke captures)
-    never_won = json.loads(json.dumps(with_paged))
-    never_won["legs"]["llama_paged_decode"]["paged_vs_dense_tokens"] \
-        = 0.9
-    r = compare_bench(tok_collapse, docs + [never_won])
-    check("paged sub-1.0 tokens vs sub-1.0 baseline passes", r["ok"],
-          json.dumps([x for x in r["legs"]
-                      if x["status"] == "regression"]))
-    dead_index = json.loads(json.dumps(with_paged))
-    dead_index["legs"]["llama_paged_decode"]["prefix_hit_rate"] = 0.1
-    r = compare_bench(dead_index, docs + [with_paged])
-    check("paged dead-prefix-index fails", not r["ok"] and any(
-        x["status"] == "regression"
-        and "prefix hit rate" in x.get("reason", "")
         for x in r["legs"]))
 
     # disagg leg (synthetic until a BENCH_r* capture carries it):

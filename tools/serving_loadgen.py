@@ -63,14 +63,14 @@ batch-drain scheduling.  Closed loop measures saturated
 on the ``--qps`` clock for latency/shed behavior at a target rate.
 ``--gen-static`` schedules FIFO head-run (batch drain) instead of
 continuous slot reclaim — the A/B the bench leg publishes.
-``--gen-paged`` (with ``--gen-page-tokens``/``--gen-pages``/
-``--gen-prefill-chunk``) swaps in the block-paged KV cache,
+``--gen-page-tokens``/``--gen-pages``/``--gen-prefill-chunk`` size
+the engine's block-paged KV cache,
 ``--gen-speculate``/``--gen-spec-tokens`` turn on speculative
 decoding (the report embeds the measured acceptance rate;
 ``--slo-accept-rate`` floors it — unmeasured is a violation), and
 ``--gen-prompt-dist shared-prefix --gen-prefix-tokens N`` makes every
 prompt one fixed N-token header + a random tail — the chat workload
-where the paged engine's prefix index skips the header's prefill.
+where the engine's prefix index skips the header's prefill.
 With ``--url`` the same workload posts ``/generate`` against a live
 replica or fleet router and the report embeds the target's
 ``/statusz`` generation block (prefix-hit rate included).
@@ -86,9 +86,9 @@ reads the target's ``/statusz``), and ``--slo-hit-rate`` floors it —
 an unmeasured floor is a violation, matching the acceptance-rate
 precedent.
 
-Used by ``bench.py run_serving``/``run_decode``/``run_paged_decode``/
-``run_recsys`` (the ``legs.serving``, ``legs.llama_decode``,
-``legs.llama_paged_decode`` and ``legs.wide_deep_recsys`` entries),
+Used by ``bench.py run_serving``/``run_decode``/``run_recsys`` (the
+``legs.serving``, ``legs.llama_decode`` and ``legs.wide_deep_recsys``
+entries),
 ``tests/test_serving.py``, ``tests/test_generation.py``,
 ``tests/test_paged_generation.py``, and
 ``tests/test_recsys_serving.py``.
@@ -1607,27 +1607,21 @@ def main(argv=None) -> int:
                          "in-process engine's max prompt length, or "
                          "half of --gen-max-seq for a remote --url "
                          "target")
-    ap.add_argument("--gen-paged", action="store_true",
-                    help="block-paged KV cache (page pool + per-slot "
-                         "block tables + prefix reuse) instead of the "
-                         "dense per-slot reservation "
-                         "(FLAGS_serving_paged for a live replica)")
     ap.add_argument("--gen-page-tokens", type=int, default=None,
-                    help="paged: tokens per KV page (default "
+                    help="tokens per KV page (default "
                          "FLAGS_serving_kv_page_tokens)")
     ap.add_argument("--gen-pages", type=int, default=None,
-                    help="paged: physical pages in the pool (default "
-                         "auto-size to the dense capacity)")
+                    help="physical pages in the pool (default "
+                         "auto-size to every slot's worst case)")
     ap.add_argument("--gen-prefill-chunk", type=int, default=None,
-                    help="paged: chunked-prefill slice size (0 = "
+                    help="chunked-prefill slice size (0 = "
                          "whole-prompt prefill; default "
                          "FLAGS_serving_prefill_chunk)")
     ap.add_argument("--gen-speculate", action="store_true",
                     help="speculative decoding on the in-process "
                          "engine (n-gram self-drafts, one-chunk "
-                         "verify, bit-exact acceptance; implies "
-                         "--gen-paged) — the report embeds the "
-                         "measured acceptance rate")
+                         "verify, bit-exact acceptance) — the report "
+                         "embeds the measured acceptance rate")
     ap.add_argument("--gen-spec-tokens", type=int, default=None,
                     help="speculative: max draft tokens per verify "
                          "(default FLAGS_serving_spec_tokens)")
@@ -1856,23 +1850,16 @@ def main(argv=None) -> int:
                      num_layers=args.gen_layers, num_heads=args.gen_heads,
                      num_kv_heads=args.gen_kv_heads,
                      intermediate=args.gen_intermediate)
-        paged_kw = {}
-        if args.gen_paged or args.gen_speculate:
-            # speculation verifies against the slot's pages: it
-            # implies the paged cache
-            paged_kw = dict(paged=True,
-                            page_tokens=args.gen_page_tokens,
-                            num_pages=args.gen_pages,
-                            prefill_chunk=args.gen_prefill_chunk)
-        if args.gen_speculate:
-            paged_kw.update(speculate=True,
-                            spec_tokens=args.gen_spec_tokens)
         gen = GenerationEngine(
             model, num_slots=args.gen_slots, max_seq_len=args.gen_max_seq,
             max_new_tokens=args.gen_out_max,
             continuous=not args.gen_static,
             queue_cap=args.queue_cap or 4 * args.requests,
-            deadline_ms=args.deadline_ms or 600000.0, **paged_kw)
+            deadline_ms=args.deadline_ms or 600000.0,
+            page_tokens=args.gen_page_tokens, num_pages=args.gen_pages,
+            prefill_chunk=args.gen_prefill_chunk,
+            speculate=True if args.gen_speculate else None,
+            spec_tokens=args.gen_spec_tokens)
         gen.warmup()
         shared = args.gen_prompt_dist == "shared-prefix"
         prefix = args.gen_prefix_tokens if shared else 0
