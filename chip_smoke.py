@@ -13,8 +13,9 @@ random seeded weights:
   ``CompiledProgram(main).with_data_parallel(...)`` over every local chip.
   First the packed Pallas kernels run forward + backward at the phase's own
   shape against the einsum formulation; then ~10 steps on one fixed batch
-  at a constant learning rate must give finite, falling losses, and on one
-  chip the compiled step itself must contain the Mosaic custom call.
+  at a constant learning rate must give finite, falling losses, and the
+  compiled step itself must contain the Mosaic custom call, on one chip
+  and (the kernels per ``dp`` shard) on several.
 * **serve** — ``serve(ServingEngine(...))`` with a paged
   ``GenerationEngine`` attached, answering HTTP ``/predict``, ``/generate``
   (prompts in different prefill buckets, one streamed), ``/healthz`` and
@@ -77,8 +78,8 @@ def attention_paths():
     from paddle_tpu.monitor import stat_get
 
     return {p: stat_get(f"attention_lowered_{p}")
-            for p in ("pallas", "blockwise", "ring", "xla", "paged_decode",
-                      "paged_decode_reference")}
+            for p in ("pallas", "pallas_sharded", "blockwise", "ring", "xla",
+                      "paged_decode", "paged_decode_reference")}
 
 
 def paths_since(before):
@@ -222,19 +223,20 @@ def train_phase(cfg=TRAIN, on_chip=True):
           f"batch not split over dp: per-device shard {shard} of {(B, S)}")
     mosaic = "tpu_custom_call" in executable.as_text()
     paths = paths_since(paths0)
-    if on_chip and n == 1:
-        check(mosaic and paths.get("pallas"),
+    if on_chip:
+        # on more than one device the same kernels run per dp shard,
+        # through shard_map (ops/attention_ops.py kernel_partition)
+        check(mosaic and paths.get("pallas")
+              and not paths.get("blockwise"),
               f"the compiled training step holds no Mosaic custom call: "
               f"the Pallas kernels were not lowered (paths {paths})")
+        check(paths.get("pallas_sharded", 0)
+              == (paths["pallas"] if n > 1 else 0),
+              f"on {n} device(s) the kernels took the wrong route "
+              f"(paths {paths})")
     say(f"train: {n} device(s), per-device batch shard {shard}, Mosaic "
         f"custom call in the compiled step: {mosaic}, attention lowered "
         f"as {paths}")
-    if n > 1:
-        say("train: on more than one device attention is the blockwise "
-            "reference, not the Pallas kernels (pallas_call pins the "
-            "layout the partitioner needs free)")
-        check(paths.get("blockwise") and not mosaic,
-              "expected the blockwise reference under a multi-device mesh")
 
     mem = [d.memory_stats() for d in devices]
     if all(mem):

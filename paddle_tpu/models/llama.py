@@ -5,8 +5,9 @@ Architecture: pre-RMSNorm, fused QKV with GQA, RoPE, causal flash
 attention (pallas / ring under sp), SwiGLU MLP, untied LM head.
 
 TPU-first notes:
-  * attention via the flash_attention op — pallas kernel single-chip,
-    ring attention when the sequence is sharded over `sp`;
+  * attention via the flash_attention op — pallas kernel on one chip
+    and per `dp` / `mp` shard of a mesh, ring attention when the
+    sequence is sharded over `sp`;
   * all projections are single large matmuls (fused QKV, fused gate+up)
     to keep the MXU busy;
   * weights stay fp32 in the scope; AMP lowers matmuls to bf16.

@@ -4,15 +4,22 @@ Reference analog: operators/fused/multihead_matmul_op.cu (inference-only,
 fixed layout). Here a first-class training op that picks the best TPU
 execution per context:
   * `sp` mesh axis bound (shard_map)  -> ring attention over ICI
-  * TPU backend                       -> pallas flash-attention kernel
+  * TPU backend, one device or a manual (shard_map) context
+                                      -> pallas flash-attention kernel
+  * TPU backend, GSPMD mesh           -> the same kernel per shard, through
+                                         shard_map (batch over `dp`, heads
+                                         over `mp`: `kernel_partition`);
+                                         blockwise where the operands do
+                                         not divide
   * CPU (tests/virtual mesh)          -> blockwise scan formulation
 """
 from __future__ import annotations
 
+import functools
 import logging
 
 from ..monitor import monitor as _monitor
-from ..parallel.mesh import SP_AXIS
+from ..parallel.mesh import DP_AXIS, MP_AXIS, SP_AXIS
 from .registry import in_var, register_op, set_out
 
 logger = logging.getLogger("paddle_tpu.ops.attention")
@@ -24,6 +31,10 @@ _LOWERED = {
     "blockwise": _monitor.get("attention_lowered_blockwise"),
     "ring": _monitor.get("attention_lowered_ring"),
     "xla": _monitor.get("attention_lowered_xla"),
+    # of the "pallas" ops, those that run the kernel per shard of a
+    # multi-device mesh (shard_map over ctx.mesh): booked beside the
+    # plain counter, which they also raise
+    "pallas_sharded": _monitor.get("attention_lowered_pallas_sharded"),
     # the paged one-token decode step (ops/decode_ops.py): the Pallas
     # kernel over live pages, or the gather + einsum formulation
     "paged_decode": _monitor.get("attention_lowered_paged_decode"),
@@ -43,7 +54,7 @@ _LOWERED = {
 _downgrades_logged = set()
 
 
-def _lowered(path, downgrade_reason=None, window=None):
+def _lowered(path, downgrade_reason=None, window=None, sharded=False):
     """Book the path taken.  On a TPU backend a reference formulation
     (blockwise, or the paged decode step's gather + einsum) is a
     downgrade from the Pallas kernels, not an equivalent: say so, once
@@ -51,11 +62,117 @@ def _lowered(path, downgrade_reason=None, window=None):
     _LOWERED[path].increase()
     if window is not None:
         _LOWERED[path + "_window"].increase()
+    if sharded:
+        _LOWERED[path + "_sharded"].increase()
     if downgrade_reason and downgrade_reason not in _downgrades_logged:
         _downgrades_logged.add(downgrade_reason)
         logger.warning("attention lowered to its reference formulation "
                        "(%s) on a TPU backend, not the Pallas kernels: %s",
                        path, downgrade_reason)
+
+
+def kernel_partition(mesh_shape, manual_axes, batch, heads):
+    """How a Pallas kernel runs under the mesh an op is lowered in, read
+    from what the op can observe: the mesh's axes and its operands' shapes.
+
+    ``mesh_shape``: axis name -> size of ``ctx.mesh`` (empty: no mesh);
+    ``manual_axes``: ``ctx.axis_names``, the axes a surrounding
+    ``shard_map`` has bound (``parallel/spmd.py``); ``batch``: the
+    operands' leading dim; ``heads``: the head count of every operand
+    that has a head dim (dim 1), or None where heads are not a dim of
+    their own (the packed ``[B, S, 3H]`` projection, whose columns are
+    ``(3, h, d)``-ordered).
+
+    Returns one of
+      * ``("direct", None)``: operands are whole (no mesh, one device) or
+        already local (manual context): call the kernel as it is;
+      * ``("shard_map", (batch_axes, head_axis))``: GSPMD context: wrap the
+        kernel in ``shard_map`` over the mesh, dim 0 split over
+        ``batch_axes`` and dim 1 over ``head_axis`` (either may be
+        empty / None);
+      * ``("reference", reason)``: no clean partition: keep the reference
+        formulation, which GSPMD shards as it likes.  The kernel's work is
+        never replicated over an axis.
+    """
+    if manual_axes:
+        return "direct", None
+    live = {a: n for a, n in mesh_shape.items() if n > 1}
+    if not live:
+        return "direct", None
+    unknown = sorted(a for a in live if a not in (DP_AXIS, MP_AXIS))
+    if unknown:
+        return "reference", (
+            f"mesh axis {', '.join(unknown)} (the kernels split over "
+            f"{DP_AXIS} and {MP_AXIS} only)")
+    # the batch over dp, as build_sharded_step shards the feeds
+    dp, mp = live.get(DP_AXIS, 1), live.get(MP_AXIS, 1)
+    if batch % dp:
+        return "reference", (
+            f"batch {batch} does not divide over {DP_AXIS}={dp}")
+    if mp > 1 and heads is None:
+        return "reference", (
+            f"packed [B, S, 3H] columns cannot split over {MP_AXIS}={mp}")
+    if mp > 1 and any(h % mp for h in heads):
+        return "reference", (
+            f"head counts {tuple(heads)} do not divide over {MP_AXIS}={mp}")
+    return "shard_map", ((DP_AXIS,) if dp > 1 else (),
+                         MP_AXIS if mp > 1 else None)
+
+
+def kernel_route(ctx, batch, heads):
+    """``kernel_partition`` for the context an op is lowered in; off a TPU
+    backend the reference formulation, with no reason to log."""
+    import jax
+
+    if jax.default_backend() != "tpu":
+        return "reference", None
+    return kernel_partition(
+        ctx.mesh.shape if ctx.mesh is not None else {},
+        getattr(ctx, "axis_names", ()) or (), batch, heads)
+
+
+# what each dim of a kernel operand is to the partition
+_BHSD = ("batch", "heads", None, None)
+_BSH = ("batch", None, None)
+_BS = ("batch", None)
+
+
+@functools.lru_cache(maxsize=64)
+def _sharded_kernel(fn, static, mesh, in_specs, out_spec):
+    """``fn(*operands, **static)`` per shard of ``mesh``, jitted and kept:
+    the layers of a model call it with the same shapes, and a jitted
+    callable is traced (kernel bodies, their JVP and transpose) once for
+    all of them, not once a layer (2.5 s of set-up for BERT-base's 12)."""
+    import jax
+
+    from ..parallel.mesh import shard_map_compat
+
+    # check off: a pallas_call has no replication rule
+    return jax.jit(shard_map_compat(
+        functools.partial(fn, **dict(static)), mesh, in_specs=in_specs,
+        out_specs=out_spec))
+
+
+def call_kernel(mesh, how, fn, operands, layouts, out_layout, **static):
+    """``fn(*operands, **static)`` on the "direct" (``how`` None) or
+    "shard_map" route of ``kernel_partition``, with ``how`` that route's
+    second value.  ``layouts`` names, per operand, what each dim is to the
+    partition ("batch", "heads" or None); under ``shard_map`` the kernel
+    sees each device's local block.  ``static`` are the kernel's
+    non-array arguments (hashable)."""
+    if how is None:
+        return fn(*operands, **static)
+    from jax.sharding import PartitionSpec as P
+
+    batch_axes, head_axis = how
+    place = {"batch": batch_axes or None, "heads": head_axis}
+
+    def spec(layout):
+        return P(*(place.get(role) for role in layout))
+
+    return _sharded_kernel(
+        fn, tuple(sorted(static.items())), mesh,
+        tuple(spec(lay) for lay in layouts), spec(out_layout))(*operands)
 
 
 def _attn_infer(op, block):
@@ -132,8 +249,6 @@ def _flash_attention(ctx, op):
         return
 
     axes = getattr(ctx, "axis_names", ()) or ()
-    on_tpu = jax.default_backend() == "tpu"
-    n_mesh = ctx.mesh.devices.size if ctx.mesh is not None else 1
     if SP_AXIS in axes:
         if bias is not None or window is not None:
             raise NotImplementedError(
@@ -143,24 +258,33 @@ def _flash_attention(ctx, op):
         fn = ring_attention if mode == "ring" else ulysses_attention
         out = fn(q, k, v, SP_AXIS, causal=causal, sm_scale=sm_scale)
         _lowered("ring")
-    elif on_tpu and n_mesh == 1:
-        if bias is not None:
-            out = flash_attention_bias(q, k, v, bias, causal, sm_scale)
-        elif window is not None:
-            out = flash_attention(q, k, v, causal, sm_scale,
-                                  window=int(window))
-        else:
-            out = flash_attention(q, k, v, causal, sm_scale)
-        _lowered("pallas", window=window)
-    else:
-        # multi-device GSPMD: the einsum formulation lets the partitioner
-        # shard batch/head/seq dims freely (pallas_call pins the layout)
+        ctx.set_output(op, "Out", out)
+        return
+
+    route, how = kernel_route(ctx, q.shape[0],
+                              (q.shape[1], k.shape[1], v.shape[1]))
+    if route == "reference":
+        # CPU, or a mesh the operands do not divide over: the einsum
+        # formulation, which the partitioner shards as it likes
         out, _ = blockwise_attention(q, k, v, causal=causal,
                                      sm_scale=sm_scale, bias=bias,
                                      window=window)
-        _lowered("blockwise",
-                 f"flash_attention under a {n_mesh}-device mesh"
-                 if on_tpu else None, window=window)
+        _lowered("blockwise", how and f"flash_attention: {how}",
+                 window=window)
+    else:
+        # one device or a manual context: the kernel as it is; a GSPMD
+        # mesh: the kernel per shard (a bare pallas_call would pin the
+        # layout; inside shard_map it sees its device's block)
+        if bias is not None:
+            out = call_kernel(ctx.mesh, how, flash_attention_bias,
+                              (q, k, v, bias), (_BHSD, _BHSD, _BHSD, _BS),
+                              _BHSD, causal=causal, sm_scale=sm_scale)
+        else:
+            kw = {} if window is None else {"window": int(window)}
+            out = call_kernel(ctx.mesh, how, flash_attention, (q, k, v),
+                              (_BHSD,) * 3, _BHSD, causal=causal,
+                              sm_scale=sm_scale, **kw)
+        _lowered("pallas", window=window, sharded=how is not None)
     ctx.set_output(op, "Out", out)
 
 
@@ -175,20 +299,22 @@ def _attn_qkv_infer(op, block):
 def _flash_attention_qkv(ctx, op):
     """Transpose-free fused attention on the packed QKV projection.
 
-    QKV [B, S, 3H] -> Out [B, S, H].  On single-device TPU this lowers to
+    QKV [B, S, 3H] -> Out [B, S, H].  On a TPU this lowers to
     the packed pallas kernels (ops/pallas/flash_attention.py:
     flash_attention_packed) whose grid reads 128-lane column chunks of
     the projection directly — none of the [B,S,3H] -> [3,B,h,S,d]
     transpose/slice traffic of the split-tensor path ever reaches HBM
     (measured ~2.4 GB/step of pure layout movement on the seq-512 BERT
-    bench).  Elsewhere (CPU meshes, GSPMD) it lowers to an einsum
-    formulation the partitioner can shard freely.
+    bench).  Under a GSPMD mesh the kernels run per batch shard through
+    shard_map (``kernel_partition``).  On the CPU, and where the mesh has
+    an ``mp`` axis (the packed columns are ``(3, h, d)``-ordered and do
+    not split over it) or the batch does not divide, it lowers to an
+    einsum formulation the partitioner can shard freely.
 
     Reference analog: operators/fused/multihead_matmul_op.cu takes the
     same packed [B, S, 3H] input (its "qkv weight" layout) — ours adds
     training (fwd+bwd) and long-sequence O(S) memory.
     """
-    import jax
     import jax.numpy as jnp
 
     from .pallas.flash_attention import (flash_attention_packed,
@@ -205,21 +331,27 @@ def _flash_attention_qkv(ctx, op):
     H = threeH // 3
     D = H // nh
 
-    on_tpu = jax.default_backend() == "tpu"
-    n_mesh = ctx.mesh.devices.size if ctx.mesh is not None else 1
-    packable = H % 128 == 0 and D in (64, 128)
-    if on_tpu and n_mesh == 1 and packable:
+    route, how = kernel_route(ctx, B, None)
+    if route != "reference" and not (H % 128 == 0 and D in (64, 128)):
+        route, how = "reference", (
+            f"hidden {H} / head_dim {D} (kernel needs hidden % 128 == 0 "
+            f"and head_dim 64 or 128)")
+    if route != "reference":
         if bias is not None:
-            out = flash_attention_packed_bias(qkv, bias, nh, causal,
-                                              sm_scale)
+            out = call_kernel(ctx.mesh, how, flash_attention_packed_bias,
+                              (qkv, bias), (_BSH, _BS), _BSH, num_heads=nh,
+                              causal=causal, sm_scale=sm_scale)
         else:
-            out = flash_attention_packed(qkv, nh, causal, sm_scale)
-        _lowered("pallas")
+            out = call_kernel(ctx.mesh, how, flash_attention_packed, (qkv,),
+                              (_BSH,), _BSH, num_heads=nh, causal=causal,
+                              sm_scale=sm_scale)
+        _lowered("pallas", sharded=how is not None)
     else:
-        # fallback (CPU / GSPMD meshes): blockwise online-softmax — keeps
-        # O(S) attention memory so long-sequence mesh training doesn't
-        # regress to an [B,h,S,S] materialization, and the einsum body is
-        # layout-free for the partitioner
+        # fallback (CPU, or a mesh the packed form does not divide over):
+        # blockwise online-softmax — keeps O(S) attention memory so
+        # long-sequence mesh training doesn't regress to an [B,h,S,S]
+        # materialization, and the einsum body is layout-free for the
+        # partitioner
         from .pallas.flash_attention import blockwise_attention
 
         x = qkv.reshape(B, S, 3, nh, D)
@@ -229,14 +361,7 @@ def _flash_attention_qkv(ctx, op):
         o, _ = blockwise_attention(q, k, v, causal=causal,
                                    sm_scale=sm_scale, bias=bias)
         out = jnp.moveaxis(o, 1, 2).reshape(B, S, H).astype(qkv.dtype)
-        reason = None
-        if on_tpu:
-            reason = (f"flash_attention_qkv under a {n_mesh}-device mesh"
-                      if n_mesh > 1 else
-                      f"flash_attention_qkv with hidden {H} / head_dim "
-                      f"{D} (kernel needs hidden % 128 == 0 and head_dim "
-                      f"64 or 128)")
-        _lowered("blockwise", reason)
+        _lowered("blockwise", how and f"flash_attention_qkv: {how}")
     ctx.set_output(op, "Out", out)
 
 

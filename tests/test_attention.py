@@ -380,3 +380,213 @@ def test_flash_attention_qkv_op_and_layer():
     np.testing.assert_allclose(outs[0], np.asarray(ref), atol=2e-2,
                                rtol=2e-2)
     assert np.abs(outs[1]).max() > 0
+
+
+# ---------------------------------------------------------------------------
+# the Pallas kernels under a mesh (ops/attention_ops.py kernel_partition)
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("mesh_shape,manual,batch,heads,want,says", [
+    # no mesh, one device, a mesh of size-one axes: the kernel as it is
+    ({}, (), 64, None, ("direct", None), None),
+    ({"dp": 1}, (), 64, (12, 12, 12), ("direct", None), None),
+    # the dp4 cell: packed form, batch 160 over dp
+    ({"dp": 4}, (), 160, None, ("shard_map", (("dp",), None)), None),
+    ({"dp": 4}, (), 160, (12, 12, 12), ("shard_map", (("dp",), None)), None),
+    # dp x mp: the split form splits heads too, the packed form cannot
+    ({"dp": 2, "mp": 2}, (), 8, (12, 12, 12),
+     ("shard_map", (("dp",), "mp")), None),
+    ({"mp": 4}, (), 1, (32, 32, 32), ("shard_map", ((), "mp")), None),
+    ({"dp": 2, "mp": 2}, (), 8, None, "reference", "mp=2"),
+    ({"dp": 2, "mp": 1}, (), 8, None, ("shard_map", (("dp",), None)), None),
+    # batch not divisible: never replicated over dp
+    ({"dp": 4}, (), 6, None, "reference", "batch 6 does not divide"),
+    ({"dp": 4, "mp": 2}, (), 2, (8, 8, 8), "reference", "batch 2"),
+    # GQA head counts: every operand's heads must divide
+    ({"mp": 4}, (), 1, (32, 8, 8), ("shard_map", ((), "mp")), None),
+    ({"mp": 4}, (), 1, (28, 2, 2), "reference", "(28, 2, 2)"),
+    ({"dp": 2, "mp": 8}, (), 4, (32, 4, 4), "reference", "mp=8"),
+    # an axis the rule does not know
+    ({"dp": 2, "ep": 2}, (), 8, None, "reference", "axis ep"),
+    ({"dp": 2, "zero": 4}, (), 8, (4, 4, 4), "reference", "axis zero"),
+    ({"dp": 2, "sp": 2}, (), 8, (4, 4, 4), "reference", "axis sp"),
+    # a manual context (parallel/spmd.py): operands are local already
+    ({"dp": 8}, ("dp",), 3, None, ("direct", None), None),
+    ({"dp": 2, "mp": 2}, ("dp", "mp"), 3, (5, 5, 5), ("direct", None), None),
+    ({"dp": 2, "sp": 4}, ("dp", "sp"), 3, (5, 5, 5), ("direct", None), None),
+])
+def test_kernel_partition_rule(mesh_shape, manual, batch, heads, want, says):
+    """The partition as a pure function of the mesh's axes and the
+    operands' shapes.  (With ``sp`` bound the ops take their ring / Ulysses
+    branch before they ask.)"""
+    from paddle_tpu.ops.attention_ops import kernel_partition
+
+    got = kernel_partition(mesh_shape, manual, batch, heads)
+    if want == "reference":
+        assert got[0] == "reference" and says in got[1], got
+    else:
+        assert got == want
+
+
+def test_kernel_route_off_a_tpu_is_the_reference_without_a_reason():
+    from types import SimpleNamespace
+
+    from paddle_tpu.ops.attention_ops import kernel_route
+
+    mesh = make_mesh({"dp": 4}, devices=jax.devices()[:4])
+    assert kernel_route(SimpleNamespace(mesh=mesh), 8, None) == (
+        "reference", None)
+    assert kernel_route(SimpleNamespace(mesh=None), 8, None) == (
+        "reference", None)
+
+
+def _sharded(mesh_axes, batch, heads, kernel, operands, layouts, out_layout):
+    """``kernel`` through the route ``kernel_partition`` gives under a mesh
+    of the forced host devices, jitted as the GSPMD builders jit it."""
+    from paddle_tpu.ops.attention_ops import call_kernel, kernel_partition
+
+    n = int(np.prod(list(mesh_axes.values())))
+    mesh = make_mesh(mesh_axes, devices=jax.devices()[:n])
+    route, how = kernel_partition(dict(mesh.shape), (), batch, heads)
+    assert route == "shard_map", (route, how)
+    return jax.jit(lambda *xs: call_kernel(mesh, how, kernel, xs, layouts,
+                                           out_layout))(*operands)
+
+
+def _close(got, want, atol):
+    for g, w in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        scale = max(float(jnp.abs(w).max()), 1e-6)
+        np.testing.assert_allclose(np.asarray(g) / scale,
+                                   np.asarray(w) / scale, atol=atol)
+
+
+@pytest.mark.parametrize("mesh_axes", [{"dp": 4}, {"dp": 2, "mp": 2}],
+                         ids=["dp4", "dp2xmp2"])
+@pytest.mark.parametrize("causal,with_bias", [(False, False), (True, False),
+                                              (False, True)],
+                         ids=["plain", "causal", "bias"])
+def test_sharded_split_kernels_match_blockwise(mesh_axes, causal, with_bias):
+    """The split-form kernels (interpret mode) per shard of a ``dp`` and a
+    ``dp x mp`` mesh against ``blockwise_attention`` on whole operands:
+    the output and the gradients of q, k, v."""
+    from paddle_tpu.ops.attention_ops import _BHSD, _BS
+    from paddle_tpu.ops.pallas.flash_attention import flash_attention_bias
+
+    rng = np.random.RandomState(5)
+    b, h, s, d = 4, 4, 64, 32
+    q, k, v = (jnp.asarray(rng.randn(b, h, s, d).astype("float32"))
+               for _ in range(3))
+    bias = jnp.asarray(
+        np.where(rng.rand(b, s) > 0.2, 0.0, -1e4).astype("float32"))
+    if with_bias:
+        operands, layouts = (q, k, v, bias), (_BHSD,) * 3 + (_BS,)
+
+        def kernel(q, k, v, bb):
+            return flash_attention_bias(q, k, v, bb, causal, None, 32, 32,
+                                        True)
+    else:
+        operands, layouts = (q, k, v), (_BHSD,) * 3
+
+        def kernel(q, k, v):
+            return flash_attention(q, k, v, causal, None, 32, 32, True)
+
+    def ref(q, k, v):
+        return blockwise_attention(q, k, v, causal=causal, block_k=32,
+                                   bias=bias if with_bias else None)[0]
+
+    def run(q, k, v):
+        return _sharded(mesh_axes, b, (h, h, h), kernel,
+                        (q, k, v) + operands[3:], layouts, _BHSD)
+
+    _close(run(q, k, v), ref(q, k, v), 2e-5)
+    grads = jax.grad(lambda *a: (run(*a) ** 2).sum(), (0, 1, 2))(q, k, v)
+    want = jax.grad(lambda *a: (ref(*a) ** 2).sum(), (0, 1, 2))(q, k, v)
+    _close(grads, want, 1e-4)
+
+
+@pytest.mark.parametrize("causal,with_bias", [(False, False), (True, False),
+                                              (False, True)],
+                         ids=["plain", "causal", "bias"])
+def test_sharded_packed_kernels_match_blockwise(causal, with_bias):
+    """The packed kernels per ``dp`` shard (what the dp4 BERT cell runs on
+    the chip) against the op's own blockwise lowering of the packed
+    projection: the output and the gradient of ``qkv``."""
+    from paddle_tpu.ops.attention_ops import _BS, _BSH
+    from paddle_tpu.ops.pallas.flash_attention import (
+        flash_attention_packed, flash_attention_packed_bias)
+
+    rng = np.random.RandomState(6)
+    b = 8
+    qkv = jnp.asarray(rng.randn(b, PS, 3 * PH).astype("float32"))
+    bias = jnp.asarray(
+        np.where(rng.rand(b, PS) > 0.2, 0.0, -1e4).astype("float32"))
+    if with_bias:
+        extra, layouts = (bias,), (_BSH, _BS)
+
+        def kernel(x, bb):
+            return flash_attention_packed_bias(x, bb, PNH, causal, None, 64,
+                                               32, True)
+    else:
+        extra, layouts = (), (_BSH,)
+
+        def kernel(x):
+            return flash_attention_packed(x, PNH, causal, None, 64, 32, True)
+
+    def ref(x):
+        t = x.reshape(b, PS, 3, PNH, PH // PNH)
+        q, k, v = (jnp.moveaxis(t[:, :, i], 1, 2) for i in range(3))
+        o, _ = blockwise_attention(q, k, v, causal=causal, block_k=32,
+                                   bias=bias if with_bias else None)
+        return jnp.moveaxis(o, 1, 2).reshape(b, PS, PH)
+
+    def run(x):
+        return _sharded({"dp": 4}, b, None, kernel, (x,) + extra, layouts,
+                        _BSH)
+
+    _close(run(qkv), ref(qkv), 2e-5)
+    _close(jax.grad(lambda x: (run(x) ** 2).sum())(qkv),
+           jax.grad(lambda x: (ref(x) ** 2).sum())(qkv), 1e-4)
+
+
+def test_sharded_bert_step_on_the_cpu_keeps_the_blockwise_route():
+    """A small BERT step through ``build_sharded_step`` over a ``dp`` mesh
+    of host devices: not a TPU backend, so every attention op (and its
+    re-lowering inside the auto-grad op) books ``blockwise`` and nothing
+    takes the ``shard_map`` route."""
+    import bench
+    from paddle_tpu.monitor import stat_get
+    from paddle_tpu.parallel import build_sharded_step, dp_mesh
+
+    names = ("pallas", "pallas_sharded", "blockwise")
+    before = {n: stat_get(f"attention_lowered_{n}") for n in names}
+    layers_, batch, seq, pred = 2, 8, 64, 10
+    main_p, startup, feed_names, loss, _ = bench.build_bert_train_programs(
+        dict(batch_size=batch, seq_len=seq, vocab_size=211, hidden=128,
+             num_layers=layers_, num_heads=2, intermediate=256,
+             max_predictions=pred, use_flash=True, dropout=0.1))
+    scope = pt.Scope()
+    pt.Executor(pt.CPUPlace()).run(startup, scope=scope)
+    mesh = dp_mesh(4, devices=jax.devices()[:4])
+    fn, mut_in, const_in, _ = build_sharded_step(
+        main_p, feed_names, [loss.name], mesh)
+    rng = np.random.RandomState(7)
+    feed = {
+        "input_ids": rng.randint(0, 211, (batch, seq)).astype("int32"),
+        "token_type_ids": np.zeros((batch, seq), "int32"),
+        "attn_mask": np.ones((batch, seq), "float32"),
+        "mlm_positions": np.sort(np.stack(
+            [rng.choice(seq, pred, replace=False) for _ in range(batch)]),
+            axis=1).astype("int32"),
+        "mlm_labels": rng.randint(0, 211, (batch, pred)).astype("int32"),
+        "mlm_weights": np.ones((batch, pred), "float32"),
+    }
+    fetches, _, _ = fn(tuple(feed[n] for n in feed_names),
+                       tuple(scope.find_var(n) for n in mut_in),
+                       tuple(scope.find_var(n) for n in const_in),
+                       np.int32(1))
+    assert np.isfinite(np.asarray(fetches[0])).all()
+    moved = {n: stat_get(f"attention_lowered_{n}") - before[n]
+             for n in names}
+    assert moved == {"pallas": 0, "pallas_sharded": 0,
+                     "blockwise": 2 * layers_}

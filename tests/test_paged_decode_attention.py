@@ -17,7 +17,10 @@ and the op's two lowerings.
 * **For the chip, without one**: the kernel compiles for a described TPU
   v5e at both serving cells' shapes (skipped where no topology can be
   described; the topology is described inside a fixture of this one file,
-  because one process at a time may load the TPU's library).
+  because one process at a time may load the TPU's library).  The same
+  fixture serves the one compile of the training attention kernels under
+  a four-chip mesh (``ops/attention_ops.py`` ``kernel_partition``, PR 31),
+  which is here and not in ``test_attention.py`` for that reason.
 """
 import os
 
@@ -265,16 +268,36 @@ def test_reference_path_on_a_tpu_backend_is_logged_once(caplog):
 # ---------------------------------------------------------------------------
 
 @pytest.fixture(scope="module")
-def chip():
-    """One device of a described TPU v5e: nothing is attached."""
+def topo():
+    """A described four-chip TPU v5e host: nothing is attached."""
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
     try:
-        topo = topologies.get_topology_desc(platform="tpu",
+        return topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
     except Exception as e:  # noqa: BLE001 — any failure means no compiler
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def chip(topo):
+    """One device of it."""
     return topo.devices[0]
+
+
+def _spec(shape, dtype, sharding):
+    """A step argument by shape alone (no array can be put on a described
+    device)."""
+    import jax
+
+    dtype = {"int64": "int32"}.get(str(dtype), str(dtype))
+    return jax.ShapeDtypeStruct(tuple(shape), np.dtype(dtype),
+                                sharding=sharding)
+
+
+def _state(block, names, sharding):
+    return tuple(_spec(v.shape, v.dtype, sharding) for v in
+                 map(block._find_var_recursive, names))
 
 
 @pytest.mark.parametrize("slots,max_seq", [(32, 1408), (8, 3712)])
@@ -344,24 +367,89 @@ def test_decode_step_for_a_described_v5e_reads_the_pools_in_place(
         main, feeds, [fetches["next_token"].name], mesh)
     rep = NamedSharding(mesh, P())
     block = main.global_block()
-
-    def spec(shape, dtype):
-        dtype = {"int64": "int32"}.get(str(dtype), str(dtype))
-        return jax.ShapeDtypeStruct(tuple(shape), np.dtype(dtype),
-                                    sharding=rep)
-
-    def state(names):
-        return tuple(spec(v.shape, v.dtype) for v in
-                     map(block._find_var_recursive, names))
-
     shapes = {"tokens": ((slots, 1), "int32"),
               "positions": ((slots,), "int32"),
               "block_tables": ((slots, max_seq // page), "int32"),
               "live": ((slots,), "int32")}
-    text = fn.lower(tuple(spec(*shapes[n]) for n in feeds), state(mut_in),
-                    state(const_in), spec((), "int32")).compile().as_text()
+    text = fn.lower(tuple(_spec(*shapes[n], rep) for n in feeds),
+                    _state(block, mut_in, rep), _state(block, const_in, rep),
+                    _spec((), "int32", rep)).compile().as_text()
     assert stat_get("attention_lowered_paged_decode") == before + layers_
     assert text.count('custom_call_target="tpu_custom_call"') == layers_
     pool = rf"f32\[{pages},2,{page},128\]"
     assert re.search(pool, text), "no pool in the step's text"
     assert not re.findall(pool + r"\{[^}]*\} copy\(", text)
+
+
+def test_bert_step_for_a_described_v5e_mesh_keeps_the_pallas_kernels(
+        topo, monkeypatch):
+    """A two-layer BERT training step at the dp4 cell's widths (hidden 768,
+    12 heads, sequence 512) and the published 64 sequences a chip, lowered
+    through ``build_sharded_step`` for the described 2x2 mesh: every
+    attention op runs the packed Pallas kernels per ``dp`` shard (Mosaic
+    calls in the text), no float32 ``[B, h, S, bk]`` score block of the
+    blockwise reference is left, and the step fits the chip."""
+    import re
+    import sys
+
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from paddle_tpu import compile_cache
+    from paddle_tpu.parallel import build_sharded_step, dp_mesh
+    from paddle_tpu.parallel import sharded
+
+    sys.path.insert(0, os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    import bench
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(compile_cache, "ensure_compile_cache", lambda: None)
+    monkeypatch.setattr(sharded, "ensure_compile_cache", lambda: None)
+    layers_, chips, per_chip, seq, pred = 2, 4, 64, 512, 77
+    batch = chips * per_chip
+    main_p, _, feed_names, loss, _ = bench.build_bert_train_programs(
+        dict(batch_size=batch, seq_len=seq, vocab_size=30522, hidden=768,
+             num_layers=layers_, num_heads=12, intermediate=3072,
+             max_predictions=pred, use_flash=True, dropout=0.1))
+    names = ("pallas", "pallas_sharded", "blockwise")
+    before = {n: stat_get(f"attention_lowered_{n}") for n in names}
+    mesh = dp_mesh(chips, devices=list(topo.devices)[:chips])
+    fn, mut_in, const_in, _ = build_sharded_step(
+        main_p, feed_names, [loss.name], mesh)
+    rep, dp = NamedSharding(mesh, P()), NamedSharding(mesh, P("dp"))
+    block = main_p.global_block()
+    shapes = {"input_ids": ((batch, seq), "int32"),
+              "token_type_ids": ((batch, seq), "int32"),
+              "attn_mask": ((batch, seq), "float32"),
+              "mlm_positions": ((batch, pred), "int32"),
+              "mlm_labels": ((batch, pred), "int32"),
+              "mlm_weights": ((batch, pred), "float32")}
+    # a compile for a described device is written to the persistent cache
+    # but can never be read back
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        compiled = fn.lower(
+            tuple(_spec(*shapes[n], dp) for n in feed_names),
+            _state(block, mut_in, rep), _state(block, const_in, rep),
+            _spec((), "int32", rep)).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        cc.reset_cache()
+    moved = {n: stat_get(f"attention_lowered_{n}") - before[n]
+             for n in names}
+    # every layer's op, and its re-lowering inside the auto-grad op
+    assert moved == {"pallas": 2 * layers_, "pallas_sharded": 2 * layers_,
+                     "blockwise": 0}
+    text = compiled.as_text()
+    # a layer's forward kernel (once for the op, once more inside its
+    # auto-grad op, as on one chip), its dK/dV and its dQ kernel
+    assert text.count('custom_call_target="tpu_custom_call"') == 4 * layers_
+    assert "all-reduce" in text
+    assert not re.search(rf"f32\[({per_chip}|{batch}),12,{seq},{seq}\]", text)
+    m = compiled.memory_analysis()
+    total = (m.argument_size_in_bytes + m.output_size_in_bytes
+             + m.temp_size_in_bytes - m.alias_size_in_bytes)
+    assert total < 15.75 * 2 ** 30, total
