@@ -23,7 +23,7 @@ __all__ = [
     "adaptive_pool2d", "flash_attention", "flash_attention_qkv",
     "rms_norm", "rope",
     "cached_attention", "kv_pool_write", "kv_pool_gather",
-    "paged_decode_attention",
+    "paged_decode_attention", "block_begin", "block_unmask",
     "linear_chain_crf", "crf_decoding", "warpctc",
     "nce", "hsigmoid", "conv3d", "pool3d", "lrn", "row_conv",
     "shuffle_channel", "temporal_shift", "multiplex",
@@ -538,7 +538,7 @@ def pad(x, paddings, pad_value=0.0, name=None):
 def flash_attention(q, k, v, bias=None, causal=False, scale=None,
                     seq_parallel_mode="ring", impl="auto", layout="bhsd",
                     dropout_prob=0.0, is_test=False, name=None,
-                    window=None):
+                    window=None, mask_block=None, precision=None):
     """Fused multi-head attention; q/k/v: [B, H, S, D] (layout "bhsd")
     or [B, S, H, D] (layout "bshd", impl="xla" only).
 
@@ -552,6 +552,13 @@ def flash_attention(q, k, v, bias=None, causal=False, scale=None,
     window: with ``causal``, query i attends keys j with
     ``i - window < j <= i`` (the window counts the token itself); None
     leaves the op exactly as it was.
+    mask_block: with ``causal``, the block-causal mask of block
+    diffusion: query i attends keys j with ``j // mask_block <= i //
+    mask_block`` (causal across blocks, a block sees itself whole).
+    precision: None leaves the two products (scores, and probabilities
+    times values) at the backend's default, under which a TPU rounds
+    float32 operands to bfloat16; "highest" feeds them whole, whatever
+    the mask (forward only, no padding bias).
     """
     helper = LayerHelper("flash_attention", name=name)
     out = helper.create_variable_for_type_inference(q.dtype)
@@ -562,6 +569,10 @@ def flash_attention(q, k, v, bias=None, causal=False, scale=None,
         attrs["scale"] = float(scale)
     if window is not None:
         attrs["window"] = int(window)
+    if mask_block is not None:
+        attrs["mask_block"] = int(mask_block)
+    if precision is not None:
+        attrs["precision"] = str(precision)
     inputs = {"Q": [q], "K": [k], "V": [v]}
     if bias is not None:
         inputs["Bias"] = [bias]
@@ -627,14 +638,17 @@ def rope(x, base=10000.0, position_offset=0, offset=None, name=None):
 
 
 def kv_pool_write(pool, new, positions, block_table, lengths,
-                  name=None):
+                  name=None, per_head=False):
     """Paged-cache write, in place: ``pool`` [P, Hkv, pt, D] gets row
     (b, t) of ``new`` [B, Hkv, T, D] at logical position
     ``positions[b] + t`` of slot b, routed through ``block_table``
     [B, NP] to a physical page; rows with ``t >= lengths[b]`` go to
     the reserved trash page 0.  The op's output is the pool variable
     itself, so the executor classifies the pool as mutated persistable
-    state → donated buffer (HBM reused, no copy).  Returns the pool
+    state → donated buffer (HBM reused, no copy).  ``per_head`` scatters
+    each (row, head) under its own index, as the one-row step always
+    does: the form for the few rows a slot of a decode grid writes (a
+    prefill chunk's many rows keep the [Hkv, D] window).  Returns the pool
     Variable (now carrying the updated value in the lowered graph)."""
     helper = LayerHelper("kv_pool_write", name=name)
     helper.append_op("kv_pool_write",
@@ -642,7 +656,8 @@ def kv_pool_write(pool, new, positions, block_table, lengths,
                              "Positions": [positions],
                              "BlockTable": [block_table],
                              "Lengths": [lengths]},
-                     outputs={"Out": [pool]})
+                     outputs={"Out": [pool]},
+                     attrs={"per_head": True} if per_head else {})
     return pool
 
 
@@ -687,6 +702,8 @@ def paged_decode_attention(q, pool_k, pool_v, block_table, positions,
     """The paged decode step's attention: ``q`` [B, H, 1, D] (one new
     token per slot) attends pools ``pool_k``/``pool_v`` [P, Hkv, pt, D]
     through ``block_table`` [B, NP] at columns ``j <= positions[b]``.
+    ``q`` [B, H, T, D] is a block of T rows at ``positions[b]`` whose
+    rows all attend ``j <= positions[b] + T - 1`` (no window then).
     A TPU backend reads the live pages in place (Pallas kernel); any
     other runs :func:`kv_pool_gather` + :func:`cached_attention`'s
     formulation, bit for bit.  ``window`` bounds the columns below too
@@ -707,6 +724,37 @@ def paged_decode_attention(q, pool_k, pool_v, block_table, positions,
                              "Positions": [positions]},
                      outputs={"Out": [out]}, attrs=attrs)
     return out
+
+
+def block_begin(tokens, masked, fresh, mask_id, name=None):
+    """Block diffusion (ops/decode_ops.py ``block_begin``): ``tokens``
+    and ``masked`` [S, B] as the last pass left them, except where
+    ``fresh`` [S] is set: there a new block, ``mask_id`` everywhere and
+    every position undecided.  Returns ``(tokens, masked)``."""
+    helper = LayerHelper("block_begin", name=name)
+    t_out = helper.create_variable_for_type_inference(tokens.dtype)
+    m_out = helper.create_variable_for_type_inference(masked.dtype)
+    helper.append_op("block_begin",
+                     inputs={"Tokens": [tokens], "Masked": [masked],
+                             "Fresh": [fresh]},
+                     outputs={"TokensOut": [t_out], "MaskedOut": [m_out]},
+                     attrs={"mask_id": int(mask_id)})
+    return t_out, m_out
+
+
+def block_unmask(logits, tokens, masked, quota, name=None):
+    """Block diffusion's unmasking (ops/decode_ops.py ``block_unmask``):
+    of each slot's undecided positions (``masked`` [S, B] = 1) the
+    ``quota`` [S] whose ``argmax(logits)`` is most confident take that
+    token.  ``logits`` [S, B, V].  Returns ``(tokens, masked)``."""
+    helper = LayerHelper("block_unmask", name=name)
+    t_out = helper.create_variable_for_type_inference(tokens.dtype)
+    m_out = helper.create_variable_for_type_inference(masked.dtype)
+    helper.append_op("block_unmask",
+                     inputs={"Logits": [logits], "Tokens": [tokens],
+                             "Masked": [masked], "Quota": [quota]},
+                     outputs={"TokensOut": [t_out], "MaskedOut": [m_out]})
+    return t_out, m_out
 
 
 def resize_bilinear(input, out_shape=None, scale=None, name=None,
@@ -1097,6 +1145,7 @@ def moe_routed_ffn(x, router_x, num_experts, top_k, d_ff,
     scores highest (float32 logits, softmax over the selected), through
     ``act(x W_gate) * (x W_up)`` then ``W_down``; no capacity, nothing
     dropped.  ``valid`` [B] int: real rows per batch row, for the count.
+    ``activation``: "relu" or "silu".
     ``name`` prefixes the parameters ``.router.w`` [H, E], ``.gate_up.w``
     [E, H, 2 d_ff] and ``.down.w`` [E, d_ff, H].  Returns ``(out,
     expert_count [E] int32, router_logits or None)``."""

@@ -44,10 +44,18 @@ from .. import layers
 #           i - W < j <= i (the window counts the token itself)
 #   rope:   rotary embeddings on q and k, or none at all (NoPE)
 #   ffn:    "dense" (SwiGLU of width ``intermediate``) or a dict
-#           {"experts": E, "top_k": k, "width": I, "activation": "relu"}:
-#           dropless top-k gated experts routed from the layer's RAW
-#           input, before its first norm and its attention
-DEFAULT_LAYER = {"window": None, "rope": True, "ffn": "dense"}
+#           {"experts": E, "top_k": k, "width": I, "activation": "relu",
+#            "route_from": "raw"}: dropless top-k gated experts
+#           (``activation`` "relu" or "silu") routed from the layer's
+#           RAW input, before its first norm and its attention, or with
+#           "route_from": "normed" from what the experts read: the
+#           normed post-attention stream
+#   attn_precision: None (the prefill attention kernel's two products at
+#           the backend's default: a TPU rounds float32 operands to
+#           bfloat16) or "highest" (operands whole, as the paged decode
+#           kernel and the matmuls take float32), whatever the mask
+DEFAULT_LAYER = {"window": None, "rope": True, "ffn": "dense",
+                 "attn_precision": None}
 
 
 def layer_spec(layer_pattern, i):
@@ -111,8 +119,19 @@ def llama_block(x, hidden, num_heads, num_kv_heads, seq_len, head_dim,
                 intermediate, name=None, attn_impl="auto",
                 kv_cache=None, positions=None, collect_kv=False,
                 block_table=None, kv_lengths=None, rms_norm_eps=1e-6,
-                rope_base=10000.0, layer=None, valid=None, taps=None):
+                rope_base=10000.0, layer=None, valid=None, taps=None,
+                qk_norm=False, mask_block=None, block=False):
     """One decoder layer. x: [B, S, H].
+
+    ``qk_norm``: q and k are RMS-normalised over ``head_dim`` with a
+    learned weight each (``.q_norm`` / ``.k_norm``) before RoPE.
+    ``mask_block`` (uncached and ``collect_kv`` modes): the attention
+    mask is block-causal, row i admits column j iff ``j // mask_block
+    <= i // mask_block`` (block diffusion's prefill).  ``block`` (with
+    ``kv_cache``): the ``seq_len`` rows of a batch row are one block at
+    ``positions[b]`` whose rows all attend the committed columns and
+    the whole block (``paged_decode_attention`` with that many rows: a
+    denoising or a commit pass of block diffusion).
 
     ``layer`` is the layer's entry of the model's pattern
     (:data:`DEFAULT_LAYER`; None is the default: full causal attention,
@@ -164,6 +183,9 @@ def llama_block(x, hidden, num_heads, num_kv_heads, seq_len, head_dim,
 
     q, k, v = heads(q, num_heads), heads(k, num_kv_heads), \
         heads(v, num_kv_heads)
+    if qk_norm:
+        q = layers.rms_norm(q, epsilon=rms_norm_eps, param_attr=p("q_norm"))
+        k = layers.rms_norm(k, epsilon=rms_norm_eps, param_attr=p("k_norm"))
     if layer["rope"]:
         offset = positions if kv_cache is not None else None
         q = layers.rope(q, base=rope_base, offset=offset)
@@ -176,12 +198,17 @@ def llama_block(x, hidden, num_heads, num_kv_heads, seq_len, head_dim,
         # read makes the fresh rows visible (the mask admits
         # j <= positions[b] + t, which includes this step's own columns)
         cache_k, cache_v = kv_cache
+        # a block's few rows a slot scatter as the one-row step's do
+        per_head = {"per_head": True} if block else {}
         cache_k = layers.kv_pool_write(cache_k, k, positions,
-                                       block_table, kv_lengths)
+                                       block_table, kv_lengths, **per_head)
         cache_v = layers.kv_pool_write(cache_v, v, positions,
-                                       block_table, kv_lengths)
-        if seq_len == 1:
-            # the decode step: live pages in place
+                                       block_table, kv_lengths, **per_head)
+        if seq_len == 1 or block:
+            # the decode step: live pages in place.  A block's rows are
+            # written before they are read, every pass: the pass whose
+            # input holds no mask token leaves the K/V later blocks
+            # attend, and nothing is rolled back
             attn = layers.paged_decode_attention(
                 q, cache_k, cache_v, block_table, positions, **win)
         else:
@@ -207,6 +234,10 @@ def llama_block(x, hidden, num_heads, num_kv_heads, seq_len, head_dim,
                                           head_dim])
 
             k, v = expand_kv(k), expand_kv(v)
+        if mask_block is not None:
+            win = dict(win, mask_block=mask_block)
+        if layer.get("attn_precision") is not None:
+            win = dict(win, precision=layer["attn_precision"])
         attn = layers.flash_attention(q, k, v, causal=True,
                                       impl=attn_impl, **win)
     attn = layers.transpose(attn, [0, 2, 1, 3])
@@ -227,7 +258,8 @@ def llama_block(x, hidden, num_heads, num_kv_heads, seq_len, head_dim,
     else:
         taps = taps if taps is not None else {}
         y, counts, logits = layers.moe_routed_ffn(
-            h, x_in, ffn["experts"], ffn["top_k"], ffn["width"],
+            h, h if ffn.get("route_from", "raw") == "normed" else x_in,
+            ffn["experts"], ffn["top_k"], ffn["width"],
             activation=ffn.get("activation", "relu"), valid=valid,
             name=p("moe"), keep_router_logits=bool(taps.get("keep_logits")))
         taps.setdefault("counts", []).append(counts)
@@ -242,12 +274,14 @@ def llama_block(x, hidden, num_heads, num_kv_heads, seq_len, head_dim,
 def llama(input_ids, vocab_size=32000, hidden=4096, num_layers=32,
           num_heads=32, num_kv_heads=None, intermediate=11008,
           seq_len=2048, name=None, attn_impl="auto", head_dim=None,
-          rms_norm_eps=1e-6, rope_base=10000.0, layer_pattern=None):
+          rms_norm_eps=1e-6, rope_base=10000.0, layer_pattern=None,
+          qk_norm=False, mask_block=None):
     """Returns logits [B, S, V]. input_ids: [B, S] int64.
 
     ``head_dim`` defaults to ``hidden // num_heads`` (a model may
     publish another: q is then ``num_heads * head_dim`` wide);
-    ``layer_pattern`` is described at :data:`DEFAULT_LAYER`.  The
+    ``layer_pattern`` is described at :data:`DEFAULT_LAYER`, ``qk_norm``
+    and ``mask_block`` at :func:`llama_block`.  The
     defaults build exactly the program they always did."""
     num_kv_heads = num_kv_heads or num_heads
     head_dim = head_dim or hidden // num_heads
@@ -260,7 +294,8 @@ def llama(input_ids, vocab_size=32000, hidden=4096, num_layers=32,
                         name=f"{name}.blk{i}" if name else None,
                         attn_impl=attn_impl, rms_norm_eps=rms_norm_eps,
                         rope_base=rope_base,
-                        layer=layer_spec(layer_pattern, i))
+                        layer=layer_spec(layer_pattern, i),
+                        qk_norm=qk_norm, mask_block=mask_block)
     x = layers.rms_norm(x, epsilon=rms_norm_eps, param_attr=p("ln_f"))
     return _linear(x, vocab_size, pname=p("head.w"))
 
@@ -312,9 +347,20 @@ def build_llama_prefill(batch_size, seq_len, vocab_size=32000,
                         paged=None, num_pages=None, page_tokens=None,
                         head_dim=None, rms_norm_eps=1e-6,
                         rope_base=10000.0, layer_pattern=None,
-                        num_window_pages=None, keep_router_logits=False):
+                        num_window_pages=None, keep_router_logits=False,
+                        qk_norm=False, mask_block=None):
     """Prefill entry point: one causal forward over the (padded) prompt
     that populates a decode cache in one shot.
+
+    ``mask_block=B`` (block diffusion; the paged mode only): the forward
+    runs under the block-causal mask and only commits K/V — the engine
+    feeds ``prompt_len`` = the prompt's whole blocks, and the first
+    generated block (with the prompt's tail at its head) is denoised by
+    passes of :func:`build_llama_decode`.  There is then no head, no
+    ``last_pos`` feed and no ``logits`` / ``next_token``: the fetches are
+    ``rows_written`` [1] (the ``prompt_len`` fed), the expert counts and,
+    with ``keep_router_logits``, ``router_logits`` [B, L_moe, S, E] of
+    every row.
 
     Sliding-window layers keep their pages in pools of
     their own (``num_window_pages`` pages each) behind a second feed
@@ -357,9 +403,15 @@ def build_llama_prefill(batch_size, seq_len, vocab_size=32000,
     head_dim = head_dim or hidden // num_heads
     input_ids = layers.data("input_ids", [batch_size, seq_len],
                             dtype="int64", append_batch_size=False)
-    last_pos = layers.data("last_pos", [batch_size], dtype="int64",
-                           append_batch_size=False)
-    feeds = ["input_ids", "last_pos"]
+    feeds = ["input_ids"]
+    last_pos = None
+    if mask_block is None:
+        last_pos = layers.data("last_pos", [batch_size], dtype="int64",
+                               append_batch_size=False)
+        feeds.append("last_pos")
+    elif cache_slots is None:
+        raise ValueError("a block-causal prefill (mask_block) only "
+                         "commits K/V: it needs the paged cache")
     block_table = bt_window = prompt_len = zero_pos = None
     windowed = window_layers(layer_pattern, num_layers)
     if cache_slots is not None:
@@ -404,7 +456,8 @@ def build_llama_prefill(batch_size, seq_len, vocab_size=32000,
                               collect_kv=True, rms_norm_eps=rms_norm_eps,
                               rope_base=rope_base,
                               layer=layer_spec(layer_pattern, i),
-                              valid=valid, taps=taps)
+                              valid=valid, taps=taps, qk_norm=qk_norm,
+                              mask_block=mask_block)
         if block_table is not None:
             # paged: the prompt's K/V scatter across the slot's pages
             # from logical position 0; pad-tail rows (>= prompt_len)
@@ -420,6 +473,11 @@ def build_llama_prefill(batch_size, seq_len, vocab_size=32000,
                     bt_window if in_window else block_table, prompt_len)
         else:
             kvs.append((k, v))
+    if mask_block is not None:
+        # (kept router logits are every row's, [B, L_moe, S, E]: no row
+        # is yielded, so none is picked)
+        return feeds, dict({"rows_written": prompt_len + 0},
+                           **_taps_fetches(taps))
     logits = _head_on_rows(x, last_pos, vocab_size, name, rms_norm_eps)
     return feeds, _prefill_fetches(logits, kvs, taps, last_pos)
 
@@ -448,8 +506,27 @@ def build_llama_decode(num_slots, max_seq_len, vocab_size=32000,
                        name="llama", paged=None, num_pages=None,
                        page_tokens=None, head_dim=None, rms_norm_eps=1e-6,
                        rope_base=10000.0, layer_pattern=None,
-                       num_window_pages=None, keep_router_logits=False):
+                       num_window_pages=None, keep_router_logits=False,
+                       qk_norm=False, block=None, mask_id=None):
     """Cached decode step over a fixed slot grid.
+
+    ``block=B`` (block diffusion, with ``mask_id``; full-attention
+    layers only) makes a slot's rows a block of B positions at
+    ``positions`` (the block's base), and one run a **pass**: feeds
+    ``tokens`` [slots, B] int64 and ``masked`` [slots, B] int32 (1 = the
+    position is undecided and holds ``mask_id``), ``quota`` [slots] int32
+    (how many undecided positions this pass decides; 0 = a commit pass,
+    whose input holds no mask) and ``fresh`` [slots] int32 (1 = ignore
+    ``tokens`` / ``masked``: the slot starts a new block, all mask).  One
+    program serves slots in any mix of phases.  Every pass writes the
+    block's K/V at ``base .. base+B-1`` before its rows read them (all of
+    the block, and the committed columns before it), so the commit pass
+    leaves what later blocks attend and nothing is rolled back.  The
+    logits of position i predict the token AT position i.  Fetches:
+    ``tokens`` / ``masked`` [slots, B] after this pass's decisions (the
+    next pass's feeds as the device holds them), ``logits`` [slots, B, V],
+    and the expert layers' ``expert_counts`` / ``router_logits``
+    [slots, L_moe, B, E].
 
     Sliding-window layers read pools of
     ``num_window_pages`` pages through a feed of their own,
@@ -480,7 +557,8 @@ def build_llama_decode(num_slots, max_seq_len, vocab_size=32000,
         raise ValueError("paged decode needs num_pages and page_tokens")
     num_kv_heads = num_kv_heads or num_heads
     head_dim = head_dim or hidden // num_heads
-    tokens = layers.data("tokens", [num_slots, 1], dtype="int64",
+    rows = int(block) if block else 1
+    tokens = layers.data("tokens", [num_slots, rows], dtype="int64",
                          append_batch_size=False)
     positions = layers.data("positions", [num_slots], dtype="int32",
                             append_batch_size=False)
@@ -492,6 +570,23 @@ def build_llama_decode(num_slots, max_seq_len, vocab_size=32000,
     feeds = ["tokens", "positions", "block_tables", "live"]
     bt_window = None
     windowed = window_layers(layer_pattern, num_layers)
+    masked = quota = None
+    n_rows = live          # real rows a slot: the K/V written, the count
+    if block:
+        if windowed:
+            raise ValueError("a block of rows a slot shares its columns; "
+                             "sliding-window layers give each row its own")
+        if mask_id is None:
+            raise ValueError("block decode needs mask_id")
+        masked = layers.data("masked", [num_slots, rows], dtype="int32",
+                             append_batch_size=False)
+        quota = layers.data("quota", [num_slots], dtype="int32",
+                            append_batch_size=False)
+        fresh = layers.data("fresh", [num_slots], dtype="int32",
+                            append_batch_size=False)
+        feeds += ["masked", "quota", "fresh"]
+        tokens, masked = layers.block_begin(tokens, masked, fresh, mask_id)
+        n_rows = live * rows
     if windowed:
         if not num_window_pages:
             raise ValueError("paged decode with sliding-window "
@@ -500,11 +595,11 @@ def build_llama_decode(num_slots, max_seq_len, vocab_size=32000,
                                 [num_slots, np_slot], dtype="int32",
                                 append_batch_size=False)
         feeds.append("block_tables_window")
-    block = default_main_program().global_block()
+    gblock = default_main_program().global_block()
     cache_names = []
     caches = []
     for i in range(num_layers):
-        ck, cv = _kv_vars(block, name, i, [
+        ck, cv = _kv_vars(gblock, name, i, [
             num_window_pages if i in windowed else num_pages,
             num_kv_heads, page_tokens, head_dim])
         caches.append((ck, cv))
@@ -513,17 +608,24 @@ def build_llama_decode(num_slots, max_seq_len, vocab_size=32000,
                          param_attr=f"{name}.embed")
     taps = {"keep_logits": keep_router_logits}
     for i, (ck, cv) in enumerate(caches):
-        x = llama_block(x, hidden, num_heads, num_kv_heads, 1, head_dim,
-                        intermediate, name=f"{name}.blk{i}",
+        x = llama_block(x, hidden, num_heads, num_kv_heads, rows,
+                        head_dim, intermediate, name=f"{name}.blk{i}",
                         kv_cache=(ck, cv), positions=positions,
                         block_table=bt_window if i in windowed
-                        else block_tables, kv_lengths=live,
+                        else block_tables, kv_lengths=n_rows,
                         rms_norm_eps=rms_norm_eps, rope_base=rope_base,
-                        layer=layer_spec(layer_pattern, i), valid=live,
-                        taps=taps)
+                        layer=layer_spec(layer_pattern, i), valid=n_rows,
+                        taps=taps, qk_norm=qk_norm, block=bool(block))
     x = layers.rms_norm(x, epsilon=rms_norm_eps,
                         param_attr=f"{name}.ln_f")
     logits = _linear(x, vocab_size, pname=f"{name}.head.w")  # [slots,1,V]
+    if block:
+        new_tokens, new_masked = layers.block_unmask(logits, tokens,
+                                                     masked, quota)
+        fetches = {"logits": logits, "tokens": new_tokens,
+                   "masked": new_masked}
+        fetches.update(_taps_fetches(taps))
+        return feeds, fetches, cache_names
     logits = layers.squeeze(logits, [1])                     # [slots, V]
     next_token = layers.argmax(logits, axis=-1)              # [slots]
     fetches = {"logits": logits, "next_token": next_token}
@@ -536,7 +638,7 @@ def build_llama_decode(num_slots, max_seq_len, vocab_size=32000,
 def _chunk_forward(chunk_len, max_seq_len, num_pages, page_tokens,
                    vocab_size, hidden, num_layers, num_heads, num_kv_heads,
                    intermediate, name, head_dim=None, rms_norm_eps=1e-6,
-                   rope_base=10000.0, layer_pattern=None):
+                   rope_base=10000.0, layer_pattern=None, qk_norm=False):
     """The forward that the chunk and the verify programs share: C new
     tokens at ``base`` attend the slot's pages plus themselves causally.
     Returns ``(feed_names, x [1, C, H] before the final norm,
@@ -578,7 +680,7 @@ def _chunk_forward(chunk_len, max_seq_len, num_pages, page_tokens,
                         block_table=block_table, kv_lengths=ck_len,
                         rms_norm_eps=rms_norm_eps, rope_base=rope_base,
                         layer=layer_spec(layer_pattern, i), valid=ck_len,
-                        taps=taps)
+                        taps=taps, qk_norm=qk_norm)
     return ["chunk_ids", "base", "block_table", "chunk_len"], x, \
         cache_names, taps
 

@@ -204,6 +204,22 @@ def _flash_attention(ctx, op):
         raise NotImplementedError(
             "flash_attention: a sliding window needs causal=True and "
             "no padding bias")
+    # block-causal (block diffusion): j // mask_block <= i // mask_block
+    mask_block = op.attr("mask_block", None)
+    if mask_block is not None and (not causal or bias is not None
+                                   or window is not None):
+        raise NotImplementedError(
+            "flash_attention: mask_block needs causal=True, no padding "
+            "bias and no sliding window")
+    blk = {} if mask_block is None else {"mask_block": int(mask_block)}
+    # "highest": float32 operands reach both products whole, whatever
+    # the mask; None is each route's default (the model's layer decides)
+    precision = op.attr("precision", None)
+    if precision is not None and bias is not None:
+        raise NotImplementedError(
+            "flash_attention: precision with a padding bias not "
+            "supported (the biased kernel trains at the default)")
+    prec = {} if precision is None else {"precision": str(precision)}
 
     if op.attr("impl", "auto") == "xla":
         if SP_AXIS in (getattr(ctx, "axis_names", ()) or ()):
@@ -224,12 +240,15 @@ def _flash_attention(ctx, op):
         scale = sm_scale if sm_scale is not None else 1.0 / (d ** 0.5)
         eq = ("bqhd,bkhd->bhqk" if layout == "bshd"
               else "bhqd,bhkd->bhqk")
-        s = jnp.einsum(eq, q, k) * scale
+        s = jnp.einsum(eq, q, k, **prec) * scale
         if bias is not None:
             s = s + bias[:, None, None, :].astype(s.dtype)
         if causal:
             S = s.shape[-1]
             keep = jnp.tril(jnp.ones((S, S), bool))
+            if mask_block is not None:
+                blk_of = jnp.arange(S) // int(mask_block)
+                keep = blk_of[:, None] >= blk_of[None, :]
             if window is not None:
                 # i - window < j <= i: the window counts the token itself
                 keep = keep & ~jnp.tril(jnp.ones((S, S), bool),
@@ -243,16 +262,17 @@ def _flash_attention(ctx, op):
             p = jnp.where(keep, p / (1.0 - prob), 0.0).astype(p.dtype)
         eo = ("bhqk,bkhd->bqhd" if layout == "bshd"
               else "bhqk,bhkd->bhqd")
-        out = jnp.einsum(eo, p, v)
+        out = jnp.einsum(eo, p, v, **prec)
         _lowered("xla", window=window)
         ctx.set_output(op, "Out", out)
         return
 
     axes = getattr(ctx, "axis_names", ()) or ()
     if SP_AXIS in axes:
-        if bias is not None or window is not None:
+        if bias is not None or window is not None or blk or prec:
             raise NotImplementedError(
-                "flash_attention: padding bias or a sliding window under "
+                "flash_attention: padding bias, a sliding window, a "
+                "block-causal mask or a set precision under "
                 "sequence parallelism not supported yet — pad-free "
                 "bucketing or causal only")
         fn = ring_attention if mode == "ring" else ulysses_attention
@@ -268,7 +288,7 @@ def _flash_attention(ctx, op):
         # formulation, which the partitioner shards as it likes
         out, _ = blockwise_attention(q, k, v, causal=causal,
                                      sm_scale=sm_scale, bias=bias,
-                                     window=window)
+                                     window=window, **blk, **prec)
         _lowered("blockwise", how and f"flash_attention: {how}",
                  window=window)
     else:
@@ -280,7 +300,9 @@ def _flash_attention(ctx, op):
                               (q, k, v, bias), (_BHSD, _BHSD, _BHSD, _BS),
                               _BHSD, causal=causal, sm_scale=sm_scale)
         else:
-            kw = {} if window is None else {"window": int(window)}
+            kw = dict(blk, **prec)
+            if window is not None:
+                kw["window"] = int(window)
             out = call_kernel(ctx.mesh, how, flash_attention, (q, k, v),
                               (_BHSD,) * 3, _BHSD, causal=causal,
                               sm_scale=sm_scale, **kw)

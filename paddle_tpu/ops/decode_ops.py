@@ -26,7 +26,7 @@ per-slot block table that maps logical page index -> physical page.
 All are inference-only (``grad=None``): the decode path never trains.
 
 ``paged_decode_attention`` is the decode step's attention (one
-query token per slot).  Its contract has two halves.  On a TPU backend
+query token per slot, or one block of rows that see their whole block).  Its contract has two halves.  On a TPU backend
 it is a Pallas kernel (``ops/pallas/paged_attention.py``) that reads
 each slot's **live** pages in place through the block table — no dense
 view, no GQA expansion, no contraction over dead columns — and is held
@@ -87,8 +87,9 @@ def _kv_pool_write(ctx, op):
     rows = jnp.transpose(new, (0, 2, 1, 3)).reshape(B * T, Hkv, D)
     rows = rows.astype(pool.dtype)
     phys, off = phys.reshape(-1), off.reshape(-1)
-    if T == 1:
-        # the decode step: every (row, head) is its own index, so the
+    if T == 1 or op.attr("per_head", False):
+        # the decode step (one row a slot, or with ``per_head`` a short
+        # block of rows): every (row, head) is its own index, so the
         # scatter's window is the D lanes alone and the pool keeps its
         # row-major layout.  With the [Hkv, D] window below XLA:TPU lays
         # the pool out {D, Hkv, pt, P} for the scatter and copies every
@@ -148,10 +149,12 @@ def _cached_attn_infer(op, block):
     set_out(op, block, "Out", q.shape, q.dtype)
 
 
-def _attend_cache(q, k, v, pos, scale=None, window=None):
+def _attend_cache(q, k, v, pos, scale=None, window=None, block=False):
     """Q [B, H, T, D] over the logical cache view K/V [B, Hkv, S, D] with the
     validity rule ``j <= pos[b] + t`` (and, under a sliding ``window``,
-    ``j > pos[b] + t - window``): the einsum formulation."""
+    ``j > pos[b] + t - window``): the einsum formulation.  With ``block``
+    the T rows are one block whose rows all see the whole block: ``j <=
+    pos[b] + T - 1`` for every row."""
     import jax
     import jax.numpy as jnp
 
@@ -181,7 +184,7 @@ def _attend_cache(q, k, v, pos, scale=None, window=None):
     # the PV contraction are bit-identical to the shorter uncached row
     j = jnp.arange(S, dtype=jnp.int32)[None, None, None, :]
     t = jnp.arange(T, dtype=jnp.int32)[None, None, :, None]
-    limit = pos[:, None, None, None] + t
+    limit = pos[:, None, None, None] + (T - 1 if block else t)
     keep = j <= limit
     if window is not None:
         keep = keep & (j > limit - int(window))
@@ -215,7 +218,12 @@ def _paged_decode_attention(ctx, op):
     [B, H, 1, D] over the pools PoolK/PoolV [P, Hkv, pt, D] through
     BlockTable [B, NP]; Positions [B] as in ``cached_attention`` (the
     column ``positions[b]`` this step's ``kv_pool_write`` filled is
-    attended: the pool inputs are that op's outputs).
+    attended: the pool inputs are that op's outputs).  Q [B, H, T, D]
+    with T > 1 is a block of T rows a slot at ``positions[b] ..
+    positions[b] + T - 1`` (block diffusion): every row attends the
+    committed columns and the whole block, ``j <= positions[b] + T -
+    1``, so the rows of a slot share their columns and one page walk
+    serves them all.
 
     On a TPU backend, one device, at a shape the kernel takes (``D`` a
     multiple of 128, ``pt`` of 8) this is the Pallas kernel of
@@ -242,24 +250,96 @@ def _paged_decode_attention(ctx, op):
     # left of it may point at the trash page (masked in both lowerings)
     window = op.attr("window", None)
 
+    rows = q.shape[2]
+    if rows > 1 and window is not None:
+        raise NotImplementedError(
+            "paged_decode_attention: a block of query rows under a "
+            "sliding window (each row would admit its own columns)")
     on_tpu = jax.default_backend() == "tpu"
     n_mesh = ctx.mesh.devices.size if ctx.mesh is not None else 1
-    fits = paged_attention.supported(q.shape, pool_k.shape)
+    fits = paged_attention.supported(q.shape, pool_k.shape, window)
     if on_tpu and n_mesh == 1 and fits:
         kw = {} if window is None else {"window": int(window)}
+        # the kernel takes the last column a slot admits
+        last = pos if rows == 1 else pos + (rows - 1)
         out = paged_attention.paged_decode_attention(
-            q, pool_k, pool_v, bt, pos, scale=scale, **kw)
+            q, pool_k, pool_v, bt, last, scale=scale, **kw)
         _lowered("paged_decode", window=window)
     else:
+        kw = {} if rows == 1 else {"block": True}
         out = _attend_cache(q, _gather_pages(pool_k, bt),
                             _gather_pages(pool_v, bt), pos, scale,
-                            window)
+                            window, **kw)
         reason = None
         if on_tpu:
             reason = (f"paged_decode_attention under a {n_mesh}-device "
                       f"mesh" if n_mesh > 1 else
                       f"paged_decode_attention with Q {q.shape} over "
-                      f"pages {pool_k.shape[1:]} (kernel needs one query "
-                      f"token, head_dim % 128 == 0, page_tokens % 8 == 0)")
+                      f"pages {pool_k.shape[1:]} (kernel needs head_dim "
+                      f"% 128 == 0, page_tokens % 8 == 0, at most "
+                      f"{paged_attention.MAX_GROUP_ROWS} query rows a "
+                      f"KV head)")
         _lowered("paged_decode_reference", reason, window=window)
     ctx.set_output(op, "Out", out)
+
+
+# ---------------------------------------------------------------------------
+# block diffusion: a decode step whose unit is a block of positions
+# ---------------------------------------------------------------------------
+
+def _block_pair_infer(op, block):
+    t = in_var(op, block, "Tokens")
+    m = in_var(op, block, "Masked")
+    set_out(op, block, "TokensOut", t.shape, t.dtype)
+    set_out(op, block, "MaskedOut", m.shape, m.dtype)
+
+
+@register_op("block_begin", infer=_block_pair_infer, grad=None)
+def _block_begin(ctx, op):
+    """The block a slot's pass works on: Tokens [S, B] and Masked [S, B]
+    (1 = undecided) as the pass before left them on the device, or, for
+    a slot with Fresh [S] set, a new block: the ``mask_id`` token at
+    every position, all of them undecided.  So the pass after a commit
+    starts the next block without the host having read the last."""
+    import jax.numpy as jnp
+
+    tokens = ctx.get_input(op, "Tokens")
+    masked = ctx.get_input(op, "Masked")
+    fresh = ctx.get_input(op, "Fresh").astype(bool)[:, None]
+    mask_id = jnp.asarray(int(op.attr("mask_id")), tokens.dtype)
+    ctx.set_output(op, "TokensOut", jnp.where(fresh, mask_id, tokens))
+    ctx.set_output(op, "MaskedOut",
+                   jnp.where(fresh, jnp.ones_like(masked), masked))
+
+
+@register_op("block_unmask", infer=_block_pair_infer, grad=None)
+def _block_unmask(ctx, op):
+    """One denoising pass's decision, on the device: Logits [S, B, V] of
+    a block's B positions, Tokens [S, B], Masked [S, B] (1 = undecided),
+    Quota [S] int.  Every undecided position proposes ``x0 =
+    argmax(logits)`` with the confidence ``softmax(logits)[x0]``; the
+    ``quota`` undecided positions of highest confidence (ties to the
+    lower index) take their ``x0`` and are decided from here on.  Quota
+    0 (a commit pass, an idle slot) changes nothing."""
+    import jax.numpy as jnp
+
+    logits = ctx.get_input(op, "Logits").astype(jnp.float32)
+    tokens = ctx.get_input(op, "Tokens")
+    masked_in = ctx.get_input(op, "Masked")
+    masked = masked_in.astype(bool)
+    quota = ctx.get_input(op, "Quota").astype(jnp.int32)
+    B = tokens.shape[1]
+    x0 = jnp.argmax(logits, axis=-1).astype(tokens.dtype)
+    top = logits.max(axis=-1, keepdims=True)
+    # softmax(logits)[argmax] = 1 / sum(exp(logits - max))
+    conf = 1.0 / jnp.exp(logits - top).sum(axis=-1)
+    conf = jnp.where(masked, conf, -jnp.inf)
+    ci, cj = conf[:, :, None], conf[:, None, :]
+    idx = jnp.arange(B)
+    ahead = (cj > ci) | ((cj == ci) & (idx[None, None, :]
+                                       < idx[None, :, None]))
+    rank = (ahead & masked[:, None, :]).sum(axis=-1)
+    fix = masked & (rank < quota[:, None])
+    ctx.set_output(op, "TokensOut", jnp.where(fix, x0, tokens))
+    ctx.set_output(op, "MaskedOut",
+                   jnp.where(fix, jnp.zeros_like(masked_in), masked_in))

@@ -69,7 +69,7 @@ def _block_loop(n_blocks, lower, upper, body, init):
 
 def blockwise_attention(q, k, v, causal=False, sm_scale=None,
                         block_k=DEFAULT_BLOCK_K, kv_offset=0, bias=None,
-                        window=None):
+                        window=None, mask_block=None, precision=None):
     """Online-softmax attention, scanning kv blocks.
 
     q: [B, H, Sq, D], k/v: [B, H, Sk, D]. kv_offset shifts the global kv
@@ -79,6 +79,10 @@ def blockwise_attention(q, k, v, causal=False, sm_scale=None,
     window: with ``causal``, query i attends keys j with
     ``i - window < j <= i`` (a sliding window that counts the token
     itself); None is plain causal.
+    mask_block: with ``causal``, query i attends keys j with ``j //
+    mask_block <= i // mask_block`` (block-causal: a block of that many
+    positions sees itself whole and every earlier block).
+    precision: of the two products (None: the backend's default).
     Returns (out, (m, l)): out [B,H,Sq,D], m/l the softmax running stats
     [B,H,Sq] (used by ring accumulation).
     """
@@ -108,12 +112,16 @@ def blockwise_attention(q, k, v, causal=False, sm_scale=None,
     def body(carry, blk):
         m, l, acc, j = carry
         kb, vb = blk[:2]
-        s = jnp.einsum("bhqd,bhkd->bhqk", qf, kb)  # [B,H,Sq,bk]
+        s = jnp.einsum("bhqd,bhkd->bhqk", qf, kb,
+                       precision=precision)        # [B,H,Sq,bk]
         if len(blk) == 3:
             s = s + blk[2][:, None, None, :]
         if causal:
             k_pos = j * bk + jnp.arange(bk)[None, :] + kv_offset
-            mask = q_pos >= k_pos
+            if mask_block is None:
+                mask = q_pos >= k_pos
+            else:
+                mask = q_pos // mask_block >= k_pos // mask_block
             if window is not None:
                 mask = mask & (q_pos - k_pos < window)
             s = jnp.where(mask[None, None], s, NEG_INF)
@@ -125,7 +133,7 @@ def blockwise_attention(q, k, v, causal=False, sm_scale=None,
         corr = jnp.where(m <= NEG_INF / 2, 0.0, jnp.exp(m - m_new))
         l_new = l * corr + p.sum(-1)
         acc_new = acc * corr[..., None] + jnp.einsum(
-            "bhqk,bhkd->bhqd", p, vb)
+            "bhqk,bhkd->bhqd", p, vb, precision=precision)
         return (m_new, l_new, acc_new, j + 1), None
 
     # derive initializers from qf so they inherit any shard_map
@@ -143,7 +151,8 @@ def blockwise_attention(q, k, v, causal=False, sm_scale=None,
 # ---------------------------------------------------------------------------
 
 def _fa_fwd_kernel(q_ref, k_ref, v_ref, *rest, block_k, causal, scale,
-                   seq_k, has_bias=False, window=None):
+                   seq_k, has_bias=False, window=None, mask_block=None,
+                   precision=None):
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -156,6 +165,9 @@ def _fa_fwd_kernel(q_ref, k_ref, v_ref, *rest, block_k, causal, scale,
     q = q_ref[0].astype(jnp.float32) * scale          # [Bq, D]
     bq, d = q.shape
     nk = seq_k // block_k
+    # None: the MXU's default (float32 operands rounded to bf16)
+    prec = {} if precision is None \
+        else {"precision": jax.lax.Precision(precision)}
 
     def body(j, carry):
         m, l, acc = carry
@@ -163,7 +175,7 @@ def _fa_fwd_kernel(q_ref, k_ref, v_ref, *rest, block_k, causal, scale,
         vb = v_ref[0, pl.ds(j * block_k, block_k), :].astype(jnp.float32)
         s = jax.lax.dot_general(
             q, kb, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)        # [Bq, Bk]
+            preferred_element_type=jnp.float32, **prec)  # [Bq, Bk]
         if has_bias:
             bb = b_ref[0, 0, pl.ds(j * block_k, block_k)].astype(
                 jnp.float32)
@@ -173,7 +185,12 @@ def _fa_fwd_kernel(q_ref, k_ref, v_ref, *rest, block_k, causal, scale,
                 jnp.int32, (bq, block_k), 0)
             k_pos = j * block_k + jax.lax.broadcasted_iota(
                 jnp.int32, (bq, block_k), 1)
-            keep = q_pos >= k_pos
+            if mask_block is None:
+                keep = q_pos >= k_pos
+            else:
+                # block-causal: a block of mask_block positions sees
+                # itself whole
+                keep = q_pos // mask_block >= k_pos // mask_block
             if window is not None:
                 keep = keep & (q_pos - k_pos < window)
             s = jnp.where(keep, s, NEG_INF)
@@ -182,7 +199,7 @@ def _fa_fwd_kernel(q_ref, k_ref, v_ref, *rest, block_k, causal, scale,
         corr = jnp.exp(m - m_new)
         l_new = l * corr + p.sum(-1, keepdims=True)
         acc_new = acc * corr + jnp.dot(
-            p, vb, preferred_element_type=jnp.float32)
+            p, vb, preferred_element_type=jnp.float32, **prec)
         return m_new, l_new, acc_new
 
     m0 = jnp.full((bq, 1), NEG_INF, jnp.float32)
@@ -190,8 +207,13 @@ def _fa_fwd_kernel(q_ref, k_ref, v_ref, *rest, block_k, causal, scale,
     acc0 = jnp.zeros((bq, d), jnp.float32)
     lower = 0
     if causal:
-        # kv blocks past this q block's last row are fully masked
-        upper = jnp.minimum(nk, ((qi + 1) * bq + block_k - 1) // block_k)
+        # kv blocks past this q block's last row are fully masked (with
+        # mask_block: past the end of that row's block of positions)
+        rows_end = (qi + 1) * bq
+        if mask_block is not None:
+            rows_end = (rows_end + mask_block - 1) // mask_block \
+                * mask_block
+        upper = jnp.minimum(nk, (rows_end + block_k - 1) // block_k)
         if window is not None:
             # and so are those wholly left of the band of its first
             # row.  A later row of the block may find the first block
@@ -214,7 +236,7 @@ _VMEM_ASK_OVER = 10 << 20
 
 
 def _flash_forward(q, k, v, causal, sm_scale, block_q, block_k, interpret,
-                   bias=None, window=None):
+                   bias=None, window=None, mask_block=None, precision=None):
     """Returns (out [B,H,Sq,D], lse [B,H,Sq] f32)."""
     import jax
     from jax.experimental import pallas as pl
@@ -233,6 +255,13 @@ def _flash_forward(q, k, v, causal, sm_scale, block_q, block_k, interpret,
         raise ValueError("flash attention: a sliding window needs "
                          "causal=True")
     kw = {} if window is None else {"window": int(window)}
+    if mask_block is not None:
+        if not causal:
+            raise ValueError("flash attention: mask_block needs "
+                             "causal=True")
+        kw["mask_block"] = int(mask_block)
+    if precision is not None:
+        kw["precision"] = precision
     kernel = functools.partial(_fa_fwd_kernel, block_k=bk, causal=causal,
                                scale=scale, seq_k=Sk,
                                has_bias=bias is not None, **kw)
@@ -486,29 +515,40 @@ def _flash_backward(q, k, v, out, lse, g, causal, sm_scale, block_q,
 # public entries: pallas forward + pallas backward via custom_vjp
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+@functools.partial(jax.custom_vjp,
+                   nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10))
 def flash_attention(q, k, v, causal=False, sm_scale=None,
                     block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K,
-                    interpret=False, window=None):
+                    interpret=False, window=None, mask_block=None,
+                    precision=None):
     """Multi-head attention, q/k/v: [B, H, S, D] -> [B, H, Sq, D].
     ``window`` (with ``causal``): query i attends keys j with
     ``i - window < j <= i``; key blocks wholly left of the band are
-    skipped as those above the diagonal are.  Forward only."""
+    skipped as those above the diagonal are.  ``mask_block`` (with
+    ``causal``): the block-causal mask, ``j // mask_block <= i //
+    mask_block``.  ``precision`` ("highest"; None is the MXU's default,
+    which rounds float32 operands to bf16): of the kernel's two products,
+    whatever the mask.  All three forward only."""
     return _flash_forward(q, k, v, causal, sm_scale, block_q, block_k,
-                          interpret, window=window)[0]
+                          interpret, window=window, mask_block=mask_block,
+                          precision=precision)[0]
 
 
 def _fa_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret,
-            window):
+            window, mask_block, precision):
     out, lse = _flash_forward(q, k, v, causal, sm_scale, block_q, block_k,
-                              interpret, window=window)
+                              interpret, window=window,
+                              mask_block=mask_block, precision=precision)
     return out, (q, k, v, out, lse)
 
 
-def _fa_bwd(causal, sm_scale, block_q, block_k, interpret, window, res, g):
-    if window is not None:
+def _fa_bwd(causal, sm_scale, block_q, block_k, interpret, window,
+            mask_block, precision, res, g):
+    if window is not None or mask_block is not None \
+            or precision is not None:
         raise NotImplementedError(
-            "flash attention: the sliding window has no backward kernel "
+            "flash attention: the sliding window, the block-causal "
+            "mask and a set precision have no backward kernel "
             "(the serving path is forward only)")
     q, k, v, out, lse = res
     dq, dk, dv, _ = _flash_backward(q, k, v, out, lse, g, causal, sm_scale,

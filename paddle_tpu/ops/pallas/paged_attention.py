@@ -1,5 +1,6 @@
-"""Paged decode attention for TPU — one query token per slot attends its
-live KV pages in place.
+"""Paged decode attention for TPU — one query token per slot, or a block
+of query rows that all see the same columns, attends its live KV pages in
+place.
 
 The paged cache is a per-layer pool ``[P, Hkv, pt, D]`` with a block
 table ``[B, NP]`` (logical page -> physical page) per slot
@@ -56,12 +57,22 @@ GRANULE_POSITIONS = 128
 PRECISION = jax.lax.Precision.HIGHEST
 
 
-def supported(q_shape, pool_shape):
-    """Whether the compiled kernel takes these shapes: one query token,
-    whole lane tiles of ``D`` and whole float32 sublane tiles of ``pt``."""
+# query rows of one KV head the kernel holds at once: ``rep`` query heads
+# times the rows of a block
+MAX_GROUP_ROWS = 256
+
+
+def supported(q_shape, pool_shape, window=None):
+    """Whether the compiled kernel takes these shapes: whole lane tiles
+    of ``D``, whole float32 sublane tiles of ``pt``, and the query rows
+    of a slot all admitting the same columns (one token, or a block of
+    rows without a sliding window) with a KV head's group of them in
+    ``MAX_GROUP_ROWS``."""
     _, H, T, D = q_shape
     _, Hkv, pt, _ = pool_shape
-    return T == 1 and D % 128 == 0 and pt % 8 == 0 and H % Hkv == 0
+    return (D % 128 == 0 and pt % 8 == 0 and H % Hkv == 0
+            and (T == 1 or window is None)
+            and (H // Hkv) * T <= MAX_GROUP_ROWS)
 
 
 def _kernel(bt_ref, pos_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem,
@@ -196,18 +207,27 @@ def _kernel(bt_ref, pos_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem,
 def paged_decode_attention(q, pool_k, pool_v, block_table, positions,
                            scale=None, interpret=False,
                            granule=GRANULE_POSITIONS, window=None):
-    """``q`` [B, H, 1, D] over pools ``[P, Hkv, pt, D]`` through
-    ``block_table`` [B, NP] int32; ``positions`` [B] int32 is each slot's
-    pre-step length, and the query attends columns ``j <= positions[b]``
-    (the column this step wrote included).  ``granule`` is the number
-    of positions fetched and contracted per loop turn, rounded to whole
-    pages.  ``window`` adds the lower bound ``j > positions[b] -
-    window``: the granule loop starts at the window's first page and
-    nothing left of it is fetched.  Returns [B, H, 1, D]."""
-    B, H, _, D = q.shape
+    """``q`` [B, H, T, D] over pools ``[P, Hkv, pt, D]`` through
+    ``block_table`` [B, NP] int32; ``positions`` [B] int32 is the last
+    column each slot admits: every one of its ``T`` query rows attends
+    columns ``j <= positions[b]``.  For the one-token step that is the
+    slot's pre-step length (the column this step wrote included); for
+    a block of ``T`` rows at ``base`` that see their whole block it is
+    ``base + T - 1``.  The rows of a KV head's group are then ``rep x
+    T`` and the page walk is the one-token step's.  ``granule`` is the
+    number of positions fetched and contracted per loop turn, rounded
+    to whole pages.  ``window`` (one row only) adds the lower bound ``j
+    > positions[b] - window``: the granule loop starts at the window's
+    first page and nothing left of it is fetched.  Returns
+    [B, H, T, D]."""
+    B, H, T, D = q.shape
     P, Hkv, pt, _ = pool_k.shape
     NP = block_table.shape[1]
-    rep = H // Hkv
+    if T > 1 and window is not None:
+        raise ValueError("paged_decode_attention: the rows of a block "
+                         "share their columns, a sliding window gives "
+                         "each row its own")
+    rep = (H // Hkv) * T              # query rows that share a KV head
     R = -(-rep // 8) * 8                             # whole sublane tiles
     G = max(1, min(granule // pt, NP))
     scale = scale if scale is not None else 1.0 / (D ** 0.5)
@@ -241,4 +261,4 @@ def paged_decode_attention(q, pool_k, pool_v, block_table, positions,
         name="paged_decode_attention",
     )(block_table.reshape(-1).astype(jnp.int32),
       positions.astype(jnp.int32), qg, pool_k, pool_v)
-    return out[:, :, :rep].reshape(B, H, 1, D)
+    return out[:, :, :rep].reshape(B, H, T, D)

@@ -155,17 +155,11 @@ def route_top_k(router_x, router_w, top_k: int):
 
 
 ROW_TILE = 64     # the row tile XLA:TPU's ragged-dot kernel picks here
+RUN_ROWS = 3 * ROW_TILE   # ... for a call of up to this many rows
+WIDE_TILE = 512           # and the tile it picks from 512 rows on
 
 
-def grouped_matmul(rows, weights, group_sizes, precision=None):
-    """``rows`` [M, K], sorted by group, times ``weights`` [G, K, N]:
-    row ``r`` of group ``g`` is multiplied by ``weights[g]``.  One
-    formulation per shape, chosen on the chip (README "Routed experts"):
-    ``jax.lax.ragged_dot`` for both the decode step's few rows and a
-    prefill's many.  XLA:TPU lowers it to a grouped Mosaic kernel that
-    visits only the (row tile, group) pairs that hold rows, so the
-    weights of a group without rows are never read; the CPU lowering is
-    a masked dense product."""
+def _one_call(rows, weights, group_sizes, precision):
     import jax
     import jax.numpy as jnp
 
@@ -183,6 +177,53 @@ def grouped_matmul(rows, weights, group_sizes, precision=None):
     return out[:m] if pad else out
 
 
+def _in_runs(rows, weights, group_sizes, precision, run):
+    """The sorted rows in runs of ``run``, one kernel call a run with the
+    run's own group sizes (a group that straddles a cut counts its rows
+    on either side): the one call's numbers, row for row."""
+    import jax
+    import jax.numpy as jnp
+
+    m = rows.shape[0]
+    n = -(-m // run)
+    rows = jnp.pad(rows, ((0, n * run - m), (0, 0))).reshape(n, run, -1)
+    ends = jnp.cumsum(group_sizes)
+    starts = ends - group_sizes
+    lo = jnp.arange(n, dtype=ends.dtype)[:, None] * run
+    sizes = jnp.clip(jnp.minimum(ends, lo + run) - jnp.maximum(starts, lo),
+                     0, None).astype(group_sizes.dtype)         # [n, G]
+    out = jax.lax.map(
+        lambda a: _one_call(a[0], weights, a[1], precision), (rows, sizes))
+    return out.reshape(n * run, -1)[:m]
+
+
+def grouped_matmul(rows, weights, group_sizes, precision=None):
+    """``rows`` [M, K], sorted by group, times ``weights`` [G, K, N]:
+    row ``r`` of group ``g`` is multiplied by ``weights[g]``.  One
+    formulation per shape, chosen on the chip (README "Routed experts"):
+    ``jax.lax.ragged_dot`` for both the decode step's few rows and a
+    prefill's many.  XLA:TPU lowers it to a grouped Mosaic kernel that
+    visits only the (row tile, group) pairs that hold rows, so the
+    weights of a group without rows are never read; the CPU lowering is
+    a masked dense product.
+
+    The kernel picks its row tile from the call's M (64 up to 192 rows,
+    512 from 512 on) and pays a whole tile for every group with a row in
+    it, so a large M whose groups hold fewer rows than the wide tile is
+    mostly padding.  Such an M goes through the kernel in runs of
+    ``RUN_ROWS`` sorted rows (:func:`_in_runs`: the same numbers).  Both
+    matmuls of a layer at "highest" on a v5e, one call -> runs (my chip
+    run, PR 32): 128 groups of K 2048 at 12 / 32 / 64 rows a group 20.1
+    -> 4.8, 20.8 -> 6.3, 22.1 -> 9.0 ms; 64 groups of K 2560 at 48 / 96
+    / 192 / 384 rows a group 13.4 -> 4.6, 14.5 -> 6.7, 16.7 -> 10.8,
+    21.7 -> 19.6 ms, and at 768 a group 31.1 -> 37.3: there the wide
+    tile is full and the one call stays."""
+    m, groups = rows.shape[0], weights.shape[0]
+    if RUN_ROWS < m < WIDE_TILE * groups:
+        return _in_runs(rows, weights, group_sizes, precision, RUN_ROWS)
+    return _one_call(rows, weights, group_sizes, precision)
+
+
 def moe_routed_tokens(x, router_x, router_w, w_gate_up, w_down, *,
                       top_k: int, activation: str = "relu", valid=None,
                       precision=None):
@@ -195,12 +236,14 @@ def moe_routed_tokens(x, router_x, router_w, w_gate_up, w_down, *,
     counted).  Every token goes to its k experts: there is no capacity
     and nothing is dropped.  Tokens are sorted by expert, the two
     grouped matmuls read only experts that got rows, and the k results
-    per token are summed under the routing weights.
+    per token are summed under the routing weights.  ``activation`` is
+    the gate's: "relu" or "silu".
 
     Returns ``(out [N, H], counts [E] int32, logits [N, E])``; ``counts``
     are the group sizes the grouped matmul ran with, restricted to valid
     rows, so ``counts.sum() == valid.sum() * k`` proves no token was
     dropped."""
+    import jax
     import jax.numpy as jnp
 
     N, H = x.shape
@@ -214,9 +257,13 @@ def moe_routed_tokens(x, router_x, router_w, w_gate_up, w_down, *,
     h = grouped_matmul(rows, w_gate_up.astype(x.dtype), group_sizes,
                        precision)
     gate, up = h[:, :inter], h[:, inter:]
-    if activation != "relu":
+    if activation == "relu":
+        gate = jnp.maximum(gate, 0)
+    elif activation == "silu":
+        gate = jax.nn.silu(gate)
+    else:
         raise ValueError(f"unknown expert activation {activation!r}")
-    y = grouped_matmul(jnp.maximum(gate, 0) * up, w_down.astype(x.dtype), group_sizes,
+    y = grouped_matmul(gate * up, w_down.astype(x.dtype), group_sizes,
                        precision)                       # [N*k, H]
     y = y * jnp.take(weights.reshape(-1), order)[:, None].astype(y.dtype)
     # back to token order: row r of the sorted list is pair order[r]

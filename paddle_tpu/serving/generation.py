@@ -137,7 +137,10 @@ each fails only the then-active requests),
 ``serving_spec_tokens_proposed``, ``serving_spec_tokens_accepted``,
 ``serving_spec_rollbacks``,
 ``serving_kv_window_pages_released``, ``moe_tokens_routed``,
-``moe_tokens_dropped`` (must read 0); gauges
+``moe_tokens_dropped`` (must read 0),
+``serving_block_passes_denoise`` / ``serving_block_passes_commit``
+(block diffusion: slot-passes that decided positions / that only
+committed a block's K/V), ``serving_block_tokens_committed``; gauges
 ``serving_spec_acceptance_rate``,
 ``serving_slot_occupancy``,
 ``serving_kv_cache_bytes`` (allocated cache capacity: the page pools),
@@ -375,7 +378,9 @@ class _Slot:
     __slots__ = ("idx", "req", "position", "steps", "tokens", "t_start",
                  "logits", "pages", "wpages", "router_logits",
                  "prefill_pos", "hit_tokens",
-                 "decoding", "span", "page_us", "page_t", "page_tenant")
+                 "decoding", "span", "page_us", "page_t", "page_tenant",
+                 "blk_tokens", "blk_masked", "blk_left", "blk_done",
+                 "blk_head", "passes")
 
     def __init__(self, idx: int):
         self.idx = idx
@@ -403,6 +408,17 @@ class _Slot:
         self.page_us = 0
         self.page_t = 0.0
         self.page_tenant: Optional[str] = None
+        # block diffusion: the block at ``position`` as the last booked
+        # pass left it (the host's mirror of what the device carries),
+        # how many of its positions are still undecided, how many
+        # denoising passes it has had, and how many prompt tokens sit,
+        # fixed, at its head (the first generated block only)
+        self.blk_tokens: Optional[np.ndarray] = None
+        self.blk_masked: Optional[np.ndarray] = None
+        self.blk_left = 0
+        self.blk_done = 0
+        self.blk_head = 0
+        self.passes: List[dict] = []     # keep_logits: every pass's record
 
     @property
     def active(self) -> bool:
@@ -433,7 +449,17 @@ class GenerationEngine:
     """KV-cached generation over a fixed decode-slot grid.
 
     ``model``: dict of llama size kwargs (``vocab_size``, ``hidden``,
-    ``num_layers``, ``num_heads``, ``num_kv_heads``, ``intermediate``).
+    ``num_layers``, ``num_heads``, ``num_kv_heads``, ``intermediate``;
+    optionally ``head_dim``, ``rms_norm_eps``, ``rope_base``,
+    ``layer_pattern``, ``qk_norm``).  ``block_diffusion={"block": B,
+    "passes": T, "mask_id": id}`` in it makes a slot's unit of work a
+    block of B positions: the prompt's whole blocks prefill under the
+    block-causal mask, each later block takes up to T denoising passes
+    (the static schedule: ``ceil(undecided / passes_left)`` positions
+    fixed a pass, by confidence) and one commit pass, and its tokens
+    are booked and streamed together at the commit.  Such an engine
+    refuses ``prefix_reuse``, ``prefill_chunk``, ``speculate`` and the
+    disaggregated roles, which walk one token a step.
     ``scope``: optional pre-initialized :class:`~paddle_tpu.framework.
     executor.Scope` whose weights use the same ``name`` prefix (the
     engine then shares them zero-copy); omitted, the engine seeds its
@@ -459,6 +485,19 @@ class GenerationEngine:
 
         ensure_compile_cache()
         self.model = dict(model)
+        # block diffusion (the class docstring): B positions a step, T
+        # denoising passes a block; 0 = one token a step
+        bd = self.model.pop("block_diffusion", None) or {}
+        self._blk = int(bd.get("block", 0))
+        self._blk_passes = int(bd.get("passes", 0))
+        self._blk_mask_id = int(bd.get("mask_id", 0))
+        self._rows = self._blk or 1        # positions a slot's step covers
+        if bd and (self._blk < 2 or not 1 <= self._blk_passes <= self._blk
+                   or not 0 <= self._blk_mask_id
+                   < self.model["vocab_size"]):
+            raise ValueError(
+                f"block_diffusion needs block >= 2, 1 <= passes <= block "
+                f"and a mask_id inside the vocabulary, got {bd}")
         self.name = name
         self.attn_impl = attn_impl
         self.continuous = bool(continuous)
@@ -589,6 +628,31 @@ class GenerationEngine:
             if self.spec_ngram < 1:
                 raise ValueError(f"spec_ngram must be >= 1, got "
                                  f"{self.spec_ngram}")
+        if self._blk:
+            if self._window_layers:
+                raise ValueError(
+                    "block diffusion over sliding-window layers is not "
+                    "built: the rows of a block share their columns, a "
+                    "window gives each row its own")
+            if pt_ % self._blk:
+                raise ValueError(
+                    f"page_tokens {pt_} is not a multiple of the block "
+                    f"length {self._blk}: a block lies inside one page")
+            # a step yields a block: what walks one token a step is no
+            # part of this engine (PERF.md section 7)
+            refused = [what for what, on in (
+                ("prefix_reuse", self.prefix_reuse),
+                ("speculate", self.speculate),
+                ("prefill_chunk > 0", self.prefill_chunk > 0),
+                (f"role={self.role!r} (KV-segment handoff)",
+                 self.role != "both")) if on]
+            if refused:
+                raise ValueError(
+                    f"a block-diffusion model commits a block of "
+                    f"{self._blk} positions a step and does not support "
+                    f"{', '.join(refused)}: prefix reuse, chunked "
+                    f"prefill, speculation and segment adoption / export "
+                    f"walk one token a step and a causal prefix")
         if self._wpool is not None:
             # two page kinds: what walks ONE block table per slot is no
             # part of this engine yet (PERF.md section 7)
@@ -657,7 +721,8 @@ class GenerationEngine:
                    "spec_tokens_proposed": 0,
                    "spec_tokens_accepted": 0, "spec_rollbacks": 0,
                    "window_pages_released": 0, "moe_tokens_routed": 0,
-                   "moe_tokens_dropped": 0}
+                   "moe_tokens_dropped": 0, "block_passes_denoise": 0,
+                   "block_passes_commit": 0, "block_tokens_committed": 0}
         self._n_lock = threading.Lock()
         # per-bucket manifest-flops cache for usage attribution: the
         # executor cache walk is paid once per bucket, not per dispatch
@@ -704,7 +769,8 @@ class GenerationEngine:
                 self.num_slots, self.max_seq_len, name=self.name,
                 num_pages=self.num_pages, page_tokens=self.page_tokens,
                 num_window_pages=self.num_window_pages or None,
-                keep_router_logits=self.keep_logits, **self.model)
+                keep_router_logits=self.keep_logits, **self._blk_args(),
+                **self.model)
         self._decode_prog = main
         self._decode_feeds = feeds
         self._decode_fetches = fetches
@@ -713,6 +779,15 @@ class GenerationEngine:
             # engine-owned weights: the decode program references every
             # parameter, so one startup run initializes the full set
             self._prefill_exe.run(startup, scope=self.scope)
+
+    def _blk_args(self, prefill: bool = False) -> dict:
+        """What block diffusion adds to a program builder's arguments;
+        nothing for a model of one token a step."""
+        if not self._blk:
+            return {}
+        if prefill:
+            return {"mask_block": self._blk}
+        return {"block": self._blk, "mask_id": self._blk_mask_id}
 
     def _place_on_mesh(self, shard_rules):
         """Shard every decode-program weight onto the mesh — once,
@@ -762,9 +837,14 @@ class GenerationEngine:
             # allocates a fresh buffer per call)
             shp = wshape if j // 2 in self._window_layers else shape
             zeros = jnp.zeros(shp, jnp.float32)
-            self.scope.set_var(
-                n, jax.device_put(zeros, cache_sh)
-                if cache_sh is not None else zeros.copy())
+            pool = jax.device_put(zeros, cache_sh) \
+                if cache_sh is not None else zeros.copy()
+            # the host dispatches faster than the device fills: without
+            # the wait every pool's ``zeros`` lies beside its copy until
+            # the device catches up, a second pool's worth of memory
+            # (1.6 GB at 48 slots x 2048 x 4 layers, my chip run, PR 32)
+            jax.block_until_ready(pool)
+            self.scope.set_var(n, pool)
             total += int(np.prod(shp)) * 4
         # capacity actually ALLOCATED (the pools, trash pages included)
         self.kv_cache_bytes = total
@@ -804,13 +884,17 @@ class GenerationEngine:
         (another list would be another compilation): the greedy token,
         what the expert layers counted, and with ``keep_logits`` the
         logits and the router's."""
-        names = ["next_token"]
+        # a block-diffusion pass yields the block as it stands; its
+        # prefill yields nothing but K/V
+        names = ["next_token"] if not self._blk \
+            else [n for n in ("tokens", "masked", "rows_written")
+                  if n in fetches]
         if "expert_counts" in fetches:
             names.append("expert_counts")
         if self.keep_logits:
-            names.append("logits")
-            if "router_logits" in fetches:
-                names.append("router_logits")
+            # (a block-diffusion prefill has router logits and no row's)
+            names += [n for n in ("logits", "router_logits")
+                      if n in fetches]
         return names
 
     def _run_fetching(self, exe, prog, fetches, feed) -> dict:
@@ -838,7 +922,8 @@ class GenerationEngine:
                     num_pages=self.num_pages,
                     page_tokens=self.page_tokens,
                     num_window_pages=self.num_window_pages or None,
-                    keep_router_logits=self.keep_logits, **self.model)
+                    keep_router_logits=self.keep_logits,
+                    **self._blk_args(prefill=True), **self.model)
             entry = self._prefill_progs[bucket] = (main, fetches)
         return entry
 
@@ -937,9 +1022,10 @@ class GenerationEngine:
                 if b not in self._prefill_progs:
                     prog, fetches = self._prefill_prog_for(b)
                     feed = {"input_ids": np.zeros((1, b), "int64"),
-                            "last_pos": np.zeros((1,), "int64"),
                             "block_table": np.zeros((1, np_slot), "int32"),
                             "prompt_len": np.zeros((1,), "int32")}
+                    if not self._blk:      # (a block prefill yields no row)
+                        feed["last_pos"] = np.zeros((1,), "int64")
                     if self._wpool is not None:
                         feed["block_table_window"] = np.zeros(
                             (1, np_slot), "int32")
@@ -987,10 +1073,16 @@ class GenerationEngine:
         them, which compiles the reshape that carries a step's tokens
         into the step dispatched ahead of its settle."""
         positions = np.zeros((self.num_slots,), "int32")
-        outs = self._dispatch_decode(
-            self._host_tokens(np.zeros((self.num_slots,), "int32")),
-            positions)
-        outs = self._dispatch_decode(self._carried_tokens(outs), positions)
+        idle = self._host_tokens(np.zeros((self.num_slots, self._rows),
+                                          "int32"))
+        zeros = np.zeros((self.num_slots,), "int32")
+        block = {"masked": idle, "quota": zeros, "fresh": zeros} \
+            if self._blk else None
+        outs = self._dispatch_decode(idle, positions, block=block)
+        if self._blk:
+            block["masked"] = outs["masked"].value
+        outs = self._dispatch_decode(self._carried_tokens(outs), positions,
+                                     block=block)
         self._fetch_decode(outs)
 
     # -- lifecycle ----------------------------------------------------------
@@ -1362,6 +1454,10 @@ class GenerationEngine:
         if self.role == "prefill":
             raise ValueError("prefill-role engine cannot adopt "
                              "segments (it has no decode grid)")
+        if self._blk:
+            raise ValueError("a block-diffusion engine cannot adopt "
+                             "segments: a segment is a causal prefix and "
+                             "one pending token")
         self._check_segment(segment)
         mnt = max(1, int(max_new_tokens if max_new_tokens is not None
                          else self.max_new_tokens))
@@ -2080,23 +2176,28 @@ class GenerationEngine:
         n_prompt = int(prompt.size)
         parent = slot.span.context() if slot.span is not None else None
         if slot.prefill_pos == 0 and self.prefill_chunk <= 0:
-            bucket = batcher.prompt_bucket_for(n_prompt,
+            # block diffusion prefills (commits) the prompt's whole
+            # blocks; its tail rides at the head of the first block
+            n_rows = n_prompt - n_prompt % self._rows
+            bucket = batcher.prompt_bucket_for(max(n_rows, 1),
                                                self.prefill_buckets)
             with telemetry.trace_span("generation/prefill_prepare",
                                       parent=parent, slot=slot.idx,
                                       bucket=bucket):
-                self._ensure_pages(slot, n_prompt)
+                self._ensure_pages(slot, n_rows)
                 prog, fetches = self._prefill_prog_for(bucket)
                 feed = {"input_ids":
-                        batcher.pad_prompt(prompt, bucket)[None],
-                        "last_pos": np.asarray([n_prompt - 1], "int64"),
+                        batcher.pad_prompt(prompt[:max(n_rows, 1)],
+                                           bucket)[None],
                         "block_table": self._slot_block_table(slot)[None],
-                        "prompt_len": np.asarray([n_prompt], "int32")}
+                        "prompt_len": np.asarray([n_rows], "int32")}
+                if not self._blk:
+                    feed["last_pos"] = np.asarray([n_prompt - 1], "int64")
                 if self._wpool is not None:
                     feed["block_table_window"] = \
                         self._slot_block_table(slot, window=True)[None]
             with telemetry.trace_span("generation/prefill", parent=parent,
-                                      tokens=n_prompt, bucket=bucket,
+                                      tokens=n_rows, bucket=bucket,
                                       slot=slot.idx):
                 outs = self._run_fetching(self._prefill_exe, prog,
                                           fetches, feed)
@@ -2104,7 +2205,7 @@ class GenerationEngine:
             if req.tenant is not None:
                 usage.ledger().book(req.tenant,
                                     flops=self._exe_flops(bucket))
-            self._complete_prefill(slot, req, outs, n_prompt)
+            self._complete_prefill(slot, req, outs, n_rows)
             return
         # chunk continuation (chunked prefill and/or prefix-hit tail):
         # this iteration runs the FIRST remaining span; later spans
@@ -2152,9 +2253,12 @@ class GenerationEngine:
         span = telemetry.span_begin("generation/prefill_fetch",
                                     parent=parent, slot=slot.idx)
         try:
-            first = int(np.asarray(outs["next_token"].numpy())[0])
+            # (a block-diffusion prefill yields K/V alone: its first
+            # tokens come from the first block's passes)
+            first = int(np.asarray(outs["rows_written" if self._blk else
+                                        "next_token"].numpy())[0])
             slot.logits = [np.asarray(outs["logits"].numpy())[0]] \
-                if self.keep_logits else []
+                if self.keep_logits and "logits" in outs else []
             slot.router_logits = \
                 [np.asarray(outs["router_logits"].numpy())[0]] \
                 if "router_logits" in outs else []
@@ -2219,6 +2323,9 @@ class GenerationEngine:
                 self._prefix.register(req.prompt, slot.pages[:full])
                 self._publish_pool_gauges()
         slot.prefill_pos = n_prompt
+        if self._blk:
+            self._enter_blocks(slot, req, n_rows)
+            return
         slot.position = n_prompt
         slot.tokens = [first]
         if self.role == "prefill":
@@ -2231,6 +2338,20 @@ class GenerationEngine:
         if req.bb is not None:
             blackbox.request_phase(req.bb, "decoding")
         self._book_token(slot, first, time.monotonic())
+
+    def _enter_blocks(self, slot: _Slot, req: GenRequest, n_rows: int):
+        """A block-diffusion sequence enters the grid after its prefill
+        committed ``n_rows`` positions (the prompt's whole blocks): its
+        first block starts there, with the prompt's tail fixed at its
+        head and every other position undecided.  Nothing is booked
+        yet: the first tokens come with that block's commit pass."""
+        slot.position = n_rows
+        slot.tokens = []
+        self._new_block(slot, req.prompt[n_rows:])
+        slot.passes = []
+        slot.decoding = True
+        if req.bb is not None:
+            blackbox.request_phase(req.bb, "decoding")
 
     def _export_segment(self, slot: _Slot, req: GenRequest):
         """Gather the slot's populated pages into a detached
@@ -2327,29 +2448,38 @@ class GenerationEngine:
         bind the one compiled step."""
         import jax
 
-        return jax.device_put(tokens.reshape(-1, 1))
+        return jax.device_put(tokens.reshape(self.num_slots, -1))
 
     def _carried_tokens(self, outs: dict):
         """The ``tokens`` feed of the step after the one that returned
-        ``outs``: its greedy tokens as the device holds them, never
-        brought to the host."""
+        ``outs``: its greedy tokens (block diffusion: the blocks as its
+        decisions left them) as the device holds them, never brought to
+        the host."""
+        if self._blk:
+            return outs["tokens"].value
         return outs["next_token"].value.reshape(self.num_slots, 1)
 
     def _dispatch_decode(self, tokens, positions: np.ndarray,
                          block_tables: Optional[np.ndarray] = None,
                          live: Optional[np.ndarray] = None,
                          block_tables_window:
-                         Optional[np.ndarray] = None) -> dict:
+                         Optional[np.ndarray] = None,
+                         block: Optional[dict] = None) -> dict:
         """Hand one grid step to the device.  Returns its fetch handles
         by name, unread: ``next_token`` and, where the program has
         them, ``logits``, ``expert_counts``, ``router_logits`` (the
-        counts ride the token fetch: one wait for the one program)."""
+        counts ride the token fetch: one wait for the one program).
+        ``block``: a block-diffusion pass's further feeds (``masked``,
+        ``quota``, ``fresh``); its fetches are ``tokens`` and
+        ``masked``."""
         empty = (self.num_slots, self.pages_per_slot)
         feed = {"tokens": tokens, "positions": positions,
                 "block_tables": block_tables if block_tables is not None
                 else np.zeros(empty, "int32"),
                 "live": live if live is not None
                 else np.zeros((self.num_slots,), "int32")}
+        if self._blk:
+            feed.update(block)
         if self._wpool is not None:
             feed["block_tables_window"] = block_tables_window \
                 if block_tables_window is not None \
@@ -2578,8 +2708,8 @@ class GenerationEngine:
         if "expert_counts" in outs:
             # the counts cover every live row of the step, the rows the
             # settle discards too
-            attrs.update(self._book_experts(outs["expert_counts"],
-                                            len(fl.riders)))
+            attrs.update(self._book_experts(
+                outs["expert_counts"], len(fl.riders) * self._rows))
         if self._wpool is not None:
             # the pages this step's feeds let go, what both kinds hold
             # now, the positions its rows attended
@@ -2591,6 +2721,20 @@ class GenerationEngine:
                 live_positions=int(sum(s.position + 1 for s in rows)),
                 live_positions_window=int(sum(
                     min(s.position + 1, self.window) for s in rows)))
+        if self._blk:
+            # what this pass was, slot by slot: the booked state is the
+            # state it was dispatched from
+            rows = [s for s, r in fl.riders if s.req is r]
+            commit = [s for s in rows if not s.blk_left]
+            attrs.update(
+                passes_denoise=len(rows) - len(commit),
+                passes_commit=len(commit),
+                rows=len(rows) * self._blk,
+                live_positions=int(sum(s.position + self._blk
+                                       for s in rows)),
+                rows_masked=int(sum(s.blk_left for s in rows)),
+                tokens_committed=int(sum(
+                    self._block_yield(s) for s in commit)))
         if step is not None:
             step.attrs.update(attrs)
         return outs
@@ -2650,6 +2794,8 @@ class GenerationEngine:
         ``lead``'s on the device, and a sequence the host knows will
         end at ``lead`` (its budget or its cache is one token from
         full) stays out."""
+        if self._blk:
+            return self._build_block_feeds(lead)
         ahead = int(lead is not None)
         riding = [s for s in self._decoding_slots()
                   if s.idx not in skip
@@ -2694,10 +2840,124 @@ class GenerationEngine:
                 btw[s.idx] = self._slot_block_table(s, window=True)
         return active, (tokens, positions, bt, live, btw)
 
+    # -- block diffusion: a slot's step is a pass over a block -------------
+    def _block_quota(self, left: int, done: int) -> int:
+        """Positions a denoising pass decides, ``left`` undecided after
+        ``done`` passes: the static schedule, ``ceil(left /
+        passes_left)``.  0 is the commit pass."""
+        return -(-left // (self._blk_passes - done)) if left else 0
+
+    def _block_yield(self, s: _Slot) -> int:
+        """Tokens the commit of ``s``'s current block books: the block
+        less the prompt's tail at its head, cut by the budget."""
+        return min(self._blk - s.blk_head,
+                   s.req.max_new_tokens - len(s.tokens))
+
+    def _new_block(self, s: _Slot, head=()):
+        """The host's mirror of a block no pass has touched: ``head``
+        (the prompt's tail, for the first block) fixed at its start,
+        the mask token, undecided, everywhere else."""
+        B, n = self._blk, len(head)
+        s.blk_tokens = np.full((B,), self._blk_mask_id, "int32")
+        s.blk_tokens[:n] = head
+        s.blk_masked = (np.arange(B) >= n).astype("int32")
+        s.blk_left, s.blk_done, s.blk_head = B - n, 0, n
+
+    def _block_phase(self, s: _Slot, ahead: int):
+        """``(base, undecided, denoising passes done, fresh)`` of the
+        block ``s`` works on ``ahead`` passes past its booked state.
+        The schedule is static, so the host knows every slot's phase
+        and position ahead without reading the device."""
+        base, left, done, fresh = s.position, s.blk_left, s.blk_done, 0
+        for _ in range(ahead):
+            if left:
+                left -= self._block_quota(left, done)
+                done += 1
+            else:                    # that pass commits: the next block
+                base, left, done, fresh = base + self._blk, self._blk, 0, 1
+        return base, left, done, fresh
+
+    def _build_block_feeds(self, lead: Optional[_StepInFlight]):
+        """:meth:`_build_decode_feeds` for block diffusion: each rider's
+        row is its block's next pass (``quota`` 0: the commit pass).
+        With ``lead`` the blocks are ``lead``'s on the device, a slot
+        whose block ``lead`` commits starts the next one (``fresh``),
+        and a sequence whose last block ``lead`` commits stays out."""
+        B, ahead = self._blk, int(lead is not None)
+        riding = [s for s in self._decoding_slots()
+                  if not (ahead and not s.blk_left and (
+                      len(s.tokens) + self._block_yield(s)
+                      >= s.req.max_new_tokens
+                      or s.position + 2 * B > self.max_seq_len))]
+        self._released_in_feeds = 0
+        n = self.num_slots
+        positions, quota, fresh, live = (np.zeros((n,), "int32")
+                                         for _ in range(4))
+        bt = np.zeros((n, self.pages_per_slot), "int32")
+        for s in riding:
+            base, left, done, new = self._block_phase(s, ahead)
+            try:
+                self._ensure_pages(s, base + B)
+            except PoolExhausted:
+                if ahead:
+                    raise
+                self._finish(s, "cache_full")
+                continue
+            positions[s.idx], fresh[s.idx], live[s.idx] = base, new, 1
+            quota[s.idx] = self._block_quota(left, done)
+            bt[s.idx] = self._slot_block_table(s)
+        active = [s for s in riding if s.req is not None]
+        if not active:
+            return active, None
+        if ahead:
+            tokens = self._carried_tokens(lead.outs)
+            masked = lead.outs["masked"].value
+        else:
+            host = np.zeros((2, n, B), "int32")
+            for s in active:
+                host[0, s.idx], host[1, s.idx] = s.blk_tokens, s.blk_masked
+            tokens, masked = (self._host_tokens(h) for h in host)
+        return active, (tokens, positions, bt, live, None,
+                        {"masked": masked, "quota": quota, "fresh": fresh})
+
+    def _book_pass(self, s: _Slot, outs: dict, now: float, riders: int):
+        """Book one slot's pass: a denoising pass moves the host's
+        mirror of the block on; a commit pass books and streams the
+        block's tokens (one timestamp for all of them), cut by the
+        budget, and moves the slot a block on."""
+        s.steps += 1
+        if self.keep_logits:
+            # the block as the program was fed it, beside what it gave
+            # and how many slots rode the pass
+            rec = {"base": s.position, "tokens": s.blk_tokens.copy(),
+                   "masked": s.blk_masked.copy(),
+                   "quota": self._block_quota(s.blk_left, s.blk_done),
+                   "riders": riders, "logits": outs["logits"][s.idx]}
+            if "router_logits" in outs:       # [L_moe, B, E]
+                rec["router_logits"] = outs["router_logits"][s.idx]
+            s.passes.append(rec)
+        if s.blk_left:
+            s.blk_left -= self._block_quota(s.blk_left, s.blk_done)
+            s.blk_done += 1
+            s.blk_tokens = outs["tokens"][s.idx]
+            s.blk_masked = outs["masked"][s.idx]
+            return
+        toks = [int(t) for t in
+                s.blk_tokens[s.blk_head:s.blk_head + self._block_yield(s)]]
+        s.position += self._blk
+        self._new_block(s)
+        self._count("block_tokens_committed", len(toks))
+        stat_add("serving_block_tokens_committed", len(toks))
+        for i, tok in enumerate(toks):
+            s.tokens.append(tok)
+            self._book_token(s, tok, now, last=i == len(toks) - 1)
+            if s.req is None:
+                break                # EOS inside the block
+
     def _book_step(self, active, outs: dict, t0: float, t1: float):
         """The host half of a grid step after its token fetch: step
         accounting, then one booked token per riding slot."""
-        next_tokens, logits = outs["next_token"], outs.get("logits")
+        next_tokens, logits = outs.get("next_token"), outs.get("logits")
         router = outs.get("router_logits")
         ms = (t1 - t0) * 1e3
         self._t_decode_total += ms
@@ -2721,6 +2981,17 @@ class GenerationEngine:
         self._decode_rate_ema = (1.0 / dt if self._decode_rate_ema is None
                                  else 0.9 * self._decode_rate_ema
                                  + 0.1 / dt)
+        if self._blk:
+            # (the booked state is the state the pass was dispatched from)
+            commits = sum(not s.blk_left for s in active)
+            for key, n in (("block_passes_denoise", len(active) - commits),
+                           ("block_passes_commit", commits)):
+                if n:
+                    self._count(key, n)
+                    stat_add("serving_" + key, n)
+            for s in active:
+                self._book_pass(s, outs, t1, len(active))
+            return
         for s in active:
             tok = int(next_tokens[s.idx])
             s.position += 1
@@ -2734,14 +3005,17 @@ class GenerationEngine:
             # bookkeeping adds no extra clock reads to the step
             self._book_token(s, tok, t1)
 
-    def _book_token(self, slot: _Slot, tok: int, now: float):
+    def _book_token(self, slot: _Slot, tok: int, now: float,
+                    last: bool = True):
         """Account one generated token and finish the slot on EOS /
         token budget / cache exhaustion — freeing it for the next
         queued request at the very next scheduler iteration.  ``now``
         is the caller's already-taken post-step timestamp (the whole
         grid shares one clock read): it feeds the sequence timeline,
         the TTFT / inter-token histograms, and the per-token
-        callback."""
+        callback.  ``last``: the step booked nothing after this token
+        (all but the last token of a committed block pass False: the
+        cache is not full while the block is being booked)."""
         self._count("generated_tokens")
         stat_add("serving_generated_tokens")
         req = slot.req
@@ -2782,7 +3056,7 @@ class GenerationEngine:
             finish = "eos"
         elif len(slot.tokens) >= req.max_new_tokens:
             finish = "length"
-        elif slot.position >= self.max_seq_len:
+        elif last and slot.position + self._rows > self.max_seq_len:
             # the next decode step would write at index max_seq_len —
             # past the cache bucket, where dynamic_update_slice would
             # silently clamp onto the last row; finishing HERE is the
@@ -2821,8 +3095,15 @@ class GenerationEngine:
         if self.keep_logits:
             result["logits"] = slot.logits
             slot.logits = []
+            if self._blk:
+                # every pass, denoising and commit: the block as fed
+                # (base, tokens, masked, quota) and its [B, V] logits
+                result["passes"] = slot.passes
+                slot.passes = []
             if slot.router_logits:
                 # [L_moe, E] per generated token, for a reference check
+                # (block diffusion: the prefill's, [L_moe, bucket, E];
+                # the passes' are on their records)
                 result["router_logits"] = slot.router_logits
                 slot.router_logits = []
         if slot.hit_tokens:

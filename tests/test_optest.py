@@ -1134,6 +1134,12 @@ SKIP = {
         "tests/test_paged_decode_attention.py (op == the gather + "
         "cached_attention triple bit for bit off the TPU; the Pallas "
         "kernel vs a float32 'highest' reference under interpret mode)",
+    "block_begin":
+        "tests/test_block_diffusion.py (with block_unmask against the "
+        "benchmark reference's host loop: fresh slots, quotas, ties)",
+    "block_unmask":
+        "tests/test_block_diffusion.py (against the benchmark reference's "
+        "host loop, and inside the engine against reference.generate)",
     "moe_routed_ffn":
         "tests/test_window_moe.py (routing, dropless counts and the "
         "grouped matmul vs a plain float64 loop; the op inside the "

@@ -134,12 +134,16 @@ def test_kernel_single_slot_single_page():
     ((8, 32, 1, 128), (1857, 8, 16, 128), True),
     ((3, 4, 1, 8), (19, 2, 16, 8), False),      # head 8: no lane tile
     ((3, 4, 1, 128), (19, 2, 4, 128), False),   # page 4: no sublane tile
-    ((1, 4, 5, 128), (19, 2, 16, 128), False),  # a chunk of query rows
+    ((1, 4, 5, 128), (19, 2, 16, 128), True),   # a block of query rows
+    ((1, 64, 16, 128), (19, 2, 16, 128), False),  # 512 rows a KV head
 ])
 def test_supported_shapes(q_shape, pool_shape, ok):
     from paddle_tpu.ops.pallas.paged_attention import supported
 
     assert supported(q_shape, pool_shape) is ok
+    # a sliding window gives each row of a block its own columns
+    assert supported(q_shape, pool_shape, window=64) \
+        is (ok and q_shape[2] == 1)
 
 
 # ---------------------------------------------------------------------------
