@@ -2055,11 +2055,6 @@ def main():
 
     from paddle_tpu.compile_cache import ensure_compile_cache
 
-    # rbg PRNG: threefry dropout-mask generation costs ~10% of the step
-    # on TPU; rbg makes it free (measured 600 -> 660 samples/s)
-    if "JAX_DEFAULT_PRNG_IMPL" not in os.environ:
-        jax.config.update("jax_default_prng_impl", "rbg")
-
     seq = int(os.environ.get("BENCH_SEQ", "128"))
     # batch sweeps on v5e (round-4 after the dot_general-mul +
     # remat-dropout fixes; round-5 re-sweep):

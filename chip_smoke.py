@@ -37,6 +37,7 @@ from __future__ import annotations
 
 import gc
 import json
+import re
 import sys
 import threading
 import time
@@ -85,6 +86,50 @@ def attention_paths():
 def paths_since(before):
     return {k: v - before[k] for k, v in attention_paths().items()
             if v - before[k]}
+
+
+def dropout_draws():
+    from paddle_tpu.monitor import stat_get
+
+    return {p: stat_get(f"dropout_lowered_{p}")
+            for p in ("hw_bits", "threefry")}
+
+
+def check_dropout_shards(devices, seq, hidden):
+    """One dropout site over ones, through ``build_sharded_step`` on a
+    ``dp`` mesh of ``devices``: the shards' keep masks must differ (the
+    draw runs per shard with the shard's index in its key), a step must
+    repeat and the next must not."""
+    import paddle_tpu as pt
+    from paddle_tpu import layers
+    from paddle_tpu.parallel import build_sharded_step, dp_mesh
+
+    n = len(devices)
+    shape = [2 * n, seq, hidden]
+    main_p, startup = pt.Program(), pt.Program()
+    with pt.program_guard(main_p, startup):
+        x = pt.data(name="x", shape=shape, append_batch_size=False)
+        out = layers.dropout(x, dropout_prob=0.5,
+                             dropout_implementation="upscale_in_train")
+    fn, _, _, _ = build_sharded_step(main_p, ["x"], [out.name],
+                                     dp_mesh(n, devices=devices))
+    ones = np.ones(shape, "float32")
+
+    def keep(step):
+        (ov,), _, _ = fn((ones,), (), (), np.int32(step))
+        return np.asarray(ov) != 0
+
+    first, again, later = keep(1), keep(1), keep(2)
+    check(abs(first.mean() - 0.5) < 0.01,
+          f"dropout keep rate {first.mean():.4f} at p = 0.5")
+    check((first == again).all() and 0.45 < (first == later).mean() < 0.55,
+          "a dropout step does not repeat, or two steps share a mask")
+    shards = np.split(first, n)
+    agree = [float((shards[i] == shards[j]).mean())
+             for i in range(n) for j in range(i + 1, n)]
+    check(all(0.45 < a < 0.55 for a in agree),
+          f"dp shards draw the same dropout mask: pairwise agreement {agree}")
+    return agree
 
 
 # ---------------------------------------------------------------------------
@@ -166,6 +211,7 @@ def train_phase(cfg=TRAIN, on_chip=True):
     B, S = cfg["global_batch"], cfg["seq"]
     check(B % n == 0, f"global batch {B} does not split over {n} devices")
     paths0 = attention_paths()
+    draws0 = dropout_draws()
 
     t_phase = t0 = time.perf_counter()
     worst = check_packed_kernels(B // n, S, cfg["hidden"], cfg["heads"],
@@ -238,6 +284,26 @@ def train_phase(cfg=TRAIN, on_chip=True):
         f"custom call in the compiled step: {mosaic}, attention lowered "
         f"as {paths}")
 
+    # the dropout sites' mask bits come from XLA's bit generator: a draw a
+    # site, of the shard's shape (ops/nn_ops.py _mask_route)
+    sites = 1 + 2 * cfg["layers"]
+    draws = {k: v - draws0[k] for k, v in dropout_draws().items()}
+    check(draws == {"hw_bits": sites, "threefry": 0},
+          f"{sites} dropout sites lowered as {draws}")
+    drawn = re.findall(r"= (u8\[[\d,]+\])\S* rng-bit-generator\(",
+                       executable.as_text())
+    if on_chip:
+        want = f"u8[{B // n},{S},{cfg['hidden']}]"
+        check(len(drawn) >= sites and set(drawn) == {want},
+              f"the compiled step's rng-bit-generator operations are "
+              f"{sorted(set(drawn))} x {len(drawn)}, not {want} a site")
+    say(f"train: dropout lowered as {draws}, rng-bit-generator in the "
+        f"compiled step: {len(drawn)} of {sorted(set(drawn))}")
+    if n > 1:
+        agree = check_dropout_shards(devices, S, cfg["hidden"])
+        say(f"train: dropout masks of the {n} dp shards agree pairwise on "
+            + " ".join(f"{a:.3f}" for a in agree) + " of their elements")
+
     mem = [d.memory_stats() for d in devices]
     if all(mem):
         in_use = [m["bytes_in_use"] for m in mem]
@@ -251,7 +317,7 @@ def train_phase(cfg=TRAIN, on_chip=True):
               f"state is not on every device: {in_use} against "
               f"{params} bytes of replicated state")
     return {"losses": losses, "paths": paths, "mosaic": mosaic,
-            "devices": n, "setup_s": setup_s}
+            "dropout": draws, "devices": n, "setup_s": setup_s}
 
 
 # ---------------------------------------------------------------------------

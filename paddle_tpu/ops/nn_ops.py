@@ -10,12 +10,17 @@ and XLA fuses the pointwise epilogues.
 from __future__ import annotations
 
 import functools
+import logging
 
 import numpy as np
 
 from ..framework.core import Block, Operator, dtype_to_np
+from ..monitor import monitor as _monitor
 from .registry import (LowerContext, in_var, register_op, same_as_input,
                        set_out)
+
+
+logger = logging.getLogger("paddle_tpu.ops.nn")
 
 
 def _jnp():
@@ -254,10 +259,98 @@ def _dropout_infer(op, block):
         set_out(op, block, "Mask", x.shape, "uint8")
 
 
-def _dropout_keep(key, shape, thresh):
+# sites lowered per program build, like the attention_lowered_* stats:
+# onto XLA's RngBitGenerator, or onto threefry where a backend has none
+_DROPOUT_LOWERED = {
+    "hw_bits": _monitor.get("dropout_lowered_hw_bits"),
+    "threefry": _monitor.get("dropout_lowered_threefry"),
+}
+# platforms whose XLA backend lowers RngBitGenerator
+_BIT_GENERATOR_BACKENDS = ("tpu", "cpu", "gpu", "cuda", "rocm")
+_dropout_logged = set()
+
+
+def _log_once(msg, reason):
+    if reason not in _dropout_logged:
+        _dropout_logged.add(reason)
+        logger.warning(msg, reason)
+
+
+def _draw_mask_bits(key, *, shape, axes=()):
+    """uint8 bits of ``shape`` from XLA's RngBitGenerator (JAX's ``rbg``),
+    keyed by the key ``ctx.rng(op)`` hands the op: its key data, widened
+    to the four words the generator's key holds the way JAX's own ``rbg``
+    seed widens threefry's two (twice over), so the step, the program seed
+    and ``__op_seed__`` decide the mask, and a process that sets
+    ``jax_default_prng_impl`` to ``rbg`` draws the same masks.  Under
+    ``shard_map`` (``axes``: the mesh axes dim 0 of ``shape`` is split
+    over) a shard draws its block, with its index folded into the key.
+
+    Bytes are drawn as bytes.  One site of ``[64, 512, 768]`` on a v5e
+    (``tools/dropout_microbench.py``, my chip run, PR 33): the draw, the
+    compare and the select over a bf16 input take 0.209 ms as ``u8``,
+    0.567 ms from threefry, and 0.572 / 0.567 / 0.247 ms as a quarter as
+    many ``u32`` bitcast to bytes (drawn flat, along the last dim, along
+    the first: the bitcast re-tiles), beside 0.232 ms for one elementwise
+    pass over the input alone; five chained sites 0.30 ms against 2.62
+    (threefry) and 1.77-2.39 (words).
+    """
     import jax
-    jnp = _jnp()
-    return jax.random.bits(key, shape, "uint8") >= jnp.uint8(thresh)
+
+    if axes:
+        key = jax.random.fold_in(key, jax.lax.axis_index(axes))
+        shape = (shape[0] // jax.lax.axis_size(axes),) + shape[1:]
+    words = _jnp().resize(jax.random.key_data(key).ravel(), 4)
+    return jax.lax.rng_bit_generator(words, shape, dtype="uint8")[1]
+
+
+def _mask_route(ctx, shape):
+    """Where a site's bits are drawn, from the mesh the op is lowered in:
+    ``None`` on a backend without a bit generator (threefry), else
+    ``(mesh, how)`` for ``attention_ops.call_kernel``.  XLA's partitioner
+    does not split an RngBitGenerator: under a GSPMD mesh every device
+    would draw the global array and slice it (the compiled dp4 module,
+    PR 33), so there the draw runs per ``dp`` shard through ``shard_map``,
+    as the attention kernels do."""
+    import jax
+
+    from .attention_ops import kernel_partition
+
+    backend = jax.default_backend()
+    if backend not in _BIT_GENERATOR_BACKENDS:
+        _log_once("dropout draws its mask bits with threefry: %s",
+                  f"backend {backend!r} is not known to lower "
+                  f"RngBitGenerator")
+        return None
+    route, how = "direct", None
+    if shape:
+        route, how = kernel_partition(
+            ctx.mesh.shape if ctx.mesh is not None else {},
+            getattr(ctx, "axis_names", ()) or (), shape[0], ())
+    if route == "reference":
+        _log_once("dropout draws each site's whole mask on every device "
+                  "of the mesh: %s", how)
+        how = None
+    return ctx.mesh, how
+
+
+def _dropout_keep(key, shape, thresh, route):
+    """The keep mask of one site: uint8 bits >= ``thresh`` (keep
+    probability 1 - thresh/256).  ``route``: ``_mask_route``'s value,
+    hashable, so it rides ``_remat_dropout`` as a static argument and the
+    backward regenerates the forward's bits."""
+    import jax
+
+    from .attention_ops import call_kernel
+
+    if route is None:
+        bits = jax.random.bits(key, shape, "uint8")
+    else:
+        mesh, how = route
+        bits = call_kernel(mesh, how, _draw_mask_bits, (key,), ((),),
+                           ("batch",) + (None,) * (len(shape) - 1),
+                           shape=tuple(shape), axes=how[0] if how else ())
+    return bits >= _jnp().uint8(thresh)
 
 
 _REMAT_DROPOUT = None
@@ -267,11 +360,12 @@ def _remat_dropout():
     """Dropout whose backward REGENERATES the keep mask from the
     stateless key instead of saving it as a residual.
 
-    The saved state is just the key (a few bytes) — the [*x.shape] mask
-    never round-trips HBM between forward and backward, and the forward
-    select stays free to fuse into its producer (the mask residual was
-    pinning a materialization per site; 25 sites x ~13 MB at the BERT
-    flagship config). rbg bit generation is cheap enough to pay twice.
+    The op saves just the key (a few bytes) and no ``[*x.shape]`` mask, so
+    the forward select stays free to fuse into its producer.  What XLA
+    makes of the two equal draws is its own choice: in the compiled
+    BERT-base step (25 sites, v5e, PR 33) it merges them, 25
+    ``rng-bit-generator`` of ``u8[64,512,768]`` whose 25 MB masks live
+    from forward to backward, as it merged the threefry draws before.
 
     Built lazily on first dropout lowering so module import stays
     jax-free (the ops package convention).
@@ -281,17 +375,17 @@ def _remat_dropout():
         import jax
         jnp = _jnp()
 
-        @functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
-        def fn(x, key, thresh, scale):
-            keep = _dropout_keep(key, jnp.shape(x), thresh)
+        @functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4))
+        def fn(x, key, thresh, scale, route):
+            keep = _dropout_keep(key, jnp.shape(x), thresh, route)
             return jnp.where(keep, x * scale if scale != 1.0 else x,
                              0.0).astype(x.dtype)
 
-        def fwd(x, key, thresh, scale):
-            return fn(x, key, thresh, scale), key
+        def fwd(x, key, thresh, scale, route):
+            return fn(x, key, thresh, scale, route), key
 
-        def bwd(thresh, scale, key, g):
-            keep = _dropout_keep(key, jnp.shape(g), thresh)
+        def bwd(thresh, scale, route, key, g):
+            keep = _dropout_keep(key, jnp.shape(g), thresh, route)
             dx = jnp.where(keep, g * scale if scale != 1.0 else g, 0.0)
             return dx.astype(g.dtype), None
 
@@ -302,7 +396,6 @@ def _remat_dropout():
 
 @register_op("dropout", infer=_dropout_infer)
 def _dropout(ctx: LowerContext, op: Operator):
-    import jax
     jnp = _jnp()
     x = ctx.get_input(op, "X")
     p = op.attr("dropout_prob", 0.5)
@@ -315,21 +408,18 @@ def _dropout(ctx: LowerContext, op: Operator):
             ctx.set_output(op, "Mask",
                            jnp.ones(jnp.shape(x), dtype="uint8"))
         return
-    # NOTE(perf): a pallas fused-dropout kernel with in-kernel hardware
-    # PRNG (pltpu.prng_random_bits) was built and measured on v5e:
-    # 775 samples/s vs 847 for this XLA path on the BERT flagship — the
-    # pallas_call boundary costs more fusion than the in-kernel bits
-    # save in HBM traffic. XLA already fuses bernoulli+select into the
-    # surrounding elementwise chains; keep the XLA path.
+    # NOTE(perf): the bits are XLA's RngBitGenerator, not a Pallas kernel
+    # with an in-kernel generator: XLA fuses the compare and select into
+    # the surrounding elementwise chains, which a pallas_call boundary
+    # would cut (a fused-dropout kernel: not measured since the ledger
+    # began).  BERT-base at 64 x 512 on a v5e, 25 sites (my chip runs,
+    # PR 33): step 222.9 ms with threefry bits, 195.4 ms with these.
     scale = (0.0 if p >= 1.0 else 1.0 / (1.0 - p)) \
         if impl == "upscale_in_train" else 1.0
     # raw-bits threshold instead of bernoulli: same keep distribution
     # (uniform bits >= p*2^n has probability ~1-p) without bernoulli's
-    # bits->float _uniform conversion pass (profiled ~1.4% of the BERT
-    # step across 37 dropout sites).  uint8 bits: 4x less rng HBM
-    # traffic than u32 (the [B,h,S,S] prob-dropout bits tensor alone is
-    # 100 MB at seq-128); keep-probability granularity 1/256 (p quantized
-    # by <0.4%, irrelevant for regularization)
+    # bits->float conversion pass; keep-probability granularity 1/256 (p
+    # quantized by <0.4%, irrelevant for regularization)
     if p >= 255.5 / 256.0:  # not representable in u8 granularity: drop all
         keep = jnp.zeros(jnp.shape(x), bool)
         out = jnp.where(keep, x, 0.0).astype(x.dtype)
@@ -338,17 +428,22 @@ def _dropout(ctx: LowerContext, op: Operator):
             ctx.set_output(op, "Mask", keep.astype("uint8"))
         return
     thresh = round(max(p, 0.0) * 256.0)
+    route = _mask_route(ctx, jnp.shape(x))
+    if not getattr(ctx, "relowered", False):
+        # a site once: its re-lowering inside the auto-grad op is the
+        # same draw
+        _DROPOUT_LOWERED["threefry" if route is None
+                         else "hw_bits"].increase()
     if op.output("Mask"):
         # mask requested (reference-compat Mask output): materialize it
-        bits = jax.random.bits(ctx.rng(op), jnp.shape(x), "uint8")
-        keep = bits >= jnp.uint8(thresh)
+        keep = _dropout_keep(ctx.rng(op), jnp.shape(x), thresh, route)
         out = jnp.where(keep, x * scale if scale != 1.0 else x,
                         0.0).astype(x.dtype)
         ctx.set_output(op, "Out", out)
         ctx.set_output(op, "Mask", keep.astype("uint8"))
         return
     ctx.set_output(op, "Out",
-                   _remat_dropout()(x, ctx.rng(op), thresh, scale))
+                   _remat_dropout()(x, ctx.rng(op), thresh, scale, route))
 
 
 # ---------------------------------------------------------------------------

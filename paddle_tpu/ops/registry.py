@@ -376,6 +376,8 @@ def _lower_auto_grad(ctx: LowerContext, gop: Operator):
                            is_test=ctx.is_test, mesh=ctx.mesh, amp=ctx.amp)
         sub.axis_names = getattr(ctx, "axis_names", ())
         sub.ring_table = getattr(ctx, "ring_table", {})
+        # lowerings that book what they lowered to, once a site, read this
+        sub.relowered = True
         _lower_with_amp(sub, opdef, fwd_op)
         return tuple(env[n] for n in out_order)
 

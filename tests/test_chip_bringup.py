@@ -54,6 +54,7 @@ def test_chip_smoke_train_phase_toy():
     out = chip_smoke.train_phase(TOY_TRAIN, on_chip=False)
     assert out["devices"] == 8 and not out["mosaic"]
     assert out["paths"] == {"blockwise": out["paths"]["blockwise"]}
+    assert out["dropout"]["hw_bits"] > 0 and not out["dropout"]["threefry"]
     assert out["losses"][-1] < out["losses"][0]
 
 
@@ -158,9 +159,6 @@ def _stub_bench(monkeypatch, serving):
                  "BENCH_DECODE", "BENCH_PAGED", "BENCH_SPEC",
                  "BENCH_DISAGG"):
         monkeypatch.setenv(name, "0")
-    # main() would otherwise switch the process's PRNG to rbg — for
-    # every test that runs after this one
-    monkeypatch.setenv("JAX_DEFAULT_PRNG_IMPL", "threefry2x32")
     monkeypatch.setattr(bench, "run_config",
                         lambda *a, **k: {"value": 1.0, "device_kind": "cpu"})
     monkeypatch.setattr(bench, "run_serving", serving)
