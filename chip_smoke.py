@@ -24,6 +24,10 @@ random seeded weights:
   configuration: hidden 2048, 16 heads of 128, SwiGLU FFN 8192, vocab
   50304, 16 layers, 8 slots x 2048 positions, float32.
 
+* **slot state** (PR 34) — the paged-decode kernel at heads of 64 over a
+  pool packed two heads a row, and one convolution-state round trip
+  (prefill, three decode steps, the slot reused) through a two-slot engine.
+
 Any failed check raises: the exit code is non-zero and no result line is
 printed.  Without a TPU backend the script refuses to run (exit 2).  The
 last line of stdout is one JSON object
@@ -589,6 +593,94 @@ def sparse_window_phase(cfg=SPARSE):
         f"within {rel:.4g} of the loop over all experts")
 
 
+HYBRID = dict(heads=32, kv_heads=8, head_dim=64, page_tokens=16,
+              contexts=(1, 70, 900, 3000), hidden=2048, conv_taps=3,
+              prompt=70, steps=3)
+
+
+def slot_state_phase(cfg=HYBRID):
+    """What a decoder with gated short-convolution layers beside heads of
+    64 adds (PR 34), at that family's published shapes: the paged-decode
+    kernel at 32 query over 8 KV heads of 64, the pool packed two heads a
+    128-lane row, against the gather + einsum formulation, with the two
+    counters of the op inside a program; and one convolution-state round
+    trip through a two-slot ``GenerationEngine`` of one conv layer and
+    one attention layer at hidden 2048: a prefill, three decode steps,
+    the slot taken again, each against the uncached forward."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops.decode_ops import (_attend_cache, _gather_pages,
+                                           pool_shape)
+    from paddle_tpu.ops.pallas.paged_attention import (
+        paged_decode_attention, supported)
+    from paddle_tpu.serving import GenerationEngine
+
+    key = jax.random.key(34)
+    pt_, ctx, D = cfg["page_tokens"], cfg["contexts"], cfg["head_dim"]
+    B, NP = len(ctx), -(-max(ctx) // cfg["page_tokens"])
+    shape = tuple(pool_shape(B * NP + 1, cfg["kv_heads"], pt_, D))
+    check(shape[1:] == (cfg["kv_heads"] // 2, pt_, 128),
+          f"heads of 64 are not packed two a row: pool {shape}")
+    pk = jax.random.normal(jax.random.fold_in(key, 0), shape)
+    pv = jax.random.normal(jax.random.fold_in(key, 1), shape)
+    q = jax.random.normal(jax.random.fold_in(key, 2),
+                          (B, cfg["heads"], 1, D))
+    check(supported(q.shape, shape), "the paged kernel refuses head 64")
+    bt = jnp.asarray(np.arange(1, B * NP + 1, dtype=np.int32).reshape(B, NP))
+    pos = jnp.asarray([n - 1 for n in ctx], jnp.int32)
+    got = paged_decode_attention(q, pk, pv, bt, pos)
+    with jax.default_matmul_precision("highest"):
+        want = _attend_cache(q, _gather_pages(pk, bt, D),
+                             _gather_pages(pv, bt, D), pos)
+    rel = float(jnp.abs(got - want).max() / jnp.abs(want).max())
+    check(bool(jnp.isfinite(got).all()) and rel <= TOL,
+          f"head-64 paged decode off the gather formulation by {rel:.4g}")
+    say(f"hybrid: paged decode at {cfg['heads']} / {cfg['kv_heads']} heads "
+        f"of {D}, contexts {ctx}, within {rel:.4g} of the gather "
+        f"formulation (tolerance {TOL})")
+
+    conv = {"kind": "conv", "L_cache": cfg["conv_taps"], "bias": False}
+    model = dict(vocab_size=4096, hidden=cfg["hidden"], num_layers=2,
+                 num_heads=cfg["heads"], num_kv_heads=cfg["kv_heads"],
+                 intermediate=4096, rms_norm_eps=1e-5, rope_base=1e6,
+                 qk_norm=True, tie_head=True,
+                 layer_pattern=[{"mixer": conv}, {"mixer": "attention"}])
+    before = attention_paths()
+    gen = GenerationEngine(model, num_slots=2, max_seq_len=256,
+                           prefill_buckets=[128], page_tokens=pt_,
+                           prefill_chunk=0, prefix_reuse=False,
+                           speculate=False, keep_logits=True, eos_id=-1)
+    try:
+        gen.warmup()
+        lowered = paths_since(before)
+        check(lowered.get("paged_decode", 0) >= 1
+              and not lowered.get("paged_decode_reference"),
+              f"the head-64 decode step did not lower to the Pallas "
+              f"kernel: {lowered}")
+        rng = np.random.default_rng(34)
+        worst = 0.0
+        for n in (cfg["prompt"], 2):     # the second reuses slot 0
+            prompt = rng.integers(1, 4096, n).tolist()
+            res = gen.generate(prompt, cfg["steps"] + 1, timeout=600)
+            check(res["slot"] == 0, f"request landed in slot {res['slot']}")
+            seq = prompt + res["tokens"]
+            want = _forward_logits(gen, model, seq, 128)[n - 1:n + cfg["steps"]]
+            got = np.stack(res["logits"])
+            rel = float(np.abs(got - want).max() / np.abs(want).max())
+            worst = max(worst, rel)
+            check(np.isfinite(got).all() and rel <= TOL,
+                  f"conv-state round trip (prompt {n}) off the uncached "
+                  f"forward by {rel:.4g}")
+        writes = gen.stats()["counters"]["slot_state_writes"]
+        check(writes == 2, f"{writes} prefills wrote a slot's state, not 2")
+    finally:
+        gen.close()
+    say(f"hybrid: conv state through prefill, {cfg['steps']} decode steps "
+        f"and a reused slot within {worst:.4g} of the uncached forward; "
+        f"decode step on the Pallas kernel ({lowered})")
+
+
 def main():
     t_start = time.perf_counter()
     # the program first: in a directory that holds only this file the
@@ -632,6 +724,12 @@ def main():
     t0 = time.perf_counter()
     sparse_window_phase()
     say(f"sparse and windowed kernels done "
+        f"[{time.perf_counter() - t0:.1f} s]")
+    gc.collect()
+
+    t0 = time.perf_counter()
+    slot_state_phase()
+    say(f"slot state and head-64 kernel done "
         f"[{time.perf_counter() - t0:.1f} s]")
 
     say(f"set-up (compile-dominated: kernel check + first train step + "

@@ -24,6 +24,7 @@ __all__ = [
     "rms_norm", "rope",
     "cached_attention", "kv_pool_write", "kv_pool_gather",
     "paged_decode_attention", "block_begin", "block_unmask",
+    "short_conv", "short_conv_tail", "slot_state_write", "short_conv_step",
     "linear_chain_crf", "crf_decoding", "warpctc",
     "nce", "hsigmoid", "conv3d", "pool3d", "lrn", "row_conv",
     "shuffle_channel", "temporal_shift", "multiplex",
@@ -661,17 +662,22 @@ def kv_pool_write(pool, new, positions, block_table, lengths,
     return pool
 
 
-def kv_pool_gather(pool, block_table, name=None):
+def kv_pool_gather(pool, block_table, name=None, head_dim=None):
     """Gather a slot's pages back into the logical cache layout:
     ``pool`` [P, Hkv, pt, D] through ``block_table`` [B, NP] ->
     [B, Hkv, NP*pt, D] (column j = logical position j, exactly what
-    :func:`cached_attention` contracts over)."""
+    :func:`cached_attention` contracts over).  ``head_dim``: the heads'
+    own width where the pool packs two a row (ops/decode_ops.py
+    ``pool_shape``); the view comes back unpacked."""
     helper = LayerHelper("kv_pool_gather", name=name)
     out = helper.create_variable_for_type_inference(pool.dtype)
+    attrs = {}
+    if head_dim is not None and int(head_dim) != int(pool.shape[-1]):
+        attrs["head_dim"] = int(head_dim)
     helper.append_op("kv_pool_gather",
                      inputs={"Pool": [pool],
                              "BlockTable": [block_table]},
-                     outputs={"Out": [out]})
+                     outputs={"Out": [out]}, attrs=attrs)
     return out
 
 
@@ -755,6 +761,75 @@ def block_unmask(logits, tokens, masked, quota, name=None):
                              "Masked": [masked], "Quota": [quota]},
                      outputs={"TokensOut": [t_out], "MaskedOut": [m_out]})
     return t_out, m_out
+
+
+def _short_conv_params(helper, hidden, kernel, param_attr, bias_attr, dtype):
+    """The depthwise kernel [H, L] (Glorot over one channel's fan: L taps
+    in, L out) and, where asked for, the bias [H]."""
+    from ..framework.initializer import XavierInitializer
+
+    inputs = {"W": [helper.create_parameter(
+        param_attr, [hidden, int(kernel)], dtype,
+        default_initializer=XavierInitializer(fan_in=int(kernel),
+                                              fan_out=int(kernel)))]}
+    if bias_attr:
+        inputs["Bias"] = [helper.create_parameter(bias_attr, [hidden], dtype,
+                                                  is_bias=True)]
+    return inputs
+
+
+def short_conv(x, kernel, param_attr=None, bias_attr=None, name=None):
+    """Causal depthwise convolution of ``kernel`` taps over ``x``
+    [B, S, H] with zero history (ops/decode_ops.py ``short_conv``): the
+    whole-sequence form of a gated short-convolution mixer."""
+    helper = LayerHelper("short_conv", name=name)
+    inputs = _short_conv_params(helper, int(x.shape[-1]), kernel,
+                                param_attr, bias_attr, x.dtype)
+    out = helper.create_variable_for_type_inference(x.dtype)
+    helper.append_op("short_conv", inputs=dict(inputs, X=[x]),
+                     outputs={"Out": [out]})
+    return out
+
+
+def short_conv_tail(x, lengths, rows, name=None):
+    """The ``rows`` rows of ``x`` [B, S, H] before position
+    ``lengths[b]``, oldest first, zero where there are fewer: what a
+    right-padded prompt leaves for the decode step's convolution."""
+    helper = LayerHelper("short_conv_tail", name=name)
+    out = helper.create_variable_for_type_inference(x.dtype)
+    helper.append_op("short_conv_tail",
+                     inputs={"X": [x], "Lengths": [lengths]},
+                     outputs={"Out": [out]}, attrs={"rows": int(rows)})
+    return out
+
+
+def slot_state_write(state, rows, slot, name=None):
+    """Per-slot state that is not pages, in place: ``state`` [slots + 1,
+    R, H] gets ``rows`` [1, R, H] as the whole of row ``slot[0]`` (row
+    ``slots`` is the trash row).  Returns the state Variable, donated
+    like a page pool."""
+    helper = LayerHelper("slot_state_write", name=name)
+    helper.append_op("slot_state_write",
+                     inputs={"State": [state], "Rows": [rows],
+                             "Slot": [slot]},
+                     outputs={"StateOut": [state]})
+    return state
+
+
+def short_conv_step(x, state, live, kernel, param_attr=None, bias_attr=None,
+                    name=None):
+    """One decode step of :func:`short_conv`: ``x`` [slots, 1, H] over
+    ``state`` [slots + 1, kernel - 1, H]; the state moves on by one row
+    for rows with ``live`` set, in place.  Returns the output
+    [slots, 1, H]."""
+    helper = LayerHelper("short_conv_step", name=name)
+    inputs = _short_conv_params(helper, int(x.shape[-1]), kernel,
+                                param_attr, bias_attr, x.dtype)
+    out = helper.create_variable_for_type_inference(x.dtype)
+    helper.append_op("short_conv_step",
+                     inputs=dict(inputs, X=[x], State=[state], Live=[live]),
+                     outputs={"Out": [out], "StateOut": [state]})
+    return out
 
 
 def resize_bilinear(input, out_shape=None, scale=None, name=None,
@@ -1138,7 +1213,8 @@ def py_func(func, x, out, backward_func=None,
 
 def moe_routed_ffn(x, router_x, num_experts, top_k, d_ff,
                    activation="relu", valid=None, name=None,
-                   keep_router_logits=False):
+                   keep_router_logits=False, score="softmax",
+                   expert_bias=False, norm_topk=True, route_scale=1.0):
     """Dropless top-k mixture of gated experts without bias
     (ops/moe_ops.py ``moe_routed_ffn``): each token of ``x`` [B, S, H]
     goes to the ``top_k`` experts its row of ``router_x`` [B, S, H]
@@ -1146,6 +1222,12 @@ def moe_routed_ffn(x, router_x, num_experts, top_k, d_ff,
     ``act(x W_gate) * (x W_up)`` then ``W_down``; no capacity, nothing
     dropped.  ``valid`` [B] int: real rows per batch row, for the count.
     ``activation``: "relu" or "silu".
+    ``score`` "sigmoid" scores each expert on its own: the ``top_k``
+    largest of ``sigmoid(logits)`` (plus, with ``expert_bias``, the
+    float32 parameter ``.expert_bias`` [E], which moves the choice and
+    never the weights), weighted by their unbiased sigmoids, with
+    ``norm_topk`` divided by their sum plus 1e-6, times ``route_scale``
+    (``parallel/moe.py`` ``route_top_k``).
     ``name`` prefixes the parameters ``.router.w`` [H, E], ``.gate_up.w``
     [E, H, 2 d_ff] and ``.down.w`` [E, d_ff, H].  Returns ``(out,
     expert_count [E] int32, router_logits or None)``."""
@@ -1168,14 +1250,20 @@ def moe_routed_ffn(x, router_x, num_experts, top_k, d_ff,
               "GateUpW": [gate_up], "DownW": [down]}
     if valid is not None:
         inputs["Valid"] = [valid]
+    attrs = {"top_k": int(top_k), "activation": activation}
+    if score != "softmax" or not norm_topk or route_scale != 1.0:
+        attrs.update(score=score, norm_topk=bool(norm_topk),
+                     route_scale=float(route_scale))
+    if expert_bias:
+        inputs["ExpertBias"] = [helper.create_parameter(
+            p("expert_bias"), [e], "float32", is_bias=True)]
     outputs = {"Out": [out], "ExpertCount": [counts]}
     logits = None
     if keep_router_logits:
         logits = helper.create_variable_for_type_inference("float32")
         outputs["RouterLogits"] = [logits]
     helper.append_op("moe_routed_ffn", inputs=inputs, outputs=outputs,
-                     attrs={"top_k": int(top_k),
-                            "activation": activation})
+                     attrs=attrs)
     return out, counts, logits
 
 

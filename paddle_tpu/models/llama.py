@@ -49,13 +49,24 @@ from .. import layers
 #           (``activation`` "relu" or "silu") routed from the layer's
 #           RAW input, before its first norm and its attention, or with
 #           "route_from": "normed" from what the experts read: the
-#           normed post-attention stream
+#           normed post-attention stream.  Further keys, as
+#           ``parallel/moe.py`` ``route_top_k`` reads them: "score"
+#           ("softmax", or "sigmoid": each expert scored on its own),
+#           "expert_bias" (True: a learned [E] float32 bias moves the
+#           choice, never the weights), "norm_topk" (True), "route_scale"
+#           (1.0)
+#   mixer:  "attention" (q, k, v, RoPE, pages) or a dict {"kind": "conv",
+#           "L_cache": L, "bias": False}: a gated short convolution,
+#           ``[B, C, u] = split3(h W_in)``, ``y = (C * conv_L(B * u))
+#           W_out``, depthwise and causal over L taps.  Such a layer has
+#           no q, k, v, no RoPE and no pages: its cache is the last L - 1
+#           rows of ``B * u``, per slot (``cache_spec``)
 #   attn_precision: None (the prefill attention kernel's two products at
 #           the backend's default: a TPU rounds float32 operands to
 #           bfloat16) or "highest" (operands whole, as the paged decode
 #           kernel and the matmuls take float32), whatever the mask
 DEFAULT_LAYER = {"window": None, "rope": True, "ffn": "dense",
-                 "attn_precision": None}
+                 "attn_precision": None, "mixer": "attention"}
 
 
 def layer_spec(layer_pattern, i):
@@ -65,10 +76,60 @@ def layer_spec(layer_pattern, i):
     return dict(DEFAULT_LAYER, **layer_pattern[i % len(layer_pattern)])
 
 
-def window_layers(layer_pattern, num_layers):
-    """Indices of the layers whose attention is a sliding window."""
+def conv_layers(layer_pattern, num_layers):
+    """Indices of the layers whose mixer is a gated short convolution."""
     return [i for i in range(num_layers)
-            if layer_spec(layer_pattern, i)["window"] is not None]
+            if layer_spec(layer_pattern, i)["mixer"] != "attention"]
+
+
+def window_layers(layer_pattern, num_layers):
+    """Indices of the attention layers whose attention is a sliding
+    window."""
+    conv = conv_layers(layer_pattern, num_layers)
+    return [i for i in range(num_layers) if i not in conv
+            and layer_spec(layer_pattern, i)["window"] is not None]
+
+
+def cache_spec(name, num_layers, layer_pattern=None, *, num_slots,
+               num_pages, page_tokens, num_kv_heads, head_dim, hidden,
+               num_window_pages=None):
+    """What a decoder keeps between steps, layer by layer: the one
+    description the program builders declare their persistable state
+    from and the serving engine allocates from.  A list of ``{"name",
+    "layer", "kind", "shape"}``, kinds:
+
+    * ``"pages"`` / ``"window_pages"``: an attention layer's K and V page
+      pools (two entries, K first), ``ops/decode_ops.py`` ``pool_shape``
+      of the full or the window pool's page count;
+    * ``"slot_state"``: a conv layer's last ``L_cache - 1`` gated inputs
+      per slot, ``[num_slots + 1, L_cache - 1, hidden]`` (the last row is
+      the trash row a warm-up writes)."""
+    from ..ops.decode_ops import pool_shape
+
+    windowed = window_layers(layer_pattern, num_layers)
+    spec = []
+    for i in range(num_layers):
+        mixer = layer_spec(layer_pattern, i)["mixer"]
+        if mixer != "attention":
+            spec.append({"name": f"{name}.conv_state_{i}", "layer": i,
+                         "kind": "slot_state",
+                         "shape": [num_slots + 1, int(mixer["L_cache"]) - 1,
+                                   hidden]})
+            continue
+        kind = "window_pages" if i in windowed else "pages"
+        shape = pool_shape(num_window_pages if i in windowed else num_pages,
+                           num_kv_heads, page_tokens, head_dim)
+        spec += [{"name": f"{name}.pool_{kv}_{i}", "layer": i, "kind": kind,
+                  "shape": shape} for kv in ("k", "v")]
+    return spec
+
+
+def _cache_vars(block, spec, layer):
+    """Layer ``layer``'s persistable cache variables, declared from its
+    entries of :func:`cache_spec` (K and V pools, or the one state)."""
+    return tuple(block.create_var(
+        name=e["name"], persistable=True, shape=e["shape"], dtype="float32",
+        stop_gradient=True) for e in spec if e["layer"] == layer)
 
 
 def expert_layers(layer_pattern, num_layers):
@@ -94,14 +155,20 @@ def _taps_fetches(taps):
     return out
 
 
-def _kv_vars(block, name, i, shape):
-    """Layer ``i``'s persistable K and V page pools."""
-    return tuple(block.create_var(
-        name=f"{name}.pool_{kind}_{i}", persistable=True, shape=shape,
-        dtype="float32", stop_gradient=True) for kind in ("k", "v"))
+def _head(x, vocab_size, name, tie_head=False):
+    """The LM head over normed rows x [B, S, H] -> [B, S, V]: its own
+    matrix ``.head.w`` [H, V], or with ``tie_head`` the embedding table
+    ``.embed`` [V, H] itself, read transposed (one parameter, not two)."""
+    if not tie_head:
+        return _linear(x, vocab_size, pname=f"{name}.head.w" if name
+                       else None)
+    from ..framework.core import default_main_program
+
+    table = default_main_program().global_block().var(f"{name}.embed")
+    return layers.matmul(x, table, transpose_y=True)
 
 
-def _head_on_rows(x, rows_idx, vocab_size, name, eps):
+def _head_on_rows(x, rows_idx, vocab_size, name, eps, tie_head=False):
     """Final norm and LM head on one gathered row per batch row: x
     [B, S, H], ``rows_idx`` [B] int64 -> logits [B, V].  The head then
     costs V x H per request, not S x V x H (5 GB of float32 logits at
@@ -111,8 +178,41 @@ def _head_on_rows(x, rows_idx, vocab_size, name, eps):
     coords = layers.stack([rows, rows_idx], axis=1)          # [B, 2]
     x = layers.unsqueeze(layers.gather_nd(x, coords), [1])   # [B, 1, H]
     x = layers.rms_norm(x, epsilon=eps, param_attr=f"{name}.ln_f")
-    return layers.squeeze(
-        _linear(x, vocab_size, pname=f"{name}.head.w"), [1])
+    return layers.squeeze(_head(x, vocab_size, name, tie_head), [1])
+
+
+def _conv_mixer(h, hidden, mixer, p, valid=None, conv_state=None,
+                slot=None, live=None):
+    """The gated short-convolution mixer on normed rows h [B, S, H]:
+    ``[B, C, u] = split3(h W_in)``, ``z = B * u``, ``y = (C * conv(z))
+    W_out``.  Three modes, as the attention mixer has them: with
+    ``conv_state`` and ``live`` the decode step (S = 1: the state's rows
+    and the fresh one, the state moved on in place for live rows); with
+    ``conv_state`` and ``slot`` a prefill that also leaves the rows
+    before ``valid`` (the prompt's true length) as slot ``slot``'s
+    state; else the plain whole-sequence form.  Returns ``(y, tail)``:
+    ``tail`` [B, L - 1, H] where a prefill has ``valid`` and no state to
+    write to (the caller fetches it), else None."""
+    kernel = int(mixer["L_cache"])
+    conv_w = dict(param_attr=p("conv.w"),
+                  bias_attr=p("conv.b") if mixer.get("bias") else None)
+    bcu = _linear(h, 3 * hidden, pname=p("conv_in.w"))
+    gate_b, gate_c, u = (layers.slice(bcu, axes=[2], starts=[j * hidden],
+                                      ends=[(j + 1) * hidden])
+                         for j in range(3))
+    z = layers.elementwise_mul(gate_b, u)
+    tail = None
+    if live is not None:
+        c = layers.short_conv_step(z, conv_state, live, kernel, **conv_w)
+    else:
+        c = layers.short_conv(z, kernel, **conv_w)
+        if valid is not None:
+            tail = layers.short_conv_tail(z, valid, kernel - 1)
+            if conv_state is not None:
+                layers.slot_state_write(conv_state, tail, slot)
+                tail = None
+    return _linear(layers.elementwise_mul(gate_c, c), hidden,
+                   pname=p("conv_out.w")), tail
 
 
 def llama_block(x, hidden, num_heads, num_kv_heads, seq_len, head_dim,
@@ -120,8 +220,16 @@ def llama_block(x, hidden, num_heads, num_kv_heads, seq_len, head_dim,
                 kv_cache=None, positions=None, collect_kv=False,
                 block_table=None, kv_lengths=None, rms_norm_eps=1e-6,
                 rope_base=10000.0, layer=None, valid=None, taps=None,
-                qk_norm=False, mask_block=None, block=False):
+                qk_norm=False, mask_block=None, block=False,
+                conv_state=None, slot=None, live=None):
     """One decoder layer. x: [B, S, H].
+
+    A layer whose ``mixer`` is a gated short convolution
+    (:data:`DEFAULT_LAYER`) runs :func:`_conv_mixer` where the others
+    run attention: ``conv_state`` is its per-slot state variable, with
+    ``live`` [B] in the decode step and ``slot`` [1] (and ``valid``, the
+    prompt's length) in a prefill; with ``collect_kv`` it returns ``(x,
+    tail, None)``, the state rows where no variable took them.
 
     ``qk_norm``: q and k are RMS-normalised over ``head_dim`` with a
     learned weight each (``.q_norm`` / ``.k_norm``) before RoPE.
@@ -170,6 +278,12 @@ def llama_block(x, hidden, num_heads, num_kv_heads, seq_len, head_dim,
     p = (lambda s: f"{name}.{s}") if name else (lambda s: None)
     x_in = x          # routed experts read the raw layer input
     h = layers.rms_norm(x, epsilon=rms_norm_eps, param_attr=p("ln1"))
+    if layer["mixer"] != "attention":
+        y, tail = _conv_mixer(h, hidden, layer["mixer"], p, valid=valid,
+                              conv_state=conv_state, slot=slot, live=live)
+        out = _ffn(layers.elementwise_add(x, y), x_in, hidden, intermediate,
+                   layer["ffn"], p, rms_norm_eps, valid, taps)
+        return (out, tail, None) if collect_kv else out
     qkv = _linear(h, q_size + 2 * kv_size, pname=p("qkv.w"))
     q = layers.slice(qkv, axes=[2], starts=[0], ends=[q_size])
     k = layers.slice(qkv, axes=[2], starts=[q_size],
@@ -213,8 +327,10 @@ def llama_block(x, hidden, num_heads, num_kv_heads, seq_len, head_dim,
                 q, cache_k, cache_v, block_table, positions, **win)
         else:
             # a chunk of query rows: the gathered logical view
-            gk = layers.kv_pool_gather(cache_k, block_table)
-            gv = layers.kv_pool_gather(cache_v, block_table)
+            gk = layers.kv_pool_gather(cache_k, block_table,
+                                       head_dim=head_dim)
+            gv = layers.kv_pool_gather(cache_v, block_table,
+                                       head_dim=head_dim)
             attn = layers.cached_attention(q, gk, gv, positions, **win)
     else:
         cache_k = cache_v = None
@@ -244,9 +360,18 @@ def llama_block(x, hidden, num_heads, num_kv_heads, seq_len, head_dim,
     attn = layers.reshape(attn, [0, seq_len, q_size])
     x = layers.elementwise_add(x, _linear(attn, hidden,
                                           pname=p("attn_out.w")))
+    out = _ffn(x, x_in, hidden, intermediate, layer["ffn"], p, rms_norm_eps,
+               valid, taps)
+    if collect_kv:
+        return out, new_k, new_v
+    return out
 
+
+def _ffn(x, x_in, hidden, intermediate, ffn, p, rms_norm_eps, valid, taps):
+    """The layer's second half on the post-mixer stream x: norm, dense
+    SwiGLU or routed experts (``x_in``: the layer's raw input, which some
+    routers read), residual."""
     h = layers.rms_norm(x, epsilon=rms_norm_eps, param_attr=p("ln2"))
-    ffn = layer["ffn"]
     if ffn == "dense":
         gate_up = _linear(h, 2 * intermediate, pname=p("gate_up.w"))
         gate = layers.slice(gate_up, axes=[2], starts=[0],
@@ -261,27 +386,27 @@ def llama_block(x, hidden, num_heads, num_kv_heads, seq_len, head_dim,
             h, h if ffn.get("route_from", "raw") == "normed" else x_in,
             ffn["experts"], ffn["top_k"], ffn["width"],
             activation=ffn.get("activation", "relu"), valid=valid,
-            name=p("moe"), keep_router_logits=bool(taps.get("keep_logits")))
+            name=p("moe"), keep_router_logits=bool(taps.get("keep_logits")),
+            **{k: ffn[k] for k in ("score", "expert_bias", "norm_topk",
+                                   "route_scale") if k in ffn})
         taps.setdefault("counts", []).append(counts)
         if logits is not None:
             taps.setdefault("logits", []).append(logits)
-    out = layers.elementwise_add(x, y)
-    if collect_kv:
-        return out, new_k, new_v
-    return out
+    return layers.elementwise_add(x, y)
 
 
 def llama(input_ids, vocab_size=32000, hidden=4096, num_layers=32,
           num_heads=32, num_kv_heads=None, intermediate=11008,
           seq_len=2048, name=None, attn_impl="auto", head_dim=None,
           rms_norm_eps=1e-6, rope_base=10000.0, layer_pattern=None,
-          qk_norm=False, mask_block=None):
+          qk_norm=False, mask_block=None, tie_head=False):
     """Returns logits [B, S, V]. input_ids: [B, S] int64.
 
     ``head_dim`` defaults to ``hidden // num_heads`` (a model may
     publish another: q is then ``num_heads * head_dim`` wide);
     ``layer_pattern`` is described at :data:`DEFAULT_LAYER`, ``qk_norm``
-    and ``mask_block`` at :func:`llama_block`.  The
+    and ``mask_block`` at :func:`llama_block`; ``tie_head`` makes the
+    head's product read the embedding table (needs ``name``).  The
     defaults build exactly the program they always did."""
     num_kv_heads = num_kv_heads or num_heads
     head_dim = head_dim or hidden // num_heads
@@ -297,7 +422,7 @@ def llama(input_ids, vocab_size=32000, hidden=4096, num_layers=32,
                         layer=layer_spec(layer_pattern, i),
                         qk_norm=qk_norm, mask_block=mask_block)
     x = layers.rms_norm(x, epsilon=rms_norm_eps, param_attr=p("ln_f"))
-    return _linear(x, vocab_size, pname=p("head.w"))
+    return _head(x, vocab_size, name, tie_head)
 
 
 def build_llama_train(batch_size=None, seq_len=2048, vocab_size=32000,
@@ -348,9 +473,17 @@ def build_llama_prefill(batch_size, seq_len, vocab_size=32000,
                         head_dim=None, rms_norm_eps=1e-6,
                         rope_base=10000.0, layer_pattern=None,
                         num_window_pages=None, keep_router_logits=False,
-                        qk_norm=False, mask_block=None):
+                        qk_norm=False, mask_block=None, tie_head=False):
     """Prefill entry point: one causal forward over the (padded) prompt
     that populates a decode cache in one shot.
+
+    A model with gated short-convolution layers (``mixer`` of
+    :data:`DEFAULT_LAYER`) takes one more feed in the paged mode, ``slot``
+    [1] int32: each such layer writes the rows its convolution leaves
+    behind at the prompt's TRUE last positions (``prompt_len``, not the
+    bucket's) as the whole of that slot's state (``cache_spec``;
+    ``slot`` = ``cache_slots`` is the trash row).  In the other mode the
+    rows come back as fetches ``state_<i>`` [B, L - 1, H].
 
     ``mask_block=B`` (block diffusion; the paged mode only): the forward
     runs under the block-causal mask and only commits K/V — the engine
@@ -412,8 +545,13 @@ def build_llama_prefill(batch_size, seq_len, vocab_size=32000,
     elif cache_slots is None:
         raise ValueError("a block-causal prefill (mask_block) only "
                          "commits K/V: it needs the paged cache")
-    block_table = bt_window = prompt_len = zero_pos = None
+    block_table = bt_window = prompt_len = zero_pos = slot = None
     windowed = window_layers(layer_pattern, num_layers)
+    has_conv = bool(conv_layers(layer_pattern, num_layers))
+    spec = []
+    if mask_block is not None and has_conv:
+        raise ValueError("a block-causal prefill over convolution layers "
+                         "is not built: their state is causal")
     if cache_slots is not None:
         if batch_size != 1:
             raise ValueError("in-graph cache insert prefills one "
@@ -438,47 +576,62 @@ def build_llama_prefill(batch_size, seq_len, vocab_size=32000,
                                     dtype="int32",
                                     append_batch_size=False)
             feeds.append("block_table_window")
+        if has_conv:
+            slot = layers.data("slot", [1], dtype="int32",
+                               append_batch_size=False)
+            feeds.append("slot")
         zero_pos = layers.fill_constant([1], "int32", 0)
+        spec = cache_spec(name, num_layers, layer_pattern,
+                          num_slots=cache_slots, num_pages=num_pages,
+                          page_tokens=page_tokens,
+                          num_kv_heads=num_kv_heads, head_dim=head_dim,
+                          hidden=hidden, num_window_pages=num_window_pages)
     x = layers.embedding(input_ids, size=[vocab_size, hidden],
                          param_attr=f"{name}.embed")
     kvs = []
     taps = {"keep_logits": keep_router_logits}
-    # the expert layers count real rows only: the paged path feeds their
-    # number, the others have it as last_pos + 1
+    # the expert and the convolution layers tell real rows from the pad
+    # tail: the paged path feeds their number, the others have it as
+    # last_pos + 1
     valid = prompt_len
-    if valid is None and expert_layers(layer_pattern, num_layers):
+    if valid is None and (has_conv
+                          or expert_layers(layer_pattern, num_layers)):
         valid = layers.cast(last_pos + 1, "int32")
     block = default_main_program().global_block()
     for i in range(num_layers):
+        caches = _cache_vars(block, spec, i)
+        lspec = layer_spec(layer_pattern, i)
+        state = {"conv_state": caches[0], "slot": slot} \
+            if caches and lspec["mixer"] != "attention" else {}
         x, k, v = llama_block(x, hidden, num_heads, num_kv_heads,
                               seq_len, head_dim, intermediate,
                               name=f"{name}.blk{i}", attn_impl=attn_impl,
                               collect_kv=True, rms_norm_eps=rms_norm_eps,
-                              rope_base=rope_base,
-                              layer=layer_spec(layer_pattern, i),
+                              rope_base=rope_base, layer=lspec,
                               valid=valid, taps=taps, qk_norm=qk_norm,
-                              mask_block=mask_block)
-        if block_table is not None:
+                              mask_block=mask_block, **state)
+        if lspec["mixer"] != "attention":
+            if not caches:
+                kvs.append((i, {"state": k}))
+        elif block_table is not None:
             # paged: the prompt's K/V scatter across the slot's pages
             # from logical position 0; pad-tail rows (>= prompt_len)
             # go to the trash page, and so do a window layer's rows
             # whose page the window no longer covers
-            in_window = i in windowed
-            pools = _kv_vars(block, name, i, [
-                num_window_pages if in_window else num_pages,
-                num_kv_heads, page_tokens, head_dim])
-            for pool, t in zip(pools, (k, v)):
+            for pool, t in zip(caches, (k, v)):
                 layers.kv_pool_write(
                     pool, t, zero_pos,
-                    bt_window if in_window else block_table, prompt_len)
+                    bt_window if i in windowed else block_table,
+                    prompt_len)
         else:
-            kvs.append((k, v))
+            kvs.append((i, {"k": k, "v": v}))
     if mask_block is not None:
         # (kept router logits are every row's, [B, L_moe, S, E]: no row
         # is yielded, so none is picked)
         return feeds, dict({"rows_written": prompt_len + 0},
                            **_taps_fetches(taps))
-    logits = _head_on_rows(x, last_pos, vocab_size, name, rms_norm_eps)
+    logits = _head_on_rows(x, last_pos, vocab_size, name, rms_norm_eps,
+                           tie_head)
     return feeds, _prefill_fetches(logits, kvs, taps, last_pos)
 
 
@@ -488,9 +641,8 @@ def _prefill_fetches(logits, kvs, taps, last_pos):
     router logits at ``last_pos``)."""
     fetches = {"logits": logits,
                "next_token": layers.argmax(logits, axis=-1)}  # [B] int64
-    for i, (k, v) in enumerate(kvs):
-        fetches[f"k_{i}"] = k
-        fetches[f"v_{i}"] = v
+    for i, rows in kvs:          # an uncached prefill's K/V or state rows
+        fetches.update({f"{kind}_{i}": t for kind, t in rows.items()})
     if taps.get("logits"):
         rows = layers.range(0, int(logits.shape[0]), 1, dtype="int64")
         coords = layers.stack([rows, last_pos], axis=1)
@@ -507,8 +659,16 @@ def build_llama_decode(num_slots, max_seq_len, vocab_size=32000,
                        page_tokens=None, head_dim=None, rms_norm_eps=1e-6,
                        rope_base=10000.0, layer_pattern=None,
                        num_window_pages=None, keep_router_logits=False,
-                       qk_norm=False, block=None, mask_id=None):
+                       qk_norm=False, block=None, mask_id=None,
+                       tie_head=False):
     """Cached decode step over a fixed slot grid.
+
+    A gated short-convolution layer (``mixer`` of :data:`DEFAULT_LAYER`)
+    has no pools: its state ``<name>.conv_state_<i>`` [slots + 1, L - 1,
+    H] (``cache_spec``) is read and moved on by one row, in place, for
+    the rows ``live`` marks; a dead row's state stays as it was.  The
+    engine finds those variables through ``cache_spec``; the
+    ``cache_names`` returned here are the page pools alone.
 
     ``block=B`` (block diffusion, with ``mask_id``; full-attention
     layers only) makes a slot's rows a block of B positions at
@@ -573,6 +733,10 @@ def build_llama_decode(num_slots, max_seq_len, vocab_size=32000,
     masked = quota = None
     n_rows = live          # real rows a slot: the K/V written, the count
     if block:
+        if conv_layers(layer_pattern, num_layers):
+            raise ValueError("a block of rows a slot over convolution "
+                             "layers is not built: their state moves on "
+                             "one row a step")
         if windowed:
             raise ValueError("a block of rows a slot shares its columns; "
                              "sliding-window layers give each row its own")
@@ -596,29 +760,31 @@ def build_llama_decode(num_slots, max_seq_len, vocab_size=32000,
                                 append_batch_size=False)
         feeds.append("block_tables_window")
     gblock = default_main_program().global_block()
-    cache_names = []
-    caches = []
-    for i in range(num_layers):
-        ck, cv = _kv_vars(gblock, name, i, [
-            num_window_pages if i in windowed else num_pages,
-            num_kv_heads, page_tokens, head_dim])
-        caches.append((ck, cv))
-        cache_names += [ck.name, cv.name]
+    spec = cache_spec(name, num_layers, layer_pattern, num_slots=num_slots,
+                      num_pages=num_pages, page_tokens=page_tokens,
+                      num_kv_heads=num_kv_heads, head_dim=head_dim,
+                      hidden=hidden, num_window_pages=num_window_pages)
+    cache_names = [e["name"] for e in spec if e["kind"] != "slot_state"]
     x = layers.embedding(tokens, size=[vocab_size, hidden],
                          param_attr=f"{name}.embed")
     taps = {"keep_logits": keep_router_logits}
-    for i, (ck, cv) in enumerate(caches):
+    for i in range(num_layers):
+        caches = _cache_vars(gblock, spec, i)
+        lspec = layer_spec(layer_pattern, i)
+        if lspec["mixer"] != "attention":
+            cache = {"conv_state": caches[0], "live": live}
+        else:
+            cache = {"kv_cache": caches, "positions": positions,
+                     "block_table": bt_window if i in windowed
+                     else block_tables, "kv_lengths": n_rows}
         x = llama_block(x, hidden, num_heads, num_kv_heads, rows,
                         head_dim, intermediate, name=f"{name}.blk{i}",
-                        kv_cache=(ck, cv), positions=positions,
-                        block_table=bt_window if i in windowed
-                        else block_tables, kv_lengths=n_rows,
                         rms_norm_eps=rms_norm_eps, rope_base=rope_base,
-                        layer=layer_spec(layer_pattern, i), valid=n_rows,
-                        taps=taps, qk_norm=qk_norm, block=bool(block))
+                        layer=lspec, valid=n_rows, taps=taps,
+                        qk_norm=qk_norm, block=bool(block), **cache)
     x = layers.rms_norm(x, epsilon=rms_norm_eps,
                         param_attr=f"{name}.ln_f")
-    logits = _linear(x, vocab_size, pname=f"{name}.head.w")  # [slots,1,V]
+    logits = _head(x, vocab_size, name, tie_head)            # [slots,1,V]
     if block:
         new_tokens, new_masked = layers.block_unmask(logits, tokens,
                                                      masked, quota)
@@ -638,7 +804,8 @@ def build_llama_decode(num_slots, max_seq_len, vocab_size=32000,
 def _chunk_forward(chunk_len, max_seq_len, num_pages, page_tokens,
                    vocab_size, hidden, num_layers, num_heads, num_kv_heads,
                    intermediate, name, head_dim=None, rms_norm_eps=1e-6,
-                   rope_base=10000.0, layer_pattern=None, qk_norm=False):
+                   rope_base=10000.0, layer_pattern=None, qk_norm=False,
+                   tie_head=False):
     """The forward that the chunk and the verify programs share: C new
     tokens at ``base`` attend the slot's pages plus themselves causally.
     Returns ``(feed_names, x [1, C, H] before the final norm,
@@ -652,6 +819,12 @@ def _chunk_forward(chunk_len, max_seq_len, num_pages, page_tokens,
             "prefill continuation (chunked prefill, prefix reuse, "
             "speculative verify) is not built for a model with "
             "sliding-window layers: their pages live in a second pool")
+    if conv_layers(layer_pattern, num_layers):
+        raise ValueError(
+            "prefill continuation (chunked prefill, prefix reuse, "
+            "speculative verify) is not built for a model with "
+            "convolution layers: a chunk would have to start from, and a "
+            "rejected draft roll back, state that is not pages")
     num_kv_heads = num_kv_heads or num_heads
     head_dim = head_dim or hidden // num_heads
     np_slot = max_seq_len // page_tokens
@@ -664,14 +837,16 @@ def _chunk_forward(chunk_len, max_seq_len, num_pages, page_tokens,
     ck_len = layers.data("chunk_len", [1], dtype="int32",
                          append_batch_size=False)
     block = default_main_program().global_block()
-    cache_names = []
+    spec = cache_spec(name, num_layers, layer_pattern, num_slots=0,
+                      num_pages=num_pages, page_tokens=page_tokens,
+                      num_kv_heads=num_kv_heads, head_dim=head_dim,
+                      hidden=hidden)
+    cache_names = [e["name"] for e in spec]
     x = layers.embedding(chunk_ids, size=[vocab_size, hidden],
                          param_attr=f"{name}.embed")
     taps = {}
     for i in range(num_layers):
-        ck, cv = _kv_vars(block, name, i, [num_pages, num_kv_heads,
-                                            page_tokens, head_dim])
-        cache_names += [ck.name, cv.name]
+        ck, cv = _cache_vars(block, spec, i)
         # rope offset = base per row; cached_attention's validity mask
         # (j <= base + t) is exactly causal-over-prefix-plus-chunk
         x = llama_block(x, hidden, num_heads, num_kv_heads, chunk_len,
@@ -715,7 +890,8 @@ def build_llama_prefill_chunk(chunk_len, max_seq_len, num_pages,
     last_off = layers.data("last_off", [1], dtype="int64",
                            append_batch_size=False)
     logits = _head_on_rows(x, last_off, vocab_size, name,
-                           arch.get("rms_norm_eps", 1e-6))
+                           arch.get("rms_norm_eps", 1e-6),
+                           arch.get("tie_head", False))
     next_token = layers.argmax(logits, axis=-1)              # [1] int64
     fetches = {"logits": logits, "next_token": next_token}
     fetches.update(_taps_fetches(taps))
@@ -758,6 +934,6 @@ def build_llama_verify(chunk_len, max_seq_len, num_pages, page_tokens,
         **arch)
     x = layers.rms_norm(x, epsilon=arch.get("rms_norm_eps", 1e-6),
                         param_attr=f"{name}.ln_f")
-    all_logits = _linear(x, vocab_size, pname=f"{name}.head.w")
+    all_logits = _head(x, vocab_size, name, arch.get("tie_head", False))
     tokens = layers.argmax(all_logits, axis=-1)              # [1, C]
     return feeds, {"logits": all_logits, "tokens": tokens}, cache_names

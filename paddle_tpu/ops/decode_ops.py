@@ -38,10 +38,40 @@ attention.
 Physical page 0 is the reserved **trash page**: rows a write must
 discard (idle slots, pad-tail rows of a chunk) are redirected there
 instead of branching, so the scatter stays a single fused op.
+
+**Heads narrower than a lane tile** (``head_dim`` 64): the pool is kept
+``[P, Hkv / 2, pt, 128]``, KV heads ``2p`` and ``2p + 1`` side by side in
+the 128 lanes of one row (``pool_shape``), because a TPU pads a minor
+dim of 64 to 128 in HBM: the plain shape would take twice the memory and
+twice the bytes of every read.  ``kv_pool_write`` packs by the pool's
+own shape, the gathered view unpacks by the query's ``head_dim``, and the
+Pallas kernel reads the packed pages as they lie (``paged_attention``).
+
+**Slot state that is not pages** (a gated short convolution's last
+``L - 1`` inputs): one ``[slots + 1, L - 1, H]`` variable a layer, row
+``slots`` the **trash row** a warm-up prefill writes.  ``short_conv`` is
+the causal depthwise convolution over a whole sequence (zero history),
+``short_conv_tail`` takes the rows a prompt leaves behind at its TRUE
+last positions, ``slot_state_write`` puts them in a slot's row, and
+``short_conv_step`` is the decode step: the state's rows and the fresh
+one give the output, the state moves on by one, in place, for live rows
+only.  All plain ``jax.numpy``: XLA fuses them, there is no kernel.
 """
 from __future__ import annotations
 
 from .registry import in_var, register_op, set_out
+
+
+LANES = 128
+
+
+def pool_shape(num_pages, num_kv_heads, page_tokens, head_dim):
+    """The shape of a layer's K (or V) page pool: ``[P, Hkv, pt, D]``, or
+    for heads of half a lane tile with an even head count ``[P, Hkv / 2,
+    pt, 2 D]``, two KV heads a row (this module's docstring)."""
+    if 2 * head_dim == LANES and num_kv_heads % 2 == 0:
+        return [num_pages, num_kv_heads // 2, page_tokens, LANES]
+    return [num_pages, num_kv_heads, page_tokens, head_dim]
 
 
 def _kv_pool_write_infer(op, block):
@@ -84,6 +114,7 @@ def _kv_pool_write(ctx, op):
     # unmasked
     phys = jnp.where(valid, phys, 0)
     off = jnp.where(valid, off, 0)
+    # (the pool's own Hkv and D: a packed pool's row takes two heads)
     rows = jnp.transpose(new, (0, 2, 1, 3)).reshape(B * T, Hkv, D)
     rows = rows.astype(pool.dtype)
     phys, off = phys.reshape(-1), off.reshape(-1)
@@ -113,19 +144,27 @@ def _kv_pool_gather_infer(op, block):
     bt = in_var(op, block, "BlockTable")
     P, hkv, pt, d = pool.shape
     b, np_ = bt.shape
-    set_out(op, block, "Out", (b, hkv, np_ * pt, d), pool.dtype)
+    pack = d // int(op.attr("head_dim", d))
+    set_out(op, block, "Out", (b, hkv * pack, np_ * pt, d // pack),
+            pool.dtype)
 
 
-def _gather_pages(pool, bt):
+def _gather_pages(pool, bt, head_dim=None):
     """Pool [P, Hkv, pt, D] through BlockTable [B, NP] -> the dense
-    logical view [B, Hkv, NP*pt, D]."""
+    logical view [B, Hkv, NP*pt, D].  A packed pool (``pool_shape``)
+    comes back unpacked: ``head_dim`` is the heads' own width."""
     import jax.numpy as jnp
 
     P, Hkv, pt, D = pool.shape
     B, NP = bt.shape
     pages = jnp.take(pool, bt.reshape(-1), axis=0, mode="clip")
-    return jnp.transpose(pages.reshape(B, NP, Hkv, pt, D),
-                         (0, 2, 1, 3, 4)).reshape(B, Hkv, NP * pt, D)
+    pack = D // (head_dim or D)
+    if pack == 1:
+        return jnp.transpose(pages.reshape(B, NP, Hkv, pt, D),
+                             (0, 2, 1, 3, 4)).reshape(B, Hkv, NP * pt, D)
+    pages = pages.reshape(B, NP, Hkv, pt, pack, D // pack)
+    return jnp.transpose(pages, (0, 2, 4, 1, 3, 5)).reshape(
+        B, Hkv * pack, NP * pt, D // pack)
 
 
 @register_op("kv_pool_gather", infer=_kv_pool_gather_infer, grad=None)
@@ -141,7 +180,8 @@ def _kv_pool_gather(ctx, op):
 
     pool = ctx.get_input(op, "Pool")
     bt = ctx.get_input(op, "BlockTable").astype(jnp.int32)
-    ctx.set_output(op, "Out", _gather_pages(pool, bt))
+    ctx.set_output(op, "Out",
+                   _gather_pages(pool, bt, op.attr("head_dim", None)))
 
 
 def _cached_attn_infer(op, block):
@@ -226,7 +266,8 @@ def _paged_decode_attention(ctx, op):
     serves them all.
 
     On a TPU backend, one device, at a shape the kernel takes (``D`` a
-    multiple of 128, ``pt`` of 8) this is the Pallas kernel of
+    multiple of 128, or 64 over a pool packed two heads a row; ``pt`` a
+    multiple of 8) this is the Pallas kernel of
     ``ops/pallas/paged_attention.py``: live pages read in place, no
     dense view, no GQA expansion, online softmax; it matches the einsum
     formulation to float32 rounding, not bit for bit.  Anywhere else it
@@ -258,6 +299,7 @@ def _paged_decode_attention(ctx, op):
     on_tpu = jax.default_backend() == "tpu"
     n_mesh = ctx.mesh.devices.size if ctx.mesh is not None else 1
     fits = paged_attention.supported(q.shape, pool_k.shape, window)
+    D = q.shape[3]
     if on_tpu and n_mesh == 1 and fits:
         kw = {} if window is None else {"window": int(window)}
         # the kernel takes the last column a slot admits
@@ -267,8 +309,8 @@ def _paged_decode_attention(ctx, op):
         _lowered("paged_decode", window=window)
     else:
         kw = {} if rows == 1 else {"block": True}
-        out = _attend_cache(q, _gather_pages(pool_k, bt),
-                            _gather_pages(pool_v, bt), pos, scale,
+        out = _attend_cache(q, _gather_pages(pool_k, bt, D),
+                            _gather_pages(pool_v, bt, D), pos, scale,
                             window, **kw)
         reason = None
         if on_tpu:
@@ -276,7 +318,8 @@ def _paged_decode_attention(ctx, op):
                       f"mesh" if n_mesh > 1 else
                       f"paged_decode_attention with Q {q.shape} over "
                       f"pages {pool_k.shape[1:]} (kernel needs head_dim "
-                      f"% 128 == 0, page_tokens % 8 == 0, at most "
+                      f"% 128 == 0 or heads of 64 packed two a row, "
+                      f"page_tokens % 8 == 0, at most "
                       f"{paged_attention.MAX_GROUP_ROWS} query rows a "
                       f"KV head)")
         _lowered("paged_decode_reference", reason, window=window)
@@ -343,3 +386,117 @@ def _block_unmask(ctx, op):
     ctx.set_output(op, "TokensOut", jnp.where(fix, x0, tokens))
     ctx.set_output(op, "MaskedOut",
                    jnp.where(fix, jnp.zeros_like(masked_in), masked_in))
+
+
+# ---------------------------------------------------------------------------
+# slot state that is not pages: the gated short convolution's history
+# ---------------------------------------------------------------------------
+
+def _taps(rows, w, bias=None):
+    """``sum_j w[:, j] * rows[j]`` in one fixed order (oldest first), so
+    the whole-sequence form and the one-row step give the same float for
+    the same inputs.  ``rows``: L arrays [..., H], oldest first; ``w``
+    [H, L]."""
+    acc = rows[0] * w[:, 0]
+    for j in range(1, len(rows)):
+        acc = acc + rows[j] * w[:, j]
+    return acc if bias is None else acc + bias
+
+
+def _same_as_x(op, block):
+    x = in_var(op, block, "X")
+    set_out(op, block, "Out", x.shape, x.dtype)
+
+
+@register_op("short_conv", infer=_same_as_x, grad="auto")
+def _short_conv(ctx, op):
+    """Causal depthwise convolution over a sequence, zero history: X
+    [B, S, H], W [H, L], optional Bias [H]; ``Out[:, t] = sum_j W[:, j] *
+    X[:, t - (L-1) + j]`` with ``X[:, <0] = 0``: L shifted multiply-adds."""
+    import jax.numpy as jnp
+
+    x = ctx.get_input(op, "X")
+    w = ctx.get_input(op, "W").astype(x.dtype)
+    bias = ctx.get_input(op, "Bias") if op.single_input("Bias") else None
+    L, S = w.shape[1], x.shape[1]
+    xp = jnp.pad(x, ((0, 0), (L - 1, 0), (0, 0)))
+    ctx.set_output(op, "Out", _taps([xp[:, j:j + S] for j in range(L)],
+                                    w, bias))
+
+
+def _short_conv_tail_infer(op, block):
+    x = in_var(op, block, "X")
+    set_out(op, block, "Out", (x.shape[0], int(op.attr("rows")),
+                               x.shape[2]), x.dtype)
+
+
+@register_op("short_conv_tail", infer=_short_conv_tail_infer, grad=None)
+def _short_conv_tail(ctx, op):
+    """What a sequence leaves behind for the step after it: X [B, S, H]
+    (right-padded), Lengths [B] int (real rows) -> the ``rows`` rows
+    before position ``lengths[b]``, oldest first, zero where the sequence
+    is shorter: [B, rows, H].  Taken at the TRUE last positions, not the
+    padded ones."""
+    import jax
+    import jax.numpy as jnp
+
+    x = ctx.get_input(op, "X")
+    n = ctx.get_input(op, "Lengths").astype(jnp.int32)
+    r = int(op.attr("rows"))
+    xp = jnp.pad(x, ((0, 0), (r, 0), (0, 0)))      # row i is position i - r
+    take = jax.vmap(lambda seq, at: jax.lax.dynamic_slice_in_dim(
+        seq, at, r, axis=0))
+    ctx.set_output(op, "Out", take(xp, jnp.clip(n, 0, x.shape[1])))
+
+
+def _state_infer(op, block):
+    s = in_var(op, block, "State")
+    set_out(op, block, "StateOut", s.shape, s.dtype)
+
+
+@register_op("slot_state_write", infer=_state_infer, grad=None,
+             stateful_outputs=("StateOut",))
+def _slot_state_write(ctx, op):
+    """State [slots + 1, R, H] gets Rows [1, R, H] as the whole of row
+    ``Slot[0]``; ``slots`` is the trash row (a warm-up's).  The output
+    aliases the state variable: donated, updated in place, as the page
+    pools are."""
+    import jax
+    import jax.numpy as jnp
+
+    state = ctx.get_input(op, "State")
+    rows = ctx.get_input(op, "Rows").astype(state.dtype)
+    slot = ctx.get_input(op, "Slot").astype(jnp.int32)[0]
+    ctx.set_output(op, "StateOut", jax.lax.dynamic_update_slice_in_dim(
+        state, rows, slot, axis=0))
+
+
+def _short_conv_step_infer(op, block):
+    _same_as_x(op, block)
+    _state_infer(op, block)
+
+
+@register_op("short_conv_step", infer=_short_conv_step_infer, grad=None,
+             stateful_outputs=("StateOut",))
+def _short_conv_step(ctx, op):
+    """The decode step of ``short_conv``: X [slots, 1, H] is each slot's
+    fresh row, State [slots + 1, L - 1, H] its last ``L - 1`` (oldest
+    first); ``Out`` [slots, 1, H] is the convolution at the fresh row and
+    the state moves on by one row where Live [slots] is set; a dead
+    row's state stays as it was.  StateOut aliases State."""
+    import jax.numpy as jnp
+
+    x = ctx.get_input(op, "X")
+    state = ctx.get_input(op, "State")
+    w = ctx.get_input(op, "W").astype(x.dtype)
+    bias = ctx.get_input(op, "Bias") if op.single_input("Bias") else None
+    live = ctx.get_input(op, "Live").astype(bool)
+    n = x.shape[0]
+    old = state[:n]                                       # [slots, L-1, H]
+    fresh = x[:, 0]
+    rows = [old[:, j] for j in range(old.shape[1])] + [fresh]
+    ctx.set_output(op, "Out", _taps(rows, w, bias)[:, None])
+    moved = jnp.concatenate([old[:, 1:], fresh[:, None].astype(old.dtype)],
+                            axis=1)
+    new = jnp.where(live[:, None, None], moved, old)
+    ctx.set_output(op, "StateOut", state.at[:n].set(new))

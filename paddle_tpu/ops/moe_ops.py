@@ -63,7 +63,8 @@ def _moe_routed_ffn(ctx, op):
     ``moe_routed_tokens``): X [B, S, H] is the experts' input, RouterX
     [B, S, H] what the router reads (an architecture may route from the
     layer's raw input, before attention), Valid [B] int the number of
-    real rows of each batch row (optional: all).  Inference only."""
+    real rows of each batch row (optional: all), ExpertBias [E] the
+    selection bias of sigmoid scoring (optional).  Inference only."""
     import jax.numpy as jnp
 
     from ..parallel.moe import moe_routed_tokens
@@ -83,7 +84,12 @@ def _moe_routed_ffn(ctx, op):
         ctx.get_input(op, "RouterW"), ctx.get_input(op, "GateUpW"),
         ctx.get_input(op, "DownW"), top_k=int(op.attr("top_k")),
         activation=op.attr("activation", "relu"), valid=valid,
-        precision=_mm_precision(x.dtype))
+        precision=_mm_precision(x.dtype),
+        score=op.attr("score", "softmax"),
+        expert_bias=ctx.get_input(op, "ExpertBias")
+        if op.single_input("ExpertBias") else None,
+        norm_topk=bool(op.attr("norm_topk", True)),
+        route_scale=float(op.attr("route_scale", 1.0)))
     ctx.set_output(op, "Out", out.reshape(shape))
     ctx.set_output(op, "ExpertCount", counts)
     if op.output("RouterLogits"):

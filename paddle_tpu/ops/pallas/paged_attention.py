@@ -32,6 +32,18 @@ HBM through the block table, and nothing else but Q and the output:
 Shapes the compiled kernel takes: ``D`` a multiple of 128 (lanes) and
 ``pt`` a multiple of 8 (float32 sublanes); ``supported()`` says so and
 the op lowers anything else to the reference formulation.
+
+**Heads of 64.**  A TPU pads a minor dim of 64 to the 128 lanes, in HBM
+too, so such a model's pool is kept ``[P, Hkv / 2, pt, 128]``: KV heads
+``2p`` and ``2p + 1`` side by side in one row (``ops/decode_ops.py``
+``pool_shape``).  The kernel reads those pages as they lie, as ``Hkv /
+2`` heads of 128: the query rows of head ``2p`` go in with zeros in lanes
+64-127 and those of head ``2p + 1`` with zeros in lanes 0-63, so ``q .
+k`` over the 128 lanes is each row's own head's score; ``p @ v`` then
+holds the row's own head's output in its own half of the lanes (the
+other half, the neighbour's V under this row's weights, is cut off).
+The same compiled kernel, twice the rows a pair and twice the MXU work
+of a step that HBM bounds; every live K and V byte is read once.
 """
 from __future__ import annotations
 
@@ -69,8 +81,10 @@ def supported(q_shape, pool_shape, window=None):
     rows without a sliding window) with a KV head's group of them in
     ``MAX_GROUP_ROWS``."""
     _, H, T, D = q_shape
-    _, Hkv, pt, _ = pool_shape
-    return (D % 128 == 0 and pt % 8 == 0 and H % Hkv == 0
+    _, Hkv, pt, pool_d = pool_shape
+    pack = pool_d // D          # KV heads a pool row (2: heads of 64)
+    return (pool_d % 128 == 0 and pack in (1, 2) and pack * D == pool_d
+            and pt % 8 == 0 and H % (Hkv * pack) == 0
             and (T == 1 or window is None)
             and (H // Hkv) * T <= MAX_GROUP_ROWS)
 
@@ -218,21 +232,34 @@ def paged_decode_attention(q, pool_k, pool_v, block_table, positions,
     number of positions fetched and contracted per loop turn, rounded
     to whole pages.  ``window`` (one row only) adds the lower bound ``j
     > positions[b] - window``: the granule loop starts at the window's
-    first page and nothing left of it is fetched.  Returns
-    [B, H, T, D]."""
-    B, H, T, D = q.shape
-    P, Hkv, pt, _ = pool_k.shape
+    first page and nothing left of it is fetched.  Pools ``[P, Hkv / 2,
+    pt, 2 D]`` hold two KV heads a row (this module's docstring).
+    Returns [B, H, T, D]."""
+    B, H, T, head_d = q.shape
+    P, Hkv, pt, D = pool_k.shape
     NP = block_table.shape[1]
     if T > 1 and window is not None:
         raise ValueError("paged_decode_attention: the rows of a block "
                          "share their columns, a sliding window gives "
                          "each row its own")
-    rep = (H // Hkv) * T              # query rows that share a KV head
+    if D not in (head_d, 2 * head_d):
+        raise ValueError(f"paged_decode_attention: heads of {head_d} over "
+                         f"pool rows of {D}")
+    packed = D != head_d
+    rep = (H // Hkv) * T              # query rows that share a pool row
     R = -(-rep // 8) * 8                             # whole sublane tiles
     G = max(1, min(granule // pt, NP))
-    scale = scale if scale is not None else 1.0 / (D ** 0.5)
+    scale = scale if scale is not None else 1.0 / (head_d ** 0.5)
 
-    qg = q.reshape(B, Hkv, rep, D)
+    if packed:
+        # [B, pair, half, rows, d] -> each half's rows in its own lanes
+        qh = q.reshape(B, Hkv, 2, rep // 2, head_d)
+        none = jnp.zeros_like(qh[:, :, 0])
+        qg = jnp.concatenate(
+            [jnp.concatenate([qh[:, :, 0], none], axis=-1),
+             jnp.concatenate([none, qh[:, :, 1]], axis=-1)], axis=2)
+    else:
+        qg = q.reshape(B, Hkv, rep, D)
     if R != rep:
         qg = jnp.pad(qg, ((0, 0), (0, 0), (0, R - rep), (0, 0)))
     kw = {} if window is None else {"window": int(window)}
@@ -261,4 +288,9 @@ def paged_decode_attention(q, pool_k, pool_v, block_table, positions,
         name="paged_decode_attention",
     )(block_table.reshape(-1).astype(jnp.int32),
       positions.astype(jnp.int32), qg, pool_k, pool_v)
+    if packed:
+        half = rep // 2
+        out = jnp.stack([out[:, :, :half, :head_d],
+                         out[:, :, half:rep, head_d:]], axis=2)
+        return out.reshape(B, H, T, head_d)
     return out[:, :, :rep].reshape(B, H, T, D)

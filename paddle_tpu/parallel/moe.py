@@ -135,23 +135,49 @@ def moe_rules(mesh, axis: str = EP_AXIS, inner=None):
 # dropless top-k routing with gated experts (the serving path's expert FFN)
 # ---------------------------------------------------------------------------
 
-def route_top_k(router_x, router_w, top_k: int):
+def route_top_k(router_x, router_w, top_k: int, score: str = "softmax",
+                expert_bias=None, norm_topk: bool = True,
+                route_scale: float = 1.0):
     """Router logits and the top-k choice per token.
 
     router_x [N, H] (whatever the architecture routes from — it need not
     be the experts' input), router_w [H, E].  Logits are float32 at
     "highest" precision: routing is discrete, and a rounded logit flips
     a choice.  Returns ``(logits [N, E], experts [N, k] int32, weights
-    [N, k])`` with the weights a softmax over the k selected logits,
-    which equals softmax over all E, select, renormalise."""
+    [N, k])``.
+
+    ``score`` "softmax": the k largest logits, weighted by a softmax
+    over the k selected, which equals softmax over all E, select,
+    renormalise (``norm_topk`` False leaves the softmax over all E as it
+    is).  ``score`` "sigmoid": every expert is scored on its own, ``s =
+    sigmoid(logits)``; the k largest of ``s + expert_bias`` are chosen
+    (``expert_bias`` [E] float32 or None; ties to the lower index), and
+    the weights are the UNBIASED ``s`` of the chosen, with ``norm_topk``
+    divided by their sum plus 1e-6.  Either way the weights are scaled
+    by ``route_scale``.  The logits returned are always the raw ones."""
     import jax
     import jax.numpy as jnp
 
     logits = jnp.dot(router_x.astype(jnp.float32),
                      router_w.astype(jnp.float32),
                      precision=jax.lax.Precision.HIGHEST)
-    top, experts = jax.lax.top_k(logits, top_k)
-    return logits, experts.astype(jnp.int32), jax.nn.softmax(top, axis=-1)
+    if score == "softmax":
+        top, experts = jax.lax.top_k(logits, top_k)
+        weights = jax.nn.softmax(top, axis=-1) if norm_topk else jnp.exp(
+            top - jax.nn.logsumexp(logits, axis=-1, keepdims=True))
+    elif score == "sigmoid":
+        s = jax.nn.sigmoid(logits)
+        _, experts = jax.lax.top_k(
+            s if expert_bias is None
+            else s + expert_bias.astype(jnp.float32), top_k)
+        weights = jnp.take_along_axis(s, experts, axis=-1)
+        if norm_topk:
+            weights = weights / (weights.sum(axis=-1, keepdims=True) + 1e-6)
+    else:
+        raise ValueError(f"unknown router score {score!r}")
+    if route_scale != 1.0:
+        weights = weights * route_scale
+    return logits, experts.astype(jnp.int32), weights
 
 
 ROW_TILE = 64     # the row tile XLA:TPU's ragged-dot kernel picks here
@@ -226,7 +252,9 @@ def grouped_matmul(rows, weights, group_sizes, precision=None):
 
 def moe_routed_tokens(x, router_x, router_w, w_gate_up, w_down, *,
                       top_k: int, activation: str = "relu", valid=None,
-                      precision=None):
+                      precision=None, score: str = "softmax",
+                      expert_bias=None, norm_topk: bool = True,
+                      route_scale: float = 1.0):
     """Dropless top-k mixture of gated experts over flat tokens.
 
     x [N, H] is the experts' input, router_x [N, H] what the router
@@ -237,7 +265,8 @@ def moe_routed_tokens(x, router_x, router_w, w_gate_up, w_down, *,
     and nothing is dropped.  Tokens are sorted by expert, the two
     grouped matmuls read only experts that got rows, and the k results
     per token are summed under the routing weights.  ``activation`` is
-    the gate's: "relu" or "silu".
+    the gate's: "relu" or "silu"; ``score``, ``expert_bias``,
+    ``norm_topk`` and ``route_scale`` are :func:`route_top_k`'s.
 
     Returns ``(out [N, H], counts [E] int32, logits [N, E])``; ``counts``
     are the group sizes the grouped matmul ran with, restricted to valid
@@ -249,7 +278,9 @@ def moe_routed_tokens(x, router_x, router_w, w_gate_up, w_down, *,
     N, H = x.shape
     E = router_w.shape[1]
     inter = w_down.shape[1]
-    logits, experts, weights = route_top_k(router_x, router_w, top_k)
+    logits, experts, weights = route_top_k(
+        router_x, router_w, top_k, score, expert_bias, norm_topk,
+        route_scale)
     flat = experts.reshape(-1)                          # [N*k]
     order = jnp.argsort(flat, stable=True)              # rows by expert
     group_sizes = jnp.bincount(flat, length=E).astype(jnp.int32)

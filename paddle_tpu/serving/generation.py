@@ -140,7 +140,12 @@ each fails only the then-active requests),
 ``moe_tokens_dropped`` (must read 0),
 ``serving_block_passes_denoise`` / ``serving_block_passes_commit``
 (block diffusion: slot-passes that decided positions / that only
-committed a block's K/V), ``serving_block_tokens_committed``; gauges
+committed a block's K/V), ``serving_block_tokens_committed``,
+``serving_slot_state_writes`` (prefills that wrote a slot's state: a
+model with convolution layers keeps, beside the pages, a per-slot state
+variable a layer that the prefill program overwrites whole and the
+decode program advances on the device); gauges
+``serving_slot_state_bytes`` (what those variables take),
 ``serving_spec_acceptance_rate``,
 ``serving_slot_occupancy``,
 ``serving_kv_cache_bytes`` (allocated cache capacity: the page pools),
@@ -185,13 +190,16 @@ class GenRequest:
                  "t_claimed", "t_deadline", "trace_id", "prefill_ms",
                  "on_token", "record_timeline", "events", "t_tokens",
                  "t_first", "t_last", "segment", "speculate", "bb",
-                 "tenant")
+                 "tenant", "keep_logits")
 
     def __init__(self, prompt: np.ndarray, max_new_tokens: int):
         self.prompt = prompt
         self.max_new_tokens = max_new_tokens
         self.segment = None  # adopted KVSegment (decode-role handoff)
         self.speculate = None  # per-request override (None = engine)
+        # on an engine that keeps logits: False leaves this request's
+        # (one row a token) off its record
+        self.keep_logits = True
         self.future = ServingFuture()
         self.t_submit = time.monotonic()
         self.t_claimed: Optional[float] = None
@@ -460,6 +468,16 @@ class GenerationEngine:
     are booked and streamed together at the commit.  Such an engine
     refuses ``prefix_reuse``, ``prefill_chunk``, ``speculate`` and the
     disaggregated roles, which walk one token a step.
+    A ``layer_pattern`` with gated short-convolution layers (``mixer``,
+    ``models/llama.py``) gives those layers no pages but a state of
+    ``L_cache - 1`` rows a slot (``cache_spec``: the engine allocates
+    every layer's cache from that description).  The prefill program
+    writes the whole of its slot's state from the prompt's true last
+    positions, so a reused slot needs no reset; the decode program moves
+    the state of the rows that ride a step on by one, on the device, and
+    leaves a dead row's alone; no host code reads or writes it between
+    steps.  Such an engine refuses ``prefix_reuse``, ``prefill_chunk``,
+    ``speculate``, the disaggregated roles and ``block_diffusion``.
     ``scope``: optional pre-initialized :class:`~paddle_tpu.framework.
     executor.Scope` whose weights use the same ``name`` prefix (the
     engine then shares them zero-copy); omitted, the engine seeds its
@@ -481,7 +499,8 @@ class GenerationEngine:
                  spec_tokens=None, spec_ngram=None, num_window_pages=None):
         import paddle_tpu as pt
         from ..compile_cache import ensure_compile_cache
-        from ..models.llama import build_llama_prefill, layer_spec
+        from ..models.llama import (build_llama_prefill, cache_spec,
+                                    conv_layers, layer_spec, window_layers)
 
         ensure_compile_cache()
         self.model = dict(model)
@@ -543,8 +562,10 @@ class GenerationEngine:
         n_layers = self.model["num_layers"]
         specs = [layer_spec(self.model.get("layer_pattern"), i)
                  for i in range(n_layers)]
-        self._window_layers = [i for i, sp in enumerate(specs)
-                               if sp["window"] is not None]
+        # layers whose mixer is a convolution hold state, not pages
+        pattern = self.model.get("layer_pattern")
+        self._conv_layers = conv_layers(pattern, n_layers)
+        self._window_layers = window_layers(pattern, n_layers)
         widths = {specs[i]["window"] for i in self._window_layers}
         if len(widths) > 1:
             raise ValueError(f"sliding-window layers of one model share "
@@ -653,6 +674,26 @@ class GenerationEngine:
                     f"{', '.join(refused)}: prefix reuse, chunked "
                     f"prefill, speculation and segment adoption / export "
                     f"walk one token a step and a causal prefix")
+        if self._conv_layers:
+            # state that is not pages: what starts from, hands over or
+            # rolls back a sequence's cache knows pages only (PERF.md
+            # section 7)
+            refused = [what for what, on in (
+                ("prefix_reuse", self.prefix_reuse),
+                ("speculate", self.speculate),
+                ("prefill_chunk > 0", self.prefill_chunk > 0),
+                (f"role={self.role!r} (KV-segment handoff)",
+                 self.role != "both"),
+                ("block_diffusion", bool(self._blk))) if on]
+            if refused:
+                raise ValueError(
+                    f"a model with convolution layers keeps per-slot "
+                    f"state that is not pages and does not support "
+                    f"{', '.join(refused)}: a shared prefix or a chunk "
+                    f"would have to start from a state nobody kept, a "
+                    f"rejected draft would have to roll it back, a "
+                    f"segment carries pages only, and the state moves on "
+                    f"one row a step, not a block")
         if self._wpool is not None:
             # two page kinds: what walks ONE block table per slot is no
             # part of this engine yet (PERF.md section 7)
@@ -689,6 +730,15 @@ class GenerationEngine:
         # placements on the scope arrays drive GSPMD at jit time, and
         # the donated cache buffers stay sharded in place across steps.
         self.mesh = mesh
+        # every layer's cache, by kind: page pools and slot state
+        self._cache_spec = cache_spec(
+            self.name, n_layers, pattern,
+            num_slots=self.num_slots, num_pages=self.num_pages,
+            page_tokens=pt_, num_kv_heads=self._n_kv,
+            head_dim=self._head_dim, hidden=self.model["hidden"],
+            num_window_pages=self.num_window_pages or None)
+        self.state_names = [e["name"] for e in self._cache_spec
+                            if e["kind"] == "slot_state"]
         self._build_decode(scope_ready=scope is not None)
         if mesh is not None:
             self._place_on_mesh(shard_rules)
@@ -722,7 +772,8 @@ class GenerationEngine:
                    "spec_tokens_accepted": 0, "spec_rollbacks": 0,
                    "window_pages_released": 0, "moe_tokens_routed": 0,
                    "moe_tokens_dropped": 0, "block_passes_denoise": 0,
-                   "block_passes_commit": 0, "block_tokens_committed": 0}
+                   "block_passes_commit": 0, "block_tokens_committed": 0,
+                   "slot_state_writes": 0}
         self._n_lock = threading.Lock()
         # per-bucket manifest-flops cache for usage attribution: the
         # executor cache walk is paid once per bucket, not per dispatch
@@ -812,7 +863,9 @@ class GenerationEngine:
         from ..parallel.mesh import MP_AXIS, axis_size
 
         mp = axis_size(self.mesh, MP_AXIS)
-        if mp > 1 and self._n_kv % mp == 0:
+        pool_heads = next((e["shape"][1] for e in self._cache_spec
+                           if e["kind"] != "slot_state"), self._n_kv)
+        if mp > 1 and pool_heads % mp == 0:
             return NamedSharding(self.mesh, P(None, MP_AXIS)), MP_AXIS
         return NamedSharding(self.mesh, P()), None
 
@@ -820,34 +873,38 @@ class GenerationEngine:
         import jax
         import jax.numpy as jnp
 
-        shape = (self.num_pages, self._n_kv, self.page_tokens,
-                 self._head_dim)
-        # a window layer's pools are the window kind's size (cache_names
-        # holds K then V, layer by layer)
-        wshape = (self.num_window_pages,) + shape[1:]
         cache_sh = None
         self.kv_shard_axis = None
         if self.mesh is not None:
             cache_sh, self.kv_shard_axis = self._cache_sharding()
-        total = 0
-        for j, n in enumerate(self.cache_names):
+        total = state_total = 0
+        for entry in self._cache_spec:
             # one DISTINCT zero buffer per pool: the decode step and
             # the prefill scatter donate all pools in one call, and XLA
             # rejects donating the same buffer twice (device_put also
             # allocates a fresh buffer per call)
-            shp = wshape if j // 2 in self._window_layers else shape
+            n, shp = entry["name"], tuple(entry["shape"])
+            pages = entry["kind"] != "slot_state"
             zeros = jnp.zeros(shp, jnp.float32)
+            # (slot state is replicated under a mesh: it is small, and
+            # its rows are slots, not heads)
             pool = jax.device_put(zeros, cache_sh) \
-                if cache_sh is not None else zeros.copy()
+                if cache_sh is not None and pages else zeros.copy()
             # the host dispatches faster than the device fills: without
             # the wait every pool's ``zeros`` lies beside its copy until
             # the device catches up, a second pool's worth of memory
             # (1.6 GB at 48 slots x 2048 x 4 layers, my chip run, PR 32)
             jax.block_until_ready(pool)
             self.scope.set_var(n, pool)
-            total += int(np.prod(shp)) * 4
+            if pages:
+                total += int(np.prod(shp)) * 4
+            else:
+                state_total += int(np.prod(shp)) * 4
         # capacity actually ALLOCATED (the pools, trash pages included)
         self.kv_cache_bytes = total
+        # ... and the per-slot state that is not pages (trash row included)
+        self.slot_state_bytes = state_total
+        telemetry.gauge_set("serving_slot_state_bytes", state_total)
         # bytes one page costs across every layer's K+V pool (of its
         # kind: a window page spans the window layers only)
         layer_page = 2 * self._n_kv * self.page_tokens * self._head_dim * 4
@@ -992,7 +1049,8 @@ class GenerationEngine:
         """Compile every prefill bucket + the decode step now (off the
         request path).  Returns the number of programs compiled.
         Warmup dispatches run with all-zero block tables and zero
-        valid lengths, so every write lands on the trash page."""
+        valid lengths, so every write lands on the trash page (and a
+        prefill's slot state on the trash row)."""
         compiled = 0
         np_slot = self.pages_per_slot
         if self.role == "decode":
@@ -1029,6 +1087,8 @@ class GenerationEngine:
                     if self._wpool is not None:
                         feed["block_table_window"] = np.zeros(
                             (1, np_slot), "int32")
+                    if self.state_names:   # the trash row, no slot's state
+                        feed["slot"] = np.asarray([self.num_slots], "int32")
                     self._run_fetching(self._prefill_exe, prog, fetches,
                                        feed)
                     compiled += 1
@@ -1134,7 +1194,8 @@ class GenerationEngine:
                on_token=None,
                timeline: Optional[bool] = None,
                speculate: Optional[bool] = None,
-               tenant: Optional[str] = None) -> ServingFuture:
+               tenant: Optional[str] = None,
+               keep_logits: bool = True) -> ServingFuture:
         """Admit one generation request.  ``prompt``: 1-D int token ids
         (1 ≤ len ≤ the largest prefill bucket).  Returns a future whose
         ``result()`` is ``{"tokens", "prompt_len", "steps", "finish",
@@ -1159,7 +1220,11 @@ class GenerationEngine:
         ``False`` opts this sequence out of drafting (it rides the
         plain grid step even on a speculating engine — bit-exact
         either way, this knob only trades verify compute); ``True``
-        or ``None`` follow the engine's ``speculate`` setting."""
+        or ``None`` follow the engine's ``speculate`` setting.
+        ``keep_logits`` — on an engine built with ``keep_logits``,
+        ``False`` keeps this request's logits and router logits off its
+        record (a check that compares three slots of a full grid would
+        otherwise hold every slot's rows on the host)."""
         if self.role == "decode":
             raise ValueError("decode-role engine accepts KV segments "
                              "via adopt(), not prompts (role=decode)")
@@ -1180,6 +1245,7 @@ class GenerationEngine:
                          else self.max_new_tokens))
         req = GenRequest(ids.astype("int64"), mnt)
         req.speculate = speculate
+        req.keep_logits = bool(keep_logits)
         budget_s = self._deadline_s
         if deadline_ms is not None:
             budget_s = min(budget_s, float(deadline_ms) / 1e3)
@@ -1233,7 +1299,7 @@ class GenerationEngine:
         """The swap surface: every scope array that is NOT a KV cache
         (the cache/pool vars carry live sequence state and must ride
         through a swap untouched)."""
-        caches = set(self.cache_names)
+        caches = set(self.cache_names) | set(self.state_names)
         return [n for n in self.scope.local_var_names()
                 if n not in caches]
 
@@ -2196,11 +2262,20 @@ class GenerationEngine:
                 if self._wpool is not None:
                     feed["block_table_window"] = \
                         self._slot_block_table(slot, window=True)[None]
+                state = {}
+                if self.state_names:
+                    # the program overwrites the whole of this slot's
+                    # state: whatever the slot's last sequence left goes
+                    feed["slot"] = np.asarray([slot.idx], "int32")
+                    state = {"state_written": 1}
             with telemetry.trace_span("generation/prefill", parent=parent,
                                       tokens=n_rows, bucket=bucket,
-                                      slot=slot.idx):
+                                      slot=slot.idx, **state):
                 outs = self._run_fetching(self._prefill_exe, prog,
                                           fetches, feed)
+            if state:
+                self._count("slot_state_writes")
+                stat_add("serving_slot_state_writes")
             req.prefill_ms += (time.monotonic() - t0) * 1e3
             if req.tenant is not None:
                 usage.ledger().book(req.tenant,
@@ -2257,11 +2332,12 @@ class GenerationEngine:
             # tokens come from the first block's passes)
             first = int(np.asarray(outs["rows_written" if self._blk else
                                         "next_token"].numpy())[0])
+            keep = slot.req.keep_logits
             slot.logits = [np.asarray(outs["logits"].numpy())[0]] \
-                if self.keep_logits and "logits" in outs else []
+                if keep and self.keep_logits and "logits" in outs else []
             slot.router_logits = \
                 [np.asarray(outs["router_logits"].numpy())[0]] \
-                if "router_logits" in outs else []
+                if keep and "router_logits" in outs else []
             if "expert_counts" in outs:
                 self._book_experts(
                     np.asarray(outs["expert_counts"].numpy()), n_tokens)
@@ -2721,6 +2797,13 @@ class GenerationEngine:
                 live_positions=int(sum(s.position + 1 for s in rows)),
                 live_positions_window=int(sum(
                     min(s.position + 1, self.window) for s in rows)))
+        if self.state_names:
+            # the slots whose state this step moved on (every row that
+            # rode it), and the positions its attention layers read
+            attrs.update(
+                state_slots=len(fl.riders),
+                live_positions=int(sum(s.position + 1
+                                       for s, r in fl.riders if s.req is r)))
         if self._blk:
             # what this pass was, slot by slot: the booked state is the
             # state it was dispatched from
@@ -2997,9 +3080,9 @@ class GenerationEngine:
             s.position += 1
             s.steps += 1
             s.tokens.append(tok)
-            if logits is not None:
+            if logits is not None and s.req.keep_logits:
                 s.logits.append(logits[s.idx])
-            if router is not None:
+            if router is not None and s.req.keep_logits:
                 s.router_logits.append(router[s.idx])
             # one timestamp for the whole grid step: per-token
             # bookkeeping adds no extra clock reads to the step
@@ -3091,6 +3174,7 @@ class GenerationEngine:
             "ttft_ms": round((req.t_first - req.t_submit) * 1e3, 3)
             if req.t_first is not None else None,
             "total_ms": round(total_ms, 3),
+            "slot": slot.idx,
         }
         if self.keep_logits:
             result["logits"] = slot.logits
@@ -3262,6 +3346,7 @@ class GenerationEngine:
             "prefill_buckets": list(self.prefill_buckets),
             "kv_cache_bytes": self.kv_cache_bytes,
             "kv_live_bytes": self.kv_live_bytes,
+            "slot_state_bytes": self.slot_state_bytes,
             "paged": {
                 "page_tokens": self.page_tokens,
                 "num_pages": self.num_pages,
