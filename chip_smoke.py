@@ -92,6 +92,15 @@ def paths_since(before):
             if v - before[k]}
 
 
+def attention_grads():
+    """How the attention grad ops lowered: off the forward's saved output
+    and softmax statistic, or as jax.vjp of the forward lowering."""
+    from paddle_tpu.monitor import stat_get
+
+    return {p: stat_get(f"attention_grad_{p}")
+            for p in ("saved", "relowered")}
+
+
 def dropout_draws():
     from paddle_tpu.monitor import stat_get
 
@@ -204,6 +213,59 @@ def check_packed_kernels(batch, seq, hidden, heads, interpret=False):
     return worst
 
 
+def check_packed_op(batch, seq, hidden, heads):
+    """``flash_attention_qkv`` and its grad op through a program (the op's
+    forward keeps the statistic, the grad op runs the backward kernels off
+    it): ``Out`` and ``QKV@GRAD`` against the blockwise formulation the op
+    lowers to off the chip, at "highest" matmul precision."""
+    import jax
+    import jax.numpy as jnp
+
+    import paddle_tpu as pt
+    from paddle_tpu import layers
+    from paddle_tpu.ops.pallas.flash_attention import blockwise_attention
+
+    rng = np.random.RandomState(2)
+    feed = {"qkv": rng.randn(batch, seq, 3 * hidden).astype("float32"),
+            "w": rng.randn(batch, seq, hidden).astype("float32"),
+            "bias": np.where(np.arange(seq)[None, :] < seq - 3, 0.0, -1e4)
+            * np.ones((batch, 1), "float32")}
+    main_p, startup = pt.Program(), pt.Program()
+    with pt.program_guard(main_p, startup):
+        x = layers.data("qkv", [batch, seq, 3 * hidden],
+                        append_batch_size=False)
+        x.stop_gradient = False
+        bias = layers.data("bias", [batch, seq], append_batch_size=False)
+        w = layers.data("w", [batch, seq, hidden], append_batch_size=False)
+        out = layers.flash_attention_qkv(x, heads, bias=bias)
+        pt.append_backward(
+            layers.reduce_sum(layers.elementwise_mul(out, w)))
+    got = pt.Executor(pt.TPUPlace()).run(
+        main_p, feed=feed, fetch_list=[out.name, "qkv@GRAD"])
+
+    def reference(qkv):
+        t = qkv.reshape(batch, seq, 3, heads, hidden // heads)
+        q, k, v = (jnp.moveaxis(t[:, :, i], 1, 2) for i in range(3))
+        o, _ = blockwise_attention(q, k, v, bias=jnp.asarray(feed["bias"]))
+        o = jnp.moveaxis(o, 1, 2).reshape(batch, seq, hidden)
+        return (o * feed["w"]).sum(), o
+
+    with jax.default_matmul_precision("highest"):
+        (_, out_r), grad_r = jax.jit(
+            jax.value_and_grad(reference, has_aux=True))(feed["qkv"])
+    worst = 0.0
+    for what, g, ref in (("Out", got[0], out_r), ("QKV@GRAD", got[1],
+                                                  grad_r)):
+        g, ref = np.asarray(g, "float32"), np.asarray(ref, "float32")
+        check(np.isfinite(g).all(), f"flash_attention_qkv {what} not finite")
+        rel = float(np.abs(g - ref).max() / np.abs(ref).max())
+        worst = max(worst, rel)
+        check(rel <= TOL,
+              f"flash_attention_qkv {what} off the blockwise formulation "
+              f"by {rel:.4g} of its range (tolerance {TOL:.4g})")
+    return worst
+
+
 def train_phase(cfg=TRAIN, on_chip=True):
     import jax
 
@@ -214,7 +276,6 @@ def train_phase(cfg=TRAIN, on_chip=True):
     n = len(devices)
     B, S = cfg["global_batch"], cfg["seq"]
     check(B % n == 0, f"global batch {B} does not split over {n} devices")
-    paths0 = attention_paths()
     draws0 = dropout_draws()
 
     t_phase = t0 = time.perf_counter()
@@ -224,6 +285,19 @@ def train_phase(cfg=TRAIN, on_chip=True):
         f"3x{cfg['hidden']}] bf16 within {worst:.4g} of the einsum "
         f"reference's range (tolerance {TOL:.4g}) "
         f"[{time.perf_counter() - t0:.1f} s]")
+    t0 = time.perf_counter()
+    grads0 = attention_grads()
+    worst = check_packed_op(B // n, S, cfg["hidden"], cfg["heads"])
+    op_grads = {k: v - grads0[k] for k, v in attention_grads().items()}
+    check(op_grads == ({"saved": 1, "relowered": 0} if on_chip
+                       else {"saved": 0, "relowered": 1}),
+          f"the flash_attention_qkv grad op lowered as {op_grads}")
+    say(f"train: flash_attention_qkv op, Out and QKV@GRAD through a "
+        f"program, within {worst:.4g} of the blockwise formulation's range; "
+        f"grad op lowered as {op_grads} "
+        f"[{time.perf_counter() - t0:.1f} s]")
+    paths0 = attention_paths()
+    grads0 = attention_grads()
 
     max_pred = max(1, int(round(0.15 * S)))
     main_p, startup, feed_names, loss, _ = bench.build_bert_train_programs(
@@ -271,8 +345,10 @@ def train_phase(cfg=TRAIN, on_chip=True):
     shard = feed_sh.shard_shape((B, S))
     check(shard[0] * n == B,
           f"batch not split over dp: per-device shard {shard} of {(B, S)}")
-    mosaic = "tpu_custom_call" in executable.as_text()
+    mosaic = len(re.findall(r'custom_call_target="tpu_custom_call"',
+                            executable.as_text()))
     paths = paths_since(paths0)
+    grads = {k: v - grads0[k] for k, v in attention_grads().items()}
     if on_chip:
         # on more than one device the same kernels run per dp shard,
         # through shard_map (ops/attention_ops.py kernel_partition)
@@ -284,9 +360,16 @@ def train_phase(cfg=TRAIN, on_chip=True):
               == (paths["pallas"] if n > 1 else 0),
               f"on {n} device(s) the kernels took the wrong route "
               f"(paths {paths})")
+        # forward, dkv, dq a layer: the grad op reads the forward's saved
+        # output and statistic and does not launch the forward again
+        check(mosaic == 3 * cfg["layers"]
+              and grads == {"saved": cfg["layers"], "relowered": 0},
+              f"the compiled training step holds {mosaic} Mosaic custom "
+              f"calls, not 3 a layer ({3 * cfg['layers']}); grad ops "
+              f"lowered as {grads}")
     say(f"train: {n} device(s), per-device batch shard {shard}, Mosaic "
-        f"custom call in the compiled step: {mosaic}, attention lowered "
-        f"as {paths}")
+        f"custom calls in the compiled step: {mosaic}, attention lowered "
+        f"as {paths}, its grad ops as {grads}")
 
     # the dropout sites' mask bits come from XLA's bit generator: a draw a
     # site, of the shard's shape (ops/nn_ops.py _mask_route)
@@ -321,7 +404,7 @@ def train_phase(cfg=TRAIN, on_chip=True):
               f"state is not on every device: {in_use} against "
               f"{params} bytes of replicated state")
     return {"losses": losses, "paths": paths, "mosaic": mosaic,
-            "dropout": draws, "devices": n, "setup_s": setup_s}
+            "grads": grads, "dropout": draws, "devices": n, "setup_s": setup_s}
 
 
 # ---------------------------------------------------------------------------

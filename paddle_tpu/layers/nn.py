@@ -577,8 +577,14 @@ def flash_attention(q, k, v, bias=None, causal=False, scale=None,
     inputs = {"Q": [q], "K": [k], "V": [v]}
     if bias is not None:
         inputs["Bias"] = [bias]
-    helper.append_op("flash_attention", inputs=inputs,
-                     outputs={"Out": [out]}, attrs=attrs)
+    outputs = {"Out": [out]}
+    if impl != "xla":
+        # the kernels' softmax statistic (log-sum-exp rows, float32):
+        # written where the op lowers to them, read by its grad op
+        outputs["SoftmaxLse"] = [
+            helper.create_variable_for_type_inference("float32")]
+    helper.append_op("flash_attention", inputs=inputs, outputs=outputs,
+                     attrs=attrs)
     return out
 
 
@@ -600,8 +606,11 @@ def flash_attention_qkv(qkv, num_heads, bias=None, causal=False,
     inputs = {"QKV": [qkv]}
     if bias is not None:
         inputs["Bias"] = [bias]
+    # SoftmaxLse: as in flash_attention
+    lse = helper.create_variable_for_type_inference("float32")
     helper.append_op("flash_attention_qkv", inputs=inputs,
-                     outputs={"Out": [out]}, attrs=attrs)
+                     outputs={"Out": [out], "SoftmaxLse": [lse]},
+                     attrs=attrs)
     return out
 
 

@@ -20,7 +20,8 @@ import logging
 
 from ..monitor import monitor as _monitor
 from ..parallel.mesh import DP_AXIS, MP_AXIS, SP_AXIS
-from .registry import in_var, register_op, set_out
+from .registry import (_lower_auto_grad, build_auto_grad_specs, in_var,
+                       infer_auto_grad, register_op, set_out)
 
 logger = logging.getLogger("paddle_tpu.ops.attention")
 
@@ -50,6 +51,14 @@ _LOWERED = {
         _monitor.get("attention_lowered_paged_decode_window"),
     "paged_decode_reference_window":
         _monitor.get("attention_lowered_paged_decode_reference_window"),
+}
+# how each grad op of the two attention ops got its gradients (per program
+# build): off what the forward saved (its output and softmax statistic: the
+# two backward kernels alone), or through the auto-grad op, which lowers
+# the whole forward a second time inside jax.vjp
+_GRAD = {
+    "saved": _monitor.get("attention_grad_saved"),
+    "relowered": _monitor.get("attention_grad_relowered"),
 }
 _downgrades_logged = set()
 
@@ -158,8 +167,9 @@ def call_kernel(mesh, how, fn, operands, layouts, out_layout, **static):
     "shard_map" route of ``kernel_partition``, with ``how`` that route's
     second value.  ``layouts`` names, per operand, what each dim is to the
     partition ("batch", "heads" or None); under ``shard_map`` the kernel
-    sees each device's local block.  ``static`` are the kernel's
-    non-array arguments (hashable)."""
+    sees each device's local block.  ``out_layout`` is the output's layout,
+    or a tuple of layouts where ``fn`` returns a tuple.  ``static`` are the
+    kernel's non-array arguments (hashable)."""
     if how is None:
         return fn(*operands, **static)
     from jax.sharding import PartitionSpec as P
@@ -170,32 +180,207 @@ def call_kernel(mesh, how, fn, operands, layouts, out_layout, **static):
     def spec(layout):
         return P(*(place.get(role) for role in layout))
 
+    if isinstance(out_layout[0], tuple):
+        out_spec = tuple(spec(lay) for lay in out_layout)
+    else:
+        out_spec = spec(out_layout)
     return _sharded_kernel(
         fn, tuple(sorted(static.items())), mesh,
-        tuple(spec(lay) for lay in layouts), spec(out_layout))(*operands)
+        tuple(spec(lay) for lay in layouts), out_spec)(*operands)
+
+
+# ---------------------------------------------------------------------------
+# the forward's softmax statistic, and the backward that reads it
+# ---------------------------------------------------------------------------
+#
+# The forward kernels write the log-sum-exp of every score row beside the
+# output; the backward kernels need both.  The kernel route lowers to
+# ``flash_attention_lse`` / ``flash_attention_packed_lse``, which return
+# both and differentiate as the kernels' own backward, so whatever takes
+# jax.vjp of the forward lowering (the auto-grad op, a pipeline stage, the
+# dygraph tracer, a ``run_program`` block) trains through it.  An op whose desc has
+# the ``SoftmaxLse`` output slot (layers.flash_attention /
+# flash_attention_qkv create it) binds the statistic there, and its grad op
+# runs the two backward kernels on (inputs, Out, SoftmaxLse, dOut).
+# Without the slot (a program saved before it existed), or off the kernel
+# route, the grad op is the registry's auto-grad: jax.vjp of the forward
+# lowering, which on the kernel route launches the forward kernel a second
+# time (XLA does not merge two custom calls).
+
+_STAT = "SoftmaxLse"
+_BHS = ("batch", "heads", None)
+
+
+def _bind_statistic(ctx, op, shape, lse=None):
+    """Bind the op's ``SoftmaxLse`` slot, where its desc has one: the
+    kernels' statistic, or zeros of its shape off the kernel route, where
+    no backward reads it and XLA drops it.  Whoever lowers the op and
+    collects its outputs (the auto-grad op, the dygraph tracer) finds
+    every declared output bound."""
+    if not op.single_output(_STAT):
+        return
+    if lse is None:
+        import jax.numpy as jnp
+
+        lse = jnp.zeros(shape, jnp.float32)
+    ctx.set_output(op, _STAT, lse)
+
+
+def _split_backward(q, k, v, out, lse, g, bias=None, *, causal, sm_scale):
+    """(dq, dk, dv[, dbias]) off the forward's saved ``out`` and ``lse``:
+    what ``flash_attention_lse``'s vjp computes, without the forward."""
+    from .pallas.flash_attention import (DEFAULT_BLOCK_K, DEFAULT_BLOCK_Q,
+                                         _flash_backward)
+
+    *grads, db = _flash_backward(q, k, v, out, lse, g, causal, sm_scale,
+                                 DEFAULT_BLOCK_Q, DEFAULT_BLOCK_K, False,
+                                 bias=bias)
+    return tuple(grads) if bias is None else (*grads, db)
+
+
+def _packed_backward(qkv, out, lse, g, bias=None, *, num_heads, causal,
+                     sm_scale):
+    """(dqkv[, dbias]) off the forward's saved ``out`` and ``lse``."""
+    from .pallas.flash_attention import packed_backward
+
+    dqkv, db = packed_backward(qkv, bias, out, lse, g, num_heads, causal,
+                               sm_scale)
+    return (dqkv,) if bias is None else (dqkv, db)
+
+
+def _if_bias(bias, item):
+    """``(item,)`` for an op with a score bias, ``()`` without: the bias
+    operand, its layout and its gradient's ride last in every tuple."""
+    return () if bias is None else (item,)
+
+
+def _row_bias(ctx, op):
+    """The op's additive score bias as rows [B, S] ([B,1,1,S]-style masks
+    flattened), or None."""
+    bias = ctx.get_input(op, "Bias") if op.single_input("Bias") else None
+    if bias is not None and bias.ndim != 2:
+        bias = bias.reshape(bias.shape[0], bias.shape[-1])
+    return bias
+
+
+def _split_route(ctx, op, q, k, v):
+    """``kernel_route`` of a ``flash_attention`` op (or its grad op) that
+    reaches the kernel-or-blockwise branch; None where an earlier branch
+    takes it (the einsum formulation, ring / Ulysses)."""
+    if op.attr("impl", "auto") == "xla" \
+            or SP_AXIS in (getattr(ctx, "axis_names", ()) or ()):
+        return None
+    return kernel_route(ctx, q.shape[0],
+                        (q.shape[1], k.shape[1], v.shape[1]))
+
+
+def _packed_route(ctx, qkv, num_heads):
+    """``kernel_route`` of a ``flash_attention_qkv`` op (or its grad op),
+    and the packed kernels' own shape test."""
+    H = qkv.shape[-1] // 3
+    D = H // num_heads
+    route, how = kernel_route(ctx, qkv.shape[0], None)
+    if route != "reference" and not (H % 128 == 0 and D in (64, 128)):
+        route, how = "reference", (
+            f"hidden {H} / head_dim {D} (kernel needs hidden % 128 == 0 "
+            f"and head_dim 64 or 128)")
+    return route, how
+
+
+def _saved(ctx, gop):
+    """((Out, SoftmaxLse, dOut), None) of a grad op from the environment,
+    or (None, why the saved backward cannot run)."""
+    if getattr(ctx, "relowered", False):
+        # double backward differentiates this lowering: the kernels have
+        # no derivative of their own, the auto-grad formulation has
+        return None, "lowered inside another op's vjp"
+    names = [gop.single_input(s) for s in ("Out", _STAT, "Out@GRAD")]
+    if names[1] is None:
+        return None, f"the forward op has no {_STAT} output"
+    vals = [ctx.env.get(n) for n in names]
+    if any(v is None for v in vals):
+        return None, f"Out, Out@GRAD or {_STAT} has no value"
+    out, lse, g = vals
+    return (out, lse, g.astype(out.dtype).reshape(out.shape)), None
+
+
+def _write_grads(ctx, gop, grads):
+    """Bind ``{forward input slot: gradient}`` to the grad op's outputs, in
+    the dtype and shape of the forward input; one var in several slots
+    gets their sum, and a var other ops read too accumulates, as in the
+    auto-grad lowering."""
+    total = {}
+    for slot, val in grads.items():
+        gname = gop.single_output(slot + "@GRAD")
+        if not gname:
+            continue
+        x = ctx.get_input(gop, slot)
+        val = val.astype(x.dtype).reshape(x.shape)
+        total[gname] = total[gname] + val if gname in total else val
+    for gname, val in total.items():
+        if gname in ctx.env and gop.attr("__accumulate__", False):
+            val = ctx.env[gname] + val
+        ctx.env[gname] = val
+
+
+def _attention_grad(saved_backward):
+    """Lowering of an attention op's grad op.  ``saved_backward(ctx, gop)``
+    runs the backward kernels off the forward's saved values and returns
+    None, or returns why it cannot; then the auto-grad lowering does it.
+    On a TPU backend that is a second forward kernel a layer, or the
+    reference formulation where the kernels could run: say so, once per
+    reason."""
+    def lower(ctx, gop):
+        import jax
+
+        why = saved_backward(ctx, gop)
+        if why is None:
+            _GRAD["saved"].increase()
+            return
+        if not getattr(ctx, "relowered", False):
+            _GRAD["relowered"].increase()
+            if jax.default_backend() == "tpu" \
+                    and why not in _downgrades_logged:
+                _downgrades_logged.add(why)
+                logger.warning(
+                    "%s lowered as jax.vjp of the forward lowering, not "
+                    "as the backward kernels off the forward's saved "
+                    "output and statistic: %s", gop.type, why)
+        _lower_auto_grad(ctx, gop)
+    return lower
+
+
+def _attention_grad_maker(fwd_op, block, helper):
+    """The auto-grad desc without the statistic's cotangent: the statistic
+    is a residual (stop_gradient), nothing ever produces that cotangent,
+    and a grad op that names it as an input could not be differentiated
+    again (double backward reads every input of the op it re-lowers)."""
+    specs = build_auto_grad_specs(fwd_op, block, helper.no_grad_set)
+    for spec in specs:
+        spec["inputs"].pop(_STAT + "@GRAD", None)
+    return specs
 
 
 def _attn_infer(op, block):
     q = in_var(op, block, "Q")
     set_out(op, block, "Out", q.shape, q.dtype)
+    # layout "bhsd": the only one the kernels, and so the statistic, have
+    set_out(op, block, _STAT, q.shape[:3], "float32", stop_gradient=True)
 
 
-@register_op("flash_attention", infer=_attn_infer, grad="auto")
+@register_op("flash_attention", infer=_attn_infer,
+             grad=_attention_grad_maker)
 def _flash_attention(ctx, op):
     import jax
 
     from .pallas.flash_attention import (blockwise_attention,
-                                         flash_attention,
-                                         flash_attention_bias)
+                                         flash_attention_lse)
     from ..parallel.ring import ring_attention, ulysses_attention
 
     q = ctx.get_input(op, "Q")
     k = ctx.get_input(op, "K")
     v = ctx.get_input(op, "V")
-    bias = ctx.get_input(op, "Bias") if op.single_input("Bias") else None
-    if bias is not None and bias.ndim != 2:
-        # accept [B,1,1,S]-style additive masks; flatten to rows [B, S]
-        bias = bias.reshape(bias.shape[0], bias.shape[-1])
+    bias = _row_bias(ctx, op)
     causal = op.attr("causal", False)
     sm_scale = op.attr("scale", None)
     mode = op.attr("seq_parallel_mode", "ring")
@@ -265,6 +450,7 @@ def _flash_attention(ctx, op):
         out = jnp.einsum(eo, p, v, **prec)
         _lowered("xla", window=window)
         ctx.set_output(op, "Out", out)
+        _bind_statistic(ctx, op, q.shape[:3])
         return
 
     axes = getattr(ctx, "axis_names", ()) or ()
@@ -279,10 +465,11 @@ def _flash_attention(ctx, op):
         out = fn(q, k, v, SP_AXIS, causal=causal, sm_scale=sm_scale)
         _lowered("ring")
         ctx.set_output(op, "Out", out)
+        _bind_statistic(ctx, op, q.shape[:3])
         return
 
-    route, how = kernel_route(ctx, q.shape[0],
-                              (q.shape[1], k.shape[1], v.shape[1]))
+    route, how = _split_route(ctx, op, q, k, v)
+    lse = None
     if route == "reference":
         # CPU, or a mesh the operands do not divide over: the einsum
         # formulation, which the partitioner shards as it likes
@@ -295,19 +482,52 @@ def _flash_attention(ctx, op):
         # one device or a manual context: the kernel as it is; a GSPMD
         # mesh: the kernel per shard (a bare pallas_call would pin the
         # layout; inside shard_map it sees its device's block)
-        if bias is not None:
-            out = call_kernel(ctx.mesh, how, flash_attention_bias,
-                              (q, k, v, bias), (_BHSD, _BHSD, _BHSD, _BS),
-                              _BHSD, causal=causal, sm_scale=sm_scale)
-        else:
-            kw = dict(blk, **prec)
-            if window is not None:
-                kw["window"] = int(window)
-            out = call_kernel(ctx.mesh, how, flash_attention, (q, k, v),
-                              (_BHSD,) * 3, _BHSD, causal=causal,
-                              sm_scale=sm_scale, **kw)
+        kw = dict(blk, **prec)
+        if window is not None:
+            kw["window"] = int(window)
+        out, lse = call_kernel(
+            ctx.mesh, how, flash_attention_lse,
+            (q, k, v) + _if_bias(bias, bias),
+            (_BHSD,) * 3 + _if_bias(bias, _BS), (_BHSD, _BHS),
+            causal=causal, sm_scale=sm_scale, **kw)
         _lowered("pallas", window=window, sharded=how is not None)
     ctx.set_output(op, "Out", out)
+    _bind_statistic(ctx, op, q.shape[:3], lse)
+
+
+def _flash_attention_saved_backward(ctx, gop):
+    q = ctx.get_input(gop, "Q")
+    k = ctx.get_input(gop, "K")
+    v = ctx.get_input(gop, "V")
+    route = _split_route(ctx, gop, q, k, v)
+    if route is None:
+        return "the einsum or the ring / Ulysses branch"
+    if route[0] == "reference":
+        return route[1] or "not a TPU backend"
+    if any(gop.attr(a, None) is not None
+           for a in ("window", "mask_block", "precision")):
+        return "window, mask_block and precision are forward only"
+    saved, why = _saved(ctx, gop)
+    if why:
+        return why
+    bias = _row_bias(ctx, gop)
+    if bias is not None and route[1] and route[1][1] \
+            and gop.single_output("Bias@GRAD"):
+        # each mp shard holds its heads' part of dBias: the vjp through
+        # shard_map sums them, the kernels called per shard would not
+        return f"a gradient of Bias with heads split over {route[1][1]}"
+    grads = call_kernel(
+        ctx.mesh, route[1], _split_backward,
+        (q, k, v) + saved + _if_bias(bias, bias),
+        (_BHSD,) * 4 + (_BHS, _BHSD) + _if_bias(bias, _BS),
+        (_BHSD,) * 3 + _if_bias(bias, _BS),
+        causal=gop.attr("causal", False), sm_scale=gop.attr("scale", None))
+    _write_grads(ctx, gop, dict(zip(("Q", "K", "V", "Bias"), grads)))
+    return None
+
+
+register_op("flash_attention_grad", infer=infer_auto_grad,
+            lower=_attention_grad(_flash_attention_saved_backward))
 
 
 def _attn_qkv_infer(op, block):
@@ -315,9 +535,12 @@ def _attn_qkv_infer(op, block):
     shape = list(qkv.shape)
     shape[-1] = shape[-1] // 3
     set_out(op, block, "Out", tuple(shape), qkv.dtype)
+    set_out(op, block, _STAT, (shape[0], op.attr("num_heads"), shape[1]),
+            "float32", stop_gradient=True)
 
 
-@register_op("flash_attention_qkv", infer=_attn_qkv_infer, grad="auto")
+@register_op("flash_attention_qkv", infer=_attn_qkv_infer,
+             grad=_attention_grad_maker)
 def _flash_attention_qkv(ctx, op):
     """Transpose-free fused attention on the packed QKV projection.
 
@@ -339,13 +562,10 @@ def _flash_attention_qkv(ctx, op):
     """
     import jax.numpy as jnp
 
-    from .pallas.flash_attention import (flash_attention_packed,
-                                         flash_attention_packed_bias)
+    from .pallas.flash_attention import flash_attention_packed_lse
 
     qkv = ctx.get_input(op, "QKV")
-    bias = ctx.get_input(op, "Bias") if op.single_input("Bias") else None
-    if bias is not None and bias.ndim != 2:
-        bias = bias.reshape(bias.shape[0], bias.shape[-1])
+    bias = _row_bias(ctx, op)
     causal = op.attr("causal", False)
     sm_scale = op.attr("scale", None)
     nh = op.attr("num_heads")
@@ -353,20 +573,13 @@ def _flash_attention_qkv(ctx, op):
     H = threeH // 3
     D = H // nh
 
-    route, how = kernel_route(ctx, B, None)
-    if route != "reference" and not (H % 128 == 0 and D in (64, 128)):
-        route, how = "reference", (
-            f"hidden {H} / head_dim {D} (kernel needs hidden % 128 == 0 "
-            f"and head_dim 64 or 128)")
+    route, how = _packed_route(ctx, qkv, nh)
+    lse = None
     if route != "reference":
-        if bias is not None:
-            out = call_kernel(ctx.mesh, how, flash_attention_packed_bias,
-                              (qkv, bias), (_BSH, _BS), _BSH, num_heads=nh,
-                              causal=causal, sm_scale=sm_scale)
-        else:
-            out = call_kernel(ctx.mesh, how, flash_attention_packed, (qkv,),
-                              (_BSH,), _BSH, num_heads=nh, causal=causal,
-                              sm_scale=sm_scale)
+        out, lse = call_kernel(
+            ctx.mesh, how, flash_attention_packed_lse,
+            (qkv,) + _if_bias(bias, bias), (_BSH,) + _if_bias(bias, _BS),
+            (_BSH, _BHS), num_heads=nh, causal=causal, sm_scale=sm_scale)
         _lowered("pallas", sharded=how is not None)
     else:
         # fallback (CPU, or a mesh the packed form does not divide over):
@@ -385,7 +598,32 @@ def _flash_attention_qkv(ctx, op):
         out = jnp.moveaxis(o, 1, 2).reshape(B, S, H).astype(qkv.dtype)
         _lowered("blockwise", how and f"flash_attention_qkv: {how}")
     ctx.set_output(op, "Out", out)
+    _bind_statistic(ctx, op, (B, nh, S), lse)
 
+
+def _flash_attention_qkv_saved_backward(ctx, gop):
+    qkv = ctx.get_input(gop, "QKV")
+    nh = gop.attr("num_heads")
+    route, how = _packed_route(ctx, qkv, nh)
+    if route == "reference":
+        return how or "not a TPU backend"
+    saved, why = _saved(ctx, gop)
+    if why:
+        return why
+    bias = _row_bias(ctx, gop)
+    grads = call_kernel(
+        ctx.mesh, how, _packed_backward,
+        (qkv,) + saved + _if_bias(bias, bias),
+        (_BSH, _BSH, _BHS, _BSH) + _if_bias(bias, _BS),
+        (_BSH,) + _if_bias(bias, _BS),
+        num_heads=nh, causal=gop.attr("causal", False),
+        sm_scale=gop.attr("scale", None))
+    _write_grads(ctx, gop, dict(zip(("QKV", "Bias"), grads)))
+    return None
+
+
+register_op("flash_attention_qkv_grad", infer=infer_auto_grad,
+            lower=_attention_grad(_flash_attention_qkv_saved_backward))
 
 
 # ---------------------------------------------------------------------------

@@ -516,12 +516,18 @@ def _flash_backward(q, k, v, out, lse, g, causal, sm_scale, block_q,
 # ---------------------------------------------------------------------------
 
 @functools.partial(jax.custom_vjp,
-                   nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10))
-def flash_attention(q, k, v, causal=False, sm_scale=None,
-                    block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K,
-                    interpret=False, window=None, mask_block=None,
-                    precision=None):
-    """Multi-head attention, q/k/v: [B, H, S, D] -> [B, H, Sq, D].
+                   nondiff_argnums=(4, 5, 6, 7, 8, 9, 10, 11))
+def flash_attention_lse(q, k, v, bias=None, causal=False, sm_scale=None,
+                        block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K,
+                        interpret=False, window=None, mask_block=None,
+                        precision=None):
+    """Multi-head attention and its softmax statistic, q/k/v: [B, H, S, D]
+    -> (out [B, H, Sq, D], lse [B, H, Sq] float32): the forward kernel's
+    one call, both of its outputs.  ``lse`` is what the backward kernels
+    need beside ``out``; a caller that keeps both (the op's grad lowering)
+    runs ``_flash_backward`` without this forward.  It is a residual, not
+    a result: its cotangent is dropped.
+    ``bias``: an additive [B, Sk] score bias (padding mask), or None.
     ``window`` (with ``causal``): query i attends keys j with
     ``i - window < j <= i``; key blocks wholly left of the band are
     skipped as those above the diagonal are.  ``mask_block`` (with
@@ -530,59 +536,48 @@ def flash_attention(q, k, v, causal=False, sm_scale=None,
     which rounds float32 operands to bf16): of the kernel's two products,
     whatever the mask.  All three forward only."""
     return _flash_forward(q, k, v, causal, sm_scale, block_q, block_k,
-                          interpret, window=window, mask_block=mask_block,
-                          precision=precision)[0]
+                          interpret, bias=bias, window=window,
+                          mask_block=mask_block, precision=precision)
 
 
-def _fa_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret,
-            window, mask_block, precision):
-    out, lse = _flash_forward(q, k, v, causal, sm_scale, block_q, block_k,
-                              interpret, window=window,
-                              mask_block=mask_block, precision=precision)
-    return out, (q, k, v, out, lse)
+def _fal_fwd(q, k, v, bias, *static):
+    out, lse = flash_attention_lse.fun(q, k, v, bias, *static)
+    return (out, lse), (q, k, v, bias, out, lse)
 
 
-def _fa_bwd(causal, sm_scale, block_q, block_k, interpret, window,
-            mask_block, precision, res, g):
+def _fal_bwd(causal, sm_scale, block_q, block_k, interpret, window,
+             mask_block, precision, res, g):
     if window is not None or mask_block is not None \
             or precision is not None:
         raise NotImplementedError(
             "flash attention: the sliding window, the block-causal "
             "mask and a set precision have no backward kernel "
             "(the serving path is forward only)")
-    q, k, v, out, lse = res
-    dq, dk, dv, _ = _flash_backward(q, k, v, out, lse, g, causal, sm_scale,
-                                    block_q, block_k, interpret)
-    return dq, dk, dv
+    q, k, v, bias, out, lse = res
+    return _flash_backward(q, k, v, out, lse, g[0], causal, sm_scale,
+                           block_q, block_k, interpret, bias=bias)
 
 
-flash_attention.defvjp(_fa_fwd, _fa_bwd)
+flash_attention_lse.defvjp(_fal_fwd, _fal_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
+def flash_attention(q, k, v, causal=False, sm_scale=None,
+                    block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K,
+                    interpret=False, window=None, mask_block=None,
+                    precision=None):
+    """``flash_attention_lse`` without a bias, the output alone."""
+    return flash_attention_lse(q, k, v, None, causal, sm_scale, block_q,
+                               block_k, interpret, window, mask_block,
+                               precision)[0]
+
+
 def flash_attention_bias(q, k, v, bias, causal=False, sm_scale=None,
                          block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K,
                          interpret=False):
-    """flash_attention with an additive [B, Sk] score bias (padding
-    mask). Separate entry so the unbiased path keeps its 3-arg vjp."""
-    return _flash_forward(q, k, v, causal, sm_scale, block_q, block_k,
-                          interpret, bias=bias)[0]
-
-
-def _fab_fwd(q, k, v, bias, causal, sm_scale, block_q, block_k, interpret):
-    out, lse = _flash_forward(q, k, v, causal, sm_scale, block_q, block_k,
-                              interpret, bias=bias)
-    return out, (q, k, v, bias, out, lse)
-
-
-def _fab_bwd(causal, sm_scale, block_q, block_k, interpret, res, g):
-    q, k, v, bias, out, lse = res
-    dq, dk, dv, db = _flash_backward(q, k, v, out, lse, g, causal, sm_scale,
-                                     block_q, block_k, interpret, bias=bias)
-    return dq, dk, dv, db
-
-
-flash_attention_bias.defvjp(_fab_fwd, _fab_bwd)
+    """``flash_attention_lse`` with an additive [B, Sk] score bias
+    (padding mask), the output alone."""
+    return flash_attention_lse(q, k, v, bias, causal, sm_scale, block_q,
+                               block_k, interpret)[0]
 
 
 # ---------------------------------------------------------------------------
@@ -930,56 +925,61 @@ def _packed_backward(qkv, num_heads, out, lse, g, causal, sm_scale,
     return dqkv, db
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4, 5, 6))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4, 5, 6, 7))
+def flash_attention_packed_lse(qkv, bias=None, num_heads=None, causal=False,
+                               sm_scale=None, block_q=DEFAULT_BLOCK_Q,
+                               block_k=DEFAULT_BLOCK_K, interpret=False):
+    """Transpose-free attention on the fused projection, and its softmax
+    statistic: qkv [B, S, 3H] -> (out [B, S, H], lse [B, heads, S]
+    float32), as ``flash_attention_lse``.  ``bias``: an additive [B, S]
+    score bias, or None.  Requires H % 128 == 0 and head_dim in
+    (64, 128)."""
+    out, lse = _packed_forward(qkv, num_heads, causal, sm_scale, block_q,
+                               block_k, interpret, bias=bias)
+    # the kernel's [B, H/128, heads a chunk, S]: the heads in order
+    return out, lse.reshape(lse.shape[0], num_heads, lse.shape[-1])
+
+
+def packed_backward(qkv, bias, out, lse, g, num_heads, causal, sm_scale,
+                    block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K,
+                    interpret=False):
+    """(dqkv, dbias or None) off ``flash_attention_packed_lse``'s saved
+    ``out`` and ``lse`` [B, heads, S]."""
+    B, S, H, _, HP, hpc = _packed_dims(qkv.shape, num_heads)
+    return _packed_backward(qkv, num_heads, out, lse.reshape(B, HP, hpc, S),
+                            g, causal, sm_scale, block_q, block_k,
+                            interpret, bias=bias)
+
+
+def _fpl_fwd(qkv, bias, *static):
+    out, lse = flash_attention_packed_lse.fun(qkv, bias, *static)
+    return (out, lse), (qkv, bias, out, lse)
+
+
+def _fpl_bwd(num_heads, causal, sm_scale, block_q, block_k, interpret,
+             res, g):
+    qkv, bias, out, lse = res
+    return packed_backward(qkv, bias, out, lse, g[0], num_heads, causal,
+                           sm_scale, block_q, block_k, interpret)
+
+
+flash_attention_packed_lse.defvjp(_fpl_fwd, _fpl_bwd)
+
+
 def flash_attention_packed(qkv, num_heads, causal=False, sm_scale=None,
                            block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K,
                            interpret=False):
-    """Transpose-free attention on the fused projection: qkv [B, S, 3H]
-    -> [B, S, H]. Requires H % 128 == 0 and head_dim in (64, 128)."""
-    return _packed_forward(qkv, num_heads, causal, sm_scale, block_q,
-                           block_k, interpret)[0]
+    """``flash_attention_packed_lse`` without a bias, the output alone."""
+    return flash_attention_packed_lse(qkv, None, num_heads, causal,
+                                      sm_scale, block_q, block_k,
+                                      interpret)[0]
 
 
-def _fpk_fwd(qkv, num_heads, causal, sm_scale, block_q, block_k, interpret):
-    out, lse = _packed_forward(qkv, num_heads, causal, sm_scale, block_q,
-                               block_k, interpret)
-    return out, (qkv, out, lse)
-
-
-def _fpk_bwd(num_heads, causal, sm_scale, block_q, block_k, interpret,
-             res, g):
-    qkv, out, lse = res
-    dqkv, _ = _packed_backward(qkv, num_heads, out, lse, g, causal,
-                               sm_scale, block_q, block_k, interpret)
-    return (dqkv,)
-
-
-flash_attention_packed.defvjp(_fpk_fwd, _fpk_bwd)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4, 5, 6, 7))
 def flash_attention_packed_bias(qkv, bias, num_heads, causal=False,
                                 sm_scale=None, block_q=DEFAULT_BLOCK_Q,
                                 block_k=DEFAULT_BLOCK_K, interpret=False):
-    """flash_attention_packed with an additive [B, S] score bias."""
-    return _packed_forward(qkv, num_heads, causal, sm_scale, block_q,
-                           block_k, interpret, bias=bias)[0]
-
-
-def _fpkb_fwd(qkv, bias, num_heads, causal, sm_scale, block_q, block_k,
-              interpret):
-    out, lse = _packed_forward(qkv, num_heads, causal, sm_scale, block_q,
-                               block_k, interpret, bias=bias)
-    return out, (qkv, bias, out, lse)
-
-
-def _fpkb_bwd(num_heads, causal, sm_scale, block_q, block_k, interpret,
-              res, g):
-    qkv, bias, out, lse = res
-    dqkv, db = _packed_backward(qkv, num_heads, out, lse, g, causal,
-                                sm_scale, block_q, block_k, interpret,
-                                bias=bias)
-    return dqkv, db
-
-
-flash_attention_packed_bias.defvjp(_fpkb_fwd, _fpkb_bwd)
+    """``flash_attention_packed_lse`` with an additive [B, S] score bias,
+    the output alone."""
+    return flash_attention_packed_lse(qkv, bias, num_heads, causal,
+                                      sm_scale, block_q, block_k,
+                                      interpret)[0]

@@ -17,9 +17,14 @@ registers:
   * ``grad``   -- how to build the backward ops for framework.backward:
                   'auto' (default) emits a generic ``<type>_grad`` op whose
                   lowering computes jax.vjp of the forward lowering (XLA CSE
-                  removes the recomputation); a callable builds custom grad
-                  op descs (used where semantics demand it, e.g. ops whose
-                  grad must reuse a saved random mask).
+                  removes the recomputation, except of custom calls: two
+                  ``tpu_custom_call``s on the same operands both run, which
+                  is why flash_attention / flash_attention_qkv register a
+                  ``<type>_grad`` lowering of their own, in
+                  ops/attention_ops.py, that reads the forward's saved
+                  output and softmax statistic); a callable builds custom
+                  grad op descs (used where semantics demand it, e.g. ops
+                  whose grad must reuse a saved random mask).
 """
 from __future__ import annotations
 
@@ -183,7 +188,16 @@ _AMP_CASTABLE = ("float16", "bfloat16", "float32")
 def _lower_with_amp(ctx: LowerContext, opdef: "OpDef", op: Operator):
     """Autocast wrapper: white-list ops see low-precision float inputs,
     black-list ops see float32; env bindings are restored afterwards so
-    other consumers keep the original precision."""
+    other consumers keep the original precision.
+
+    A grad op (one whose desc carries ``__fwd_outputs__``) autocasts like
+    its forward, with one rule of its own: of its inputs, the forward's
+    inputs and the cotangents are cast, and what the forward saved (its
+    outputs) is NOT: it reaches the grad lowering in the precision the
+    forward emitted it in.  The auto-grad lowering never reads those; an
+    explicit grad lowering that does (flash_attention[_qkv]_grad: a bf16
+    ``Out`` beside a float32 softmax statistic) sees them as saved, and
+    casts for itself what it wants cast."""
     amp = ctx.amp
     target = None
     if amp is not None:
@@ -199,8 +213,15 @@ def _lower_with_amp(ctx: LowerContext, opdef: "OpDef", op: Operator):
     if target is None:
         opdef.lower(ctx, op)
         return
+    # a var that is forward input and output at once (in place) is cast
+    kept = {n for slot in op.attr("__fwd_outputs__", ())
+            for n in op.input(slot)}
+    kept.difference_update(
+        n for ns in op.attr("__fwd_inputs__", {}).values() for n in ns)
     saved = {}
     for name in op.input_arg_names():
+        if name in kept:
+            continue
         v = ctx.env.get(name)
         dt = str(getattr(v, "dtype", ""))
         if v is not None and dt in _AMP_CASTABLE and dt != target:

@@ -590,3 +590,565 @@ def test_sharded_bert_step_on_the_cpu_keeps_the_blockwise_route():
              for n in names}
     assert moved == {"pallas": 0, "pallas_sharded": 0,
                      "blockwise": 2 * layers_}
+
+
+# ---------------------------------------------------------------------------
+# the grad op reads the forward's saved output and softmax statistic
+# (ops/attention_ops.py _attention_grad): the kernel route without a chip
+# ---------------------------------------------------------------------------
+
+@pytest.fixture
+def as_tpu(monkeypatch):
+    """``jax.default_backend()`` answers "tpu", so the ops take the kernel
+    route.  A Mosaic kernel does not compile on the CPU: such a step is
+    traced (``jax.make_jaxpr``), or run with ``interpreted`` as well."""
+    import paddle_tpu.parallel.sharded as sharded
+    from paddle_tpu.ops import attention_ops
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(sharded, "ensure_compile_cache", lambda: None)
+    monkeypatch.setattr(attention_ops, "_downgrades_logged", set())
+    attention_ops._sharded_kernel.cache_clear()
+    yield
+    attention_ops._sharded_kernel.cache_clear()
+
+
+@pytest.fixture
+def interpreted(as_tpu, monkeypatch):
+    """Every ``pallas_call`` in interpret mode, whatever its caller asks."""
+    from jax.experimental import pallas as pl
+
+    real = pl.pallas_call
+    monkeypatch.setattr(
+        pl, "pallas_call",
+        lambda *a, **kw: real(*a, **dict(kw, interpret=True)))
+
+
+def _count_pallas_calls(jaxpr):
+    n = 0
+    for eqn in jaxpr.eqns:
+        n += eqn.primitive.name == "pallas_call"
+        for val in eqn.params.values():
+            for sub in val if isinstance(val, (list, tuple)) else [val]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    n += _count_pallas_calls(sub)
+    return n
+
+
+def _grad_counts():
+    from paddle_tpu.monitor import stat_get
+
+    return {n: stat_get(f"attention_{n}")
+            for n in ("grad_saved", "grad_relowered", "lowered_pallas",
+                      "lowered_blockwise")}
+
+
+def _moved(before):
+    return {k: v - before[k] for k, v in _grad_counts().items()}
+
+
+def _mesh(axes):
+    n = int(np.prod(list(axes.values())))
+    return make_mesh(axes, devices=jax.devices()[:n])
+
+
+def _encoder_step(num_layers, with_bias, mesh_axes, batch=8, seq=128):
+    """The training step of a small BERT encoder (packed attention, hidden
+    128 in 2 heads of 64) under bf16 AMP with ``flash_attention_qkv``
+    white-listed, as the benchmark's recipe has it: ``(fn, args)`` of
+    ``build_sharded_step``."""
+    from paddle_tpu import optimizer
+    from paddle_tpu.contrib import mixed_precision
+    from paddle_tpu.models.bert import bert_encoder
+    from paddle_tpu.parallel import build_sharded_step
+
+    main_p, startup = pt.Program(), pt.Program()
+    startup._is_startup = True
+    with pt.program_guard(main_p, startup):
+        ids = layers.data("input_ids", [batch, seq], dtype="int64",
+                          append_batch_size=False)
+        mask = layers.data("attn_mask", [batch, seq],
+                           append_batch_size=False) if with_bias else None
+        enc = bert_encoder(ids, None, mask, vocab_size=211, hidden=128,
+                           num_layers=num_layers, num_heads=2, seq_len=seq,
+                           intermediate=256, max_position=seq, dropout=0.0)
+        loss = layers.reduce_mean(enc)
+        mixed_precision.decorate(
+            optimizer.AdamOptimizer(1e-3), dtype="bfloat16",
+            amp_lists=mixed_precision.AutoMixedPrecisionLists(
+                custom_white_list=["flash_attention_qkv", "layer_norm",
+                                   "elementwise_add"])).minimize(loss)
+    scope = pt.Scope()
+    pt.Executor(pt.CPUPlace()).run(startup, scope=scope)
+    feed_names = ["input_ids"] + (["attn_mask"] if with_bias else [])
+    fn, mut_in, const_in, _ = build_sharded_step(
+        main_p, feed_names, [loss.name], _mesh(mesh_axes))
+    rng = np.random.RandomState(8)
+    feed = {"input_ids": rng.randint(0, 211, (batch, seq)).astype("int32"),
+            "attn_mask": (rng.rand(batch, seq) > 0.1).astype("float32")}
+    return fn, (tuple(feed[n] for n in feed_names),
+                tuple(scope.find_var(n) for n in mut_in),
+                tuple(scope.find_var(n) for n in const_in), np.int32(1))
+
+
+@pytest.mark.parametrize("mesh_axes", [{"dp": 1}, {"dp": 4}],
+                         ids=["one_device", "dp4"])
+@pytest.mark.parametrize("with_bias", [True, False], ids=["bias", "nobias"])
+@pytest.mark.parametrize("num_layers", [1, 2])
+def test_training_step_holds_three_kernels_a_layer(as_tpu, monkeypatch,
+                                                   num_layers, with_bias,
+                                                   mesh_axes):
+    """Forward, dkv, dq: the forward kernel is not launched a second time
+    inside the grad op, on one device and per ``dp`` shard; and under
+    bf16 AMP the statistic reaches the backward kernels in float32."""
+    from paddle_tpu.ops import attention_ops
+
+    seen = []
+    real = attention_ops._packed_backward
+
+    def spy(qkv, out, lse, g, *bias, **static):
+        seen.append((str(qkv.dtype), str(out.dtype), str(lse.dtype),
+                     str(g.dtype), lse.shape[1:]))
+        return real(qkv, out, lse, g, *bias, **static)
+
+    monkeypatch.setattr(attention_ops, "_packed_backward", spy)
+    before = _grad_counts()
+    fn, args = _encoder_step(num_layers, with_bias, mesh_axes)
+    jaxpr = jax.make_jaxpr(fn)(*args).jaxpr
+    assert _count_pallas_calls(jaxpr) == 3 * num_layers
+    assert _moved(before) == {
+        "grad_saved": num_layers, "grad_relowered": 0,
+        "lowered_pallas": num_layers, "lowered_blockwise": 0}
+    assert set(seen) == {("bfloat16", "bfloat16", "float32", "bfloat16",
+                          (2, 128))}
+
+
+def test_training_step_under_mp_keeps_the_reference_route(as_tpu, caplog):
+    """The packed columns do not split over ``mp``: forward and grad op
+    stay on the blockwise formulation and say why, once each."""
+    import logging
+
+    before = _grad_counts()
+    fn, args = _encoder_step(2, True, {"dp": 2, "mp": 2})
+    with caplog.at_level(logging.WARNING, "paddle_tpu.ops.attention"):
+        jaxpr = jax.make_jaxpr(fn)(*args).jaxpr
+    assert _count_pallas_calls(jaxpr) == 0
+    assert _moved(before) == {
+        "grad_saved": 0, "grad_relowered": 2, "lowered_pallas": 0,
+        "lowered_blockwise": 4}
+    said = [r.getMessage() for r in caplog.records]
+    assert len(said) == 2 and all("mp=2" in s for s in said)
+    assert sum("flash_attention_qkv_grad lowered as jax.vjp" in s
+               for s in said) == 1
+
+
+def _attention_program(packed, with_bias, causal, batch, bias_grad=True):
+    """One attention op, a weighted sum of its output as the loss, and
+    the backward ops: (program, feed dict, names of the gradients)."""
+    rng = np.random.RandomState(9)
+    main_p, startup = pt.Program(), pt.Program()
+    startup._is_startup = True
+    feed = {"w": rng.randn(batch, PS, PH).astype("float32")}
+    with pt.program_guard(main_p, startup):
+        bias = None
+        if with_bias:
+            bias = layers.data("bias", [batch, PS], append_batch_size=False)
+            bias.stop_gradient = not bias_grad
+            feed["bias"] = np.where(rng.rand(batch, PS) > 0.2, 0.0,
+                                    -1e4).astype("float32")
+        if packed:
+            x = layers.data("x", [batch, PS, 3 * PH],
+                            append_batch_size=False)
+            x.stop_gradient = False
+            feed["x"] = rng.randn(batch, PS, 3 * PH).astype("float32")
+            out = layers.flash_attention_qkv(x, PNH, bias=bias,
+                                             causal=causal)
+            wanted = ["x"]
+        else:
+            wanted = ["q", "k", "v"]
+            qkv = []
+            for n in wanted:
+                t = layers.data(n, [batch, PNH, PS, PH // PNH],
+                                append_batch_size=False)
+                t.stop_gradient = False
+                feed[n] = rng.randn(batch, PNH, PS,
+                                    PH // PNH).astype("float32")
+                qkv.append(t)
+            out = layers.transpose(
+                layers.flash_attention(*qkv, bias=bias, causal=causal),
+                [0, 2, 1, 3])
+            out = layers.reshape(out, [batch, PS, PH])
+        w = layers.data("w", [batch, PS, PH], append_batch_size=False)
+        loss = layers.reduce_sum(layers.elementwise_mul(out, w))
+        pt.append_backward(loss)
+    return main_p, feed, [n + "@GRAD" for n in wanted + (
+        ["bias"] if with_bias and bias_grad else [])]
+
+
+def _run_program(main_p, feed, fetch_names, mesh_axes):
+    from paddle_tpu.parallel import build_sharded_step
+
+    names = sorted(feed)
+    fn, _, _, _ = build_sharded_step(main_p, names, fetch_names,
+                                     _mesh(mesh_axes))
+    fetches, _, _ = fn(tuple(feed[n] for n in names), (), (), np.int32(1))
+    return [np.asarray(f) for f in fetches]
+
+
+@pytest.mark.parametrize("mesh_axes", [{"dp": 1}, {"dp": 4}],
+                         ids=["one_device", "dp4"])
+@pytest.mark.parametrize("causal", [False, True], ids=["full", "causal"])
+@pytest.mark.parametrize("with_bias", [True, False], ids=["bias", "nobias"])
+@pytest.mark.parametrize("packed", [True, False], ids=["packed", "split"])
+def test_saved_backward_equals_the_custom_vjp(interpreted, packed,
+                                              with_bias, causal, mesh_axes):
+    """The grad op's gradients off the saved (Out, SoftmaxLse) against
+    ``jax.vjp`` of the ``custom_vjp`` entry on the same operands by the
+    same route: the same kernels on the same numbers, so equal; and
+    against the blockwise formulation's, within its tolerance."""
+    from paddle_tpu.ops.attention_ops import (_BHSD, _BS, _BSH, call_kernel,
+                                              kernel_partition)
+    from paddle_tpu.ops.pallas.flash_attention import (
+        flash_attention_bias, flash_attention_packed,
+        flash_attention_packed_bias)
+
+    batch = 4
+    main_p, feed, grad_names = _attention_program(packed, with_bias,
+                                                  causal, batch)
+    before = _grad_counts()
+    got = _run_program(main_p, feed, grad_names, mesh_axes)
+    assert _moved(before) == {"grad_saved": 1, "grad_relowered": 0,
+                              "lowered_pallas": 1, "lowered_blockwise": 0}
+
+    mesh = _mesh(mesh_axes)
+    heads = None if packed else (PNH,) * 3
+    route, how = kernel_partition(dict(mesh.shape), (), batch, heads)
+    assert route == ("direct" if mesh_axes["dp"] == 1 else "shard_map")
+    if packed:
+        entry = flash_attention_packed_bias if with_bias \
+            else flash_attention_packed
+        operands = [feed["x"]]
+        layouts, out_layout, static = (_BSH,), _BSH, dict(num_heads=PNH)
+    else:
+        entry = flash_attention_bias if with_bias else flash_attention
+        operands = [feed[n] for n in "qkv"]
+        layouts, out_layout, static = (_BHSD,) * 3, _BHSD, {}
+    if with_bias:
+        operands.append(feed["bias"])
+        layouts += (_BS,)
+
+    def through_entry(*xs):
+        out = call_kernel(mesh, how, entry, xs, layouts, out_layout,
+                          causal=causal, sm_scale=None, **static)
+        if not packed:
+            out = jnp.moveaxis(out, 1, 2).reshape(batch, PS, PH)
+        return (out * feed["w"]).sum()
+
+    def through_blockwise(*xs):
+        if packed:
+            t = xs[0].reshape(batch, PS, 3, PNH, PH // PNH)
+            q, k, v = (jnp.moveaxis(t[:, :, i], 1, 2) for i in range(3))
+        else:
+            q, k, v = xs[:3]
+        o, _ = blockwise_attention(q, k, v, causal=causal,
+                                   bias=xs[-1] if with_bias else None)
+        return (jnp.moveaxis(o, 1, 2).reshape(batch, PS, PH)
+                * feed["w"]).sum()
+
+    argnums = tuple(range(len(operands)))
+    want = jax.jit(jax.grad(through_entry, argnums))(*operands)
+    for name, g, w in zip(grad_names, got, want):
+        np.testing.assert_array_equal(g, np.asarray(w), err_msg=name)
+    _close(got, jax.grad(through_blockwise, argnums)(*operands), 1e-4)
+
+
+@pytest.mark.parametrize("bias_grad", [False, True],
+                         ids=["mask", "learned_bias"])
+def test_split_heads_over_mp_sum_the_bias_gradient(interpreted, bias_grad):
+    """Heads split over ``mp``: the statistic splits with them and the
+    saved backward runs per shard, unless a gradient of ``Bias`` is asked
+    for, whose per-shard parts only the vjp through ``shard_map`` sums."""
+    main_p, feed, grad_names = _attention_program(False, True, False, 4,
+                                                  bias_grad=bias_grad)
+    before = _grad_counts()
+    got = _run_program(main_p, feed, grad_names, {"dp": 2, "mp": 2})
+    moved = _moved(before)
+    assert (moved["grad_saved"], moved["grad_relowered"]) == (
+        (0, 1) if bias_grad else (1, 0))
+
+    def through_blockwise(q, k, v, bias):
+        o, _ = blockwise_attention(q, k, v, bias=bias)
+        return (jnp.moveaxis(o, 1, 2).reshape(4, PS, PH) * feed["w"]).sum()
+
+    want = jax.grad(through_blockwise, (0, 1, 2, 3))(
+        *(feed[n] for n in ("q", "k", "v", "bias")))
+    _close(got, want[:len(got)], 1e-4)
+
+
+def test_forward_op_without_the_statistic_slot_trains_as_before(interpreted):
+    """A program saved before the slot existed: its grad op goes through
+    the auto-grad lowering (the forward kernel a second time) and gives
+    the gradients the slot's program gives."""
+    main_p, feed, grad_names = _attention_program(True, True, False, 4)
+    want = _run_program(main_p, feed, grad_names, {"dp": 1})
+
+    old_p, _, _ = _attention_program(True, True, False, 4)
+    for op in old_p.global_block().ops:
+        for slots in (op.inputs, op.outputs):
+            slots.pop("SoftmaxLse", None)
+            slots.pop("SoftmaxLse@GRAD", None)
+        if "__fwd_outputs__" in op.attrs:
+            op.attrs["__fwd_outputs__"].pop("SoftmaxLse", None)
+    before = _grad_counts()
+    got = _run_program(old_p, feed, grad_names, {"dp": 1})
+    assert _moved(before) == {"grad_saved": 0, "grad_relowered": 1,
+                              "lowered_pallas": 2, "lowered_blockwise": 0}
+    for name, g, w in zip(grad_names, got, want):
+        np.testing.assert_array_equal(g, w, err_msg=name)
+
+
+@pytest.mark.parametrize("packed", [True, False], ids=["packed", "split"])
+def test_cpu_route_books_the_relowered_grad_without_a_warning(packed,
+                                                              caplog):
+    import logging
+
+    main_p, feed, grad_names = _attention_program(packed, True, False, 4)
+    before = _grad_counts()
+    with caplog.at_level(logging.WARNING, "paddle_tpu.ops.attention"):
+        got = _run_program(main_p, feed, grad_names, {"dp": 1})
+    assert _moved(before) == {"grad_saved": 0, "grad_relowered": 1,
+                              "lowered_pallas": 0, "lowered_blockwise": 2}
+    assert not caplog.records
+    assert all(np.isfinite(g).all() and np.abs(g).max() > 0 for g in got)
+
+
+def test_double_backward_walks_through_the_attention_grad_op():
+    """``gradients`` of a gradient through ``flash_attention_grad`` on the
+    CPU route: the grad op's desc names no cotangent of the statistic, so
+    every input its own grad op reads has a value."""
+    rng = np.random.RandomState(10)
+    shape = [2, 2, 32, 16]
+    vals = {n: rng.randn(*shape).astype("float32") for n in "qkv"}
+    main_p, startup = pt.Program(), pt.Program()
+    startup._is_startup = True
+    with pt.program_guard(main_p, startup):
+        q, k, v = (layers.data(n, shape, append_batch_size=False)
+                   for n in "qkv")
+        q.stop_gradient = False
+        out = layers.flash_attention(q, k, v, causal=True)
+        g1 = pt.gradients(layers.reduce_sum(layers.square(out)), q)[0]
+        g2 = pt.gradients(layers.reduce_sum(layers.square(g1)), q)[0]
+    got = pt.Executor().run(main_p, feed=vals, fetch_list=[g1, g2])
+
+    def first(q):
+        return jax.grad(lambda q: (blockwise_attention(
+            q, vals["k"], vals["v"], causal=True)[0] ** 2).sum())(q)
+
+    _close(got[0], first(vals["q"]), 1e-5)
+    _close(got[1], jax.grad(lambda q: (first(q) ** 2).sum())(vals["q"]),
+           1e-4)
+
+
+# ---------------------------------------------------------------------------
+# whoever differentiates the forward lowering itself (not the program's
+# grad op) goes through the two-output custom_vjp entry: the dygraph
+# tracer, a pipeline stage, a differentiable sub-block
+# ---------------------------------------------------------------------------
+
+def _packed_blockwise_grad(x, w, causal=False):
+    def loss(x):
+        t = x.reshape(x.shape[0], PS, 3, PNH, PH // PNH)
+        q, k, v = (jnp.moveaxis(t[:, :, i], 1, 2) for i in range(3))
+        o, _ = blockwise_attention(q, k, v, causal=causal)
+        return (jnp.moveaxis(o, 1, 2).reshape(x.shape[0], PS, PH) * w).sum()
+    return jax.grad(loss)(x)
+
+
+@pytest.mark.parametrize("packed", [True, False], ids=["packed", "split"])
+@pytest.mark.parametrize("route", ["cpu", "kernel"])
+def test_dygraph_trains_through_attention(request, route, packed):
+    """The tracer takes ``jax.vjp`` of the op's forward lowering and
+    collects every declared output: the statistic is bound on both routes,
+    and on the kernel route the lowering differentiates as the kernels'
+    own backward (a bare ``pallas_call`` has no transpose)."""
+    from paddle_tpu import dygraph
+
+    if route == "kernel":
+        request.getfixturevalue("interpreted")
+    rng = np.random.RandomState(11)
+    x = rng.randn(2, PS, 3 * PH).astype("float32")
+    w = rng.randn(2, PS, PH).astype("float32")
+    before = _grad_counts()
+    # the split form takes the same numbers as [B, heads, S, D] tensors
+    parts = [np.ascontiguousarray(np.moveaxis(
+        x.reshape(2, PS, 3, PNH, PH // PNH)[:, :, i], 1, 2))
+        for i in range(3)]
+    with dygraph.guard():
+        leaves = [dygraph.to_variable(a) for a in ([x] if packed else parts)]
+        for leaf in leaves:
+            leaf.stop_gradient = False
+        if packed:
+            out = layers.flash_attention_qkv(leaves[0], PNH)
+        else:
+            out = layers.reshape(
+                layers.transpose(layers.flash_attention(*leaves),
+                                 [0, 2, 1, 3]), [2, PS, PH])
+        layers.reduce_sum(out * dygraph.to_variable(w)).backward()
+        got = [leaf.gradient() for leaf in leaves]
+    moved = _moved(before)
+    assert (moved["lowered_pallas"], moved["lowered_blockwise"]) == (
+        (1, 0) if route == "kernel" else (0, 1))
+    want = np.asarray(_packed_blockwise_grad(x, w))
+    if not packed:
+        want = np.moveaxis(want.reshape(2, PS, 3, PNH, PH // PNH), 2, 0)
+        want = [np.moveaxis(t, 1, 2) for t in want]
+    _close(got, want if not packed else [want], 1e-4)
+
+
+def _staged_attention(num_stages, batch):
+    """``num_stages`` uniform stages, each a projection to [B, S, 3H] and
+    packed attention over it; mean-square loss."""
+    from paddle_tpu import optimizer
+    from paddle_tpu.framework.core import device_guard
+
+    main_p, startup = pt.Program(), pt.Program()
+    startup._is_startup = True
+    with pt.program_guard(main_p, startup):
+        x = layers.data("x", [batch, PS, PH], append_batch_size=False)
+        label = layers.data("label", [batch, PS, PH],
+                            append_batch_size=False)
+        h = x
+        for s in range(num_stages):
+            with device_guard(f"gpu:{s}"):
+                h = layers.flash_attention_qkv(
+                    layers.fc(h, 3 * PH, num_flatten_dims=2,
+                              name=f"stage{s}"), PNH)
+        diff = layers.elementwise_sub(h, label)
+        loss = layers.reduce_mean(layers.elementwise_mul(diff, diff))
+        optimizer.SGD(learning_rate=0.5).minimize(loss)
+    return main_p, startup, loss
+
+
+def test_pipeline_stage_trains_through_the_kernels(interpreted):
+    """``build_pp_pipeline_step`` takes ``jax.value_and_grad`` of the
+    stages' forward lowerings (no grad op is lowered): on the kernel route
+    that is the custom_vjp entry, and the trajectory is the plain
+    program's, whose grad ops read the saved output and statistic."""
+    from paddle_tpu.parallel import build_pp_pipeline_step
+
+    rng = np.random.RandomState(12)
+    feed = {"x": rng.randn(4, PS, PH).astype("float32"),
+            "label": rng.randn(4, PS, PH).astype("float32")}
+    names = ["x", "label"]
+
+    main_p, startup, loss = _staged_attention(2, 4)
+    scope = pt.Scope()
+    exe = pt.Executor()
+    exe.run(startup, scope=scope)
+    params = [p.name for p in main_p.global_block().all_parameters()]
+    init = [np.asarray(scope.find_var(n)) for n in params]
+    before = _grad_counts()
+    plain = [float(np.asarray(exe.run(main_p, feed=feed, fetch_list=[loss],
+                                      scope=scope)[0]).reshape(-1)[0])
+             for _ in range(3)]
+    assert _moved(before)["grad_saved"] == 2
+
+    main_p, startup, loss = _staged_attention(2, 4)
+    scope = pt.Scope()
+    exe.run(startup, scope=scope)
+    for p, v in zip(main_p.global_block().all_parameters(), init):
+        scope.set_var(p.name, v)
+    before = _grad_counts()
+    fn, mut_in, const_in, _ = build_pp_pipeline_step(
+        main_p, names, [loss.name], 2, make_mesh({"pp": 2}))
+    fn.prepare_scope(scope)
+    mut = tuple(scope.find_var(n) for n in mut_in)
+    const = tuple(scope.find_var(n) for n in const_in)
+    piped = []
+    for step in range(3):
+        fetches, mut, _ = fn(tuple(feed[n] for n in names), mut, const,
+                             np.int32(step + 1))
+        piped.append(float(np.asarray(fetches[0]).reshape(-1)[0]))
+    moved = _moved(before)
+    assert moved["lowered_pallas"] >= 1 and not moved["lowered_blockwise"]
+    assert not moved["grad_saved"] and not moved["grad_relowered"]
+    np.testing.assert_allclose(piped, plain, rtol=2e-4, atol=1e-6)
+    assert piped[-1] < piped[0]
+
+
+def test_sub_block_trains_through_the_kernels(interpreted):
+    """``run_program`` is the sub-block op with a gradient (``while`` and
+    the conditionals have none): its auto-grad op takes ``jax.vjp`` of the
+    block's forward lowerings in a fresh context, attention among them."""
+    rng = np.random.RandomState(13)
+    feed = {"x": rng.randn(2, PS, 3 * PH).astype("float32"),
+            "w": rng.randn(2, PS, PH).astype("float32")}
+    main_p, startup = pt.Program(), pt.Program()
+    startup._is_startup = True
+    with pt.program_guard(main_p, startup):
+        x = layers.data("x", [2, PS, 3 * PH], append_batch_size=False)
+        x.stop_gradient = False
+        w = layers.data("w", [2, PS, PH], append_batch_size=False)
+        block = main_p.current_block()
+        out = block.create_var(name="attended", shape=[2, PS, PH],
+                               dtype="float32")
+        sub = main_p._create_block()
+        layers.assign(layers.flash_attention_qkv(x, PNH, causal=True), out)
+        main_p._rollback()
+        block.append_op("run_program", inputs={"X": [x]},
+                        outputs={"Out": [out]},
+                        attrs={"sub_block": sub.idx}, infer_shape=False)
+        loss = layers.reduce_sum(layers.elementwise_mul(out, w))
+        pt.append_backward(loss)
+    before = _grad_counts()
+    got, = _run_program(main_p, feed, ["x@GRAD"], {"dp": 1})
+    moved = _moved(before)
+    # the op in the block, and once more inside run_program's auto-grad op
+    assert moved["lowered_pallas"] == 2 and not moved["lowered_blockwise"]
+    _close(got, _packed_blockwise_grad(feed["x"], feed["w"], causal=True),
+           1e-4)
+
+
+def test_amp_leaves_what_the_forward_saved_uncast_for_any_grad_op():
+    """``_lower_with_amp``'s rule for grad ops, on an op that is not
+    attention: forward inputs and cotangents are cast to the AMP dtype,
+    forward outputs reach an explicit grad lowering as the forward emitted
+    them, a var that is both (in place) is cast, and the environment is
+    restored afterwards."""
+    from paddle_tpu.framework.core import Operator
+    from paddle_tpu.ops import registry
+
+    seen = {}
+
+    def lower(ctx, gop):
+        seen.update((n, str(ctx.env[n].dtype))
+                    for n in gop.input_arg_names())
+
+    registry.register_op("amp_probe_grad", lower=lower, grad=None)
+    try:
+        gop = Operator(
+            None, "amp_probe_grad",
+            {"X": ["x"], "State": ["s"], "Out": ["out"], "Stat": ["stat"],
+             "StateOut": ["s"], "Out@GRAD": ["out@GRAD"]},
+            {"X@GRAD": ["x@GRAD"]},
+            {"__fwd_type__": "amp_probe",
+             "__fwd_inputs__": {"X": ["x"], "State": ["s"]},
+             "__fwd_outputs__": {"Out": ["out"], "Stat": ["stat"],
+                                 "StateOut": ["s"]}})
+        env = {"x": jnp.ones((2,), jnp.float32),
+               "s": jnp.ones((2,), jnp.float32),
+               "out": jnp.ones((2,), jnp.bfloat16),
+               "stat": jnp.ones((2,), jnp.float32),
+               "out@GRAD": jnp.ones((2,), jnp.float32)}
+        ctx = registry.LowerContext(
+            None, env, amp={"dtype": "bfloat16", "white": {"amp_probe"},
+                            "black": set()})
+        registry.lower_op(ctx, gop)
+    finally:
+        registry._REGISTRY.pop("amp_probe_grad")
+    assert seen == {"x": "bfloat16", "s": "bfloat16", "out": "bfloat16",
+                    "stat": "float32", "out@GRAD": "bfloat16"}
+    assert {n: str(v.dtype) for n, v in env.items()} == {
+        "x": "float32", "s": "float32", "out": "bfloat16",
+        "stat": "float32", "out@GRAD": "float32"}

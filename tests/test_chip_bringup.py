@@ -54,6 +54,9 @@ def test_chip_smoke_train_phase_toy():
     out = chip_smoke.train_phase(TOY_TRAIN, on_chip=False)
     assert out["devices"] == 8 and not out["mosaic"]
     assert out["paths"] == {"blockwise": out["paths"]["blockwise"]}
+    # off the chip the grad ops go through the auto-grad lowering
+    assert out["grads"] == {"saved": 0,
+                            "relowered": TOY_TRAIN["layers"]}
     assert out["dropout"]["hw_bits"] > 0 and not out["dropout"]["threefry"]
     assert out["losses"][-1] < out["losses"][0]
 

@@ -417,8 +417,9 @@ def test_bert_step_for_a_described_v5e_mesh_keeps_the_pallas_kernels(
         dict(batch_size=batch, seq_len=seq, vocab_size=30522, hidden=768,
              num_layers=layers_, num_heads=12, intermediate=3072,
              max_predictions=pred, use_flash=True, dropout=0.1))
-    names = ("pallas", "pallas_sharded", "blockwise")
-    before = {n: stat_get(f"attention_lowered_{n}") for n in names}
+    names = ("lowered_pallas", "lowered_pallas_sharded", "lowered_blockwise",
+             "grad_saved", "grad_relowered")
+    before = {n: stat_get(f"attention_{n}") for n in names}
     mesh = dp_mesh(chips, devices=list(topo.devices)[:chips])
     fn, mut_in, const_in, _ = build_sharded_step(
         main_p, feed_names, [loss.name], mesh)
@@ -442,15 +443,16 @@ def test_bert_step_for_a_described_v5e_mesh_keeps_the_pallas_kernels(
     finally:
         jax.config.update("jax_enable_compilation_cache", True)
         cc.reset_cache()
-    moved = {n: stat_get(f"attention_lowered_{n}") - before[n]
-             for n in names}
-    # every layer's op, and its re-lowering inside the auto-grad op
-    assert moved == {"pallas": 2 * layers_, "pallas_sharded": 2 * layers_,
-                     "blockwise": 0}
+    moved = {n: stat_get(f"attention_{n}") - before[n] for n in names}
+    # every layer's op once: its grad op reads the forward's saved output
+    # and statistic and lowers no forward of its own (PR 35)
+    assert moved == {"lowered_pallas": layers_,
+                     "lowered_pallas_sharded": layers_,
+                     "lowered_blockwise": 0, "grad_saved": layers_,
+                     "grad_relowered": 0}
     text = compiled.as_text()
-    # a layer's forward kernel (once for the op, once more inside its
-    # auto-grad op, as on one chip), its dK/dV and its dQ kernel
-    assert text.count('custom_call_target="tpu_custom_call"') == 4 * layers_
+    # a layer's forward kernel, its dK/dV and its dQ kernel
+    assert text.count('custom_call_target="tpu_custom_call"') == 3 * layers_
     assert "all-reduce" in text
     assert not re.search(rf"f32\[({per_chip}|{batch}),12,{seq},{seq}\]", text)
     m = compiled.memory_analysis()
