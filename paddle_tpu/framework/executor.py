@@ -129,6 +129,13 @@ class FetchHandle:
         return (f"FetchHandle(step={self._step}, shape={self.shape}, "
                 f"dtype={self.dtype}, {state})")
 
+    def ready(self) -> bool:
+        """Whether the device has already produced the value: a probe
+        that neither waits nor counts as a host sync.  The device runs
+        what it is given in order, so False also says that it is still
+        busy with this step or one before it."""
+        return self._np is not None or self._value.is_ready()
+
     # -- host-side (first call fences) --------------------------------------
     def numpy(self) -> np.ndarray:
         if self._np is None:
@@ -387,7 +394,13 @@ class Executor:
             fetch_list: Optional[Sequence] = None,
             scope: Optional[Scope] = None,
             return_numpy: bool = True,
-            use_program_cache: bool = True):
+            use_program_cache: bool = True,
+            on_launch=None):
+        """``on_launch``: called with no argument the moment before the
+        compiled step is handed to the device, after every host phase
+        that precedes it (the generation engine asks there whether the
+        device had run dry); only with ``FLAGS_telemetry`` on, and only on
+        the compiled path."""
         if program is None:
             program = default_main_program()
         # CompiledProgram (data-parallel wrapper) delegates here
@@ -409,11 +422,12 @@ class Executor:
             return self._run_compiled(program, feed, fetch_names, scope,
                                       return_numpy, use_program_cache)[0]
         t0 = time.perf_counter()
-        span = _telemetry.span_begin("executor/step", step=self._step + 1)
+        span = _telemetry.span_begin("executor/step", cpu=True,
+                                     step=self._step + 1)
         try:
             out, examples = self._run_compiled(
                 program, feed, fetch_names, scope, return_numpy,
-                use_program_cache)
+                use_program_cache, on_launch)
         finally:
             _telemetry.span_end(span)
         _telemetry.note_step(self._step,
@@ -422,7 +436,7 @@ class Executor:
         return out
 
     def _run_compiled(self, program, feed, fetch_names, scope,
-                      return_numpy, use_program_cache):
+                      return_numpy, use_program_cache, on_launch=None):
         """The compiled-run body of :meth:`run`; returns (fetch result,
         examples in this step's feed) so the telemetry wrapper can feed
         the throughput gauge without re-inspecting the feed."""
@@ -510,6 +524,8 @@ class Executor:
         if entry.manifest and "peak_hbm_bytes" in entry.manifest:
             dattrs["peak_hbm_bytes"] = entry.manifest["peak_hbm_bytes"]
         dspan = _telemetry.span_begin("executor/dispatch", **dattrs)
+        if on_launch is not None:
+            on_launch()
         try:
             out_vals = call(feed_vals, mut_vals, const_vals, step)
         except (TypeError, ValueError):

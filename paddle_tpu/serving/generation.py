@@ -176,6 +176,7 @@ from . import batcher
 from . import usage
 from .engine import (OverloadedError, PoisonedInput, RequestFailed,
                      ServingFuture, poison_sentinel_matches)
+from .server import stream_meter
 from .sharded import describe_mesh as _describe_mesh
 
 __all__ = ["GenerationEngine", "GenRequest", "PagePool", "PrefixIndex",
@@ -431,6 +432,107 @@ class _Slot:
     @property
     def active(self) -> bool:
         return self.req is not None
+
+
+class DeviceAccount:
+    """What the scheduler thread knows of the device without a profiler:
+    a floor and a ceiling for the time the chip sat idle.
+
+    The device runs what it is given in order, and every program the
+    scheduler launches passes :meth:`launched`, every wait for one
+    :meth:`fetch_begin` / :meth:`fetch_end`.  So a fetch that waited
+    ends at the instant its program finished; a probe of the last
+    program's output (``FetchHandle.ready``) that reads not ready says
+    the chip is busy now; one that reads ready says it has been idle
+    since some instant after the last at which it was seen busy.  At a
+    launch the gap since the chip ran dry is therefore at least
+    ``now - _t_done`` (``idle_known``: idle for certain since the last
+    program was known to have finished) and at most ``now - _t_busy``
+    (``idle_slack``: possibly idle since it was last seen busy); a
+    launch behind a program still running books a gap of zero.  Fed on
+    the scheduler thread only, and only while telemetry is on."""
+
+    __slots__ = ("_clock", "_last", "_t_done", "_t_busy", "_t_probe",
+                 "_fetching", "dispatches", "drained", "idle_known_s",
+                 "idle_slack_s", "wait_s")
+
+    def __init__(self, clock=time.monotonic):
+        self._clock = clock
+        # first output of the last program launched while the device is
+        # not known to have finished it; None: nothing outstanding
+        self._last = None
+        self._t_done: Optional[float] = None   # idle for certain since
+        self._t_busy: Optional[float] = None   # last seen busy
+        self._t_probe = 0.0                    # when it was last asked
+        self._fetching = None     # (handle, ready) of the wait under way
+        self.dispatches = 0
+        self.drained = 0
+        self.idle_known_s = 0.0
+        self.idle_slack_s = 0.0
+        self.wait_s = 0.0         # seconds blocked in fetches, in all
+
+    def probe(self):
+        """Ask, without waiting, whether the last program launched is
+        still running, and note the answer with its time."""
+        now = self._t_probe = self._clock()
+        if self._last is None:
+            return
+        if self._last.ready():
+            self._last, self._t_done = None, now
+        else:
+            self._t_busy = now
+
+    def launched(self, handle) -> dict:
+        """A program went to the device the moment after the last
+        :meth:`probe` (the executor calls it as it hands the step
+        over); ``handle`` is the program's first output.  Returns what
+        the launch's span carries: ``drained`` and, when the chip had
+        run dry, the gap's floor and ceiling in ms."""
+        at = self._t_probe
+        drained = self._last is None
+        known = slack = 0.0
+        if drained and self._t_done is not None:
+            known = max(0.0, at - self._t_done)
+            slack = max(known, at - self._t_busy)
+        self._last, self._t_busy = handle, at
+        self.dispatches += 1
+        stat_add("serving_device_dispatches")
+        if not drained:
+            return {"drained": 0}
+        self.drained += 1
+        self.idle_known_s += known
+        self.idle_slack_s += slack
+        attrs = {"drained": 1, "idle_known_ms": round(known * 1e3, 3),
+                 "idle_slack_ms": round(slack * 1e3, 3)}
+        stat_add("serving_device_dispatches_drained")
+        stat_add("serving_device_idle_known_ms", attrs["idle_known_ms"])
+        stat_add("serving_device_idle_slack_ms", attrs["idle_slack_ms"])
+        return attrs
+
+    def fetch_begin(self, handle, at: float) -> int:
+        """On entry of a wait for the program whose first output is
+        ``handle``: 1 when it had finished already."""
+        ready = int(handle.ready())
+        self._fetching = (handle, ready)
+        if ready and handle is self._last:
+            self._last, self._t_done = None, at
+        elif ready:
+            self.probe()        # of the program launched behind it
+        return ready
+
+    def fetch_end(self, start: float, end: float):
+        """The wait that :meth:`fetch_begin` opened is over.  One that
+        found its program still running ended when the program did: the
+        chip was busy until ``end``, with this program or, from then on,
+        with the one behind it."""
+        handle, ready = self._fetching
+        self._fetching = None
+        self.wait_s += end - start
+        if ready:
+            return
+        self._t_busy = end
+        if handle is self._last:
+            self._last, self._t_done = None, end
 
 
 class _StepInFlight:
@@ -784,8 +886,6 @@ class GenerationEngine:
         self._h_verify = telemetry.Histogram("serving_spec_verify_ms")
         self._h_ttft = telemetry.Histogram("serving_ttft_ms")
         self._h_itl = telemetry.Histogram("serving_inter_token_ms")
-        self._t_prefill_total = 0.0
-        self._t_decode_total = 0.0
         self._decode_rate_ema: Optional[float] = None
         # finished-sequence timeline store (the /tracez generation
         # block): recent ring + always-kept slowest-N tail, like the
@@ -797,9 +897,13 @@ class GenerationEngine:
         self._tail_keep = max(0, int(
             flag_value("FLAGS_trace_tail_keep") or 8))
         self._occ_vec: Optional[tuple] = None  # last slot-track sample
-        # seconds this scheduler iteration blocked on the device
-        # (scheduler thread only: _end_device_wait)
-        self._iter_wait_s = 0.0
+        # the scheduler thread's account of the device: gaps at every
+        # launch, seconds blocked in fetches (_launch,
+        # _begin_device_wait, _end_device_wait)
+        self._account = DeviceAccount()
+        self._on_launch = None    # the account's probe, during a launch
+        # the stream handlers' totals as the last pass read them
+        self._stream_seen = stream_meter.totals()
         self._released_in_feeds = 0  # window pages the last feeds freed
         # the one decode grid step dispatched and not yet fetched
         self._inflight: Optional[_StepInFlight] = None
@@ -958,8 +1062,31 @@ class GenerationEngine:
         names = self._fetch_names(fetches)
         outs = exe.run(prog, feed=feed,
                        fetch_list=[fetches[n] for n in names],
-                       scope=self.scope, return_numpy=False)
+                       scope=self.scope, return_numpy=False,
+                       on_launch=self._on_launch)
         return dict(zip(names, outs))
+
+    def _launch(self, span_name: str, run, parent=None, **attrs) -> dict:
+        """Hand one program to the device under a span named
+        ``span_name``: ``run()`` makes the executor call (with
+        ``on_launch=self._on_launch``) and returns the program's fetch
+        handles by name, unread.  Every program the scheduler launches
+        goes through here, so the span also says whether the chip had
+        run dry by then (:class:`DeviceAccount`); warm-up runs the same
+        programs from its caller's thread and books nothing."""
+        span = telemetry.span_begin(span_name, parent=parent, **attrs)
+        acct = self._account if self._on_scheduler(span) else None
+        try:
+            if acct is not None:
+                acct.probe()
+                self._on_launch = acct.probe
+            outs = run()
+            if acct is not None:
+                span.attrs.update(acct.launched(next(iter(outs.values()))))
+        finally:
+            self._on_launch = None
+            telemetry.span_end(span)
+        return outs
 
     def _prefill_prog_for(self, bucket: int):
         """Whole-prompt prefill: the causal forward over the prompt with
@@ -1694,34 +1821,60 @@ class GenerationEngine:
                 # one iteration = one trace of its own (the stack is
                 # empty here); every phase below hangs under it on
                 # this thread's stack
-                it = telemetry.span_begin("generation/iteration")
-                cpu0 = time.thread_time()
+                it = telemetry.span_begin("generation/iteration", cpu=True)
                 claim = telemetry.span_begin("generation/claim")
                 claimed = self._claim_locked()
                 queued = len(self._queue)
             active = None
+            if it is not None:
+                waited0 = self._account.wait_s
             try:
                 active = self._iteration(claimed, claim)
             finally:
                 if it is not None:
+                    # what the stream handlers took of the interpreter
+                    # since the last pass ended
+                    seen, self._stream_seen = \
+                        self._stream_seen, stream_meter.totals()
                     it.attrs.update(
                         active=active, claimed=len(claimed), queued=queued,
-                        cpu_ms=round((time.thread_time() - cpu0) * 1e3, 3))
+                        stream_write_ms=round(
+                            (self._stream_seen[0] - seen[0]) * 1e3, 3),
+                        stream_cpu_ms=round(
+                            (self._stream_seen[1] - seen[1]) * 1e3, 3))
                     # unwinds whatever phase a raise left open
                     telemetry.span_end(it)
                     telemetry.histogram_observe(
                         "serving_iteration_host_ms",
-                        (it.end - it.start - self._iter_wait_s) * 1e3)
-                self._iter_wait_s = 0.0
+                        (it.end - it.start
+                         - (self._account.wait_s - waited0)) * 1e3)
+
+    def _on_scheduler(self, span) -> bool:
+        """Whether ``span`` (None with telemetry off) was opened by the
+        scheduler thread: only its launches and waits feed the device
+        account; warm-up and a set-up check run the same programs from
+        their caller's thread."""
+        return span is not None \
+            and threading.current_thread() is self._thread
+
+    def _begin_device_wait(self, name: str, outs: dict, **attrs):
+        """Open a span that blocks on the device for the program that
+        returned ``outs``; ``ready`` on it says whether the program had
+        already finished."""
+        span = telemetry.span_begin(name, **attrs)
+        if self._on_scheduler(span):
+            span.attrs["ready"] = self._account.fetch_begin(
+                next(iter(outs.values())), span.start)
+        return span
 
     def _end_device_wait(self, span):
-        """Close a span that blocked on the device and keep its time out
-        of the iteration's host time (``serving_iteration_host_ms``).
-        Only the scheduler's own waits count: warm-up runs the same
-        programs from its caller's thread."""
+        """Close a span that blocked on the device: its time stays out
+        of the iteration's host time (``serving_iteration_host_ms``),
+        and where it waited its end is the instant the program
+        finished."""
         telemetry.span_end(span)
-        if span is not None and threading.current_thread() is self._thread:
-            self._iter_wait_s += span.end - span.start
+        if self._on_scheduler(span):
+            self._account.fetch_end(span.start, span.end)
 
     def _iteration(self, claimed: List[tuple], claim) -> int:
         """One scheduler pass after the claim: admit the claimed
@@ -2268,11 +2421,11 @@ class GenerationEngine:
                     # state: whatever the slot's last sequence left goes
                     feed["slot"] = np.asarray([slot.idx], "int32")
                     state = {"state_written": 1}
-            with telemetry.trace_span("generation/prefill", parent=parent,
-                                      tokens=n_rows, bucket=bucket,
-                                      slot=slot.idx, **state):
-                outs = self._run_fetching(self._prefill_exe, prog,
-                                          fetches, feed)
+            outs = self._launch(
+                "generation/prefill", lambda: self._run_fetching(
+                    self._prefill_exe, prog, fetches, feed),
+                parent=parent, tokens=n_rows, bucket=bucket, slot=slot.idx,
+                **state)
             if state:
                 self._count("slot_state_writes")
                 stat_add("serving_slot_state_writes")
@@ -2302,11 +2455,11 @@ class GenerationEngine:
                     "chunk_len": np.asarray([n], "int32"),
                     "last_off": np.asarray([n - 1], "int64")}
         last = start + n >= n_prompt
-        with telemetry.trace_span("generation/prefill_chunk",
-                                  parent=parent, tokens=n, base=start,
-                                  bucket=bucket, slot=slot.idx):
-            outs = self._run_fetching(self._prefill_exe, prog, fetches,
-                                      feed)
+        outs = self._launch(
+            "generation/prefill_chunk", lambda: self._run_fetching(
+                self._prefill_exe, prog, fetches, feed),
+            parent=parent, tokens=n, base=start, bucket=bucket,
+            slot=slot.idx)
         self._count("prefill_chunks")
         stat_add("serving_prefill_chunks")
         if req.tenant is not None:
@@ -2325,8 +2478,8 @@ class GenerationEngine:
         logits row when kept, and the expert layers' counts over the
         program's ``n_tokens`` real rows), under
         ``generation/prefill_fetch``."""
-        span = telemetry.span_begin("generation/prefill_fetch",
-                                    parent=parent, slot=slot.idx)
+        span = self._begin_device_wait("generation/prefill_fetch", outs,
+                                       parent=parent, slot=slot.idx)
         try:
             # (a block-diffusion prefill yields K/V alone: its first
             # tokens come from the first block's passes)
@@ -2380,7 +2533,6 @@ class GenerationEngine:
             slot, outs, slot.span.context() if slot.span is not None
             else None, n_rows)
         n_prompt = int(req.prompt.size)
-        self._t_prefill_total += req.prefill_ms
         self._h_prefill.observe(req.prefill_ms, trace_id=req.trace_id)
         telemetry.histogram_observe("serving_prefill_ms",
                                     req.prefill_ms,
@@ -2560,13 +2712,14 @@ class GenerationEngine:
             feed["block_tables_window"] = block_tables_window \
                 if block_tables_window is not None \
                 else np.zeros(empty, "int32")
-        with telemetry.trace_span("generation/decode_dispatch"):
-            return self._run_fetching(self._decode_exe, self._decode_prog,
-                                      self._decode_fetches, feed)
+        return self._launch(
+            "generation/decode_dispatch", lambda: self._run_fetching(
+                self._decode_exe, self._decode_prog, self._decode_fetches,
+                feed))
 
     def _fetch_decode(self, outs: dict) -> dict:
         """Block on a dispatched grid step: its fetches as arrays."""
-        span = telemetry.span_begin("generation/token_fetch")
+        span = self._begin_device_wait("generation/token_fetch", outs)
         try:
             return {n: np.asarray(o.numpy()) for n, o in outs.items()}
         finally:
@@ -2630,32 +2783,28 @@ class GenerationEngine:
             chunk = np.zeros((bucket,), "int64")
             chunk[0] = slot.tokens[-1]
             chunk[1:c] = draft
-            fetch = [fetches["tokens"]]
-            if self.keep_logits:
-                fetch.append(fetches["logits"])
-            with telemetry.trace_span("generation/spec_verify",
-                                      parent=slot.span.context()
-                                      if slot.span is not None else None,
-                                      draft=len(draft), bucket=bucket,
-                                      slot=slot.idx):
-                outs = self._prefill_exe.run(
-                    prog,
-                    feed={"chunk_ids": chunk[None],
-                          "base": np.asarray([slot.position], "int32"),
-                          "block_table":
-                          self._slot_block_table(slot)[None],
-                          "chunk_len": np.asarray([c], "int32")},
-                    fetch_list=fetch, scope=self.scope,
-                    return_numpy=False)
-            m = np.asarray(outs[0].numpy())[0]
-            logits_arr = np.asarray(outs[1].numpy())[0] \
+            names = ["tokens", "logits"] if self.keep_logits else ["tokens"]
+            feed = {"chunk_ids": chunk[None],
+                    "base": np.asarray([slot.position], "int32"),
+                    "block_table": self._slot_block_table(slot)[None],
+                    "chunk_len": np.asarray([c], "int32")}
+            outs = self._launch(
+                "generation/spec_verify", lambda: dict(zip(
+                    names, self._prefill_exe.run(
+                        prog, feed=feed,
+                        fetch_list=[fetches[n] for n in names],
+                        scope=self.scope, return_numpy=False,
+                        on_launch=self._on_launch))),
+                parent=slot.span.context() if slot.span is not None
+                else None, draft=len(draft), bucket=bucket, slot=slot.idx)
+            m = np.asarray(outs["tokens"].numpy())[0]
+            logits_arr = np.asarray(outs["logits"].numpy())[0] \
                 if self.keep_logits else None
             a = 0
             while a < len(draft) and int(draft[a]) == int(m[a]):
                 a += 1
             t1 = time.monotonic()
             ms = (t1 - t0) * 1e3
-            self._t_decode_total += ms
             self._h_verify.observe(ms, trace_id=req.trace_id)
             telemetry.histogram_observe("serving_spec_verify_ms", ms,
                                         trace_id=req.trace_id)
@@ -2741,6 +2890,7 @@ class GenerationEngine:
             # row of it will be booked, and nothing waits for it
             self._inflight = None
             self._discard_rows(len(riders))
+        self._release_step(lead)
 
     def _discard_rows(self, n: int):
         """Count ``n`` rows of a step dispatched ahead that no sequence
@@ -2775,6 +2925,7 @@ class GenerationEngine:
         finally:
             telemetry.span_end(step)
         self._book_inflight(fl, outs)
+        self._release_step(fl)
 
     def _fetch_inflight(self, fl: _StepInFlight, step) -> dict:
         """Wait for ``fl``'s fetches and write what the step did onto
@@ -2815,7 +2966,6 @@ class GenerationEngine:
                 rows=len(rows) * self._blk,
                 live_positions=int(sum(s.position + self._blk
                                        for s in rows)),
-                rows_masked=int(sum(s.blk_left for s in rows)),
                 tokens_committed=int(sum(
                     self._block_yield(s) for s in commit)))
         if step is not None:
@@ -2827,7 +2977,7 @@ class GenerationEngine:
         to the riders whose slot still serves the request that rode."""
         t1 = time.monotonic()
         rows = [s for s, r in fl.riders if s.req is r]
-        span = telemetry.span_begin("generation/book_tokens",
+        span = telemetry.span_begin("generation/book_tokens", cpu=True,
                                     links=fl.links, tokens=len(rows))
         try:
             self._book_step(rows, outs, fl.t0, t1)
@@ -2844,6 +2994,21 @@ class GenerationEngine:
         finally:
             telemetry.span_end(span)
 
+    def _release_step(self, fl: _StepInFlight):
+        """Let go of a settled step's device arrays, here and not
+        wherever a caller's frame ends: the runtime gives up the
+        interpreter to free them, and the stream handlers that the
+        booking just woke run before this thread has it back (6 ms a
+        pass with 64 streams, PERF.md section 6, PR 36), so the wait has
+        a span, ``generation/release``."""
+        span = telemetry.span_begin("generation/release", cpu=True)
+        fl.outs = None
+        telemetry.span_end(span)
+        if self._on_scheduler(span):
+            # the longest stretch of a pass with no word from the device
+            # ends here
+            self._account.probe()
+
     def _decode_feeds_for(self, skip: frozenset,
                           lead: Optional[_StepInFlight]):
         """:meth:`_build_decode_feeds` under ``generation/decode_feeds``.
@@ -2852,7 +3017,7 @@ class GenerationEngine:
         ``lead``'s settle and a rider has no page for the position
         ahead."""
         t0 = time.monotonic()
-        span = telemetry.span_begin("generation/decode_feeds")
+        span = telemetry.span_begin("generation/decode_feeds", cpu=True)
         try:
             active, feeds = self._build_decode_feeds(skip, lead)
             # the grid step serves N sequences at once: link their
@@ -3043,7 +3208,6 @@ class GenerationEngine:
         next_tokens, logits = outs.get("next_token"), outs.get("logits")
         router = outs.get("router_logits")
         ms = (t1 - t0) * 1e3
-        self._t_decode_total += ms
         self._h_step.observe(ms)
         telemetry.histogram_observe("serving_decode_step_ms", ms)
         self._count("decode_steps")
@@ -3389,9 +3553,6 @@ class GenerationEngine:
             "counters": n,
             "tokens_per_request": round(
                 n["generated_tokens"] / max(n["served"], 1), 2),
-            "prefill_decode_ms_ratio": round(
-                self._t_prefill_total / max(self._t_decode_total, 1e-9),
-                4),
             "generate_ms": self._h_gen.summary(),
             "prefill_ms": self._h_prefill.summary(),
             "decode_step_ms": self._h_step.summary(),

@@ -126,6 +126,39 @@ VERSION_HEADER = "X-PaddleTPU-Weights-Version"
 TENANT_HEADER = "X-PaddleTPU-Tenant"
 
 
+class StreamMeter:
+    """What the streaming handler threads take, process-wide: seconds
+    inside ``wfile.write`` + ``flush`` and thread CPU seconds of the
+    handler loops (a span a token would push a window's spans out of
+    the ring).  A handler adds its share under the lock every
+    ``EVERY`` tokens written and when its stream ends: the thread clock
+    is a system call, and 64 handlers read it while the scheduler waits
+    for the interpreter.  The generation scheduler reads both sums at
+    the end of a pass and writes the differences on its
+    ``generation/iteration`` span (``stream_write_ms``,
+    ``stream_cpu_ms``): the handlers share one interpreter with it."""
+
+    EVERY = 16
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._write_s = 0.0
+        self._cpu_s = 0.0
+
+    def add(self, write_s: float, cpu_s: float):
+        with self._lock:
+            self._write_s += write_s
+            self._cpu_s += cpu_s
+
+    def totals(self):
+        """``(write seconds, CPU seconds)`` so far."""
+        with self._lock:
+            return self._write_s, self._cpu_s
+
+
+stream_meter = StreamMeter()
+
+
 def parse_trace_header(value) -> Optional[str]:
     """Validate an incoming trace-id header: a short url-safe token or
     nothing (a malformed id is dropped, never adopted — trace identity
@@ -790,6 +823,10 @@ class _Handler(_JsonHandler):
         n = 0
         client_gone = False
         timed_out = False
+        # (metered only with telemetry on: no clock is read without it)
+        metered = telemetry.enabled()
+        cpu0 = time.thread_time() if metered else 0.0
+        write_s = 0.0
         while True:
             try:
                 tok, ts = q.get(timeout=0.05)
@@ -805,6 +842,7 @@ class _Handler(_JsonHandler):
             if client_gone:
                 continue  # drain for accounting, write nothing
             line = json.dumps({"i": n, "token": int(tok)}) + "\n"
+            w0 = time.monotonic() if metered else 0.0
             try:
                 self.wfile.write(line.encode())
                 self.wfile.flush()
@@ -812,6 +850,16 @@ class _Handler(_JsonHandler):
                 # the client hung up mid-stream: the sequence keeps
                 # generating (no cancellation), we just stop writing
                 client_gone = True
+            if metered:
+                write_s += time.monotonic() - w0
+                if n % StreamMeter.EVERY == 0:
+                    # this loop's CPU since the last reading (the polls
+                    # of an empty queue between tokens with it)
+                    cpu1 = time.thread_time()
+                    stream_meter.add(write_s, cpu1 - cpu0)
+                    cpu0, write_s = cpu1, 0.0
+        if metered:
+            stream_meter.add(write_s, time.thread_time() - cpu0)
         final = {"done": True}
         status = 200
         try:

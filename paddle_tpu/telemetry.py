@@ -146,7 +146,7 @@ class Span:
     requests it serves)."""
 
     __slots__ = ("name", "attrs", "span_id", "parent_id", "trace_id",
-                 "links", "tid", "start", "end", "_annotation")
+                 "links", "tid", "start", "end", "_annotation", "_cpu0")
     _next_id = [1]
     _id_lock = threading.Lock()
 
@@ -162,6 +162,7 @@ class Span:
         self.links: Tuple[SpanContext, ...] = tuple(links or ())
         self.tid = tid
         self._annotation = None
+        self._cpu0: Optional[float] = None
         self.start = time.monotonic()
         self.end: Optional[float] = None
 
@@ -255,7 +256,7 @@ class _SpanCtx:
 
 def span_begin(name: str, parent: Optional[SpanContext] = None,
                links=None, detached: bool = False,
-               trace_id: Optional[str] = None,
+               trace_id: Optional[str] = None, cpu: bool = False,
                **attrs) -> Optional[Span]:
     """Open a span without a ``with`` block (executor hot path); pair
     with :func:`span_end`.  Returns None when telemetry is disabled.
@@ -272,7 +273,11 @@ def span_begin(name: str, parent: Optional[SpanContext] = None,
     ``trace_id`` — adopt an externally-minted trace id at a root span
     (the cross-process propagation half: a router/replica hop carries
     the id in a header and both tiers' spans join one trace).  Ignored
-    when a parent supplies the trace."""
+    when a parent supplies the trace.
+    ``cpu=True`` — the span also carries ``cpu_ms``, this thread's CPU
+    time (``time.thread_time()``) between begin and end: its wall time
+    less that is the time the thread waited or wanted to run and could
+    not.  Written when the span ends on the thread that began it."""
     if not enabled():
         return None
     if parent is not None:
@@ -296,6 +301,8 @@ def span_begin(name: str, parent: Optional[SpanContext] = None,
         if profiler is not None:
             span._annotation = profiler.TraceAnnotation(name)
             span._annotation.__enter__()
+    if cpu:
+        span._cpu0 = time.thread_time()
     return span
 
 
@@ -303,6 +310,9 @@ def _close(span: Span, now: float, ring: deque):
     """Stamp the end, exit the span's profiler annotation (if it has
     one) and record the span in the ring."""
     span.end = now
+    if span._cpu0 is not None and span.tid == threading.get_ident():
+        span.attrs["cpu_ms"] = round(
+            (time.thread_time() - span._cpu0) * 1e3, 3)
     if span._annotation is not None:
         span._annotation.__exit__(None, None, None)
         span._annotation = None
@@ -349,7 +359,7 @@ def current_span() -> Optional[SpanContext]:
 
 
 def trace_span(name: str, parent: Optional[SpanContext] = None,
-               links=None, **attrs):
+               links=None, cpu: bool = False, **attrs):
     """``with trace_span("ckpt/write", step=n): ...`` — times the block
     on the monotonic clock and records a :class:`Span` with the current
     thread's innermost open span as parent — or, with ``parent=ctx``,
@@ -358,7 +368,8 @@ def trace_span(name: str, parent: Optional[SpanContext] = None,
     under ``FLAGS_telemetry=0``."""
     if not enabled():
         return _NOOP
-    return _SpanCtx(span_begin(name, parent=parent, links=links, **attrs))
+    return _SpanCtx(span_begin(name, parent=parent, links=links, cpu=cpu,
+                               **attrs))
 
 
 def get_spans() -> List[Span]:

@@ -29,6 +29,7 @@ import importlib.util
 import json
 import os
 import threading
+import time
 
 import numpy as np
 import pytest
@@ -786,6 +787,12 @@ def test_eos_inside_a_block_ends_the_sequence_and_discards_the_row_ahead():
         res = eng.generate(prompt, 14, timeout=300)
         assert res["finish"] == "eos"
         assert res["tokens"] == tokens[:cut + 1]
+        # the future resolves inside the pass that books the EOS, a
+        # moment before that pass counts the row dispatched ahead
+        deadline = time.monotonic() + 10.0
+        while eng._inflight is not None and time.monotonic() < deadline:
+            time.sleep(0.005)
+        time.sleep(0.05)
         n = _counters(eng)
         assert n["generated_tokens"] == cut + 1
         assert n["decode_rows_discarded"] == 1

@@ -43,6 +43,17 @@ def _engine(kind, **kw):
         prefix_reuse=False, speculate=False, attn_impl="xla", seed=0, **kw)
 
 
+@pytest.fixture(scope="module", autouse=True)
+def _weights_of_a_fresh_process():
+    """The engines here draw their weights from the process-wide op-seed
+    counter, and several tests need a stream with a token that occurs
+    once (``_pick_eos``): start the counter where a fresh process has it,
+    so the draw does not depend on which files the worker ran before."""
+    from paddle_tpu.ops.registry import reset_op_seed
+
+    reset_op_seed()
+
+
 @pytest.fixture(scope="module", params=["paged"])
 def eng(request):
     e = _engine(request.param)

@@ -74,6 +74,14 @@ def _inside(inner, outer):
     return outer.start <= inner.start and inner.end <= outer.end
 
 
+def _less_cpu(span):
+    """A phase span's attributes without its ``cpu_ms`` (PR 36): every
+    phase that times the thread's CPU must carry one."""
+    attrs = dict(span.attrs)
+    assert attrs.pop("cpu_ms") >= 0.0
+    return attrs
+
+
 def test_decode_iteration_span_tree(traced):
     """Every scheduler pass is a root ``generation/iteration`` whose
     children, in order, are the phases of the table in README "Serving
@@ -89,14 +97,16 @@ def test_decode_iteration_span_tree(traced):
     for it in iters:
         assert it.parent_id is None
         assert it.trace_id != res["trace_id"]
-        assert set(it.attrs) == {"active", "claimed", "queued", "cpu_ms"}
+        assert set(it.attrs) == {"active", "claimed", "queued", "cpu_ms",
+                                 "stream_write_ms", "stream_cpu_ms"}
     for i, it in enumerate(decoding):
         first, last = i == 0, i == len(decoding) - 1
         kids = sorted(_children(spans, it), key=lambda s: s.start)
         assert [k.name for k in kids if k.name != "generation/publish"] \
             == ["generation/claim", "generation/decode_feeds",
                 "generation/decode_step"] \
-            + ([] if first else ["generation/book_tokens"])
+            + ([] if first else ["generation/book_tokens",
+                                 "generation/release"])
         assert kids[-1].name == "generation/publish"
         assert all(k.tid == it.tid and _inside(k, it) for k in kids)
         step = _named(kids, "generation/decode_step")[0]
@@ -117,9 +127,10 @@ def test_decode_iteration_span_tree(traced):
             == EXECUTOR_PHASES
     feeds = _named(spans, "generation/decode_feeds")
     book = _named(spans, "generation/book_tokens")
-    assert [f.attrs for f in feeds] == [{"active": 1}] * 4 + [{"active": 0}]
-    assert book[0].attrs == {"tokens": 1, "finished": 0}
-    assert book[-1].attrs == {"tokens": 1, "finished": 1}
+    assert [_less_cpu(f) for f in feeds] \
+        == [{"active": 1}] * 4 + [{"active": 0}]
+    assert _less_cpu(book[0]) == {"tokens": 1, "finished": 0}
+    assert _less_cpu(book[-1]) == {"tokens": 1, "finished": 1}
     assert _named(spans, "generation/claim")[0].attrs == {"claimed": 1}
 
 
@@ -152,8 +163,12 @@ def test_existing_spans_keep_start_end_and_attributes(traced):
     prepare, = _named(spans, "generation/prefill_prepare")
     prefill, = _named(spans, "generation/prefill")
     fetch, = _named(spans, "generation/prefill_fetch")
-    assert prefill.attrs == {"tokens": len(PROMPT), "bucket": 16,
-                             "slot": prefill.attrs["slot"]}
+    # (PR 36: the span that holds a launch also says whether the device
+    # had run dry; the idle engine's first launch always finds it so)
+    assert prefill.attrs == {
+        "tokens": len(PROMPT), "bucket": 16, "slot": prefill.attrs["slot"],
+        "drained": 1, "idle_known_ms": prefill.attrs["idle_known_ms"],
+        "idle_slack_ms": prefill.attrs["idle_slack_ms"]}
     assert prepare.attrs == {"slot": prefill.attrs["slot"], "bucket": 16}
     # the prefill span opens at the executor call and closes at
     # dispatch; the blocking read of its token is the fetch span's
@@ -411,3 +426,399 @@ def test_device_live_bytes_falls_back_to_the_live_array_walk(monkeypatch):
     monkeypatch.setattr(jax, "local_devices", lambda: [
         _StatsDevice(0, {"bytes_in_use": 7}), _StatsDevice(1, None)])
     assert observatory.device_live_bytes()["total"] >= keep.nbytes
+
+
+# -- the device account and a pass's own account of its time (PR 36) -------
+
+from paddle_tpu.framework.executor import FetchHandle  # noqa: E402
+from paddle_tpu.monitor import stat_get  # noqa: E402
+from paddle_tpu.serving import generation  # noqa: E402
+from paddle_tpu.serving.generation import DeviceAccount  # noqa: E402
+from paddle_tpu.serving.server import stream_meter  # noqa: E402
+
+
+class _Program:
+    """A launched program as the account sees it: its first output,
+    which reads ready from the instant the device finishes it."""
+
+    def __init__(self, clock, done_at=None):
+        self.clock, self.done_at = clock, done_at
+
+    def ready(self):
+        return self.done_at is not None and self.clock() >= self.done_at
+
+
+class _Device:
+    """A scripted device behind a fake clock: runs what it is given in
+    order, ``step`` seconds each, and keeps its own idle time: the truth
+    the account's floor and ceiling must enclose."""
+
+    def __init__(self, step):
+        self.now, self.step = 0.0, step
+        self.free_at = self.idle = 0.0
+        self.acct = DeviceAccount(clock=lambda: self.now)
+
+    def clock(self):
+        return self.now
+
+    def host(self, seconds):
+        self.now += seconds
+
+    def launch(self):
+        self.acct.probe()
+        start = max(self.now, self.free_at)
+        self.idle += start - self.free_at if self.free_at else 0.0
+        prog = _Program(self.clock, start + self.step)
+        self.free_at = prog.done_at
+        return prog, self.acct.launched(prog)
+
+    def fetch(self, prog):
+        start = self.now
+        ready = self.acct.fetch_begin(prog, start)
+        self.now = max(self.now, prog.done_at)
+        self.acct.fetch_end(start, self.now)
+        return ready
+
+
+def test_account_books_nothing_while_the_device_sets_the_pace():
+    """One step kept in flight under a host half shorter than the
+    device's step: every probe reads not ready, every fetch waits."""
+    dev = _Device(step=0.017)
+    ahead, first = dev.launch()
+    assert first == {"drained": 1, "idle_known_ms": 0.0,
+                     "idle_slack_ms": 0.0}       # nothing is known yet
+    for _ in range(50):
+        dev.host(0.004)                          # feeds
+        nxt, attrs = dev.launch()
+        assert attrs == {"drained": 0}
+        assert dev.fetch(ahead) == 0             # it waited
+        dev.host(0.006)                          # booking
+        dev.acct.probe()
+        ahead = nxt
+    a = dev.acct
+    assert (a.dispatches, a.drained) == (51, 1)
+    assert a.idle_known_s == a.idle_slack_s == dev.idle == 0.0
+    # the first fetch waits out a whole step less the feeds, the others
+    # a step less the host's half
+    assert a.wait_s == pytest.approx(0.013 + 49 * 0.007, abs=1e-9)
+
+
+def test_account_books_a_settle_pass_exactly():
+    """A pass that settles first: the fetch waits for the only program
+    outstanding, so the gap is the launch less the fetch's end, known to
+    the last microsecond, floor and ceiling alike."""
+    dev = _Device(step=0.017)
+    prog, _ = dev.launch()
+    dev.host(0.002)
+    assert dev.fetch(prog) == 0
+    fetch_end = dev.now
+    dev.host(0.0093)                             # book, feeds, prepare
+    _, attrs = dev.launch()
+    assert attrs == {"drained": 1, "idle_known_ms": 9.3,
+                     "idle_slack_ms": 9.3}
+    assert dev.acct.idle_known_s == pytest.approx(dev.now - fetch_end)
+    assert dev.idle == pytest.approx(0.0093)
+
+
+def test_account_encloses_the_truth_when_the_host_sets_the_pace():
+    """A host half longer than the device's step: the chip runs dry
+    somewhere between two probes, so the truth lies between the floor
+    (since the probe that found it dry) and the ceiling (since it was
+    last seen busy), and the two are a probe's distance apart."""
+    dev = _Device(step=0.005)
+    ahead, _ = dev.launch()
+    for _ in range(40):
+        dev.host(0.004)
+        nxt, attrs = dev.launch()
+        dev.fetch(ahead)
+        dev.host(0.003)
+        dev.acct.probe()
+        dev.host(0.003)
+        dev.acct.probe()
+        ahead = nxt
+    a = dev.acct
+    assert a.drained > 30 and dev.idle > 0.1
+    assert a.idle_known_s <= dev.idle <= a.idle_slack_s
+    # no stretch between two probes is longer than 4 ms
+    assert a.idle_slack_s - a.idle_known_s <= a.drained * 0.004 + 1e-9
+
+
+def test_account_takes_a_fetch_that_found_its_program_ready():
+    """A fetch that did not wait says only that the program finished
+    before it: idle for certain from the fetch's entry, possibly since
+    the launch (the last time the chip was seen busy)."""
+    dev = _Device(step=0.002)
+    prog, _ = dev.launch()
+    t_launch = dev.now
+    dev.host(0.010)
+    assert dev.fetch(prog) == 1
+    t_fetch = dev.now
+    dev.host(0.003)
+    _, attrs = dev.launch()
+    assert attrs["drained"] == 1
+    assert attrs["idle_known_ms"] == pytest.approx(
+        (dev.now - t_fetch) * 1e3)
+    assert attrs["idle_slack_ms"] == pytest.approx(
+        (dev.now - t_launch) * 1e3)
+    assert attrs["idle_known_ms"] <= dev.idle * 1e3 <= attrs["idle_slack_ms"]
+
+
+def test_fetch_handle_ready_neither_waits_nor_counts_a_host_sync():
+    import jax.numpy as jnp
+
+    before = stat_get("host_syncs")
+    h = FetchHandle(jnp.arange(4) + 1)
+    while not h.ready():        # a probe, however often, is no sync
+        time.sleep(0.001)
+    assert stat_get("host_syncs") == before
+    assert h.numpy().tolist() == [1, 2, 3, 4] and h.ready()
+    assert stat_get("host_syncs") == before + 1
+
+
+def test_launch_and_fetch_spans_carry_the_account(traced, engine):
+    """Every span that holds a launch says ``drained``; a fetch span says
+    whether its program was ``ready``; the sums on the spans are the
+    account's own and the counters' (`/metrics`)."""
+    _, spans = traced
+    launches = [s for s in spans if s.name in (
+        "generation/prefill", "generation/decode_dispatch")]
+    assert len(launches) == 5           # one prefill, four decode steps
+    for s in launches:
+        assert s.attrs["drained"] in (0, 1)
+        if s.attrs["drained"]:
+            assert 0.0 <= s.attrs["idle_known_ms"] <= s.attrs["idle_slack_ms"]
+        else:
+            assert "idle_known_ms" not in s.attrs
+    fetches = [s for s in spans if s.name in (
+        "generation/prefill_fetch", "generation/token_fetch")]
+    assert len(fetches) == 5
+    assert all(s.attrs["ready"] in (0, 1) for s in fetches)
+    # the first decode step goes out after the prefill was fetched: the
+    # chip is dry for certain, the whole gap known
+    first = _named(spans, "generation/decode_dispatch")[0]
+    assert first.attrs["drained"] == 1
+    assert first.attrs["idle_known_ms"] > 0.0
+
+
+def test_account_sums_reach_the_counters(engine):
+    pt.set_flags({"FLAGS_telemetry": True})
+    names = ("serving_device_dispatches", "serving_device_dispatches_drained",
+             "serving_device_idle_known_ms", "serving_device_idle_slack_ms")
+    before = [stat_get(n) for n in names]
+    a = engine._account
+    mine = [a.dispatches, a.drained, a.idle_known_s, a.idle_slack_s]
+    res = engine.generate(PROMPT, 4, timeout=120)
+    _spans_after(1)
+    d = [stat_get(n) - b for n, b in zip(names, before)]
+    assert d[0] == a.dispatches - mine[0] == res["steps"] + 1
+    assert d[1] == a.drained - mine[1] >= 1
+    # (the spans and counters carry whole microseconds)
+    assert d[2] == pytest.approx((a.idle_known_s - mine[2]) * 1e3,
+                                 abs=1e-3 * d[0])
+    assert d[3] == pytest.approx((a.idle_slack_s - mine[3]) * 1e3,
+                                 abs=1e-3 * d[0])
+    assert d[2] <= d[3]
+
+
+def test_warmup_from_another_thread_books_nothing():
+    """Warm-up runs the scheduler's programs from its caller's thread:
+    the account hears of none of them."""
+    pt.set_flags({"FLAGS_telemetry": True})
+    gen = GenerationEngine(MODEL, num_slots=2, max_seq_len=64,
+                           attn_impl="xla", seed=0, page_tokens=8,
+                           prefix_reuse=False)
+    try:
+        telemetry.clear_spans()
+        assert gen.warmup() > 0
+        a = gen._account
+        assert (a.dispatches, a.drained, a.wait_s) == (0, 0, 0.0)
+        mine = [s for s in telemetry.get_spans()
+                if s.name.startswith("generation/")]
+        assert mine and all("drained" not in s.attrs
+                            and "ready" not in s.attrs for s in mine)
+    finally:
+        gen.close()
+
+
+def test_telemetry_off_probes_nothing_and_reads_no_thread_clock(
+        engine, monkeypatch):
+    pt.set_flags({"FLAGS_telemetry": False})
+    try:
+        engine.generate(PROMPT, 1, timeout=120)  # ends the idle wait span
+        time.sleep(0.1)
+        calls = {"ready": 0, "thread_time": 0}
+        real_ready, real_tt = FetchHandle.ready, time.thread_time
+
+        def ready(self):
+            calls["ready"] += 1
+            return real_ready(self)
+
+        def thread_time():
+            calls["thread_time"] += 1
+            return real_tt()
+
+        monkeypatch.setattr(FetchHandle, "ready", ready)
+        monkeypatch.setattr(time, "thread_time", thread_time)
+        a = engine._account
+        mine = (a.dispatches, a.wait_s, stream_meter.totals())
+        res = engine.generate(PROMPT, 4, timeout=120)
+        time.sleep(0.1)
+        assert len(res["tokens"]) == 4
+        assert calls == {"ready": 0, "thread_time": 0}
+        assert (a.dispatches, a.wait_s, stream_meter.totals()) == mine
+        # ... and with it on, the same request does both
+        pt.set_flags({"FLAGS_telemetry": True})
+        engine.generate(PROMPT, 4, timeout=120)
+        time.sleep(0.1)
+        assert calls["ready"] >= 5 and calls["thread_time"] >= 10
+    finally:
+        pt.set_flags({"FLAGS_telemetry": True})
+
+
+def test_phases_time_the_threads_cpu_inside_the_iterations(traced):
+    """``cpu_ms`` on the iteration, on the phases that are a pass's host
+    work (``decode_feeds``, ``book_tokens``, ``executor/step``) and on
+    ``release``, whose wall time is a wait; a phase's CPU time lies
+    inside the iteration's (one thread clock, nested reads).  The short
+    phases carry none: a reading costs microseconds where the thread
+    clock is a system call."""
+    _, spans = traced
+    timed = ("generation/decode_feeds", "generation/book_tokens",
+             "generation/release", "executor/step")
+    for name in ("generation/iteration",) + timed:
+        found = _named(spans, name)
+        assert found and all(s.attrs["cpu_ms"] >= 0.0 for s in found), name
+    for name in ("generation/claim", "generation/publish",
+                 "generation/decode_step", "generation/token_fetch",
+                 "generation/prefill", "generation/prefill_fetch"):
+        assert all("cpu_ms" not in s.attrs for s in _named(spans, name))
+    for it in _named(spans, "generation/iteration"):
+        inner = [s for s in spans if s.name in timed and s.tid == it.tid
+                 and _inside(s, it)]
+        # (each reading is rounded to a microsecond)
+        assert sum(s.attrs["cpu_ms"] for s in inner) \
+            <= it.attrs["cpu_ms"] + 1e-3 * (len(inner) + 1)
+
+
+def test_span_cpu_ms_is_written_only_by_the_thread_that_began_it():
+    import threading
+
+    pt.set_flags({"FLAGS_telemetry": True})
+    telemetry.clear_spans()
+    with telemetry.trace_span("t/cpu", cpu=True) as mine:
+        sum(range(20000))
+    assert mine.attrs["cpu_ms"] > 0.0
+    plain = telemetry.span_begin("t/plain")
+    telemetry.span_end(plain)
+    assert "cpu_ms" not in plain.attrs
+    other = telemetry.span_begin("t/elsewhere", cpu=True, detached=True)
+    t = threading.Thread(target=telemetry.span_end, args=(other,))
+    t.start()
+    t.join()
+    assert other.end is not None and "cpu_ms" not in other.attrs
+
+
+def _bench_reader(name):
+    import importlib.util
+    import sys
+
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    spec = importlib.util.spec_from_file_location(
+        "reader_" + name, os.path.join(bench, "readers", name + ".py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def test_the_phases_leave_little_of_a_pass_unnamed(engine):
+    """The benchmark's reader of an iteration's self time
+    (``iter_unnamed_ms.*``) over real passes of the CPU engine: what no
+    direct child span covers is the spans' own cost, under a tenth of a
+    2 ms pass when nothing else runs; the median pass of twelve must
+    leave under a fifth unnamed (a share of one clock's readings, and
+    the median so that a pass the machine interrupted does not count)."""
+    pt.set_flags({"FLAGS_telemetry": True})
+    telemetry.clear_spans()
+    engine.generate(PROMPT, 12, timeout=120)
+    spans = _spans_after(12)    # eleven decode steps and the last settle
+    reader = _bench_reader("iter_account")
+    table = reader.passes(reader.scheduler_spans(spans))
+    assert len(table) >= 12
+    unnamed = reader.read({"spans": spans}, what="unnamed_ms")
+    shares = sorted(reader.self_ms(it, inner) / ((it.end - it.start) * 1e3)
+                    for it, inner in table)
+    assert unnamed >= 0.0 and 0.0 <= shares[0]
+    assert shares[len(shares) // 2] < 0.2
+    # every phase of the README's table is a direct child
+    direct = {path[0] for _, inner in table for path, _ in inner}
+    assert {"generation/claim", "generation/decode_feeds",
+            "generation/decode_step", "generation/book_tokens",
+            "generation/release", "generation/publish",
+            "generation/prefill"} <= direct
+
+
+def test_stream_cpu_ms_sums_to_the_handlers_accumulator():
+    """Streamed tokens are metered by the handler threads, process-wide;
+    every pass writes what was added since the pass before it, so the
+    iterations' ``stream_cpu_ms`` and ``stream_write_ms`` sum to the
+    accumulators' growth."""
+    import json
+    import threading
+
+    pt.set_flags({"FLAGS_telemetry": True})
+    main, startup = pt.Program(), pt.Program()
+    startup._is_startup = True
+    with pt.program_guard(main, startup):
+        x = layers.data("x", [8])
+        y = layers.fc(x, 8)
+    scope = pt.Scope()
+    pt.Executor().run(startup, scope=scope)
+    from paddle_tpu.inference import Predictor
+
+    eng = ServingEngine(Predictor(main, ["x"], [y], scope=scope),
+                        workers=1, warmup_shapes={"x": (8,)})
+    gen = GenerationEngine(MODEL, num_slots=2, max_seq_len=64,
+                           attn_impl="xla", seed=0,
+                           page_tokens=8, prefix_reuse=False)
+    eng.attach_generator(gen)
+    gen.warmup()
+    srv = ServingServer(eng).start()
+
+    def stream(n):
+        body = json.dumps({"prompt": PROMPT, "max_new_tokens": n,
+                           "stream": True}).encode()
+        req = urllib.request.Request(
+            srv.url + "/generate", data=body,
+            headers={"Content-Type": "application/json"})
+        with urllib.request.urlopen(req, timeout=60) as r:
+            lines = r.read().decode().splitlines()
+        assert len(lines) == n + 1
+
+    try:
+        gen.generate(PROMPT, 1, timeout=120)    # a pass reads the totals
+        time.sleep(0.1)
+        telemetry.clear_spans()
+        write0, cpu0 = stream_meter.totals()
+        workers = [threading.Thread(target=stream, args=(9,))
+                   for _ in range(2)]
+        for w in workers:
+            w.start()
+        for w in workers:
+            w.join()
+        write1, cpu1 = stream_meter.totals()
+        # the handlers are done: one more pass picks up what the last
+        # streamed tokens added after their own pass had closed
+        done = len(_named(telemetry.get_spans(), "generation/iteration"))
+        gen.generate(PROMPT, 1, timeout=120)
+        iters = _named(_spans_after(done + 1), "generation/iteration")
+    finally:
+        srv.close()
+    assert cpu1 > cpu0 and write1 > write0      # 18 tokens were metered
+    n = len(iters)
+    assert sum(it.attrs["stream_cpu_ms"] for it in iters) \
+        == pytest.approx((cpu1 - cpu0) * 1e3, abs=1e-3 * n)
+    assert sum(it.attrs["stream_write_ms"] for it in iters) \
+        == pytest.approx((write1 - write0) * 1e3, abs=1e-3 * n)
