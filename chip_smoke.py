@@ -92,6 +92,14 @@ def paths_since(before):
             if v - before[k]}
 
 
+def pool_writes():
+    """How the ``T > 1`` ``kv_pool_write`` ops lowered: whole pages, or a
+    [Hkv, D] window a row (which re-lays the whole pool on a TPU)."""
+    from paddle_tpu.monitor import stat_get
+
+    return {k: stat_get(f"kv_pool_write_{k}") for k in ("pages", "rows")}
+
+
 def attention_grads():
     """How the attention grad ops lowered: off the forward's saved output
     and softmax statistic, or as jax.vjp of the forward lowering."""
@@ -476,7 +484,7 @@ def serve_phase(cfg=SERVE, on_chip=True):
     from paddle_tpu import promtext
     from paddle_tpu.serving import GenerationEngine, ServingEngine, serve
 
-    paths0 = attention_paths()
+    paths0, writes0 = attention_paths(), pool_writes()
     model = dict(vocab_size=cfg["vocab"], hidden=cfg["hidden"],
                  num_layers=cfg["layers"], num_heads=cfg["heads"],
                  num_kv_heads=cfg["heads"], intermediate=cfg["ffn"])
@@ -582,6 +590,16 @@ def serve_phase(cfg=SERVE, on_chip=True):
             f"valid exposition lines")
         paths = paths_since(paths0)
         say(f"serve: attention lowered as {paths}")
+        # a prefill bucket of whole pages puts its prompt into every pool
+        # page by page, and re-lays none (SERVE's buckets all are)
+        writes = {k: v - writes0[k] for k, v in pool_writes().items()}
+        pools = 2 * cfg["layers"]
+        ragged = [b for b in gen.prefill_buckets if b % cfg["page_tokens"]]
+        say(f"serve: prefill pool writes lowered as {writes}")
+        check(writes["pages"] >= pools * len(buckets - set(ragged))
+              and bool(writes["rows"]) == bool(ragged),
+              f"a prefill of whole pages did not write its pools page by "
+              f"page: {writes}")
         if on_chip:
             # the engine holds one device whatever the host has
             check(paths.get("pallas") and not paths.get("blockwise"),
@@ -599,7 +617,7 @@ def serve_phase(cfg=SERVE, on_chip=True):
              if t is not threading.main_thread()]
     check(not alive, f"threads alive after close(): {alive}")
     say("serve: closed, only the main thread alive")
-    return {"paths": paths, "setup_s": setup_s}
+    return {"paths": paths, "writes": writes, "setup_s": setup_s}
 
 
 # ---------------------------------------------------------------------------

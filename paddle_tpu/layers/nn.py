@@ -648,7 +648,7 @@ def rope(x, base=10000.0, position_offset=0, offset=None, name=None):
 
 
 def kv_pool_write(pool, new, positions, block_table, lengths,
-                  name=None, per_head=False):
+                  name=None, per_head=False, whole_pages=False):
     """Paged-cache write, in place: ``pool`` [P, Hkv, pt, D] gets row
     (b, t) of ``new`` [B, Hkv, T, D] at logical position
     ``positions[b] + t`` of slot b, routed through ``block_table``
@@ -658,16 +658,26 @@ def kv_pool_write(pool, new, positions, block_table, lengths,
     state → donated buffer (HBM reused, no copy).  ``per_head`` scatters
     each (row, head) under its own index, as the one-row step always
     does: the form for the few rows a slot of a decode grid writes (a
-    prefill chunk's many rows keep the [Hkv, D] window).  Returns the pool
-    Variable (now carrying the updated value in the lowered graph)."""
+    prefill chunk's many rows keep the [Hkv, D] window).  ``whole_pages``
+    is the whole-prompt prefill's form: one slot (B = 1), ``positions`` a
+    page boundary (the caller's word), T a whole number of pages; the
+    rows go in page by page, the pool's other bytes as the row forms
+    leave them, and on a TPU the pool is not re-laid for it.  Returns the
+    pool Variable (now carrying the updated value in the lowered graph)."""
     helper = LayerHelper("kv_pool_write", name=name)
+    # an attr only where asked for: the other programs' text stays the same
+    attrs = {}
+    if per_head:
+        attrs["per_head"] = True
+    if whole_pages:
+        attrs["whole_pages"] = True
     helper.append_op("kv_pool_write",
                      inputs={"Pool": [pool], "New": [new],
                              "Positions": [positions],
                              "BlockTable": [block_table],
                              "Lengths": [lengths]},
                      outputs={"Out": [pool]},
-                     attrs={"per_head": True} if per_head else {})
+                     attrs=attrs)
     return pool
 
 

@@ -617,12 +617,13 @@ def build_llama_prefill(batch_size, seq_len, vocab_size=32000,
             # paged: the prompt's K/V scatter across the slot's pages
             # from logical position 0; pad-tail rows (>= prompt_len)
             # go to the trash page, and so do a window layer's rows
-            # whose page the window no longer covers
+            # whose page the window no longer covers.  One slot from
+            # position 0: a bucket of whole pages goes in page by page
             for pool, t in zip(caches, (k, v)):
                 layers.kv_pool_write(
                     pool, t, zero_pos,
                     bt_window if i in windowed else block_table,
-                    prompt_len)
+                    prompt_len, whole_pages=seq_len % page_tokens == 0)
         else:
             kvs.append((i, {"k": k, "v": v}))
     if mask_block is not None:

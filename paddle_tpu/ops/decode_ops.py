@@ -11,7 +11,18 @@ per-slot block table that maps logical page index -> physical page.
   the block table names.  The output aliases the pool *variable name*,
   so the executor classifies the pool as mutated persistable state →
   donated buffer → XLA updates it in place in HBM (no pool copy per
-  token).
+  token).  One algorithm, three scatter windows, chosen from the shape of
+  the write, because XLA:TPU lays a scatter's operand out index dims
+  major, window dims minor, and copies a whole pool in and out of any
+  layout that is not the pool's own row-major one: a decode step's rows
+  (``T == 1``, or ``per_head``) go in under a ``(page, head, offset)``
+  index with the ``D`` lanes as the window; a whole-prompt prefill
+  (``whole_pages``: one slot, from a page boundary, a whole number of
+  pages) goes in page by page, the window a whole ``[Hkv, pt, D]`` page;
+  only a chunk at a runtime base position (prefill chunks, prefix-reuse
+  tails, the verify program) keeps the ``[Hkv, D]`` window a row, and
+  pays the pool's re-layout.  ``kv_pool_write_pages`` /
+  ``kv_pool_write_rows`` count which a ``T > 1`` write lowered to.
 * ``kv_pool_gather`` — reconstruct a slot's logical
   ``[B, n_kv, NP*page_tokens, D]`` cache view from its pages.
 * ``cached_attention`` — a chunk of query rows attends over that view
@@ -59,10 +70,19 @@ only.  All plain ``jax.numpy``: XLA fuses them, there is no kernel.
 """
 from __future__ import annotations
 
+from ..monitor import monitor as _monitor
 from .registry import in_var, register_op, set_out
 
 
 LANES = 128
+
+# the form a T > 1 ``kv_pool_write`` lowered to, counted at trace time
+# per pool per program build (like ``attention_lowered_*``): whole pages,
+# or one [Hkv, D] window a row (the form that re-lays the pool on a TPU)
+_WRITE_LOWERED = {
+    "pages": _monitor.get("kv_pool_write_pages"),
+    "rows": _monitor.get("kv_pool_write_rows"),
+}
 
 
 def pool_shape(num_pages, num_kv_heads, page_tokens, head_dim):
@@ -92,7 +112,13 @@ def _kv_pool_write(ctx, op):
     slots, the pad tail of a bucketed prefill chunk) are redirected to
     the reserved trash page 0 — one scatter, no branch on data.  The
     output aliases the pool variable name, so the executor donates the
-    buffer (in-place HBM update)."""
+    buffer (in-place HBM update).
+
+    Attr ``whole_pages`` (the whole-prompt prefill): the caller vouches
+    that ``positions[0]`` is a page boundary; ``B == 1`` and ``T % pt ==
+    0`` are checked.  New is then ``T / pt`` whole pages and goes in page
+    by page; every page but the trash page ends up with the bytes the
+    row forms leave there."""
     import jax.numpy as jnp
 
     pool = ctx.get_input(op, "Pool")
@@ -102,6 +128,15 @@ def _kv_pool_write(ctx, op):
     length = ctx.get_input(op, "Lengths").astype(jnp.int32)
     P, Hkv, pt, D = pool.shape
     B, _, T, _ = new.shape
+    if op.attr("whole_pages", False):
+        if B != 1 or T % pt:
+            raise ValueError(
+                f"kv_pool_write(whole_pages=True) takes one slot's whole "
+                f"pages: New {tuple(new.shape)} over pages of {pt} tokens")
+        _WRITE_LOWERED["pages"].increase()
+        ctx.set_output(op, "Out", _write_whole_pages(
+            pool, new, pos[0] // pt, bt[0], length[0]))
+        return
     t = jnp.arange(T, dtype=jnp.int32)[None, :]
     logical = pos[:, None] + t                        # [B, T]
     page_idx = jnp.clip(logical // pt, 0, bt.shape[1] - 1)
@@ -131,12 +166,43 @@ def _kv_pool_write(ctx, op):
         head = jnp.arange(Hkv, dtype=jnp.int32)[None, :]
         out = pool.at[phys[:, None], head, off[:, None], :].set(rows)
     else:
-        # a chunk of rows: one [Hkv, D] window per row.  B * T * Hkv
-        # single-lane-row updates were slower at the long prefill rungs
-        # on the chip (543 against 523 ms at 3712 tokens), faster at the
-        # short ones (70 against 85 ms at 1024): left as it was
+        # a chunk of rows from a runtime base position (prefill chunks,
+        # prefix-reuse tails, verify; no benchmark cell runs them): one
+        # [Hkv, D] window a row, and on a TPU the pool's re-layout with
+        # it.  B * T * Hkv single-lane-row updates instead were slower
+        # at the long rungs on the chip (543 against 523 ms at 3712
+        # tokens), faster at the short ones (70 against 85 ms at 1024,
+        # PERF.md PR 25)
+        _WRITE_LOWERED["rows"].increase()
         out = pool.at[phys, :, off, :].set(rows)
     ctx.set_output(op, "Out", out)
+
+
+def _write_whole_pages(pool, new, first_page, table, length):
+    """New [1, Hkv, T, D] as ``T / pt`` whole pages of Pool [P, Hkv, pt,
+    D], from logical page ``first_page`` of the slot's ``table`` [NP];
+    ``length`` rows are real.  The scatter's window is a whole page,
+    contiguous in the pool's row-major layout, so XLA:TPU updates the
+    pool where it lies.  The one page ``length`` cuts keeps the pool's
+    rows behind the cut (pages are compared, and shared, by content);
+    pages wholly behind it go to the trash page, as a table's zero
+    entries already send a window layer's uncovered pages."""
+    import jax.numpy as jnp
+
+    _, Hkv, pt, D = pool.shape
+    n = new.shape[2] // pt
+    # (the pool's own Hkv and D: a packed pool's row takes two heads)
+    pages = jnp.transpose(new, (0, 2, 1, 3)).reshape(n, pt, Hkv, D)
+    pages = jnp.transpose(pages, (0, 2, 1, 3)).astype(pool.dtype)
+    idx = jnp.clip(first_page + jnp.arange(n, dtype=jnp.int32), 0,
+                   table.shape[0] - 1)
+    phys = jnp.take(table, idx)
+    first_row = jnp.arange(n, dtype=jnp.int32) * pt
+    row = first_row[:, None] + jnp.arange(pt, dtype=jnp.int32)[None, :]
+    cut = jnp.clip(length // pt, 0, n - 1)          # the page length cuts
+    held = pool[phys[cut]]                          # [Hkv, pt, D]
+    pages = jnp.where((row < length)[:, None, :, None], pages, held[None])
+    return pool.at[jnp.where(first_row < length, phys, 0)].set(pages)
 
 
 def _kv_pool_gather_infer(op, block):

@@ -64,6 +64,9 @@ def test_chip_smoke_train_phase_toy():
 def test_chip_smoke_serve_phase_toy():
     out = chip_smoke.serve_phase(TOY_SERVE, on_chip=False)
     assert out["paths"].get("blockwise") and "pallas" not in out["paths"]
+    # the bucket of two whole pages wrote page by page, the one of half a
+    # page by rows
+    assert out["writes"]["pages"] and out["writes"]["rows"]
 
 
 def test_chip_smoke_check_raises():

@@ -17,7 +17,9 @@ and the op's two lowerings.
 * **For the chip, without one**: the kernel compiles for a described TPU
   v5e at both serving cells' shapes (skipped where no topology can be
   described; the topology is described inside a fixture of this one file,
-  because one process at a time may load the TPU's library).  The same
+  because one process at a time may load the TPU's library); a whole
+  decode step and a whole-prompt prefill compile with no copy of a pool
+  (``kv_pool_write``'s forms, ``ops/decode_ops.py``).  The same
   fixture serves the one compile of the training attention kernels under
   a four-chip mesh (``ops/attention_ops.py`` ``kernel_partition``, PR 31),
   which is here and not in ``test_attention.py`` for that reason.
@@ -383,6 +385,90 @@ def test_decode_step_for_a_described_v5e_reads_the_pools_in_place(
     pool = rf"f32\[{pages},2,{page},128\]"
     assert re.search(pool, text), "no pool in the step's text"
     assert not re.findall(pool + r"\{[^}]*\} copy\(", text)
+
+
+@pytest.mark.parametrize("head_dim", [128, 64])
+def test_prefill_for_a_described_v5e_writes_the_pools_in_place(
+        chip, monkeypatch, head_dim):
+    """The whole-prompt prefill program of a small Llama (two layers; a
+    head of 128, and a head of 64 over pools packed two heads a row),
+    compiled for the described chip: every pool goes in page by page
+    (``kv_pool_write_pages`` once a pool) and no copy of a pool-sized
+    array is in the text.  The chunk program, whose base position is a
+    feed, keeps the [Hkv, D] window a row (``kv_pool_write_rows``), and
+    XLA:TPU re-lays every pool for it, in and out: the copies the prefill
+    left behind, and the proof that the pattern sees one."""
+    import math
+    import re
+
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from paddle_tpu import compile_cache
+    from paddle_tpu.models.llama import (build_llama_prefill,
+                                         build_llama_prefill_chunk)
+    from paddle_tpu.ops.decode_ops import pool_shape
+    from paddle_tpu.parallel import build_sharded_step, dp_mesh
+    from paddle_tpu.parallel import sharded
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(compile_cache, "ensure_compile_cache", lambda: None)
+    monkeypatch.setattr(sharded, "ensure_compile_cache", lambda: None)
+    layers_, slots, max_seq, page, bucket = 2, 4, 256, 16, 128
+    pages = slots * (max_seq // page) + 1
+    model = dict(vocab_size=61, hidden=4 * head_dim, num_layers=layers_,
+                 num_heads=4, num_kv_heads=4, intermediate=128)
+    mesh = dp_mesh(1, devices=[chip])
+    rep = NamedSharding(mesh, P())
+    shape = pool_shape(pages, 4, page, head_dim)
+    pool = r"f32\[%s\]" % ",".join(map(str, shape))
+    pool_elems = math.prod(shape)
+
+    def compiled_text(build, fetch, shapes):
+        main, startup = pt.Program(), pt.Program()
+        startup._is_startup = True
+        with pt.program_guard(main, startup):
+            feeds, fetches = build()[:2]
+        fn, mut_in, const_in, _ = build_sharded_step(
+            main, feeds, [fetches[fetch].name], mesh)
+        block = main.global_block()
+        assert len(mut_in) == 2 * layers_
+        text = fn.lower(tuple(_spec(*shapes[n], rep) for n in feeds),
+                        _state(block, mut_in, rep),
+                        _state(block, const_in, rep),
+                        _spec((), "int32", rep)).compile().as_text()
+        assert re.search(pool, text), "no pool in the program's text"
+        # a copy of anything with a pool's element count: the compiler
+        # also re-lays a pool under a bitcast shape, [P * pt, Hkv, D]
+        copied = re.findall(r"= f32\[([\d,]+)\]\{[^}]*\} copy\(", text)
+        return [dims for dims in copied
+                if math.prod(map(int, dims.split(","))) == pool_elems]
+
+    def booked():
+        return [stat_get("kv_pool_write_" + k) for k in ("pages", "rows")]
+
+    table = ((1, max_seq // page), "int32")
+    before = booked()
+    copies = compiled_text(
+        lambda: build_llama_prefill(
+            1, bucket, name="pwp", cache_slots=slots, max_seq_len=max_seq,
+            num_pages=pages, page_tokens=page, **model),
+        "next_token",
+        {"input_ids": ((1, bucket), "int32"), "last_pos": ((1,), "int32"),
+         "block_table": table, "prompt_len": ((1,), "int32")})
+    assert booked() == [before[0] + 2 * layers_, before[1]]
+    assert not copies
+
+    before = booked()
+    copies = compiled_text(
+        lambda: build_llama_prefill_chunk(
+            bucket, max_seq, pages, page, name="pwc", **model),
+        "next_token",
+        {"chunk_ids": ((1, bucket), "int32"), "base": ((1,), "int32"),
+         "block_table": table, "chunk_len": ((1,), "int32"),
+         "last_off": ((1,), "int32")})
+    assert booked() == [before[0], before[1] + 2 * layers_]
+    assert len(copies) >= 2 * layers_
 
 
 def test_bert_step_for_a_described_v5e_mesh_keeps_the_pallas_kernels(

@@ -139,6 +139,139 @@ def test_kv_pool_write_gather_roundtrip():
                           got_pool[[3]].reshape(1, 4, 2))
 
 
+def _pool_write(pool0, new, base, table, n, **form):
+    """One ``kv_pool_write`` of ``new`` [B, Hkv, T, D] into a copy of
+    ``pool0`` through the executor, in the form the attrs ask for."""
+    main, startup = pt.Program(), pt.Program()
+    startup._is_startup = True
+    with pt.program_guard(main, startup):
+        pool = main.global_block().create_var(
+            name="w_pool", persistable=True, shape=list(pool0.shape),
+            dtype="float32", stop_gradient=True)
+        feeds = {"new": new, "base": base, "table": table, "n": n}
+        args = [layers.data(k, list(v.shape), dtype=str(v.dtype),
+                            append_batch_size=False)
+                for k, v in feeds.items()]
+        out = layers.kv_pool_write(pool, *args, **form)
+    scope = pt.Scope()
+    scope.set_var("w_pool", pool0.copy())
+    got, = pt.Executor().run(main, feed=feeds, fetch_list=[out],
+                             scope=scope)
+    return got
+
+
+# page 4, a rung of four pages, a slot of six: prompt lengths 1, k * pt,
+# k * pt + 1 and the whole rung
+@pytest.mark.parametrize("n", [1, 8, 9, 16])
+@pytest.mark.parametrize("table", ["permuted", "window"])
+@pytest.mark.parametrize("head_dim", [8, 64])
+def test_whole_pages_write_leaves_the_bytes_the_row_form_leaves(
+        n, table, head_dim):
+    """The whole-prompt prefill's page-by-page write against the [Hkv, D]
+    window a row: every page but the trash page byte for byte.  The
+    pool's pages that the table does not name, and the rows behind the
+    prompt's end in its last page, hold NaN and must keep it; a window
+    layer's table sends the pages its window left to the trash page; a
+    head of 64 lies packed two heads a row."""
+    from paddle_tpu.monitor import stat_get
+    from paddle_tpu.ops.decode_ops import pool_shape
+
+    rng = np.random.default_rng(100 * n + head_dim)
+    pt_, T, NP, Hkv = 4, 16, 6, 2
+    P = 2 * NP + 1
+    new = rng.normal(size=(1, Hkv, T, head_dim)).astype("float32")
+    pool0 = rng.normal(size=pool_shape(P, Hkv, pt_, head_dim))
+    pool0 = pool0.astype("float32")
+    bt = rng.permutation(np.arange(1, P))[:NP].astype("int32")[None]
+    named = set(bt[0].tolist())
+    live = -(-n // pt_)
+    if table == "window":          # the window covers the last two pages
+        bt[0, :max(0, live - 2)] = 0
+    for page in range(1, P):
+        if page not in named:
+            pool0[page] = np.nan
+    if n % pt_:
+        pool0[bt[0, live - 1], :, n % pt_:] = np.nan
+    feeds = (new, np.zeros(1, "int32"), bt, np.asarray([n], "int32"))
+
+    before = {k: stat_get("kv_pool_write_" + k) for k in ("pages", "rows")}
+    want = _pool_write(pool0, *feeds)
+    assert stat_get("kv_pool_write_rows") == before["rows"] + 1
+    got = _pool_write(pool0, *feeds, whole_pages=True)
+    assert stat_get("kv_pool_write_pages") == before["pages"] + 1
+    assert stat_get("kv_pool_write_rows") == before["rows"] + 1
+    assert got[1:].tobytes() == want[1:].tobytes()
+    # and the row form's bytes are the right ones: the prompt's rows where
+    # the table says, everything else as it was
+    touched = np.zeros(P, bool)
+    for i in range(live):
+        page, rows = bt[0, i], min(pt_, n - i * pt_)
+        if page == 0:
+            continue
+        touched[page] = True
+        fresh = new[0, :, i * pt_:i * pt_ + rows]         # [Hkv, rows, D]
+        fresh = fresh.transpose(1, 0, 2).reshape(rows, -1, pool0.shape[3])
+        assert np.array_equal(got[page, :, :rows], fresh.transpose(1, 0, 2))
+        assert np.array_equal(got[page, :, rows:], pool0[page, :, rows:],
+                              equal_nan=True)
+    assert touched.sum() == (live if table == "permuted" else min(live, 2))
+    untouched = ~touched
+    untouched[0] = False
+    assert np.array_equal(got[untouched], pool0[untouched], equal_nan=True)
+
+
+def test_whole_pages_write_from_a_later_page_boundary():
+    """``whole_pages`` takes any page boundary for its base, as the row
+    form takes any position."""
+    rng = np.random.default_rng(5)
+    pool0 = rng.normal(size=(9, 2, 4, 8)).astype("float32")
+    feeds = (rng.normal(size=(1, 2, 8, 8)).astype("float32"),
+             np.asarray([8], "int32"),
+             rng.permutation(np.arange(1, 9))[None, :6].astype("int32"),
+             np.asarray([7], "int32"))
+    want = _pool_write(pool0, *feeds)
+    got = _pool_write(pool0, *feeds, whole_pages=True)
+    assert got[1:].tobytes() == want[1:].tobytes()
+    assert not np.array_equal(got, pool0)
+
+
+@pytest.mark.parametrize("B,T", [(1, 6), (1, 3), (2, 8)])
+def test_whole_pages_write_refuses_what_is_not_one_slots_whole_pages(B, T):
+    """A rung that is not a whole number of pages, or more than one slot,
+    has no page form: the op says so when the program is lowered."""
+    pool0 = np.zeros((5, 2, 4, 8), "float32")
+    with pytest.raises(Exception, match="whole_pages"):
+        _pool_write(pool0, np.zeros((B, 2, T, 8), "float32"),
+                    np.zeros(B, "int32"), np.ones((B, 4), "int32"),
+                    np.full(B, T, "int32"), whole_pages=True)
+
+
+@pytest.mark.parametrize("bucket,whole", [(32, True), (24, False)])
+def test_the_whole_prompt_prefill_asks_for_whole_pages(bucket, whole):
+    """The builder of the whole-prompt prefill asks for the page form
+    where its bucket is whole pages, and for nothing else; the chunk
+    program, whose base position is a feed, never does."""
+    from paddle_tpu.models.llama import (build_llama_prefill,
+                                         build_llama_prefill_chunk)
+
+    def attrs(build):
+        main, startup = pt.Program(), pt.Program()
+        startup._is_startup = True
+        with pt.program_guard(main, startup):
+            build()
+        return [op.attr("whole_pages", False)
+                for op in main.global_block().ops
+                if op.type == "kv_pool_write"]
+
+    got = attrs(lambda: build_llama_prefill(
+        1, bucket, name="wp", cache_slots=2, max_seq_len=96, num_pages=13,
+        page_tokens=PAGE, **MODEL))
+    assert got == [whole] * (2 * MODEL["num_layers"])
+    got = attrs(lambda: build_llama_prefill_chunk(
+        bucket, 96, 13, PAGE, name="wp", **MODEL))
+    assert got == [False] * (2 * MODEL["num_layers"])
+
+
 def test_chunk_spans():
     assert batcher.chunk_spans(0, 20, 8) == [(0, 8), (8, 16), (16, 20)]
     assert batcher.chunk_spans(32, 40, 8) == [(32, 40)]
