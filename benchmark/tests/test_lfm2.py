@@ -23,6 +23,7 @@ HBM_BYTES = 16 * 2 ** 30
 CELL = "lfm2-24b-longanswer"
 
 from test_compile_only import as_tpu, topo  # noqa: E402,F401 (fixtures)
+from test_manifest import check_closed_loop_cell  # noqa: E402
 
 
 def _json(*parts):
@@ -165,27 +166,8 @@ def test_cell_is_declared_with_its_metrics():
     gate, = [m for m in bench["end_to_end"]
              if m["name"] == "served_tokens_per_s"]
     assert CELL in gate["workloads"] and gate["bound"] == 0.06
-    ttft, = [m for m in bench["per_layer"]
-             if m["name"] == "ttft_closed_p50_ms"]
-    assert CELL in ttft["workloads"]
-    mine = [m for m in bench["per_layer"] if m.get("workloads") == [CELL]]
-    assert sorted(m["name"] for m in mine) == sorted(
-        [n + ".lfm" for n in (
-            "decode_step_mean_ms", "prefill_mean_ms", "compiles_in_window",
-            "device_idle_pct", "hbm_peak_gb", "iter_host_ms",
-            "executor_run_host_ms", "decode_feeds_ms", "book_tokens_ms",
-            "queue_wait_p50_ms", "decode_ahead_pct", "idle_decode_host_pct",
-            "idle_prefill_host_pct", "idle_unattributed_pct",
-            "moe_experts_touched_pct", "moe_expert_load_max_over_mean",
-            "expert_matmul_share_pct", "attention_kernel_share_pct",
-            "decode_step_roofline", "prefill_roofline",
-            "paged_kernel_roofline", "state_slots_pct")])
-    for m in mine:
-        spec = _json("metrics", m["name"] + ".json")
-        assert spec["moves"] == m["moves"] == "served_tokens_per_s"
-        assert spec["layer"] == m["layer"] and spec["unit"] == m["unit"]
-        assert os.path.exists(os.path.join(BENCH, "readers",
-                                           spec["reader"] + ".py"))
+    # its own entries by name and the shared ``.pool`` entries that list it
+    assert check_closed_loop_cell(CELL) == (4, 26)
 
 
 def test_new_readers_read_spans_and_leave_out_what_is_not_there():

@@ -22,7 +22,7 @@ with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
 NEW = ("iter_host_ms", "decode_feeds_ms", "book_tokens_ms",
        "queue_wait_p50_ms", "executor_run_host_ms", "idle_decode_host_pct",
        "idle_prefill_host_pct", "idle_no_work_pct", "idle_unattributed_pct")
-SERVING = {"mistral7b-chat": "chat", "mistral7b-longprompt": "long"}
+SERVING = {"mistral7b-chat": "chat", "mistral7b-longprompt": "pool"}
 
 
 def span(name, start, end, tid=1, **attrs):
@@ -44,7 +44,7 @@ def new_metrics(cell):
 def test_both_serving_cells_list_the_new_metrics():
     assert len(new_metrics("mistral7b-chat")) == 9
     assert len(new_metrics("mistral7b-longprompt")) == 8    # no open loop
-    assert "idle_no_work_pct.long" not in new_metrics(
+    assert "idle_no_work_pct.pool" not in new_metrics(
         "mistral7b-longprompt")
 
 
@@ -85,7 +85,7 @@ def test_iter_host_is_the_iteration_less_its_device_waits():
     got = reader.read({"spans": SPANS}, **args("iter_host_ms.chat"))
     # 100 - 30 - 40 = 30 ms and 60 - 40 = 20 ms
     assert got == pytest.approx(25.0)
-    assert reader.read({"spans": []}, **args("iter_host_ms.long")) is None
+    assert reader.read({"spans": []}, **args("iter_host_ms.pool")) is None
 
 
 def test_span_means_and_the_queue_wait_median():
@@ -93,20 +93,20 @@ def test_span_means_and_the_queue_wait_median():
     ctx = {"spans": SPANS}
     assert mean.read(ctx, **args("decode_feeds_ms.chat")) \
         == pytest.approx(2.0)
-    assert mean.read(ctx, **args("book_tokens_ms.long")) \
+    assert mean.read(ctx, **args("book_tokens_ms.pool")) \
         == pytest.approx(7.0)
     q = harness.load_module("readers", "span_attr_quantile")
     assert q.read(ctx, **args("queue_wait_p50_ms.chat")) == 3.0
     # the parent commit's sequence spans carry no such attribute
     bare = [span("generation/sequence", 1.0, 2.0)]
-    assert q.read({"spans": bare}, **args("queue_wait_p50_ms.long")) is None
+    assert q.read({"spans": bare}, **args("queue_wait_p50_ms.pool")) is None
     inside = harness.load_module("readers", "span_inside_mean")
     # the executor steps under the two decode steps of tid 1 (4 and 6
     # ms); not the prefill's, not the other thread's stray one
     assert inside.read(ctx, **args("executor_run_host_ms.chat")) \
         == pytest.approx(5.0)
     assert inside.read({"spans": bare},
-                       **args("executor_run_host_ms.long")) is None
+                       **args("executor_run_host_ms.pool")) is None
 
 
 def traced_ctx(spans):
@@ -186,19 +186,19 @@ def test_idle_under_wait_work_and_under_no_span():
     # one gap, 10.010-10.040: 20 ms waiting for work, 9.5 bare iteration
     assert reader.read(ctx, **args("idle_no_work_pct.chat")) \
         == pytest.approx(100 * 20.0 / 180.0)
-    assert reader.read(ctx, **args("idle_unattributed_pct.long")) \
+    assert reader.read(ctx, **args("idle_unattributed_pct.pool")) \
         == pytest.approx(100 * 9.5 / 180.0)
     # a program that records none of the phases (the parent commit):
     # everything is unattributed, nothing raises
     ctx, _ = traced_ctx([span("serving/request", 10.0, 10.2, tid=3)])
-    assert reader.read(ctx, **args("idle_decode_host_pct.long")) == 0.0
-    assert reader.read(ctx, **args("idle_unattributed_pct.long")) \
+    assert reader.read(ctx, **args("idle_decode_host_pct.pool")) == 0.0
+    assert reader.read(ctx, **args("idle_unattributed_pct.pool")) \
         == pytest.approx(100 * 40.0 / 180.0)
 
 
 @pytest.mark.parametrize("metric", [
-    "idle_decode_host_pct.chat", "idle_prefill_host_pct.long",
-    "idle_no_work_pct.chat", "idle_unattributed_pct.long"])
+    "idle_decode_host_pct.chat", "idle_prefill_host_pct.pool",
+    "idle_no_work_pct.chat", "idle_unattributed_pct.pool"])
 def test_idle_readers_return_none_without_a_trace(metric):
     reader = harness.load_module("readers", "idle_by_span")
     assert reader.read({"trace": None, "trace_spans": SPANS},

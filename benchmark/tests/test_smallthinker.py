@@ -21,6 +21,8 @@ os.environ.setdefault("TPU_LOG_DIR", "disabled")
 HBM_BYTES = 16 * 2 ** 30
 CELL = "smallthinker21b-mixedlen"
 
+from test_manifest import check_closed_loop_cell  # noqa: E402
+
 
 def _json(*parts):
     with open(os.path.join(BENCH, *parts)) as f:
@@ -112,14 +114,8 @@ def test_cell_is_declared_with_its_metrics():
     gate, = [m for m in bench["end_to_end"]
              if m["name"] == "served_tokens_per_s"]
     assert CELL in gate["workloads"] and gate["bound"] == 0.06
-    mine = [m for m in bench["per_layer"] if m.get("workloads") == [CELL]]
-    assert len(mine) == 20
-    for m in mine:
-        spec = _json("metrics", m["name"] + ".json")
-        assert spec["moves"] == m["moves"] == "served_tokens_per_s"
-        assert spec["layer"] == m["layer"] and spec["unit"] == m["unit"]
-        assert os.path.exists(os.path.join(BENCH, "readers",
-                                           spec["reader"] + ".py"))
+    # its own entries by name and the shared ``.pool`` entries that list it
+    assert check_closed_loop_cell(CELL) == (4, 25)
 
 
 def test_rehearsal_reaches_its_last_line():
