@@ -25,7 +25,9 @@ random seeded weights:
   50304, 16 layers, 8 slots x 2048 positions, float32.
 
 * **slot state** (PR 34) — the paged-decode kernel at heads of 64 over a
-  pool packed two heads a row, and one convolution-state round trip
+  pool packed two heads a row, one convolution-state round trip and
+  (PR 41) the two gated delta-rule kernels at 30 heads of 96 x 192
+  against their XLA formulations with one delta-state round trip
   (prefill, three decode steps, the slot reused) through a two-slot engine.
 
 Any failed check raises: the exit code is non-zero and no result line is
@@ -782,6 +784,126 @@ def slot_state_phase(cfg=HYBRID):
         f"decode step on the Pallas kernel ({lowered})")
 
 
+DELTA = dict(heads=30, key_dim=96, value_dim=192, conv=4, slots=32,
+             seq=640, valid=600, hidden=3840, prompt=150, steps=3)
+
+
+def delta_state_phase(cfg=DELTA):
+    """What a decoder with gated delta-rule layers adds (PR 41), at that
+    family's published head sizes (30 heads, keys of 96, values of 192):
+    the two Pallas kernels of ``ops/pallas/gated_delta.py`` against the
+    XLA formulations of ``ops/gated_delta_ops.py`` (the chunk pass over a
+    padded prompt, the step over 32 slots of which some are dead: their
+    state and the trash row bit for bit what they were); and one
+    delta-state round trip through a two-slot ``GenerationEngine`` of one
+    delta layer and one full-attention layer at hidden 3840 (norms on the
+    outputs, QK-norm over the projection, no rotary embedding): a prefill
+    of more than two chunks, three decode steps, the slot taken again,
+    each against the uncached forward, with the lowering counters."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.monitor import stat_get
+    from paddle_tpu.ops import gated_delta_ops as gd
+    from paddle_tpu.ops.pallas import gated_delta as kern
+    from paddle_tpu.serving import GenerationEngine
+
+    key = jax.random.key(41)
+    H, Dk, Dv = cfg["heads"], cfg["key_dim"], cfg["value_dim"]
+
+    def draw(i, *shape):
+        return jax.random.normal(jax.random.fold_in(key, i), shape)
+
+    def unit(x):
+        return x / jnp.linalg.norm(x, axis=-1, keepdims=True)
+
+    T, n = cfg["seq"], cfg["slots"]
+    q, k = unit(draw(0, 1, T, H, Dk)) * Dk ** -0.5, unit(draw(1, 1, T, H, Dk))
+    v, g = draw(2, 1, T, H, Dv), -jnp.abs(draw(3, 1, T, H))
+    beta = 2 * jax.nn.sigmoid(draw(4, 1, T, H))
+    valid = jnp.asarray([cfg["valid"]], jnp.int32)
+    want_o, want_s = jax.jit(lambda *a: gd.chunked(*a, valid=valid))(
+        q, k, v, g, beta)
+    got_o, got_s = jax.jit(lambda *a: gd.chunked(
+        *a, valid=valid, carry=kern.carry_chunks))(q, k, v, g, beta)
+    rel = max(float(jnp.abs(got_o - want_o).max() / jnp.abs(want_o).max()),
+              float(jnp.abs(got_s - want_s).max() / jnp.abs(want_s).max()))
+    check(bool(jnp.isfinite(got_o).all()) and rel <= TOL,
+          f"gated_delta_chunk kernel off the scan by {rel:.4g}")
+    state = draw(5, n + 1, H, Dk, Dv)
+    live = jnp.asarray(np.arange(n) % 5 != 3, jnp.int32)
+    sq, sk = unit(draw(6, n, H, Dk)) * Dk ** -0.5, unit(draw(7, n, H, Dk))
+    sv, sg = draw(8, n, H, Dv), -jnp.abs(draw(9, n, H))
+    sb = 2 * jax.nn.sigmoid(draw(10, n, H))
+    want_o, want_s = jax.jit(gd.step)(sq, sk, sv, sg, sb, state,
+                                      live.astype(bool))
+    got_o, got_s = kern.step(sq, sk, sv, sg, sb, state, live)
+    on = np.asarray(live, bool)
+    rel_step = max(
+        float(np.abs(np.asarray(got_o - want_o))[on].max()
+              / jnp.abs(want_o).max()),
+        float(jnp.abs(got_s - want_s).max() / jnp.abs(want_s).max()))
+    check(rel_step <= TOL,
+          f"gated_delta_step kernel off the contractions by {rel_step:.4g}")
+    check(bool(jnp.array_equal(got_s[:n][~on], state[:n][~on]))
+          and bool(jnp.array_equal(got_s[n], state[n])),
+          "gated_delta_step moved a dead slot's state or the trash row")
+    say(f"delta: kernels at {H} heads of {Dk} x {Dv}: the chunk pass over "
+        f"{cfg['valid']} of {T} rows within {rel:.4g} of the scan, the step "
+        f"over {int(on.sum())} live of {n} slots within {rel_step:.4g} of "
+        f"the contractions, dead slots untouched (tolerance {TOL})")
+
+    delta = {"kind": "gated_delta", "key_heads": H, "value_heads": H,
+             "key_dim": Dk, "value_dim": Dv, "conv": cfg["conv"],
+             "neg_eigval": True}
+    model = dict(vocab_size=4096, hidden=cfg["hidden"], num_layers=2,
+                 num_heads=30, num_kv_heads=30, intermediate=4096,
+                 rms_norm_eps=1e-6, qk_norm="proj", norm="post",
+                 layer_pattern=[
+                     {"mixer": delta, "rope": False},
+                     {"mixer": "attention", "rope": False,
+                      "attn_precision": "highest"}])
+    pal0 = stat_get("gated_delta_lowered_pallas")
+    ref0 = stat_get("gated_delta_lowered_reference")
+    gen = GenerationEngine(model, num_slots=2, max_seq_len=512,
+                           prefill_buckets=[256], page_tokens=16,
+                           prefill_chunk=0, prefix_reuse=False,
+                           speculate=False, keep_logits=True, eos_id=-1)
+    try:
+        gen.warmup()
+        pallas = stat_get("gated_delta_lowered_pallas") - pal0
+        reference = stat_get("gated_delta_lowered_reference") - ref0
+        check(pallas >= 2 and reference == 0,
+              f"the delta ops lowered to {pallas} kernels and {reference} "
+              f"XLA formulations, not to kernels alone")
+        rng = np.random.default_rng(41)
+        worst = 0.0
+        for n_prompt in (cfg["prompt"], 3):   # the second reuses slot 0
+            prompt = rng.integers(1, 4096, n_prompt).tolist()
+            res = gen.generate(prompt, cfg["steps"] + 1, timeout=600)
+            check(res["slot"] == 0, f"request landed in slot {res['slot']}")
+            seq = prompt + res["tokens"]
+            want = _forward_logits(gen, model, seq, 256)[
+                n_prompt - 1:n_prompt + cfg["steps"]]
+            got = np.stack(res["logits"])
+            rel = float(np.abs(got - want).max() / np.abs(want).max())
+            worst = max(worst, rel)
+            check(np.isfinite(got).all() and rel <= TOL,
+                  f"delta-state round trip (prompt {n_prompt}) off the "
+                  f"uncached forward by {rel:.4g}")
+        counters = gen.stats()["counters"]
+        check(counters["slot_state_writes"] == 2
+              and counters["delta_state_steps"] >= 2 * cfg["steps"],
+              f"state counters {counters['slot_state_writes']} writes, "
+              f"{counters['delta_state_steps']} steps")
+    finally:
+        gen.close()
+    say(f"delta: state through a chunked prefill, {cfg['steps']} decode "
+        f"steps and a reused slot within {worst:.4g} of the uncached "
+        f"forward; gated_delta_lowered_pallas +{pallas}, "
+        f"gated_delta_lowered_reference +{reference}")
+
+
 def main():
     t_start = time.perf_counter()
     # the program first: in a directory that holds only this file the
@@ -831,6 +953,12 @@ def main():
     t0 = time.perf_counter()
     slot_state_phase()
     say(f"slot state and head-64 kernel done "
+        f"[{time.perf_counter() - t0:.1f} s]")
+    gc.collect()
+
+    t0 = time.perf_counter()
+    delta_state_phase()
+    say(f"delta-rule kernels and state done "
         f"[{time.perf_counter() - t0:.1f} s]")
 
     say(f"set-up (compile-dominated: kernel check + first train step + "

@@ -25,6 +25,7 @@ __all__ = [
     "cached_attention", "kv_pool_write", "kv_pool_gather",
     "paged_decode_attention", "block_begin", "block_unmask",
     "short_conv", "short_conv_tail", "slot_state_write", "short_conv_step",
+    "gated_delta_chunk", "gated_delta_step",
     "linear_chain_crf", "crf_decoding", "warpctc",
     "nce", "hsigmoid", "conv3d", "pool3d", "lrn", "row_conv",
     "shuffle_channel", "temporal_shift", "multiplex",
@@ -847,6 +848,42 @@ def short_conv_step(x, state, live, kernel, param_attr=None, bias_attr=None,
     out = helper.create_variable_for_type_inference(x.dtype)
     helper.append_op("short_conv_step",
                      inputs=dict(inputs, X=[x], State=[state], Live=[live]),
+                     outputs={"Out": [out], "StateOut": [state]})
+    return out
+
+
+def gated_delta_chunk(q, k, v, g, beta, state0=None, valid=None, name=None):
+    """The gated delta rule over a whole sequence (ops/gated_delta_ops.py
+    ``gated_delta_chunk``): ``q``, ``k`` [B, T, H, Dk], ``v`` [B, T, H, Dv],
+    log decay ``g`` and ``beta`` [B, T, H], optionally from ``state0``
+    [B, H, Dk, Dv] and with ``valid`` [B] real rows.  Returns ``(out
+    [B, T, H, Dv], state [B, H, Dk, Dv])``, the state after the last real
+    token."""
+    helper = LayerHelper("gated_delta_chunk", name=name)
+    out = helper.create_variable_for_type_inference(v.dtype)
+    state = helper.create_variable_for_type_inference(v.dtype)
+    inputs = {"Q": [q], "K": [k], "V": [v], "G": [g], "Beta": [beta]}
+    if state0 is not None:
+        inputs["State0"] = [state0]
+    if valid is not None:
+        inputs["Valid"] = [valid]
+    helper.append_op("gated_delta_chunk", inputs=inputs,
+                     outputs={"Out": [out], "StateOut": [state]})
+    return out, state
+
+
+def gated_delta_step(q, k, v, g, beta, state, live, name=None):
+    """One decode step of :func:`gated_delta_chunk`: one row a slot
+    (``q``, ``k`` [slots, 1, H, Dk], ``v`` [slots, 1, H, Dv], ``g``,
+    ``beta`` [slots, 1, H]) over ``state`` [slots + 1, H, Dk, Dv], which
+    moves on in place for rows with ``live`` set.  Returns the output
+    [slots, 1, H, Dv]."""
+    helper = LayerHelper("gated_delta_step", name=name)
+    out = helper.create_variable_for_type_inference(v.dtype)
+    helper.append_op("gated_delta_step",
+                     inputs={"Q": [q], "K": [k], "V": [v], "G": [g],
+                             "Beta": [beta], "State": [state],
+                             "Live": [live]},
                      outputs={"Out": [out], "StateOut": [state]})
     return out
 

@@ -60,7 +60,13 @@ from .. import layers
 #           ``[B, C, u] = split3(h W_in)``, ``y = (C * conv_L(B * u))
 #           W_out``, depthwise and causal over L taps.  Such a layer has
 #           no q, k, v, no RoPE and no pages: its cache is the last L - 1
-#           rows of ``B * u``, per slot (``cache_spec``)
+#           rows of ``B * u``, per slot (``cache_spec``).  Or a dict
+#           {"kind": "gated_delta", "key_heads": H, "value_heads": H,
+#           "key_dim": Dk, "value_dim": Dv, "conv": L, "neg_eigval":
+#           True}: gated delta-rule linear attention
+#           (:func:`_gated_delta_mixer`), whose cache is two states a
+#           slot: the last L - 1 rows that its convolution over q | k | v
+#           saw, and a matrix [H, Dk, Dv] that every token moves on
 #   attn_precision: None (the prefill attention kernel's two products at
 #           the backend's default: a TPU rounds float32 operands to
 #           bfloat16) or "highest" (operands whole, as the paged decode
@@ -76,17 +82,30 @@ def layer_spec(layer_pattern, i):
     return dict(DEFAULT_LAYER, **layer_pattern[i % len(layer_pattern)])
 
 
-def conv_layers(layer_pattern, num_layers):
-    """Indices of the layers whose mixer is a gated short convolution."""
+def state_layers(layer_pattern, num_layers):
+    """Indices of the layers that keep slot state, not pages: those whose
+    mixer is a gated short convolution or the gated delta rule."""
     return [i for i in range(num_layers)
             if layer_spec(layer_pattern, i)["mixer"] != "attention"]
+
+
+def _delta_dims(mixer):
+    """A gated-delta mixer's ``(heads, key_dim, value_dim, channels of
+    its convolution: q | k | v)``."""
+    heads = int(mixer["key_heads"])
+    if int(mixer["value_heads"]) != heads:
+        raise ValueError(
+            f"gated_delta mixer: {mixer['value_heads']} value heads over "
+            f"{heads} key heads is not built (one state a head)")
+    dk, dv = int(mixer["key_dim"]), int(mixer["value_dim"])
+    return heads, dk, dv, heads * (2 * dk + dv)
 
 
 def window_layers(layer_pattern, num_layers):
     """Indices of the attention layers whose attention is a sliding
     window."""
-    conv = conv_layers(layer_pattern, num_layers)
-    return [i for i in range(num_layers) if i not in conv
+    state = state_layers(layer_pattern, num_layers)
+    return [i for i in range(num_layers) if i not in state
             and layer_spec(layer_pattern, i)["window"] is not None]
 
 
@@ -101,9 +120,14 @@ def cache_spec(name, num_layers, layer_pattern=None, *, num_slots,
     * ``"pages"`` / ``"window_pages"``: an attention layer's K and V page
       pools (two entries, K first), ``ops/decode_ops.py`` ``pool_shape``
       of the full or the window pool's page count;
-    * ``"slot_state"``: a conv layer's last ``L_cache - 1`` gated inputs
-      per slot, ``[num_slots + 1, L_cache - 1, hidden]`` (the last row is
-      the trash row a warm-up writes)."""
+    * ``"slot_state"``: state a slot that is not pages, row ``num_slots``
+      the trash row a warm-up writes.  A conv layer has one, its last
+      ``L_cache - 1`` gated inputs: ``<name>.conv_state_<i>`` ``[num_slots
+      + 1, L_cache - 1, hidden]``.  A gated-delta layer has two: the last
+      ``conv - 1`` rows its convolution saw, ``<name>.conv_state_<i>``
+      ``[num_slots + 1, conv - 1, heads * (2 * key_dim + value_dim)]``,
+      then the delta state ``<name>.delta_state_<i>`` ``[num_slots + 1,
+      heads, key_dim, value_dim]``."""
     from ..ops.decode_ops import pool_shape
 
     windowed = window_layers(layer_pattern, num_layers)
@@ -111,10 +135,15 @@ def cache_spec(name, num_layers, layer_pattern=None, *, num_slots,
     for i in range(num_layers):
         mixer = layer_spec(layer_pattern, i)["mixer"]
         if mixer != "attention":
-            spec.append({"name": f"{name}.conv_state_{i}", "layer": i,
-                         "kind": "slot_state",
-                         "shape": [num_slots + 1, int(mixer["L_cache"]) - 1,
-                                   hidden]})
+            if mixer["kind"] == "conv":
+                shapes = {"conv_state": [int(mixer["L_cache"]) - 1, hidden]}
+            else:
+                heads, dk, dv, channels = _delta_dims(mixer)
+                shapes = {"conv_state": [int(mixer["conv"]) - 1, channels],
+                          "delta_state": [heads, dk, dv]}
+            spec += [{"name": f"{name}.{what}_{i}", "layer": i,
+                      "kind": "slot_state", "shape": [num_slots + 1] + shape}
+                     for what, shape in shapes.items()]
             continue
         kind = "window_pages" if i in windowed else "pages"
         shape = pool_shape(num_window_pages if i in windowed else num_pages,
@@ -126,7 +155,8 @@ def cache_spec(name, num_layers, layer_pattern=None, *, num_slots,
 
 def _cache_vars(block, spec, layer):
     """Layer ``layer``'s persistable cache variables, declared from its
-    entries of :func:`cache_spec` (K and V pools, or the one state)."""
+    entries of :func:`cache_spec` (K and V pools, or the layer's one or
+    two states)."""
     return tuple(block.create_var(
         name=e["name"], persistable=True, shape=e["shape"], dtype="float32",
         stop_gradient=True) for e in spec if e["layer"] == layer)
@@ -181,6 +211,27 @@ def _head_on_rows(x, rows_idx, vocab_size, name, eps, tie_head=False):
     return layers.squeeze(_head(x, vocab_size, name, tie_head), [1])
 
 
+def _conv_over_state(z, kernel, conv_w, valid, conv_state, slot, live):
+    """The causal depthwise convolution of z [B, S, C] in the three modes
+    of a mixer that keeps its last ``kernel - 1`` rows a slot: with
+    ``live`` the decode step over ``conv_state`` (moved on in place for
+    live rows); with ``valid`` a prefill whose rows before the prompt's
+    true length go to slot ``slot``'s state, or come back as ``tail``
+    where there is no variable; else the plain whole-sequence form.
+    Returns ``(c, tail)``."""
+    if live is not None:
+        return layers.short_conv_step(z, conv_state, live, kernel,
+                                      **conv_w), None
+    c = layers.short_conv(z, kernel, **conv_w)
+    tail = None
+    if valid is not None:
+        tail = layers.short_conv_tail(z, valid, kernel - 1)
+        if conv_state is not None:
+            layers.slot_state_write(conv_state, tail, slot)
+            tail = None
+    return c, tail
+
+
 def _conv_mixer(h, hidden, mixer, p, valid=None, conv_state=None,
                 slot=None, live=None):
     """The gated short-convolution mixer on normed rows h [B, S, H]:
@@ -201,18 +252,95 @@ def _conv_mixer(h, hidden, mixer, p, valid=None, conv_state=None,
                                       ends=[(j + 1) * hidden])
                          for j in range(3))
     z = layers.elementwise_mul(gate_b, u)
-    tail = None
-    if live is not None:
-        c = layers.short_conv_step(z, conv_state, live, kernel, **conv_w)
-    else:
-        c = layers.short_conv(z, kernel, **conv_w)
-        if valid is not None:
-            tail = layers.short_conv_tail(z, valid, kernel - 1)
-            if conv_state is not None:
-                layers.slot_state_write(conv_state, tail, slot)
-                tail = None
+    c, tail = _conv_over_state(z, kernel, conv_w, valid, conv_state, slot,
+                               live)
     return _linear(layers.elementwise_mul(gate_c, c), hidden,
                    pname=p("conv_out.w")), tail
+
+
+def _delta_gate_init(name, heads):
+    """``A_log`` and ``dt_bias`` [heads] as the family's modelling code
+    draws them: A uniform in (0, 16), ``A_log = log A``; dt log-uniform in
+    [0.001, 0.1], ``dt_bias = dt + log(-expm1(-dt))`` (softplus's
+    inverse).  Drawn from the layer's name, so every program of a model
+    gives the same constants (a benchmark redraws them from its seed)."""
+    import zlib
+
+    import numpy as np
+
+    rng = np.random.default_rng(zlib.crc32((name or "").encode()))
+    a = rng.uniform(1e-3, 16.0, heads)
+    dt = np.exp(rng.uniform(np.log(1e-3), np.log(0.1), heads))
+    return (np.log(a).astype("float32"),
+            (dt + np.log(-np.expm1(-dt))).astype("float32"))
+
+
+def _gated_delta_mixer(h, seq_len, hidden, mixer, p, eps, valid=None,
+                       states=None, slot=None, live=None):
+    """Gated delta-rule linear attention on rows h [B, S, H]:
+    ``q | k | v = silu(conv(h W_qkv))`` (one causal depthwise
+    convolution over all their channels), q and k L2-normalised a head
+    and q scaled by ``key_dim ** -0.5``, ``beta = sigmoid(h W_b)`` (twice
+    that with ``neg_eigval``), ``g = -exp(A_log) * softplus(h W_a +
+    dt_bias)``, the delta rule (``ops/gated_delta_ops.py``), then ``y =
+    (rmsnorm(o) * silu(h W_gate)) W_out`` with the norm over a head's
+    ``value_dim``.  ``states`` is the layer's ``(conv_state,
+    delta_state)`` pair, and the three modes are :func:`_conv_mixer`'s:
+    with ``live`` the decode step (both states moved on in place for
+    live rows); with ``slot`` a prefill that leaves both states as they
+    stand after the prompt's TRUE last token in that slot's rows; else
+    the whole sequence.  Returns ``(y, tail, state)``: what a prefill
+    with ``valid`` and no variables to write to leaves the caller to
+    fetch, else None."""
+    from ..framework.initializer import NumpyArrayInitializer
+
+    heads, dk, dv, channels = _delta_dims(mixer)
+    kernel = int(mixer["conv"])
+    conv_state, delta_state = states if states else (None, None)
+    qkv = _linear(h, channels, pname=p("gdn_qkv.w"))
+    c, tail = _conv_over_state(qkv, kernel, {"param_attr": p("gdn_conv.w")},
+                               valid, conv_state, slot, live)
+    c = layers.silu(c)
+
+    def part(lo, n, d):
+        t = layers.slice(c, axes=[2], starts=[lo], ends=[lo + n * d])
+        return layers.reshape(t, [0, seq_len, n, d])
+
+    q = layers.scale(layers.l2_normalize(part(0, heads, dk), axis=-1,
+                                         epsilon=1e-6), scale=dk ** -0.5)
+    k = layers.l2_normalize(part(heads * dk, heads, dk), axis=-1,
+                            epsilon=1e-6)
+    v = part(2 * heads * dk, heads, dv)
+    ab = _linear(h, 2 * heads, pname=p("gdn_ab.w"))
+    a = layers.slice(ab, axes=[2], starts=[0], ends=[heads])
+    b = layers.slice(ab, axes=[2], starts=[heads], ends=[2 * heads])
+    beta = layers.sigmoid(b)
+    if mixer.get("neg_eigval"):
+        beta = layers.scale(beta, scale=2.0)
+    a_log, dt_bias = (layers.create_parameter(
+        [heads], "float32", name=p(what),
+        default_initializer=NumpyArrayInitializer(init))
+        for what, init in zip(("gdn_A_log", "gdn_dt_bias"),
+                              _delta_gate_init(p("gdn"), heads)))
+    g = layers.scale(layers.elementwise_mul(
+        layers.softplus(layers.elementwise_add(a, dt_bias)),
+        layers.exp(a_log)), scale=-1.0)
+    state = None
+    if live is not None:
+        o = layers.gated_delta_step(q, k, v, g, beta, delta_state, live)
+    else:
+        # (a slot's state is all it has: a prefill starts from none)
+        o, last = layers.gated_delta_chunk(q, k, v, g, beta, valid=valid)
+        if delta_state is not None:
+            layers.slot_state_write(delta_state, last, slot)
+        elif valid is not None:
+            state = last
+    o = layers.rms_norm(o, epsilon=eps, param_attr=p("gdn_norm"))
+    gate = layers.reshape(_linear(h, heads * dv, pname=p("gdn_gate.w")),
+                          [0, seq_len, heads, dv])
+    o = layers.reshape(layers.elementwise_mul(o, layers.silu(gate)),
+                       [0, seq_len, heads * dv])
+    return _linear(o, hidden, pname=p("gdn_out.w")), tail, state
 
 
 def llama_block(x, hidden, num_heads, num_kv_heads, seq_len, head_dim,
@@ -221,7 +349,7 @@ def llama_block(x, hidden, num_heads, num_kv_heads, seq_len, head_dim,
                 block_table=None, kv_lengths=None, rms_norm_eps=1e-6,
                 rope_base=10000.0, layer=None, valid=None, taps=None,
                 qk_norm=False, mask_block=None, block=False,
-                conv_state=None, slot=None, live=None):
+                conv_state=None, slot=None, live=None, norm="pre"):
     """One decoder layer. x: [B, S, H].
 
     A layer whose ``mixer`` is a gated short convolution
@@ -229,10 +357,18 @@ def llama_block(x, hidden, num_heads, num_kv_heads, seq_len, head_dim,
     run attention: ``conv_state`` is its per-slot state variable, with
     ``live`` [B] in the decode step and ``slot`` [1] (and ``valid``, the
     prompt's length) in a prefill; with ``collect_kv`` it returns ``(x,
-    tail, None)``, the state rows where no variable took them.
+    tail, None)``, the state rows where no variable took them.  A
+    gated-delta layer (:func:`_gated_delta_mixer`) takes its two state
+    variables as the pair ``conv_state`` and returns ``(x, tail, state)``.
 
     ``qk_norm``: q and k are RMS-normalised over ``head_dim`` with a
-    learned weight each (``.q_norm`` / ``.k_norm``) before RoPE.
+    learned weight each (``.q_norm`` / ``.k_norm``) before RoPE; with
+    ``qk_norm="proj"`` over the whole projection (all heads' ``num_heads
+    * head_dim`` at once, weights of that length) before the split into
+    heads.  ``norm``: "pre" (the norms on the mixer's and the FFN's
+    input) or "post" (on their OUTPUT, none on their input: ``x = x +
+    norm(mixer(x)); x = x + norm(ffn(x))``, weights ``.ln1`` / ``.ln2``
+    still).
     ``mask_block`` (uncached and ``collect_kv`` modes): the attention
     mask is block-causal, row i admits column j iff ``j // mask_block
     <= i // mask_block`` (block diffusion's prefill).  ``block`` (with
@@ -277,13 +413,27 @@ def llama_block(x, hidden, num_heads, num_kv_heads, seq_len, head_dim,
     kv_size = num_kv_heads * head_dim
     p = (lambda s: f"{name}.{s}") if name else (lambda s: None)
     x_in = x          # routed experts read the raw layer input
-    h = layers.rms_norm(x, epsilon=rms_norm_eps, param_attr=p("ln1"))
+    post = _norm_is_post(norm)
+
+    def normed(t, pname):
+        return layers.rms_norm(t, epsilon=rms_norm_eps, param_attr=p(pname))
+
+    h = x if post else normed(x, "ln1")
     if layer["mixer"] != "attention":
-        y, tail = _conv_mixer(h, hidden, layer["mixer"], p, valid=valid,
-                              conv_state=conv_state, slot=slot, live=live)
+        state = None
+        if layer["mixer"]["kind"] == "conv":
+            y, tail = _conv_mixer(h, hidden, layer["mixer"], p, valid=valid,
+                                  conv_state=conv_state, slot=slot,
+                                  live=live)
+        else:
+            y, tail, state = _gated_delta_mixer(
+                h, seq_len, hidden, layer["mixer"], p, rms_norm_eps,
+                valid=valid, states=conv_state, slot=slot, live=live)
+        if post:
+            y = normed(y, "ln1")
         out = _ffn(layers.elementwise_add(x, y), x_in, hidden, intermediate,
-                   layer["ffn"], p, rms_norm_eps, valid, taps)
-        return (out, tail, None) if collect_kv else out
+                   layer["ffn"], p, rms_norm_eps, valid, taps, post)
+        return (out, tail, state) if collect_kv else out
     qkv = _linear(h, q_size + 2 * kv_size, pname=p("qkv.w"))
     q = layers.slice(qkv, axes=[2], starts=[0], ends=[q_size])
     k = layers.slice(qkv, axes=[2], starts=[q_size],
@@ -295,9 +445,11 @@ def llama_block(x, hidden, num_heads, num_kv_heads, seq_len, head_dim,
         t = layers.reshape(t, [0, seq_len, n, head_dim])
         return layers.transpose(t, [0, 2, 1, 3])  # [B,n,S,D]
 
+    if qk_norm == "proj":
+        q, k = normed(q, "q_norm"), normed(k, "k_norm")
     q, k, v = heads(q, num_heads), heads(k, num_kv_heads), \
         heads(v, num_kv_heads)
-    if qk_norm:
+    if qk_norm and qk_norm != "proj":
         q = layers.rms_norm(q, epsilon=rms_norm_eps, param_attr=p("q_norm"))
         k = layers.rms_norm(k, epsilon=rms_norm_eps, param_attr=p("k_norm"))
     if layer["rope"]:
@@ -358,20 +510,29 @@ def llama_block(x, hidden, num_heads, num_kv_heads, seq_len, head_dim,
                                       impl=attn_impl, **win)
     attn = layers.transpose(attn, [0, 2, 1, 3])
     attn = layers.reshape(attn, [0, seq_len, q_size])
-    x = layers.elementwise_add(x, _linear(attn, hidden,
-                                          pname=p("attn_out.w")))
+    y = _linear(attn, hidden, pname=p("attn_out.w"))
+    x = layers.elementwise_add(x, normed(y, "ln1") if post else y)
     out = _ffn(x, x_in, hidden, intermediate, layer["ffn"], p, rms_norm_eps,
-               valid, taps)
+               valid, taps, post)
     if collect_kv:
         return out, new_k, new_v
     return out
 
 
-def _ffn(x, x_in, hidden, intermediate, ffn, p, rms_norm_eps, valid, taps):
+def _norm_is_post(norm):
+    if norm not in ("pre", "post"):
+        raise ValueError(f"norm is 'pre' or 'post', got {norm!r}")
+    return norm == "post"
+
+
+def _ffn(x, x_in, hidden, intermediate, ffn, p, rms_norm_eps, valid, taps,
+         post=False):
     """The layer's second half on the post-mixer stream x: norm, dense
     SwiGLU or routed experts (``x_in``: the layer's raw input, which some
-    routers read), residual."""
-    h = layers.rms_norm(x, epsilon=rms_norm_eps, param_attr=p("ln2"))
+    routers read), residual.  ``post``: the norm is on the FFN's output,
+    not its input."""
+    h = x if post else layers.rms_norm(x, epsilon=rms_norm_eps,
+                                       param_attr=p("ln2"))
     if ffn == "dense":
         gate_up = _linear(h, 2 * intermediate, pname=p("gate_up.w"))
         gate = layers.slice(gate_up, axes=[2], starts=[0],
@@ -392,6 +553,8 @@ def _ffn(x, x_in, hidden, intermediate, ffn, p, rms_norm_eps, valid, taps):
         taps.setdefault("counts", []).append(counts)
         if logits is not None:
             taps.setdefault("logits", []).append(logits)
+    if post:
+        y = layers.rms_norm(y, epsilon=rms_norm_eps, param_attr=p("ln2"))
     return layers.elementwise_add(x, y)
 
 
@@ -399,13 +562,13 @@ def llama(input_ids, vocab_size=32000, hidden=4096, num_layers=32,
           num_heads=32, num_kv_heads=None, intermediate=11008,
           seq_len=2048, name=None, attn_impl="auto", head_dim=None,
           rms_norm_eps=1e-6, rope_base=10000.0, layer_pattern=None,
-          qk_norm=False, mask_block=None, tie_head=False):
+          qk_norm=False, mask_block=None, tie_head=False, norm="pre"):
     """Returns logits [B, S, V]. input_ids: [B, S] int64.
 
     ``head_dim`` defaults to ``hidden // num_heads`` (a model may
     publish another: q is then ``num_heads * head_dim`` wide);
     ``layer_pattern`` is described at :data:`DEFAULT_LAYER`, ``qk_norm``
-    and ``mask_block`` at :func:`llama_block`; ``tie_head`` makes the
+    ``norm`` and ``mask_block`` at :func:`llama_block`; ``tie_head`` makes the
     head's product read the embedding table (needs ``name``).  The
     defaults build exactly the program they always did."""
     num_kv_heads = num_kv_heads or num_heads
@@ -420,7 +583,7 @@ def llama(input_ids, vocab_size=32000, hidden=4096, num_layers=32,
                         attn_impl=attn_impl, rms_norm_eps=rms_norm_eps,
                         rope_base=rope_base,
                         layer=layer_spec(layer_pattern, i),
-                        qk_norm=qk_norm, mask_block=mask_block)
+                        qk_norm=qk_norm, mask_block=mask_block, norm=norm)
     x = layers.rms_norm(x, epsilon=rms_norm_eps, param_attr=p("ln_f"))
     return _head(x, vocab_size, name, tie_head)
 
@@ -473,17 +636,21 @@ def build_llama_prefill(batch_size, seq_len, vocab_size=32000,
                         head_dim=None, rms_norm_eps=1e-6,
                         rope_base=10000.0, layer_pattern=None,
                         num_window_pages=None, keep_router_logits=False,
-                        qk_norm=False, mask_block=None, tie_head=False):
+                        qk_norm=False, mask_block=None, tie_head=False,
+                        norm="pre"):
     """Prefill entry point: one causal forward over the (padded) prompt
     that populates a decode cache in one shot.
 
-    A model with gated short-convolution layers (``mixer`` of
-    :data:`DEFAULT_LAYER`) takes one more feed in the paged mode, ``slot``
-    [1] int32: each such layer writes the rows its convolution leaves
-    behind at the prompt's TRUE last positions (``prompt_len``, not the
-    bucket's) as the whole of that slot's state (``cache_spec``;
-    ``slot`` = ``cache_slots`` is the trash row).  In the other mode the
-    rows come back as fetches ``state_<i>`` [B, L - 1, H].
+    A model with layers that keep slot state (gated short convolutions,
+    the gated delta rule: ``mixer`` of :data:`DEFAULT_LAYER`) takes one
+    more feed in the paged mode, ``slot`` [1] int32: each such layer
+    writes what it leaves behind at the prompt's TRUE last positions
+    (``prompt_len``, not the bucket's: the rows its convolution saw and,
+    for the delta rule, its matrix state after the last real token) as
+    the whole of that slot's state (``cache_spec``; ``slot`` =
+    ``cache_slots`` is the trash row).  In the other mode they come back
+    as fetches ``state_<i>`` [B, L - 1, C] and ``delta_state_<i>``
+    [B, heads, Dk, Dv].
 
     ``mask_block=B`` (block diffusion; the paged mode only): the forward
     runs under the block-causal mask and only commits K/V — the engine
@@ -547,11 +714,11 @@ def build_llama_prefill(batch_size, seq_len, vocab_size=32000,
                          "commits K/V: it needs the paged cache")
     block_table = bt_window = prompt_len = zero_pos = slot = None
     windowed = window_layers(layer_pattern, num_layers)
-    has_conv = bool(conv_layers(layer_pattern, num_layers))
+    has_state = bool(state_layers(layer_pattern, num_layers))
     spec = []
-    if mask_block is not None and has_conv:
-        raise ValueError("a block-causal prefill over convolution layers "
-                         "is not built: their state is causal")
+    if mask_block is not None and has_state:
+        raise ValueError("a block-causal prefill over layers that keep "
+                         "slot state is not built: their state is causal")
     if cache_slots is not None:
         if batch_size != 1:
             raise ValueError("in-graph cache insert prefills one "
@@ -576,7 +743,7 @@ def build_llama_prefill(batch_size, seq_len, vocab_size=32000,
                                     dtype="int32",
                                     append_batch_size=False)
             feeds.append("block_table_window")
-        if has_conv:
+        if has_state:
             slot = layers.data("slot", [1], dtype="int32",
                                append_batch_size=False)
             feeds.append("slot")
@@ -590,18 +757,18 @@ def build_llama_prefill(batch_size, seq_len, vocab_size=32000,
                          param_attr=f"{name}.embed")
     kvs = []
     taps = {"keep_logits": keep_router_logits}
-    # the expert and the convolution layers tell real rows from the pad
-    # tail: the paged path feeds their number, the others have it as
-    # last_pos + 1
+    # the expert layers and those that keep slot state tell real rows
+    # from the pad tail: the paged path feeds their number, the others
+    # have it as last_pos + 1
     valid = prompt_len
-    if valid is None and (has_conv
+    if valid is None and (has_state
                           or expert_layers(layer_pattern, num_layers)):
         valid = layers.cast(last_pos + 1, "int32")
     block = default_main_program().global_block()
     for i in range(num_layers):
         caches = _cache_vars(block, spec, i)
         lspec = layer_spec(layer_pattern, i)
-        state = {"conv_state": caches[0], "slot": slot} \
+        state = {"conv_state": _layer_state(lspec, caches), "slot": slot} \
             if caches and lspec["mixer"] != "attention" else {}
         x, k, v = llama_block(x, hidden, num_heads, num_kv_heads,
                               seq_len, head_dim, intermediate,
@@ -609,10 +776,11 @@ def build_llama_prefill(batch_size, seq_len, vocab_size=32000,
                               collect_kv=True, rms_norm_eps=rms_norm_eps,
                               rope_base=rope_base, layer=lspec,
                               valid=valid, taps=taps, qk_norm=qk_norm,
-                              mask_block=mask_block, **state)
+                              mask_block=mask_block, norm=norm, **state)
         if lspec["mixer"] != "attention":
             if not caches:
-                kvs.append((i, {"state": k}))
+                kvs.append((i, {"state": k} if v is None
+                            else {"state": k, "delta_state": v}))
         elif block_table is not None:
             # paged: the prompt's K/V scatter across the slot's pages
             # from logical position 0; pad-tail rows (>= prompt_len)
@@ -634,6 +802,12 @@ def build_llama_prefill(batch_size, seq_len, vocab_size=32000,
     logits = _head_on_rows(x, last_pos, vocab_size, name, rms_norm_eps,
                            tie_head)
     return feeds, _prefill_fetches(logits, kvs, taps, last_pos)
+
+
+def _layer_state(lspec, caches):
+    """What ``llama_block`` takes as ``conv_state``: a conv layer's one
+    state variable, a gated-delta layer's pair."""
+    return caches[0] if lspec["mixer"]["kind"] == "conv" else caches
 
 
 def _prefill_fetches(logits, kvs, taps, last_pos):
@@ -661,13 +835,15 @@ def build_llama_decode(num_slots, max_seq_len, vocab_size=32000,
                        rope_base=10000.0, layer_pattern=None,
                        num_window_pages=None, keep_router_logits=False,
                        qk_norm=False, block=None, mask_id=None,
-                       tie_head=False):
+                       tie_head=False, norm="pre"):
     """Cached decode step over a fixed slot grid.
 
-    A gated short-convolution layer (``mixer`` of :data:`DEFAULT_LAYER`)
-    has no pools: its state ``<name>.conv_state_<i>`` [slots + 1, L - 1,
-    H] (``cache_spec``) is read and moved on by one row, in place, for
-    the rows ``live`` marks; a dead row's state stays as it was.  The
+    A layer that keeps slot state (``mixer`` of :data:`DEFAULT_LAYER`)
+    has no pools: a gated short convolution's ``<name>.conv_state_<i>``
+    [slots + 1, L - 1, H] (``cache_spec``) is read and moved on by one
+    row, a gated-delta layer's ``<name>.delta_state_<i>`` [slots + 1,
+    heads, Dk, Dv] by one token (its convolution's rows too), in place,
+    for the rows ``live`` marks; a dead row's state stays as it was.  The
     engine finds those variables through ``cache_spec``; the
     ``cache_names`` returned here are the page pools alone.
 
@@ -734,10 +910,10 @@ def build_llama_decode(num_slots, max_seq_len, vocab_size=32000,
     masked = quota = None
     n_rows = live          # real rows a slot: the K/V written, the count
     if block:
-        if conv_layers(layer_pattern, num_layers):
-            raise ValueError("a block of rows a slot over convolution "
-                             "layers is not built: their state moves on "
-                             "one row a step")
+        if state_layers(layer_pattern, num_layers):
+            raise ValueError("a block of rows a slot over layers that "
+                             "keep slot state is not built: their state "
+                             "moves on one row a step")
         if windowed:
             raise ValueError("a block of rows a slot shares its columns; "
                              "sliding-window layers give each row its own")
@@ -773,7 +949,8 @@ def build_llama_decode(num_slots, max_seq_len, vocab_size=32000,
         caches = _cache_vars(gblock, spec, i)
         lspec = layer_spec(layer_pattern, i)
         if lspec["mixer"] != "attention":
-            cache = {"conv_state": caches[0], "live": live}
+            cache = {"conv_state": _layer_state(lspec, caches),
+                     "live": live}
         else:
             cache = {"kv_cache": caches, "positions": positions,
                      "block_table": bt_window if i in windowed
@@ -782,7 +959,8 @@ def build_llama_decode(num_slots, max_seq_len, vocab_size=32000,
                         head_dim, intermediate, name=f"{name}.blk{i}",
                         rms_norm_eps=rms_norm_eps, rope_base=rope_base,
                         layer=lspec, valid=n_rows, taps=taps,
-                        qk_norm=qk_norm, block=bool(block), **cache)
+                        qk_norm=qk_norm, block=bool(block), norm=norm,
+                        **cache)
     x = layers.rms_norm(x, epsilon=rms_norm_eps,
                         param_attr=f"{name}.ln_f")
     logits = _head(x, vocab_size, name, tie_head)            # [slots,1,V]
@@ -806,7 +984,7 @@ def _chunk_forward(chunk_len, max_seq_len, num_pages, page_tokens,
                    vocab_size, hidden, num_layers, num_heads, num_kv_heads,
                    intermediate, name, head_dim=None, rms_norm_eps=1e-6,
                    rope_base=10000.0, layer_pattern=None, qk_norm=False,
-                   tie_head=False):
+                   tie_head=False, norm="pre"):
     """The forward that the chunk and the verify programs share: C new
     tokens at ``base`` attend the slot's pages plus themselves causally.
     Returns ``(feed_names, x [1, C, H] before the final norm,
@@ -820,11 +998,11 @@ def _chunk_forward(chunk_len, max_seq_len, num_pages, page_tokens,
             "prefill continuation (chunked prefill, prefix reuse, "
             "speculative verify) is not built for a model with "
             "sliding-window layers: their pages live in a second pool")
-    if conv_layers(layer_pattern, num_layers):
+    if state_layers(layer_pattern, num_layers):
         raise ValueError(
             "prefill continuation (chunked prefill, prefix reuse, "
-            "speculative verify) is not built for a model with "
-            "convolution layers: a chunk would have to start from, and a "
+            "speculative verify) is not built for a model whose layers "
+            "keep slot state: a chunk would have to start from, and a "
             "rejected draft roll back, state that is not pages")
     num_kv_heads = num_kv_heads or num_heads
     head_dim = head_dim or hidden // num_heads
@@ -856,7 +1034,7 @@ def _chunk_forward(chunk_len, max_seq_len, num_pages, page_tokens,
                         block_table=block_table, kv_lengths=ck_len,
                         rms_norm_eps=rms_norm_eps, rope_base=rope_base,
                         layer=layer_spec(layer_pattern, i), valid=ck_len,
-                        taps=taps, qk_norm=qk_norm)
+                        taps=taps, qk_norm=qk_norm, norm=norm)
     return ["chunk_ids", "base", "block_table", "chunk_len"], x, \
         cache_names, taps
 

@@ -142,10 +142,12 @@ each fails only the then-active requests),
 (block diffusion: slot-passes that decided positions / that only
 committed a block's K/V), ``serving_block_tokens_committed``,
 ``serving_slot_state_writes`` (prefills that wrote a slot's state: a
-model with convolution layers keeps, beside the pages, a per-slot state
-variable a layer that the prefill program overwrites whole and the
-decode program advances on the device); gauges
-``serving_slot_state_bytes`` (what those variables take),
+model whose layers keep slot state, a convolution's last rows or the
+delta rule's matrix, has beside the pages one or two per-slot state
+variables a layer that the prefill program overwrites whole and the
+decode program advances on the device), ``serving_delta_state_steps``
+(slot-layers whose delta state a decode step moved on); gauges
+``serving_slot_state_bytes`` (what those variables take, every kind),
 ``serving_spec_acceptance_rate``,
 ``serving_slot_occupancy``,
 ``serving_kv_cache_bytes`` (allocated cache capacity: the page pools),
@@ -172,6 +174,7 @@ import numpy as np
 from .. import blackbox, costmodel, fault, telemetry
 from ..flags import flag_value
 from ..monitor import stat_add
+from ..ops.gated_delta_ops import CHUNK as DELTA_CHUNK
 from . import batcher
 from . import usage
 from .engine import (OverloadedError, PoisonedInput, RequestFailed,
@@ -570,10 +573,13 @@ class GenerationEngine:
     are booked and streamed together at the commit.  Such an engine
     refuses ``prefix_reuse``, ``prefill_chunk``, ``speculate`` and the
     disaggregated roles, which walk one token a step.
-    A ``layer_pattern`` with gated short-convolution layers (``mixer``,
-    ``models/llama.py``) gives those layers no pages but a state of
-    ``L_cache - 1`` rows a slot (``cache_spec``: the engine allocates
-    every layer's cache from that description).  The prefill program
+    A ``layer_pattern`` with layers that keep slot state (``mixer``,
+    ``models/llama.py``) gives those layers no pages: a gated short
+    convolution keeps ``L_cache - 1`` rows a slot, a gated-delta layer
+    the rows of its convolution and a matrix ``[heads, Dk, Dv]`` a slot
+    (``cache_spec``: the engine allocates every layer's cache, however
+    many entries and of whatever shape, from that description).  The
+    prefill program
     writes the whole of its slot's state from the prompt's true last
     positions, so a reused slot needs no reset; the decode program moves
     the state of the rows that ride a step on by one, on the device, and
@@ -602,7 +608,8 @@ class GenerationEngine:
         import paddle_tpu as pt
         from ..compile_cache import ensure_compile_cache
         from ..models.llama import (build_llama_prefill, cache_spec,
-                                    conv_layers, layer_spec, window_layers)
+                                    layer_spec, state_layers,
+                                    window_layers)
 
         ensure_compile_cache()
         self.model = dict(model)
@@ -664,9 +671,14 @@ class GenerationEngine:
         n_layers = self.model["num_layers"]
         specs = [layer_spec(self.model.get("layer_pattern"), i)
                  for i in range(n_layers)]
-        # layers whose mixer is a convolution hold state, not pages
+        # layers that keep slot state (a convolution's rows, the delta
+        # rule's matrix), not pages
         pattern = self.model.get("layer_pattern")
-        self._conv_layers = conv_layers(pattern, n_layers)
+        self._state_layers = state_layers(pattern, n_layers)
+        # ... those of them whose state is the delta rule's matrix, which
+        # a prefill scans the prompt for in chunks
+        self._delta_layers = [i for i in self._state_layers
+                              if specs[i]["mixer"]["kind"] == "gated_delta"]
         self._window_layers = window_layers(pattern, n_layers)
         widths = {specs[i]["window"] for i in self._window_layers}
         if len(widths) > 1:
@@ -776,7 +788,7 @@ class GenerationEngine:
                     f"{', '.join(refused)}: prefix reuse, chunked "
                     f"prefill, speculation and segment adoption / export "
                     f"walk one token a step and a causal prefix")
-        if self._conv_layers:
+        if self._state_layers:
             # state that is not pages: what starts from, hands over or
             # rolls back a sequence's cache knows pages only (PERF.md
             # section 7)
@@ -789,13 +801,15 @@ class GenerationEngine:
                 ("block_diffusion", bool(self._blk))) if on]
             if refused:
                 raise ValueError(
-                    f"a model with convolution layers keeps per-slot "
-                    f"state that is not pages and does not support "
+                    f"a model whose layers keep slot state (a "
+                    f"convolution's last rows, the delta rule's matrix) "
+                    f"has per-slot state that is not pages and does not "
+                    f"support "
                     f"{', '.join(refused)}: a shared prefix or a chunk "
                     f"would have to start from a state nobody kept, a "
                     f"rejected draft would have to roll it back, a "
                     f"segment carries pages only, and the state moves on "
-                    f"one row a step, not a block")
+                    f"one token a step, not a block")
         if self._wpool is not None:
             # two page kinds: what walks ONE block table per slot is no
             # part of this engine yet (PERF.md section 7)
@@ -875,7 +889,7 @@ class GenerationEngine:
                    "window_pages_released": 0, "moe_tokens_routed": 0,
                    "moe_tokens_dropped": 0, "block_passes_denoise": 0,
                    "block_passes_commit": 0, "block_tokens_committed": 0,
-                   "slot_state_writes": 0}
+                   "slot_state_writes": 0, "delta_state_steps": 0}
         self._n_lock = threading.Lock()
         # per-bucket manifest-flops cache for usage attribution: the
         # executor cache walk is paid once per bucket, not per dispatch
@@ -2421,6 +2435,15 @@ class GenerationEngine:
                     # state: whatever the slot's last sequence left goes
                     feed["slot"] = np.asarray([slot.idx], "int32")
                     state = {"state_written": 1}
+                    if self._delta_layers:
+                        # what a delta layer's scan covered: the prompt's
+                        # tokens, the rung's chunks, and those of them
+                        # wholly behind the prompt's end
+                        chunks = -(-bucket // DELTA_CHUNK)
+                        state.update(
+                            scan_tokens=n_rows, scan_chunks=chunks,
+                            scan_pad_chunks=chunks
+                            - -(-n_rows // DELTA_CHUNK))
             outs = self._launch(
                 "generation/prefill", lambda: self._run_fetching(
                     self._prefill_exe, prog, fetches, feed),
@@ -2955,6 +2978,10 @@ class GenerationEngine:
                 state_slots=len(fl.riders),
                 live_positions=int(sum(s.position + 1
                                        for s, r in fl.riders if s.req is r)))
+            if self._delta_layers:
+                moved = len(fl.riders) * len(self._delta_layers)
+                self._count("delta_state_steps", moved)
+                stat_add("serving_delta_state_steps", moved)
         if self._blk:
             # what this pass was, slot by slot: the booked state is the
             # state it was dispatched from

@@ -1,0 +1,150 @@
+"""``BENCHMARK.json`` against the files under ``benchmark/`` among the
+tier-1 tests (PR 41; PR 38 left it to "a later PR of another kind"): the
+pure-JSON checks of ``benchmark/tests/test_manifest.py`` are collected
+here as they stand (entries against data files, every entry's
+``workloads``, each cell's count of values), and the cell PR 41 added is
+pinned beside them: its own entries, the shared ``.pool`` entries that
+list it, its configuration's cut and its mix.  No JAX is imported and no
+engine started.
+"""
+import importlib.util
+import json
+import os
+
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+BENCH = os.path.join(REPO, "benchmark")
+
+_spec = importlib.util.spec_from_file_location(
+    "benchmark_tests_manifest",
+    os.path.join(BENCH, "tests", "test_manifest.py"))
+manifest = importlib.util.module_from_spec(_spec)
+_spec.loader.exec_module(manifest)
+
+# the benchmark's own checks, collected here under their own names
+globals().update({name: fn for name, fn in vars(manifest).items()
+                  if name.startswith("test_")})
+
+CELL = "olmo-hybrid7b-longdoc"
+OWN = ["decode_step_roofline.olmo", "prefill_roofline.olmo",
+       "paged_kernel_roofline.olmo", "gdn_step_roofline.olmo",
+       "gdn_chunk_roofline.olmo", "gdn_kernel_share_pct.olmo",
+       "state_slots_pct.olmo", "scan_pad_pct.olmo"]
+
+
+def _json(*parts):
+    with open(os.path.join(BENCH, *parts)) as f:
+        return json.load(f)
+
+
+def test_the_benchmark_has_six_configurations_and_eight_cells():
+    spec = manifest.SPEC
+    assert [c["name"] for c in spec["configs"]] == [
+        "bert-base-mlm", "mistral-7b-v0.1", "smallthinker-21b-a3b",
+        "sdar-30b-a3b-chat", "lfm2-24b-a2b", "olmo-hybrid-7b"]
+    assert manifest.CELLS == [
+        "bert-base-seq512", "mistral7b-chat", "mistral7b-longprompt",
+        "bert-base-seq512-dp4", "smallthinker21b-mixedlen",
+        "sdar30b-blockgen", "lfm2-24b-longanswer", CELL]
+    assert [w["name"] for w in spec["workloads"] if w["chips"] == 4] \
+        == ["bert-base-seq512-dp4"]
+    used = {w["config"] for w in spec["workloads"]}
+    assert used == {c["name"] for c in spec["configs"]}
+    for c in spec["configs"]:
+        assert len(c["why"]) <= 200 and len(c["source"]) <= 200, c["name"]
+        assert os.path.exists(os.path.join(REPO, c["file"]))
+        assert os.path.exists(os.path.join(
+            BENCH, "reference", c["name"] + ".py"))
+    for w in spec["workloads"]:
+        mix = _json("traffic", w["traffic"] + ".json")
+        assert os.path.exists(os.path.join(BENCH, mix["driver"] + ".py"))
+        assert len(w["why"]) <= 200
+
+
+def test_the_new_cell_reports_its_own_and_the_dense_decoders_shared_entries():
+    own, shared = manifest.reported_by(CELL)
+    assert sorted(own) == sorted(OWN)
+    assert sorted(shared) == sorted(manifest.POOL)
+    # a dense decoder: none of the experts' families
+    assert not set(shared) & set(manifest.POOL_EXPERTS + [manifest.TOUCHED])
+    by_name = {m["name"]: m for m in manifest.PER_LAYER}
+    for name in own + shared:
+        assert by_name[name]["moves"] == "served_tokens_per_s"
+        assert by_name[name]["workloads"][-1] == CELL
+    gate, = [m for m in manifest.SPEC["end_to_end"]
+             if m["name"] == "served_tokens_per_s"]
+    assert gate["workloads"][-1] == CELL and gate["bound"] == 0.06
+    assert len(manifest.PER_LAYER) == 79 + len(OWN)
+
+
+@pytest.mark.parametrize("name,reader,reads", [
+    ("decode_step_roofline.olmo", "roofline_span",
+     ["live_positions", "state_slots"]),
+    ("paged_kernel_roofline.olmo", "roofline_kernel",
+     "^%?paged_decode_attention"),
+    ("gdn_step_roofline.olmo", "roofline_kernel", "^%?gated_delta_step"),
+    ("gdn_chunk_roofline.olmo", "roofline_kernel_prefill",
+     "^%?gated_delta_chunk"),
+    ("gdn_kernel_share_pct.olmo", "trace_op_share", "^%?gated_delta"),
+    ("state_slots_pct.olmo", "span_attr_mean", "state_slots"),
+    ("scan_pad_pct.olmo", "span_attr_ratio", "scan_pad_chunks"),
+    ("prefill_roofline.olmo", "roofline", "prefill"),
+])
+def test_each_new_metric_reads_a_span_a_counter_or_a_named_kernel(
+        name, reader, reads):
+    """What a new per-layer metric reads is in the program: the span
+    attribute by its name in ``serving/generation.py``, the kernel by the
+    ``name=`` of its ``pallas_call``."""
+    spec = _json("metrics", name + ".json")
+    assert spec["reader"] == reader
+    args = spec["args"]
+    assert reads in (args.get("attrs"), args.get("pattern"),
+                     args.get("attr"), args.get("num"), args.get("per"))
+    with open(os.path.join(REPO, "paddle_tpu", "serving",
+                           "generation.py")) as f:
+        engine = f.read()
+    with open(os.path.join(REPO, "paddle_tpu", "ops", "pallas",
+                           "gated_delta.py")) as f:
+        kernels = f.read()
+    for attr in ("state_slots", "live_positions", "scan_tokens",
+                 "scan_chunks", "scan_pad_chunks"):
+        assert attr + "=" in engine
+    assert 'name="gated_delta_step"' in kernels
+    assert 'name="gated_delta_chunk"' in kernels
+
+
+def test_the_new_configuration_cuts_depth_and_nothing_else():
+    cfg = _json("configs", "olmo-hybrid-7b.json")
+    entry, = [c for c in manifest.SPEC["configs"]
+              if c["name"] == "olmo-hybrid-7b"]
+    assert entry["reduced"] == cfg["reduced"] \
+        == ["num_hidden_layers", "layer_types"]
+    assert entry["source"] == cfg["source"]
+    assert (cfg["hidden_size"], cfg["num_attention_heads"],
+            cfg["intermediate_size"], cfg["vocab_size"],
+            cfg["linear_key_head_dim"], cfg["linear_value_head_dim"],
+            cfg["linear_num_value_heads"], cfg["linear_conv_kernel_dim"]) \
+        == (3840, 30, 11008, 100352, 96, 192, 30, 4)
+    assert cfg["num_hidden_layers"] == 4
+    assert cfg["layer_types"] == cfg["published"]["layer_types"][:4]
+    assert cfg["published"]["num_hidden_layers"] == 32
+    for key in ("assumed", "as_run", "deployment", "check_tolerance",
+                "rehearse", "builder"):
+        assert key in cfg
+    assert len(cfg["check_tolerance"]["why"]) > 200
+
+
+def test_the_new_mix_is_closed_loop_over_whole_chunks_and_pages():
+    mix = _json("traffic", "longdoc-pool.json")
+    e = mix["engine"]
+    assert (mix["driver"], mix["loop"], mix["workers_per_slot"],
+            mix["block"]) == ("serve_delta", "closed", 2, 16)
+    assert all(b % 64 == 0 and b % e["page_tokens"] == 0
+               for b in e["prefill_buckets"])
+    assert mix["prompt_len"]["max"] + mix["output_len"]["max"] \
+        <= e["max_seq_len"]
+    assert not (e["prefill_chunk"] or e["prefix_reuse"] or e["speculate"])
+    rungs = sorted(e["prefill_buckets"])
+    assert [min(b for b in rungs if b >= n)
+            for n in mix["reference_prompts"]] == [512, 2048, 6144]

@@ -1146,6 +1146,12 @@ SKIP = {
        "the benchmark reference's full forward)" for op in [
            "short_conv", "short_conv_tail", "slot_state_write",
            "short_conv_step"]},
+    **{op: "tests/test_gated_delta.py (the recurrence token by token in "
+       "float64: whole and broken chunks, an initial state, NaN behind "
+       "valid, live rows only and the trash row; the Pallas kernels in "
+       "interpret mode; inside the engine against the benchmark "
+       "reference's full forward)" for op in [
+           "gated_delta_chunk", "gated_delta_step"]},
     "moe_routed_ffn":
         "tests/test_window_moe.py (routing, dropless counts and the "
         "grouped matmul vs a plain float64 loop; the op inside the "

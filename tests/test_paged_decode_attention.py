@@ -545,3 +545,40 @@ def test_bert_step_for_a_described_v5e_mesh_keeps_the_pallas_kernels(
     total = (m.argument_size_in_bytes + m.output_size_in_bytes
              + m.temp_size_in_bytes - m.alias_size_in_bytes)
     assert total < 15.75 * 2 ** 30, total
+
+
+def test_gated_delta_kernels_compile_for_a_described_v5e(chip):
+    """The delta rule's two kernels at the published head sizes (30 heads,
+    keys of 96, values of 192): the step over 32 slots with its state
+    aliased in place, the chunk pass over a 6144 rung.  Both hold one
+    Mosaic call; the step's state is no temporary."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from paddle_tpu.ops import gated_delta_ops as gd
+    from paddle_tpu.ops.pallas import gated_delta as kern
+
+    one_chip = SingleDeviceSharding(chip)
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    n, H, Dk, Dv, T = 32, 30, 96, 192, 6144
+    state = sds((n + 1, H, Dk, Dv))
+    step = jax.jit(kern.step, donate_argnums=5).lower(
+        sds((n, H, Dk)), sds((n, H, Dk)), sds((n, H, Dv)), sds((n, H)),
+        sds((n, H)), state, sds((n,), jnp.int32)).compile()
+    assert step.as_text().count("tpu_custom_call") == 1
+    state_bytes = (n + 1) * H * Dk * Dv * 4
+    assert step.memory_analysis().temp_size_in_bytes < state_bytes // 4
+
+    def prefill(q, k, v, g, beta, valid):
+        return gd.chunked(q, k, v, g, beta, valid=valid,
+                          carry=kern.carry_chunks)
+
+    chunk = jax.jit(prefill).lower(
+        sds((1, T, H, Dk)), sds((1, T, H, Dk)), sds((1, T, H, Dv)),
+        sds((1, T, H)), sds((1, T, H)), sds((1,), jnp.int32)).compile()
+    assert chunk.as_text().count("tpu_custom_call") == 1
+    assert chunk.memory_analysis().temp_size_in_bytes < 1.5e9

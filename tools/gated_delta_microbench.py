@@ -1,0 +1,137 @@
+#!/usr/bin/env python
+"""The gated delta rule's kernels against their XLA formulations, on the
+chip.
+
+    chiprun -- python tools/gated_delta_microbench.py
+
+Times, at a hybrid decoder's published head sizes (30 heads, keys of 96,
+values of 192, float32): the decode step over 28 slots, a hundred steps
+inside one jitted loop that carries the state (one call of a kernel this
+short is mostly the host's dispatch), as the XLA formulation (three
+contractions) and as the Pallas kernel at 1, 10 and 30 heads a block (a
+loop over one 62 MB state reads above the HBM peak on a v5e: read the
+lines against each other, not against 819 GB/s); and the prefill's scan over a rung of 2048 and of 6144
+rows, as ``chunk_terms`` + ``lax.scan`` and as ``chunk_terms`` + the
+Pallas chunk pass, with the pass alone beside them.  Prints one line per
+formulation: milliseconds, and the share of 819 GB/s that the bytes the
+mathematics must move make of it (the state read once and written once;
+q, k, v, decay, beta read and the outputs written).  Writes
+``chiprun_out/gated_delta_microbench.json``.  Refuses to run without a
+TPU backend.
+"""
+from __future__ import annotations
+
+import json
+import os
+import sys
+import time
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+
+H, DK, DV, SLOTS = 30, 96, 192, 28
+HBM = 819e9
+
+
+def timed(fn, *args, reps=20):
+    import jax
+
+    jax.block_until_ready(fn(*args))
+    t0 = time.perf_counter()
+    for _ in range(reps):
+        out = fn(*args)
+    jax.block_until_ready(out)
+    return (time.perf_counter() - t0) / reps * 1e3
+
+
+def main() -> int:
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops import gated_delta_ops as gd
+    from paddle_tpu.ops.pallas import gated_delta as kern
+
+    if jax.default_backend() != "tpu":
+        print("gated_delta_microbench: no TPU backend", file=sys.stderr)
+        return 2
+    key = jax.random.key(41)
+
+    def draw(i, *shape):
+        return jax.random.normal(jax.random.fold_in(key, i), shape)
+
+    def unit(x):
+        return x / jnp.linalg.norm(x, axis=-1, keepdims=True)
+
+    rows = []
+
+    def say(what, ms, nbytes):
+        rows.append({"what": what, "ms": ms,
+                     "roofline_pct": 100 * nbytes / HBM / (ms / 1e3)})
+        print(f"{what}: {ms:.4f} ms, {rows[-1]['roofline_pct']:.1f}% of "
+              f"819 GB/s for {nbytes / 1e6:.1f} MB", flush=True)
+
+    n = SLOTS
+    state = draw(0, n + 1, H, DK, DV)
+    q, k = unit(draw(1, n, H, DK)) * DK ** -0.5, unit(draw(2, n, H, DK))
+    v, g = draw(3, n, H, DV), -jnp.abs(draw(4, n, H))
+    beta = 2 * jax.nn.sigmoid(draw(5, n, H))
+    live = jnp.ones((n,), jnp.int32)
+    step_bytes = 2 * n * H * DK * DV * 4
+    loops = 100
+
+    def looped(one):
+        """``loops`` steps in one program, the state carried: a step's
+        device time without the dispatch of a call."""
+        def run(q, k, v, g, beta, state):
+            def body(_, carry):
+                state, acc = carry
+                out, state = one(q, k, v, g, beta, state)
+                return state, acc + out[0, 0, 0]
+            return jax.lax.fori_loop(0, loops, body, (state, 0.0))
+        return jax.jit(run)
+
+    xla = looped(lambda *a: gd.step(*a, live.astype(bool)))
+    say("step, XLA contractions",
+        timed(xla, q, k, v, g, beta, state, reps=3) / loops, step_bytes)
+    for hb in (1, 10, 30):
+        what = f"step, Pallas, {hb} heads a block"
+        try:
+            fn = looped(lambda *a, hb=hb: kern.step(*a, live,
+                                                    heads_block=hb))
+            say(what, timed(fn, q, k, v, g, beta, state, reps=3) / loops,
+                step_bytes)
+        except Exception as e:  # noqa: BLE001 — a block the chip refuses
+            print(f"{what}: refused: {str(e)[:300]}", flush=True)
+    for T in (2048, 6144):
+        q, k = unit(draw(6, 1, T, H, DK)) * DK ** -0.5, \
+            unit(draw(7, 1, T, H, DK))
+        v, g = draw(8, 1, T, H, DV), -jnp.abs(draw(9, 1, T, H))
+        beta = 2 * jax.nn.sigmoid(draw(10, 1, T, H))
+        valid = jnp.asarray([T - 100], jnp.int32)
+        nbytes = 4 * (H * (2 * DK + 2 * DV + 2) * T + H * DK * DV)
+        scan = jax.jit(lambda *a: gd.chunked(*a, valid=valid))
+        both = jax.jit(lambda *a: gd.chunked(*a, valid=valid,
+                                             carry=kern.carry_chunks))
+        say(f"chunk {T}, terms + lax.scan", timed(scan, q, k, v, g, beta,
+                                                  reps=5), nbytes)
+        say(f"chunk {T}, terms + Pallas pass", timed(both, q, k, v, g, beta,
+                                                     reps=5), nbytes)
+        N = T // gd.CHUNK
+        lay = [jnp.moveaxis(x.reshape((1, N, gd.CHUNK) + x.shape[2:]), 3, 1)
+               for x in (q, k, v, g, beta)]
+        terms = jax.jit(gd.chunk_terms)(*lay)
+        s0 = jnp.zeros((1, H, DK, DV), jnp.float32)
+        say(f"chunk {T}, the Pallas pass alone",
+            timed(kern.carry_chunks, terms, s0, reps=5), nbytes)
+        say(f"chunk {T}, lax.scan alone",
+            timed(jax.jit(gd.scan_chunks), terms, s0, reps=5), nbytes)
+        say(f"chunk {T}, the terms alone (XLA)",
+            timed(jax.jit(gd.chunk_terms), *lay, reps=5), nbytes)
+    os.makedirs("chiprun_out", exist_ok=True)
+    with open("chiprun_out/gated_delta_microbench.json", "w") as f:
+        json.dump(rows, f, indent=1)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())
