@@ -118,6 +118,12 @@ def dropout_draws():
             for p in ("hw_bits", "threefry")}
 
 
+def overlap_builds():
+    from paddle_tpu.monitor import stat_get
+
+    return {p: stat_get(f"sharded_step_overlap_{p}") for p in ("on", "off")}
+
+
 def check_dropout_shards(devices, seq, hidden):
     """One dropout site over ones, through ``build_sharded_step`` on a
     ``dp`` mesh of ``devices``: the shards' keep masks must differ (the
@@ -287,6 +293,7 @@ def train_phase(cfg=TRAIN, on_chip=True):
     B, S = cfg["global_batch"], cfg["seq"]
     check(B % n == 0, f"global batch {B} does not split over {n} devices")
     draws0 = dropout_draws()
+    overlap0 = overlap_builds()
 
     t_phase = t0 = time.perf_counter()
     worst = check_packed_kernels(B // n, S, cfg["hidden"], cfg["heads"],
@@ -380,6 +387,26 @@ def train_phase(cfg=TRAIN, on_chip=True):
     say(f"train: {n} device(s), per-device batch shard {shard}, Mosaic "
         f"custom calls in the compiled step: {mosaic}, attention lowered "
         f"as {paths}, its grad ops as {grads}")
+
+    # across chips the weight matrices' gradients are reduced one by one,
+    # asynchronously, under the weight-gradient matmuls that follow them
+    # (parallel/sharded.py overlap_compiler_options); one chip's step is
+    # compiled without the options
+    overlap = {k: v - overlap0[k] for k, v in overlap_builds().items()}
+    if on_chip:
+        from tools.collective_schedule import collectives
+
+        hidden_under = [r for r in collectives(executable.as_text())
+                        if r["op"] == "all-reduce" and r["pair"]]
+        check(overlap == ({"on": 1, "off": 0} if n > 1
+                          else {"on": 0, "off": 1})
+              and (len(hidden_under) >= 3 * cfg["layers"]) == (n > 1),
+              f"on {n} device(s) the step was built with the overlap "
+              f"options {overlap} and holds {len(hidden_under)} "
+              f"asynchronous all-reduces")
+        say(f"train: step built with the collective overlap options "
+            f"{overlap}; {len(hidden_under)} asynchronous all-reduces in "
+            f"the compiled step")
 
     # the dropout sites' mask bits come from XLA's bit generator: a draw a
     # site, of the shard's shape (ops/nn_ops.py _mask_route)

@@ -22,6 +22,12 @@ _spec = importlib.util.spec_from_file_location(
 manifest = importlib.util.module_from_spec(_spec)
 _spec.loader.exec_module(manifest)
 
+# PR 42 added one entry to the dp4 cell (`collective_exposed_all_pct.train`,
+# the exposed share with XLA:TPU's asynchronous collective fusions counted);
+# the benchmark's own table of counts is a `benchmark` PR's to edit, so the
+# count the collected check holds the cell to is raised here
+manifest.REPORTS["bert-base-seq512-dp4"] += 1
+
 # the benchmark's own checks, collected here under their own names
 globals().update({name: fn for name, fn in vars(manifest).items()
                   if name.startswith("test_")})
@@ -75,7 +81,8 @@ def test_the_new_cell_reports_its_own_and_the_dense_decoders_shared_entries():
     gate, = [m for m in manifest.SPEC["end_to_end"]
              if m["name"] == "served_tokens_per_s"]
     assert gate["workloads"][-1] == CELL and gate["bound"] == 0.06
-    assert len(manifest.PER_LAYER) == 79 + len(OWN)
+    # ... plus the dp4 cell's one entry of PR 42, after them
+    assert len(manifest.PER_LAYER) == 79 + len(OWN) + 1
 
 
 @pytest.mark.parametrize("name,reader,reads", [
@@ -148,3 +155,48 @@ def test_the_new_mix_is_closed_loop_over_whole_chunks_and_pages():
     rungs = sorted(e["prefill_buckets"])
     assert [min(b for b in rungs if b >= n)
             for n in mix["reference_prompts"]] == [512, 2048, 6144]
+
+
+# -- PR 42: the exposed share of collectives, asynchronous ones counted -----
+
+def _reader(name):
+    spec = importlib.util.spec_from_file_location(
+        "benchmark_reader_" + name,
+        os.path.join(BENCH, "readers", name + ".py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def test_the_dp4_cell_reports_both_exposed_shares():
+    by_name = {m["name"]: m for m in manifest.PER_LAYER}
+    old = by_name["collective_exposed_pct.train"]
+    new = manifest.PER_LAYER[-1]
+    assert new["name"] == "collective_exposed_all_pct.train"
+    for key in ("unit", "better", "source", "layer", "moves", "workloads"):
+        assert new[key] == old[key], key
+    assert _json("metrics", new["name"] + ".json")["reader"] \
+        == "collective_exposed_all"
+
+
+@pytest.mark.parametrize("ops,exposed_s,want", [
+    # the parent's trace: synchronous all-reduces only, nothing to add
+    ({"all-reduce.159": 0.0523, "fusion.12": 0.9}, 0.1009, None),
+    # the change's: the start / done fusions run alone on the core
+    ({"all-reduce.671": 0.0196, "async-collective-start.5": 0.002,
+      "async-collective-done.5": 0.0036, "fusion.2960": 0.9}, 0.0228,
+     100.0 * (0.0228 + 0.0056) / 1.4467),
+    # a compute fusion that carries a step of a collective is compute
+    ({"fusion.7": 0.5, "async-collective-done": 0.001}, 0.0,
+     100.0 * 0.001 / 1.4467),
+])
+def test_the_exposed_share_counts_asynchronous_fusions(ops, exposed_s, want):
+    read = _reader("collective_exposed_all").read
+    trace = {"window_s": 1.4467, "collective_s": exposed_s,
+             "collective_exposed_s": exposed_s, "op_seconds": ops}
+    got = read({"trace": trace})
+    assert got is None if want is None else got == pytest.approx(want)
+    assert read({"trace": None}) is None
+    # the accepted reader sees the synchronous part alone
+    assert _reader("collective_exposed").read({"trace": trace}) == (
+        pytest.approx(100.0 * exposed_s / 1.4467) if exposed_s else None)
