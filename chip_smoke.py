@@ -30,6 +30,10 @@ random seeded weights:
   against their XLA formulations with one delta-state round trip
   (prefill, three decode steps, the slot reused) through a two-slot engine.
 
+* **an expert share** (PR 43) — the same two kernels with a log decay a
+  key channel at 64 heads of 128 x 128, and the held experts' part of a
+  layer (20 of a 320-wide router's experts) against a plain loop.
+
 Any failed check raises: the exit code is non-zero and no result line is
 printed.  Without a TPU backend the script refuses to run (exit 2).  The
 last line of stdout is one JSON object
@@ -815,28 +819,22 @@ DELTA = dict(heads=30, key_dim=96, value_dim=192, conv=4, slots=32,
              seq=640, valid=600, hidden=3840, prompt=150, steps=3)
 
 
-def delta_state_phase(cfg=DELTA):
-    """What a decoder with gated delta-rule layers adds (PR 41), at that
-    family's published head sizes (30 heads, keys of 96, values of 192):
-    the two Pallas kernels of ``ops/pallas/gated_delta.py`` against the
-    XLA formulations of ``ops/gated_delta_ops.py`` (the chunk pass over a
-    padded prompt, the step over 32 slots of which some are dead: their
-    state and the trash row bit for bit what they were); and one
-    delta-state round trip through a two-slot ``GenerationEngine`` of one
-    delta layer and one full-attention layer at hidden 3840 (norms on the
-    outputs, QK-norm over the projection, no rotary embedding): a prefill
-    of more than two chunks, three decode steps, the slot taken again,
-    each against the uncached forward, with the lowering counters."""
+def _delta_kernels_against_xla(tag, seed, H, Dk, Dv, T, n_valid, n,
+                               channel):
+    """The two Pallas kernels of ``ops/pallas/gated_delta.py`` against the
+    XLA formulations of ``ops/gated_delta_ops.py``: the chunk pass over a
+    padded prompt (``n_valid`` of ``T`` rows real) and the step over ``n``
+    slots of which some are dead (their state and the trash row bit for
+    bit what they were).  ``channel``: the log decay a vector a key
+    channel, not a number a head."""
     import jax
     import jax.numpy as jnp
 
-    from paddle_tpu.monitor import stat_get
     from paddle_tpu.ops import gated_delta_ops as gd
     from paddle_tpu.ops.pallas import gated_delta as kern
-    from paddle_tpu.serving import GenerationEngine
 
-    key = jax.random.key(41)
-    H, Dk, Dv = cfg["heads"], cfg["key_dim"], cfg["value_dim"]
+    key = jax.random.key(seed)
+    gdim = (Dk,) if channel else ()
 
     def draw(i, *shape):
         return jax.random.normal(jax.random.fold_in(key, i), shape)
@@ -844,11 +842,10 @@ def delta_state_phase(cfg=DELTA):
     def unit(x):
         return x / jnp.linalg.norm(x, axis=-1, keepdims=True)
 
-    T, n = cfg["seq"], cfg["slots"]
     q, k = unit(draw(0, 1, T, H, Dk)) * Dk ** -0.5, unit(draw(1, 1, T, H, Dk))
-    v, g = draw(2, 1, T, H, Dv), -jnp.abs(draw(3, 1, T, H))
+    v, g = draw(2, 1, T, H, Dv), -jnp.abs(draw(3, 1, T, H, *gdim))
     beta = 2 * jax.nn.sigmoid(draw(4, 1, T, H))
-    valid = jnp.asarray([cfg["valid"]], jnp.int32)
+    valid = jnp.asarray([n_valid], jnp.int32)
     want_o, want_s = jax.jit(lambda *a: gd.chunked(*a, valid=valid))(
         q, k, v, g, beta)
     got_o, got_s = jax.jit(lambda *a: gd.chunked(
@@ -860,7 +857,7 @@ def delta_state_phase(cfg=DELTA):
     state = draw(5, n + 1, H, Dk, Dv)
     live = jnp.asarray(np.arange(n) % 5 != 3, jnp.int32)
     sq, sk = unit(draw(6, n, H, Dk)) * Dk ** -0.5, unit(draw(7, n, H, Dk))
-    sv, sg = draw(8, n, H, Dv), -jnp.abs(draw(9, n, H))
+    sv, sg = draw(8, n, H, Dv), -jnp.abs(draw(9, n, H, *gdim))
     sb = 2 * jax.nn.sigmoid(draw(10, n, H))
     want_o, want_s = jax.jit(gd.step)(sq, sk, sv, sg, sb, state,
                                       live.astype(bool))
@@ -875,10 +872,31 @@ def delta_state_phase(cfg=DELTA):
     check(bool(jnp.array_equal(got_s[:n][~on], state[:n][~on]))
           and bool(jnp.array_equal(got_s[n], state[n])),
           "gated_delta_step moved a dead slot's state or the trash row")
-    say(f"delta: kernels at {H} heads of {Dk} x {Dv}: the chunk pass over "
-        f"{cfg['valid']} of {T} rows within {rel:.4g} of the scan, the step "
+    say(f"{tag}: kernels at {H} heads of {Dk} x {Dv}"
+        f"{', a decay a key channel' if channel else ''}: the chunk pass "
+        f"over {n_valid} of {T} rows within {rel:.4g} of the scan, the step "
         f"over {int(on.sum())} live of {n} slots within {rel_step:.4g} of "
         f"the contractions, dead slots untouched (tolerance {TOL})")
+
+
+def delta_state_phase(cfg=DELTA):
+    """What a decoder with gated delta-rule layers adds (PR 41), at that
+    family's published head sizes (30 heads, keys of 96, values of 192):
+    the two Pallas kernels of ``ops/pallas/gated_delta.py`` against the
+    XLA formulations of ``ops/gated_delta_ops.py`` (the chunk pass over a
+    padded prompt, the step over 32 slots of which some are dead: their
+    state and the trash row bit for bit what they were); and one
+    delta-state round trip through a two-slot ``GenerationEngine`` of one
+    delta layer and one full-attention layer at hidden 3840 (norms on the
+    outputs, QK-norm over the projection, no rotary embedding): a prefill
+    of more than two chunks, three decode steps, the slot taken again,
+    each against the uncached forward, with the lowering counters."""
+    from paddle_tpu.monitor import stat_get
+    from paddle_tpu.serving import GenerationEngine
+
+    H, Dk, Dv = cfg["heads"], cfg["key_dim"], cfg["value_dim"]
+    _delta_kernels_against_xla("delta", 41, H, Dk, Dv, cfg["seq"],
+                               cfg["valid"], cfg["slots"], channel=False)
 
     delta = {"kind": "gated_delta", "key_heads": H, "value_heads": H,
              "key_dim": Dk, "value_dim": Dv, "conv": cfg["conv"],
@@ -929,6 +947,80 @@ def delta_state_phase(cfg=DELTA):
         f"steps and a reused slot within {worst:.4g} of the uncached "
         f"forward; gated_delta_lowered_pallas +{pallas}, "
         f"gated_delta_lowered_reference +{reference}")
+
+
+SHARE = dict(heads=64, head_dim=128, slots=64, seq=640, valid=600,
+             hidden=4096, width=1280, router=320, held=(40, 20), top_k=8,
+             rows=(64, 1000))
+
+
+def share_and_channel_phase(cfg=SHARE):
+    """What one chip's share of an expert-parallel group with KDA layers
+    adds (PR 43), at that family's published sizes: the two delta-rule
+    kernels with a log decay a key channel (64 heads of 128 x 128: the
+    chunk pass over a padded prompt with blocks of 16 inside a chunk, the
+    step over 64 slots of which some are dead) against their XLA
+    formulations; and the held experts' part of a layer (20 of a 320-wide
+    router's experts of width 1280 on hidden 4096, 8 a token, sigmoid
+    scores with a bias) at a decode step's rows and at a prefill's,
+    against a plain loop over the held experts, with its counts."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.parallel.moe import moe_routed_tokens, route_top_k
+
+    H, D = cfg["heads"], cfg["head_dim"]
+    _delta_kernels_against_xla("share", 43, H, D, D, cfg["seq"],
+                               cfg["valid"], cfg["slots"], channel=True)
+    key = jax.random.key(43)
+
+    def draw(i, *shape):
+        return jax.random.normal(jax.random.fold_in(key, i), shape)
+
+    hid, width, E, k_top = cfg["hidden"], cfg["width"], cfg["router"], \
+        cfg["top_k"]
+    first, held = cfg["held"]
+    router = draw(11, hid, E) * hid ** -0.5
+    bias = 0.02 * draw(12, E)
+    gate_up = draw(13, held, hid, 2 * width) * hid ** -0.5
+    down = draw(14, held, width, hid) * width ** -0.5
+    hi = jax.lax.Precision.HIGHEST
+
+    # (the weights are arguments: closed over, 1.3 GB of them would be
+    # constants of each compiled program)
+    @jax.jit
+    def plain(x, router, bias, gate_up, down):
+        _, experts, weights = route_top_k(x, router, k_top, "sigmoid", bias)
+        dense = jnp.zeros((x.shape[0], E)).at[
+            jnp.arange(x.shape[0])[:, None], experts].set(weights)
+
+        def one(e, acc):
+            h = jnp.dot(x, gate_up[e], precision=hi)
+            y = jnp.dot(jax.nn.silu(h[:, :width]) * h[:, width:], down[e],
+                        precision=hi)
+            return acc + dense[:, first + e, None] * y
+
+        return jax.lax.fori_loop(0, held, one, jnp.zeros_like(x)), experts
+
+    share = jax.jit(lambda x, router, bias, gate_up, down: moe_routed_tokens(
+        x, x, router, gate_up, down, top_k=k_top, activation="silu",
+        precision=hi, score="sigmoid", expert_bias=bias, held_first=first))
+    for rows in cfg["rows"]:
+        x = draw(20 + rows, rows, hid)
+        want, experts = plain(x, router, bias, gate_up, down)
+        out, counts, _ = share(x, router, bias, gate_up, down)
+        here = int(((experts >= first) & (experts < first + held)).sum())
+        rel = float(jnp.abs(out - want).max() / jnp.abs(want).max())
+        check(bool(jnp.isfinite(out).all()) and rel <= TOL,
+              f"the held experts' part of {rows} rows off the plain loop "
+              f"by {rel:.4g}")
+        check(int(counts.sum()) == rows * k_top
+              and int(counts[first:first + held].sum()) == here,
+              f"counts {int(counts.sum())} routed, "
+              f"{int(counts[first:first + held].sum())} held, want "
+              f"{rows * k_top} and {here}")
+        say(f"share: {held} of {E} experts held, {rows} rows x {k_top}: "
+            f"{here} held pairs within {rel:.4g} of the plain loop")
 
 
 def main():
@@ -986,6 +1078,12 @@ def main():
     t0 = time.perf_counter()
     delta_state_phase()
     say(f"delta-rule kernels and state done "
+        f"[{time.perf_counter() - t0:.1f} s]")
+    gc.collect()
+
+    t0 = time.perf_counter()
+    share_and_channel_phase()
+    say(f"held experts and per-channel delta kernels done "
         f"[{time.perf_counter() - t0:.1f} s]")
 
     say(f"set-up (compile-dominated: kernel check + first train step + "

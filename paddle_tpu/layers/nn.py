@@ -1270,7 +1270,8 @@ def py_func(func, x, out, backward_func=None,
 def moe_routed_ffn(x, router_x, num_experts, top_k, d_ff,
                    activation="relu", valid=None, name=None,
                    keep_router_logits=False, score="softmax",
-                   expert_bias=False, norm_topk=True, route_scale=1.0):
+                   expert_bias=False, norm_topk=True, route_scale=1.0,
+                   held=None):
     """Dropless top-k mixture of gated experts without bias
     (ops/moe_ops.py ``moe_routed_ffn``): each token of ``x`` [B, S, H]
     goes to the ``top_k`` experts its row of ``router_x`` [B, S, H]
@@ -1284,6 +1285,10 @@ def moe_routed_ffn(x, router_x, num_experts, top_k, d_ff,
     never the weights), weighted by their unbiased sigmoids, with
     ``norm_topk`` divided by their sum plus 1e-6, times ``route_scale``
     (``parallel/moe.py`` ``route_top_k``).
+    ``held`` ``(first, count)``: this chip holds experts ``first ..
+    first + count - 1`` of the ``num_experts`` the router scores (its
+    share of an expert-parallel group): the expert matrices have
+    ``count`` leading rows and ``out`` is those experts' part of the sum.
     ``name`` prefixes the parameters ``.router.w`` [H, E], ``.gate_up.w``
     [E, H, 2 d_ff] and ``.down.w`` [E, d_ff, H].  Returns ``(out,
     expert_count [E] int32, router_logits or None)``."""
@@ -1291,14 +1296,20 @@ def moe_routed_ffn(x, router_x, num_experts, top_k, d_ff,
 
     helper = LayerHelper("moe_routed_ffn", name=name)
     h, e, i = int(x.shape[-1]), int(num_experts), int(d_ff)
+    here = e
+    if held is not None:
+        first, here = int(held[0]), int(held[1])
+        if not 0 <= first < first + here <= e:
+            raise ValueError(f"moe_routed_ffn holds experts {first} .. "
+                             f"{first + here - 1} of {e}")
     p = (lambda s: f"{name}.{s}") if name else (lambda s: None)
     router_w = helper.create_parameter(p("router.w"), [h, e], x.dtype)
     # per-expert matrices: Glorot over one expert's fan, not the stack's
     gate_up = helper.create_parameter(
-        p("gate_up.w"), [e, h, 2 * i], x.dtype,
+        p("gate_up.w"), [here, h, 2 * i], x.dtype,
         default_initializer=XavierInitializer(fan_in=h, fan_out=2 * i))
     down = helper.create_parameter(
-        p("down.w"), [e, i, h], x.dtype,
+        p("down.w"), [here, i, h], x.dtype,
         default_initializer=XavierInitializer(fan_in=i, fan_out=h))
     out = helper.create_variable_for_type_inference(x.dtype)
     counts = helper.create_variable_for_type_inference("int32")
@@ -1310,6 +1321,8 @@ def moe_routed_ffn(x, router_x, num_experts, top_k, d_ff,
     if score != "softmax" or not norm_topk or route_scale != 1.0:
         attrs.update(score=score, norm_topk=bool(norm_topk),
                      route_scale=float(route_scale))
+    if held is not None:
+        attrs["held_first"] = first
     if expert_bias:
         inputs["ExpertBias"] = [helper.create_parameter(
             p("expert_bias"), [e], "float32", is_bias=True)]

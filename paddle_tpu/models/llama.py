@@ -54,7 +54,13 @@ from .. import layers
 #           ("softmax", or "sigmoid": each expert scored on its own),
 #           "expert_bias" (True: a learned [E] float32 bias moves the
 #           choice, never the weights), "norm_topk" (True), "route_scale"
-#           (1.0)
+#           (1.0).  "held": (first, count): this chip holds that range of
+#           the E experts, its share of an expert-parallel group: the
+#           router scores all E, only the held experts' pairs are
+#           multiplied and their part of the sum goes on (no exchange, and
+#           nothing in the absent chips' stead).  "shared_width": I adds a
+#           shared expert, a SwiGLU of that width that every row goes
+#           through, beside the routed ones
 #   mixer:  "attention" (q, k, v, RoPE, pages) or a dict {"kind": "conv",
 #           "L_cache": L, "bias": False}: a gated short convolution,
 #           ``[B, C, u] = split3(h W_in)``, ``y = (C * conv_L(B * u))
@@ -66,13 +72,22 @@ from .. import layers
 #           True}: gated delta-rule linear attention
 #           (:func:`_gated_delta_mixer`), whose cache is two states a
 #           slot: the last L - 1 rows that its convolution over q | k | v
-#           saw, and a matrix [H, Dk, Dv] that every token moves on
+#           saw, and a matrix [H, Dk, Dv] that every token moves on.
+#           With "decay": "channel" the layer is Kimi Delta Attention: the
+#           log decay is a vector a head, one value a key channel, from a
+#           low-rank projection of width "decay_rank"; "gate": "sigmoid"
+#           with "gate_rank" makes the output gate ``sigmoid(h W_down
+#           W_up)`` (the default: ``silu(h W_gate)``, full rank)
+#   attn_gate: True multiplies the attention's output, before its output
+#           projection, by ``sigmoid(h W_g)``, ``W_g`` [hidden, heads *
+#           head_dim], elementwise
 #   attn_precision: None (the prefill attention kernel's two products at
 #           the backend's default: a TPU rounds float32 operands to
 #           bfloat16) or "highest" (operands whole, as the paged decode
 #           kernel and the matmuls take float32), whatever the mask
 DEFAULT_LAYER = {"window": None, "rope": True, "ffn": "dense",
-                 "attn_precision": None, "mixer": "attention"}
+                 "attn_precision": None, "mixer": "attention",
+                 "attn_gate": False}
 
 
 def layer_spec(layer_pattern, i):
@@ -258,19 +273,20 @@ def _conv_mixer(h, hidden, mixer, p, valid=None, conv_state=None,
                    pname=p("conv_out.w")), tail
 
 
-def _delta_gate_init(name, heads):
-    """``A_log`` and ``dt_bias`` [heads] as the family's modelling code
-    draws them: A uniform in (0, 16), ``A_log = log A``; dt log-uniform in
-    [0.001, 0.1], ``dt_bias = dt + log(-expm1(-dt))`` (softplus's
-    inverse).  Drawn from the layer's name, so every program of a model
-    gives the same constants (a benchmark redraws them from its seed)."""
+def _delta_gate_init(name, heads, channels=None):
+    """``A_log`` [heads] and ``dt_bias`` [heads, or ``channels`` under a
+    decay a key channel] as the family's modelling code draws them: A
+    uniform in (0, 16), ``A_log = log A``; dt log-uniform in [0.001,
+    0.1], ``dt_bias = dt + log(-expm1(-dt))`` (softplus's inverse).
+    Drawn from the layer's name, so every program of a model gives the
+    same constants (a benchmark redraws them from its seed)."""
     import zlib
 
     import numpy as np
 
     rng = np.random.default_rng(zlib.crc32((name or "").encode()))
     a = rng.uniform(1e-3, 16.0, heads)
-    dt = np.exp(rng.uniform(np.log(1e-3), np.log(0.1), heads))
+    dt = np.exp(rng.uniform(np.log(1e-3), np.log(0.1), channels or heads))
     return (np.log(a).astype("float32"),
             (dt + np.log(-np.expm1(-dt))).astype("float32"))
 
@@ -291,11 +307,19 @@ def _gated_delta_mixer(h, seq_len, hidden, mixer, p, eps, valid=None,
     stand after the prompt's TRUE last token in that slot's rows; else
     the whole sequence.  Returns ``(y, tail, state)``: what a prefill
     with ``valid`` and no variables to write to leaves the caller to
-    fetch, else None."""
+    fetch, else None.
+
+    With ``mixer["decay"] == "channel"`` (Kimi Delta Attention) the log
+    decay is a vector a head: ``g = -exp(A_log) * softplus((h W_f_down)
+    W_f_up + dt_bias)`` [B, S, H, Dk], ``A_log`` [H] and ``dt_bias`` [H *
+    Dk], through a projection of rank ``decay_rank``; ``beta`` has its
+    own matrix; and with ``mixer["gate"] == "sigmoid"`` the output gate
+    is ``sigmoid((h W_g_down) W_g_up)`` of rank ``gate_rank``."""
     from ..framework.initializer import NumpyArrayInitializer
 
     heads, dk, dv, channels = _delta_dims(mixer)
     kernel = int(mixer["conv"])
+    per_channel = mixer.get("decay", "head") == "channel"
     conv_state, delta_state = states if states else (None, None)
     qkv = _linear(h, channels, pname=p("gdn_qkv.w"))
     c, tail = _conv_over_state(qkv, kernel, {"param_attr": p("gdn_conv.w")},
@@ -311,20 +335,34 @@ def _gated_delta_mixer(h, seq_len, hidden, mixer, p, eps, valid=None,
     k = layers.l2_normalize(part(heads * dk, heads, dk), axis=-1,
                             epsilon=1e-6)
     v = part(2 * heads * dk, heads, dv)
-    ab = _linear(h, 2 * heads, pname=p("gdn_ab.w"))
-    a = layers.slice(ab, axes=[2], starts=[0], ends=[heads])
-    b = layers.slice(ab, axes=[2], starts=[heads], ends=[2 * heads])
+    if per_channel:
+        a = _linear(_linear(h, int(mixer["decay_rank"]),
+                            pname=p("gdn_f_down.w")),
+                    heads * dk, pname=p("gdn_f_up.w"))
+        b = _linear(h, heads, pname=p("gdn_b.w"))
+    else:
+        ab = _linear(h, 2 * heads, pname=p("gdn_ab.w"))
+        a = layers.slice(ab, axes=[2], starts=[0], ends=[heads])
+        b = layers.slice(ab, axes=[2], starts=[heads], ends=[2 * heads])
     beta = layers.sigmoid(b)
     if mixer.get("neg_eigval"):
         beta = layers.scale(beta, scale=2.0)
     a_log, dt_bias = (layers.create_parameter(
-        [heads], "float32", name=p(what),
+        [len(init)], "float32", name=p(what),
         default_initializer=NumpyArrayInitializer(init))
-        for what, init in zip(("gdn_A_log", "gdn_dt_bias"),
-                              _delta_gate_init(p("gdn"), heads)))
-    g = layers.scale(layers.elementwise_mul(
-        layers.softplus(layers.elementwise_add(a, dt_bias)),
-        layers.exp(a_log)), scale=-1.0)
+        for what, init in zip(
+            ("gdn_A_log", "gdn_dt_bias"),
+            _delta_gate_init(p("gdn"), heads,
+                             heads * dk if per_channel else None)))
+    if per_channel:
+        g = layers.scale(layers.elementwise_mul(
+            layers.reshape(layers.softplus(
+                layers.elementwise_add(a, dt_bias)), [0, seq_len, heads, dk]),
+            layers.exp(a_log), axis=2), scale=-1.0)
+    else:
+        g = layers.scale(layers.elementwise_mul(
+            layers.softplus(layers.elementwise_add(a, dt_bias)),
+            layers.exp(a_log)), scale=-1.0)
     state = None
     if live is not None:
         o = layers.gated_delta_step(q, k, v, g, beta, delta_state, live)
@@ -336,9 +374,14 @@ def _gated_delta_mixer(h, seq_len, hidden, mixer, p, eps, valid=None,
         elif valid is not None:
             state = last
     o = layers.rms_norm(o, epsilon=eps, param_attr=p("gdn_norm"))
-    gate = layers.reshape(_linear(h, heads * dv, pname=p("gdn_gate.w")),
-                          [0, seq_len, heads, dv])
-    o = layers.reshape(layers.elementwise_mul(o, layers.silu(gate)),
+    low_rank = mixer.get("gate", "silu") == "sigmoid"
+    gate = _linear(_linear(h, int(mixer["gate_rank"]),
+                           pname=p("gdn_g_down.w")),
+                   heads * dv, pname=p("gdn_g_up.w")) if low_rank \
+        else _linear(h, heads * dv, pname=p("gdn_gate.w"))
+    gate = layers.reshape(gate, [0, seq_len, heads, dv])
+    gate = layers.sigmoid(gate) if low_rank else layers.silu(gate)
+    o = layers.reshape(layers.elementwise_mul(o, gate),
                        [0, seq_len, heads * dv])
     return _linear(o, hidden, pname=p("gdn_out.w")), tail, state
 
@@ -510,6 +553,9 @@ def llama_block(x, hidden, num_heads, num_kv_heads, seq_len, head_dim,
                                       impl=attn_impl, **win)
     attn = layers.transpose(attn, [0, 2, 1, 3])
     attn = layers.reshape(attn, [0, seq_len, q_size])
+    if layer.get("attn_gate"):
+        attn = layers.elementwise_mul(attn, layers.sigmoid(
+            _linear(h, q_size, pname=p("attn_gate.w"))))
     y = _linear(attn, hidden, pname=p("attn_out.w"))
     x = layers.elementwise_add(x, normed(y, "ln1") if post else y)
     out = _ffn(x, x_in, hidden, intermediate, layer["ffn"], p, rms_norm_eps,
@@ -525,6 +571,15 @@ def _norm_is_post(norm):
     return norm == "post"
 
 
+def _swiglu(h, hidden, width, gate_up_name, down_name):
+    """``(silu(h W_gate) * (h W_up)) W_down`` with gate | up fused."""
+    gate_up = _linear(h, 2 * width, pname=gate_up_name)
+    gate = layers.slice(gate_up, axes=[2], starts=[0], ends=[width])
+    up = layers.slice(gate_up, axes=[2], starts=[width], ends=[2 * width])
+    return _linear(layers.elementwise_mul(layers.silu(gate), up), hidden,
+                   pname=down_name)
+
+
 def _ffn(x, x_in, hidden, intermediate, ffn, p, rms_norm_eps, valid, taps,
          post=False):
     """The layer's second half on the post-mixer stream x: norm, dense
@@ -534,13 +589,7 @@ def _ffn(x, x_in, hidden, intermediate, ffn, p, rms_norm_eps, valid, taps,
     h = x if post else layers.rms_norm(x, epsilon=rms_norm_eps,
                                        param_attr=p("ln2"))
     if ffn == "dense":
-        gate_up = _linear(h, 2 * intermediate, pname=p("gate_up.w"))
-        gate = layers.slice(gate_up, axes=[2], starts=[0],
-                            ends=[intermediate])
-        up = layers.slice(gate_up, axes=[2], starts=[intermediate],
-                          ends=[2 * intermediate])
-        y = _linear(layers.elementwise_mul(layers.silu(gate), up), hidden,
-                    pname=p("ffn_out.w"))
+        y = _swiglu(h, hidden, intermediate, p("gate_up.w"), p("ffn_out.w"))
     else:
         taps = taps if taps is not None else {}
         y, counts, logits = layers.moe_routed_ffn(
@@ -549,10 +598,16 @@ def _ffn(x, x_in, hidden, intermediate, ffn, p, rms_norm_eps, valid, taps,
             activation=ffn.get("activation", "relu"), valid=valid,
             name=p("moe"), keep_router_logits=bool(taps.get("keep_logits")),
             **{k: ffn[k] for k in ("score", "expert_bias", "norm_topk",
-                                   "route_scale") if k in ffn})
+                                   "route_scale", "held") if k in ffn})
         taps.setdefault("counts", []).append(counts)
         if logits is not None:
             taps.setdefault("logits", []).append(logits)
+        if ffn.get("shared_width"):
+            # every row, whatever it was routed to; on every chip of an
+            # expert-parallel group alike, so counted once
+            y = layers.elementwise_add(y, _swiglu(
+                h, hidden, int(ffn["shared_width"]),
+                p("moe.shared_gate_up.w"), p("moe.shared_down.w")))
     if post:
         y = layers.rms_norm(y, epsilon=rms_norm_eps, param_attr=p("ln2"))
     return layers.elementwise_add(x, y)

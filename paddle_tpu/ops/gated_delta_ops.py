@@ -4,8 +4,11 @@ key and reads along its query::
 
     S_t = a_t S_{t-1} + k_t (b_t (v_t - (a_t S_{t-1})^T k_t))^T,  o_t = S_t^T q_t
 
-with ``a_t = exp(g_t)`` in (0, 1] and ``b_t`` in [0, 2].  Two ops, float32
-throughout, every product at "highest":
+with ``a_t = exp(g_t)`` in (0, 1] and ``b_t`` in [0, 2].  The log decay
+``g_t`` is a scalar a head (G [.., H]: Gated DeltaNet) or a vector a head,
+one value a key channel (G [.., H, Dk]: Kimi Delta Attention, ``a_t S`` is
+then ``Diag(a_t) S``, a scaling of the state's rows); both ops take either,
+by G's rank.  Two ops, float32 throughout, every product at "highest":
 
 * ``gated_delta_chunk``: a whole (right-padded) sequence, the prefill's
   and the uncached forward's.  Q, K [B, T, H, Dk], V [B, T, H, Dv], G and
@@ -28,6 +31,23 @@ throughout, every product at "highest":
 ``gated_delta_lowered_pallas`` / ``gated_delta_lowered_reference`` count,
 per program build, which an op lowered to (as ``attention_lowered_*``):
 on a TPU the second is a downgrade and is logged once with its reason.
+``gated_delta_lowered_channel_decay`` counts, beside them, the ops built
+with a decay a key channel.
+
+**A chunk with a decay a channel** (``chunk_terms_channel``).  What token
+t needs of token i < t is ``sum_d x_t[d] k_i[d] exp(cum_t[d] - cum_i[d])``
+(x = k for the corrections, q for the outputs), ``cum`` the running sum of
+g inside the chunk.  With a scalar decay the exponential leaves the sum and
+[C, C] differences do; a channel each, the array of differences is [C, C,
+Dk] a head a chunk, and the factored form ``(x_t exp(cum_t)) . (k_i
+exp(-cum_i))`` overflows float32 inside one chunk.  So every exponent is
+kept non-positive: the chunk's 64 tokens are taken in blocks of ``BLOCK``
+= 16; for t in a later block than i the difference is split at the first
+token ``T0`` of t's block, ``exp(cum_t - cum_T0) exp(cum_T0 - cum_i)``,
+two safe scalings around one product; within a block the 16 x 16 x Dk
+differences are taken outright.  From there on the chunk's terms, the
+unit-triangular solve and the carried pass are the scalar form's, the
+state scaled by ``exp(cum_last)`` a row where that was one number.
 """
 from __future__ import annotations
 
@@ -39,16 +59,21 @@ from .registry import in_var, register_op, set_out
 logger = logging.getLogger(__name__)
 
 CHUNK = 64
+BLOCK = 16        # a chunk's sub-blocks under a decay a channel
+HEAD_GROUP = 8    # ... whose terms are made for this many heads a turn
 
 _LOWERED = {
     "pallas": _monitor.get("gated_delta_lowered_pallas"),
     "reference": _monitor.get("gated_delta_lowered_reference"),
+    "channel": _monitor.get("gated_delta_lowered_channel_decay"),
 }
 _downgrades_logged = set()
 
 
-def _lowered(path, downgrade_reason=None):
+def _lowered(path, downgrade_reason=None, channel=False):
     _LOWERED[path].increase()
+    if channel:
+        _LOWERED["channel"].increase()
     if downgrade_reason and downgrade_reason not in _downgrades_logged:
         _downgrades_logged.add(downgrade_reason)
         logger.warning("the gated delta rule lowered to its XLA "
@@ -109,9 +134,78 @@ def chunk_terms(q, k, v, g, beta):
     return q * gam, w, u0, p, kd, jnp.exp(last[..., 0])
 
 
+def _decayed_products(q, k, cum):
+    """``sum_d x_t[d] k_i[d] exp(cum_t[d] - cum_i[d])`` for i <= t inside
+    a chunk and 0 above the diagonal, for x = k and for x = q: q, k, cum
+    [..., C, Dk] -> two [..., C, C], every exponent non-positive (this
+    module's docstring)."""
+    import jax.numpy as jnp
+
+    C, Dk = k.shape[-2:]
+    nb, lead = C // BLOCK, k.shape[:-2]
+
+    def blocks(x):
+        return x.reshape(lead + (nb, BLOCK, Dk))
+
+    cb, kb = blocks(cum), blocks(k)
+    x = jnp.stack([kb, blocks(q)], axis=-3)              # [.., nb, 2, t, Dk]
+    # within a block the differences outright, one fused pass over
+    # [.., nb, 2, t, i, Dk] that is never stored
+    tri = jnp.tril(jnp.ones((BLOCK, BLOCK), bool))[:, :, None]
+    diff = cb[..., :, None, :] - cb[..., None, :, :]     # [.., nb, t, i, Dk]
+    near = jnp.where(tri, jnp.exp(jnp.where(tri, diff, 0.0)), 0.0) \
+        * kb[..., None, :, :]
+    diag = (x[..., :, None, :] * near[..., None, :, :, :]).sum(-1)
+    # across blocks: the rows of blocks 1.. scaled back to their block's
+    # first token, the columns before that token scaled on to it
+    before = C - BLOCK
+    first = cb[..., 1:, :1, :]                           # [.., nb-1, 1, Dk]
+    rows = x[..., 1:, :, :, :] * jnp.exp(cb[..., 1:, :, :]
+                                         - first)[..., None, :, :]
+    old = (jnp.arange(before)[None, :]
+           < (jnp.arange(1, nb) * BLOCK)[:, None])[..., None]
+    ahead = first - cum[..., None, :before, :]       # [.., nb-1, before, Dk]
+    cols = jnp.where(old, jnp.exp(jnp.where(old, ahead, 0.0)), 0.0) \
+        * k[..., None, :before, :]
+    far = jnp.einsum("...nxtd,...nid->...xnti", rows, cols, precision=_hi())
+    far = jnp.pad(far.reshape(lead + (2, before, before)),
+                  [(0, 0)] * (len(lead) + 1) + [(BLOCK, 0), (0, BLOCK)])
+    # the diagonal blocks into place: [.., 2, nb, t, nb, i]
+    diag = jnp.moveaxis(diag, -3, -4)[..., :, :, None, :] \
+        * jnp.eye(nb, dtype=k.dtype)[:, None, :, None]
+    both = far + diag.reshape(lead + (2, C, C))
+    return both[..., 0, :, :], both[..., 1, :, :]
+
+
+def chunk_terms_channel(q, k, v, g, beta):
+    """:func:`chunk_terms` with a log decay a key channel, ``g`` [B, H, N,
+    C, Dk]: the same six terms, ``gc`` [B, H, N, Dk] the state's row
+    scaling over the chunk."""
+    import jax.numpy as jnp
+
+    C = q.shape[-2]
+    if C % BLOCK:
+        raise ValueError(f"a chunk of {C} tokens is not whole blocks of "
+                         f"{BLOCK}")
+    cum = jnp.cumsum(g, axis=-2)
+    kk, p = _decayed_products(q, k, cum)
+    a = jnp.where(jnp.tril(jnp.ones((C, C), bool), -1),
+                  beta[..., :, None] * kk, 0.0)
+    t = _unit_lower_inverse(a)
+    gam = jnp.exp(cum)
+    w = jnp.einsum("...ij,...jd->...id", t, beta[..., None] * gam * k,
+                   precision=_hi())
+    u0 = jnp.einsum("...ij,...jd->...id", t, beta[..., None] * v,
+                    precision=_hi())
+    last = cum[..., -1:, :]
+    return q * gam, w, u0, p, k * jnp.exp(last - cum), \
+        jnp.exp(last[..., 0, :])
+
+
 def scan_chunks(terms, s0):
     """The state from chunk to chunk under ``lax.scan``: ``terms`` of
-    :func:`chunk_terms`, ``s0`` [B, H, Dk, Dv] -> (out [B, H, N, C, Dv],
+    :func:`chunk_terms` (or ``chunk_terms_channel``: ``gc`` then scales
+    the state's rows), ``s0`` [B, H, Dk, Dv] -> (out [B, H, N, C, Dv],
     the last state)."""
     import jax
     import jax.numpy as jnp
@@ -121,13 +215,39 @@ def scan_chunks(terms, s0):
         u = u0 - jnp.einsum("bhck,bhkv->bhcv", w, s, precision=_hi())
         o = jnp.einsum("bhck,bhkv->bhcv", qg, s, precision=_hi()) \
             + jnp.einsum("bhct,bhtv->bhcv", p, u, precision=_hi())
-        s = gc[..., None, None] * s \
+        s = (gc[..., None, None] if gc.ndim == 2 else gc[..., None]) * s \
             + jnp.einsum("bhck,bhcv->bhkv", kd, u, precision=_hi())
         return s, o
 
     s, o = jax.lax.scan(step, s0,
                         tuple(jnp.moveaxis(x, 2, 0) for x in terms))
     return jnp.moveaxis(o, 0, 2), s
+
+
+def _by_head_groups(fn, *xs):
+    """``fn`` over [B, H, ...] operands ``HEAD_GROUP`` heads a turn
+    (``lax.map``), so that what a chunk's tokens need of each other is
+    held for a group's heads and not for all of them: at 64 heads of 128
+    over 4096 tokens the terms of a decay a channel are 3.3 GB at once
+    and 0.4 GB a group (compiled for a v5e, PR 43)."""
+    import jax
+    import jax.numpy as jnp
+
+    H = xs[0].shape[1]
+    if H <= HEAD_GROUP or H % HEAD_GROUP:
+        return fn(*xs)
+
+    def split(x):
+        x = x.reshape((x.shape[0], H // HEAD_GROUP, HEAD_GROUP)
+                      + x.shape[2:])
+        return jnp.moveaxis(x, 1, 0)
+
+    def join(x):
+        x = jnp.moveaxis(x, 0, 1)
+        return x.reshape((x.shape[0], H) + x.shape[3:])
+
+    return jax.tree_util.tree_map(
+        join, jax.lax.map(lambda a: fn(*a), tuple(split(x) for x in xs)))
 
 
 def chunked(q, k, v, g, beta, s0=None, valid=None, chunk=CHUNK,
@@ -159,21 +279,28 @@ def chunked(q, k, v, g, beta, s0=None, valid=None, chunk=CHUNK,
 
     if s0 is None:
         s0 = jnp.zeros((B, H, Dk, Dv), q.dtype)
-    terms = chunk_terms(lay(q), lay(k), lay(v), lay(g), lay(beta))
-    o, s = carry(terms, s0)
+    if g.ndim == 3:
+        terms = chunk_terms(lay(q), lay(k), lay(v), lay(g), lay(beta))
+        o, s = carry(terms, s0)
+    else:
+        o, s = _by_head_groups(
+            lambda *x: carry(chunk_terms_channel(*x[:5]), x[5]),
+            lay(q), lay(k), lay(v), lay(g), lay(beta), s0)
     o = jnp.moveaxis(o, 1, 3).reshape(B, N * chunk, H, Dv)
     return o[:, :T], s
 
 
 def step(q, k, v, g, beta, state, live):
     """The one-row step in plain ``jax.numpy``: q, k [n, H, Dk], v
-    [n, H, Dv], g, beta [n, H], state [n + 1, H, Dk, Dv], live [n] bool
-    -> (out [n, H, Dv], the state with live rows moved on)."""
+    [n, H, Dv], g [n, H] or [n, H, Dk], beta [n, H], state [n + 1, H, Dk,
+    Dv], live [n] bool -> (out [n, H, Dv], the state with live rows moved
+    on)."""
     import jax.numpy as jnp
 
     n = q.shape[0]
     old = state[:n]
-    s = jnp.exp(g)[..., None, None] * old
+    s = (jnp.exp(g)[..., None, None] if g.ndim == 2
+         else jnp.exp(g)[..., None]) * old
     r = v - jnp.einsum("nhkv,nhk->nhv", s, k, precision=_hi())
     s = s + k[..., :, None] * (beta[..., None] * r)[..., None, :]
     o = jnp.einsum("nhkv,nhk->nhv", s, q, precision=_hi())
@@ -220,7 +347,7 @@ def _gated_delta_chunk(ctx, op):
     carry = gated_delta.carry_chunks if kernel else scan_chunks
     out, state = chunked(*(x.astype(jnp.float32) for x in (q, k, v, g, beta)),
                          s0=s0, valid=valid, carry=carry)
-    _lowered("pallas" if kernel else "reference", why)
+    _lowered("pallas" if kernel else "reference", why, g.ndim == 4)
     ctx.set_output(op, "Out", out.astype(v.dtype))
     ctx.set_output(op, "StateOut", state)
 
@@ -234,9 +361,9 @@ def _step_infer(op, block):
 @register_op("gated_delta_step", infer=_step_infer, grad=None,
              stateful_outputs=("StateOut",))
 def _gated_delta_step(ctx, op):
-    """Q, K [slots, 1, H, Dk], V [slots, 1, H, Dv], G, Beta [slots, 1, H]
-    over State [slots + 1, H, Dk, Dv]; Live [slots].  StateOut aliases
-    State."""
+    """Q, K [slots, 1, H, Dk], V [slots, 1, H, Dv], G [slots, 1, H] or
+    [slots, 1, H, Dk], Beta [slots, 1, H] over State [slots + 1, H, Dk,
+    Dv]; Live [slots].  StateOut aliases State."""
     import jax.numpy as jnp
 
     from .pallas import gated_delta
@@ -254,6 +381,6 @@ def _gated_delta_step(ctx, op):
                                     live.astype(jnp.int32))
     else:
         out, new = step(q, k, v, g, beta, state, live.astype(bool))
-    _lowered("pallas" if kernel else "reference", why)
+    _lowered("pallas" if kernel else "reference", why, g.ndim == 3)
     ctx.set_output(op, "Out", out[:, None].astype(v.dtype))
     ctx.set_output(op, "StateOut", new)

@@ -64,7 +64,10 @@ def _moe_routed_ffn(ctx, op):
     [B, S, H] what the router reads (an architecture may route from the
     layer's raw input, before attention), Valid [B] int the number of
     real rows of each batch row (optional: all), ExpertBias [E] the
-    selection bias of sigmoid scoring (optional).  Inference only."""
+    selection bias of sigmoid scoring (optional).  With the attribute
+    ``held_first`` GateUpW and DownW hold the experts from that index on
+    alone, one chip's share of RouterW's E, and Out is their part of the
+    sum.  Inference only."""
     import jax.numpy as jnp
 
     from ..parallel.moe import moe_routed_tokens
@@ -89,7 +92,8 @@ def _moe_routed_ffn(ctx, op):
         expert_bias=ctx.get_input(op, "ExpertBias")
         if op.single_input("ExpertBias") else None,
         norm_topk=bool(op.attr("norm_topk", True)),
-        route_scale=float(op.attr("route_scale", 1.0)))
+        route_scale=float(op.attr("route_scale", 1.0)),
+        held_first=op.attr("held_first", None))
     ctx.set_output(op, "Out", out.reshape(shape))
     ctx.set_output(op, "ExpertCount", counts)
     if op.output("RouterLogits"):

@@ -250,11 +250,71 @@ def grouped_matmul(rows, weights, group_sizes, precision=None):
     return _one_call(rows, weights, group_sizes, precision)
 
 
+def _gated(h, inter, activation):
+    """``act(gate) * up`` of the fused first product's columns."""
+    import jax
+    import jax.numpy as jnp
+
+    gate, up = h[:, :inter], h[:, inter:]
+    if activation == "relu":
+        gate = jnp.maximum(gate, 0)
+    elif activation == "silu":
+        gate = jax.nn.silu(gate)
+    else:
+        raise ValueError(f"unknown expert activation {activation!r}")
+    return gate * up
+
+
+def _held_share(x, local, weights, w_gate_up, w_down, activation,
+                precision):
+    """The held experts' part of the layer: ``local`` [N, k] is each
+    pair's index among the experts held here, or ``held`` (= ``w_gate_up``
+    's leading size) where its expert lives on another chip.  The pairs
+    are sorted with the held ones first, and only the runs of ``RUN_ROWS``
+    sorted pairs that hold a held one are gathered, multiplied and added
+    to their tokens' rows (a loop whose trip count the routing gives): an
+    absent expert's pair costs no row of either matmul and adds nothing,
+    and no [N * k, H] array is made.  Returns ``out`` [N, H]."""
+    import jax
+    import jax.numpy as jnp
+
+    N, H = x.shape
+    top_k = local.shape[1]
+    held, inter = w_gate_up.shape[0], w_down.shape[1]
+    flat = local.reshape(-1)
+    order = jnp.argsort(flat, stable=True)              # held pairs first
+    sizes = jnp.bincount(flat, length=held + 1)[:held].astype(jnp.int32)
+    ends = jnp.cumsum(sizes)
+    starts, n_held = ends - sizes, ends[-1]
+    run = min(RUN_ROWS, -(-N * top_k // ROW_TILE) * ROW_TILE)
+    order = jnp.pad(order, (0, -order.shape[0] % run))
+    pair_w = weights.reshape(-1)
+
+    def body(i, out):
+        lo = i * run
+        pairs = jax.lax.dynamic_slice_in_dim(order, lo, run)
+        real = lo + jnp.arange(run) < n_held
+        tok = pairs // top_k
+        rows = jnp.take(x, tok, axis=0)                 # [run, H]
+        size = jnp.clip(jnp.minimum(ends, lo + run) - jnp.maximum(starts, lo),
+                        0, None).astype(jnp.int32)
+        h = _one_call(rows, w_gate_up.astype(x.dtype), size, precision)
+        y = _one_call(_gated(h, inter, activation), w_down.astype(x.dtype),
+                      size, precision)
+        y = y * jnp.take(pair_w, pairs)[:, None].astype(y.dtype)
+        # rows past the held pairs belong to no group: whatever the
+        # kernel left there is dropped, not scaled
+        return out.at[tok].add(jnp.where(real[:, None], y, 0))
+
+    return jax.lax.fori_loop(0, -(-n_held // run), body,
+                             jnp.zeros((N, H), x.dtype))
+
+
 def moe_routed_tokens(x, router_x, router_w, w_gate_up, w_down, *,
                       top_k: int, activation: str = "relu", valid=None,
                       precision=None, score: str = "softmax",
                       expert_bias=None, norm_topk: bool = True,
-                      route_scale: float = 1.0):
+                      route_scale: float = 1.0, held_first=None):
     """Dropless top-k mixture of gated experts over flat tokens.
 
     x [N, H] is the experts' input, router_x [N, H] what the router
@@ -268,10 +328,21 @@ def moe_routed_tokens(x, router_x, router_w, w_gate_up, w_down, *,
     the gate's: "relu" or "silu"; ``score``, ``expert_bias``,
     ``norm_topk`` and ``route_scale`` are :func:`route_top_k`'s.
 
+    ``held_first`` (one chip's share of an expert-parallel group): the
+    weights are those of experts ``held_first .. held_first + w_gate_up
+    .shape[0] - 1`` alone.  The router keeps its width E, its ``top_k``
+    and the weights' normalisation over all ``top_k`` chosen; only the
+    pairs whose expert is held here are multiplied (:func:`_held_share`:
+    a pad-tail row's or an idle slot's pairs are not), and ``out`` is
+    that part of the layer's sum: what the absent experts would add is
+    left out, and nothing stands in for the chips that hold them.
+
     Returns ``(out [N, H], counts [E] int32, logits [N, E])``; ``counts``
     are the group sizes the grouped matmul ran with, restricted to valid
     rows, so ``counts.sum() == valid.sum() * k`` proves no token was
-    dropped."""
+    dropped.  Under ``held_first`` they are the pairs routed to each of
+    the E experts, and their slice over the held experts is what the
+    matmuls ran with."""
     import jax
     import jax.numpy as jnp
 
@@ -281,20 +352,26 @@ def moe_routed_tokens(x, router_x, router_w, w_gate_up, w_down, *,
     logits, experts, weights = route_top_k(
         router_x, router_w, top_k, score, expert_bias, norm_topk,
         route_scale)
+    if held_first is not None:
+        held = w_gate_up.shape[0]
+        pair_valid = jnp.ones((N, 1), bool) if valid is None \
+            else valid[:, None]
+        here = (experts >= held_first) & (experts < held_first + held) \
+            & pair_valid
+        out = _held_share(x, jnp.where(here, experts - held_first, held),
+                          weights, w_gate_up, w_down, activation, precision)
+        counts = jnp.zeros((E,), jnp.int32).at[experts.reshape(-1)].add(
+            jnp.broadcast_to(pair_valid, experts.shape).reshape(-1)
+            .astype(jnp.int32))
+        return out, counts, logits
     flat = experts.reshape(-1)                          # [N*k]
     order = jnp.argsort(flat, stable=True)              # rows by expert
     group_sizes = jnp.bincount(flat, length=E).astype(jnp.int32)
     rows = jnp.take(x, order // top_k, axis=0)          # [N*k, H]
     h = grouped_matmul(rows, w_gate_up.astype(x.dtype), group_sizes,
                        precision)
-    gate, up = h[:, :inter], h[:, inter:]
-    if activation == "relu":
-        gate = jnp.maximum(gate, 0)
-    elif activation == "silu":
-        gate = jax.nn.silu(gate)
-    else:
-        raise ValueError(f"unknown expert activation {activation!r}")
-    y = grouped_matmul(gate * up, w_down.astype(x.dtype), group_sizes,
+    y = grouped_matmul(_gated(h, inter, activation),
+                       w_down.astype(x.dtype), group_sizes,
                        precision)                       # [N*k, H]
     y = y * jnp.take(weights.reshape(-1), order)[:, None].astype(y.dtype)
     # back to token order: row r of the sorted list is pair order[r]

@@ -137,7 +137,12 @@ each fails only the then-active requests),
 ``serving_spec_tokens_proposed``, ``serving_spec_tokens_accepted``,
 ``serving_spec_rollbacks``,
 ``serving_kv_window_pages_released``, ``moe_tokens_routed``,
-``moe_tokens_dropped`` (must read 0),
+``moe_tokens_dropped`` (must read 0), and for a model whose expert
+layers hold one chip's share of the router's experts (the layer
+pattern's ``held``) ``moe_pairs_routed`` / ``moe_pairs_held`` (the
+token-expert pairs the router placed over all its experts, and those
+whose expert is held here and went through the matmuls), with a shared
+expert ``moe_shared_expert_rows`` (row-layers it ran on),
 ``serving_block_passes_denoise`` / ``serving_block_passes_commit``
 (block diffusion: slot-passes that decided positions / that only
 committed a block's K/V), ``serving_block_tokens_committed``,
@@ -687,6 +692,10 @@ class GenerationEngine:
         self.window = widths.pop() if widths else None
         routed = [sp["ffn"] for sp in specs if sp["ffn"] != "dense"]
         self._moe_top_k = routed[0]["top_k"] if routed else 0
+        # one chip's share of an expert-parallel group: the range of the
+        # router's experts held here (None: all), and a shared expert
+        self._moe_held = routed[0].get("held") if routed else None
+        self._moe_shared = bool(routed and routed[0].get("shared_width"))
         self._build_fn_prefill = build_llama_prefill
         self._seed = seed
 
@@ -889,7 +898,9 @@ class GenerationEngine:
                    "window_pages_released": 0, "moe_tokens_routed": 0,
                    "moe_tokens_dropped": 0, "block_passes_denoise": 0,
                    "block_passes_commit": 0, "block_tokens_committed": 0,
-                   "slot_state_writes": 0, "delta_state_steps": 0}
+                   "slot_state_writes": 0, "delta_state_steps": 0,
+                   "moe_pairs_routed": 0, "moe_pairs_held": 0,
+                   "moe_shared_expert_rows": 0}
         self._n_lock = threading.Lock()
         # per-bucket manifest-flops cache for usage attribution: the
         # executor cache walk is paid once per bucket, not per dispatch
@@ -2515,8 +2526,13 @@ class GenerationEngine:
                 [np.asarray(outs["router_logits"].numpy())[0]] \
                 if keep and "router_logits" in outs else []
             if "expert_counts" in outs:
-                self._book_experts(
+                booked = self._book_experts(
                     np.asarray(outs["expert_counts"].numpy()), n_tokens)
+                if span is not None and "pairs_held" in booked:
+                    # (the counts come back with this fetch, after the
+                    # ``generation/prefill`` span that launched them)
+                    span.attrs.update(pairs_routed=booked["pairs_routed"],
+                                      pairs_held=booked["pairs_held"])
         finally:
             self._end_device_wait(span)
         return first
@@ -2542,8 +2558,26 @@ class GenerationEngine:
         if telemetry.enabled():
             telemetry.gauge_set("moe_experts_touched", touched)
             telemetry.gauge_set("moe_expert_load_max_over_mean", load)
-        return {"experts_touched": round(touched, 3),
-                "expert_load_max_over_mean": round(load, 4)}
+        attrs = {"experts_touched": round(touched, 3),
+                 "expert_load_max_over_mean": round(load, 4)}
+        if self._moe_shared:
+            rows = counts.shape[0] * n_tokens
+            self._count("moe_shared_expert_rows", rows)
+            stat_add("moe_shared_expert_rows", rows)
+        if self._moe_held is not None:
+            # the router scored every expert of the group; the matmuls
+            # ran over the pairs whose expert this chip holds
+            first, n = self._moe_held
+            here = counts[:, first:first + n]
+            held = int(here.sum())
+            for what, k in (("moe_pairs_routed", routed),
+                            ("moe_pairs_held", held)):
+                self._count(what, k)
+                stat_add(what, k)
+            attrs.update(pairs_routed=routed, pairs_held=held,
+                         experts_held_touched=round(
+                             float((here > 0).sum(axis=1).mean()), 3))
+        return attrs
 
     def _complete_prefill(self, slot: _Slot, req: GenRequest, outs,
                           n_rows: int):

@@ -2,10 +2,10 @@
 tier-1 tests (PR 41; PR 38 left it to "a later PR of another kind"): the
 pure-JSON checks of ``benchmark/tests/test_manifest.py`` are collected
 here as they stand (entries against data files, every entry's
-``workloads``, each cell's count of values), and the cell PR 41 added is
-pinned beside them: its own entries, the shared ``.pool`` entries that
-list it, its configuration's cut and its mix.  No JAX is imported and no
-engine started.
+``workloads``, each cell's count of values), and the cells that PR 41 and
+PR 43 added are pinned beside them, by name: each one's own entries, the
+shared ``.pool`` entries that list it, its configuration's cut and its
+mix.  No JAX is imported and no engine started.
 """
 import importlib.util
 import json
@@ -32,11 +32,35 @@ manifest.REPORTS["bert-base-seq512-dp4"] += 1
 globals().update({name: fn for name, fn in vars(manifest).items()
                   if name.startswith("test_")})
 
-CELL = "olmo-hybrid7b-longdoc"
-OWN = ["decode_step_roofline.olmo", "prefill_roofline.olmo",
-       "paged_kernel_roofline.olmo", "gdn_step_roofline.olmo",
-       "gdn_chunk_roofline.olmo", "gdn_kernel_share_pct.olmo",
-       "state_slots_pct.olmo", "scan_pad_pct.olmo"]
+# the cells that model_config PRs added since the merge (PR 41, PR 43),
+# in the order they were added: the cell's configuration and mix, its own
+# entries, the shared families that list it beside ``POOL``, what its
+# configuration cuts, and its mix's driver and reference rungs
+OLMO, SOLAR = "olmo-hybrid7b-longdoc", "solar-open2-agentturns"
+ADDED = {
+    OLMO: {
+        "config": "olmo-hybrid-7b", "mix": "longdoc-pool",
+        "own": ["decode_step_roofline.olmo", "prefill_roofline.olmo",
+                "paged_kernel_roofline.olmo", "gdn_step_roofline.olmo",
+                "gdn_chunk_roofline.olmo", "gdn_kernel_share_pct.olmo",
+                "state_slots_pct.olmo", "scan_pad_pct.olmo"],
+        "experts": [], "reduced": ["num_hidden_layers", "layer_types"],
+        "driver": "serve_delta", "rungs": [512, 2048, 6144]},
+    SOLAR: {
+        "config": "solar-open2-250b", "mix": "agentturns-pool",
+        "own": ["decode_step_roofline.solar", "prefill_roofline.solar",
+                "paged_kernel_roofline.solar", "kda_step_roofline.solar",
+                "kda_chunk_roofline.solar", "kda_kernel_share_pct.solar",
+                "state_slots_pct.solar", "scan_pad_pct.solar",
+                "moe_held_touched_pct.solar", "moe_pairs_held_pct.solar"],
+        # of the expert cells' families, those whose reader and arguments
+        # mean the same over a share of the experts
+        "experts": ["moe_expert_load_max_over_mean.pool",
+                    "expert_matmul_share_pct.pool"],
+        "reduced": ["num_hidden_layers", "gqa_layers", "n_routed_experts",
+                    "vocab_size"],
+        "driver": "serve_share", "rungs": [256, 2048, 4096]},
+}
 
 
 def _json(*parts):
@@ -44,15 +68,16 @@ def _json(*parts):
         return json.load(f)
 
 
-def test_the_benchmark_has_six_configurations_and_eight_cells():
+def test_the_benchmark_has_seven_configurations_and_nine_cells():
     spec = manifest.SPEC
     assert [c["name"] for c in spec["configs"]] == [
         "bert-base-mlm", "mistral-7b-v0.1", "smallthinker-21b-a3b",
-        "sdar-30b-a3b-chat", "lfm2-24b-a2b", "olmo-hybrid-7b"]
+        "sdar-30b-a3b-chat", "lfm2-24b-a2b"] \
+        + [a["config"] for a in ADDED.values()]
     assert manifest.CELLS == [
         "bert-base-seq512", "mistral7b-chat", "mistral7b-longprompt",
         "bert-base-seq512-dp4", "smallthinker21b-mixedlen",
-        "sdar30b-blockgen", "lfm2-24b-longanswer", CELL]
+        "sdar30b-blockgen", "lfm2-24b-longanswer"] + list(ADDED)
     assert [w["name"] for w in spec["workloads"] if w["chips"] == 4] \
         == ["bert-base-seq512-dp4"]
     used = {w["config"] for w in spec["workloads"]}
@@ -66,23 +91,36 @@ def test_the_benchmark_has_six_configurations_and_eight_cells():
         mix = _json("traffic", w["traffic"] + ".json")
         assert os.path.exists(os.path.join(BENCH, mix["driver"] + ".py"))
         assert len(w["why"]) <= 200
+    # 79 entries at the merge, each added cell's own, and the dp4 cell's
+    # one entry of PR 42
+    assert len(manifest.PER_LAYER) \
+        == 79 + sum(len(a["own"]) for a in ADDED.values()) + 1
 
 
-def test_the_new_cell_reports_its_own_and_the_dense_decoders_shared_entries():
-    own, shared = manifest.reported_by(CELL)
-    assert sorted(own) == sorted(OWN)
-    assert sorted(shared) == sorted(manifest.POOL)
-    # a dense decoder: none of the experts' families
-    assert not set(shared) & set(manifest.POOL_EXPERTS + [manifest.TOUCHED])
+@pytest.mark.parametrize("cell", list(ADDED))
+def test_an_added_cell_reports_its_own_and_the_shared_entries(cell):
+    added = ADDED[cell]
+    own, shared = manifest.reported_by(cell)
+    assert sorted(own) == sorted(added["own"])
+    assert sorted(shared) == sorted(manifest.POOL + added["experts"])
+    # a dense decoder reports none of the experts' families; a share of
+    # the experts not the one that divides by ``num_experts``
+    assert manifest.TOUCHED not in shared
     by_name = {m["name"]: m for m in manifest.PER_LAYER}
+    later = list(ADDED)[list(ADDED).index(cell) + 1:]
     for name in own + shared:
         assert by_name[name]["moves"] == "served_tokens_per_s"
-        assert by_name[name]["workloads"][-1] == CELL
+        # appended in the order the cells came: only later cells follow
+        lists = by_name[name]["workloads"]
+        assert set(lists[lists.index(cell) + 1:]) <= set(later), name
     gate, = [m for m in manifest.SPEC["end_to_end"]
              if m["name"] == "served_tokens_per_s"]
-    assert gate["workloads"][-1] == CELL and gate["bound"] == 0.06
-    # ... plus the dp4 cell's one entry of PR 42, after them
-    assert len(manifest.PER_LAYER) == 79 + len(OWN) + 1
+    assert gate["workloads"][-len(ADDED):] == list(ADDED)
+    assert gate["bound"] == 0.06
+    # its own entries lie together, in the order its PR gave them
+    names = [m["name"] for m in manifest.PER_LAYER]
+    at = names.index(added["own"][0])
+    assert names[at:at + len(added["own"])] == added["own"]
 
 
 @pytest.mark.parametrize("name,reader,reads", [
@@ -97,6 +135,20 @@ def test_the_new_cell_reports_its_own_and_the_dense_decoders_shared_entries():
     ("state_slots_pct.olmo", "span_attr_mean", "state_slots"),
     ("scan_pad_pct.olmo", "span_attr_ratio", "scan_pad_chunks"),
     ("prefill_roofline.olmo", "roofline", "prefill"),
+    ("decode_step_roofline.solar", "roofline_span",
+     ["experts_held_touched", "live_positions", "state_slots"]),
+    ("paged_kernel_roofline.solar", "roofline_kernel",
+     "^%?paged_decode_attention"),
+    ("kda_step_roofline.solar", "roofline_kernel", "^%?gated_delta_step"),
+    ("kda_chunk_roofline.solar", "roofline_kernel_prefill",
+     "^%?gated_delta_chunk"),
+    ("kda_kernel_share_pct.solar", "trace_op_share", "^%?gated_delta"),
+    ("state_slots_pct.solar", "span_attr_mean", "state_slots"),
+    ("scan_pad_pct.solar", "span_attr_ratio", "scan_pad_chunks"),
+    ("prefill_roofline.solar", "roofline", "prefill"),
+    ("moe_held_touched_pct.solar", "span_attr_mean",
+     "experts_held_touched"),
+    ("moe_pairs_held_pct.solar", "span_attr_ratio", "pairs_held"),
 ])
 def test_each_new_metric_reads_a_span_a_counter_or_a_named_kernel(
         name, reader, reads):
@@ -115,38 +167,61 @@ def test_each_new_metric_reads_a_span_a_counter_or_a_named_kernel(
                            "gated_delta.py")) as f:
         kernels = f.read()
     for attr in ("state_slots", "live_positions", "scan_tokens",
-                 "scan_chunks", "scan_pad_chunks"):
+                 "scan_chunks", "scan_pad_chunks", "pairs_routed",
+                 "pairs_held", "experts_held_touched"):
         assert attr + "=" in engine
     assert 'name="gated_delta_step"' in kernels
     assert 'name="gated_delta_chunk"' in kernels
 
 
-def test_the_new_configuration_cuts_depth_and_nothing_else():
-    cfg = _json("configs", "olmo-hybrid-7b.json")
+@pytest.mark.parametrize("cell", list(ADDED))
+def test_an_added_configuration_cuts_what_it_says_and_no_width(cell):
+    added = ADDED[cell]
+    cfg = _json("configs", added["config"] + ".json")
     entry, = [c for c in manifest.SPEC["configs"]
-              if c["name"] == "olmo-hybrid-7b"]
-    assert entry["reduced"] == cfg["reduced"] \
-        == ["num_hidden_layers", "layer_types"]
+              if c["name"] == added["config"]]
+    assert entry["reduced"] == cfg["reduced"] == added["reduced"]
     assert entry["source"] == cfg["source"]
-    assert (cfg["hidden_size"], cfg["num_attention_heads"],
-            cfg["intermediate_size"], cfg["vocab_size"],
-            cfg["linear_key_head_dim"], cfg["linear_value_head_dim"],
-            cfg["linear_num_value_heads"], cfg["linear_conv_kernel_dim"]) \
-        == (3840, 30, 11008, 100352, 96, 192, 30, 4)
+    if cell == OLMO:
+        assert (cfg["hidden_size"], cfg["num_attention_heads"],
+                cfg["intermediate_size"], cfg["vocab_size"],
+                cfg["linear_key_head_dim"], cfg["linear_value_head_dim"],
+                cfg["linear_num_value_heads"],
+                cfg["linear_conv_kernel_dim"]) \
+            == (3840, 30, 11008, 100352, 96, 192, 30, 4)
+        assert cfg["layer_types"] == cfg["published"]["layer_types"][:4]
+        assert cfg["published"]["num_hidden_layers"] == 32
+    else:
+        lin = cfg["linear_attn_config"]
+        assert (cfg["hidden_size"], cfg["num_attention_heads"],
+                cfg["num_key_value_heads"], cfg["head_dim"],
+                cfg["moe_intermediate_size"], cfg["num_experts_per_tok"],
+                cfg["n_shared_experts"], lin["num_heads"], lin["head_dim"],
+                lin["short_conv_kernel_size"], cfg["rms_norm_eps"]) \
+            == (4096, 64, 8, 128, 1280, 8, 1, 64, 128, 4, 1e-5)
+        # the guide's floors: a whole period, 8 experts or more held, an
+        # eighth of the vocabulary or more
+        assert cfg["gqa_layers"] == cfg["published"]["gqa_layers"][:1]
+        assert cfg["published"] == {
+            "num_hidden_layers": 48, "gqa_layers": list(range(0, 48, 4)),
+            "n_routed_experts": 320, "vocab_size": 196608}
+        assert cfg["n_routed_experts"] == 20 >= 8
+        assert cfg["expert_share"]["router_experts"] == 320
+        assert cfg["vocab_size"] * 8 == 196608
     assert cfg["num_hidden_layers"] == 4
-    assert cfg["layer_types"] == cfg["published"]["layer_types"][:4]
-    assert cfg["published"]["num_hidden_layers"] == 32
     for key in ("assumed", "as_run", "deployment", "check_tolerance",
                 "rehearse", "builder"):
         assert key in cfg
     assert len(cfg["check_tolerance"]["why"]) > 200
 
 
-def test_the_new_mix_is_closed_loop_over_whole_chunks_and_pages():
-    mix = _json("traffic", "longdoc-pool.json")
+@pytest.mark.parametrize("cell", list(ADDED))
+def test_an_added_mix_is_closed_loop_over_whole_chunks_and_pages(cell):
+    added = ADDED[cell]
+    mix = _json("traffic", added["mix"] + ".json")
     e = mix["engine"]
     assert (mix["driver"], mix["loop"], mix["workers_per_slot"],
-            mix["block"]) == ("serve_delta", "closed", 2, 16)
+            mix["block"]) == (added["driver"], "closed", 2, 16)
     assert all(b % 64 == 0 and b % e["page_tokens"] == 0
                for b in e["prefill_buckets"])
     assert mix["prompt_len"]["max"] + mix["output_len"]["max"] \
@@ -154,7 +229,7 @@ def test_the_new_mix_is_closed_loop_over_whole_chunks_and_pages():
     assert not (e["prefill_chunk"] or e["prefix_reuse"] or e["speculate"])
     rungs = sorted(e["prefill_buckets"])
     assert [min(b for b in rungs if b >= n)
-            for n in mix["reference_prompts"]] == [512, 2048, 6144]
+            for n in mix["reference_prompts"]] == added["rungs"]
 
 
 # -- PR 42: the exposed share of collectives, asynchronous ones counted -----
@@ -171,8 +246,7 @@ def _reader(name):
 def test_the_dp4_cell_reports_both_exposed_shares():
     by_name = {m["name"]: m for m in manifest.PER_LAYER}
     old = by_name["collective_exposed_pct.train"]
-    new = manifest.PER_LAYER[-1]
-    assert new["name"] == "collective_exposed_all_pct.train"
+    new = by_name["collective_exposed_all_pct.train"]
     for key in ("unit", "better", "source", "layer", "moves", "workloads"):
         assert new[key] == old[key], key
     assert _json("metrics", new["name"] + ".json")["reader"] \

@@ -2,7 +2,13 @@
 """The gated delta rule's kernels against their XLA formulations, on the
 chip.
 
-    chiprun -- python tools/gated_delta_microbench.py
+    chiprun -- python tools/gated_delta_microbench.py [channel]
+
+With ``channel``: the decay a vector a key channel at 64 heads of 128 x
+128 over 64 slots (``solar-open2-250b``), the step at 1, 4, 8, 16 and 32
+heads a block, the scan over rungs of 1024 and 4096 (its terms made eight
+heads a turn, as the op makes them); written to
+``chiprun_out/gated_delta_microbench_channel.json``.
 
 Times, at a hybrid decoder's published head sizes (30 heads, keys of 96,
 values of 192, float32): the decode step over 28 slots, a hundred steps
@@ -29,7 +35,10 @@ import time
 sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
-H, DK, DV, SLOTS = 30, 96, 192, 28
+CHANNEL = "channel" in sys.argv[1:]
+H, DK, DV, SLOTS = (64, 128, 128, 64) if CHANNEL else (30, 96, 192, 28)
+HEADS_BLOCKS = (1, 4, 8, 16, 32) if CHANNEL else (1, 10, 30)
+RUNGS = (1024, 4096) if CHANNEL else (2048, 6144)
 HBM = 819e9
 
 
@@ -73,7 +82,9 @@ def main() -> int:
     n = SLOTS
     state = draw(0, n + 1, H, DK, DV)
     q, k = unit(draw(1, n, H, DK)) * DK ** -0.5, unit(draw(2, n, H, DK))
-    v, g = draw(3, n, H, DV), -jnp.abs(draw(4, n, H))
+    # the log decay: one value a head, or one a key channel
+    gdim = (DK,) if CHANNEL else ()
+    v, g = draw(3, n, H, DV), -jnp.abs(draw(4, n, H, *gdim))
     beta = 2 * jax.nn.sigmoid(draw(5, n, H))
     live = jnp.ones((n,), jnp.int32)
     step_bytes = 2 * n * H * DK * DV * 4
@@ -93,7 +104,7 @@ def main() -> int:
     xla = looped(lambda *a: gd.step(*a, live.astype(bool)))
     say("step, XLA contractions",
         timed(xla, q, k, v, g, beta, state, reps=3) / loops, step_bytes)
-    for hb in (1, 10, 30):
+    for hb in HEADS_BLOCKS:
         what = f"step, Pallas, {hb} heads a block"
         try:
             fn = looped(lambda *a, hb=hb: kern.step(*a, live,
@@ -102,13 +113,14 @@ def main() -> int:
                 step_bytes)
         except Exception as e:  # noqa: BLE001 — a block the chip refuses
             print(f"{what}: refused: {str(e)[:300]}", flush=True)
-    for T in (2048, 6144):
+    for T in RUNGS:
         q, k = unit(draw(6, 1, T, H, DK)) * DK ** -0.5, \
             unit(draw(7, 1, T, H, DK))
-        v, g = draw(8, 1, T, H, DV), -jnp.abs(draw(9, 1, T, H))
+        v, g = draw(8, 1, T, H, DV), -jnp.abs(draw(9, 1, T, H, *gdim))
         beta = 2 * jax.nn.sigmoid(draw(10, 1, T, H))
         valid = jnp.asarray([T - 100], jnp.int32)
-        nbytes = 4 * (H * (2 * DK + 2 * DV + 2) * T + H * DK * DV)
+        nbytes = 4 * (H * (2 * DK + 2 * DV + 1 + (DK if CHANNEL else 1)) * T
+                      + H * DK * DV)
         scan = jax.jit(lambda *a: gd.chunked(*a, valid=valid))
         both = jax.jit(lambda *a: gd.chunked(*a, valid=valid,
                                              carry=kern.carry_chunks))
@@ -119,8 +131,17 @@ def main() -> int:
         N = T // gd.CHUNK
         lay = [jnp.moveaxis(x.reshape((1, N, gd.CHUNK) + x.shape[2:]), 3, 1)
                for x in (q, k, v, g, beta)]
-        terms = jax.jit(gd.chunk_terms)(*lay)
         s0 = jnp.zeros((1, H, DK, DV), jnp.float32)
+        if CHANNEL:
+            # all 64 heads' terms at once are 3.3 GB at 4096: the op makes
+            # them HEAD_GROUP heads a turn, and so does this line
+            grouped = jax.jit(lambda *a: gd._by_head_groups(
+                lambda *x: gd.chunk_terms_channel(*x), *a))
+            say(f"chunk {T}, the terms alone (XLA, "
+                f"{gd.HEAD_GROUP} heads a turn)",
+                timed(grouped, *lay, reps=5), nbytes)
+            continue
+        terms = jax.jit(gd.chunk_terms)(*lay)
         say(f"chunk {T}, the Pallas pass alone",
             timed(kern.carry_chunks, terms, s0, reps=5), nbytes)
         say(f"chunk {T}, lax.scan alone",
@@ -128,7 +149,8 @@ def main() -> int:
         say(f"chunk {T}, the terms alone (XLA)",
             timed(jax.jit(gd.chunk_terms), *lay, reps=5), nbytes)
     os.makedirs("chiprun_out", exist_ok=True)
-    with open("chiprun_out/gated_delta_microbench.json", "w") as f:
+    with open("chiprun_out/gated_delta_microbench"
+              + ("_channel" if CHANNEL else "") + ".json", "w") as f:
         json.dump(rows, f, indent=1)
     return 0
 
