@@ -516,8 +516,10 @@ def _forward_logits(gen, model, token_ids, seq):
 def serve_phase(cfg=SERVE, on_chip=True):
     from paddle_tpu import promtext
     from paddle_tpu.serving import GenerationEngine, ServingEngine, serve
+    from paddle_tpu.serving.streams import stream_writer
 
     paths0, writes0 = attention_paths(), pool_writes()
+    streams0 = stream_writer.stats()
     model = dict(vocab_size=cfg["vocab"], hidden=cfg["hidden"],
                  num_layers=cfg["layers"], num_heads=cfg["heads"],
                  num_kv_heads=cfg["heads"], intermediate=cfg["ffn"])
@@ -621,6 +623,16 @@ def serve_phase(cfg=SERVE, on_chip=True):
               f"/metrics {status}: {errors[:3]}")
         say(f"serve: /healthz ready, /metrics {len(text.splitlines())} "
             f"valid exposition lines")
+        # the streamed prompt's lines left through the one stream
+        # writer: a wake-up a booking batch, its start and its summary
+        writer = {k: v - streams0[k]
+                  for k, v in health["generation"]["stream_writer"].items()}
+        check(writer["lines"] == new and 0 < writer["wakeups"] <= new + 2
+              and writer["would_block"] == 0 and writer["open"] == 0
+              and "serving_stream_writer_wakeups" in text,
+              f"the stream writer did not write the streamed prompt's "
+              f"{new} lines: {writer}")
+        say(f"serve: stream writer {writer}")
         paths = paths_since(paths0)
         say(f"serve: attention lowered as {paths}")
         # a prefill bucket of whole pages puts its prompt into every pool

@@ -117,7 +117,12 @@ track (``generation_slots`` via ``telemetry.counter_sample``).
 ``submit(on_token=...)`` registers a per-token callback ((token_id,
 monotonic_ts), called on the scheduler thread, exceptions contained)
 — the HTTP ``stream`` mode and the loadgen's client-side TTFT/ITL
-measurement hang off it.  All of it is admission-time gated: with
+measurement hang off it.  The HTTP streams' callback only appends to
+the process's stream writer (``serving/streams.py``); where a booking
+batch ends (a settled step, a prefill's first token, a speculative
+round, an adoption's replay) the scheduler calls
+``stream_writer.flush()``, which wakes the writer once if anything was
+pushed.  All of it is admission-time gated: with
 ``FLAGS_telemetry=0`` and no callback, the per-token cost is zero
 extra work.
 
@@ -184,7 +189,7 @@ from . import batcher
 from . import usage
 from .engine import (OverloadedError, PoisonedInput, RequestFailed,
                      ServingFuture, poison_sentinel_matches)
-from .server import stream_meter
+from .streams import stream_meter, stream_writer
 from .sharded import describe_mesh as _describe_mesh
 
 __all__ = ["GenerationEngine", "GenRequest", "PagePool", "PrefixIndex",
@@ -1857,7 +1862,7 @@ class GenerationEngine:
                 active = self._iteration(claimed, claim)
             finally:
                 if it is not None:
-                    # what the stream handlers took of the interpreter
+                    # what the stream writer took of the interpreter
                     # since the last pass ended
                     seen, self._stream_seen = \
                         self._stream_seen, stream_meter.totals()
@@ -2118,6 +2123,7 @@ class GenerationEngine:
                     logger.warning("on_token callback failed (token "
                                    "dropped from stream): %s", e)
                     req.on_token = None
+        stream_writer.flush()
         req.t_last = now
         self._publish_pool_gauges()
         # a segment can arrive already finished (EOS at prefill, or a
@@ -2623,6 +2629,7 @@ class GenerationEngine:
         if req.bb is not None:
             blackbox.request_phase(req.bb, "decoding")
         self._book_token(slot, first, time.monotonic())
+        stream_writer.flush()
 
     def _enter_blocks(self, slot: _Slot, req: GenRequest, n_rows: int):
         """A block-diffusion sequence enters the grid after its prefill
@@ -2888,6 +2895,7 @@ class GenerationEngine:
                 self._book_token(slot, tok, t1)
                 if slot.req is None:
                     break  # finished mid-burst (_finish freed pages)
+            stream_writer.flush()
             if slot.req is not None:
                 self._rollback_draft_pages(
                     slot, max(keep,
@@ -3054,14 +3062,17 @@ class GenerationEngine:
                 span.attrs["finished"] = sum(s.req is None for s in rows)
         finally:
             telemetry.span_end(span)
+            # one wake-up for the step's lines, however many streams
+            stream_writer.flush()
 
     def _release_step(self, fl: _StepInFlight):
         """Let go of a settled step's device arrays, here and not
         wherever a caller's frame ends: the runtime gives up the
-        interpreter to free them, and the stream handlers that the
-        booking just woke run before this thread has it back (6 ms a
-        pass with 64 streams, PERF.md section 6, PR 36), so the wait has
-        a span, ``generation/release``."""
+        interpreter to free them, and the stream writer that the booking
+        just woke may run before this thread has it back (until PR 44 a
+        handler thread a stream did: 6 ms a pass with 64 streams,
+        PERF.md section 6, PR 36), so the wait has a span,
+        ``generation/release``."""
         span = telemetry.span_begin("generation/release", cpu=True)
         fl.outs = None
         telemetry.span_end(span)
@@ -3612,6 +3623,9 @@ class GenerationEngine:
             "draining": draining,
             "weights_version": self.weights_version,
             "counters": n,
+            # the process's one writer of token streams (every engine
+            # of the process reads the same figures)
+            "stream_writer": stream_writer.stats(),
             "tokens_per_request": round(
                 n["generated_tokens"] / max(n["served"], 1), 2),
             "generate_ms": self._h_gen.summary(),

@@ -34,7 +34,11 @@ Endpoints:
   one ``{"i", "token"}`` line per token AS IT IS GENERATED, then one
   ``{"done": true, ...result}`` summary line; framed by ``Connection:
   close`` (no Content-Length), which is what lets a client measure
-  true TTFT and inter-token latency.  Sheds → **503**
+  true TTFT and inter-token latency.  The handler thread sends the
+  status line and headers and then sleeps on the request's future;
+  every token line of every stream is written by the process's one
+  stream-writer thread (``serving/streams.py``), which the scheduler
+  wakes once a booking batch.  Sheds → **503**
   like ``/predict``; malformed or over-long prompts → 400; no
   generator attached → 404.
 * ``POST /swap`` — in-place weight hot-swap: body ``{"dir":
@@ -93,6 +97,7 @@ from ..flags import all_flags, flag_value
 from ..monitor import process_uptime_s, stat_add
 from . import usage
 from .engine import OverloadedError, RequestFailed, ServingEngine
+from .streams import stream_writer
 
 __all__ = ["ServingServer", "serve"]
 
@@ -124,39 +129,6 @@ VERSION_HEADER = "X-PaddleTPU-Weights-Version"
 # decode cost land on the same tenant; absent/malformed values book
 # under FLAGS_usage_default_tenant
 TENANT_HEADER = "X-PaddleTPU-Tenant"
-
-
-class StreamMeter:
-    """What the streaming handler threads take, process-wide: seconds
-    inside ``wfile.write`` + ``flush`` and thread CPU seconds of the
-    handler loops (a span a token would push a window's spans out of
-    the ring).  A handler adds its share under the lock every
-    ``EVERY`` tokens written and when its stream ends: the thread clock
-    is a system call, and 64 handlers read it while the scheduler waits
-    for the interpreter.  The generation scheduler reads both sums at
-    the end of a pass and writes the differences on its
-    ``generation/iteration`` span (``stream_write_ms``,
-    ``stream_cpu_ms``): the handlers share one interpreter with it."""
-
-    EVERY = 16
-
-    def __init__(self):
-        self._lock = threading.Lock()
-        self._write_s = 0.0
-        self._cpu_s = 0.0
-
-    def add(self, write_s: float, cpu_s: float):
-        with self._lock:
-            self._write_s += write_s
-            self._cpu_s += cpu_s
-
-    def totals(self):
-        """``(write seconds, CPU seconds)`` so far."""
-        with self._lock:
-            return self._write_s, self._cpu_s
-
-
-stream_meter = StreamMeter()
 
 
 def parse_trace_header(value) -> Optional[str]:
@@ -589,6 +561,9 @@ class _Handler(_JsonHandler):
                "trace_id": tid}
         if deadline_ms is not None:
             rec["deadline_ms"] = deadline_ms
+        if payload.get("stream"):
+            rec["streamed_tokens"] = payload["streamed_tokens"]
+            rec["client_gone"] = payload["client_gone"]
         if trace:
             rec["rows"] = trace.get("rows")
             rec["phases"] = trace.get("phases")
@@ -761,12 +736,13 @@ class _Handler(_JsonHandler):
                          speculate: Optional[bool] = None,
                          tenant: Optional[str] = None):
         """``{"stream": true}`` generation: one NDJSON line per token,
-        written the moment the scheduler books it (the engine's
-        ``on_token`` hook feeds a handler-side queue, so a slow client
-        never blocks the decode grid), then a final ``{"done": true,
-        ...}`` summary line carrying the full result record (timeline
-        included).  No Content-Length — the response frames by
-        ``Connection: close``, which urllib and the loadgen read
+        written in the pass that booked it (the engine's ``on_token``
+        hook appends to the stream writer's pending batch and the
+        writer sends on non-blocking sockets, so a slow client never
+        blocks the decode grid or another stream), then a final
+        ``{"done": true, ...}`` summary line carrying the full result
+        record (timeline included).  No Content-Length — the response
+        frames by ``Connection: close``, which urllib and the loadgen read
         line-by-line; that is what makes CLIENT-side TTFT and
         inter-token latency measurable at all.  Admission sheds and
         bad prompts still answer plain JSON (nothing streamed yet).
@@ -789,114 +765,92 @@ class _Handler(_JsonHandler):
     def _stream_from(self, gen, submit, hop_trace: Optional[str],
                      deadline_ms: Optional[float]):
         """Shared NDJSON streaming core: ``submit(on_token)`` starts
-        the generation (a prompt submit or a segment adopt) and the
-        handler copies tokens to the wire as they are booked."""
-        import queue as queue_mod
-
+        the generation (a prompt submit or a segment adopt).  This
+        thread sends the status line and headers, hands its socket to
+        the process's stream writer (``serving/streams.py``), which
+        writes every token line in the pass that booked it, and sleeps
+        on the request's future.  Woken once, it encodes the summary
+        line, gives it to the writer and waits for it to leave."""
         from .disagg import SegmentMismatch
 
-        q: queue_mod.Queue = queue_mod.Queue()
-        t0 = time.monotonic()
+        stream = stream_writer.open(self.connection)
         try:
-            fut = submit(lambda tok, ts: q.put((tok, ts)))
-        except OverloadedError as e:
-            return 503, {"error": "overloaded", "reason": e.reason,
-                         "detail": str(e),
-                         "retry_after_s": round(gen.retry_after_s(), 3),
-                         "trace_id": getattr(e, "trace_id", None)}, None
-        except SegmentMismatch as e:
-            return 409, {"error": "segment_mismatch",
-                         "detail": str(e)}, None
-        except ValueError as e:
-            return 400, {"error": "bad request", "detail": str(e)}, None
-        self.send_response(200)
-        self.send_header("Content-Type", "application/x-ndjson")
-        self.send_header("Connection", "close")
-        self.send_header(VERSION_HEADER,
-                         str(self.engine.weights_version))
-        if hop_trace:
-            self.send_header(TRACE_HEADER, hop_trace)
-        self.end_headers()
-        self.close_connection = True
-        wait_s = self._wait_s(deadline_ms)
-        t_give_up = None if wait_s is None else t0 + wait_s
-        n = 0
-        client_gone = False
-        timed_out = False
-        # (metered only with telemetry on: no clock is read without it)
-        metered = telemetry.enabled()
-        cpu0 = time.thread_time() if metered else 0.0
-        write_s = 0.0
-        while True:
+            t0 = time.monotonic()
             try:
-                tok, ts = q.get(timeout=0.05)
-            except queue_mod.Empty:
-                if fut.done() and q.empty():
-                    break
-                if t_give_up is not None \
-                        and time.monotonic() > t_give_up:
-                    timed_out = True
-                    break
-                continue
-            n += 1
-            if client_gone:
-                continue  # drain for accounting, write nothing
-            line = json.dumps({"i": n, "token": int(tok)}) + "\n"
-            w0 = time.monotonic() if metered else 0.0
+                fut = submit(stream.push)
+            except OverloadedError as e:
+                return 503, {
+                    "error": "overloaded", "reason": e.reason,
+                    "detail": str(e),
+                    "retry_after_s": round(gen.retry_after_s(), 3),
+                    "trace_id": getattr(e, "trace_id", None)}, None
+            except SegmentMismatch as e:
+                return 409, {"error": "segment_mismatch",
+                             "detail": str(e)}, None
+            except ValueError as e:
+                return 400, {"error": "bad request",
+                             "detail": str(e)}, None
+            self.send_response(200)
+            self.send_header("Content-Type", "application/x-ndjson")
+            self.send_header("Connection", "close")
+            self.send_header(VERSION_HEADER,
+                             str(self.engine.weights_version))
+            if hop_trace:
+                self.send_header(TRACE_HEADER, hop_trace)
+            self.end_headers()
+            self.close_connection = True
+            stream_writer.register(stream)
+            wait_s = self._wait_s(deadline_ms)
+            t_give_up = None if wait_s is None else t0 + wait_s
+            final = {"done": True}
+            status = 200
             try:
-                self.wfile.write(line.encode())
-                self.wfile.flush()
-            except OSError:
-                # the client hung up mid-stream: the sequence keeps
-                # generating (no cancellation), we just stop writing
-                client_gone = True
-            if metered:
-                write_s += time.monotonic() - w0
-                if n % StreamMeter.EVERY == 0:
-                    # this loop's CPU since the last reading (the polls
-                    # of an empty queue between tokens with it)
-                    cpu1 = time.thread_time()
-                    stream_meter.add(write_s, cpu1 - cpu0)
-                    cpu0, write_s = cpu1, 0.0
-        if metered:
-            stream_meter.add(write_s, time.thread_time() - cpu0)
-        final = {"done": True}
-        status = 200
-        try:
-            # the loop only exits with the future resolved or the wait
-            # budget spent — never block the handler a second time
-            res = dict(fut.result(0.001))
-            res.pop("logits", None)
-            res["ms"] = round((time.monotonic() - t0) * 1e3, 3)
-            res["streamed_tokens"] = n
-            final.update(res)
-        except (RequestFailed, TimeoutError) as e:
-            status = 500
-            final.update({"error": "request failed",
-                          "detail": "stream timeout" if timed_out
-                          else str(e)})
-        except OverloadedError as e:
-            # shed after admission (draining close): surfaced on the
-            # final line — the HTTP status is long gone
-            status = 503
-            final.update({"error": "overloaded", "reason": e.reason,
-                          "detail": str(e)})
-        if not client_gone:
-            try:
-                self.wfile.write((json.dumps(final) + "\n").encode())
-                self.wfile.flush()
-            except OSError:
-                client_gone = True
-        summary = {"http_status": status, "stream": True,
-                   "streamed_tokens": n, "client_gone": client_gone,
-                   "trace_id": final.get("trace_id") or hop_trace}
-        trace = {"trace_id": summary["trace_id"],
-                 "rows": final.get("steps"),
-                 "status": ("ok:" + final.get("finish", "")
-                            if status == 200 else f"error:{status}"),
-                 "phases": {"queue_wait_ms": final.get("queue_wait_ms"),
-                            "predict_ms": final.get("prefill_ms")}}
-        return None, summary, trace
+                res = dict(fut.result(
+                    None if t_give_up is None
+                    else max(t_give_up - time.monotonic(), 0.0)))
+                res.pop("logits", None)
+                res["ms"] = round((time.monotonic() - t0) * 1e3, 3)
+                final.update(res)
+            except (RequestFailed, TimeoutError) as e:
+                status = 500
+                final.update({"error": "request failed",
+                              "detail": "stream timeout"
+                              if isinstance(e, TimeoutError) else str(e)})
+            except OverloadedError as e:
+                # shed after admission (draining close): surfaced on the
+                # final line — the HTTP status is long gone
+                status = 503
+                final.update({"error": "overloaded", "reason": e.reason,
+                              "detail": str(e)})
+            # (every token was pushed before the future resolved; one
+            # that timed out goes on being pushed to, and is not read)
+            n = stream.pushed
+            if status == 200:
+                final["streamed_tokens"] = n
+            stream_writer.finish(stream,
+                                 (json.dumps(final) + "\n").encode())
+            # the summary leaves behind the last token line; a client
+            # that has not taken both within the budget (a second of
+            # grace past it) is dropped by close()
+            stream.done.wait(None if t_give_up is None
+                             else max(t_give_up - time.monotonic(), 1.0))
+            summary = {"http_status": status, "stream": True,
+                       "streamed_tokens": n,
+                       "client_gone": stream.client_gone
+                       or not stream.done.is_set(),
+                       "trace_id": final.get("trace_id") or hop_trace}
+            trace = {"trace_id": summary["trace_id"],
+                     "rows": final.get("steps"),
+                     "status": ("ok:" + final.get("finish", "")
+                                if status == 200 else f"error:{status}"),
+                     "phases": {
+                         "queue_wait_ms": final.get("queue_wait_ms"),
+                         "predict_ms": final.get("prefill_ms")}}
+            return None, summary, trace
+        finally:
+            # the socket is this thread's again (a stream whose summary
+            # never left is dropped first)
+            stream_writer.close(stream)
 
     def _wait_s(self, deadline_ms: Optional[float]) -> Optional[float]:
         """How long the handler thread blocks for the future: the
@@ -1029,6 +983,8 @@ class ServingServer:
                         "access_log": self.access_log})
         self._httpd = ThreadingHTTPServer((host, port), handler)
         self._httpd.daemon_threads = True
+        # the process's stream writer lives while a server may stream
+        stream_writer.acquire()
         self.host, self.port = self._httpd.server_address[:2]
         self._thread: Optional[threading.Thread] = None
         self._closed = False
@@ -1077,6 +1033,7 @@ class ServingServer:
         self._closed = True
         self.engine.close(drain=drain, timeout=timeout)
         self._stop_listener()
+        stream_writer.release()
         if self._thread is not None:
             self._thread.join(timeout)
         self.access_log.close()

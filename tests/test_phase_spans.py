@@ -434,7 +434,7 @@ from paddle_tpu.framework.executor import FetchHandle  # noqa: E402
 from paddle_tpu.monitor import stat_get  # noqa: E402
 from paddle_tpu.serving import generation  # noqa: E402
 from paddle_tpu.serving.generation import DeviceAccount  # noqa: E402
-from paddle_tpu.serving.server import stream_meter  # noqa: E402
+from paddle_tpu.serving.streams import stream_meter  # noqa: E402
 
 
 class _Program:
@@ -760,11 +760,12 @@ def test_the_phases_leave_little_of_a_pass_unnamed(engine):
             "generation/prefill"} <= direct
 
 
-def test_stream_cpu_ms_sums_to_the_handlers_accumulator():
-    """Streamed tokens are metered by the handler threads, process-wide;
-    every pass writes what was added since the pass before it, so the
-    iterations' ``stream_cpu_ms`` and ``stream_write_ms`` sum to the
-    accumulators' growth."""
+def test_stream_cpu_ms_sums_to_the_writers_accumulator():
+    """Streamed tokens are metered by the process's stream writer (its
+    thread CPU seconds and its seconds inside ``send``, added before a
+    stream's end is signalled); every pass writes what was added since
+    the pass before it, so the iterations' ``stream_cpu_ms`` and
+    ``stream_write_ms`` sum to the accumulators' growth."""
     import json
     import threading
 
@@ -809,7 +810,7 @@ def test_stream_cpu_ms_sums_to_the_handlers_accumulator():
         for w in workers:
             w.join()
         write1, cpu1 = stream_meter.totals()
-        # the handlers are done: one more pass picks up what the last
+        # the streams are done: one more pass picks up what the last
         # streamed tokens added after their own pass had closed
         done = len(_named(telemetry.get_spans(), "generation/iteration"))
         gen.generate(PROMPT, 1, timeout=120)
