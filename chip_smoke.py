@@ -610,6 +610,22 @@ def serve_phase(cfg=SERVE, on_chip=True):
             f"{rel:.4g} of the uncached einsum forward's range "
             f"(tolerance {TOL})")
 
+        # two overlapping prompts: the second finishes its prefill with a
+        # step of the first in flight and rides the step dispatched ahead
+        # on its prefill's token as the device holds it, through the one
+        # decode executable
+        joined0 = gen.stats()["counters"]["decode_joiners_ahead"]
+        pair = [gen.submit(p, new) for p in prompts[:2]]
+        want = [gen.generate(p, new)["tokens"] for p in prompts[:2]]
+        joined = gen.stats()["counters"]["decode_joiners_ahead"] - joined0
+        compiled = gen._decode_exe.cache_info()["compiled"]
+        check([f.result(600)["tokens"] for f in pair] == want
+              and joined >= 1 and compiled == 1,
+              f"a joiner did not ride the step ahead: {joined} joined, "
+              f"{compiled} decode executables")
+        say(f"serve: {joined} joiner rode the step ahead of the settle, "
+            f"{compiled} decode executable")
+
         status, body = _http(server.url + "/healthz")
         health = json.loads(body)
         check(status == 200 and health.get("ready")

@@ -552,10 +552,14 @@ class _StepInFlight:
     """A decode grid step the device has been handed and the host has
     not read yet.  ``riders`` are the ``(slot, request)`` pairs of its
     live rows: a row is booked at the settle only if its slot still
-    serves that request.  ``outs`` are the step's fetch handles by
-    name, ``t0`` the moment the device could begin it (its feeds'
-    start, or the settle of the step before when it was dispatched
-    ahead of that), ``released`` the window pages its feeds let go."""
+    serves that request.  A joiner (a sequence whose prefill was
+    launched behind the step before and is read only after this step's
+    dispatch, :class:`_Joiner`) is a rider like the others: its row is
+    discarded if its first token ended it or its prefill failed.
+    ``outs`` are the step's fetch handles by name, ``t0`` the moment
+    the device could begin it (its feeds' start, or the settle of the
+    step before when it was dispatched ahead of that), ``released`` the
+    window pages its feeds let go."""
 
     __slots__ = ("riders", "outs", "links", "t0", "ahead", "released")
 
@@ -566,6 +570,58 @@ class _StepInFlight:
         self.t0 = t0
         self.ahead = ahead
         self.released = released
+
+
+class _Joiner:
+    """A finished prefill the device has been handed and the host has
+    not read yet, launched with a decode step in flight.  Its slot is
+    in the grid already, at the position the prefill leaves it, with no
+    token booked: the next step takes the slot's first input as the
+    device holds it (``outs["next_token"]``; block diffusion: the first
+    block as the host made it) and goes out before the scheduler blocks
+    here.  ``n_rows``: real rows of the program that returned
+    ``outs``."""
+
+    __slots__ = ("slot", "req", "outs", "n_rows")
+
+    def __init__(self, slot, req, outs, n_rows):
+        self.slot = slot
+        self.req = req
+        self.outs = outs
+        self.n_rows = n_rows
+
+
+_JOIN_ROW = None
+
+
+def _join_row(tokens, rows, idx):
+    """``tokens``, what one grid step yields for the next on the device
+    ([slots] greedy tokens; block diffusion: [slots, B] blocks), as the
+    next step's feed [slots, W] with row ``idx`` taken from ``rows``
+    instead: [slots, W] from the host (block diffusion: a joiner's
+    first block), or the one token a prefill yielded, [1], as the
+    device holds it.  One small jitted select, compiled at warm-up
+    (``idx`` -1 takes no row); its result has the array type and width
+    of the carried feed.  It does :meth:`_carried_tokens`' reshape
+    itself, so a joiner's step costs one program and not two, and the
+    reshape alone runs once a step WITHOUT a joiner: less often than
+    the decode program, by which whoever reads a device trace (the
+    benchmark's ``readers/module_time.py``) tells that program from the
+    microsecond ones around it."""
+    global _JOIN_ROW
+    if _JOIN_ROW is None:
+        import jax
+        import jax.numpy as jnp
+
+        def select(tokens, rows, idx):
+            carried = tokens.reshape(tokens.shape[0], -1)
+            rows = rows.astype(carried.dtype).reshape(
+                -1, carried.shape[1])
+            row = jnp.arange(carried.shape[0], dtype=idx.dtype)[:, None]
+            return jnp.where(row == idx, rows, carried)
+
+        _JOIN_ROW = jax.jit(select)
+    return _JOIN_ROW(tokens, rows, np.int32(idx))
 
 
 class GenerationEngine:
@@ -891,6 +947,7 @@ class GenerationEngine:
 
         self._n = {"requests": 0, "shed": 0, "served": 0, "prefills": 0,
                    "decode_steps": 0, "decode_steps_ahead": 0,
+                   "decode_joiners_ahead": 0,
                    "decode_rows_discarded": 0, "generated_tokens": 0,
                    "prefill_tokens": 0, "slot_reclaims": 0,
                    "failed": 0, "prefix_hits": 0,
@@ -937,6 +994,8 @@ class GenerationEngine:
         self._released_in_feeds = 0  # window pages the last feeds freed
         # the one decode grid step dispatched and not yet fetched
         self._inflight: Optional[_StepInFlight] = None
+        # the one finished prefill launched behind it and not yet read
+        self._joining: Optional[_Joiner] = None
 
         if autostart:
             self.start()
@@ -1209,6 +1268,7 @@ class GenerationEngine:
         valid lengths, so every write lands on the trash page (and a
         prefill's slot state on the trash row)."""
         compiled = 0
+        first = None    # the last prefill's first token, on the device
         np_slot = self.pages_per_slot
         if self.role == "decode":
             # a decode-role engine never prefills: the decode step
@@ -1246,14 +1306,15 @@ class GenerationEngine:
                             (1, np_slot), "int32")
                     if self.state_names:   # the trash row, no slot's state
                         feed["slot"] = np.asarray([self.num_slots], "int32")
-                    self._run_fetching(self._prefill_exe, prog, fetches,
-                                       feed)
+                    first = self._run_fetching(
+                        self._prefill_exe, prog, fetches,
+                        feed).get("next_token")
                     compiled += 1
         if self.prefill_chunk > 0 or self.prefix_reuse:
             for b in self._chunk_buckets():
                 if b not in self._chunk_progs:
                     prog, fetches = self._chunk_prog_for(b)
-                    self._prefill_exe.run(
+                    first, = self._prefill_exe.run(
                         prog,
                         feed={"chunk_ids": np.zeros((1, b), "int64"),
                               "base": np.zeros((1,), "int32"),
@@ -1281,25 +1342,38 @@ class GenerationEngine:
                         fetch_list=[fetches["tokens"]],
                         scope=self.scope, return_numpy=False)
                     compiled += 1
-        self._warm_decode()
+        self._warm_decode(first.value if first is not None else None)
         return compiled + 1
 
-    def _warm_decode(self):
-        """Two grid steps over idle rows: the decode program, then the
+    def _warm_decode(self, first=None):
+        """Three grid steps over idle rows: the decode program, then the
         same program fed the first one's tokens as the device holds
         them, which compiles the reshape that carries a step's tokens
-        into the step dispatched ahead of its settle."""
+        into the step dispatched ahead of its settle, then the same
+        again through :func:`_join_row`, which compiles
+        the select that puts a joiner's row among them.  ``first``: a
+        prefill's ``next_token`` as the device holds it (the warm-up's
+        last; a decode-role engine has none and no such joiner)."""
         positions = np.zeros((self.num_slots,), "int32")
-        idle = self._host_tokens(np.zeros((self.num_slots, self._rows),
-                                          "int32"))
+        host = np.zeros((self.num_slots, self._rows), "int32")
+        idle = self._host_tokens(host)
         zeros = np.zeros((self.num_slots,), "int32")
         block = {"masked": idle, "quota": zeros, "fresh": zeros} \
             if self._blk else None
-        outs = self._dispatch_decode(idle, positions, block=block)
-        if self._blk:
-            block["masked"] = outs["masked"].value
-        outs = self._dispatch_decode(self._carried_tokens(outs), positions,
-                                     block=block)
+
+        def ahead(outs, rows):
+            # (row -1: no slot's)
+            tokens = self._carried_tokens(outs, rows)
+            if self._blk:
+                block["masked"] = outs["masked"].value if rows is None \
+                    else _join_row(outs["masked"].value, rows, -1)
+            return self._dispatch_decode(tokens, positions, block=block)
+
+        outs = ahead(self._dispatch_decode(idle, positions, block=block),
+                     None)
+        rows = host if self._blk else first
+        if rows is not None:
+            outs = ahead(outs, rows)
         self._fetch_decode(outs)
 
     # -- lifecycle ----------------------------------------------------------
@@ -2199,7 +2273,8 @@ class GenerationEngine:
         # chunked prefill writes into, so after a mid-step crash no
         # slot's cache state is knowable
         active = self._active()
-        self._inflight = None  # its rows die with their requests
+        # its rows die with their requests, the unread prefill's too
+        self._inflight = self._joining = None
         self._count("failed", len(active))
         stat_add("serving_decode_failures")
         logger.warning("decode step failed; failing %d active "
@@ -2587,11 +2662,46 @@ class GenerationEngine:
 
     def _complete_prefill(self, slot: _Slot, req: GenRequest, outs,
                           n_rows: int):
-        """Shared tail of every prefill path: book the first
-        generated token, publish the prompt's fully-covered pages to
-        the prefix index, and enter the decode grid.  ``n_rows``: real
-        rows of the program that produced ``outs`` (the whole prompt, or
-        its last chunk: only that one's expert counts are fetched)."""
+        """Shared tail of every prefill path: the sequence enters the
+        decode grid at the position its prefill leaves it, with nothing
+        booked, and the prefill is left to :meth:`_join` to read: at
+        once, or, with a decode step in flight, after
+        :meth:`_decode_step` has sent the next step out ahead with this
+        sequence in it.  ``n_rows``: real rows of the program that
+        produced ``outs`` (the whole prompt, or its last chunk: only
+        that one's expert counts are fetched)."""
+        n_prompt = int(req.prompt.size)
+        slot.prefill_pos = n_prompt
+        self._joining = _Joiner(slot, req, outs, n_rows)
+        if self._blk:
+            self._enter_blocks(slot, req, n_rows)
+        else:
+            slot.position, slot.tokens = n_prompt, []
+            # (a prefill-role engine exports the pages instead)
+            slot.decoding = self.role != "prefill"
+        if self._inflight is None:
+            self._join()
+
+    def _join(self):
+        """Read the prefill that :meth:`_complete_prefill` left unread,
+        if there is one: block on its first generated token, publish
+        the prompt's fully-covered pages to the prefix index and book
+        the token.  A prefill that fails here fails its request only:
+        its row of a step dispatched ahead is discarded at that step's
+        settle, as a row whose sequence ended is."""
+        j, self._joining = self._joining, None
+        if j is None:
+            return
+        slot, req = j.slot, j.req
+        try:
+            self._read_prefill(slot, req, j.outs, j.n_rows)
+        except Exception as e:  # noqa: BLE001 — a prefill failure
+            # fails this request only
+            self._fail_request(slot, req, "prefill", e)
+
+    def _read_prefill(self, slot: _Slot, req: GenRequest, outs,
+                      n_rows: int):
+        """:meth:`_join`'s body: whatever raises here fails ``req``."""
         first = self._fetch_first_token(
             slot, outs, slot.span.context() if slot.span is not None
             else None, n_rows)
@@ -2613,11 +2723,9 @@ class GenerationEngine:
             if full:
                 self._prefix.register(req.prompt, slot.pages[:full])
                 self._publish_pool_gauges()
-        slot.prefill_pos = n_prompt
         if self._blk:
-            self._enter_blocks(slot, req, n_rows)
+            # (its first tokens come with the first block's commit pass)
             return
-        slot.position = n_prompt
         slot.tokens = [first]
         if self.role == "prefill":
             # disaggregated prefill: export the populated pages as a
@@ -2625,7 +2733,6 @@ class GenerationEngine:
             # slot (and its pages) free for the next prompt now
             self._export_segment(slot, req)
             return
-        slot.decoding = True
         if req.bb is not None:
             blackbox.request_phase(req.bb, "decoding")
         self._book_token(slot, first, time.monotonic())
@@ -2742,14 +2849,18 @@ class GenerationEngine:
 
         return jax.device_put(tokens.reshape(self.num_slots, -1))
 
-    def _carried_tokens(self, outs: dict):
+    def _carried_tokens(self, outs: dict, rows=None, idx: int = -1):
         """The ``tokens`` feed of the step after the one that returned
         ``outs``: its greedy tokens (block diffusion: the blocks as its
         decisions left them) as the device holds them, never brought to
-        the host."""
-        if self._blk:
-            return outs["tokens"].value
-        return outs["next_token"].value.reshape(self.num_slots, 1)
+        the host.  A joiner did not ride that step: its row ``idx`` is
+        taken from ``rows`` (:func:`_join_row`), its unread prefill's
+        first token as the device holds that one (block diffusion: its
+        first block from the host)."""
+        tokens = outs["tokens" if self._blk else "next_token"].value
+        if rows is not None:
+            return _join_row(tokens, rows, idx)
+        return tokens if self._blk else tokens.reshape(self.num_slots, 1)
 
     def _dispatch_decode(self, tokens, positions: np.ndarray,
                          block_tables: Optional[np.ndarray] = None,
@@ -2910,11 +3021,18 @@ class GenerationEngine:
         while the device works on the next.  That order needs the next
         step built without the last one's tokens on the host: every
         sequence due to ride it rode the step in flight, whose tokens
-        it takes as the device holds them.  After a joiner (a finished
-        prefill, an adopted segment) or with no page left for the
-        position ahead, the settle comes first and the step goes out
-        from the host's tokens: the same pass, the settle placed
-        before the dispatch."""
+        it takes as the device holds them, or is the joiner, the
+        sequence whose finished prefill this pass launched behind that
+        step and has not read (:class:`_Joiner`): its token is the
+        prefill's, as the device holds it.  So a pass that carries a
+        prefill runs: launch the prefill, dispatch the step ahead with
+        the joiner in it, settle the step in flight, and only then
+        block on the prefill and book its first token; the device goes
+        from step to prefill to step without a gap.  With an adopted
+        segment in the grid or no page left for a position ahead, the
+        settle (and the joiner's first token) comes first and the step
+        goes out from the host's tokens: the same pass, the settle
+        placed before the dispatch."""
         kind = fault.fire("decode_step")
         fault.maybe_delay(kind)
         if kind == "fail":
@@ -2949,13 +3067,18 @@ class GenerationEngine:
             telemetry.span_end(step)
         if lead is None:
             return
+        joining = self._joining
+        if joining is not None and any(s is joining.slot for s, _ in riders):
+            self._count("decode_joiners_ahead")
+            stat_add("serving_decode_joiners_ahead")
         self._book_inflight(lead, outs)
+        self._release_step(lead)
+        self._join()
         if not any(s.req is r for s, r in riders):
             # every sequence of the step ahead ended at this settle: no
             # row of it will be booked, and nothing waits for it
             self._inflight = None
             self._discard_rows(len(riders))
-        self._release_step(lead)
 
     def _discard_rows(self, n: int):
         """Count ``n`` rows of a step dispatched ahead that no sequence
@@ -2967,19 +3090,23 @@ class GenerationEngine:
     def _rides_on(self) -> bool:
         """Whether the next grid step can go out ahead of the settle of
         the one in flight: there is one, and every sequence decoding
-        now rode it (no joiner since its dispatch)."""
+        now rode it or is the joiner, whose first input the device
+        holds as it holds the riders' (an adopted segment's tokens are
+        the host's: after one, the settle comes first)."""
         fl = self._inflight
         if fl is None:
             return False
         rode = {s.idx: r for s, r in fl.riders}
-        return all(rode.get(s.idx) is s.req
+        joiner = self._joining.slot if self._joining is not None else None
+        return all(rode.get(s.idx) is s.req or s is joiner
                    for s in self._decoding_slots())
 
     def _settle_inflight(self):
-        """Fetch and book the step in flight, if there is one: what
-        comes before anything that reads or ends the sequences' booked
-        state (a step built from the host's tokens, a speculative
-        draft, a weight swap)."""
+        """Fetch and book the step in flight, if there is one, and then
+        the first token of the prefill launched behind it
+        (:meth:`_join`): what comes before anything that reads or ends
+        the sequences' booked state (a step built from the host's
+        tokens, a speculative draft, a weight swap)."""
         fl, self._inflight = self._inflight, None
         if fl is None:
             return
@@ -2991,6 +3118,7 @@ class GenerationEngine:
             telemetry.span_end(step)
         self._book_inflight(fl, outs)
         self._release_step(fl)
+        self._join()
 
     def _fetch_inflight(self, fl: _StepInFlight, step) -> dict:
         """Wait for ``fl``'s fetches and write what the step did onto
@@ -3104,6 +3232,15 @@ class GenerationEngine:
             telemetry.span_end(span)
         return [(s, s.req) for s in active], feeds, links, t0
 
+    def _joiner_ahead(self, lead: Optional[_StepInFlight]) -> Optional[_Slot]:
+        """The slot that enters the step going out ahead of ``lead``'s
+        settle without having ridden ``lead``: the joiner's, if there
+        is one.  It stands no step past its booked state but AT it: the
+        host knows the position its unread prefill leaves it at."""
+        if lead is None or self._joining is None:
+            return None
+        return self._joining.slot
+
     def _build_decode_feeds(self, skip: frozenset,
                             lead: Optional[_StepInFlight] = None):
         """The host half of a grid step before its dispatch: the page
@@ -3111,17 +3248,20 @@ class GenerationEngine:
         feeds ``(tokens, positions, block_tables, live)``.  With
         ``lead`` the step goes out ahead of ``lead``'s settle: each
         rider sits one position past its booked one, its token is
-        ``lead``'s on the device, and a sequence the host knows will
-        end at ``lead`` (its budget or its cache is one token from
-        full) stays out."""
+        ``lead``'s on the device (the joiner's: its prefill's), and a
+        sequence the host knows will end at ``lead`` or at its first
+        token (its budget or its cache is one token from full) stays
+        out."""
         if self._blk:
             return self._build_block_feeds(lead)
         ahead = int(lead is not None)
+        joiner = self._joiner_ahead(lead)
         riding = [s for s in self._decoding_slots()
                   if s.idx not in skip
                   and not (ahead and (
                       len(s.tokens) + 1 >= s.req.max_new_tokens
-                      or s.position + 1 >= self.max_seq_len))]
+                      or s.position + (s is not joiner)
+                      >= self.max_seq_len))]
         self._released_in_feeds = 0
         # pool-exhaustion guard: a slot about to cross into an
         # unmapped page must get one BEFORE the step (the write
@@ -3132,7 +3272,8 @@ class GenerationEngine:
         # a settle it has more to come: the caller settles first)
         for s in riding:
             try:
-                self._ensure_pages(s, s.position + ahead + 1)
+                self._ensure_pages(
+                    s, s.position + ahead * (s is not joiner) + 1)
             except PoolExhausted:
                 if ahead:
                     raise
@@ -3142,8 +3283,12 @@ class GenerationEngine:
             return active, None
         positions = np.zeros((self.num_slots,), "int32")
         for s in active:
-            positions[s.idx] = s.position + ahead
-        if ahead:
+            positions[s.idx] = s.position + ahead * (s is not joiner)
+        if ahead and joiner in active:
+            tokens = self._carried_tokens(
+                lead.outs, self._joining.outs["next_token"].value,
+                joiner.idx)
+        elif ahead:
             tokens = self._carried_tokens(lead.outs)
         else:
             last = np.zeros((self.num_slots,), "int32")
@@ -3202,10 +3347,13 @@ class GenerationEngine:
         row is its block's next pass (``quota`` 0: the commit pass).
         With ``lead`` the blocks are ``lead``'s on the device, a slot
         whose block ``lead`` commits starts the next one (``fresh``),
-        and a sequence whose last block ``lead`` commits stays out."""
+        and a sequence whose last block ``lead`` commits stays out; the
+        joiner's row is its first block as the host made it, behind the
+        positions its unread prefill commits."""
         B, ahead = self._blk, int(lead is not None)
+        joiner = self._joiner_ahead(lead)
         riding = [s for s in self._decoding_slots()
-                  if not (ahead and not s.blk_left and (
+                  if not (ahead and s is not joiner and not s.blk_left and (
                       len(s.tokens) + self._block_yield(s)
                       >= s.req.max_new_tokens
                       or s.position + 2 * B > self.max_seq_len))]
@@ -3215,7 +3363,8 @@ class GenerationEngine:
                                          for _ in range(4))
         bt = np.zeros((n, self.pages_per_slot), "int32")
         for s in riding:
-            base, left, done, new = self._block_phase(s, ahead)
+            base, left, done, new = self._block_phase(
+                s, ahead * (s is not joiner))
             try:
                 self._ensure_pages(s, base + B)
             except PoolExhausted:
@@ -3229,14 +3378,20 @@ class GenerationEngine:
         active = [s for s in riding if s.req is not None]
         if not active:
             return active, None
-        if ahead:
+        # the blocks the host holds: every rider's, or ahead of a settle
+        # the joiner's alone
+        host = np.zeros((2, n, B), "int32")
+        for s in (active if not ahead else [joiner] if joiner else []):
+            host[0, s.idx], host[1, s.idx] = s.blk_tokens, s.blk_masked
+        if not ahead:
+            tokens, masked = (self._host_tokens(h) for h in host)
+        elif joiner is not None:
+            tokens = self._carried_tokens(lead.outs, host[0], joiner.idx)
+            masked = _join_row(lead.outs["masked"].value, host[1],
+                               joiner.idx)
+        else:
             tokens = self._carried_tokens(lead.outs)
             masked = lead.outs["masked"].value
-        else:
-            host = np.zeros((2, n, B), "int32")
-            for s in active:
-                host[0, s.idx], host[1, s.idx] = s.blk_tokens, s.blk_masked
-            tokens, masked = (self._host_tokens(h) for h in host)
         return active, (tokens, positions, bt, live, None,
                         {"masked": masked, "quota": quota, "fresh": fresh})
 

@@ -477,7 +477,11 @@ def test_fleet_books_sigkill_death_with_postmortems():
         [d] = fz["deaths"]
         assert d["replica"] == 0 and d["attribution"] \
             == "signal:SIGKILL"
-        # the respawn came back serving
+        # the respawn came back serving (it is spawned a backoff after
+        # the death is booked, and wait_ready gives up on a replica
+        # whose process is dead: wait for the new process first)
+        while rep.proc.pid == old_pid and time.monotonic() < deadline:
+            time.sleep(0.05)
         sup.wait_ready(timeout_s=240)
     finally:
         sup.close()

@@ -735,6 +735,48 @@ def test_slots_join_and_leave_in_the_middle_of_others_blocks():
         eng.close()
 
 
+@pytest.mark.parametrize("passes", [1, 2])
+def test_a_joiners_first_block_rides_the_pass_ahead(passes):
+    """A long request is inside its blocks when two others finish their
+    prefills: each joiner's first block, made on the host, goes into the
+    pass dispatched ahead of the settle beside the blocks the device
+    carries, so only the very first pass was not ahead; every pass of
+    every request is the reference's full forward, and the merged feeds
+    bind the one compiled pass."""
+    cfg = _cfg(passes=passes)
+    eng = _engine(cfg)
+    try:
+        eng.warmup()
+        before = _counters(eng)
+        prompts = [_prompt(400 + i, n) for i, n in enumerate([9, 14, 20])]
+        budgets = [40, 6, 11]
+        gate = threading.Event()
+        futs = [eng.submit(prompts[0], budgets[0],
+                           on_token=lambda _t, _ts: gate.set())]
+        assert gate.wait(60)
+        futs += [eng.submit(p, m)
+                 for p, m in zip(prompts[1:], budgets[1:])]
+        results = [f.result(300) for f in futs]
+        for prompt, m, res in zip(prompts, budgets, results):
+            assert res["finish"] == "length"
+            assert res["tokens"] == _generate(eng, cfg, prompt, m)
+            for p, w in zip(res["passes"],
+                            _reference_passes(eng, cfg, prompt, res)):
+                assert _rel(p["logits"], w) < TOL_LOGITS
+        deadline = time.monotonic() + 10.0
+        while eng._inflight is not None and time.monotonic() < deadline:
+            time.sleep(0.005)
+        time.sleep(0.05)
+        n = {k: v - before[k] for k, v in _counters(eng).items()}
+        assert n["decode_joiners_ahead"] == 2
+        assert n["decode_steps_ahead"] == n["decode_steps"] - 1
+        assert n["decode_rows_discarded"] == 0
+        assert eng._pool.live_pages == 0
+        assert eng._decode_exe.cache_info()["compiled"] == 1
+    finally:
+        eng.close()
+
+
 @pytest.mark.parametrize("num_slots", [3, 5])
 def test_passes_of_a_busy_grid_equal_the_references_full_forward(num_slots):
     """Seven requests sent at once through ``num_slots`` slots, so slots

@@ -1,13 +1,17 @@
 """One decode step in flight (PR 29): the scheduler hands grid step n+1
-to the device before it fetches and books step n.
+to the device before it fetches and books step n, also (PR 45) in the
+pass that carries a finished prefill: the joiner rides step n+1 on its
+prefill's first token as the device holds it, and the scheduler blocks
+on that token only after step n+1 has gone out and step n is booked.
 
 What must hold whatever the order of dispatch and settle: every
 sequence's token stream is the one it has when it runs alone; a row of
-a step that overtook its sequence's end is discarded and never booked
-to the slot's next owner; a length-bounded answer costs no extra step;
-pages are mapped before the dispatch that writes them and all return to
-their pools; a fault, a weight swap and a drain meet the step in flight
-and leave nothing behind; per-tenant sums stay equal to the counters.
+a step that overtook its sequence's end (or its joiner's failed
+prefill) is discarded and never booked to the slot's next owner; a
+length-bounded answer costs no extra step; pages are mapped before the
+dispatch that writes them and all return to their pools; a fault, a
+weight swap and a drain meet the step in flight and leave nothing
+behind; per-tenant sums stay equal to the counters.
 
 Toy widths on the CPU: every number here is a count or an ordering.
 """
@@ -110,32 +114,75 @@ def _pages_live(e):
 
 
 # -- (1) streams -------------------------------------------------------------
-def test_streams_equal_solo_streams_with_joiners_midstream(eng, solo):
-    before = _counters(eng)
-    on_a, a_running = _after_tokens(4)
-    fa = eng.submit(PROMPTS[0], 16, on_token=on_a)
-    assert a_running.wait(60)
-    on_b, b_running = _after_tokens(3)
-    fb = eng.submit(PROMPTS[1], 12, on_token=on_b)   # joins a's grid
-    assert b_running.wait(60)
-    fc = eng.submit(PROMPTS[2], 9)                   # joins both
-    fd = eng.submit(PROMPTS[3], 7)                   # waits for a slot
-    got = [f.result(120) for f in (fa, fb, fc, fd)]
-    for res, want, n in zip(got, solo, (16, 12, 9, 7)):
-        assert res["tokens"] == want[:n] and res["finish"] == "length"
-        assert res["steps"] == n - 1
-    _quiet(eng)
-    after = _counters(eng)
-    steps = after["decode_steps"] - before["decode_steps"]
-    ahead = after["decode_steps_ahead"] - before["decode_steps_ahead"]
-    # every step but the first and those right after a joiner went out
-    # ahead of the settle before it
-    assert 0 < ahead < steps
-    assert after["decode_rows_discarded"] == before["decode_rows_discarded"]
-    assert _pages_live(eng) == 0
-    # tokens from the host and tokens carried on the device bind the one
-    # decode executable warm-up compiled
-    assert eng._decode_exe.cache_info()["compiled"] == 1
+def _delta(e, before):
+    return {k: v - before[k] for k, v in _counters(e).items()}
+
+
+@pytest.mark.parametrize("kind", ["paged", "window"])
+def test_streams_equal_solo_streams_with_joiners_midstream(kind):
+    e = _engine(kind)
+    e.warmup()
+    try:
+        budgets = (40, 12, 9, 7)
+        solo = [e.generate(p, n, timeout=120)["tokens"]
+                for p, n in zip(PROMPTS, budgets)]
+        _quiet(e)
+        before = _counters(e)
+        on_a, a_running = _after_tokens(4)
+        fa = e.submit(PROMPTS[0], 40, on_token=on_a)  # outlives the others
+        assert a_running.wait(60)
+        on_b, b_running = _after_tokens(3)
+        fb = e.submit(PROMPTS[1], 12, on_token=on_b)  # joins a's grid
+        assert b_running.wait(60)
+        fc = e.submit(PROMPTS[2], 9)                  # joins both
+        fd = e.submit(PROMPTS[3], 7)                  # waits for a slot
+        got = [f.result(120) for f in (fa, fb, fc, fd)]
+        for res, want, n in zip(got, solo, budgets):
+            assert res["tokens"] == want and res["finish"] == "length"
+            assert res["steps"] == n - 1
+        _quiet(e)
+        n = _delta(e, before)
+        # every step but a's first went out ahead of the settle before
+        # it, the three that carried a joiner too: a finished prefill
+        # does not make the grid wait for its first token
+        assert n["decode_steps_ahead"] == n["decode_steps"] - 1
+        assert n["decode_joiners_ahead"] == 3
+        assert n["decode_rows_discarded"] == 0
+        assert _pages_live(e) == 0
+        # tokens from the host, tokens carried on the device and a
+        # joiner's row among them bind the one decode executable warm-up
+        # compiled
+        assert e._decode_exe.cache_info()["compiled"] == 1
+    finally:
+        e.close()
+
+
+@pytest.mark.parametrize("kind", ["paged", "window"])
+def test_a_joiners_first_step_reads_its_prefills_token_on_the_device(kind):
+    """The joiner's row of the step ahead is its prefill's first token,
+    never fetched first: its logits, from its first decode step on, and
+    the rider's beside it are those of a plain forward over prompt +
+    stream."""
+    e = _engine(kind, keep_logits=True)
+    try:
+        on_a, a_running = _after_tokens(3)
+        fa = e.submit(PROMPTS[0], 40, on_token=on_a)
+        assert a_running.wait(60)
+        fb = e.submit(PROMPTS[2] + PROMPTS[4][:3], 20)    # 10 tokens
+        for prompt, res in ((PROMPTS[0], fa.result(300)),
+                            (PROMPTS[2] + PROMPTS[4][:3], fb.result(300))):
+            ref = uncached_logits(e, prompt + res["tokens"])
+            got = np.stack(res["logits"])
+            want = ref[len(prompt) - 1:len(prompt) - 1 + len(got)]
+            assert np.abs(got - want).max() <= 1e-4 * np.ptp(want)
+            assert res["tokens"] == [int(t) for t in want.argmax(-1)]
+        _quiet(e)
+        c = _counters(e)
+        assert c["decode_joiners_ahead"] == 1
+        assert c["decode_steps_ahead"] == c["decode_steps"] - 1
+        assert _pages_live(e) == 0
+    finally:
+        e.close()
 
 
 @pytest.mark.parametrize("kind", ["paged", "window"])
@@ -197,21 +244,38 @@ def test_dispatch_precedes_fetch_and_ahead_marks_it(paged):
     first = (("decode_dispatch",), 0)
     settle = (("token_fetch",), None)
     # a alone: its first step has nothing to overtake, the next ones go
-    # out before the step before them is fetched ...
-    assert shapes[0] == first and shapes[1] == steady
-    # ... b's finished prefill is a joiner: the step in flight is
-    # settled first, the next goes out from the host's tokens (ahead 0),
-    # and the one after it is ahead again
-    prefill_b = sorted(_named(spans, "generation/prefill_fetch"),
-                       key=lambda s: s.start)[1]
-    i = next(i for i, st in enumerate(steps) if st.start > prefill_b.end)
-    assert shapes[i] == settle and shapes[i + 1] == first
-    assert shapes[i + 2] == steady
-    # no other pass settles first, and the last only settles
-    assert [s for s in shapes[:-1] if s == settle] == [settle]
-    assert shapes[-1] == settle
-    assert shapes.count(first) == 2
-    assert all(s in (steady, first, settle) for s in shapes)
+    # out before the step before them is fetched, and so does the one in
+    # the pass that carries b's finished prefill; the last only settles
+    assert shapes[0] == first and shapes[-1] == settle
+    assert shapes[1:-1] == [steady] * (len(shapes) - 2)
+    # b's prefill pass: the prefill is launched behind the step in
+    # flight, the next step goes out behind the prefill with b's row in
+    # it, the step in flight is fetched and booked, and only then does
+    # the scheduler block on the prefill's first token
+    launch_b = sorted(_named(spans, "generation/prefill"),
+                      key=lambda s: s.start)[1]
+    it = next(s for s in _named(spans, "generation/iteration")
+              if s.start <= launch_b.start and launch_b.end <= s.end)
+    order = [k.name.split("/")[1] for k in _within(spans, it)
+             if k.name in ("generation/prefill",
+                           "generation/decode_dispatch",
+                           "generation/token_fetch",
+                           "generation/prefill_fetch")]
+    assert order == ["prefill", "decode_dispatch", "token_fetch",
+                     "prefill_fetch"]
+    step_b = next(st for st in steps
+                  if it.start <= st.start and st.end <= it.end)
+    assert step_b.attrs["ahead"] == 1 and step_b.attrs["active"] == 2
+    # b's first token is booked before the step that carries its second
+    # is fetched: a stream's order is what it was
+    fetch_b = next(s for s in _named(spans, "generation/prefill_fetch")
+                   if it.start <= s.start and s.end <= it.end)
+    assert fetch_b.start >= step_b.end
+    assert steps[steps.index(step_b) + 1].start >= fetch_b.end
+    # a alone had no prefill to wait behind: its own is read at once
+    first_fetch = min(_named(spans, "generation/prefill_fetch"),
+                      key=lambda s: s.start)
+    assert first_fetch.end <= steps[0].start
     # spans of the scheduler thread never overlap without nesting
     for st in steps:
         for k in _within(spans, st):
@@ -280,6 +344,123 @@ def test_eos_at_settle_discards_the_row_that_overtook_it(kind):
         assert _pages_live(e) == 0
     finally:
         e.eos_id = -1
+        e.close()
+
+
+# -- (3b) a joiner that ends at its first token, or before it ------------------
+@pytest.mark.parametrize("kind", ["paged", "window"])
+@pytest.mark.parametrize("ends", ["eos", "budget"])
+def test_a_joiner_that_ends_at_its_first_token_leaves_nothing(kind, ends):
+    """The host cannot see an EOS coming: the joiner's row of the step
+    ahead is dispatched and then discarded.  A budget of one token it
+    can see: the joiner stays out, and the step goes out ahead without
+    it all the same."""
+    e = _engine(kind)
+    e.warmup()
+    try:
+        streams = [e.generate(p, 16, timeout=120)["tokens"]
+                   for p in PROMPTS]
+        # j's first token occurs nowhere in r's stream
+        r, j = next((r, j) for r in range(len(PROMPTS))
+                    for j in range(len(PROMPTS))
+                    if r != j and streams[j][0] not in streams[r])
+        if ends == "eos":
+            e.eos_id = streams[j][0]
+        _quiet(e)
+        before = _counters(e)
+        on_r, r_running = _after_tokens(2)
+        fr = e.submit(PROMPTS[r], 16, on_token=on_r)
+        assert r_running.wait(60)
+        fj = e.submit(PROMPTS[j], 16 if ends == "eos" else 1)
+        rj, rr = fj.result(120), fr.result(120)
+        assert rj["tokens"] == streams[j][:1] and rj["steps"] == 0
+        assert rj["finish"] == ("eos" if ends == "eos" else "length")
+        assert rr["tokens"] == streams[r]
+        _quiet(e)
+        n = _delta(e, before)
+        rode = int(ends == "eos")
+        assert n["decode_joiners_ahead"] == rode
+        assert n["decode_rows_discarded"] == rode
+        assert n["decode_steps_ahead"] == n["decode_steps"] - 1 == 14
+        assert n["generated_tokens"] == 17
+        assert _pages_live(e) == 0
+        # the slot j left serves its next owner nothing but its own tokens
+        e.eos_id = -1
+        assert e.generate(PROMPTS[j], 9, timeout=120)["tokens"] \
+            == streams[j][:9]
+    finally:
+        e.eos_id = -1
+        e.close()
+
+
+def test_no_page_for_the_joiners_position_ahead_settles_first():
+    """Two pages, a rider on one and a joiner whose prompt fills the
+    other: the step cannot go out ahead with the joiner in it, so the
+    settle and the joiner's first token come first, as before PR 45, and
+    the joiner finishes ``cache_full`` with that token."""
+    e = GenerationEngine(MODEL, num_slots=2, max_seq_len=64,
+                         attn_impl="xla", seed=0,
+                         page_tokens=8, num_pages=3, prefix_reuse=False,
+                         prefill_chunk=0, speculate=False)
+    try:
+        joiner = PROMPTS[0] + PROMPTS[1][:2]               # 8 tokens: a page
+        want_r = e.generate(PROMPTS[1], 12, timeout=120)["tokens"]
+        want_j = e.generate(joiner, 2, timeout=120)["tokens"]
+        _quiet(e)
+        before = _counters(e)
+        with e._cv:       # claimed in one pass: r prefills first, then j
+            fr = e.submit(PROMPTS[1], 12)                  # 4 + 12: 2 pages
+            fj = e.submit(joiner, 10)
+        rj, rr = fj.result(120), fr.result(120)
+        assert (rj["tokens"], rj["finish"]) == (want_j[:1], "cache_full")
+        assert (rr["tokens"], rr["finish"]) == (want_r, "length")
+        _quiet(e)
+        n = _delta(e, before)
+        assert n["failed"] == 0 and n["decode_joiners_ahead"] == 0
+        # r's first step and the one after the joiner went out from the
+        # host's tokens
+        assert n["decode_steps_ahead"] == n["decode_steps"] - 2
+        assert _pages_live(e) == 0
+    finally:
+        e.close()
+
+
+@pytest.mark.parametrize("kind", ["paged", "window"])
+def test_a_prefill_that_fails_under_a_step_ahead_fails_its_request_only(
+        kind):
+    e = _engine(kind)
+    e.warmup()
+    fetch = e._fetch_first_token
+    try:
+        want = [e.generate(p, 16, timeout=120)["tokens"]
+                for p in PROMPTS[:2]]
+        _quiet(e)
+        before = _counters(e)
+
+        def broken(slot, outs, parent, n_tokens):
+            if slot.req.prompt.size == len(PROMPTS[1]):
+                e._fetch_first_token = fetch
+                raise RuntimeError("the prefill's read failed")
+            return fetch(slot, outs, parent, n_tokens)
+
+        e._fetch_first_token = broken
+        on_a, a_running = _after_tokens(3)
+        fa = e.submit(PROMPTS[0], 16, on_token=on_a)
+        assert a_running.wait(60)
+        fb = e.submit(PROMPTS[1], 16)
+        with pytest.raises(RequestFailed, match="prefill failed"):
+            fb.result(120)
+        assert fa.result(120)["tokens"] == want[0]
+        _quiet(e)
+        n = _delta(e, before)
+        # b's row of the step ahead was dispatched and nobody took it
+        assert n["failed"] == 1 and n["decode_joiners_ahead"] == 1
+        assert n["decode_rows_discarded"] == 1
+        assert n["decode_steps_ahead"] == n["decode_steps"] - 1 == 14
+        assert _pages_live(e) == 0
+        assert e.generate(PROMPTS[1], 16, timeout=120)["tokens"] == want[1]
+    finally:
+        e._fetch_first_token = fetch
         e.close()
 
 

@@ -508,6 +508,10 @@ def test_prefill_then_cached_decode_in_a_reused_slot_between_neighbours():
         assert _off_reference(eng, cfg, _prompt(f, 9 + f - 50), r) < TOL
     assert counters["slot_state_writes"] == 5
     assert stat_get("serving_slot_state_writes") == w0 + 5
+    # all but the first request joined a grid with a step in flight, and
+    # rode the step ahead on the states their prefills left on the device
+    assert counters["decode_joiners_ahead"] == 4
+    assert counters["decode_steps_ahead"] == counters["decode_steps"] - 1
     # every rider of every step moved three layers' states on
     assert counters["delta_state_steps"] % 3 == 0
     assert counters["delta_state_steps"] >= 3 * (2 * 59 + 5 + 8 + 8)

@@ -377,10 +377,19 @@ def test_a_joiners_prefill_and_dead_rows_leave_a_live_slot_alone():
         r1 = f1.result(300)
         f2 = eng.submit(j2, 6)
         r2, r0 = f2.result(300), f0.result(300)
+        counters = eng.stats()["counters"]
+        compiled = eng._decode_exe.cache_info()["compiled"]
     finally:
         eng.close()
     assert (r0["slot"], r1["slot"], r2["slot"]) == (0, 1, 1)
     assert stat_get("serving_decode_steps_ahead") > ahead0
+    # both joiners rode the step dispatched ahead of the settle, on their
+    # prefill's token as the device holds it: the prefill writes the
+    # slot's state on the device and the step behind it in the queue
+    # reads it, so only the long request's first step was not ahead
+    assert counters["decode_joiners_ahead"] == 2
+    assert counters["decode_steps_ahead"] == counters["decode_steps"] - 1
+    assert counters["decode_rows_discarded"] == 0 and compiled == 1
     for prompt, res in ((long_, r0), (j1, r1), (j2, r2)):
         fresh = _alone(cfg, prompt, len(res["tokens"]), eng.scope)
         assert res["tokens"] == fresh["tokens"]
