@@ -6,8 +6,9 @@ entry points a user calls, at the full width of the models below, with
 random seeded weights:
 
 * **train** — BERT-base (12 layers, hidden 768, 12 heads, FFN 3072, vocab
-  30522) at sequence 512, the recipe ``bench.py`` builds (bf16 AMP white
-  list, Adam + global-norm clip, masked-gather MLM head), built under
+  30522) at sequence 512, the recipe ``paddle_tpu.models.bert`` builds
+  (bf16 AMP white list, Adam + global-norm clip, masked-gather MLM head),
+  built under
   ``program_guard``, initialised with ``Executor(TPUPlace()).run(startup)``
   and stepped through ``Executor.run`` on
   ``CompiledProgram(main).with_data_parallel(...)`` over every local chip.
@@ -289,8 +290,8 @@ def check_packed_op(batch, seq, hidden, heads):
 def train_phase(cfg=TRAIN, on_chip=True):
     import jax
 
-    import bench
     import paddle_tpu as pt
+    from paddle_tpu.models.bert import build_bert_train_programs
 
     devices = jax.devices()
     n = len(devices)
@@ -321,7 +322,7 @@ def train_phase(cfg=TRAIN, on_chip=True):
     grads0 = attention_grads()
 
     max_pred = max(1, int(round(0.15 * S)))
-    main_p, startup, feed_names, loss, _ = bench.build_bert_train_programs(
+    main_p, startup, feed_names, loss, _ = build_bert_train_programs(
         dict(batch_size=B, seq_len=S, vocab_size=cfg["vocab"],
              hidden=cfg["hidden"], num_layers=cfg["layers"],
              num_heads=cfg["heads"], intermediate=cfg["ffn"],
@@ -1055,7 +1056,7 @@ def main():
     t_start = time.perf_counter()
     # the program first: in a directory that holds only this file the
     # script must fail before it prints anything
-    import bench  # noqa: F401
+    import paddle_tpu  # noqa: F401
     import jax
     from paddle_tpu.compile_cache import ensure_compile_cache
     from paddle_tpu.monitor import stat_get

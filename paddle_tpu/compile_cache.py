@@ -2,7 +2,7 @@
 
 One rule, applied wherever the program first compiles (executor,
 ``Predictor``, ``GenerationEngine``, ``build_sharded_step``,
-``chip_smoke.py``, ``bench.py``) through :func:`ensure_compile_cache`:
+``chip_smoke.py``) through :func:`ensure_compile_cache`:
 
 * ``JAX_COMPILATION_CACHE_DIR`` set — the cache was placed from
   outside.  jax reads the variable itself; this module sets no

@@ -14,7 +14,7 @@ does this compiled program actually cost".  Three layers use it:
   bf16 FLOP/s and HBM bytes/s (one table; ``FLAGS_device_peak_flops``
   / ``FLAGS_device_peak_bw`` override).  A ``device_kind`` the table
   does not know has no peak, so nothing computed against it reports a
-  utilization.  ``bench.py``'s MFU routes through here.
+  utilization.
 * **Achieved efficiency** — :func:`mfu` / :func:`bw_util` /
   :func:`publish_achieved` turn (manifest, steps/sec) into live
   ``device_mfu`` / ``device_bw_util`` gauges on every training step.

@@ -49,8 +49,8 @@ working (as plain pass-throughs) after ``disable()``.
 from __future__ import annotations
 
 import os
+import sys
 import threading
-import traceback
 from typing import Dict, List, Optional, Set, Tuple
 
 __all__ = ["enable", "disable", "enabled", "violations",
@@ -91,12 +91,15 @@ def _caller_site() -> str:
     components: a bare basename would merge e.g. every package's
     ``__init__.py:N`` into one graph node and manufacture false
     cycles."""
-    for frame, lineno in traceback.walk_stack(None):
+    # walked by hand: how many frames ``traceback.walk_stack(None)``
+    # skips differs between interpreter versions
+    frame = sys._getframe(1)
+    while frame is not None:
         fn = frame.f_code.co_filename
-        if fn.endswith(("locksan.py", "threading.py")):
-            continue
-        short = "/".join(fn.replace("\\", "/").rsplit("/", 2)[-2:])
-        return f"{short}:{lineno}"
+        if not fn.endswith(("locksan.py", "threading.py")):
+            short = "/".join(fn.replace("\\", "/").rsplit("/", 2)[-2:])
+            return f"{short}:{frame.f_lineno}"
+        frame = frame.f_back
     return "<unknown>"
 
 

@@ -12,7 +12,9 @@ in-tree book tests python/paddle/fluid/tests/book/).
 """
 from .lenet import lenet, build_mnist_train  # noqa
 from .resnet import resnet, build_resnet_train  # noqa
-from .bert import bert_encoder, build_bert_pretrain  # noqa
+from .bert import (bert_encoder, build_bert_pretrain,  # noqa
+                   build_bert_train_programs,
+                   bert_train_flops_per_sample)
 from .llama import (llama, llama_block, build_llama_train,  # noqa
                     build_llama_forward, build_llama_prefill,
                     build_llama_decode)

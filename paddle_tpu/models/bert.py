@@ -220,3 +220,59 @@ def build_bert_pretrain(batch_size=None, seq_len=128, vocab_size=30522,
     feeds = ["input_ids", "token_type_ids", "attn_mask", "mlm_mask",
              "mlm_labels"]
     return feeds, {"loss": mean_loss}
+
+
+# bf16 activation stream: embeddings, layer norm, residual adds, softmax
+# and the attention ops join AMP's matmul white list.  Master weights stay
+# float32; the step is HBM-bound, so halving activation bytes is the lever.
+_BF16_STREAM_OPS = ("lookup_table", "lookup_table_v2", "layer_norm",
+                    "elementwise_add", "elementwise_mul", "dropout",
+                    "gelu", "relu", "scale", "transpose2",
+                    "reshape2", "gather_nd", "squeeze2", "unsqueeze2",
+                    "flash_attention", "flash_attention_qkv", "softmax")
+
+
+def build_bert_train_programs(cfg, *, learning_rate=None):
+    """The flagship training recipe as (main, startup, feed_names, loss,
+    bf16_stream): ``build_bert_pretrain(**cfg)`` under bf16 AMP with the
+    activation-stream white list, Adam + global-norm clip at 1.0.
+    ``bf16_stream`` is always True.  ``learning_rate=None`` is the
+    recipe's 10 000-step linear warm-up to 1e-4; ``chip_smoke.py`` passes
+    a constant, because ten steps into that warm-up nothing moves."""
+    from .. import clip, optimizer
+    from ..contrib import mixed_precision
+    from ..framework.core import Program, program_guard
+
+    main_p, startup = Program(), Program()
+    startup._is_startup = True
+    with program_guard(main_p, startup):
+        feed_names, outs = build_bert_pretrain(**cfg)
+        lr = learning_rate
+        if lr is None:
+            lr = layers.linear_lr_warmup(1e-4, warmup_steps=10000,
+                                         start_lr=0.0, end_lr=1e-4)
+        opt = optimizer.AdamOptimizer(
+            learning_rate=lr,
+            grad_clip=clip.GradientClipByGlobalNorm(1.0))
+        opt = mixed_precision.decorate(
+            opt, dtype="bfloat16",
+            amp_lists=mixed_precision.AutoMixedPrecisionLists(
+                custom_white_list=_BF16_STREAM_OPS))
+        opt.minimize(outs["loss"])
+    return main_p, startup, feed_names, outs["loss"], True
+
+
+def bert_train_flops_per_sample(seq, vocab, hidden, layers_n, inter,
+                                n_pred):
+    """Analytic matmul FLOPs for one BERT MLM training sample.
+
+    Per token, per layer: QKV proj 6H^2, attn scores+PV 4*H*S, out proj
+    2H^2, FFN 4*H*I (each matmul = 2mk per output elem). MLM head runs on
+    the n_pred gathered positions only: (2H^2 + 2*H*V) per prediction.
+    Train = 3x forward (bwd ~ 2x fwd matmul FLOPs).
+    """
+    per_layer = 6 * hidden ** 2 + 2 * hidden ** 2 + 4 * hidden * seq \
+        + 4 * hidden * inter
+    head = 2 * hidden ** 2 + 2 * hidden * vocab
+    fwd = layers_n * per_layer * seq + head * n_pred
+    return 3.0 * fwd

@@ -316,8 +316,7 @@ def default_transport() -> SegmentTransport:
 
 class DisaggPair:
     """Chain a prefill-role engine and a decode-role engine into one
-    ``submit()`` surface (the single-host disaggregated deployment,
-    and the A/B driver ``bench.py run_disagg`` measures).
+    ``submit()`` surface (the single-host disaggregated deployment).
 
     One pump thread polls outstanding prefill futures; the moment one
     resolves, its segment rides ``transport.send`` into

@@ -325,8 +325,8 @@ class ShardedEmbeddingTable:
         the donated buffer instead of allocating a fresh result.
         Compiled under the lock (two racing threads must not both
         build the same signature); the manifest rides the cache entry
-        into :meth:`gather_cache_info` (the bench reads gather-path
-        flops/bytes off it)."""
+        into :meth:`gather_cache_info` (gather-path flops/bytes are
+        read off it)."""
         import jax
         import jax.numpy as jnp
 

@@ -258,7 +258,7 @@ class ServingEngine:
     omit it to compile lazily on first use instead.
 
     In-process API: :meth:`submit` (future) / :meth:`predict`
-    (blocking) — tests and the bench drive the engine without sockets;
+    (blocking) — tests drive the engine without sockets;
     the HTTP front end (:mod:`paddle_tpu.serving.server`) is a thin
     JSON veneer over the same calls.
     """

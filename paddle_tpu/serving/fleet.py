@@ -42,7 +42,7 @@ own port, metrics dir, and ``PADDLE_TPU_REPLICA_ID`` env.
   buckets primed) before touching the next replica — at every instant
   N-1 replicas are routable, which is what lets the router pass
   traffic through a rollout with zero non-shed failures (asserted by
-  ``bench.py run_router`` and ``tests/test_router.py``).
+  ``tests/test_router.py``).
 
 * **In-place hot-swap rollout.** :meth:`hot_swap` rolls a new weights
   checkpoint through the fleet ONE replica at a time via ``POST
@@ -539,8 +539,8 @@ class FleetSupervisor:
         at the same port → wait for the successor's ``ready`` — then
         the next replica.  The fleet never has more than one replica
         out at a time, so a router keeps serving throughout (the
-        zero-non-shed-failure window asserted by the bench leg and the
-        test matrix).  Returns per-replica timings."""
+        zero-non-shed-failure window asserted by the test matrix).
+        Returns per-replica timings."""
         stat_add("fleet_rolling_restarts")
         t0 = time.monotonic()
         out = []

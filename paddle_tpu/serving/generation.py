@@ -26,7 +26,7 @@ identified.  This module is the repo's answer:
   next queued request into it between decode steps while the other
   slots keep generating.  ``continuous=False`` restores FIFO head-run
   static batching (claim only when every slot is idle, i.e. batch
-  drain) — the measured baseline the bench leg compares against.
+  drain) — the baseline ``tests/test_generation.py`` compares against.
 * **Paged KV cache** (PagedAttention-style, the engine's only cache)
   — a flat per-layer pool plus per-slot block tables, so concurrency
   is bounded by LIVE tokens and not by a worst-case sequence per slot.

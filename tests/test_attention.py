@@ -554,14 +554,14 @@ def test_sharded_bert_step_on_the_cpu_keeps_the_blockwise_route():
     of host devices: not a TPU backend, so every attention op (and its
     re-lowering inside the auto-grad op) books ``blockwise`` and nothing
     takes the ``shard_map`` route."""
-    import bench
+    from paddle_tpu.models.bert import build_bert_train_programs
     from paddle_tpu.monitor import stat_get
     from paddle_tpu.parallel import build_sharded_step, dp_mesh
 
     names = ("pallas", "pallas_sharded", "blockwise")
     before = {n: stat_get(f"attention_lowered_{n}") for n in names}
     layers_, batch, seq, pred = 2, 8, 64, 10
-    main_p, startup, feed_names, loss, _ = bench.build_bert_train_programs(
+    main_p, startup, feed_names, loss, _ = build_bert_train_programs(
         dict(batch_size=batch, seq_len=seq, vocab_size=211, hidden=128,
              num_layers=layers_, num_heads=2, intermediate=256,
              max_predictions=pred, use_flash=True, dropout=0.1))

@@ -3,10 +3,8 @@
 ``chip_smoke.py`` itself only passes on a TPU; here its two phases run at
 a toy size on the CPU with the Pallas kernels in interpret mode, and the
 rules around it are pinned: no TPU -> refuse, where the compile cache
-lands, no guessed peak, a failing bench leg fails the run, no CPU re-exec,
-one chip per replica.
+lands, no CPU re-exec, one chip per replica.
 """
-import json
 import os
 import subprocess
 import sys
@@ -19,7 +17,6 @@ import paddle_tpu as pt
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
-import bench  # noqa: E402
 import chip_smoke  # noqa: E402
 
 TOY_TRAIN = dict(seq=64, hidden=128, layers=1, heads=1, ffn=256, vocab=211,
@@ -142,70 +139,8 @@ def test_cpu_backend_gets_no_persistent_cache(monkeypatch,
 
 
 # ---------------------------------------------------------------------------
-# bench.py
+# no CPU re-exec
 # ---------------------------------------------------------------------------
-
-def test_unknown_device_has_no_peak_and_no_chip_metric():
-    import jax
-
-    cpu = jax.devices()[0]
-    eff = bench._efficiency_block(100.0, 1e9, {"flops": 1e9,
-                                               "bytes_accessed": 1e6},
-                                  cpu, 1)
-    assert eff["mfu"] is None and eff["bw_util"] is None
-    assert eff["peak_tflops"] is None and eff["peak_source"] == "unknown"
-    assert bench.chip_name("x_samples_per_sec_per_chip", cpu) \
-        == "x_samples_per_sec_on_cpu"
-    assert bench.chip_name("tokens/sec/chip", cpu) == "tokens/sec/cpu-device"
-
-
-def _stub_bench(monkeypatch, serving):
-    for name in ("BENCH_ROUTER", "BENCH_CHAOS", "BENCH_ROLLOUT",
-                 "BENCH_RESNET", "BENCH_RECSYS", "BENCH_SHARDED",
-                 "BENCH_DECODE", "BENCH_PAGED", "BENCH_SPEC",
-                 "BENCH_DISAGG"):
-        monkeypatch.setenv(name, "0")
-    monkeypatch.setattr(bench, "run_config",
-                        lambda *a, **k: {"value": 1.0, "device_kind": "cpu"})
-    monkeypatch.setattr(bench, "run_serving", serving)
-
-
-def test_bench_raising_leg_fails_the_run(monkeypatch, capsys):
-    """No leg's failure may become an "error" field under exit code 0:
-    the exception leaves main(), so `python bench.py` exits non-zero
-    and prints no result."""
-    def boom():
-        raise RuntimeError("serving leg broke")
-
-    _stub_bench(monkeypatch, boom)
-    with pytest.raises(RuntimeError, match="serving leg broke"):
-        bench.main()
-    assert capsys.readouterr().out == ""
-
-
-def test_bench_on_the_cpu_prints_no_per_chip_metric(monkeypatch, capsys):
-    _stub_bench(monkeypatch, lambda: {"value": 2.0})
-    bench.main()
-    out = json.loads(capsys.readouterr().out)
-    assert out["metric"] == "bert_base_mlm_train_samples_per_sec_on_cpu"
-    assert set(out["legs"]) == {"seq512", "serving"}
-    assert "per_chip" not in json.dumps(out)
-
-
-def test_measure_windows_times_and_reruns_the_outlier():
-    clock = iter([0.0, 1.0, 1.0, 2.0, 2.0, 5.0, 5.0, 6.0])
-    real = bench.time.perf_counter
-    bench.time.perf_counter = lambda: next(clock)
-    try:
-        dts, state, loss, reruns = bench.measure_windows(
-            lambda s: (s + 1, [np.float32(0.5)]), bench._fence, 0,
-            n_windows=3, rerun_budget=1)
-    finally:
-        bench.time.perf_counter = real
-    assert dts == [1.0, 1.0, 1.0] and reruns == 1 and state == 4
-    with pytest.raises(RuntimeError, match="non-finite"):
-        bench._fence([np.float32("nan")])
-
 
 def test_dryrun_multichip_too_few_devices_is_an_error(monkeypatch):
     import __graft_entry__ as ge
@@ -261,17 +196,6 @@ def test_fleet_on_a_tpu_host_pins_or_refuses(monkeypatch, tmp_path):
         sup._spawn(rep)
     assert [e["TPU_VISIBLE_CHIPS"] for e in spawned] == ["0", "1"]
     assert all(e["TPU_PROCESS_BOUNDS"] == "1,1,1" for e in spawned)
-
-
-def test_bench_chaos_and_rollout_legs_refused_on_a_tpu_host(monkeypatch):
-    from paddle_tpu.serving import fleet
-
-    bench._refuse_on_tpu_host("chaos")  # CPU host: no-op
-    monkeypatch.setattr(fleet, "local_tpu_chips", lambda env=None: 1)
-    with pytest.raises(RuntimeError, match="BENCH_CHAOS=0"):
-        bench.run_chaos()
-    with pytest.raises(RuntimeError, match="BENCH_ROLLOUT=0"):
-        bench.run_rollout()
 
 
 # ---------------------------------------------------------------------------

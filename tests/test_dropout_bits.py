@@ -166,10 +166,10 @@ SITES = 1 + 2 * LAYERS          # the embeddings, then two a layer
 def _bert_step(n_devices):
     """(lowered step, its arguments' scope values) of a 2-layer BERT
     through ``build_sharded_step`` over ``n_devices`` host devices."""
-    import bench
+    from paddle_tpu.models.bert import build_bert_train_programs
     from paddle_tpu.parallel import build_sharded_step, dp_mesh
 
-    main_p, startup, feed_names, loss, _ = bench.build_bert_train_programs(
+    main_p, startup, feed_names, loss, _ = build_bert_train_programs(
         dict(batch_size=BATCH, seq_len=SEQ, vocab_size=211, hidden=HIDDEN,
              num_layers=LAYERS, num_heads=2, intermediate=256,
              max_predictions=PRED, use_flash=True, dropout=0.1))

@@ -657,11 +657,10 @@ def test_chaos_harness_smoke_three_replica_fleet():
     and every recovery path actually fired."""
     chaos = _load_tool("chaos")
     # the classic six explicitly: disagg_crash and hot_swap (both in
-    # DEFAULT_SCENARIOS for the CLI/bench) each spawn their own
+    # DEFAULT_SCENARIOS for the CLI) each spawn their own
     # multi-replica fleet — far too heavy for a tier-1 smoke on a
-    # core-bound host; they run live via bench.py run_chaos /
-    # run_rollout, and their page-leak / torn-version verdicts are
-    # hard-zeroed by tools/perf_gate.py
+    # core-bound host; `python tools/chaos.py` runs them, and their
+    # page-leak / torn-version counts land in the report's totals
     report = chaos.run_chaos(replicas=3, qps=30.0, duration_s=2.5,
                              availability_pct=99.0,
                              liveness_timeout_ms=1200.0,

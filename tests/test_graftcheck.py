@@ -4,8 +4,7 @@ clean; the waiver/baseline machinery round-trips; and the runtime
 lock-order sanitizer detects a provoked A->B / B->A inversion.
 
 The fixtures are the rules' contract: a rule that silently stopped
-firing on its own triggering shape is worse than no rule (the same
-argument as perf_gate --smoke).
+firing on its own triggering shape is worse than no rule.
 """
 import json
 import os

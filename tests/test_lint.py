@@ -30,7 +30,6 @@ import urllib.request
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 LINT = os.path.join(REPO, "tools", "check_no_bare_pass.py")
 CATALOG = os.path.join(REPO, "tools", "check_stat_catalog.py")
-PERF_GATE = os.path.join(REPO, "tools", "perf_gate.py")
 
 
 def test_graftcheck_full_suite_clean_within_budget():
@@ -136,19 +135,6 @@ def test_stat_catalog_lint_catches_undocumented_name(tmp_path):
         [sys.executable, CATALOG, str(bad), "--readme", str(readme)],
         capture_output=True, text=True, timeout=60)
     assert r.returncode == 0, r.stdout
-
-
-def test_perf_gate_smoke():
-    """tools/perf_gate.py --smoke: the perf-regression gate's pass/fail
-    logic validated against synthetic reports and the
-    op_bench_baseline.json fixture — no benchmark run.  This keeps the
-    gate itself load-bearing: a gate that silently stopped failing on
-    regressions is worse than no gate."""
-    r = subprocess.run(
-        [sys.executable, PERF_GATE, "--smoke"],
-        capture_output=True, text=True, timeout=120)
-    assert r.returncode == 0, r.stdout + r.stderr
-    assert "gate-logic checks passed" in r.stdout
 
 
 def test_every_serving_flag_is_documented_in_readme():

@@ -1,14 +1,13 @@
 """Device-observatory tests (paddle_tpu/costmodel.py +
-paddle_tpu/observatory.py + the perf gate).
+paddle_tpu/observatory.py).
 
 Covers: executable-manifest capture and determinism (same signature =>
 identical flops/peak-HBM across two processes), live efficiency gauges
 (device_mfu / device_bw_util), the HBM watermark + Perfetto counter
 track (incl. the acceptance artifact: a 20-step guarded run whose
 trace.json carries the HBM timeline alongside the host spans), the
-``/profilez`` on-demand capture contract, the perf-gate pass/fail
-matrix on synthetic reports, loadgen SLO assertions, and per-device
-collective-stat attribution.
+``/profilez`` on-demand capture contract, loadgen SLO assertions, and
+per-device collective-stat attribution.
 """
 import gc
 import importlib.util
@@ -409,107 +408,6 @@ def test_per_device_collective_stats():
     assert deltas[0] == deltas[1] >= 4  # 3 scatters + 1 gather
     # every shard got the same attribution as the aggregate emit
     assert stat_get("collective_all_to_all_calls") >= deltas[0]
-
-
-# ---------------------------------------------------------------------------
-# perf gate matrix (synthetic reports)
-# ---------------------------------------------------------------------------
-
-def _leg(median, p10=None, p90=None, device="TPU v5 lite",
-         anomaly=None):
-    return {"value": median, "device_kind": device, "anomaly": anomaly,
-            "stats": {"median": median,
-                      "p10": p10 if p10 is not None else median * 0.98,
-                      "p90": p90 if p90 is not None else median * 1.02}}
-
-
-def _doc(flagship, **legs):
-    d = dict(flagship)
-    d["legs"] = legs
-    return d
-
-
-def test_perf_gate_pass_fail_matrix():
-    pg = _load_tool("perf_gate")
-    base = _doc(_leg(1000.0), seq512=_leg(300.0))
-
-    # identical -> pass
-    assert pg.compare_bench(base, [base])["ok"]
-    # within the 10% drift floor -> pass
-    ok = pg.compare_bench(_doc(_leg(950.0), seq512=_leg(285.0)), [base])
-    assert ok["ok"]
-    # 20% down on one leg -> that leg regresses, gate fails
-    bad = pg.compare_bench(_doc(_leg(1000.0), seq512=_leg(240.0)),
-                           [base])
-    assert not bad["ok"]
-    statuses = {r["leg"]: r["status"] for r in bad["legs"]}
-    assert statuses == {"flagship": "ok", "seq512": "regression"}
-    # noisy baseline widens the tolerance past the floor
-    noisy = _doc(_leg(1000.0, p10=600.0, p90=1400.0))
-    assert pg.compare_bench(_doc(_leg(650.0)), [noisy])["ok"]
-    assert not pg.compare_bench(_doc(_leg(150.0)), [noisy])["ok"]
-    # device mismatch -> skip, not fail
-    r = pg.compare_bench(
-        _doc(_leg(10.0, device="cpu"), seq512=_leg(3.0, device="cpu")),
-        [base])
-    assert r["ok"]
-    assert all(x["status"] == "skipped" for x in r["legs"])
-    # anomalous baseline leg -> skip; anomalous fresh leg -> skip
-    r = pg.compare_bench(
-        base, [_doc(_leg(1000.0, anomaly="spread 3x"),
-                    seq512=_leg(300.0))])
-    assert r["ok"] and any(x["status"] == "skipped" for x in r["legs"])
-    r = pg.compare_bench(_doc(_leg(100.0, anomaly="contention"),
-                              seq512=_leg(300.0)), [base])
-    assert r["ok"]
-    # leg missing from the fresh report -> regression
-    assert not pg.compare_bench(_doc(_leg(1000.0)), [base])["ok"]
-    # trajectory: last baseline carrying the leg wins
-    older = _doc(_leg(2000.0), seq512=_leg(300.0))
-    assert pg.compare_bench(base, [older, base])["ok"]
-    assert not pg.compare_bench(base, [base, older])["ok"]
-
-    # driver-envelope unwrap
-    import tempfile
-    with tempfile.NamedTemporaryFile("w", suffix=".json",
-                                     delete=False) as f:
-        json.dump({"n": 5, "rc": 0, "parsed": base}, f)
-    try:
-        assert pg.load_report(f.name) == base
-    finally:
-        os.unlink(f.name)
-
-
-def test_perf_gate_cli_against_a_baseline_file(tmp_path):
-    """The acceptance check: a report vs itself passes; a degraded
-    copy fails with exit 1."""
-    pg = _load_tool("perf_gate")
-    gate = os.path.join(REPO, "tools", "perf_gate.py")
-    base = str(tmp_path / "base.json")
-    with open(base, "w") as f:
-        json.dump(pg.smoke_trajectory()[-1], f)
-    r = subprocess.run(
-        [sys.executable, gate, "--report", base, "--baseline", base],
-        capture_output=True, text=True, timeout=60)
-    assert r.returncode == 0, r.stdout + r.stderr
-    assert "GATE PASSED" in r.stdout
-    degraded = pg._degrade(pg.load_report(base), 0.7)
-    import tempfile
-    with tempfile.NamedTemporaryFile("w", suffix=".json",
-                                     delete=False) as f:
-        json.dump(degraded, f)
-    try:
-        r = subprocess.run(
-            [sys.executable, gate, "--report", f.name,
-             "--baseline", base, "--json"],
-            capture_output=True, text=True, timeout=60)
-        assert r.returncode == 1, r.stdout + r.stderr
-        verdict = json.loads(r.stdout)
-        assert not verdict["ok"]
-        assert any(leg["status"] == "regression"
-                   for leg in verdict["bench"]["legs"])
-    finally:
-        os.unlink(f.name)
 
 
 # ---------------------------------------------------------------------------

@@ -480,26 +480,22 @@ def test_bert_step_for_a_described_v5e_mesh_keeps_the_pallas_kernels(
     calls in the text), no float32 ``[B, h, S, bk]`` score block of the
     blockwise reference is left, and the step fits the chip."""
     import re
-    import sys
 
     import jax
     from jax.experimental.compilation_cache import compilation_cache as cc
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from paddle_tpu import compile_cache
+    from paddle_tpu.models.bert import build_bert_train_programs
     from paddle_tpu.parallel import build_sharded_step, dp_mesh
     from paddle_tpu.parallel import sharded
-
-    sys.path.insert(0, os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
-    import bench
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     monkeypatch.setattr(compile_cache, "ensure_compile_cache", lambda: None)
     monkeypatch.setattr(sharded, "ensure_compile_cache", lambda: None)
     layers_, chips, per_chip, seq, pred = 2, 4, 64, 512, 77
     batch = chips * per_chip
-    main_p, _, feed_names, loss, _ = bench.build_bert_train_programs(
+    main_p, _, feed_names, loss, _ = build_bert_train_programs(
         dict(batch_size=batch, seq_len=seq, vocab_size=30522, hidden=768,
              num_layers=layers_, num_heads=12, intermediate=3072,
              max_predictions=pred, use_flash=True, dropout=0.1))

@@ -3,7 +3,7 @@
 Times one training-style attention call (value + grads wrt q,k,v) for the
 pallas flash kernel vs the unfused einsum formulation, across seq lengths
 and block sizes. Used to pick DEFAULT_BLOCK_Q/K and the per-seq default
-impl (bench.py cites the result).
+impl.
 """
 from __future__ import annotations
 

@@ -133,9 +133,9 @@ Usage::
         --scenarios crash,hang,slow,poison --availability-pct 99 \
         --out chaos.json
 
-``bench.py run_chaos`` publishes the same report as ``legs.chaos``
-and ``tools/perf_gate.py`` hard-fails any capture with collateral
-failures or poison leaks (no anomaly flag shields them).
+A collateral failure or a poison leak fails the report (``ok`` false,
+exit 1) whatever else it measured; the leak, torn-response and
+usage totals below are counts a reader holds to zero.
 """
 from __future__ import annotations
 
@@ -511,8 +511,8 @@ def _scenario(name: str, sup, router, url: str, cfg: dict) -> dict:
         # carry >=1 harvested artifact, and be attributed exactly as
         # induced (SIGKILL decodes to signal:SIGKILL; the watchdog's
         # kill mark decodes to hung_kill).  The per-scenario
-        # unexplained count rides into totals for the perf_gate
-        # hard-zero (None = the death was never even booked)
+        # unexplained count rides into totals, where anything but 0
+        # fails the report (None = the death was never even booked)
         death, pm_err = _postmortem_verdict(
             sup._replicas[0], old_pid,
             "signal:SIGKILL" if name == "crash" else "hung_kill")
@@ -2005,33 +2005,32 @@ def run_chaos(replicas: int = 3, qps: float = 40.0,
               "poison_leaks"):
         totals[k] = sum(r[k] for r in per_scenario.values())
     # alert-contract verdicts: missed fires, missed clears, and false
-    # positives all land in scenario errors; this count gives the gate
-    # (and the bench leg) a single number to hard-zero
+    # positives all land in scenario errors (which fail the report);
+    # this count gives a reader a single number to hold to zero
     totals["alert_errors"] = sum(
         1 for r in per_scenario.values()
         if "error" in r and "burn-rate alert" in r["error"])
-    # disagg page-pool leak verdict (None when the scenario didn't
-    # run): perf_gate hard-zeroes it like collateral/leaks
+    # disagg page-pool leak verdict (absent when the scenario didn't
+    # run): any page still held after the storm counts here
     if any("leaked_pages" in r for r in per_scenario.values()):
         totals["leaked_pages"] = sum(
             r.get("leaked_pages") or 0 for r in per_scenario.values())
-    # hot-swap torn-version verdict (None when the scenario didn't
+    # hot-swap torn-version verdict (absent when the scenario didn't
     # run): a single torn response breaks the rollout contract, so
-    # perf_gate hard-zeroes the sum
+    # the sum must read 0
     if any("torn_responses" in r for r in per_scenario.values()):
         totals["torn_responses"] = sum(
             r.get("torn_responses") or 0 for r in per_scenario.values())
-    # embedding-tier pin-leak verdict (None when the scenario didn't
+    # embedding-tier pin-leak verdict (absent when the scenario didn't
     # run): a row still pinned after the storm means a lookup lost its
-    # unpin — perf_gate hard-zeroes the sum like leaked_pages
+    # unpin, so the sum must read 0 like leaked_pages
     if any("leaked_rows" in r for r in per_scenario.values()):
         totals["leaked_rows"] = sum(
             r.get("leaked_rows") or 0 for r in per_scenario.values())
-    # usage-observatory verdicts (None when noisy_neighbor didn't run,
-    # or when it ran but could not measure — perf_gate treats a
-    # present-but-None value as a failed rule, never a pass):
-    # conservation delta hard-zeroes, the hog attribution ratio has a
-    # floor, and the sketch bound violation count hard-zeroes
+    # usage-observatory verdicts (absent when noisy_neighbor didn't
+    # run; None when it ran but could not measure, which is a failure,
+    # never a pass): the worst conservation delta, the lowest hog
+    # attribution ratio and the count of sketch bound violations
     if any("usage_conservation_delta" in r
            for r in per_scenario.values()):
         vals = [r["usage_conservation_delta"]
@@ -2053,8 +2052,8 @@ def run_chaos(replicas: int = 3, qps: float = 40.0,
             None if any(v is None for v in vals) else sum(vals)
     # crash-forensics verdict: every induced death must be harvested
     # AND explained.  A per-scenario None means a death was never even
-    # booked — that vacuousness propagates to the total (perf_gate
-    # treats present-but-None as a failed rule, not a pass)
+    # booked — that vacuousness propagates to the total, and a None
+    # total fails ``ok`` below like a count above 0
     pm_scens = [r for r in per_scenario.values()
                 if "unexplained_deaths" in r]
     if pm_scens:

@@ -11,7 +11,7 @@ Three rules over the ``register_flag`` registry
 
 ``flag-unused``
     A registered flag that no code anywhere (paddle_tpu/, tools/,
-    tests/, bench.py, __graft_entry__.py) ever reads through the flag
+    tests/, __graft_entry__.py) ever reads through the flag
     APIs — dead configuration surface an operator can set with no
     effect.  Reference-API-compat flags that are intentionally
     advisory carry baseline waivers.
@@ -32,7 +32,7 @@ from ..core import (REPO, SourceFile, Violation, call_name,
 
 # extra roots consulted for read evidence (a flag only tests read is
 # still read; violations are only ever attached to the registry file)
-READ_EVIDENCE_ROOTS = ("tests", "bench.py", "__graft_entry__.py")
+READ_EVIDENCE_ROOTS = ("tests", "__graft_entry__.py")
 FLAG_READ_FUNCS = {"flag_value", "get_flags"}
 # module-level so tests can point the pass at a fixture README
 README_PATH = os.path.join(REPO, "README.md")

@@ -62,7 +62,7 @@ batch-drain scheduling.  Closed loop measures saturated
 ``tokens_per_sec``; open loop (``--mode open``) paces request arrivals
 on the ``--qps`` clock for latency/shed behavior at a target rate.
 ``--gen-static`` schedules FIFO head-run (batch drain) instead of
-continuous slot reclaim — the A/B the bench leg publishes.
+continuous slot reclaim — the A/B ``tests/test_generation.py`` runs.
 ``--gen-page-tokens``/``--gen-pages``/``--gen-prefill-chunk`` size
 the engine's block-paged KV cache,
 ``--gen-speculate``/``--gen-spec-tokens`` turn on speculative
@@ -86,10 +86,7 @@ reads the target's ``/statusz``), and ``--slo-hit-rate`` floors it —
 an unmeasured floor is a violation, matching the acceptance-rate
 precedent.
 
-Used by ``bench.py run_serving``/``run_decode``/``run_recsys`` (the
-``legs.serving``, ``legs.llama_decode`` and ``legs.wide_deep_recsys``
-entries),
-``tests/test_serving.py``, ``tests/test_generation.py``,
+Used by ``tests/test_serving.py``, ``tests/test_generation.py``,
 ``tests/test_paged_generation.py``, and
 ``tests/test_recsys_serving.py``.
 """
@@ -842,8 +839,8 @@ def _http_predict(url: str, body: bytes,
     """One POST /predict -> ``('ok' | 'shed' | 'failed', version)``
     where ``version`` is the ``X-PaddleTPU-Weights-Version`` response
     header (replicas and the router both publish it; ``None`` when
-    the server predates it or the connection died) — the rollout
-    bench watches the distribution flip during a hot swap.
+    the server predates it or the connection died) — a rollout
+    run watches the distribution flip during a hot swap.
 
     Not every 503 is a shed: a replica's admission 503s (queue_full /
     deadline / draining) are explicit backpressure and count as shed,
