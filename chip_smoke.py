@@ -1052,6 +1052,141 @@ def share_and_channel_phase(cfg=SHARE):
             f"{here} held pairs within {rel:.4g} of the plain loop")
 
 
+LATENT = dict(heads=64, latent=512, nope=128, rope=64, v=128, slots=32,
+              rows=2000, page_tokens=16, seq=768, hidden=1024)
+
+
+def latent_phase(cfg=LATENT):
+    """What a decoder with latent (MLA) attention adds (PR 47), at that
+    family's published head sizes (64 heads of nope 128 + rope 64 over a
+    latent of 512, values of 128): the absorbed decode kernel
+    ``mla_decode_attention`` over a pool of 640-lane rows at 32 slots of
+    up to 2,000 rows (an idle slot, permuted pages, NaN in every page a
+    slot does not own and behind every live length) and the expanded
+    prefill kernel ``mla_prefill_attention`` at keys of 192 over values of
+    128, each against plain float32 "highest" einsums; then one round trip
+    through a two-slot ``GenerationEngine`` of one latent layer and one
+    delta layer with two value heads a key head (sandwich norms, clamped
+    SwiGLU, YaRN over interleaved pairs): a prefill, eight absorbed decode
+    steps, the slot taken again, against the uncached forward's expanded
+    path, with the lowering counters."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.monitor import stat_get
+    from paddle_tpu.ops.pallas import latent_attention as la
+    from paddle_tpu.serving import GenerationEngine
+
+    H, C, dn, dr, dv = (cfg[k] for k in ("heads", "latent", "nope", "rope",
+                                         "v"))
+    B, pt_ = cfg["slots"], cfg["page_tokens"]
+    row = la.row_lanes(C + dr)
+    np_slot = -(-cfg["rows"] // pt_)
+    P = B * np_slot + 1
+    key = jax.random.key(47)
+    pos = jnp.asarray(np.r_[0, cfg["rows"] - 1, np.random.default_rng(47)
+                            .integers(1, cfg["rows"], B - 2)], jnp.int32)
+    table = np.random.default_rng(48).permutation(P - 1)[:B * np_slot] + 1
+    table = jnp.asarray(table.reshape(B, np_slot), jnp.int32)
+    rows = jax.random.normal(jax.random.fold_in(key, 1),
+                             (B, np_slot * pt_, C + dr), jnp.float32)
+    live = jnp.arange(np_slot * pt_)[None, :] <= pos[:, None]
+    padded = jnp.pad(jnp.where(live[..., None], rows, jnp.nan),
+                     ((0, 0), (0, 0), (0, row - C - dr)))
+    pool = jnp.full((P, 1, pt_, row), jnp.nan, jnp.float32).at[
+        table.reshape(-1), 0].set(padded.reshape(B * np_slot, pt_, row))
+    q = jax.random.normal(jax.random.fold_in(key, 2), (B, H, C + dr),
+                          jnp.float32) * 0.1
+    q_row = jnp.pad(q, ((0, 0), (0, 0), (0, row - C - dr)))
+    scale = (dn + dr) ** -0.5
+    got = la.mla_decode_attention(q_row, pool, table, pos, scale=scale,
+                                  value_dim=C)
+    hi = jax.lax.Precision.HIGHEST
+    clean = jnp.where(live[..., None], rows, 0.0)
+    s = jnp.einsum("bhc,bsc->bhs", q, clean, precision=hi) * scale
+    p = jax.nn.softmax(jnp.where(live[:, None], s, -jnp.inf), -1)
+    want = jnp.einsum("bhs,bsc->bhc", p, clean[..., :C], precision=hi)
+    rel = float(jnp.abs(got - want).max() / jnp.abs(want).max())
+    check(bool(jnp.isfinite(got).all()) and rel <= 2.0 ** -16,
+          f"mla_decode_attention off the einsums by {rel:.4g}")
+
+    S = cfg["seq"]
+    qf, kf = (jax.random.normal(jax.random.fold_in(key, i),
+                                (1, H, S, dn + dr), jnp.float32)
+              for i in (3, 4))
+    vf = jax.random.normal(jax.random.fold_in(key, 5), (1, H, S, dv),
+                           jnp.float32)
+    got = la.mla_prefill_attention(qf, kf, vf, scale=scale)
+    s = jnp.einsum("bhqd,bhkd->bhqk", qf, kf, precision=hi) * scale
+    s = jnp.where(jnp.tril(jnp.ones((S, S), bool)), s, -jnp.inf)
+    want = jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, -1), vf,
+                      precision=hi)
+    rel_pre = float(jnp.abs(got - want).max() / jnp.abs(want).max())
+    check(bool(jnp.isfinite(got).all()) and rel_pre <= 2.0 ** -16,
+          f"mla_prefill_attention off the einsums by {rel_pre:.4g}")
+    say(f"latent: the absorbed decode kernel over {int(pos.sum()) + B} "
+        f"live rows of {B} slots within {rel:.4g} of the einsums, the "
+        f"expanded prefill kernel at {S} rows within {rel_pre:.4g}")
+
+    delta = {"kind": "gated_delta", "key_heads": 4, "value_heads": 8,
+             "key_dim": 128, "value_dim": 128, "conv": 4,
+             "gate": "sigmoid", "gate_scale": 2.0}
+    mla = {"q_rank": 384, "kv_rank": C, "nope_dim": dn, "rope_dim": dr,
+           "v_dim": dv, "interleave": True,
+           "scale": scale * (0.1 * np.log(8) + 1) ** 2,
+           "yarn": {"factor": 8, "original_max": 32768, "beta_fast": 32,
+                    "beta_slow": 1}}
+    model = dict(vocab_size=4096, hidden=cfg["hidden"], num_layers=2,
+                 num_heads=H, num_kv_heads=H, intermediate=2048,
+                 rms_norm_eps=1e-6, rope_base=1e5, norm="pre_post",
+                 layer_pattern=[
+                     {"mixer": delta, "swiglu_limit": 10.0},
+                     {"mixer": "attention", "mla": mla, "attn_gate": True,
+                      "swiglu_limit": 10.0}])
+    names = ("attention_lowered_latent_decode",
+             "attention_lowered_latent_decode_reference",
+             "attention_lowered_latent_prefill",
+             "gated_delta_lowered_pallas", "gated_delta_lowered_reference")
+    before = {n: stat_get(n) for n in names}
+    writes0 = pool_writes()
+    gen = GenerationEngine(model, num_slots=2, max_seq_len=512,
+                           prefill_buckets=[256], page_tokens=16,
+                           prefill_chunk=0, prefix_reuse=False,
+                           speculate=False, keep_logits=True, eos_id=-1)
+    try:
+        gen.warmup()
+        grew = {n: stat_get(n) - before[n] for n in names}
+        check(grew["attention_lowered_latent_decode"] == 1
+              and grew["attention_lowered_latent_decode_reference"] == 0
+              and grew["attention_lowered_latent_prefill"] == 1
+              and grew["gated_delta_lowered_pallas"] >= 2
+              and grew["gated_delta_lowered_reference"] == 0,
+              f"the latent and delta ops lowered to {grew}")
+        wrote = {k: v - writes0[k] for k, v in pool_writes().items()}
+        check(wrote == {"pages": 1, "rows": 0},
+              f"the latent pool's prefill write lowered to {wrote}")
+        rng = np.random.default_rng(47)
+        worst = 0.0
+        for n_prompt in (200, 3):             # the second reuses slot 0
+            prompt = rng.integers(1, 4096, n_prompt).tolist()
+            res = gen.generate(prompt, 9, timeout=600)
+            check(res["slot"] == 0, f"request landed in slot {res['slot']}")
+            seq = prompt + res["tokens"]
+            want = _forward_logits(gen, model, seq, 256)[
+                n_prompt - 1:n_prompt + 8]
+            got = np.stack(res["logits"])
+            rel = float(np.abs(got - want).max() / np.abs(want).max())
+            worst = max(worst, rel)
+            check(np.isfinite(got).all() and rel <= TOL,
+                  f"latent round trip (prompt {n_prompt}) off the "
+                  f"uncached forward by {rel:.4g}")
+    finally:
+        gen.close()
+    say(f"latent: pages through a prefill, eight absorbed decode steps "
+        f"and a reused slot within {worst:.4g} of the uncached forward's "
+        f"expanded path; lowered {grew}")
+
+
 def main():
     t_start = time.perf_counter()
     # the program first: in a directory that holds only this file the
@@ -1113,6 +1248,12 @@ def main():
     t0 = time.perf_counter()
     share_and_channel_phase()
     say(f"held experts and per-channel delta kernels done "
+        f"[{time.perf_counter() - t0:.1f} s]")
+    gc.collect()
+
+    t0 = time.perf_counter()
+    latent_phase()
+    say(f"latent attention kernels and pages done "
         f"[{time.perf_counter() - t0:.1f} s]")
 
     say(f"set-up (compile-dominated: kernel check + first train step + "

@@ -23,7 +23,8 @@ __all__ = [
     "adaptive_pool2d", "flash_attention", "flash_attention_qkv",
     "rms_norm", "rope",
     "cached_attention", "kv_pool_write", "kv_pool_gather",
-    "paged_decode_attention", "block_begin", "block_unmask",
+    "paged_decode_attention", "latent_prefill_attention",
+    "latent_decode_attention", "block_begin", "block_unmask",
     "short_conv", "short_conv_tail", "slot_state_write", "short_conv_step",
     "gated_delta_chunk", "gated_delta_step",
     "linear_chain_crf", "crf_decoding", "warpctc",
@@ -631,20 +632,31 @@ def rms_norm(x, epsilon=1e-6, param_attr=None, name=None):
     return out
 
 
-def rope(x, base=10000.0, position_offset=0, offset=None, name=None):
+def rope(x, base=10000.0, position_offset=0, offset=None, name=None,
+         interleave=False, yarn=None):
     """Rotary position embedding; x: [B, H, S, D].
 
     ``offset``: optional [B] int Variable of per-row dynamic position
     offsets (cached decode: row b's S positions start at ``offset[b]``);
-    the static ``position_offset`` attr applies when it is absent."""
+    the static ``position_offset`` attr applies when it is absent.
+    ``interleave``: rotate the pairs ``(2i, 2i + 1)``, not ``(i, i + D /
+    2)``.  ``yarn``: ``{"factor", "original_max", "beta_fast",
+    "beta_slow"}``, YaRN's frequency table (``ops/rope_ops.py``
+    ``yarn_inv_freq``)."""
     helper = LayerHelper("rope", name=name)
     out = helper.create_variable_for_type_inference(x.dtype)
     inputs = {"X": [x]}
     if offset is not None:
         inputs["Offset"] = [offset]
+    attrs = {"base": base, "position_offset": position_offset}
+    # (an attr only where asked for: the other programs' text stays)
+    if interleave:
+        attrs["interleave"] = True
+    if yarn:
+        attrs["yarn"] = [float(yarn[k]) for k in (
+            "factor", "original_max", "beta_fast", "beta_slow")]
     helper.append_op("rope", inputs=inputs, outputs={"Out": [out]},
-                     attrs={"base": base,
-                            "position_offset": position_offset})
+                     attrs=attrs)
     return out
 
 
@@ -749,6 +761,44 @@ def paged_decode_attention(q, pool_k, pool_v, block_table, positions,
                              "BlockTable": [block_table],
                              "Positions": [positions]},
                      outputs={"Out": [out]}, attrs=attrs)
+    return out
+
+
+def latent_prefill_attention(q, k, v, scale, impl="auto", name=None):
+    """A latent (MLA) layer's prefill attention, the expanded form: ``q``
+    and ``k`` [B, H, S, nope + rope], ``v`` [B, H, S, v_dim], causal, keys
+    wider than values (ops/latent_attention_ops.py).  Returns [B, H, S,
+    v_dim]."""
+    helper = LayerHelper("latent_prefill_attention", name=name)
+    out = helper.create_variable_for_type_inference(q.dtype)
+    attrs = {"scale": float(scale)}
+    if impl != "auto":
+        attrs["impl"] = impl
+    helper.append_op("latent_prefill_attention",
+                     inputs={"Q": [q], "K": [k], "V": [v]},
+                     outputs={"Out": [out]}, attrs=attrs)
+    return out
+
+
+def latent_decode_attention(q_nope, q_rope, w_kvb, pool, block_table,
+                            positions, scale, value_dim, name=None):
+    """A latent (MLA) layer's decode-step attention, the absorbed form:
+    ``q_nope`` [B, H, 1, nope] and ``q_rope`` [B, H, 1, rope] over the
+    latent pool [P, 1, pt, ROW] (rows ``[c_kv | k_r | 0]``) through
+    ``block_table`` at columns ``j <= positions[b]``, with the layer's
+    up-projection ``w_kvb`` [C, H * (nope + value_dim)] read as ``W_UK``
+    and ``W_UV`` (ops/latent_attention_ops.py).  Returns [B, H, 1,
+    value_dim]."""
+    helper = LayerHelper("latent_decode_attention", name=name)
+    out = helper.create_variable_for_type_inference(q_nope.dtype)
+    helper.append_op("latent_decode_attention",
+                     inputs={"QNope": [q_nope], "QRope": [q_rope],
+                             "Wkvb": [w_kvb], "Pool": [pool],
+                             "BlockTable": [block_table],
+                             "Positions": [positions]},
+                     outputs={"Out": [out]},
+                     attrs={"scale": float(scale),
+                            "value_dim": int(value_dim)})
     return out
 
 
@@ -1271,7 +1321,7 @@ def moe_routed_ffn(x, router_x, num_experts, top_k, d_ff,
                    activation="relu", valid=None, name=None,
                    keep_router_logits=False, score="softmax",
                    expert_bias=False, norm_topk=True, route_scale=1.0,
-                   held=None):
+                   held=None, limit=None):
     """Dropless top-k mixture of gated experts without bias
     (ops/moe_ops.py ``moe_routed_ffn``): each token of ``x`` [B, S, H]
     goes to the ``top_k`` experts its row of ``router_x`` [B, S, H]
@@ -1289,6 +1339,7 @@ def moe_routed_ffn(x, router_x, num_experts, top_k, d_ff,
     first + count - 1`` of the ``num_experts`` the router scores (its
     share of an expert-parallel group): the expert matrices have
     ``count`` leading rows and ``out`` is those experts' part of the sum.
+    ``limit`` L: ``act(min(gate, L)) * clip(up, -L, L)``.
     ``name`` prefixes the parameters ``.router.w`` [H, E], ``.gate_up.w``
     [E, H, 2 d_ff] and ``.down.w`` [E, d_ff, H].  Returns ``(out,
     expert_count [E] int32, router_logits or None)``."""
@@ -1323,6 +1374,8 @@ def moe_routed_ffn(x, router_x, num_experts, top_k, d_ff,
                      route_scale=float(route_scale))
     if held is not None:
         attrs["held_first"] = first
+    if limit is not None:
+        attrs["limit"] = float(limit)
     if expert_bias:
         inputs["ExpertBias"] = [helper.create_parameter(
             p("expert_bias"), [e], "float32", is_bias=True)]

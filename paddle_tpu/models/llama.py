@@ -73,11 +73,28 @@ from .. import layers
 #           (:func:`_gated_delta_mixer`), whose cache is two states a
 #           slot: the last L - 1 rows that its convolution over q | k | v
 #           saw, and a matrix [H, Dk, Dv] that every token moves on.
+#           "value_heads" may be a multiple r of "key_heads": key head j
+#           then serves value heads r j .. r j + r - 1, each with a state,
+#           a decay and a beta of its own (state [Hv, Dk, Dv]).
 #           With "decay": "channel" the layer is Kimi Delta Attention: the
 #           log decay is a vector a head, one value a key channel, from a
 #           low-rank projection of width "decay_rank"; "gate": "sigmoid"
 #           with "gate_rank" makes the output gate ``sigmoid(h W_down
-#           W_up)`` (the default: ``silu(h W_gate)``, full rank)
+#           W_up)`` (the default: ``silu(h W_gate)``, full rank); "gate":
+#           "sigmoid" WITHOUT "gate_rank" is the full-rank ``gate_scale *
+#           sigmoid(h W_gate)`` ("gate_scale" 1.0)
+#   mla:    None, or a dict that makes the attention layer LATENT
+#           (:func:`_mla_mixer`): {"q_rank": Rq, "kv_rank": C, "nope_dim":
+#           dn, "rope_dim": dr, "v_dim": dv, "scale": the softmax scale
+#           (default (dn + dr) ** -0.5), "interleave": rotate pairs (2i,
+#           2i + 1), "yarn": None or ``layers.rope``'s dict}: queries
+#           through a low-rank pair with a norm between, ONE latent
+#           ``c_kv`` [C] and one rotated key ``k_r`` [dr] a token shared
+#           by all heads, which are all that is cached (``cache_spec``
+#           kind "latent_pages"); the model's ``num_kv_heads`` and
+#           ``head_dim`` are not read by such a layer
+#   swiglu_limit: None, or L: every SwiGLU of the layer (dense, routed,
+#           shared) is ``silu(min(gate, L)) * clip(up, -L, L)``
 #   attn_gate: True multiplies the attention's output, before its output
 #           projection, by ``sigmoid(h W_g)``, ``W_g`` [hidden, heads *
 #           head_dim], elementwise
@@ -87,7 +104,7 @@ from .. import layers
 #           kernel and the matmuls take float32), whatever the mask
 DEFAULT_LAYER = {"window": None, "rope": True, "ffn": "dense",
                  "attn_precision": None, "mixer": "attention",
-                 "attn_gate": False}
+                 "attn_gate": False, "mla": None, "swiglu_limit": None}
 
 
 def layer_spec(layer_pattern, i):
@@ -105,15 +122,15 @@ def state_layers(layer_pattern, num_layers):
 
 
 def _delta_dims(mixer):
-    """A gated-delta mixer's ``(heads, key_dim, value_dim, channels of
-    its convolution: q | k | v)``."""
-    heads = int(mixer["key_heads"])
-    if int(mixer["value_heads"]) != heads:
+    """A gated-delta mixer's ``(value heads: one state each, key_dim,
+    value_dim, channels of its convolution: q | k | v, key heads)``."""
+    key_heads, heads = int(mixer["key_heads"]), int(mixer["value_heads"])
+    if heads % key_heads:
         raise ValueError(
-            f"gated_delta mixer: {mixer['value_heads']} value heads over "
-            f"{heads} key heads is not built (one state a head)")
+            f"gated_delta mixer: {heads} value heads over {key_heads} key "
+            f"heads is not built (value heads a multiple of key heads)")
     dk, dv = int(mixer["key_dim"]), int(mixer["value_dim"])
-    return heads, dk, dv, heads * (2 * dk + dv)
+    return heads, dk, dv, key_heads * 2 * dk + heads * dv, key_heads
 
 
 def window_layers(layer_pattern, num_layers):
@@ -135,15 +152,21 @@ def cache_spec(name, num_layers, layer_pattern=None, *, num_slots,
     * ``"pages"`` / ``"window_pages"``: an attention layer's K and V page
       pools (two entries, K first), ``ops/decode_ops.py`` ``pool_shape``
       of the full or the window pool's page count;
+    * ``"latent_pages"``: a latent (``mla``) attention layer's ONE pool
+      ``<name>.pool_c_<i>`` ``[num_pages, 1, page_tokens, ROW]``, a row
+      ``[c_kv | k_r]`` a token padded to whole lane tiles
+      (``ops/latent_attention_ops.py`` ``latent_pool_shape``); pages are
+      allocated, mapped and released as the K and V pools' are;
     * ``"slot_state"``: state a slot that is not pages, row ``num_slots``
       the trash row a warm-up writes.  A conv layer has one, its last
       ``L_cache - 1`` gated inputs: ``<name>.conv_state_<i>`` ``[num_slots
       + 1, L_cache - 1, hidden]``.  A gated-delta layer has two: the last
       ``conv - 1`` rows its convolution saw, ``<name>.conv_state_<i>``
-      ``[num_slots + 1, conv - 1, heads * (2 * key_dim + value_dim)]``,
+      ``[num_slots + 1, conv - 1, key_heads * 2 * key_dim + value_heads * value_dim]``,
       then the delta state ``<name>.delta_state_<i>`` ``[num_slots + 1,
-      heads, key_dim, value_dim]``."""
+      value_heads, key_dim, value_dim]``."""
     from ..ops.decode_ops import pool_shape
+    from ..ops.latent_attention_ops import latent_pool_shape
 
     windowed = window_layers(layer_pattern, num_layers)
     spec = []
@@ -153,12 +176,23 @@ def cache_spec(name, num_layers, layer_pattern=None, *, num_slots,
             if mixer["kind"] == "conv":
                 shapes = {"conv_state": [int(mixer["L_cache"]) - 1, hidden]}
             else:
-                heads, dk, dv, channels = _delta_dims(mixer)
+                heads, dk, dv, channels, _ = _delta_dims(mixer)
                 shapes = {"conv_state": [int(mixer["conv"]) - 1, channels],
                           "delta_state": [heads, dk, dv]}
             spec += [{"name": f"{name}.{what}_{i}", "layer": i,
                       "kind": "slot_state", "shape": [num_slots + 1] + shape}
                      for what, shape in shapes.items()]
+            continue
+        mla = layer_spec(layer_pattern, i)["mla"]
+        if mla:
+            if i in windowed:
+                raise ValueError("a latent (mla) attention layer under a "
+                                 "sliding window is not built")
+            spec.append({"name": f"{name}.pool_c_{i}", "layer": i,
+                         "kind": "latent_pages",
+                         "shape": latent_pool_shape(
+                             num_pages, page_tokens, int(mla["kv_rank"]),
+                             int(mla["rope_dim"]))})
             continue
         kind = "window_pages" if i in windowed else "pages"
         shape = pool_shape(num_window_pages if i in windowed else num_pages,
@@ -170,8 +204,8 @@ def cache_spec(name, num_layers, layer_pattern=None, *, num_slots,
 
 def _cache_vars(block, spec, layer):
     """Layer ``layer``'s persistable cache variables, declared from its
-    entries of :func:`cache_spec` (K and V pools, or the layer's one or
-    two states)."""
+    entries of :func:`cache_spec` (K and V pools, the one latent pool,
+    or the layer's one or two states)."""
     return tuple(block.create_var(
         name=e["name"], persistable=True, shape=e["shape"], dtype="float32",
         stop_gradient=True) for e in spec if e["layer"] == layer)
@@ -317,7 +351,7 @@ def _gated_delta_mixer(h, seq_len, hidden, mixer, p, eps, valid=None,
     is ``sigmoid((h W_g_down) W_g_up)`` of rank ``gate_rank``."""
     from ..framework.initializer import NumpyArrayInitializer
 
-    heads, dk, dv, channels = _delta_dims(mixer)
+    heads, dk, dv, channels, key_heads = _delta_dims(mixer)
     kernel = int(mixer["conv"])
     per_channel = mixer.get("decay", "head") == "channel"
     conv_state, delta_state = states if states else (None, None)
@@ -330,11 +364,20 @@ def _gated_delta_mixer(h, seq_len, hidden, mixer, p, eps, valid=None,
         t = layers.slice(c, axes=[2], starts=[lo], ends=[lo + n * d])
         return layers.reshape(t, [0, seq_len, n, d])
 
-    q = layers.scale(layers.l2_normalize(part(0, heads, dk), axis=-1,
+    q = layers.scale(layers.l2_normalize(part(0, key_heads, dk), axis=-1,
                                          epsilon=1e-6), scale=dk ** -0.5)
-    k = layers.l2_normalize(part(heads * dk, heads, dk), axis=-1,
+    k = layers.l2_normalize(part(key_heads * dk, key_heads, dk), axis=-1,
                             epsilon=1e-6)
-    v = part(2 * heads * dk, heads, dv)
+    v = part(2 * key_heads * dk, heads, dv)
+    if heads != key_heads:
+        # key head j serves value heads r j .. r j + r - 1: the ops take
+        # one q and one k a state, so each key head is repeated
+        def per_value_head(t):
+            t = layers.reshape(t, [0, seq_len, key_heads, 1, dk])
+            t = layers.tile(t, [1, 1, 1, heads // key_heads, 1])
+            return layers.reshape(t, [0, seq_len, heads, dk])
+
+        q, k = per_value_head(q), per_value_head(k)
     if per_channel:
         a = _linear(_linear(h, int(mixer["decay_rank"]),
                             pname=p("gdn_f_down.w")),
@@ -374,13 +417,16 @@ def _gated_delta_mixer(h, seq_len, hidden, mixer, p, eps, valid=None,
         elif valid is not None:
             state = last
     o = layers.rms_norm(o, epsilon=eps, param_attr=p("gdn_norm"))
-    low_rank = mixer.get("gate", "silu") == "sigmoid"
+    sigmoid = mixer.get("gate", "silu") == "sigmoid"
+    low_rank = sigmoid and "gate_rank" in mixer
     gate = _linear(_linear(h, int(mixer["gate_rank"]),
                            pname=p("gdn_g_down.w")),
                    heads * dv, pname=p("gdn_g_up.w")) if low_rank \
         else _linear(h, heads * dv, pname=p("gdn_gate.w"))
     gate = layers.reshape(gate, [0, seq_len, heads, dv])
-    gate = layers.sigmoid(gate) if low_rank else layers.silu(gate)
+    gate = layers.sigmoid(gate) if sigmoid else layers.silu(gate)
+    if float(mixer.get("gate_scale", 1.0)) != 1.0:
+        gate = layers.scale(gate, scale=float(mixer["gate_scale"]))
     o = layers.reshape(layers.elementwise_mul(o, gate),
                        [0, seq_len, heads * dv])
     return _linear(o, hidden, pname=p("gdn_out.w")), tail, state
@@ -409,9 +455,13 @@ def llama_block(x, hidden, num_heads, num_kv_heads, seq_len, head_dim,
     ``qk_norm="proj"`` over the whole projection (all heads' ``num_heads
     * head_dim`` at once, weights of that length) before the split into
     heads.  ``norm``: "pre" (the norms on the mixer's and the FFN's
-    input) or "post" (on their OUTPUT, none on their input: ``x = x +
+    input), "post" (on their OUTPUT, none on their input: ``x = x +
     norm(mixer(x)); x = x + norm(ffn(x))``, weights ``.ln1`` / ``.ln2``
-    still).
+    still) or "pre_post" (both: ``x = x + norm(mixer(norm(x)))``, four
+    weights a layer, ``.ln1`` / ``.ln1_post`` / ``.ln2`` / ``.ln2_post``).
+    A layer with ``mla`` in its pattern entry is latent attention
+    (:func:`_mla_mixer`): ``kv_cache`` is then its ONE pool, a 1-tuple,
+    and with ``collect_kv`` it returns ``(x, row, None)``.
     ``mask_block`` (uncached and ``collect_kv`` modes): the attention
     mask is block-causal, row i admits column j iff ``j // mask_block
     <= i // mask_block`` (block diffusion's prefill).  ``block`` (with
@@ -456,12 +506,20 @@ def llama_block(x, hidden, num_heads, num_kv_heads, seq_len, head_dim,
     kv_size = num_kv_heads * head_dim
     p = (lambda s: f"{name}.{s}") if name else (lambda s: None)
     x_in = x          # routed experts read the raw layer input
-    post = _norm_is_post(norm)
+    pre, post = _norm_modes(norm)
 
     def normed(t, pname):
         return layers.rms_norm(t, epsilon=rms_norm_eps, param_attr=p(pname))
 
-    h = x if post else normed(x, "ln1")
+    def post_normed(y):
+        """The mixer's output under its own norm, where the layout has
+        one (``.ln1`` under "post", ``.ln1_post`` beside the input's)."""
+        return normed(y, "ln1_post" if pre else "ln1") if post else y
+
+    ffn_args = dict(ffn=layer["ffn"], p=p, rms_norm_eps=rms_norm_eps,
+                    valid=valid, taps=taps, norm=norm,
+                    limit=layer.get("swiglu_limit"))
+    h = normed(x, "ln1") if pre else x
     if layer["mixer"] != "attention":
         state = None
         if layer["mixer"]["kind"] == "conv":
@@ -472,11 +530,18 @@ def llama_block(x, hidden, num_heads, num_kv_heads, seq_len, head_dim,
             y, tail, state = _gated_delta_mixer(
                 h, seq_len, hidden, layer["mixer"], p, rms_norm_eps,
                 valid=valid, states=conv_state, slot=slot, live=live)
-        if post:
-            y = normed(y, "ln1")
-        out = _ffn(layers.elementwise_add(x, y), x_in, hidden, intermediate,
-                   layer["ffn"], p, rms_norm_eps, valid, taps, post)
+        out = _ffn(layers.elementwise_add(x, post_normed(y)), x_in, hidden,
+                   intermediate, **ffn_args)
         return (out, tail, state) if collect_kv else out
+    if layer.get("mla"):
+        y, row = _mla_mixer(
+            h, seq_len, hidden, num_heads, layer, p, rms_norm_eps,
+            rope_base, attn_impl, kv_cache=kv_cache, positions=positions,
+            block_table=block_table, kv_lengths=kv_lengths,
+            want_row=collect_kv)
+        out = _ffn(layers.elementwise_add(x, post_normed(y)), x_in, hidden,
+                   intermediate, **ffn_args)
+        return (out, row, None) if collect_kv else out
     qkv = _linear(h, q_size + 2 * kv_size, pname=p("qkv.w"))
     q = layers.slice(qkv, axes=[2], starts=[0], ends=[q_size])
     k = layers.slice(qkv, axes=[2], starts=[q_size],
@@ -557,39 +622,137 @@ def llama_block(x, hidden, num_heads, num_kv_heads, seq_len, head_dim,
         attn = layers.elementwise_mul(attn, layers.sigmoid(
             _linear(h, q_size, pname=p("attn_gate.w"))))
     y = _linear(attn, hidden, pname=p("attn_out.w"))
-    x = layers.elementwise_add(x, normed(y, "ln1") if post else y)
-    out = _ffn(x, x_in, hidden, intermediate, layer["ffn"], p, rms_norm_eps,
-               valid, taps, post)
+    x = layers.elementwise_add(x, post_normed(y))
+    out = _ffn(x, x_in, hidden, intermediate, **ffn_args)
     if collect_kv:
         return out, new_k, new_v
     return out
 
 
-def _norm_is_post(norm):
-    if norm not in ("pre", "post"):
-        raise ValueError(f"norm is 'pre' or 'post', got {norm!r}")
-    return norm == "post"
+def _mla_mixer(h, seq_len, hidden, num_heads, layer, p, eps, rope_base,
+               attn_impl, kv_cache=None, positions=None, block_table=None,
+               kv_lengths=None, want_row=False):
+    """Latent attention (MLA) on normed rows h [B, S, H]: ``c_q = norm(h
+    W_qa)``, ``[q_nope | q_rope] = c_q W_qb`` a head; ``[c_kv | k_r] = h
+    W_kva``, ``c_kv = norm(c_kv)``; ``q_rope`` and the one ``k_r`` rotated;
+    ``[k_nope | v] = c_kv W_kvb`` a head; scores ``(q_nope . k_nope +
+    q_rope . k_r) * scale``, causal softmax, ``o = sum p v``; ``y = (o *
+    sigmoid(h W_g)) W_o`` with ``attn_gate``.  What a token leaves behind
+    is the row ``[c_kv | k_r]`` (post-norm, post-RoPE), padded to the
+    pool's whole lane tiles.
+
+    Two paths.  **Expanded** (whole sequences: the full forward and the
+    prefill): keys and values of every head are made from the latent and
+    ``latent_prefill_attention`` runs over them.  **Absorbed** (the
+    decode step, ``kv_cache`` the layer's one pool and ``seq_len`` 1): the
+    row is written, then ``latent_decode_attention`` meets the cached
+    rows as they lie, with ``W_kvb`` read as ``W_UK`` and ``W_UV``: the
+    one parameter ``.kv_b.w`` in both paths.  Returns ``(y, row)``: the
+    rows [B, 1, S, ROW] with ``want_row`` (a prefill scatters them), else
+    None."""
+    from ..ops.latent_attention_ops import latent_pool_shape
+
+    mla = layer["mla"]
+    rank_q, rank_kv = int(mla["q_rank"]), int(mla["kv_rank"])
+    dn, dr, dv = (int(mla[k]) for k in ("nope_dim", "rope_dim", "v_dim"))
+    scale = float(mla.get("scale") or (dn + dr) ** -0.5)
+    rot = dict(base=rope_base, interleave=bool(mla.get("interleave")),
+               yarn=mla.get("yarn"),
+               offset=positions if kv_cache is not None else None)
+
+    def normed(t, pname):
+        return layers.rms_norm(t, epsilon=eps, param_attr=p(pname))
+
+    def cut(t, axis, lo, hi):
+        return layers.slice(t, axes=[axis], starts=[lo], ends=[hi])
+
+    def heads(t, n, d):
+        return layers.transpose(layers.reshape(t, [0, seq_len, n, d]),
+                                [0, 2, 1, 3])               # [B, n, S, d]
+
+    q = heads(_linear(normed(_linear(h, rank_q, pname=p("q_a.w")),
+                             "q_a_norm"),
+                      num_heads * (dn + dr), pname=p("q_b.w")),
+              num_heads, dn + dr)
+    q_nope = cut(q, 3, 0, dn)
+    q_rope = layers.rope(cut(q, 3, dn, dn + dr), **rot)
+    kv_a = _linear(h, rank_kv + dr, pname=p("kv_a.w"))
+    c_kv = normed(cut(kv_a, 2, 0, rank_kv), "kv_a_norm")     # [B, S, C]
+    k_r = layers.rope(heads(cut(kv_a, 2, rank_kv, rank_kv + dr), 1, dr),
+                      **rot)                                 # [B, 1, S, dr]
+    row = None
+    if want_row or kv_cache is not None:
+        lanes = latent_pool_shape(1, 1, rank_kv, dr)[-1]
+        row = layers.pad(
+            layers.concat([layers.unsqueeze(c_kv, [1]), k_r], axis=3),
+            [0, 0, 0, 0, 0, 0, 0, lanes - rank_kv - dr])
+    kv_b = num_heads * (dn + dv)
+    if kv_cache is not None:
+        if seq_len != 1:
+            raise ValueError(
+                "a chunk of rows that attends latent pages is not built "
+                "(the absorbed path is the one-row decode step's)")
+        pool = layers.kv_pool_write(kv_cache[0], row, positions,
+                                    block_table, kv_lengths)
+        attn = layers.latent_decode_attention(
+            q_nope, q_rope,
+            layers.create_parameter([rank_kv, kv_b], "float32",
+                                    name=p("kv_b.w")),
+            pool, block_table, positions, scale, dv)
+    else:
+        kv = heads(_linear(c_kv, kv_b, pname=p("kv_b.w")), num_heads,
+                   dn + dv)
+        k = layers.concat([cut(kv, 3, 0, dn),
+                           layers.tile(k_r, [1, num_heads, 1, 1])], axis=3)
+        attn = layers.latent_prefill_attention(
+            layers.concat([q_nope, q_rope], axis=3), k,
+            cut(kv, 3, dn, dn + dv), scale, impl=attn_impl)
+    attn = layers.reshape(layers.transpose(attn, [0, 2, 1, 3]),
+                          [0, seq_len, num_heads * dv])
+    if layer.get("attn_gate"):
+        attn = layers.elementwise_mul(attn, layers.sigmoid(
+            _linear(h, num_heads * dv, pname=p("attn_gate.w"))))
+    return _linear(attn, hidden, pname=p("attn_out.w")), row
 
 
-def _swiglu(h, hidden, width, gate_up_name, down_name):
-    """``(silu(h W_gate) * (h W_up)) W_down`` with gate | up fused."""
+def _norm_modes(norm):
+    """``(pre, post)``: whether a sublayer's input and its output are
+    normed under this layout."""
+    if norm not in ("pre", "post", "pre_post"):
+        raise ValueError(f"norm is 'pre', 'post' or 'pre_post', got "
+                         f"{norm!r}")
+    return norm != "post", norm != "pre"
+
+
+def _swiglu(h, hidden, width, gate_up_name, down_name, limit=None):
+    """``(silu(h W_gate) * (h W_up)) W_down`` with gate | up fused; with
+    ``limit`` L the gate is held under L and the up to [-L, L] first."""
     gate_up = _linear(h, 2 * width, pname=gate_up_name)
     gate = layers.slice(gate_up, axes=[2], starts=[0], ends=[width])
     up = layers.slice(gate_up, axes=[2], starts=[width], ends=[2 * width])
+    if limit is not None:
+        # (the gate is held from above alone: the clip's floor is float32's)
+        gate = layers.clip(gate, -3.0e38, float(limit))
+        up = layers.clip(up, -float(limit), float(limit))
     return _linear(layers.elementwise_mul(layers.silu(gate), up), hidden,
                    pname=down_name)
 
 
 def _ffn(x, x_in, hidden, intermediate, ffn, p, rms_norm_eps, valid, taps,
-         post=False):
+         norm="pre", limit=None):
     """The layer's second half on the post-mixer stream x: norm, dense
     SwiGLU or routed experts (``x_in``: the layer's raw input, which some
-    routers read), residual.  ``post``: the norm is on the FFN's output,
-    not its input."""
-    h = x if post else layers.rms_norm(x, epsilon=rms_norm_eps,
-                                       param_attr=p("ln2"))
+    routers read), residual.  ``norm``: where the norms sit
+    (:func:`_norm_modes`; the output's is ``.ln2`` under "post",
+    ``.ln2_post`` beside the input's ``.ln2``).  ``limit``: the SwiGLUs'
+    clamp."""
+    pre, post = _norm_modes(norm)
+    h = layers.rms_norm(x, epsilon=rms_norm_eps,
+                        param_attr=p("ln2")) if pre else x
+    clamp = {} if limit is None else {"limit": float(limit)}
     if ffn == "dense":
-        y = _swiglu(h, hidden, intermediate, p("gate_up.w"), p("ffn_out.w"))
+        y = _swiglu(h, hidden, intermediate, p("gate_up.w"), p("ffn_out.w"),
+                    **clamp)
     else:
         taps = taps if taps is not None else {}
         y, counts, logits = layers.moe_routed_ffn(
@@ -598,7 +761,8 @@ def _ffn(x, x_in, hidden, intermediate, ffn, p, rms_norm_eps, valid, taps,
             activation=ffn.get("activation", "relu"), valid=valid,
             name=p("moe"), keep_router_logits=bool(taps.get("keep_logits")),
             **{k: ffn[k] for k in ("score", "expert_bias", "norm_topk",
-                                   "route_scale", "held") if k in ffn})
+                                   "route_scale", "held") if k in ffn},
+            **clamp)
         taps.setdefault("counts", []).append(counts)
         if logits is not None:
             taps.setdefault("logits", []).append(logits)
@@ -607,9 +771,10 @@ def _ffn(x, x_in, hidden, intermediate, ffn, p, rms_norm_eps, valid, taps,
             # expert-parallel group alike, so counted once
             y = layers.elementwise_add(y, _swiglu(
                 h, hidden, int(ffn["shared_width"]),
-                p("moe.shared_gate_up.w"), p("moe.shared_down.w")))
+                p("moe.shared_gate_up.w"), p("moe.shared_down.w"), **clamp))
     if post:
-        y = layers.rms_norm(y, epsilon=rms_norm_eps, param_attr=p("ln2"))
+        y = layers.rms_norm(y, epsilon=rms_norm_eps,
+                            param_attr=p("ln2_post" if pre else "ln2"))
     return layers.elementwise_add(x, y)
 
 
@@ -716,6 +881,12 @@ def build_llama_prefill(batch_size, seq_len, vocab_size=32000,
     ``rows_written`` [1] (the ``prompt_len`` fed), the expert counts and,
     with ``keep_router_logits``, ``router_logits`` [B, L_moe, S, E] of
     every row.
+
+    A latent (``mla``) attention layer attends by the expanded path and
+    writes whole pages of ``[c_kv | k_r]`` rows (post-norm, post-RoPE)
+    into its ONE pool ``<name>.pool_c_<i>`` through the same block table;
+    in the other mode the rows come back as the fetch ``latent_<i>``
+    [B, 1, S, ROW].
 
     Sliding-window layers keep their pages in pools of
     their own (``num_window_pages`` pages each) behind a second feed
@@ -842,13 +1013,14 @@ def build_llama_prefill(batch_size, seq_len, vocab_size=32000,
             # go to the trash page, and so do a window layer's rows
             # whose page the window no longer covers.  One slot from
             # position 0: a bucket of whole pages goes in page by page
+            # (a latent layer has the one pool, and the one row for it)
             for pool, t in zip(caches, (k, v)):
                 layers.kv_pool_write(
                     pool, t, zero_pos,
                     bt_window if i in windowed else block_table,
                     prompt_len, whole_pages=seq_len % page_tokens == 0)
         else:
-            kvs.append((i, {"k": k, "v": v}))
+            kvs.append((i, {"latent": k} if v is None else {"k": k, "v": v}))
     if mask_block is not None:
         # (kept router logits are every row's, [B, L_moe, S, E]: no row
         # is yielded, so none is picked)
@@ -900,7 +1072,11 @@ def build_llama_decode(num_slots, max_seq_len, vocab_size=32000,
     heads, Dk, Dv] by one token (its convolution's rows too), in place,
     for the rows ``live`` marks; a dead row's state stays as it was.  The
     engine finds those variables through ``cache_spec``; the
-    ``cache_names`` returned here are the page pools alone.
+    ``cache_names`` returned here are the page pools alone.  A latent
+    (``mla``) attention layer has ONE pool, ``<name>.pool_c_<i>``
+    (``cache_spec`` kind ``latent_pages``): the step writes its row
+    ``[c_kv | k_r]`` and attends by the absorbed path
+    (``latent_decode_attention``), each live page read once.
 
     ``block=B`` (block diffusion, with ``mask_id``; full-attention
     layers only) makes a slot's rows a block of B positions at
@@ -1059,6 +1235,12 @@ def _chunk_forward(chunk_len, max_seq_len, num_pages, page_tokens,
             "speculative verify) is not built for a model whose layers "
             "keep slot state: a chunk would have to start from, and a "
             "rejected draft roll back, state that is not pages")
+    if any(layer_spec(layer_pattern, i)["mla"] for i in range(num_layers)):
+        raise ValueError(
+            "prefill continuation (chunked prefill, prefix reuse, "
+            "speculative verify) is not built for a model with latent "
+            "(mla) attention layers: a chunk would attend latent pages "
+            "by the expanded path, from rows it has to expand first")
     num_kv_heads = num_kv_heads or num_heads
     head_dim = head_dim or hidden // num_heads
     np_slot = max_seq_len // page_tokens

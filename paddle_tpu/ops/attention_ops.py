@@ -41,6 +41,13 @@ _LOWERED = {
     "paged_decode": _monitor.get("attention_lowered_paged_decode"),
     "paged_decode_reference":
         _monitor.get("attention_lowered_paged_decode_reference"),
+    # a latent (MLA) layer's two attentions (ops/latent_attention_ops.py):
+    # the expanded prefill kernel, and the absorbed decode step as the
+    # Pallas kernel over live latent pages or the gathered formulation
+    "latent_prefill": _monitor.get("attention_lowered_latent_prefill"),
+    "latent_decode": _monitor.get("attention_lowered_latent_decode"),
+    "latent_decode_reference":
+        _monitor.get("attention_lowered_latent_decode_reference"),
     # the same ops under a sliding window (``window`` attr set): booked
     # beside the plain counters, which they also raise
     "pallas_window": _monitor.get("attention_lowered_pallas_window"),

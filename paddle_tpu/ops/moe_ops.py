@@ -67,7 +67,8 @@ def _moe_routed_ffn(ctx, op):
     selection bias of sigmoid scoring (optional).  With the attribute
     ``held_first`` GateUpW and DownW hold the experts from that index on
     alone, one chip's share of RouterW's E, and Out is their part of the
-    sum.  Inference only."""
+    sum.  Attribute ``limit``: the experts' SwiGLU clamp.  Inference
+    only."""
     import jax.numpy as jnp
 
     from ..parallel.moe import moe_routed_tokens
@@ -93,7 +94,8 @@ def _moe_routed_ffn(ctx, op):
         if op.single_input("ExpertBias") else None,
         norm_topk=bool(op.attr("norm_topk", True)),
         route_scale=float(op.attr("route_scale", 1.0)),
-        held_first=op.attr("held_first", None))
+        held_first=op.attr("held_first", None),
+        limit=op.attr("limit", None))
     ctx.set_output(op, "Out", out.reshape(shape))
     ctx.set_output(op, "ExpertCount", counts)
     if op.output("RouterLogits"):

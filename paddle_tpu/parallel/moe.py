@@ -250,12 +250,15 @@ def grouped_matmul(rows, weights, group_sizes, precision=None):
     return _one_call(rows, weights, group_sizes, precision)
 
 
-def _gated(h, inter, activation):
-    """``act(gate) * up`` of the fused first product's columns."""
+def _gated(h, inter, activation, limit=None):
+    """``act(gate) * up`` of the fused first product's columns; with
+    ``limit`` L the gate is held under L and the up to [-L, L] first."""
     import jax
     import jax.numpy as jnp
 
     gate, up = h[:, :inter], h[:, inter:]
+    if limit is not None:
+        gate, up = jnp.minimum(gate, limit), jnp.clip(up, -limit, limit)
     if activation == "relu":
         gate = jnp.maximum(gate, 0)
     elif activation == "silu":
@@ -266,7 +269,7 @@ def _gated(h, inter, activation):
 
 
 def _held_share(x, local, weights, w_gate_up, w_down, activation,
-                precision):
+                precision, limit=None):
     """The held experts' part of the layer: ``local`` [N, k] is each
     pair's index among the experts held here, or ``held`` (= ``w_gate_up``
     's leading size) where its expert lives on another chip.  The pairs
@@ -299,8 +302,8 @@ def _held_share(x, local, weights, w_gate_up, w_down, activation,
         size = jnp.clip(jnp.minimum(ends, lo + run) - jnp.maximum(starts, lo),
                         0, None).astype(jnp.int32)
         h = _one_call(rows, w_gate_up.astype(x.dtype), size, precision)
-        y = _one_call(_gated(h, inter, activation), w_down.astype(x.dtype),
-                      size, precision)
+        y = _one_call(_gated(h, inter, activation, limit),
+                      w_down.astype(x.dtype), size, precision)
         y = y * jnp.take(pair_w, pairs)[:, None].astype(y.dtype)
         # rows past the held pairs belong to no group: whatever the
         # kernel left there is dropped, not scaled
@@ -314,7 +317,8 @@ def moe_routed_tokens(x, router_x, router_w, w_gate_up, w_down, *,
                       top_k: int, activation: str = "relu", valid=None,
                       precision=None, score: str = "softmax",
                       expert_bias=None, norm_topk: bool = True,
-                      route_scale: float = 1.0, held_first=None):
+                      route_scale: float = 1.0, held_first=None,
+                      limit=None):
     """Dropless top-k mixture of gated experts over flat tokens.
 
     x [N, H] is the experts' input, router_x [N, H] what the router
@@ -325,7 +329,8 @@ def moe_routed_tokens(x, router_x, router_w, w_gate_up, w_down, *,
     and nothing is dropped.  Tokens are sorted by expert, the two
     grouped matmuls read only experts that got rows, and the k results
     per token are summed under the routing weights.  ``activation`` is
-    the gate's: "relu" or "silu"; ``score``, ``expert_bias``,
+    the gate's: "relu" or "silu" (``limit``: :func:`_gated`'s clamp);
+    ``score``, ``expert_bias``,
     ``norm_topk`` and ``route_scale`` are :func:`route_top_k`'s.
 
     ``held_first`` (one chip's share of an expert-parallel group): the
@@ -359,7 +364,8 @@ def moe_routed_tokens(x, router_x, router_w, w_gate_up, w_down, *,
         here = (experts >= held_first) & (experts < held_first + held) \
             & pair_valid
         out = _held_share(x, jnp.where(here, experts - held_first, held),
-                          weights, w_gate_up, w_down, activation, precision)
+                          weights, w_gate_up, w_down, activation, precision,
+                          limit)
         counts = jnp.zeros((E,), jnp.int32).at[experts.reshape(-1)].add(
             jnp.broadcast_to(pair_valid, experts.shape).reshape(-1)
             .astype(jnp.int32))
@@ -370,7 +376,7 @@ def moe_routed_tokens(x, router_x, router_w, w_gate_up, w_down, *,
     rows = jnp.take(x, order // top_k, axis=0)          # [N*k, H]
     h = grouped_matmul(rows, w_gate_up.astype(x.dtype), group_sizes,
                        precision)
-    y = grouped_matmul(_gated(h, inter, activation),
+    y = grouped_matmul(_gated(h, inter, activation, limit),
                        w_down.astype(x.dtype), group_sizes,
                        precision)                       # [N*k, H]
     y = y * jnp.take(weights.reshape(-1), order)[:, None].astype(y.dtype)

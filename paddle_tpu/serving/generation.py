@@ -746,6 +746,10 @@ class GenerationEngine:
         self._delta_layers = [i for i in self._state_layers
                               if specs[i]["mixer"]["kind"] == "gated_delta"]
         self._window_layers = window_layers(pattern, n_layers)
+        # attention layers whose pages hold one latent row a token
+        self._latent_layers = [i for i in range(n_layers)
+                               if i not in self._state_layers
+                               and specs[i]["mla"]]
         widths = {specs[i]["window"] for i in self._window_layers}
         if len(widths) > 1:
             raise ValueError(f"sliding-window layers of one model share "
@@ -1098,13 +1102,15 @@ class GenerationEngine:
         # ... and the per-slot state that is not pages (trash row included)
         self.slot_state_bytes = state_total
         telemetry.gauge_set("serving_slot_state_bytes", state_total)
-        # bytes one page costs across every layer's K+V pool (of its
-        # kind: a window page spans the window layers only)
-        layer_page = 2 * self._n_kv * self.page_tokens * self._head_dim * 4
-        n_window = len(self._window_layers)
-        self.page_bytes = (len(self.cache_names) // 2 - n_window) \
-            * layer_page
-        self.window_page_bytes = n_window * layer_page
+        # bytes one page costs across every layer's pools of its kind (a
+        # window page spans the window layers only; a latent layer has
+        # one pool of rows, not K and V of heads)
+        def page_bytes(*kinds):
+            return sum(int(np.prod(e["shape"][1:])) * 4
+                       for e in self._cache_spec if e["kind"] in kinds)
+
+        self.page_bytes = page_bytes("pages", "latent_pages")
+        self.window_page_bytes = page_bytes("window_pages")
         telemetry.gauge_set("serving_kv_cache_bytes", total)
         self._publish_pool_gauges()
 
@@ -1114,6 +1120,9 @@ class GenerationEngine:
         telemetry.gauge_set("serving_kv_pages_live",
                             self._pool.live_pages)
         telemetry.gauge_set("serving_kv_live_bytes", self.kv_live_bytes)
+        if self._latent_layers:
+            telemetry.gauge_set("serving_latent_pages_live",
+                                self._pool.live_pages)
         if self._wpool is not None:
             telemetry.gauge_set("serving_kv_pages_live_full",
                                 self._pool.live_pages)
@@ -2536,11 +2545,14 @@ class GenerationEngine:
                             scan_tokens=n_rows, scan_chunks=chunks,
                             scan_pad_chunks=chunks
                             - -(-n_rows // DELTA_CHUNK))
+                # the rows [c_kv | k_r] a latent layer's pool took
+                latent = {"latent_rows_written": n_rows} \
+                    if self._latent_layers else {}
             outs = self._launch(
                 "generation/prefill", lambda: self._run_fetching(
                     self._prefill_exe, prog, fetches, feed),
                 parent=parent, tokens=n_rows, bucket=bucket, slot=slot.idx,
-                **state)
+                **state, **latent)
             if state:
                 self._count("slot_state_writes")
                 stat_add("serving_slot_state_writes")
@@ -3141,17 +3153,20 @@ class GenerationEngine:
                 live_positions=int(sum(s.position + 1 for s in rows)),
                 live_positions_window=int(sum(
                     min(s.position + 1, self.window) for s in rows)))
+        if self.state_names or self._latent_layers:
+            live = int(sum(s.position + 1
+                           for s, r in fl.riders if s.req is r))
         if self.state_names:
             # the slots whose state this step moved on (every row that
             # rode it), and the positions its attention layers read
-            attrs.update(
-                state_slots=len(fl.riders),
-                live_positions=int(sum(s.position + 1
-                                       for s, r in fl.riders if s.req is r)))
+            attrs.update(state_slots=len(fl.riders), live_positions=live)
             if self._delta_layers:
                 moved = len(fl.riders) * len(self._delta_layers)
                 self._count("delta_state_steps", moved)
                 stat_add("serving_delta_state_steps", moved)
+        if self._latent_layers:
+            # the cached rows a latent layer's decode kernel read
+            attrs["latent_positions"] = live
         if self._blk:
             # what this pass was, slot by slot: the booked state is the
             # state it was dispatched from
@@ -3745,6 +3760,7 @@ class GenerationEngine:
                 "pages_free": self._pool.free_pages,
                 "pages_live": self._pool.live_pages,
                 "page_bytes": self.page_bytes,
+                "latent_layers": len(self._latent_layers),
                 "window": None if self._wpool is None else {
                     "window": self.window,
                     "num_pages": self.num_window_pages,

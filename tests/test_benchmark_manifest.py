@@ -2,8 +2,8 @@
 tier-1 tests (PR 41; PR 38 left it to "a later PR of another kind"): the
 pure-JSON checks of ``benchmark/tests/test_manifest.py`` are collected
 here as they stand (entries against data files, every entry's
-``workloads``, each cell's count of values), and the cells that PR 41 and
-PR 43 added are pinned beside them, by name: each one's own entries, the
+``workloads``, each cell's count of values), and the cells that PR 41, PR
+43 and PR 47 added are pinned beside them, by name: each one's own entries, the
 shared ``.pool`` entries that list it, its configuration's cut and its
 mix.  No JAX is imported and no engine started.
 """
@@ -32,11 +32,12 @@ manifest.REPORTS["bert-base-seq512-dp4"] += 1
 globals().update({name: fn for name, fn in vars(manifest).items()
                   if name.startswith("test_")})
 
-# the cells that model_config PRs added since the merge (PR 41, PR 43),
+# the cells that model_config PRs added since the merge (PR 41, 43, 47),
 # in the order they were added: the cell's configuration and mix, its own
 # entries, the shared families that list it beside ``POOL``, what its
 # configuration cuts, and its mix's driver and reference rungs
 OLMO, SOLAR = "olmo-hybrid7b-longdoc", "solar-open2-agentturns"
+GIGA = "gigachat35-ragturns"
 ADDED = {
     OLMO: {
         "config": "olmo-hybrid-7b", "mix": "longdoc-pool",
@@ -60,6 +61,22 @@ ADDED = {
         "reduced": ["num_hidden_layers", "gqa_layers", "n_routed_experts",
                     "vocab_size"],
         "driver": "serve_share", "rungs": [256, 2048, 4096]},
+    GIGA: {
+        "config": "gigachat35-432b-a28b", "mix": "ragturns-pool",
+        "own": ["decode_step_roofline.giga", "prefill_roofline.giga",
+                "mla_decode_bytes_roofline.giga",
+                "mla_decode_flops_roofline.giga",
+                "mla_prefill_roofline.giga", "mla_kernel_share_pct.giga",
+                "gdn_step_roofline.giga", "gdn_chunk_roofline.giga",
+                "gdn_kernel_share_pct.giga", "state_slots_pct.giga",
+                "scan_pad_pct.giga", "moe_pairs_held_pct.giga",
+                "moe_held_touched_pct.giga", "latent_fill_pct.giga"],
+        "experts": ["moe_expert_load_max_over_mean.pool",
+                    "expert_matmul_share_pct.pool"],
+        "reduced": ["num_hidden_layers", "first_k_dense_replace",
+                    "full_attention_layers", "n_routed_experts",
+                    "vocab_size", "num_nextn_predict_layers"],
+        "driver": "serve_share", "rungs": [256, 1024, 2048]},
 }
 
 
@@ -68,7 +85,7 @@ def _json(*parts):
         return json.load(f)
 
 
-def test_the_benchmark_has_seven_configurations_and_nine_cells():
+def test_the_benchmark_has_eight_configurations_and_ten_cells():
     spec = manifest.SPEC
     assert [c["name"] for c in spec["configs"]] == [
         "bert-base-mlm", "mistral-7b-v0.1", "smallthinker-21b-a3b",
@@ -149,6 +166,27 @@ def test_an_added_cell_reports_its_own_and_the_shared_entries(cell):
     ("moe_held_touched_pct.solar", "span_attr_mean",
      "experts_held_touched"),
     ("moe_pairs_held_pct.solar", "span_attr_ratio", "pairs_held"),
+    ("decode_step_roofline.giga", "roofline_span",
+     ["experts_held_touched", "latent_positions", "state_slots"]),
+    ("prefill_roofline.giga", "roofline", "prefill"),
+    ("mla_decode_bytes_roofline.giga", "roofline_kernel",
+     "^%?mla_decode_attention"),
+    ("mla_decode_flops_roofline.giga", "roofline_kernel",
+     "^%?mla_decode_attention"),
+    ("mla_prefill_roofline.giga", "roofline_kernel_prefill",
+     "^%?mla_prefill_attention"),
+    ("mla_kernel_share_pct.giga", "trace_op_share",
+     "^%?mla_(decode|prefill)_attention"),
+    ("gdn_step_roofline.giga", "roofline_kernel", "^%?gated_delta_step"),
+    ("gdn_chunk_roofline.giga", "roofline_kernel_prefill",
+     "^%?gated_delta_chunk"),
+    ("gdn_kernel_share_pct.giga", "trace_op_share", "^%?gated_delta"),
+    ("state_slots_pct.giga", "span_attr_mean", "state_slots"),
+    ("scan_pad_pct.giga", "span_attr_ratio", "scan_pad_chunks"),
+    ("moe_pairs_held_pct.giga", "span_attr_ratio", "pairs_held"),
+    ("moe_held_touched_pct.giga", "span_attr_mean",
+     "experts_held_touched"),
+    ("latent_fill_pct.giga", "span_attr_mean", "latent_positions"),
 ])
 def test_each_new_metric_reads_a_span_a_counter_or_a_named_kernel(
         name, reader, reads):
@@ -172,6 +210,16 @@ def test_each_new_metric_reads_a_span_a_counter_or_a_named_kernel(
         assert attr + "=" in engine
     assert 'name="gated_delta_step"' in kernels
     assert 'name="gated_delta_chunk"' in kernels
+    if name.endswith(".giga"):
+        with open(os.path.join(REPO, "paddle_tpu", "ops", "pallas",
+                               "latent_attention.py")) as f:
+            latent = f.read()
+        assert 'name="mla_decode_attention"' in latent
+        assert 'name="mla_prefill_attention"' in latent
+        assert 'attrs["latent_positions"]' in engine
+        assert '"latent_rows_written"' in engine
+        if "attr" in args and reader == "roofline_kernel_prefill":
+            assert args["attr"] in ("latent_rows_written", "scan_tokens")
 
 
 @pytest.mark.parametrize("cell", list(ADDED))
@@ -182,7 +230,32 @@ def test_an_added_configuration_cuts_what_it_says_and_no_width(cell):
               if c["name"] == added["config"]]
     assert entry["reduced"] == cfg["reduced"] == added["reduced"]
     assert entry["source"] == cfg["source"]
-    if cell == OLMO:
+    if cell == GIGA:
+        assert (cfg["hidden_size"], cfg["num_attention_heads"],
+                cfg["kv_lora_rank"], cfg["q_lora_rank"],
+                cfg["qk_nope_head_dim"], cfg["qk_rope_head_dim"],
+                cfg["v_head_dim"], cfg["intermediate_size"],
+                cfg["moe_intermediate_size"], cfg["num_experts_per_tok"],
+                cfg["linear_num_key_heads"], cfg["linear_num_value_heads"],
+                cfg["linear_key_head_dim"], cfg["linear_value_head_dim"],
+                cfg["swiglu_limit"], cfg["routed_scaling_factor"]) \
+            == (7168, 64, 512, 1536, 128, 64, 128, 18432, 2048, 8, 32, 64,
+                128, 128, 10, 2.5)
+        # ``reduced`` against ``published``: the guide's floors (a leading
+        # dense layer and a whole period, 8 experts held, an eighth of the
+        # vocabulary), the drafting heads gone with the depth
+        assert cfg["published"] == {
+            "num_hidden_layers": 40, "first_k_dense_replace": 3,
+            "full_attention_layers": list(range(3, 40, 4)),
+            "n_routed_experts": 256, "vocab_size": 128256,
+            "num_nextn_predict_layers": 2}
+        assert [cfg[k] for k in added["reduced"]] \
+            == [5, 1, [1], 8, 16032, 0]
+        assert cfg["expert_share"] == dict(
+            cfg["expert_share"], router_experts=256, first=0)
+        assert cfg["vocab_size"] * 8 == 128256
+        assert cfg["as_run"]["latent_row"]["lanes"] == 640
+    elif cell == OLMO:
         assert (cfg["hidden_size"], cfg["num_attention_heads"],
                 cfg["intermediate_size"], cfg["vocab_size"],
                 cfg["linear_key_head_dim"], cfg["linear_value_head_dim"],
@@ -208,7 +281,7 @@ def test_an_added_configuration_cuts_what_it_says_and_no_width(cell):
         assert cfg["n_routed_experts"] == 20 >= 8
         assert cfg["expert_share"]["router_experts"] == 320
         assert cfg["vocab_size"] * 8 == 196608
-    assert cfg["num_hidden_layers"] == 4
+    assert cfg["num_hidden_layers"] == (5 if cell == GIGA else 4)
     for key in ("assumed", "as_run", "deployment", "check_tolerance",
                 "rehearse", "builder"):
         assert key in cfg

@@ -625,21 +625,27 @@ def test_the_uncached_prefill_returns_both_states():
     assert tuple(fetches["delta_state_0"].shape) == (2, 4, 8, 16)
 
 
-def test_unequal_key_and_value_heads_are_refused():
+def test_value_heads_that_are_no_multiple_of_key_heads_are_refused():
+    """Since PR 47 value heads may be a multiple of the key heads (4
+    here): 8 give a state a value head, 6 are refused."""
     from paddle_tpu.models.llama import cache_spec
 
-    mixer = dict(BUILDER.layer_pattern(_cfg())[0]["mixer"], value_heads=8)
-    with pytest.raises(ValueError, match="one state a head"):
+    mixer = dict(BUILDER.layer_pattern(_cfg())[0]["mixer"], value_heads=6)
+    with pytest.raises(ValueError, match="multiple of key heads"):
         cache_spec("llama", 1, [{"mixer": mixer}], num_slots=2, num_pages=4,
                    page_tokens=PAGE, num_kv_heads=4, head_dim=16, hidden=64)
+    spec = cache_spec("llama", 1, [{"mixer": dict(mixer, value_heads=8)}],
+                      num_slots=2, num_pages=4, page_tokens=PAGE,
+                      num_kv_heads=4, head_dim=16, hidden=64)
+    assert [e["shape"][1] for e in spec if "delta_state" in e["name"]] == [8]
 
 
-def test_norm_is_pre_or_post():
+def test_norm_is_pre_post_or_both_by_name():
     from paddle_tpu.models.llama import build_llama_forward
 
     main, startup = pt.Program(), pt.Program()
     with pt.program_guard(main, startup):
-        with pytest.raises(ValueError, match="'pre' or 'post'"):
+        with pytest.raises(ValueError, match="'pre', 'post' or 'pre_post'"):
             build_llama_forward(1, 8, vocab_size=97, hidden=64,
                                 num_layers=1, num_heads=4, intermediate=96,
                                 name="llama", norm="both")

@@ -107,6 +107,12 @@ def test_nn_namespace_builds_and_runs():
 def test_hapi_model_fit_evaluate_predict(tmp_path):
     x, y = _toy_data()
     from paddle_tpu import dygraph
+    from paddle_tpu.ops.registry import reset_op_seed
+    # the draw of the weights and the shuffle follow process-wide counters:
+    # pinned, so that the loss this test holds to a threshold does not
+    # depend on which test files the worker ran before this one
+    np.random.seed(0)
+    reset_op_seed()
     with dygraph.guard():
         net = nn.Sequential(nn.Linear(6, 16), nn.Tanh(),
                             nn.Linear(16, 2))

@@ -2,13 +2,24 @@
 """The gated delta rule's kernels against their XLA formulations, on the
 chip.
 
-    chiprun -- python tools/gated_delta_microbench.py [channel]
+    chiprun -- python tools/gated_delta_microbench.py [channel | giga]
 
 With ``channel``: the decay a vector a key channel at 64 heads of 128 x
 128 over 64 slots (``solar-open2-250b``), the step at 1, 4, 8, 16 and 32
 heads a block, the scan over rungs of 1024 and 4096 (its terms made eight
 heads a turn, as the op makes them); written to
 ``chiprun_out/gated_delta_microbench_channel.json``.
+
+With ``giga``: a state of [64, 128, 128] a slot whose 64 value heads
+share 32 key heads, over 32 slots, rungs of 1024 and 2048
+(``gigachat35-432b-a28b``): the step and the scan with each key head
+REPEATED for its two value heads, as the mixer hands them to the ops; what
+making the chunk's ``k k^T`` and ``q k^T`` once a KEY head could save at
+most (those two products alone, at 32 heads against 64: decay and beta are
+a value head's, so everything behind them is not shared); and the latent
+decode kernel alone, 32 slots of 2,000 cached rows of 640 lanes, a hundred
+calls inside one jitted loop; written to
+``chiprun_out/gated_delta_microbench_giga.json``.
 
 Times, at a hybrid decoder's published head sizes (30 heads, keys of 96,
 values of 192, float32): the decode step over 28 slots, a hundred steps
@@ -36,9 +47,13 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
 CHANNEL = "channel" in sys.argv[1:]
-H, DK, DV, SLOTS = (64, 128, 128, 64) if CHANNEL else (30, 96, 192, 28)
-HEADS_BLOCKS = (1, 4, 8, 16, 32) if CHANNEL else (1, 10, 30)
-RUNGS = (1024, 4096) if CHANNEL else (2048, 6144)
+GIGA = "giga" in sys.argv[1:]
+H, DK, DV, SLOTS = (64, 128, 128, 64) if CHANNEL else \
+    (64, 128, 128, 32) if GIGA else (30, 96, 192, 28)
+HEADS_BLOCKS = (1, 4, 8, 16, 32) if CHANNEL else \
+    (8, 16, 32) if GIGA else (1, 10, 30)
+RUNGS = (1024, 4096) if CHANNEL else (1024, 2048) if GIGA else (2048, 6144)
+KEY_HEADS = 32                      # under ``giga``: two value heads each
 HBM = 819e9
 
 
@@ -51,6 +66,45 @@ def timed(fn, *args, reps=20):
         out = fn(*args)
     jax.block_until_ready(out)
     return (time.perf_counter() - t0) / reps * 1e3
+
+
+def latent_decode(say, loops, slots=32, cached=2000, pt=16):
+    """The latent decode kernel alone: 64 query rows of 640 lanes a slot
+    over ``cached`` rows a slot, ``loops`` calls in one jitted loop (each
+    call's output feeds the next one's query, so none is dropped)."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops.pallas import latent_attention as la
+
+    heads, c, row = 64, 512, la.row_lanes(576)
+    np_slot = -(-cached // pt)
+    key = jax.random.key(47)
+    pool = jax.random.normal(key, (slots * np_slot + 1, 1, pt, row),
+                             jnp.float32)
+    table = (jnp.arange(slots * np_slot, dtype=jnp.int32) + 1).reshape(
+        slots, np_slot)
+    pos = jnp.full((slots,), cached - 1, jnp.int32)
+    q = jax.random.normal(jax.random.fold_in(key, 1), (slots, heads, row),
+                          jnp.float32) * 0.05
+
+    @jax.jit
+    def run(q, pool):
+        def body(_, q):
+            o = la.mla_decode_attention(q, pool, table, pos, scale=0.07,
+                                        value_dim=c)
+            return q.at[:, :, :c].add(1e-3 * o)
+        return jax.lax.fori_loop(0, loops, body, q)
+
+    ms = timed(run, q, pool, reps=3) / loops
+    nbytes = slots * cached * row * 4
+    say(f"latent decode kernel, {slots} slots x {cached} rows of {row} "
+        f"lanes", ms, nbytes)
+    flops = 2.0 * heads * (576 + c) * slots * cached
+    print(f"  = {flops / (ms / 1e3) / 1e12:.2f} TFLOP/s of the work's "
+          f"{flops / 1e9:.2f} GFLOP ({100 * flops / (ms / 1e3) / 197e12:.1f}"
+          f"% of the bf16 peak; float32 whole takes six passes)",
+          flush=True)
 
 
 def main() -> int:
@@ -128,6 +182,25 @@ def main() -> int:
                                                   reps=5), nbytes)
         say(f"chunk {T}, terms + Pallas pass", timed(both, q, k, v, g, beta,
                                                      reps=5), nbytes)
+        if GIGA:
+            # the two products of a chunk that read q and k alone, at the
+            # 64 repeated heads the ops run and at the 32 key heads
+            def grams(q, k):
+                qc, kc = (x.reshape(1, T // gd.CHUNK, gd.CHUNK, -1, DK)
+                          for x in (q, k))
+                hi = jax.lax.Precision.HIGHEST
+                return (jnp.einsum("bnchk,bndhk->bnhcd", kc, kc,
+                                   precision=hi),
+                        jnp.einsum("bnchk,bndhk->bnhcd", qc, kc,
+                                   precision=hi))
+
+            gram = jax.jit(grams)
+            say(f"chunk {T}, k k^T and q k^T at {H} repeated heads",
+                timed(gram, q, k, reps=5), nbytes)
+            say(f"chunk {T}, k k^T and q k^T once a key head "
+                f"({KEY_HEADS})",
+                timed(gram, q[:, :, ::2], k[:, :, ::2], reps=5), nbytes)
+            continue
         N = T // gd.CHUNK
         lay = [jnp.moveaxis(x.reshape((1, N, gd.CHUNK) + x.shape[2:]), 3, 1)
                for x in (q, k, v, g, beta)]
@@ -148,9 +221,12 @@ def main() -> int:
             timed(jax.jit(gd.scan_chunks), terms, s0, reps=5), nbytes)
         say(f"chunk {T}, the terms alone (XLA)",
             timed(jax.jit(gd.chunk_terms), *lay, reps=5), nbytes)
+    if GIGA:
+        latent_decode(say, loops)
     os.makedirs("chiprun_out", exist_ok=True)
     with open("chiprun_out/gated_delta_microbench"
-              + ("_channel" if CHANNEL else "") + ".json", "w") as f:
+              + ("_channel" if CHANNEL else "_giga" if GIGA else "")
+              + ".json", "w") as f:
         json.dump(rows, f, indent=1)
     return 0
 
