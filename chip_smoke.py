@@ -851,7 +851,7 @@ DELTA = dict(heads=30, key_dim=96, value_dim=192, conv=4, slots=32,
 def _delta_kernels_against_xla(tag, seed, H, Dk, Dv, T, n_valid, n,
                                channel):
     """The two Pallas kernels of ``ops/pallas/gated_delta.py`` against the
-    XLA formulations of ``ops/gated_delta_ops.py``: the chunk pass over a
+    XLA formulations of ``ops/gated_delta_ops.py``: the whole scan over a
     padded prompt (``n_valid`` of ``T`` rows real) and the step over ``n``
     slots of which some are dead (their state and the trash row bit for
     bit what they were).  ``channel``: the log decay a vector a key
@@ -877,8 +877,7 @@ def _delta_kernels_against_xla(tag, seed, H, Dk, Dv, T, n_valid, n,
     valid = jnp.asarray([n_valid], jnp.int32)
     want_o, want_s = jax.jit(lambda *a: gd.chunked(*a, valid=valid))(
         q, k, v, g, beta)
-    got_o, got_s = jax.jit(lambda *a: gd.chunked(
-        *a, valid=valid, carry=kern.carry_chunks))(q, k, v, g, beta)
+    got_o, got_s = kern.chunk(q, k, v, g, beta, valid=valid)
     rel = max(float(jnp.abs(got_o - want_o).max() / jnp.abs(want_o).max()),
               float(jnp.abs(got_s - want_s).max() / jnp.abs(want_s).max()))
     check(bool(jnp.isfinite(got_o).all()) and rel <= TOL,
@@ -902,8 +901,8 @@ def _delta_kernels_against_xla(tag, seed, H, Dk, Dv, T, n_valid, n,
           and bool(jnp.array_equal(got_s[n], state[n])),
           "gated_delta_step moved a dead slot's state or the trash row")
     say(f"{tag}: kernels at {H} heads of {Dk} x {Dv}"
-        f"{', a decay a key channel' if channel else ''}: the chunk pass "
-        f"over {n_valid} of {T} rows within {rel:.4g} of the scan, the step "
+        f"{', a decay a key channel' if channel else ''}: the whole scan "
+        f"over {n_valid} of {T} rows within {rel:.4g} of the XLA form, the step "
         f"over {int(on.sum())} live of {n} slots within {rel_step:.4g} of "
         f"the contractions, dead slots untouched (tolerance {TOL})")
 
@@ -912,7 +911,7 @@ def delta_state_phase(cfg=DELTA):
     """What a decoder with gated delta-rule layers adds (PR 41), at that
     family's published head sizes (30 heads, keys of 96, values of 192):
     the two Pallas kernels of ``ops/pallas/gated_delta.py`` against the
-    XLA formulations of ``ops/gated_delta_ops.py`` (the chunk pass over a
+    XLA formulations of ``ops/gated_delta_ops.py`` (the whole scan over a
     padded prompt, the step over 32 slots of which some are dead: their
     state and the trash row bit for bit what they were); and one
     delta-state round trip through a two-slot ``GenerationEngine`` of one
@@ -987,7 +986,7 @@ def share_and_channel_phase(cfg=SHARE):
     """What one chip's share of an expert-parallel group with KDA layers
     adds (PR 43), at that family's published sizes: the two delta-rule
     kernels with a log decay a key channel (64 heads of 128 x 128: the
-    chunk pass over a padded prompt with blocks of 16 inside a chunk, the
+    whole scan over a padded prompt with blocks of 16 inside a chunk, the
     step over 64 slots of which some are dead) against their XLA
     formulations; and the held experts' part of a layer (20 of a 320-wide
     router's experts of width 1280 on hidden 4096, 8 a token, sigmoid

@@ -18,10 +18,16 @@ by G's rank.  Two ops, float32 throughout, every product at "highest":
   and whatever they hold (a NaN too) reaches nothing.  The recurrence is
   rearranged exactly into chunks of ``CHUNK`` tokens: inside a chunk a
   unit-triangular system gives every token's correction from the chunk's
-  first state (``chunk_terms``: batched matmuls over all chunks at once,
-  the system solved row by row), and the state is carried from chunk to
-  chunk (``scan_chunks`` under ``lax.scan``, or on a TPU the Pallas kernel
-  ``gated_delta_chunk``, which keeps it in VMEM across a head's chunks).
+  first state, and the state is carried from chunk to chunk.  On a TPU
+  all of that is ONE Pallas kernel, ``gated_delta_chunk``
+  (``pallas/gated_delta.py``: a chunk's terms are made in VMEM and
+  applied there, the state in VMEM across a head's chunks; q, k, v, the
+  decay, beta, the outputs and the last state are all that touches HBM,
+  after ``lay``'s copies where a head is not whole lane tiles).  This
+  module keeps the same mathematics in XLA, ``chunked``: ``chunk_terms``
+  (batched matmuls over all chunks at once, the system solved row by
+  row) and ``scan_chunks`` under ``lax.scan``: what the kernel is held
+  to, and the path off a TPU and under a mesh (``_kernel_route``).
 * ``gated_delta_step``: the decode step, one row a slot over State
   [slots + 1, H, Dk, Dv] (row ``slots`` is the trash row a warm-up's
   prefill writes): the state of the rows ``Live`` marks moves on in
@@ -47,7 +53,8 @@ token ``T0`` of t's block, ``exp(cum_t - cum_T0) exp(cum_T0 - cum_i)``,
 two safe scalings around one product; within a block the 16 x 16 x Dk
 differences are taken outright.  From there on the chunk's terms, the
 unit-triangular solve and the carried pass are the scalar form's, the
-state scaled by ``exp(cum_last)`` a row where that was one number.
+state scaled by ``exp(cum_last)`` a row where that was one number.  The
+kernel takes the same blocks the same way (its ``_decayed_products``).
 """
 from __future__ import annotations
 
@@ -60,7 +67,6 @@ logger = logging.getLogger(__name__)
 
 CHUNK = 64
 BLOCK = 16        # a chunk's sub-blocks under a decay a channel
-HEAD_GROUP = 8    # ... whose terms are made for this many heads a turn
 
 _LOWERED = {
     "pallas": _monitor.get("gated_delta_lowered_pallas"),
@@ -224,69 +230,37 @@ def scan_chunks(terms, s0):
     return jnp.moveaxis(o, 0, 2), s
 
 
-def _by_head_groups(fn, *xs):
-    """``fn`` over [B, H, ...] operands ``HEAD_GROUP`` heads a turn
-    (``lax.map``), so that what a chunk's tokens need of each other is
-    held for a group's heads and not for all of them: at 64 heads of 128
-    over 4096 tokens the terms of a decay a channel are 3.3 GB at once
-    and 0.4 GB a group (compiled for a v5e, PR 43)."""
-    import jax
+def lay(x, valid, chunk=CHUNK):
+    """[B, T, H, ...] -> [B, H, N, C, ...] in whole chunks, the rows that
+    are not real (behind ``valid`` [B], or the padding to a whole chunk)
+    zero: decay 1, correction 0, nothing read."""
     import jax.numpy as jnp
 
-    H = xs[0].shape[1]
-    if H <= HEAD_GROUP or H % HEAD_GROUP:
-        return fn(*xs)
-
-    def split(x):
-        x = x.reshape((x.shape[0], H // HEAD_GROUP, HEAD_GROUP)
-                      + x.shape[2:])
-        return jnp.moveaxis(x, 1, 0)
-
-    def join(x):
-        x = jnp.moveaxis(x, 0, 1)
-        return x.reshape((x.shape[0], H) + x.shape[3:])
-
-    return jax.tree_util.tree_map(
-        join, jax.lax.map(lambda a: fn(*a), tuple(split(x) for x in xs)))
+    B, T = x.shape[:2]
+    if valid is not None:
+        real = jnp.arange(T)[None, :] < valid.astype(jnp.int32)[:, None]
+        x = jnp.where(real.reshape(real.shape + (1,) * (x.ndim - 2)), x, 0.0)
+    pad = -T % chunk
+    if pad:
+        x = jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
+    x = x.reshape((B, (T + pad) // chunk, chunk) + x.shape[2:])
+    return jnp.moveaxis(x, 3, 1)
 
 
-def chunked(q, k, v, g, beta, s0=None, valid=None, chunk=CHUNK,
-            carry=scan_chunks):
-    """The whole-sequence form on [B, T, H, ...] operands: mask the rows
-    behind ``valid``, pad to whole chunks, ``chunk_terms``, then ``carry``
-    (``scan_chunks`` or the Pallas kernel).  Returns (out [B, T, H, Dv],
-    state [B, H, Dk, Dv])."""
+def chunked(q, k, v, g, beta, s0=None, valid=None, chunk=CHUNK):
+    """The whole-sequence form on [B, T, H, ...] operands, in XLA: ``lay``,
+    ``chunk_terms`` (``chunk_terms_channel`` under a decay a channel), then
+    ``scan_chunks``.  Returns (out [B, T, H, Dv], state [B, H, Dk, Dv])."""
     import jax.numpy as jnp
 
     B, T, H, Dk = q.shape
     Dv = v.shape[-1]
-    N = -(-T // chunk)
-    pad = N * chunk - T
-    real = None
-    if valid is not None:
-        real = jnp.arange(T)[None, :] < valid.astype(jnp.int32)[:, None]
-
-    def lay(x):
-        """[B, T, H, ...] -> [B, H, N, C, ...], rows that are not real
-        zero: decay 1, correction 0, nothing read."""
-        if real is not None:
-            x = jnp.where(real.reshape(real.shape + (1,) * (x.ndim - 2)),
-                          x, 0.0)
-        if pad:
-            x = jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
-        x = x.reshape((B, N, chunk) + x.shape[2:])
-        return jnp.moveaxis(x, 3, 1)
-
     if s0 is None:
         s0 = jnp.zeros((B, H, Dk, Dv), q.dtype)
-    if g.ndim == 3:
-        terms = chunk_terms(lay(q), lay(k), lay(v), lay(g), lay(beta))
-        o, s = carry(terms, s0)
-    else:
-        o, s = _by_head_groups(
-            lambda *x: carry(chunk_terms_channel(*x[:5]), x[5]),
-            lay(q), lay(k), lay(v), lay(g), lay(beta), s0)
-    o = jnp.moveaxis(o, 1, 3).reshape(B, N * chunk, H, Dv)
+    terms = chunk_terms if g.ndim == 3 else chunk_terms_channel
+    o, s = scan_chunks(terms(*(lay(x, valid, chunk)
+                               for x in (q, k, v, g, beta))), s0)
+    o = jnp.moveaxis(o, 1, 3).reshape(B, -1, H, Dv)
     return o[:, :T], s
 
 
@@ -344,9 +318,9 @@ def _gated_delta_chunk(ctx, op):
         kernel, why = False, (f"gated_delta_chunk with Q {q.shape}, V "
                               f"{v.shape} (kernel needs whole sublane "
                               f"tiles)")
-    carry = gated_delta.carry_chunks if kernel else scan_chunks
-    out, state = chunked(*(x.astype(jnp.float32) for x in (q, k, v, g, beta)),
-                         s0=s0, valid=valid, carry=carry)
+    out, state = (gated_delta.chunk if kernel else chunked)(
+        *(x.astype(jnp.float32) for x in (q, k, v, g, beta)),
+        s0=s0, valid=valid)
     _lowered("pallas" if kernel else "reference", why, g.ndim == 4)
     ctx.set_output(op, "Out", out.astype(v.dtype))
     ctx.set_output(op, "StateOut", state)

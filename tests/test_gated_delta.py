@@ -294,24 +294,99 @@ def test_step_moves_live_rows_only_in_place_and_prefill_takes_the_trash_row():
 # kernels (interpret mode) against the XLA formulations
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("T,Dk,Dv", [(192, 8, 16), (128, 96, 192)])
+def _fused(*ops, **kw):
+    """The fused scan kernel in interpret mode, on numpy operands."""
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops.pallas import gated_delta as kern
+
+    out, state = kern.chunk(
+        *(jnp.asarray(x) for x in ops), interpret=True,
+        **{n: jnp.asarray(x) for n, x in kw.items()})
+    return np.asarray(out), np.asarray(state)
+
+
+@pytest.mark.parametrize("T,Dk,Dv", [(192, 8, 16), (128, 96, 192),
+                                     (256, 128, 128)])
 def test_chunk_kernel_is_the_scan(T, Dk, Dv):
+    """The whole scan as one kernel, from a state and with a prompt that
+    ends inside a chunk: what ``chunked`` makes of it in XLA, and what the
+    recurrence makes of it token by token."""
     import jax.numpy as jnp
 
     from paddle_tpu.ops import gated_delta_ops as gd
     from paddle_tpu.ops.pallas import gated_delta as kern
 
-    ops = [jnp.asarray(x) for x in _operands(T, 2, T, H=2, Dk=Dk, Dv=Dv)]
-    s0 = jnp.asarray(np.random.default_rng(2).normal(size=(2, 2, Dk, Dv)),
-                     jnp.float32)
-    valid = jnp.asarray([T, T - 70], jnp.int32)
+    ops = _operands(T, 2, T, H=2, Dk=Dk, Dv=Dv)
+    s0 = np.random.default_rng(2).normal(size=(2, 2, Dk, Dv)) \
+        .astype("float32")
+    valid = np.asarray([T, T - 70], "int32")
     assert kern.chunk_supported(ops[0].shape, gd.CHUNK)
-    want_o, want_s = gd.chunked(*ops, s0=s0, valid=valid)
-    got_o, got_s = gd.chunked(
-        *ops, s0=s0, valid=valid,
-        carry=lambda t, s: kern.carry_chunks(t, s, interpret=True))
-    np.testing.assert_allclose(got_o, want_o, rtol=0, atol=2e-6)
-    np.testing.assert_allclose(got_s, want_s, rtol=0, atol=2e-6)
+    want_o, want_s = gd.chunked(*(jnp.asarray(x) for x in ops),
+                                s0=jnp.asarray(s0), valid=jnp.asarray(valid))
+    got_o, got_s = _fused(*ops, s0=s0, valid=valid)
+    np.testing.assert_allclose(got_o, want_o, rtol=0, atol=1e-5)
+    np.testing.assert_allclose(got_s, want_s, rtol=0, atol=1e-5)
+    true_o, true_s = _recurrence(*ops, s0=s0, valid=valid)
+    np.testing.assert_allclose(got_o, true_o, rtol=0, atol=2e-5)
+    np.testing.assert_allclose(got_s, true_s, rtol=0, atol=2e-5)
+
+
+@pytest.mark.parametrize("heads_block", [1, 2, 3])
+def test_chunk_kernel_takes_as_many_heads_a_block_as_divide_them(
+        heads_block):
+    """Six heads 1, 2 and 3 a grid step: the same numbers."""
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops.pallas import gated_delta as kern
+
+    ops = [jnp.asarray(x) for x in _operands(5, 1, 130, H=6)]
+    want_o, want_s = kern.chunk(*ops, interpret=True, heads_block=6)
+    got_o, got_s = kern.chunk(*ops, interpret=True, heads_block=heads_block)
+    np.testing.assert_allclose(got_o, want_o, rtol=0, atol=1e-6)
+    np.testing.assert_allclose(got_s, want_s, rtol=0, atol=1e-6)
+
+
+@pytest.mark.parametrize("n", [1, 63, 64, 70, 129])
+def test_chunk_kernel_reads_nothing_behind_valid(n):
+    """``n`` real rows of 192, NaN behind them in every operand: the real
+    rows and the state are the ``n`` tokens' alone; a chunk wholly behind
+    ``valid`` leaves zeros and hands the state through."""
+    T = 192
+    ops = _operands(n, 1, T)
+    padded = [x.copy() for x in ops]
+    for x in padded:
+        x[:, n:] = np.nan
+    s0 = np.random.default_rng(n).normal(size=(1, 3, 8, 12)) \
+        .astype("float32")
+    out, state = _fused(*padded, s0=s0, valid=np.asarray([n], "int32"))
+    want_o, want_s = _recurrence(*[x[:, :n] for x in ops], s0=s0)
+    assert np.isfinite(out).all() and np.isfinite(state).all()
+    np.testing.assert_allclose(out[:, :n], want_o, rtol=0, atol=2e-5)
+    np.testing.assert_allclose(state, want_s, rtol=0, atol=2e-5)
+    assert not out[:, n:].any()
+
+
+def test_chunk_kernel_two_calls_that_carry_the_state_are_one_call():
+    T, cut = 200, 77
+    ops = _operands(8, 1, T)
+    whole_o, whole_s = _fused(*ops)
+    o1, s1 = _fused(*[x[:, :cut] for x in ops])
+    o2, s2 = _fused(*[x[:, cut:] for x in ops], s0=s1)
+    np.testing.assert_allclose(np.concatenate([o1, o2], 1), whole_o,
+                               rtol=0, atol=2e-5)
+    np.testing.assert_allclose(s2, whole_s, rtol=0, atol=2e-5)
+
+
+def test_chunk_kernel_under_strong_decay_neither_overflows_nor_drifts():
+    """``test_strong_decay_inside_a_chunk_neither_overflows_nor_drifts``
+    through the kernel."""
+    ops = _operands(3, 1, 128, decay=12.0)
+    out, state = _fused(*ops)
+    want_o, want_s = _recurrence(*ops)
+    assert np.isfinite(out).all() and np.isfinite(state).all()
+    np.testing.assert_allclose(out, want_o, rtol=0, atol=2e-5)
+    np.testing.assert_allclose(state, want_s, rtol=0, atol=2e-5)
 
 
 @pytest.mark.parametrize("Dk,Dv", [(8, 16), (96, 192)])

@@ -546,13 +546,12 @@ def test_bert_step_for_a_described_v5e_mesh_keeps_the_pallas_kernels(
 def test_gated_delta_kernels_compile_for_a_described_v5e(chip):
     """The delta rule's two kernels at the published head sizes (30 heads,
     keys of 96, values of 192): the step over 32 slots with its state
-    aliased in place, the chunk pass over a 6144 rung.  Both hold one
+    aliased in place, the whole scan over a 6144 rung.  Both hold one
     Mosaic call; the step's state is no temporary."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import SingleDeviceSharding
 
-    from paddle_tpu.ops import gated_delta_ops as gd
     from paddle_tpu.ops.pallas import gated_delta as kern
 
     one_chip = SingleDeviceSharding(chip)
@@ -570,11 +569,15 @@ def test_gated_delta_kernels_compile_for_a_described_v5e(chip):
     assert step.memory_analysis().temp_size_in_bytes < state_bytes // 4
 
     def prefill(q, k, v, g, beta, valid):
-        return gd.chunked(q, k, v, g, beta, valid=valid,
-                          carry=kern.carry_chunks)
+        return kern.chunk(q, k, v, g, beta, valid=valid)
 
-    chunk = jax.jit(prefill).lower(
-        sds((1, T, H, Dk)), sds((1, T, H, Dk)), sds((1, T, H, Dv)),
-        sds((1, T, H)), sds((1, T, H)), sds((1,), jnp.int32)).compile()
-    assert chunk.as_text().count("tpu_custom_call") == 1
-    assert chunk.memory_analysis().temp_size_in_bytes < 1.5e9
+    # ... and the scan at the three shapes the benchmark's cells run: a
+    # number a head (30 heads 96 x 192; 64 of 128 x 128) and a channel
+    for H, Dk, Dv, T, gdim in ((H, Dk, Dv, T, ()), (64, 128, 128, 2048, ()),
+                               (64, 128, 128, 4096, (128,))):
+        chunk = jax.jit(prefill).lower(
+            sds((1, T, H, Dk)), sds((1, T, H, Dk)), sds((1, T, H, Dv)),
+            sds((1, T, H) + gdim), sds((1, T, H)),
+            sds((1,), jnp.int32)).compile()
+        assert chunk.as_text().count("tpu_custom_call") == 1
+        assert chunk.memory_analysis().temp_size_in_bytes < 1.0e9

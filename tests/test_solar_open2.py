@@ -394,25 +394,96 @@ def test_step_op_with_a_decay_a_channel_moves_live_rows_only():
     np.testing.assert_array_equal(state[n], state0[n])
 
 
+def _fused(*ops, **kw):
+    """The fused scan kernel in interpret mode, on numpy operands."""
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops.pallas import gated_delta as P
+
+    out, state = P.chunk(*(jnp.asarray(x) for x in ops), interpret=True,
+                         **{n: jnp.asarray(x) for n, x in kw.items()})
+    return np.asarray(out), np.asarray(state)
+
+
 @pytest.mark.parametrize("T,Dk,Dv", [(192, 16, 16), (128, 128, 128)])
 def test_chunk_kernel_scales_the_states_rows(T, Dk, Dv):
-    """The carried pass as the Pallas kernel (interpret mode): the chunk's
-    decay is a row of a sublane tile, turned into the column that scales
-    the state's rows."""
-    import functools
-
+    """The scan as the Pallas kernel (interpret mode): the chunk's decay
+    is a row of a sublane tile, turned into the column that scales the
+    state's rows."""
     import jax.numpy as jnp
 
     from paddle_tpu.ops import gated_delta_ops as G
     from paddle_tpu.ops.pallas import gated_delta as P
 
-    ops = [jnp.asarray(x) for x in _operands(T, 1, T, 2, Dk, Dv)]
+    ops = _operands(T, 1, T, 2, Dk, Dv)
     assert P.chunk_supported(ops[0].shape, G.CHUNK)
-    want, want_s = G.chunked(*ops)
-    out, state = G.chunked(*ops, carry=functools.partial(P.carry_chunks,
-                                                         interpret=True))
-    assert np.abs(np.asarray(out) - np.asarray(want)).max() < 1e-5
-    assert np.abs(np.asarray(state) - np.asarray(want_s)).max() < 1e-5
+    want, want_s = G.chunked(*(jnp.asarray(x) for x in ops))
+    out, state = _fused(*ops)
+    assert np.abs(out - np.asarray(want)).max() < 1e-5
+    assert np.abs(state - np.asarray(want_s)).max() < 1e-5
+
+
+@pytest.mark.parametrize("T,Dk,Dv", [(192, 8, 16), (128, 96, 192),
+                                     (256, 128, 128)])
+def test_chunk_kernel_with_a_decay_a_channel_is_the_scan(T, Dk, Dv):
+    """From a state, a prompt that ends inside a chunk: against the XLA
+    form and against the recurrence."""
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops import gated_delta_ops as G
+
+    ops = _operands(T, 2, T, 2, Dk, Dv)
+    s0 = np.random.default_rng(2).normal(size=(2, 2, Dk, Dv)) \
+        .astype("float32")
+    valid = np.asarray([T, T - 70], "int32")
+    want, want_s = G.chunked(*(jnp.asarray(x) for x in ops),
+                             s0=jnp.asarray(s0), valid=jnp.asarray(valid))
+    out, state = _fused(*ops, s0=s0, valid=valid)
+    assert np.abs(out - np.asarray(want)).max() < 1e-5
+    assert np.abs(state - np.asarray(want_s)).max() < 1e-5
+    true, true_s = _recurrence(*ops, s0=s0, valid=valid)
+    assert np.abs(out - true).max() < 2e-5
+    assert np.abs(state - true_s).max() < 2e-5
+
+
+@pytest.mark.parametrize("n", [1, 63, 64, 70, 129])
+def test_chunk_kernel_with_a_decay_a_channel_reads_nothing_behind_valid(n):
+    B, T, H, Dk, Dv = 1, 192, 2, 16, 8
+    ops = _operands(n, B, T, H, Dk, Dv)
+    padded = [x.copy() for x in ops]
+    for x in padded:
+        x[:, n:] = np.nan
+    s0 = np.random.default_rng(n).normal(size=(B, H, Dk, Dv)) \
+        .astype("float32")
+    out, state = _fused(*padded, s0=s0, valid=np.array([n], "int32"))
+    want, want_s = _recurrence(*(x[:, :n] for x in ops), s0=s0)
+    assert np.isfinite(out).all() and np.isfinite(state).all()
+    assert np.abs(out[:, :n] - want).max() < 2e-5
+    assert np.abs(state - want_s).max() < 2e-5
+    assert not out[:, n:].any()
+
+
+def test_chunk_kernel_with_a_decay_a_channel_carries_the_state():
+    T, cut = 200, 77
+    ops = _operands(8, 1, T, 2, 16, 8)
+    whole, whole_s = _fused(*ops)
+    o1, s1 = _fused(*[x[:, :cut] for x in ops])
+    o2, s2 = _fused(*[x[:, cut:] for x in ops], s0=s1)
+    assert np.abs(np.concatenate([o1, o2], 1) - whole).max() < 2e-5
+    assert np.abs(s2 - whole_s).max() < 2e-5
+
+
+def test_chunk_kernel_under_strong_decay_a_channel():
+    """``test_strong_decay_a_channel_neither_overflows_nor_drifts``
+    through the kernel: every exponent it takes is non-positive."""
+    B, T, H, Dk, Dv = 1, 192, 2, 16, 8
+    q, k, v, g, beta = _operands(4, B, T, H, Dk, Dv, decay=6.0, floor=-8.0)
+    g[..., ::2] *= 1e-3
+    out, state = _fused(q, k, v, g, beta)
+    want, want_s = _recurrence(q, k, v, g, beta)
+    assert np.isfinite(out).all() and np.isfinite(state).all()
+    assert np.abs(out - want).max() < 2e-5 * max(1.0, np.abs(want).max())
+    assert np.abs(state - want_s).max() < 2e-5 * np.abs(want_s).max()
 
 
 @pytest.mark.parametrize("H,Dk,Dv", [(3, 16, 16), (16, 128, 128)])

@@ -5,12 +5,12 @@
 
 With ``channel``: the decay a vector a key channel (Kimi Delta Attention)
 at three heads of 128 x 128, slow, middle and fast channels inside every
-head: the chunked op (blocks of 16 inside a chunk), the Pallas pass, both
+head: the chunked op (blocks of 16 inside a chunk), the fused Pallas scan, both
 steps and the token-by-token float32 recurrence with the decay as
 ``exp(g)`` and as ``1 + expm1(g)``, each against float64 (PERF.md section
 6, PR 43); the per-term readings are the scalar form's alone.
 
-Each term of ``chunk_terms``, the scan, the Pallas pass, the step kernel
+Each term of ``chunk_terms``, the scan, the fused Pallas scan, the step kernel
 and a token-by-token float32 recurrence (what the benchmark's plain
 reference runs), each against float64 numpy on the host, at 1024 tokens
 and three heads of 96 x 192 with slow, middle and fast decay; then the
@@ -63,7 +63,7 @@ if not CHANNEL:
     tt=jax.jit(G._unit_lower_inverse)(f32(a)); print("term t (inverse)", rel(tt,t64))
     print("cumsum", rel(jnp.cumsum(f32(gl),-1),cum))
 o,s=jax.jit(lambda *x: G.chunked(*x))(*map(f32,(q,k,v,g,beta))); print("chunked xla: o",rel(o[0],O),"s",rel(s[0],S))
-o,s=jax.jit(lambda *x: G.chunked(*x,carry=K.carry_chunks))(*map(f32,(q,k,v,g,beta))); print("chunked pallas: o",rel(o[0],O),"s",rel(s[0],S))
+o,s=K.chunk(*map(f32,(q,k,v,g,beta))); print("chunked pallas (the fused kernel): o",rel(o[0],O),"s",rel(s[0],S))
 if not CHANNEL:
     # scan with float64-exact terms rounded to f32: isolates the carry
     o2,s2=jax.jit(G.scan_chunks)(tuple(f32(truth[n]) for n in ("qg","w","u0","p","kd","gc")), jnp.zeros((B,H,Dk,Dv),jnp.float32))

@@ -6,8 +6,7 @@ chip.
 
 With ``channel``: the decay a vector a key channel at 64 heads of 128 x
 128 over 64 slots (``solar-open2-250b``), the step at 1, 4, 8, 16 and 32
-heads a block, the scan over rungs of 1024 and 4096 (its terms made eight
-heads a turn, as the op makes them); written to
+heads a block, the scan over rungs of 1024 and 4096; written to
 ``chiprun_out/gated_delta_microbench_channel.json``.
 
 With ``giga``: a state of [64, 128, 128] a slot whose 64 value heads
@@ -27,9 +26,13 @@ inside one jitted loop that carries the state (one call of a kernel this
 short is mostly the host's dispatch), as the XLA formulation (three
 contractions) and as the Pallas kernel at 1, 10 and 30 heads a block (a
 loop over one 62 MB state reads above the HBM peak on a v5e: read the
-lines against each other, not against 819 GB/s); and the prefill's scan over a rung of 2048 and of 6144
-rows, as ``chunk_terms`` + ``lax.scan`` and as ``chunk_terms`` + the
-Pallas chunk pass, with the pass alone beside them.  Prints one line per
+lines against each other, not against 819 GB/s); and the prefill's scan
+over a rung of 2048 and of 6144 rows, as ``chunk_terms`` + ``lax.scan``
+(the XLA form that stays) and as the fused Pallas kernel at 1, 2, 4 and
+its own choice of heads a grid step (with how far it lies off the XLA
+form) and with half the rung behind ``valid``, and the XLA form's parts
+beside them: the five operands laid out by chunks, the terms alone, the
+``lax.scan`` alone.  Prints one line per
 formulation: milliseconds, and the share of 819 GB/s that the bytes the
 mathematics must move make of it (the state read once and written once;
 q, k, v, decay, beta read and the outputs written).  Writes
@@ -53,6 +56,7 @@ H, DK, DV, SLOTS = (64, 128, 128, 64) if CHANNEL else \
 HEADS_BLOCKS = (1, 4, 8, 16, 32) if CHANNEL else \
     (8, 16, 32) if GIGA else (1, 10, 30)
 RUNGS = (1024, 4096) if CHANNEL else (1024, 2048) if GIGA else (2048, 6144)
+CHUNK_HEADS = (None, 1, 2, 8)       # the fused scan's heads a grid step
 KEY_HEADS = 32                      # under ``giga``: two value heads each
 HBM = 819e9
 
@@ -176,12 +180,27 @@ def main() -> int:
         nbytes = 4 * (H * (2 * DK + 2 * DV + 1 + (DK if CHANNEL else 1)) * T
                       + H * DK * DV)
         scan = jax.jit(lambda *a: gd.chunked(*a, valid=valid))
-        both = jax.jit(lambda *a: gd.chunked(*a, valid=valid,
-                                             carry=kern.carry_chunks))
-        say(f"chunk {T}, terms + lax.scan", timed(scan, q, k, v, g, beta,
-                                                  reps=5), nbytes)
-        say(f"chunk {T}, terms + Pallas pass", timed(both, q, k, v, g, beta,
-                                                     reps=5), nbytes)
+        say(f"chunk {T}, terms + lax.scan (XLA)", timed(scan, q, k, v, g,
+                                                        beta, reps=5), nbytes)
+        want = scan(q, k, v, g, beta)
+        for hb in CHUNK_HEADS:
+            what = f"chunk {T}, the fused kernel, {hb or 'default'} heads " \
+                   f"a block"
+            try:
+                fused = jax.jit(lambda *a, hb=hb: kern.chunk(
+                    *a, valid=valid, heads_block=hb))
+                got = fused(q, k, v, g, beta)
+                off = max(float(jnp.abs(a - b).max() / jnp.abs(b).max())
+                          for a, b in zip(got, want))
+                say(what, timed(fused, q, k, v, g, beta, reps=5), nbytes)
+                print(f"  off the XLA form by {off:.3g} of the range",
+                      flush=True)
+            except Exception as e:  # noqa: BLE001 — a block the chip refuses
+                print(f"{what}: refused: {str(e)[:300]}", flush=True)
+        short = jnp.asarray([T // 2 + 1], jnp.int32)
+        say(f"chunk {T}, the fused kernel, {T // 2 + 1} rows real",
+            timed(jax.jit(lambda *a: kern.chunk(*a, valid=short)), q, k, v,
+                  g, beta, reps=5), nbytes)
         if GIGA:
             # the two products of a chunk that read q and k alone, at the
             # 64 repeated heads the ops run and at the 32 key heads
@@ -201,26 +220,18 @@ def main() -> int:
                 f"({KEY_HEADS})",
                 timed(gram, q[:, :, ::2], k[:, :, ::2], reps=5), nbytes)
             continue
-        N = T // gd.CHUNK
-        lay = [jnp.moveaxis(x.reshape((1, N, gd.CHUNK) + x.shape[2:]), 3, 1)
-               for x in (q, k, v, g, beta)]
-        s0 = jnp.zeros((1, H, DK, DV), jnp.float32)
-        if CHANNEL:
-            # all 64 heads' terms at once are 3.3 GB at 4096: the op makes
-            # them HEAD_GROUP heads a turn, and so does this line
-            grouped = jax.jit(lambda *a: gd._by_head_groups(
-                lambda *x: gd.chunk_terms_channel(*x), *a))
-            say(f"chunk {T}, the terms alone (XLA, "
-                f"{gd.HEAD_GROUP} heads a turn)",
-                timed(grouped, *lay, reps=5), nbytes)
-            continue
-        terms = jax.jit(gd.chunk_terms)(*lay)
-        say(f"chunk {T}, the Pallas pass alone",
-            timed(kern.carry_chunks, terms, s0, reps=5), nbytes)
-        say(f"chunk {T}, lax.scan alone",
-            timed(jax.jit(gd.scan_chunks), terms, s0, reps=5), nbytes)
+        lay = [gd.lay(x, None) for x in (q, k, v, g, beta)]
+        say(f"chunk {T}, the five operands laid out by chunks (XLA)",
+            timed(jax.jit(lambda *a: [gd.lay(x, valid) for x in a]),
+                  q, k, v, g, beta, reps=5), nbytes)
+        terms_of = gd.chunk_terms_channel if CHANNEL else gd.chunk_terms
         say(f"chunk {T}, the terms alone (XLA)",
-            timed(jax.jit(gd.chunk_terms), *lay, reps=5), nbytes)
+            timed(jax.jit(terms_of), *lay, reps=5), nbytes)
+        if not CHANNEL:
+            s0 = jnp.zeros((1, H, DK, DV), jnp.float32)
+            terms = jax.jit(terms_of)(*lay)
+            say(f"chunk {T}, lax.scan alone",
+                timed(jax.jit(gd.scan_chunks), terms, s0, reps=5), nbytes)
     if GIGA:
         latent_decode(say, loops)
     os.makedirs("chiprun_out", exist_ok=True)
