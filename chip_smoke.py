@@ -686,7 +686,8 @@ def serve_phase(cfg=SERVE, on_chip=True):
 
 SPARSE = dict(heads=28, kv_heads=4, head_dim=128, window=4096,
               page_tokens=16, contexts=(100, 4096, 4097, 8000),
-              hidden=2560, experts=64, top_k=6, expert_width=768, slots=32)
+              hidden=2560, experts=64, top_k=6, expert_width=768, slots=32,
+              prefill_rung=512)
 
 
 def sparse_window_phase(cfg=SPARSE):
@@ -694,11 +695,15 @@ def sparse_window_phase(cfg=SPARSE):
     step, at that family's published shapes (28 query over 4 KV heads of
     128, window 4096; 64 experts, top 6, width 768 on hidden 2560): the
     windowed paged-decode kernel against the gather + einsum formulation,
-    and one routed expert step against a loop over all experts, both at
-    "highest" precision."""
+    and the routed expert layer against a loop over all experts, both at
+    "highest" precision: the layer at a decode step's 32 tokens and a
+    prefill rung's 512 (both products on the Pallas grouped matmul:
+    ``grouped_matmul_lowered_pallas`` +2 each, PR 50) and at the check
+    engine's 2 (``grouped_matmul_lowered_ragged_dot`` +2)."""
     import jax
     import jax.numpy as jnp
 
+    from paddle_tpu.monitor import stat_get
     from paddle_tpu.ops.decode_ops import _attend_cache, _gather_pages
     from paddle_tpu.ops.pallas.paged_attention import \
         paged_decode_attention
@@ -731,29 +736,43 @@ def sparse_window_phase(cfg=SPARSE):
     H, E, I, k = (cfg[n] for n in ("hidden", "experts", "expert_width",
                                    "top_k"))
     x, rx = (jax.random.normal(jax.random.fold_in(key, i),
-                               (cfg["slots"], H)) for i in (3, 4))
+                               (cfg["prefill_rung"], H)) for i in (3, 4))
     rw = jax.random.normal(jax.random.fold_in(key, 5), (H, E)) * 0.02
     gu = jax.random.normal(jax.random.fold_in(key, 6), (E, H, 2 * I)) * 0.02
     dn = jax.random.normal(jax.random.fold_in(key, 7), (E, I, H)) * 0.02
     hi = jax.lax.Precision.HIGHEST
-    got, counts, logits = jax.jit(
-        lambda *a: moe_routed_tokens(*a, top_k=k, precision=hi))(
-            x, rx, rw, gu, dn)
-    with jax.default_matmul_precision("highest"):
-        top = jax.lax.top_k(logits, k)[1]
-        chosen = jax.nn.one_hot(top, E, dtype=bool).any(1)
-        wts = jax.nn.softmax(jnp.where(chosen, logits, -jnp.inf), -1)
-        h = jnp.einsum("nh,ehf->enf", x, gu)
-        y = jnp.einsum("eni,eih->enh",
-                       jnp.maximum(h[..., :I], 0) * h[..., I:], dn)
-        want = jnp.einsum("ne,enh->nh", jnp.where(chosen, wts, 0.0), y)
-    rel = float(jnp.abs(got - want).max() / jnp.abs(want).max())
-    check(int(counts.sum()) == cfg["slots"] * k and rel <= TOL,
-          f"routed expert step off the loop over all experts by {rel:.4g}, "
-          f"{int(counts.sum())} pairs placed")
-    say(f"sparse: {cfg['slots']} tokens x top-{k} of {E} experts, "
-        f"{int((counts > 0).sum())} experts touched, nothing dropped, "
-        f"within {rel:.4g} of the loop over all experts")
+    lowered = ("grouped_matmul_lowered_pallas",
+               "grouped_matmul_lowered_ragged_dot")
+    # a decode step's rows, a prefill rung's, and the check engine's two
+    # slots (fewer rows than the kernel's row block: the one ragged_dot)
+    for tokens, kernels in ((cfg["slots"], 2), (cfg["prefill_rung"], 2),
+                            (2, 0)):
+        before = [stat_get(n) for n in lowered]
+        got, counts, logits = jax.jit(
+            lambda *a: moe_routed_tokens(*a, top_k=k, precision=hi))(
+                x[:tokens], rx[:tokens], rw, gu, dn)
+        grew = [stat_get(n) - b for n, b in zip(lowered, before)]
+        with jax.default_matmul_precision("highest"):
+            top = jax.lax.top_k(logits, k)[1]
+            chosen = jax.nn.one_hot(top, E, dtype=bool).any(1)
+            wts = jax.nn.softmax(jnp.where(chosen, logits, -jnp.inf), -1)
+            h = jnp.einsum("nh,ehf->enf", x[:tokens], gu)
+            y = jnp.einsum("eni,eih->enh",
+                           jnp.maximum(h[..., :I], 0) * h[..., I:], dn)
+            want = jnp.einsum("ne,enh->nh", jnp.where(chosen, wts, 0.0), y)
+        rel = float(jnp.abs(got - want).max() / jnp.abs(want).max())
+        check(int(counts.sum()) == tokens * k and rel <= TOL,
+              f"routed expert layer of {tokens} tokens off the loop over "
+              f"all experts by {rel:.4g}, {int(counts.sum())} pairs placed")
+        check(grew == [kernels, 2 - kernels],
+              f"routed expert layer of {tokens} tokens lowered "
+              f"{dict(zip(lowered, grew))}, expected {kernels} of its two "
+              f"products on the Pallas kernel")
+        say(f"sparse: {tokens} tokens x top-{k} of {E} experts, "
+            f"{int((counts > 0).sum())} experts touched, nothing dropped, "
+            f"within {rel:.4g} of the loop over all experts; "
+            f"grouped_matmul_lowered_pallas +{grew[0]}, _ragged_dot "
+            f"+{grew[1]}")
 
 
 HYBRID = dict(heads=32, kv_heads=8, head_dim=64, page_tokens=16,

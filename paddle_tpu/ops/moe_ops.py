@@ -95,7 +95,8 @@ def _moe_routed_ffn(ctx, op):
         norm_topk=bool(op.attr("norm_topk", True)),
         route_scale=float(op.attr("route_scale", 1.0)),
         held_first=op.attr("held_first", None),
-        limit=op.attr("limit", None))
+        limit=op.attr("limit", None),
+        mesh_devices=ctx.mesh.devices.size if ctx.mesh is not None else 1)
     ctx.set_output(op, "Out", out.reshape(shape))
     ctx.set_output(op, "ExpertCount", counts)
     if op.output("RouterLogits"):

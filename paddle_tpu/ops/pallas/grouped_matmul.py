@@ -1,0 +1,119 @@
+"""Pallas TPU grouped matmul: ``rows`` [M, K], sorted by group, times
+``weights`` [G, K, N], float32 at "highest" (``parallel/moe.py``
+``grouped_matmul`` routes to it by shape and has the XLA formulation it is
+held to).
+
+The grid is (column block, visit).  A visit is one (row block, group) pair
+that holds a row, in row order; the pairs come from the group offsets,
+handed over by scalar prefetch (the scheme of JAX's ``megablox.gmm``): a
+row block that straddles two groups is visited once a group and stores
+under a row mask, a group without rows is never visited, so its weights
+are never read, and the number of visits is the grid's own (dynamic)
+extent.  A block of weights is a group's whole ``[K, tn]``: consecutive
+visits of one group keep its block index, so the pipeline fetches it once
+a column block however many row blocks the group spans, and rows are read
+once a column block.  Rows past ``group_sizes.sum()`` are in no visit and
+are left as they lie.
+
+A visit multiplies a whole row block whatever part of it is the group's,
+so a block far over a group's rows is mostly wasted passes, and one far
+under them latches each 128 x 128 tile of the weights for too few rows.
+On a v5e ``ROW_BLOCK`` = 64 rows won at every shape measured, from 3 rows
+a group (a decode step: several groups share a block, a visit each) to
+768, and the widest column block won with it (:func:`tiles`; PERF.md
+section 6, PR 50, has the sweep: 32 rows lose up to 22%, 128 win nowhere
+and lose 3-46%, 256 and 512 lose everywhere).
+"""
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+PRECISION = jax.lax.Precision.HIGHEST
+LANES = 128                   # a column block is whole lane tiles, or all of N
+ROW_BLOCK = 64                # rows a block: eight float32 sublane tiles
+VMEM_LIMIT = 100 << 20        # of a v5e core's 128 MiB
+WEIGHT_BLOCK_BYTES = 26 << 20  # a group's [K, tn], held twice by the pipeline
+
+
+def tiles(m, k, n):
+    """``(tm, tn)``, rows and columns a block, for ``[m, k]`` rows over
+    groups of ``[k, n]``: ``ROW_BLOCK`` rows and the widest column block
+    whose weights fit ``WEIGHT_BLOCK_BYTES``; None for fewer rows than one
+    block, or where no whole-lane-tile divisor of ``n`` fits."""
+    if m < ROW_BLOCK:
+        return None
+    tn = next((t for t in (n, *range(n - n % LANES, 0, -LANES))
+               if n % t == 0 and k * t * 4 <= WEIGHT_BLOCK_BYTES), None)
+    return tn and (ROW_BLOCK, tn)
+
+
+def visits(group_sizes, m, tm):
+    """``(offsets [G + 1], group [V], row block [V], visits)``: the (row
+    block, group) pairs that hold a row, in row order, V = row blocks + G
+    - 1 the most there can be; entries past ``visits`` repeat the last."""
+    groups = group_sizes.shape[0]
+    blocks_m = -(-m // tm)
+    bound = blocks_m + groups - 1
+    sizes = group_sizes.astype(jnp.int32)
+    ends = jnp.cumsum(sizes)
+    starts = ends - sizes
+    first = starts // tm
+    spans = jnp.where(sizes > 0, (ends - 1) // tm - first + 1, 0)
+    before = jnp.cumsum(spans) - spans
+    group = jnp.repeat(jnp.arange(groups, dtype=jnp.int32), spans,
+                       total_repeat_length=bound)
+    block = first[group] + jnp.arange(bound, dtype=jnp.int32) - before[group]
+    offsets = jnp.concatenate([jnp.zeros(1, jnp.int32), ends])
+    return (offsets, group, jnp.clip(block, 0, blocks_m - 1),
+            spans.sum().astype(jnp.int32))
+
+
+def _kernel(offsets_ref, group_ref, block_ref, rows_ref, w_ref, out_ref,
+            *, tm):
+    v = pl.program_id(1)
+    g = group_ref[v]
+    lo, hi = offsets_ref[g], offsets_ref[g + 1]
+    acc = jax.lax.dot_general(
+        rows_ref[...], w_ref[...], (((1,), (0,)), ((), ())),
+        precision=PRECISION, preferred_element_type=jnp.float32)
+    row = block_ref[v] * tm + jax.lax.broadcasted_iota(
+        jnp.int32, acc.shape, 0)
+    out_ref[...] = jnp.where((row >= lo) & (row < hi), acc, out_ref[...])
+
+
+@functools.partial(jax.jit, static_argnames=("tm", "tn", "interpret"))
+def grouped_matmul(rows, weights, group_sizes, *, tm, tn, interpret=False):
+    """``rows`` [M, K] float32 sorted by group, ``weights`` [G, K, N]
+    float32, ``group_sizes`` [G] -> [M, N]; ``tm`` rows (whole sublane
+    tiles) and ``tn`` columns (whole lane tiles that divide N, or N) a
+    block: :func:`tiles`."""
+    m, k = rows.shape
+    groups, _, n = weights.shape
+    offsets, group, block, n_visits = visits(group_sizes, m, tm)
+    return pl.pallas_call(
+        functools.partial(_kernel, tm=tm),
+        out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(n // tn, n_visits),
+            in_specs=[
+                pl.BlockSpec((tm, k), lambda j, v, o, g, b: (b[v], 0)),
+                pl.BlockSpec((None, k, tn),
+                             lambda j, v, o, g, b: (g[v], 0, j)),
+            ],
+            out_specs=pl.BlockSpec((tm, tn), lambda j, v, o, g, b: (b[v], j)),
+        ),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT),
+        cost_estimate=pl.CostEstimate(
+            flops=2 * m * k * n, transcendentals=0,
+            bytes_accessed=4 * (m * k * (n // tn) + groups * k * n + m * n)),
+        name="grouped_matmul_ragged-dot",
+        interpret=interpret,
+    )(offsets, group, block, rows, weights)

@@ -20,12 +20,16 @@ Monitor stats: ``collective_all_to_all_calls`` /
 """
 from __future__ import annotations
 
+import logging
 from typing import Optional
 
 import numpy as np
 
+from ..monitor import monitor as _monitor
 from ..monitor import stat_add, stat_add_per_device
 from .mesh import EP_AXIS
+
+logger = logging.getLogger(__name__)
 
 
 def moe_ffn_tokens(x, gate_w, w1, b1, w2, b2, *,
@@ -182,7 +186,18 @@ def route_top_k(router_x, router_w, top_k: int, score: str = "softmax",
 
 ROW_TILE = 64     # the row tile XLA:TPU's ragged-dot kernel picks here
 RUN_ROWS = 3 * ROW_TILE   # ... for a call of up to this many rows
-WIDE_TILE = 512           # and the tile it picks from 512 rows on
+
+_LOWERED = {"pallas": _monitor.get("grouped_matmul_lowered_pallas"),
+            "ragged_dot": _monitor.get("grouped_matmul_lowered_ragged_dot")}
+_downgrades_logged = set()
+
+
+def _downgrade(reason):
+    if reason not in _downgrades_logged:
+        _downgrades_logged.add(reason)
+        logger.warning("the grouped expert matmul lowered to "
+                       "jax.lax.ragged_dot on a TPU backend, not the Pallas "
+                       "kernel: %s", reason)
 
 
 def _one_call(rows, weights, group_sizes, precision):
@@ -203,50 +218,54 @@ def _one_call(rows, weights, group_sizes, precision):
     return out[:m] if pad else out
 
 
-def _in_runs(rows, weights, group_sizes, precision, run):
-    """The sorted rows in runs of ``run``, one kernel call a run with the
-    run's own group sizes (a group that straddles a cut counts its rows
-    on either side): the one call's numbers, row for row."""
+def grouped_matmul(rows, weights, group_sizes, precision=None,
+                   mesh_devices=1):
+    """``rows`` [M, K], sorted by group, times ``weights`` [G, K, N]:
+    row ``r`` of group ``g`` is multiplied by ``weights[g]``.  One
+    formulation per shape, chosen on the chip with no flag (README "Routed
+    experts"; ``tools/moe_microbench.py``):
+
+    * float32 at "highest" on a TPU, one device, at least one row block
+      of rows: the Pallas kernel of ``ops/pallas/grouped_matmul.py``
+      (``tiles``: 64 rows by the widest column block that fits).  It
+      visits only the (row block, group) pairs that hold rows and reads a
+      group's weights once a column block.  Both matmuls of a layer on a
+      v5e, the routing's skew of 2.5, PR 32's formulation -> the kernel
+      (my chip run, PR 50): 64 groups of K 2560, width 768 at 48 / 96 /
+      192 / 384 / 768 rows a group 4.50 -> 3.25, 6.58 -> 4.50, 10.71 ->
+      6.85, 19.52 -> 11.57, 31.07 -> 20.91 ms and a decode step's 192 rows
+      2.48 -> 1.99; 64 groups of K 2048, width 1536 at 8 / 32 / 256 a
+      group 4.07 -> 3.51, 5.67 -> 4.50, 21.59 -> 13.41 and a step's 256
+      rows 3.71 -> 3.27; 128 groups of K 2048, width 768 at 8 / 64 a group
+      4.44 -> 3.52, 8.87 -> 5.95 and a block pass's 1,536 rows 4.73 ->
+      3.70; error against a float64 loop 3e-7 to 4e-7 on both sides.
+    * everything else (off a TPU, another dtype or precision, fewer rows
+      than a row block, under a mesh of more than one device: on a TPU
+      that last is a downgrade and is logged once): one
+      ``jax.lax.ragged_dot`` call.  XLA:TPU lowers it to a grouped Mosaic
+      kernel that picks its row tile from the call's M (64 up to 192 rows,
+      512 from 512 on) and pays a whole tile for every group with a row in
+      it; the CPU lowering is a masked dense product.
+
+    ``grouped_matmul_lowered_pallas`` / ``grouped_matmul_lowered_ragged_dot``
+    count, per program build, which a product lowered to."""
     import jax
     import jax.numpy as jnp
 
-    m = rows.shape[0]
-    n = -(-m // run)
-    rows = jnp.pad(rows, ((0, n * run - m), (0, 0))).reshape(n, run, -1)
-    ends = jnp.cumsum(group_sizes)
-    starts = ends - group_sizes
-    lo = jnp.arange(n, dtype=ends.dtype)[:, None] * run
-    sizes = jnp.clip(jnp.minimum(ends, lo + run) - jnp.maximum(starts, lo),
-                     0, None).astype(group_sizes.dtype)         # [n, G]
-    out = jax.lax.map(
-        lambda a: _one_call(a[0], weights, a[1], precision), (rows, sizes))
-    return out.reshape(n * run, -1)[:m]
+    from ..ops.pallas import grouped_matmul as pallas
 
-
-def grouped_matmul(rows, weights, group_sizes, precision=None):
-    """``rows`` [M, K], sorted by group, times ``weights`` [G, K, N]:
-    row ``r`` of group ``g`` is multiplied by ``weights[g]``.  One
-    formulation per shape, chosen on the chip (README "Routed experts"):
-    ``jax.lax.ragged_dot`` for both the decode step's few rows and a
-    prefill's many.  XLA:TPU lowers it to a grouped Mosaic kernel that
-    visits only the (row tile, group) pairs that hold rows, so the
-    weights of a group without rows are never read; the CPU lowering is
-    a masked dense product.
-
-    The kernel picks its row tile from the call's M (64 up to 192 rows,
-    512 from 512 on) and pays a whole tile for every group with a row in
-    it, so a large M whose groups hold fewer rows than the wide tile is
-    mostly padding.  Such an M goes through the kernel in runs of
-    ``RUN_ROWS`` sorted rows (:func:`_in_runs`: the same numbers).  Both
-    matmuls of a layer at "highest" on a v5e, one call -> runs (my chip
-    run, PR 32): 128 groups of K 2048 at 12 / 32 / 64 rows a group 20.1
-    -> 4.8, 20.8 -> 6.3, 22.1 -> 9.0 ms; 64 groups of K 2560 at 48 / 96
-    / 192 / 384 rows a group 13.4 -> 4.6, 14.5 -> 6.7, 16.7 -> 10.8,
-    21.7 -> 19.6 ms, and at 768 a group 31.1 -> 37.3: there the wide
-    tile is full and the one call stays."""
-    m, groups = rows.shape[0], weights.shape[0]
-    if RUN_ROWS < m < WIDE_TILE * groups:
-        return _in_runs(rows, weights, group_sizes, precision, RUN_ROWS)
+    (m, k), n = rows.shape, weights.shape[2]
+    tiles = pallas.tiles(m, k, n)
+    kernel = (tiles is not None and jax.default_backend() == "tpu"
+              and rows.dtype == weights.dtype == jnp.float32
+              and precision == jax.lax.Precision.HIGHEST)
+    if kernel and mesh_devices > 1:
+        kernel = False
+        _downgrade(f"grouped_matmul under a {mesh_devices}-device mesh")
+    _LOWERED["pallas" if kernel else "ragged_dot"].increase()
+    if kernel:
+        return pallas.grouped_matmul(rows, weights, group_sizes,
+                                     tm=tiles[0], tn=tiles[1])
     return _one_call(rows, weights, group_sizes, precision)
 
 
@@ -318,7 +337,7 @@ def moe_routed_tokens(x, router_x, router_w, w_gate_up, w_down, *,
                       precision=None, score: str = "softmax",
                       expert_bias=None, norm_topk: bool = True,
                       route_scale: float = 1.0, held_first=None,
-                      limit=None):
+                      limit=None, mesh_devices: int = 1):
     """Dropless top-k mixture of gated experts over flat tokens.
 
     x [N, H] is the experts' input, router_x [N, H] what the router
@@ -331,7 +350,9 @@ def moe_routed_tokens(x, router_x, router_w, w_gate_up, w_down, *,
     per token are summed under the routing weights.  ``activation`` is
     the gate's: "relu" or "silu" (``limit``: :func:`_gated`'s clamp);
     ``score``, ``expert_bias``,
-    ``norm_topk`` and ``route_scale`` are :func:`route_top_k`'s.
+    ``norm_topk`` and ``route_scale`` are :func:`route_top_k`'s;
+    ``mesh_devices`` (the devices of the program's mesh) is
+    :func:`grouped_matmul`'s.
 
     ``held_first`` (one chip's share of an expert-parallel group): the
     weights are those of experts ``held_first .. held_first + w_gate_up
@@ -375,10 +396,10 @@ def moe_routed_tokens(x, router_x, router_w, w_gate_up, w_down, *,
     group_sizes = jnp.bincount(flat, length=E).astype(jnp.int32)
     rows = jnp.take(x, order // top_k, axis=0)          # [N*k, H]
     h = grouped_matmul(rows, w_gate_up.astype(x.dtype), group_sizes,
-                       precision)
+                       precision, mesh_devices)
     y = grouped_matmul(_gated(h, inter, activation, limit),
                        w_down.astype(x.dtype), group_sizes,
-                       precision)                       # [N*k, H]
+                       precision, mesh_devices)         # [N*k, H]
     y = y * jnp.take(weights.reshape(-1), order)[:, None].astype(y.dtype)
     # back to token order: row r of the sorted list is pair order[r]
     y = jnp.zeros_like(y).at[order].set(y)

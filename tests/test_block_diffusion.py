@@ -223,56 +223,6 @@ def test_silu_experts_equal_a_plain_loop():
                           top_k=3, activation="gelu")
 
 
-@pytest.mark.parametrize("n_rows,run", [(40, 16), (48, 16), (130, 64)])
-def test_grouped_matmul_in_runs_of_rows_is_the_one_call(n_rows, run):
-    """The sorted rows in runs, each with its own group sizes (groups
-    that straddle a cut, an empty group, a short last run): the one
-    call's numbers."""
-    import jax
-    import jax.numpy as jnp
-
-    from paddle_tpu.parallel.moe import _in_runs, _one_call
-
-    rng = np.random.default_rng(n_rows)
-    sizes = np.zeros(8, np.int32)
-    for g in rng.integers(0, 8, n_rows):
-        sizes[g] += 1
-    sizes[3] += sizes[5]
-    sizes[5] = 0                                   # an empty group
-    rows = jnp.asarray(rng.standard_normal((n_rows, 16)), jnp.float32)
-    w = jnp.asarray(rng.standard_normal((8, 16, 12)), jnp.float32)
-    one = _one_call(rows, w, jnp.asarray(sizes), None)
-    cut = jax.jit(lambda r, w, s: _in_runs(r, w, s, None, run))(
-        rows, w, jnp.asarray(sizes))
-    assert cut.shape == one.shape
-    assert np.array_equal(np.asarray(cut), np.asarray(one))
-
-
-@pytest.mark.parametrize("m,groups,runs", [
-    (192, 64, 0),          # a decode step of 32 slots x top-6: one call
-    (1536, 128, 8),        # a pass of 48 slots x 4 rows x top-8
-    (1024, 128, 6),        # rung 128 x top-8: a short last run
-    (24576, 64, 128),      # rung 4096 x top-6: 384 rows a group
-    (49152, 64, 0)])       # rung 8192 x top-6: the wide tile is full
-def test_grouped_matmul_chooses_runs_from_rows_and_groups(m, groups, runs):
-    """``grouped_matmul`` cuts the rows into runs of ``RUN_ROWS`` where a
-    group holds fewer rows than the kernel's wide tile, and only there."""
-    import jax
-    import jax.numpy as jnp
-
-    from paddle_tpu.parallel.moe import grouped_matmul
-
-    S = jax.ShapeDtypeStruct
-    jaxpr = jax.make_jaxpr(grouped_matmul)(
-        S((m, 8), jnp.float32), S((groups, 8, 4), jnp.float32),
-        S((groups,), jnp.int32))
-    loops = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "scan"]
-    assert len(loops) == (1 if runs else 0)
-    if runs:
-        assert loops[0].params["length"] == runs
-    assert jaxpr.out_avals[0].shape == (m, 4)
-
-
 # ---------------------------------------------------------------------------
 # kernels
 # ---------------------------------------------------------------------------

@@ -1,0 +1,269 @@
+"""The Pallas grouped matmul of the experts' products
+(``ops/pallas/grouped_matmul.py``) and the route to it
+(``parallel/moe.py`` ``grouped_matmul``).
+
+* The kernel in interpret mode against a float64 loop and against
+  ``_one_call`` (``jax.lax.ragged_dot``), over the group layouts a sorted
+  list of token-expert pairs takes.
+* The route: which (M, G, K, N) go to the kernel on a stubbed TPU backend,
+  what stays on ``ragged_dot`` there and why, and that the CPU builds what
+  it always did.
+"""
+import logging
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from paddle_tpu.monitor import stat_get
+from paddle_tpu.ops.pallas import grouped_matmul as kernel
+from paddle_tpu.parallel import moe
+
+HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def _rel(got, want):
+    return float(np.abs(got - want).max() / np.abs(want).max())
+
+
+def _loop(rows, weights, sizes):
+    """Row r of group g times ``weights[g]`` in float64, for the rows that
+    belong to a group."""
+    ends = np.cumsum(sizes)
+    which = np.searchsorted(ends, np.arange(int(ends[-1])), side="right")
+    return np.stack([rows[r].astype(np.float64)
+                     @ weights[g].astype(np.float64)
+                     for r, g in enumerate(which)])
+
+
+# (m, K, N, tm, tn, group sizes): 8 groups each
+LAYOUTS = {
+    "even_groups": (128, 16, 128, 16, 128, [16] * 8),
+    "skewed_groups": (128, 16, 128, 16, 128,
+                      [40, 3, 1, 20, 30, 2, 25, 7]),
+    "an_empty_group": (96, 16, 128, 16, 128, [16, 0, 24, 0, 0, 40, 16, 0]),
+    "the_first_and_last_groups_empty": (
+        64, 16, 128, 16, 128, [0, 10, 20, 4, 20, 6, 4, 0]),
+    "a_group_that_straddles_row_blocks": (
+        96, 16, 128, 16, 128, [5, 50, 3, 3, 3, 3, 24, 5]),
+    "a_short_last_row_block": (100, 16, 128, 32, 128,
+                               [12, 13, 12, 13, 12, 13, 12, 13]),
+    "rows_past_the_groups": (96, 16, 128, 16, 128,
+                             [10, 0, 21, 9, 0, 17, 3, 8]),
+    "m_not_a_multiple_of_the_row_block": (
+        77, 16, 128, 24, 128, [9, 10, 9, 10, 9, 10, 10, 10]),
+    "one_group_holds_every_row": (64, 16, 128, 16, 128,
+                                  [0, 0, 0, 64, 0, 0, 0, 0]),
+    "a_row_block_of_many_groups": (64, 16, 128, 64, 128,
+                                   [8, 1, 15, 2, 14, 3, 13, 8]),
+    "two_column_blocks": (96, 24, 256, 16, 128, [12] * 8),
+    "the_first_products_widths": (72, 32, 48, 24, 48, [9] * 8),
+    "the_second_products_widths": (72, 24, 32, 24, 32, [9] * 8),
+}
+
+
+@pytest.mark.parametrize("layout", LAYOUTS)
+def test_kernel_is_the_float64_loop_and_the_one_call(layout):
+    m, k, n, tm, tn, sizes = LAYOUTS[layout]
+    sizes = np.asarray(sizes, np.int32)
+    assert sizes.sum() <= m and tm % 8 == 0 and n % tn == 0
+    rng = np.random.default_rng(len(layout))
+    rows = rng.standard_normal((m, k)).astype(np.float32)
+    weights = rng.standard_normal((8, k, n)).astype(np.float32)
+    # a group without rows is never read: its weights may hold anything
+    weights[sizes == 0] = np.nan
+    got = np.asarray(kernel.grouped_matmul(
+        jnp.asarray(rows), jnp.asarray(weights), jnp.asarray(sizes),
+        tm=tm, tn=tn, interpret=True))
+    assert got.shape == (m, n)
+    real = int(sizes.sum())
+    assert _rel(got[:real], _loop(rows, weights, sizes)) < 1e-5
+    one = np.asarray(moe._one_call(
+        jnp.asarray(rows), jnp.asarray(np.nan_to_num(weights)),
+        jnp.asarray(sizes), None))
+    assert _rel(got[:real], one[:real]) < 1e-5
+
+
+@pytest.mark.parametrize("m,tm,sizes", [
+    (128, 16, [16] * 8),                       # every block one group's
+    (96, 16, [5, 50, 3, 3, 3, 3, 24, 5]),
+    (100, 32, [0, 0, 100, 0, 0, 0, 0, 0]),
+    (64, 16, [0, 10, 20, 4, 20, 6, 4, 0]),
+    (77, 24, [9, 10, 9, 10, 9, 10, 10, 0]),    # 67 rows in groups
+])
+def test_visits_are_the_row_block_group_pairs_that_hold_a_row(m, tm, sizes):
+    sizes = np.asarray(sizes, np.int32)
+    offsets, group, block, n = kernel.visits(jnp.asarray(sizes), m, tm)
+    ends = np.cumsum(sizes)
+    want = sorted({(r // tm, int(np.searchsorted(ends, r, side="right")))
+                   for r in range(int(ends[-1]))})
+    n = int(n)
+    assert n == len(want) <= group.shape[0] == -(-m // tm) + 7
+    assert list(zip(np.asarray(block)[:n].tolist(),
+                    np.asarray(group)[:n].tolist())) == want
+    assert np.asarray(offsets).tolist() == [0] + ends.tolist()
+    # the entries no visit reads still index a block and a group
+    assert 0 <= np.asarray(block).min() and np.asarray(block).max() < -(-m // tm)
+    assert 0 <= np.asarray(group).min() and np.asarray(group).max() < 8
+
+
+# ---------------------------------------------------------------------------
+# the route
+# ---------------------------------------------------------------------------
+
+@pytest.fixture
+def as_tpu(monkeypatch):
+    """``jax.default_backend()`` answers "tpu": the route takes the kernel
+    (traced with ``jax.make_jaxpr``; ``interpreted`` also runs it)."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(moe, "_downgrades_logged", set())
+
+
+@pytest.fixture
+def interpreted(as_tpu, monkeypatch):
+    from jax.experimental import pallas as pl
+
+    real = pl.pallas_call
+    monkeypatch.setattr(
+        pl, "pallas_call",
+        lambda *a, **kw: real(*a, **dict(kw, interpret=True)))
+    kernel.grouped_matmul.clear_cache()
+    yield
+    kernel.grouped_matmul.clear_cache()
+
+
+def _primitives(jaxpr, found=None):
+    found = [] if found is None else found
+    for eqn in jaxpr.eqns:
+        found.append(eqn.primitive.name)
+        for val in eqn.params.values():
+            for sub in val if isinstance(val, (list, tuple)) else [val]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    _primitives(sub, found)
+    return found
+
+
+def _traced(m, groups, k, n, precision=HIGHEST, dtype=jnp.float32, **kw):
+    S = jax.ShapeDtypeStruct
+    before = {p: stat_get(f"grouped_matmul_lowered_{p}")
+              for p in ("pallas", "ragged_dot")}
+    jaxpr = jax.make_jaxpr(
+        lambda r, w, s: moe.grouped_matmul(r, w, s, precision, **kw))(
+        S((m, k), dtype), S((groups, k, n), dtype), S((groups,), jnp.int32))
+    moved = {p: stat_get(f"grouped_matmul_lowered_{p}") - v
+             for p, v in before.items()}
+    assert jaxpr.out_avals[0].shape == (m, n)
+    return _primitives(jaxpr.jaxpr), moved
+
+
+# the shapes the benchmark's three routed configurations build (rows,
+# groups, K, N) and whether the kernel takes them (my chip run, PR 50:
+# PERF.md section 6)
+SHAPES = [
+    # smallthinker-21b-a3b: 64 experts of 768 over 2560, top 6
+    (3072, 64, 2560, 1536, True), (3072, 64, 768, 2560, True),
+    (6144, 64, 2560, 1536, True), (12288, 64, 768, 2560, True),
+    (24576, 64, 2560, 1536, True), (49152, 64, 768, 2560, True),
+    (192, 64, 2560, 1536, True), (192, 64, 768, 2560, True),
+    (12, 64, 2560, 1536, False),           # the check engine's two slots
+    (18, 64, 768, 2560, False),            # ... and its three
+    # lfm2-24b-a2b: 64 experts of 1536 over 2048, top 4
+    (512, 64, 2048, 3072, True), (16384, 64, 1536, 2048, True),
+    (256, 64, 2048, 3072, True),
+    # sdar-30b-a3b-chat: 128 experts of 768 over 2048, top 8
+    (1024, 128, 2048, 1536, True), (1536, 128, 768, 2048, True),
+    (8192, 128, 2048, 1536, True),
+    # fewer rows than a row block; columns no lane tile divides, too wide
+    (63, 8, 256, 512, False), (4096, 8, 8192, 1000, False),
+]
+
+
+@pytest.mark.parametrize("m,groups,k,n,takes", SHAPES)
+def test_route_on_a_tpu_is_chosen_from_the_shape(as_tpu, m, groups, k, n,
+                                                 takes):
+    prims, moved = _traced(m, groups, k, n)
+    assert ("pallas_call" in prims) == takes
+    assert ("ragged_dot_general" in prims) == (not takes)
+    assert moved == {"pallas": int(takes), "ragged_dot": int(not takes)}
+    assert (kernel.tiles(m, k, n) is not None) == takes
+    if takes:
+        tm, tn = kernel.tiles(m, k, n)
+        assert tm == kernel.ROW_BLOCK and n % tn == 0 \
+            and (tn % 128 == 0 or tn == n)
+        # a block of weights, of rows and of the output, twice each, in
+        # well under the kernel's VMEM limit
+        assert 8 * (k * tn + tm * k + tm * tn) < 0.6 * kernel.VMEM_LIMIT
+        assert "scan" not in prims and "pad" not in prims
+        # one small jaxpr a shape: set-up traces and lowers it again in
+        # every program that holds it
+        assert len(prims) < 150
+
+
+@pytest.mark.parametrize("m,groups,k,n,takes", SHAPES[:2] + SHAPES[-3:-2])
+def test_route_on_the_cpu_is_the_ragged_dot(m, groups, k, n, takes):
+    prims, moved = _traced(m, groups, k, n)
+    assert "pallas_call" not in prims and "ragged_dot_general" in prims
+    assert moved == {"pallas": 0, "ragged_dot": 1}
+
+
+@pytest.mark.parametrize("why,kw", [
+    ("default_precision", {"precision": None}),
+    ("bfloat16_operands", {"dtype": jnp.bfloat16, "precision": None}),
+])
+def test_route_on_a_tpu_leaves_other_arithmetic_alone(as_tpu, caplog, why,
+                                                      kw):
+    """The kernel is float32 at "highest" and nothing else: another
+    precision is not its shape, and no downgrade."""
+    with caplog.at_level(logging.WARNING, "paddle_tpu.parallel.moe"):
+        prims, moved = _traced(24576, 64, 2560, 1536, **kw)
+    assert "pallas_call" not in prims
+    assert moved == {"pallas": 0, "ragged_dot": 1}
+    assert not caplog.records
+
+
+def test_route_under_a_mesh_stays_and_says_so_once(as_tpu, caplog):
+    with caplog.at_level(logging.WARNING, "paddle_tpu.parallel.moe"):
+        for _ in range(2):
+            prims, moved = _traced(24576, 64, 2560, 1536, mesh_devices=4)
+            assert "pallas_call" not in prims
+            assert moved == {"pallas": 0, "ragged_dot": 1}
+    said = [r.getMessage() for r in caplog.records]
+    assert len(said) == 1 and "4-device mesh" in said[0]
+
+
+@pytest.mark.parametrize("activation", ["relu", "silu"])
+def test_routed_layer_through_the_kernel_is_the_layer(interpreted,
+                                                      monkeypatch,
+                                                      activation):
+    """``moe_routed_tokens`` with both products on the kernel (interpret
+    mode; small tiles stand in for the chip's) against a float64 loop."""
+    monkeypatch.setattr(kernel, "tiles", lambda m, k, n: (16, n))
+    rng = np.random.default_rng(3)
+    tokens, hidden, experts, width, top_k = 40, 32, 8, 24, 3
+    x = rng.standard_normal((tokens, hidden)).astype(np.float32)
+    wr = rng.standard_normal((hidden, experts)).astype(np.float32)
+    wgu = rng.standard_normal((experts, hidden, 2 * width)).astype(
+        np.float32) * 0.3
+    wd = rng.standard_normal((experts, width, hidden)).astype(
+        np.float32) * 0.3
+    before = stat_get("grouped_matmul_lowered_pallas")
+    out, counts, _ = moe.moe_routed_tokens(
+        jnp.asarray(x), jnp.asarray(x), wr, wgu, wd, top_k=top_k,
+        activation=activation, precision=HIGHEST)
+    assert stat_get("grouped_matmul_lowered_pallas") == before + 2
+    want = np.zeros((tokens, hidden))
+    for t in range(tokens):
+        l = x[t].astype(np.float64) @ wr
+        top = np.argsort(-l)[:top_k]
+        w = np.exp(l[top] - l[top].max())
+        w /= w.sum()
+        for e, we in zip(top, w):
+            gu = x[t].astype(np.float64) @ wgu[e]
+            g = gu[:width]
+            g = np.maximum(g, 0) if activation == "relu" \
+                else g / (1 + np.exp(-g))
+            want[t] += we * ((g * gu[width:]) @ wd[e])
+    assert _rel(np.asarray(out), want) < 1e-5
+    assert int(counts.sum()) == tokens * top_k

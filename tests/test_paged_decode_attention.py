@@ -337,6 +337,45 @@ def test_kernel_compiles_for_a_described_v5e(chip, slots, max_seq):
     assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20
 
 
+@pytest.mark.parametrize("m,groups,k,n", [
+    (49152, 64, 2560, 1536),     # smallthinker-21b-a3b, rung 8192, gate + up
+    (192, 64, 768, 2560),        # ... a decode step's rows, down
+    (16384, 64, 2048, 3072),     # lfm2-24b-a2b, rung 4096: the widest block
+    (1536, 128, 768, 2048),      # sdar-30b-a3b-chat, a block pass, down
+])
+def test_grouped_matmul_kernel_compiles_for_a_described_v5e(chip, m, groups,
+                                                            k, n):
+    """The experts' products at published widths, at the blocks
+    ``grouped_matmul.tiles`` gives them: Mosaic takes the kernel (a block
+    of weights twice in VMEM, "highest" products), and nothing is padded
+    or copied around it.  Nothing runs: a compile is not a chip run."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from paddle_tpu.ops.pallas import grouped_matmul as kernel
+
+    one_chip = SingleDeviceSharding(chip)
+
+    def spec(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    tm, tn = kernel.tiles(m, k, n)
+    compiled = jax.jit(
+        lambda r, w, s: kernel.grouped_matmul(r, w, s, tm=tm, tn=tn)).lower(
+        spec((m, k)), spec((groups, k, n)),
+        spec((groups,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    # the trace's name for it: the benchmark's expert share reads the
+    # operations whose text holds "ragged-dot"
+    assert "%grouped_matmul_ragged-dot" in text
+    # the visit lists and the call's own scratch (6.6 MB at the largest
+    # shape): no copy of the rows, the output or a group's weights
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < min(2 ** 23, 4 * k * n)
+
+
 def test_decode_step_for_a_described_v5e_reads_the_pools_in_place(
         chip, monkeypatch):
     """The whole paged decode step, small but at a head of 128, compiled
