@@ -35,6 +35,15 @@ random seeded weights:
   key channel at 64 heads of 128 x 128, and the held experts' part of a
   layer (20 of a 320-wide router's experts) against a plain loop.
 
+* **chunks over two page kinds** (PR 51) — the Pallas chunk attention
+  kernel at 128 query over 8 KV heads of 128, a chunk of 512 rows at
+  ``base`` 4096 with and without a window of 4096, against the einsum
+  formulation; and a two-layer window + full decoder under the parallel
+  LayerNorm block that prefills a 1,300-token prompt in chunks of 256:
+  ``attention_lowered_chunk_pallas`` grows with every chunk program built,
+  ``attention_lowered_chunk_reference`` stays 0, and the logits are the
+  single-shot prefill's.
+
 Any failed check raises: the exit code is non-zero and no result line is
 printed.  Without a TPU backend the script refuses to run (exit 2).  The
 last line of stdout is one JSON object
@@ -1205,6 +1214,107 @@ def latent_phase(cfg=LATENT):
         f"expanded path; lowered {grew}")
 
 
+CHUNKS = dict(heads=128, kv_heads=8, head_dim=128, rows=512, base=4096,
+              view=6144, window=4096,
+              engine=dict(hidden=1024, heads=16, kv_heads=1, head_dim=128,
+                          ffn=2048, vocab=4096, window=512, chunk=256,
+                          max_seq=2048, page_tokens=16, prompt=1300,
+                          steps=4))
+
+
+def chunk_phase(cfg=CHUNKS):
+    """The chunk attention kernel at the published head shape against the
+    einsum formulation, then a chunked prefill over a full and a window
+    page pool through a two-slot engine: which chunk attention the
+    programs lowered to, and the logits against the same weights' single-
+    shot prefill."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.monitor import stat_get
+    from paddle_tpu.ops.decode_ops import _attend_cache
+    from paddle_tpu.ops.pallas.flash_attention import chunk_attention
+    from paddle_tpu.serving import GenerationEngine
+
+    key = jax.random.key(51)
+    H, Hkv, D = cfg["heads"], cfg["kv_heads"], cfg["head_dim"]
+    q = jax.random.normal(jax.random.fold_in(key, 0),
+                          (1, H, cfg["rows"], D))
+    k = jax.random.normal(jax.random.fold_in(key, 1),
+                          (1, Hkv, cfg["view"], D))
+    v = jax.random.normal(jax.random.fold_in(key, 2),
+                          (1, Hkv, cfg["view"], D))
+    base = jnp.asarray([cfg["base"]], jnp.int32)
+    for window in (None, cfg["window"]):
+        got = chunk_attention(q, k, v, base, window=window)
+        with jax.default_matmul_precision("highest"):
+            want = jax.jit(lambda q, k, v, b, w=window: _attend_cache(
+                q, k, v, b, None, w))(q, k, v, base)
+        rel = float(jnp.abs(got - want).max() / jnp.abs(want).max())
+        check(bool(jnp.isfinite(got).all()) and rel <= 2.0 ** -12,
+              f"chunk attention under window {window} off the einsum "
+              f"formulation by {rel:.4g}")
+        say(f"chunks: {cfg['rows']} rows of {H} query over {Hkv} KV heads "
+            f"at base {cfg['base']}, window {window}: within {rel:.4g} of "
+            f"the einsum formulation (float32 operands whole)")
+    del q, k, v
+
+    e = cfg["engine"]
+    model = dict(vocab_size=e["vocab"], hidden=e["hidden"], num_layers=2,
+                 num_heads=e["heads"], num_kv_heads=e["kv_heads"],
+                 head_dim=e["head_dim"], intermediate=e["ffn"],
+                 norm="parallel", norm_kind="layer", tie_head=True,
+                 rope_base=50000.0, layer_pattern=[
+                     {"window": e["window"], "rope": True,
+                      "rope_interleave": True, "attn_precision": "highest"},
+                     {"window": None, "rope": False,
+                      "attn_precision": "highest"}])
+    args = dict(num_slots=2, max_seq_len=e["max_seq"], eos_id=-1,
+                page_tokens=e["page_tokens"], prefix_reuse=False,
+                speculate=False, keep_logits=True, deadline_ms=600000)
+    names = ("attention_lowered_chunk_pallas",
+             "attention_lowered_chunk_reference")
+    before = [stat_get(n) for n in names]
+    prompt = np.random.default_rng(51).integers(
+        1, e["vocab"], e["prompt"]).tolist()
+    whole = GenerationEngine(model, prefill_chunk=0,
+                             prefill_buckets=[2048], **args)
+    try:
+        want = np.stack(whole.generate(prompt, e["steps"],
+                                       timeout=600)["logits"])
+        chunked = GenerationEngine(
+            model, scope=whole.scope, prefill_chunk=e["chunk"],
+            prefill_buckets=[64, e["chunk"]], **args)
+        try:
+            res = chunked.generate(prompt, e["steps"], timeout=600)
+            stats = chunked.stats()
+        finally:
+            chunked.close()
+    finally:
+        whole.close()
+    got = np.stack(res["logits"])
+    grew = [stat_get(n) - b for n, b in zip(names, before)]
+    rel = float(np.abs(got - want).max() / np.abs(want).max())
+    chunks = -(-e["prompt"] // e["chunk"])
+    check(stats["counters"]["prefill_chunks"] == chunks
+          and stats["paged"]["window"]["pages_released_in_prefill"] > 0,
+          f"chunked prefill ran {stats['counters']['prefill_chunks']} "
+          f"chunks, let go of "
+          f"{stats['paged']['window']['pages_released_in_prefill']} window "
+          f"pages while the prompt came in")
+    # two chunk rungs built, one op a layer each; nothing downgraded
+    check(grew == [4, 0], f"chunk programs lowered {dict(zip(names, grew))}"
+          f", expected every chunk attention on the Pallas kernel")
+    check(bool(np.isfinite(got).all()) and rel <= 2.0 ** -10,
+          f"chunked prefill off the single-shot prefill by {rel:.4g}")
+    say(f"chunks: a {e['prompt']}-token prompt in {chunks} chunks of "
+        f"{e['chunk']} over a full and a window-{e['window']} page pool, "
+        f"{stats['paged']['window']['pages_released_in_prefill']} window "
+        f"pages let go while it came in; attention_lowered_chunk_pallas "
+        f"+{grew[0]}, _chunk_reference +{grew[1]}; logits within "
+        f"{rel:.4g} of the single-shot prefill's")
+
+
 def main():
     t_start = time.perf_counter()
     # the program first: in a directory that holds only this file the
@@ -1272,6 +1382,12 @@ def main():
     t0 = time.perf_counter()
     latent_phase()
     say(f"latent attention kernels and pages done "
+        f"[{time.perf_counter() - t0:.1f} s]")
+    gc.collect()
+
+    t0 = time.perf_counter()
+    chunk_phase()
+    say(f"chunk attention kernel and chunks over two page kinds done "
         f"[{time.perf_counter() - t0:.1f} s]")
 
     say(f"set-up (compile-dominated: kernel check + first train step + "

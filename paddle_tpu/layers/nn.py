@@ -22,7 +22,8 @@ __all__ = [
     "soft_relu", "log_loss", "clip", "clip_by_norm", "mean", "pad",
     "adaptive_pool2d", "flash_attention", "flash_attention_qkv",
     "rms_norm", "rope",
-    "cached_attention", "kv_pool_write", "kv_pool_gather",
+    "cached_attention", "chunk_attention", "kv_pool_write",
+    "kv_pool_gather",
     "paged_decode_attention", "latent_prefill_attention",
     "latent_decode_attention", "block_begin", "block_unmask",
     "short_conv", "short_conv_tail", "slot_state_write", "short_conv_step",
@@ -729,6 +730,29 @@ def cached_attention(q, cache_k, cache_v, positions, scale=None,
     if window is not None:
         attrs["window"] = int(window)
     helper.append_op("cached_attention",
+                     inputs={"Q": [q], "K": [cache_k], "V": [cache_v],
+                             "Positions": [positions]},
+                     outputs={"Out": [out]}, attrs=attrs)
+    return out
+
+
+def chunk_attention(q, cache_k, cache_v, positions, scale=None, name=None,
+                    window=None):
+    """A prefill chunk's attention: ``q`` [B, H, C, D] at positions
+    ``positions[b] + t`` over the gathered views ``cache_k`` / ``cache_v``
+    [B, Hkv, S, D] (:func:`kv_pool_gather`; the chunk's own rows already
+    written), :func:`cached_attention`'s rule and ``window``.  A TPU
+    backend runs a Pallas kernel that never forms the ``[H, C, S]``
+    scores nor repeats K / V to the query heads; any other runs
+    :func:`cached_attention`'s formulation, bit for bit."""
+    helper = LayerHelper("chunk_attention", name=name)
+    out = helper.create_variable_for_type_inference(q.dtype)
+    attrs = {}
+    if scale is not None:
+        attrs["scale"] = float(scale)
+    if window is not None:
+        attrs["window"] = int(window)
+    helper.append_op("chunk_attention",
                      inputs={"Q": [q], "K": [cache_k], "V": [cache_v],
                              "Positions": [positions]},
                      outputs={"Out": [out]}, attrs=attrs)

@@ -42,7 +42,9 @@ from .. import layers
 # the depth: layer i runs ``layer_pattern[i % len(layer_pattern)]``.
 #   window: None (every earlier token) or W: token i attends j with
 #           i - W < j <= i (the window counts the token itself)
-#   rope:   rotary embeddings on q and k, or none at all (NoPE)
+#   rope:   rotary embeddings on q and k, or none at all (NoPE);
+#           "rope_interleave": True rotates the pairs (2i, 2i + 1)
+#           (GPT-J's layout), not (i, i + D / 2)
 #   ffn:    "dense" (SwiGLU of width ``intermediate``) or a dict
 #           {"experts": E, "top_k": k, "width": I, "activation": "relu",
 #            "route_from": "raw"}: dropless top-k gated experts
@@ -60,7 +62,9 @@ from .. import layers
 #           multiplied and their part of the sum goes on (no exchange, and
 #           nothing in the absent chips' stead).  "shared_width": I adds a
 #           shared expert, a SwiGLU of that width that every row goes
-#           through, beside the routed ones
+#           through, beside the routed ones; "shared_scale": c multiplies
+#           its output (n shared experts of width w that are AVERAGED are
+#           one SwiGLU of width n w at c = 1 / n)
 #   mixer:  "attention" (q, k, v, RoPE, pages) or a dict {"kind": "conv",
 #           "L_cache": L, "bias": False}: a gated short convolution,
 #           ``[B, C, u] = split3(h W_in)``, ``y = (C * conv_L(B * u))
@@ -104,7 +108,8 @@ from .. import layers
 #           kernel and the matmuls take float32), whatever the mask
 DEFAULT_LAYER = {"window": None, "rope": True, "ffn": "dense",
                  "attn_precision": None, "mixer": "attention",
-                 "attn_gate": False, "mla": None, "swiglu_limit": None}
+                 "attn_gate": False, "mla": None, "swiglu_limit": None,
+                 "rope_interleave": False}
 
 
 def layer_spec(layer_pattern, i):
@@ -234,20 +239,39 @@ def _taps_fetches(taps):
     return out
 
 
-def _head(x, vocab_size, name, tie_head=False):
+def _norm(x, eps, pname, kind="rms"):
+    """A decoder norm over the last dim with a learned weight and no
+    bias: ``kind`` "rms" (``x / rms(x)``) or "layer" (a LayerNorm: the
+    mean is subtracted first)."""
+    if kind == "rms":
+        return layers.rms_norm(x, epsilon=eps, param_attr=pname)
+    if kind != "layer":
+        raise ValueError(f"norm_kind is 'rms' or 'layer', got {kind!r}")
+    return layers.layer_norm(x, scale=True, shift=False,
+                             begin_norm_axis=len(x.shape) - 1, epsilon=eps,
+                             param_attr=pname)
+
+
+def _head(x, vocab_size, name, tie_head=False, logit_scale=1.0):
     """The LM head over normed rows x [B, S, H] -> [B, S, V]: its own
     matrix ``.head.w`` [H, V], or with ``tie_head`` the embedding table
-    ``.embed`` [V, H] itself, read transposed (one parameter, not two)."""
+    ``.embed`` [V, H] itself, read transposed (one parameter, not two);
+    times ``logit_scale`` where that is not 1."""
     if not tie_head:
-        return _linear(x, vocab_size, pname=f"{name}.head.w" if name
-                       else None)
-    from ..framework.core import default_main_program
+        logits = _linear(x, vocab_size, pname=f"{name}.head.w" if name
+                         else None)
+    else:
+        from ..framework.core import default_main_program
 
-    table = default_main_program().global_block().var(f"{name}.embed")
-    return layers.matmul(x, table, transpose_y=True)
+        table = default_main_program().global_block().var(f"{name}.embed")
+        logits = layers.matmul(x, table, transpose_y=True)
+    if float(logit_scale) != 1.0:
+        logits = layers.scale(logits, scale=float(logit_scale))
+    return logits
 
 
-def _head_on_rows(x, rows_idx, vocab_size, name, eps, tie_head=False):
+def _head_on_rows(x, rows_idx, vocab_size, name, eps, tie_head=False,
+                  norm_kind="rms", logit_scale=1.0):
     """Final norm and LM head on one gathered row per batch row: x
     [B, S, H], ``rows_idx`` [B] int64 -> logits [B, V].  The head then
     costs V x H per request, not S x V x H (5 GB of float32 logits at
@@ -256,8 +280,9 @@ def _head_on_rows(x, rows_idx, vocab_size, name, eps, tie_head=False):
     rows = layers.range(0, batch, 1, dtype="int64")
     coords = layers.stack([rows, rows_idx], axis=1)          # [B, 2]
     x = layers.unsqueeze(layers.gather_nd(x, coords), [1])   # [B, 1, H]
-    x = layers.rms_norm(x, epsilon=eps, param_attr=f"{name}.ln_f")
-    return layers.squeeze(_head(x, vocab_size, name, tie_head), [1])
+    x = _norm(x, eps, f"{name}.ln_f", norm_kind)
+    return layers.squeeze(_head(x, vocab_size, name, tie_head, logit_scale),
+                          [1])
 
 
 def _conv_over_state(z, kernel, conv_w, valid, conv_state, slot, live):
@@ -438,7 +463,8 @@ def llama_block(x, hidden, num_heads, num_kv_heads, seq_len, head_dim,
                 block_table=None, kv_lengths=None, rms_norm_eps=1e-6,
                 rope_base=10000.0, layer=None, valid=None, taps=None,
                 qk_norm=False, mask_block=None, block=False,
-                conv_state=None, slot=None, live=None, norm="pre"):
+                conv_state=None, slot=None, live=None, norm="pre",
+                norm_kind="rms", chunk_pages=False):
     """One decoder layer. x: [B, S, H].
 
     A layer whose ``mixer`` is a gated short convolution
@@ -457,8 +483,12 @@ def llama_block(x, hidden, num_heads, num_kv_heads, seq_len, head_dim,
     heads.  ``norm``: "pre" (the norms on the mixer's and the FFN's
     input), "post" (on their OUTPUT, none on their input: ``x = x +
     norm(mixer(x)); x = x + norm(ffn(x))``, weights ``.ln1`` / ``.ln2``
-    still) or "pre_post" (both: ``x = x + norm(mixer(norm(x)))``, four
-    weights a layer, ``.ln1`` / ``.ln1_post`` / ``.ln2`` / ``.ln2_post``).
+    still), "pre_post" (both: ``x = x + norm(mixer(norm(x)))``, four
+    weights a layer, ``.ln1`` / ``.ln1_post`` / ``.ln2`` / ``.ln2_post``)
+    or "parallel" (ONE norm a layer, ``.ln1``: ``h = norm(x); x = x +
+    mixer(h) + ffn(h)``, both halves read the same ``h`` and are added to
+    the raw ``x``).  ``norm_kind``: every norm of the residual path is
+    "rms" or "layer" (:func:`_norm`; the q / k norms stay RMS).
     A layer with ``mla`` in its pattern entry is latent attention
     (:func:`_mla_mixer`): ``kv_cache`` is then its ONE pool, a 1-tuple,
     and with ``collect_kv`` it returns ``(x, row, None)``.
@@ -493,8 +523,11 @@ def llama_block(x, hidden, num_heads, num_kv_heads, seq_len, head_dim,
         the gather + einsum formulation anywhere else).  ``seq_len`` > 1
         is a *prefill chunk*: S new tokens starting at ``positions[b]``
         attend the gathered logical view plus themselves causally
-        (``kv_pool_gather`` -> ``cached_attention``).  The program's
-        shape picks the path.
+        (``kv_pool_gather`` -> ``chunk_attention``, under the layer's
+        window where it has one).  The program's shape picks the path.
+        ``chunk_pages``: the caller vouches that ``positions`` is a page
+        boundary and the chunk whole pages, so its K/V go in page by
+        page (``kv_pool_write(whole_pages=True)``: no pool re-laid).
       * ``collect_kv=True`` — prefill: returns ``(x, k, v)`` where
         k/v are the post-RoPE [B, n_kv, S, D] cache rows.
     """
@@ -509,17 +542,18 @@ def llama_block(x, hidden, num_heads, num_kv_heads, seq_len, head_dim,
     pre, post = _norm_modes(norm)
 
     def normed(t, pname):
-        return layers.rms_norm(t, epsilon=rms_norm_eps, param_attr=p(pname))
+        return _norm(t, rms_norm_eps, p(pname), norm_kind)
 
     def post_normed(y):
         """The mixer's output under its own norm, where the layout has
         one (``.ln1`` under "post", ``.ln1_post`` beside the input's)."""
         return normed(y, "ln1_post" if pre else "ln1") if post else y
 
+    h = normed(x, "ln1") if pre else x
     ffn_args = dict(ffn=layer["ffn"], p=p, rms_norm_eps=rms_norm_eps,
                     valid=valid, taps=taps, norm=norm,
-                    limit=layer.get("swiglu_limit"))
-    h = normed(x, "ln1") if pre else x
+                    limit=layer.get("swiglu_limit"), norm_kind=norm_kind,
+                    h=h if norm == "parallel" else None)
     if layer["mixer"] != "attention":
         state = None
         if layer["mixer"]["kind"] == "conv":
@@ -561,9 +595,11 @@ def llama_block(x, hidden, num_heads, num_kv_heads, seq_len, head_dim,
         q = layers.rms_norm(q, epsilon=rms_norm_eps, param_attr=p("q_norm"))
         k = layers.rms_norm(k, epsilon=rms_norm_eps, param_attr=p("k_norm"))
     if layer["rope"]:
-        offset = positions if kv_cache is not None else None
-        q = layers.rope(q, base=rope_base, offset=offset)
-        k = layers.rope(k, base=rope_base, offset=offset)
+        rot = dict(base=rope_base,
+                   offset=positions if kv_cache is not None else None,
+                   interleave=bool(layer.get("rope_interleave")))
+        q = layers.rope(q, **rot)
+        k = layers.rope(k, **rot)
 
     if kv_cache is not None:
         # cached decode: scatter this step's K/V into the slots' pages,
@@ -573,11 +609,12 @@ def llama_block(x, hidden, num_heads, num_kv_heads, seq_len, head_dim,
         # j <= positions[b] + t, which includes this step's own columns)
         cache_k, cache_v = kv_cache
         # a block's few rows a slot scatter as the one-row step's do
-        per_head = {"per_head": True} if block else {}
+        form = {"per_head": True} if block else \
+            {"whole_pages": True} if chunk_pages and seq_len > 1 else {}
         cache_k = layers.kv_pool_write(cache_k, k, positions,
-                                       block_table, kv_lengths, **per_head)
+                                       block_table, kv_lengths, **form)
         cache_v = layers.kv_pool_write(cache_v, v, positions,
-                                       block_table, kv_lengths, **per_head)
+                                       block_table, kv_lengths, **form)
         if seq_len == 1 or block:
             # the decode step: live pages in place.  A block's rows are
             # written before they are read, every pass: the pass whose
@@ -591,7 +628,7 @@ def llama_block(x, hidden, num_heads, num_kv_heads, seq_len, head_dim,
                                        head_dim=head_dim)
             gv = layers.kv_pool_gather(cache_v, block_table,
                                        head_dim=head_dim)
-            attn = layers.cached_attention(q, gk, gv, positions, **win)
+            attn = layers.chunk_attention(q, gk, gv, positions, **win)
     else:
         cache_k = cache_v = None
         new_k, new_v = k, v  # pre-expansion rows are what a cache stores
@@ -717,11 +754,12 @@ def _mla_mixer(h, seq_len, hidden, num_heads, layer, p, eps, rope_base,
 
 def _norm_modes(norm):
     """``(pre, post)``: whether a sublayer's input and its output are
-    normed under this layout."""
-    if norm not in ("pre", "post", "pre_post"):
-        raise ValueError(f"norm is 'pre', 'post' or 'pre_post', got "
-                         f"{norm!r}")
-    return norm != "post", norm != "pre"
+    normed under this layout.  "parallel" norms the layer's input once,
+    for both halves (``llama_block``), and no output."""
+    if norm not in ("pre", "post", "pre_post", "parallel"):
+        raise ValueError(f"norm is 'pre', 'post', 'pre_post' or "
+                         f"'parallel', got {norm!r}")
+    return norm != "post", norm in ("post", "pre_post")
 
 
 def _swiglu(h, hidden, width, gate_up_name, down_name, limit=None):
@@ -739,16 +777,17 @@ def _swiglu(h, hidden, width, gate_up_name, down_name, limit=None):
 
 
 def _ffn(x, x_in, hidden, intermediate, ffn, p, rms_norm_eps, valid, taps,
-         norm="pre", limit=None):
+         norm="pre", limit=None, norm_kind="rms", h=None):
     """The layer's second half on the post-mixer stream x: norm, dense
     SwiGLU or routed experts (``x_in``: the layer's raw input, which some
     routers read), residual.  ``norm``: where the norms sit
     (:func:`_norm_modes`; the output's is ``.ln2`` under "post",
-    ``.ln2_post`` beside the input's ``.ln2``).  ``limit``: the SwiGLUs'
-    clamp."""
+    ``.ln2_post`` beside the input's ``.ln2``).  Under "parallel" ``h``
+    is the layer's one normed input, which the mixer read too, and there
+    is no ``.ln2``.  ``limit``: the SwiGLUs' clamp."""
     pre, post = _norm_modes(norm)
-    h = layers.rms_norm(x, epsilon=rms_norm_eps,
-                        param_attr=p("ln2")) if pre else x
+    if h is None:
+        h = _norm(x, rms_norm_eps, p("ln2"), norm_kind) if pre else x
     clamp = {} if limit is None else {"limit": float(limit)}
     if ffn == "dense":
         y = _swiglu(h, hidden, intermediate, p("gate_up.w"), p("ffn_out.w"),
@@ -769,12 +808,16 @@ def _ffn(x, x_in, hidden, intermediate, ffn, p, rms_norm_eps, valid, taps,
         if ffn.get("shared_width"):
             # every row, whatever it was routed to; on every chip of an
             # expert-parallel group alike, so counted once
-            y = layers.elementwise_add(y, _swiglu(
+            shared = _swiglu(
                 h, hidden, int(ffn["shared_width"]),
-                p("moe.shared_gate_up.w"), p("moe.shared_down.w"), **clamp))
+                p("moe.shared_gate_up.w"), p("moe.shared_down.w"), **clamp)
+            if float(ffn.get("shared_scale", 1.0)) != 1.0:
+                shared = layers.scale(shared,
+                                      scale=float(ffn["shared_scale"]))
+            y = layers.elementwise_add(y, shared)
     if post:
-        y = layers.rms_norm(y, epsilon=rms_norm_eps,
-                            param_attr=p("ln2_post" if pre else "ln2"))
+        y = _norm(y, rms_norm_eps, p("ln2_post" if pre else "ln2"),
+                  norm_kind)
     return layers.elementwise_add(x, y)
 
 
@@ -782,15 +825,17 @@ def llama(input_ids, vocab_size=32000, hidden=4096, num_layers=32,
           num_heads=32, num_kv_heads=None, intermediate=11008,
           seq_len=2048, name=None, attn_impl="auto", head_dim=None,
           rms_norm_eps=1e-6, rope_base=10000.0, layer_pattern=None,
-          qk_norm=False, mask_block=None, tie_head=False, norm="pre"):
+          qk_norm=False, mask_block=None, tie_head=False, norm="pre",
+          norm_kind="rms", logit_scale=1.0):
     """Returns logits [B, S, V]. input_ids: [B, S] int64.
 
     ``head_dim`` defaults to ``hidden // num_heads`` (a model may
     publish another: q is then ``num_heads * head_dim`` wide);
     ``layer_pattern`` is described at :data:`DEFAULT_LAYER`, ``qk_norm``
-    ``norm`` and ``mask_block`` at :func:`llama_block`; ``tie_head`` makes the
-    head's product read the embedding table (needs ``name``).  The
-    defaults build exactly the program they always did."""
+    ``norm``, ``norm_kind`` and ``mask_block`` at :func:`llama_block`;
+    ``tie_head`` makes the head's product read the embedding table (needs
+    ``name``) and ``logit_scale`` multiplies the logits.  The defaults
+    build exactly the program they always did."""
     num_kv_heads = num_kv_heads or num_heads
     head_dim = head_dim or hidden // num_heads
     p = (lambda s: f"{name}.{s}") if name else (lambda s: None)
@@ -803,9 +848,10 @@ def llama(input_ids, vocab_size=32000, hidden=4096, num_layers=32,
                         attn_impl=attn_impl, rms_norm_eps=rms_norm_eps,
                         rope_base=rope_base,
                         layer=layer_spec(layer_pattern, i),
-                        qk_norm=qk_norm, mask_block=mask_block, norm=norm)
-    x = layers.rms_norm(x, epsilon=rms_norm_eps, param_attr=p("ln_f"))
-    return _head(x, vocab_size, name, tie_head)
+                        qk_norm=qk_norm, mask_block=mask_block, norm=norm,
+                        norm_kind=norm_kind)
+    x = _norm(x, rms_norm_eps, p("ln_f"), norm_kind)
+    return _head(x, vocab_size, name, tie_head, logit_scale)
 
 
 def build_llama_train(batch_size=None, seq_len=2048, vocab_size=32000,
@@ -857,7 +903,7 @@ def build_llama_prefill(batch_size, seq_len, vocab_size=32000,
                         rope_base=10000.0, layer_pattern=None,
                         num_window_pages=None, keep_router_logits=False,
                         qk_norm=False, mask_block=None, tie_head=False,
-                        norm="pre"):
+                        norm="pre", norm_kind="rms", logit_scale=1.0):
     """Prefill entry point: one causal forward over the (padded) prompt
     that populates a decode cache in one shot.
 
@@ -1002,7 +1048,8 @@ def build_llama_prefill(batch_size, seq_len, vocab_size=32000,
                               collect_kv=True, rms_norm_eps=rms_norm_eps,
                               rope_base=rope_base, layer=lspec,
                               valid=valid, taps=taps, qk_norm=qk_norm,
-                              mask_block=mask_block, norm=norm, **state)
+                              mask_block=mask_block, norm=norm,
+                              norm_kind=norm_kind, **state)
         if lspec["mixer"] != "attention":
             if not caches:
                 kvs.append((i, {"state": k} if v is None
@@ -1027,7 +1074,7 @@ def build_llama_prefill(batch_size, seq_len, vocab_size=32000,
         return feeds, dict({"rows_written": prompt_len + 0},
                            **_taps_fetches(taps))
     logits = _head_on_rows(x, last_pos, vocab_size, name, rms_norm_eps,
-                           tie_head)
+                           tie_head, norm_kind, logit_scale)
     return feeds, _prefill_fetches(logits, kvs, taps, last_pos)
 
 
@@ -1062,7 +1109,8 @@ def build_llama_decode(num_slots, max_seq_len, vocab_size=32000,
                        rope_base=10000.0, layer_pattern=None,
                        num_window_pages=None, keep_router_logits=False,
                        qk_norm=False, block=None, mask_id=None,
-                       tie_head=False, norm="pre"):
+                       tie_head=False, norm="pre", norm_kind="rms",
+                       logit_scale=1.0):
     """Cached decode step over a fixed slot grid.
 
     A layer that keeps slot state (``mixer`` of :data:`DEFAULT_LAYER`)
@@ -1191,10 +1239,10 @@ def build_llama_decode(num_slots, max_seq_len, vocab_size=32000,
                         rms_norm_eps=rms_norm_eps, rope_base=rope_base,
                         layer=lspec, valid=n_rows, taps=taps,
                         qk_norm=qk_norm, block=bool(block), norm=norm,
-                        **cache)
-    x = layers.rms_norm(x, epsilon=rms_norm_eps,
-                        param_attr=f"{name}.ln_f")
-    logits = _head(x, vocab_size, name, tie_head)            # [slots,1,V]
+                        norm_kind=norm_kind, **cache)
+    x = _norm(x, rms_norm_eps, f"{name}.ln_f", norm_kind)
+    logits = _head(x, vocab_size, name, tie_head,
+                   logit_scale)                              # [slots,1,V]
     if block:
         new_tokens, new_masked = layers.block_unmask(logits, tokens,
                                                      masked, quota)
@@ -1215,20 +1263,26 @@ def _chunk_forward(chunk_len, max_seq_len, num_pages, page_tokens,
                    vocab_size, hidden, num_layers, num_heads, num_kv_heads,
                    intermediate, name, head_dim=None, rms_norm_eps=1e-6,
                    rope_base=10000.0, layer_pattern=None, qk_norm=False,
-                   tie_head=False, norm="pre"):
+                   tie_head=False, norm="pre", norm_kind="rms",
+                   logit_scale=1.0, num_window_pages=None,
+                   page_aligned=False, keep_router_logits=False):
     """The forward that the chunk and the verify programs share: C new
     tokens at ``base`` attend the slot's pages plus themselves causally.
     Returns ``(feed_names, x [1, C, H] before the final norm,
-    cache_names, taps)``.  One block table serves every layer, so a
-    model with sliding-window layers (two page kinds) has no such
-    program."""
+    cache_names, taps)``.
+
+    A model with sliding-window layers (two page kinds) takes a second
+    feed, ``block_table_window`` [1, NP], as its prefill and decode
+    programs do: a window layer writes the chunk's K/V through it into
+    its own pool (``num_window_pages`` pages) and attends, of the view
+    gathered through it, the columns its window admits; entries left of
+    ``base - window + 1``'s page may be the trash page.  ``page_aligned``:
+    the caller vouches that ``base`` is a page boundary (``chunk_len`` a
+    whole number of pages is checked), so every layer's K/V go in page by
+    page and no pool is re-laid for the write.  Layers that keep slot
+    state and latent (``mla``) layers have no such program."""
     from ..framework.core import default_main_program
 
-    if window_layers(layer_pattern, num_layers):
-        raise ValueError(
-            "prefill continuation (chunked prefill, prefix reuse, "
-            "speculative verify) is not built for a model with "
-            "sliding-window layers: their pages live in a second pool")
     if state_layers(layer_pattern, num_layers):
         raise ValueError(
             "prefill continuation (chunked prefill, prefix reuse, "
@@ -1241,6 +1295,13 @@ def _chunk_forward(chunk_len, max_seq_len, num_pages, page_tokens,
             "speculative verify) is not built for a model with latent "
             "(mla) attention layers: a chunk would attend latent pages "
             "by the expanded path, from rows it has to expand first")
+    if page_aligned and chunk_len % page_tokens:
+        raise ValueError(f"a page-aligned chunk is whole pages: "
+                         f"{chunk_len} rows over pages of {page_tokens}")
+    windowed = window_layers(layer_pattern, num_layers)
+    if windowed and not num_window_pages:
+        raise ValueError("a chunk program with sliding-window layers "
+                         "needs num_window_pages")
     num_kv_heads = num_kv_heads or num_heads
     head_dim = head_dim or hidden // num_heads
     np_slot = max_seq_len // page_tokens
@@ -1252,28 +1313,35 @@ def _chunk_forward(chunk_len, max_seq_len, num_pages, page_tokens,
                               dtype="int32", append_batch_size=False)
     ck_len = layers.data("chunk_len", [1], dtype="int32",
                          append_batch_size=False)
+    feeds = ["chunk_ids", "base", "block_table", "chunk_len"]
+    bt_window = None
+    if windowed:
+        bt_window = layers.data("block_table_window", [1, np_slot],
+                                dtype="int32", append_batch_size=False)
+        feeds.append("block_table_window")
     block = default_main_program().global_block()
     spec = cache_spec(name, num_layers, layer_pattern, num_slots=0,
                       num_pages=num_pages, page_tokens=page_tokens,
                       num_kv_heads=num_kv_heads, head_dim=head_dim,
-                      hidden=hidden)
+                      hidden=hidden, num_window_pages=num_window_pages)
     cache_names = [e["name"] for e in spec]
     x = layers.embedding(chunk_ids, size=[vocab_size, hidden],
                          param_attr=f"{name}.embed")
-    taps = {}
+    taps = {"keep_logits": keep_router_logits}
     for i in range(num_layers):
         ck, cv = _cache_vars(block, spec, i)
-        # rope offset = base per row; cached_attention's validity mask
+        # rope offset = base per row; the attention's validity mask
         # (j <= base + t) is exactly causal-over-prefix-plus-chunk
         x = llama_block(x, hidden, num_heads, num_kv_heads, chunk_len,
                         head_dim, intermediate, name=f"{name}.blk{i}",
                         kv_cache=(ck, cv), positions=base,
-                        block_table=block_table, kv_lengths=ck_len,
+                        block_table=bt_window if i in windowed
+                        else block_table, kv_lengths=ck_len,
                         rms_norm_eps=rms_norm_eps, rope_base=rope_base,
                         layer=layer_spec(layer_pattern, i), valid=ck_len,
-                        taps=taps, qk_norm=qk_norm, norm=norm)
-    return ["chunk_ids", "base", "block_table", "chunk_len"], x, \
-        cache_names, taps
+                        taps=taps, qk_norm=qk_norm, norm=norm,
+                        norm_kind=norm_kind, chunk_pages=page_aligned)
+    return feeds, x, cache_names, taps
 
 
 def build_llama_prefill_chunk(chunk_len, max_seq_len, num_pages,
@@ -1296,7 +1364,12 @@ def build_llama_prefill_chunk(chunk_len, max_seq_len, num_pages,
     last real token within the chunk).  Fetches: ``logits`` [1, V] at
     ``last_off`` and greedy ``next_token`` [1] — meaningful only for
     a prompt's final chunk.  ``arch``: ``head_dim``, ``rms_norm_eps``,
-    ``rope_base``, ``layer_pattern`` as :func:`llama` takes them.
+    ``rope_base``, ``layer_pattern`` ... as :func:`llama` takes them, and
+    :func:`_chunk_forward`'s ``num_window_pages`` (with it the feed
+    ``block_table_window`` [1, NP], before ``last_off``) and
+    ``page_aligned``.  With routed experts the fetch ``expert_counts``
+    [L_moe, E] over the chunk's real rows and, with
+    ``keep_router_logits``, ``router_logits`` [1, L_moe, E] at ``last_off``.
 
     Returns ``(feed_names, fetches, cache_names)``."""
     feeds, x, cache_names, taps = _chunk_forward(
@@ -1307,11 +1380,11 @@ def build_llama_prefill_chunk(chunk_len, max_seq_len, num_pages,
                            append_batch_size=False)
     logits = _head_on_rows(x, last_off, vocab_size, name,
                            arch.get("rms_norm_eps", 1e-6),
-                           arch.get("tie_head", False))
-    next_token = layers.argmax(logits, axis=-1)              # [1] int64
-    fetches = {"logits": logits, "next_token": next_token}
-    fetches.update(_taps_fetches(taps))
-    return feeds + ["last_off"], fetches, cache_names
+                           arch.get("tie_head", False),
+                           arch.get("norm_kind", "rms"),
+                           arch.get("logit_scale", 1.0))
+    return feeds + ["last_off"], \
+        _prefill_fetches(logits, [], taps, last_off), cache_names
 
 
 def build_llama_verify(chunk_len, max_seq_len, num_pages, page_tokens,
@@ -1348,8 +1421,9 @@ def build_llama_verify(chunk_len, max_seq_len, num_pages, page_tokens,
         chunk_len, max_seq_len, num_pages, page_tokens, vocab_size,
         hidden, num_layers, num_heads, num_kv_heads, intermediate, name,
         **arch)
-    x = layers.rms_norm(x, epsilon=arch.get("rms_norm_eps", 1e-6),
-                        param_attr=f"{name}.ln_f")
-    all_logits = _head(x, vocab_size, name, arch.get("tie_head", False))
+    x = _norm(x, arch.get("rms_norm_eps", 1e-6), f"{name}.ln_f",
+              arch.get("norm_kind", "rms"))
+    all_logits = _head(x, vocab_size, name, arch.get("tie_head", False),
+                       arch.get("logit_scale", 1.0))
     tokens = layers.argmax(all_logits, axis=-1)              # [1, C]
     return feeds, {"logits": all_logits, "tokens": tokens}, cache_names

@@ -48,6 +48,10 @@ _LOWERED = {
     "latent_decode": _monitor.get("attention_lowered_latent_decode"),
     "latent_decode_reference":
         _monitor.get("attention_lowered_latent_decode_reference"),
+    # a prefill chunk over the slot's cache view (ops/decode_ops.py
+    # ``chunk_attention``): the Pallas kernel, or the einsum formulation
+    "chunk_pallas": _monitor.get("attention_lowered_chunk_pallas"),
+    "chunk_reference": _monitor.get("attention_lowered_chunk_reference"),
     # the same ops under a sliding window (``window`` attr set): booked
     # beside the plain counters, which they also raise
     "pallas_window": _monitor.get("attention_lowered_pallas_window"),

@@ -318,6 +318,55 @@ def _cached_attention(ctx, op):
                                  op.attr("window", None)))
 
 
+@register_op("chunk_attention", infer=_cached_attn_infer, grad=None)
+def _chunk_attention(ctx, op):
+    """A prefill chunk's attention: Q [B, H, C, D], the chunk's rows at
+    ``positions[b] + t``, over the gathered logical views K / V [B, Hkv, S,
+    D] of the slot's pages, which already hold the chunk's own rows;
+    ``cached_attention``'s validity rule and ``window``.
+
+    On a TPU backend, one device, at a shape the kernel takes (one slot,
+    ``D`` a multiple of 128, ``C`` of 8, ``S`` of 128) this is the Pallas
+    kernel ``chunk_attention`` of ``ops/pallas/flash_attention.py``: online
+    softmax over key blocks fetched one by one, so no ``[H, C, S]`` scores
+    and no K / V repeated to the query heads ever lie in HBM, blocks right
+    of the diagonal or left of the window neither fetched nor multiplied,
+    float32 operands whole.  It matches the einsum formulation to float32
+    rounding.  Anywhere else it IS ``cached_attention`` (the same
+    function), so on the CPU a chunk, the decode step and the verify
+    program share one attention.  ``attention_lowered_chunk_pallas`` /
+    ``_chunk_reference`` count which, per program build."""
+    import jax
+    import jax.numpy as jnp
+
+    from .attention_ops import _lowered
+    from .pallas.flash_attention import (chunk_attention,
+                                         chunk_attention_supported)
+
+    q = ctx.get_input(op, "Q")
+    k = ctx.get_input(op, "K")
+    v = ctx.get_input(op, "V")
+    pos = ctx.get_input(op, "Positions").astype(jnp.int32)
+    scale, window = op.attr("scale", None), op.attr("window", None)
+    on_tpu = jax.default_backend() == "tpu"
+    n_mesh = ctx.mesh.devices.size if ctx.mesh is not None else 1
+    if on_tpu and n_mesh == 1 \
+            and chunk_attention_supported(q.shape, k.shape):
+        out = chunk_attention(q, k, v, pos, window=window, sm_scale=scale)
+        _lowered("chunk_pallas")
+    else:
+        out = _attend_cache(q, k, v, pos, scale, window)
+        reason = None
+        if on_tpu:
+            reason = (f"chunk_attention under a {n_mesh}-device mesh"
+                      if n_mesh > 1 else
+                      f"chunk_attention with Q {q.shape} over a view "
+                      f"{k.shape} (kernel needs one slot, head_dim % 128 "
+                      f"== 0, rows % 8 == 0, columns % 128 == 0)")
+        _lowered("chunk_reference", reason)
+    ctx.set_output(op, "Out", out)
+
+
 @register_op("paged_decode_attention", infer=_cached_attn_infer, grad=None)
 def _paged_decode_attention(ctx, op):
     """The paged decode step's attention, one query token per slot: Q

@@ -581,6 +581,148 @@ def flash_attention_bias(q, k, v, bias, causal=False, sm_scale=None,
 
 
 # ---------------------------------------------------------------------------
+# chunk attention: C query rows at a runtime base over a slot's cache view
+# ---------------------------------------------------------------------------
+#
+# The forward kernel above with three things changed.  The rows sit at
+# ``base .. base + C - 1``, ``base`` a scalar the kernel reads (scalar
+# prefetch), so the causal and window bounds of its block loop move with
+# it.  K and V stay in HBM and come in block by block, two buffers a
+# stream, so a block right of the diagonal or left of the window is
+# neither fetched nor multiplied and a head's keys need not fit VMEM
+# whole.  And query head ``g`` reads KV head ``g // rep`` by indexing: no
+# key or value is repeated.  Forward only; no statistic comes back.
+
+def chunk_attention_supported(q_shape, kv_shape):
+    """Whether the compiled chunk kernel takes these shapes: one slot,
+    whole lane tiles of ``D``, whole sublane tiles of rows, key blocks of
+    whole 128-lane tiles that divide the view."""
+    B, H, C, D = q_shape
+    _, Hkv, S, _ = kv_shape
+    return (B == 1 and D % 128 == 0 and C % 8 == 0 and H % Hkv == 0
+            and S % 128 == 0 and kv_shape[3] == D)
+
+
+def _chunk_kernel(base_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem, *,
+                  block_k, n_kblocks, rep, scale, window, precision):
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    h, qi = pl.program_id(0), pl.program_id(1)
+    kv_head = h // rep
+    q = q_ref[0].astype(jnp.float32) * scale          # [Bq, D]
+    bq, d = q.shape
+    row0 = base_ref[0] + qi * bq        # this block's first row's position
+    prec = {} if precision is None \
+        else {"precision": jax.lax.Precision(precision)}
+    # key blocks [lower, upper) hold a column some row of the block admits
+    upper = jnp.minimum(n_kblocks, (row0 + bq + block_k - 1) // block_k)
+    lower = 0 if window is None else jnp.minimum(
+        jnp.maximum(row0 - window + 1, 0) // block_k, upper - 1)
+
+    def copies(j, buf):
+        at = (kv_head, pl.ds(j * block_k, block_k), slice(None))
+        return (pltpu.make_async_copy(k_hbm.at[at], kbuf.at[buf],
+                                      sem.at[buf, 0]),
+                pltpu.make_async_copy(v_hbm.at[at], vbuf.at[buf],
+                                      sem.at[buf, 1]))
+
+    for c in copies(lower, 0):
+        c.start()
+
+    def body(j, carry):
+        m, l, acc = carry
+        buf = (j - lower) % 2
+
+        @pl.when(j + 1 < upper)
+        def _():
+            for c in copies(j + 1, 1 - buf):
+                c.start()
+
+        for c in copies(j, buf):
+            c.wait()
+        kb = kbuf[buf].astype(jnp.float32)
+        vb = vbuf[buf].astype(jnp.float32)
+        s = jax.lax.dot_general(
+            q, kb, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32, **prec)  # [Bq, Bk]
+        q_pos = row0 + jax.lax.broadcasted_iota(
+            jnp.int32, (bq, block_k), 0)
+        k_pos = j * block_k + jax.lax.broadcasted_iota(
+            jnp.int32, (bq, block_k), 1)
+        keep = q_pos >= k_pos
+        if window is not None:
+            keep = keep & (q_pos - k_pos < window)
+        s = jnp.where(keep, s, NEG_INF)
+        # (a row whose first visited block is wholly left of its window
+        # keeps m at NEG_INF there; its own diagonal rescales that away,
+        # as in the forward kernel)
+        m_new = jnp.maximum(m, s.max(-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        corr = jnp.exp(m - m_new)
+        l_new = l * corr + p.sum(-1, keepdims=True)
+        acc_new = acc * corr + jnp.dot(
+            p, vb, preferred_element_type=jnp.float32, **prec)
+        return m_new, l_new, acc_new
+
+    m0 = jnp.full((bq, 1), NEG_INF, jnp.float32)
+    l0 = jnp.zeros((bq, 1), jnp.float32)
+    acc0 = jnp.zeros((bq, d), jnp.float32)
+    _, l, acc = jax.lax.fori_loop(lower, upper, body, (m0, l0, acc0))
+    o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "window", "sm_scale", "block_q", "block_k", "interpret", "precision"))
+def chunk_attention(q, k, v, base, window=None, sm_scale=None,
+                    block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K,
+                    interpret=False, precision="highest"):
+    """``q`` [1, H, C, D], the chunk's rows at absolute positions ``base
+    .. base + C - 1`` (``base`` [1] int32, read at run time), over the
+    slot's logical cache view ``k`` / ``v`` [1, Hkv, S, D], the chunk's own
+    rows already in it: row ``t`` attends columns ``j <= base + t`` and,
+    under ``window``, ``j > base + t - window``.  Query head ``g`` reads
+    KV head ``g // (H / Hkv)`` where it lies.  ``precision``: of the two
+    products ("highest": float32 operands whole, as the paged decode
+    kernel takes them; None: the MXU's default).  Returns [1, H, C, D]."""
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    _, H, C, D = q.shape
+    _, Hkv, S, _ = k.shape
+    scale = sm_scale if sm_scale is not None else 1.0 / np.sqrt(D)
+    bq = _fit_block(block_q, C)
+    bk = _fit_block(block_k, S, compiled=not interpret)
+    kernel = functools.partial(
+        _chunk_kernel, block_k=bk, n_kblocks=S // bk, rep=H // Hkv,
+        scale=scale, window=None if window is None else int(window),
+        precision=precision)
+    blk = pl.BlockSpec((1, bq, D), lambda h, i, *_: (h, i, 0))
+    out = pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct((H, C, D), q.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(H, C // bq),
+            in_specs=[blk, pl.BlockSpec(memory_space=pl.ANY),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=blk,
+            scratch_shapes=[pltpu.VMEM((2, bk, D), k.dtype),
+                            pltpu.VMEM((2, bk, D), v.dtype),
+                            pltpu.SemaphoreType.DMA((2, 2))]),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")),
+        interpret=interpret,
+        name="chunk_attention",
+    )(base.astype(jnp.int32).reshape(1), q.reshape(H, C, D),
+      k.reshape(Hkv, S, D), v.reshape(Hkv, S, D))
+    return out.reshape(1, H, C, D)
+
+
+# ---------------------------------------------------------------------------
 # packed-QKV kernels: transpose-free attention on [B, S, 3H]
 # ---------------------------------------------------------------------------
 #

@@ -47,9 +47,22 @@ identified.  This module is the repo's answer:
 * **Chunked prefill** (``FLAGS_serving_prefill_chunk``) — long prompts
   feed in fixed-size slices, ONE slice per scheduler iteration
   interleaved with decode steps (SarathiServe-style), so a long prompt
-  no longer stalls the whole grid's inter-token latency.  A prefix-hit
+  no longer stalls the whole grid's inter-token latency.  Slices go
+  round-robin over the prefilling slots.  A prefix-hit
   tail prefill rides the same chunk program with ``base`` set past the
-  shared pages.
+  shared pages.  A model with sliding-window layers (two page kinds)
+  prefills in chunks too (PR 51): the chunk program takes both block
+  tables, a window layer attends only the pages still inside its window,
+  and the window kind lets pages go WHILE the prompt is still coming in:
+  before the chunk at ``base`` a slot's window pages cover ``[base -
+  window + 1, base + C)``, and what the next rows no longer admit goes
+  back to the pool behind the chunk, so only the slot whose chunk runs
+  holds more than ``window / page_tokens + 1`` window pages and the pool
+  is ``slots x (window / page_tokens + 1)`` pages and ONE chunk's beyond.
+  With chunking on a prompt may be as long as the cache; only the chunk
+  needs a prefill rung.  Prefix reuse, speculation and KV-segment handoff
+  stay refused over two page kinds, and a chunk over layers that keep
+  slot state or latent pages is not built.
 * **Speculative decoding** (``FLAGS_serving_speculate``) — self-
   speculation over the slot's pages: a prompt-lookup drafter
   (:func:`ngram_draft` — longest n-gram suffix match over the
@@ -141,7 +154,9 @@ each fails only the then-active requests),
 ``serving_kv_pool_stalls``, ``serving_spec_drafts``,
 ``serving_spec_tokens_proposed``, ``serving_spec_tokens_accepted``,
 ``serving_spec_rollbacks``,
-``serving_kv_window_pages_released``, ``moe_tokens_routed``,
+``serving_kv_window_pages_released`` (and, of those, the ones let go
+while their prompt was still coming in,
+``serving_kv_window_pages_released_in_prefill``), ``moe_tokens_routed``,
 ``moe_tokens_dropped`` (must read 0), and for a model whose expert
 layers hold one chip's share of the router's experts (the layer
 pattern's ``held``) ``moe_pairs_routed`` / ``moe_pairs_held`` (the
@@ -399,7 +414,7 @@ class _Slot:
 
     __slots__ = ("idx", "req", "position", "steps", "tokens", "t_start",
                  "logits", "pages", "wpages", "router_logits",
-                 "prefill_pos", "hit_tokens",
+                 "prefill_pos", "hit_tokens", "chunk_counts",
                  "decoding", "span", "page_us", "page_t", "page_tenant",
                  "blk_tokens", "blk_masked", "blk_left", "blk_done",
                  "blk_head", "passes")
@@ -420,6 +435,9 @@ class _Slot:
         self.router_logits: List[np.ndarray] = []  # keep_logits, experts
         self.prefill_pos = 0         # next position to prefill
         self.hit_tokens = 0          # tokens served by the index
+        # the expert counts of the prompt's chunks before its last, as
+        # the device holds them: [(handle, real rows)]
+        self.chunk_counts: List[tuple] = []
         self.decoding = False        # prefill complete, in the grid
         # KV page-second integration (usage ledger): page_us
         # accumulates held-pages-×-wall-time in µs, marked forward at
@@ -723,8 +741,6 @@ class GenerationEngine:
                 if spec else None
         self.prefill_buckets = batcher.prompt_buckets(
             self.max_seq_len, buckets=prefill_buckets)
-        self.max_prompt_len = min(self.prefill_buckets[-1],
-                                  self.max_seq_len - 1)
         if self.num_slots < 1:
             raise ValueError("GenerationEngine needs at least one slot")
 
@@ -791,6 +807,12 @@ class GenerationEngine:
         self.prefix_reuse = bool(
             prefix_reuse if prefix_reuse is not None
             else flag_value("FLAGS_serving_prefix_reuse"))
+        # a prompt goes in whole, so the largest rung bounds it; in
+        # chunks it goes in slices of at most the chunk, and only the
+        # chunk needs a rung
+        self.max_prompt_len = self.max_seq_len - 1 \
+            if 0 < self.prefill_chunk <= self.prefill_buckets[-1] \
+            else min(self.prefill_buckets[-1], self.max_seq_len - 1)
         self._pool = PagePool(self.num_pages)
         self._prefix: Optional[PrefixIndex] = (
             PrefixIndex(self._pool, pt_) if self.prefix_reuse else None)
@@ -806,9 +828,14 @@ class GenerationEngine:
                     f"of page_tokens {pt_}")
             self.window_pages_per_slot = min(
                 self.pages_per_slot, self.window // pt_ + 1)
+            # chunked prefill: the ONE slot whose chunk runs holds the
+            # chunk's pages beside its window's; every other slot is
+            # back under ``window_pages_per_slot`` before the next
+            # chunk is chosen (``_prefill_advance`` lets go behind it)
             self.num_window_pages = int(
                 num_window_pages if num_window_pages is not None
-                else self.num_slots * self.window_pages_per_slot + 1)
+                else self.num_slots * self.window_pages_per_slot + 1
+                + -(-max(self.prefill_chunk, 0) // pt_))
             self._wpool = PagePool(self.num_window_pages)
         # disaggregated serving role: "both" (colocated, the default)
         # runs prefill AND the decode grid; "prefill" exports each
@@ -885,21 +912,27 @@ class GenerationEngine:
                     f"segment carries pages only, and the state moves on "
                     f"one token a step, not a block")
         if self._wpool is not None:
-            # two page kinds: what walks ONE block table per slot is no
-            # part of this engine yet (PERF.md section 7)
+            # two page kinds: a chunk program walks both block tables
+            # (models/llama.py ``_chunk_forward``); what shares, rolls
+            # back or hands over ONE table per slot is no part of this
+            # engine yet (PERF.md section 7)
             refused = [what for what, on in (
                 ("prefix_reuse", self.prefix_reuse),
                 ("speculate", self.speculate),
-                ("prefill_chunk > 0", self.prefill_chunk > 0),
                 (f"role={self.role!r} (the disagg segment codec)",
                  self.role != "both")) if on]
             if refused:
                 raise ValueError(
                     f"a model with sliding-window layers keeps two "
                     f"page pools (full and window) and does not support "
-                    f"{', '.join(refused)}: prefix reuse, chunked "
-                    f"prefill, speculation and KV-segment handoff walk "
-                    f"one block table per slot")
+                    f"{', '.join(refused)}: prefix reuse, speculation "
+                    f"and KV-segment handoff walk one block table per "
+                    f"slot (chunked prefill walks both)")
+            if self.prefill_chunk % pt_:
+                raise ValueError(
+                    f"prefill_chunk {self.prefill_chunk} is not a "
+                    f"multiple of page_tokens {pt_}: window pages are "
+                    f"let go chunk by chunk, at page boundaries")
         self._fingerprint: Optional[str] = None
         self._chunk_progs: Dict[int, tuple] = {}
         self._verify_progs: Dict[int, tuple] = {}
@@ -961,7 +994,9 @@ class GenerationEngine:
                    "adopt_rejects": 0, "spec_drafts": 0,
                    "spec_tokens_proposed": 0,
                    "spec_tokens_accepted": 0, "spec_rollbacks": 0,
-                   "window_pages_released": 0, "moe_tokens_routed": 0,
+                   "window_pages_released": 0,
+                   "window_pages_released_in_prefill": 0,
+                   "moe_tokens_routed": 0,
                    "moe_tokens_dropped": 0, "block_passes_denoise": 0,
                    "block_passes_commit": 0, "block_tokens_committed": 0,
                    "slot_state_writes": 0, "delta_state_steps": 0,
@@ -1221,13 +1256,39 @@ class GenerationEngine:
             main, startup = pt.Program(), pt.Program()
             startup._is_startup = True
             startup.random_seed = main.random_seed = self._seed
+            # every chunk the engine sends starts at a page boundary (a
+            # multiple of the chunk, or a prefix hit's whole pages):
+            # where chunk and rung are whole pages the K/V go in page by
+            # page
+            aligned = bucket % self.page_tokens == 0 \
+                and self.prefill_chunk % self.page_tokens == 0
             with pt.program_guard(main, startup):
                 _feeds, fetches, _names = build_llama_prefill_chunk(
                     bucket, self.max_seq_len, self.num_pages,
                     self.page_tokens, name=self.name,
-                    **self.model)
+                    num_window_pages=self.num_window_pages or None,
+                    page_aligned=aligned,
+                    keep_router_logits=self.keep_logits, **self.model)
             entry = self._chunk_progs[bucket] = (main, fetches)
         return entry
+
+    def _chunk_feed(self, ids: np.ndarray, base: int, n: int,
+                    slot: Optional[_Slot]) -> dict:
+        """The chunk program's feeds for ``n`` real rows ``ids`` at
+        ``base`` of ``slot``'s pages (None: a warm-up's, every write to
+        the trash page)."""
+        def table(window=False):
+            return (np.zeros((self.pages_per_slot,), "int32") if slot is None
+                    else self._slot_block_table(slot, window))[None]
+
+        feed = {"chunk_ids": ids[None],
+                "base": np.asarray([base], "int32"),
+                "block_table": table(),
+                "chunk_len": np.asarray([n], "int32"),
+                "last_off": np.asarray([max(n - 1, 0)], "int64")}
+        if self._wpool is not None:
+            feed["block_table_window"] = table(window=True)
+        return feed
 
     def _chunk_buckets(self) -> List[int]:
         """Prefill-bucket lengths the chunk program can be asked for:
@@ -1323,16 +1384,12 @@ class GenerationEngine:
             for b in self._chunk_buckets():
                 if b not in self._chunk_progs:
                     prog, fetches = self._chunk_prog_for(b)
-                    first, = self._prefill_exe.run(
-                        prog,
-                        feed={"chunk_ids": np.zeros((1, b), "int64"),
-                              "base": np.zeros((1,), "int32"),
-                              "block_table": np.zeros((1, np_slot),
-                                                      "int32"),
-                              "chunk_len": np.zeros((1,), "int32"),
-                              "last_off": np.zeros((1,), "int64")},
-                        fetch_list=[fetches["next_token"]],
-                        scope=self.scope, return_numpy=False)
+                    # (the fetches every run takes: another list would
+                    # be another compilation, inside the window)
+                    first = self._run_fetching(
+                        self._prefill_exe, prog, fetches, self._chunk_feed(
+                            np.zeros((b,), "int64"), 0, 0,
+                            None))["next_token"]
                     compiled += 1
         if self.role == "prefill":
             # a prefill-role engine never runs the decode grid
@@ -1878,6 +1935,7 @@ class GenerationEngine:
             slot.wpages = []
             slot.prefill_pos = 0
             slot.hit_tokens = 0
+            slot.chunk_counts = []
             slot.decoding = False
             slot.span = None
             claimed.append((slot, req))
@@ -2400,12 +2458,14 @@ class GenerationEngine:
         slot.hit_tokens = 0
         slot.prefill_pos = 0
 
-    def _ensure_pages(self, slot: _Slot, n_tokens: int):
+    def _ensure_pages(self, slot: _Slot, n_tokens: int, rows: int = 1):
         """Grow the slot's block table to cover ``n_tokens`` logical
         tokens, evicting idle prefix-index pages when the free list
-        runs dry.  Raises :class:`PoolExhausted` when nothing is left
-        to evict — the caller turns that into ``cache_full`` (decode)
-        or a failed request (prefill)."""
+        runs dry.  ``rows``: how many of them, the last ones, the next
+        program attends from (a chunk's; the window kind keeps what the
+        earliest of them admits).  Raises :class:`PoolExhausted` when
+        nothing is left to evict — the caller turns that into
+        ``cache_full`` (decode) or a failed request (prefill)."""
         needed = -(-int(n_tokens) // self.page_tokens)  # ceil
         if len(slot.pages) < needed:
             self._mark_pages(slot)
@@ -2422,20 +2482,23 @@ class GenerationEngine:
                     f"evictable)")
             slot.pages.append(p)
         if self._wpool is not None:
-            self._slide_window_pages(slot, int(n_tokens), needed)
+            self._slide_window_pages(slot, int(n_tokens), needed, rows)
         self._publish_pool_gauges()
 
     def _slide_window_pages(self, slot: _Slot, n_tokens: int,
-                            needed: int):
-        """The window kind's half of :meth:`_ensure_pages`: the last of
-        ``n_tokens`` positions attends ``j >= n_tokens - window``, so
-        logical pages left of that column's page are released (their
-        entries become the trash page 0) and pages up to ``needed`` are
-        mapped from the window pool.  A slot so holds at most ``window
-        / page_tokens + 1`` window pages, and a single-shot prefill
+                            needed: int, rows: int = 1):
+        """The window kind's half of :meth:`_ensure_pages`: the
+        earliest of the last ``rows`` of ``n_tokens`` positions attends
+        ``j >= n_tokens - rows + 1 - window``, so logical pages left of
+        that column's page are released (their entries become the trash
+        page 0) and pages up to ``needed`` are mapped from the window
+        pool.  A decoding slot so holds at most ``window / page_tokens
+        + 1`` window pages, a slot whose chunk of C rows runs at most
+        ``(window + C) / page_tokens + 1``, and a single-shot prefill
         maps only the last window of a long prompt: rows of earlier
         pages follow their zero entries to the trash page."""
-        first = max(0, n_tokens - self.window) // self.page_tokens
+        first = max(0, n_tokens - rows + 1 - self.window) \
+            // self.page_tokens
         wp = slot.wpages
         gone = [p for p in wp[:first] if p]
         if gone:
@@ -2569,24 +2632,46 @@ class GenerationEngine:
             slot.prefill_pos, n_prompt, self.prefill_chunk)[0]
         n = end - start
         bucket = batcher.prompt_bucket_for(n, self.prefill_buckets)
+        window = {}
         with telemetry.trace_span("generation/prefill_prepare",
                                   parent=parent, slot=slot.idx,
                                   bucket=bucket):
-            self._ensure_pages(slot, start + n)
+            in_feeds = self._released_in_feeds
+            had = self._wpool.live_pages if self._wpool is not None else 0
+            # the window kind keeps what the chunk's FIRST row admits
+            self._ensure_pages(slot, start + n, rows=n)
             prog, fetches = self._chunk_prog_for(bucket)
             chunk = np.zeros((bucket,), "int64")
             chunk[:n] = prompt[start:start + n]
-            feed = {"chunk_ids": chunk[None],
-                    "base": np.asarray([start], "int32"),
-                    "block_table": self._slot_block_table(slot)[None],
-                    "chunk_len": np.asarray([n], "int32"),
-                    "last_off": np.asarray([n - 1], "int64")}
+            feed = self._chunk_feed(chunk, start, n, slot)
+            if self._wpool is not None:
+                held = sum(1 for p in slot.wpages if p)
+                mapped = self._wpool.live_pages - had \
+                    + self._released_in_feeds - in_feeds
+                # programs run in the order sent, and the feed names the
+                # pages this chunk reads: what the NEXT rows (the next
+                # chunk's, or the first decode step's, at ``start + n``)
+                # no longer admit goes back to the pool now, so that
+                # only the slot whose chunk runs holds more than a
+                # window's pages
+                self._slide_window_pages(slot, start + n + 1, 0)
+                gone = self._released_in_feeds - in_feeds
+                # (a decode step's span counts what ITS feeds let go)
+                self._released_in_feeds = in_feeds
+                self._count("window_pages_released_in_prefill", gone)
+                stat_add("serving_kv_window_pages_released_in_prefill",
+                         gone)
+                self._publish_pool_gauges()
+                window = {"window_pages_held": held,
+                          "window_pages_mapped": mapped,
+                          "window_pages_released": gone}
         last = start + n >= n_prompt
         outs = self._launch(
             "generation/prefill_chunk", lambda: self._run_fetching(
                 self._prefill_exe, prog, fetches, feed),
             parent=parent, tokens=n, base=start, bucket=bucket,
-            slot=slot.idx)
+            pad_rows=bucket - n, slot=slot.idx,
+            attended_pairs=self._chunk_pairs(start, n), **window)
         self._count("prefill_chunks")
         stat_add("serving_prefill_chunks")
         if req.tenant is not None:
@@ -2598,6 +2683,23 @@ class GenerationEngine:
         slot.prefill_pos = start + n
         if last:
             self._complete_prefill(slot, req, outs, n)
+        elif "expert_counts" in outs:
+            # booked with the prompt's last chunk, when all have run
+            slot.chunk_counts.append((outs["expert_counts"], n))
+
+    def _chunk_pairs(self, base: int, n: int) -> int:
+        """The (row, column) pairs the ``n`` rows of a chunk at ``base``
+        admit, summed over the attention layers: row ``t`` of a full
+        layer every ``j <= base + t``, of a window layer the last
+        ``window`` of them."""
+        ends = np.arange(base + 1, base + n + 1, dtype=np.int64)
+        n_window = len(self._window_layers)
+        n_full = self.model["num_layers"] - n_window \
+            - len(self._state_layers)
+        pairs = n_full * int(ends.sum())
+        if n_window:
+            pairs += n_window * int(np.minimum(ends, self.window).sum())
+        return pairs
 
     def _fetch_first_token(self, slot: _Slot, outs, parent,
                            n_tokens: int) -> int:
@@ -2619,13 +2721,16 @@ class GenerationEngine:
                 [np.asarray(outs["router_logits"].numpy())[0]] \
                 if keep and "router_logits" in outs else []
             if "expert_counts" in outs:
-                booked = self._book_experts(
-                    np.asarray(outs["expert_counts"].numpy()), n_tokens)
-                if span is not None and "pairs_held" in booked:
+                # (the prompt's earlier chunks ran before this one)
+                chunks, slot.chunk_counts = slot.chunk_counts \
+                    + [(outs["expert_counts"], n_tokens)], []
+                booked = [self._book_experts(np.asarray(c.numpy()), n)
+                          for c, n in chunks]
+                if span is not None and "pairs_held" in booked[0]:
                     # (the counts come back with this fetch, after the
                     # ``generation/prefill`` span that launched them)
-                    span.attrs.update(pairs_routed=booked["pairs_routed"],
-                                      pairs_held=booked["pairs_held"])
+                    span.attrs.update({k: sum(b[k] for b in booked) for k
+                                       in ("pairs_routed", "pairs_held")})
         finally:
             self._end_device_wait(span)
         return first
@@ -3769,6 +3874,8 @@ class GenerationEngine:
                     "pages_live": self._wpool.live_pages,
                     "page_bytes": self.window_page_bytes,
                     "pages_released": n["window_pages_released"],
+                    "pages_released_in_prefill":
+                        n["window_pages_released_in_prefill"],
                 },
                 "prefill_chunk": self.prefill_chunk,
                 "prefix_reuse": self.prefix_reuse,

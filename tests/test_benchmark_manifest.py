@@ -3,7 +3,7 @@ tier-1 tests (PR 41; PR 38 left it to "a later PR of another kind"): the
 pure-JSON checks of ``benchmark/tests/test_manifest.py`` are collected
 here as they stand (entries against data files, every entry's
 ``workloads``, each cell's count of values), and the cells that PR 41, PR
-43 and PR 47 added are pinned beside them, by name: each one's own entries, the
+43, PR 47 and PR 51 added are pinned beside them, by name: each one's own entries, the
 shared ``.pool`` entries that list it, its configuration's cut and its
 mix.  No JAX is imported and no engine started.
 """
@@ -32,12 +32,13 @@ manifest.REPORTS["bert-base-seq512-dp4"] += 1
 globals().update({name: fn for name, fn in vars(manifest).items()
                   if name.startswith("test_")})
 
-# the cells that model_config PRs added since the merge (PR 41, 43, 47),
+# the cells that model_config PRs added since the merge (PR 41, 43, 47, 51),
 # in the order they were added: the cell's configuration and mix, its own
 # entries, the shared families that list it beside ``POOL``, what its
 # configuration cuts, and its mix's driver and reference rungs
 OLMO, SOLAR = "olmo-hybrid7b-longdoc", "solar-open2-agentturns"
 GIGA = "gigachat35-ragturns"
+CMDA = "command-a-plus-ragdocs"
 ADDED = {
     OLMO: {
         "config": "olmo-hybrid-7b", "mix": "longdoc-pool",
@@ -77,6 +78,24 @@ ADDED = {
                     "full_attention_layers", "n_routed_experts",
                     "vocab_size", "num_nextn_predict_layers"],
         "driver": "serve_share", "rungs": [256, 1024, 2048]},
+    CMDA: {
+        "config": "command-a-plus-05-2026", "mix": "ragdocs-pool",
+        "own": ["decode_step_roofline.cmda", "prefill_roofline.cmda",
+                "chunk_attention_roofline.cmda",
+                "paged_kernel_roofline.cmda",
+                "chunk_attention_share_pct.cmda",
+                "chunk_share_of_busy_pct.cmda",
+                "kv_window_pages_saved_pct.cmda",
+                "window_released_in_prefill_pct.cmda", "chunk_pad_pct.cmda",
+                "moe_pairs_held_pct.cmda", "moe_held_touched_pct.cmda"],
+        "experts": ["moe_expert_load_max_over_mean.pool",
+                    "expert_matmul_share_pct.pool"],
+        "reduced": ["num_hidden_layers", "layer_types", "num_experts",
+                    "vocab_size"],
+        # the first cell that prefills in chunks: the rungs are those of
+        # each reference prompt's LAST chunk
+        "driver": "serve_chunks", "rungs": [256, 1024, 1024],
+        "chunk": 1024},
 }
 
 
@@ -85,7 +104,7 @@ def _json(*parts):
         return json.load(f)
 
 
-def test_the_benchmark_has_eight_configurations_and_ten_cells():
+def test_the_benchmark_has_nine_configurations_and_eleven_cells():
     spec = manifest.SPEC
     assert [c["name"] for c in spec["configs"]] == [
         "bert-base-mlm", "mistral-7b-v0.1", "smallthinker-21b-a3b",
@@ -95,8 +114,12 @@ def test_the_benchmark_has_eight_configurations_and_ten_cells():
         "bert-base-seq512", "mistral7b-chat", "mistral7b-longprompt",
         "bert-base-seq512-dp4", "smallthinker21b-mixedlen",
         "sdar30b-blockgen", "lfm2-24b-longanswer"] + list(ADDED)
+    assert (len(spec["configs"]), len(manifest.CELLS)) == (9, 11)
     assert [w["name"] for w in spec["workloads"] if w["chips"] == 4] \
         == ["bert-base-seq512-dp4"]
+    new, = [w for w in spec["workloads"] if w["name"] == CMDA]
+    assert (new["chips"], new["config"], new["traffic"]) \
+        == (1, ADDED[CMDA]["config"], ADDED[CMDA]["mix"])
     used = {w["config"] for w in spec["workloads"]}
     assert used == {c["name"] for c in spec["configs"]}
     for c in spec["configs"]:
@@ -111,7 +134,7 @@ def test_the_benchmark_has_eight_configurations_and_ten_cells():
     # 79 entries at the merge, each added cell's own, and the dp4 cell's
     # one entry of PR 42
     assert len(manifest.PER_LAYER) \
-        == 79 + sum(len(a["own"]) for a in ADDED.values()) + 1
+        == 79 + sum(len(a["own"]) for a in ADDED.values()) + 1 == 123
 
 
 @pytest.mark.parametrize("cell", list(ADDED))
@@ -187,6 +210,25 @@ def test_an_added_cell_reports_its_own_and_the_shared_entries(cell):
     ("moe_held_touched_pct.giga", "span_attr_mean",
      "experts_held_touched"),
     ("latent_fill_pct.giga", "span_attr_mean", "latent_positions"),
+    ("decode_step_roofline.cmda", "roofline_span",
+     ["experts_held_touched", "live_positions", "live_positions_window"]),
+    ("prefill_roofline.cmda", "roofline_chunks",
+     "ops_bytes_command_a_plus.chunk_flops"),
+    ("chunk_attention_roofline.cmda", "roofline_kernel_prefill",
+     "^%?chunk_attention"),
+    ("paged_kernel_roofline.cmda", "roofline_kernel",
+     "^%?paged_decode_attention"),
+    ("chunk_attention_share_pct.cmda", "trace_op_share",
+     "^%?chunk_attention"),
+    ("chunk_share_of_busy_pct.cmda", "module_busy_share", "prefill"),
+    ("kv_window_pages_saved_pct.cmda", "kv_pages_saved",
+     "ops_bytes_command_a_plus.window_layer_count"),
+    ("window_released_in_prefill_pct.cmda", "span_attr_ratio",
+     "window_pages_released"),
+    ("chunk_pad_pct.cmda", "span_attr_ratio", "pad_rows"),
+    ("moe_pairs_held_pct.cmda", "span_attr_ratio", "pairs_held"),
+    ("moe_held_touched_pct.cmda", "span_attr_mean",
+     "experts_held_touched"),
 ])
 def test_each_new_metric_reads_a_span_a_counter_or_a_named_kernel(
         name, reader, reads):
@@ -197,7 +239,8 @@ def test_each_new_metric_reads_a_span_a_counter_or_a_named_kernel(
     assert spec["reader"] == reader
     args = spec["args"]
     assert reads in (args.get("attrs"), args.get("pattern"),
-                     args.get("attr"), args.get("num"), args.get("per"))
+                     args.get("attr"), args.get("num"), args.get("per"),
+                     args.get("fn"), args.get("which"))
     with open(os.path.join(REPO, "paddle_tpu", "serving",
                            "generation.py")) as f:
         engine = f.read()
@@ -220,6 +263,21 @@ def test_each_new_metric_reads_a_span_a_counter_or_a_named_kernel(
         assert '"latent_rows_written"' in engine
         if "attr" in args and reader == "roofline_kernel_prefill":
             assert args["attr"] in ("latent_rows_written", "scan_tokens")
+    if name.endswith(".cmda"):
+        with open(os.path.join(REPO, "paddle_tpu", "ops", "pallas",
+                               "flash_attention.py")) as f:
+            assert 'name="chunk_attention"' in f.read()
+        for attr in ("attended_pairs=", '"window_pages_released"',
+                     '"window_pages_mapped"', '"window_pages_held"',
+                     "pad_rows=", "live_positions_window="):
+            assert attr in engine, attr
+        if reader in ("roofline_kernel_prefill", "span_attr_ratio") \
+                and "prefill_chunk" in str(args.get("span")):
+            assert args["span"] == "generation/prefill_chunk"
+        if "fn" in args:
+            module, _, fn = args["fn"].rpartition(".")
+            with open(os.path.join(BENCH, module + ".py")) as f:
+                assert f"def {fn}(" in f.read()
 
 
 @pytest.mark.parametrize("cell", list(ADDED))
@@ -230,7 +288,29 @@ def test_an_added_configuration_cuts_what_it_says_and_no_width(cell):
               if c["name"] == added["config"]]
     assert entry["reduced"] == cfg["reduced"] == added["reduced"]
     assert entry["source"] == cfg["source"]
-    if cell == GIGA:
+    if cell == CMDA:
+        assert (cfg["hidden_size"], cfg["num_attention_heads"],
+                cfg["num_key_value_heads"], cfg["head_dim"],
+                cfg["intermediate_size"], cfg["num_experts_per_tok"],
+                cfg["num_shared_experts"], cfg["sliding_window"],
+                cfg["layer_norm_eps"], cfg["rms_norm_eps"],
+                cfg["rope_theta"], cfg["logit_scale"]) \
+            == (4096, 128, 8, 128, 4096, 8, 4, 4096, 1e-5, None, 50000, 1)
+        # ``reduced`` against ``published``: the guide's floors (one whole
+        # period, 8 experts held, an eighth of the vocabulary)
+        period = ["sliding_attention"] * 3 + ["full_attention"]
+        assert cfg["published"] == {
+            "num_hidden_layers": 32, "layer_types": period * 8,
+            "num_experts": 128, "vocab_size": 262144}
+        assert [cfg[k] for k in added["reduced"]] == [4, period, 8, 32768]
+        assert cfg["expert_share"] == dict(
+            cfg["expert_share"], router_experts=128, first=0)
+        assert cfg["vocab_size"] * 8 == 262144
+        assert (cfg["use_parallel_block"], cfg["tie_word_embeddings"],
+                cfg["position_embedding_type"],
+                cfg["shared_expert_combination_strategy"]) \
+            == (True, True, "rope_gptj", "average")
+    elif cell == GIGA:
         assert (cfg["hidden_size"], cfg["num_attention_heads"],
                 cfg["kv_lora_rank"], cfg["q_lora_rank"],
                 cfg["qk_nope_head_dim"], cfg["qk_rope_head_dim"],
@@ -299,9 +379,16 @@ def test_an_added_mix_is_closed_loop_over_whole_chunks_and_pages(cell):
                for b in e["prefill_buckets"])
     assert mix["prompt_len"]["max"] + mix["output_len"]["max"] \
         <= e["max_seq_len"]
-    assert not (e["prefill_chunk"] or e["prefix_reuse"] or e["speculate"])
+    assert not (e["prefix_reuse"] or e["speculate"])
+    chunk = added.get("chunk", 0)
+    assert e["prefill_chunk"] == chunk
     rungs = sorted(e["prefill_buckets"])
-    assert [min(b for b in rungs if b >= n)
+    if chunk:
+        # whole pages, and chunk rungs that divide the chunk
+        assert chunk % e["page_tokens"] == 0 and rungs[-1] == chunk
+        assert all(chunk % b == 0 for b in rungs)
+    assert [min(b for b in rungs if b >= ((n - 1) % chunk + 1 if chunk
+                                          else n))
             for n in mix["reference_prompts"]] == added["rungs"]
 
 

@@ -720,7 +720,8 @@ def test_norm_is_pre_post_or_both_by_name():
 
     main, startup = pt.Program(), pt.Program()
     with pt.program_guard(main, startup):
-        with pytest.raises(ValueError, match="'pre', 'post' or 'pre_post'"):
+        with pytest.raises(ValueError,
+                           match="'pre', 'post', 'pre_post' or 'parallel'"):
             build_llama_forward(1, 8, vocab_size=97, hidden=64,
                                 num_layers=1, num_heads=4, intermediate=96,
                                 name="llama", norm="both")

@@ -455,10 +455,15 @@ def test_admission_needs_room_in_both_pools():
 
 
 @pytest.mark.parametrize("refused", [
-    {"prefix_reuse": True}, {"speculate": True}, {"prefill_chunk": 16},
+    {"prefix_reuse": True}, {"speculate": True}, {"prefill_chunk": 12},
     {"role": "prefill"}, {"role": "decode"}])
 def test_window_model_refuses_what_walks_one_block_table(refused):
-    with pytest.raises(ValueError, match="sliding-window layers"):
+    """(Since PR 51 a chunk program walks both tables: a chunk of whole
+    pages is taken, ``tests/test_command_a_plus.py``; one that is not
+    whole pages is refused, as window pages go back page by page.)"""
+    match = "multiple of page_tokens" if "prefill_chunk" in refused \
+        else "sliding-window layers"
+    with pytest.raises(ValueError, match=match):
         _engine(autostart=False, **refused)
 
 
