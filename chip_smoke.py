@@ -708,7 +708,9 @@ def sparse_window_phase(cfg=SPARSE):
     "highest" precision: the layer at a decode step's 32 tokens and a
     prefill rung's 512 (both products on the Pallas grouped matmul:
     ``grouped_matmul_lowered_pallas`` +2 each, PR 50) and at the check
-    engine's 2 (``grouped_matmul_lowered_ragged_dot`` +2)."""
+    engine's 2 (``grouped_matmul_lowered_ragged_dot`` +2).  The held
+    share of such a layer (``held_first``) is checked at published widths
+    in ``share_and_channel_phase``."""
     import jax
     import jax.numpy as jnp
 
@@ -1007,7 +1009,7 @@ def delta_state_phase(cfg=DELTA):
 
 SHARE = dict(heads=64, head_dim=128, slots=64, seq=640, valid=600,
              hidden=4096, width=1280, router=320, held=(40, 20), top_k=8,
-             rows=(64, 1000))
+             rows=(64, 1000, 4096))
 
 
 def share_and_channel_phase(cfg=SHARE):
@@ -1018,12 +1020,17 @@ def share_and_channel_phase(cfg=SHARE):
     step over 64 slots of which some are dead) against their XLA
     formulations; and the held experts' part of a layer (20 of a 320-wide
     router's experts of width 1280 on hidden 4096, 8 a token, sigmoid
-    scores with a bias) at a decode step's rows and at a prefill's,
-    against a plain loop over the held experts, with its counts."""
+    scores with a bias) at a decode step's rows, a chunk's and the widest
+    rung's, against a plain loop over the held experts, with its counts;
+    both products of each are the Pallas grouped matmul (PR 52:
+    ``grouped_matmul_lowered_pallas`` +2 a program, ``_ragged_dot`` +0) on
+    runs of ``held_run`` sorted pairs."""
     import jax
     import jax.numpy as jnp
 
-    from paddle_tpu.parallel.moe import moe_routed_tokens, route_top_k
+    from paddle_tpu.monitor import stat_get
+    from paddle_tpu.parallel.moe import (held_run, moe_routed_tokens,
+                                         route_top_k)
 
     H, D = cfg["heads"], cfg["head_dim"]
     _delta_kernels_against_xla("share", 43, H, D, D, cfg["seq"],
@@ -1061,10 +1068,18 @@ def share_and_channel_phase(cfg=SHARE):
     share = jax.jit(lambda x, router, bias, gate_up, down: moe_routed_tokens(
         x, x, router, gate_up, down, top_k=k_top, activation="silu",
         precision=hi, score="sigmoid", expert_bias=bias, held_first=first))
+    lowered = ("grouped_matmul_lowered_pallas",
+               "grouped_matmul_lowered_ragged_dot")
     for rows in cfg["rows"]:
         x = draw(20 + rows, rows, hid)
         want, experts = plain(x, router, bias, gate_up, down)
+        before = [stat_get(n) for n in lowered]
         out, counts, _ = share(x, router, bias, gate_up, down)
+        grew = [stat_get(n) - b for n, b in zip(lowered, before)]
+        check(grew == [2, 0],
+              f"the held experts' part of {rows} rows lowered "
+              f"{dict(zip(lowered, grew))}, expected both products on the "
+              f"Pallas kernel")
         here = int(((experts >= first) & (experts < first + held)).sum())
         rel = float(jnp.abs(out - want).max() / jnp.abs(want).max())
         check(bool(jnp.isfinite(out).all()) and rel <= TOL,
@@ -1076,7 +1091,10 @@ def share_and_channel_phase(cfg=SHARE):
               f"{int(counts[first:first + held].sum())} held, want "
               f"{rows * k_top} and {here}")
         say(f"share: {held} of {E} experts held, {rows} rows x {k_top}: "
-            f"{here} held pairs within {rel:.4g} of the plain loop")
+            f"{here} held pairs in runs of "
+            f"{held_run(rows * k_top, held, E, True)} within {rel:.4g} of "
+            f"the plain loop; grouped_matmul_lowered_pallas +{grew[0]}, "
+            f"_ragged_dot +{grew[1]}")
 
 
 LATENT = dict(heads=64, latent=512, nope=128, rope=64, v=128, slots=32,

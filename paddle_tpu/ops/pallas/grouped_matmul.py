@@ -40,15 +40,15 @@ VMEM_LIMIT = 100 << 20        # of a v5e core's 128 MiB
 WEIGHT_BLOCK_BYTES = 26 << 20  # a group's [K, tn], held twice by the pipeline
 
 
-def tiles(m, k, n):
+def tiles(m, k, n, scoped=False):
     """``(tm, tn)``, rows and columns a block, for ``[m, k]`` rows over
     groups of ``[k, n]``: ``ROW_BLOCK`` rows and the widest column block
-    whose weights fit ``WEIGHT_BLOCK_BYTES``; None for fewer rows than one
+    whose weights fit ``block_bytes(scoped)``; None for fewer rows than one
     block, or where no whole-lane-tile divisor of ``n`` fits."""
     if m < ROW_BLOCK:
         return None
     tn = next((t for t in (n, *range(n - n % LANES, 0, -LANES))
-               if n % t == 0 and k * t * 4 <= WEIGHT_BLOCK_BYTES), None)
+               if n % t == 0 and k * t * 4 <= block_bytes(scoped)), None)
     return tn and (ROW_BLOCK, tn)
 
 
@@ -86,12 +86,12 @@ def _kernel(offsets_ref, group_ref, block_ref, rows_ref, w_ref, out_ref,
     out_ref[...] = jnp.where((row >= lo) & (row < hi), acc, out_ref[...])
 
 
-@functools.partial(jax.jit, static_argnames=("tm", "tn", "interpret"))
-def grouped_matmul(rows, weights, group_sizes, *, tm, tn, interpret=False):
-    """``rows`` [M, K] float32 sorted by group, ``weights`` [G, K, N]
-    float32, ``group_sizes`` [G] -> [M, N]; ``tm`` rows (whole sublane
-    tiles) and ``tn`` columns (whole lane tiles that divide N, or N) a
-    block: :func:`tiles`."""
+@functools.partial(jax.jit,
+                   static_argnames=("tm", "tn", "scoped", "interpret"))
+def grouped_matmul(rows, weights, group_sizes, *, tm, tn, scoped=False,
+                   interpret=False):
+    """``rows`` [M, K] sorted by group x ``weights`` [G, K, N] -> [M, N],
+    float32; ``tm``, ``tn``, ``scoped``: :func:`tiles`, :func:`block_bytes`."""
     m, k = rows.shape
     groups, _, n = weights.shape
     offsets, group, block, n_visits = visits(group_sizes, m, tm)
@@ -110,10 +110,27 @@ def grouped_matmul(rows, weights, group_sizes, *, tm, tn, interpret=False):
         ),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
-            vmem_limit_bytes=VMEM_LIMIT),
+            vmem_limit_bytes=None if scoped else VMEM_LIMIT),
         cost_estimate=pl.CostEstimate(
             flops=2 * m * k * n, transcendentals=0,
             bytes_accessed=4 * (m * k * (n // tn) + groups * k * n + m * n)),
         name="grouped_matmul_ragged-dot",
         interpret=interpret,
     )(offsets, group, block, rows, weights)
+
+
+# A group's [K, tn] under XLA:TPU's default 16 MiB of scoped VMEM, held twice
+SCOPED_BLOCK_BYTES = 6 << 20
+
+
+def block_bytes(scoped):
+    """The most a block of weights may take.  ``scoped``: the call stays
+    inside the scoped VMEM every XLA:TPU operation gets by default and
+    sets no ``vmem_limit_bytes``.  A limit over the default on ONE call
+    of a program makes XLA lay out the scoped VMEM of every other
+    operation again: a decode program of 64 slots whose four expert
+    layers held such calls took a window for its 24,576-wide vocabulary
+    product that XLA's own estimate puts 18 times slower, 2.5 ms a step
+    where the parent's took under 1 (my chip run, PR 52); ``tiles``
+    without ``scoped`` keeps the wide blocks PR 50 measured."""
+    return SCOPED_BLOCK_BYTES if scoped else WEIGHT_BLOCK_BYTES

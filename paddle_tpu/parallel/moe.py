@@ -247,8 +247,8 @@ def grouped_matmul(rows, weights, group_sizes, precision=None,
       512 from 512 on) and pays a whole tile for every group with a row in
       it; the CPU lowering is a masked dense product.
 
-    ``grouped_matmul_lowered_pallas`` / ``grouped_matmul_lowered_ragged_dot``
-    count, per program build, which a product lowered to."""
+    ``grouped_matmul_lowered_pallas`` / ``..._ragged_dot`` count, per program
+    build, which a product lowered to (``_held_share``'s runs call it too)."""
     import jax
     import jax.numpy as jnp
 
@@ -287,49 +287,49 @@ def _gated(h, inter, activation, limit=None):
     return gate * up
 
 
-def _held_share(x, local, weights, w_gate_up, w_down, activation,
-                precision, limit=None):
-    """The held experts' part of the layer: ``local`` [N, k] is each
-    pair's index among the experts held here, or ``held`` (= ``w_gate_up``
-    's leading size) where its expert lives on another chip.  The pairs
-    are sorted with the held ones first, and only the runs of ``RUN_ROWS``
-    sorted pairs that hold a held one are gathered, multiplied and added
-    to their tokens' rows (a loop whose trip count the routing gives): an
-    absent expert's pair costs no row of either matmul and adds nothing,
-    and no [N * k, H] array is made.  Returns ``out`` [N, H]."""
-    import jax
-    import jax.numpy as jnp
+# ``_held_share`` (under ``moe_routed_tokens``) multiplies the held pairs in
+# runs.  ``RUN_ROWS`` above is the longest where its products are
+# ``jax.lax.ragged_dot`` calls: XLA:TPU picks a 64-row tile for a call of up
+# to 192 rows and a 512-row one after.  The Pallas kernel has no such cliff,
+# and there ``held_run`` sizes a run to the pairs expected, up to:
+KERNEL_RUN_ROWS = 1024
+# A run's results are added to their tokens' rows in pieces of so many
+# rows: from 192-512 rows on XLA:TPU's scatter-add sorts the indices and
+# takes the whole accumulator through VMEM, 1.6 ms for 512 rows of 7168
+# where four pieces take 0.4 (my chip run, PR 52)
+SCATTER_ROWS = 128
 
-    N, H = x.shape
-    top_k = local.shape[1]
-    held, inter = w_gate_up.shape[0], w_down.shape[1]
-    flat = local.reshape(-1)
-    order = jnp.argsort(flat, stable=True)              # held pairs first
-    sizes = jnp.bincount(flat, length=held + 1)[:held].astype(jnp.int32)
-    ends = jnp.cumsum(sizes)
-    starts, n_held = ends - sizes, ends[-1]
-    run = min(RUN_ROWS, -(-N * top_k // ROW_TILE) * ROW_TILE)
-    order = jnp.pad(order, (0, -order.shape[0] % run))
-    pair_w = weights.reshape(-1)
 
-    def body(i, out):
-        lo = i * run
-        pairs = jax.lax.dynamic_slice_in_dim(order, lo, run)
-        real = lo + jnp.arange(run) < n_held
-        tok = pairs // top_k
-        rows = jnp.take(x, tok, axis=0)                 # [run, H]
-        size = jnp.clip(jnp.minimum(ends, lo + run) - jnp.maximum(starts, lo),
-                        0, None).astype(jnp.int32)
-        h = _one_call(rows, w_gate_up.astype(x.dtype), size, precision)
-        y = _one_call(_gated(h, inter, activation, limit),
-                      w_down.astype(x.dtype), size, precision)
-        y = y * jnp.take(pair_w, pairs)[:, None].astype(y.dtype)
-        # rows past the held pairs belong to no group: whatever the
-        # kernel left there is dropped, not scaled
-        return out.at[tok].add(jnp.where(real[:, None], y, 0))
+def held_run(pairs, held, experts, kernel):
+    """Sorted pairs a trip of :func:`_held_share`'s loop, from what the
+    program's shapes say: ``pairs`` = N * top_k token-expert pairs, of
+    which a share ``held / experts`` is expected here.
 
-    return jax.lax.fori_loop(0, -(-n_held // run), body,
-                             jnp.zeros((N, H), x.dtype))
+    Where the two products are ``jax.lax.ragged_dot`` calls (``kernel``
+    False), ``RUN_ROWS``: the most rows for which XLA:TPU picks its 64-row
+    tile, so a trip pays a tile for each of the two or three groups it
+    touches and not a 512-row one.  Where they are the Pallas kernel,
+    which visits only the row blocks that hold rows, a run's empty tail
+    costs its share of the gather and the gate and no visit, and a run's
+    end inside a group makes the next trip read that group's weights
+    again: the run is the expected held pairs and half as many again, in
+    whole row blocks, up to ``KERNEL_RUN_ROWS`` (a step, a chunk or a
+    rung of up to 2048 rows of the three published shares is one trip;
+    the widest rung reads a group about once).  On a v5e, a layer's held
+    share, PR 43's runs of 192 through ``ragged_dot`` -> these (my chip
+    run, PR 52; ``tools/moe_microbench.py --held 1``): 20 of 320 experts
+    of 1280 over 4096 at a step of 64 rows 1.67 -> 1.41 ms, at rungs of
+    512 / 1024 / 4096 rows 2.58 -> 2.06, 3.29 -> 2.46, 7.70 -> 5.48; 8 of
+    256 of 2048 over 7168 at a step of 32 rows 1.41 -> 1.25, at rungs of
+    512 / 1024 / 2048 2.87 -> 2.30, 4.42 -> 2.81, 4.79 -> 4.04; 8 of 128
+    of 4096 over 4096 at a step of 10 rows 1.39 -> 0.87, at a chunk of
+    1024 5.10 -> 4.02."""
+    whole = -(-pairs // ROW_TILE) * ROW_TILE
+    if not kernel:
+        return min(RUN_ROWS, whole)
+    expected = -(-3 * pairs * held // (2 * experts))
+    return min(max(-(-expected // ROW_TILE) * ROW_TILE, ROW_TILE),
+               KERNEL_RUN_ROWS, whole)
 
 
 def moe_routed_tokens(x, router_x, router_w, w_gate_up, w_down, *,
@@ -386,7 +386,7 @@ def moe_routed_tokens(x, router_x, router_w, w_gate_up, w_down, *,
             & pair_valid
         out = _held_share(x, jnp.where(here, experts - held_first, held),
                           weights, w_gate_up, w_down, activation, precision,
-                          limit)
+                          limit, mesh_devices, E)
         counts = jnp.zeros((E,), jnp.int32).at[experts.reshape(-1)].add(
             jnp.broadcast_to(pair_valid, experts.shape).reshape(-1)
             .astype(jnp.int32))
@@ -410,3 +410,112 @@ def moe_routed_tokens(x, router_x, router_w, w_gate_up, w_down, *,
         pair_valid = jnp.repeat(valid.astype(jnp.int32), top_k)
         counts = jnp.zeros((E,), jnp.int32).at[flat].add(pair_valid)
     return out.astype(x.dtype), counts, logits
+
+
+def _scoped_tiles(rows, weights, precision, mesh_devices):
+    """The Pallas kernel's blocks for a product of :func:`_held_share`, or
+    None where :func:`grouped_matmul` would not send it to the kernel (its
+    own condition, asked ahead of the call; ``rows`` may be a shape with a
+    dtype).  The blocks are the ``scoped`` ones, which stay inside the
+    default scoped VMEM (``pallas.block_bytes`` says why)."""
+    import jax
+    import jax.numpy as jnp
+
+    from ..ops.pallas import grouped_matmul as pallas
+
+    if not (jax.default_backend() == "tpu" and mesh_devices == 1
+            and rows.dtype == weights.dtype == jnp.float32
+            and precision == jax.lax.Precision.HIGHEST):
+        return None
+    return pallas.tiles(rows.shape[0], rows.shape[1], weights.shape[2],
+                        scoped=True)
+
+
+def _held_matmul(rows, weights, group_sizes, precision, mesh_devices):
+    """:func:`grouped_matmul` for a run of :func:`_held_share`: its route
+    and its counters, and on the kernel :func:`_scoped_tiles`' blocks."""
+    from ..ops.pallas import grouped_matmul as pallas
+
+    tiles = _scoped_tiles(rows, weights, precision, mesh_devices)
+    if tiles is None:
+        return grouped_matmul(rows, weights, group_sizes, precision,
+                              mesh_devices)
+    _LOWERED["pallas"].increase()
+    return pallas.grouped_matmul(rows, weights, group_sizes, tm=tiles[0],
+                                 tn=tiles[1], scoped=True)
+
+
+def _held_share(x, local, weights, w_gate_up, w_down, activation,
+                precision, limit=None, mesh_devices=1, experts=None,
+                run=None):
+    """The held experts' part of the layer: ``local`` [N, k] is each
+    pair's index among the experts held here, or ``held`` (= ``w_gate_up``
+    's leading size) where its expert lives on another chip (one of the
+    router's ``experts``).  The pairs are sorted with the held ones first,
+    and only the runs of ``run`` sorted pairs (:func:`held_run`, unless
+    given) that hold a held one are gathered, multiplied
+    (:func:`_held_matmul`: the Pallas kernel on one TPU device at
+    float32 "highest") and added to their tokens' rows (a loop whose trip
+    count the routing gives): an absent expert's pair costs no row of
+    either matmul and adds nothing, and no [N * k, H] array is made.
+    A run longer than ``SCATTER_ROWS`` is added in pieces of that many
+    rows, those that hold a held pair alone: XLA:TPU's scatter-add of
+    more rows at once sorts them and takes the whole of ``out`` through
+    VMEM, 1.6 ms for 512 rows of 7168 where four pieces take 0.4 (my chip
+    run, PR 52).  Returns ``out`` [N, H]."""
+    import jax
+    import jax.numpy as jnp
+
+    N, H = x.shape
+    top_k = local.shape[1]
+    held, inter = w_gate_up.shape[0], w_down.shape[1]
+    w_gate_up, w_down = w_gate_up.astype(x.dtype), w_down.astype(x.dtype)
+    flat = local.reshape(-1)
+    order = jnp.argsort(flat, stable=True)              # held pairs first
+    sizes = jnp.bincount(flat, length=held + 1)[:held].astype(jnp.int32)
+    ends = jnp.cumsum(sizes)
+    starts, n_held = ends - sizes, ends[-1]
+    if run is None:
+        block = jax.ShapeDtypeStruct((ROW_TILE, H), x.dtype)
+        run = held_run(
+            N * top_k, held, experts or held,
+            None not in (
+                _scoped_tiles(block, w_gate_up, precision, mesh_devices),
+                _scoped_tiles(block.update(shape=(ROW_TILE, inter)), w_down,
+                              precision, mesh_devices)))
+    order = jnp.pad(order, (0, -order.shape[0] % run))
+    pair_w = weights.reshape(-1)
+
+    def body(i, out):
+        lo = i * run
+        pairs = jax.lax.dynamic_slice_in_dim(order, lo, run)
+        real = lo + jnp.arange(run) < n_held
+        tok = pairs // top_k
+        rows = jnp.take(x, tok, axis=0)                 # [run, H]
+        size = jnp.clip(jnp.minimum(ends, lo + run) - jnp.maximum(starts, lo),
+                        0, None).astype(jnp.int32)
+        h = _held_matmul(rows, w_gate_up, size, precision, mesh_devices)
+        y = _held_matmul(_gated(h, inter, activation, limit), w_down, size,
+                         precision, mesh_devices)
+        y = y * jnp.take(pair_w, pairs)[:, None].astype(y.dtype)
+        # rows past the held pairs belong to no group: whatever the
+        # kernel left there is dropped, not scaled
+        y = jnp.where(real[:, None], y, 0)
+        if run <= SCATTER_ROWS:
+            return out.at[tok].add(y)
+
+        def piece(c, out):
+            # the last piece of a run that is not whole pieces starts
+            # early, and the rows it shares with the one before add 0
+            at = jnp.minimum(c * SCATTER_ROWS, run - SCATTER_ROWS)
+            fresh = at + jnp.arange(SCATTER_ROWS) >= c * SCATTER_ROWS
+            return out.at[
+                jax.lax.dynamic_slice_in_dim(tok, at, SCATTER_ROWS)].add(
+                jnp.where(fresh[:, None], jax.lax.dynamic_slice_in_dim(
+                    y, at, SCATTER_ROWS), 0))
+
+        return jax.lax.fori_loop(
+            0, -(-jnp.minimum(run, n_held - lo) // SCATTER_ROWS), piece, out)
+
+    return jax.lax.fori_loop(0, -(-n_held // run), body,
+                             jnp.zeros((N, H), x.dtype))

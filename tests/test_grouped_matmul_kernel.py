@@ -133,16 +133,25 @@ def interpreted(as_tpu, monkeypatch):
     kernel.grouped_matmul.clear_cache()
 
 
-def _primitives(jaxpr, found=None):
-    found = [] if found is None else found
+def _eqns(jaxpr):
+    """Every equation of ``jaxpr`` and of the jaxprs inside it."""
     for eqn in jaxpr.eqns:
-        found.append(eqn.primitive.name)
+        yield eqn
         for val in eqn.params.values():
             for sub in val if isinstance(val, (list, tuple)) else [val]:
                 sub = getattr(sub, "jaxpr", sub)
                 if hasattr(sub, "eqns"):
-                    _primitives(sub, found)
-    return found
+                    yield from _eqns(sub)
+
+
+def _primitives(jaxpr):
+    return [eqn.primitive.name for eqn in _eqns(jaxpr)]
+
+
+def _result_rows(jaxpr, primitive):
+    """The leading sizes of the first result of every ``primitive``."""
+    return {eqn.outvars[0].aval.shape[0] for eqn in _eqns(jaxpr)
+            if eqn.primitive.name == primitive}
 
 
 def _traced(m, groups, k, n, precision=HIGHEST, dtype=jnp.float32, **kw):
@@ -239,7 +248,8 @@ def test_routed_layer_through_the_kernel_is_the_layer(interpreted,
                                                       activation):
     """``moe_routed_tokens`` with both products on the kernel (interpret
     mode; small tiles stand in for the chip's) against a float64 loop."""
-    monkeypatch.setattr(kernel, "tiles", lambda m, k, n: (16, n))
+    monkeypatch.setattr(kernel, "tiles",
+                        lambda m, k, n, scoped=False: (16, n))
     rng = np.random.default_rng(3)
     tokens, hidden, experts, width, top_k = 40, 32, 8, 24, 3
     x = rng.standard_normal((tokens, hidden)).astype(np.float32)
@@ -267,3 +277,158 @@ def test_routed_layer_through_the_kernel_is_the_layer(interpreted,
             want[t] += we * ((g * gu[width:]) @ wd[e])
     assert _rel(np.asarray(out), want) < 1e-5
     assert int(counts.sum()) == tokens * top_k
+
+
+# ---------------------------------------------------------------------------
+# the held experts' share (``_held_share``, PR 52)
+# ---------------------------------------------------------------------------
+
+# the three configurations that serve one chip's share of an expert-
+# parallel group: (K, N) of the first and the second product, the column
+# block ``tiles`` gives each, and the ``scoped`` one ``_held_share`` runs
+HELD_TILES = {
+    "solar-open2-250b": ((4096, 2560, 1280, 256), (1280, 4096, 4096, 1024)),
+    "gigachat35-432b-a28b": ((7168, 4096, 512, 128),
+                             (2048, 7168, 1792, 512)),
+    "command-a-plus-05-2026": ((4096, 8192, 1024, 256),
+                               (4096, 4096, 1024, 256)),
+}
+
+
+@pytest.mark.parametrize("config", HELD_TILES)
+@pytest.mark.parametrize("product", [0, 1])
+def test_tiles_of_the_held_shapes_and_a_tail_of_rows_in_no_group(config,
+                                                                 product):
+    """``tiles`` pinned at the held shapes, wide and ``scoped``; and the
+    kernel in interpret mode at the scoped blocks (two column blocks of the
+    published K, three groups) over a run whose tail belongs to no group:
+    the rows of the groups are the float64 loop's, the tail is left as it
+    lies (whatever that is: ``_held_share`` masks it)."""
+    k, n, wide, tn = HELD_TILES[config][product]
+    for m in (64, 768, 1024):
+        assert kernel.tiles(m, k, n) == (kernel.ROW_BLOCK, wide)
+        assert kernel.tiles(m, k, n, scoped=True) == (kernel.ROW_BLOCK, tn)
+    # a block of weights, of rows and of the output, twice each, inside
+    # the 16 MiB of scoped VMEM an operation gets by default
+    assert 8 * (k * tn + 64 * k + 64 * tn) < 0.75 * (16 << 20)
+    cols = min(n, 2 * tn)
+    sizes = np.asarray([70, 0, 41], np.int32)        # 111 of 192 rows
+    rng = np.random.default_rng(k + n)
+    rows = rng.standard_normal((192, k)).astype(np.float32)
+    weights = rng.standard_normal((3, k, cols)).astype(np.float32) * 0.02
+    weights[1] = np.nan
+    got = np.asarray(kernel.grouped_matmul(
+        jnp.asarray(rows), jnp.asarray(weights), jnp.asarray(sizes),
+        tm=kernel.ROW_BLOCK, tn=tn, scoped=True, interpret=True))
+    assert got.shape == (192, cols)
+    at = np.asarray([0, 63, 64, 69, 70, 110])        # both groups' edges
+    want = np.stack([rows[r].astype(np.float64)
+                     @ weights[0 if r < 70 else 2].astype(np.float64)
+                     for r in at])
+    assert _rel(got[at], want) < 1e-5
+    assert np.isfinite(got[:111]).all()
+
+
+@pytest.mark.parametrize("held_pairs,run", [(0, 64), (1, 64), (63, 64),
+                                            (64, 64), (200, 64), (200, 128),
+                                            (230, 192), (200, 256)])
+def test_held_share_through_the_kernel_is_the_loop(interpreted, monkeypatch,
+                                                   held_pairs, run):
+    """``_held_share`` with both products on the kernel (interpret mode;
+    small tiles stand in for the chip's), a trip's tail of rows in no
+    group included, against a float64 loop over the held experts."""
+    monkeypatch.setattr(kernel, "tiles",
+                        lambda m, k, n, scoped=False: (16, n))
+    rng = np.random.default_rng(held_pairs + run)
+    tokens, hidden, held, width, top_k = 60, 32, 5, 24, 4
+    x = rng.standard_normal((tokens, hidden)).astype(np.float32)
+    w = rng.uniform(size=(tokens, top_k)).astype(np.float32)
+    wgu = rng.standard_normal((held, hidden, 2 * width)).astype(
+        np.float32) * 0.3
+    wd = rng.standard_normal((held, width, hidden)).astype(np.float32) * 0.3
+    local = np.full(tokens * top_k, held, np.int32)
+    at = rng.choice(tokens * top_k, held_pairs, replace=False)
+    local[at] = rng.integers(0, held, held_pairs)
+    local = local.reshape(tokens, top_k)
+    before = {p: stat_get(f"grouped_matmul_lowered_{p}")
+              for p in ("pallas", "ragged_dot")}
+    out = np.asarray(moe._held_share(
+        jnp.asarray(x), jnp.asarray(local), jnp.asarray(w), jnp.asarray(wgu),
+        jnp.asarray(wd), "silu", HIGHEST, experts=20, run=run))
+    assert {p: stat_get(f"grouped_matmul_lowered_{p}") - v
+            for p, v in before.items()} == {"pallas": 2, "ragged_dot": 0}
+    want = np.zeros((tokens, hidden))
+    for t, slot in zip(*np.nonzero(local < held)):
+        gu = x[t].astype(np.float64) @ wgu[local[t, slot]]
+        g = gu[:width] / (1 + np.exp(-gu[:width]))
+        want[t] += w[t, slot] * ((g * gu[width:]) @ wd[local[t, slot]])
+    assert np.isfinite(out).all()
+    assert np.abs(out - want).max() < 1e-5 * max(1.0, np.abs(want).max())
+
+
+def test_held_share_under_a_mesh_stays_on_ragged_dot_and_says_so(as_tpu,
+                                                                 caplog):
+    S = jax.ShapeDtypeStruct
+    before = stat_get("grouped_matmul_lowered_ragged_dot")
+    with caplog.at_level(logging.WARNING, "paddle_tpu.parallel.moe"):
+        jaxpr = jax.make_jaxpr(lambda x, l, w, gu, dn: moe._held_share(
+            x, l, w, gu, dn, "silu", HIGHEST, mesh_devices=4, experts=320))(
+            S((4096, 256), jnp.float32), S((4096, 8), jnp.int32),
+            S((4096, 8), jnp.float32), S((20, 256, 512), jnp.float32),
+            S((20, 256, 256), jnp.float32))
+    prims = _primitives(jaxpr.jaxpr)
+    assert "pallas_call" not in prims and "ragged_dot_general" in prims
+    assert stat_get("grouped_matmul_lowered_ragged_dot") == before + 2
+    assert len([r for r in caplog.records
+                if "4-device mesh" in r.getMessage()]) == 1
+    # ... in the runs ragged_dot's 64-row tile is good for
+    assert _result_rows(jaxpr.jaxpr, "ragged_dot_general") == {moe.RUN_ROWS}
+
+
+# (rows, the router's experts, held, top k) of a decode step, a chunk or a
+# middle rung and the widest rung of each configuration; the run on the
+# kernel, and on ``ragged_dot``
+HELD_RUNS = [
+    ("solar-open2-250b", 64, 320, 20, 8, 64, 192),
+    ("solar-open2-250b", 1024, 320, 20, 8, 768, 192),
+    ("solar-open2-250b", 4096, 320, 20, 8, 1024, 192),
+    ("gigachat35-432b-a28b", 32, 256, 8, 8, 64, 192),
+    ("gigachat35-432b-a28b", 1024, 256, 8, 8, 384, 192),
+    ("gigachat35-432b-a28b", 2048, 256, 8, 8, 768, 192),
+    ("command-a-plus-05-2026", 10, 128, 8, 8, 64, 128),
+    ("command-a-plus-05-2026", 1024, 128, 8, 8, 768, 192),
+    ("the_check_engines_two_slots", 2, 320, 20, 8, 64, 64),
+]
+
+
+@pytest.mark.parametrize("config,rows,experts,held,top_k,on_kernel,on_ragged",
+                         HELD_RUNS)
+def test_held_run_is_read_off_the_shapes(config, rows, experts, held, top_k,
+                                         on_kernel, on_ragged):
+    """One trip for a step, a chunk and every rung but the widest: the
+    expected held pairs and half again in whole row blocks, at most
+    ``KERNEL_RUN_ROWS``; ``ragged_dot``'s runs are the parent's."""
+    pairs = rows * top_k
+    assert moe.held_run(pairs, held, experts, True) == on_kernel
+    assert moe.held_run(pairs, held, experts, False) == on_ragged
+    assert on_kernel % kernel.ROW_BLOCK == 0
+    assert on_kernel >= min(pairs * held / experts, moe.KERNEL_RUN_ROWS)
+
+
+@pytest.mark.parametrize("rows,run", [(64, 64), (4096, 1024)])
+def test_held_share_on_a_tpu_takes_the_kernel_at_the_rules_run(as_tpu, rows,
+                                                               run):
+    """The route and the run inside a traced ``_held_share`` at solar's
+    widths: both products are Mosaic calls over ``held_run`` rows."""
+    S = jax.ShapeDtypeStruct
+    before = stat_get("grouped_matmul_lowered_pallas")
+    jaxpr = jax.make_jaxpr(lambda x, l, w, gu, dn: moe._held_share(
+        x, l, w, gu, dn, "silu", HIGHEST, experts=320))(
+        S((rows, 4096), jnp.float32), S((rows, 8), jnp.int32),
+        S((rows, 8), jnp.float32), S((20, 4096, 2560), jnp.float32),
+        S((20, 1280, 4096), jnp.float32))
+    prims = _primitives(jaxpr.jaxpr)
+    assert prims.count("pallas_call") == 2
+    assert "ragged_dot_general" not in prims
+    assert stat_get("grouped_matmul_lowered_pallas") == before + 2
+    assert _result_rows(jaxpr.jaxpr, "pallas_call") == {run}

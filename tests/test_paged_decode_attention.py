@@ -337,14 +337,20 @@ def test_kernel_compiles_for_a_described_v5e(chip, slots, max_seq):
     assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20
 
 
-@pytest.mark.parametrize("m,groups,k,n", [
-    (49152, 64, 2560, 1536),     # smallthinker-21b-a3b, rung 8192, gate + up
-    (192, 64, 768, 2560),        # ... a decode step's rows, down
-    (16384, 64, 2048, 3072),     # lfm2-24b-a2b, rung 4096: the widest block
-    (1536, 128, 768, 2048),      # sdar-30b-a3b-chat, a block pass, down
+@pytest.mark.parametrize("m,groups,k,n,scoped", [
+    (49152, 64, 2560, 1536, False),  # smallthinker-21b-a3b, rung 8192, gate + up
+    (192, 64, 768, 2560, False),     # ... a decode step's rows, down
+    (16384, 64, 2048, 3072, False),  # lfm2-24b-a2b, rung 4096: the widest block
+    (1536, 128, 768, 2048, False),   # sdar-30b-a3b-chat, a block pass, down
+    # the held share's runs (PR 52), blocks inside the default scoped
+    # VMEM: solar's widest rung, gate + up; giga's (32 and 14 column
+    # blocks a product); cmda's chunk and its step
+    (1024, 20, 4096, 2560, True), (768, 8, 7168, 4096, True),
+    (768, 8, 2048, 7168, True), (768, 8, 4096, 8192, True),
+    (64, 8, 4096, 4096, True),
 ])
 def test_grouped_matmul_kernel_compiles_for_a_described_v5e(chip, m, groups,
-                                                            k, n):
+                                                            k, n, scoped):
     """The experts' products at published widths, at the blocks
     ``grouped_matmul.tiles`` gives them: Mosaic takes the kernel (a block
     of weights twice in VMEM, "highest" products), and nothing is padded
@@ -360,9 +366,10 @@ def test_grouped_matmul_kernel_compiles_for_a_described_v5e(chip, m, groups,
     def spec(shape, dtype=jnp.float32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    tm, tn = kernel.tiles(m, k, n)
+    tm, tn = kernel.tiles(m, k, n, scoped)
     compiled = jax.jit(
-        lambda r, w, s: kernel.grouped_matmul(r, w, s, tm=tm, tn=tn)).lower(
+        lambda r, w, s: kernel.grouped_matmul(
+            r, w, s, tm=tm, tn=tn, scoped=scoped)).lower(
         spec((m, k)), spec((groups, k, n)),
         spec((groups,), jnp.int32)).compile()
     text = compiled.as_text()

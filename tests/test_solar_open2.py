@@ -227,6 +227,53 @@ def test_pairs_of_absent_experts_and_pad_rows_reach_no_matmul(n):
         assert int(np.asarray(counts)[first:first + held].sum()) > RUN_ROWS
 
 
+@pytest.mark.parametrize("run", [64, 192, 256])
+@pytest.mark.parametrize("pairs", ["none", "one", "a_run_less_one", "a_run",
+                                   "three_runs_and_a_tail"])
+def test_held_share_is_the_loop_over_the_held_experts(pairs, run):
+    """``_held_share`` on exactly so many held pairs, in runs of ``run``
+    sorted pairs, against a float64 loop over the held experts: nothing
+    for none, one trip up to a whole run, several trips and a tail, with
+    the rows behind the prompt's end (NaN) in no pair.  A run of 64 is
+    added to ``out`` at once, one of 256 in two pieces of ``SCATTER_ROWS``,
+    one of 192 in two of which the second starts early."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.parallel.moe import _held_share
+
+    count = {"none": 0, "one": 1, "a_run_less_one": run - 1, "a_run": run,
+             "three_runs_and_a_tail": 3 * run + 5}[pairs]
+    rng = np.random.default_rng(count + run)
+    n, real, held, top_k = 150, 141, 6, TOP_K
+    assert count <= real * top_k
+    x = rng.normal(size=(n, HID)).astype("float32")
+    x[real:] = np.nan
+    w = rng.uniform(size=(n, top_k)).astype("float32")
+    gate_up = rng.normal(size=(held, HID, 2 * WIDTH)).astype("float32") \
+        * HID ** -0.5
+    down = rng.normal(size=(held, WIDTH, HID)).astype("float32") \
+        * WIDTH ** -0.5
+    local = np.full(n * top_k, held, "int32")
+    at = rng.choice(real * top_k, count, replace=False)
+    local[at] = rng.integers(0, held, count)
+    local = local.reshape(n, top_k)
+    out = np.asarray(jax.jit(lambda *a: _held_share(
+        *a, "silu", jax.lax.Precision.HIGHEST, run=run))(
+            jnp.asarray(x), jnp.asarray(local), jnp.asarray(w),
+            jnp.asarray(gate_up), jnp.asarray(down)))
+    want = np.zeros((n, HID))
+    for t, slot in zip(*np.nonzero(local < held)):
+        e = local[t, slot]
+        h = x[t].astype("float64") @ gate_up[e]
+        a = h[:WIDTH] / (1 + np.exp(-h[:WIDTH])) * h[WIDTH:]
+        want[t] += w[t, slot] * (a @ down[e])
+    assert out.shape == (n, HID) and np.isfinite(out).all()
+    assert np.abs(out - want).max() < 1e-5 * max(1.0, np.abs(want).max())
+    # a row with no held pair, and every row behind the end, adds nothing
+    assert not out[(local == held).all(-1)].any()
+
+
 def test_a_share_outside_the_routers_experts_is_refused():
     x = layers.data("x", [1, 4, 8], append_batch_size=False)
     with pytest.raises(ValueError, match="holds experts"):

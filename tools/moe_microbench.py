@@ -2,6 +2,7 @@
 """Which formulation of the grouped expert matmul, on the chip.
 
     chiprun -- python tools/moe_microbench.py [--only NAME] [--tiles TM,TN;...]
+    chiprun -- python tools/moe_microbench.py --held 1 [--only NAME] [--runs R,...]
 
 Times the two grouped matmuls of one expert FFN (gate + up, then down;
 float32 "highest") over rows sorted by expert, at the shapes the
@@ -24,6 +25,17 @@ mean):
   the shape, or the one ``ragged_dot`` call;
 * ``kernel`` -- the kernel at every TM,TN pair of ``--tiles`` (the sweep
   those blocks were chosen from).
+
+``--held 1`` times instead the whole held share of a layer
+(``parallel/moe.py`` ``_held_share``: the sort, and a trip's gather, two
+products, gate and scatter-add) at the three configurations that serve one
+chip's share of an expert-parallel group (``HELD``), the held pairs drawn
+at the cell's share of all pairs and its routing's skew over the router's
+whole width: ``parent`` (PR 43's loop: runs of 192 through
+``jax.lax.ragged_dot``, kept here as ``parent_held_share``), the loop with
+both products on the route of ``grouped_matmul`` at each run length of
+``--runs``, and ``rule``, the run ``held_run`` gives the shape.  Writes
+``chiprun_out/moe_held_sweep.json``.
 
 Writes ``chiprun_out/moe_formulation_sweep.json`` and prints one line per
 formulation: milliseconds for the pair of matmuls, against the six-pass
@@ -54,6 +66,18 @@ SHAPES = {
     "sdar-30b-a3b-chat": (128, 2048, 768, (8, 16, 32, 64), (1536,)),
 }
 SKEW = 2.5                      # moe_expert_load_max_over_mean.pool
+# name: (router's experts, held, top k, K, width I, gate, the cell's
+# moe_pairs_held_pct.* and moe_expert_load_max_over_mean.pool (ledger, PR
+# 51), the decode step's slots, the prefill rungs' or the chunk's rows)
+HELD = {
+    "solar-open2-250b": (320, 20, 8, 4096, 1280, 6.24, 5.3, 64,
+                         (256, 512, 1024, 2048, 4096)),
+    "gigachat35-432b-a28b": (256, 8, 8, 7168, 2048, 2.97, 5.9, 32,
+                             (256, 512, 1024, 2048)),
+    "command-a-plus-05-2026": (128, 8, 8, 4096, 4096, 6.03, 5.5, 10,
+                               (1024,)),
+}
+HELD_RUNS = (64, 128, 192, 512, 1024, 2048, 4096)
 PEAK, HBM = 197e12, 819e9
 RUN_ROWS, WIDE_TILE = 192, 512  # the parent's runs (PR 32)
 
@@ -75,13 +99,19 @@ def group_sizes(rng, m, groups, skew):
         sizes = np.full(groups, m // groups)
         sizes[:m - sizes.sum()] += 1
         return sizes.astype(np.int32)
+    return rng.multinomial(m, skewed_loads(rng, groups, skew)).astype(
+        np.int32)
+
+
+def skewed_loads(rng, groups, skew):
+    """Shares of ``groups`` whose largest is ``skew`` times their mean."""
     z = rng.standard_normal(groups)
     lo, hi = 0.0, 4.0
     for _ in range(50):
         s = (lo + hi) / 2
         p = np.exp(s * z)
         lo, hi = (s, hi) if p.max() / p.mean() < skew else (lo, s)
-    return rng.multinomial(m, p / p.sum()).astype(np.int32)
+    return p / p.sum()
 
 
 def parent_runs(rows, weights, sizes, precision, run=RUN_ROWS):
@@ -104,6 +134,157 @@ def parent_runs(rows, weights, sizes, precision, run=RUN_ROWS):
     return out.reshape(n * run, -1)[:m]
 
 
+def parent_held_share(x, local, weights, w_gate_up, w_down, precision):
+    """``_held_share`` as PR 43 to PR 51 had it (SiLU, no clamp): runs of
+    192 sorted pairs, each trip two ``jax.lax.ragged_dot`` calls."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.parallel.moe import ROW_TILE, _gated, _one_call
+
+    N, H = x.shape
+    top_k = local.shape[1]
+    held, inter = w_gate_up.shape[0], w_down.shape[1]
+    flat = local.reshape(-1)
+    order = jnp.argsort(flat, stable=True)
+    sizes = jnp.bincount(flat, length=held + 1)[:held].astype(jnp.int32)
+    ends = jnp.cumsum(sizes)
+    starts, n_held = ends - sizes, ends[-1]
+    run = min(RUN_ROWS, -(-N * top_k // ROW_TILE) * ROW_TILE)
+    order = jnp.pad(order, (0, -order.shape[0] % run))
+    pair_w = weights.reshape(-1)
+
+    def body(i, out):
+        lo = i * run
+        pairs = jax.lax.dynamic_slice_in_dim(order, lo, run)
+        real = lo + jnp.arange(run) < n_held
+        tok = pairs // top_k
+        rows = jnp.take(x, tok, axis=0)
+        size = jnp.clip(jnp.minimum(ends, lo + run) - jnp.maximum(starts, lo),
+                        0, None).astype(jnp.int32)
+        h = _one_call(rows, w_gate_up, size, precision)
+        y = _one_call(_gated(h, inter, "silu"), w_down, size, precision)
+        y = y * jnp.take(pair_w, pairs)[:, None].astype(y.dtype)
+        return out.at[tok].add(jnp.where(real[:, None], y, 0))
+
+    return jax.lax.fori_loop(0, -(-n_held // run), body,
+                             jnp.zeros((N, H), x.dtype))
+
+
+def held_pairs(rng, n, experts, held, top_k, pct, skew):
+    """``local`` [n, top_k] as ``moe_routed_tokens`` hands it to
+    ``_held_share``: each row draws ``top_k`` distinct experts of the
+    router's ``experts`` from loads whose largest is ``skew`` times their
+    mean, and the ``held`` consecutive experts whose load is nearest
+    ``pct`` percent of all are the ones held (index ``held``: absent)."""
+    p = skewed_loads(rng, experts, skew)
+    chosen = np.argsort(-(np.log(p) + rng.gumbel(size=(n, experts))),
+                        axis=-1)[:, :top_k]
+    load = np.bincount(chosen.reshape(-1), minlength=experts) / chosen.size
+    window = np.convolve(load, np.ones(held), "valid")
+    first = int(np.abs(window - pct / 100).argmin())
+    local = np.where((chosen >= first) & (chosen < first + held),
+                     chosen - first, held)
+    return local.astype(np.int32)
+
+
+def held_main(args) -> int:
+    """The ``--held 1`` table (the module docstring)."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.parallel import moe
+
+    highest = jax.lax.Precision.HIGHEST
+    runs = tuple(int(r) for r in args.runs.split(",") if r) or HELD_RUNS
+    results = []
+    for name, (E, held, top_k, K, I, pct, skew, slots, rungs) in HELD.items():
+        if args.only and name != args.only:
+            continue
+        key = jax.random.key(E + K)
+        gu = jax.random.normal(jax.random.fold_in(key, 1),
+                               (held, K, 2 * I)) * .02
+        dn = jax.random.normal(jax.random.fold_in(key, 2),
+                               (held, I, K)) * .02
+        gu64 = dn64 = None
+        for n, label in [(slots, "step")] + [(r, f"rung {r}") for r in rungs]:
+            rng = np.random.default_rng(n + E)
+            local_np = held_pairs(rng, n, E, held, top_k, pct, skew)
+            local = jnp.asarray(local_np)
+            x = jax.random.normal(jax.random.fold_in(key, n), (n, K))
+            w = jax.random.uniform(jax.random.fold_in(key, n + 1),
+                                   (n, top_k)) / top_k
+            sizes = np.bincount(local_np.reshape(-1),
+                                minlength=held + 1)[:held]
+            n_held, touched = int(sizes.sum()), int((sizes > 0).sum())
+            pairs = n * top_k
+            rule = moe.held_run(pairs, held, E, True)
+            whole = -(-pairs // 64) * 64
+            cases = [("parent", None)] + [
+                (f"run {r}", r) for r in runs if r <= whole] + [
+                (f"rule: run {rule}", rule)]
+            # float64 loop on the host over up to 8 rows that hold a pair
+            at = np.unique(np.nonzero((local_np < held).any(-1))[0][
+                :: max(1, n_held // 8)])[:8]
+            if gu64 is None:
+                gu64, dn64 = (np.asarray(a, np.float64) for a in (gu, dn))
+            x64, w64 = np.asarray(x, np.float64), np.asarray(w, np.float64)
+            want = np.zeros((len(at), K))
+            for j, t in enumerate(at):
+                for slot in np.nonzero(local_np[t] < held)[0]:
+                    g = local_np[t, slot]
+                    h = x64[t] @ gu64[g]
+                    a = h[:I] / (1 + np.exp(-h[:I])) * h[I:]
+                    want[j] += w64[t, slot] * (a @ dn64[g])
+            flops = 2.0 * n_held * 3 * K * I
+            floor_mxu = 6 * flops / PEAK * 1e3
+            floor_hbm = touched * 3 * K * I * 4 / HBM * 1e3
+            print(f"{name} {label} ({n} rows, {pairs} pairs, {n_held} held "
+                  f"= {100 * n_held / pairs:.2f}%, {touched} of {held} "
+                  f"touched, largest group {sizes.max()}): six-pass MXU "
+                  f"floor {floor_mxu:.2f} ms, weights' bytes "
+                  f"{floor_hbm:.2f} ms", flush=True)
+            for case, run in cases:
+                rec = {"shape": name, "rows": n, "label": label,
+                       "pairs_held": n_held, "formulation": case}
+                if run is None:
+                    f = jax.jit(lambda x, l, w, gu, dn: parent_held_share(
+                        x, l, w, gu, dn, highest))
+                else:
+                    f = jax.jit(lambda x, l, w, gu, dn, run=run:
+                                moe._held_share(x, l, w, gu, dn, "silu",
+                                                highest, experts=E, run=run))
+                try:
+                    ms, out = timed(f, x, local, w, gu, dn)
+                    peak = f.lower(x, local, w, gu, dn).compile() \
+                        .memory_analysis().temp_size_in_bytes
+                except Exception as e:  # noqa: BLE001 — as in main
+                    print(f"    {case:20s} refused: {str(e)[:200]}",
+                          flush=True)
+                    results.append(dict(rec, refused=str(e)[:400]))
+                    continue
+                err = float(np.abs(np.asarray(out[at]) - want).max()
+                            / np.abs(want).max()) if len(at) else 0.0
+                print(f"    {case:20s} {ms:9.3f} ms  x"
+                      f"{ms / max(floor_mxu, floor_hbm, 1e-9):.2f} the larger "
+                      f"floor, temporaries {peak / 2**20:.0f} MiB, off the "
+                      f"float64 loop by {err:.3g}", flush=True)
+                results.append(dict(
+                    rec, ms=ms, run=run, experts_touched=touched,
+                    largest_group=int(sizes.max()), mxu_floor_ms=floor_mxu,
+                    bytes_floor_ms=floor_hbm, temp_bytes=int(peak),
+                    rel_err=err))
+        del gu, dn
+    os.makedirs("chiprun_out", exist_ok=True)
+    out = "chiprun_out/moe_held_sweep.json"
+    if args.only:
+        out = out.replace(".json", f"_{args.only}.json")
+    with open(out, "w") as f:
+        json.dump({"device": jax.devices()[0].device_kind,
+                   "results": results}, f, indent=1)
+    return 0
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None, help="one name of SHAPES")
@@ -111,6 +292,10 @@ def main(argv=None) -> int:
                     help="kernel lines at these TM,TN pairs too (a sweep)")
     ap.add_argument("--even", type=int, default=1,
                     help="0: the skewed groups alone")
+    ap.add_argument("--held", type=int, default=0,
+                    help="1: the held share's loop at HELD's shapes")
+    ap.add_argument("--runs", default="",
+                    help="with --held: the run lengths (HELD_RUNS)")
     args = ap.parse_args(argv)
     import jax
     import jax.numpy as jnp
@@ -119,6 +304,8 @@ def main(argv=None) -> int:
         print("moe_microbench: no TPU backend, nothing measured",
               file=sys.stderr)
         return 2
+    if args.held:
+        return held_main(args)
     from jax.experimental.pallas.ops.tpu.megablox import gmm
 
     from paddle_tpu.ops.pallas import grouped_matmul as kernel
