@@ -95,6 +95,28 @@ def check(cond, what):
         raise AssertionError(f"chip_smoke: {what}")
 
 
+def say_startup_account():
+    """Where this process's start-up went so far, by part and by program
+    (``telemetry.startup_account()``), beside the cache's counters."""
+    from paddle_tpu import telemetry
+    from paddle_tpu.monitor import stat_get
+
+    account = telemetry.startup_account()
+    programs = account.pop("programs")
+    asked = sum("cache_hit" in s.attrs
+                for s in telemetry.get_spans(kept=True))
+    say("start-up account (self seconds x spans): " + ", ".join(
+        f"{part} {p['s']:.2f} x {p['n']}" for part, p in sorted(
+            account.items(), key=lambda kv: -kv[1]["s"]))
+        + f"; compile_cache_hits {stat_get('compile_cache_hits')}, "
+        f"compile_cache_misses {stat_get('compile_cache_misses')}, "
+        f"compile/backend spans that asked the cache {asked}")
+    for *program, trace_s, lower_s, backend_s, hit in programs[:5]:
+        say(f"  {telemetry.program_label(*program)}: trace {trace_s:.2f} "
+            f"s, lower {lower_s:.2f} s, backend {backend_s:.2f} s, "
+            f"cache_hit {hit}")
+
+
 def attention_paths():
     from paddle_tpu.monitor import stat_get
 
@@ -1371,6 +1393,7 @@ def main():
     t0 = time.perf_counter()
     serve = serve_phase()
     say(f"serve phase done [{time.perf_counter() - t0:.1f} s]")
+    say_startup_account()
     gc.collect()
 
     t0 = time.perf_counter()

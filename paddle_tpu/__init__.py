@@ -8,11 +8,23 @@ Architecture (vs. the reference, see SURVEY.md):
     not NCCL ops.
   * The imperative mode shares the same op lowerings via an eager tracer.
 """
+# importing the package is the first part of the start-up account
+# (``startup/import``, recorded on the last line; telemetry.py)
+import sys as _sys
+import time as _time
+_import_t0 = _time.monotonic()
+
 # the lock-order sanitizer must patch threading BEFORE any module
 # constructs its locks, so this hook runs first (no-op unless
 # FLAGS_debug_lock_order is set in the environment)
 from . import locksan as _locksan  # noqa: E402
 _locksan.install_from_flag()
+
+# the operator library imports jax; import it here, where it can be timed
+# apart from the package's own modules (0 when it was imported before)
+_jax_t0 = None if "jax" in _sys.modules else _time.monotonic()
+import jax as _jax  # noqa: E402,F401
+_jax_ms = 0.0 if _jax_t0 is None else (_time.monotonic() - _jax_t0) * 1e3
 
 from . import ops  # registers the operator library
 from .framework.core import (Program, Variable, Parameter, OpRole,  # noqa
@@ -105,3 +117,8 @@ def device_count() -> int:
 
 # fluid-compat namespace: `import paddle_tpu.fluid as fluid`
 from . import fluid  # noqa  (must come after the symbols above exist)
+
+telemetry.span_record(
+    "startup/import", _import_t0, _time.monotonic(),
+    jax_ms=round(_jax_ms, 3),
+    modules=sum(m.startswith("paddle_tpu.") for m in list(_sys.modules)))

@@ -21,9 +21,15 @@ derived from program.random_seed (replaces cuRAND generator state).
 Telemetry (paddle_tpu/telemetry.py; all opt-out via ``FLAGS_telemetry=0``):
 every compiled run opens an ``executor/step`` span whose children name
 the run's host phases in order: ``executor/prepare`` (feed conversion,
-signature, cache lookup), ``executor/compile`` (jit build, on a miss),
-``executor/gather_state`` (scope reads), ``executor/stage_feed`` (H2D
-staging), ``executor/dispatch`` (the compiled call),
+signature, cache lookup), ``startup/step_build`` (block analysis,
+``lower_block``'s closure and ``jax.jit``, on a miss; a part of the
+start-up account, ``telemetry.py``; until PR 53 a first span named
+``executor/compile``), ``executor/gather_state`` (scope reads),
+``executor/stage_feed`` (H2D staging), ``executor/compile`` (the entry's
+one ahead-of-time ``lower().compile()``, at its first dispatch; jax's
+trace, lowering and backend compile of the program fall under it as
+``compile/trace`` / ``lower`` / ``backend`` spans that carry its
+``program``), ``executor/dispatch`` (the compiled call),
 ``executor/commit_state`` (scope writes, efficiency gauges) and
 ``executor/fetch`` (blocking host reads); the host
 wall time per run feeds the ``executor_step_host_ms`` histogram and the
@@ -471,9 +477,9 @@ class Executor:
         if entry is None:
             _JIT_STAT.increase()
             ensure_compile_cache()
-            with _telemetry.trace_span("executor/compile",
-                                       program=program._uid,
-                                       fetches=len(fetch_names)):
+            with _telemetry.startup_span("startup/step_build",
+                                         program=program._uid,
+                                         fetches=len(fetch_names)):
                 entry = self._build(program, block, list(feed_arrays),
                                     fetch_names, guard_loss)
             if use_program_cache:
@@ -509,7 +515,7 @@ class Executor:
         call = entry.compiled
         if call is None and not entry.aot_failed:
             call = self._aot_compile(entry, sig, feed_vals, mut_vals,
-                                     const_vals, step)
+                                     const_vals, step, program._uid)
         if call is None:
             call = fn
         bench = flag_value("FLAGS_benchmark")
@@ -573,13 +579,16 @@ class Executor:
                                     resolve_guard=True), examples
 
     def _aot_compile(self, entry: "_CacheEntry", sig, feed_vals,
-                     mut_vals, const_vals, step):
+                     mut_vals, const_vals, step, program_uid=None):
         """Lower + compile the entry's step function at the concrete
         argument set and capture its executable manifest.  On any
         failure the entry latches ``aot_failed`` and the caller uses
         the plain jit path — observability must never break a step."""
         try:
+            # (jax's three compile events of this program become
+            # ``compile/*`` spans under this one, and copy ``program``)
             with _telemetry.trace_span("executor/compile",
+                                       program=program_uid,
                                        step=int(step), aot=True):
                 entry.compiled, entry.manifest = _costmodel.aot_compile(
                     entry.fn, feed_vals, mut_vals, const_vals, step,

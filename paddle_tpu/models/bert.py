@@ -239,13 +239,16 @@ def build_bert_train_programs(cfg, *, learning_rate=None):
     ``bf16_stream`` is always True.  ``learning_rate=None`` is the
     recipe's 10 000-step linear warm-up to 1e-4; ``chip_smoke.py`` passes
     a constant, because ten steps into that warm-up nothing moves."""
-    from .. import clip, optimizer
+    from .. import clip, optimizer, telemetry
     from ..contrib import mixed_precision
     from ..framework.core import Program, program_guard
 
     main_p, startup = Program(), Program()
     startup._is_startup = True
-    with program_guard(main_p, startup):
+    # (forward, backward and optimizer: a part of the start-up account)
+    with telemetry.startup_span("startup/program_build", kind="bert_train",
+                                seq=cfg.get("seq_len")), \
+            program_guard(main_p, startup):
         feed_names, outs = build_bert_pretrain(**cfg)
         lr = learning_rate
         if lr is None:

@@ -35,7 +35,27 @@ forward).
 """
 from __future__ import annotations
 
-from .. import layers
+import functools
+
+from .. import layers, telemetry
+
+
+def _program_build(kind, bucket_at=None):
+    """A program builder whose Python construction (ops appended, shapes
+    inferred) is a ``startup/program_build`` span of the start-up account
+    (``telemetry.py``): ``kind``, and as ``bucket`` the length the program
+    is built for, its positional argument ``bucket_at`` (a prefill rung,
+    a chunk's rows)."""
+    def wrap(build):
+        @functools.wraps(build)
+        def timed(*args, **kwargs):
+            attrs = {"kind": kind}
+            if bucket_at is not None and bucket_at < len(args):
+                attrs["bucket"] = args[bucket_at]
+            with telemetry.startup_span("startup/program_build", **attrs):
+                return build(*args, **kwargs)
+        return timed
+    return wrap
 
 # One layer of the per-layer pattern.  A model's ``layer_pattern`` is a
 # list of such dicts (keys left out take these values) that tiles over
@@ -893,6 +913,7 @@ def build_llama_forward(batch_size, seq_len, vocab_size=32000,
     return ["input_ids"], {"logits": logits}
 
 
+@_program_build("prefill", bucket_at=1)
 def build_llama_prefill(batch_size, seq_len, vocab_size=32000,
                         hidden=4096, num_layers=32, num_heads=32,
                         num_kv_heads=None, intermediate=11008,
@@ -1101,6 +1122,7 @@ def _prefill_fetches(logits, kvs, taps, last_pos):
     return fetches
 
 
+@_program_build("decode")
 def build_llama_decode(num_slots, max_seq_len, vocab_size=32000,
                        hidden=4096, num_layers=32, num_heads=32,
                        num_kv_heads=None, intermediate=11008,
@@ -1344,6 +1366,7 @@ def _chunk_forward(chunk_len, max_seq_len, num_pages, page_tokens,
     return feeds, x, cache_names, taps
 
 
+@_program_build("chunk", bucket_at=0)
 def build_llama_prefill_chunk(chunk_len, max_seq_len, num_pages,
                               page_tokens, vocab_size=32000,
                               hidden=4096, num_layers=32, num_heads=32,
@@ -1387,6 +1410,7 @@ def build_llama_prefill_chunk(chunk_len, max_seq_len, num_pages,
         _prefill_fetches(logits, [], taps, last_off), cache_names
 
 
+@_program_build("verify", bucket_at=0)
 def build_llama_verify(chunk_len, max_seq_len, num_pages, page_tokens,
                        vocab_size=32000, hidden=4096, num_layers=32,
                        num_heads=32, num_kv_heads=None,

@@ -25,6 +25,7 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from .. import telemetry as _telemetry
 from ..compile_cache import ensure_compile_cache
 from ..framework.core import Block, Program, Variable
 from ..framework.executor import analyze_block, lower_block
@@ -170,11 +171,25 @@ def build_sharded_step(program: Program, feed_names: Sequence[str],
     straight back in; ``extra_vals`` aligns with ``extra_out`` (persistable
     vars written but never read, e.g. fetch-only state). Feed arrays are
     sharded on dim 0 over `batch_axes`; state arrays are placed by `rules`.
+
+    The build is a ``startup/step_build`` span of the start-up account
+    (``telemetry.py``), like the executor's.
     """
+    ensure_compile_cache()
+    with _telemetry.startup_span(
+            "startup/step_build", program=program._uid,
+            fetches=len(fetch_names),
+            mesh="x".join(str(n) for n in mesh.devices.shape)):
+        return _build_sharded_step(program, feed_names, fetch_names, mesh,
+                                   rules, batch_axes, donate_state,
+                                   feed_pspecs)
+
+
+def _build_sharded_step(program, feed_names, fetch_names, mesh, rules,
+                        batch_axes, donate_state, feed_pspecs):
     import jax
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    ensure_compile_cache()
     rules = rules or data_parallel_rules()
     block = program.global_block()
     state_in, state_out = analyze_block(block, feed_names)

@@ -445,7 +445,8 @@ class ServingEngine:
                 sigs.append({n: (b,) + tuple(s)
                              for n, s in shapes.items()})
         compiled = 0
-        with telemetry.trace_span("serving/warmup", buckets=len(sigs)):
+        # (a part of the start-up account; until PR 53 ``serving/warmup``)
+        with telemetry.startup_span("startup/warmup", programs=len(sigs)):
             for p in dict.fromkeys(self._pool):  # unique when shared
                 compiled += p.warmup(sigs)
         self._warmed = True

@@ -188,6 +188,7 @@ sequences or the prefix index), ``serving_kv_pages_free``,
 from __future__ import annotations
 
 import collections
+import contextlib
 import logging
 import os
 import threading
@@ -962,6 +963,7 @@ class GenerationEngine:
             num_window_pages=self.num_window_pages or None)
         self.state_names = [e["name"] for e in self._cache_spec
                             if e["kind"] == "slot_state"]
+        self._warm = False        # no :meth:`warmup` has finished yet
         self._build_decode(scope_ready=scope is not None)
         if mesh is not None:
             self._place_on_mesh(shard_rules)
@@ -1102,6 +1104,15 @@ class GenerationEngine:
         return NamedSharding(self.mesh, P()), None
 
     def _init_caches(self):
+        """The page pools and the slot state, allocated and zero filled
+        on the device: ``startup/pool_alloc`` of the start-up account."""
+        with telemetry.startup_span("startup/pool_alloc",
+                                    pools=len(self._cache_spec)) as span:
+            self._alloc_caches()
+            span.attrs["bytes"] = self.kv_cache_bytes \
+                + self.slot_state_bytes
+
+    def _alloc_caches(self):
         import jax
         import jax.numpy as jnp
 
@@ -1337,6 +1348,25 @@ class GenerationEngine:
         Warmup dispatches run with all-zero block tables and zero
         valid lengths, so every write lands on the trash page (and a
         prefill's slot state on the trash row)."""
+        if self._warm:
+            # every program is warm: nothing of start-up is left to time
+            return self._warm_programs()
+        with telemetry.startup_span("startup/warmup") as span:
+            compiled = span.attrs["programs"] = self._warm_programs()
+        self._warm = True
+        return compiled
+
+    def _warming(self, kind: str, bucket=None):
+        """One program of :meth:`warmup`, from its Python construction to
+        its first run: a ``startup/warm_program`` span whose self time is
+        that first run and its fetch.  What the program compiles under it
+        copies ``kind`` and ``bucket`` (``compile_cache.py``)."""
+        if self._warm:
+            return contextlib.nullcontext()
+        return telemetry.startup_span("startup/warm_program", kind=kind,
+                                      bucket=bucket)
+
+    def _warm_programs(self) -> int:
         compiled = 0
         first = None    # the last prefill's first token, on the device
         np_slot = self.pages_per_slot
@@ -1345,26 +1375,15 @@ class GenerationEngine:
             # (plus the verify program when speculating) is all it runs
             compiled = 0
             if self.speculate:
-                for b in self._verify_buckets():
-                    if b not in self._verify_progs:
-                        prog, fetches = self._verify_prog_for(b)
-                        self._prefill_exe.run(
-                            prog,
-                            feed={"chunk_ids": np.zeros((1, b),
-                                                        "int64"),
-                                  "base": np.zeros((1,), "int32"),
-                                  "block_table": np.zeros(
-                                      (1, np_slot), "int32"),
-                                  "chunk_len": np.zeros((1,),
-                                                        "int32")},
-                            fetch_list=[fetches["tokens"]],
-                            scope=self.scope, return_numpy=False)
-                        compiled += 1
-            self._warm_decode()
+                compiled += self._warm_verify()
+            with self._warming("decode"):
+                self._warm_decode()
             return compiled + 1
         if self.prefill_chunk <= 0:
             for b in self.prefill_buckets:
-                if b not in self._prefill_progs:
+                if b in self._prefill_progs:
+                    continue
+                with self._warming("prefill", b):
                     prog, fetches = self._prefill_prog_for(b)
                     feed = {"input_ids": np.zeros((1, b), "int64"),
                             "block_table": np.zeros((1, np_slot), "int32"),
@@ -1379,10 +1398,12 @@ class GenerationEngine:
                     first = self._run_fetching(
                         self._prefill_exe, prog, fetches,
                         feed).get("next_token")
-                    compiled += 1
+                compiled += 1
         if self.prefill_chunk > 0 or self.prefix_reuse:
             for b in self._chunk_buckets():
-                if b not in self._chunk_progs:
+                if b in self._chunk_progs:
+                    continue
+                with self._warming("chunk", b):
                     prog, fetches = self._chunk_prog_for(b)
                     # (the fetches every run takes: another list would
                     # be another compilation, inside the window)
@@ -1390,26 +1411,35 @@ class GenerationEngine:
                         self._prefill_exe, prog, fetches, self._chunk_feed(
                             np.zeros((b,), "int64"), 0, 0,
                             None))["next_token"]
-                    compiled += 1
+                compiled += 1
         if self.role == "prefill":
             # a prefill-role engine never runs the decode grid
             return compiled
         if self.speculate:
-            for b in self._verify_buckets():
-                if b not in self._verify_progs:
-                    prog, fetches = self._verify_prog_for(b)
-                    self._prefill_exe.run(
-                        prog,
-                        feed={"chunk_ids": np.zeros((1, b), "int64"),
-                              "base": np.zeros((1,), "int32"),
-                              "block_table": np.zeros((1, np_slot),
-                                                      "int32"),
-                              "chunk_len": np.zeros((1,), "int32")},
-                        fetch_list=[fetches["tokens"]],
-                        scope=self.scope, return_numpy=False)
-                    compiled += 1
-        self._warm_decode(first.value if first is not None else None)
+            compiled += self._warm_verify()
+        with self._warming("decode"):
+            self._warm_decode(first.value if first is not None else None)
         return compiled + 1
+
+    def _warm_verify(self) -> int:
+        """Run every verify rung not yet built, once; how many."""
+        compiled = 0
+        for b in self._verify_buckets():
+            if b in self._verify_progs:
+                continue
+            with self._warming("verify", b):
+                prog, fetches = self._verify_prog_for(b)
+                self._prefill_exe.run(
+                    prog,
+                    feed={"chunk_ids": np.zeros((1, b), "int64"),
+                          "base": np.zeros((1,), "int32"),
+                          "block_table": np.zeros(
+                              (1, self.pages_per_slot), "int32"),
+                          "chunk_len": np.zeros((1,), "int32")},
+                    fetch_list=[fetches["tokens"]],
+                    scope=self.scope, return_numpy=False)
+            compiled += 1
+        return compiled
 
     def _warm_decode(self, first=None):
         """Three grid steps over idle rows: the decode program, then the
@@ -3904,6 +3934,9 @@ class GenerationEngine:
             # the process's one writer of token streams (every engine
             # of the process reads the same figures)
             "stream_writer": stream_writer.stats(),
+            # where this process's start-up went, by part and by program
+            # (the process's, like the writer's: ``telemetry.py``)
+            "startup": telemetry.startup_account(),
             "tokens_per_request": round(
                 n["generated_tokens"] / max(n["served"], 1), 2),
             "generate_ms": self._h_gen.summary(),

@@ -21,6 +21,19 @@ was step N slow" and "is the job alive" without print statements:
   on one thread, ended on another); ``links=[ctx, ...]`` records
   fan-in/fan-out references to other traces (a serving batch links the
   N request traces it carries).
+* **The start-up account** — what a process does before it serves or
+  trains, split into parts that are spans of two families,
+  ``startup/`` (:func:`startup_span`: ``import``, ``backend_init``,
+  ``program_build``, ``step_build``, ``pool_alloc``, ``warmup``,
+  ``warm_program``) and ``compile/`` (:func:`span_record`, made after
+  the fact from jax's compile events by ``compile_cache.py``:
+  ``trace``, ``lower``, ``backend``), each opened where the work
+  happens.  A span of either family carries its self time
+  (``self_ms``), feeds the counter ``startup_<part>_us`` /
+  ``compile_<part>_us`` and is kept past any window in a ring of its
+  own (``get_spans(kept=True)``); :func:`startup_account` sums them by
+  part and by program.  The comment above :data:`KEPT_FAMILIES` has the
+  rules; the README's "Observability" the table of spans.
 * **Typed metrics** — :class:`Gauge`, :class:`Timer`, and fixed-bucket
   :class:`Histogram` (p50/p95/p99 summaries) in a
   :class:`MetricsRegistry` alongside the monitor's counters.
@@ -51,6 +64,7 @@ import json
 import logging
 import math
 import os
+import re
 import sys
 import threading
 import time
@@ -63,7 +77,9 @@ from .monitor import monitor as _monitor
 from .monitor import process_start_time, stat_add
 
 __all__ = ["SpanContext", "new_trace_id", "trace_span", "span_begin",
-           "span_end", "current_span", "get_spans", "clear_spans",
+           "span_end", "span_record", "startup_span", "startup_account",
+           "program_label",
+           "current_span", "get_spans", "clear_spans",
            "span_tree", "counter_sample", "get_counter_samples",
            "export_chrome_trace", "spans_to_chrome_events", "Gauge",
            "Timer", "Histogram", "MetricsRegistry", "metrics",
@@ -216,12 +232,15 @@ def _stack() -> list:
 
 
 def _get_ring() -> deque:
+    """The span ring, as large as ``FLAGS_trace_buffer_size`` says now:
+    importing the package records a span (``startup/import``), so the
+    flag is most often set after the ring exists."""
     global _ring
-    if _ring is None:
+    cap = max(1, int(flag_value("FLAGS_trace_buffer_size") or 4096))
+    if _ring is None or _ring.maxlen != cap:
         with _ring_lock:
-            if _ring is None:
-                cap = int(flag_value("FLAGS_trace_buffer_size") or 4096)
-                _ring = deque(maxlen=max(1, cap))
+            if _ring is None or _ring.maxlen != cap:
+                _ring = deque(_ring or (), maxlen=cap)
     return _ring
 
 
@@ -235,6 +254,12 @@ class _NoopSpan:
 
     def __exit__(self, *exc):
         return False
+
+    @property
+    def attrs(self) -> dict:
+        """What ``with ... as span: span.attrs[...] = ...`` writes to
+        when nothing is recorded: a dict nobody keeps."""
+        return {}
 
 
 _NOOP = _NoopSpan()
@@ -372,19 +397,201 @@ def trace_span(name: str, parent: Optional[SpanContext] = None,
                                **attrs))
 
 
-def get_spans() -> List[Span]:
+def get_spans(kept: bool = False) -> List[Span]:
     """Completed spans, oldest first (bounded by
-    ``FLAGS_trace_buffer_size``)."""
+    ``FLAGS_trace_buffer_size``).  ``kept=True``: the spans of the
+    ``startup/`` and ``compile/`` families instead, which a window's
+    traffic does not evict (the start-up account, below)."""
     with _ring_lock:
+        if kept:
+            return list(_kept)
         return list(_ring) if _ring is not None else []
 
 
 def clear_spans():
-    global _ring, _counter_ring
+    global _ring, _counter_ring, _booked
     with _ring_lock:
         _ring = None
         _counter_ring = None
+        _kept.clear()
+        _booked += 1
     _tls.stack = []
+    _tls.parts = None
+
+
+# ---------------------------------------------------------------------------
+# the start-up account: what a process does before it serves or trains
+# ---------------------------------------------------------------------------
+
+# Spans of these two families are the parts of start-up, each opened where
+# the work happens (the table is in the README, "Observability"):
+# ``startup/import``, ``backend_init``, ``program_build``, ``step_build``,
+# ``pool_alloc``, ``warmup`` and ``warm_program`` through
+# :func:`startup_span`, and ``compile/trace``, ``lower`` and ``backend``
+# after the fact, from jax's own compile events
+# (``compile_cache.py``'s listener), through :func:`span_record`.  A part's
+# seconds are its spans' SELF time: the duration less what the spans of
+# the two families that lie inside it on the same thread cover, so a
+# compile under a warm-up is counted once and the parts add up to no more
+# than the wall time.  A span that closes writes its self time on itself
+# (``self_ms``), adds it to the counter ``startup_<part>_us`` /
+# ``compile_<part>_us`` and is kept, beside the ring, in a ring of its own
+# that a window's traffic does not turn over (a process makes a few
+# hundred).
+KEPT_FAMILIES = ("startup/", "compile/")
+_JIT_OF = re.compile(r"^jit[(_](.*?)\)?$")
+_kept: deque = deque(maxlen=4096)
+_booked = 0                       # spans kept so far
+_account: Tuple[int, dict] = (-1, {})   # the account as of that many
+
+
+def _parts() -> list:
+    """This thread's stack of the open parts' covered intervals: one list
+    of ``(start, end)`` a part open on the thread, over the thread's own
+    (the last few hundred, for a span made after the fact to claim)."""
+    p = getattr(_tls, "parts", None)
+    if p is None:
+        p = _tls.parts = [deque(maxlen=512)]
+    return p
+
+
+def _book(span: Span, inside):
+    """``span``, of a kept family, has closed with the intervals
+    ``inside`` it covered by others: write its self time, count it, keep
+    it, and tell the part that encloses it."""
+    self_s = max(0.0, (span.end - span.start)
+                 - sum(e - s for s, e in inside))
+    span.attrs["self_ms"] = round(self_s * 1e3, 3)
+    family, _, part = span.name.partition("/")
+    _monitor.get(f"{family}_{part}_us").increase(int(round(self_s * 1e6)))
+    global _booked
+    with _ring_lock:
+        _kept.append(span)
+        _booked += 1
+    _parts()[-1].append((span.start, span.end))
+
+
+class _PartCtx:
+    __slots__ = ("_span",)
+
+    def __init__(self, span: Span):
+        self._span = span
+
+    def __enter__(self):
+        _parts().append([])
+        return self._span
+
+    def __exit__(self, *exc):
+        span_end(self._span)
+        _book(self._span, _parts().pop())
+        return False
+
+
+def startup_span(name: str, **attrs):
+    """``with startup_span("startup/pool_alloc", pools=n) as span: ...``
+    — a :func:`trace_span` that is also a part of the start-up account
+    (see above).  For code that runs once a program or once a process,
+    never once a step.  A no-op under ``FLAGS_telemetry=0`` (what is
+    written to ``span.attrs`` then is dropped)."""
+    if not enabled():
+        return _NOOP
+    return _PartCtx(span_begin(name, **attrs))
+
+
+def span_record(name: str, start: float, end: float, inherit=(),
+                **attrs) -> Optional[Span]:
+    """Record a span that has already ended: ``start`` and ``end`` on the
+    span clock (``time.monotonic()``; a wall-clock time less
+    ``_EPOCH_OFFSET``), this thread's, under the span open on it now.
+    ``inherit`` names attributes to copy, each from the nearest open span
+    on the thread that carries it.  A span of a kept family also enters
+    the start-up account, claiming the family's spans on this thread that
+    began inside it (jax reports an inner ``jit``'s trace before the
+    outer one's).  None when telemetry is disabled."""
+    if not enabled():
+        return None
+    stack = _stack()
+    top = stack[-1] if stack else None
+    for key in inherit:
+        for open_span in reversed(stack):
+            if key in open_span.attrs:
+                attrs[key] = open_span.attrs[key]
+                break
+    span = Span(name, attrs, top.span_id if top is not None else None,
+                threading.get_ident(),
+                trace_id=top.trace_id if top is not None else None)
+    span.start, span.end = start, end
+    ring = _get_ring()
+    with _ring_lock:
+        ring.append(span)
+    if name.startswith(KEPT_FAMILIES):
+        around, inside = _parts()[-1], []
+        while around and around[-1][0] >= start:
+            inside.append(around.pop())
+        _book(span, inside)
+    return span
+
+
+def program_label(fun_name, kind, bucket) -> str:
+    """A row of the account's ``programs`` by the engine's name for it
+    (``prefill 2048``, ``decode``); every Program is jitted as
+    ``step_fn``, so another function under a program is named too
+    (``decode [wrapped]``: ``pallas_call``'s own jit, the kernels'
+    bodies), and outside a warm-up the function's name stands alone."""
+    if kind is None:      # (a bucket alone: a request's own prefill)
+        return fun_name if bucket is None else f"{fun_name} {bucket}"
+    program = kind if bucket is None else f"{kind} {bucket}"
+    return program if fun_name == "step_fn" else f"{program} [{fun_name}]"
+
+
+def startup_account(spans: Optional[List[Span]] = None) -> dict:
+    """The start-up account so far (or of ``spans``, some of
+    ``get_spans(kept=True)``): ``{part: {"s": self seconds, "n":
+    spans}}`` for every part that has a span (``import``, ``trace``,
+    ``backend``, ...; the same seconds as the ``*_us`` counters), and
+    under ``"programs"`` one row a jitted function and engine program,
+    costliest first: ``(fun_name, kind, bucket, trace_s, lower_s,
+    backend_s, cache_hit)``, ``kind`` and ``bucket`` those of the
+    ``startup/warm_program`` it compiled under (None outside one),
+    ``cache_hit`` 1 when every backend compile of the row was a read of
+    the persistent cache, 0 when one was not, None where no cache was
+    asked.  Served as ``GenerationEngine.stats()["startup"]``."""
+    global _account
+    booked = None
+    if spans is None:
+        with _ring_lock:
+            booked, spans = _booked, list(_kept)
+        if _account[0] == booked:  # (a health probe asks every second)
+            return dict(_account[1])
+    parts: Dict[str, dict] = {}
+    rows: Dict[tuple, list] = {}
+    for span in spans:
+        family, _, part = span.name.partition("/")
+        self_s = span.attrs["self_ms"] / 1e3
+        entry = parts.setdefault(part, {"s": 0.0, "n": 0})
+        entry["s"] += self_s
+        entry["n"] += 1
+        if family != "compile":
+            continue
+        a = span.attrs
+        # jax names the trace by the function, its module ``jit(<it>)``
+        key = (_JIT_OF.sub(r"\1", str(a.get("fun_name"))),
+               a.get("kind"), a.get("bucket"))
+        row = rows.setdefault(key, [0.0, 0.0, 0.0, None])
+        row[("trace", "lower", "backend").index(part)] += self_s
+        if "cache_hit" in a:
+            row[3] = a["cache_hit"] if row[3] is None \
+                else min(row[3], a["cache_hit"])
+    for entry in parts.values():
+        entry["s"] = round(entry["s"], 6)
+    programs = sorted(
+        (key + (round(t, 6), round(lo, 6), round(b, 6), hit)
+         for key, (t, lo, b, hit) in rows.items()),
+        key=lambda r: -(r[3] + r[4] + r[5]))
+    account = dict(parts, programs=programs)
+    if booked is not None:
+        _account = (booked, dict(account))
+    return account
 
 
 def span_tree(spans: Optional[List[Span]] = None) -> List[dict]:

@@ -5,7 +5,8 @@ here as they stand (entries against data files, every entry's
 ``workloads``, each cell's count of values), and the cells that PR 41, PR
 43, PR 47 and PR 51 added are pinned beside them, by name: each one's own entries, the
 shared ``.pool`` entries that list it, its configuration's cut and its
-mix.  No JAX is imported and no engine started.
+mix; so is the start-up account's `.setup` family that PR 53 added to
+every cell.  No JAX is imported and no engine started.
 """
 import importlib.util
 import json
@@ -27,6 +28,26 @@ _spec.loader.exec_module(manifest)
 # the benchmark's own table of counts is a `benchmark` PR's to edit, so the
 # count the collected check holds the cell to is raised here
 manifest.REPORTS["bert-base-seq512-dp4"] += 1
+
+# PR 53 added the start-up account's four `.setup` entries, which every
+# cell reports and which move `setup_s`.  The collected checks count a
+# cell's values and compare its shared families with ``POOL``, the
+# families that move the cell's own gate: they go on looking at those, and
+# the `.setup` family is pinned by a check of its own, below
+SETUP = {"setup_import_s.setup": ("startup_part", ["startup/import"]),
+         "setup_trace_lower_s.setup": (
+             "startup_part", ["compile/trace", "compile/lower"]),
+         "setup_backend_s.setup": ("startup_part", ["compile/backend"]),
+         "setup_unaccounted_s.setup": ("startup_unaccounted", None)}
+_reported_by = manifest.reported_by
+
+
+def _reported_by_gate(cell):
+    own, shared = _reported_by(cell)
+    return own, [name for name in shared if name not in SETUP]
+
+
+manifest.reported_by = _reported_by_gate
 
 # the benchmark's own checks, collected here under their own names
 globals().update({name: fn for name, fn in vars(manifest).items()
@@ -131,10 +152,42 @@ def test_the_benchmark_has_nine_configurations_and_eleven_cells():
         mix = _json("traffic", w["traffic"] + ".json")
         assert os.path.exists(os.path.join(BENCH, mix["driver"] + ".py"))
         assert len(w["why"]) <= 200
-    # 79 entries at the merge, each added cell's own, and the dp4 cell's
-    # one entry of PR 42
+    # 79 entries at the merge, each added cell's own, the dp4 cell's one
+    # entry of PR 42 and the start-up account's four of PR 53
     assert len(manifest.PER_LAYER) \
-        == 79 + sum(len(a["own"]) for a in ADDED.values()) + 1 == 123
+        == 79 + sum(len(a["own"]) for a in ADDED.values()) + 1 \
+        + len(SETUP) == 127
+
+
+@pytest.mark.parametrize("name", list(SETUP))
+def test_every_cell_reports_the_start_up_account(name):
+    """The `.setup` family (PR 53): four entries at the end of
+    ``per_layer``, each listing the eleven cells in the manifest's order
+    and moving ``setup_s``, each with its data file and a reader that
+    reads the program's kept spans by name."""
+    names = [m["name"] for m in manifest.PER_LAYER]
+    assert names[-len(SETUP):] == list(SETUP)
+    entry, = [m for m in manifest.PER_LAYER if m["name"] == name]
+    assert entry == {"name": name, "unit": "s", "better": "lower",
+                     "source": "program_span", "layer": "start-up",
+                     "moves": "setup_s", "workloads": manifest.CELLS}
+    gate, = [m for m in manifest.SPEC["end_to_end"]
+             if m["name"] == "setup_s"]
+    assert "workloads" not in gate and gate["bound"] == 0.1
+    reader, spans = SETUP[name]
+    spec = _json("metrics", name + ".json")
+    assert spec["reader"] == reader and len(spec["why"]) > 40
+    assert spec["args"] == ({} if spans is None else {"spans": spans})
+    assert os.path.exists(os.path.join(BENCH, "readers", reader + ".py"))
+    # the spans it names are ones the program makes
+    made = open(os.path.join(REPO, "paddle_tpu", "compile_cache.py")).read() \
+        + open(os.path.join(REPO, "paddle_tpu", "__init__.py")).read()
+    for span in spans or ():
+        assert f'"{span}"' in made, span
+    # and every cell still reports, beside them, what moves its own gate
+    for cell in manifest.CELLS:
+        own, shared = _reported_by(cell)
+        assert name in shared and name not in own
 
 
 @pytest.mark.parametrize("cell", list(ADDED))
