@@ -718,7 +718,7 @@ def serve_phase(cfg=SERVE, on_chip=True):
 SPARSE = dict(heads=28, kv_heads=4, head_dim=128, window=4096,
               page_tokens=16, contexts=(100, 4096, 4097, 8000),
               hidden=2560, experts=64, top_k=6, expert_width=768, slots=32,
-              prefill_rung=512)
+              prefill_rung=512, rung_real_rows=300)
 
 
 def sparse_window_phase(cfg=SPARSE):
@@ -730,7 +730,11 @@ def sparse_window_phase(cfg=SPARSE):
     "highest" precision: the layer at a decode step's 32 tokens and a
     prefill rung's 512 (both products on the Pallas grouped matmul:
     ``grouped_matmul_lowered_pallas`` +2 each, PR 50) and at the check
-    engine's 2 (``grouped_matmul_lowered_ragged_dot`` +2).  The held
+    engine's 2 (``grouped_matmul_lowered_ragged_dot`` +2); and (PR 54)
+    the rung with 300 real rows and the check engine's two slots with one
+    live, NaN in every row behind ``valid``: the real rows are the
+    loop's, the others' ``out`` exactly 0, the counts the real rows'
+    pairs, the products lowered as for the full rows.  The held
     share of such a layer (``held_first``) is checked at published widths
     in ``share_and_channel_phase``."""
     import jax
@@ -778,13 +782,27 @@ def sparse_window_phase(cfg=SPARSE):
                "grouped_matmul_lowered_ragged_dot")
     # a decode step's rows, a prefill rung's, and the check engine's two
     # slots (fewer rows than the kernel's row block: the one ragged_dot)
-    for tokens, kernels in ((cfg["slots"], 2), (cfg["prefill_rung"], 2),
-                            (2, 0)):
+    # ... and a rung and the two slots with rows behind ``valid``
+    for tokens, real, kernels in (
+            (cfg["slots"], None, 2), (cfg["prefill_rung"], None, 2),
+            (cfg["prefill_rung"], cfg["rung_real_rows"], 2), (2, None, 0),
+            (2, 1, 0)):
         before = [stat_get(n) for n in lowered]
+        fed, live = [x[:tokens], rx[:tokens]], None
+        if real is not None:
+            live = jnp.arange(tokens) < real
+            fed = [jnp.where(live[:, None], a, jnp.nan) for a in fed]
         got, counts, logits = jax.jit(
-            lambda *a: moe_routed_tokens(*a, top_k=k, precision=hi))(
-                x[:tokens], rx[:tokens], rw, gu, dn)
+            lambda *a, valid: moe_routed_tokens(
+                *a, top_k=k, precision=hi, valid=valid))(
+                *fed, rw, gu, dn, valid=live)
         grew = [stat_get(n) - b for n, b in zip(lowered, before)]
+        if real is not None:
+            check(not bool(got[real:].any()),
+                  f"routed expert layer of {tokens} rows, {real} real: a "
+                  f"row behind them has an expert output")
+            got, logits = got[:real], logits[:real]
+        rows, tokens = tokens, tokens if real is None else real
         with jax.default_matmul_precision("highest"):
             top = jax.lax.top_k(logits, k)[1]
             chosen = jax.nn.one_hot(top, E, dtype=bool).any(1)
@@ -801,7 +819,8 @@ def sparse_window_phase(cfg=SPARSE):
               f"routed expert layer of {tokens} tokens lowered "
               f"{dict(zip(lowered, grew))}, expected {kernels} of its two "
               f"products on the Pallas kernel")
-        say(f"sparse: {tokens} tokens x top-{k} of {E} experts, "
+        say(f"sparse: {tokens} tokens in {rows} rows x top-{k} of {E} "
+            f"experts, "
             f"{int((counts > 0).sum())} experts touched, nothing dropped, "
             f"within {rel:.4g} of the loop over all experts; "
             f"grouped_matmul_lowered_pallas +{grew[0]}, _ragged_dot "

@@ -1351,7 +1351,8 @@ def moe_routed_ffn(x, router_x, num_experts, top_k, d_ff,
     goes to the ``top_k`` experts its row of ``router_x`` [B, S, H]
     scores highest (float32 logits, softmax over the selected), through
     ``act(x W_gate) * (x W_up)`` then ``W_down``; no capacity, nothing
-    dropped.  ``valid`` [B] int: real rows per batch row, for the count.
+    dropped.  ``valid`` [B] int: real rows per batch row; the rows
+    behind them go through no expert and their output is 0.
     ``activation``: "relu" or "silu".
     ``score`` "sigmoid" scores each expert on its own: the ``top_k``
     largest of ``sigmoid(logits)`` (plus, with ``expert_bias``, the

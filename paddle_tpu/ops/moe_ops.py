@@ -63,7 +63,8 @@ def _moe_routed_ffn(ctx, op):
     ``moe_routed_tokens``): X [B, S, H] is the experts' input, RouterX
     [B, S, H] what the router reads (an architecture may route from the
     layer's raw input, before attention), Valid [B] int the number of
-    real rows of each batch row (optional: all), ExpertBias [E] the
+    real rows of each batch row (optional: all; the rows behind them go
+    through no expert and their Out is 0), ExpertBias [E] the
     selection bias of sigmoid scoring (optional).  With the attribute
     ``held_first`` GateUpW and DownW hold the experts from that index on
     alone, one chip's share of RouterW's E, and Out is their part of the

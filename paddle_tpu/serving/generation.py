@@ -157,7 +157,10 @@ each fails only the then-active requests),
 ``serving_kv_window_pages_released`` (and, of those, the ones let go
 while their prompt was still coming in,
 ``serving_kv_window_pages_released_in_prefill``), ``moe_tokens_routed``,
-``moe_tokens_dropped`` (must read 0), and for a model whose expert
+``moe_tokens_dropped`` (must read 0), ``moe_pad_pairs_left_out`` (the
+pairs of the rows a program holds beyond the tokens it was fed, a rung's
+pad tail and a step's idle slots, which no expert multiplied), and for a
+model whose expert
 layers hold one chip's share of the router's experts (the layer
 pattern's ``held``) ``moe_pairs_routed`` / ``moe_pairs_held`` (the
 token-expert pairs the router placed over all its experts, and those
@@ -599,15 +602,16 @@ class _Joiner:
     device holds it (``outs["next_token"]``; block diffusion: the first
     block as the host made it) and goes out before the scheduler blocks
     here.  ``n_rows``: real rows of the program that returned
-    ``outs``."""
+    ``outs``, of the ``bucket`` it was built for."""
 
-    __slots__ = ("slot", "req", "outs", "n_rows")
+    __slots__ = ("slot", "req", "outs", "n_rows", "bucket")
 
-    def __init__(self, slot, req, outs, n_rows):
+    def __init__(self, slot, req, outs, n_rows, bucket):
         self.slot = slot
         self.req = req
         self.outs = outs
         self.n_rows = n_rows
+        self.bucket = bucket
 
 
 _JOIN_ROW = None
@@ -998,7 +1002,7 @@ class GenerationEngine:
                    "spec_tokens_accepted": 0, "spec_rollbacks": 0,
                    "window_pages_released": 0,
                    "window_pages_released_in_prefill": 0,
-                   "moe_tokens_routed": 0,
+                   "moe_tokens_routed": 0, "moe_pad_pairs_left_out": 0,
                    "moe_tokens_dropped": 0, "block_passes_denoise": 0,
                    "block_passes_commit": 0, "block_tokens_committed": 0,
                    "slot_state_writes": 0, "delta_state_steps": 0,
@@ -2653,7 +2657,7 @@ class GenerationEngine:
             if req.tenant is not None:
                 usage.ledger().book(req.tenant,
                                     flops=self._exe_flops(bucket))
-            self._complete_prefill(slot, req, outs, n_rows)
+            self._complete_prefill(slot, req, outs, n_rows, bucket)
             return
         # chunk continuation (chunked prefill and/or prefix-hit tail):
         # this iteration runs the FIRST remaining span; later spans
@@ -2712,10 +2716,10 @@ class GenerationEngine:
         req.note("chunk", now, {"base": start, "tokens": n})
         slot.prefill_pos = start + n
         if last:
-            self._complete_prefill(slot, req, outs, n)
+            self._complete_prefill(slot, req, outs, n, bucket)
         elif "expert_counts" in outs:
             # booked with the prompt's last chunk, when all have run
-            slot.chunk_counts.append((outs["expert_counts"], n))
+            slot.chunk_counts.append((outs["expert_counts"], n, bucket))
 
     def _chunk_pairs(self, base: int, n: int) -> int:
         """The (row, column) pairs the ``n`` rows of a chunk at ``base``
@@ -2732,10 +2736,10 @@ class GenerationEngine:
         return pairs
 
     def _fetch_first_token(self, slot: _Slot, outs, parent,
-                           n_tokens: int) -> int:
+                           n_tokens: int, bucket: int) -> int:
         """Block on a prefill's outputs: the first generated token (the
         logits row when kept, and the expert layers' counts over the
-        program's ``n_tokens`` real rows), under
+        ``n_tokens`` real rows of the program's ``bucket``), under
         ``generation/prefill_fetch``."""
         span = self._begin_device_wait("generation/prefill_fetch", outs,
                                        parent=parent, slot=slot.idx)
@@ -2753,28 +2757,38 @@ class GenerationEngine:
             if "expert_counts" in outs:
                 # (the prompt's earlier chunks ran before this one)
                 chunks, slot.chunk_counts = slot.chunk_counts \
-                    + [(outs["expert_counts"], n_tokens)], []
-                booked = [self._book_experts(np.asarray(c.numpy()), n)
-                          for c, n in chunks]
-                if span is not None and "pairs_held" in booked[0]:
+                    + [(outs["expert_counts"], n_tokens, bucket)], []
+                booked = [self._book_experts(np.asarray(c.numpy()), n, rows)
+                          for c, n, rows in chunks]
+                if span is not None:
                     # (the counts come back with this fetch, after the
                     # ``generation/prefill`` span that launched them)
-                    span.attrs.update({k: sum(b[k] for b in booked) for k
-                                       in ("pairs_routed", "pairs_held")})
+                    span.attrs.update({
+                        k: sum(b[k] for b in booked) for k in (
+                            "pairs_routed", "pairs_held",
+                            "pad_pairs_left_out") if k in booked[0]})
         finally:
             self._end_device_wait(span)
         return first
 
-    def _book_experts(self, counts: np.ndarray, n_tokens: int) -> dict:
+    def _book_experts(self, counts: np.ndarray, n_tokens: int,
+                      built_for: int) -> dict:
         """Book what a program's expert layers counted: ``counts``
-        [L_moe, E] tokens per expert over the ``n_tokens`` valid rows.
-        Routing is dropless, so every layer must have placed ``n_tokens
-        * top_k`` pairs; the shortfall is ``moe_tokens_dropped`` and
-        must read 0.  Returns the load figures of the step."""
+        [L_moe, E] tokens per expert over the ``n_tokens`` valid rows of
+        the ``built_for`` rows the program holds.  Routing is dropless, so
+        every layer must have placed ``n_tokens * top_k`` pairs; the
+        shortfall is ``moe_tokens_dropped`` and must read 0.  The other
+        rows' pairs (a rung's pad tail, a step's idle slots) went
+        through no expert: ``moe_pad_pairs_left_out``.  Returns the load
+        figures of the step."""
         routed = int(counts.sum())
         dropped = counts.shape[0] * n_tokens * self._moe_top_k - routed
-        self._count("moe_tokens_routed", routed)
-        stat_add("moe_tokens_routed", routed)
+        left_out = counts.shape[0] * (built_for - n_tokens) \
+            * self._moe_top_k
+        for what, k in (("moe_tokens_routed", routed),
+                        ("moe_pad_pairs_left_out", left_out)):
+            self._count(what, k)
+            stat_add(what, k)
         if dropped:
             self._count("moe_tokens_dropped", dropped)
             stat_add("moe_tokens_dropped", dropped)
@@ -2787,7 +2801,8 @@ class GenerationEngine:
             telemetry.gauge_set("moe_experts_touched", touched)
             telemetry.gauge_set("moe_expert_load_max_over_mean", load)
         attrs = {"experts_touched": round(touched, 3),
-                 "expert_load_max_over_mean": round(load, 4)}
+                 "expert_load_max_over_mean": round(load, 4),
+                 "pad_pairs_left_out": left_out}
         if self._moe_shared:
             rows = counts.shape[0] * n_tokens
             self._count("moe_shared_expert_rows", rows)
@@ -2808,7 +2823,7 @@ class GenerationEngine:
         return attrs
 
     def _complete_prefill(self, slot: _Slot, req: GenRequest, outs,
-                          n_rows: int):
+                          n_rows: int, bucket: int):
         """Shared tail of every prefill path: the sequence enters the
         decode grid at the position its prefill leaves it, with nothing
         booked, and the prefill is left to :meth:`_join` to read: at
@@ -2816,10 +2831,10 @@ class GenerationEngine:
         :meth:`_decode_step` has sent the next step out ahead with this
         sequence in it.  ``n_rows``: real rows of the program that
         produced ``outs`` (the whole prompt, or its last chunk: only
-        that one's expert counts are fetched)."""
+        that one's expert counts are fetched), built for ``bucket``."""
         n_prompt = int(req.prompt.size)
         slot.prefill_pos = n_prompt
-        self._joining = _Joiner(slot, req, outs, n_rows)
+        self._joining = _Joiner(slot, req, outs, n_rows, bucket)
         if self._blk:
             self._enter_blocks(slot, req, n_rows)
         else:
@@ -2841,17 +2856,17 @@ class GenerationEngine:
             return
         slot, req = j.slot, j.req
         try:
-            self._read_prefill(slot, req, j.outs, j.n_rows)
+            self._read_prefill(slot, req, j.outs, j.n_rows, j.bucket)
         except Exception as e:  # noqa: BLE001 — a prefill failure
             # fails this request only
             self._fail_request(slot, req, "prefill", e)
 
     def _read_prefill(self, slot: _Slot, req: GenRequest, outs,
-                      n_rows: int):
+                      n_rows: int, bucket: int):
         """:meth:`_join`'s body: whatever raises here fails ``req``."""
         first = self._fetch_first_token(
             slot, outs, slot.span.context() if slot.span is not None
-            else None, n_rows)
+            else None, n_rows, bucket)
         n_prompt = int(req.prompt.size)
         self._h_prefill.observe(req.prefill_ms, trace_id=req.trace_id)
         telemetry.histogram_observe("serving_prefill_ms",
@@ -3276,7 +3291,8 @@ class GenerationEngine:
             # the counts cover every live row of the step, the rows the
             # settle discards too
             attrs.update(self._book_experts(
-                outs["expert_counts"], len(fl.riders) * self._rows))
+                outs["expert_counts"], len(fl.riders) * self._rows,
+                self.num_slots * self._rows))
         if self._wpool is not None:
             # the pages this step's feeds let go, what both kinds hold
             # now, the positions its rows attended

@@ -437,11 +437,11 @@ def test_a_prefill_that_fails_under_a_step_ahead_fails_its_request_only(
         _quiet(e)
         before = _counters(e)
 
-        def broken(slot, outs, parent, n_tokens):
+        def broken(slot, *fetched):
             if slot.req.prompt.size == len(PROMPTS[1]):
                 e._fetch_first_token = fetch
                 raise RuntimeError("the prefill's read failed")
-            return fetch(slot, outs, parent, n_tokens)
+            return fetch(slot, *fetched)
 
         e._fetch_first_token = broken
         on_a, a_running = _after_tokens(3)

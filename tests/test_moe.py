@@ -208,3 +208,128 @@ def test_moe_rules_shard_expert_weights():
     assert rules2.spec("moe_ffn.w_1", (8, 16, 32)) == P("ep", None,
                                                         None)
     assert rules2.spec("fc.w_0", (16, 32)) == P(None, "mp")
+
+
+# ---------------------------------------------------------------------------
+# dropless top-k routing: the rows behind ``valid`` (``moe_routed_tokens``)
+# ---------------------------------------------------------------------------
+
+def _valid_rows(which, n):
+    return {"None": None, "all": np.ones(n, bool),
+            "a_pad_tail": np.arange(n) < (5 * n) // 8,
+            "every_second_row": np.arange(n) % 2 == 0,
+            "none": np.zeros(n, bool)}[which]
+
+
+@pytest.mark.parametrize("route", ["ragged_dot", "kernel"])
+@pytest.mark.parametrize("n", [6, 48], ids=["a_decodes_rows", "a_rungs_rows"])
+@pytest.mark.parametrize("which", ["None", "all", "a_pad_tail",
+                                   "every_second_row", "none"])
+def test_rows_behind_valid_go_through_no_expert(monkeypatch, which, n, route):
+    """The pairs of a row behind ``valid`` sort past the last group: both
+    ``grouped_matmul`` calls get sizes that sum to the valid rows' pairs,
+    a real row's ``out`` is the all-rows formulation's, a pad row's is
+    exactly 0, ``counts`` and ``logits`` are what they were, and a NaN in
+    a pad row of ``x`` reaches no real row.  ``route`` "kernel": both
+    products through the Pallas kernel in interpret mode."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+
+    from paddle_tpu.ops.pallas import grouped_matmul as kernel
+    from paddle_tpu.parallel import moe
+
+    highest = jax.lax.Precision.HIGHEST
+    if route == "kernel":
+        real = pl.pallas_call
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        monkeypatch.setattr(pl, "pallas_call", lambda *a, **kw: real(
+            *a, **dict(kw, interpret=True)))
+        monkeypatch.setattr(kernel, "tiles",
+                            lambda m, k, n, scoped=False: (8, n))
+        kernel.grouped_matmul.clear_cache()
+    seen = []
+    product = moe.grouped_matmul
+    monkeypatch.setattr(moe, "grouped_matmul", lambda rows, w, sizes, *a: (
+        seen.append(np.asarray(sizes)), product(rows, w, sizes, *a))[1])
+    rng = np.random.default_rng(n)
+    hidden, experts, width, top_k = 16, 8, 12, 3
+    x, rx = (rng.standard_normal((n, hidden)).astype(np.float32)
+             for _ in range(2))
+    wr = rng.standard_normal((hidden, experts)).astype(np.float32)
+    wgu = rng.standard_normal((experts, hidden, 2 * width)).astype(np.float32)
+    wd = rng.standard_normal((experts, width, hidden)).astype(np.float32)
+    valid = _valid_rows(which, n)
+
+    def layer(x, rx, valid):
+        return [np.asarray(a) for a in moe.moe_routed_tokens(
+            jnp.asarray(x), jnp.asarray(rx), wr, wgu, wd, top_k=top_k,
+            valid=None if valid is None else jnp.asarray(valid),
+            precision=highest)]
+
+    want, every, logits = layer(x, rx, None)
+    seen.clear()
+    out, counts, got_logits = layer(x, rx, valid)
+    live = np.ones(n, bool) if valid is None else valid
+    chosen = np.argsort(-logits, axis=-1, kind="stable")[:, :top_k]
+    assert [int(s.sum()) for s in seen] == [int(live.sum()) * top_k] * 2
+    assert np.array_equal(seen[0], counts) and np.array_equal(seen[1], counts)
+    assert np.array_equal(counts, np.bincount(chosen[live].reshape(-1),
+                                              minlength=experts))
+    assert np.array_equal(got_logits, logits)
+    if live.all():
+        assert np.array_equal(counts, every)
+    if live.any():
+        assert np.abs(out[live] - want[live]).max() \
+            < 1e-6 * np.abs(want).max()
+    assert not out[~live].any()
+    if valid is None:
+        return
+    # NaN where no request reads: the rows behind ``valid``
+    for a in (x, rx):
+        a[~live] = np.nan
+    out, counts_nan, _ = layer(x, rx, valid)
+    assert np.array_equal(counts_nan, counts)
+    assert not out[~live].any()
+    if live.any():
+        assert np.abs(out[live] - want[live]).max() \
+            < 1e-6 * np.abs(want).max()
+    if route == "kernel":
+        kernel.grouped_matmul.clear_cache()
+
+
+@pytest.mark.parametrize("sizes", [[10, 0, 21, 9, 0, 17, 3, 8], [0] * 8,
+                                   [0, 0, 0, 0, 0, 0, 5, 0]],
+                         ids=["short_of_m", "zero", "one_short_group"])
+def test_kernel_visits_nothing_past_the_last_group(sizes):
+    """The Pallas grouped matmul (interpret mode) over sizes that sum
+    short of M, and to 0: the visit list ends at the block of the last
+    row in a group, NaN rows past it reach no row of a group."""
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops.pallas import grouped_matmul as kernel
+
+    m, k, n, tm = 96, 16, 128, 16
+    sizes = np.asarray(sizes, np.int32)
+    filled = int(sizes.sum())
+    rng = np.random.default_rng(filled)
+    rows = rng.standard_normal((m, k)).astype(np.float32)
+    rows[filled:] = np.nan
+    weights = rng.standard_normal((8, k, n)).astype(np.float32)
+    weights[sizes == 0] = np.nan
+    _, _, block, visits = kernel.visits(jnp.asarray(sizes), m, tm)
+    ends = np.cumsum(sizes)
+    assert int(visits) == len({
+        (r // tm, int(np.searchsorted(ends, r, side="right")))
+        for r in range(filled)})
+    assert not filled or int(np.asarray(block)[:int(visits)].max()) \
+        == (filled - 1) // tm
+    got = np.asarray(kernel.grouped_matmul(
+        jnp.asarray(rows), jnp.asarray(weights), jnp.asarray(sizes),
+        tm=tm, tn=n, interpret=True))
+    assert got.shape == (m, n)
+    which = np.searchsorted(ends, np.arange(filled), side="right")
+    for r, g in enumerate(which):
+        np.testing.assert_allclose(
+            got[r], rows[r].astype(np.float64) @ weights[g], rtol=1e-5,
+            atol=1e-5)

@@ -161,8 +161,10 @@ def test_nothing_is_dropped_when_one_expert_gets_every_token(top_k):
                                        top_k=top_k, valid=valid)
     counts = np.asarray(counts)
     assert counts[5] == 40 and counts.sum() == 40 * top_k
-    assert _rel(np.asarray(out),
-                _plain_experts(x, x, rw, gu, dn, top_k)) < TOL
+    assert _rel(np.asarray(out)[:40],
+                _plain_experts(x, x, rw, gu, dn, top_k)[:40]) < TOL
+    # the rows behind ``valid`` went through no expert
+    assert not np.asarray(out)[40:].any()
 
 
 def test_grouped_matmul_with_empty_groups_matches_a_loop():
@@ -386,6 +388,12 @@ def test_engine_books_experts_and_window_pages(served):
     # every prefill row and decode step, four layers, three experts each
     tokens = n["prefill_tokens"] + n["generated_tokens"] - n["served"]
     assert n["moe_tokens_routed"] == tokens * 4 * 3
+    # the rows no expert multiplied: the rungs' tails behind prompts of
+    # 10, 16 and 30 in rungs of 16, 16 and 32, and two idle slots of
+    # three beside every decode step's one rider
+    idle = 6 + 0 + 2 + 2 * (n["generated_tokens"] - n["served"])
+    assert n["moe_pad_pairs_left_out"] == idle * 4 * 3
+    assert stat_get("moe_pad_pairs_left_out") >= idle * 4 * 3
     win = st["paged"]["window"]
     assert win["pages_per_slot"] == WINDOW // PAGE + 1
     assert win["pages_released"] > 0 and win["pages_live"] == 0

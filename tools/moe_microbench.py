@@ -3,6 +3,7 @@
 
     chiprun -- python tools/moe_microbench.py [--only NAME] [--tiles TM,TN;...]
     chiprun -- python tools/moe_microbench.py --held 1 [--only NAME] [--runs R,...]
+    chiprun -- python tools/moe_microbench.py --valid 1 [--only NAME]
 
 Times the two grouped matmuls of one expert FFN (gate + up, then down;
 float32 "highest") over rows sorted by expert, at the shapes the
@@ -36,6 +37,16 @@ whole width: ``parent`` (PR 43's loop: runs of 192 through
 both products on the route of ``grouped_matmul`` at each run length of
 ``--runs``, and ``rule``, the run ``held_run`` gives the shape.  Writes
 ``chiprun_out/moe_held_sweep.json``.
+
+``--valid 1`` times one whole routed layer (``moe_routed_tokens``: router,
+sort, gather, both products, gate, scatter back) at a rung whose tail lies
+behind the prompt's end (``VALID``: rows, real rows), three ways: ``all
+rows`` (``valid=None``: every pair is multiplied, as before PR 54), ``valid:
+every row`` (the mechanism with nothing to leave out) and ``valid: the
+prompt's rows`` (the tail's pairs sort past the last group).  Real rows are
+standard normal and routed with the cell's skew; the tail holds ONE drawn
+row repeated, as a rung's tail holds one token id, so all its pairs go to
+the same ``top_k`` experts.  Writes ``chiprun_out/moe_valid_sweep.json``.
 
 Writes ``chiprun_out/moe_formulation_sweep.json`` and prints one line per
 formulation: milliseconds for the pair of matmuls, against the six-pass
@@ -78,6 +89,15 @@ HELD = {
                                (1024,)),
 }
 HELD_RUNS = (64, 128, 192, 512, 1024, 2048, 4096)
+# name: (top k, the gate, (rows of the program, real rows) ...): a rung of
+# the cell's ladder under a prompt of the mix's mean share of it, and the
+# decode step or block pass with every slot live
+VALID = {
+    "smallthinker-21b-a3b": (6, "relu", ((4096, 2900), (8192, 5800),
+                                         (512, 360), (32, 32))),
+    "lfm2-24b-a2b": (4, "silu", ((1024, 700), (64, 64))),
+    "sdar-30b-a3b-chat": (8, "silu", ((512, 350), (192, 192))),
+}
 PEAK, HBM = 197e12, 819e9
 RUN_ROWS, WIDE_TILE = 192, 512  # the parent's runs (PR 32)
 
@@ -285,6 +305,82 @@ def held_main(args) -> int:
     return 0
 
 
+def valid_main(args) -> int:
+    """The ``--valid`` table (the module docstring)."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.parallel import moe
+
+    highest = jax.lax.Precision.HIGHEST
+    results = []
+    for name, (top_k, gate, rungs) in VALID.items():
+        if args.only and name != args.only:
+            continue
+        groups, K, I = SHAPES[name][:3]
+        key = jax.random.key(groups + K)
+        gu = jax.random.normal(jax.random.fold_in(key, 1),
+                               (groups, K, 2 * I)) * .02
+        dn = jax.random.normal(jax.random.fold_in(key, 2),
+                               (groups, I, K)) * .02
+        for n, real in rungs:
+            rng = np.random.default_rng(n + groups)
+            # the router reads rows of its own: their last column is 1 and
+            # the router's last row the log of the skewed loads
+            router = rng.standard_normal((K, groups)).astype(np.float32) * .02
+            router[-1] = np.log(skewed_loads(rng, groups, SKEW))
+            x, rx = (rng.standard_normal((n, K)).astype(np.float32)
+                     for _ in range(2))
+            x[real:], rx[real:] = x[real:real + 1], rx[real:real + 1]
+            rx[:, -1] = 1.0
+            live = np.arange(n) < real
+            cases = [("all rows (valid=None)", None),
+                     ("valid: every row", np.ones(n, bool)),
+                     (f"valid: the prompt's {real} rows", live)]
+            f = jax.jit(lambda x, rx, r, gu, dn, v: moe.moe_routed_tokens(
+                x, rx, r, gu, dn, top_k=top_k, activation=gate, valid=v,
+                precision=highest))
+            outs, took = [], []
+            for case, valid in cases:
+                operands = (jnp.asarray(x), jnp.asarray(rx),
+                            jnp.asarray(router), gu, dn,
+                            None if valid is None else jnp.asarray(valid))
+                ms, out = timed(lambda *a: f(*a)[0], *operands)
+                counts = np.asarray(f(*operands)[1])
+                outs.append(np.asarray(out))
+                took.append(ms)
+                results.append({
+                    "shape": name, "rows": n, "real_rows": real,
+                    "formulation": case, "ms": ms,
+                    "pairs_multiplied": int(counts.sum()),
+                    "experts_touched": int((counts > 0).sum()),
+                    "largest_group": int(counts.max())})
+            every, _, cut = outs
+            off = float(np.abs(cut[:real] - every[:real]).max()
+                        / np.abs(every[:real]).max())
+            pad = n - real
+            print(f"{name} rung {n}, {real} real rows, {pad} behind them "
+                  f"(one row repeated; top {top_k} of {groups}): all rows "
+                  f"{took[0]:.3f} ms, valid every row {took[1]:.3f}, valid "
+                  f"the prompt's rows {took[2]:.3f}"
+                  + (f": {(took[0] - took[2]) / pad * 1e3:.2f} us a pad row"
+                     if pad else "")
+                  + f"; real rows off the all-rows layer by {off:.3g}, pad "
+                  f"rows' out all 0: {not cut[real:].any()}", flush=True)
+            results[-1].update(real_rows_rel_diff=off,
+                               pad_rows_zero=not cut[real:].any())
+        del gu, dn
+    os.makedirs("chiprun_out", exist_ok=True)
+    out = "chiprun_out/moe_valid_sweep.json"
+    if args.only:
+        out = out.replace(".json", f"_{args.only}.json")
+    with open(out, "w") as f:
+        json.dump({"device": jax.devices()[0].device_kind,
+                   "pad_rows": "one drawn row repeated", "skew": SKEW,
+                   "results": results}, f, indent=1)
+    return 0
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None, help="one name of SHAPES")
@@ -296,6 +392,9 @@ def main(argv=None) -> int:
                     help="1: the held share's loop at HELD's shapes")
     ap.add_argument("--runs", default="",
                     help="with --held: the run lengths (HELD_RUNS)")
+    ap.add_argument("--valid", type=int, default=0,
+                    help="1: a whole routed layer at VALID's rungs, all rows "
+                         "against the prompt's")
     args = ap.parse_args(argv)
     import jax
     import jax.numpy as jnp
@@ -306,6 +405,8 @@ def main(argv=None) -> int:
         return 2
     if args.held:
         return held_main(args)
+    if args.valid:
+        return valid_main(args)
     from jax.experimental.pallas.ops.tpu.megablox import gmm
 
     from paddle_tpu.ops.pallas import grouped_matmul as kernel
