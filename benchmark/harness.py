@@ -15,6 +15,17 @@ name a function ``<module>.<function>`` of a module under ``benchmark/``
 (``resolve``).  A cell reports the end-to-end and per-layer metrics of
 ``BENCHMARK.json`` that have no ``workloads`` key or list the cell under
 it.
+
+One entry a family (PR 38, PR 55): a metric's file holds the reader and
+the arguments every cell shares; what differs by cell comes from the
+cell's own files, under the key ``per_layer_args`` and the entry's name:
+``benchmark/configs/<config>.json`` where it follows from the model (the
+``ops_bytes*`` function that counts its work, the span attributes that
+function takes, the key that counts its experts), then
+``benchmark/traffic/<traffic>.json`` where it follows from the engine the
+mix asks for (100 / slots) (``Cell.reader_of``).  So a later cell joins a
+family by appending its name to the entry's ``workloads`` and bringing
+its arguments in its own new files; it edits no file that is there.
 """
 from __future__ import annotations
 
@@ -61,6 +72,9 @@ def resolve(dotted: str):
     return getattr(importlib.import_module(module), name)
 
 
+ARGS_KEY = "per_layer_args"
+
+
 class Cell:
     """One entry of ``workloads`` with its files resolved."""
 
@@ -86,6 +100,18 @@ class Cell:
     def metrics(self, group: str) -> list:
         return [m for m in self.bench[group]
                 if "workloads" not in m or self.name in m["workloads"]]
+
+    def reader_of(self, name: str):
+        """``(reader, arguments)`` of per-layer entry ``name`` in this
+        cell: the reader's name under ``benchmark/readers`` and what its
+        ``read`` gets beside ``ctx``: the arguments of the entry's data
+        file, then the configuration's own, then the mix's own (the later
+        wins)."""
+        spec = load_json("metrics", name + ".json")
+        args = dict(spec.get("args", {}))
+        for own in (self.cfg, self.mix):
+            args.update(own.get(ARGS_KEY, {}).get(name, {}))
+        return spec["reader"], args
 
     def reference(self):
         return load_module("reference", self.config_name)
@@ -280,9 +306,8 @@ class Run:
         out."""
         out = {}
         for m in self.cell.metrics("per_layer"):
-            spec = load_json("metrics", m["name"] + ".json")
-            reader = load_module("readers", spec["reader"])
-            value = reader.read(ctx, **spec.get("args", {}))
+            reader, args = self.cell.reader_of(m["name"])
+            value = load_module("readers", reader).read(ctx, **args)
             if value is None or not math.isfinite(value):
                 self.say(f"per-layer {m['name']}: nothing to read")
                 continue

@@ -1,7 +1,7 @@
 """Operations and bytes the ``command-a-plus-05-2026`` configuration needs,
-from shapes alone: the numerators of ``decode_step_roofline.cmda``,
-``prefill_roofline.cmda``, ``chunk_attention_roofline.cmda`` and
-``paged_kernel_roofline.cmda``.  They count the least the mathematics
+from shapes alone: the numerators of ``decode_step_roofline.pool``,
+``prefill_chunk_roofline.pool``, ``chunk_attention_roofline.pool`` and
+``paged_kernel_roofline.pool``.  They count the least the mathematics
 requires whatever implements it, for THIS chip's share (the held experts
 that got a row, never the absent ones; the four shared experts; the
 router over all its experts; the keys a row admits, a window's worth in

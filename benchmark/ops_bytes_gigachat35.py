@@ -1,8 +1,8 @@
 """Operations and bytes the ``gigachat35-432b-a28b`` configuration needs,
-from shapes alone: the numerators of ``decode_step_roofline.giga``,
-``prefill_roofline.giga``, ``mla_decode_bytes_roofline.giga``,
-``mla_decode_flops_roofline.giga``, ``mla_prefill_roofline.giga``,
-``gdn_step_roofline.giga`` and ``gdn_chunk_roofline.giga``.  They count the
+from shapes alone: the numerators of ``decode_step_roofline.pool``,
+``prefill_roofline.pool``, ``mla_decode_bytes_roofline.pool``,
+``mla_decode_flops_roofline.pool``, ``mla_prefill_roofline.pool``,
+``delta_step_roofline.pool`` and ``delta_chunk_roofline.pool``.  They count the
 least the mathematics requires whatever implements it, for THIS chip's
 share (the held experts that got a row, never the absent ones; the shared
 expert; the router over all its experts; the head over the vocabulary

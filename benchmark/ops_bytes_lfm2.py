@@ -1,6 +1,6 @@
 """Operations and bytes the ``lfm2-24b-a2b`` configuration needs, from
-shapes alone: the numerators of ``decode_step_roofline.lfm``,
-``prefill_roofline.lfm`` and ``paged_kernel_roofline.lfm``.  They count
+shapes alone: the numerators of ``decode_step_roofline.pool``,
+``prefill_roofline.pool`` and ``paged_kernel_roofline.pool``.  They count
 the least the mathematics requires (the experts a row was routed to, the
 keys a causal row attends, the head on one row), from the configuration's
 published keys, so no PR that changes the program can move them.  A count

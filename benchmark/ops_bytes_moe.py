@@ -1,6 +1,6 @@
 """Operations and bytes the ``smallthinker-21b-a3b`` configuration needs,
 from shapes alone: the numerators of ``decode_step_roofline.mix`` and
-``prefill_roofline.mix``.  They count the least the mathematics requires
+``prefill_roofline.pool``.  They count the least the mathematics requires
 (the experts a token was routed to, the keys inside a window, the head on
 one row), from the configuration's published keys, so no PR that changes
 the program can move them.  A count never exceeds what the program does: a

@@ -1,7 +1,7 @@
 """Operations and bytes the ``olmo-hybrid-7b`` configuration needs, from
-shapes alone: the numerators of ``decode_step_roofline.olmo``,
-``prefill_roofline.olmo``, ``paged_kernel_roofline.olmo``,
-``gdn_step_roofline.olmo`` and ``gdn_chunk_roofline.olmo``.  They count
+shapes alone: the numerators of ``decode_step_roofline.pool``,
+``prefill_roofline.pool``, ``paged_kernel_roofline.pool``,
+``delta_step_roofline.pool`` and ``delta_chunk_roofline.pool``.  They count
 the least the mathematics requires whatever implements it (the
 recurrence's 6 x 96 x 192 operations a head a token, a slot's state read
 once and written once a step, the keys a causal row attends, the head on

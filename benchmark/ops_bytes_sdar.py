@@ -1,6 +1,6 @@
 """Operations and bytes the ``sdar-30b-a3b-chat`` configuration needs, from
 shapes alone: the numerators of ``block_step_roofline.blk`` and
-``prefill_roofline.blk``.  They count the least the mathematics requires
+``prefill_roofline.pool``.  They count the least the mathematics requires
 (the experts a row was routed to, the keys a block-causal row attends, no
 head in a prefill that yields no row), from the configuration's published
 keys, so no PR that changes the program can move them.  A count never

@@ -1,7 +1,7 @@
 """Operations and bytes the ``solar-open2-250b`` configuration needs, from
-shapes alone: the numerators of ``decode_step_roofline.solar``,
-``prefill_roofline.solar``, ``paged_kernel_roofline.solar``,
-``kda_step_roofline.solar`` and ``kda_chunk_roofline.solar``.  They count
+shapes alone: the numerators of ``decode_step_roofline.pool``,
+``prefill_roofline.pool``, ``paged_kernel_roofline.pool``,
+``delta_step_roofline.pool`` and ``delta_chunk_roofline.pool``.  They count
 the least the mathematics requires whatever implements it, for THIS
 chip's share (the held experts that got a row, never the absent ones; the
 shared expert; the router over all its experts; the recurrence's 6 x 128

@@ -241,7 +241,7 @@ def test_mistral_counts_by_hand():
         == 8 * (436_207_616_000 + 8_192_000_000) + 262_144_000
 
 
-def test_roofline_reader_takes_its_arithmetic_from_the_metric_file():
+def test_roofline_reader_takes_its_arithmetic_from_the_cells_files():
     """A fake traced window: the decode module ran 3 times for 50 ms, one
     prefill module once for 400 ms after a span of 2000 tokens; 10 pages
     of 16 tokens live.  The shares are the named function over the named
@@ -262,15 +262,20 @@ def test_roofline_reader_takes_its_arithmetic_from_the_metric_file():
            "run": types.SimpleNamespace(peaks=peaks),
            "engine": types.SimpleNamespace(page_tokens=16)}
 
-    def args(name):
-        with open(os.path.join(BENCH, "metrics", name + ".json")) as f:
-            return json.load(f)["args"]
+    def args(name, cell):
+        reader_name, found = harness.Cell(cell).reader_of(name)
+        assert reader_name == "roofline"
+        return found
 
-    got = reader.read(ctx, **args("decode_step_roofline.chat"))
+    got = reader.read(ctx, **args("decode_step_roofline.chat",
+                                  "mistral7b-chat"))
     want = 100 * ops_bytes.mistral_decode_step_bytes(cfg, 160, 4) \
         / 819e9 / 0.05
     assert got == pytest.approx(want)
-    got = reader.read(ctx, **args("prefill_roofline.long"))
+    # the family's file gives the pairing and the peak, the cell's
+    # configuration the function that counts its work
+    got = reader.read(ctx, **args("prefill_roofline.pool",
+                                  "mistral7b-longprompt"))
     want = 100 * ops_bytes.mistral_prefill_flops(cfg, 2000) / 197e12 / 0.4
     assert got == pytest.approx(want)
 

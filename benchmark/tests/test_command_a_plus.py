@@ -30,19 +30,9 @@ os.environ.setdefault("TPU_LOG_DIR", "disabled")
 CELL = "command-a-plus-ragdocs"
 
 from test_compile_only import as_tpu, topo  # noqa: E402,F401 (fixtures)
-from test_manifest import POOL, reported_by  # noqa: E402
+from test_manifest import (  # noqa: E402
+    TABLE, check_cell, check_cell_loads, resolved)
 
-OWN = ["decode_step_roofline.cmda", "prefill_roofline.cmda",
-       "chunk_attention_roofline.cmda", "paged_kernel_roofline.cmda",
-       "chunk_attention_share_pct.cmda", "chunk_share_of_busy_pct.cmda",
-       "kv_window_pages_saved_pct.cmda",
-       "window_released_in_prefill_pct.cmda", "chunk_pad_pct.cmda",
-       "moe_pairs_held_pct.cmda", "moe_held_touched_pct.cmda"]
-# (as the expert siblings: ``moe_experts_touched_pct.pool`` divides by
-# ``num_experts``, here the experts HELD; ``attention_kernel_share_pct
-# .pool`` counts every Mosaic call that is no ragged-dot)
-SHARED_EXPERTS = ["moe_expert_load_max_over_mean.pool",
-                  "expert_matmul_share_pct.pool"]
 
 
 def _json(*parts):
@@ -254,24 +244,17 @@ def test_cell_is_declared_with_its_metrics():
     gate, = [m for m in bench["end_to_end"]
              if m["name"] == "served_tokens_per_s"]
     assert CELL in gate["workloads"] and gate["bound"] == 0.06
-    own, shared = reported_by(CELL)
-    assert sorted(own) == sorted(OWN)
-    assert sorted(shared) == sorted(POOL + SHARED_EXPERTS)
-    for m in bench["per_layer"]:
-        if CELL in m["workloads"]:
-            assert m["moves"] == "served_tokens_per_s"
-            if m["name"].endswith("_roofline.cmda"):
-                assert m["unit"] == "%" and m["source"] == "device_trace"
-    names = [m["name"] for m in bench["per_layer"]]
-    at = names.index(OWN[0])             # its own entries, in one run
-    assert names[at:at + len(OWN)] == OWN and at + len(OWN) == 123
-    import harness
-
-    for name in OWN:
-        spec = _json("metrics", name + ".json")
-        harness.load_module("readers", spec["reader"])
-        if "fn" in spec["args"]:
-            assert callable(harness.resolve(spec["args"]["fn"]))
+    # (as the expert siblings: ``moe_experts_touched_pct.pool`` divides by
+    # ``num_experts``, here the experts HELD; ``attention_kernel_share_pct
+    # .pool`` counts every Mosaic call that is no ragged-dot); the one
+    # cell that prefills in chunks, and so none of whole prompts
+    assert check_cell(CELL) == TABLE[CELL][2]
+    assert "experts, all held" not in TABLE[CELL][1]
+    assert [c for c, row in TABLE.items() if "chunked prefill" in row[1]] \
+        == [CELL] and "whole-prompt prefill" not in TABLE[CELL][1]
+    # the data files and the cell's own name readers and functions that
+    # are there
+    check_cell_loads(CELL)
 
 
 def test_new_readers_find_nothing_where_there_is_nothing():

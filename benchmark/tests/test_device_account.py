@@ -23,11 +23,13 @@ import harness  # noqa: E402
 with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
     SPEC = json.load(f)
 
+from test_manifest import TABLE  # noqa: E402
+
 FAMILIES = ("device_starved_pct", "device_idle_known_pct",
             "device_idle_slack_pct", "iter_offcpu_ms", "iter_unnamed_ms",
             "stream_cpu_pct", "pass_max_ms")
-POOL = ["mistral7b-longprompt", "smallthinker21b-mixedlen",
-        "sdar30b-blockgen", "lfm2-24b-longanswer"]
+# the closed-loop serving cells, by the one table of pins
+POOL = [cell for cell, row in TABLE.items() if "closed loop" in row[1]]
 
 
 def span(name, start, end, tid=1, **attrs):
@@ -82,12 +84,12 @@ SPANS = [
 ]
 
 
-def test_fourteen_entries_seven_families_for_the_five_serving_cells():
+def test_fourteen_entries_seven_families_for_the_serving_cells():
     """A family is two entries: ``.chat`` moves ``itl_p99_ms`` in the
-    one open-loop cell, ``.pool`` ``served_tokens_per_s`` in the four
-    closed-loop cells (one entry a family, not a copy a cell: PR 38
-    folded the older families the same way), and every entry has its
-    data file."""
+    one open-loop cell, ``.pool`` ``served_tokens_per_s`` in every
+    closed-loop cell (four at PR 36, eight since PR 51: one entry a
+    family, not a copy a cell), and every entry has its data file."""
+    assert len(POOL) == 8
     mine = [m for m in SPEC["per_layer"]
             if m["name"].rsplit(".", 1)[0] in FAMILIES]
     assert [m["name"] for m in mine] == [

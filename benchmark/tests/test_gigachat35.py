@@ -25,20 +25,9 @@ HBM_BYTES = 16 * 2 ** 30
 CELL = "gigachat35-ragturns"
 
 from test_compile_only import as_tpu, topo  # noqa: E402,F401 (fixtures)
-from test_manifest import POOL, reported_by  # noqa: E402
+from test_manifest import (  # noqa: E402
+    TABLE, check_cell, check_cell_loads, resolved)
 
-OWN = ["decode_step_roofline.giga", "prefill_roofline.giga",
-       "mla_decode_bytes_roofline.giga", "mla_decode_flops_roofline.giga",
-       "mla_prefill_roofline.giga", "mla_kernel_share_pct.giga",
-       "gdn_step_roofline.giga", "gdn_chunk_roofline.giga",
-       "gdn_kernel_share_pct.giga", "state_slots_pct.giga",
-       "scan_pad_pct.giga", "moe_pairs_held_pct.giga",
-       "moe_held_touched_pct.giga", "latent_fill_pct.giga"]
-# (as solar-open2-agentturns: ``moe_experts_touched_pct.pool`` divides by
-# ``num_experts`` and ``attention_kernel_share_pct.pool`` would count the
-# delta kernels)
-SHARED_EXPERTS = ["moe_expert_load_max_over_mean.pool",
-                  "expert_matmul_share_pct.pool"]
 
 
 def _json(*parts):
@@ -213,10 +202,10 @@ def test_counts_by_hand():
         == 2 * 4 * 32 * 64 * 128 * 128 * 4 == 1073741824
     assert ob.delta_chunk_bytes(CFG, 1000.0, 4) \
         == 4 * 4 * (64 * (4 * 128 + 2) * 1000 + 64 * 128 * 128)
-    assert _json("metrics", "state_slots_pct.giga.json")["args"]["scale"] \
+    assert resolved("state_slots_pct.pool", CELL)[1]["scale"] \
         == pytest.approx(100 / MIX["engine"]["num_slots"])
     e = MIX["engine"]
-    assert _json("metrics", "latent_fill_pct.giga.json")["args"]["scale"] \
+    assert resolved("latent_fill_pct.pool", CELL)[1]["scale"] \
         == pytest.approx(100 / (e["num_slots"] * e["max_seq_len"]))
     base = ob.decode_step_bytes(CFG, 0.0, 0.0, 0.0, 4)
     assert base == 4 * (4 * 235864320 + 159844352 + 5 * 4 * h + 396361728
@@ -254,23 +243,13 @@ def test_cell_is_declared_with_its_metrics():
     gate, = [m for m in bench["end_to_end"]
              if m["name"] == "served_tokens_per_s"]
     assert CELL in gate["workloads"] and gate["bound"] == 0.06
-    own, shared = reported_by(CELL)
-    assert sorted(own) == sorted(OWN)
-    assert sorted(shared) == sorted(POOL + SHARED_EXPERTS)
-    for m in bench["per_layer"]:
-        if CELL in m["workloads"]:
-            assert m["moves"] == "served_tokens_per_s"
-            if m["name"].endswith("_roofline.giga"):
-                assert m["unit"] == "%" and m["source"] == "device_trace"
-    names = [m["name"] for m in bench["per_layer"]]
-    assert names[-len(OWN):] == OWN      # its own entries, last, in one run
-    for name in OWN:
-        import harness
-
-        spec = _json("metrics", name + ".json")
-        harness.load_module("readers", spec["reader"])
-        if "fn" in spec["args"]:
-            assert callable(harness.resolve(spec["args"]["fn"]))
+    # one chip's share of the experts, and the one cell with latent pages
+    assert check_cell(CELL) == TABLE[CELL][2]
+    assert [c for c, row in TABLE.items() if "latent pages" in row[1]] \
+        == [CELL]
+    # the data files and the cell's own name readers and functions that
+    # are there
+    check_cell_loads(CELL)
 
 
 def test_rehearsal_reaches_its_last_line():

@@ -24,12 +24,8 @@ HBM_BYTES = 16 * 2 ** 30
 CELL = "olmo-hybrid7b-longdoc"
 
 from test_compile_only import as_tpu, topo  # noqa: E402,F401 (fixtures)
-from test_manifest import POOL, reported_by  # noqa: E402
-
-OWN = ["decode_step_roofline.olmo", "prefill_roofline.olmo",
-       "paged_kernel_roofline.olmo", "gdn_step_roofline.olmo",
-       "gdn_chunk_roofline.olmo", "gdn_kernel_share_pct.olmo",
-       "state_slots_pct.olmo", "scan_pad_pct.olmo"]
+from test_manifest import (  # noqa: E402
+    TABLE, check_cell, check_cell_loads, resolved)
 
 
 def _json(*parts):
@@ -137,7 +133,7 @@ def test_counts_by_hand():
     assert ob.paged_kernel_bytes(CFG, 32 * 3000.0, 4) == 30720 * 96000
     assert ob.delta_step_bytes(CFG, 32.0, 4) \
         == 2 * 3 * 32 * 30 * 96 * 192 * 4 == 424673280
-    assert _json("metrics", "state_slots_pct.olmo.json")["args"]["scale"] \
+    assert resolved("state_slots_pct.pool", CELL)[1]["scale"] \
         == pytest.approx(100 / MIX["engine"]["num_slots"])
     assert ob.delta_chunk_bytes(CFG, 1000.0, 4) \
         == 4 * 3 * (30 * (192 + 384 + 2) * 1000 + 30 * 96 * 192)
@@ -165,24 +161,16 @@ def test_cell_is_declared_with_its_metrics():
     cell, = [w for w in bench["workloads"] if w["name"] == CELL]
     assert (cell["chips"], cell["config"], cell["traffic"]) \
         == (1, "olmo-hybrid-7b", "longdoc-pool")
-    assert bench["workloads"][-1] is cell and len(bench["workloads"]) == 8
     assert sum(w["chips"] == 4 for w in bench["workloads"]) == 1
     config, = [c for c in bench["configs"] if c["name"] == "olmo-hybrid-7b"]
     assert config["source"] == CFG["source"] \
         and config["reduced"] == CFG["reduced"]
     gate, = [m for m in bench["end_to_end"]
              if m["name"] == "served_tokens_per_s"]
-    assert gate["workloads"][-1] == CELL and gate["bound"] == 0.06
-    own, shared = reported_by(CELL)
-    assert sorted(own) == sorted(OWN) and sorted(shared) == sorted(POOL)
-    for m in bench["per_layer"]:
-        if CELL in m["workloads"]:
-            assert m["moves"] == "served_tokens_per_s"
-            assert m["workloads"][-1] == CELL
-            if m["name"].endswith("_roofline.olmo"):
-                assert m["unit"] == "%" and m["source"] == "device_trace"
-    # the cell's own entries come last, in one run
-    assert [m["name"] for m in bench["per_layer"][-len(OWN):]] == OWN
+    assert CELL in gate["workloads"] and gate["bound"] == 0.06
+    # a dense decoder with delta layers: no group of the expert path
+    assert check_cell(CELL) == TABLE[CELL][2]
+    assert not [g for g in TABLE[CELL][1] if g.startswith("experts")]
 
 
 def test_the_prefill_kernel_reader_reads_spans_and_leaves_out_what_is_not_there():
@@ -222,12 +210,9 @@ def test_the_prefill_kernel_reader_reads_spans_and_leaves_out_what_is_not_there(
     assert reader.read(dict(ctx, trace_spans=old), *args) is None
     assert reader.read({}, *args) is None
     assert reader.read(ctx, *args[:3], "no such kernel") is None
-    # the data files name readers and functions that are there
-    for name in OWN:
-        spec = _json("metrics", name + ".json")
-        harness.load_module("readers", spec["reader"])
-        if "fn" in spec["args"]:
-            assert callable(harness.resolve(spec["args"]["fn"]))
+    # the data files and the cell's own name readers and functions that
+    # are there
+    check_cell_loads(CELL)
 
 
 def test_rehearsal_reaches_its_last_line():

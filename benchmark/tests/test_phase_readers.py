@@ -205,6 +205,49 @@ def test_idle_readers_return_none_without_a_trace(metric):
                        **args(metric)) is None
 
 
+def test_the_decode_program_is_told_by_more_than_a_run_count(monkeypatch):
+    """A traced window that held no finished prefill: the decode step and
+    the one-microsecond reshape that carries its tokens (PR 45) ran
+    equally often, and the reshape stands first on the trace's line.
+    ``split`` takes the most-run module of a millisecond or more, so a
+    roofline over the step's time does not read millions of percent; with
+    a prefill in the window, and on a trace of tiny programs alone, it
+    chooses as it did."""
+    module_time = harness.load_module("readers", "module_time")
+    steps = [(1.0 + 0.03 * i, 1.025 + 0.03 * i) for i in range(5)]
+    tiny = [(e + 1e-4, e + 1e-4 + 1e-6) for _, e in steps]
+    tie = {"modules": {"jit_reshape": tiny, "jit_decode": steps}}
+    assert module_time.split(tie) == (steps, [])
+    assert module_time.read({"trace": tie}, which="decode") \
+        == pytest.approx(25.0)
+    assert module_time.read({"trace": tie}, which="prefill") is None
+    # the reshape ran MORE often (a joiner's merge): still the step
+    more = {"modules": {"jit_reshape": tiny + [(2.0, 2.000001)],
+                        "jit_decode": steps}}
+    assert module_time.split(more)[0] == steps
+    # with prefills: every other module's runs of a millisecond or more
+    rung = [(1.2, 1.6)]
+    full = {"modules": {"jit_prefill_2048": rung, "jit_reshape": tiny,
+                        "jit_decode": steps}}
+    assert module_time.split(full) == (steps, rung)
+    # nothing of a millisecond: the most-run module, as before
+    assert module_time.split({"modules": {"a": tiny, "b": tiny[:2]}}) \
+        == (tiny, [])
+    assert module_time.split({"modules": {}}) == ([], [])
+    # what the tie did to a roofline: the step's share, not 25,000 times it
+    roofline_span = harness.load_module("readers", "roofline_span")
+    run = types.SimpleNamespace(trace_t0=0.0, trace_t1=10.0,
+                                peaks={"hbm_bytes_per_s": 819e9})
+    ctx = {"trace": tie, "run": run, "cfg": {"as_run": {"dtype": "float32"}},
+           "trace_spans": [span("generation/decode_step", 1.0, 1.03,
+                                state_slots=4)]}
+    monkeypatch.setattr(harness, "half_a_step", raising=False,
+                        value=lambda cfg, slots, itemsize: 819e9 * 0.0125)
+    assert roofline_span.read(
+        ctx, fn="harness.half_a_step", peak="hbm_bytes_per_s",
+        attrs=["state_slots"]) == pytest.approx(50.0)
+
+
 # -- both serving cells rehearsed, every new metric read by name ------------------
 
 REHEARSE = r"""
@@ -227,9 +270,9 @@ finally:
     run.cleanup()
 out = {}
 for name in sys.argv[4:]:
-    spec = harness.load_json("metrics", name + ".json")
-    reader = harness.load_module("readers", spec["reader"])
-    out[name] = reader.read(kept["ctx"], **spec.get("args", {})) is not None
+    reader, args = cell.reader_of(name)
+    out[name] = harness.load_module("readers", reader).read(
+        kept["ctx"], **args) is not None
 print(json.dumps({"read": out}))
 """
 

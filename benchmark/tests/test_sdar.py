@@ -22,7 +22,7 @@ HBM_BYTES = 16 * 2 ** 30
 CELL = "sdar30b-blockgen"
 
 from test_compile_only import as_tpu, topo  # noqa: E402,F401 (fixtures)
-from test_manifest import check_closed_loop_cell  # noqa: E402
+from test_manifest import TABLE, check_cell  # noqa: E402
 
 
 def _json(*parts):
@@ -134,8 +134,9 @@ def test_cell_is_declared_with_its_metrics():
     gate, = [m for m in bench["end_to_end"]
              if m["name"] == "served_tokens_per_s"]
     assert CELL in gate["workloads"] and gate["bound"] == 0.06
-    # its own entries by name and the shared ``.pool`` entries that list it
-    assert check_closed_loop_cell(CELL) == (4, 26)
+    # the entries of its groups, each moving the gate, and the start-up
+    # account's four: as many values as on its newest ledger line
+    assert check_cell(CELL) == TABLE[CELL][2]
 
 
 def test_new_readers_read_spans_and_leave_out_what_is_not_there():

@@ -24,19 +24,9 @@ HBM_BYTES = 16 * 2 ** 30
 CELL = "solar-open2-agentturns"
 
 from test_compile_only import as_tpu, topo  # noqa: E402,F401 (fixtures)
-from test_manifest import POOL, reported_by  # noqa: E402
+from test_manifest import (  # noqa: E402
+    TABLE, check_cell, check_cell_loads, resolved)
 
-OWN = ["decode_step_roofline.solar", "prefill_roofline.solar",
-       "paged_kernel_roofline.solar", "kda_step_roofline.solar",
-       "kda_chunk_roofline.solar", "kda_kernel_share_pct.solar",
-       "state_slots_pct.solar", "scan_pad_pct.solar",
-       "moe_held_touched_pct.solar", "moe_pairs_held_pct.solar"]
-# of the families the expert cells share, those whose reader and
-# arguments mean the same here (``moe_experts_touched_pct.pool`` divides
-# by ``num_experts``, a key this configuration does not have, and
-# ``attention_kernel_share_pct.pool`` would count the delta kernels)
-SHARED_EXPERTS = ["moe_expert_load_max_over_mean.pool",
-                  "expert_matmul_share_pct.pool"]
 
 
 def _json(*parts):
@@ -178,7 +168,7 @@ def test_counts_by_hand():
     assert ob.paged_kernel_bytes(CFG, 64 * 1500.0, 4) == 8192 * 96000
     assert ob.kda_step_bytes(CFG, 64.0, 4) \
         == 2 * 3 * 64 * 64 * 128 * 128 * 4 == 1610612736
-    assert _json("metrics", "state_slots_pct.solar.json")["args"]["scale"] \
+    assert resolved("state_slots_pct.pool", CELL)[1]["scale"] \
         == pytest.approx(100 / MIX["engine"]["num_slots"])
     assert ob.kda_chunk_bytes(CFG, 1000.0, 4) \
         == 4 * 3 * (64 * (5 * 128 + 1) * 1000 + 64 * 128 * 128)
@@ -216,24 +206,14 @@ def test_cell_is_declared_with_its_metrics():
     gate, = [m for m in bench["end_to_end"]
              if m["name"] == "served_tokens_per_s"]
     assert CELL in gate["workloads"] and gate["bound"] == 0.06
-    own, shared = reported_by(CELL)
-    assert sorted(own) == sorted(OWN)
-    assert sorted(shared) == sorted(POOL + SHARED_EXPERTS)
-    for m in bench["per_layer"]:
-        if CELL in m["workloads"]:
-            assert m["moves"] == "served_tokens_per_s"
-            if m["name"].endswith("_roofline.solar"):
-                assert m["unit"] == "%" and m["source"] == "device_trace"
-    names = [m["name"] for m in bench["per_layer"]]
-    at = names.index(OWN[0])
-    assert names[at:at + len(OWN)] == OWN   # its own entries, in one run
-    for name in OWN:
-        import harness
-
-        spec = _json("metrics", name + ".json")
-        harness.load_module("readers", spec["reader"])
-        if "fn" in spec["args"]:
-            assert callable(harness.resolve(spec["args"]["fn"]))
+    # one chip's share of the experts: the share's two entries, not the
+    # two of a cell that holds them all
+    assert check_cell(CELL) == TABLE[CELL][2]
+    assert "experts, a share held" in TABLE[CELL][1] \
+        and "experts, all held" not in TABLE[CELL][1]
+    # the data files and the cell's own name readers and functions that
+    # are there
+    check_cell_loads(CELL)
 
 
 def test_rehearsal_reaches_its_last_line():
