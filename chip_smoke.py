@@ -44,6 +44,15 @@ random seeded weights:
   ``attention_lowered_chunk_reference`` stays 0, and the logits are the
   single-shot prefill's.
 
+* **chunks over latent pages** (PR 56) — the chunk kernel over latent
+  rows (``mla_chunk_attention``) at 128 heads of nope 128 + rope 64 over a
+  latent of 512, 512 rows at ``base`` 4096, against "highest" einsums of
+  the expanded arithmetic; and a two-layer all-latent decoder with a
+  group-limited router (one whole group held, YaRN at ``mscale_all_dim``
+  0.707) that prefills a 1,300-token prompt in chunks of 256 over latent
+  pages: ``attention_lowered_latent_chunk`` +4, ``_latent_chunk_reference``
+  +0, and the logits are the single-shot prefill's.
+
 Any failed check raises: the exit code is non-zero and no result line is
 printed.  Without a TPU backend the script refuses to run (exit 2).  The
 last line of stdout is one JSON object
@@ -1374,6 +1383,133 @@ def chunk_phase(cfg=CHUNKS):
         f"{rel:.4g} of the single-shot prefill's")
 
 
+LATENT_CHUNK = dict(heads=128, latent=512, nope=128, rope=64, v=128,
+                    rows=512, base=4096, view=6144, check_heads=16,
+                    engine=dict(hidden=1024, heads=16, q_rank=384, ffn=2048,
+                                vocab=4096, chunk=256, max_seq=2048,
+                                page_tokens=16, prompt=1300, steps=4,
+                                experts=16, groups=4, keep=2, top_k=3,
+                                width=256))
+
+
+def latent_chunk_phase(cfg=LATENT_CHUNK):
+    """What an all-latent decoder with group-limited routing adds (PR 56):
+    the chunk kernel over latent rows (``mla_chunk_attention``) at
+    DeepSeek-V2's published head sizes (128 heads of nope 128 + rope 64
+    over a latent of 512, values of 128), 512 rows at base 4096 over a
+    view whose rows behind the chunk are zero, against "highest" einsums of
+    the expanded arithmetic on a few of the heads; then a 1,300-token
+    prompt in chunks of 256 over latent pages through a two-slot engine
+    (one latent layer over the dense SwiGLU, one over a group-limited
+    router of which one whole group is held, YaRN with ``mscale_all_dim``),
+    with the lowering counters and the single-shot prefill's logits."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.monitor import stat_get
+    from paddle_tpu.ops.pallas import latent_attention as la
+    from paddle_tpu.serving import GenerationEngine
+
+    H, C, dn, dr, dv = (cfg[k] for k in ("heads", "latent", "nope", "rope",
+                                         "v"))
+    T, base, S = cfg["rows"], cfg["base"], cfg["view"]
+    row = la.row_lanes(C + dr)
+    key = jax.random.key(56)
+    qn = jax.random.normal(jax.random.fold_in(key, 0), (H, T, dn)) * 0.3
+    qr = jax.random.normal(jax.random.fold_in(key, 1), (H, T, dr)) * 0.3
+    live = (jnp.arange(S) < base + T)[:, None]
+    rows = jnp.pad(jnp.where(live, jax.random.normal(
+        jax.random.fold_in(key, 2), (S, C + dr)), 0.0),
+        ((0, 0), (0, row - C - dr)))
+    w = jax.random.normal(jax.random.fold_in(key, 3),
+                          (C, H * (dn + dv))) * C ** -0.5
+    scale = (dn + dr) ** -0.5
+    at = jnp.asarray([base], jnp.int32)
+    got = la.mla_chunk_attention(qn, qr, rows, w, at, scale=scale,
+                                 nope_dim=dn, latent_dim=C)
+    G = cfg["check_heads"]
+    hi = jax.lax.Precision.HIGHEST
+    kv = jnp.dot(rows[:, :C], w[:, :G * (dn + dv)],
+                 precision=hi).reshape(S, G, dn + dv)
+    s = (jnp.einsum("hqd,shd->hqs", qn[:G], kv[..., :dn], precision=hi)
+         + jnp.einsum("hqr,sr->hqs", qr[:G], rows[:, C:C + dr],
+                      precision=hi)) * scale
+    keep = jnp.arange(S)[None, :] <= base + jnp.arange(T)[:, None]
+    want = jnp.einsum("hqs,shd->hqd", jax.nn.softmax(
+        jnp.where(keep[None], s, -jnp.inf), -1), kv[..., dn:], precision=hi)
+    rel = float(jnp.abs(got[:G] - want).max() / jnp.abs(want).max())
+    check(bool(jnp.isfinite(got).all()) and rel <= 2.0 ** -16,
+          f"mla_chunk_attention off the einsums by {rel:.4g}")
+    say(f"latent chunks: {T} rows of {H} heads at base {base} over "
+        f"{base + T} latent rows within {rel:.4g} of the expanded einsums "
+        f"({G} heads compared)")
+    del qn, qr, rows, w, kv, s, want, got
+
+    e = cfg["engine"]
+    mla = {"q_rank": e["q_rank"], "kv_rank": C, "nope_dim": dn,
+           "rope_dim": dr, "v_dim": dv, "interleave": True,
+           "yarn": {"factor": 40, "original_max": 4096, "beta_fast": 32,
+                    "beta_slow": 1, "mscale": 0.707, "mscale_all_dim": 0.707}}
+    per = e["experts"] // e["groups"]
+    moe = {"experts": e["experts"], "held": (per, per), "top_k": e["top_k"],
+           "width": e["width"], "activation": "silu", "route_from": "normed",
+           "norm_topk": False, "route_scale": 16.0, "n_group": e["groups"],
+           "topk_group": e["keep"], "shared_width": 2 * e["width"]}
+    model = dict(vocab_size=e["vocab"], hidden=e["hidden"], num_layers=2,
+                 num_heads=e["heads"], num_kv_heads=e["heads"],
+                 intermediate=e["ffn"], rms_norm_eps=1e-6, rope_base=1e4,
+                 layer_pattern=[{"mla": mla}, {"mla": mla, "ffn": moe}])
+    args = dict(num_slots=2, max_seq_len=e["max_seq"], eos_id=-1,
+                page_tokens=e["page_tokens"], prefix_reuse=False,
+                speculate=False, keep_logits=True, deadline_ms=600000)
+    names = ("attention_lowered_latent_chunk",
+             "attention_lowered_latent_chunk_reference")
+    before = [stat_get(n) for n in names]
+    prompt = np.random.default_rng(56).integers(
+        1, e["vocab"], e["prompt"]).tolist()
+    whole = GenerationEngine(model, prefill_chunk=0,
+                             prefill_buckets=[2048], **args)
+    try:
+        want = np.stack(whole.generate(prompt, e["steps"],
+                                       timeout=600)["logits"])
+        chunked = GenerationEngine(
+            model, scope=whole.scope, prefill_chunk=e["chunk"],
+            prefill_buckets=[64, e["chunk"]], **args)
+        try:
+            res = chunked.generate(prompt, e["steps"], timeout=600)
+            counters = chunked.stats()["counters"]
+        finally:
+            chunked.close()
+    finally:
+        whole.close()
+    got = np.stack(res["logits"])
+    grew = [stat_get(n) - b for n, b in zip(names, before)]
+    rel = float(np.abs(got - want).max() / np.abs(want).max())
+    chunks = -(-e["prompt"] // e["chunk"])
+    check(counters["prefill_chunks"] == chunks
+          and counters["moe_tokens_dropped"] == 0
+          and 0 < counters["moe_pairs_held"] < counters["moe_pairs_routed"]
+          and counters["moe_rows_group_held"] > 0,
+          f"chunked latent prefill ran {counters['prefill_chunks']} "
+          f"chunks; pairs held {counters['moe_pairs_held']} of "
+          f"{counters['moe_pairs_routed']}, rows that kept the held group "
+          f"{counters['moe_rows_group_held']}")
+    # two chunk rungs built, one op a layer each; nothing downgraded
+    check(grew == [4, 0], f"chunk programs lowered {dict(zip(names, grew))}"
+          f", expected every latent chunk on the Pallas kernel")
+    check(bool(np.isfinite(got).all()) and rel <= 2.0 ** -10,
+          f"chunked latent prefill off the single-shot prefill by "
+          f"{rel:.4g}")
+    say(f"latent chunks: a {e['prompt']}-token prompt in {chunks} chunks "
+        f"of {e['chunk']} over latent pages, group-limited routing with "
+        f"one group of {per} held ({counters['moe_pairs_held']} of "
+        f"{counters['moe_pairs_routed']} pairs, "
+        f"{counters['moe_rows_group_held']} row-layers kept the group); "
+        f"attention_lowered_latent_chunk +{grew[0]}, "
+        f"_latent_chunk_reference +{grew[1]}; logits within {rel:.4g} of "
+        f"the single-shot prefill's")
+
+
 def main():
     t_start = time.perf_counter()
     # the program first: in a directory that holds only this file the
@@ -1448,6 +1584,12 @@ def main():
     t0 = time.perf_counter()
     chunk_phase()
     say(f"chunk attention kernel and chunks over two page kinds done "
+        f"[{time.perf_counter() - t0:.1f} s]")
+    gc.collect()
+
+    t0 = time.perf_counter()
+    latent_chunk_phase()
+    say(f"latent chunk kernel and chunks over latent pages done "
         f"[{time.perf_counter() - t0:.1f} s]")
 
     say(f"set-up (compile-dominated: kernel check + first train step + "

@@ -25,7 +25,8 @@ __all__ = [
     "cached_attention", "chunk_attention", "kv_pool_write",
     "kv_pool_gather",
     "paged_decode_attention", "latent_prefill_attention",
-    "latent_decode_attention", "block_begin", "block_unmask",
+    "latent_decode_attention", "latent_chunk_attention", "block_begin",
+    "block_unmask",
     "short_conv", "short_conv_tail", "slot_state_write", "short_conv_step",
     "gated_delta_chunk", "gated_delta_step",
     "linear_chain_crf", "crf_decoding", "warpctc",
@@ -826,6 +827,29 @@ def latent_decode_attention(q_nope, q_rope, w_kvb, pool, block_table,
     return out
 
 
+def latent_chunk_attention(q_nope, q_rope, w_kvb, pool, block_table,
+                           positions, lengths, scale, value_dim, name=None):
+    """A latent (MLA) layer's prefill-chunk attention: ``q_nope`` [1, H, C,
+    nope] and ``q_rope`` [1, H, C, rope], the chunk's rows at
+    ``positions[0] + t``, over the slot's latent pages (the chunk's own
+    rows already written; ``lengths`` [1] of them real) through
+    ``block_table`` [1, NP], in the expanded arithmetic with ``w_kvb``
+    expanding each cached row (ops/latent_attention_ops.py).  Returns
+    [1, H, C, value_dim]."""
+    helper = LayerHelper("latent_chunk_attention", name=name)
+    out = helper.create_variable_for_type_inference(q_nope.dtype)
+    helper.append_op("latent_chunk_attention",
+                     inputs={"QNope": [q_nope], "QRope": [q_rope],
+                             "Wkvb": [w_kvb], "Pool": [pool],
+                             "BlockTable": [block_table],
+                             "Positions": [positions],
+                             "Lengths": [lengths]},
+                     outputs={"Out": [out]},
+                     attrs={"scale": float(scale),
+                            "value_dim": int(value_dim)})
+    return out
+
+
 def block_begin(tokens, masked, fresh, mask_id, name=None):
     """Block diffusion (ops/decode_ops.py ``block_begin``): ``tokens``
     and ``masked`` [S, B] as the last pass left them, except where
@@ -1345,7 +1369,7 @@ def moe_routed_ffn(x, router_x, num_experts, top_k, d_ff,
                    activation="relu", valid=None, name=None,
                    keep_router_logits=False, score="softmax",
                    expert_bias=False, norm_topk=True, route_scale=1.0,
-                   held=None, limit=None):
+                   held=None, limit=None, n_group=1, topk_group=1):
     """Dropless top-k mixture of gated experts without bias
     (ops/moe_ops.py ``moe_routed_ffn``): each token of ``x`` [B, S, H]
     goes to the ``top_k`` experts its row of ``router_x`` [B, S, H]
@@ -1365,6 +1389,10 @@ def moe_routed_ffn(x, router_x, num_experts, top_k, d_ff,
     share of an expert-parallel group): the expert matrices have
     ``count`` leading rows and ``out`` is those experts' part of the sum.
     ``limit`` L: ``act(min(gate, L)) * clip(up, -L, L)``.
+    ``n_group`` > 1 with ``topk_group``: group-limited selection over
+    softmax scores (``route_top_k``); ``expert_count`` then comes back as
+    the pair ``(expert_count, group_rows [n_group] int32)``, the valid rows
+    that kept each group.
     ``name`` prefixes the parameters ``.router.w`` [H, E], ``.gate_up.w``
     [E, H, 2 d_ff] and ``.down.w`` [E, d_ff, H].  Returns ``(out,
     expert_count [E] int32, router_logits or None)``."""
@@ -1394,7 +1422,8 @@ def moe_routed_ffn(x, router_x, num_experts, top_k, d_ff,
     if valid is not None:
         inputs["Valid"] = [valid]
     attrs = {"top_k": int(top_k), "activation": activation}
-    if score != "softmax" or not norm_topk or route_scale != 1.0:
+    if score != "softmax" or not norm_topk or route_scale != 1.0 \
+            or int(n_group) > 1:
         attrs.update(score=score, norm_topk=bool(norm_topk),
                      route_scale=float(route_scale))
     if held is not None:
@@ -1405,6 +1434,14 @@ def moe_routed_ffn(x, router_x, num_experts, top_k, d_ff,
         inputs["ExpertBias"] = [helper.create_parameter(
             p("expert_bias"), [e], "float32", is_bias=True)]
     outputs = {"Out": [out], "ExpertCount": [counts]}
+    if int(n_group) > 1:
+        if e % int(n_group) or not 1 <= int(topk_group) <= int(n_group):
+            raise ValueError(f"moe_routed_ffn keeps {topk_group} of "
+                             f"{n_group} groups of {e} experts")
+        attrs.update(n_group=int(n_group), topk_group=int(topk_group))
+        group_rows = helper.create_variable_for_type_inference("int32")
+        outputs["GroupRows"] = [group_rows]
+        counts = (counts, group_rows)
     logits = None
     if keep_router_logits:
         logits = helper.create_variable_for_type_inference("float32")

@@ -76,7 +76,10 @@ def _program_build(kind, bucket_at=None):
 #           ("softmax", or "sigmoid": each expert scored on its own),
 #           "expert_bias" (True: a learned [E] float32 bias moves the
 #           choice, never the weights), "norm_topk" (True), "route_scale"
-#           (1.0).  "held": (first, count): this chip holds that range of
+#           (1.0), "n_group" / "topk_group" (group-limited selection over
+#           softmax scores: the E experts in n_group groups of consecutive
+#           indices, a token's experts out of its topk_group best groups).
+#           "held": (first, count): this chip holds that range of
 #           the E experts, its share of an expert-parallel group: the
 #           router scores all E, only the held experts' pairs are
 #           multiplied and their part of the sum goes on (no exchange, and
@@ -110,8 +113,10 @@ def _program_build(kind, bucket_at=None):
 #   mla:    None, or a dict that makes the attention layer LATENT
 #           (:func:`_mla_mixer`): {"q_rank": Rq, "kv_rank": C, "nope_dim":
 #           dn, "rope_dim": dr, "v_dim": dv, "scale": the softmax scale
-#           (default (dn + dr) ** -0.5), "interleave": rotate pairs (2i,
-#           2i + 1), "yarn": None or ``layers.rope``'s dict}: queries
+#           (default (dn + dr) ** -0.5, times YaRN's ``mscale_all_dim``
+#           factor squared where "yarn" has that key: ``_mla_scale``),
+#           "interleave": rotate pairs (2i, 2i + 1), "yarn": None or
+#           ``layers.rope``'s dict}: queries
 #           through a low-rank pair with a norm between, ONE latent
 #           ``c_kv`` [C] and one rotated key ``k_r`` [dr] a token shared
 #           by all heads, which are all that is cached (``cache_spec``
@@ -250,10 +255,14 @@ def _linear(x, size, pname=None, name=None):
 def _taps_fetches(taps):
     """What a program's expert layers recorded, as fetches:
     ``expert_counts`` [L_moe, E] int32 (tokens each expert got, valid
-    rows only) and, where kept, ``router_logits`` [B, L_moe, E]."""
+    rows only), under group-limited selection ``expert_group_rows``
+    [L_moe, n_group] int32 (valid rows that kept each group) and, where
+    kept, ``router_logits`` [B, L_moe, E]."""
     out = {}
     if taps.get("counts"):
         out["expert_counts"] = layers.stack(taps["counts"], axis=0)
+    if taps.get("group_rows"):
+        out["expert_group_rows"] = layers.stack(taps["group_rows"], axis=0)
     if taps.get("logits"):
         out["router_logits"] = layers.stack(taps["logits"], axis=1)
     return out
@@ -592,7 +601,7 @@ def llama_block(x, hidden, num_heads, num_kv_heads, seq_len, head_dim,
             h, seq_len, hidden, num_heads, layer, p, rms_norm_eps,
             rope_base, attn_impl, kv_cache=kv_cache, positions=positions,
             block_table=block_table, kv_lengths=kv_lengths,
-            want_row=collect_kv)
+            want_row=collect_kv, chunk_pages=chunk_pages)
         out = _ffn(layers.elementwise_add(x, post_normed(y)), x_in, hidden,
                    intermediate, **ffn_args)
         return (out, row, None) if collect_kv else out
@@ -686,9 +695,31 @@ def llama_block(x, hidden, num_heads, num_kv_heads, seq_len, head_dim,
     return out
 
 
+def _mla_scale(mla):
+    """A latent layer's softmax scale: ``mla["scale"]`` where given, else
+    ``(nope + rope) ** -0.5``, times ``yarn_mscale(factor, mscale_all_dim)
+    ** 2`` where its ``yarn`` dict carries ``mscale_all_dim`` (cos and sin
+    carry ``mscale / mscale_all_dim`` of the same formula, which must come
+    out as 1: another factor is not built)."""
+    from ..ops.rope_ops import yarn_mscale
+
+    if mla.get("scale"):
+        return float(mla["scale"])
+    scale = (int(mla["nope_dim"]) + int(mla["rope_dim"])) ** -0.5
+    yarn = mla.get("yarn") or {}
+    if yarn.get("mscale_all_dim"):
+        factor, all_dim = yarn["factor"], yarn["mscale_all_dim"]
+        if yarn_mscale(factor, yarn.get("mscale", all_dim)) \
+                != yarn_mscale(factor, all_dim):
+            raise ValueError("YaRN with mscale != mscale_all_dim (a factor "
+                             "on cos and sin) is not built")
+        scale *= yarn_mscale(factor, all_dim) ** 2
+    return scale
+
+
 def _mla_mixer(h, seq_len, hidden, num_heads, layer, p, eps, rope_base,
                attn_impl, kv_cache=None, positions=None, block_table=None,
-               kv_lengths=None, want_row=False):
+               kv_lengths=None, want_row=False, chunk_pages=False):
     """Latent attention (MLA) on normed rows h [B, S, H]: ``c_q = norm(h
     W_qa)``, ``[q_nope | q_rope] = c_q W_qb`` a head; ``[c_kv | k_r] = h
     W_kva``, ``c_kv = norm(c_kv)``; ``q_rope`` and the one ``k_r`` rotated;
@@ -698,13 +729,18 @@ def _mla_mixer(h, seq_len, hidden, num_heads, layer, p, eps, rope_base,
     is the row ``[c_kv | k_r]`` (post-norm, post-RoPE), padded to the
     pool's whole lane tiles.
 
-    Two paths.  **Expanded** (whole sequences: the full forward and the
+    Three paths.  **Expanded** (whole sequences: the full forward and the
     prefill): keys and values of every head are made from the latent and
     ``latent_prefill_attention`` runs over them.  **Absorbed** (the
     decode step, ``kv_cache`` the layer's one pool and ``seq_len`` 1): the
     row is written, then ``latent_decode_attention`` meets the cached
-    rows as they lie, with ``W_kvb`` read as ``W_UK`` and ``W_UV``: the
-    one parameter ``.kv_b.w`` in both paths.  Returns ``(y, row)``: the
+    rows as they lie, with ``W_kvb`` read as ``W_UK`` and ``W_UV``.  **A
+    chunk** (``kv_cache`` and ``seq_len`` > 1: S new rows of one slot at
+    ``positions[0]``): the rows are written (``chunk_pages``: as whole
+    pages), then ``latent_chunk_attention`` expands the slot's cached rows
+    block by block through ``W_kvb`` and the chunk attends them and itself
+    causally; the chunk at base 0 is the same program.  The one parameter
+    ``.kv_b.w`` in all three.  Returns ``(y, row)``: the
     rows [B, 1, S, ROW] with ``want_row`` (a prefill scatters them), else
     None."""
     from ..ops.latent_attention_ops import latent_pool_shape
@@ -712,7 +748,7 @@ def _mla_mixer(h, seq_len, hidden, num_heads, layer, p, eps, rope_base,
     mla = layer["mla"]
     rank_q, rank_kv = int(mla["q_rank"]), int(mla["kv_rank"])
     dn, dr, dv = (int(mla[k]) for k in ("nope_dim", "rope_dim", "v_dim"))
-    scale = float(mla.get("scale") or (dn + dr) ** -0.5)
+    scale = _mla_scale(mla)
     rot = dict(base=rope_base, interleave=bool(mla.get("interleave")),
                yarn=mla.get("yarn"),
                offset=positions if kv_cache is not None else None)
@@ -745,17 +781,19 @@ def _mla_mixer(h, seq_len, hidden, num_heads, layer, p, eps, rope_base,
             [0, 0, 0, 0, 0, 0, 0, lanes - rank_kv - dr])
     kv_b = num_heads * (dn + dv)
     if kv_cache is not None:
-        if seq_len != 1:
-            raise ValueError(
-                "a chunk of rows that attends latent pages is not built "
-                "(the absorbed path is the one-row decode step's)")
+        form = {"whole_pages": True} if chunk_pages and seq_len > 1 else {}
         pool = layers.kv_pool_write(kv_cache[0], row, positions,
-                                    block_table, kv_lengths)
-        attn = layers.latent_decode_attention(
-            q_nope, q_rope,
-            layers.create_parameter([rank_kv, kv_b], "float32",
-                                    name=p("kv_b.w")),
-            pool, block_table, positions, scale, dv)
+                                    block_table, kv_lengths, **form)
+        w_kvb = layers.create_parameter([rank_kv, kv_b], "float32",
+                                        name=p("kv_b.w"))
+        if seq_len == 1:
+            attn = layers.latent_decode_attention(
+                q_nope, q_rope, w_kvb, pool, block_table, positions, scale,
+                dv)
+        else:
+            attn = layers.latent_chunk_attention(
+                q_nope, q_rope, w_kvb, pool, block_table, positions,
+                kv_lengths, scale, dv)
     else:
         kv = heads(_linear(c_kv, kv_b, pname=p("kv_b.w")), num_heads,
                    dn + dv)
@@ -820,8 +858,12 @@ def _ffn(x, x_in, hidden, intermediate, ffn, p, rms_norm_eps, valid, taps,
             activation=ffn.get("activation", "relu"), valid=valid,
             name=p("moe"), keep_router_logits=bool(taps.get("keep_logits")),
             **{k: ffn[k] for k in ("score", "expert_bias", "norm_topk",
-                                   "route_scale", "held") if k in ffn},
+                                   "route_scale", "held", "n_group",
+                                   "topk_group") if k in ffn},
             **clamp)
+        if int(ffn.get("n_group", 1)) > 1:
+            counts, group_rows = counts
+            taps.setdefault("group_rows", []).append(group_rows)
         taps.setdefault("counts", []).append(counts)
         if logits is not None:
             taps.setdefault("logits", []).append(logits)
@@ -1301,8 +1343,11 @@ def _chunk_forward(chunk_len, max_seq_len, num_pages, page_tokens,
     ``base - window + 1``'s page may be the trash page.  ``page_aligned``:
     the caller vouches that ``base`` is a page boundary (``chunk_len`` a
     whole number of pages is checked), so every layer's K/V go in page by
-    page and no pool is re-laid for the write.  Layers that keep slot
-    state and latent (``mla``) layers have no such program."""
+    page and no pool is re-laid for the write.  A latent (``mla``) layer
+    writes the chunk's ``[c_kv | k_r]`` rows into its one pool and attends
+    the slot's latent rows expanded block by block
+    (``latent_chunk_attention``).  Layers that keep slot state have no such
+    program."""
     from ..framework.core import default_main_program
 
     if state_layers(layer_pattern, num_layers):
@@ -1311,12 +1356,6 @@ def _chunk_forward(chunk_len, max_seq_len, num_pages, page_tokens,
             "speculative verify) is not built for a model whose layers "
             "keep slot state: a chunk would have to start from, and a "
             "rejected draft roll back, state that is not pages")
-    if any(layer_spec(layer_pattern, i)["mla"] for i in range(num_layers)):
-        raise ValueError(
-            "prefill continuation (chunked prefill, prefix reuse, "
-            "speculative verify) is not built for a model with latent "
-            "(mla) attention layers: a chunk would attend latent pages "
-            "by the expanded path, from rows it has to expand first")
     if page_aligned and chunk_len % page_tokens:
         raise ValueError(f"a page-aligned chunk is whole pages: "
                          f"{chunk_len} rows over pages of {page_tokens}")
@@ -1351,12 +1390,11 @@ def _chunk_forward(chunk_len, max_seq_len, num_pages, page_tokens,
                          param_attr=f"{name}.embed")
     taps = {"keep_logits": keep_router_logits}
     for i in range(num_layers):
-        ck, cv = _cache_vars(block, spec, i)
         # rope offset = base per row; the attention's validity mask
         # (j <= base + t) is exactly causal-over-prefix-plus-chunk
         x = llama_block(x, hidden, num_heads, num_kv_heads, chunk_len,
                         head_dim, intermediate, name=f"{name}.blk{i}",
-                        kv_cache=(ck, cv), positions=base,
+                        kv_cache=_cache_vars(block, spec, i), positions=base,
                         block_table=bt_window if i in windowed
                         else block_table, kv_lengths=ck_len,
                         rms_norm_eps=rms_norm_eps, rope_base=rope_base,

@@ -48,6 +48,11 @@ _LOWERED = {
     "latent_decode": _monitor.get("attention_lowered_latent_decode"),
     "latent_decode_reference":
         _monitor.get("attention_lowered_latent_decode_reference"),
+    # ... and a prefill chunk over latent pages: the Pallas kernel that
+    # expands each key block in VMEM, or einsums over the whole view
+    "latent_chunk": _monitor.get("attention_lowered_latent_chunk"),
+    "latent_chunk_reference":
+        _monitor.get("attention_lowered_latent_chunk_reference"),
     # a prefill chunk over the slot's cache view (ops/decode_ops.py
     # ``chunk_attention``): the Pallas kernel, or the einsum formulation
     "chunk_pallas": _monitor.get("attention_lowered_chunk_pallas"),

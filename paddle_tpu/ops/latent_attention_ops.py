@@ -20,13 +20,32 @@ pool of one head is.
   ``mla_decode_attention`` reads each live page once; anywhere else the
   gathered view and einsums of the same absorbed arithmetic.
 
-Both are inference only and book which lowering ran
+* ``latent_chunk_attention``: a **prefill chunk** over latent pages.
+  QNope [1, H, C, nope] and QRope [1, H, C, rope], the chunk's rows at
+  ``positions[0] + t``, Wkvb, Pool (the chunk's own rows already written),
+  BlockTable [1, NP], Positions [1], Lengths [1] (the chunk's real rows).
+  The slot's logical view of its latent rows is gathered ([S, ROW]: 2.5 KB
+  a position, 33 MB at 12,800), rows behind the chunk's last real one are
+  zeroed (a recycled page's NaN must meet no product), and row ``t``
+  attends ``j <= positions[0] + t`` in the EXPANDED arithmetic: ``[k_nope
+  | v] = c_kv W_kvb`` a head.  A TPU backend, one device: the Pallas kernel
+  ``mla_chunk_attention`` expands each key block in VMEM, so no head's
+  keys or values lie in HBM; anywhere else einsums of the same arithmetic
+  over the whole view (toy sizes: ``[S, H, nope + v]`` is made).
+
+All are inference only and book which lowering ran
 (``attention_lowered_latent_prefill``, ``_latent_decode``,
-``_latent_decode_reference``), the last with its reason, once, on a TPU.
+``_latent_decode_reference``, ``_latent_chunk``,
+``_latent_chunk_reference``), a reference with its reason, once, on a TPU.
 """
 from __future__ import annotations
 
 from .registry import in_var, register_op, set_out
+
+
+# cached rows the chunk kernel expands at a time: the engine's span
+# attribute ``latent_rows_expanded`` counts in these
+CHUNK_BLOCK_K = 512
 
 
 def latent_pool_shape(num_pages, page_tokens, latent_dim, rope_dim):
@@ -146,3 +165,69 @@ def _latent_decode_attention(ctx, op):
         _lowered("latent_decode_reference", reason)
     out = jnp.einsum("bhc,chd->bhd", o_lat, w_uv, precision=prec)
     ctx.set_output(op, "Out", out[:, :, None].astype(q_nope.dtype))
+
+
+def _chunk_infer(op, block):
+    q = in_var(op, block, "QNope")
+    set_out(op, block, "Out", tuple(q.shape[:-1])
+            + (int(op.attr("value_dim")),), q.dtype)
+
+
+@register_op("latent_chunk_attention", infer=_chunk_infer, grad=None)
+def _latent_chunk_attention(ctx, op):
+    import jax
+    import jax.numpy as jnp
+
+    from .attention_ops import _lowered
+    from .decode_ops import _gather_pages
+    from .math_ops import _mm_precision
+    from .pallas import latent_attention
+
+    q_nope = ctx.get_input(op, "QNope")                     # [1, H, C, nope]
+    q_rope = ctx.get_input(op, "QRope")
+    w_kvb = ctx.get_input(op, "Wkvb")
+    pool = ctx.get_input(op, "Pool")
+    bt = ctx.get_input(op, "BlockTable").astype(jnp.int32)
+    base = ctx.get_input(op, "Positions").astype(jnp.int32)
+    length = ctx.get_input(op, "Lengths").astype(jnp.int32)
+    scale = float(op.attr("scale"))
+    B, H, T, nope = q_nope.shape
+    if B != 1:
+        raise ValueError(f"latent_chunk_attention takes one slot's chunk, "
+                         f"got {B}")
+    C, rope = w_kvb.shape[0], q_rope.shape[-1]
+    rows = _gather_pages(pool, bt)[0, 0]                    # [S, ROW]
+    S = rows.shape[0]
+    col = jnp.arange(S, dtype=jnp.int32)
+    rows = jnp.where((col < base[0] + length[0])[:, None], rows, 0)
+    on_tpu = jax.default_backend() == "tpu"
+    n_mesh = ctx.mesh.devices.size if ctx.mesh is not None else 1
+    dv = w_kvb.shape[1] // H - nope
+    fits = latent_attention.chunk_supported(H, T, rows.shape, C, nope, dv)
+    if on_tpu and n_mesh == 1 and fits:
+        out = latent_attention.mla_chunk_attention(
+            q_nope[0], q_rope[0], rows, w_kvb, base, scale=scale,
+            nope_dim=nope, latent_dim=C, block_k=CHUNK_BLOCK_K)
+        _lowered("latent_chunk")
+    else:
+        prec = _mm_precision(q_nope.dtype)
+        kv = jnp.dot(rows[:, :C], w_kvb, precision=prec).reshape(S, H, -1)
+        s = (jnp.einsum("hqd,shd->hqs", q_nope[0], kv[..., :nope],
+                        precision=prec)
+             + jnp.einsum("hqr,sr->hqs", q_rope[0], rows[:, C:C + rope],
+                          precision=prec)) * scale
+        t = jnp.arange(T, dtype=jnp.int32)
+        keep = col[None, :] <= base[0] + t[:, None]          # [T, S]
+        s = jnp.where(keep[None], s, jnp.asarray(-1e30, s.dtype))
+        p = jax.nn.softmax(s, axis=-1)
+        out = jnp.einsum("hqs,shd->hqd", p, kv[..., nope:], precision=prec)
+        reason = None
+        if on_tpu:
+            reason = (f"latent_chunk_attention under a {n_mesh}-device mesh"
+                      if n_mesh > 1 else
+                      f"latent_chunk_attention with {T} rows over a view "
+                      f"{rows.shape}, latent {C}, heads of {nope} | {dv} "
+                      f"(kernel needs whole lane tiles of each, rows % 8 "
+                      f"== 0)")
+        _lowered("latent_chunk_reference", reason)
+    ctx.set_output(op, "Out", out[None].astype(q_nope.dtype))

@@ -55,6 +55,8 @@ def _moe_routed_infer(op, block):
     if op.output("RouterLogits"):
         set_out(op, block, "RouterLogits", tuple(x.shape[:-1]) + (e,),
                 "float32")
+    if op.output("GroupRows"):
+        set_out(op, block, "GroupRows", (int(op.attr("n_group")),), "int32")
 
 
 @register_op("moe_routed_ffn", infer=_moe_routed_infer, grad=None)
@@ -68,11 +70,14 @@ def _moe_routed_ffn(ctx, op):
     selection bias of sigmoid scoring (optional).  With the attribute
     ``held_first`` GateUpW and DownW hold the experts from that index on
     alone, one chip's share of RouterW's E, and Out is their part of the
-    sum.  Attribute ``limit``: the experts' SwiGLU clamp.  Inference
-    only."""
+    sum.  Attribute ``limit``: the experts' SwiGLU clamp.  Attributes
+    ``n_group`` / ``topk_group``: group-limited selection
+    (``route_top_k``); the output GroupRows [n_group] int32 then counts the
+    valid rows that kept each group.  Inference only."""
+    import jax
     import jax.numpy as jnp
 
-    from ..parallel.moe import moe_routed_tokens
+    from ..parallel.moe import group_keep, moe_routed_tokens
     from .math_ops import _mm_precision
 
     x = ctx.get_input(op, "X")
@@ -83,6 +88,8 @@ def _moe_routed_ffn(ctx, op):
     if n_valid is not None:
         t = jnp.arange(shape[1], dtype=jnp.int32)[None, :]
         valid = (t < n_valid.astype(jnp.int32)[:, None]).reshape(-1)
+    n_group = int(op.attr("n_group", 1))
+    topk_group = int(op.attr("topk_group", 1))
     out, counts, logits = moe_routed_tokens(
         x.reshape(-1, shape[-1]),
         ctx.get_input(op, "RouterX").reshape(-1, shape[-1]),
@@ -97,9 +104,16 @@ def _moe_routed_ffn(ctx, op):
         route_scale=float(op.attr("route_scale", 1.0)),
         held_first=op.attr("held_first", None),
         limit=op.attr("limit", None),
-        mesh_devices=ctx.mesh.devices.size if ctx.mesh is not None else 1)
+        mesh_devices=ctx.mesh.devices.size if ctx.mesh is not None else 1,
+        n_group=n_group, topk_group=topk_group)
     ctx.set_output(op, "Out", out.reshape(shape))
     ctx.set_output(op, "ExpertCount", counts)
+    if op.output("GroupRows"):
+        kept = group_keep(jax.nn.softmax(logits, axis=-1), n_group,
+                          topk_group)
+        if valid is not None:
+            kept = kept & valid[:, None]
+        ctx.set_output(op, "GroupRows", kept.sum(axis=0).astype(jnp.int32))
     if op.output("RouterLogits"):
         ctx.set_output(op, "RouterLogits",
                        logits.reshape(shape[:-1] + (logits.shape[-1],)))

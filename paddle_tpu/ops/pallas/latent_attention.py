@@ -24,7 +24,19 @@ for 576: a page of 16 tokens is 40 KB of float32, contiguous).
   whole lane tiles (256: the MXU contracts 192 in two passes of 128
   either way); K and V of one head sit whole in VMEM.
 
-Both feed the MXU float32 operands whole (``PRECISION``), as the paged
+* :func:`mla_chunk_attention`, the **expanded form over latent rows** of
+  a prefill chunk: ``C`` new rows at ``base`` attend the slot's cached
+  latent rows plus themselves.  Per head the cached rows come in key
+  block by key block, each block is expanded through the head's columns
+  of ``W_kvb`` in VMEM (``[k_nope | v] = c_kv W_kvb,h``) and meets the
+  head's whole chunk of queries under an online softmax, so no key or
+  value of any head ever lies in HBM and no temporary grows with
+  ``context x heads``.  A pair costs the expanded form's 640 FLOP a head
+  and a cached row is re-expanded once a chunk (the absorbed form at ``C
+  x H`` query rows costs 2,176 a pair; PERF.md section 6, PR 56, has both
+  on the chip).
+
+All feed the MXU float32 operands whole (``PRECISION``), as the paged
 kernel does.  Each ``pallas_call`` has a ``name`` of its own, which is
 what a trace matches.
 """
@@ -260,3 +272,122 @@ def mla_prefill_attention(q, k, v, *, scale, interpret=False):
     )(q.reshape(B * H, S, dk), k.reshape(B * H, S, dk),
       v.reshape(B * H, S, dv))
     return out.reshape(B, H, S, dv)
+
+
+# ---------------------------------------------------------------------------
+# a prefill chunk over latent rows: expanded block by block, in VMEM
+# ---------------------------------------------------------------------------
+
+# q (two parts), a block of rows, a head's W_kvb columns and the output,
+# each double-buffered, beside scores and probabilities of [C, block_k]:
+# about 12 MB at 1024 rows, over the default scoped 16 MB with Mosaic's own
+CHUNK_VMEM_BYTES = 64 * 1024 * 1024
+
+
+def chunk_supported(num_heads, chunk_len, view_shape, latent_dim, nope_dim,
+                    value_dim):
+    """Whether the compiled chunk kernel takes these shapes."""
+    S, row = view_shape
+    return (row % LANES == 0 and latent_dim % LANES == 0
+            and latent_dim < row and nope_dim % LANES == 0
+            and value_dim % LANES == 0 and chunk_len % 8 == 0
+            and S % LANES == 0)
+
+
+def _chunk_kernel(base_ref, qn_ref, qr_ref, rows_ref, w_ref, o_ref,
+                  m_ref, l_ref, acc_ref, *, scale, block_k, ck, dn):
+    j = pl.program_id(1)
+    nk = pl.num_programs(1)
+    base = base_ref[0]
+    rows_q = qn_ref.shape[1]
+
+    @pl.when(j == 0)
+    def _():
+        m_ref[...] = jnp.full(m_ref.shape, NEG_INF, jnp.float32)
+        l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+    # a block right of the chunk's last row holds no admitted column
+    @pl.when(j * block_k < base + rows_q)
+    def _():
+        lat = rows_ref[...].astype(jnp.float32)          # [bk, ROW]
+        kv = jnp.dot(lat[:, :ck], w_ref[...].astype(jnp.float32),
+                     preferred_element_type=jnp.float32,
+                     precision=PRECISION)                # [bk, dn + dv]
+        contract = (((1,), (1,)), ((), ()))
+        s = (jax.lax.dot_general(
+            qn_ref[0].astype(jnp.float32), kv[:, :dn], contract,
+            preferred_element_type=jnp.float32, precision=PRECISION)
+            + jax.lax.dot_general(
+                qr_ref[0].astype(jnp.float32), lat[:, ck:], contract,
+                preferred_element_type=jnp.float32,
+                precision=PRECISION)) * scale            # [C_q, bk]
+        q_pos = base + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        k_pos = j * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(k_pos <= q_pos, s, NEG_INF)
+        m = m_ref[...]
+        m_new = jnp.maximum(m, s.max(-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        corr = jnp.exp(m - m_new)
+        l_ref[...] = l_ref[...] * corr + p.sum(-1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * corr + jnp.dot(
+            p, kv[:, dn:], preferred_element_type=jnp.float32,
+            precision=PRECISION)
+        m_ref[...] = m_new
+
+    @pl.when(j == nk - 1)
+    def _():
+        o_ref[0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+                    ).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "scale", "nope_dim", "latent_dim", "block_k", "interpret"))
+def mla_chunk_attention(q_nope, q_rope, rows, w_kvb, base, *, scale,
+                        nope_dim, latent_dim, block_k=512,
+                        interpret=False):
+    """``q_nope`` [H, C, nope] and ``q_rope`` [H, C, rope], the chunk's
+    rows at absolute positions ``base .. base + C - 1`` (``base`` [1]
+    int32, read at run time), over ``rows`` [S, ROW], the slot's logical
+    view of its latent pages (``[c_kv | k_r | 0]`` a row, the chunk's own
+    rows already in it; rows no query admits must be finite: the caller
+    zeroes what lies behind the chunk), with ``w_kvb`` [latent_dim, H *
+    (nope + v)].  Row ``t`` attends columns ``j <= base + t``.  Returns
+    [H, C, v]."""
+    H, C, dn = q_nope.shape
+    S, row = rows.shape
+    ck = int(latent_dim)
+    per_head = w_kvb.shape[1] // H
+    dv = per_head - dn
+    rest = row - ck
+    # the rotated part over the row's lanes behind the latent: zeros meet
+    # the row's zero padding
+    q_rope = jnp.pad(q_rope, ((0, 0), (0, 0), (0, rest - q_rope.shape[-1])))
+    bk = _fit_block(block_k, S, compiled=not interpret)
+    kernel = functools.partial(_chunk_kernel, scale=float(scale),
+                               block_k=bk, ck=ck, dn=dn)
+
+    def rows_at(h, j, base):
+        # past the last admitted block the index stays: nothing is fetched
+        return jnp.minimum(j, (base[0] + C - 1) // bk), 0
+
+    return pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct((H, C, dv), q_nope.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(H, S // bk),
+            in_specs=[pl.BlockSpec((1, C, dn), lambda h, j, *_: (h, 0, 0)),
+                      pl.BlockSpec((1, C, rest), lambda h, j, *_: (h, 0, 0)),
+                      pl.BlockSpec((bk, row), rows_at),
+                      pl.BlockSpec((ck, per_head), lambda h, j, *_: (0, h))],
+            out_specs=pl.BlockSpec((1, C, dv), lambda h, j, *_: (h, 0, 0)),
+            scratch_shapes=[pltpu.VMEM((C, 1), jnp.float32),
+                            pltpu.VMEM((C, 1), jnp.float32),
+                            pltpu.VMEM((C, dv), jnp.float32)]),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=CHUNK_VMEM_BYTES),
+        interpret=interpret,
+        name="mla_chunk_attention",
+    )(base.astype(jnp.int32).reshape(1), q_nope, q_rope, rows, w_kvb)

@@ -28,6 +28,16 @@ def yarn_inv_freq(inv_freq, base, dim, factor, original_max, beta_fast,
     return inv_freq * (ramp / factor + (1.0 - ramp))
 
 
+def yarn_mscale(factor, mscale=1.0):
+    """YaRN's attention factor ``0.1 mscale ln(factor) + 1`` (1 where
+    nothing is stretched).  The DeepSeek family multiplies cos and sin by
+    ``yarn_mscale(factor, mscale) / yarn_mscale(factor, mscale_all_dim)``
+    and the softmax scale by ``yarn_mscale(factor, mscale_all_dim) ** 2``."""
+    if factor <= 1:
+        return 1.0
+    return 0.1 * float(mscale) * float(np.log(factor)) + 1.0
+
+
 def _rope_infer(op, block):
     x = in_var(op, block, "X")
     set_out(op, block, "Out", x.shape, x.dtype)

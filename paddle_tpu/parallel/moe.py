@@ -139,9 +139,25 @@ def moe_rules(mesh, axis: str = EP_AXIS, inner=None):
 # dropless top-k routing with gated experts (the serving path's expert FFN)
 # ---------------------------------------------------------------------------
 
+def group_keep(scores, n_group: int, topk_group: int):
+    """Group-limited selection's first step: ``scores`` [N, E] in
+    ``n_group`` groups of consecutive experts, a group scored by the MAX of
+    its experts; the ``topk_group`` best groups of a row are kept (ties to
+    the lower index).  Returns the mask [N, n_group]."""
+    import jax
+    import jax.numpy as jnp
+
+    n = scores.shape[0]
+    best = scores.reshape(n, n_group, -1).max(axis=-1)
+    _, kept = jax.lax.top_k(best, topk_group)
+    return jnp.zeros((n, n_group), bool).at[
+        jnp.arange(n)[:, None], kept].set(True)
+
+
 def route_top_k(router_x, router_w, top_k: int, score: str = "softmax",
                 expert_bias=None, norm_topk: bool = True,
-                route_scale: float = 1.0):
+                route_scale: float = 1.0, n_group: int = 1,
+                topk_group: int = 1):
     """Router logits and the top-k choice per token.
 
     router_x [N, H] (whatever the architecture routes from — it need not
@@ -158,14 +174,32 @@ def route_top_k(router_x, router_w, top_k: int, score: str = "softmax",
     (``expert_bias`` [E] float32 or None; ties to the lower index), and
     the weights are the UNBIASED ``s`` of the chosen, with ``norm_topk``
     divided by their sum plus 1e-6.  Either way the weights are scaled
-    by ``route_scale``.  The logits returned are always the raw ones."""
+    by ``route_scale``.  The logits returned are always the raw ones.
+
+    ``n_group`` > 1 (``score`` "softmax"): group-limited greedy selection
+    (DeepSeek-V2's device-limited routing).  ``s = softmax(logits)`` over
+    all E; the E experts lie in ``n_group`` groups of consecutive indices
+    and only the ``topk_group`` best groups (:func:`group_keep`) keep
+    their scores, the others read 0; the ``top_k`` largest of what is left
+    are the token's experts, weighted by their ``s`` (with ``norm_topk``
+    divided by their sum plus 1e-20)."""
     import jax
     import jax.numpy as jnp
 
     logits = jnp.dot(router_x.astype(jnp.float32),
                      router_w.astype(jnp.float32),
                      precision=jax.lax.Precision.HIGHEST)
-    if score == "softmax":
+    if n_group > 1:
+        if score != "softmax" or expert_bias is not None:
+            raise ValueError("group-limited selection is built over softmax "
+                             "scores without a selection bias")
+        s = jax.nn.softmax(logits, axis=-1)
+        keep = jnp.repeat(group_keep(s, n_group, topk_group),
+                          logits.shape[1] // n_group, axis=1)
+        weights, experts = jax.lax.top_k(jnp.where(keep, s, 0.0), top_k)
+        if norm_topk:
+            weights = weights / (weights.sum(axis=-1, keepdims=True) + 1e-20)
+    elif score == "softmax":
         top, experts = jax.lax.top_k(logits, top_k)
         weights = jax.nn.softmax(top, axis=-1) if norm_topk else jnp.exp(
             top - jax.nn.logsumexp(logits, axis=-1, keepdims=True))
@@ -337,7 +371,8 @@ def moe_routed_tokens(x, router_x, router_w, w_gate_up, w_down, *,
                       precision=None, score: str = "softmax",
                       expert_bias=None, norm_topk: bool = True,
                       route_scale: float = 1.0, held_first=None,
-                      limit=None, mesh_devices: int = 1):
+                      limit=None, mesh_devices: int = 1, n_group: int = 1,
+                      topk_group: int = 1):
     """Dropless top-k mixture of gated experts over flat tokens.
 
     x [N, H] is the experts' input, router_x [N, H] what the router reads;
@@ -351,8 +386,8 @@ def moe_routed_tokens(x, router_x, router_w, w_gate_up, w_down, *,
     x 768, top 6, rung 4096 of 2,900 real rows 19.33 -> 16.71 ms, 8192 of
     5,800 33.16 -> 27.96; 64 x 2048 x 1536, top 4, 1024 of 700 6.40 -> 5.65).
     ``activation``, ``limit``: :func:`_gated`'s; ``score``, ``expert_bias``,
-    ``norm_topk``, ``route_scale``: :func:`route_top_k`'s; ``mesh_devices``:
-    :func:`grouped_matmul`'s.
+    ``norm_topk``, ``route_scale``, ``n_group``, ``topk_group``:
+    :func:`route_top_k`'s; ``mesh_devices``: :func:`grouped_matmul`'s.
 
     ``held_first`` (one chip's share of an expert-parallel group): the
     weights are those of experts ``held_first .. held_first + w_gate_up
@@ -377,7 +412,7 @@ def moe_routed_tokens(x, router_x, router_w, w_gate_up, w_down, *,
     inter = w_down.shape[1]
     logits, experts, weights = route_top_k(
         router_x, router_w, top_k, score, expert_bias, norm_topk,
-        route_scale)
+        route_scale, n_group, topk_group)
     if held_first is not None:
         held = w_gate_up.shape[0]
         pair_valid = jnp.ones((N, 1), bool) if valid is None \

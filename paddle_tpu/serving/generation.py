@@ -62,7 +62,12 @@ identified.  This module is the repo's answer:
   With chunking on a prompt may be as long as the cache; only the chunk
   needs a prefill rung.  Prefix reuse, speculation and KV-segment handoff
   stay refused over two page kinds, and a chunk over layers that keep
-  slot state or latent pages is not built.
+  slot state is not built.  A model whose attention layers are latent
+  (MLA) prefills in chunks over its latent pages (PR 56): a chunk writes
+  its ``[c_kv | k_r]`` rows as whole pages and attends the slot's cached
+  rows expanded block by block (``latent_chunk_attention``); at 128 heads
+  a single-shot rung wide enough for a long prompt would expand gigabytes
+  of keys and values a layer, so chunks are the only way in.
 * **Speculative decoding** (``FLAGS_serving_speculate``) — self-
   speculation over the slot's pages: a prompt-lookup drafter
   (:func:`ngram_draft` — longest n-gram suffix match over the
@@ -164,7 +169,9 @@ model whose expert
 layers hold one chip's share of the router's experts (the layer
 pattern's ``held``) ``moe_pairs_routed`` / ``moe_pairs_held`` (the
 token-expert pairs the router placed over all its experts, and those
-whose expert is held here and went through the matmuls), with a shared
+whose expert is held here and went through the matmuls; under
+group-limited selection ``moe_rows_group_held``, the row-layers whose kept
+groups include the one held here), with a shared
 expert ``moe_shared_expert_rows`` (row-layers it ran on),
 ``serving_block_passes_denoise`` / ``serving_block_passes_commit``
 (block diffusion: slot-passes that decided positions / that only
@@ -204,6 +211,7 @@ from .. import blackbox, costmodel, fault, telemetry
 from ..flags import flag_value
 from ..monitor import stat_add
 from ..ops.gated_delta_ops import CHUNK as DELTA_CHUNK
+from ..ops.latent_attention_ops import CHUNK_BLOCK_K
 from . import batcher
 from . import usage
 from .engine import (OverloadedError, PoisonedInput, RequestFailed,
@@ -782,6 +790,8 @@ class GenerationEngine:
         # router's experts held here (None: all), and a shared expert
         self._moe_held = routed[0].get("held") if routed else None
         self._moe_shared = bool(routed and routed[0].get("shared_width"))
+        # group-limited selection: the router's groups (1: none)
+        self._moe_groups = int(routed[0].get("n_group", 1)) if routed else 1
         self._build_fn_prefill = build_llama_prefill
         self._seed = seed
 
@@ -1007,7 +1017,7 @@ class GenerationEngine:
                    "block_passes_commit": 0, "block_tokens_committed": 0,
                    "slot_state_writes": 0, "delta_state_steps": 0,
                    "moe_pairs_routed": 0, "moe_pairs_held": 0,
-                   "moe_shared_expert_rows": 0}
+                   "moe_rows_group_held": 0, "moe_shared_expert_rows": 0}
         self._n_lock = threading.Lock()
         # per-bucket manifest-flops cache for usage attribution: the
         # executor cache walk is paid once per bucket, not per dispatch
@@ -1198,8 +1208,8 @@ class GenerationEngine:
         names = ["next_token"] if not self._blk \
             else [n for n in ("tokens", "masked", "rows_written")
                   if n in fetches]
-        if "expert_counts" in fetches:
-            names.append("expert_counts")
+        names += [n for n in ("expert_counts", "expert_group_rows")
+                  if n in fetches]
         if self.keep_logits:
             # (a block-diffusion prefill has router logits and no row's)
             names += [n for n in ("logits", "router_logits")
@@ -2700,12 +2710,24 @@ class GenerationEngine:
                           "window_pages_mapped": mapped,
                           "window_pages_released": gone}
         last = start + n >= n_prompt
+        latent = {}
+        if self._latent_layers:
+            # a latent layer's pool took the chunk's rows [c_kv | k_r];
+            # its rows attended the slot's cached rows and themselves,
+            # which the chunk kernel expanded in whole key blocks up to
+            # the rung's last row (a layer's figures, not their sum)
+            block = min(CHUNK_BLOCK_K, self.max_seq_len)
+            latent = {"latent_rows_written": n,
+                      "latent_rows_attended": start + n,
+                      "latent_rows_expanded": min(
+                          -(-(start + bucket) // block) * block,
+                          self.max_seq_len)}
         outs = self._launch(
             "generation/prefill_chunk", lambda: self._run_fetching(
                 self._prefill_exe, prog, fetches, feed),
             parent=parent, tokens=n, base=start, bucket=bucket,
             pad_rows=bucket - n, slot=slot.idx,
-            attended_pairs=self._chunk_pairs(start, n), **window)
+            attended_pairs=self._chunk_pairs(start, n), **window, **latent)
         self._count("prefill_chunks")
         stat_add("serving_prefill_chunks")
         if req.tenant is not None:
@@ -2719,7 +2741,8 @@ class GenerationEngine:
             self._complete_prefill(slot, req, outs, n, bucket)
         elif "expert_counts" in outs:
             # booked with the prompt's last chunk, when all have run
-            slot.chunk_counts.append((outs["expert_counts"], n, bucket))
+            slot.chunk_counts.append((outs["expert_counts"], n, bucket,
+                                      outs.get("expert_group_rows")))
 
     def _chunk_pairs(self, base: int, n: int) -> int:
         """The (row, column) pairs the ``n`` rows of a chunk at ``base``
@@ -2757,30 +2780,36 @@ class GenerationEngine:
             if "expert_counts" in outs:
                 # (the prompt's earlier chunks ran before this one)
                 chunks, slot.chunk_counts = slot.chunk_counts \
-                    + [(outs["expert_counts"], n_tokens, bucket)], []
-                booked = [self._book_experts(np.asarray(c.numpy()), n, rows)
-                          for c, n, rows in chunks]
+                    + [(outs["expert_counts"], n_tokens, bucket,
+                        outs.get("expert_group_rows"))], []
+                booked = [self._book_experts(
+                    np.asarray(c.numpy()), n, rows,
+                    None if g is None else np.asarray(g.numpy()))
+                    for c, n, rows, g in chunks]
                 if span is not None:
                     # (the counts come back with this fetch, after the
                     # ``generation/prefill`` span that launched them)
                     span.attrs.update({
                         k: sum(b[k] for b in booked) for k in (
-                            "pairs_routed", "pairs_held",
+                            "pairs_routed", "pairs_held", "rows_group_held",
                             "pad_pairs_left_out") if k in booked[0]})
         finally:
             self._end_device_wait(span)
         return first
 
     def _book_experts(self, counts: np.ndarray, n_tokens: int,
-                      built_for: int) -> dict:
+                      built_for: int, group_rows=None) -> dict:
         """Book what a program's expert layers counted: ``counts``
         [L_moe, E] tokens per expert over the ``n_tokens`` valid rows of
         the ``built_for`` rows the program holds.  Routing is dropless, so
         every layer must have placed ``n_tokens * top_k`` pairs; the
         shortfall is ``moe_tokens_dropped`` and must read 0.  The other
         rows' pairs (a rung's pad tail, a step's idle slots) went
-        through no expert: ``moe_pad_pairs_left_out``.  Returns the load
-        figures of the step."""
+        through no expert: ``moe_pad_pairs_left_out``.  ``group_rows``
+        [L_moe, n_group] (group-limited selection): the valid rows that
+        kept each group; where the held experts lie inside one group, its
+        rows are ``moe_rows_group_held``.  Returns the load figures of the
+        step."""
         routed = int(counts.sum())
         dropped = counts.shape[0] * n_tokens * self._moe_top_k - routed
         left_out = counts.shape[0] * (built_for - n_tokens) \
@@ -2820,6 +2849,14 @@ class GenerationEngine:
             attrs.update(pairs_routed=routed, pairs_held=held,
                          experts_held_touched=round(
                              float((here > 0).sum(axis=1).mean()), 3))
+            per = counts.shape[1] // self._moe_groups
+            if group_rows is not None and first // per == (first + n - 1) \
+                    // per:
+                # the rows whose kept groups include the one held here
+                rows = int(group_rows[:, first // per].sum())
+                self._count("moe_rows_group_held", rows)
+                stat_add("moe_rows_group_held", rows)
+                attrs["rows_group_held"] = rows
         return attrs
 
     def _complete_prefill(self, slot: _Slot, req: GenRequest, outs,
@@ -3292,7 +3329,7 @@ class GenerationEngine:
             # settle discards too
             attrs.update(self._book_experts(
                 outs["expert_counts"], len(fl.riders) * self._rows,
-                self.num_slots * self._rows))
+                self.num_slots * self._rows, outs.get("expert_group_rows")))
         if self._wpool is not None:
             # the pages this step's feeds let go, what both kinds hold
             # now, the positions its rows attended

@@ -6,7 +6,9 @@ here as they stand (entries against data files, every entry's
 43, PR 47 and PR 51 added are pinned beside them, by name: each one's own entries, the
 shared ``.pool`` entries that list it, its configuration's cut and its
 mix; so is the start-up account's `.setup` family that PR 53 added to
-every cell.  No JAX is imported and no engine started.
+every cell.  The cell PR 56 added joined families and brought no entry
+(PR 55's rule): it is pinned by its row, as ``test_manifest.TABLE`` has
+the others'.  No JAX is imported and no engine started.
 """
 import importlib.util
 import json
@@ -120,27 +122,50 @@ ADDED = {
 }
 
 
+# the cell PR 56 added, after the merge of PR 55: it joined families and
+# brought its arguments in its own files, so it has a row (the end-to-end
+# metric it moves, its groups, its count of values) and no entry of its
+# own.  Of "latent pages" it reports all but the single-shot latent
+# prefill kernel's share, which its timed path never runs
+DSV2 = "deepseek-v2-docqa"
+DSV2_NOT_RUN = "mla_prefill_roofline.pool"
+DSV2_ROW = ("served_tokens_per_s", [
+    "closed loop", "experts", "experts, a share held", "step on its span",
+    "latent pages", "chunked prefill"], 40)
+JOINED = {DSV2: {"config": "deepseek-v2", "mix": "docqa-pool",
+                 "reduced": ["num_hidden_layers", "n_routed_experts",
+                             "vocab_size"],
+                 "driver": "serve_chunks", "rungs": [512, 512, 1024],
+                 "chunk": 1024}}
+CELLS_AT_PR54 = 11
+
+
 def _json(*parts):
     with open(os.path.join(BENCH, *parts)) as f:
         return json.load(f)
 
 
-def test_the_benchmark_has_nine_configurations_and_eleven_cells():
+def test_the_benchmark_has_ten_configurations_and_twelve_cells():
     spec = manifest.SPEC
     assert [c["name"] for c in spec["configs"]] == [
         "bert-base-mlm", "mistral-7b-v0.1", "smallthinker-21b-a3b",
         "sdar-30b-a3b-chat", "lfm2-24b-a2b"] \
-        + [a["config"] for a in ADDED.values()]
+        + [a["config"] for a in ADDED.values()] \
+        + [a["config"] for a in JOINED.values()]
     assert manifest.CELLS == [
         "bert-base-seq512", "mistral7b-chat", "mistral7b-longprompt",
         "bert-base-seq512-dp4", "smallthinker21b-mixedlen",
-        "sdar30b-blockgen", "lfm2-24b-longanswer"] + list(ADDED)
-    assert (len(spec["configs"]), len(manifest.CELLS)) == (9, 11)
+        "sdar30b-blockgen", "lfm2-24b-longanswer"] + list(ADDED) \
+        + list(JOINED)
+    assert (len(spec["configs"]), len(manifest.CELLS)) == (10, 12)
     assert [w["name"] for w in spec["workloads"] if w["chips"] == 4] \
         == ["bert-base-seq512-dp4"]
-    new, = [w for w in spec["workloads"] if w["name"] == CMDA]
+    new, = [w for w in spec["workloads"] if w["name"] == DSV2]
     assert (new["chips"], new["config"], new["traffic"]) \
-        == (1, ADDED[CMDA]["config"], ADDED[CMDA]["mix"])
+        == (1, JOINED[DSV2]["config"], JOINED[DSV2]["mix"])
+    assert spec["workloads"][-1] == new
+    # a cell that joins families adds no entry
+    assert len(manifest.ENTRIES) == 97
     used = {w["config"] for w in spec["workloads"]}
     assert used == {c["name"] for c in spec["configs"]}
     for c in spec["configs"]:
@@ -162,15 +187,20 @@ def test_the_benchmark_has_nine_configurations_and_eleven_cells():
 @pytest.mark.parametrize("name", list(SETUP))
 def test_every_cell_reports_the_start_up_account(name):
     """The `.setup` family (PR 53): four entries at the end of
-    ``per_layer``, each listing the eleven cells in the manifest's order
-    and moving ``setup_s``, each with its data file and a reader that
-    reads the program's kept spans by name."""
+    ``per_layer``, each listing every cell in the manifest's order (the
+    eleven of PR 54 under the names of PR 54, all twelve now) and moving
+    ``setup_s``, each with its data file and a reader that reads the
+    program's kept spans by name."""
     names = [m["name"] for m in manifest.PER_LAYER]
     assert names[-len(SETUP):] == list(SETUP)
     entry, = [m for m in manifest.PER_LAYER if m["name"] == name]
     assert entry == {"name": name, "unit": "s", "better": "lower",
                      "source": "program_span", "layer": "start-up",
-                     "moves": "setup_s", "workloads": manifest.CELLS}
+                     "moves": "setup_s",
+                     "workloads": manifest.CELLS[:CELLS_AT_PR54]}
+    assert manifest.BY_NAME[name] == dict(entry, workloads=manifest.CELLS)
+    assert [m["name"] for m in manifest.ENTRIES][-len(SETUP):] \
+        == list(SETUP)
     gate, = [m for m in manifest.SPEC["end_to_end"]
              if m["name"] == "setup_s"]
     assert "workloads" not in gate and gate["bound"] == 0.1
@@ -185,9 +215,11 @@ def test_every_cell_reports_the_start_up_account(name):
     for span in spans or ():
         assert f'"{span}"' in made, span
     # and every cell still reports, beside them, what moves its own gate
-    for cell in manifest.CELLS:
+    for cell in manifest.CELLS[:CELLS_AT_PR54]:
         own, shared = _reported_by(cell)
         assert name in shared and name not in own
+    for cell in manifest.CELLS:
+        assert name in manifest.entries_of(cell)
 
 
 @pytest.mark.parametrize("cell", list(ADDED))
@@ -208,7 +240,8 @@ def test_an_added_cell_reports_its_own_and_the_shared_entries(cell):
         assert set(lists[lists.index(cell) + 1:]) <= set(later), name
     gate, = [m for m in manifest.SPEC["end_to_end"]
              if m["name"] == "served_tokens_per_s"]
-    assert gate["workloads"][-len(ADDED):] == list(ADDED)
+    assert gate["workloads"][-len(ADDED) - len(JOINED):] \
+        == list(ADDED) + list(JOINED)
     assert gate["bound"] == 0.06
     # its own entries lie together, in the order its PR gave them
     names = [m["name"] for m in manifest.PER_LAYER]
@@ -421,9 +454,9 @@ def test_an_added_configuration_cuts_what_it_says_and_no_width(cell):
     assert len(cfg["check_tolerance"]["why"]) > 200
 
 
-@pytest.mark.parametrize("cell", list(ADDED))
+@pytest.mark.parametrize("cell", list(ADDED) + list(JOINED))
 def test_an_added_mix_is_closed_loop_over_whole_chunks_and_pages(cell):
-    added = ADDED[cell]
+    added = dict(ADDED, **JOINED)[cell]
     mix = _json("traffic", added["mix"] + ".json")
     e = mix["engine"]
     assert (mix["driver"], mix["loop"], mix["workers_per_slot"],
@@ -443,6 +476,123 @@ def test_an_added_mix_is_closed_loop_over_whole_chunks_and_pages(cell):
     assert [min(b for b in rungs if b >= ((n - 1) % chunk + 1 if chunk
                                           else n))
             for n in mix["reference_prompts"]] == added["rungs"]
+
+
+# -- PR 56: a cell that joined families and brought no entry ----------------
+
+def _groups_less_not_run():
+    return dict(manifest.GROUPS, **{"latent pages": [
+        n for n in manifest.GROUPS["latent pages"] if n != DSV2_NOT_RUN]})
+
+
+def test_the_joined_cell_reports_its_groups_and_forty_values(monkeypatch):
+    """Its row: the 22 of the closed loop, the experts' two, a held
+    share's two, the step on its span, four of the five of latent pages,
+    the five of chunked prefill and the four of the start-up account."""
+    monkeypatch.setattr(manifest, "GROUPS", _groups_less_not_run())
+    assert manifest.check_cell(DSV2, DSV2_ROW) == DSV2_ROW[2] == 40
+    assert DSV2 not in manifest.BY_NAME[DSV2_NOT_RUN]["workloads"]
+    # every entry lists it last: the cells stand in the order they came
+    for name in manifest.entries_of(DSV2):
+        assert manifest.BY_NAME[name]["workloads"][-1] == DSV2, name
+    assert DSV2 not in manifest.TABLE         # (a ``benchmark`` PR's to add)
+
+
+@pytest.mark.parametrize("name,reader,key,reads", [
+    ("decode_step_roofline.pool", "roofline_span", "attrs",
+     ["experts_held_touched", "latent_positions"]),
+    ("decode_step_roofline.pool", "roofline_span", "fn",
+     "ops_bytes_deepseek_v2.decode_step_bytes"),
+    ("mla_decode_bytes_roofline.pool", "roofline_kernel", "fn",
+     "ops_bytes_deepseek_v2.mla_decode_bytes"),
+    ("mla_decode_flops_roofline.pool", "roofline_kernel", "fn",
+     "ops_bytes_deepseek_v2.mla_decode_flops"),
+    ("mla_decode_flops_roofline.pool", "roofline_kernel", "pattern",
+     "^%?mla_decode_attention"),
+    ("mla_kernel_share_pct.pool", "trace_op_share", "pattern",
+     "^%?mla_(decode|chunk)_attention"),
+    ("latent_fill_pct.pool", "span_attr_mean", "scale", 100 / (10 * 12800)),
+    ("prefill_chunk_roofline.pool", "roofline_chunks", "fn",
+     "ops_bytes_deepseek_v2.chunk_flops"),
+    ("chunk_attention_roofline.pool", "roofline_kernel_prefill", "fn",
+     "ops_bytes_deepseek_v2.chunk_attention_flops"),
+    ("chunk_attention_roofline.pool", "roofline_kernel_prefill", "pattern",
+     "^%?mla_chunk_attention"),
+    ("chunk_attention_roofline.pool", "roofline_kernel_prefill", "attr",
+     "attended_pairs"),
+    ("chunk_attention_share_pct.pool", "trace_op_share", "pattern",
+     "^%?mla_chunk_attention"),
+    ("chunk_pad_pct.pool", "span_attr_ratio", "num", "pad_rows"),
+    ("moe_held_touched_pct.pool", "span_attr_mean", "per",
+     "n_routed_experts"),
+    ("moe_pairs_held_pct.pool", "span_attr_ratio", "num", "pairs_held"),
+])
+def test_the_joined_cell_hands_each_family_its_own_arguments(
+        name, reader, key, reads):
+    """What the cell's own files give a family's reader, resolved as the
+    harness resolves it, and that the program makes it: the kernels by the
+    ``name=`` of their ``pallas_call``, the attributes by their names in
+    ``serving/generation.py``."""
+    got_reader, args = manifest.check_arguments(name, DSV2)
+    assert got_reader == reader and args[key] == reads
+    with open(os.path.join(REPO, "paddle_tpu", "ops", "pallas",
+                           "latent_attention.py")) as f:
+        latent = f.read()
+    assert 'name="mla_chunk_attention"' in latent
+    assert 'name="mla_decode_attention"' in latent
+    with open(os.path.join(REPO, "paddle_tpu", "serving",
+                           "generation.py")) as f:
+        engine = f.read()
+    for attr in ('"latent_rows_written"', '"latent_rows_attended"',
+                 '"latent_rows_expanded"', "attended_pairs=",
+                 '"rows_group_held"', '"moe_rows_group_held"'):
+        assert attr in engine, attr
+    # a sibling's arguments are its own still
+    assert manifest.resolved("mla_kernel_share_pct.pool", GIGA)[1] \
+        == {"pattern": "^%?mla_(decode|prefill)_attention"}
+    assert manifest.resolved("chunk_attention_roofline.pool", CMDA)[1][
+        "pattern"] == "^%?chunk_attention"
+
+
+def test_the_joined_configuration_cuts_what_it_says_and_no_width():
+    joined = JOINED[DSV2]
+    cfg = _json("configs", joined["config"] + ".json")
+    entry, = [c for c in manifest.SPEC["configs"]
+              if c["name"] == joined["config"]]
+    assert entry["reduced"] == cfg["reduced"] == joined["reduced"]
+    assert entry["source"] == cfg["source"]
+    assert (cfg["hidden_size"], cfg["num_attention_heads"],
+            cfg["kv_lora_rank"], cfg["q_lora_rank"],
+            cfg["qk_nope_head_dim"], cfg["qk_rope_head_dim"],
+            cfg["v_head_dim"], cfg["intermediate_size"],
+            cfg["moe_intermediate_size"], cfg["num_experts_per_tok"],
+            cfg["n_shared_experts"], cfg["n_group"], cfg["topk_group"],
+            cfg["routed_scaling_factor"], cfg["norm_topk_prob"],
+            cfg["topk_method"], cfg["first_k_dense_replace"]) \
+        == (5120, 128, 512, 1536, 128, 64, 128, 12288, 1536, 6, 2, 8, 3, 16,
+            False, "group_limited_greedy", 1)
+    assert cfg["rope_scaling"] == {
+        "beta_fast": 32, "beta_slow": 1, "factor": 40, "mscale": 0.707,
+        "mscale_all_dim": 0.707, "original_max_position_embeddings": 4096,
+        "type": "yarn"}
+    # ``reduced`` against ``published``: the one leading dense layer and
+    # four expert layers, ONE WHOLE GROUP of the 8 held, an eighth of the
+    # vocabulary
+    assert cfg["published"] == {"num_hidden_layers": 60,
+                                "n_routed_experts": 160,
+                                "vocab_size": 102400}
+    assert [cfg[k] for k in joined["reduced"]] == [5, 20, 12800]
+    assert cfg["expert_share"] == dict(
+        cfg["expert_share"], router_experts=160, first=0)
+    assert cfg["n_routed_experts"] * cfg["n_group"] == 160
+    assert cfg["vocab_size"] * 8 == 12800 * 8 == 102400
+    assert cfg["as_run"]["latent_row"]["lanes"] == 640
+    for key in ("assumed", "as_run", "deployment", "check_tolerance",
+                "rehearse", "builder", "per_layer_args"):
+        assert key in cfg
+    assert len(cfg["check_tolerance"]["why"]) > 200
+    assert os.path.exists(os.path.join(
+        BENCH, "builders", cfg["builder"] + ".py"))
 
 
 # -- PR 42: the exposed share of collectives, asynchronous ones counted -----

@@ -288,19 +288,29 @@ def test_a_chunk_that_is_not_whole_pages_is_refused_over_two_kinds():
 
 @pytest.mark.parametrize("layer,reason", [
     ({"mixer": {"kind": "conv", "L_cache": 3}}, "slot state"),
+    # (a latent layer has a chunk program since PR 56)
     ({"mla": {"q_rank": 8, "kv_rank": 16, "nope_dim": 8, "rope_dim": 8,
-              "v_dim": 8}}, "latent"),
+              "v_dim": 8}}, None),
 ])
-def test_state_and_latent_layers_still_have_no_chunk_program(layer, reason):
+def test_state_layers_still_have_no_chunk_program_and_latent_ones_do(
+        layer, reason):
     from paddle_tpu.models.llama import build_llama_prefill_chunk
+
+    def build():
+        return build_llama_prefill_chunk(
+            8, 64, 9, PAGE, name="llama", vocab_size=97, hidden=32,
+            num_layers=1, num_heads=2, intermediate=48,
+            layer_pattern=[layer])
 
     main, startup = pt.Program(), pt.Program()
     with pt.program_guard(main, startup):
+        if reason is None:
+            assert build()[2] == ["llama.pool_c_0"]
+            assert "latent_chunk_attention" in [
+                op.type for op in main.global_block().ops]
+            return
         with pytest.raises(ValueError, match=reason):
-            build_llama_prefill_chunk(
-                8, 64, 9, PAGE, name="llama", vocab_size=97, hidden=32,
-                num_layers=1, num_heads=2, intermediate=48,
-                layer_pattern=[layer])
+            build()
 
 
 def test_a_long_prompt_needs_only_the_chunks_rung():

@@ -720,7 +720,10 @@ def test_what_walks_pages_only_is_refused_beside_latent_pages_too(kw,
     assert reason in str(e.value)
 
 
-def test_a_chunk_program_over_latent_pages_is_refused():
+def test_a_chunk_program_over_latent_pages_is_built_since_pr_56():
+    """The latent layer alone has a chunk program (``latent_chunk_attention``
+    over its one pool; ``tests/test_deepseek_v2.py`` runs it); beside the
+    delta layers' slot state it stays refused."""
     from paddle_tpu.models.llama import build_llama_prefill_chunk
 
     model = BUILDER.model_args(_cfg())
@@ -728,9 +731,14 @@ def test_a_chunk_program_over_latent_pages_is_refused():
                        layer_pattern=[model["layer_pattern"][1]])
     main, startup = pt.Program(), pt.Program()
     with pt.program_guard(main, startup):
-        with pytest.raises(ValueError, match="latent"):
-            build_llama_prefill_chunk(8, 64, 9, PAGE, name="llama",
-                                      **only_latent)
+        _, _, caches = build_llama_prefill_chunk(8, 64, 9, PAGE,
+                                                 name="llama", **only_latent)
+        with pytest.raises(ValueError, match="slot state"):
+            build_llama_prefill_chunk(8, 64, 9, PAGE, name="llama", **model)
+    assert caches == ["llama.pool_c_0"]
+    ops = [op.type for op in main.global_block().ops]
+    assert ops.count("latent_chunk_attention") == 1
+    assert "latent_decode_attention" not in ops
 
 
 def test_the_balanced_bias_evens_the_loads_and_leaves_the_weights():

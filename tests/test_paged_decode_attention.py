@@ -24,6 +24,7 @@ and the op's two lowerings.
   a four-chip mesh (``ops/attention_ops.py`` ``kernel_partition``, PR 31),
   which is here and not in ``test_attention.py`` for that reason.
 """
+import functools
 import os
 
 import numpy as np
@@ -627,3 +628,34 @@ def test_gated_delta_kernels_compile_for_a_described_v5e(chip):
             sds((1,), jnp.int32)).compile()
         assert chunk.as_text().count("tpu_custom_call") == 1
         assert chunk.memory_analysis().temp_size_in_bytes < 1.0e9
+
+
+@pytest.mark.parametrize("rows", [256, 1024])
+def test_latent_chunk_kernel_compiles_for_a_described_v5e(chip, rows):
+    """The chunk kernel over latent rows at DeepSeek-V2's published head
+    sizes (128 heads of nope 128 + rope 64 over a latent of 512, values of
+    128) over a slot's view of 12,800 rows of 640 lanes: one Mosaic call,
+    and no temporary that grows with context x heads (the 1024 rung's
+    expanded keys and values would be 2 GB)."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from paddle_tpu.ops.pallas import latent_attention as kern
+
+    one_chip = SingleDeviceSharding(chip)
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    H, dn, dr, dv, C, S = 128, 128, 64, 128, 512, 12800
+    assert kern.chunk_supported(H, rows, (S, 640), C, dn, dv)
+    compiled = jax.jit(functools.partial(
+        kern.mla_chunk_attention, scale=0.11472, nope_dim=dn,
+        latent_dim=C)).lower(
+        sds((H, rows, dn)), sds((H, rows, dr)), sds((S, 640)),
+        sds((C, H * (dn + dv))), sds((1,), jnp.int32)).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+    # (the rotated queries padded to the row's 128 lanes behind the latent)
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < 1.5 * H * rows * 128 * 4
