@@ -743,7 +743,11 @@ def sparse_window_phase(cfg=SPARSE):
     the rung with 300 real rows and the check engine's two slots with one
     live, NaN in every row behind ``valid``: the real rows are the
     loop's, the others' ``out`` exactly 0, the counts the real rows'
-    pairs, the products lowered as for the full rows.  The held
+    pairs, the products lowered as for the full rows; and (PR 57) that
+    the layer built the gate and the routing weight as the kernels'
+    epilogues (``grouped_matmul_epilogue_gate`` / ``_scale`` +1 each where
+    the products are the kernel, +0 at the check engine's rows) and came
+    back by the gather-sum (``moe_combine_gather`` +1).  The held
     share of such a layer (``held_first``) is checked at published widths
     in ``share_and_channel_phase``."""
     import jax
@@ -789,6 +793,10 @@ def sparse_window_phase(cfg=SPARSE):
     hi = jax.lax.Precision.HIGHEST
     lowered = ("grouped_matmul_lowered_pallas",
                "grouped_matmul_lowered_ragged_dot")
+    # ... and (PR 57) what the layer built round them: the gate and the
+    # routing weight as the kernels' epilogues, the one gather-sum
+    built = ("grouped_matmul_epilogue_gate", "grouped_matmul_epilogue_scale",
+             "moe_combine_gather")
     # a decode step's rows, a prefill rung's, and the check engine's two
     # slots (fewer rows than the kernel's row block: the one ragged_dot)
     # ... and a rung and the two slots with rows behind ``valid``
@@ -796,7 +804,7 @@ def sparse_window_phase(cfg=SPARSE):
             (cfg["slots"], None, 2), (cfg["prefill_rung"], None, 2),
             (cfg["prefill_rung"], cfg["rung_real_rows"], 2), (2, None, 0),
             (2, 1, 0)):
-        before = [stat_get(n) for n in lowered]
+        before = [stat_get(n) for n in lowered + built]
         fed, live = [x[:tokens], rx[:tokens]], None
         if real is not None:
             live = jnp.arange(tokens) < real
@@ -805,7 +813,12 @@ def sparse_window_phase(cfg=SPARSE):
             lambda *a, valid: moe_routed_tokens(
                 *a, top_k=k, precision=hi, valid=valid))(
                 *fed, rw, gu, dn, valid=live)
-        grew = [stat_get(n) - b for n, b in zip(lowered, before)]
+        grew = [stat_get(n) - b for n, b in zip(lowered + built, before)]
+        grew, fused = grew[:2], grew[2:]
+        check(fused == [int(kernels == 2)] * 2 + [1],
+              f"routed expert layer of {tokens} rows built "
+              f"{dict(zip(built, fused))}, expected both epilogues exactly "
+              f"where its products are the Pallas kernel, and the gather-sum")
         if real is not None:
             check(not bool(got[real:].any()),
                   f"routed expert layer of {tokens} rows, {real} real: a "

@@ -134,3 +134,87 @@ def block_bytes(scoped):
     where the parent's took under 1 (my chip run, PR 52); ``tiles``
     without ``scoped`` keeps the wide blocks PR 50 measured."""
     return SCOPED_BLOCK_BYTES if scoped else WEIGHT_BLOCK_BYTES
+
+
+# ---------------------------------------------------------------------------
+# The same product with an epilogue on its accumulator (PR 57), for a
+# routed layer that gives no [N k, .] array an HBM pass of its own: the
+# gate of the layer's first product (``act(gate) * up`` of the accumulator's
+# two halves, stored half as wide, so ``h`` [M, 2I] is never written) and
+# the routing weight of its second (a row's scale, a ``(tm, 1)`` block
+# beside the rows).  ``_kernel`` and ``grouped_matmul`` above stay on
+# their lines for the calls without one: a Mosaic call's serialised body
+# carries file and line, and the held experts' programs are the parent's.
+# ---------------------------------------------------------------------------
+
+def gate_fits(n, tn):
+    """Whether a product of ``n`` columns in blocks of ``tn`` can gate in
+    its epilogue: one block holds a row's gate and up halves whole, and
+    each half is whole lane tiles."""
+    return tn == n and n % (2 * LANES) == 0
+
+
+def _epilogue_kernel(offsets_ref, group_ref, block_ref, rows_ref, w_ref,
+                     *rest, tm, gate, scaled):
+    scale_ref, out_ref = rest if scaled else (None, *rest)
+    v = pl.program_id(1)
+    g = group_ref[v]
+    lo, hi = offsets_ref[g], offsets_ref[g + 1]
+    acc = jax.lax.dot_general(
+        rows_ref[...], w_ref[...], (((1,), (0,)), ((), ())),
+        precision=PRECISION, preferred_element_type=jnp.float32)
+    if gate is not None:
+        acc = gate(acc)
+    if scaled:
+        acc = acc * scale_ref[...]
+    row = block_ref[v] * tm + jax.lax.broadcasted_iota(
+        jnp.int32, acc.shape, 0)
+    out_ref[...] = jnp.where((row >= lo) & (row < hi), acc, out_ref[...])
+
+
+def grouped_matmul_epilogue(rows, weights, group_sizes, row_scale=None, *,
+                            tm, tn, gate=None, interpret=False):
+    """:func:`grouped_matmul` whose accumulator goes through an epilogue
+    before the masked store.  ``gate`` (needs :func:`gate_fits`): a
+    function of the ``[tm, N]`` float32 accumulator that yields ``[tm, N
+    // 2]`` (``parallel/moe.py`` ``_gated`` with its arguments bound), and
+    the result is [M, N // 2].  ``row_scale`` [M] float32: row ``r`` of the
+    result is multiplied by ``row_scale[r]``, after the gate.  Rows past
+    ``group_sizes.sum()`` are left as they lie, unscaled."""
+    m, k = rows.shape
+    groups, _, n = weights.shape
+    if gate is not None and not gate_fits(n, tn):
+        raise ValueError(f"a gate epilogue needs a row's {n} columns in one "
+                         f"block of whole lane tiles a half, not {tn}")
+    halve = 2 if gate is not None else 1
+    offsets, group, block, n_visits = visits(group_sizes, m, tm)
+    operands = [rows, weights]
+    in_specs = [
+        pl.BlockSpec((tm, k), lambda j, v, o, g, b: (b[v], 0)),
+        pl.BlockSpec((None, k, tn), lambda j, v, o, g, b: (g[v], 0, j)),
+    ]
+    if row_scale is not None:
+        operands.append(row_scale.astype(jnp.float32).reshape(m, 1))
+        in_specs.append(pl.BlockSpec((tm, 1), lambda j, v, o, g, b: (b[v], 0)))
+    return pl.pallas_call(
+        functools.partial(_epilogue_kernel, tm=tm, gate=gate,
+                          scaled=row_scale is not None),
+        out_shape=jax.ShapeDtypeStruct((m, n // halve), jnp.float32),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(n // tn, n_visits),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec((tm, tn // halve),
+                                   lambda j, v, o, g, b: (b[v], j)),
+        ),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT),
+        cost_estimate=pl.CostEstimate(
+            flops=2 * m * k * n,
+            transcendentals=m * n // 2 if gate is not None else 0,
+            bytes_accessed=4 * (m * k * (n // tn) + groups * k * n
+                                + m * n // halve)),
+        name="grouped_matmul_ragged-dot",
+        interpret=interpret,
+    )(offsets, group, block, *operands)

@@ -253,36 +253,32 @@ def _one_call(rows, weights, group_sizes, precision):
 
 
 def grouped_matmul(rows, weights, group_sizes, precision=None,
-                   mesh_devices=1):
+                   mesh_devices=1, row_scale=None, gate=None):
     """``rows`` [M, K], sorted by group, times ``weights`` [G, K, N]:
-    row ``r`` of group ``g`` is multiplied by ``weights[g]``.  One
-    formulation per shape, chosen on the chip with no flag (README "Routed
-    experts"; ``tools/moe_microbench.py``):
+    row ``r`` of group ``g`` is multiplied by ``weights[g]``.  ``gate``
+    ``(activation, limit)``: the result is :func:`_gated` of the product,
+    [M, N // 2]; ``row_scale`` [M]: row ``r`` of it times ``row_scale[r]``.
+    One formulation per shape, chosen on the chip with no flag (README
+    "Routed experts"; ``tools/moe_microbench.py``):
 
     * float32 at "highest" on a TPU, one device, at least one row block
       of rows: the Pallas kernel of ``ops/pallas/grouped_matmul.py``
       (``tiles``: 64 rows by the widest column block that fits).  It
       visits only the (row block, group) pairs that hold rows and reads a
-      group's weights once a column block.  Both matmuls of a layer on a
-      v5e, the routing's skew of 2.5, PR 32's formulation -> the kernel
-      (my chip run, PR 50): 64 groups of K 2560, width 768 at 48 / 96 /
-      192 / 384 / 768 rows a group 4.50 -> 3.25, 6.58 -> 4.50, 10.71 ->
-      6.85, 19.52 -> 11.57, 31.07 -> 20.91 ms and a decode step's 192 rows
-      2.48 -> 1.99; 64 groups of K 2048, width 1536 at 8 / 32 / 256 a
-      group 4.07 -> 3.51, 5.67 -> 4.50, 21.59 -> 13.41 and a step's 256
-      rows 3.71 -> 3.27; 128 groups of K 2048, width 768 at 8 / 64 a group
-      4.44 -> 3.52, 8.87 -> 5.95 and a block pass's 1,536 rows 4.73 ->
-      3.70; error against a float64 loop 3e-7 to 4e-7 on both sides.
+      group's weights once a column block.  The gate (where a column
+      block is all of N, ``gate_fits``) and the scale are epilogues on its
+      accumulator there, so neither [M, N] array gets a pass of its own
+      (:func:`_with_epilogue`; README "Routed experts" has the times).
     * everything else (off a TPU, another dtype or precision, fewer rows
       than a row block, under a mesh of more than one device: on a TPU
       that last is a downgrade and is logged once): one
-      ``jax.lax.ragged_dot`` call.  XLA:TPU lowers it to a grouped Mosaic
-      kernel that picks its row tile from the call's M (64 up to 192 rows,
-      512 from 512 on) and pays a whole tile for every group with a row in
-      it; the CPU lowering is a masked dense product.
+      ``jax.lax.ragged_dot`` call (XLA:TPU: a grouped Mosaic kernel that
+      pays a whole tile for every group with a row; the CPU: a masked
+      dense product), the gate and the scale after it in ``jnp``.
 
     ``grouped_matmul_lowered_pallas`` / ``..._ragged_dot`` count, per program
-    build, which a product lowered to (``_held_share``'s runs call it too)."""
+    build, which a product lowered to (``_held_share``'s runs call it too);
+    ``grouped_matmul_epilogue_gate`` / ``..._scale`` the epilogues built."""
     import jax
     import jax.numpy as jnp
 
@@ -297,10 +293,14 @@ def grouped_matmul(rows, weights, group_sizes, precision=None,
         kernel = False
         _downgrade(f"grouped_matmul under a {mesh_devices}-device mesh")
     _LOWERED["pallas" if kernel else "ragged_dot"].increase()
+    if kernel and (row_scale is not None or gate is not None):
+        return _with_epilogue(rows, weights, group_sizes, tiles, row_scale,
+                              gate)
     if kernel:
         return pallas.grouped_matmul(rows, weights, group_sizes,
                                      tm=tiles[0], tn=tiles[1])
-    return _one_call(rows, weights, group_sizes, precision)
+    return _after(_one_call(rows, weights, group_sizes, precision),
+                  row_scale, gate)
 
 
 def _gated(h, inter, activation, limit=None):
@@ -377,17 +377,17 @@ def moe_routed_tokens(x, router_x, router_w, w_gate_up, w_down, *,
 
     x [N, H] is the experts' input, router_x [N, H] what the router reads;
     router_w [H, E]; w_gate_up [E, H, 2I] (gate columns first); w_down [E, I,
-    H].  Every token goes to its k experts: no capacity, nothing dropped.
-    Pairs are sorted by expert, the two grouped matmuls read only experts that
-    got rows, and a token's k results are summed under the routing weights.
+    H].  Every token goes to its k experts: nothing dropped.  Pairs are sorted
+    by expert and the two grouped matmuls read only experts that got rows.
     ``valid`` [N] bool marks the real tokens: the pairs of a row behind it (a
     rung's pad tail, an idle slot) sort past the last group, no product visits
-    them and its ``out`` is 0 (a layer on a v5e, my chip run, PR 54: 64 x 2560
-    x 768, top 6, rung 4096 of 2,900 real rows 19.33 -> 16.71 ms, 8192 of
-    5,800 33.16 -> 27.96; 64 x 2048 x 1536, top 4, 1024 of 700 6.40 -> 5.65).
-    ``activation``, ``limit``: :func:`_gated`'s; ``score``, ``expert_bias``,
-    ``norm_topk``, ``route_scale``, ``n_group``, ``topk_group``:
-    :func:`route_top_k`'s; ``mesh_devices``: :func:`grouped_matmul`'s.
+    them and its ``out`` is 0.  Between the sort and ``out`` the layer writes
+    to HBM the gathered rows [N k, H], the GATED first product [N k, I], the
+    second product [N k, H] under its routing weight (both epilogues of the
+    kernel, :func:`grouped_matmul`) and their gather back (:func:`_combine`):
+    64 x 2560 x 768, top 6, rung 4096 of 2,900 real rows 16.70 ms (PR 56) ->
+    11.22, 8192 of 5,800 27.94 -> 20.17 (v5e, my chip run, PR 57).  ``limit``,
+    ``activation``: :func:`_gated`'s; the router's: :func:`route_top_k`'s.
 
     ``held_first`` (one chip's share of an expert-parallel group): the
     weights are those of experts ``held_first .. held_first + w_gate_up
@@ -431,20 +431,20 @@ def moe_routed_tokens(x, router_x, router_w, w_gate_up, w_down, *,
         flat = jnp.where(jnp.repeat(valid, top_k), flat, E)
     order = jnp.argsort(flat, stable=True)              # rows by expert
     group_sizes = jnp.bincount(flat, length=E).astype(jnp.int32)
-    rows = jnp.take(x, order // top_k, axis=0)          # [N*k, H]
-    h = grouped_matmul(rows, w_gate_up.astype(x.dtype), group_sizes,
-                       precision, mesh_devices)
-    y = grouped_matmul(_gated(h, inter, activation, limit),
-                       w_down.astype(x.dtype), group_sizes,
-                       precision, mesh_devices)         # [N*k, H]
-    y = y * jnp.take(weights.reshape(-1), order)[:, None].astype(y.dtype)
-    if valid is not None:   # rows past the groups hold what lay in memory
-        order = jnp.where(jnp.arange(N * top_k) < group_sizes.sum(), order,
-                          N * top_k)                    # ... and go nowhere
-    # back to token order: row r of the sorted list is pair order[r]
-    y = jnp.zeros_like(y).at[order].set(y, mode="drop")
-    out = y.reshape(N, top_k, H).sum(axis=1)
-    return out.astype(x.dtype), group_sizes, logits
+    rows = jnp.take(x, order // top_k, axis=0, mode="clip")   # [N*k, H]
+    # no [N*k, .] array gets a pass of its own where the products are the
+    # kernel: the gate is the first one's epilogue ([N*k, I] is written,
+    # never [N*k, 2I]) and the routing weight the second one's
+    act = grouped_matmul(rows, w_gate_up.astype(x.dtype), group_sizes,
+                         precision, mesh_devices, gate=(activation, limit))
+    y = grouped_matmul(act, w_down.astype(x.dtype), group_sizes, precision,
+                       mesh_devices,
+                       row_scale=jnp.take(weights.reshape(-1), order))
+    # back to token order by one gather-sum.  With ``valid`` the rows of y
+    # past the groups hold what lay in memory: a pad row's sum is selected
+    # to 0, never multiplied by it
+    return (_combine(y, order, top_k, valid).astype(x.dtype), group_sizes,
+            logits)
 
 
 def _scoped_tiles(rows, weights, precision, mesh_devices):
@@ -554,3 +554,78 @@ def _held_share(x, local, weights, w_gate_up, w_down, activation,
 
     return jax.lax.fori_loop(0, -(-n_held // run), body,
                              jnp.zeros((N, H), x.dtype))
+
+
+# ---------------------------------------------------------------------------
+# PR 57: the routed layer (``moe_routed_tokens`` without ``held_first``)
+# between and after its two products.  Kept down here so that every line
+# above stays where PR 56 had it: a Mosaic call's serialised body carries its
+# call sites' file and line, and the held experts' programs are the parent's
+# (``tools/moe_layer_hash.py``).
+# ---------------------------------------------------------------------------
+
+_FUSED = {"gate": _monitor.get("grouped_matmul_epilogue_gate"),
+          "scale": _monitor.get("grouped_matmul_epilogue_scale"),
+          "combine": _monitor.get("moe_combine_gather")}
+
+
+def _after(out, row_scale, gate):
+    """:func:`grouped_matmul`'s gate and scale as passes of their own over
+    ``out``, where the kernel does not take them as epilogues."""
+    if gate is not None:
+        out = _gated(out, out.shape[1] // 2, *gate)
+    if row_scale is not None:
+        out = out * row_scale[:, None].astype(out.dtype)
+    return out
+
+
+def _with_epilogue(rows, weights, group_sizes, tiles, row_scale, gate):
+    """:func:`grouped_matmul` on the Pallas kernel (blocks ``tiles``) with
+    ``gate`` and ``row_scale`` as epilogues on its accumulator.  A gate
+    whose columns are not one block (``gate_fits``) leaves the kernel's
+    plain call and :func:`_after` to do both."""
+    import functools
+
+    from ..ops.pallas import grouped_matmul as pallas
+
+    tm, tn = tiles
+    if gate is not None and not pallas.gate_fits(weights.shape[2], tn):
+        return _after(pallas.grouped_matmul(rows, weights, group_sizes,
+                                            tm=tm, tn=tn), row_scale, gate)
+    if gate is not None:
+        _FUSED["gate"].increase()
+        gate = functools.partial(_gated, inter=weights.shape[2] // 2,
+                                 activation=gate[0], limit=gate[1])
+    if row_scale is not None:
+        _FUSED["scale"].increase()
+    return pallas.grouped_matmul_epilogue(rows, weights, group_sizes,
+                                          row_scale, tm=tm, tn=tn, gate=gate)
+
+
+def _combine(y, order, top_k, valid=None):
+    """A token's k results, summed in the order j = 0 .. k - 1: ``y`` [N k,
+    H] holds the scaled results in sorted order, row ``r`` that of pair
+    ``order[r]`` = n k + j.  ``where[j N + n]`` is the row of pair (n, j) (a
+    scatter of N k int32), ONE gather of y's rows by it writes the planes
+    [k, N, H], plane j whole before plane j + 1, and the k-sum runs over the
+    leading axis, whole rows added to whole rows, where PR 28 to PR 56 wrote
+    [N k, H] of zeros, scattered y into them and reduced [N, k, H] over its
+    middle axis, k = 6 rows in a sublane tile of 8 (3.9 + 1.5 ms at 64 x
+    2560 x 768, top 6, rung 4096, against 1.7 for this; my chip run, PR 57,
+    ``tools/moe_microbench.py --pieces 1``).  The indices are in bounds
+    and said to be (``mode="clip"``): the default fills what an index out of
+    bounds would read, a select over the whole result.  With ``valid`` the
+    pairs of a row behind it point past the groups, at rows of y that hold
+    what lay in memory (NaN for all anyone knows): its sum is selected to
+    0."""
+    import jax.numpy as jnp
+
+    _FUSED["combine"].increase()
+    pairs = order.shape[0]
+    n = pairs // top_k
+    where = jnp.zeros((pairs,), jnp.int32).at[
+        (order % top_k) * n + order // top_k].set(
+        jnp.arange(pairs, dtype=jnp.int32))
+    out = jnp.take(y, where, axis=0, mode="clip").reshape(
+        top_k, n, -1).sum(axis=0)
+    return out if valid is None else jnp.where(valid[:, None], out, 0)

@@ -133,19 +133,22 @@ def interpreted(as_tpu, monkeypatch):
     kernel.grouped_matmul.clear_cache()
 
 
-def _eqns(jaxpr):
-    """Every equation of ``jaxpr`` and of the jaxprs inside it."""
+def _eqns(jaxpr, into_kernels=True):
+    """Every equation of ``jaxpr`` and of the jaxprs inside it (a Pallas
+    kernel's body too, unless ``into_kernels`` is False)."""
     for eqn in jaxpr.eqns:
         yield eqn
+        if eqn.primitive.name == "pallas_call" and not into_kernels:
+            continue
         for val in eqn.params.values():
             for sub in val if isinstance(val, (list, tuple)) else [val]:
                 sub = getattr(sub, "jaxpr", sub)
                 if hasattr(sub, "eqns"):
-                    yield from _eqns(sub)
+                    yield from _eqns(sub, into_kernels)
 
 
-def _primitives(jaxpr):
-    return [eqn.primitive.name for eqn in _eqns(jaxpr)]
+def _primitives(jaxpr, into_kernels=True):
+    return [eqn.primitive.name for eqn in _eqns(jaxpr, into_kernels)]
 
 
 def _result_rows(jaxpr, primitive):
@@ -432,3 +435,207 @@ def test_held_share_on_a_tpu_takes_the_kernel_at_the_rules_run(as_tpu, rows,
     assert "ragged_dot_general" not in prims
     assert stat_get("grouped_matmul_lowered_pallas") == before + 2
     assert _result_rows(jaxpr.jaxpr, "pallas_call") == {run}
+
+
+# ---------------------------------------------------------------------------
+# the epilogues (PR 57): the gate on the first product's accumulator, the
+# routing weight on the second's
+# ---------------------------------------------------------------------------
+
+GATES = {"no_gate": None, "relu": ("relu", None), "silu": ("silu", None),
+         "silu_with_limit": ("silu", 0.75)}
+# (m, K, N, tm, group sizes): N is two lane tiles, so a half is one
+EPILOGUE_LAYOUTS = {
+    "even_groups": (128, 16, 256, 16, [16] * 8),
+    "a_group_that_straddles_row_blocks": (
+        96, 16, 256, 16, [5, 50, 3, 3, 3, 3, 24, 5]),
+    "empty_groups": (96, 16, 256, 16, [16, 0, 24, 0, 0, 40, 16, 0]),
+    "rows_past_the_groups": (96, 16, 256, 16, [10, 0, 21, 9, 0, 17, 3, 8]),
+    "a_short_last_row_block": (100, 16, 256, 32,
+                               [12, 13, 12, 13, 12, 13, 12, 13]),
+}
+
+
+def _bound(gate, n):
+    import functools
+
+    return gate and functools.partial(moe._gated, inter=n // 2,
+                                      activation=gate[0], limit=gate[1])
+
+
+@pytest.mark.parametrize("gate,scaled", [
+    (g, s) for g in GATES for s in (False, True) if s or GATES[g]],
+    ids=lambda v: v if isinstance(v, str) else ("unscaled", "scaled")[v])
+@pytest.mark.parametrize("layout", EPILOGUE_LAYOUTS)
+def test_epilogue_kernel_is_the_plain_formulation(layout, gate, scaled):
+    """The gated and the scaled kernel (interpret mode) against the plain
+    kernel followed by ``_gated`` and the scale in ``jnp``: the same bits
+    in every row of a group; a group without rows is never read."""
+    m, k, n, tm, sizes = EPILOGUE_LAYOUTS[layout]
+    sizes = np.asarray(sizes, np.int32)
+    real = int(sizes.sum())
+    rng = np.random.default_rng(len(layout) + len(gate))
+    rows = rng.standard_normal((m, k)).astype(np.float32)
+    rows[real:] = np.nan
+    weights = rng.standard_normal((8, k, n)).astype(np.float32)
+    weights[sizes == 0] = np.nan
+    scale = rng.uniform(0.1, 1.0, m).astype(np.float32)
+    operands = (jnp.asarray(rows), jnp.asarray(weights), jnp.asarray(sizes))
+    got = np.asarray(kernel.grouped_matmul_epilogue(
+        *operands, jnp.asarray(scale) if scaled else None, tm=tm, tn=n,
+        gate=_bound(GATES[gate], n), interpret=True))
+    want = moe._after(
+        kernel.grouped_matmul(*operands, tm=tm, tn=n, interpret=True),
+        jnp.asarray(scale) if scaled else None, GATES[gate])
+    assert got.shape == want.shape == (m, n // 2 if GATES[gate] else n)
+    assert np.isfinite(got[:real]).all()
+    assert np.array_equal(got[:real], np.asarray(want)[:real])
+    if GATES[gate]:
+        gu = _loop(rows, weights, sizes)
+        g, up = gu[:, :n // 2], gu[:, n // 2:]
+        limit = GATES[gate][1]
+        if limit is not None:
+            g, up = np.minimum(g, limit), np.clip(up, -limit, limit)
+        g = np.maximum(g, 0) if GATES[gate][0] == "relu" \
+            else g / (1 + np.exp(-g))
+        loop = g * up * (scale[:real, None] if scaled else 1.0)
+        assert _rel(got[:real], loop) < 1e-5
+
+
+def test_scale_epilogue_over_two_column_blocks_and_a_gate_that_does_not_fit():
+    """The scale is an epilogue at any column block; the gate only where
+    one block holds a row's two halves in whole lane tiles."""
+    m, k, n, tm, sizes = EPILOGUE_LAYOUTS["empty_groups"]
+    rng = np.random.default_rng(7)
+    rows = jnp.asarray(rng.standard_normal((m, k)).astype(np.float32))
+    weights = jnp.asarray(
+        np.nan_to_num(rng.standard_normal((8, k, n))).astype(np.float32))
+    scale = jnp.asarray(rng.uniform(0.1, 1.0, m).astype(np.float32))
+    sizes = jnp.asarray(sizes, jnp.int32)
+    real = int(sizes.sum())
+    got = kernel.grouped_matmul_epilogue(rows, weights, sizes, scale, tm=tm,
+                                         tn=n // 2, interpret=True)
+    want = kernel.grouped_matmul(rows, weights, sizes, tm=tm, tn=n // 2,
+                                 interpret=True) * scale[:, None]
+    assert np.array_equal(np.asarray(got)[:real], np.asarray(want)[:real])
+    assert kernel.gate_fits(256, 256) and kernel.gate_fits(3072, 3072)
+    assert not kernel.gate_fits(256, 128)      # two column blocks
+    assert not kernel.gate_fits(48, 48)        # a half is no lane tile
+    with pytest.raises(ValueError, match="one block"):
+        kernel.grouped_matmul_epilogue(
+            rows, weights, sizes, tm=tm, tn=n // 2,
+            gate=_bound(("relu", None), n), interpret=True)
+
+
+EPILOGUE_COUNTERS = ("grouped_matmul_epilogue_gate",
+                     "grouped_matmul_epilogue_scale", "moe_combine_gather")
+
+
+def _traced_with(m, groups, k, n, gate, scaled, tiles=None, **kw):
+    """``moe.grouped_matmul`` with an epilogue asked for, traced: its
+    primitives outside any kernel's body, the result's shape, how far each
+    counter moved."""
+    S = jax.ShapeDtypeStruct
+    names = EPILOGUE_COUNTERS + ("grouped_matmul_lowered_pallas",
+                                 "grouped_matmul_lowered_ragged_dot")
+    before = {c: stat_get(c) for c in names}
+    args = [S((m, k), jnp.float32), S((groups, k, n), jnp.float32),
+            S((groups,), jnp.int32)] + ([S((m,), jnp.float32)] * scaled)
+    jaxpr = jax.make_jaxpr(
+        lambda r, w, s, c=None: moe.grouped_matmul(
+            r, w, s, HIGHEST, row_scale=c, gate=gate, **kw))(*args)
+    moved = [stat_get(c) - before[c] for c in names]
+    return (_primitives(jaxpr.jaxpr, into_kernels=False),
+            jaxpr.out_avals[0].shape, moved)
+
+
+# SmallThinker's, LFM2's and SDAR's first and second product at a rung's
+# and a step's rows
+@pytest.mark.parametrize("m,groups,k,inter,act", [
+    (24576, 64, 2560, 768, "relu"), (192, 64, 2560, 768, "relu"),
+    (4096, 64, 2048, 1536, "silu"), (1536, 128, 2048, 768, "silu")])
+def test_route_on_a_tpu_takes_the_epilogues_into_the_kernel(as_tpu, m, groups,
+                                                            k, inter, act):
+    prims, shape, moved = _traced_with(m, groups, k, 2 * inter, (act, None),
+                                       False)
+    assert prims.count("pallas_call") == 1 and shape == (m, inter)
+    assert moved == [1, 0, 0, 1, 0]
+    # the gate ran inside the kernel: nothing of it is left outside
+    assert not {"max", "logistic", "mul"} & set(
+        prims[prims.index("pallas_call") + 1:])
+    prims, shape, moved = _traced_with(m, groups, inter, k, None, True)
+    assert prims.count("pallas_call") == 1 and shape == (m, k)
+    assert moved == [0, 1, 0, 1, 0]
+    assert "mul" not in prims[prims.index("pallas_call") + 1:]
+
+
+@pytest.mark.parametrize("why,kw,route", [
+    ("the_cpu", {}, "ragged_dot"),
+    ("fewer_rows_than_a_row_block", {"m": 18}, "ragged_dot"),
+    ("under_a_mesh", {"mesh_devices": 4}, "ragged_dot"),
+    ("two_column_blocks", {"tiles": (64, 768)}, "pallas"),
+])
+def test_route_elsewhere_gates_and_scales_after_the_call(monkeypatch, why, kw,
+                                                         route):
+    """Everywhere the kernel does not take an epilogue the same arithmetic
+    runs after the call, and the epilogue counters say so by standing."""
+    kw = dict(kw)
+    if why != "the_cpu":
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        monkeypatch.setattr(moe, "_downgrades_logged", set())
+    tiles = kw.pop("tiles", None)
+    if tiles:
+        monkeypatch.setattr(kernel, "tiles",
+                            lambda m, k, n, scoped=False: tiles)
+    m = kw.pop("m", 3072)
+    prims, shape, moved = _traced_with(m, 64, 2560, 1536, ("relu", None),
+                                       True, **kw)
+    assert shape == (m, 768)
+    assert moved == [0, 0, 0, int(route == "pallas"),
+                     int(route == "ragged_dot")]
+    product = "pallas_call" if route == "pallas" else "ragged_dot_general"
+    assert prims.count(product) == 1
+    after = prims[prims.index(product) + 1:]
+    assert "max" in after and after.count("mul") >= 2
+
+
+@pytest.mark.parametrize("activation,limit", [("relu", None), ("silu", None),
+                                              ("silu", 0.5)])
+def test_routed_layer_with_both_epilogues_is_the_layer(interpreted,
+                                                       monkeypatch,
+                                                       activation, limit):
+    """``moe_routed_tokens`` with the gate and the routing weight inside the
+    kernels (interpret mode; a width of one lane tile so the gate fits)
+    against a float64 loop, and the three counters each up by one."""
+    monkeypatch.setattr(kernel, "tiles",
+                        lambda m, k, n, scoped=False: (16, n))
+    rng = np.random.default_rng(5)
+    tokens, hidden, experts, width, top_k = 40, 32, 8, 128, 3
+    x = rng.standard_normal((tokens, hidden)).astype(np.float32)
+    wr = rng.standard_normal((hidden, experts)).astype(np.float32)
+    wgu = rng.standard_normal((experts, hidden, 2 * width)).astype(
+        np.float32) * 0.3
+    wd = rng.standard_normal((experts, width, hidden)).astype(
+        np.float32) * 0.3
+    before = [stat_get(c) for c in EPILOGUE_COUNTERS]
+    out, counts, _ = moe.moe_routed_tokens(
+        jnp.asarray(x), jnp.asarray(x), wr, wgu, wd, top_k=top_k,
+        activation=activation, limit=limit, precision=HIGHEST)
+    assert [stat_get(c) - b for c, b in zip(EPILOGUE_COUNTERS, before)] \
+        == [1, 1, 1]
+    want = np.zeros((tokens, hidden))
+    for t in range(tokens):
+        l = x[t].astype(np.float64) @ wr
+        top = np.argsort(-l)[:top_k]
+        w = np.exp(l[top] - l[top].max())
+        w /= w.sum()
+        for e, we in zip(top, w):
+            gu = x[t].astype(np.float64) @ wgu[e]
+            g, up = gu[:width], gu[width:]
+            if limit is not None:
+                g, up = np.minimum(g, limit), np.clip(up, -limit, limit)
+            g = np.maximum(g, 0) if activation == "relu" \
+                else g / (1 + np.exp(-g))
+            want[t] += we * ((g * up) @ wd[e])
+    assert _rel(np.asarray(out), want) < 1e-5
+    assert int(counts.sum()) == tokens * top_k

@@ -250,8 +250,10 @@ def test_rows_behind_valid_go_through_no_expert(monkeypatch, which, n, route):
         kernel.grouped_matmul.clear_cache()
     seen = []
     product = moe.grouped_matmul
-    monkeypatch.setattr(moe, "grouped_matmul", lambda rows, w, sizes, *a: (
-        seen.append(np.asarray(sizes)), product(rows, w, sizes, *a))[1])
+    monkeypatch.setattr(
+        moe, "grouped_matmul", lambda rows, w, sizes, *a, **kw: (
+            seen.append(np.asarray(sizes)),
+            product(rows, w, sizes, *a, **kw))[1])
     rng = np.random.default_rng(n)
     hidden, experts, width, top_k = 16, 8, 12, 3
     x, rx = (rng.standard_normal((n, hidden)).astype(np.float32)
@@ -333,3 +335,129 @@ def test_kernel_visits_nothing_past_the_last_group(sizes):
         np.testing.assert_allclose(
             got[r], rows[r].astype(np.float64) @ weights[g], rtol=1e-5,
             atol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# the layer between and after its two products (PR 57)
+# ---------------------------------------------------------------------------
+
+def _parent_routed_tokens(x, router_x, router_w, w_gate_up, w_down, *, top_k,
+                          activation, valid, precision, limit=None):
+    """``moe_routed_tokens`` without ``held_first`` as PR 54 to PR 56 had
+    it, kept here as what the new formulation is held to: ``h`` [N k, 2I]
+    gated by a pass of its own, ``y`` scaled by another, scattered into
+    [N k, H] of zeros and summed."""
+    import jax.numpy as jnp
+
+    from paddle_tpu.parallel import moe
+
+    N, E, inter = x.shape[0], router_w.shape[1], w_down.shape[1]
+    logits, experts, weights = moe.route_top_k(router_x, router_w, top_k)
+    flat = experts.reshape(-1)
+    if valid is not None:
+        flat = jnp.where(jnp.repeat(valid, top_k), flat, E)
+    order = jnp.argsort(flat, stable=True)
+    group_sizes = jnp.bincount(flat, length=E).astype(jnp.int32)
+    rows = jnp.take(x, order // top_k, axis=0)
+    h = moe.grouped_matmul(rows, w_gate_up, group_sizes, precision)
+    y = moe.grouped_matmul(moe._gated(h, inter, activation, limit), w_down,
+                           group_sizes, precision)
+    y = y * jnp.take(weights.reshape(-1), order)[:, None]
+    if valid is not None:
+        order = jnp.where(jnp.arange(N * top_k) < group_sizes.sum(), order,
+                          N * top_k)
+    y = jnp.zeros_like(y).at[order].set(y, mode="drop")
+    return y.reshape(N, top_k, -1).sum(axis=1), group_sizes, logits
+
+
+@pytest.mark.parametrize("route", ["ragged_dot", "kernel"])
+@pytest.mark.parametrize("gate", [("relu", None), ("silu", None),
+                                  ("silu", 0.5)],
+                         ids=["relu", "silu", "silu_with_limit"])
+@pytest.mark.parametrize("which", ["None", "all", "a_pad_tail", "none"])
+def test_routed_layer_is_the_parents_bit_for_bit(monkeypatch, which, gate,
+                                                 route):
+    """The gate and the routing weight as the products' epilogues and the
+    one gather-sum, against the parent's formulation: the same bits in
+    ``out``, ``counts`` and ``logits``.  Every product's rows past
+    ``group_sizes.sum()`` are set to NaN on both sides (they "hold what lay
+    in memory"): a pad row's ``out`` is exactly 0 and no NaN reaches a real
+    row.  The three counters move on the kernel's route alone (the
+    gather-sum is the one combine, so its counter moves on both)."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+
+    from paddle_tpu.monitor import stat_get
+    from paddle_tpu.ops.pallas import grouped_matmul as kernel
+    from paddle_tpu.parallel import moe
+
+    highest = jax.lax.Precision.HIGHEST
+    if route == "kernel":
+        real = pl.pallas_call
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        monkeypatch.setattr(pl, "pallas_call", lambda *a, **kw: real(
+            *a, **dict(kw, interpret=True)))
+        monkeypatch.setattr(kernel, "tiles",
+                            lambda m, k, n, scoped=False: (8, n))
+        kernel.grouped_matmul.clear_cache()
+    product = moe.grouped_matmul
+
+    def poisoned(rows, w, sizes, *a, **kw):
+        out = product(rows, w, sizes, *a, **kw)
+        return jnp.where((jnp.arange(out.shape[0]) < sizes.sum())[:, None],
+                         out, jnp.nan)
+
+    monkeypatch.setattr(moe, "grouped_matmul", poisoned)
+    n, hidden, experts, width, top_k = 40, 32, 8, 128, 3
+    rng = np.random.default_rng(n + len(which))
+    x, rx = (rng.standard_normal((n, hidden)).astype(np.float32)
+             for _ in range(2))
+    wr = rng.standard_normal((hidden, experts)).astype(np.float32)
+    wgu = rng.standard_normal((experts, hidden, 2 * width)).astype(
+        np.float32) * 0.3
+    wd = rng.standard_normal((experts, width, hidden)).astype(
+        np.float32) * 0.3
+    valid = _valid_rows(which, n)
+    operands = [jnp.asarray(a) for a in (x, rx, wr, wgu, wd)]
+    kw = dict(top_k=top_k, activation=gate[0], limit=gate[1],
+              valid=None if valid is None else jnp.asarray(valid),
+              precision=highest)
+    want = [np.asarray(a) for a in _parent_routed_tokens(*operands, **kw)]
+    names = ("grouped_matmul_epilogue_gate", "grouped_matmul_epilogue_scale",
+             "moe_combine_gather")
+    before = [stat_get(c) for c in names]
+    got = [np.asarray(a) for a in moe.moe_routed_tokens(*operands, **kw)]
+    fused = int(route == "kernel")
+    assert [stat_get(c) - b for c, b in zip(names, before)] \
+        == [fused, fused, 1]
+    live = np.ones(n, bool) if valid is None else valid
+    for g, w in zip(got, want):
+        assert g.dtype == w.dtype and np.array_equal(g, w)
+    out, counts, _ = got
+    assert np.isfinite(out).all() and not out[~live].any()
+    assert not live.any() or np.abs(out[live]).min(axis=-1).max() > 0
+    assert int(counts.sum()) == int(live.sum()) * top_k
+    if route == "kernel":
+        kernel.grouped_matmul.clear_cache()
+
+
+def test_the_held_share_builds_no_epilogue_and_no_gather_sum():
+    """``held_first`` is the parent's path, line for line: none of the
+    three counters moves."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.monitor import stat_get
+    from paddle_tpu.parallel import moe
+
+    names = ("grouped_matmul_epilogue_gate", "grouped_matmul_epilogue_scale",
+             "moe_combine_gather")
+    before = [stat_get(c) for c in names]
+    S = jax.ShapeDtypeStruct
+    jax.make_jaxpr(lambda x, r, gu, dn: moe.moe_routed_tokens(
+        x, x, r, gu, dn, top_k=2, activation="silu", held_first=4,
+        precision=jax.lax.Precision.HIGHEST))(
+        S((32, 16), jnp.float32), S((16, 8), jnp.float32),
+        S((2, 16, 256), jnp.float32), S((2, 128, 16), jnp.float32))
+    assert [stat_get(c) for c in names] == before

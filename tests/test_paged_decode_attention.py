@@ -384,6 +384,54 @@ def test_grouped_matmul_kernel_compiles_for_a_described_v5e(chip, m, groups,
         < min(2 ** 23, 4 * k * n)
 
 
+@pytest.mark.parametrize("m,groups,k,inter,activation,limit", [
+    (49152, 64, 2560, 768, "relu", None),   # smallthinker-21b-a3b, rung 8192
+    (192, 64, 2560, 768, "relu", None),     # ... a decode step's pairs
+    (16384, 64, 2048, 1536, "silu", None),  # lfm2-24b-a2b, rung 4096
+    (1536, 128, 2048, 768, "silu", 7.0),    # sdar-30b-a3b-chat's pass, clamped
+])
+def test_grouped_matmul_epilogues_compile_for_a_described_v5e(
+        chip, m, groups, k, inter, activation, limit):
+    """A routed layer's two products with their epilogues (PR 57) at
+    published widths: the gate on the first one's accumulator, stored half
+    as wide, and the routing weight, a ``(tm, 1)`` block beside the rows,
+    on the second's.  Mosaic takes both; nothing runs."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from paddle_tpu.ops.pallas import grouped_matmul as kernel
+    from paddle_tpu.parallel import moe
+
+    one_chip = SingleDeviceSharding(chip)
+
+    def spec(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    tm, tn = kernel.tiles(m, k, 2 * inter)
+    assert kernel.gate_fits(2 * inter, tn)
+    gate = functools.partial(moe._gated, inter=inter, activation=activation,
+                             limit=limit)
+    first = jax.jit(lambda r, w, s: kernel.grouped_matmul_epilogue(
+        r, w, s, tm=tm, tn=tn, gate=gate)).lower(
+        spec((m, k)), spec((groups, k, 2 * inter)),
+        spec((groups,), jnp.int32)).compile()
+    tm, tn = kernel.tiles(m, inter, k)
+    second = jax.jit(lambda r, w, s, c: kernel.grouped_matmul_epilogue(
+        r, w, s, c, tm=tm, tn=tn)).lower(
+        spec((m, inter)), spec((groups, inter, k)),
+        spec((groups,), jnp.int32), spec((m,))).compile()
+    for compiled in (first, second):
+        text = compiled.as_text()
+        assert text.count('custom_call_target="tpu_custom_call"') == 1
+        assert "%grouped_matmul_ragged-dot" in text
+    # no [M, 2I] array beside the gated one
+    assert first.memory_analysis().temp_size_in_bytes < 2 ** 23
+    assert first.memory_analysis().output_size_in_bytes == 4 * m * inter
+
+
 def test_decode_step_for_a_described_v5e_reads_the_pools_in_place(
         chip, monkeypatch):
     """The whole paged decode step, small but at a head of 128, compiled

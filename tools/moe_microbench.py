@@ -4,6 +4,7 @@
     chiprun -- python tools/moe_microbench.py [--only NAME] [--tiles TM,TN;...]
     chiprun -- python tools/moe_microbench.py --held 1 [--only NAME] [--runs R,...]
     chiprun -- python tools/moe_microbench.py --valid 1 [--only NAME]
+    chiprun -- python tools/moe_microbench.py --pieces 1 [--only NAME] [--rows N,...]
 
 Times the two grouped matmuls of one expert FFN (gate + up, then down;
 float32 "highest") over rows sorted by expert, at the shapes the
@@ -47,6 +48,23 @@ prompt's rows`` (the tail's pairs sort past the last group).  Real rows are
 standard normal and routed with the cell's skew; the tail holds ONE drawn
 row repeated, as a rung's tail holds one token id, so all its pairs go to
 the same ``top_k`` experts.  Writes ``chiprun_out/moe_valid_sweep.json``.
+
+``--pieces 1`` times one routed layer's pieces apart (``PIECES``: the
+three configurations at a step's or pass's rows and at each rung of the
+mix, every row real): route and top-k; argsort and bincount; the row gather
+into sorted order; product 1; the gate; product 2; the routing weight; the
+un-sort; the k-sum, as the layer was built until PR 56 (``routed_layer``
+with nothing fused is that formulation, kept here), and beside them what PR
+57 put in their place: the row gather with its indices said to be in bounds,
+product 1 with the gate as its epilogue, product 2 with the routing weight
+as its epilogue, the inverse of the sort and the gather-sum (``gather_sum``:
+the planes the layer takes, and the two forms it was chosen over).  Each
+piece has its bytes' floor at 819 GB/s beside it (a product: the larger of
+that and the six-pass MXU floor); a piece alone costs a dispatch, about
+0.25 ms here, which the whole layer pays once.  Then the whole layer:
+``routed_layer`` at each step from the parent's formulation to the new, and
+``moe_routed_tokens`` as the tree has it.  Writes
+``chiprun_out/moe_pieces.json``.
 
 Writes ``chiprun_out/moe_formulation_sweep.json`` and prints one line per
 formulation: milliseconds for the pair of matmuls, against the six-pass
@@ -97,6 +115,12 @@ VALID = {
                                          (512, 360), (32, 32))),
     "lfm2-24b-a2b": (4, "silu", ((1024, 700), (64, 64))),
     "sdar-30b-a3b-chat": (8, "silu", ((512, 350), (192, 192))),
+}
+# name: (top k, the gate, the step's or pass's rows, the mix's rungs)
+PIECES = {
+    "smallthinker-21b-a3b": (6, "relu", 32, (512, 1024, 2048, 4096, 8192)),
+    "lfm2-24b-a2b": (4, "silu", 64, (128, 256, 512, 1024, 2048, 4096)),
+    "sdar-30b-a3b-chat": (8, "silu", 192, (128, 256, 512, 1024)),
 }
 PEAK, HBM = 197e12, 819e9
 RUN_ROWS, WIDE_TILE = 192, 512  # the parent's runs (PR 32)
@@ -381,6 +405,275 @@ def valid_main(args) -> int:
     return 0
 
 
+def inverse_of(order, top_k=None):
+    """Where each pair went: ``inverse[order[r]] == r``; with ``top_k`` in
+    plane order, ``inverse[j N + n]`` the row of pair (n, j)."""
+    import jax.numpy as jnp
+
+    at = order
+    if top_k:
+        at = (order % top_k) * (order.shape[0] // top_k) + order // top_k
+    return jnp.zeros(order.shape, jnp.int32).at[at].set(
+        jnp.arange(order.shape[0], dtype=jnp.int32))
+
+
+def gather_sum(y, inverse, top_k, how="planes"):
+    """``out[n] = sum_j y[row of pair (n, j)]``, j in order.  ``planes``
+    (what ``moe._combine`` does): ``inverse`` in plane order, one gather
+    writes [k, N, H] and the k-sum runs over the LEADING axis, whole rows
+    added to whole rows.  ``one``: ``inverse`` in pair order, one gather of
+    [N k, H] and the k-sum over the middle axis of [N, k, H] (what PR 57
+    first built: the parent's bits, and its slow reduce).  ``each``: k
+    gathers of [N, H] added up.  The indices are in bounds, and said to be
+    (``mode="clip"``): ``jnp.take``'s default fills what an index out of
+    bounds would read, a select over the whole result."""
+    import jax.numpy as jnp
+
+    n = inverse.shape[0] // top_k
+    if how == "planes":
+        return jnp.take(y, inverse, axis=0, mode="clip").reshape(
+            top_k, n, -1).sum(axis=0)
+    if how == "one":
+        return jnp.take(y, inverse, axis=0, mode="clip").reshape(
+            n, top_k, -1).sum(axis=1)
+    at = inverse.reshape(n, top_k)
+    out = jnp.take(y, at[:, 0], axis=0, mode="clip")
+    for j in range(1, top_k):
+        out = out + jnp.take(y, at[:, j], axis=0, mode="clip")
+    return out
+
+
+def routed_layer(x, rx, router, gu, dn, top_k, gate, fuse_gate=False,
+                 fuse_scale=False, combine="scatter"):
+    """``moe_routed_tokens`` (every row real, softmax router) built from
+    its pieces.  With nothing fused and ``combine`` "scatter" it is the
+    formulation of PR 28 to PR 56, kept here as the line PR 57 is timed
+    against: the rows gathered with ``jnp.take``'s default fill, ``h`` [N k,
+    2I] written and gated by XLA, ``y`` scaled by a pass of its own,
+    scattered into zeros and summed."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops.pallas import grouped_matmul as kernel
+    from paddle_tpu.parallel import moe
+
+    highest = jax.lax.Precision.HIGHEST
+    n, inter = x.shape[0], dn.shape[1]
+    _, experts, weights = moe.route_top_k(rx, router, top_k)
+    flat = experts.reshape(-1)
+    order = jnp.argsort(flat, stable=True)
+    sizes = jnp.bincount(flat, length=router.shape[1]).astype(jnp.int32)
+    rows = jnp.take(x, order // top_k, axis=0,
+                    mode="fill" if combine == "scatter" else "clip")
+    scale = jnp.take(weights.reshape(-1), order)
+    t1 = kernel.tiles(rows.shape[0], rows.shape[1], gu.shape[2])
+    if fuse_gate and t1 and kernel.gate_fits(gu.shape[2], t1[1]):
+        act = kernel.grouped_matmul_epilogue(
+            rows, gu, sizes, tm=t1[0], tn=t1[1], gate=functools.partial(
+                moe._gated, inter=inter, activation=gate))
+    else:
+        act = moe._gated(moe.grouped_matmul(rows, gu, sizes, highest),
+                         inter, gate)
+    t2 = kernel.tiles(act.shape[0], act.shape[1], dn.shape[2])
+    if fuse_scale and t2:
+        y = kernel.grouped_matmul_epilogue(act, dn, sizes, scale, tm=t2[0],
+                                           tn=t2[1])
+    else:
+        y = moe.grouped_matmul(act, dn, sizes, highest) * scale[:, None]
+    if combine == "scatter":
+        return jnp.zeros_like(y).at[order].set(y, mode="drop").reshape(
+            n, top_k, -1).sum(axis=1)
+    return gather_sum(
+        y, inverse_of(order, top_k if combine == "planes" else None), top_k,
+        combine)
+
+
+def pieces_main(args) -> int:
+    """The ``--pieces`` table (the module docstring)."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops.pallas import grouped_matmul as kernel
+    from paddle_tpu.parallel import moe
+
+    highest = jax.lax.Precision.HIGHEST
+    only_rows = {int(r) for r in args.rows.split(",") if r}
+    os.makedirs("chiprun_out", exist_ok=True)
+    out_path = "chiprun_out/moe_pieces.json"
+    if args.only or only_rows:
+        out_path = out_path.replace(
+            ".json", f"_{args.only or 'all'}" + (
+                f"_{'-'.join(map(str, sorted(only_rows)))}"
+                if only_rows else "") + ".json")
+    results = []
+    for name, (top_k, gate, step, rungs) in PIECES.items():
+        if args.only and name != args.only:
+            continue
+        groups, K, I = SHAPES[name][:3]
+        key = jax.random.key(groups + K)
+        gu = jax.random.normal(jax.random.fold_in(key, 1),
+                               (groups, K, 2 * I)) * .02
+        dn = jax.random.normal(jax.random.fold_in(key, 2),
+                               (groups, I, K)) * .02
+        for n in (step,) + rungs:
+            if only_rows and n not in only_rows:
+                continue
+            rng = np.random.default_rng(n + groups)
+            # the router reads rows of its own: their last column is 1 and
+            # the router's last row the log of the skewed loads
+            router = rng.standard_normal((K, groups)).astype(np.float32) * .02
+            router[-1] = np.log(skewed_loads(rng, groups, SKEW))
+            x, rx = (rng.standard_normal((n, K)).astype(np.float32)
+                     for _ in range(2))
+            rx[:, -1] = 1.0
+            x, rx, router = (jnp.asarray(a) for a in (x, rx, router))
+            m = n * top_k
+            # every piece's operands, made once by the parent's pieces
+            _, experts, weights = jax.jit(functools.partial(
+                moe.route_top_k, top_k=top_k))(rx, router)
+            flat = experts.reshape(-1)
+            order = jnp.argsort(flat, stable=True)
+            sizes = jnp.bincount(flat, length=groups).astype(jnp.int32)
+            rows = jnp.take(x, order // top_k, axis=0)
+            mm = jax.jit(lambda a, b, s: moe.grouped_matmul(a, b, s, highest))
+            h = mm(rows, gu, sizes)
+            act = jax.jit(lambda h: moe._gated(h, I, gate))(h)
+            y = mm(act, dn, sizes)
+            scale = jnp.take(weights.reshape(-1), order)
+            inv = inverse_of(order)
+            del h
+            t1, t2 = kernel.tiles(m, K, 2 * I), kernel.tiles(m, I, K)
+            touched = int((np.asarray(sizes) > 0).sum())
+
+            def passes(floats):
+                return floats * 4 / HBM * 1e3
+
+            def product(width_in, width_out, wrote):
+                return max(6 * 2.0 * m * width_in * width_out / PEAK * 1e3,
+                           (touched * width_in * width_out + m * width_in
+                            + m * wrote) * 4 / HBM * 1e3)
+
+            mh = m * K
+            cases = [
+                ("route and top-k", lambda: jax.jit(functools.partial(
+                    moe.route_top_k, top_k=top_k)), (rx, router),
+                 passes(n * K)),
+                ("argsort and bincount", lambda: jax.jit(lambda f: (
+                    jnp.argsort(f, stable=True),
+                    jnp.bincount(f, length=groups))), (flat,), 0.0),
+                ("row gather", lambda: jax.jit(lambda x, o: jnp.take(
+                    x, o // top_k, axis=0)), (x, order), passes(2 * mh)),
+                ("new: row gather, indices said in bounds", lambda: jax.jit(
+                    lambda x, o: jnp.take(x, o // top_k, axis=0,
+                                          mode="clip")), (x, order),
+                 passes(2 * mh)),
+                ("product 1", lambda: mm, (rows, gu, sizes),
+                 product(K, 2 * I, 2 * I)),
+                ("gate", lambda: jax.jit(lambda h: moe._gated(h, I, gate)),
+                 (mm(rows, gu, sizes),), passes(3 * m * I)),
+                ("product 2", lambda: mm, (act, dn, sizes),
+                 product(I, K, K)),
+                ("scale", lambda: jax.jit(lambda y, s: y * s[:, None]),
+                 (y, scale), passes(2 * mh)),
+                ("un-sort (zeros, scatter)", lambda: jax.jit(
+                    lambda y, o: jnp.zeros_like(y).at[o].set(
+                        y, mode="drop")), (y, order), passes(3 * mh)),
+                ("k-sum", lambda: jax.jit(lambda y: y.reshape(
+                    n, top_k, -1).sum(axis=1)), (y,),
+                 passes(mh + n * K)),
+                ("new: inverse of the sort (a scatter of int32)",
+                 lambda: jax.jit(functools.partial(inverse_of, top_k=top_k)),
+                 (order,), 0.0),
+                ("new: gather-sum, planes [k, N, H]", lambda: jax.jit(
+                    functools.partial(gather_sum, top_k=top_k)),
+                 (y, inverse_of(order, top_k)), passes(mh + n * K)),
+                ("new: gather-sum, one gather of [N k, H], sum over [N, k, H]",
+                 lambda: jax.jit(functools.partial(
+                     gather_sum, top_k=top_k, how="one")),
+                 (y, inv), passes(mh + n * K)),
+                ("new: gather-sum, k gathers of [N, H]", lambda: jax.jit(
+                    functools.partial(gather_sum, top_k=top_k, how="each")),
+                 (y, inv), passes(mh + n * K)),
+            ]
+            if t1 and kernel.gate_fits(2 * I, t1[1]):
+                cases.append((
+                    "new: product 1, gate its epilogue", lambda: jax.jit(
+                        lambda a, b, s: kernel.grouped_matmul_epilogue(
+                            a, b, s, tm=t1[0], tn=t1[1],
+                            gate=functools.partial(
+                                moe._gated, inter=I, activation=gate))),
+                    (rows, gu, sizes), product(K, 2 * I, I)))
+            if t2:
+                cases.append((
+                    "new: product 2, scale its epilogue", lambda: jax.jit(
+                        lambda a, b, s, c: kernel.grouped_matmul_epilogue(
+                            a, b, s, c, tm=t2[0], tn=t2[1])),
+                    (act, dn, sizes, scale), product(I, K, K)))
+            layer = functools.partial(routed_layer, top_k=top_k, gate=gate)
+            wholes = [
+                ("whole: parent formulation", layer),
+                ("whole: gather-sum (one gather, [N, k, H])",
+                 functools.partial(layer, combine="one")),
+                ("whole: gather-sum (k gathers)", functools.partial(
+                    layer, combine="each")),
+                ("whole: gather-sum (planes)", functools.partial(
+                    layer, combine="planes")),
+                ("whole: gather-sum (planes), scale fused",
+                 functools.partial(layer, combine="planes",
+                                   fuse_scale=True)),
+                ("whole: gather-sum (planes), scale and gate fused",
+                 functools.partial(layer, combine="planes", fuse_scale=True,
+                                   fuse_gate=True)),
+                ("whole: moe_routed_tokens as the tree has it",
+                 lambda x, rx, r, gu, dn: moe.moe_routed_tokens(
+                     x, rx, r, gu, dn, top_k=top_k, activation=gate,
+                     precision=highest)[0]),
+            ]
+            cases += [(case, lambda f=f: jax.jit(f), (x, rx, router, gu, dn),
+                       0.0) for case, f in wholes]
+            print(f"{name} {n} rows ({m} pairs, top {top_k} of {groups}, "
+                  f"{touched} touched, largest group "
+                  f"{int(np.asarray(sizes).max())}; kernel blocks {t1} "
+                  f"{t2})", flush=True)
+            first = None
+            for case, build, operands, floor in cases:
+                rec = {"shape": name, "rows": n, "pairs": m, "piece": case}
+                try:
+                    f = build()
+                    ms, out = timed(
+                        lambda *a: jax.tree_util.tree_leaves(f(*a))[0],
+                        *operands, reps=10)
+                except Exception as e:  # noqa: BLE001 — as in main
+                    print(f"    {case:48s} refused: {str(e)[:200]}",
+                          flush=True)
+                    results.append(dict(rec, refused=str(e)[:400]))
+                    continue
+                if case.startswith("whole"):
+                    out = np.asarray(out)
+                    first = out if first is None else first
+                    rec["rel_diff_to_parent"] = float(
+                        np.abs(out - first).max() / np.abs(first).max())
+                    rec["same_bits_as_parent"] = bool(
+                        np.array_equal(out, first))
+                print(f"    {case:48s} {ms:8.3f} ms"
+                      + (f"  floor {floor:6.3f}" if floor else "")
+                      + (f"  off the parent's by "
+                         f"{rec['rel_diff_to_parent']:.3g}"
+                         if "rel_diff_to_parent" in rec else ""),
+                      flush=True)
+                results.append(dict(rec, ms=ms, floor_ms=floor))
+            # after every shape: a run cut short keeps what it measured
+            with open(out_path, "w") as f:
+                json.dump({"device": jax.devices()[0].device_kind,
+                           "skew": SKEW, "results": results}, f, indent=1)
+        del gu, dn
+    return 0
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None, help="one name of SHAPES")
@@ -395,6 +688,11 @@ def main(argv=None) -> int:
     ap.add_argument("--valid", type=int, default=0,
                     help="1: a whole routed layer at VALID's rungs, all rows "
                          "against the prompt's")
+    ap.add_argument("--pieces", type=int, default=0,
+                    help="1: a routed layer's pieces apart at PIECES' rows, "
+                         "the parent's beside PR 57's")
+    ap.add_argument("--rows", default="",
+                    help="with --pieces: these rows of PIECES alone")
     args = ap.parse_args(argv)
     import jax
     import jax.numpy as jnp
@@ -407,6 +705,8 @@ def main(argv=None) -> int:
         return held_main(args)
     if args.valid:
         return valid_main(args)
+    if args.pieces:
+        return pieces_main(args)
     from jax.experimental.pallas.ops.tpu.megablox import gmm
 
     from paddle_tpu.ops.pallas import grouped_matmul as kernel
