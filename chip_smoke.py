@@ -53,6 +53,14 @@ random seeded weights:
   pages: ``attention_lowered_latent_chunk`` +4, ``_latent_chunk_reference``
   +0, and the logits are the single-shot prefill's.
 
+* **state-space layers** (PR 59) — the two SSD kernels
+  (``ops/pallas/ssd.py``) at 64 heads of 64 over 128 state rows against
+  the XLA formulations of ``ops/ssd_ops.py`` (the whole scan over a padded
+  prompt, the step over 32 slots of which some are dead), and one
+  state-space round trip through a two-slot engine under the family's four
+  multipliers: ``ssd_lowered_pallas`` grows, ``ssd_lowered_reference``
+  stays 0.
+
 Any failed check raises: the exit code is non-zero and no result line is
 printed.  Without a TPU backend the script refuses to run (exit 2).  The
 last line of stdout is one JSON object
@@ -1523,6 +1531,129 @@ def latent_chunk_phase(cfg=LATENT_CHUNK):
         f"the single-shot prefill's")
 
 
+SSD = dict(heads=64, head_dim=64, state=128, conv=4, slots=32, seq=512,
+           valid=450, hidden=2048, prompt=300, steps=3)
+
+
+def ssd_phase(cfg=SSD):
+    """What a decoder with state-space duality (Mamba-2) layers adds (PR
+    59), at granite-4.0-h-micro's published sizes (64 heads of 64 over 128
+    state rows, one group): the two Pallas kernels of
+    ``ops/pallas/ssd.py`` against the XLA formulations of
+    ``ops/ssd_ops.py`` (the whole scan over a padded prompt; the step over
+    32 slots of which some are dead: their state and the trash row bit for
+    bit what they were); and one state round trip through a two-slot
+    ``GenerationEngine`` of one state-space layer and one attention layer
+    without rotary embedding at hidden 2048 under the family's four
+    multipliers and the tied head: a prefill of more than two chunks,
+    three decode steps, the slot taken again, each against the uncached
+    forward, with the lowering counters."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.monitor import stat_get
+    from paddle_tpu.ops import ssd_ops
+    from paddle_tpu.ops.pallas import ssd as kern
+    from paddle_tpu.serving import GenerationEngine
+
+    H, P, N = cfg["heads"], cfg["head_dim"], cfg["state"]
+    T, n = cfg["seq"], cfg["slots"]
+    key = jax.random.key(59)
+
+    def draw(i, *shape):
+        return jax.random.normal(jax.random.fold_in(key, i), shape)
+
+    def steps(i, *shape):
+        return jnp.exp(jax.random.uniform(
+            jax.random.fold_in(key, i), shape, jnp.float32,
+            np.log(1e-3), np.log(0.1)))
+
+    a = -jax.random.uniform(jax.random.fold_in(key, 20), (H,), jnp.float32,
+                            1.0, 16.0)
+    d = jnp.ones((H,), jnp.float32)
+    valid = jnp.asarray([cfg["valid"]], jnp.int32)
+    ops = (draw(0, 1, T, H, P), steps(1, 1, T, H), a, draw(2, 1, T, N),
+           draw(3, 1, T, N), d)
+    want_o, want_s = jax.jit(lambda *t: ssd_ops.chunked(*t, valid=valid))(
+        *ops)
+    got_o, got_s = kern.chunk(*ops, valid=valid)
+    rel = max(float(jnp.abs(got_o - want_o).max() / jnp.abs(want_o).max()),
+              float(jnp.abs(got_s - want_s).max() / jnp.abs(want_s).max()))
+    check(bool(jnp.isfinite(got_o).all()) and rel <= TOL,
+          f"ssd_chunk kernel off the XLA scan by {rel:.4g}")
+    state = draw(5, n + 1, N, H * P)
+    live = jnp.asarray(np.arange(n) % 5 != 3, jnp.int32)
+    row = (draw(6, n, H, P), steps(7, n, H), a, draw(8, n, N),
+           draw(9, n, N), d)
+    want_o, want_s = jax.jit(ssd_ops.step)(*row, state, live.astype(bool))
+    got_o, got_s = kern.step(*row, state, live)
+    on = np.asarray(live, bool)
+    rel_step = max(
+        float(np.abs(np.asarray(got_o - want_o))[on].max()
+              / jnp.abs(want_o).max()),
+        float(jnp.abs(got_s - want_s).max() / jnp.abs(want_s).max()))
+    check(rel_step <= TOL,
+          f"ssd_step kernel off the XLA step by {rel_step:.4g}")
+    check(bool(jnp.array_equal(got_s[:n][~on], state[:n][~on]))
+          and bool(jnp.array_equal(got_s[n], state[n])),
+          "ssd_step moved a dead slot's state or the trash row")
+    say(f"ssd: kernels at {H} heads of {P} over {N} state rows: the whole "
+        f"scan over {cfg['valid']} of {T} rows within {rel:.4g} of the XLA "
+        f"form, the step over {int(on.sum())} live of {n} slots within "
+        f"{rel_step:.4g} of it, dead slots untouched (tolerance {TOL})")
+
+    ssd = {"kind": "ssd", "heads": H, "head_dim": P, "state": N,
+           "groups": 1, "conv": cfg["conv"], "conv_bias": True}
+    model = dict(vocab_size=4096, hidden=cfg["hidden"], num_layers=2,
+                 num_heads=32, num_kv_heads=8, intermediate=4096,
+                 rms_norm_eps=1e-5, tie_head=True, embed_scale=12.0,
+                 residual_scale=0.22, attn_scale=0.015625,
+                 logit_scale=0.125,
+                 layer_pattern=[
+                     {"mixer": ssd, "rope": False},
+                     {"mixer": "attention", "rope": False,
+                      "attn_precision": "highest"}])
+    pal0 = stat_get("ssd_lowered_pallas")
+    ref0 = stat_get("ssd_lowered_reference")
+    gen = GenerationEngine(model, num_slots=2, max_seq_len=512,
+                           prefill_buckets=[384], page_tokens=16,
+                           prefill_chunk=0, prefix_reuse=False,
+                           speculate=False, keep_logits=True, eos_id=-1)
+    try:
+        gen.warmup()
+        pallas = stat_get("ssd_lowered_pallas") - pal0
+        reference = stat_get("ssd_lowered_reference") - ref0
+        check(pallas >= 2 and reference == 0,
+              f"the state-space ops lowered to {pallas} kernels and "
+              f"{reference} XLA formulations, not to kernels alone")
+        rng = np.random.default_rng(59)
+        worst = 0.0
+        for n_prompt in (cfg["prompt"], 3):   # the second reuses slot 0
+            prompt = rng.integers(1, 4096, n_prompt).tolist()
+            res = gen.generate(prompt, cfg["steps"] + 1, timeout=600)
+            check(res["slot"] == 0, f"request landed in slot {res['slot']}")
+            seq = prompt + res["tokens"]
+            want = _forward_logits(gen, model, seq, 384)[
+                n_prompt - 1:n_prompt + cfg["steps"]]
+            got = np.stack(res["logits"])
+            rel = float(np.abs(got - want).max() / np.abs(want).max())
+            worst = max(worst, rel)
+            check(np.isfinite(got).all() and rel <= TOL,
+                  f"state-space round trip (prompt {n_prompt}) off the "
+                  f"uncached forward by {rel:.4g}")
+        counters = gen.stats()["counters"]
+        check(counters["slot_state_writes"] == 2
+              and counters["ssm_state_steps"] >= 2 * cfg["steps"],
+              f"state counters {counters['slot_state_writes']} writes, "
+              f"{counters['ssm_state_steps']} steps")
+    finally:
+        gen.close()
+    say(f"ssd: state through a chunked prefill, {cfg['steps']} decode "
+        f"steps and a reused slot within {worst:.4g} of the uncached "
+        f"forward; ssd_lowered_pallas +{pallas}, ssd_lowered_reference "
+        f"+{reference}")
+
+
 def main():
     t_start = time.perf_counter()
     # the program first: in a directory that holds only this file the
@@ -1603,6 +1734,12 @@ def main():
     t0 = time.perf_counter()
     latent_chunk_phase()
     say(f"latent chunk kernel and chunks over latent pages done "
+        f"[{time.perf_counter() - t0:.1f} s]")
+    gc.collect()
+
+    t0 = time.perf_counter()
+    ssd_phase()
+    say(f"state-space kernels and state done "
         f"[{time.perf_counter() - t0:.1f} s]")
 
     say(f"set-up (compile-dominated: kernel check + first train step + "

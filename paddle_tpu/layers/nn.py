@@ -28,7 +28,7 @@ __all__ = [
     "latent_decode_attention", "latent_chunk_attention", "block_begin",
     "block_unmask",
     "short_conv", "short_conv_tail", "slot_state_write", "short_conv_step",
-    "gated_delta_chunk", "gated_delta_step",
+    "gated_delta_chunk", "gated_delta_step", "ssd_chunk", "ssd_step",
     "linear_chain_crf", "crf_decoding", "warpctc",
     "nce", "hsigmoid", "conv3d", "pool3d", "lrn", "row_conv",
     "shuffle_channel", "temporal_shift", "multiplex",
@@ -981,6 +981,42 @@ def gated_delta_step(q, k, v, g, beta, state, live, name=None):
     helper.append_op("gated_delta_step",
                      inputs={"Q": [q], "K": [k], "V": [v], "G": [g],
                              "Beta": [beta], "State": [state],
+                             "Live": [live]},
+                     outputs={"Out": [out], "StateOut": [state]})
+    return out
+
+
+def ssd_chunk(x, dt, a, bm, cm, d, state0=None, valid=None, name=None):
+    """The state-space duality recurrence over a whole sequence
+    (ops/ssd_ops.py ``ssd_chunk``): ``x`` [B, T, H, P], ``dt`` [B, T, H]
+    (positive), ``a`` and ``d`` [H] (``a`` negative), ``bm``, ``cm``
+    [B, T, N], optionally from ``state0`` [B, N, H * P] and with ``valid``
+    [B] real rows.  Returns ``(out [B, T, H, P], state [B, N, H * P])``,
+    the state after the last real token."""
+    helper = LayerHelper("ssd_chunk", name=name)
+    out = helper.create_variable_for_type_inference(x.dtype)
+    state = helper.create_variable_for_type_inference(x.dtype)
+    inputs = {"X": [x], "Dt": [dt], "A": [a], "Bm": [bm], "Cm": [cm],
+              "D": [d]}
+    if state0 is not None:
+        inputs["State0"] = [state0]
+    if valid is not None:
+        inputs["Valid"] = [valid]
+    helper.append_op("ssd_chunk", inputs=inputs,
+                     outputs={"Out": [out], "StateOut": [state]})
+    return out, state
+
+
+def ssd_step(x, dt, a, bm, cm, d, state, live, name=None):
+    """One decode step of :func:`ssd_chunk`: one row a slot (``x``
+    [slots, 1, H, P], ``dt`` [slots, 1, H], ``bm``, ``cm`` [slots, 1, N])
+    over ``state`` [slots + 1, N, H * P], which moves on in place for rows
+    with ``live`` set.  Returns the output [slots, 1, H, P]."""
+    helper = LayerHelper("ssd_step", name=name)
+    out = helper.create_variable_for_type_inference(x.dtype)
+    helper.append_op("ssd_step",
+                     inputs={"X": [x], "Dt": [dt], "A": [a], "Bm": [bm],
+                             "Cm": [cm], "D": [d], "State": [state],
                              "Live": [live]},
                      outputs={"Out": [out], "StateOut": [state]})
     return out

@@ -109,7 +109,14 @@ def _program_build(kind, bucket_at=None):
 #           with "gate_rank" makes the output gate ``sigmoid(h W_down
 #           W_up)`` (the default: ``silu(h W_gate)``, full rank); "gate":
 #           "sigmoid" WITHOUT "gate_rank" is the full-rank ``gate_scale *
-#           sigmoid(h W_gate)`` ("gate_scale" 1.0)
+#           sigmoid(h W_gate)`` ("gate_scale" 1.0).  Or a dict {"kind":
+#           "ssd", "heads": H, "head_dim": P, "state": N, "groups": 1,
+#           "conv": L, "conv_bias": True}: a state-space duality (Mamba-2)
+#           layer (:func:`_ssd_mixer`), whose cache is two states a slot:
+#           the last L - 1 rows that its convolution over x | B | C saw,
+#           and a matrix of N state rows over all H * P channels that every
+#           token decays by one number a head and writes ``B (dt x)^T``
+#           into (``ops/ssd_ops.py`` says how it lies)
 #   mla:    None, or a dict that makes the attention layer LATENT
 #           (:func:`_mla_mixer`): {"q_rank": Rq, "kv_rank": C, "nope_dim":
 #           dn, "rope_dim": dr, "v_dim": dv, "scale": the softmax scale
@@ -146,7 +153,8 @@ def layer_spec(layer_pattern, i):
 
 def state_layers(layer_pattern, num_layers):
     """Indices of the layers that keep slot state, not pages: those whose
-    mixer is a gated short convolution or the gated delta rule."""
+    mixer is a gated short convolution, the gated delta rule or a
+    state-space (SSD) layer."""
     return [i for i in range(num_layers)
             if layer_spec(layer_pattern, i)["mixer"] != "attention"]
 
@@ -161,6 +169,17 @@ def _delta_dims(mixer):
             f"heads is not built (value heads a multiple of key heads)")
     dk, dv = int(mixer["key_dim"]), int(mixer["value_dim"])
     return heads, dk, dv, key_heads * 2 * dk + heads * dv, key_heads
+
+
+def _ssd_dims(mixer):
+    """A state-space mixer's ``(heads, head_dim, state rows, channels of
+    its convolution: x | B | C)``."""
+    heads, p, n = (int(mixer[k]) for k in ("heads", "head_dim", "state"))
+    if int(mixer.get("groups", 1)) != 1:
+        raise ValueError(
+            f"ssd mixer: {mixer['groups']} groups of B and C are not built "
+            f"(one group: every head reads the same B and C)")
+    return heads, p, n, heads * p + 2 * n
 
 
 def window_layers(layer_pattern, num_layers):
@@ -194,7 +213,10 @@ def cache_spec(name, num_layers, layer_pattern=None, *, num_slots,
       ``conv - 1`` rows its convolution saw, ``<name>.conv_state_<i>``
       ``[num_slots + 1, conv - 1, key_heads * 2 * key_dim + value_heads * value_dim]``,
       then the delta state ``<name>.delta_state_<i>`` ``[num_slots + 1,
-      value_heads, key_dim, value_dim]``."""
+      value_heads, key_dim, value_dim]``.  A state-space (SSD) layer has
+      two: ``<name>.conv_state_<i>`` ``[num_slots + 1, conv - 1, heads *
+      head_dim + 2 * state]``, then ``<name>.ssm_state_<i>`` ``[num_slots +
+      1, state, heads * head_dim]``."""
     from ..ops.decode_ops import pool_shape
     from ..ops.latent_attention_ops import latent_pool_shape
 
@@ -205,6 +227,10 @@ def cache_spec(name, num_layers, layer_pattern=None, *, num_slots,
         if mixer != "attention":
             if mixer["kind"] == "conv":
                 shapes = {"conv_state": [int(mixer["L_cache"]) - 1, hidden]}
+            elif mixer["kind"] == "ssd":
+                heads, p, n, channels = _ssd_dims(mixer)
+                shapes = {"conv_state": [int(mixer["conv"]) - 1, channels],
+                          "ssm_state": [n, heads * p]}
             else:
                 heads, dk, dv, channels, _ = _delta_dims(mixer)
                 shapes = {"conv_state": [int(mixer["conv"]) - 1, channels],
@@ -486,6 +512,93 @@ def _gated_delta_mixer(h, seq_len, hidden, mixer, p, eps, valid=None,
     return _linear(o, hidden, pname=p("gdn_out.w")), tail, state
 
 
+def _ssd_init(name, heads):
+    """``A_log``, ``dt_bias`` and ``D`` [heads] as the family's modelling
+    code draws them: A uniform in (1, 16), ``A_log = log A``; dt
+    log-uniform in [0.001, 0.1], ``dt_bias = dt + log(-expm1(-dt))``
+    (softplus's inverse); D ones.  Drawn from the layer's name, so every
+    program of a model gives the same constants (a benchmark redraws them
+    from its seed)."""
+    import zlib
+
+    import numpy as np
+
+    rng = np.random.default_rng(zlib.crc32((name or "").encode()))
+    a = rng.uniform(1.0, 16.0, heads)
+    dt = np.exp(rng.uniform(np.log(1e-3), np.log(0.1), heads))
+    return {"ssd_A_log": np.log(a).astype("float32"),
+            "ssd_dt_bias": (dt + np.log(-np.expm1(-dt))).astype("float32"),
+            "ssd_D": np.ones(heads, "float32")}
+
+
+def _ssd_mixer(h, seq_len, hidden, mixer, p, eps, valid=None, states=None,
+               slot=None, live=None):
+    """A state-space duality (Mamba-2) layer on normed rows h [B, S, H]:
+    ``z | xBC | dt = h W_in`` (no bias); ``x | B | C = silu(conv(xBC) +
+    b)``, one causal depthwise convolution over all their channels, ``x``
+    [heads, head_dim] and ``B``, ``C`` [state] shared by every head; ``dt =
+    softplus(dt + dt_bias)``, ``A = -exp(A_log)`` a head; the recurrence
+    ``S <- exp(dt A) S + (dt x) B^T``, ``y = S C + D x``
+    (``ops/ssd_ops.py``); then ``out = rmsnorm(y * silu(z)) W_out``, the
+    norm over all ``heads * head_dim`` channels with one learned weight.
+    ``states`` is the layer's ``(conv_state, ssm_state)`` pair and the
+    three modes are :func:`_gated_delta_mixer`'s, as is what it
+    returns."""
+    from ..framework.initializer import NumpyArrayInitializer
+
+    heads, hp, n, channels = _ssd_dims(mixer)
+    inner = heads * hp
+    conv_state, ssm_state = states if states else (None, None)
+    zxd = _linear(h, inner + channels + heads, pname=p("ssd_in.w"))
+
+    def cut(t, lo, width):
+        return layers.slice(t, axes=[2], starts=[lo], ends=[lo + width])
+
+    z, dt = cut(zxd, 0, inner), cut(zxd, inner + channels, heads)
+    conv_w = {"param_attr": p("ssd_conv.w"),
+              "bias_attr": p("ssd_conv.b") if mixer.get("conv_bias") else None}
+    c, tail = _conv_over_state(cut(zxd, inner, channels), int(mixer["conv"]),
+                               conv_w, valid, conv_state, slot, live)
+    c = layers.silu(c)
+    x = layers.reshape(cut(c, 0, inner), [0, seq_len, heads, hp])
+    bm, cm = cut(c, inner, n), cut(c, inner + n, n)
+    a_log, dt_bias, d = (layers.create_parameter(
+        [heads], "float32", name=p(what),
+        default_initializer=NumpyArrayInitializer(init))
+        for what, init in _ssd_init(p("ssd"), heads).items())
+    dt = layers.softplus(layers.elementwise_add(dt, dt_bias))
+    a = layers.scale(layers.exp(a_log), scale=-1.0)
+    state = None
+    if live is not None:
+        y = layers.ssd_step(x, dt, a, bm, cm, d, ssm_state, live)
+    else:
+        # (a slot's state is all it has: a prefill starts from none)
+        y, last = layers.ssd_chunk(x, dt, a, bm, cm, d, valid=valid)
+        if ssm_state is not None:
+            layers.slot_state_write(ssm_state, last, slot)
+        elif valid is not None:
+            state = last
+    y = layers.elementwise_mul(layers.reshape(y, [0, seq_len, inner]),
+                               layers.silu(z))
+    y = layers.rms_norm(y, epsilon=eps, param_attr=p("ssd_norm"))
+    return _linear(y, hidden, pname=p("ssd_out.w")), tail, state
+
+
+def _residual(x, y, scale=1.0):
+    """``x + scale * y``: a sublayer's output joins the stream."""
+    if float(scale) != 1.0:
+        y = layers.scale(y, scale=float(scale))
+    return layers.elementwise_add(x, y)
+
+
+def _embed(ids, vocab_size, hidden, pname, scale=1.0):
+    """The token rows, times ``scale`` where that is not 1."""
+    x = layers.embedding(ids, size=[vocab_size, hidden], param_attr=pname)
+    if float(scale) != 1.0:
+        x = layers.scale(x, scale=float(scale))
+    return x
+
+
 def llama_block(x, hidden, num_heads, num_kv_heads, seq_len, head_dim,
                 intermediate, name=None, attn_impl="auto",
                 kv_cache=None, positions=None, collect_kv=False,
@@ -493,7 +606,8 @@ def llama_block(x, hidden, num_heads, num_kv_heads, seq_len, head_dim,
                 rope_base=10000.0, layer=None, valid=None, taps=None,
                 qk_norm=False, mask_block=None, block=False,
                 conv_state=None, slot=None, live=None, norm="pre",
-                norm_kind="rms", chunk_pages=False):
+                norm_kind="rms", chunk_pages=False, residual_scale=1.0,
+                attn_scale=None):
     """One decoder layer. x: [B, S, H].
 
     A layer whose ``mixer`` is a gated short convolution
@@ -503,7 +617,12 @@ def llama_block(x, hidden, num_heads, num_kv_heads, seq_len, head_dim,
     prompt's length) in a prefill; with ``collect_kv`` it returns ``(x,
     tail, None)``, the state rows where no variable took them.  A
     gated-delta layer (:func:`_gated_delta_mixer`) takes its two state
-    variables as the pair ``conv_state`` and returns ``(x, tail, state)``.
+    variables as the pair ``conv_state`` and returns ``(x, tail, state)``,
+    and so does a state-space layer (:func:`_ssd_mixer`).
+    ``residual_scale`` multiplies what the mixer and the FFN add to the
+    stream (``x = x + c * mixer(norm(x)); x = x + c * ffn(norm(x))``);
+    ``attn_scale`` is the attention layers' softmax scale where it is not
+    ``head_dim ** -0.5``.
 
     ``qk_norm``: q and k are RMS-normalised over ``head_dim`` with a
     learned weight each (``.q_norm`` / ``.k_norm``) before RoPE; with
@@ -564,6 +683,8 @@ def llama_block(x, hidden, num_heads, num_kv_heads, seq_len, head_dim,
     # a layer without a window calls the attention layers exactly as
     # before the pattern existed
     win = {} if layer["window"] is None else {"window": layer["window"]}
+    if attn_scale is not None:
+        win = dict(win, scale=float(attn_scale))
     q_size = num_heads * head_dim
     kv_size = num_kv_heads * head_dim
     p = (lambda s: f"{name}.{s}") if name else (lambda s: None)
@@ -582,7 +703,8 @@ def llama_block(x, hidden, num_heads, num_kv_heads, seq_len, head_dim,
     ffn_args = dict(ffn=layer["ffn"], p=p, rms_norm_eps=rms_norm_eps,
                     valid=valid, taps=taps, norm=norm,
                     limit=layer.get("swiglu_limit"), norm_kind=norm_kind,
-                    h=h if norm == "parallel" else None)
+                    h=h if norm == "parallel" else None,
+                    residual_scale=residual_scale)
     if layer["mixer"] != "attention":
         state = None
         if layer["mixer"]["kind"] == "conv":
@@ -590,11 +712,13 @@ def llama_block(x, hidden, num_heads, num_kv_heads, seq_len, head_dim,
                                   conv_state=conv_state, slot=slot,
                                   live=live)
         else:
-            y, tail, state = _gated_delta_mixer(
+            mixer = _ssd_mixer if layer["mixer"]["kind"] == "ssd" \
+                else _gated_delta_mixer
+            y, tail, state = mixer(
                 h, seq_len, hidden, layer["mixer"], p, rms_norm_eps,
                 valid=valid, states=conv_state, slot=slot, live=live)
-        out = _ffn(layers.elementwise_add(x, post_normed(y)), x_in, hidden,
-                   intermediate, **ffn_args)
+        out = _ffn(_residual(x, post_normed(y), residual_scale), x_in,
+                   hidden, intermediate, **ffn_args)
         return (out, tail, state) if collect_kv else out
     if layer.get("mla"):
         y, row = _mla_mixer(
@@ -602,8 +726,8 @@ def llama_block(x, hidden, num_heads, num_kv_heads, seq_len, head_dim,
             rope_base, attn_impl, kv_cache=kv_cache, positions=positions,
             block_table=block_table, kv_lengths=kv_lengths,
             want_row=collect_kv, chunk_pages=chunk_pages)
-        out = _ffn(layers.elementwise_add(x, post_normed(y)), x_in, hidden,
-                   intermediate, **ffn_args)
+        out = _ffn(_residual(x, post_normed(y), residual_scale), x_in,
+                   hidden, intermediate, **ffn_args)
         return (out, row, None) if collect_kv else out
     qkv = _linear(h, q_size + 2 * kv_size, pname=p("qkv.w"))
     q = layers.slice(qkv, axes=[2], starts=[0], ends=[q_size])
@@ -688,7 +812,7 @@ def llama_block(x, hidden, num_heads, num_kv_heads, seq_len, head_dim,
         attn = layers.elementwise_mul(attn, layers.sigmoid(
             _linear(h, q_size, pname=p("attn_gate.w"))))
     y = _linear(attn, hidden, pname=p("attn_out.w"))
-    x = layers.elementwise_add(x, post_normed(y))
+    x = _residual(x, post_normed(y), residual_scale)
     out = _ffn(x, x_in, hidden, intermediate, **ffn_args)
     if collect_kv:
         return out, new_k, new_v
@@ -835,14 +959,16 @@ def _swiglu(h, hidden, width, gate_up_name, down_name, limit=None):
 
 
 def _ffn(x, x_in, hidden, intermediate, ffn, p, rms_norm_eps, valid, taps,
-         norm="pre", limit=None, norm_kind="rms", h=None):
+         norm="pre", limit=None, norm_kind="rms", h=None,
+         residual_scale=1.0):
     """The layer's second half on the post-mixer stream x: norm, dense
     SwiGLU or routed experts (``x_in``: the layer's raw input, which some
     routers read), residual.  ``norm``: where the norms sit
     (:func:`_norm_modes`; the output's is ``.ln2`` under "post",
     ``.ln2_post`` beside the input's ``.ln2``).  Under "parallel" ``h``
     is the layer's one normed input, which the mixer read too, and there
-    is no ``.ln2``.  ``limit``: the SwiGLUs' clamp."""
+    is no ``.ln2``.  ``limit``: the SwiGLUs' clamp; ``residual_scale``:
+    what the FFN's output is multiplied by as it joins the stream."""
     pre, post = _norm_modes(norm)
     if h is None:
         h = _norm(x, rms_norm_eps, p("ln2"), norm_kind) if pre else x
@@ -880,7 +1006,7 @@ def _ffn(x, x_in, hidden, intermediate, ffn, p, rms_norm_eps, valid, taps,
     if post:
         y = _norm(y, rms_norm_eps, p("ln2_post" if pre else "ln2"),
                   norm_kind)
-    return layers.elementwise_add(x, y)
+    return _residual(x, y, residual_scale)
 
 
 def llama(input_ids, vocab_size=32000, hidden=4096, num_layers=32,
@@ -888,7 +1014,8 @@ def llama(input_ids, vocab_size=32000, hidden=4096, num_layers=32,
           seq_len=2048, name=None, attn_impl="auto", head_dim=None,
           rms_norm_eps=1e-6, rope_base=10000.0, layer_pattern=None,
           qk_norm=False, mask_block=None, tie_head=False, norm="pre",
-          norm_kind="rms", logit_scale=1.0):
+          norm_kind="rms", logit_scale=1.0, embed_scale=1.0,
+          residual_scale=1.0, attn_scale=None):
     """Returns logits [B, S, V]. input_ids: [B, S] int64.
 
     ``head_dim`` defaults to ``hidden // num_heads`` (a model may
@@ -896,13 +1023,14 @@ def llama(input_ids, vocab_size=32000, hidden=4096, num_layers=32,
     ``layer_pattern`` is described at :data:`DEFAULT_LAYER`, ``qk_norm``
     ``norm``, ``norm_kind`` and ``mask_block`` at :func:`llama_block`;
     ``tie_head`` makes the head's product read the embedding table (needs
-    ``name``) and ``logit_scale`` multiplies the logits.  The defaults
-    build exactly the program they always did."""
+    ``name``) and ``logit_scale`` multiplies the logits; ``embed_scale``
+    multiplies the embedding's rows, ``residual_scale`` and ``attn_scale``
+    are :func:`llama_block`'s.  The defaults build exactly the program
+    they always did."""
     num_kv_heads = num_kv_heads or num_heads
     head_dim = head_dim or hidden // num_heads
     p = (lambda s: f"{name}.{s}") if name else (lambda s: None)
-    x = layers.embedding(input_ids, size=[vocab_size, hidden],
-                         param_attr=p("embed"))
+    x = _embed(input_ids, vocab_size, hidden, p("embed"), embed_scale)
     for i in range(num_layers):
         x = llama_block(x, hidden, num_heads, num_kv_heads, seq_len,
                         head_dim, intermediate,
@@ -911,7 +1039,8 @@ def llama(input_ids, vocab_size=32000, hidden=4096, num_layers=32,
                         rope_base=rope_base,
                         layer=layer_spec(layer_pattern, i),
                         qk_norm=qk_norm, mask_block=mask_block, norm=norm,
-                        norm_kind=norm_kind)
+                        norm_kind=norm_kind, residual_scale=residual_scale,
+                        attn_scale=attn_scale)
     x = _norm(x, rms_norm_eps, p("ln_f"), norm_kind)
     return _head(x, vocab_size, name, tie_head, logit_scale)
 
@@ -966,7 +1095,9 @@ def build_llama_prefill(batch_size, seq_len, vocab_size=32000,
                         rope_base=10000.0, layer_pattern=None,
                         num_window_pages=None, keep_router_logits=False,
                         qk_norm=False, mask_block=None, tie_head=False,
-                        norm="pre", norm_kind="rms", logit_scale=1.0):
+                        norm="pre", norm_kind="rms", logit_scale=1.0,
+                        embed_scale=1.0, residual_scale=1.0,
+                        attn_scale=None):
     """Prefill entry point: one causal forward over the (padded) prompt
     that populates a decode cache in one shot.
 
@@ -979,7 +1110,8 @@ def build_llama_prefill(batch_size, seq_len, vocab_size=32000,
     the whole of that slot's state (``cache_spec``; ``slot`` =
     ``cache_slots`` is the trash row).  In the other mode they come back
     as fetches ``state_<i>`` [B, L - 1, C] and ``delta_state_<i>``
-    [B, heads, Dk, Dv].
+    [B, heads, Dk, Dv] (a state-space layer's: ``ssm_state_<i>`` [B, N,
+    heads * head_dim]).
 
     ``mask_block=B`` (block diffusion; the paged mode only): the forward
     runs under the block-causal mask and only commits K/V — the engine
@@ -1088,8 +1220,7 @@ def build_llama_prefill(batch_size, seq_len, vocab_size=32000,
                           page_tokens=page_tokens,
                           num_kv_heads=num_kv_heads, head_dim=head_dim,
                           hidden=hidden, num_window_pages=num_window_pages)
-    x = layers.embedding(input_ids, size=[vocab_size, hidden],
-                         param_attr=f"{name}.embed")
+    x = _embed(input_ids, vocab_size, hidden, f"{name}.embed", embed_scale)
     kvs = []
     taps = {"keep_logits": keep_router_logits}
     # the expert layers and those that keep slot state tell real rows
@@ -1112,11 +1243,15 @@ def build_llama_prefill(batch_size, seq_len, vocab_size=32000,
                               rope_base=rope_base, layer=lspec,
                               valid=valid, taps=taps, qk_norm=qk_norm,
                               mask_block=mask_block, norm=norm,
-                              norm_kind=norm_kind, **state)
+                              norm_kind=norm_kind,
+                              residual_scale=residual_scale,
+                              attn_scale=attn_scale, **state)
         if lspec["mixer"] != "attention":
             if not caches:
+                matrix = "ssm_state" if lspec["mixer"]["kind"] == "ssd" \
+                    else "delta_state"
                 kvs.append((i, {"state": k} if v is None
-                            else {"state": k, "delta_state": v}))
+                            else {"state": k, matrix: v}))
         elif block_table is not None:
             # paged: the prompt's K/V scatter across the slot's pages
             # from logical position 0; pad-tail rows (>= prompt_len)
@@ -1143,7 +1278,7 @@ def build_llama_prefill(batch_size, seq_len, vocab_size=32000,
 
 def _layer_state(lspec, caches):
     """What ``llama_block`` takes as ``conv_state``: a conv layer's one
-    state variable, a gated-delta layer's pair."""
+    state variable, a gated-delta or a state-space layer's pair."""
     return caches[0] if lspec["mixer"]["kind"] == "conv" else caches
 
 
@@ -1174,14 +1309,17 @@ def build_llama_decode(num_slots, max_seq_len, vocab_size=32000,
                        num_window_pages=None, keep_router_logits=False,
                        qk_norm=False, block=None, mask_id=None,
                        tie_head=False, norm="pre", norm_kind="rms",
-                       logit_scale=1.0):
+                       logit_scale=1.0, embed_scale=1.0, residual_scale=1.0,
+                       attn_scale=None):
     """Cached decode step over a fixed slot grid.
 
     A layer that keeps slot state (``mixer`` of :data:`DEFAULT_LAYER`)
     has no pools: a gated short convolution's ``<name>.conv_state_<i>``
     [slots + 1, L - 1, H] (``cache_spec``) is read and moved on by one
     row, a gated-delta layer's ``<name>.delta_state_<i>`` [slots + 1,
-    heads, Dk, Dv] by one token (its convolution's rows too), in place,
+    heads, Dk, Dv] by one token (its convolution's rows too), a
+    state-space layer's ``<name>.ssm_state_<i>`` [slots + 1, N, heads *
+    head_dim] likewise, in place,
     for the rows ``live`` marks; a dead row's state stays as it was.  The
     engine finds those variables through ``cache_spec``; the
     ``cache_names`` returned here are the page pools alone.  A latent
@@ -1285,8 +1423,7 @@ def build_llama_decode(num_slots, max_seq_len, vocab_size=32000,
                       num_kv_heads=num_kv_heads, head_dim=head_dim,
                       hidden=hidden, num_window_pages=num_window_pages)
     cache_names = [e["name"] for e in spec if e["kind"] != "slot_state"]
-    x = layers.embedding(tokens, size=[vocab_size, hidden],
-                         param_attr=f"{name}.embed")
+    x = _embed(tokens, vocab_size, hidden, f"{name}.embed", embed_scale)
     taps = {"keep_logits": keep_router_logits}
     for i in range(num_layers):
         caches = _cache_vars(gblock, spec, i)
@@ -1303,7 +1440,8 @@ def build_llama_decode(num_slots, max_seq_len, vocab_size=32000,
                         rms_norm_eps=rms_norm_eps, rope_base=rope_base,
                         layer=lspec, valid=n_rows, taps=taps,
                         qk_norm=qk_norm, block=bool(block), norm=norm,
-                        norm_kind=norm_kind, **cache)
+                        norm_kind=norm_kind, residual_scale=residual_scale,
+                        attn_scale=attn_scale, **cache)
     x = _norm(x, rms_norm_eps, f"{name}.ln_f", norm_kind)
     logits = _head(x, vocab_size, name, tie_head,
                    logit_scale)                              # [slots,1,V]
@@ -1329,7 +1467,8 @@ def _chunk_forward(chunk_len, max_seq_len, num_pages, page_tokens,
                    rope_base=10000.0, layer_pattern=None, qk_norm=False,
                    tie_head=False, norm="pre", norm_kind="rms",
                    logit_scale=1.0, num_window_pages=None,
-                   page_aligned=False, keep_router_logits=False):
+                   page_aligned=False, keep_router_logits=False,
+                   embed_scale=1.0, residual_scale=1.0, attn_scale=None):
     """The forward that the chunk and the verify programs share: C new
     tokens at ``base`` attend the slot's pages plus themselves causally.
     Returns ``(feed_names, x [1, C, H] before the final norm,
@@ -1386,8 +1525,7 @@ def _chunk_forward(chunk_len, max_seq_len, num_pages, page_tokens,
                       num_kv_heads=num_kv_heads, head_dim=head_dim,
                       hidden=hidden, num_window_pages=num_window_pages)
     cache_names = [e["name"] for e in spec]
-    x = layers.embedding(chunk_ids, size=[vocab_size, hidden],
-                         param_attr=f"{name}.embed")
+    x = _embed(chunk_ids, vocab_size, hidden, f"{name}.embed", embed_scale)
     taps = {"keep_logits": keep_router_logits}
     for i in range(num_layers):
         # rope offset = base per row; the attention's validity mask
@@ -1400,7 +1538,8 @@ def _chunk_forward(chunk_len, max_seq_len, num_pages, page_tokens,
                         rms_norm_eps=rms_norm_eps, rope_base=rope_base,
                         layer=layer_spec(layer_pattern, i), valid=ck_len,
                         taps=taps, qk_norm=qk_norm, norm=norm,
-                        norm_kind=norm_kind, chunk_pages=page_aligned)
+                        norm_kind=norm_kind, chunk_pages=page_aligned,
+                        residual_scale=residual_scale, attn_scale=attn_scale)
     return feeds, x, cache_names, taps
 
 

@@ -16,6 +16,7 @@ from . import attention_ops  # noqa: F401
 from . import rope_ops  # noqa: F401
 from . import decode_ops  # noqa: F401
 from . import gated_delta_ops  # noqa: F401
+from . import ssd_ops  # noqa: F401
 from . import latent_attention_ops  # noqa: F401
 from . import io_ops  # noqa: F401
 from . import debug_ops  # noqa: F401

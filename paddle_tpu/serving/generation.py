@@ -181,7 +181,9 @@ model whose layers keep slot state, a convolution's last rows or the
 delta rule's matrix, has beside the pages one or two per-slot state
 variables a layer that the prefill program overwrites whole and the
 decode program advances on the device), ``serving_delta_state_steps``
-(slot-layers whose delta state a decode step moved on); gauges
+(slot-layers whose delta state a decode step moved on),
+``serving_ssm_state_steps`` (the same of a state-space layer's matrix);
+gauges
 ``serving_slot_state_bytes`` (what those variables take, every kind),
 ``serving_spec_acceptance_rate``,
 ``serving_slot_occupancy``,
@@ -211,6 +213,7 @@ from .. import blackbox, costmodel, fault, telemetry
 from ..flags import flag_value
 from ..monitor import stat_add
 from ..ops.gated_delta_ops import CHUNK as DELTA_CHUNK
+from ..ops.ssd_ops import CHUNK as SSD_CHUNK
 from ..ops.latent_attention_ops import CHUNK_BLOCK_K
 from . import batcher
 from . import usage
@@ -774,6 +777,16 @@ class GenerationEngine:
         # a prefill scans the prompt for in chunks
         self._delta_layers = [i for i in self._state_layers
                               if specs[i]["mixer"]["kind"] == "gated_delta"]
+        # ... and those whose state is a state-space layer's matrix,
+        # scanned likewise in chunks of their own length
+        self._ssd_layers = [i for i in self._state_layers
+                            if specs[i]["mixer"]["kind"] == "ssd"]
+        if self._delta_layers and self._ssd_layers:
+            raise ValueError("delta-rule and state-space layers in one "
+                             "model are not built: a prefill's span "
+                             "counts one scan's chunks")
+        self._scan_chunk = DELTA_CHUNK if self._delta_layers \
+            else SSD_CHUNK if self._ssd_layers else None
         self._window_layers = window_layers(pattern, n_layers)
         # attention layers whose pages hold one latent row a token
         self._latent_layers = [i for i in range(n_layers)
@@ -1016,6 +1029,7 @@ class GenerationEngine:
                    "moe_tokens_dropped": 0, "block_passes_denoise": 0,
                    "block_passes_commit": 0, "block_tokens_committed": 0,
                    "slot_state_writes": 0, "delta_state_steps": 0,
+                   "ssm_state_steps": 0,
                    "moe_pairs_routed": 0, "moe_pairs_held": 0,
                    "moe_rows_group_held": 0, "moe_shared_expert_rows": 0}
         self._n_lock = threading.Lock()
@@ -2643,15 +2657,15 @@ class GenerationEngine:
                     # state: whatever the slot's last sequence left goes
                     feed["slot"] = np.asarray([slot.idx], "int32")
                     state = {"state_written": 1}
-                    if self._delta_layers:
-                        # what a delta layer's scan covered: the prompt's
-                        # tokens, the rung's chunks, and those of them
-                        # wholly behind the prompt's end
-                        chunks = -(-bucket // DELTA_CHUNK)
+                    if self._scan_chunk:
+                        # what a delta or a state-space layer's scan
+                        # covered: the prompt's tokens, the rung's chunks,
+                        # and those of them wholly behind the prompt's end
+                        chunks = -(-bucket // self._scan_chunk)
                         state.update(
                             scan_tokens=n_rows, scan_chunks=chunks,
                             scan_pad_chunks=chunks
-                            - -(-n_rows // DELTA_CHUNK))
+                            - -(-n_rows // self._scan_chunk))
                 # the rows [c_kv | k_r] a latent layer's pool took
                 latent = {"latent_rows_written": n_rows} \
                     if self._latent_layers else {}
@@ -3348,10 +3362,12 @@ class GenerationEngine:
             # the slots whose state this step moved on (every row that
             # rode it), and the positions its attention layers read
             attrs.update(state_slots=len(fl.riders), live_positions=live)
-            if self._delta_layers:
-                moved = len(fl.riders) * len(self._delta_layers)
-                self._count("delta_state_steps", moved)
-                stat_add("serving_delta_state_steps", moved)
+            for key, scanned in (("delta_state_steps", self._delta_layers),
+                                 ("ssm_state_steps", self._ssd_layers)):
+                if scanned:
+                    moved = len(fl.riders) * len(scanned)
+                    self._count(key, moved)
+                    stat_add("serving_" + key, moved)
         if self._latent_layers:
             # the cached rows a latent layer's decode kernel read
             attrs["latent_positions"] = live

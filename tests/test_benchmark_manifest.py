@@ -8,7 +8,10 @@ shared ``.pool`` entries that list it, its configuration's cut and its
 mix; so is the start-up account's `.setup` family that PR 53 added to
 every cell.  The cell PR 56 added joined families and brought no entry
 (PR 55's rule): it is pinned by its row, as ``test_manifest.TABLE`` has
-the others'.  No JAX is imported and no engine started.
+the others'.  The cell PR 59 added joined families likewise and brought
+three entries for what no cell had (the state-space kernels): a group of
+their own, put on the collected module here.  No JAX is imported and no
+engine started.
 """
 import importlib.util
 import json
@@ -50,6 +53,14 @@ def _reported_by_gate(cell):
 
 
 manifest.reported_by = _reported_by_gate
+
+# PR 59 added three entries, the state-space kernels' (``ssm_*``), that only
+# its cell reports: a group of their own, so that the collected check that
+# every entry is in one group holds (the benchmark's own table of groups is
+# a `benchmark` PR's to edit)
+SSM = ["ssm_step_roofline.pool", "ssm_chunk_roofline.pool",
+       "ssm_kernel_share_pct.pool"]
+manifest.GROUPS["state space"] = SSM
 
 # the benchmark's own checks, collected here under their own names
 globals().update({name: fn for name, fn in vars(manifest).items()
@@ -132,11 +143,21 @@ DSV2_NOT_RUN = "mla_prefill_roofline.pool"
 DSV2_ROW = ("served_tokens_per_s", [
     "closed loop", "experts", "experts, a share held", "step on its span",
     "latent pages", "chunked prefill"], 40)
+# the cell PR 59 added: it joined families as PR 56's did, and its row
+# names the group of the three entries it brought
+GRANITE = "granite4h-micro-manychats"
+GRANITE_ROW = ("served_tokens_per_s", [
+    "closed loop", "whole-prompt prefill", "step on its span",
+    "paged decode kernel", "slot state", "state space"], 34)
 JOINED = {DSV2: {"config": "deepseek-v2", "mix": "docqa-pool",
                  "reduced": ["num_hidden_layers", "n_routed_experts",
                              "vocab_size"],
                  "driver": "serve_chunks", "rungs": [512, 512, 1024],
-                 "chunk": 1024}}
+                 "chunk": 1024},
+          GRANITE: {"config": "granite-4.0-h-micro",
+                    "mix": "manychats-pool",
+                    "reduced": ["num_hidden_layers", "layer_types"],
+                    "driver": "serve_delta", "rungs": [128, 512, 1024]}}
 CELLS_AT_PR54 = 11
 
 
@@ -145,7 +166,7 @@ def _json(*parts):
         return json.load(f)
 
 
-def test_the_benchmark_has_ten_configurations_and_twelve_cells():
+def test_the_benchmark_has_eleven_configurations_and_thirteen_cells():
     spec = manifest.SPEC
     assert [c["name"] for c in spec["configs"]] == [
         "bert-base-mlm", "mistral-7b-v0.1", "smallthinker-21b-a3b",
@@ -157,15 +178,18 @@ def test_the_benchmark_has_ten_configurations_and_twelve_cells():
         "bert-base-seq512-dp4", "smallthinker21b-mixedlen",
         "sdar30b-blockgen", "lfm2-24b-longanswer"] + list(ADDED) \
         + list(JOINED)
-    assert (len(spec["configs"]), len(manifest.CELLS)) == (10, 12)
+    assert (len(spec["configs"]), len(manifest.CELLS)) == (11, 13)
     assert [w["name"] for w in spec["workloads"] if w["chips"] == 4] \
         == ["bert-base-seq512-dp4"]
-    new, = [w for w in spec["workloads"] if w["name"] == DSV2]
-    assert (new["chips"], new["config"], new["traffic"]) \
-        == (1, JOINED[DSV2]["config"], JOINED[DSV2]["mix"])
+    for cell, joined in JOINED.items():
+        new, = [w for w in spec["workloads"] if w["name"] == cell]
+        assert (new["chips"], new["config"], new["traffic"]) \
+            == (1, joined["config"], joined["mix"])
     assert spec["workloads"][-1] == new
-    # a cell that joins families adds no entry
-    assert len(manifest.ENTRIES) == 97
+    # a cell that joins families adds no entry; PR 59's brought three for
+    # what no cell had, at the end
+    assert len(manifest.ENTRIES) == 97 + len(SSM) == 100
+    assert [m["name"] for m in manifest.ENTRIES][-len(SSM):] == SSM
     used = {w["config"] for w in spec["workloads"]}
     assert used == {c["name"] for c in spec["configs"]}
     for c in spec["configs"]:
@@ -199,8 +223,8 @@ def test_every_cell_reports_the_start_up_account(name):
                      "moves": "setup_s",
                      "workloads": manifest.CELLS[:CELLS_AT_PR54]}
     assert manifest.BY_NAME[name] == dict(entry, workloads=manifest.CELLS)
-    assert [m["name"] for m in manifest.ENTRIES][-len(SETUP):] \
-        == list(SETUP)
+    assert [m["name"] for m in manifest.ENTRIES][
+        -len(SETUP) - len(SSM):-len(SSM)] == list(SETUP)
     gate, = [m for m in manifest.SPEC["end_to_end"]
              if m["name"] == "setup_s"]
     assert "workloads" not in gate and gate["bound"] == 0.1
@@ -492,9 +516,11 @@ def test_the_joined_cell_reports_its_groups_and_forty_values(monkeypatch):
     monkeypatch.setattr(manifest, "GROUPS", _groups_less_not_run())
     assert manifest.check_cell(DSV2, DSV2_ROW) == DSV2_ROW[2] == 40
     assert DSV2 not in manifest.BY_NAME[DSV2_NOT_RUN]["workloads"]
-    # every entry lists it last: the cells stand in the order they came
+    # every entry lists it last, or last but for the cell that came after
+    # it: the cells stand in the order they came
     for name in manifest.entries_of(DSV2):
-        assert manifest.BY_NAME[name]["workloads"][-1] == DSV2, name
+        lists = manifest.BY_NAME[name]["workloads"]
+        assert lists[lists.index(DSV2) + 1:] in ([], [GRANITE]), name
     assert DSV2 not in manifest.TABLE         # (a ``benchmark`` PR's to add)
 
 
@@ -593,6 +619,155 @@ def test_the_joined_configuration_cuts_what_it_says_and_no_width():
     assert len(cfg["check_tolerance"]["why"]) > 200
     assert os.path.exists(os.path.join(
         BENCH, "builders", cfg["builder"] + ".py"))
+
+
+# -- PR 59: a cell that joined families and brought three entries ------------
+
+def test_the_fixture_is_the_whole_parent_and_52_names_went():
+    """The collected check of this name, at this PR's counts: the three
+    entries PR 59 added came on top of the 22 that PR 55's merge made
+    (the collected function holds 97 entries; the benchmark's own file is
+    a `benchmark` PR's to edit, PERF.md section 7)."""
+    at_pr54 = manifest.AT_PR54
+    assert len(at_pr54) == 127
+    assert len({r["old"] for r in at_pr54}) == 127
+    went = {r["old"] for r in at_pr54} - set(manifest.BY_NAME)
+    came = set(manifest.BY_NAME) - {r["old"] for r in at_pr54}
+    assert (len(went), len(came), len(manifest.ENTRIES)) \
+        == (52, 22 + len(SSM), 100)
+    assert came >= set(SSM)
+    for r in at_pr54:
+        assert set(r["cells"]) \
+            <= set(manifest.BY_NAME[r["new"]]["workloads"]), r
+
+
+def test_the_state_space_cell_reports_its_groups_and_thirty_four_values():
+    """Its row: the 22 of the closed loop, the whole-prompt prefill, the
+    step on its span, the paged decode kernel, slot state with the scan's
+    padding (four), the three of the state-space kernels and the four of
+    the start-up account.  No group of the expert path: the model routes
+    over no experts."""
+    groups = dict(manifest.GROUPS)
+    # (``scan_pad_pct.pool`` sits in the delta rule's group; this cell's
+    # scan is the state-space layers', and it reports that one entry of it)
+    groups["slot state"] = groups["slot state"] + ["scan_pad_pct.pool"]
+    manifest.GROUPS, kept = groups, manifest.GROUPS
+    try:
+        assert manifest.check_cell(GRANITE, GRANITE_ROW) \
+            == GRANITE_ROW[2] == 34
+    finally:
+        manifest.GROUPS = kept
+    for name in manifest.entries_of(GRANITE):
+        assert manifest.BY_NAME[name]["workloads"][-1] == GRANITE, name
+    assert GRANITE not in manifest.TABLE       # (a ``benchmark`` PR's to add)
+    for name in SSM:
+        assert manifest.BY_NAME[name] == {
+            "name": name, "unit": "%", "better": "higher",
+            "source": "device_trace", "layer": "kernels",
+            "moves": "served_tokens_per_s", "workloads": [GRANITE]}
+    # nothing of the delta rule's or the experts' is claimed
+    for name in ("delta_step_roofline.pool", "delta_kernel_share_pct.pool",
+                 "expert_matmul_share_pct.pool",
+                 "attention_kernel_share_pct.pool"):
+        assert GRANITE not in manifest.BY_NAME[name]["workloads"], name
+
+
+@pytest.mark.parametrize("name,reader,key,reads", [
+    ("decode_step_roofline.pool", "roofline_span", "attrs",
+     ["live_positions", "state_slots"]),
+    ("decode_step_roofline.pool", "roofline_span", "fn",
+     "ops_bytes_granite_hybrid.decode_step_bytes"),
+    ("prefill_roofline.pool", "roofline", "fn",
+     "ops_bytes_granite_hybrid.prefill_flops"),
+    ("paged_kernel_roofline.pool", "roofline_kernel", "fn",
+     "ops_bytes_granite_hybrid.paged_kernel_bytes"),
+    ("paged_kernel_roofline.pool", "roofline_kernel", "pattern",
+     "^%?paged_decode_attention"),
+    ("state_slots_pct.pool", "span_attr_mean", "scale", 100 / 128),
+    ("scan_pad_pct.pool", "span_attr_ratio", "num", "scan_pad_chunks"),
+    ("ssm_step_roofline.pool", "roofline_kernel", "fn",
+     "ops_bytes_granite_hybrid.ssm_step_bytes"),
+    ("ssm_step_roofline.pool", "roofline_kernel", "pattern",
+     "^%?ssd_step"),
+    ("ssm_step_roofline.pool", "roofline_kernel", "attrs", ["state_slots"]),
+    ("ssm_chunk_roofline.pool", "roofline_kernel_prefill", "fn",
+     "ops_bytes_granite_hybrid.ssm_chunk_bytes"),
+    ("ssm_chunk_roofline.pool", "roofline_kernel_prefill", "pattern",
+     "^%?ssd_chunk"),
+    ("ssm_chunk_roofline.pool", "roofline_kernel_prefill", "attr",
+     "scan_tokens"),
+    ("ssm_kernel_share_pct.pool", "trace_op_share", "pattern", "^%?ssd_"),
+])
+def test_the_state_space_cell_hands_each_family_its_own_arguments(
+        name, reader, key, reads):
+    """What the cell's own files give a family's reader, resolved as the
+    harness resolves it, and that the program makes it: the kernels by the
+    ``name=`` of their ``pallas_call``, the attributes by their names in
+    ``serving/generation.py``."""
+    got_reader, args = manifest.check_arguments(name, GRANITE)
+    assert got_reader == reader and args[key] == reads
+    with open(os.path.join(REPO, "paddle_tpu", "ops", "pallas",
+                           "ssd.py")) as f:
+        kernels = f.read()
+    assert 'name="ssd_step"' in kernels and 'name="ssd_chunk"' in kernels
+    with open(os.path.join(REPO, "paddle_tpu", "serving",
+                           "generation.py")) as f:
+        engine = f.read()
+    for attr in ("state_slots=", "live_positions=", "scan_tokens=",
+                 "scan_chunks=", "scan_pad_chunks=", '"ssm_state_steps"'):
+        assert attr in engine, attr
+    if "fn" in args:
+        module, _, fn = args["fn"].rpartition(".")
+        with open(os.path.join(BENCH, module + ".py")) as f:
+            assert f"def {fn}(" in f.read()
+    # a sibling's arguments are its own still
+    assert manifest.resolved("state_slots_pct.pool", OLMO)[1]["scale"] \
+        == 100 / 28
+    assert manifest.resolved("decode_step_roofline.pool", OLMO)[1]["fn"] \
+        == "ops_bytes_olmo_hybrid.decode_step_bytes"
+
+
+def test_the_state_space_configuration_cuts_depth_and_no_width():
+    joined = JOINED[GRANITE]
+    cfg = _json("configs", joined["config"] + ".json")
+    entry, = [c for c in manifest.SPEC["configs"]
+              if c["name"] == joined["config"]]
+    assert entry["reduced"] == cfg["reduced"] == joined["reduced"]
+    assert entry["source"] == cfg["source"]
+    assert manifest.SPEC["configs"][-1] == entry
+    assert (cfg["hidden_size"], cfg["num_attention_heads"],
+            cfg["num_key_value_heads"], cfg["intermediate_size"],
+            cfg["shared_intermediate_size"], cfg["mamba_n_heads"],
+            cfg["mamba_d_head"], cfg["mamba_d_state"], cfg["mamba_n_groups"],
+            cfg["mamba_d_conv"], cfg["mamba_expand"], cfg["mamba_conv_bias"],
+            cfg["num_local_experts"], cfg["num_experts_per_tok"],
+            cfg["rms_norm_eps"], cfg["tie_word_embeddings"],
+            cfg["position_embedding_type"]) \
+        == (2048, 32, 8, 8192, 8192, 64, 64, 128, 1, 4, 2, True, 0, 0, 1e-5,
+            True, "nope")
+    # the family's four multipliers, as published
+    assert (cfg["embedding_multiplier"], cfg["attention_multiplier"],
+            cfg["residual_multiplier"], cfg["logits_scaling"]) \
+        == (12, 0.015625, 0.22, 8)
+    # ``reduced`` against ``published``: depth alone, one WHOLE period of
+    # the pattern (nine state-space layers to one of attention; the
+    # issue's last resort: two did not fit the run's time limit), every
+    # row of the vocabulary
+    period = ["mamba"] * 5 + ["attention"] + ["mamba"] * 4
+    assert cfg["published"] == {"num_hidden_layers": 40,
+                                "layer_types": period * 4}
+    assert [cfg[k] for k in joined["reduced"]] == [10, period]
+    assert cfg["vocab_size"] == 100352
+    assert cfg["as_run"]["dtype"] == "float32"
+    for key in ("assumed", "as_run", "deployment", "check_tolerance",
+                "rehearse", "builder", "per_layer_args"):
+        assert key in cfg
+    assert len(cfg["check_tolerance"]["why"]) > 200
+    assert os.path.exists(os.path.join(
+        BENCH, "builders", cfg["builder"] + ".py"))
+    mix = _json("traffic", joined["mix"] + ".json")
+    assert (mix["engine"]["num_slots"], mix["engine"]["max_seq_len"],
+            mix["warm_blocks"] * mix["block"]) == (128, 1792, 128)
 
 
 # -- PR 42: the exposed share of collectives, asynchronous ones counted -----

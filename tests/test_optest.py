@@ -1166,6 +1166,12 @@ SKIP = {
        "interpret mode; inside the engine against the benchmark "
        "reference's full forward)" for op in [
            "gated_delta_chunk", "gated_delta_step"]},
+    **{op: "tests/test_granite_hybrid.py (the recurrence token by token "
+       "in float64: valid inside a chunk, at its edge and behind a whole "
+       "chunk, an initial state, NaN behind valid, live rows only and the "
+       "trash row; the Pallas kernels in interpret mode; inside the "
+       "engine against the benchmark reference's full forward)" for op in [
+           "ssd_chunk", "ssd_step"]},
     "moe_routed_ffn":
         "tests/test_window_moe.py (routing, dropless counts and the "
         "grouped matmul vs a plain float64 loop; the op inside the "

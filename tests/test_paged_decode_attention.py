@@ -678,6 +678,62 @@ def test_gated_delta_kernels_compile_for_a_described_v5e(chip):
         assert chunk.memory_analysis().temp_size_in_bytes < 1.0e9
 
 
+def test_state_space_step_kernel_compiles_for_a_described_v5e(chip):
+    """The SSD step at the ``granite4h-micro-manychats`` cell's shapes (64
+    heads of 64 over 128 state rows, 128 slots): one Mosaic call, the
+    state ``[129, 128, 4096]`` aliased in place and no temporary of its
+    size."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from paddle_tpu.ops.pallas import ssd as kern
+
+    one_chip = SingleDeviceSharding(chip)
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    n, H, P, N = 128, 64, 64, 128
+    assert kern.step_supported((n + 1, N, H * P))
+    step = jax.jit(kern.step, donate_argnums=6).lower(
+        sds((n, H, P)), sds((n, H)), sds((H,)), sds((n, N)), sds((n, N)),
+        sds((H,)), sds((n + 1, N, H * P)), sds((n,), jnp.int32)).compile()
+    assert step.as_text().count("tpu_custom_call") == 1
+    state_bytes = (n + 1) * N * H * P * 4
+    assert step.memory_analysis().temp_size_in_bytes < state_bytes // 4
+
+
+@pytest.mark.parametrize("rung", [128, 256, 512, 1024])
+def test_state_space_scan_kernel_compiles_for_a_described_v5e(chip, rung):
+    """The SSD chunked scan over each of the cell's prefill rungs, at the
+    chunk the program runs (``ssd_ops.CHUNK``): one Mosaic call."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from paddle_tpu.ops.pallas import ssd as kern
+    from paddle_tpu.ops.ssd_ops import CHUNK
+
+    one_chip = SingleDeviceSharding(chip)
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    H, P, N = 64, 64, 128
+    assert kern.chunk_supported((1, rung, H, P), N, CHUNK)
+
+    def prefill(x, dt, a, bm, cm, d, valid):
+        return kern.chunk(x, dt, a, bm, cm, d, valid=valid)
+
+    scan = jax.jit(prefill).lower(
+        sds((1, rung, H, P)), sds((1, rung, H)), sds((H,)),
+        sds((1, rung, N)), sds((1, rung, N)), sds((H,)),
+        sds((1,), jnp.int32)).compile()
+    assert scan.as_text().count("tpu_custom_call") == 1
+    assert scan.memory_analysis().temp_size_in_bytes < 1.0e9
+
+
 @pytest.mark.parametrize("rows", [256, 1024])
 def test_latent_chunk_kernel_compiles_for_a_described_v5e(chip, rows):
     """The chunk kernel over latent rows at DeepSeek-V2's published head
