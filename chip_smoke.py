@@ -61,6 +61,15 @@ random seeded weights:
   multipliers: ``ssd_lowered_pallas`` grows, ``ssd_lowered_reference``
   stays 0.
 
+* **the program store** (PR 60) — first of all, before this process
+  touches the chip: two child processes, one after the other, each build
+  the same two-layer decoder (8 heads of 128 over 4 KV heads, two slots),
+  warm its prefill rung and its decode program (both hold Pallas kernels)
+  and answer one prompt.  The second must load every module the first
+  stored (``program_store_hits`` > 0, ``_misses`` 0), compile nothing anew
+  (``compile_cache_misses`` 0), book the lowering counters the first
+  booked and return its logits to the bit.
+
 Any failed check raises: the exit code is non-zero and no result line is
 printed.  Without a TPU backend the script refuses to run (exit 2).  The
 last line of stdout is one JSON object
@@ -73,8 +82,11 @@ and the 1200 s limit being met — not rates.
 from __future__ import annotations
 
 import gc
+import hashlib
 import json
+import os
 import re
+import subprocess
 import sys
 import threading
 import time
@@ -127,7 +139,10 @@ def say_startup_account():
             account.items(), key=lambda kv: -kv[1]["s"]))
         + f"; compile_cache_hits {stat_get('compile_cache_hits')}, "
         f"compile_cache_misses {stat_get('compile_cache_misses')}, "
-        f"compile/backend spans that asked the cache {asked}")
+        f"compile/backend spans that asked the cache {asked}; "
+        f"program_store_hits {stat_get('program_store_hits')}, _misses "
+        f"{stat_get('program_store_misses')}, _refused "
+        f"{stat_get('program_store_refused')}")
     for *program, trace_s, lower_s, backend_s, hit in programs[:5]:
         say(f"  {telemetry.program_label(*program)}: trace {trace_s:.2f} "
             f"s, lower {lower_s:.2f} s, backend {backend_s:.2f} s, "
@@ -1654,18 +1669,129 @@ def ssd_phase(cfg=SSD):
         f"+{reference}")
 
 
+STORE = dict(hidden=1024, heads=8, kv_heads=4, ffn=2048, vocab=4096,
+             layers=2, slots=2, max_seq=512, bucket=256, prompt=200,
+             new_tokens=4)
+STORE_STATS = ("program_store_hits", "program_store_misses",
+               "program_store_refused", "compile_cache_hits",
+               "compile_cache_misses", "attention_lowered_pallas",
+               "attention_lowered_paged_decode", "kv_pool_write_pages",
+               "attention_lowered_blockwise",
+               "attention_lowered_paged_decode_reference")
+
+
+def store_child(cfg=STORE):
+    """One of ``store_phase``'s two processes: the decoder's prefill rung
+    and decode program warm, one prompt answered, and one line of JSON:
+    the device, the stats, a digest of the logits' bits."""
+    import jax
+
+    from paddle_tpu.monitor import stat_get
+    from paddle_tpu.serving import GenerationEngine
+
+    d0 = jax.devices()[0]
+    if d0.platform != "tpu":
+        return 2
+    # (jax keeps only programs that took a second to compile; here every
+    # one is kept, so that the second process finds all of them)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    model = dict(vocab_size=cfg["vocab"], hidden=cfg["hidden"],
+                 num_layers=cfg["layers"], num_heads=cfg["heads"],
+                 num_kv_heads=cfg["kv_heads"], intermediate=cfg["ffn"])
+    gen = GenerationEngine(
+        model, num_slots=cfg["slots"], max_seq_len=cfg["max_seq"],
+        prefill_buckets=[cfg["bucket"]], page_tokens=16, prefill_chunk=0,
+        prefix_reuse=False, speculate=False, keep_logits=True, seed=0,
+        eos_id=-1)
+    try:
+        programs = gen.warmup()
+        prompt = np.random.default_rng(60).integers(
+            1, cfg["vocab"], cfg["prompt"]).tolist()
+        res = gen.generate(prompt, cfg["new_tokens"], timeout=600)
+    finally:
+        gen.close()
+    logits = np.stack(res["logits"])
+    print("STORE " + json.dumps({
+        "platform": d0.platform, "programs": programs,
+        "tokens": res["tokens"], "finite": bool(np.isfinite(logits).all()),
+        "logits_sha256": hashlib.sha256(logits.tobytes()).hexdigest(),
+        "stats": {k: stat_get(k) for k in STORE_STATS}}), flush=True)
+    return 0
+
+
+def store_phase():
+    """The program store across two processes on the chip (PR 60; alone:
+    ``python -c "import chip_smoke; chip_smoke.store_phase()"``).  The
+    caller must not have touched the chip: a chip belongs to one process.
+    Returns 2 where the children find no TPU."""
+    runs = []
+    for turn in ("first", "second"):
+        t0 = time.perf_counter()
+        done = subprocess.run(
+            [sys.executable, os.path.abspath(__file__), "--store-child"],
+            capture_output=True, text=True, timeout=900)
+        if done.returncode == 2:
+            return 2
+        lines = [ln for ln in done.stdout.splitlines()
+                 if ln.startswith("STORE ")]
+        check(done.returncode == 0 and len(lines) == 1,
+              f"the {turn} store process exited {done.returncode}: "
+              f"{done.stderr[-2000:]}")
+        run = json.loads(lines[0][len("STORE "):])
+        runs.append(run)
+        say(f"store: {turn} process [{time.perf_counter() - t0:.1f} s] "
+            f"{run['programs']} programs, " + ", ".join(
+                f"{k} {v}" for k, v in run["stats"].items()))
+    first, second = (r["stats"] for r in runs)
+    check(first["attention_lowered_paged_decode"] > 0
+          and first["attention_lowered_pallas"] > 0
+          and first["attention_lowered_paged_decode_reference"] == 0,
+          "the programs hold no Pallas kernel")
+    check(first["program_store_refused"] == 0
+          and second["program_store_refused"] == 0,
+          "the store refused a program")
+    check(second["program_store_hits"] > 0
+          and second["program_store_misses"] == 0,
+          f"the second process loaded {second['program_store_hits']} "
+          f"modules and made {second['program_store_misses']}")
+    check(second["program_store_hits"] == first["program_store_hits"]
+          + first["program_store_misses"],
+          "the two processes asked the store for different programs")
+    check(second["compile_cache_misses"] == 0,
+          f"the second process compiled {second['compile_cache_misses']} "
+          f"programs anew")
+    lowered = [k for k in STORE_STATS if "lowered" in k or "kv_pool" in k]
+    check([first[k] for k in lowered] == [second[k] for k in lowered],
+          "a hit did not book what the trace booked")
+    check(runs[0]["finite"] and runs[0]["tokens"] == runs[1]["tokens"]
+          and runs[0]["logits_sha256"] == runs[1]["logits_sha256"],
+          "the loaded modules' logits are not the traced ones' to the bit")
+    say(f"store: the second process loaded {second['program_store_hits']} "
+        f"modules, compiled nothing anew and returned the first's "
+        f"{STORE['new_tokens']} rows of logits to the bit")
+    return 0
+
+
 def main():
+    if sys.argv[1:] == ["--store-child"]:
+        return store_child()
     t_start = time.perf_counter()
     # the program first: in a directory that holds only this file the
     # script must fail before it prints anything
     import paddle_tpu  # noqa: F401
+
+    # (two processes of their own, so before this one claims the chip)
+    store_rc = store_phase()
+    t_store = time.perf_counter() - t_start
+
     import jax
     from paddle_tpu.compile_cache import ensure_compile_cache
     from paddle_tpu.monitor import stat_get
 
     devices = jax.devices()
     d0 = devices[0]
-    if d0.platform != "tpu":
+    if d0.platform != "tpu" or store_rc:
         print(f"chip_smoke: refusing to run: jax.devices()[0].platform is "
               f"{d0.platform!r}, not 'tpu'.  This script proves the main "
               f"path on the chip; on the CPU run the tests.",
@@ -1682,6 +1808,7 @@ def main():
         f"{len(devices)}; jax {jax.__version__}, jaxlib "
         f"{jaxlib.__version__}, libtpu {libtpu_version}")
 
+    say(f"program store done [{t_store:.1f} s]")
     say(f"compile cache: {ensure_compile_cache()}")
 
     t0 = time.perf_counter()

@@ -16,6 +16,14 @@ One rule, applied wherever the program first compiles (executor,
   of SIGILL when it loads one elsewhere), CPU programs compile in
   seconds, and a checkout's directory travels with the tree.
 
+**The program store** (``program_store.py``, PR 60) lives beside the cache
+by the same rule and no other: ``<that directory>/programs/`` holds the
+lowered module of every Program's step (``jax.export``), which a later
+process loads in place of tracing the Program and lowering its kernels;
+jax's cache lists and evicts only the ``*-cache`` files of its directory,
+never the subdirectory.  No cache (the third branch), no store: no file is
+made and ``costmodel.aot_compile`` is ``jitted.lower(*args).compile()``.
+
 Child processes (fleet replicas) inherit the environment and resolve
 the same directory.  jax's own thresholds decide what is worth
 caching (programs that took about a second or more to compile).  Hits
@@ -48,11 +56,13 @@ and ``compile_cache_misses`` stays 0.
 """
 from __future__ import annotations
 
+import functools
 import os
 import threading
 from typing import Optional
 
 from . import telemetry as _telemetry
+from . import watch as _watch
 from .monitor import monitor as _monitor
 
 __all__ = ["ENV_VAR", "DEFAULT_DIR", "ensure_compile_cache"]
@@ -87,6 +97,20 @@ _backend_timed = False
 _tls = threading.local()
 
 
+def _unwatched(listener):
+    """The listeners run on the compiling thread in the middle of a trace;
+    the flags they read and the stats they book are no lowering's
+    (``watch.py``)."""
+    @functools.wraps(listener)
+    def quiet(*args, **kw):
+        if not _watch.active:
+            return listener(*args, **kw)
+        with _watch.paused():
+            return listener(*args, **kw)
+    return quiet
+
+
+@_unwatched
 def _on_event(event, **_kw):
     if event == _HIT_EVENT:
         _HIT_STAT.increase()
@@ -101,6 +125,7 @@ def _on_event(event, **_kw):
             _tls.asked = {"cache_hit": 0, "retrieval_ms": 0.0}
 
 
+@_unwatched
 def _on_duration(event, secs, **_kw):
     if event == _RETRIEVAL_EVENT:
         asked = getattr(_tls, "asked", None)
@@ -108,6 +133,7 @@ def _on_duration(event, secs, **_kw):
             asked["retrieval_ms"] = round(secs * 1e3, 3)
 
 
+@_unwatched
 def _on_begin(event, _start, **_kw):
     """(jax reports an event's start as a scalar.)  How many compile
     events are open on this thread: an inner ``jit``'s trace ends before
@@ -117,6 +143,7 @@ def _on_begin(event, _start, **_kw):
         _tls.open = getattr(_tls, "open", 0) + 1
 
 
+@_unwatched
 def _on_time_span(event, start, end, fun_name=None, **_kw):
     if not _telemetry.enabled():
         return

@@ -188,10 +188,20 @@ def manifest_summary(manifest: Optional[dict]) -> Optional[dict]:
                                      "peak_hbm_bytes") if k in manifest}
 
 
-def aot_compile(jitted, *args, signature=None):
+def aot_compile(jitted, *args, signature=None, store_digest=None,
+                donate_argnums=()):
     """``jitted.lower(*args).compile()`` plus its manifest:
     ``(compiled, manifest)``.  The manifest half never raises; the
-    compile half raises exactly as jax would."""
+    compile half raises exactly as jax would.  With ``store_digest``
+    (``program_store.program_digest``: a store is placed and the Program
+    has a key) what is lowered and compiled is the stored module of the
+    step, loaded or made and kept now, under ``donate_argnums``, the
+    donation ``jitted`` was built with."""
+    if store_digest is not None:
+        from . import program_store
+
+        jitted = program_store.stored_step(jitted, args, store_digest,
+                                           donate_argnums)
     compiled = jitted.lower(*args).compile()
     return compiled, executable_manifest(compiled, signature=signature)
 

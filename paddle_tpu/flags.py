@@ -9,6 +9,8 @@ from __future__ import annotations
 import os
 from typing import Dict, Iterable, Union
 
+from . import watch as _watch
+
 __all__ = ["set_flags", "get_flags", "register_flag", "all_flags"]
 
 _FLAGS: Dict[str, object] = {}
@@ -49,7 +51,7 @@ def get_flags(flags: Union[str, Iterable[str]]):
     if isinstance(flags, str):
         if flags not in _FLAGS:
             raise ValueError(f"unknown flag {flags!r}")
-        return {flags: _FLAGS[flags]}
+        return {flags: flag_value(flags)}
     return {f: get_flags(f)[f] for f in flags}
 
 
@@ -61,8 +63,15 @@ def all_flags() -> Dict[str, object]:
 
 
 def flag_value(name: str):
-    """Internal fast-path accessor."""
-    return _FLAGS.get(name, _DEFS.get(name, (None, None))[1])
+    """Internal fast-path accessor; the one reader of a flag's value
+    (a thread that traces a Program has its reads recorded:
+    ``watch.py``)."""
+    value = _FLAGS.get(name, _DEFS.get(name, (None, None))[1])
+    if _watch.active:
+        seen = _watch.current()
+        if seen is not None:
+            seen.flags[name] = value
+    return value
 
 
 # -- the flag set (reference platform/flags.cc + nan_inf_utils) -------------

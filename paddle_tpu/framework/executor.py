@@ -46,6 +46,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from .. import costmodel as _costmodel
+from .. import program_store as _program_store
 from .. import telemetry as _telemetry
 from ..compile_cache import ensure_compile_cache
 
@@ -66,6 +67,8 @@ _SKIP_STAT = _monitor.get("skipped_nonfinite_steps")
 _CKPT_FAIL_STAT = _monitor.get("checkpoint_write_failures")
 _HOST_SYNC_STAT = _monitor.get("host_syncs")
 _GUARD_RES_STAT = _monitor.get("guard_resolutions")
+# the step's argument that is donated: the state it rebinds
+_DONATED = (1,)
 
 
 # ---------------------------------------------------------------------------
@@ -356,10 +359,15 @@ class _CacheEntry:
     (``aot_failed`` latches the fallback so it is attempted once)."""
 
     __slots__ = ("fn", "mut_in", "const_in", "state_out", "guarded",
-                 "compiled", "manifest", "aot_failed", "sig", "prev_t")
+                 "compiled", "manifest", "aot_failed", "sig", "prev_t",
+                 "store_digest")
 
-    def __init__(self, fn, mut_in, const_in, state_out, guarded):
+    def __init__(self, fn, mut_in, const_in, state_out, guarded,
+                 store_digest=None):
         self.fn = fn
+        # the Program's half of its key in the program store, None where
+        # there is no store (``program_store.py``)
+        self.store_digest = store_digest
         self.mut_in = mut_in
         self.const_in = const_in
         self.state_out = state_out
@@ -421,6 +429,8 @@ class Executor:
         scope = scope or global_scope()
 
         if flag_value("FLAGS_check_nan_inf"):
+            _program_store.refuse("FLAGS_check_nan_inf runs the ops one by "
+                                  "one and makes no module")
             return self._run_debug(program, feed, fetch_names, scope,
                                    return_numpy)
 
@@ -592,7 +602,8 @@ class Executor:
                                        step=int(step), aot=True):
                 entry.compiled, entry.manifest = _costmodel.aot_compile(
                     entry.fn, feed_vals, mut_vals, const_vals, step,
-                    signature=sig)
+                    signature=sig, store_digest=entry.store_digest,
+                    donate_argnums=_DONATED)
             entry.sig = sig
         except Exception as e:
             entry.compiled, entry.aot_failed = None, True
@@ -977,9 +988,11 @@ class Executor:
             return fetches, new_state
 
         # Donate only rebound state: params update in place in HBM.
-        fn = jax.jit(step_fn, donate_argnums=(1,))
+        fn = jax.jit(step_fn, donate_argnums=_DONATED)
         return _CacheEntry(fn, mut_in, const_in, state_out,
-                           guard_loss is not None)
+                           guard_loss is not None,
+                           _program_store.program_digest(
+                               program, feed_names, fetch_names, guard_loss))
 
     def _run_pipeline(self, program, feed, fetch_list, scope, return_numpy):
         """Programs marked by PipelineOptimizer: microbatch-scan schedule

@@ -25,6 +25,8 @@ import threading
 import time
 from typing import Dict, List, Tuple
 
+from . import watch as _watch
+
 __all__ = ["StatValue", "StatRegistry", "monitor", "stat_add", "stat_get",
            "stat_add_per_device", "process_start_time", "process_uptime_s",
            "program_to_dot", "save_program_dot"]
@@ -55,6 +57,10 @@ class StatValue:
         self._lock = threading.Lock()
 
     def increase(self, n: int = 1) -> int:
+        if _watch.active:      # (a thread that traces a Program: watch.py)
+            seen = _watch.current()
+            if seen is not None:
+                seen.stats[self.name] = seen.stats.get(self.name, 0) + n
         with self._lock:
             self._v += n
             return self._v

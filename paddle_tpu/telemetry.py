@@ -439,6 +439,9 @@ def clear_spans():
 # that a window's traffic does not turn over (a process makes a few
 # hundred).
 KEPT_FAMILIES = ("startup/", "compile/")
+# the ``compile/`` parts that make a row of the account's ``programs``
+# (``compile/program_store`` is a part, and no column of a row)
+_ROW_PARTS = ("trace", "lower", "backend")
 _JIT_OF = re.compile(r"^jit[(_](.*?)\)?$")
 _kept: deque = deque(maxlen=4096)
 _booked = 0                       # spans kept so far
@@ -487,15 +490,28 @@ class _PartCtx:
         return False
 
 
-def startup_span(name: str, **attrs):
+def _inherited(inherit) -> dict:
+    """The attributes named ``inherit``, each from the nearest span open
+    on this thread that carries it."""
+    out, stack = {}, _stack()
+    for key in inherit:
+        for open_span in reversed(stack):
+            if key in open_span.attrs:
+                out[key] = open_span.attrs[key]
+                break
+    return out
+
+
+def startup_span(name: str, inherit=(), **attrs):
     """``with startup_span("startup/pool_alloc", pools=n) as span: ...``
     — a :func:`trace_span` that is also a part of the start-up account
     (see above).  For code that runs once a program or once a process,
-    never once a step.  A no-op under ``FLAGS_telemetry=0`` (what is
-    written to ``span.attrs`` then is dropped)."""
+    never once a step.  ``inherit`` as :func:`span_record`'s.  A no-op
+    under ``FLAGS_telemetry=0`` (what is written to ``span.attrs`` then is
+    dropped)."""
     if not enabled():
         return _NOOP
-    return _PartCtx(span_begin(name, **attrs))
+    return _PartCtx(span_begin(name, **_inherited(inherit), **attrs))
 
 
 def span_record(name: str, start: float, end: float, inherit=(),
@@ -512,11 +528,7 @@ def span_record(name: str, start: float, end: float, inherit=(),
         return None
     stack = _stack()
     top = stack[-1] if stack else None
-    for key in inherit:
-        for open_span in reversed(stack):
-            if key in open_span.attrs:
-                attrs[key] = open_span.attrs[key]
-                break
+    attrs.update(_inherited(inherit))
     span = Span(name, attrs, top.span_id if top is not None else None,
                 threading.get_ident(),
                 trace_id=top.trace_id if top is not None else None)
@@ -548,7 +560,8 @@ def startup_account(spans: Optional[List[Span]] = None) -> dict:
     """The start-up account so far (or of ``spans``, some of
     ``get_spans(kept=True)``): ``{part: {"s": self seconds, "n":
     spans}}`` for every part that has a span (``import``, ``trace``,
-    ``backend``, ...; the same seconds as the ``*_us`` counters), and
+    ``backend``, ...; the same seconds as the ``*_us`` counters;
+    ``program_store`` also has ``"hits"``, the modules loaded), and
     under ``"programs"`` one row a jitted function and engine program,
     costliest first: ``(fun_name, kind, bucket, trace_s, lower_s,
     backend_s, cache_hit)``, ``kind`` and ``bucket`` those of the
@@ -571,14 +584,16 @@ def startup_account(spans: Optional[List[Span]] = None) -> dict:
         entry = parts.setdefault(part, {"s": 0.0, "n": 0})
         entry["s"] += self_s
         entry["n"] += 1
-        if family != "compile":
+        if "hit" in span.attrs:        # (``compile/program_store``)
+            entry["hits"] = entry.get("hits", 0) + span.attrs["hit"]
+        if family != "compile" or part not in _ROW_PARTS:
             continue
         a = span.attrs
         # jax names the trace by the function, its module ``jit(<it>)``
         key = (_JIT_OF.sub(r"\1", str(a.get("fun_name"))),
                a.get("kind"), a.get("bucket"))
         row = rows.setdefault(key, [0.0, 0.0, 0.0, None])
-        row[("trace", "lower", "backend").index(part)] += self_s
+        row[_ROW_PARTS.index(part)] += self_s
         if "cache_hit" in a:
             row[3] = a["cache_hit"] if row[3] is None \
                 else min(row[3], a["cache_hit"])

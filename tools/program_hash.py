@@ -16,22 +16,45 @@ the cells sharing its code kept theirs.  The CPU lowering takes the
 reference formulations (no Mosaic call), so it says nothing of a kernel's
 body: those are compared on the chip.  Default cells: the five that share
 code with ``deepseek-v2-docqa`` (PR 56).
+
+    python tools/program_hash.py --compiled TREE [CELL ...]
+
+(PR 60) compiles instead, for a described v5e and with the backend answered
+for, so the Pallas kernels are in: the decode program and the widest rung
+of each cell as the ``Executor`` builds them (``Executor._build``'s jitted
+step), once as ``fn.lower(...).compile()`` and once through the program
+store's round trip (``jax.export``, serialise, deserialise,
+``program_store.wrapped(...).lower(...).compile()``), and prints a hash of
+each executable's optimised HLO (``as_text()`` less its ``metadata``; a
+second hash is of its lines sorted, for two schedules of the same
+operations) with its ``cost_analysis`` flops and ``memory_analysis`` bytes:
+the stored module compiles to the executable the step compiled to where
+the two lines of a program agree.  A minute or two a program; no chip, nothing
+runs.  Default cells: ``mistral7b-chat``, ``smallthinker21b-mixedlen``,
+``olmo-hybrid7b-longdoc``.
 """
 import hashlib
 import json
 import os
+import re
 import sys
 
 CELLS = ["gigachat35-ragturns", "command-a-plus-ragdocs",
          "solar-open2-agentturns", "smallthinker21b-mixedlen",
          "lfm2-24b-longanswer"]
+COMPILED_CELLS = ["mistral7b-chat", "smallthinker21b-mixedlen",
+                  "olmo-hybrid7b-longdoc"]
 
 
 def main(argv) -> int:
+    compiled = argv[1:2] == ["--compiled"]
+    if compiled:
+        del argv[1]
     tree = os.path.abspath(argv[1])
-    cells = argv[2:] or CELLS
+    cells = argv[2:] or (COMPILED_CELLS if compiled else CELLS)
     sys.path[:0] = [os.path.join(tree, "benchmark"), tree]
     os.environ["JAX_PLATFORMS"] = "cpu"
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
     import jax
     import numpy as np
 
@@ -47,6 +70,53 @@ def main(argv) -> int:
         print(f"{llama.__file__} is not under {tree}", file=sys.stderr)
         return 2
     mesh = dp_mesh(1, devices=jax.devices()[:1])
+    chip = None
+    if compiled:
+        from jax.experimental import topologies
+        from jax.sharding import SingleDeviceSharding
+
+        chip = SingleDeviceSharding(topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2").devices[0])
+        # (the ops ask the backend which formulation to lower)
+        jax.default_backend = lambda: "tpu"
+
+    def canonical(text):
+        """An executable's text less what names it and does not make it:
+        ``metadata``, the tables of files and frames above the module, the
+        numbers and ``.clone`` XLA appends to a name, the entry's
+        parameter names (jit names them after the Python signature)."""
+        lines, table = [], False
+        for line in re.sub(r", metadata=\{[^}]*\}", "",
+                           text).splitlines():
+            if line in ("FileNames", "FunctionNames", "FileLocations",
+                        "StackFrames"):
+                table = True
+            elif table:
+                table = bool(line.strip())
+            else:
+                lines.append(re.sub(r"\b([A-Za-z_][\w\-]*?)(\.\d+|\.clone)+\b",
+                                    r"\1", line))
+        text = "\n".join(lines)
+        entry = re.search(r"^ENTRY %\w+ \((.*?)\) -> ", text, re.M)
+        for i, name in enumerate(re.findall(r"([\w\-]+): ",
+                                            entry.group(1))):
+            text = re.sub(r"(?<![\w\-])%s(?![\w\-])" % re.escape(name),
+                          "p%d" % i, text)
+        return text
+
+    def executable(fn, args):
+        """An executable's line: its optimised HLO's hash, flops, bytes."""
+        exe = fn.lower(*args).compile()
+        text = canonical(exe.as_text())
+        cost, mem = exe.cost_analysis(), exe.memory_analysis()
+        cost = cost[0] if isinstance(cost, (list, tuple)) else cost
+        lines = "\n".join(sorted(text.splitlines()))
+        return "%s %s flops %.6g bytes %.6g args %d out %d temp %d alias %d" % (
+            hashlib.sha256(text.encode()).hexdigest()[:16],
+            hashlib.sha256(lines.encode()).hexdigest()[:16],
+            cost.get("flops", 0.0), cost.get("bytes accessed", 0.0),
+            mem.argument_size_in_bytes, mem.output_size_in_bytes,
+            mem.temp_size_in_bytes, mem.alias_size_in_bytes)
 
     def lowered(build, shapes):
         main, startup = pt.Program(), pt.Program()
@@ -56,20 +126,41 @@ def main(argv) -> int:
         feeds, fetches = out[0], out[1]
         names = [fetches[n].name for n in ("next_token", "expert_counts")
                  if n in fetches]
-        fn, mut_in, const_in, _ = build_sharded_step(main, feeds, names,
-                                                     mesh)
         block = main.global_block()
 
         def spec(shape, dtype):
             dtype = {"int64": "int32", "float64": "float32"}.get(
                 str(dtype), str(dtype))
-            return jax.ShapeDtypeStruct(tuple(shape), np.dtype(dtype))
+            return jax.ShapeDtypeStruct(tuple(shape), np.dtype(dtype),
+                                        sharding=chip)
 
         def state(ns):
             return tuple(spec(block._find_var_recursive(n).shape,
                               block._find_var_recursive(n).dtype)
                          for n in ns)
 
+        if compiled:
+            from jax import export
+
+            from paddle_tpu import program_store
+            from paddle_tpu.framework import executor
+
+            entry = pt.Executor()._build(main, block, list(feeds), names)
+            args = (tuple(spec(*shapes[n]) for n in feeds),
+                    state(entry.mut_in), state(entry.const_in),
+                    spec((), "int32"))
+            direct = executable(entry.fn, args)
+            blob = program_store.export_step(entry.fn, args,
+                                             ("tpu",)).serialize()
+            stored = executable(program_store.wrapped(
+                export.deserialize(blob), executor._DONATED), args)
+            same = "same" if direct == stored else (
+                "same operations, some scheduled in another order"
+                if direct.split()[1:] == stored.split()[1:] else "DIFFERENT")
+            return "%s\n    direct %s\n    stored %s (module %d bytes)" % (
+                same, direct, stored, len(blob))
+        fn, mut_in, const_in, _ = build_sharded_step(main, feeds, names,
+                                                     mesh)
         text = fn.lower(tuple(spec(*shapes[n]) for n in feeds),
                         state(mut_in), state(const_in),
                         spec((), "int32")).as_text()
@@ -97,7 +188,7 @@ def main(argv) -> int:
             slots, seq, name="llama", **paged, **model), shapes), flush=True)
         rungs = sorted(e["prefill_buckets"])
         one = ((1, np_slot), "int32")
-        for b in (rungs[0], rungs[-1]):
+        for b in rungs[-1:] if compiled else (rungs[0], rungs[-1]):
             if chunk:
                 shapes = {"chunk_ids": ((1, b), "int64"),
                           "base": ((1,), "int32"), "block_table": one,
