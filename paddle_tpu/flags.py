@@ -171,7 +171,8 @@ register_flag("FLAGS_serving_kv_page_tokens", 16,
               "pages + per-slot block tables, so concurrency is bounded "
               "by LIVE tokens): tokens per page (power of two dividing "
               "FLAGS_serving_max_seq_len); smaller pages waste less on "
-              "short sequences but deepen the per-slot block table")
+              "short sequences but deepen the per-slot block table "
+              "(paddle_tpu/serving/kv_cache.py)")
 register_flag("FLAGS_serving_kv_pages", 0,
               "paged KV cache: physical pages in the per-layer pool "
               "(page 0 is the reserved trash page garbage writes are "
@@ -318,7 +319,7 @@ register_flag("FLAGS_embedding_placement", "mod",
               "bit-exact vs the unsharded table")
 register_flag("FLAGS_embedding_cache_rows", 4096,
               "embedding tier hot-row cache capacity in ROWS (refcounted"
-              " LRU fronting the shard gathers, PrefixIndex-style): a "
+              " LRU fronting the shard gathers, as kv_cache.PrefixIndex): a "
               "hit skips the device gather for that id; eviction only "
               "takes rows no in-flight lookup has pinned.  0 disables "
               "the cache (every id gathers)")

@@ -14,7 +14,7 @@ This module is the handoff layer that lets the fleet split the roles:
   and frees the slot for the next prompt.  It never occupies a decode
   slot.
 * A **decode-role** engine *adopts* a segment: free pages come from
-  its own :class:`~paddle_tpu.serving.generation.PagePool` (refcount-
+  its own :class:`~paddle_tpu.serving.kv_cache.PagePool` (refcount-
   integrated; pool exhaustion evicts idle prefix pages / requeues
   exactly like a local prefill), the segment's page blocks scatter
   into those physical pages, and the sequence enters the decode grid

@@ -10,7 +10,7 @@ ring (the ep axis — pure data placement, no contracting dims, so
 reassembly is bit-exact vs the unsharded table), each shard owns one
 donated gather program, and a refcounted **hot-row cache** fronts the
 shards with the same LRU discipline the paged KV cache's
-:class:`~paddle_tpu.serving.generation.PrefixIndex` uses for prompt
+:class:`~paddle_tpu.serving.kv_cache.PrefixIndex` uses for prompt
 prefixes — hit rate, evictions and bytes are first-class stats.
 
 Three layers:
@@ -135,10 +135,10 @@ class _HotRow:
 
 class HotRowCache:
     """Refcounted LRU cache of embedding rows, modeled on the paged KV
-    cache's PrefixIndex/PagePool discipline: entries a live lookup has
-    **pinned** (refcount > 0) are never evicted; eviction takes the
-    least-recently-used unpinned entry; ``unpin`` below zero is a
-    refcount-discipline bug and asserts.  All mutation is lock-guarded
+    cache's PrefixIndex/PagePool discipline (``kv_cache.py``): entries a
+    live lookup has **pinned** (refcount > 0) are never evicted; eviction
+    takes the least-recently-used unpinned entry; ``unpin`` below zero is
+    a refcount-discipline bug and asserts.  All mutation is lock-guarded
     (lookups run on every engine worker thread).  ``capacity_rows=0``
     disables the cache (every probe misses, nothing inserts)."""
 

@@ -9,13 +9,15 @@ dominant throughput losses Orca's iteration-level scheduling (Yu et
 al., OSDI '22) and vLLM's KV-cache management (Kwon et al., SOSP '23)
 identified.  This module is the repo's answer:
 
-* **Fixed slot grid** — ``num_slots`` decode slots share per-layer KV
-  page pools ``[num_pages, n_kv, page_tokens, D]`` held as persistable
-  executor state.  The decode program writes each slot's fresh K/V
-  into the page its block table names and the executor *donates* the
-  pool buffers (``jax.jit donate_argnums`` via mutated-persistable
-  classification), so every step updates the pools in place in HBM —
-  no per-token cache copy, one compiled executable for the whole grid.
+* **Fixed slot grid** — ``num_slots`` decode slots over one cache,
+  :class:`~paddle_tpu.serving.kv_cache.KVCache` (``kv_cache.py``: page
+  pools, block tables, slot state, the prefix index, and the one table
+  of what each cache kind refuses).  The engine asks it for pages and
+  block tables and names no pool; the decode program writes each slot's
+  fresh K/V into the page its block table names and the executor
+  *donates* the pool buffers, so every step updates the pools in place
+  in HBM — no per-token cache copy, one compiled executable for the
+  whole grid.
 * **Prefill/decode split** — prompts compile against shape buckets
   (powers of two, like the one-shot batcher); decode steps run the
   whole slot grid every iteration.  Idle slots compute garbage rows
@@ -27,23 +29,13 @@ identified.  This module is the repo's answer:
   slots keep generating.  ``continuous=False`` restores FIFO head-run
   static batching (claim only when every slot is idle, i.e. batch
   drain) — the baseline ``tests/test_generation.py`` compares against.
-* **Paged KV cache** (PagedAttention-style, the engine's only cache)
-  — a flat per-layer pool plus per-slot block tables, so concurrency
-  is bounded by LIVE tokens and not by a worst-case sequence per slot.
-  :class:`PagePool` allocates physical pages on demand (page 0 is the
-  reserved trash page); running out finishes the starved slot
-  ``cache_full`` after trying to evict idle prefix-index pages.  On a
-  TPU the decode step attends the live pages in place
-  (``paged_decode_attention``); on the CPU ``kv_pool_gather`` rebuilds
-  the slot's logical ``[n_kv, max_seq_len, D]`` view and
-  ``cached_attention`` contracts over it.  The plain engine answers to
-  the uncached forward (``tests/test_generation.py``,
-  ``tests/test_paged_generation.py``).
-* **Shared-prefix reuse** — :class:`PrefixIndex` hashes page-aligned
-  prompt-prefix chunks (system prompts, few-shot headers); a hit maps
-  the shared pages into the new slot copy-on-write (refcounted,
-  mutation-free: decode and tail-prefill writes only ever touch pages
-  *past* the shared prefix) and skips their prefill entirely.
+* **Paged attention** — on a TPU the decode step attends the live
+  pages in place (``paged_decode_attention``); on the CPU
+  ``kv_pool_gather`` rebuilds the slot's logical ``[n_kv, max_seq_len,
+  D]`` view and ``cached_attention`` contracts over it.  The plain engine
+  answers to the uncached forward (``tests/test_generation.py``,
+  ``tests/test_paged_generation.py``).  A prompt whose page-aligned
+  prefix the index holds skips that part of its prefill.
 * **Chunked prefill** (``FLAGS_serving_prefill_chunk``) — long prompts
   feed in fixed-size slices, ONE slice per scheduler iteration
   interleaved with decode steps (SarathiServe-style), so a long prompt
@@ -53,16 +45,10 @@ identified.  This module is the repo's answer:
   shared pages.  A model with sliding-window layers (two page kinds)
   prefills in chunks too (PR 51): the chunk program takes both block
   tables, a window layer attends only the pages still inside its window,
-  and the window kind lets pages go WHILE the prompt is still coming in:
-  before the chunk at ``base`` a slot's window pages cover ``[base -
-  window + 1, base + C)``, and what the next rows no longer admit goes
-  back to the pool behind the chunk, so only the slot whose chunk runs
-  holds more than ``window / page_tokens + 1`` window pages and the pool
-  is ``slots x (window / page_tokens + 1)`` pages and ONE chunk's beyond.
+  and behind each chunk the engine lets go of the window pages the next
+  rows no longer admit (``kv_cache.py``).
   With chunking on a prompt may be as long as the cache; only the chunk
-  needs a prefill rung.  Prefix reuse, speculation and KV-segment handoff
-  stay refused over two page kinds, and a chunk over layers that keep
-  slot state is not built.  A model whose attention layers are latent
+  needs a prefill rung.  A model whose attention layers are latent
   (MLA) prefills in chunks over its latent pages (PR 56): a chunk writes
   its ``[c_kv | k_r]`` rows as whole pages and attends the slot's cached
   rows expanded block by block (``latent_chunk_attention``); at 128 heads
@@ -79,9 +65,8 @@ identified.  This module is the repo's answer:
   argmax-agreeing prefix plus the one bonus token is accepted —
   **bit-exact vs plain greedy decode** (tokens AND logits, tolerance
   0; the verify rows ARE the decode-step forward, batched).  Rejected
-  draft tokens roll their provisionally-grown KV pages back through
-  the refcounted pool (page accounting only — the garbage rows are
-  causally masked and overwritten by the next real write).  Slots
+  draft tokens roll their provisionally-grown KV pages back
+  (``KVCache.rollback_draft_pages``).  Slots
   with no usable draft, or ``submit(speculate=False)``, take the
   unchanged one-token grid step — mixed grids per iteration.
 * **One decode step in flight** — the scheduler hands grid step n+1
@@ -155,13 +140,12 @@ each fails only the then-active requests),
 ``serving_generated_tokens``,
 ``serving_prefill_tokens``, ``serving_slot_reclaims``,
 ``serving_prefix_hits``, ``serving_prefix_tokens_saved``,
-``serving_prefill_chunks``, ``serving_kv_page_evictions``,
+``serving_prefill_chunks``,
 ``serving_kv_pool_stalls``, ``serving_spec_drafts``,
 ``serving_spec_tokens_proposed``, ``serving_spec_tokens_accepted``,
 ``serving_spec_rollbacks``,
-``serving_kv_window_pages_released`` (and, of those, the ones let go
-while their prompt was still coming in,
-``serving_kv_window_pages_released_in_prefill``), ``moe_tokens_routed``,
+``serving_kv_window_pages_released_in_prefill`` (of the window pages let
+go, those whose prompt was still coming in), ``moe_tokens_routed``,
 ``moe_tokens_dropped`` (must read 0), ``moe_pad_pairs_left_out`` (the
 pairs of the rows a program holds beyond the tokens it was fed, a rung's
 pad tail and a step's idle slots, which no expert multiplied), and for a
@@ -176,22 +160,12 @@ expert ``moe_shared_expert_rows`` (row-layers it ran on),
 ``serving_block_passes_denoise`` / ``serving_block_passes_commit``
 (block diffusion: slot-passes that decided positions / that only
 committed a block's K/V), ``serving_block_tokens_committed``,
-``serving_slot_state_writes`` (prefills that wrote a slot's state: a
-model whose layers keep slot state, a convolution's last rows or the
-delta rule's matrix, has beside the pages one or two per-slot state
-variables a layer that the prefill program overwrites whole and the
-decode program advances on the device), ``serving_delta_state_steps``
+``serving_slot_state_writes`` (prefills that wrote a slot's state),
+``serving_delta_state_steps``
 (slot-layers whose delta state a decode step moved on),
 ``serving_ssm_state_steps`` (the same of a state-space layer's matrix);
-gauges
-``serving_slot_state_bytes`` (what those variables take, every kind),
-``serving_spec_acceptance_rate``,
-``serving_slot_occupancy``,
-``serving_kv_cache_bytes`` (allocated cache capacity: the page pools),
-``serving_kv_live_bytes`` (bytes of pages actually referenced by live
-sequences or the prefix index), ``serving_kv_pages_free``,
-``serving_kv_pages_live`` (with sliding-window layers also
-``serving_kv_pages_live_full`` / ``serving_kv_pages_live_window``),
+gauges (the cache's are listed in ``kv_cache.py``)
+``serving_spec_acceptance_rate``, ``serving_slot_occupancy``,
 ``moe_experts_touched``, ``moe_expert_load_max_over_mean``; histograms
 ``serving_generate_ms``, ``serving_prefill_ms``,
 ``serving_decode_step_ms``, ``serving_spec_verify_ms``,
@@ -219,11 +193,11 @@ from . import batcher
 from . import usage
 from .engine import (OverloadedError, PoisonedInput, RequestFailed,
                      ServingFuture, poison_sentinel_matches)
+from .kv_cache import KVCache, PoolExhausted, SlotPages
 from .streams import stream_meter, stream_writer
 from .sharded import describe_mesh as _describe_mesh
 
-__all__ = ["GenerationEngine", "GenRequest", "PagePool", "PrefixIndex",
-           "PoolExhausted", "ngram_draft"]
+__all__ = ["GenerationEngine", "GenRequest", "ngram_draft"]
 
 logger = logging.getLogger("paddle_tpu.serving.generation")
 
@@ -270,10 +244,6 @@ class GenRequest:
             self.events.append((label, ts, extra))
 
 
-class PoolExhausted(Exception):
-    """The paged KV pool has no free page and nothing evictable."""
-
-
 def ngram_draft(history: np.ndarray, k: int, max_ngram: int) -> List[int]:
     """Prompt-lookup drafter: propose up to ``k`` tokens by matching
     the longest suffix n-gram of ``history`` (``max_ngram`` down to 1)
@@ -302,139 +272,17 @@ def ngram_draft(history: np.ndarray, k: int, max_ngram: int) -> List[int]:
     return []
 
 
-class PagePool:
-    """Host-side physical-page allocator for the paged KV cache.
-
-    Physical page 0 is the reserved **trash page** (garbage writes —
-    idle slots, chunk pad tails — are redirected there in-graph) and is
-    never handed out.  Pages are refcounted: a slot holds one ref per
-    mapped page, the prefix index holds one per registered page; a page
-    returns to the free list when its count hits zero.  Not
-    thread-safe on its own — the engine mutates it only from the
-    scheduler thread."""
-
-    def __init__(self, num_pages: int):
-        if num_pages < 2:
-            raise ValueError(f"paged KV pool needs >= 2 pages (one is "
-                             f"the reserved trash page), got {num_pages}")
-        self.num_pages = int(num_pages)
-        self._free: collections.deque = collections.deque(
-            range(1, num_pages))
-        self._ref = [0] * num_pages
-
-    def alloc(self) -> Optional[int]:
-        """One free page at refcount 1, or None when exhausted."""
-        if not self._free:
-            return None
-        p = self._free.popleft()
-        self._ref[p] = 1
-        return p
-
-    def incref(self, pages: Sequence[int]):
-        for p in pages:
-            self._ref[p] += 1
-
-    def decref(self, pages: Sequence[int]):
-        for p in pages:
-            self._ref[p] -= 1
-            if self._ref[p] < 0:
-                raise AssertionError(f"page {p} refcount underflow")
-            if self._ref[p] == 0:
-                self._free.append(p)
-
-    def refcount(self, page: int) -> int:
-        return self._ref[page]
-
-    @property
-    def free_pages(self) -> int:
-        return len(self._free)
-
-    @property
-    def live_pages(self) -> int:
-        return (self.num_pages - 1) - len(self._free)
-
-
-class PrefixIndex:
-    """Shared-prefix page index: page-aligned prompt-prefix chunk ->
-    physical page holding its K/V.
-
-    Keys are the exact token bytes of the prompt's first ``(i+1) *
-    page_tokens`` tokens, so a hit is an exact prefix match chained
-    from position 0 (no hash collisions, no partial pages).  Lookup is
-    capped one token short of the whole prompt — at least one token
-    must prefill to produce the first next-token logits.  Entries hold
-    one pool ref each; :meth:`evict_one` drops the LRU entry whose page
-    only the index still references (pages mapped into live slots are
-    never evicted — the no-collateral contract chaos asserts)."""
-
-    def __init__(self, pool: PagePool, page_tokens: int):
-        self._pool = pool
-        self._pt = int(page_tokens)
-        self._entries: "collections.OrderedDict[bytes, int]" = \
-            collections.OrderedDict()
-
-    def lookup(self, prompt: np.ndarray) -> List[int]:
-        """Longest indexed page chain prefixing ``prompt`` (< its full
-        length); hit entries refresh their LRU position."""
-        max_pages = max(0, (int(prompt.size) - 1) // self._pt)
-        pages = []
-        for i in range(max_pages):
-            key = prompt[:(i + 1) * self._pt].tobytes()
-            p = self._entries.get(key)
-            if p is None:
-                break
-            self._entries.move_to_end(key)
-            pages.append(p)
-        return pages
-
-    def register(self, prompt: np.ndarray, pages: Sequence[int]):
-        """Publish a freshly prefilled prompt's fully-covered pages.
-        A key that raced in from another slot keeps its existing page
-        (this slot's copy stays private and frees with the slot)."""
-        for i, p in enumerate(pages):
-            key = prompt[:(i + 1) * self._pt].tobytes()
-            if key in self._entries:
-                self._entries.move_to_end(key)
-                continue
-            self._entries[key] = p
-            self._pool.incref([p])
-
-    def evict_one(self) -> bool:
-        """Free the LRU index-only page; False when every indexed page
-        is still mapped into a live slot (nothing safely evictable)."""
-        for key, p in list(self._entries.items()):
-            if self._pool.refcount(p) == 1:
-                del self._entries[key]
-                self._pool.decref([p])
-                return True
-        return False
-
-    def flush(self) -> int:
-        """Drop EVERY entry (decref all index-held pages) and return
-        how many were dropped — the integrity valve for a mid-step
-        executor crash, after which the donated pool buffers (and
-        therefore every indexed page's K/V) are unknowable."""
-        n = len(self._entries)
-        for p in self._entries.values():
-            self._pool.decref([p])
-        self._entries.clear()
-        return n
-
-    def __len__(self) -> int:
-        return len(self._entries)
-
-
-class _Slot:
-    """Per-slot decode state: cache offset, step count, deadline."""
+class _Slot(SlotPages):
+    """Per-slot decode state: cache offset, step count, deadline (and,
+    as :class:`SlotPages`, the pages it holds)."""
 
     __slots__ = ("idx", "req", "position", "steps", "tokens", "t_start",
-                 "logits", "pages", "wpages", "router_logits",
-                 "prefill_pos", "hit_tokens", "chunk_counts",
-                 "decoding", "span", "page_us", "page_t", "page_tenant",
-                 "blk_tokens", "blk_masked", "blk_left", "blk_done",
-                 "blk_head", "passes")
+                 "logits", "router_logits", "prefill_pos", "hit_tokens",
+                 "chunk_counts", "decoding", "span", "blk_tokens",
+                 "blk_masked", "blk_left", "blk_done", "blk_head", "passes")
 
     def __init__(self, idx: int):
+        super().__init__()
         self.idx = idx
         self.req: Optional[GenRequest] = None
         self.span = None  # generation/sequence root (telemetry on)
@@ -443,10 +291,6 @@ class _Slot:
         self.tokens: List[int] = []
         self.t_start = 0.0
         self.logits: List[np.ndarray] = []  # keep_logits only
-        self.pages: List[int] = []   # block table, logical order
-        # sliding-window layers: their block table, logical
-        # order too; a page the window slid past is 0 (the trash page)
-        self.wpages: List[int] = []
         self.router_logits: List[np.ndarray] = []  # keep_logits, experts
         self.prefill_pos = 0         # next position to prefill
         self.hit_tokens = 0          # tokens served by the index
@@ -454,15 +298,6 @@ class _Slot:
         # the device holds them: [(handle, real rows)]
         self.chunk_counts: List[tuple] = []
         self.decoding = False        # prefill complete, in the grid
-        # KV page-second integration (usage ledger): page_us
-        # accumulates held-pages-×-wall-time in µs, marked forward at
-        # every block-table change and booked at release.  page_tenant
-        # snapshots the request's tenant at claim because every finish
-        # path clears slot.req BEFORE releasing the pages; None (usage
-        # off / untracked) keeps the whole integration zero-work
-        self.page_us = 0
-        self.page_t = 0.0
-        self.page_tenant: Optional[str] = None
         # block diffusion: the block at ``position`` as the last booked
         # pass left it (the host's mirror of what the device carries),
         # how many of its positions are still undecided, how many
@@ -670,22 +505,16 @@ class GenerationEngine:
     block-causal mask, each later block takes up to T denoising passes
     (the static schedule: ``ceil(undecided / passes_left)`` positions
     fixed a pass, by confidence) and one commit pass, and its tokens
-    are booked and streamed together at the commit.  Such an engine
-    refuses ``prefix_reuse``, ``prefill_chunk``, ``speculate`` and the
-    disaggregated roles, which walk one token a step.
+    are booked and streamed together at the commit.
     A ``layer_pattern`` with layers that keep slot state (``mixer``,
-    ``models/llama.py``) gives those layers no pages: a gated short
-    convolution keeps ``L_cache - 1`` rows a slot, a gated-delta layer
-    the rows of its convolution and a matrix ``[heads, Dk, Dv]`` a slot
-    (``cache_spec``: the engine allocates every layer's cache, however
-    many entries and of whatever shape, from that description).  The
-    prefill program
-    writes the whole of its slot's state from the prompt's true last
-    positions, so a reused slot needs no reset; the decode program moves
-    the state of the rows that ride a step on by one, on the device, and
-    leaves a dead row's alone; no host code reads or writes it between
-    steps.  Such an engine refuses ``prefix_reuse``, ``prefill_chunk``,
-    ``speculate``, the disaggregated roles and ``block_diffusion``.
+    ``models/llama.py``) gives those layers no pages but per-slot state
+    (``kv_cache.py``): the prefill program writes the whole of its slot's
+    state from the prompt's true last positions, so a reused slot needs
+    no reset; the decode program moves the state of the rows that ride a
+    step on by one, on the device, and leaves a dead row's alone.  What
+    a block engine, slot state and window pages each refuse of
+    ``prefix_reuse``, ``prefill_chunk``, ``speculate`` and the
+    disaggregated roles is ``kv_cache.REFUSALS``.
     ``scope``: optional pre-initialized :class:`~paddle_tpu.framework.
     executor.Scope` whose weights use the same ``name`` prefix (the
     engine then shares them zero-copy); omitted, the engine seeds its
@@ -707,9 +536,7 @@ class GenerationEngine:
                  spec_tokens=None, spec_ngram=None, num_window_pages=None):
         import paddle_tpu as pt
         from ..compile_cache import ensure_compile_cache
-        from ..models.llama import (build_llama_prefill, cache_spec,
-                                    layer_spec, state_layers,
-                                    window_layers)
+        from ..models.llama import build_llama_prefill, layer_spec
 
         ensure_compile_cache()
         self.model = dict(model)
@@ -759,20 +586,37 @@ class GenerationEngine:
             self.max_seq_len, buckets=prefill_buckets)
         if self.num_slots < 1:
             raise ValueError("GenerationEngine needs at least one slot")
+        if paged not in (None, True):
+            raise ValueError("the dense KV cache was removed at PR 30")
+        self.prefill_chunk = int(
+            prefill_chunk if prefill_chunk is not None
+            else flag_value("FLAGS_serving_prefill_chunk"))
+        self.prefix_reuse = bool(
+            prefix_reuse if prefix_reuse is not None
+            else flag_value("FLAGS_serving_prefix_reuse"))
+        # every layer's cache, by kind (``kv_cache.py``): the page
+        # configuration (None keywords fall back to flags) and its
+        # checks, the pools, the block tables, the slot state
+        self.kv = kv = KVCache(
+            self.model, name, num_slots=self.num_slots,
+            max_seq_len=self.max_seq_len, page_tokens=page_tokens,
+            num_pages=num_pages, num_window_pages=num_window_pages,
+            prefill_chunk=self.prefill_chunk,
+            prefix_reuse=self.prefix_reuse, count=self._count)
+        # what the programs are built from and others read of it, set
+        # once (``kv_live_bytes`` moves: a property)
+        for attr in ("page_tokens", "pages_per_slot", "num_pages",
+                     "window", "num_window_pages", "window_pages_per_slot",
+                     "state_names", "kv_cache_bytes", "slot_state_bytes",
+                     "page_bytes"):
+            setattr(self, attr, getattr(kv, attr))
 
-        heads = self.model["num_heads"]
-        self._n_kv = self.model.get("num_kv_heads") or heads
-        self._head_dim = (self.model.get("head_dim")
-                          or self.model["hidden"] // heads)
-        # the per-layer pattern (models/llama.py DEFAULT_LAYER): which
-        # layers attend a sliding window, which route to experts
-        n_layers = self.model["num_layers"]
+        # the per-layer pattern (models/llama.py DEFAULT_LAYER) beside
+        # the cache's kinds: layers that keep slot state (a
+        # convolution's rows, the delta rule's matrix), not pages
         specs = [layer_spec(self.model.get("layer_pattern"), i)
-                 for i in range(n_layers)]
-        # layers that keep slot state (a convolution's rows, the delta
-        # rule's matrix), not pages
-        pattern = self.model.get("layer_pattern")
-        self._state_layers = state_layers(pattern, n_layers)
+                 for i in range(self.model["num_layers"])]
+        self._state_layers = kv.layers_of("slot_state")
         # ... those of them whose state is the delta rule's matrix, which
         # a prefill scans the prompt for in chunks
         self._delta_layers = [i for i in self._state_layers
@@ -787,16 +631,9 @@ class GenerationEngine:
                              "counts one scan's chunks")
         self._scan_chunk = DELTA_CHUNK if self._delta_layers \
             else SSD_CHUNK if self._ssd_layers else None
-        self._window_layers = window_layers(pattern, n_layers)
+        self._window_layers = kv.layers_of("window_pages")
         # attention layers whose pages hold one latent row a token
-        self._latent_layers = [i for i in range(n_layers)
-                               if i not in self._state_layers
-                               and specs[i]["mla"]]
-        widths = {specs[i]["window"] for i in self._window_layers}
-        if len(widths) > 1:
-            raise ValueError(f"sliding-window layers of one model share "
-                             f"one window, got {sorted(widths)}")
-        self.window = widths.pop() if widths else None
+        self._latent_layers = kv.layers_of("latent_pages")
         routed = [sp["ffn"] for sp in specs if sp["ffn"] != "dense"]
         self._moe_top_k = routed[0]["top_k"] if routed else 0
         # one chip's share of an expert-parallel group: the range of the
@@ -807,64 +644,12 @@ class GenerationEngine:
         self._moe_groups = int(routed[0].get("n_group", 1)) if routed else 1
         self._build_fn_prefill = build_llama_prefill
         self._seed = seed
-
-        # page configuration (None kwargs fall back to flags)
-        if paged not in (None, True):
-            raise ValueError("the dense KV cache was removed at PR 30")
-        pt_ = int(page_tokens if page_tokens is not None
-                  else flag_value("FLAGS_serving_kv_page_tokens"))
-        if pt_ < 1 or (pt_ & (pt_ - 1)):
-            raise ValueError(f"FLAGS_serving_kv_page_tokens must be "
-                             f"a power of two, got {pt_}")
-        if self.max_seq_len % pt_:
-            # the gathered logical view is exactly max_seq_len columns
-            # wide (the contraction length of the CPU lowering and of
-            # the chunk and verify programs) — no ragged last page
-            raise ValueError(
-                f"max_seq_len {self.max_seq_len} is not a multiple "
-                f"of page_tokens {pt_}")
-        self.page_tokens = pt_
-        self.pages_per_slot = self.max_seq_len // pt_
-        auto = self.num_slots * self.pages_per_slot + 1
-        self.num_pages = int(
-            num_pages if num_pages is not None
-            else (flag_value("FLAGS_serving_kv_pages") or auto))
-        self.prefill_chunk = int(
-            prefill_chunk if prefill_chunk is not None
-            else flag_value("FLAGS_serving_prefill_chunk"))
-        self.prefix_reuse = bool(
-            prefix_reuse if prefix_reuse is not None
-            else flag_value("FLAGS_serving_prefix_reuse"))
         # a prompt goes in whole, so the largest rung bounds it; in
         # chunks it goes in slices of at most the chunk, and only the
         # chunk needs a rung
         self.max_prompt_len = self.max_seq_len - 1 \
             if 0 < self.prefill_chunk <= self.prefill_buckets[-1] \
             else min(self.prefill_buckets[-1], self.max_seq_len - 1)
-        self._pool = PagePool(self.num_pages)
-        self._prefix: Optional[PrefixIndex] = (
-            PrefixIndex(self._pool, pt_) if self.prefix_reuse else None)
-        # sliding-window layers keep a second pool: a slot needs at most
-        # window / page_tokens + 1 of its pages however long it grows
-        self.num_window_pages = 0
-        self.window_pages_per_slot = 0
-        self._wpool: Optional[PagePool] = None
-        if self._window_layers:
-            if self.window % pt_:
-                raise ValueError(
-                    f"sliding window {self.window} is not a multiple "
-                    f"of page_tokens {pt_}")
-            self.window_pages_per_slot = min(
-                self.pages_per_slot, self.window // pt_ + 1)
-            # chunked prefill: the ONE slot whose chunk runs holds the
-            # chunk's pages beside its window's; every other slot is
-            # back under ``window_pages_per_slot`` before the next
-            # chunk is chosen (``_prefill_advance`` lets go behind it)
-            self.num_window_pages = int(
-                num_window_pages if num_window_pages is not None
-                else self.num_slots * self.window_pages_per_slot + 1
-                + -(-max(self.prefill_chunk, 0) // pt_))
-            self._wpool = PagePool(self.num_window_pages)
         # disaggregated serving role: "both" (colocated, the default)
         # runs prefill AND the decode grid; "prefill" exports each
         # prompt's populated pages as a KVSegment instead of decoding;
@@ -892,75 +677,8 @@ class GenerationEngine:
             if self.spec_ngram < 1:
                 raise ValueError(f"spec_ngram must be >= 1, got "
                                  f"{self.spec_ngram}")
-        if self._blk:
-            if self._window_layers:
-                raise ValueError(
-                    "block diffusion over sliding-window layers is not "
-                    "built: the rows of a block share their columns, a "
-                    "window gives each row its own")
-            if pt_ % self._blk:
-                raise ValueError(
-                    f"page_tokens {pt_} is not a multiple of the block "
-                    f"length {self._blk}: a block lies inside one page")
-            # a step yields a block: what walks one token a step is no
-            # part of this engine (PERF.md section 7)
-            refused = [what for what, on in (
-                ("prefix_reuse", self.prefix_reuse),
-                ("speculate", self.speculate),
-                ("prefill_chunk > 0", self.prefill_chunk > 0),
-                (f"role={self.role!r} (KV-segment handoff)",
-                 self.role != "both")) if on]
-            if refused:
-                raise ValueError(
-                    f"a block-diffusion model commits a block of "
-                    f"{self._blk} positions a step and does not support "
-                    f"{', '.join(refused)}: prefix reuse, chunked "
-                    f"prefill, speculation and segment adoption / export "
-                    f"walk one token a step and a causal prefix")
-        if self._state_layers:
-            # state that is not pages: what starts from, hands over or
-            # rolls back a sequence's cache knows pages only (PERF.md
-            # section 7)
-            refused = [what for what, on in (
-                ("prefix_reuse", self.prefix_reuse),
-                ("speculate", self.speculate),
-                ("prefill_chunk > 0", self.prefill_chunk > 0),
-                (f"role={self.role!r} (KV-segment handoff)",
-                 self.role != "both"),
-                ("block_diffusion", bool(self._blk))) if on]
-            if refused:
-                raise ValueError(
-                    f"a model whose layers keep slot state (a "
-                    f"convolution's last rows, the delta rule's matrix) "
-                    f"has per-slot state that is not pages and does not "
-                    f"support "
-                    f"{', '.join(refused)}: a shared prefix or a chunk "
-                    f"would have to start from a state nobody kept, a "
-                    f"rejected draft would have to roll it back, a "
-                    f"segment carries pages only, and the state moves on "
-                    f"one token a step, not a block")
-        if self._wpool is not None:
-            # two page kinds: a chunk program walks both block tables
-            # (models/llama.py ``_chunk_forward``); what shares, rolls
-            # back or hands over ONE table per slot is no part of this
-            # engine yet (PERF.md section 7)
-            refused = [what for what, on in (
-                ("prefix_reuse", self.prefix_reuse),
-                ("speculate", self.speculate),
-                (f"role={self.role!r} (the disagg segment codec)",
-                 self.role != "both")) if on]
-            if refused:
-                raise ValueError(
-                    f"a model with sliding-window layers keeps two "
-                    f"page pools (full and window) and does not support "
-                    f"{', '.join(refused)}: prefix reuse, speculation "
-                    f"and KV-segment handoff walk one block table per "
-                    f"slot (chunked prefill walks both)")
-            if self.prefill_chunk % pt_:
-                raise ValueError(
-                    f"prefill_chunk {self.prefill_chunk} is not a "
-                    f"multiple of page_tokens {pt_}: window pages are "
-                    f"let go chunk by chunk, at page boundaries")
+        kv.check_features(block=self._blk, speculate=self.speculate,
+                          role=self.role)
         self._fingerprint: Optional[str] = None
         self._chunk_progs: Dict[int, tuple] = {}
         self._verify_progs: Dict[int, tuple] = {}
@@ -981,20 +699,11 @@ class GenerationEngine:
         # placements on the scope arrays drive GSPMD at jit time, and
         # the donated cache buffers stay sharded in place across steps.
         self.mesh = mesh
-        # every layer's cache, by kind: page pools and slot state
-        self._cache_spec = cache_spec(
-            self.name, n_layers, pattern,
-            num_slots=self.num_slots, num_pages=self.num_pages,
-            page_tokens=pt_, num_kv_heads=self._n_kv,
-            head_dim=self._head_dim, hidden=self.model["hidden"],
-            num_window_pages=self.num_window_pages or None)
-        self.state_names = [e["name"] for e in self._cache_spec
-                            if e["kind"] == "slot_state"]
         self._warm = False        # no :meth:`warmup` has finished yet
         self._build_decode(scope_ready=scope is not None)
         if mesh is not None:
             self._place_on_mesh(shard_rules)
-        self._init_caches()
+        kv.allocate(self.scope, mesh)
 
         # scheduler state
         self._queue: collections.deque = collections.deque()
@@ -1105,7 +814,7 @@ class GenerationEngine:
     def _place_on_mesh(self, shard_rules):
         """Shard every decode-program weight onto the mesh — once,
         before the caches exist (the caches get their own kv-head
-        placement in :meth:`_init_caches`).  The prefill programs read
+        placement in ``KVCache.allocate``).  The prefill programs read
         the same scope, so one placement covers both paths
         (:func:`~paddle_tpu.serving.sharded.place_block_state`)."""
         from .sharded import place_block_state, serving_shard_rules
@@ -1115,102 +824,9 @@ class GenerationEngine:
                           self._decode_feeds, self.scope, self.mesh,
                           self._shard_rules, skip=self.cache_names)
 
-    def _cache_sharding(self):
-        """KV page pools [pages, n_kv, page_tokens, D] shard the kv-head
-        dim over ``mp`` when it divides (each device holds its heads'
-        pages — attention is per-head independent, so the contraction
-        never crosses devices); otherwise replicate."""
-        from jax.sharding import NamedSharding, PartitionSpec as P
-
-        from ..parallel.mesh import MP_AXIS, axis_size
-
-        mp = axis_size(self.mesh, MP_AXIS)
-        pool_heads = next((e["shape"][1] for e in self._cache_spec
-                           if e["kind"] != "slot_state"), self._n_kv)
-        if mp > 1 and pool_heads % mp == 0:
-            return NamedSharding(self.mesh, P(None, MP_AXIS)), MP_AXIS
-        return NamedSharding(self.mesh, P()), None
-
-    def _init_caches(self):
-        """The page pools and the slot state, allocated and zero filled
-        on the device: ``startup/pool_alloc`` of the start-up account."""
-        with telemetry.startup_span("startup/pool_alloc",
-                                    pools=len(self._cache_spec)) as span:
-            self._alloc_caches()
-            span.attrs["bytes"] = self.kv_cache_bytes \
-                + self.slot_state_bytes
-
-    def _alloc_caches(self):
-        import jax
-        import jax.numpy as jnp
-
-        cache_sh = None
-        self.kv_shard_axis = None
-        if self.mesh is not None:
-            cache_sh, self.kv_shard_axis = self._cache_sharding()
-        total = state_total = 0
-        for entry in self._cache_spec:
-            # one DISTINCT zero buffer per pool: the decode step and
-            # the prefill scatter donate all pools in one call, and XLA
-            # rejects donating the same buffer twice (device_put also
-            # allocates a fresh buffer per call)
-            n, shp = entry["name"], tuple(entry["shape"])
-            pages = entry["kind"] != "slot_state"
-            zeros = jnp.zeros(shp, jnp.float32)
-            # (slot state is replicated under a mesh: it is small, and
-            # its rows are slots, not heads)
-            pool = jax.device_put(zeros, cache_sh) \
-                if cache_sh is not None and pages else zeros.copy()
-            # the host dispatches faster than the device fills: without
-            # the wait every pool's ``zeros`` lies beside its copy until
-            # the device catches up, a second pool's worth of memory
-            # (1.6 GB at 48 slots x 2048 x 4 layers, my chip run, PR 32)
-            jax.block_until_ready(pool)
-            self.scope.set_var(n, pool)
-            if pages:
-                total += int(np.prod(shp)) * 4
-            else:
-                state_total += int(np.prod(shp)) * 4
-        # capacity actually ALLOCATED (the pools, trash pages included)
-        self.kv_cache_bytes = total
-        # ... and the per-slot state that is not pages (trash row included)
-        self.slot_state_bytes = state_total
-        telemetry.gauge_set("serving_slot_state_bytes", state_total)
-        # bytes one page costs across every layer's pools of its kind (a
-        # window page spans the window layers only; a latent layer has
-        # one pool of rows, not K and V of heads)
-        def page_bytes(*kinds):
-            return sum(int(np.prod(e["shape"][1:])) * 4
-                       for e in self._cache_spec if e["kind"] in kinds)
-
-        self.page_bytes = page_bytes("pages", "latent_pages")
-        self.window_page_bytes = page_bytes("window_pages")
-        telemetry.gauge_set("serving_kv_cache_bytes", total)
-        self._publish_pool_gauges()
-
-    def _publish_pool_gauges(self):
-        telemetry.gauge_set("serving_kv_pages_free",
-                            self._pool.free_pages)
-        telemetry.gauge_set("serving_kv_pages_live",
-                            self._pool.live_pages)
-        telemetry.gauge_set("serving_kv_live_bytes", self.kv_live_bytes)
-        if self._latent_layers:
-            telemetry.gauge_set("serving_latent_pages_live",
-                                self._pool.live_pages)
-        if self._wpool is not None:
-            telemetry.gauge_set("serving_kv_pages_live_full",
-                                self._pool.live_pages)
-            telemetry.gauge_set("serving_kv_pages_live_window",
-                                self._wpool.live_pages)
-
     @property
     def kv_live_bytes(self) -> int:
-        """Bytes of pool pages referenced by live sequences or the
-        prefix index right now."""
-        live = self._pool.live_pages * self.page_bytes
-        if self._wpool is not None:
-            live += self._wpool.live_pages * self.window_page_bytes
-        return live
+        return self.kv.kv_live_bytes
 
     def _fetch_names(self, fetches) -> List[str]:
         """The fetches every run of a program takes, warm-up included
@@ -1316,18 +932,11 @@ class GenerationEngine:
         """The chunk program's feeds for ``n`` real rows ``ids`` at
         ``base`` of ``slot``'s pages (None: a warm-up's, every write to
         the trash page)."""
-        def table(window=False):
-            return (np.zeros((self.pages_per_slot,), "int32") if slot is None
-                    else self._slot_block_table(slot, window))[None]
-
-        feed = {"chunk_ids": ids[None],
+        return {"chunk_ids": ids[None],
                 "base": np.asarray([base], "int32"),
-                "block_table": table(),
                 "chunk_len": np.asarray([n], "int32"),
-                "last_off": np.asarray([max(n - 1, 0)], "int64")}
-        if self._wpool is not None:
-            feed["block_table_window"] = table(window=True)
-        return feed
+                "last_off": np.asarray([max(n - 1, 0)], "int64"),
+                **self.kv.table_feeds(slot)}
 
     def _chunk_buckets(self) -> List[int]:
         """Prefill-bucket lengths the chunk program can be asked for:
@@ -1397,7 +1006,6 @@ class GenerationEngine:
     def _warm_programs(self) -> int:
         compiled = 0
         first = None    # the last prefill's first token, on the device
-        np_slot = self.pages_per_slot
         if self.role == "decode":
             # a decode-role engine never prefills: the decode step
             # (plus the verify program when speculating) is all it runs
@@ -1414,13 +1022,10 @@ class GenerationEngine:
                 with self._warming("prefill", b):
                     prog, fetches = self._prefill_prog_for(b)
                     feed = {"input_ids": np.zeros((1, b), "int64"),
-                            "block_table": np.zeros((1, np_slot), "int32"),
-                            "prompt_len": np.zeros((1,), "int32")}
+                            "prompt_len": np.zeros((1,), "int32"),
+                            **self.kv.table_feeds(None)}
                     if not self._blk:      # (a block prefill yields no row)
                         feed["last_pos"] = np.zeros((1,), "int64")
-                    if self._wpool is not None:
-                        feed["block_table_window"] = np.zeros(
-                            (1, np_slot), "int32")
                     if self.state_names:   # the trash row, no slot's state
                         feed["slot"] = np.asarray([self.num_slots], "int32")
                     first = self._run_fetching(
@@ -2219,28 +1824,22 @@ class GenerationEngine:
         # registered: a poisoned prompt sharing a cached prefix never
         # touches (or evicts) the pages other slots still reference
         self._poison_check(req.prompt)
-        if self._prefix is not None:
-            hit = self._prefix.lookup(req.prompt)
-            if hit:
-                self._pool.incref(hit)
-                self._mark_pages(slot)  # page hold starts here
-                slot.pages = list(hit)
-                slot.hit_tokens = len(hit) * self.page_tokens
-                req.note("prefix_hit", time.monotonic(),
-                         {"tokens": slot.hit_tokens})
-                self._count("prefix_hits")
-                stat_add("serving_prefix_hits")
-                if req.tenant is not None:
-                    usage.ledger().book(req.tenant, prefix_hits=1)
-                self._count("prefix_tokens_saved", slot.hit_tokens)
-                stat_add("serving_prefix_tokens_saved",
-                         slot.hit_tokens)
+        slot.hit_tokens = self.kv.map_prefix(slot, req.prompt)
+        if slot.hit_tokens:
+            req.note("prefix_hit", time.monotonic(),
+                     {"tokens": slot.hit_tokens})
+            self._count("prefix_hits")
+            stat_add("serving_prefix_hits")
+            if req.tenant is not None:
+                usage.ledger().book(req.tenant, prefix_hits=1)
+            self._count("prefix_tokens_saved", slot.hit_tokens)
+            stat_add("serving_prefix_tokens_saved", slot.hit_tokens)
         slot.prefill_pos = slot.hit_tokens
 
     def _adopt_begin(self, slot: _Slot, req: GenRequest):
         """Materialize an adopted segment into this pool: allocate the
         pages (refcounted; eviction/requeue semantics identical to a
-        local prefill via :meth:`_ensure_pages`), scatter the
+        local prefill via ``KVCache.ensure_pages``), scatter the
         segment's page blocks into them, replay the already-generated
         tokens, and enter the decode grid at the recorded position.
         Raises :class:`PoolExhausted` for the scheduler's requeue
@@ -2269,7 +1868,7 @@ class GenerationEngine:
                                   position=seg.position,
                                   pages=seg.n_pages,
                                   bytes=seg.nbytes, slot=slot.idx):
-            self._ensure_pages(slot, seg.position)  # may raise
+            self.kv.ensure_pages(slot, seg.position)  # may raise
             phys = jnp.asarray(
                 np.asarray(slot.pages[:seg.n_pages], "int32"))
             for i, (k_pages, v_pages) in enumerate(seg.layers):
@@ -2324,7 +1923,7 @@ class GenerationEngine:
                     req.on_token = None
         stream_writer.flush()
         req.t_last = now
-        self._publish_pool_gauges()
+        self.kv.publish_gauges()
         # a segment can arrive already finished (EOS at prefill, or a
         # budget the replay alone meets) — same precedence as
         # _book_token: eos > length > cache_full
@@ -2367,7 +1966,7 @@ class GenerationEngine:
                      len(others))
         self._end_seq_span(slot, "requeued")
         req.note("requeue", time.monotonic())
-        self._release_pages(slot)
+        self.kv.release_pages(slot)
         slot.req = None
         slot.decoding = False
         slot.logits = []
@@ -2383,7 +1982,7 @@ class GenerationEngine:
             usage.ledger().book(req.tenant, failures=1)
         logger.warning("%s failed: %s", phase, e)
         self._end_seq_span(slot, f"failed:{phase}")
-        self._release_pages(slot)
+        self.kv.release_pages(slot)
         blackbox.request_end(req.bb)
         req.future._resolve(error=RequestFailed(
             f"{phase} failed: {type(e).__name__}: {e}"))
@@ -2415,19 +2014,17 @@ class GenerationEngine:
             s.decoding = False
             if req.tenant is not None:
                 usage.ledger().book(req.tenant, failures=1)
-            self._release_pages(s)
+            self.kv.release_pages(s)
             blackbox.request_end(req.bb)
             req.future._resolve(error=err)
         self._sample_slot_track()
-        if self._prefix is not None:
-            # the crashed step donated the pool buffers, so every
-            # indexed page's K/V is as unknowable as the slots' —
-            # a later prefix hit must not serve possibly-corrupt rows
-            dropped = self._prefix.flush()
-            if dropped:
-                logger.warning("flushed %d prefix-index entries after "
-                               "decode-step failure", dropped)
-            self._publish_pool_gauges()
+        # the crashed step donated the pool buffers, so every indexed
+        # page's K/V is as unknowable as the slots' — a later prefix hit
+        # must not serve possibly-corrupt rows
+        dropped = self.kv.flush_prefix()
+        if dropped:
+            logger.warning("flushed %d prefix-index entries after "
+                           "decode-step failure", dropped)
 
     # -- prefill ------------------------------------------------------------
     def _poison_check(self, prompt: np.ndarray):
@@ -2477,147 +2074,7 @@ class GenerationEngine:
         self._usage_flops[-1] = fl
         return fl
 
-    # -- pages and prefill slices -------------------------------------------
-    def _mark_pages(self, slot: _Slot, now: Optional[float] = None):
-        """Advance the slot's KV page-second integral (µs × pages
-        held) up to ``now`` — called before EVERY block-table change
-        so the integral prices exactly what the pool saw.  One
-        attribute check and out when the slot carries no tenant
-        (usage off): the integration costs nothing then."""
-        if slot.page_tenant is None:
-            return
-        t = time.monotonic() if now is None else now
-        if slot.pages and slot.page_t:
-            slot.page_us += int((t - slot.page_t) * 1e6) * len(slot.pages)
-        slot.page_t = t
-
-    def _release_pages(self, slot: _Slot):
-        """Drop the slot's refs on its pages (shared prefix pages fall
-        back to the index's ref; private pages free) and refresh the
-        pool gauges.  Books the sequence's accumulated KV
-        page-seconds to its tenant — this is the single exit every
-        hold path (finish, fail, requeue, export, decode crash)
-        funnels through."""
-        if slot.pages or slot.wpages:
-            self._mark_pages(slot)
-            self._pool.decref(slot.pages)
-            if slot.wpages:
-                self._wpool.decref([p for p in slot.wpages if p])
-                slot.wpages = []
-            self._publish_pool_gauges()
-        if slot.page_tenant is not None:
-            if slot.page_us:
-                usage.ledger().book(slot.page_tenant,
-                                    page_us=slot.page_us)
-            slot.page_tenant = None
-        slot.page_us = 0
-        slot.page_t = 0.0
-        slot.pages = []
-        slot.hit_tokens = 0
-        slot.prefill_pos = 0
-
-    def _ensure_pages(self, slot: _Slot, n_tokens: int, rows: int = 1):
-        """Grow the slot's block table to cover ``n_tokens`` logical
-        tokens, evicting idle prefix-index pages when the free list
-        runs dry.  ``rows``: how many of them, the last ones, the next
-        program attends from (a chunk's; the window kind keeps what the
-        earliest of them admits).  Raises :class:`PoolExhausted` when
-        nothing is left to evict — the caller turns that into
-        ``cache_full`` (decode) or a failed request (prefill)."""
-        needed = -(-int(n_tokens) // self.page_tokens)  # ceil
-        if len(slot.pages) < needed:
-            self._mark_pages(slot)
-        while len(slot.pages) < needed:
-            p = self._pool.alloc()
-            if p is None:
-                if self._prefix is not None and self._prefix.evict_one():
-                    self._count("page_evictions")
-                    stat_add("serving_kv_page_evictions")
-                    continue
-                raise PoolExhausted(
-                    f"kv page pool exhausted ({self._pool.live_pages}"
-                    f"/{self.num_pages - 1} pages live, nothing "
-                    f"evictable)")
-            slot.pages.append(p)
-        if self._wpool is not None:
-            self._slide_window_pages(slot, int(n_tokens), needed, rows)
-        self._publish_pool_gauges()
-
-    def _slide_window_pages(self, slot: _Slot, n_tokens: int,
-                            needed: int, rows: int = 1):
-        """The window kind's half of :meth:`_ensure_pages`: the
-        earliest of the last ``rows`` of ``n_tokens`` positions attends
-        ``j >= n_tokens - rows + 1 - window``, so logical pages left of
-        that column's page are released (their entries become the trash
-        page 0) and pages up to ``needed`` are mapped from the window
-        pool.  A decoding slot so holds at most ``window / page_tokens
-        + 1`` window pages, a slot whose chunk of C rows runs at most
-        ``(window + C) / page_tokens + 1``, and a single-shot prefill
-        maps only the last window of a long prompt: rows of earlier
-        pages follow their zero entries to the trash page."""
-        first = max(0, n_tokens - rows + 1 - self.window) \
-            // self.page_tokens
-        wp = slot.wpages
-        gone = [p for p in wp[:first] if p]
-        if gone:
-            self._wpool.decref(gone)
-            wp[:first] = [0] * min(first, len(wp))
-            self._released_in_feeds += len(gone)
-            self._count("window_pages_released", len(gone))
-            stat_add("serving_kv_window_pages_released", len(gone))
-        while len(wp) < needed:
-            if len(wp) < first:
-                wp.append(0)
-                continue
-            p = self._wpool.alloc()
-            if p is None:
-                raise PoolExhausted(
-                    f"kv window page pool exhausted ("
-                    f"{self._wpool.live_pages}/"
-                    f"{self.num_window_pages - 1} pages live)")
-            wp.append(p)
-
-    def _slot_block_table(self, slot: _Slot,
-                          window: bool = False) -> np.ndarray:
-        pages = slot.wpages if window else slot.pages
-        bt = np.zeros((self.pages_per_slot,), "int32")
-        bt[:len(pages)] = pages
-        return bt
-
-    def _acquire_draft_pages(self, slot: _Slot, n_tokens: int) -> int:
-        """Provisionally grow the slot's block table to hold a draft's
-        verify rows.  Returns the page count to KEEP on rollback (the
-        pre-draft table length).  On exhaustion the partial growth is
-        rolled back HERE and :class:`PoolExhausted` re-raised — the
-        caller falls through to the plain one-token step with the
-        block table exactly as it found it."""
-        keep = len(slot.pages)
-        try:
-            self._ensure_pages(slot, n_tokens)
-        except PoolExhausted:
-            # _ensure_pages appends as it allocates: drop the partial
-            # growth so the draft leaks nothing
-            self._rollback_draft_pages(slot, keep)
-            raise
-        return keep
-
-    def _rollback_draft_pages(self, slot: _Slot, keep_pages: int) -> int:
-        """Drop the slot's refs on draft pages past ``keep_pages`` —
-        the accounting half of draft rejection.  The rejected rows'
-        K/V needs no device-side undo: rows past the committed
-        position are outside every later step's causal validity
-        window (``j <= base + t``) and the next real write at that
-        position overwrites them.  Pairs with
-        :meth:`_acquire_draft_pages` (graftcheck's resource-pairing
-        pass polices the pairing)."""
-        dropped = slot.pages[keep_pages:]
-        if dropped:
-            self._mark_pages(slot)
-            self._pool.decref(dropped)
-            del slot.pages[keep_pages:]
-            self._publish_pool_gauges()
-        return len(dropped)
-
+    # -- prefill slices -----------------------------------------------------
     def _prefill_advance(self, slot: _Slot):
         """One prefill slice for one slot: either the whole prompt
         through the full-prefill program (chunking off, no prefix
@@ -2639,18 +2096,15 @@ class GenerationEngine:
             with telemetry.trace_span("generation/prefill_prepare",
                                       parent=parent, slot=slot.idx,
                                       bucket=bucket):
-                self._ensure_pages(slot, n_rows)
+                self.kv.ensure_pages(slot, n_rows)
                 prog, fetches = self._prefill_prog_for(bucket)
                 feed = {"input_ids":
                         batcher.pad_prompt(prompt[:max(n_rows, 1)],
                                            bucket)[None],
-                        "block_table": self._slot_block_table(slot)[None],
-                        "prompt_len": np.asarray([n_rows], "int32")}
+                        "prompt_len": np.asarray([n_rows], "int32"),
+                        **self.kv.table_feeds(slot)}
                 if not self._blk:
                     feed["last_pos"] = np.asarray([n_prompt - 1], "int64")
-                if self._wpool is not None:
-                    feed["block_table_window"] = \
-                        self._slot_block_table(slot, window=True)[None]
                 state = {}
                 if self.state_names:
                     # the program overwrites the whole of this slot's
@@ -2694,32 +2148,31 @@ class GenerationEngine:
         with telemetry.trace_span("generation/prefill_prepare",
                                   parent=parent, slot=slot.idx,
                                   bucket=bucket):
-            in_feeds = self._released_in_feeds
-            had = self._wpool.live_pages if self._wpool is not None else 0
+            windowed = self.window is not None
+            released = self.kv.window_released
+            had = self.kv.live_pages("window") if windowed else 0
             # the window kind keeps what the chunk's FIRST row admits
-            self._ensure_pages(slot, start + n, rows=n)
+            self.kv.ensure_pages(slot, start + n, rows=n)
             prog, fetches = self._chunk_prog_for(bucket)
             chunk = np.zeros((bucket,), "int64")
             chunk[:n] = prompt[start:start + n]
             feed = self._chunk_feed(chunk, start, n, slot)
-            if self._wpool is not None:
+            if windowed:
                 held = sum(1 for p in slot.wpages if p)
-                mapped = self._wpool.live_pages - had \
-                    + self._released_in_feeds - in_feeds
+                mapped = self.kv.live_pages("window") - had \
+                    + self.kv.window_released - released
                 # programs run in the order sent, and the feed names the
                 # pages this chunk reads: what the NEXT rows (the next
                 # chunk's, or the first decode step's, at ``start + n``)
                 # no longer admit goes back to the pool now, so that
                 # only the slot whose chunk runs holds more than a
                 # window's pages
-                self._slide_window_pages(slot, start + n + 1, 0)
-                gone = self._released_in_feeds - in_feeds
-                # (a decode step's span counts what ITS feeds let go)
-                self._released_in_feeds = in_feeds
+                self.kv.slide_window_pages(slot, start + n + 1, 0)
+                gone = self.kv.window_released - released
                 self._count("window_pages_released_in_prefill", gone)
                 stat_add("serving_kv_window_pages_released_in_prefill",
                          gone)
-                self._publish_pool_gauges()
+                self.kv.publish_gauges()
                 window = {"window_pages_held": held,
                           "window_pages_mapped": mapped,
                           "window_pages_released": gone}
@@ -2931,11 +2384,7 @@ class GenerationEngine:
         stat_add("serving_prefill_tokens", n_prompt - slot.hit_tokens)
         if req.tenant is not None:
             usage.ledger().book(req.tenant, prefill_steps=1)
-        if self._prefix is not None:
-            full = n_prompt // self.page_tokens
-            if full:
-                self._prefix.register(req.prompt, slot.pages[:full])
-                self._publish_pool_gauges()
+        self.kv.register_prefix(slot, req.prompt)
         if self._blk:
             # (its first tokens come with the first block's commit pass)
             return
@@ -3047,7 +2496,7 @@ class GenerationEngine:
         slot.req = None
         slot.decoding = False
         slot.logits = []
-        self._release_pages(slot)
+        self.kv.release_pages(slot)
         self._sample_slot_track()
         blackbox.request_end(req.bb)
         req.future._resolve(outputs=result)
@@ -3096,7 +2545,7 @@ class GenerationEngine:
                 else np.zeros((self.num_slots,), "int32")}
         if self._blk:
             feed.update(block)
-        if self._wpool is not None:
+        if self.window is not None:
             feed["block_tables_window"] = block_tables_window \
                 if block_tables_window is not None \
                 else np.zeros(empty, "int32")
@@ -3155,7 +2604,7 @@ class GenerationEngine:
                     "injected decode_step failure (spec verify)")
             c = len(draft) + 1  # [pending, draft...]
             try:
-                keep = self._acquire_draft_pages(
+                keep = self.kv.acquire_draft_pages(
                     slot, slot.position + c)
             except PoolExhausted:
                 # transient: live sequences will free pages; the slot
@@ -3174,7 +2623,7 @@ class GenerationEngine:
             names = ["tokens", "logits"] if self.keep_logits else ["tokens"]
             feed = {"chunk_ids": chunk[None],
                     "base": np.asarray([slot.position], "int32"),
-                    "block_table": self._slot_block_table(slot)[None],
+                    "block_table": self.kv.block_table(slot)[None],
                     "chunk_len": np.asarray([c], "int32")}
             outs = self._launch(
                 "generation/spec_verify", lambda: dict(zip(
@@ -3221,7 +2670,7 @@ class GenerationEngine:
                     break  # finished mid-burst (_finish freed pages)
             stream_writer.flush()
             if slot.req is not None:
-                self._rollback_draft_pages(
+                self.kv.rollback_draft_pages(
                     slot, max(keep,
                               -(-slot.position // self.page_tokens)))
             served.add(slot.idx)
@@ -3344,14 +2793,14 @@ class GenerationEngine:
             attrs.update(self._book_experts(
                 outs["expert_counts"], len(fl.riders) * self._rows,
                 self.num_slots * self._rows, outs.get("expert_group_rows")))
-        if self._wpool is not None:
+        if self.window is not None:
             # the pages this step's feeds let go, what both kinds hold
             # now, the positions its rows attended
             rows = [s for s, r in fl.riders if s.req is r]
             attrs.update(
                 window_pages_released=fl.released,
-                pages_live_full=self._pool.live_pages,
-                pages_live_window=self._wpool.live_pages,
+                pages_live_full=self.kv.live_pages(),
+                pages_live_window=self.kv.live_pages("window"),
                 live_positions=int(sum(s.position + 1 for s in rows)),
                 live_positions_window=int(sum(
                     min(s.position + 1, self.window) for s in rows)))
@@ -3481,7 +2930,7 @@ class GenerationEngine:
                       len(s.tokens) + 1 >= s.req.max_new_tokens
                       or s.position + (s is not joiner)
                       >= self.max_seq_len))]
-        self._released_in_feeds = 0
+        released = self.kv.window_released
         # pool-exhaustion guard: a slot about to cross into an
         # unmapped page must get one BEFORE the step (the write
         # would land on the trash page and corrupt nothing, but
@@ -3491,12 +2940,13 @@ class GenerationEngine:
         # a settle it has more to come: the caller settles first)
         for s in riding:
             try:
-                self._ensure_pages(
+                self.kv.ensure_pages(
                     s, s.position + ahead * (s is not joiner) + 1)
             except PoolExhausted:
                 if ahead:
                     raise
                 self._finish(s, "cache_full")
+        self._released_in_feeds = self.kv.window_released - released
         active = [s for s in riding if s.req is not None]
         if not active:
             return active, None
@@ -3516,12 +2966,12 @@ class GenerationEngine:
             tokens = self._host_tokens(last)
         bt = np.zeros((self.num_slots, self.pages_per_slot), "int32")
         live = np.zeros((self.num_slots,), "int32")
-        btw = np.zeros_like(bt) if self._wpool is not None else None
+        btw = np.zeros_like(bt) if self.window is not None else None
         for s in active:
-            bt[s.idx] = self._slot_block_table(s)
+            bt[s.idx] = self.kv.block_table(s)
             live[s.idx] = 1
             if btw is not None:
-                btw[s.idx] = self._slot_block_table(s, window=True)
+                btw[s.idx] = self.kv.block_table(s, window=True)
         return active, (tokens, positions, bt, live, btw)
 
     # -- block diffusion: a slot's step is a pass over a block -------------
@@ -3585,7 +3035,7 @@ class GenerationEngine:
             base, left, done, new = self._block_phase(
                 s, ahead * (s is not joiner))
             try:
-                self._ensure_pages(s, base + B)
+                self.kv.ensure_pages(s, base + B)
             except PoolExhausted:
                 if ahead:
                     raise
@@ -3593,7 +3043,7 @@ class GenerationEngine:
                 continue
             positions[s.idx], fresh[s.idx], live[s.idx] = base, new, 1
             quota[s.idx] = self._block_quota(left, done)
-            bt[s.idx] = self._slot_block_table(s)
+            bt[s.idx] = self.kv.block_table(s)
         active = [s for s in riding if s.req is not None]
         if not active:
             return active, None
@@ -3808,7 +3258,7 @@ class GenerationEngine:
         self._end_seq_span(slot, finish)
         slot.req = None
         slot.decoding = False
-        self._release_pages(slot)
+        self.kv.release_pages(slot)
         self._sample_slot_track()
         blackbox.request_end(req.bb)
         req.future._resolve(outputs=result)
@@ -3961,25 +3411,24 @@ class GenerationEngine:
                 "page_tokens": self.page_tokens,
                 "num_pages": self.num_pages,
                 "pages_per_slot": self.pages_per_slot,
-                "pages_free": self._pool.free_pages,
-                "pages_live": self._pool.live_pages,
+                "pages_free": self.kv.free_pages(),
+                "pages_live": self.kv.live_pages(),
                 "page_bytes": self.page_bytes,
                 "latent_layers": len(self._latent_layers),
-                "window": None if self._wpool is None else {
+                "window": None if self.window is None else {
                     "window": self.window,
                     "num_pages": self.num_window_pages,
                     "pages_per_slot": self.window_pages_per_slot,
-                    "pages_free": self._wpool.free_pages,
-                    "pages_live": self._wpool.live_pages,
-                    "page_bytes": self.window_page_bytes,
+                    "pages_free": self.kv.free_pages("window"),
+                    "pages_live": self.kv.live_pages("window"),
+                    "page_bytes": self.kv.window_page_bytes,
                     "pages_released": n["window_pages_released"],
                     "pages_released_in_prefill":
                         n["window_pages_released_in_prefill"],
                 },
                 "prefill_chunk": self.prefill_chunk,
                 "prefix_reuse": self.prefix_reuse,
-                "prefix_index_entries":
-                    len(self._prefix) if self._prefix else 0,
+                "prefix_index_entries": self.kv.prefix_entries,
                 "prefix_hit_rate": round(
                     n["prefix_hits"] / max(n["prefills"], 1), 4),
             },
@@ -3996,7 +3445,7 @@ class GenerationEngine:
             },
             "mesh": None if self.mesh is None
             else _describe_mesh(self.mesh),
-            "kv_shard_axis": getattr(self, "kv_shard_axis", None),
+            "kv_shard_axis": self.kv.kv_shard_axis,
             "draining": draining,
             "weights_version": self.weights_version,
             "counters": n,

@@ -610,7 +610,7 @@ def test_budget_cuts_the_last_block(served, n_prompt, n_new):
     stamps = [ts for _, ts in streamed]
     assert len(set(stamps[:first_block])) == 1
     assert res["ttft_ms"] is not None
-    assert eng._pool.live_pages == 0
+    assert eng.kv.live_pages() == 0
 
 
 def test_a_prompt_may_hold_the_mask_id(served):
@@ -673,7 +673,7 @@ def test_slots_join_and_leave_in_the_middle_of_others_blocks():
         # one pass in flight: most grid steps went out ahead of a settle
         assert n["decode_steps_ahead"] >= n["decode_steps"] // 2
         assert n["decode_rows_discarded"] == 0   # no EOS: the host knows
-        assert eng._pool.live_pages == 0 and eng._inflight is None
+        assert eng.kv.live_pages() == 0 and eng._inflight is None
         if telemetry.enabled():
             steps = [s for s in telemetry.get_spans()
                      if s.name == "generation/decode_step"
@@ -721,7 +721,7 @@ def test_a_joiners_first_block_rides_the_pass_ahead(passes):
         assert n["decode_joiners_ahead"] == 2
         assert n["decode_steps_ahead"] == n["decode_steps"] - 1
         assert n["decode_rows_discarded"] == 0
-        assert eng._pool.live_pages == 0
+        assert eng.kv.live_pages() == 0
         assert eng._decode_exe.cache_info()["compiled"] == 1
     finally:
         eng.close()
@@ -788,7 +788,7 @@ def test_eos_inside_a_block_ends_the_sequence_and_discards_the_row_ahead():
         n = _counters(eng)
         assert n["generated_tokens"] == cut + 1
         assert n["decode_rows_discarded"] == 1
-        assert eng._pool.live_pages == 0
+        assert eng.kv.live_pages() == 0
     finally:
         eng.close()
 
@@ -802,7 +802,7 @@ def test_a_denoising_passs_kv_is_gone_after_its_blocks_commit():
     eng = _engine(cfg, num_slots=1, keep_logits=False)
     try:
         kept = {}
-        release = eng._release_pages
+        release = eng.kv.release_pages
 
         def spy(slot):
             if slot.pages:
@@ -811,7 +811,7 @@ def test_a_denoising_passs_kv_is_gone_after_its_blocks_commit():
                     f"llama.pool_k_{i}")).copy() for i in range(2)]
             release(slot)
 
-        eng._release_pages = spy
+        eng.kv.release_pages = spy
         prompt = _prompt(9, 10)
         res = eng.generate(prompt, 14, timeout=300)     # ends at 24
         first = dict(kept)

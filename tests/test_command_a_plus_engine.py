@@ -89,7 +89,7 @@ def _spied(eng):
             sent.append({"slot": slot.idx, "base": base, "n": n,
                          "window": feed["block_table_window"][0].copy(),
                          "full": feed["block_table"][0].copy(),
-                         "live": eng._wpool.live_pages})
+                         "live": eng.kv.live_pages("window")})
         return feed
 
     eng._chunk_feed = spy

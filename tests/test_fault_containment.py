@@ -491,16 +491,22 @@ def test_deadline_bound_timeout_is_a_shed_not_a_replica_strike():
 
 def test_router_forward_timeout_retries_once_on_alternate():
     """With an alternate replica, a timed-out forward retries there
-    (inference is idempotent) and the client still gets 200."""
-    hang_httpd, _hang_handler, hang_url = _capture_replica(sleep_s=3.0)
+    (inference is idempotent) and the client still gets 200.  The
+    timeout is an order above a warmed replica's latency under the
+    suite's load and the hung replica sleeps well past it, so what is
+    counted does not depend on how busy the machine is."""
+    hang_httpd, _hang_handler, hang_url = _capture_replica(sleep_s=10.0)
     p = _build_mlp(feat=6, seed=18)
     eng = ServingEngine(p, workers=1, max_batch=4, max_delay_ms=1.0,
                         deadline_ms=60000)
     good_srv = serve(eng)
     router = Router([hang_url, good_srv.url], autostart=False,
-                    forward_timeout_ms=250.0)
+                    forward_timeout_ms=2000.0)
     server = RouterServer(router).start()
     try:
+        # the good replica compiles its program now, not inside the
+        # routed request's forward timeout
+        assert _post_raw(good_srv.url)[0] == 200
         router.poll_once()
         # bias placement to the hung replica (load 0 vs 5)
         router._replicas[good_srv.url].health["serving"][

@@ -40,7 +40,6 @@ from conftest import assert_logits_match, uncached_logits
 import paddle_tpu as pt
 from paddle_tpu import layers
 from paddle_tpu.serving import GenerationEngine, batcher
-from paddle_tpu.serving.generation import PagePool, PrefixIndex
 
 # GQA config (kv_heads < heads) so the paged gather runs under cache
 # expansion, matching tests/test_generation.py
@@ -79,9 +78,8 @@ def paged_ref(plain_ref):
 
 
 def _drain_index(eng):
-    while eng._prefix is not None and eng._prefix.evict_one():
-        pass
-    assert eng._pool.live_pages == 0
+    eng.kv.flush_prefix()
+    assert eng.kv.live_pages() == 0
 
 
 # ---------------------------------------------------------------------------
@@ -280,54 +278,6 @@ def test_chunk_spans():
 
 
 # ---------------------------------------------------------------------------
-# allocator / prefix index units
-# ---------------------------------------------------------------------------
-
-def test_page_pool_refcounts():
-    pool = PagePool(5)  # pages 1..4 usable
-    a, b = pool.alloc(), pool.alloc()
-    assert {a, b} == {1, 2} and pool.free_pages == 2
-    pool.incref([a])          # a shared (slot + index)
-    pool.decref([a, b])       # slot releases both
-    assert pool.free_pages == 3 and pool.refcount(a) == 1
-    pool.decref([a])          # index releases a
-    assert pool.free_pages == 4 and pool.live_pages == 0
-    assert pool.alloc() is not None
-    with pytest.raises(ValueError):
-        PagePool(1)           # no room beyond the trash page
-
-
-def test_prefix_index_lookup_register_evict():
-    pool = PagePool(8)
-    idx = PrefixIndex(pool, 4)
-    prompt = np.arange(1, 11, dtype="int64")     # 10 tokens, 2 full pages
-    p0, p1 = pool.alloc(), pool.alloc()
-    idx.register(prompt, [p0, p1])
-    assert pool.refcount(p0) == 2 and pool.refcount(p1) == 2
-    # exact-prefix hit; a diverging prompt misses
-    assert idx.lookup(np.arange(1, 14, dtype="int64")) == [p0, p1]
-    other = np.arange(1, 14, dtype="int64")
-    other[2] = 55
-    assert idx.lookup(other) == []
-    # a prompt equal to one indexed page must leave >= 1 token to
-    # prefill: only page 0 may be served for a 5-token prompt, and
-    # NOTHING for a 4-token prompt
-    assert idx.lookup(np.arange(1, 6, dtype="int64")) == [p0]
-    assert idx.lookup(np.arange(1, 5, dtype="int64")) == []
-    pool.decref([p0, p1])     # the registering slot finishes
-    assert pool.free_pages == 5  # 7 usable; index still holds p0, p1
-    assert idx.evict_one() and pool.free_pages == 6
-    assert idx.evict_one() and pool.free_pages == 7
-    assert not idx.evict_one()
-    # flush: the decode-crash integrity valve drops every entry
-    q0, q1 = pool.alloc(), pool.alloc()
-    idx.register(prompt, [q0, q1])
-    pool.decref([q0, q1])
-    assert idx.flush() == 2 and len(idx) == 0
-    assert pool.free_pages == 7 and pool.live_pages == 0
-
-
-# ---------------------------------------------------------------------------
 # bit-exactness: paged == the uncached forward, across page boundaries
 # ---------------------------------------------------------------------------
 
@@ -385,7 +335,7 @@ def test_prefix_reuse_cow_isolation(plain_ref, paged_ref):
     # shared-page bytes before the borrowers run
     idx_pages = sorted(
         p for p in range(1, eng.num_pages)
-        if eng._pool.refcount(p) > 0)
+        if eng.kv.refcount(p) > 0)
     assert len(idx_pages) == 2
     pool_k0 = np.asarray(eng.scope.find_var("llama.pool_k_0"))
     shared_before = pool_k0[idx_pages].copy()
@@ -505,7 +455,7 @@ def test_pool_exhaustion_cache_full(plain_ref):
         assert res["finish"] == "cache_full"
         assert len(res["tokens"]) == capacity - len(prompt) + 1
         # pool drained and fully recovered
-        assert eng._pool.live_pages == 0
+        assert eng.kv.live_pages() == 0
         res2 = eng.generate(prompt, 500)
         assert res2["finish"] == "cache_full"
         assert res2["tokens"] == res["tokens"]

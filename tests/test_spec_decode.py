@@ -141,7 +141,7 @@ def test_spec_bitexact_concurrent_ragged(plain_ref):
         sp = eng.stats()["speculate"]
         assert sp["drafts"] > 0 and sp["tokens_proposed"] > 0
         assert sp["tokens_accepted"] <= sp["tokens_proposed"]
-        assert eng._pool.live_pages == 0
+        assert eng.kv.live_pages() == 0
     finally:
         eng.close()
 
@@ -216,7 +216,7 @@ def test_spec_rollback_refcount_balance(plain_ref):
         assert sp["rollbacks"] <= sp["drafts"]
         assert sp["tokens_accepted"] <= sp["tokens_proposed"]
         assert 0.0 <= sp["acceptance_rate"] <= 1.0
-        assert eng._pool.live_pages == 0
+        assert eng.kv.live_pages() == 0
     finally:
         eng.close()
 
@@ -239,7 +239,7 @@ def test_spec_pool_exhaustion_mid_draft(plain_ref):
             rng = np.random.RandomState(23)
             prompt = _repetitive(rng, 10)
             res = eng.generate(prompt, 500)
-            live = eng._pool.live_pages
+            live = eng.kv.live_pages()
             res2 = eng.generate(prompt, 500)
             sp = eng.stats()["speculate"]
             return res, live, res2, sp

@@ -409,20 +409,17 @@ def test_window_pool_holds_a_window_and_reuses_released_pages():
     eng = _engine(num_slots=2, keep_logits=False, autostart=False,
                   num_window_pages=2 * per_slot + 1)
     peak, handed = [], []
-    grow, alloc = eng._ensure_pages, eng._wpool.alloc
+    grow = eng.kv.ensure_pages
 
     def watched(slot, n_tokens):
+        had = list(slot.wpages)
         grow(slot, n_tokens)
+        # (the sliding table grows at its end; 0: a page never mapped)
+        handed.extend(p for p in slot.wpages[len(had):] if p)
         peak.append(sum(1 for p in slot.wpages if p))
-        assert eng._wpool.live_pages <= 2 * per_slot
+        assert eng.kv.live_pages("window") <= 2 * per_slot
 
-    def counted():
-        page = alloc()
-        handed.append(page)
-        return page
-
-    eng._ensure_pages = watched
-    eng._wpool.alloc = counted
+    eng.kv.ensure_pages = watched
     eng.start()
     try:
         rng = np.random.default_rng(3)
@@ -439,7 +436,7 @@ def test_window_pool_holds_a_window_and_reuses_released_pages():
     # its to hold) and then 4-6: three slid out
     assert st["paged"]["window"]["pages_released"] == 7
     # 16 pages handed out of a pool of 6: the released ones served again
-    assert len(handed) == 16 and None not in handed
+    assert len(handed) == 16
     assert len(set(handed)) <= 2 * per_slot
     assert st["counters"]["pool_stalls"] == 0
 
@@ -481,7 +478,7 @@ def test_one_kind_model_keeps_one_pool():
     model = dict(MODEL, layer_pattern=[{"rope": False, "ffn": MOE}])
     eng = _engine(model, autostart=False)
     try:
-        assert eng._wpool is None and eng.num_window_pages == 0
+        assert eng.kv.window is None and eng.num_window_pages == 0
         assert eng._decode_feeds == ["tokens", "positions", "block_tables",
                                      "live"]
         assert eng.page_bytes == 8 * 2 * PAGE * 32 * 4

@@ -834,7 +834,7 @@ def _scenario_spec_storm(cfg: dict) -> dict:
             if now_live == live:
                 break
             live = now_live
-        eng._prefix.flush()
+        eng.kv.flush_prefix()
         leaked = eng.stats()["paged"]["pages_live"]
         notes["leaked_pages"] = leaked
         if res["tokens"] != want[last]:

@@ -30,11 +30,13 @@ Three rules:
 
 ``pair-draft``
     Speculative-decode draft-page discipline: a function that calls
-    ``_acquire_draft_pages`` (provisional KV pages for an unverified
-    draft) must also call ``_rollback_draft_pages`` or
-    ``_release_pages`` in the same function — a rejected draft whose
-    pages are never rolled back (or a fault path that skips the
-    slot-release) strands refcounts the pool can only leak.
+    ``acquire_draft_pages`` (``serving/kv_cache.py`` ``KVCache``:
+    provisional KV pages for an unverified draft) must also call
+    ``rollback_draft_pages`` or ``release_pages`` in the same function —
+    a rejected draft whose pages are never rolled back (or a fault path
+    that skips the slot-release) strands refcounts the pool can only
+    leak.  A leading underscore on any of the three names is the same
+    name.
 """
 from __future__ import annotations
 
@@ -155,7 +157,7 @@ def run(files: List[SourceFile]) -> List[Violation]:
         has_span = "span_begin" in sf.text
         has_acq = ".acquire(" in sf.text
         has_ref = "incref" in sf.text or ".alloc(" in sf.text
-        has_draft = "_acquire_draft_pages" in sf.text
+        has_draft = "acquire_draft_pages" in sf.text
         if not (has_span or has_acq or has_ref or has_draft):
             continue
         for qn, fn in _functions(sf):
@@ -250,9 +252,9 @@ def _check_acquires(sf: SourceFile, qn: str, fn: ast.AST) -> List[Violation]:
 
 def _check_draft_pages(sf: SourceFile, qn: str,
                        fn: ast.AST) -> List[Violation]:
-    """A caller of _acquire_draft_pages holds provisional page refs
-    for a draft that may be rejected; without a _rollback_draft_pages
-    (or a whole-slot _release_pages) in the same function there is no
+    """A caller of acquire_draft_pages holds provisional page refs
+    for a draft that may be rejected; without a rollback_draft_pages
+    (or a whole-slot release_pages) in the same function there is no
     path that gives the rejected rows' pages back."""
     out: List[Violation] = []
     acquire_line = None
@@ -260,19 +262,19 @@ def _check_draft_pages(sf: SourceFile, qn: str,
     for n in _own_nodes(fn):
         if not isinstance(n, ast.Call):
             continue
-        name = _func_name(n)
-        if name == "_acquire_draft_pages":
+        name = (_func_name(n) or "").lstrip("_")
+        if name == "acquire_draft_pages":
             acquire_line = acquire_line or n.lineno
-        elif name in ("_rollback_draft_pages", "_release_pages"):
+        elif name in ("rollback_draft_pages", "release_pages"):
             has_rollback = True
     if acquire_line is not None and not has_rollback \
-            and getattr(fn, "name", "") != "_acquire_draft_pages":
+            and getattr(fn, "name", "").lstrip("_") != "acquire_draft_pages":
         # the acquire helper itself rolls back internally on the
         # exhaustion path; every OTHER caller owes an explicit pair
         out.append(Violation(
             "pair-draft", sf.path, acquire_line, f"{qn}:draft-pages",
-            "_acquire_draft_pages() without _rollback_draft_pages() "
-            "or _release_pages() in this function — rejected-draft "
+            "acquire_draft_pages() without rollback_draft_pages() "
+            "or release_pages() in this function — rejected-draft "
             "pages have no give-back path and leak refcounts"))
     return out
 
