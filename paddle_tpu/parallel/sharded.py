@@ -25,6 +25,7 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from .. import program_store as _program_store
 from .. import telemetry as _telemetry
 from ..compile_cache import ensure_compile_cache
 from ..framework.core import Block, Program, Variable
@@ -139,10 +140,43 @@ def overlap_compiler_options(mesh, batch_axes: Sequence[str]):
     return dict(_OVERLAP_OPTIONS), None
 
 
-def _jit_step(step_fn, mesh, batch_axes, **jit_kwargs):
-    """``jax.jit(step_fn, ...)``, with ``overlap_compiler_options`` where
-    the mesh has a gradient reduction to hide and exactly as without them
-    everywhere else."""
+class _Step:
+    """What a step builder returns: the step's ``jit`` (``jitted``), called
+    as it is, whose ``.lower(*args)`` goes through the program store
+    (``program_store.py``) where one is placed: a warm process loads the
+    step's lowered module and neither traces the Program nor lowers its
+    kernels, and ``.lower(*args).compile()`` is the executable the ``jit``
+    itself compiles to.  With no store, or a Program that has no key
+    (``digest`` None), ``.lower`` is ``jitted.lower``.  Any other
+    attribute is the ``jit``'s."""
+
+    def __init__(self, jitted, digest, mesh, donate_argnums, jit_kwargs):
+        self.jitted, self.digest, self.mesh = jitted, digest, mesh
+        self.donate_argnums, self.jit_kwargs = donate_argnums, jit_kwargs
+
+    def __call__(self, *args):
+        return self.jitted(*args)
+
+    def lower(self, *args):
+        if self.digest is None:
+            return self.jitted.lower(*args)
+        return _program_store.stored_step(
+            self.jitted, args, self.digest, self.donate_argnums,
+            mesh=self.mesh, **self.jit_kwargs).lower(*args)
+
+    def __getattr__(self, name):
+        if name == "jitted":    # (an instance ``copy`` has not filled yet)
+            raise AttributeError(name)
+        return getattr(self.jitted, name)
+
+
+def _jit_step(step_fn, mesh, batch_axes, keyed, donate_argnums,
+              **jit_kwargs):
+    """``jax.jit(step_fn, ...)`` as a ``_Step``, with
+    ``overlap_compiler_options`` where the mesh has a gradient reduction to
+    hide and exactly as without them everywhere else.  ``keyed`` is
+    ``(program, feed names, fetch names, what the step does with them)``,
+    the Program's half of the step's key in the program store."""
     import jax
 
     options, reason = overlap_compiler_options(mesh, batch_axes)
@@ -152,8 +186,14 @@ def _jit_step(step_fn, mesh, batch_axes, **jit_kwargs):
             _overlap_logged.add(reason)
             logger.info("sharded step compiled without collective overlap "
                         "options: %s", reason)
-        return jax.jit(step_fn, **jit_kwargs)
-    return jax.jit(step_fn, compiler_options=options, **jit_kwargs)
+    else:
+        jit_kwargs["compiler_options"] = options
+    program, feed_names, fetch_names, what = keyed
+    digest = _program_store.program_digest(program, feed_names, fetch_names,
+                                           None)
+    return _Step(
+        jax.jit(step_fn, donate_argnums=donate_argnums, **jit_kwargs),
+        digest and f"{digest} {what}", mesh, donate_argnums, jit_kwargs)
 
 
 def build_sharded_step(program: Program, feed_names: Sequence[str],
@@ -232,9 +272,10 @@ def _build_sharded_step(program, feed_names, fetch_names, mesh, rules,
     # returned arrays can be threaded straight back in (donation-safe).
     fn = _jit_step(
         step_fn, mesh, batch_axes,
+        (program, feed_names, fetch_names, "sharded step"),
+        donate_argnums=(1,) if donate_state else (),
         in_shardings=(feed_sh, mut_sh, const_sh, step_sh),
         out_shardings=(fetch_sh, mut_sh, extra_sh),
-        donate_argnums=(1,) if donate_state else (),
     )
     return fn, mut_in, const_in, extra_out
 
@@ -309,10 +350,11 @@ def build_sharded_multistep(program: Program, feed_names: Sequence[str],
 
     fn = _jit_step(
         multi_fn, mesh, batch_axes,
+        (program, feed_names, fetch_names,
+         f"sharded multistep of {num_steps}"),
+        donate_argnums=(1,) if donate_state else (),
         in_shardings=(feed_sh, mut_sh, const_sh, step_sh),
         out_shardings=(fetch_sh, mut_sh, extra_sh),
-        donate_argnums=(1,) if donate_state else (),
-        static_argnames=(),
     )
     return fn, mut_in, const_in, extra_out
 

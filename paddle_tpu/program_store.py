@@ -43,6 +43,14 @@ values, and compared on load; a run that sets a flag no lowering reads
 still hits.  A load that fails (a truncated file, a version refused, a
 flag that differs) is a miss that overwrites.
 
+**A step over a mesh** (``parallel/sharded.py``: what a step builder
+returns answers ``.lower(*args)`` from here) hands :func:`stored_step` its
+mesh and what its ``jit`` was given.  Its key also holds the mesh's axis
+names and shape, its devices' kind, every argument's and result's
+``PartitionSpec`` and the compiler options; its module is kept for the
+mesh's devices (``Exported.nr_devices``), and the stored call's ``jit``
+gets the shardings, donation and compiler options the step's own did.
+
 **What a trace does besides making the module** is stored beside it and
 done again on a hit: the stats a lowering books (``attention_lowered_*``,
 ``kv_pool_write_pages``, ...; ``watch.py``) are added, the
@@ -51,7 +59,9 @@ a process.
 
 **Refused**, each counted (``program_store_refused``) and logged once with
 its reason, falling to ``jitted.lower(*args).compile()``: an argument
-that lives on more than one device (a step under a mesh), a module with
+that lives on more than one device where no mesh was handed in (an
+``Executor``'s step over state placed on a mesh), a kept module for
+another number of devices than the mesh has, a module with
 effects or host callbacks (``jax.export`` serialises neither), a Program
 that has no key (attributes that are not JSON, a lowering from outside the
 package without a source file), and ``FLAGS_check_nan_inf`` runs, which
@@ -59,9 +69,9 @@ make no module.
 
 Stats ``program_store_hits`` / ``_misses`` / ``_refused``; one
 ``compile/program_store`` span a program in the start-up account (``hit``,
-``bytes``, ``load_ms``, and the ``program`` / ``kind`` / ``bucket`` of the
-spans it is under), whose self time leaves out the trace and lowering a
-miss holds.
+``bytes``, ``load_ms``, ``devices``, and the ``program`` / ``kind`` /
+``bucket`` of the spans it is under), whose self time leaves out the trace
+and lowering a miss holds.
 """
 from __future__ import annotations
 
@@ -214,7 +224,7 @@ def program_digest(program, feed_names, fetch_names,
     return h.hexdigest()
 
 
-def _key(digest: str, args, donate_argnums) -> str:
+def _key(digest: str, args, donate_argnums, placement=None) -> str:
     import jax
 
     leaves, tree = jax.tree_util.tree_flatten(args)
@@ -222,8 +232,25 @@ def _key(digest: str, args, donate_argnums) -> str:
              "avals": [repr(jax.typeof(leaf)) for leaf in leaves],
              "donate": list(donate_argnums),
              "source": _source_digest(), "versions": _versions()}
-    return hashlib.sha256(
-        json.dumps(parts, sort_keys=True).encode()).hexdigest()
+    if placement is not None:   # (a step under a mesh: ``_placement``)
+        parts["placement"] = placement
+    return hashlib.sha256(json.dumps(
+        parts, sort_keys=True, default=str).encode()).hexdigest()
+
+
+def _placement(mesh, jit_kwargs) -> dict:
+    """What a step built over ``mesh`` adds to the key: the mesh's axes and
+    shape and its devices' kind (they need not be ``jax.devices()``: a
+    described topology), and everything the step's ``jit`` was given
+    besides the function, a sharding as its ``PartitionSpec``."""
+    import jax
+
+    device = mesh.devices.flat[0]
+    return {"axes": mesh.axis_names, "shape": mesh.devices.shape,
+            "device": [device.platform, device.device_kind],
+            "jit": jax.tree_util.tree_map(
+                lambda x: str(x.spec) if hasattr(x, "spec") else x,
+                jit_kwargs)}
 
 
 def _on_many_devices(args) -> bool:
@@ -248,10 +275,22 @@ class _Warnings(logging.Handler):
                                record.getMessage()])
 
 
-def _load(path: str):
+def _keepable(exported, devices: int):
+    """``exported``, or ValueError where it is no module the store keeps
+    for a step over ``devices`` devices."""
+    if exported.nr_devices != devices:
+        raise ValueError(f"a module for {exported.nr_devices} devices, "
+                         f"where the step runs on {devices}")
+    if exported.ordered_effects or exported.unordered_effects:
+        raise ValueError("a module with effects")
+    return exported
+
+
+def _load(path: str, devices: int):
     """``(exported, meta)`` of the entry at ``path``, or None: no file, a
     file cut short or changed, a flag the filling trace read that reads
-    otherwise now, bytes this jax refuses."""
+    otherwise now, bytes this jax refuses.  ValueError where the module
+    loads and is for another number of devices than ``devices``."""
     from jax import export
 
     try:
@@ -268,10 +307,11 @@ def _load(path: str):
         for name, value in meta["flags"].items():
             if _flags.flag_value(name) != value:
                 return None
-        return export.deserialize(bytearray(blob)), meta
+        exported = export.deserialize(bytearray(blob))
     except Exception as e:  # noqa: BLE001 — whatever is wrong, it is a miss
         logger.debug("program store: %s does not load: %r", path, e)
         return None
+    return _keepable(exported, devices), meta
 
 
 def export_step(jitted, args, platforms=None):
@@ -294,7 +334,7 @@ def export_step(jitted, args, platforms=None):
             jax.config.update(option, old)
 
 
-def _fill(jitted, args, path: str):
+def _fill(jitted, args, path: str, devices: int, platforms=None):
     """Export ``jitted`` at ``args``, keep the module with what the trace
     read and booked at ``path``, and return ``(exported, meta)`` as a later
     load would."""
@@ -305,14 +345,10 @@ def _fill(jitted, args, path: str):
     package.addHandler(warnings)
     try:
         with _watch.watching() as seen:
-            exported = export_step(jitted, args)
+            exported = export_step(jitted, args, platforms)
     finally:
         package.removeHandler(warnings)
-    if exported.nr_devices != 1:
-        raise ValueError(f"a module for {exported.nr_devices} devices")
-    if exported.ordered_effects or exported.unordered_effects:
-        raise ValueError("a module with effects")
-    blob = bytes(exported.serialize())
+    blob = bytes(_keepable(exported, devices).serialize())
     for line in warnings.lines:
         _once(("said", line[0], line[2]))
     meta = {"bytes": len(blob), "sha256": hashlib.sha256(blob).hexdigest(),
@@ -344,28 +380,39 @@ def _replay(meta: dict):
             logging.getLogger(name).log(level, message)
 
 
-def stored_step(jitted, args, digest: str, donate_argnums=()):
-    """What ``costmodel.aot_compile`` lowers and compiles in place of
-    ``jitted``: a ``jit`` of the stored module's call, loaded (a hit) or
-    made and kept now (a miss); ``jitted`` itself where the store refuses
-    the step.  ``digest`` is ``program_digest``'s: a store is placed."""
-    if _on_many_devices(args):
+def stored_step(jitted, args, digest: str, donate_argnums=(), mesh=None,
+                **jit_kwargs):
+    """What ``costmodel.aot_compile`` and a sharded step's ``.lower``
+    (``parallel/sharded.py``) lower and compile in place of ``jitted``: a
+    ``jit`` of the stored module's call, loaded (a hit) or made and kept
+    now (a miss); ``jitted`` itself where the store refuses the step.
+    ``digest`` is ``program_digest``'s: a store is placed.  A step built
+    over a mesh hands in the ``mesh`` and the ``jit_kwargs`` its own ``jit``
+    was given besides the donation (shardings, compiler options): they are
+    in the key, the module is kept for the mesh's devices, and the stored
+    call's ``jit`` gets them as the step's did."""
+    if mesh is None and _on_many_devices(args):
         refuse("an argument lives on more than one device (a step under "
-               "a mesh)")
+               "a mesh the store was not handed)")
         return jitted
-    with _telemetry.startup_span("compile/program_store",
-                                 inherit=_INHERIT) as span:
+    devices = 1 if mesh is None else mesh.size
+    with _telemetry.startup_span("compile/program_store", inherit=_INHERIT,
+                                 devices=devices) as span:
         try:
-            path = os.path.join(
-                directory(), _key(digest, args, donate_argnums) + ".bin")
+            placement = platforms = None
+            if mesh is not None:
+                placement = _placement(mesh, jit_kwargs)
+                platforms = (mesh.devices.flat[0].platform,)
+            path = os.path.join(directory(), _key(
+                digest, args, donate_argnums, placement) + ".bin")
             t0 = time.perf_counter()
-            got = _load(path)
+            got = _load(path, devices)
             load_ms = (time.perf_counter() - t0) * 1e3
             hit = got is not None
             if not hit:
-                got = _fill(jitted, args, path)
+                got = _fill(jitted, args, path, devices, platforms)
             exported, meta = got
-            step = wrapped(exported, donate_argnums)
+            step = wrapped(exported, donate_argnums, **jit_kwargs)
         except Exception as e:  # noqa: BLE001 — any refusal of
             # jax.export's (a host callback, a custom call off its list):
             # today's path still compiles the step
@@ -402,14 +449,16 @@ def _stored_call_p():
     return p
 
 
-def wrapped(exported, donate_argnums=()):
+def wrapped(exported, donate_argnums=(), **jit_kwargs):
     """A ``jit`` of the call of ``exported``'s module under the exported
     function's own name (the module is ``jit_step_fn`` as before, and the
-    account's rows and ``program_label`` read the name) and
-    ``donate_argnums``.  An argument the module does not take (``jit``
-    drops the unused: a step that draws nothing never reads its step
-    number) is not handed to the call either, so this ``jit`` drops it too
-    and the executable has the parameters it had."""
+    account's rows and ``program_label`` read the name),
+    ``donate_argnums`` and whatever else the step's own ``jit`` was given
+    (``jit_kwargs``: a sharded step's shardings and compiler options).  An
+    argument the module does not take (``jit`` drops the unused: a step
+    that draws nothing never reads its step number) is not handed to the
+    call either, so this ``jit`` drops it too and the executable has the
+    parameters it had."""
     import jax
     import jax.numpy as jnp
 
@@ -427,4 +476,4 @@ def wrapped(exported, donate_argnums=()):
             exported.out_tree, call_p.bind(*leaves, exported=exported))
 
     call.__name__ = call.__qualname__ = exported.fun_name
-    return jax.jit(call, donate_argnums=tuple(donate_argnums))
+    return jax.jit(call, donate_argnums=tuple(donate_argnums), **jit_kwargs)

@@ -40,6 +40,31 @@ def _fresh_programs():
     ex._scope_stack[:] = [old_scope]
 
 
+@pytest.fixture
+def store(tmp_path, monkeypatch):
+    """The program store (``paddle_tpu/program_store.py``) placed by a
+    compile cache from outside in ``tmp_path``; the store's directory
+    (made by the first miss)."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    from paddle_tpu import compile_cache, program_store
+
+    knobs = {"jax_compilation_cache_dir": str(tmp_path),
+             "jax_persistent_cache_min_compile_time_secs": 0.0,
+             "jax_persistent_cache_min_entry_size_bytes": -1}
+    old = {k: getattr(jax.config, k) for k in knobs}
+    for k, v in knobs.items():
+        jax.config.update(k, v)
+    cc.reset_cache()
+    monkeypatch.setenv(compile_cache.ENV_VAR, str(tmp_path))
+    monkeypatch.setattr(program_store, "_said", set())
+    yield os.path.join(str(tmp_path), program_store.SUBDIR)
+    for k, v in old.items():
+        jax.config.update(k, v)
+    cc.reset_cache()
+
+
 def pytest_configure(config):
     config.addinivalue_line(
         "markers",

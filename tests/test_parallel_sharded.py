@@ -107,6 +107,55 @@ def test_dp_gradient_equivalence_vs_single_device():
     assert losses[-1] < losses[0]
 
 
+def test_the_step_is_callable_and_its_stored_executable_has_its_analyses(
+        store):
+    """What ``build_sharded_step`` returns (PR 62) is called as the ``jit``
+    it holds and, with a program store placed, ``.lower().compile()`` is the
+    stored module's executable: the same call signature and losses, the
+    state donated, and the ``memory_analysis()`` / ``cost_analysis()`` the
+    benchmark reads (``hbm_peak_gb.train``, ``mfu_pct.train``)."""
+    from paddle_tpu.monitor import stat_get
+
+    main, startup = pt.default_main_program(), pt.default_startup_program()
+    with pt.program_guard(main, startup):
+        loss = _build_mlp()
+        optimizer.MomentumOptimizer(0.1, 0.9).minimize(loss)
+    mesh = dp_mesh(4)
+    fn, mut_in, const_in, _ = build_sharded_step(
+        main, ["x", "y"], [loss.name], mesh)
+    feed = _feed()
+    feed_vals = tuple(shard_batch(mesh, [feed["x"], feed["y"]]))
+
+    def run(step):
+        scope = pt.Scope()
+        _init(scope)
+        mut = first = tuple(scope.find_var(n) for n in mut_in)
+        const = tuple(scope.find_var(n) for n in const_in)
+        losses = []
+        for i in range(3):
+            fetches, mut, _ = step(feed_vals, mut, const, np.int32(i + 1))
+            losses.append(np.asarray(fetches[0]).tobytes())
+        return losses, [m.is_deleted() for m in first]
+
+    assert callable(fn) and fn.digest is not None
+    scope = pt.Scope()
+    _init(scope)
+    misses = stat_get("program_store_misses")
+    compiled = fn.lower(
+        feed_vals, tuple(scope.find_var(n) for n in mut_in),
+        tuple(scope.find_var(n) for n in const_in), np.int32(1)).compile()
+    assert stat_get("program_store_misses") == misses + 1
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes > 0
+    assert memory.temp_size_in_bytes >= 0
+    cost = compiled.cost_analysis()
+    cost = cost[0] if isinstance(cost, (list, tuple)) else cost
+    assert cost["flops"] > 0
+    direct, stored = run(fn), run(compiled)
+    assert direct == stored
+    assert all(direct[1])
+
+
 # -- compiler options of a step whose gradient reductions cross chips (PR 42)
 
 class _StubDevice:

@@ -9,6 +9,11 @@ a trace books and logs besides the module is done again on a hit; what
 cache placed nothing is stored.  Two fresh subprocesses (one script, run
 twice) show the same across processes, to the bit and with the state
 donated.
+
+The step builders of ``parallel/sharded.py`` are clients too (PR 62): the
+object ``build_sharded_step`` returns answers ``.lower(*args)`` from the
+store, over a mesh of one device and of four, under a key that also holds
+the mesh, every ``PartitionSpec``, the donation and the compiler options.
 """
 import json
 import logging
@@ -29,28 +34,6 @@ from paddle_tpu.ops.registry import get_op_def, reset_op_seed
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 STORE_STATS = ("program_store_hits", "program_store_misses",
                "program_store_refused")
-
-
-@pytest.fixture
-def store(tmp_path, monkeypatch):
-    """A cache placed from outside in ``tmp_path``; the store's directory
-    (made by the first miss)."""
-    import jax
-    from jax.experimental.compilation_cache import compilation_cache as cc
-
-    knobs = {"jax_compilation_cache_dir": str(tmp_path),
-             "jax_persistent_cache_min_compile_time_secs": 0.0,
-             "jax_persistent_cache_min_entry_size_bytes": -1}
-    old = {k: getattr(jax.config, k) for k in knobs}
-    for k, v in knobs.items():
-        jax.config.update(k, v)
-    cc.reset_cache()
-    monkeypatch.setenv(compile_cache.ENV_VAR, str(tmp_path))
-    monkeypatch.setattr(program_store, "_said", set())
-    yield os.path.join(str(tmp_path), program_store.SUBDIR)
-    for k, v in old.items():
-        jax.config.update(k, v)
-    cc.reset_cache()
 
 
 def _net(prob=0.25, seed=3, width=16):
@@ -477,6 +460,177 @@ def test_the_account_has_a_part_and_no_column_for_the_store(store):
     assert account["program_store"]["n"] == 4
     assert all(len(row) == 7 for row in account["programs"])
     assert {r[0] for r in account["programs"]} >= {"step_fn"}
+
+
+# -- a step built over a mesh (``parallel/sharded.py``, PR 62) --------------
+
+def _sharded(n=4, axis="dp", multistep=0, **build):
+    """``_net()``'s step (or ``multistep`` of them in one program) over a
+    mesh of ``n`` devices, from a fresh start-up: ``(fn, args as numpy, the
+    mesh)``."""
+    import jax
+    from jax.sharding import Mesh
+
+    from paddle_tpu.parallel import (build_sharded_multistep,
+                                     build_sharded_step)
+
+    main, startup, loss, _ = _net()
+    scope = pt.Scope()
+    pt.Executor().run(startup, scope=scope)
+    mesh = Mesh(np.array(jax.devices()[:n]), (axis,))
+    feed = _feed(8)
+    if multistep:
+        fn, mut_in, const_in, _ = build_sharded_multistep(
+            main, ["x", "y"], [loss.name], mesh, multistep,
+            batch_axes=(axis,), **build)
+        feed = {k: np.stack([v] * multistep) for k, v in feed.items()}
+    else:
+        fn, mut_in, const_in, _ = build_sharded_step(
+            main, ["x", "y"], [loss.name], mesh, batch_axes=(axis,),
+            **build)
+    args = ((feed["x"], feed["y"]),
+            tuple(np.asarray(scope.find_var(k)) for k in mut_in),
+            tuple(np.asarray(scope.find_var(k)) for k in const_in))
+    return fn, args, mesh
+
+
+def _three_steps(step, fn, args):
+    """Three steps of ``step`` from ``args``, the state threaded through
+    (and donated): every loss's and the last state's bytes."""
+    import jax
+
+    feed, mut, const = (jax.device_put(a, sh) for a, sh in zip(
+        args, fn.jit_kwargs["in_shardings"]))
+    first, losses = mut, []
+    for i in range(3):
+        fetches, mut, _ = step(feed, mut, const, np.int32(i + 1))
+        losses.append(np.asarray(fetches[0]).tobytes())
+    assert all(m.is_deleted() for m in first), "the state was not donated"
+    return losses, [np.asarray(m).tobytes() for m in mut]
+
+
+@pytest.mark.parametrize("devices,multistep", [(1, 0), (4, 0), (4, 2)])
+def test_a_sharded_step_misses_then_hits_and_steps_to_the_bit(
+        store, monkeypatch, devices, multistep):
+    from paddle_tpu import telemetry
+    from paddle_tpu.parallel import sharded
+
+    lowered = []
+    lower_block = sharded.lower_block
+    monkeypatch.setattr(
+        sharded, "lower_block",
+        lambda block, *a, **kw: (lowered.append(block),
+                                 lower_block(block, *a, **kw))[1])
+    telemetry.clear_spans()
+    got = []
+    for turn in ("miss", "hit"):
+        fn, args, mesh = _sharded(devices, multistep=multistep)
+        before, hits0 = _counts(), stat_get("compile_cache_hits")
+        del lowered[:]
+        compiled = fn.lower(*args, np.int32(1)).compile()
+        assert _delta(before) == {"hits": int(turn == "hit"),
+                                  "misses": int(turn == "miss"),
+                                  "refused": 0}
+        assert len(lowered) == (turn == "miss"), "a hit traced the Program"
+        if turn == "hit":
+            assert stat_get("compile_cache_hits") - hits0 == 1
+        span = [s for s in telemetry.get_spans(kept=True)
+                if s.name == "compile/program_store"][-1]
+        assert span.attrs["hit"] == (turn == "hit")
+        assert span.attrs["devices"] == mesh.size == devices
+        got.append(_three_steps(compiled, fn, args))
+    # the step's own jit, called as it is: the same bits
+    want = _three_steps(fn, fn, args)
+    assert got[0] == want and got[1] == want
+
+
+def _options(value):
+    from paddle_tpu.parallel import sharded
+
+    return lambda mp: mp.setattr(
+        sharded, "overlap_compiler_options",
+        lambda mesh, axes: ({"xla_enable_async_all_reduce": value},
+                            None)) or {}
+
+
+def _replicated_feed(mp):
+    from jax.sharding import PartitionSpec as P
+
+    return dict(feed_pspecs={"x": P()})
+
+
+MESH_KEY_PARTS = {
+    "the builder, with its stacked feed": lambda mp: dict(multistep=1),
+    "the mesh's shape": lambda mp: dict(n=2),
+    "an axis name": lambda mp: dict(axis="data"),
+    "a PartitionSpec": _replicated_feed,
+    "donate_state": lambda mp: dict(donate_state=False),
+    "a compiler option": _options(True),
+    "a compiler option's value": _options(False),
+}
+
+
+@pytest.mark.parametrize("part", sorted(MESH_KEY_PARTS))
+def test_each_part_of_a_sharded_steps_key_alone_makes_a_miss(
+        store, monkeypatch, part):
+    """(Lowered and never compiled: the CPU's compiler knows no option of
+    the TPU's.)"""
+    def lower(**build):
+        fn, args, _ = _sharded(**build)
+        before = _counts()
+        fn.lower(*args, np.int32(1))
+        got = _delta(before)
+        assert got["refused"] == 0 and got["hits"] + got["misses"] == 1
+        return "hit" if got["hits"] else "miss"
+
+    if part == "a compiler option's value":
+        _options(True)(monkeypatch)
+    assert [lower(), lower()] == ["miss", "hit"]
+    changed = MESH_KEY_PARTS[part](monkeypatch)
+    assert [lower(**changed), lower(**changed)] == ["miss", "hit"]
+
+
+def test_a_module_for_four_devices_is_refused_under_two(store, monkeypatch,
+                                                        caplog):
+    # (one key for every mesh, as a damaged or misplaced entry would have)
+    monkeypatch.setattr(program_store, "_placement", lambda *a: {})
+    fn, args, _ = _sharded(4)
+    before = _counts()
+    fn.lower(*args, np.int32(1))
+    assert _delta(before) == {"hits": 0, "misses": 1, "refused": 0}
+    kept = {n: os.path.getsize(os.path.join(store, n))
+            for n in os.listdir(store)}
+    for turn in range(2):
+        fn, args, _ = _sharded(2)
+        before = _counts()
+        with caplog.at_level(logging.WARNING, logger="paddle_tpu"):
+            caplog.clear()
+            compiled = fn.lower(*args, np.int32(1)).compile()
+        assert _delta(before) == {"hits": 0, "misses": 0, "refused": 1}
+        said = [r.getMessage() for r in caplog.records
+                if "program store: refused" in r.getMessage()]
+        assert len(said) == (turn == 0)
+        assert all("a module for 4 devices" in m for m in said)
+        # the step's own jit compiled it, and the entry is as it was
+        assert _three_steps(compiled, fn, args) == _three_steps(fn, fn, args)
+        assert {n: os.path.getsize(os.path.join(store, n))
+                for n in os.listdir(store)} == kept
+
+
+def test_with_no_store_a_sharded_steps_lower_is_the_plain_jits(
+        tmp_path, monkeypatch):
+    monkeypatch.delenv(compile_cache.ENV_VAR, raising=False)
+    monkeypatch.chdir(tmp_path)
+    monkeypatch.setattr(program_store, "stored_step", None)
+    fn, args, _ = _sharded(4)
+    assert fn.digest is None
+    before = _counts()
+    lowered = fn.lower(*args, np.int32(1))
+    assert lowered.as_text() == fn.jitted.lower(*args, np.int32(1)).as_text()
+    compiled = lowered.compile()
+    assert _three_steps(compiled, fn, args) == _three_steps(fn, fn, args)
+    assert _delta(before) == {"hits": 0, "misses": 0, "refused": 0}
+    assert os.listdir(str(tmp_path)) == []
 
 
 _SCRIPT = r"""

@@ -193,10 +193,13 @@ def collectives(hlo: str) -> list:
     return sorted(out, key=lambda r: r["at"])
 
 
-def compile_cell(cell_name: str):
-    """``(compiled step, where it was compiled for)`` of ``cell_name``, from
-    shapes alone.  Called from ``main`` only: it edits the path for the
-    cell's builder and, off the chip, jax's answer to ``default_backend``."""
+def cell_step(cell_name: str):
+    """``(fn, args, where)`` of the training cell ``cell_name``: its step as
+    ``build_sharded_step`` builds it over a mesh of the cell's chips
+    (attached, or a described v5e's off the chip), the shapes it is lowered
+    at, and a word for the devices.  Called from a tool's ``main`` only: it
+    edits the path for the cell's builder and, off the chip, jax's answer
+    to ``default_backend``."""
     def load(*parts):
         with open(os.path.join(ROOT, *parts)) as f:
             return json.load(f)
@@ -224,7 +227,7 @@ def compile_cell(cell_name: str):
     builder = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(builder)
 
-    if jax.default_backend() == "tpu":
+    if jax.devices()[0].platform == "tpu":
         devices = jax.devices()[:chips]
         where = f"{len(devices)} attached {devices[0].device_kind}"
     else:
@@ -266,9 +269,15 @@ def compile_cell(cell_name: str):
     host = builder.host_batches(0, cfg, batch, seq, 1)[0]
     feeds = tuple(shaped(host[k].shape, host[k].dtype, dp)
                   for k in feed_names)
-    compiled = fn.lower(feeds, state(mut_in), state(const_in),
-                        shaped((), "int32", rep)).compile()
-    return compiled, where
+    return fn, (feeds, state(mut_in), state(const_in),
+                shaped((), "int32", rep)), where
+
+
+def compile_cell(cell_name: str):
+    """``(compiled step, where it was compiled for)`` of ``cell_name``, from
+    shapes alone."""
+    fn, args, where = cell_step(cell_name)
+    return fn.lower(*args).compile(), where
 
 
 def main(argv=None) -> int:

@@ -25,13 +25,19 @@ of each cell as the ``Executor`` builds them (``Executor._build``'s jitted
 step), once as ``fn.lower(...).compile()`` and once through the program
 store's round trip (``jax.export``, serialise, deserialise,
 ``program_store.wrapped(...).lower(...).compile()``), and prints a hash of
-each executable's optimised HLO (``as_text()`` less its ``metadata``; a
-second hash is of its lines sorted, for two schedules of the same
-operations) with its ``cost_analysis`` flops and ``memory_analysis`` bytes:
-the stored module compiles to the executable the step compiled to where
-the two lines of a program agree.  A minute or two a program; no chip, nothing
-runs.  Default cells: ``mistral7b-chat``, ``smallthinker21b-mixedlen``,
-``olmo-hybrid7b-longdoc``.
+each executable's optimised HLO (``as_text()`` less its ``metadata`` and
+with every value and computation named by where it first appears; a
+second hash is of its lines sorted and no operand named, for two schedules
+of the same operations) with its ``cost_analysis`` flops and
+``memory_analysis`` bytes: the stored module compiles to the executable the
+step compiled to where the two lines of a program agree.  A minute or two a
+program; no chip, nothing runs.  Default cells: ``mistral7b-chat``,
+``smallthinker21b-mixedlen``, ``olmo-hybrid7b-longdoc``.  A training cell (PR 62: ``bert-base-seq512``,
+``bert-base-seq512-dp4``) gives its one step as ``build_sharded_step``
+builds it over a mesh of the cell's chips: the step's own ``jit`` beside the
+stored call under the same shardings, donation and compiler options.
+``--dump DIR`` (after ``--compiled``) also writes every executable's text as
+it is hashed, ``DIR/<n>.txt`` in the order of the lines printed, for a diff.
 """
 import hashlib
 import json
@@ -50,6 +56,12 @@ def main(argv) -> int:
     compiled = argv[1:2] == ["--compiled"]
     if compiled:
         del argv[1]
+    dump = None
+    if argv[1:2] == ["--dump"]:
+        dump = os.path.abspath(argv[2])
+        os.makedirs(dump, exist_ok=True)
+        del argv[1:3]
+    dumped = []
     tree = os.path.abspath(argv[1])
     cells = argv[2:] or (COMPILED_CELLS if compiled else CELLS)
     sys.path[:0] = [os.path.join(tree, "benchmark"), tree]
@@ -60,7 +72,7 @@ def main(argv) -> int:
 
     import harness
     import paddle_tpu as pt
-    import importlib
+    import importlib.util
 
     # (``paddle_tpu.models`` exports a function of the module's name)
     llama = importlib.import_module("paddle_tpu.models.llama")
@@ -82,10 +94,21 @@ def main(argv) -> int:
 
     def canonical(text):
         """An executable's text less what names it and does not make it:
-        ``metadata``, the tables of files and frames above the module, the
-        numbers and ``.clone`` XLA appends to a name, the entry's
-        parameter names (jit names them after the Python signature)."""
-        lines, table = [], False
+        ``metadata``, the tables of files and frames above the module, and
+        every name: a computation is called by where it first appears in
+        the module, a value by where it first appears in its computation,
+        a computation's parameters by their place.  (XLA names what the
+        partitioner makes after the jax primitive an operation came from,
+        which is ``stored_step`` for all of a stored call, and jit names
+        the entry's parameters after the Python signature.)"""
+        lines, table, comps, local = [], False, {}, {}
+
+        def named(match):
+            name = match.group(0)
+            if name in comps and name not in local:
+                return comps[name]
+            return local.setdefault(name, "%%v%d" % len(local))
+
         for line in re.sub(r", metadata=\{[^}]*\}", "",
                            text).splitlines():
             if line in ("FileNames", "FunctionNames", "FileLocations",
@@ -94,23 +117,29 @@ def main(argv) -> int:
             elif table:
                 table = bool(line.strip())
             else:
-                lines.append(re.sub(r"\b([A-Za-z_][\w\-]*?)(\.\d+|\.clone)+\b",
-                                    r"\1", line))
-        text = "\n".join(lines)
-        entry = re.search(r"^ENTRY %\w+ \((.*?)\) -> ", text, re.M)
-        for i, name in enumerate(re.findall(r"([\w\-]+): ",
-                                            entry.group(1))):
-            text = re.sub(r"(?<![\w\-])%s(?![\w\-])" % re.escape(name),
-                          "p%d" % i, text)
-        return text
+                head = re.match(r"(ENTRY )?(%[\w.\-]+) \(", line)
+                if head:
+                    local = {}
+                    comps.setdefault(head.group(2), "%%c%d" % len(comps))
+                    place = iter(range(len(line)))
+                    line = re.sub(r"[\w.\-]+(?=: )",
+                                  lambda m: "p%d" % next(place), line)
+                lines.append(re.sub(r"%[\w.\-]+", named, line))
+        return "\n".join(lines)
 
     def executable(fn, args):
         """An executable's line: its optimised HLO's hash, flops, bytes."""
         exe = fn.lower(*args).compile()
         text = canonical(exe.as_text())
+        if dump:
+            dumped.append(os.path.join(dump, "%d.txt" % len(dumped)))
+            with open(dumped[-1], "w") as f:
+                f.write(exe.as_text())
         cost, mem = exe.cost_analysis(), exe.memory_analysis()
         cost = cost[0] if isinstance(cost, (list, tuple)) else cost
-        lines = "\n".join(sorted(text.splitlines()))
+        # (for two schedules of the same operations: no operand's name)
+        lines = "\n".join(sorted(
+            re.sub(r"%[\w.\-]+", "%", text).splitlines()))
         return "%s %s flops %.6g bytes %.6g args %d out %d temp %d alias %d" % (
             hashlib.sha256(text.encode()).hexdigest()[:16],
             hashlib.sha256(lines.encode()).hexdigest()[:16],
@@ -140,25 +169,13 @@ def main(argv) -> int:
                          for n in ns)
 
         if compiled:
-            from jax import export
-
-            from paddle_tpu import program_store
             from paddle_tpu.framework import executor
 
             entry = pt.Executor()._build(main, block, list(feeds), names)
             args = (tuple(spec(*shapes[n]) for n in feeds),
                     state(entry.mut_in), state(entry.const_in),
                     spec((), "int32"))
-            direct = executable(entry.fn, args)
-            blob = program_store.export_step(entry.fn, args,
-                                             ("tpu",)).serialize()
-            stored = executable(program_store.wrapped(
-                export.deserialize(blob), executor._DONATED), args)
-            same = "same" if direct == stored else (
-                "same operations, some scheduled in another order"
-                if direct.split()[1:] == stored.split()[1:] else "DIFFERENT")
-            return "%s\n    direct %s\n    stored %s (module %d bytes)" % (
-                same, direct, stored, len(blob))
+            return round_trip(entry.fn, args, executor._DONATED)
         fn, mut_in, const_in, _ = build_sharded_step(main, feeds, names,
                                                      mesh)
         text = fn.lower(tuple(spec(*shapes[n]) for n in feeds),
@@ -166,8 +183,44 @@ def main(argv) -> int:
                         spec((), "int32")).as_text()
         return hashlib.sha256(text.encode()).hexdigest()[:16]
 
+    def round_trip(jitted, args, donate_argnums, **jit_kwargs):
+        """``jitted``'s executable beside the one of its module after the
+        store's round trip, and whether they agree."""
+        from jax import export
+
+        from paddle_tpu import program_store
+
+        direct = executable(jitted, args)
+        blob = program_store.export_step(jitted, args, ("tpu",)).serialize()
+        stored = executable(program_store.wrapped(
+            export.deserialize(blob), donate_argnums, **jit_kwargs), args)
+        same = "same" if direct == stored else (
+            "same operations, some scheduled in another order"
+            if direct.split()[1:] == stored.split()[1:] else "DIFFERENT")
+        return "%s\n    direct %s\n    stored %s (module %d bytes)" % (
+            same, direct, stored, len(blob))
+
+    def train_step(name):
+        """The training cell's step over a mesh of its described chips, as
+        ``TREE/tools/collective_schedule.py`` builds it."""
+        spec = importlib.util.spec_from_file_location(
+            "collective_schedule",
+            os.path.join(tree, "tools", "collective_schedule.py"))
+        schedule = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(schedule)
+        fn, args, _ = schedule.cell_step(name)
+        return round_trip(fn.jitted, args, fn.donate_argnums,
+                          **fn.jit_kwargs)
+
     for name in cells:
         cell = harness.Cell(name)
+        if cell.mix.get("driver") == "train":
+            if not compiled:
+                print(f"{name}: a training cell, compared with --compiled",
+                      file=sys.stderr)
+                return 2
+            print(name, "step", train_step(name), flush=True)
+            continue
         cfg, e = cell.cfg, cell.mix["engine"]
         model = cell.builder().model_args(cfg)
         slots, pt_, seq = e["num_slots"], e["page_tokens"], e["max_seq_len"]
