@@ -1550,30 +1550,22 @@ SSD = dict(heads=64, head_dim=64, state=128, conv=4, slots=32, seq=512,
            valid=450, hidden=2048, prompt=300, steps=3)
 
 
-def ssd_phase(cfg=SSD):
-    """What a decoder with state-space duality (Mamba-2) layers adds (PR
-    59), at granite-4.0-h-micro's published sizes (64 heads of 64 over 128
-    state rows, one group): the two Pallas kernels of
-    ``ops/pallas/ssd.py`` against the XLA formulations of
-    ``ops/ssd_ops.py`` (the whole scan over a padded prompt; the step over
-    32 slots of which some are dead: their state and the trash row bit for
-    bit what they were); and one state round trip through a two-slot
-    ``GenerationEngine`` of one state-space layer and one attention layer
-    without rotary embedding at hidden 2048 under the family's four
-    multipliers and the tied head: a prefill of more than two chunks,
-    three decode steps, the slot taken again, each against the uncached
-    forward, with the lowering counters."""
+def _ssd_kernels_against_xla(tag, seed, cfg):
+    """The two kernels of ``ops/pallas/ssd.py`` against the XLA
+    formulations of ``ops/ssd_ops.py`` at ``cfg``'s sizes (``groups``
+    above 1: B and C of ``[.., G, N]``): the whole scan over a padded
+    prompt, and the step over ``slots`` slots of which some are dead,
+    their state and the trash row bit for bit what they were."""
     import jax
     import jax.numpy as jnp
 
-    from paddle_tpu.monitor import stat_get
     from paddle_tpu.ops import ssd_ops
     from paddle_tpu.ops.pallas import ssd as kern
-    from paddle_tpu.serving import GenerationEngine
 
     H, P, N = cfg["heads"], cfg["head_dim"], cfg["state"]
-    T, n = cfg["seq"], cfg["slots"]
-    key = jax.random.key(59)
+    T, n, G = cfg["seq"], cfg["slots"], cfg.get("groups", 1)
+    bc = (N,) if G == 1 else (G, N)
+    key = jax.random.key(seed)
 
     def draw(i, *shape):
         return jax.random.normal(jax.random.fold_in(key, i), shape)
@@ -1587,19 +1579,19 @@ def ssd_phase(cfg=SSD):
                             1.0, 16.0)
     d = jnp.ones((H,), jnp.float32)
     valid = jnp.asarray([cfg["valid"]], jnp.int32)
-    ops = (draw(0, 1, T, H, P), steps(1, 1, T, H), a, draw(2, 1, T, N),
-           draw(3, 1, T, N), d)
+    ops = (draw(0, 1, T, H, P), steps(1, 1, T, H), a, draw(2, 1, T, *bc),
+           draw(3, 1, T, *bc), d)
     want_o, want_s = jax.jit(lambda *t: ssd_ops.chunked(*t, valid=valid))(
         *ops)
     got_o, got_s = kern.chunk(*ops, valid=valid)
     rel = max(float(jnp.abs(got_o - want_o).max() / jnp.abs(want_o).max()),
               float(jnp.abs(got_s - want_s).max() / jnp.abs(want_s).max()))
     check(bool(jnp.isfinite(got_o).all()) and rel <= TOL,
-          f"ssd_chunk kernel off the XLA scan by {rel:.4g}")
+          f"{tag}_chunk kernel off the XLA scan by {rel:.4g}")
     state = draw(5, n + 1, N, H * P)
     live = jnp.asarray(np.arange(n) % 5 != 3, jnp.int32)
-    row = (draw(6, n, H, P), steps(7, n, H), a, draw(8, n, N),
-           draw(9, n, N), d)
+    row = (draw(6, n, H, P), steps(7, n, H), a, draw(8, n, *bc),
+           draw(9, n, *bc), d)
     want_o, want_s = jax.jit(ssd_ops.step)(*row, state, live.astype(bool))
     got_o, got_s = kern.step(*row, state, live)
     on = np.asarray(live, bool)
@@ -1608,14 +1600,35 @@ def ssd_phase(cfg=SSD):
               / jnp.abs(want_o).max()),
         float(jnp.abs(got_s - want_s).max() / jnp.abs(want_s).max()))
     check(rel_step <= TOL,
-          f"ssd_step kernel off the XLA step by {rel_step:.4g}")
+          f"{tag}_step kernel off the XLA step by {rel_step:.4g}")
     check(bool(jnp.array_equal(got_s[:n][~on], state[:n][~on]))
           and bool(jnp.array_equal(got_s[n], state[n])),
-          "ssd_step moved a dead slot's state or the trash row")
-    say(f"ssd: kernels at {H} heads of {P} over {N} state rows: the whole "
-        f"scan over {cfg['valid']} of {T} rows within {rel:.4g} of the XLA "
-        f"form, the step over {int(on.sum())} live of {n} slots within "
-        f"{rel_step:.4g} of it, dead slots untouched (tolerance {TOL})")
+          f"{tag}_step moved a dead slot's state or the trash row")
+    say(f"{tag}: kernels at {H} heads of {P} over {N} state rows in {G} "
+        f"group(s): the whole scan over {cfg['valid']} of {T} rows within "
+        f"{rel:.4g} of the XLA form, the step over {int(on.sum())} live of "
+        f"{n} slots within {rel_step:.4g} of it, dead slots untouched "
+        f"(tolerance {TOL})")
+
+
+def ssd_phase(cfg=SSD):
+    """What a decoder with state-space duality (Mamba-2) layers adds (PR
+    59), at granite-4.0-h-micro's published sizes (64 heads of 64 over 128
+    state rows, one group): the two Pallas kernels of
+    ``ops/pallas/ssd.py`` against the XLA formulations of
+    ``ops/ssd_ops.py`` (the whole scan over a padded prompt; the step over
+    32 slots of which some are dead: their state and the trash row bit for
+    bit what they were); and one state round trip through a two-slot
+    ``GenerationEngine`` of one state-space layer and one attention layer
+    without rotary embedding at hidden 2048 under the family's four
+    multipliers and the tied head: a prefill of more than two chunks,
+    three decode steps, the slot taken again, each against the uncached
+    forward, with the lowering counters."""
+    from paddle_tpu.monitor import stat_get
+    from paddle_tpu.serving import GenerationEngine
+
+    H, P, N = cfg["heads"], cfg["head_dim"], cfg["state"]
+    _ssd_kernels_against_xla("ssd", 59, cfg)
 
     ssd = {"kind": "ssd", "heads": H, "head_dim": P, "state": N,
            "groups": 1, "conv": cfg["conv"], "conv_bias": True}
@@ -1665,6 +1678,149 @@ def ssd_phase(cfg=SSD):
         gen.close()
     say(f"ssd: state through a chunked prefill, {cfg['steps']} decode "
         f"steps and a reused slot within {worst:.4g} of the uncached "
+        f"forward; ssd_lowered_pallas +{pallas}, ssd_lowered_reference "
+        f"+{reference}")
+
+
+GROUPED = dict(heads=128, head_dim=64, state=128, groups=8, slots=32,
+               seq=512, valid=450, experts=64, latent=1024, width=2688,
+               hidden=2048, rows=512, top_k=6, prompt=300, steps=3)
+
+
+def single_sublayer_phase(cfg=GROUPED):
+    """What a decoder of single-sublayer layers with grouped state-space
+    layers and latent experts of two matrices adds (PR 63), at
+    nemotron3-super-120b-a12b's published sizes: the two SSD kernels with
+    EIGHT groups of B and C at 128 heads of 64 over 128 state rows against
+    the XLA formulations (dead slots and the trash row bit for bit
+    untouched); the routed layer of non-gated ReLU^2 experts of 2688 in a
+    latent of 1024 (64 of them, 6 a token, the router reading rows twice as
+    wide) against a loop over the experts, its activation and routing
+    weight riding the grouped kernel (``grouped_matmul_epilogue_act``); and
+    one round trip through a two-slot ``GenerationEngine`` of three layers
+    of ONE sublayer each (state-space, latent experts with a share held
+    and a full-width shared expert, attention): a prefill of more than two
+    chunks, three decode steps, the slot taken again, each against the
+    uncached forward, with the lowering counters."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.monitor import stat_get
+    from paddle_tpu.parallel import moe
+    from paddle_tpu.serving import GenerationEngine
+
+    H, P, N, G = cfg["heads"], cfg["head_dim"], cfg["state"], cfg["groups"]
+    _ssd_kernels_against_xla("grouped ssd", 63, cfg)
+    key = jax.random.key(63)
+
+    def draw(i, *shape):
+        return jax.random.normal(jax.random.fold_in(key, i), shape)
+
+    # experts of two matrices in a latent row, the sorted route
+    E, R, I, k = cfg["experts"], cfg["latent"], cfg["width"], cfg["top_k"]
+    rows, wide = cfg["rows"], cfg["hidden"]
+    u, h = draw(30, rows, R), draw(31, rows, wide)
+    router = draw(32, wide, E) * wide ** -0.5
+    up = draw(33, E, R, I) * R ** -0.5
+    down = draw(34, E, I, R) * I ** -0.5
+    counted = {c: stat_get(c) for c in (
+        "grouped_matmul_lowered_pallas", "grouped_matmul_lowered_ragged_dot",
+        "grouped_matmul_epilogue_act", "grouped_matmul_epilogue_scale",
+        "grouped_matmul_epilogue_gate")}
+    got, counts, _ = jax.jit(lambda *t: moe.moe_routed_tokens(
+        *t, top_k=k, activation="relu2", score="sigmoid", route_scale=5.0,
+        precision=jax.lax.Precision.HIGHEST))(u, h, router, up, down)
+
+    @jax.jit
+    def loop(u, h, router, up, down):
+        _, experts, weights = moe.route_top_k(h, router, k, "sigmoid",
+                                             route_scale=5.0)
+        dense = jnp.zeros((rows, E), jnp.float32).at[
+            jnp.arange(rows)[:, None], experts].set(weights)
+
+        def one(e, acc):
+            y = jnp.square(jax.nn.relu(jnp.dot(
+                u, up[e], precision="highest")))
+            return acc + dense[:, e, None] * jnp.dot(
+                y, down[e], precision="highest")
+
+        return jax.lax.fori_loop(0, E, one, jnp.zeros_like(u))
+
+    want = loop(u, h, router, up, down)
+    rel_moe = float(jnp.abs(got - want).max() / jnp.abs(want).max())
+    grew = {c: stat_get(c) - v for c, v in counted.items()}
+    check(rel_moe <= TOL and int(counts.sum()) == rows * k,
+          f"latent experts of two matrices off the loop by {rel_moe:.4g}, "
+          f"{int(counts.sum())} pairs placed")
+    check(grew == {"grouped_matmul_lowered_pallas": 2,
+                   "grouped_matmul_lowered_ragged_dot": 0,
+                   "grouped_matmul_epilogue_act": 1,
+                   "grouped_matmul_epilogue_scale": 1,
+                   "grouped_matmul_epilogue_gate": 0},
+          f"the two products of experts without a gate lowered to {grew}")
+    say(f"latent experts: {E} non-gated ReLU^2 experts of {I} in a latent "
+        f"of {R}, {k} a token over {rows} rows, within {rel_moe:.4g} of a "
+        f"loop over the experts; the activation and the routing weight on "
+        f"the kernel's accumulator ({grew})")
+
+    ssd = {"kind": "ssd", "heads": H, "head_dim": P, "state": N,
+           "groups": G, "conv": 4, "conv_bias": True}
+    experts = {"experts": E, "held": (8, 8), "top_k": k, "width": I,
+               "latent": R, "activation": "relu2", "gated": False,
+               "route_from": "normed", "score": "sigmoid",
+               "expert_bias": True, "route_scale": 5.0,
+               "shared_width": 2 * I}
+    model = dict(vocab_size=4096, hidden=4096, num_layers=3, num_heads=32,
+                 num_kv_heads=2, head_dim=128, intermediate=I,
+                 rms_norm_eps=1e-5, tie_head=False,
+                 layer_pattern=[
+                     {"mixer": ssd, "ffn": None},
+                     {"mixer": None, "ffn": experts},
+                     {"mixer": "attention", "ffn": None, "rope": False,
+                      "attn_precision": "highest"}])
+    pal0 = stat_get("ssd_lowered_pallas")
+    ref0 = stat_get("ssd_lowered_reference")
+    gen = GenerationEngine(model, num_slots=2, max_seq_len=512,
+                           prefill_buckets=[384], page_tokens=16,
+                           prefill_chunk=0, prefix_reuse=False,
+                           speculate=False, keep_logits=True, eos_id=-1)
+    try:
+        gen.warmup()
+        pallas = stat_get("ssd_lowered_pallas") - pal0
+        reference = stat_get("ssd_lowered_reference") - ref0
+        check(pallas >= 2 and reference == 0,
+              f"the grouped state-space ops lowered to {pallas} kernels and "
+              f"{reference} XLA formulations, not to kernels alone")
+        rng = np.random.default_rng(63)
+        worst = 0.0
+        for n_prompt in (cfg["prompt"], 3):   # the second reuses slot 0
+            prompt = rng.integers(1, 4096, n_prompt).tolist()
+            res = gen.generate(prompt, cfg["steps"] + 1, timeout=600)
+            check(res["slot"] == 0, f"request landed in slot {res['slot']}")
+            seq = prompt + res["tokens"]
+            want = _forward_logits(gen, model, seq, 384)[
+                n_prompt - 1:n_prompt + cfg["steps"]]
+            got = np.stack(res["logits"])
+            rel = float(np.abs(got - want).max() / np.abs(want).max())
+            worst = max(worst, rel)
+            check(np.isfinite(got).all() and rel <= TOL,
+                  f"single-sublayer round trip (prompt {n_prompt}) off the "
+                  f"uncached forward by {rel:.4g}")
+        counters = gen.stats()["counters"]
+        check(counters["slot_state_writes"] == 2
+              and counters["ssm_state_steps"] >= 2 * cfg["steps"]
+              and counters["moe_tokens_dropped"] == 0
+              and 0 < counters["moe_pairs_held"]
+              < counters["moe_pairs_routed"],
+              f"counters {counters['slot_state_writes']} writes, "
+              f"{counters['ssm_state_steps']} state steps, "
+              f"{counters['moe_pairs_held']} of "
+              f"{counters['moe_pairs_routed']} pairs held")
+    finally:
+        gen.close()
+    say(f"single-sublayer layers: state, a held share of latent experts "
+        f"and NoPE attention through a chunked prefill, {cfg['steps']} "
+        f"decode steps and a reused slot within {worst:.4g} of the uncached "
         f"forward; ssd_lowered_pallas +{pallas}, ssd_lowered_reference "
         f"+{reference}")
 
@@ -1868,6 +2024,11 @@ def main():
     ssd_phase()
     say(f"state-space kernels and state done "
         f"[{time.perf_counter() - t0:.1f} s]")
+
+    t0 = time.perf_counter()
+    single_sublayer_phase()
+    say(f"grouped state-space kernels, latent experts of two matrices and "
+        f"single-sublayer layers done [{time.perf_counter() - t0:.1f} s]")
 
     say(f"set-up (compile-dominated: kernel check + first train step + "
         f"serving warm-up) {train['setup_s'] + serve['setup_s']:.1f} s, "

@@ -622,15 +622,25 @@ silu = _unary("silu")
 mish = _unary("mish")
 
 
-def rms_norm(x, epsilon=1e-6, param_attr=None, name=None):
-    """RMSNorm over the last dim (LLM configs; no fluid-era analog)."""
+def rms_norm(x, epsilon=1e-6, param_attr=None, name=None, group_size=None):
+    """RMSNorm over the last dim (LLM configs; no fluid-era analog).
+    ``group_size``: the mean of squares is taken over each run of that
+    many consecutive channels apart (it divides the last dim); the learned
+    weight is one a channel either way."""
     helper = LayerHelper("rms_norm", name=name)
     scale = helper.create_parameter(
         param_attr, [x.shape[-1]], "float32",
         default_initializer=ConstantInitializer(1.0))
     out = helper.create_variable_for_type_inference(x.dtype)
+    attrs = {"epsilon": epsilon}
+    # (an attr only where asked for: the other programs' text stays)
+    if group_size and int(group_size) != int(x.shape[-1]):
+        if int(x.shape[-1]) % int(group_size):
+            raise ValueError(f"rms_norm: groups of {group_size} channels do "
+                             f"not divide {x.shape[-1]}")
+        attrs["group_size"] = int(group_size)
     helper.append_op("rms_norm", inputs={"X": [x], "Scale": [scale]},
-                     outputs={"Y": [out]}, attrs={"epsilon": epsilon})
+                     outputs={"Y": [out]}, attrs=attrs)
     return out
 
 
@@ -990,8 +1000,10 @@ def ssd_chunk(x, dt, a, bm, cm, d, state0=None, valid=None, name=None):
     """The state-space duality recurrence over a whole sequence
     (ops/ssd_ops.py ``ssd_chunk``): ``x`` [B, T, H, P], ``dt`` [B, T, H]
     (positive), ``a`` and ``d`` [H] (``a`` negative), ``bm``, ``cm``
-    [B, T, N], optionally from ``state0`` [B, N, H * P] and with ``valid``
-    [B] real rows.  Returns ``(out [B, T, H, P], state [B, N, H * P])``,
+    [B, T, N] (or [B, T, G, N]: G groups, head ``h`` reads group ``h //
+    (H / G)``'s), optionally from ``state0`` [B, N, H * P] and with
+    ``valid`` [B] real rows.  Returns ``(out [B, T, H, P], state [B, N, H *
+    P])``,
     the state after the last real token."""
     helper = LayerHelper("ssd_chunk", name=name)
     out = helper.create_variable_for_type_inference(x.dtype)
@@ -1009,8 +1021,9 @@ def ssd_chunk(x, dt, a, bm, cm, d, state0=None, valid=None, name=None):
 
 def ssd_step(x, dt, a, bm, cm, d, state, live, name=None):
     """One decode step of :func:`ssd_chunk`: one row a slot (``x``
-    [slots, 1, H, P], ``dt`` [slots, 1, H], ``bm``, ``cm`` [slots, 1, N])
-    over ``state`` [slots + 1, N, H * P], which moves on in place for rows
+    [slots, 1, H, P], ``dt`` [slots, 1, H], ``bm``, ``cm`` [slots, 1, N]
+    or [slots, 1, G, N]) over ``state`` [slots + 1, N, H * P], which moves
+    on in place for rows
     with ``live`` set.  Returns the output [slots, 1, H, P]."""
     helper = LayerHelper("ssd_step", name=name)
     out = helper.create_variable_for_type_inference(x.dtype)
@@ -1405,7 +1418,8 @@ def moe_routed_ffn(x, router_x, num_experts, top_k, d_ff,
                    activation="relu", valid=None, name=None,
                    keep_router_logits=False, score="softmax",
                    expert_bias=False, norm_topk=True, route_scale=1.0,
-                   held=None, limit=None, n_group=1, topk_group=1):
+                   held=None, limit=None, n_group=1, topk_group=1,
+                   gated=True):
     """Dropless top-k mixture of gated experts without bias
     (ops/moe_ops.py ``moe_routed_ffn``): each token of ``x`` [B, S, H]
     goes to the ``top_k`` experts its row of ``router_x`` [B, S, H]
@@ -1429,6 +1443,11 @@ def moe_routed_ffn(x, router_x, num_experts, top_k, d_ff,
     softmax scores (``route_top_k``); ``expert_count`` then comes back as
     the pair ``(expert_count, group_rows [n_group] int32)``, the valid rows
     that kept each group.
+    ``gated`` False: experts of two matrices, ``relu(x W_up)^2 W_down``
+    (``activation`` "relu2", the squared ReLU, and no other) and no gate
+    matrix; the first stack is then ``.up.w`` [E, H, d_ff].  ``router_x``
+    may be wider than ``x`` (a router over the full row beside experts
+    that work in a latent one): ``.router.w`` is [its width, E].
     ``name`` prefixes the parameters ``.router.w`` [H, E], ``.gate_up.w``
     [E, H, 2 d_ff] and ``.down.w`` [E, d_ff, H].  Returns ``(out,
     expert_count [E] int32, router_logits or None)``."""
@@ -1443,11 +1462,13 @@ def moe_routed_ffn(x, router_x, num_experts, top_k, d_ff,
             raise ValueError(f"moe_routed_ffn holds experts {first} .. "
                              f"{first + here - 1} of {e}")
     p = (lambda s: f"{name}.{s}") if name else (lambda s: None)
-    router_w = helper.create_parameter(p("router.w"), [h, e], x.dtype)
+    router_w = helper.create_parameter(
+        p("router.w"), [int(router_x.shape[-1]), e], x.dtype)
     # per-expert matrices: Glorot over one expert's fan, not the stack's
+    cols = i * (2 if gated else 1)
     gate_up = helper.create_parameter(
-        p("gate_up.w"), [here, h, 2 * i], x.dtype,
-        default_initializer=XavierInitializer(fan_in=h, fan_out=2 * i))
+        p("gate_up.w" if gated else "up.w"), [here, h, cols], x.dtype,
+        default_initializer=XavierInitializer(fan_in=h, fan_out=cols))
     down = helper.create_parameter(
         p("down.w"), [here, i, h], x.dtype,
         default_initializer=XavierInitializer(fan_in=i, fan_out=h))

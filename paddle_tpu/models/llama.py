@@ -87,7 +87,18 @@ def _program_build(kind, bucket_at=None):
 #           shared expert, a SwiGLU of that width that every row goes
 #           through, beside the routed ones; "shared_scale": c multiplies
 #           its output (n shared experts of width w that are AVERAGED are
-#           one SwiGLU of width n w at c = 1 / n)
+#           one SwiGLU of width n w at c = 1 / n).  "gated": False makes
+#           the routed experts AND the shared one TWO matrices without a
+#           gate, ``W2 relu(W1 u)^2`` ("activation" "relu2", the squared
+#           ReLU, and no other; the shared expert's are
+#           ``.moe.shared_up.w`` / ``.moe.shared_down.w``).  "latent": R
+#           puts the routed experts in a latent row of that width: the
+#           router reads the full row, ``u = h W_down`` [R] goes through
+#           the dispatch, the experts ([R, I] and [I, R]) and the combine,
+#           and their weighted sum comes up again, ``r W_up``, once a row;
+#           the shared expert stays at full width.  Or None: the layer has
+#           NO second half (a mixer alone: ``x = x + mixer(norm(x))``, one
+#           norm ``.ln1``, one residual add)
 #   mixer:  "attention" (q, k, v, RoPE, pages) or a dict {"kind": "conv",
 #           "L_cache": L, "bias": False}: a gated short convolution,
 #           ``[B, C, u] = split3(h W_in)``, ``y = (C * conv_L(B * u))
@@ -116,7 +127,12 @@ def _program_build(kind, bucket_at=None):
 #           the last L - 1 rows that its convolution over x | B | C saw,
 #           and a matrix of N state rows over all H * P channels that every
 #           token decays by one number a head and writes ``B (dt x)^T``
-#           into (``ops/ssd_ops.py`` says how it lies)
+#           into (``ops/ssd_ops.py`` says how it lies).  "groups": G > 1
+#           gives B and C G groups of N ([G, N] each, 2 G N of the
+#           convolution's channels): head h reads group h // (H / G)'s, and
+#           the gated norm is over each group's H P / G channels apart.
+#           Or None: the layer has NO mixer (an FFN alone: ``x = x +
+#           ffn(norm(x))``, one norm ``.ln1``, one residual add, no cache)
 #   mla:    None, or a dict that makes the attention layer LATENT
 #           (:func:`_mla_mixer`): {"q_rank": Rq, "kv_rank": C, "nope_dim":
 #           dn, "rope_dim": dr, "v_dim": dv, "scale": the softmax scale
@@ -156,7 +172,8 @@ def state_layers(layer_pattern, num_layers):
     mixer is a gated short convolution, the gated delta rule or a
     state-space (SSD) layer."""
     return [i for i in range(num_layers)
-            if layer_spec(layer_pattern, i)["mixer"] != "attention"]
+            if layer_spec(layer_pattern, i)["mixer"] not in ("attention",
+                                                              None)]
 
 
 def _delta_dims(mixer):
@@ -173,20 +190,20 @@ def _delta_dims(mixer):
 
 def _ssd_dims(mixer):
     """A state-space mixer's ``(heads, head_dim, state rows, channels of
-    its convolution: x | B | C)``."""
+    its convolution: x | B | C, groups of B and C)``."""
     heads, p, n = (int(mixer[k]) for k in ("heads", "head_dim", "state"))
-    if int(mixer.get("groups", 1)) != 1:
-        raise ValueError(
-            f"ssd mixer: {mixer['groups']} groups of B and C are not built "
-            f"(one group: every head reads the same B and C)")
-    return heads, p, n, heads * p + 2 * n
+    groups = int(mixer.get("groups", 1))
+    if groups < 1 or heads % groups:
+        raise ValueError(f"ssd mixer: {groups} groups of B and C do not "
+                         f"divide {heads} heads")
+    return heads, p, n, heads * p + 2 * groups * n, groups
 
 
 def window_layers(layer_pattern, num_layers):
     """Indices of the attention layers whose attention is a sliding
     window."""
-    state = state_layers(layer_pattern, num_layers)
-    return [i for i in range(num_layers) if i not in state
+    return [i for i in range(num_layers)
+            if layer_spec(layer_pattern, i)["mixer"] == "attention"
             and layer_spec(layer_pattern, i)["window"] is not None]
 
 
@@ -215,8 +232,10 @@ def cache_spec(name, num_layers, layer_pattern=None, *, num_slots,
       then the delta state ``<name>.delta_state_<i>`` ``[num_slots + 1,
       value_heads, key_dim, value_dim]``.  A state-space (SSD) layer has
       two: ``<name>.conv_state_<i>`` ``[num_slots + 1, conv - 1, heads *
-      head_dim + 2 * state]``, then ``<name>.ssm_state_<i>`` ``[num_slots +
-      1, state, heads * head_dim]``."""
+      head_dim + 2 * groups * state]``, then ``<name>.ssm_state_<i>``
+      ``[num_slots + 1, state, heads * head_dim]``.
+
+    A layer without a mixer (an FFN alone) keeps nothing."""
     from ..ops.decode_ops import pool_shape
     from ..ops.latent_attention_ops import latent_pool_shape
 
@@ -224,11 +243,13 @@ def cache_spec(name, num_layers, layer_pattern=None, *, num_slots,
     spec = []
     for i in range(num_layers):
         mixer = layer_spec(layer_pattern, i)["mixer"]
+        if mixer is None:
+            continue
         if mixer != "attention":
             if mixer["kind"] == "conv":
                 shapes = {"conv_state": [int(mixer["L_cache"]) - 1, hidden]}
             elif mixer["kind"] == "ssd":
-                heads, p, n, channels = _ssd_dims(mixer)
+                heads, p, n, channels, _ = _ssd_dims(mixer)
                 shapes = {"conv_state": [int(mixer["conv"]) - 1, channels],
                           "ssm_state": [n, heads * p]}
             else:
@@ -270,7 +291,7 @@ def _cache_vars(block, spec, layer):
 def expert_layers(layer_pattern, num_layers):
     """Indices of the layers whose FFN is routed experts."""
     return [i for i in range(num_layers)
-            if layer_spec(layer_pattern, i)["ffn"] != "dense"]
+            if layer_spec(layer_pattern, i)["ffn"] not in ("dense", None)]
 
 
 def _linear(x, size, pname=None, name=None):
@@ -536,17 +557,19 @@ def _ssd_mixer(h, seq_len, hidden, mixer, p, eps, valid=None, states=None,
     """A state-space duality (Mamba-2) layer on normed rows h [B, S, H]:
     ``z | xBC | dt = h W_in`` (no bias); ``x | B | C = silu(conv(xBC) +
     b)``, one causal depthwise convolution over all their channels, ``x``
-    [heads, head_dim] and ``B``, ``C`` [state] shared by every head; ``dt =
-    softplus(dt + dt_bias)``, ``A = -exp(A_log)`` a head; the recurrence
-    ``S <- exp(dt A) S + (dt x) B^T``, ``y = S C + D x``
+    [heads, head_dim] and ``B``, ``C`` [state] shared by every head (with
+    ``groups`` G > 1: [G, state] each, head h reading group h // (heads /
+    G)'s); ``dt = softplus(dt + dt_bias)``, ``A = -exp(A_log)`` a head; the
+    recurrence ``S <- exp(dt A) S + (dt x) B^T``, ``y = S C + D x``
     (``ops/ssd_ops.py``); then ``out = rmsnorm(y * silu(z)) W_out``, the
-    norm over all ``heads * head_dim`` channels with one learned weight.
+    norm over all ``heads * head_dim`` channels (over each group's apart)
+    with one learned weight.
     ``states`` is the layer's ``(conv_state, ssm_state)`` pair and the
     three modes are :func:`_gated_delta_mixer`'s, as is what it
     returns."""
     from ..framework.initializer import NumpyArrayInitializer
 
-    heads, hp, n, channels = _ssd_dims(mixer)
+    heads, hp, n, channels, groups = _ssd_dims(mixer)
     inner = heads * hp
     conv_state, ssm_state = states if states else (None, None)
     zxd = _linear(h, inner + channels + heads, pname=p("ssd_in.w"))
@@ -561,7 +584,11 @@ def _ssd_mixer(h, seq_len, hidden, mixer, p, eps, valid=None, states=None,
                                conv_w, valid, conv_state, slot, live)
     c = layers.silu(c)
     x = layers.reshape(cut(c, 0, inner), [0, seq_len, heads, hp])
-    bm, cm = cut(c, inner, n), cut(c, inner + n, n)
+    bm, cm = cut(c, inner, groups * n), cut(c, inner + groups * n,
+                                            groups * n)
+    if groups > 1:
+        bm, cm = (layers.reshape(t, [0, seq_len, groups, n])
+                  for t in (bm, cm))
     a_log, dt_bias, d = (layers.create_parameter(
         [heads], "float32", name=p(what),
         default_initializer=NumpyArrayInitializer(init))
@@ -580,7 +607,8 @@ def _ssd_mixer(h, seq_len, hidden, mixer, p, eps, valid=None, states=None,
             state = last
     y = layers.elementwise_mul(layers.reshape(y, [0, seq_len, inner]),
                                layers.silu(z))
-    y = layers.rms_norm(y, epsilon=eps, param_attr=p("ssd_norm"))
+    y = layers.rms_norm(y, epsilon=eps, param_attr=p("ssd_norm"),
+                        group_size=inner // groups)
     return _linear(y, hidden, pname=p("ssd_out.w")), tail, state
 
 
@@ -648,6 +676,11 @@ def llama_block(x, hidden, num_heads, num_kv_heads, seq_len, head_dim,
     the whole block (``paged_decode_attention`` with that many rows: a
     denoising or a commit pass of block diffusion).
 
+    A layer whose ``mixer`` or whose ``ffn`` is None is that ONE sublayer
+    alone, ``x = x + f(norm(x))`` under the one norm ``.ln1`` (``norm``
+    "pre" only): an FFN alone takes and keeps no cache and returns ``(x,
+    None, None)`` with ``collect_kv``.
+
     ``layer`` is the layer's entry of the model's pattern
     (:data:`DEFAULT_LAYER`; None is the default: full causal attention,
     RoPE, dense SwiGLU).  With routed experts ``valid`` [B] int is the
@@ -705,6 +738,17 @@ def llama_block(x, hidden, num_heads, num_kv_heads, seq_len, head_dim,
                     limit=layer.get("swiglu_limit"), norm_kind=norm_kind,
                     h=h if norm == "parallel" else None,
                     residual_scale=residual_scale)
+    if layer["mixer"] is None or layer["ffn"] is None:
+        if norm != "pre" or (layer["mixer"] is None
+                             and layer["ffn"] is None):
+            raise ValueError(
+                f"a layer of one sublayer (mixer {layer['mixer']!r}, ffn "
+                f"{layer['ffn']!r}) has that one, under norm 'pre' (got "
+                f"{norm!r})")
+    if layer["mixer"] is None:
+        # the FFN alone, on the layer's one norm
+        out = _ffn(x, x_in, hidden, intermediate, **dict(ffn_args, h=h))
+        return (out, None, None) if collect_kv else out
     if layer["mixer"] != "attention":
         state = None
         if layer["mixer"]["kind"] == "conv":
@@ -958,6 +1002,12 @@ def _swiglu(h, hidden, width, gate_up_name, down_name, limit=None):
                    pname=down_name)
 
 
+def _relu2_mlp(h, hidden, width, up_name, down_name):
+    """``relu(h W_up)^2 W_down``: two matrices and no gate."""
+    up = layers.square(layers.relu(_linear(h, width, pname=up_name)))
+    return _linear(up, hidden, pname=down_name)
+
+
 def _ffn(x, x_in, hidden, intermediate, ffn, p, rms_norm_eps, valid, taps,
          norm="pre", limit=None, norm_kind="rms", h=None,
          residual_scale=1.0):
@@ -967,8 +1017,12 @@ def _ffn(x, x_in, hidden, intermediate, ffn, p, rms_norm_eps, valid, taps,
     (:func:`_norm_modes`; the output's is ``.ln2`` under "post",
     ``.ln2_post`` beside the input's ``.ln2``).  Under "parallel" ``h``
     is the layer's one normed input, which the mixer read too, and there
-    is no ``.ln2``.  ``limit``: the SwiGLUs' clamp; ``residual_scale``:
-    what the FFN's output is multiplied by as it joins the stream."""
+    is no ``.ln2`` (so too in a layer that is an FFN alone).  ``limit``:
+    the SwiGLUs' clamp; ``residual_scale``: what the FFN's output is
+    multiplied by as it joins the stream.  ``ffn`` None: the layer has no
+    second half and x is handed back."""
+    if ffn is None:
+        return x
     pre, post = _norm_modes(norm)
     if h is None:
         h = _norm(x, rms_norm_eps, p("ln2"), norm_kind) if pre else x
@@ -978,15 +1032,22 @@ def _ffn(x, x_in, hidden, intermediate, ffn, p, rms_norm_eps, valid, taps,
                     **clamp)
     else:
         taps = taps if taps is not None else {}
+        # (a latent layer's experts read and write rows of that width:
+        # down before the dispatch, up after the combine, once a row)
+        latent = ffn.get("latent")
+        u = _linear(h, int(latent), pname=p("moe.latent_down.w")) \
+            if latent else h
         y, counts, logits = layers.moe_routed_ffn(
-            h, h if ffn.get("route_from", "raw") == "normed" else x_in,
+            u, h if ffn.get("route_from", "raw") == "normed" else x_in,
             ffn["experts"], ffn["top_k"], ffn["width"],
             activation=ffn.get("activation", "relu"), valid=valid,
             name=p("moe"), keep_router_logits=bool(taps.get("keep_logits")),
             **{k: ffn[k] for k in ("score", "expert_bias", "norm_topk",
                                    "route_scale", "held", "n_group",
-                                   "topk_group") if k in ffn},
+                                   "topk_group", "gated") if k in ffn},
             **clamp)
+        if latent:
+            y = _linear(y, hidden, pname=p("moe.latent_up.w"))
         if int(ffn.get("n_group", 1)) > 1:
             counts, group_rows = counts
             taps.setdefault("group_rows", []).append(group_rows)
@@ -998,7 +1059,10 @@ def _ffn(x, x_in, hidden, intermediate, ffn, p, rms_norm_eps, valid, taps,
             # expert-parallel group alike, so counted once
             shared = _swiglu(
                 h, hidden, int(ffn["shared_width"]),
-                p("moe.shared_gate_up.w"), p("moe.shared_down.w"), **clamp)
+                p("moe.shared_gate_up.w"), p("moe.shared_down.w"), **clamp) \
+                if ffn.get("gated", True) else _relu2_mlp(
+                    h, hidden, int(ffn["shared_width"]),
+                    p("moe.shared_up.w"), p("moe.shared_down.w"))
             if float(ffn.get("shared_scale", 1.0)) != 1.0:
                 shared = layers.scale(shared,
                                       scale=float(ffn["shared_scale"]))
@@ -1246,6 +1310,8 @@ def build_llama_prefill(batch_size, seq_len, vocab_size=32000,
                               norm_kind=norm_kind,
                               residual_scale=residual_scale,
                               attn_scale=attn_scale, **state)
+        if lspec["mixer"] is None:
+            continue                 # an FFN alone leaves nothing behind
         if lspec["mixer"] != "attention":
             if not caches:
                 matrix = "ssm_state" if lspec["mixer"]["kind"] == "ssd" \
@@ -1428,7 +1494,9 @@ def build_llama_decode(num_slots, max_seq_len, vocab_size=32000,
     for i in range(num_layers):
         caches = _cache_vars(gblock, spec, i)
         lspec = layer_spec(layer_pattern, i)
-        if lspec["mixer"] != "attention":
+        if lspec["mixer"] is None:
+            cache = {}
+        elif lspec["mixer"] != "attention":
             cache = {"conv_state": _layer_state(lspec, caches),
                      "live": live}
         else:
@@ -1532,7 +1600,8 @@ def _chunk_forward(chunk_len, max_seq_len, num_pages, page_tokens,
         # (j <= base + t) is exactly causal-over-prefix-plus-chunk
         x = llama_block(x, hidden, num_heads, num_kv_heads, chunk_len,
                         head_dim, intermediate, name=f"{name}.blk{i}",
-                        kv_cache=_cache_vars(block, spec, i), positions=base,
+                        kv_cache=_cache_vars(block, spec, i) or None,
+                        positions=base,
                         block_table=bt_window if i in windowed
                         else block_table, kv_lengths=ck_len,
                         rms_norm_eps=rms_norm_eps, rope_base=rope_base,

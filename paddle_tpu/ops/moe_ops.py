@@ -70,7 +70,10 @@ def _moe_routed_ffn(ctx, op):
     selection bias of sigmoid scoring (optional).  With the attribute
     ``held_first`` GateUpW and DownW hold the experts from that index on
     alone, one chip's share of RouterW's E, and Out is their part of the
-    sum.  Attribute ``limit``: the experts' SwiGLU clamp.  Attributes
+    sum.  GateUpW [E, H, I] (not 2I) makes the experts two matrices
+    without a gate, ``W2 act(W1 x)``, and RouterX may be wider than X (a
+    router that reads the full row beside experts that work in a latent
+    one).  Attribute ``limit``: the experts' SwiGLU clamp.  Attributes
     ``n_group`` / ``topk_group``: group-limited selection
     (``route_top_k``); the output GroupRows [n_group] int32 then counts the
     valid rows that kept each group.  Inference only."""
@@ -90,9 +93,9 @@ def _moe_routed_ffn(ctx, op):
         valid = (t < n_valid.astype(jnp.int32)[:, None]).reshape(-1)
     n_group = int(op.attr("n_group", 1))
     topk_group = int(op.attr("topk_group", 1))
+    router_x = ctx.get_input(op, "RouterX")
     out, counts, logits = moe_routed_tokens(
-        x.reshape(-1, shape[-1]),
-        ctx.get_input(op, "RouterX").reshape(-1, shape[-1]),
+        x.reshape(-1, shape[-1]), router_x.reshape(-1, router_x.shape[-1]),
         ctx.get_input(op, "RouterW"), ctx.get_input(op, "GateUpW"),
         ctx.get_input(op, "DownW"), top_k=int(op.attr("top_k")),
         activation=op.attr("activation", "relu"), valid=valid,

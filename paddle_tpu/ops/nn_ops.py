@@ -871,8 +871,15 @@ def _rms_norm(ctx, op):
     scale = ctx.get_input(op, "Scale")
     eps = op.attr("epsilon", 1e-6)
     xf = x.astype("float32")
+    group = op.attr("group_size", None)
+    if group:
+        # the mean over each run of ``group`` consecutive channels apart
+        # (a state-space layer's gated norm with more than one group)
+        xf = xf.reshape(xf.shape[:-1] + (xf.shape[-1] // group, group))
     ms = jnp.mean(xf * xf, axis=-1, keepdims=True)
     y = xf / jnp.sqrt(ms + eps)
+    if group:
+        y = y.reshape(x.shape)
     if scale is not None:
         y = y * scale
     ctx.set_output(op, "Y", y.astype(x.dtype))

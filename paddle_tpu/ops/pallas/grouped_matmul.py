@@ -173,20 +173,25 @@ def _epilogue_kernel(offsets_ref, group_ref, block_ref, rows_ref, w_ref,
 
 
 def grouped_matmul_epilogue(rows, weights, group_sizes, row_scale=None, *,
-                            tm, tn, gate=None, interpret=False):
+                            tm, tn, gate=None, act=None, interpret=False):
     """:func:`grouped_matmul` whose accumulator goes through an epilogue
     before the masked store.  ``gate`` (needs :func:`gate_fits`): a
     function of the ``[tm, N]`` float32 accumulator that yields ``[tm, N
     // 2]`` (``parallel/moe.py`` ``_gated`` with its arguments bound), and
-    the result is [M, N // 2].  ``row_scale`` [M] float32: row ``r`` of the
+    the result is [M, N // 2].  ``act``, in its place: a function of a
+    ``[tm, tn]`` block that keeps its shape (an activation alone, for
+    experts of two matrices: ``_activation``), under any column block, and
+    the result is [M, N].  ``row_scale`` [M] float32: row ``r`` of the
     result is multiplied by ``row_scale[r]``, after the gate.  Rows past
     ``group_sizes.sum()`` are left as they lie, unscaled."""
     m, k = rows.shape
     groups, _, n = weights.shape
-    if gate is not None and not gate_fits(n, tn):
+    if gate is not None and act is not None:
+        raise ValueError("an epilogue gates or activates, not both")
+    halve = 2 if gate is not None else 1
+    if halve == 2 and not gate_fits(n, tn):
         raise ValueError(f"a gate epilogue needs a row's {n} columns in one "
                          f"block of whole lane tiles a half, not {tn}")
-    halve = 2 if gate is not None else 1
     offsets, group, block, n_visits = visits(group_sizes, m, tm)
     operands = [rows, weights]
     in_specs = [
@@ -197,7 +202,8 @@ def grouped_matmul_epilogue(rows, weights, group_sizes, row_scale=None, *,
         operands.append(row_scale.astype(jnp.float32).reshape(m, 1))
         in_specs.append(pl.BlockSpec((tm, 1), lambda j, v, o, g, b: (b[v], 0)))
     return pl.pallas_call(
-        functools.partial(_epilogue_kernel, tm=tm, gate=gate,
+        functools.partial(_epilogue_kernel, tm=tm,
+                          gate=gate if act is None else act,
                           scaled=row_scale is not None),
         out_shape=jax.ShapeDtypeStruct((m, n // halve), jnp.float32),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -212,7 +218,7 @@ def grouped_matmul_epilogue(rows, weights, group_sizes, row_scale=None, *,
             vmem_limit_bytes=VMEM_LIMIT),
         cost_estimate=pl.CostEstimate(
             flops=2 * m * k * n,
-            transcendentals=m * n // 2 if gate is not None else 0,
+            transcendentals=m * n // 2 if halve == 2 else 0,
             bytes_accessed=4 * (m * k * (n // tn) + groups * k * n
                                 + m * n // halve)),
         name="grouped_matmul_ragged-dot",

@@ -6,7 +6,11 @@ The state of a slot a layer is ``[N, H P]``: N state rows on sublanes,
 every head's P channels side by side on lanes.  ``x`` and ``y`` are then
 rows as they lie in HBM, ``B`` and ``C`` columns that every lane shares
 (one group), the decay a row, and nothing in either kernel is a head's
-own but the decay's value.
+own but the decay's value.  With G groups of ``B`` and ``C`` (``[.., G,
+N]``) a group's heads are H P / G consecutive lanes and a lane block lies
+inside one group (``_lane_block`` of a group's lanes): the block fetches
+its group's ``B`` and ``C`` by its index, and the kernels' bodies are the
+one group's.
 
 ``ssd_step`` is the decode step: grid (lane block, slot); a block reads
 its ``[N, lanes]`` of ``S`` once, moves it on, ``S <- a * S + B x``, reads
@@ -74,14 +78,17 @@ def _lane_block(limit, lanes):
                        if tiles % d == 0 and d * LANES <= max(limit, LANES))
 
 
-def step_supported(state_shape) -> bool:
-    return state_shape[1] % ROWS == 0 and state_shape[2] % LANES == 0
+def step_supported(state_shape, groups=1) -> bool:
+    return (state_shape[1] % ROWS == 0
+            and state_shape[2] % (groups * LANES) == 0
+            and (groups == 1 or state_shape[1] % LANES == 0))
 
 
-def chunk_supported(x_shape, n_state, chunk) -> bool:
+def chunk_supported(x_shape, n_state, chunk, groups=1) -> bool:
     heads, p = x_shape[2], x_shape[3]
-    return (LANES % p == 0 and (heads * p) % LANES == 0
-            and n_state % ROWS == 0 and chunk % ROWS == 0)
+    return (LANES % p == 0 and (heads * p) % (groups * LANES) == 0
+            and n_state % ROWS == 0 and chunk % ROWS == 0
+            and (groups == 1 or n_state % LANES == 0))
 
 
 def _step_kernel(live_ref, bc_ref, xa_ref, s_ref, y_ref, s_out_ref):
@@ -111,13 +118,17 @@ def _step_kernel(live_ref, bc_ref, xa_ref, s_ref, y_ref, s_out_ref):
 @functools.partial(jax.jit, static_argnames=("interpret", "lanes_block"))
 def step(x, dt, a, bm, cm, d, state, live, interpret=False,
          lanes_block=None):
-    """x [n, H, P], dt [n, H], a, d [H], bm, cm [n, N] float32, ``state``
-    [n + 1, N, H P] (row n the trash row), ``live`` [n] int32 -> (out
-    [n, H, P], the state, live rows moved on in place).  ``lanes_block``:
-    lanes a block, at most (default ``STEP_LANES``)."""
+    """x [n, H, P], dt [n, H], a, d [H], bm, cm [n, N] (or [n, G, N])
+    float32, ``state`` [n + 1, N, H P] (row n the trash row), ``live`` [n]
+    int32 -> (out [n, H, P], the state, live rows moved on in place).
+    ``lanes_block``: lanes a block, at most (default ``STEP_LANES``)."""
     n, H, P = x.shape
     N, HP = state.shape[1:]
-    lb = _lane_block(lanes_block or STEP_LANES, HP)
+    G = 1 if bm.ndim == 2 else bm.shape[1]
+    lb = _lane_block(lanes_block or STEP_LANES, HP // G)
+    per = HP // G // lb                # lane blocks a group
+    if bm.ndim == 3:   # a group's [N] the block's lanes of a row of [G N]
+        bm, cm = bm.reshape(n, G * N), cm.reshape(n, G * N)
     # rows as the kernel takes them: dt x, and the decay a lane
     xa = jnp.stack([(dt[..., None] * x).reshape(n, HP),
                     jnp.repeat(jnp.exp(dt * a), P, axis=1)], axis=1)
@@ -139,7 +150,8 @@ def step(x, dt, a, bm, cm, d, state, live, interpret=False,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(HP // lb, n),
-            in_specs=[pl.BlockSpec((1, ROWS, N), lambda j, s, live: (s, 0, 0)),
+            in_specs=[pl.BlockSpec((1, ROWS, N), lambda j, s, live:
+                                   (s, 0, j // per if G > 1 else 0)),
                       pl.BlockSpec((1, 2, lb), row),
                       state_blk],
             out_specs=[pl.BlockSpec((1, 1, lb), row), state_blk]),
@@ -205,7 +217,8 @@ def _chunk_kernel(valid_ref, live_ref, x_ref, b_ref, c_ref, cc_ref, cr_ref,
 def chunk(x, dt, a, bm, cm, d, s0=None, valid=None, interpret=False,
           lanes_block=None, chunk=CHUNK):
     """The whole chunked scan as one kernel: x [B, T, H, P], dt [B, T, H],
-    a, d [H], bm, cm [B, T, N] float32, ``s0`` [B, N, H P], ``valid`` [B]
+    a, d [H], bm, cm [B, T, N] (or [B, T, G, N]) float32, ``s0`` [B, N,
+    H P], ``valid`` [B]
     -> (out [B, T, H, P], the state after the last real token [B, N,
     H P]), as ``ssd_ops.chunked``.  ``lanes_block``: lanes a grid step, at
     most (default ``CHUNK_LANES``); ``chunk``: tokens a chunk."""
@@ -217,8 +230,10 @@ def chunk(x, dt, a, bm, cm, d, s0=None, valid=None, interpret=False,
     valid = jnp.minimum(valid.astype(jnp.int32), T)
     if s0 is None:
         s0 = jnp.zeros((B, N, HP), jnp.float32)
-    lb = _lane_block(lanes_block or CHUNK_LANES, HP)
+    G = 1 if bm.ndim == 3 else bm.shape[2]
+    lb = _lane_block(lanes_block or CHUNK_LANES, HP // G)
     hb, J = lb // P, HP // lb                  # heads a block, blocks
+    per = J // G                               # blocks a group
     xm, dtm, bmm, cmm = (
         jnp.pad(t, ((0, 0), (0, n * L - T)) + ((0, 0),) * (t.ndim - 2))
         for t in masked(x, dt, bm, cm, valid))
@@ -226,6 +241,8 @@ def chunk(x, dt, a, bm, cm, d, s0=None, valid=None, interpret=False,
     cols = jnp.moveaxis(cum, 3, 1).reshape(B, J, n * L, hb)
     rows = jnp.transpose(cum, (0, 3, 1, 4, 2))               # [B, J, n, hb, L]
     xd = (dtm[..., None] * xm).reshape(B, n * L, HP)
+    if bm.ndim == 4:   # a group's [L, N] the block's lanes of [L, G N]
+        bmm, cmm = bmm.reshape(B, n * L, G * N), cmm.reshape(B, n * L, G * N)
 
     live = (valid + L - 1) // L           # chunks that hold a real row
 
@@ -235,7 +252,7 @@ def chunk(x, dt, a, bm, cm, d, s0=None, valid=None, interpret=False,
         return jnp.minimum(c, jnp.maximum(live[b] - 1, 0))
 
     shared = pl.BlockSpec((1, L, N), lambda b, j, c, valid, live:
-                          (b, real(c, b, live), 0))
+                          (b, real(c, b, live), j // per if G > 1 else 0))
     state_blk = pl.BlockSpec((1, N, lb), lambda b, j, c, valid, live:
                              (b, 0, j))
     y, state = pl.pallas_call(

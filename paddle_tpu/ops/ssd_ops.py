@@ -6,7 +6,11 @@ writes one outer product into, and reads along one vector::
 
 with ``x_t`` [P] a head, ``B_t`` and ``C_t`` [N] shared by every head (one
 group), ``dt_t`` > 0 and ``a_t = exp(dt_t A)``, ``A`` < 0, one number a
-head.  The transition is diagonal (a scalar times the identity): there is
+head.  With G groups ``B_t`` and ``C_t`` are [G, N] and the H heads lie in
+G runs of H / G consecutive heads, head ``h`` reading group ``h // (H /
+G)``'s: G recurrences of H / G heads each, side by side along the state's
+lanes (every operand below that is written ``[.., N]`` may be ``[.., G,
+N]``).  The transition is diagonal (a scalar times the identity): there is
 no correction along a key and so no triangular system, which is what sets
 this recurrence beside ``gated_delta_ops``' and not inside it.
 
@@ -94,6 +98,27 @@ def masked(x, dt, bm, cm, valid):
     return keep(x), keep(dt), keep(bm), keep(cm)
 
 
+def _over_groups(fn, x, dt, a, bm, cm, d, state, **kw):
+    """``fn`` (one of the three formulations below, as it stands for one
+    group) for ``bm``, ``cm`` of ``[.., G, N]``: the G groups' heads are G
+    recurrences that share nothing, so ``fn`` runs over each group's H / G
+    heads (``vmap``), a group's lanes of the state beside the next's."""
+    import jax
+
+    G = bm.shape[-2]
+    (H, P), lead = x.shape[-2:], x.shape[:-2]
+    per = H // G
+    sg = None if state is None \
+        else state.reshape(state.shape[:-1] + (G, per * P))
+    out, new = jax.vmap(
+        lambda x, dt, a, bm, cm, d, s: fn(x, dt, a, bm, cm, d, s, **kw),
+        in_axes=(-3, -2, 0, -2, -2, 0, None if sg is None else -2),
+        out_axes=(-3, -2))(
+            x.reshape(lead + (G, per, P)), dt.reshape(lead + (G, per)),
+            a.reshape(G, per), bm, cm, d.reshape(G, per), sg)
+    return out.reshape(x.shape), new.reshape(new.shape[:-2] + (H * P,))
+
+
 def recurrence(x, dt, a, bm, cm, d, s0=None, valid=None):
     """The definition, token by token under ``lax.scan``: x [B, T, H, P],
     dt [B, T, H], a, d [H], bm, cm [B, T, N], ``s0`` [B, N, H P] -> (out
@@ -101,6 +126,9 @@ def recurrence(x, dt, a, bm, cm, d, s0=None, valid=None):
     import jax
     import jax.numpy as jnp
 
+    if bm.ndim > dt.ndim:
+        return _over_groups(recurrence, x, dt, a, bm, cm, d, s0,
+                            valid=valid)
     B, T, H, P = x.shape
     N = bm.shape[-1]
     x, dt, bm, cm = masked(x, dt, bm, cm, valid)
@@ -126,6 +154,9 @@ def chunked(x, dt, a, bm, cm, d, s0=None, valid=None, chunk=CHUNK):
     import jax
     import jax.numpy as jnp
 
+    if bm.ndim > dt.ndim:
+        return _over_groups(chunked, x, dt, a, bm, cm, d, s0, valid=valid,
+                            chunk=chunk)
     B, T, H, P = x.shape
     N = bm.shape[-1]
     x, dt, bm, cm = masked(x, dt, bm, cm, valid)
@@ -171,6 +202,8 @@ def step(x, dt, a, bm, cm, d, state, live):
     [n, H, P], the state with live rows moved on)."""
     import jax.numpy as jnp
 
+    if bm.ndim > dt.ndim:
+        return _over_groups(step, x, dt, a, bm, cm, d, state, live=live)
     n, H, P = x.shape
     old = state[:n]
     decay = jnp.repeat(jnp.exp(dt * a), P, axis=1)          # [n, H P]
@@ -205,7 +238,7 @@ def _chunk_infer(op, block):
     x, bm = in_var(op, block, "X"), in_var(op, block, "Bm")
     set_out(op, block, "Out", x.shape, x.dtype)
     set_out(op, block, "StateOut",
-            (x.shape[0], bm.shape[2], x.shape[2] * x.shape[3]), x.dtype)
+            (x.shape[0], bm.shape[-1], x.shape[2] * x.shape[3]), x.dtype)
 
 
 @register_op("ssd_chunk", infer=_chunk_infer, grad=None)
@@ -217,10 +250,13 @@ def _ssd_chunk(ctx, op):
     s0 = ctx.get_input(op, "State0") if op.single_input("State0") else None
     valid = ctx.get_input(op, "Valid") if op.single_input("Valid") else None
     kernel, why = _kernel_route(ctx, "ssd_chunk")
-    if kernel and not ssd.chunk_supported(x.shape, bm.shape[-1], CHUNK):
+    groups = 1 if bm.ndim == 3 else bm.shape[2]
+    if kernel and not ssd.chunk_supported(x.shape, bm.shape[-1], CHUNK,
+                                          groups):
         kernel, why = False, (f"ssd_chunk with X {x.shape}, state rows "
-                              f"{bm.shape[-1]} (the kernel needs heads that "
-                              f"divide a lane tile, whole tiles of both)")
+                              f"{bm.shape[-1]} in {groups} group(s) (the "
+                              f"kernel needs heads that divide a lane "
+                              f"tile, whole tiles of both and of a group)")
     out, state = (ssd.chunk if kernel else chunked)(
         x, dt, a, bm, cm, d, s0=s0, valid=valid)
     _lowered("pallas" if kernel else "reference", why)
@@ -238,8 +274,8 @@ def _step_infer(op, block):
              stateful_outputs=("StateOut",))
 def _ssd_step(ctx, op):
     """X [slots, 1, H, P], Dt [slots, 1, H], A, D [H], Bm, Cm [slots, 1,
-    N] over State [slots + 1, N, H P]; Live [slots].  StateOut aliases
-    State."""
+    N] (or [slots, 1, G, N]) over State [slots + 1, N, H P]; Live [slots].
+    StateOut aliases State."""
     import jax.numpy as jnp
 
     from .pallas import ssd
@@ -249,9 +285,11 @@ def _ssd_step(ctx, op):
     state = ctx.get_input(op, "State")
     live = ctx.get_input(op, "Live")
     kernel, why = _kernel_route(ctx, "ssd_step")
-    if kernel and not ssd.step_supported(state.shape):
-        kernel, why = False, (f"ssd_step over state {state.shape} (the "
-                              f"kernel needs whole tiles of both)")
+    groups = 1 if bm.ndim == 2 else bm.shape[1]
+    if kernel and not ssd.step_supported(state.shape, groups):
+        kernel, why = False, (f"ssd_step over state {state.shape} in "
+                              f"{groups} group(s) (the kernel needs whole "
+                              f"tiles of both and of a group)")
     if kernel:
         out, new = ssd.step(x, dt, a, bm, cm, d, state,
                             live.astype(jnp.int32))

@@ -253,13 +253,13 @@ def _one_call(rows, weights, group_sizes, precision):
 
 
 def grouped_matmul(rows, weights, group_sizes, precision=None,
-                   mesh_devices=1, row_scale=None, gate=None):
+                   mesh_devices=1, row_scale=None, gate=None, act=None):
     """``rows`` [M, K], sorted by group, times ``weights`` [G, K, N]:
     row ``r`` of group ``g`` is multiplied by ``weights[g]``.  ``gate``
     ``(activation, limit)``: the result is :func:`_gated` of the product,
-    [M, N // 2]; ``row_scale`` [M]: row ``r`` of it times ``row_scale[r]``.
-    One formulation per shape, chosen on the chip with no flag (README
-    "Routed experts"; ``tools/moe_microbench.py``):
+    [M, N // 2] (``act``: :func:`_activation` of it, [M, N]); ``row_scale``
+    [M]: row ``r`` of it times ``row_scale[r]``.
+    One formulation per shape (README "Routed experts"):
 
     * float32 at "highest" on a TPU, one device, at least one row block
       of rows: the Pallas kernel of ``ops/pallas/grouped_matmul.py``
@@ -270,15 +270,15 @@ def grouped_matmul(rows, weights, group_sizes, precision=None,
       accumulator there, so neither [M, N] array gets a pass of its own
       (:func:`_with_epilogue`; README "Routed experts" has the times).
     * everything else (off a TPU, another dtype or precision, fewer rows
-      than a row block, under a mesh of more than one device: on a TPU
-      that last is a downgrade and is logged once): one
+      than a row block, under a mesh of more than one device: on a TPU that
+      last is a downgrade and is logged once): one
       ``jax.lax.ragged_dot`` call (XLA:TPU: a grouped Mosaic kernel that
       pays a whole tile for every group with a row; the CPU: a masked
       dense product), the gate and the scale after it in ``jnp``.
 
     ``grouped_matmul_lowered_pallas`` / ``..._ragged_dot`` count, per program
     build, which a product lowered to (``_held_share``'s runs call it too);
-    ``grouped_matmul_epilogue_gate`` / ``..._scale`` the epilogues built."""
+    ``grouped_matmul_epilogue_gate`` / ``_act`` / ``_scale`` the epilogues."""
     import jax
     import jax.numpy as jnp
 
@@ -293,14 +293,14 @@ def grouped_matmul(rows, weights, group_sizes, precision=None,
         kernel = False
         _downgrade(f"grouped_matmul under a {mesh_devices}-device mesh")
     _LOWERED["pallas" if kernel else "ragged_dot"].increase()
-    if kernel and (row_scale is not None or gate is not None):
+    if kernel and (row_scale is not None or gate is not None or act):
         return _with_epilogue(rows, weights, group_sizes, tiles, row_scale,
-                              gate)
+                              gate, act)
     if kernel:
         return pallas.grouped_matmul(rows, weights, group_sizes,
                                      tm=tiles[0], tn=tiles[1])
     return _after(_one_call(rows, weights, group_sizes, precision),
-                  row_scale, gate)
+                  row_scale, gate, act)
 
 
 def _gated(h, inter, activation, limit=None):
@@ -386,8 +386,7 @@ def moe_routed_tokens(x, router_x, router_w, w_gate_up, w_down, *,
     second product [N k, H] under its routing weight (both epilogues of the
     kernel, :func:`grouped_matmul`) and their gather back (:func:`_combine`):
     64 x 2560 x 768, top 6, rung 4096 of 2,900 real rows 16.70 ms (PR 56) ->
-    11.22, 8192 of 5,800 27.94 -> 20.17 (v5e, my chip run, PR 57).  ``limit``,
-    ``activation``: :func:`_gated`'s; the router's: :func:`route_top_k`'s.
+    11.22, 8192 of 5,800 27.94 -> 20.17 (v5e, PR 57).  :func:`_acted`.
 
     ``held_first`` (one chip's share of an expert-parallel group): the
     weights are those of experts ``held_first .. held_first + w_gate_up
@@ -433,10 +432,11 @@ def moe_routed_tokens(x, router_x, router_w, w_gate_up, w_down, *,
     group_sizes = jnp.bincount(flat, length=E).astype(jnp.int32)
     rows = jnp.take(x, order // top_k, axis=0, mode="clip")   # [N*k, H]
     # no [N*k, .] array gets a pass of its own where the products are the
-    # kernel: the gate is the first one's epilogue ([N*k, I] is written,
-    # never [N*k, 2I]) and the routing weight the second one's
-    act = grouped_matmul(rows, w_gate_up.astype(x.dtype), group_sizes,
-                         precision, mesh_devices, gate=(activation, limit))
+    # kernel: the gate (or, for experts of two matrices, the activation) is
+    # the first one's epilogue and the routing weight the second one's
+    act = grouped_matmul(
+        rows, w_gate_up.astype(x.dtype), group_sizes, precision, mesh_devices,
+        **_first_epilogue(w_gate_up.shape[2], inter, activation, limit))
     y = grouped_matmul(act, w_down.astype(x.dtype), group_sizes, precision,
                        mesh_devices,
                        row_scale=jnp.take(weights.reshape(-1), order))
@@ -530,7 +530,7 @@ def _held_share(x, local, weights, w_gate_up, w_down, activation,
         size = jnp.clip(jnp.minimum(ends, lo + run) - jnp.maximum(starts, lo),
                         0, None).astype(jnp.int32)
         h = _held_matmul(rows, w_gate_up, size, precision, mesh_devices)
-        y = _held_matmul(_gated(h, inter, activation, limit), w_down, size,
+        y = _held_matmul(_acted(h, inter, activation, limit), w_down, size,
                          precision, mesh_devices)
         y = y * jnp.take(pair_w, pairs)[:, None].astype(y.dtype)
         # rows past the held pairs belong to no group: whatever the
@@ -565,25 +565,31 @@ def _held_share(x, local, weights, w_gate_up, w_down, activation,
 # ---------------------------------------------------------------------------
 
 _FUSED = {"gate": _monitor.get("grouped_matmul_epilogue_gate"),
+          "act": _monitor.get("grouped_matmul_epilogue_act"),
           "scale": _monitor.get("grouped_matmul_epilogue_scale"),
           "combine": _monitor.get("moe_combine_gather")}
 
 
-def _after(out, row_scale, gate):
-    """:func:`grouped_matmul`'s gate and scale as passes of their own over
-    ``out``, where the kernel does not take them as epilogues."""
+def _after(out, row_scale, gate=None, act=None):
+    """:func:`grouped_matmul`'s gate (or activation) and scale as passes of
+    their own over ``out``, where the kernel does not take them as
+    epilogues."""
     if gate is not None:
         out = _gated(out, out.shape[1] // 2, *gate)
+    if act:
+        out = _activation(out, act)
     if row_scale is not None:
         out = out * row_scale[:, None].astype(out.dtype)
     return out
 
 
-def _with_epilogue(rows, weights, group_sizes, tiles, row_scale, gate):
+def _with_epilogue(rows, weights, group_sizes, tiles, row_scale, gate,
+                   act=None):
     """:func:`grouped_matmul` on the Pallas kernel (blocks ``tiles``) with
-    ``gate`` and ``row_scale`` as epilogues on its accumulator.  A gate
-    whose columns are not one block (``gate_fits``) leaves the kernel's
-    plain call and :func:`_after` to do both."""
+    ``gate`` (or ``act``) and ``row_scale`` as epilogues on its
+    accumulator.  A gate whose columns are not one block (``gate_fits``)
+    leaves the kernel's plain call and :func:`_after` to do both; an
+    activation is a column's own and fits any block."""
     import functools
 
     from ..ops.pallas import grouped_matmul as pallas
@@ -596,10 +602,14 @@ def _with_epilogue(rows, weights, group_sizes, tiles, row_scale, gate):
         _FUSED["gate"].increase()
         gate = functools.partial(_gated, inter=weights.shape[2] // 2,
                                  activation=gate[0], limit=gate[1])
+    elif act:
+        _FUSED["act"].increase()
+        act = functools.partial(_activation, activation=act)
     if row_scale is not None:
         _FUSED["scale"].increase()
-    return pallas.grouped_matmul_epilogue(rows, weights, group_sizes,
-                                          row_scale, tm=tm, tn=tn, gate=gate)
+    return pallas.grouped_matmul_epilogue(
+        rows, weights, group_sizes, row_scale, tm=tm, tn=tn, gate=gate,
+        act=act or None)
 
 
 def _combine(y, order, top_k, valid=None):
@@ -629,3 +639,39 @@ def _combine(y, order, top_k, valid=None):
     out = jnp.take(y, where, axis=0, mode="clip").reshape(
         top_k, n, -1).sum(axis=0)
     return out if valid is None else jnp.where(valid[:, None], out, 0)
+
+
+# ---------------------------------------------------------------------------
+# PR 63: experts of TWO matrices, ``W2 act(W1 u)`` (no gate matrix): their
+# first stack is [E, H, I], not [E, H, 2I], which is how the functions above
+# tell them apart.  Down here for PR 57's reason.
+# ---------------------------------------------------------------------------
+
+def _activation(h, activation):
+    """``relu(h) ** 2``, elementwise: "relu2", the one activation experts
+    of two matrices have."""
+    import jax.numpy as jnp
+
+    if activation != "relu2":
+        raise ValueError(f"experts without a gate take the activation "
+                         f"'relu2', not {activation!r}")
+    h = jnp.maximum(h, 0)
+    return h * h
+
+
+def _first_epilogue(cols, inter, activation, limit):
+    """:func:`grouped_matmul`'s epilogue for a layer's first product of
+    ``cols`` columns over experts of width ``inter``: the gate (gate | up,
+    2 inter columns), or the activation alone, which no ``limit`` clamps."""
+    if cols != inter:
+        return {"gate": (activation, limit)}
+    if limit is not None:
+        raise ValueError("a clamp (limit) is the gated experts'")
+    return {"act": activation}
+
+
+def _acted(h, inter, activation, limit=None):
+    """What an expert's second matrix reads, from its first product ``h``
+    [M, 2 inter] or [M, inter]: the same epilogue, as a pass of its own."""
+    return _after(h, None,
+                  **_first_epilogue(h.shape[1], inter, activation, limit))

@@ -536,7 +536,8 @@ class GenerationEngine:
                  spec_tokens=None, spec_ngram=None, num_window_pages=None):
         import paddle_tpu as pt
         from ..compile_cache import ensure_compile_cache
-        from ..models.llama import build_llama_prefill, layer_spec
+        from ..models.llama import (build_llama_prefill, expert_layers,
+                                    layer_spec)
 
         ensure_compile_cache()
         self.model = dict(model)
@@ -634,7 +635,8 @@ class GenerationEngine:
         self._window_layers = kv.layers_of("window_pages")
         # attention layers whose pages hold one latent row a token
         self._latent_layers = kv.layers_of("latent_pages")
-        routed = [sp["ffn"] for sp in specs if sp["ffn"] != "dense"]
+        routed = [specs[i]["ffn"] for i in expert_layers(
+            self.model.get("layer_pattern"), self.model["num_layers"])]
         self._moe_top_k = routed[0]["top_k"] if routed else 0
         # one chip's share of an expert-parallel group: the range of the
         # router's experts held here (None: all), and a shared expert
@@ -2218,8 +2220,8 @@ class GenerationEngine:
         ``window`` of them."""
         ends = np.arange(base + 1, base + n + 1, dtype=np.int64)
         n_window = len(self._window_layers)
-        n_full = self.model["num_layers"] - n_window \
-            - len(self._state_layers)
+        # (a layer that is an FFN alone attends nothing)
+        n_full = len(self.kv.layers_of("pages")) + len(self._latent_layers)
         pairs = n_full * int(ends.sum())
         if n_window:
             pairs += n_window * int(np.minimum(ends, self.window).sum())
@@ -2255,11 +2257,14 @@ class GenerationEngine:
                     for c, n, rows, g in chunks]
                 if span is not None:
                     # (the counts come back with this fetch, after the
-                    # ``generation/prefill`` span that launched them)
+                    # ``generation/prefill`` span that launched them;
+                    # ``experts_held_touched`` summed over the prompt's
+                    # programs: each read the held experts it touched)
                     span.attrs.update({
                         k: sum(b[k] for b in booked) for k in (
                             "pairs_routed", "pairs_held", "rows_group_held",
-                            "pad_pairs_left_out") if k in booked[0]})
+                            "pad_pairs_left_out", "experts_held_touched")
+                        if k in booked[0]})
         finally:
             self._end_device_wait(span)
         return first

@@ -10,8 +10,10 @@ every cell.  The cell PR 56 added joined families and brought no entry
 (PR 55's rule): it is pinned by its row, as ``test_manifest.TABLE`` has
 the others'.  The cell PR 59 added joined families likewise and brought
 three entries for what no cell had (the state-space kernels): a group of
-their own, put on the collected module here.  No JAX is imported and no
-engine started.
+their own, put on the collected module here.  The cell PR 63 added did
+the same, with two entries for what no cell had (the grouped kernel's
+share of its roofline over every program that calls it, the rows a touched
+held expert multiplies) and one reader.  No JAX is imported and no engine started.
 """
 import importlib.util
 import json
@@ -61,6 +63,11 @@ manifest.reported_by = _reported_by_gate
 SSM = ["ssm_step_roofline.pool", "ssm_chunk_roofline.pool",
        "ssm_kernel_share_pct.pool"]
 manifest.GROUPS["state space"] = SSM
+# PR 63 likewise, two entries that only its cell reports
+LATENT_EXPERTS = ["expert_kernel_roofline.pool",
+                  "moe_rows_per_held_expert.pool"]
+manifest.GROUPS["experts in a latent row"] = LATENT_EXPERTS
+AFTER_SETUP = len(SSM) + len(LATENT_EXPERTS)   # entries behind `.setup`
 
 # the benchmark's own checks, collected here under their own names
 globals().update({name: fn for name, fn in vars(manifest).items()
@@ -149,6 +156,12 @@ GRANITE = "granite4h-micro-manychats"
 GRANITE_ROW = ("served_tokens_per_s", [
     "closed loop", "whole-prompt prefill", "step on its span",
     "paged decode kernel", "slot state", "state space"], 34)
+# the cell PR 63 added: families joined, and the group of its two entries
+NEMO = "nemotron3-super-agentfleet"
+NEMO_ROW = ("served_tokens_per_s", [
+    "closed loop", "experts", "experts, a share held",
+    "whole-prompt prefill", "step on its span", "paged decode kernel",
+    "slot state", "state space", "experts in a latent row"], 40)
 JOINED = {DSV2: {"config": "deepseek-v2", "mix": "docqa-pool",
                  "reduced": ["num_hidden_layers", "n_routed_experts",
                              "vocab_size"],
@@ -157,7 +170,13 @@ JOINED = {DSV2: {"config": "deepseek-v2", "mix": "docqa-pool",
           GRANITE: {"config": "granite-4.0-h-micro",
                     "mix": "manychats-pool",
                     "reduced": ["num_hidden_layers", "layer_types"],
-                    "driver": "serve_delta", "rungs": [128, 512, 1024]}}
+                    "driver": "serve_delta", "rungs": [128, 512, 1024]},
+          NEMO: {"config": "nemotron3-super-120b-a12b",
+                 "mix": "agentfleet-pool",
+                 "reduced": ["num_hidden_layers", "hybrid_override_pattern",
+                             "n_routed_experts", "vocab_size",
+                             "num_nextn_predict_layers"],
+                 "driver": "serve_share", "rungs": [256, 256, 512]}}
 CELLS_AT_PR54 = 11
 
 
@@ -166,7 +185,7 @@ def _json(*parts):
         return json.load(f)
 
 
-def test_the_benchmark_has_eleven_configurations_and_thirteen_cells():
+def test_the_benchmark_has_twelve_configurations_and_fourteen_cells():
     spec = manifest.SPEC
     assert [c["name"] for c in spec["configs"]] == [
         "bert-base-mlm", "mistral-7b-v0.1", "smallthinker-21b-a3b",
@@ -178,7 +197,7 @@ def test_the_benchmark_has_eleven_configurations_and_thirteen_cells():
         "bert-base-seq512-dp4", "smallthinker21b-mixedlen",
         "sdar30b-blockgen", "lfm2-24b-longanswer"] + list(ADDED) \
         + list(JOINED)
-    assert (len(spec["configs"]), len(manifest.CELLS)) == (11, 13)
+    assert (len(spec["configs"]), len(manifest.CELLS)) == (12, 14)
     assert [w["name"] for w in spec["workloads"] if w["chips"] == 4] \
         == ["bert-base-seq512-dp4"]
     for cell, joined in JOINED.items():
@@ -187,9 +206,10 @@ def test_the_benchmark_has_eleven_configurations_and_thirteen_cells():
             == (1, joined["config"], joined["mix"])
     assert spec["workloads"][-1] == new
     # a cell that joins families adds no entry; PR 59's brought three for
-    # what no cell had, at the end
-    assert len(manifest.ENTRIES) == 97 + len(SSM) == 100
-    assert [m["name"] for m in manifest.ENTRIES][-len(SSM):] == SSM
+    # what no cell had, at the end, and PR 63's two behind them
+    assert len(manifest.ENTRIES) == 97 + AFTER_SETUP == 102
+    assert [m["name"] for m in manifest.ENTRIES][-AFTER_SETUP:] \
+        == SSM + LATENT_EXPERTS
     used = {w["config"] for w in spec["workloads"]}
     assert used == {c["name"] for c in spec["configs"]}
     for c in spec["configs"]:
@@ -224,7 +244,7 @@ def test_every_cell_reports_the_start_up_account(name):
                      "workloads": manifest.CELLS[:CELLS_AT_PR54]}
     assert manifest.BY_NAME[name] == dict(entry, workloads=manifest.CELLS)
     assert [m["name"] for m in manifest.ENTRIES][
-        -len(SETUP) - len(SSM):-len(SSM)] == list(SETUP)
+        -len(SETUP) - AFTER_SETUP:-AFTER_SETUP] == list(SETUP)
     gate, = [m for m in manifest.SPEC["end_to_end"]
              if m["name"] == "setup_s"]
     assert "workloads" not in gate and gate["bound"] == 0.1
@@ -520,7 +540,8 @@ def test_the_joined_cell_reports_its_groups_and_forty_values(monkeypatch):
     # it: the cells stand in the order they came
     for name in manifest.entries_of(DSV2):
         lists = manifest.BY_NAME[name]["workloads"]
-        assert lists[lists.index(DSV2) + 1:] in ([], [GRANITE]), name
+        assert lists[lists.index(DSV2) + 1:] in (
+            [], [GRANITE], [NEMO], [GRANITE, NEMO]), name
     assert DSV2 not in manifest.TABLE         # (a ``benchmark`` PR's to add)
 
 
@@ -634,8 +655,8 @@ def test_the_fixture_is_the_whole_parent_and_52_names_went():
     went = {r["old"] for r in at_pr54} - set(manifest.BY_NAME)
     came = set(manifest.BY_NAME) - {r["old"] for r in at_pr54}
     assert (len(went), len(came), len(manifest.ENTRIES)) \
-        == (52, 22 + len(SSM), 100)
-    assert came >= set(SSM)
+        == (52, 22 + AFTER_SETUP, 102)
+    assert came >= set(SSM + LATENT_EXPERTS)
     for r in at_pr54:
         assert set(r["cells"]) \
             <= set(manifest.BY_NAME[r["new"]]["workloads"]), r
@@ -658,13 +679,14 @@ def test_the_state_space_cell_reports_its_groups_and_thirty_four_values():
     finally:
         manifest.GROUPS = kept
     for name in manifest.entries_of(GRANITE):
-        assert manifest.BY_NAME[name]["workloads"][-1] == GRANITE, name
+        lists = manifest.BY_NAME[name]["workloads"]
+        assert lists[lists.index(GRANITE) + 1:] in ([], [NEMO]), name
     assert GRANITE not in manifest.TABLE       # (a ``benchmark`` PR's to add)
     for name in SSM:
         assert manifest.BY_NAME[name] == {
             "name": name, "unit": "%", "better": "higher",
             "source": "device_trace", "layer": "kernels",
-            "moves": "served_tokens_per_s", "workloads": [GRANITE]}
+            "moves": "served_tokens_per_s", "workloads": [GRANITE, NEMO]}
     # nothing of the delta rule's or the experts' is claimed
     for name in ("delta_step_roofline.pool", "delta_kernel_share_pct.pool",
                  "expert_matmul_share_pct.pool",
@@ -734,7 +756,7 @@ def test_the_state_space_configuration_cuts_depth_and_no_width():
               if c["name"] == joined["config"]]
     assert entry["reduced"] == cfg["reduced"] == joined["reduced"]
     assert entry["source"] == cfg["source"]
-    assert manifest.SPEC["configs"][-1] == entry
+    assert manifest.SPEC["configs"][-2] == entry
     assert (cfg["hidden_size"], cfg["num_attention_heads"],
             cfg["num_key_value_heads"], cfg["intermediate_size"],
             cfg["shared_intermediate_size"], cfg["mamba_n_heads"],
@@ -768,6 +790,245 @@ def test_the_state_space_configuration_cuts_depth_and_no_width():
     mix = _json("traffic", joined["mix"] + ".json")
     assert (mix["engine"]["num_slots"], mix["engine"]["max_seq_len"],
             mix["warm_blocks"] * mix["block"]) == (128, 1792, 128)
+
+
+# -- PR 63: a cell that joined families and brought two entries --------------
+
+def test_the_latent_expert_cell_reports_its_groups_and_forty_values():
+    """Its row: the 22 of the closed loop, the experts' two, the held
+    share's two, the whole-prompt prefill, the step on its span, the paged
+    decode kernel, slot state with the scan's padding, the three of the
+    state-space kernels, its own two and the four of the start-up
+    account.  No group of the delta rule, of latent pages or of chunks."""
+    groups = dict(manifest.GROUPS)
+    groups["slot state"] = groups["slot state"] + ["scan_pad_pct.pool"]
+    manifest.GROUPS, kept = groups, manifest.GROUPS
+    try:
+        assert manifest.check_cell(NEMO, NEMO_ROW) == NEMO_ROW[2] == 40
+    finally:
+        manifest.GROUPS = kept
+    for name in manifest.entries_of(NEMO):
+        assert manifest.BY_NAME[name]["workloads"][-1] == NEMO, name
+    assert NEMO not in manifest.TABLE          # (a ``benchmark`` PR's to add)
+    kernel, rows = (manifest.BY_NAME[n] for n in LATENT_EXPERTS)
+    assert kernel == {
+        "name": LATENT_EXPERTS[0], "unit": "%", "better": "higher",
+        "source": "device_trace", "layer": "kernels",
+        "moves": "served_tokens_per_s", "workloads": [NEMO]}
+    assert rows == {
+        "name": LATENT_EXPERTS[1], "unit": "rows", "better": "higher",
+        "source": "program_span", "layer": "expert path",
+        "moves": "served_tokens_per_s", "workloads": [NEMO]}
+    for name in ("delta_step_roofline.pool", "mla_kernel_share_pct.pool",
+                 "prefill_chunk_roofline.pool", manifest.TOUCHED,
+                 "attention_kernel_share_pct.pool"):
+        assert NEMO not in manifest.BY_NAME[name]["workloads"], name
+    manifest.check_cell_loads(NEMO)
+
+
+@pytest.mark.parametrize("name,reader,key,reads", [
+    ("decode_step_roofline.pool", "roofline_span", "attrs",
+     ["experts_held_touched", "live_positions", "state_slots"]),
+    ("decode_step_roofline.pool", "roofline_span", "fn",
+     "ops_bytes_nemotron_h.decode_step_bytes"),
+    ("prefill_roofline.pool", "roofline", "fn",
+     "ops_bytes_nemotron_h.prefill_flops"),
+    ("paged_kernel_roofline.pool", "roofline_kernel", "fn",
+     "ops_bytes_nemotron_h.paged_kernel_bytes"),
+    ("state_slots_pct.pool", "span_attr_mean", "scale", 100 / 128),
+    ("scan_pad_pct.pool", "span_attr_ratio", "num", "scan_pad_chunks"),
+    ("ssm_step_roofline.pool", "roofline_kernel", "fn",
+     "ops_bytes_nemotron_h.ssm_step_bytes"),
+    ("ssm_step_roofline.pool", "roofline_kernel", "pattern", "^%?ssd_step"),
+    ("ssm_chunk_roofline.pool", "roofline_kernel_prefill", "fn",
+     "ops_bytes_nemotron_h.ssm_chunk_bytes"),
+    ("ssm_kernel_share_pct.pool", "trace_op_share", "pattern", "^%?ssd_"),
+    ("moe_held_touched_pct.pool", "span_attr_mean", "per",
+     "n_routed_experts"),
+    ("moe_pairs_held_pct.pool", "span_attr_ratio", "den", ["pairs_routed"]),
+    ("expert_matmul_share_pct.pool", "trace_op_share", "pattern",
+     "ragged-dot"),
+    ("expert_kernel_roofline.pool", "roofline_kernel_calls", "fn",
+     "ops_bytes_nemotron_h.expert_kernel_bytes"),
+    ("expert_kernel_roofline.pool", "roofline_kernel_calls", "attrs",
+     ["experts_held_touched", "pairs_held"]),
+    ("expert_kernel_roofline.pool", "roofline_kernel_calls", "pattern",
+     "^%?grouped_matmul_ragged-dot"),
+    ("moe_rows_per_held_expert.pool", "span_attr_ratio", "num",
+     "pairs_held"),
+    ("moe_rows_per_held_expert.pool", "span_attr_ratio", "den",
+     ["experts_held_touched"]),
+    ("moe_rows_per_held_expert.pool", "span_attr_ratio", "scale", 0.2),
+])
+def test_the_latent_expert_cell_hands_each_family_its_own_arguments(
+        name, reader, key, reads):
+    """What the cell's own files give a family's reader, resolved as the
+    harness resolves it, and that the program makes it: the kernels by the
+    ``name=`` of their ``pallas_call``, the attributes by their names in
+    ``serving/generation.py``."""
+    import re
+
+    got_reader, args = manifest.check_arguments(name, NEMO)
+    assert got_reader == reader and args[key] == reads
+    with open(os.path.join(REPO, "paddle_tpu", "ops", "pallas",
+                           "grouped_matmul.py")) as f:
+        assert 'name="grouped_matmul_ragged-dot"' in f.read()
+    with open(os.path.join(REPO, "paddle_tpu", "serving",
+                           "generation.py")) as f:
+        engine = f.read()
+    for attr in ("state_slots=", "live_positions=", "pairs_held=",
+                 "pairs_routed=", "experts_held_touched=", "scan_chunks="):
+        assert attr in engine, attr
+    if key == "pattern" and "ragged-dot" in reads and reads != "ragged-dot":
+        # the trace's text of such a call (my chip runs, PR 63): the decode
+        # program's and a rung's bear the same name, so the seconds are the
+        # kernel's in the whole trace, and the reader adds the prefills'
+        # bytes to the steps': the prefill's fetch span carries the counts
+        for rows in (320, 576, 1024):
+            assert re.search(reads, (
+                f"%grouped_matmul_ragged-dot.41 = f32[{rows},1024]"
+                "{1,0:T(8,128)S(1)} custom-call(s32[]{:T(128)} %get-tuple"))
+        spec = _json("metrics", name + ".json")
+        assert "same calls" in spec["why"] and "same names" in spec["why"]
+        assert '"pad_pairs_left_out", "experts_held_touched")' in engine
+    # a sibling's arguments are its own still
+    assert manifest.resolved("decode_step_roofline.pool", GRANITE)[1]["fn"] \
+        == "ops_bytes_granite_hybrid.decode_step_bytes"
+    assert manifest.resolved("moe_held_touched_pct.pool", SOLAR)[1]["per"] \
+        == "n_routed_experts"
+
+
+def test_the_latent_expert_configuration_cuts_what_it_says_and_no_width():
+    joined = JOINED[NEMO]
+    cfg = _json("configs", joined["config"] + ".json")
+    entry, = [c for c in manifest.SPEC["configs"]
+              if c["name"] == joined["config"]]
+    assert entry["reduced"] == cfg["reduced"] == joined["reduced"]
+    assert entry["source"] == cfg["source"]
+    assert manifest.SPEC["configs"][-1] == entry
+    # every width as published
+    assert (cfg["hidden_size"], cfg["num_attention_heads"],
+            cfg["num_key_value_heads"], cfg["head_dim"],
+            cfg["mamba_num_heads"], cfg["mamba_head_dim"],
+            cfg["ssm_state_size"], cfg["n_groups"], cfg["conv_kernel"],
+            cfg["expand"], cfg["moe_latent_size"],
+            cfg["moe_intermediate_size"],
+            cfg["moe_shared_expert_intermediate_size"],
+            cfg["n_shared_experts"], cfg["num_experts_per_tok"],
+            cfg["routed_scaling_factor"], cfg["mlp_hidden_act"],
+            cfg["layer_norm_epsilon"], cfg["tie_word_embeddings"]) \
+        == (4096, 32, 2, 128, 128, 64, 128, 8, 4, 2, 1024, 2688, 5376, 1, 22,
+            5, "relu2", 1e-5, False)
+    published = cfg["published"]
+    assert list(published) == joined["reduced"]
+    assert (published["num_hidden_layers"], published["n_routed_experts"],
+            published["vocab_size"], published["num_nextn_predict_layers"]) \
+        == (88, 512, 131072, 1)
+    pattern = published["hybrid_override_pattern"]
+    assert len(pattern) == 88 and set(pattern) == set("M*E")
+    assert (pattern.count("M"), pattern.count("E"), pattern.count("*")) \
+        == (40, 40, 8)
+    # the cut: the first eleven letters, one whole period; 32 of the
+    # router's 512 held; an eighth of the vocabulary; no drafting module
+    assert cfg["hybrid_override_pattern"] == pattern[:11] == "MEMEMEM*EME"
+    assert (cfg["num_hidden_layers"], cfg["n_routed_experts"],
+            cfg["vocab_size"], cfg["num_nextn_predict_layers"]) \
+        == (11, 32, 131072 // 8, 0)
+    assert cfg["expert_share"]["router_experts"] == 512
+    assert cfg["expert_share"]["first"] == 0
+    assert cfg["as_run"]["dtype"] == "float32"
+    for key in ("assumed", "as_run", "deployment", "check_tolerance",
+                "rehearse", "builder", "per_layer_args"):
+        assert key in cfg
+    assert len(cfg["check_tolerance"]["why"]) > 200
+    assert "TO FILL" not in json.dumps(cfg)
+    assert os.path.exists(os.path.join(
+        BENCH, "builders", cfg["builder"] + ".py"))
+    mix = _json("traffic", joined["mix"] + ".json")
+    assert mix["driver"] == joined["driver"]
+    assert "TO FILL" not in json.dumps(mix)
+    assert (mix["engine"]["num_slots"], mix["engine"]["max_seq_len"],
+            mix["engine"]["prefill_buckets"], mix["reference_prompts"]) \
+        == (128, 1280, sorted(set(joined["rungs"])), [40, 200, 450])
+    # the issue's traffic as named; of its ladder, the rung alone went
+    assert mix["prompt_len"] == {"dist": "lognormal", "median": 160,
+                                 "sigma": 0.7, "min": 32, "max": 512}
+    assert mix["warm_blocks"] * mix["block"] == 128
+    assert mix["per_layer_args"]["state_slots_pct.pool"]["scale"] \
+        == 100 / mix["engine"]["num_slots"]
+
+
+@pytest.mark.parametrize("case", ["steps and prefills", "steps alone",
+                                  "prefill spans of an earlier commit",
+                                  "no such kernel", "no trace"])
+def test_a_kernels_roofline_over_every_program_that_calls_it(case):
+    """``roofline_kernel_calls``: the steps' bytes and the prefills' over
+    the seconds of all the kernel's calls; where the prefill's fetch spans
+    lack the counts (the parent's) there is nothing to read, and nothing
+    is raised."""
+    import sys
+    import types
+
+    if BENCH not in sys.path:
+        sys.path.insert(0, BENCH)
+    import harness
+    import ops_bytes_nemotron_h as ob
+
+    cfg = _json("configs", "nemotron3-super-120b-a12b.json")
+    reader, args = harness.Cell(NEMO).reader_of("expert_kernel_roofline.pool")
+    read = harness.load_module("readers", reader).read
+
+    def span(name, start, **attrs):
+        return types.SimpleNamespace(name=name, start=start, attrs=attrs)
+
+    run = types.SimpleNamespace(peaks={"hbm_bytes_per_s": 819e9},
+                                trace_t0=100.0, trace_t1=101.0)
+    trace = {"to_monotonic": 100.0,
+             "modules": {"decode": [(0.0, 0.02), (0.03, 0.05), (0.5, 0.52)],
+                         "p256": [(0.06, 0.09), (0.3, 0.33)],
+                         "tiny": [(0.4, 0.4001)]},
+             "op_seconds": {"grouped_matmul_ragged-dot.40": 0.03,
+                            "grouped_matmul_ragged-dot.41": 0.02,
+                            "fusion.2": 0.2},
+             "op_text": {"grouped_matmul_ragged-dot.40":
+                         "%grouped_matmul_ragged-dot.40 = f32[320,2688]",
+                         "grouped_matmul_ragged-dot.41":
+                         "%grouped_matmul_ragged-dot.41 = f32[576,1024]",
+                         "fusion.2": "%fusion"}}
+    steps = [span("generation/decode_step", 100.01, pairs_held=850,
+                  experts_held_touched=30.0),
+             span("generation/decode_step", 100.04, pairs_held=910,
+                  experts_held_touched=31.0),
+             # (before the traced seconds: not a traced step)
+             span("generation/decode_step", 99.0, pairs_held=1,
+                  experts_held_touched=1.0)]
+    fetches = [span("generation/prefill_fetch", 100.07, pairs_held=1700,
+                    pairs_routed=27000, experts_held_touched=32.0),
+               span("generation/prefill_fetch", 100.31, pairs_held=1500,
+                    pairs_routed=24000, experts_held_touched=31.6)]
+    ctx = {"run": run, "cfg": cfg, "trace": trace,
+           "trace_spans": steps + fetches}
+    step = 3 * ob.expert_kernel_bytes(cfg, 30.5, 880.0, 4)
+    if case == "steps and prefills":
+        want = step + 2 * ob.expert_kernel_bytes(cfg, 31.8, 1600.0, 4)
+    elif case == "steps alone":
+        trace["modules"].pop("p256")
+        want = step
+    elif case == "prefill spans of an earlier commit":
+        for s in fetches:
+            del s.attrs["experts_held_touched"]
+        want = None
+    elif case == "no such kernel":
+        trace["op_seconds"] = {"fusion.2": 0.2}
+        want = None
+    else:
+        ctx, want = {}, None
+    got = read(ctx, **args)
+    if want is None:
+        assert got is None
+    else:
+        assert got == pytest.approx(100 * want / 819e9 / 0.05)
+        assert 0 < got < 100
 
 
 # -- PR 42: the exposed share of collectives, asynchronous ones counted -----

@@ -326,13 +326,23 @@ def test_every_state_space_layer_has_two_states(engine):
         assert n not in engine._weight_names()
 
 
-def test_more_than_one_group_is_refused():
+def test_more_than_one_group_widens_the_convolution_rows_alone():
+    """(PR 63 built what this test saw refused.)  Two groups of B and C
+    are ``2 * 2 * state`` of the convolution's channels; the matrix state
+    is every head's lanes, whatever the groups; groups that do not divide
+    the heads are refused."""
     from paddle_tpu.models.llama import cache_spec
 
-    mixer = dict(BUILDER.layer_pattern(_cfg())[0]["mixer"], groups=2)
+    def shapes(groups):
+        mixer = dict(BUILDER.layer_pattern(_cfg())[0]["mixer"], groups=groups)
+        return [e["shape"] for e in cache_spec(
+            "llama", 1, [{"mixer": mixer}], num_slots=2, num_pages=4,
+            page_tokens=PAGE, num_kv_heads=2, head_dim=16, hidden=64)]
+
+    assert shapes(1) == [[3, 3, 8 * 16 + 2 * 16], [3, 16, 8 * 16]]
+    assert shapes(2) == [[3, 3, 8 * 16 + 4 * 16], [3, 16, 8 * 16]]
     with pytest.raises(ValueError, match="groups of B and C"):
-        cache_spec("llama", 1, [{"mixer": mixer}], num_slots=2, num_pages=4,
-                   page_tokens=PAGE, num_kv_heads=2, head_dim=16, hidden=64)
+        shapes(3)
 
 
 def test_the_builder_reads_the_published_keys():

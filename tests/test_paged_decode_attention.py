@@ -349,6 +349,10 @@ def test_kernel_compiles_for_a_described_v5e(chip, slots, max_seq):
     (1024, 20, 4096, 2560, True), (768, 8, 7168, 4096, True),
     (768, 8, 2048, 7168, True), (768, 8, 4096, 8192, True),
     (64, 8, 4096, 4096, True),
+    # PR 63, 32 held experts of two matrices in a latent of 1024: a decode
+    # step's run of held pairs, up and down, and the widest rung's
+    (320, 32, 1024, 2688, True), (320, 32, 2688, 1024, True),
+    (1024, 32, 1024, 2688, True), (1024, 32, 2688, 1024, True),
 ])
 def test_grouped_matmul_kernel_compiles_for_a_described_v5e(chip, m, groups,
                                                             k, n, scoped):
@@ -430,6 +434,44 @@ def test_grouped_matmul_epilogues_compile_for_a_described_v5e(
     # no [M, 2I] array beside the gated one
     assert first.memory_analysis().temp_size_in_bytes < 2 ** 23
     assert first.memory_analysis().output_size_in_bytes == 4 * m * inter
+
+
+def test_grouped_matmul_activation_epilogue_compiles_for_a_described_v5e(
+        chip):
+    """PR 63: experts of two matrices.  The first product's epilogue is an
+    activation alone (``relu2``), a column's own, so it keeps its width
+    and takes any column block; the routing weight rides the second as it
+    does the gated experts'.  512 experts of 2688 in a latent of 1024, a
+    rung of 512 rows at 22 a token."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from paddle_tpu.ops.pallas import grouped_matmul as kernel
+    from paddle_tpu.parallel import moe
+
+    one_chip = SingleDeviceSharding(chip)
+
+    def spec(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    m, groups, k, inter = 512 * 22, 512, 1024, 2688
+    tm, tn = kernel.tiles(m, k, inter)
+    act = functools.partial(moe._activation, activation="relu2")
+    first = jax.jit(lambda r, w, s: kernel.grouped_matmul_epilogue(
+        r, w, s, tm=tm, tn=tn, act=act)).lower(
+        spec((m, k)), spec((groups, k, inter)),
+        spec((groups,), jnp.int32)).compile()
+    text = first.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "%grouped_matmul_ragged-dot" in text
+    assert first.memory_analysis().output_size_in_bytes == 4 * m * inter
+    with pytest.raises(ValueError, match="gate epilogue"):
+        kernel.grouped_matmul_epilogue(
+            jnp.zeros((64, 128)), jnp.zeros((2, 128, 384)),
+            jnp.zeros((2,), jnp.int32), tm=64, tn=128, gate=act)
 
 
 def test_decode_step_for_a_described_v5e_reads_the_pools_in_place(
@@ -702,6 +744,49 @@ def test_state_space_step_kernel_compiles_for_a_described_v5e(chip):
     assert step.as_text().count("tpu_custom_call") == 1
     state_bytes = (n + 1) * N * H * P * 4
     assert step.memory_analysis().temp_size_in_bytes < state_bytes // 4
+
+
+@pytest.mark.parametrize("rung", [None, 128, 256, 512])
+def test_grouped_state_space_kernels_compile_for_a_described_v5e(chip, rung):
+    """Both SSD kernels with EIGHT groups of B and C at the
+    ``nemotron3-super-agentfleet`` cell's shapes (128 heads of 64 over 128
+    state rows, a group's 16 heads 1024 lanes): the step over 128 slots
+    (``rung`` None), its state ``[129, 128, 8192]`` aliased in place, and
+    the scan over each prefill rung.  One Mosaic call each."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from paddle_tpu.ops.pallas import ssd as kern
+    from paddle_tpu.ops.ssd_ops import CHUNK
+
+    one_chip = SingleDeviceSharding(chip)
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    n, H, P, N, G = 128, 128, 64, 128, 8
+    if rung is None:
+        assert kern.step_supported((n + 1, N, H * P), G)
+        step = jax.jit(kern.step, donate_argnums=6).lower(
+            sds((n, H, P)), sds((n, H)), sds((H,)), sds((n, G, N)),
+            sds((n, G, N)), sds((H,)), sds((n + 1, N, H * P)),
+            sds((n,), jnp.int32)).compile()
+        assert step.as_text().count("tpu_custom_call") == 1
+        state_bytes = (n + 1) * N * H * P * 4
+        assert step.memory_analysis().temp_size_in_bytes < state_bytes // 4
+        return
+    assert kern.chunk_supported((1, rung, H, P), N, CHUNK, G)
+
+    def prefill(x, dt, a, bm, cm, d, valid):
+        return kern.chunk(x, dt, a, bm, cm, d, valid=valid)
+
+    scan = jax.jit(prefill).lower(
+        sds((1, rung, H, P)), sds((1, rung, H)), sds((H,)),
+        sds((1, rung, G, N)), sds((1, rung, G, N)), sds((H,)),
+        sds((1,), jnp.int32)).compile()
+    assert scan.as_text().count("tpu_custom_call") == 1
+    assert scan.memory_analysis().temp_size_in_bytes < 1.0e9
 
 
 @pytest.mark.parametrize("rung", [128, 256, 512, 1024])

@@ -16,7 +16,11 @@ rows, one group; float32), each against its XLA formulation
 
 ``python tools/ssd_microbench.py`` (chip only, about three minutes): each
 the median of ``--reps`` runs after a warm-up.  Writes
-``chiprun_out/ssd_microbench.json``.
+``chiprun_out/ssd_microbench.json``.  ``--heads 128 --groups 8`` (PR 63)
+times both at nemotron3-super-120b-a12b's sizes: 128 heads of 64 in eight
+groups of B and C, a state ``[129, 128, 8192]`` (lane blocks wider than a
+group's 1024 lanes are held to them); the file is then
+``ssd_microbench_g8.json``.
 """
 from __future__ import annotations
 
@@ -29,7 +33,7 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-H, P, N = 64, 64, 128
+P, N = 64, 128       # head_dim, state rows (heads: --heads, default 64)
 HBM = 819e9
 
 
@@ -59,7 +63,13 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--reps", type=int, default=7)
     ap.add_argument("--slots", type=int, default=128)
+    ap.add_argument("--heads", type=int, default=64)
+    ap.add_argument("--groups", type=int, default=1)
     args = ap.parse_args(argv)
+    H, G = args.heads, args.groups
+    # B and C of one group as the kernels have always taken them, [.., N]
+    bc = (lambda *lead: lead + (N,)) if G == 1 \
+        else (lambda *lead: lead + (G, N))
 
     import jax
     import jax.numpy as jnp
@@ -78,11 +88,12 @@ def main(argv=None) -> int:
 
     a = -jnp.asarray(rng.uniform(1, 16, (H,)), jnp.float32)
     d = jnp.ones((H,), jnp.float32)
-    out = {"device": jax.devices()[0].device_kind, "step": [], "chunk": []}
+    out = {"device": jax.devices()[0].device_kind, "heads": H, "groups": G,
+           "step": [], "chunk": []}
 
     # -- the step ----------------------------------------------------------
     n = args.slots
-    x, bm, cm = draw(n, H, P), draw(n, N), draw(n, N)
+    x, bm, cm = draw(n, H, P), draw(*bc(n)), draw(*bc(n))
     dt = jnp.asarray(np.exp(rng.uniform(np.log(1e-3), np.log(0.1), (n, H))),
                      jnp.float32)
     state0 = draw(n + 1, N, H * P)
@@ -130,7 +141,7 @@ def main(argv=None) -> int:
     # -- the chunked scan ----------------------------------------------------
     for T in (256, 1024):
         valid = jnp.asarray([T - T // 8], jnp.int32)
-        x, bm, cm = draw(1, T, H, P), draw(1, T, N), draw(1, T, N)
+        x, bm, cm = draw(1, T, H, P), draw(*bc(1, T)), draw(*bc(1, T))
         dt = jnp.asarray(np.exp(rng.uniform(np.log(1e-3), np.log(0.1),
                                             (1, T, H))), jnp.float32)
         want_y, want_s = jax.jit(ssd_ops.recurrence)(x, dt, a, bm, cm, d,
@@ -180,7 +191,8 @@ def main(argv=None) -> int:
         out["chunk"].append(row)
 
     os.makedirs("chiprun_out", exist_ok=True)
-    with open("chiprun_out/ssd_microbench.json", "w") as f:
+    name = "ssd_microbench.json" if G == 1 else f"ssd_microbench_g{G}.json"
+    with open(os.path.join("chiprun_out", name), "w") as f:
         json.dump(out, f, indent=1)
     return 0
 
