@@ -61,6 +61,16 @@ random seeded weights:
   multipliers: ``ssd_lowered_pallas`` grows, ``ssd_lowered_reference``
   stays 0.
 
+* **dense products that stop at the prompt's end** (PR 64) — a rung of
+  2048 rows through a two-slot engine of two layers (grouped-query
+  attention and a SwiGLU at hidden 2048 / 8192) whose prefill program
+  multiplies only the 256-row segments that hold a prompt token (op
+  ``mul_valid_rows``), at prompts of 600 and 2048 tokens, each against an
+  engine on the same weights whose program has the plain products: the
+  first token's logits and a decode step's (the pages written) within the
+  tolerance, and the rows counted (``prefill_rows_run`` 768 + 2048,
+  ``prefill_rows_skipped`` 1280).
+
 * **the program store** (PR 60) — first of all, before this process
   touches the chip: two child processes, one after the other, each build
   the same two-layer decoder (8 heads of 128 over 4 KV heads, two slots),
@@ -1836,6 +1846,71 @@ STORE_STATS = ("program_store_hits", "program_store_misses",
                "attention_lowered_paged_decode_reference")
 
 
+DENSE = dict(hidden=2048, heads=16, kv_heads=8, ffn=8192, vocab=4096,
+             rung=2048, prompts=(600, 2048), steps=1)
+
+
+def dense_rows_phase(cfg=DENSE):
+    """What a prefill whose dense products stop at the prompt's end adds
+    (PR 64): a 2048-row rung at ``valid`` 600 (three of eight segments run)
+    and 2048 (all eight) against the same program with the plain products
+    on the same weights, and the engine's account of the rows."""
+    import functools
+
+    from paddle_tpu.models.llama import build_llama_prefill
+    from paddle_tpu.serving import GenerationEngine
+
+    model = dict(vocab_size=cfg["vocab"], hidden=cfg["hidden"],
+                 num_layers=2, num_heads=cfg["heads"],
+                 num_kv_heads=cfg["kv_heads"], intermediate=cfg["ffn"])
+    kw = dict(num_slots=2, max_seq_len=cfg["rung"] + 64,
+              prefill_buckets=[cfg["rung"]], page_tokens=16,
+              prefill_chunk=0, prefix_reuse=False, speculate=False,
+              keep_logits=True, eos_id=-1)
+    gen = GenerationEngine(model, **kw)
+    plain = GenerationEngine(model, scope=gen.scope.new_scope(), **kw)
+    plain._build_fn_prefill = functools.partial(build_llama_prefill,
+                                                stop_at_prompt=False)
+    try:
+        for eng in (gen, plain):
+            eng.warmup()
+        ops = [[op.type for op in eng._prefill_prog_for(
+            cfg["rung"])[0].global_block().ops] for eng in (gen, plain)]
+        check("mul_valid_rows" in ops[0] and "mul_valid_rows" not in ops[1],
+              "the rung's program stops at the prompt, the other does not")
+        rng = np.random.default_rng(64)
+        worst, took = 0.0, {}
+        for n_prompt in cfg["prompts"]:
+            prompt = rng.integers(1, cfg["vocab"], n_prompt).tolist()
+            both = []
+            for tag, eng in (("stops", gen), ("plain", plain)):
+                t0 = time.perf_counter()
+                both.append(eng.generate(prompt, cfg["steps"] + 1,
+                                         timeout=600))
+                took[tag, n_prompt] = time.perf_counter() - t0
+            got, want = (np.stack(r["logits"]) for r in both)
+            rel = float(np.abs(got - want).max() / np.abs(want).max())
+            worst = max(worst, rel)
+            check(np.isfinite(got).all() and rel <= TOL
+                  and both[0]["tokens"] == both[1]["tokens"],
+                  f"a prompt of {n_prompt} on rung {cfg['rung']} off the "
+                  f"plain products by {rel:.4g}")
+        counters = gen.stats()["counters"]
+        run, skipped = (counters["prefill_rows_run"],
+                        counters["prefill_rows_skipped"])
+        check((run, skipped) == (768 + 2048, 1280),
+              f"rows counted: {run} run, {skipped} skipped")
+    finally:
+        gen.close()
+        plain.close()
+    say(f"dense rows: rung {cfg['rung']} at prompts {cfg['prompts']} within "
+        f"{worst:.4g} of the plain products; prefill_rows_run {run}, "
+        f"prefill_rows_skipped {skipped}; a request's wall time, stops | "
+        "plain: " + ", ".join(
+            f"{n}: {took['stops', n]:.3f} | {took['plain', n]:.3f} s"
+            for n in cfg["prompts"]))
+
+
 def store_child(cfg=STORE):
     """One of ``store_phase``'s two processes: the decoder's prefill rung
     and decode program warm, one prompt answered, and one line of JSON:
@@ -2029,6 +2104,11 @@ def main():
     single_sublayer_phase()
     say(f"grouped state-space kernels, latent experts of two matrices and "
         f"single-sublayer layers done [{time.perf_counter() - t0:.1f} s]")
+
+    t0 = time.perf_counter()
+    dense_rows_phase()
+    say(f"dense products that stop at the prompt's end done "
+        f"[{time.perf_counter() - t0:.1f} s]")
 
     say(f"set-up (compile-dominated: kernel check + first train step + "
         f"serving warm-up) {train['setup_s'] + serve['setup_s']:.1f} s, "

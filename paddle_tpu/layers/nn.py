@@ -14,7 +14,8 @@ from ..framework.initializer import ConstantInitializer, NormalInitializer
 from ..framework.layer_helper import LayerHelper
 
 __all__ = [
-    "fc", "conv2d", "conv2d_transpose", "pool2d", "batch_norm", "layer_norm",
+    "fc", "fc_valid_rows", "swiglu_valid_rows", "conv2d", "conv2d_transpose",
+    "pool2d", "batch_norm", "layer_norm",
     "group_norm", "instance_norm", "embedding", "dropout", "relu", "softmax",
     "log_softmax", "sigmoid", "tanh", "gelu", "leaky_relu", "relu6", "elu",
     "swish", "hard_sigmoid", "hard_swish", "prelu", "matmul", "bmm", "mul",
@@ -75,6 +76,52 @@ def fc(input, size, num_flatten_dims=1, param_attr=None, bias_attr=None,
     else:
         pre_act = pre_bias
     return helper.append_activation(pre_act, act)
+
+
+def fc_valid_rows(input, size, valid_rows, param_attr=None, name=None):
+    """``fc(input, size, num_flatten_dims=2, bias_attr=False)`` on rows
+    ``input`` [1, S, K] of which only the first ``valid_rows[0]`` hold
+    anything (a prompt padded to its rung): the product runs over the
+    segments of rows that hold one of them and the rows behind are zero
+    (op ``mul_valid_rows``).  The weight is ``fc``'s, under its name."""
+    from ..ops.math_ops import VALID_ROW_SEGMENT
+
+    helper = LayerHelper("fc", name=name)
+    w = helper.create_parameter(param_attr, [int(input.shape[2]), size],
+                                input.dtype)
+    out = helper.create_variable_for_type_inference(input.dtype)
+    helper.append_op("mul_valid_rows",
+                     inputs={"X": [input], "Y": [w],
+                             "ValidRows": [valid_rows]},
+                     outputs={"Out": [out]},
+                     attrs={"segment": VALID_ROW_SEGMENT})
+    return out
+
+
+def swiglu_valid_rows(input, width, size, valid_rows, gate_up_attr=None,
+                      down_attr=None, limit=None, name=None):
+    """``fc(silu(gate) * up, size)`` with ``gate | up = fc(input, 2 *
+    width)`` (no biases; with ``limit`` L the gate held under L and the up
+    to [-L, L]) on rows ``input`` [1, S, K] of which only the first
+    ``valid_rows[0]`` hold anything: both products run a segment of rows
+    at a time over the segments that hold one of them, the rows behind are
+    zero and no [S, 2 * width] is held (op ``swiglu_valid_rows``).  The
+    weights are the two ``fc``s', under their names."""
+    from ..ops.math_ops import VALID_ROW_SEGMENT
+
+    helper = LayerHelper("fc", name=name)
+    gate_up = helper.create_parameter(
+        gate_up_attr, [int(input.shape[2]), 2 * width], input.dtype)
+    down = helper.create_parameter(down_attr, [width, size], input.dtype)
+    out = helper.create_variable_for_type_inference(input.dtype)
+    attrs = {"segment": VALID_ROW_SEGMENT}
+    if limit is not None:
+        attrs["limit"] = float(limit)
+    helper.append_op("swiglu_valid_rows",
+                     inputs={"X": [input], "GateUp": [gate_up],
+                             "Down": [down], "ValidRows": [valid_rows]},
+                     outputs={"Out": [out]}, attrs=attrs)
+    return out
 
 
 def conv2d(input, num_filters, filter_size, stride=1, padding=0, dilation=1,

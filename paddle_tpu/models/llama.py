@@ -294,9 +294,35 @@ def expert_layers(layer_pattern, num_layers):
             if layer_spec(layer_pattern, i)["ffn"] not in ("dense", None)]
 
 
-def _linear(x, size, pname=None, name=None):
+def _linear(x, size, pname=None, name=None, rows=None):
+    """``x W`` over the last dim of x [B, S, K].  ``rows`` ([1] int32, in
+    a whole-prompt prefill of :func:`dense_rows_run`'s rungs): how many of
+    the S rows hold a token; the product stops at the segment that holds
+    the last of them and the rows behind are zero."""
+    if rows is not None:
+        return layers.fc_valid_rows(x, size, rows, param_attr=pname,
+                                    name=name)
     return layers.fc(x, size, num_flatten_dims=2, bias_attr=False,
                      param_attr=pname, name=name)
+
+
+# A whole-prompt prefill's dense products stop at the prompt's end in rungs
+# of at least this many rows: a shorter rung's program stays the plain one
+# (a decode gap that waits out a prefill waits out a short rung's).
+DENSE_MIN_ROWS = 2048
+
+
+def dense_rows_run(seq_len, prompt_len):
+    """Rows of a whole-prompt prefill rung of ``seq_len`` rows that its
+    dense products multiply at a prompt of ``prompt_len`` tokens: whole
+    segments (``ops/math_ops.py`` ``VALID_ROW_SEGMENT`` rows) up to the one
+    that holds the last token, in a rung of at least
+    :data:`DENSE_MIN_ROWS` rows; else every row."""
+    from ..ops.math_ops import VALID_ROW_SEGMENT as segment
+
+    if seq_len < DENSE_MIN_ROWS:
+        return seq_len
+    return min(seq_len, segment * -(-prompt_len // segment))
 
 
 def _taps_fetches(taps):
@@ -383,7 +409,7 @@ def _conv_over_state(z, kernel, conv_w, valid, conv_state, slot, live):
 
 
 def _conv_mixer(h, hidden, mixer, p, valid=None, conv_state=None,
-                slot=None, live=None):
+                slot=None, live=None, rows=None):
     """The gated short-convolution mixer on normed rows h [B, S, H]:
     ``[B, C, u] = split3(h W_in)``, ``z = B * u``, ``y = (C * conv(z))
     W_out``.  Three modes, as the attention mixer has them: with
@@ -391,13 +417,14 @@ def _conv_mixer(h, hidden, mixer, p, valid=None, conv_state=None,
     and the fresh one, the state moved on in place for live rows); with
     ``conv_state`` and ``slot`` a prefill that also leaves the rows
     before ``valid`` (the prompt's true length) as slot ``slot``'s
-    state; else the plain whole-sequence form.  Returns ``(y, tail)``:
+    state; else the plain whole-sequence form.  ``rows``: :func:`_linear`'s,
+    for both projections.  Returns ``(y, tail)``:
     ``tail`` [B, L - 1, H] where a prefill has ``valid`` and no state to
     write to (the caller fetches it), else None."""
     kernel = int(mixer["L_cache"])
     conv_w = dict(param_attr=p("conv.w"),
                   bias_attr=p("conv.b") if mixer.get("bias") else None)
-    bcu = _linear(h, 3 * hidden, pname=p("conv_in.w"))
+    bcu = _linear(h, 3 * hidden, pname=p("conv_in.w"), rows=rows)
     gate_b, gate_c, u = (layers.slice(bcu, axes=[2], starts=[j * hidden],
                                       ends=[(j + 1) * hidden])
                          for j in range(3))
@@ -405,7 +432,7 @@ def _conv_mixer(h, hidden, mixer, p, valid=None, conv_state=None,
     c, tail = _conv_over_state(z, kernel, conv_w, valid, conv_state, slot,
                                live)
     return _linear(layers.elementwise_mul(gate_c, c), hidden,
-                   pname=p("conv_out.w")), tail
+                   pname=p("conv_out.w"), rows=rows), tail
 
 
 def _delta_gate_init(name, heads, channels=None):
@@ -427,7 +454,7 @@ def _delta_gate_init(name, heads, channels=None):
 
 
 def _gated_delta_mixer(h, seq_len, hidden, mixer, p, eps, valid=None,
-                       states=None, slot=None, live=None):
+                       states=None, slot=None, live=None, rows=None):
     """Gated delta-rule linear attention on rows h [B, S, H]:
     ``q | k | v = silu(conv(h W_qkv))`` (one causal depthwise
     convolution over all their channels), q and k L2-normalised a head
@@ -440,7 +467,8 @@ def _gated_delta_mixer(h, seq_len, hidden, mixer, p, eps, valid=None,
     with ``live`` the decode step (both states moved on in place for
     live rows); with ``slot`` a prefill that leaves both states as they
     stand after the prompt's TRUE last token in that slot's rows; else
-    the whole sequence.  Returns ``(y, tail, state)``: what a prefill
+    the whole sequence.  ``rows``: :func:`_linear`'s, for every
+    projection.  Returns ``(y, tail, state)``: what a prefill
     with ``valid`` and no variables to write to leaves the caller to
     fetch, else None.
 
@@ -456,7 +484,7 @@ def _gated_delta_mixer(h, seq_len, hidden, mixer, p, eps, valid=None,
     kernel = int(mixer["conv"])
     per_channel = mixer.get("decay", "head") == "channel"
     conv_state, delta_state = states if states else (None, None)
-    qkv = _linear(h, channels, pname=p("gdn_qkv.w"))
+    qkv = _linear(h, channels, pname=p("gdn_qkv.w"), rows=rows)
     c, tail = _conv_over_state(qkv, kernel, {"param_attr": p("gdn_conv.w")},
                                valid, conv_state, slot, live)
     c = layers.silu(c)
@@ -481,11 +509,11 @@ def _gated_delta_mixer(h, seq_len, hidden, mixer, p, eps, valid=None,
         q, k = per_value_head(q), per_value_head(k)
     if per_channel:
         a = _linear(_linear(h, int(mixer["decay_rank"]),
-                            pname=p("gdn_f_down.w")),
-                    heads * dk, pname=p("gdn_f_up.w"))
-        b = _linear(h, heads, pname=p("gdn_b.w"))
+                            pname=p("gdn_f_down.w"), rows=rows),
+                    heads * dk, pname=p("gdn_f_up.w"), rows=rows)
+        b = _linear(h, heads, pname=p("gdn_b.w"), rows=rows)
     else:
-        ab = _linear(h, 2 * heads, pname=p("gdn_ab.w"))
+        ab = _linear(h, 2 * heads, pname=p("gdn_ab.w"), rows=rows)
         a = layers.slice(ab, axes=[2], starts=[0], ends=[heads])
         b = layers.slice(ab, axes=[2], starts=[heads], ends=[2 * heads])
     beta = layers.sigmoid(b)
@@ -521,16 +549,17 @@ def _gated_delta_mixer(h, seq_len, hidden, mixer, p, eps, valid=None,
     sigmoid = mixer.get("gate", "silu") == "sigmoid"
     low_rank = sigmoid and "gate_rank" in mixer
     gate = _linear(_linear(h, int(mixer["gate_rank"]),
-                           pname=p("gdn_g_down.w")),
-                   heads * dv, pname=p("gdn_g_up.w")) if low_rank \
-        else _linear(h, heads * dv, pname=p("gdn_gate.w"))
+                           pname=p("gdn_g_down.w"), rows=rows),
+                   heads * dv, pname=p("gdn_g_up.w"), rows=rows) \
+        if low_rank \
+        else _linear(h, heads * dv, pname=p("gdn_gate.w"), rows=rows)
     gate = layers.reshape(gate, [0, seq_len, heads, dv])
     gate = layers.sigmoid(gate) if sigmoid else layers.silu(gate)
     if float(mixer.get("gate_scale", 1.0)) != 1.0:
         gate = layers.scale(gate, scale=float(mixer["gate_scale"]))
     o = layers.reshape(layers.elementwise_mul(o, gate),
                        [0, seq_len, heads * dv])
-    return _linear(o, hidden, pname=p("gdn_out.w")), tail, state
+    return _linear(o, hidden, pname=p("gdn_out.w"), rows=rows), tail, state
 
 
 def _ssd_init(name, heads):
@@ -553,7 +582,7 @@ def _ssd_init(name, heads):
 
 
 def _ssd_mixer(h, seq_len, hidden, mixer, p, eps, valid=None, states=None,
-               slot=None, live=None):
+               slot=None, live=None, rows=None):
     """A state-space duality (Mamba-2) layer on normed rows h [B, S, H]:
     ``z | xBC | dt = h W_in`` (no bias); ``x | B | C = silu(conv(xBC) +
     b)``, one causal depthwise convolution over all their channels, ``x``
@@ -572,7 +601,8 @@ def _ssd_mixer(h, seq_len, hidden, mixer, p, eps, valid=None, states=None,
     heads, hp, n, channels, groups = _ssd_dims(mixer)
     inner = heads * hp
     conv_state, ssm_state = states if states else (None, None)
-    zxd = _linear(h, inner + channels + heads, pname=p("ssd_in.w"))
+    zxd = _linear(h, inner + channels + heads, pname=p("ssd_in.w"),
+                  rows=rows)
 
     def cut(t, lo, width):
         return layers.slice(t, axes=[2], starts=[lo], ends=[lo + width])
@@ -609,7 +639,7 @@ def _ssd_mixer(h, seq_len, hidden, mixer, p, eps, valid=None, states=None,
                                layers.silu(z))
     y = layers.rms_norm(y, epsilon=eps, param_attr=p("ssd_norm"),
                         group_size=inner // groups)
-    return _linear(y, hidden, pname=p("ssd_out.w")), tail, state
+    return _linear(y, hidden, pname=p("ssd_out.w"), rows=rows), tail, state
 
 
 def _residual(x, y, scale=1.0):
@@ -635,8 +665,12 @@ def llama_block(x, hidden, num_heads, num_kv_heads, seq_len, head_dim,
                 qk_norm=False, mask_block=None, block=False,
                 conv_state=None, slot=None, live=None, norm="pre",
                 norm_kind="rms", chunk_pages=False, residual_scale=1.0,
-                attn_scale=None):
+                attn_scale=None, dense_rows=None):
     """One decoder layer. x: [B, S, H].
+
+    ``dense_rows`` ([1] int32; a whole-prompt prefill of a long rung,
+    B = 1): the rows that hold a token, at which every dense product of
+    the layer stops (:func:`_linear`'s ``rows``).
 
     A layer whose ``mixer`` is a gated short convolution
     (:data:`DEFAULT_LAYER`) runs :func:`_conv_mixer` where the others
@@ -737,7 +771,7 @@ def llama_block(x, hidden, num_heads, num_kv_heads, seq_len, head_dim,
                     valid=valid, taps=taps, norm=norm,
                     limit=layer.get("swiglu_limit"), norm_kind=norm_kind,
                     h=h if norm == "parallel" else None,
-                    residual_scale=residual_scale)
+                    residual_scale=residual_scale, rows=dense_rows)
     if layer["mixer"] is None or layer["ffn"] is None:
         if norm != "pre" or (layer["mixer"] is None
                              and layer["ffn"] is None):
@@ -754,13 +788,14 @@ def llama_block(x, hidden, num_heads, num_kv_heads, seq_len, head_dim,
         if layer["mixer"]["kind"] == "conv":
             y, tail = _conv_mixer(h, hidden, layer["mixer"], p, valid=valid,
                                   conv_state=conv_state, slot=slot,
-                                  live=live)
+                                  live=live, rows=dense_rows)
         else:
             mixer = _ssd_mixer if layer["mixer"]["kind"] == "ssd" \
                 else _gated_delta_mixer
             y, tail, state = mixer(
                 h, seq_len, hidden, layer["mixer"], p, rms_norm_eps,
-                valid=valid, states=conv_state, slot=slot, live=live)
+                valid=valid, states=conv_state, slot=slot, live=live,
+                rows=dense_rows)
         out = _ffn(_residual(x, post_normed(y), residual_scale), x_in,
                    hidden, intermediate, **ffn_args)
         return (out, tail, state) if collect_kv else out
@@ -769,11 +804,12 @@ def llama_block(x, hidden, num_heads, num_kv_heads, seq_len, head_dim,
             h, seq_len, hidden, num_heads, layer, p, rms_norm_eps,
             rope_base, attn_impl, kv_cache=kv_cache, positions=positions,
             block_table=block_table, kv_lengths=kv_lengths,
-            want_row=collect_kv, chunk_pages=chunk_pages)
+            want_row=collect_kv, chunk_pages=chunk_pages, rows=dense_rows)
         out = _ffn(_residual(x, post_normed(y), residual_scale), x_in,
                    hidden, intermediate, **ffn_args)
         return (out, row, None) if collect_kv else out
-    qkv = _linear(h, q_size + 2 * kv_size, pname=p("qkv.w"))
+    qkv = _linear(h, q_size + 2 * kv_size, pname=p("qkv.w"),
+                  rows=dense_rows)
     q = layers.slice(qkv, axes=[2], starts=[0], ends=[q_size])
     k = layers.slice(qkv, axes=[2], starts=[q_size],
                      ends=[q_size + kv_size])
@@ -854,8 +890,8 @@ def llama_block(x, hidden, num_heads, num_kv_heads, seq_len, head_dim,
     attn = layers.reshape(attn, [0, seq_len, q_size])
     if layer.get("attn_gate"):
         attn = layers.elementwise_mul(attn, layers.sigmoid(
-            _linear(h, q_size, pname=p("attn_gate.w"))))
-    y = _linear(attn, hidden, pname=p("attn_out.w"))
+            _linear(h, q_size, pname=p("attn_gate.w"), rows=dense_rows)))
+    y = _linear(attn, hidden, pname=p("attn_out.w"), rows=dense_rows)
     x = _residual(x, post_normed(y), residual_scale)
     out = _ffn(x, x_in, hidden, intermediate, **ffn_args)
     if collect_kv:
@@ -887,7 +923,8 @@ def _mla_scale(mla):
 
 def _mla_mixer(h, seq_len, hidden, num_heads, layer, p, eps, rope_base,
                attn_impl, kv_cache=None, positions=None, block_table=None,
-               kv_lengths=None, want_row=False, chunk_pages=False):
+               kv_lengths=None, want_row=False, chunk_pages=False,
+               rows=None):
     """Latent attention (MLA) on normed rows h [B, S, H]: ``c_q = norm(h
     W_qa)``, ``[q_nope | q_rope] = c_q W_qb`` a head; ``[c_kv | k_r] = h
     W_kva``, ``c_kv = norm(c_kv)``; ``q_rope`` and the one ``k_r`` rotated;
@@ -908,7 +945,8 @@ def _mla_mixer(h, seq_len, hidden, num_heads, layer, p, eps, rope_base,
     pages), then ``latent_chunk_attention`` expands the slot's cached rows
     block by block through ``W_kvb`` and the chunk attends them and itself
     causally; the chunk at base 0 is the same program.  The one parameter
-    ``.kv_b.w`` in all three.  Returns ``(y, row)``: the
+    ``.kv_b.w`` in all three.  ``rows``: :func:`_linear`'s, for every
+    projection of the expanded path.  Returns ``(y, row)``: the
     rows [B, 1, S, ROW] with ``want_row`` (a prefill scatters them), else
     None."""
     from ..ops.latent_attention_ops import latent_pool_shape
@@ -931,13 +969,13 @@ def _mla_mixer(h, seq_len, hidden, num_heads, layer, p, eps, rope_base,
         return layers.transpose(layers.reshape(t, [0, seq_len, n, d]),
                                 [0, 2, 1, 3])               # [B, n, S, d]
 
-    q = heads(_linear(normed(_linear(h, rank_q, pname=p("q_a.w")),
+    q = heads(_linear(normed(_linear(h, rank_q, pname=p("q_a.w"), rows=rows),
                              "q_a_norm"),
-                      num_heads * (dn + dr), pname=p("q_b.w")),
+                      num_heads * (dn + dr), pname=p("q_b.w"), rows=rows),
               num_heads, dn + dr)
     q_nope = cut(q, 3, 0, dn)
     q_rope = layers.rope(cut(q, 3, dn, dn + dr), **rot)
-    kv_a = _linear(h, rank_kv + dr, pname=p("kv_a.w"))
+    kv_a = _linear(h, rank_kv + dr, pname=p("kv_a.w"), rows=rows)
     c_kv = normed(cut(kv_a, 2, 0, rank_kv), "kv_a_norm")     # [B, S, C]
     k_r = layers.rope(heads(cut(kv_a, 2, rank_kv, rank_kv + dr), 1, dr),
                       **rot)                                 # [B, 1, S, dr]
@@ -963,8 +1001,8 @@ def _mla_mixer(h, seq_len, hidden, num_heads, layer, p, eps, rope_base,
                 q_nope, q_rope, w_kvb, pool, block_table, positions,
                 kv_lengths, scale, dv)
     else:
-        kv = heads(_linear(c_kv, kv_b, pname=p("kv_b.w")), num_heads,
-                   dn + dv)
+        kv = heads(_linear(c_kv, kv_b, pname=p("kv_b.w"), rows=rows),
+                   num_heads, dn + dv)
         k = layers.concat([cut(kv, 3, 0, dn),
                            layers.tile(k_r, [1, num_heads, 1, 1])], axis=3)
         attn = layers.latent_prefill_attention(
@@ -974,8 +1012,8 @@ def _mla_mixer(h, seq_len, hidden, num_heads, layer, p, eps, rope_base,
                           [0, seq_len, num_heads * dv])
     if layer.get("attn_gate"):
         attn = layers.elementwise_mul(attn, layers.sigmoid(
-            _linear(h, num_heads * dv, pname=p("attn_gate.w"))))
-    return _linear(attn, hidden, pname=p("attn_out.w")), row
+            _linear(h, num_heads * dv, pname=p("attn_gate.w"), rows=rows)))
+    return _linear(attn, hidden, pname=p("attn_out.w"), rows=rows), row
 
 
 def _norm_modes(norm):
@@ -988,9 +1026,16 @@ def _norm_modes(norm):
     return norm != "post", norm in ("post", "pre_post")
 
 
-def _swiglu(h, hidden, width, gate_up_name, down_name, limit=None):
+def _swiglu(h, hidden, width, gate_up_name, down_name, limit=None,
+            rows=None):
     """``(silu(h W_gate) * (h W_up)) W_down`` with gate | up fused; with
-    ``limit`` L the gate is held under L and the up to [-L, L] first."""
+    ``limit`` L the gate is held under L and the up to [-L, L] first.
+    ``rows``: :func:`_linear`'s; the two products and what lies between
+    them then run a segment at a time, in one loop."""
+    if rows is not None:
+        return layers.swiglu_valid_rows(
+            h, width, hidden, rows, gate_up_attr=gate_up_name,
+            down_attr=down_name, limit=limit)
     gate_up = _linear(h, 2 * width, pname=gate_up_name)
     gate = layers.slice(gate_up, axes=[2], starts=[0], ends=[width])
     up = layers.slice(gate_up, axes=[2], starts=[width], ends=[2 * width])
@@ -1002,15 +1047,17 @@ def _swiglu(h, hidden, width, gate_up_name, down_name, limit=None):
                    pname=down_name)
 
 
-def _relu2_mlp(h, hidden, width, up_name, down_name):
-    """``relu(h W_up)^2 W_down``: two matrices and no gate."""
-    up = layers.square(layers.relu(_linear(h, width, pname=up_name)))
-    return _linear(up, hidden, pname=down_name)
+def _relu2_mlp(h, hidden, width, up_name, down_name, rows=None):
+    """``relu(h W_up)^2 W_down``: two matrices and no gate.  ``rows``:
+    :func:`_linear`'s."""
+    up = layers.square(layers.relu(_linear(h, width, pname=up_name,
+                                           rows=rows)))
+    return _linear(up, hidden, pname=down_name, rows=rows)
 
 
 def _ffn(x, x_in, hidden, intermediate, ffn, p, rms_norm_eps, valid, taps,
          norm="pre", limit=None, norm_kind="rms", h=None,
-         residual_scale=1.0):
+         residual_scale=1.0, rows=None):
     """The layer's second half on the post-mixer stream x: norm, dense
     SwiGLU or routed experts (``x_in``: the layer's raw input, which some
     routers read), residual.  ``norm``: where the norms sit
@@ -1019,7 +1066,9 @@ def _ffn(x, x_in, hidden, intermediate, ffn, p, rms_norm_eps, valid, taps,
     is the layer's one normed input, which the mixer read too, and there
     is no ``.ln2`` (so too in a layer that is an FFN alone).  ``limit``:
     the SwiGLUs' clamp; ``residual_scale``: what the FFN's output is
-    multiplied by as it joins the stream.  ``ffn`` None: the layer has no
+    multiplied by as it joins the stream; ``rows``: :func:`_linear`'s, for
+    the dense, the shared and the latent products (the routed experts
+    leave padded rows out by ``valid``).  ``ffn`` None: the layer has no
     second half and x is handed back."""
     if ffn is None:
         return x
@@ -1029,14 +1078,14 @@ def _ffn(x, x_in, hidden, intermediate, ffn, p, rms_norm_eps, valid, taps,
     clamp = {} if limit is None else {"limit": float(limit)}
     if ffn == "dense":
         y = _swiglu(h, hidden, intermediate, p("gate_up.w"), p("ffn_out.w"),
-                    **clamp)
+                    rows=rows, **clamp)
     else:
         taps = taps if taps is not None else {}
         # (a latent layer's experts read and write rows of that width:
         # down before the dispatch, up after the combine, once a row)
         latent = ffn.get("latent")
-        u = _linear(h, int(latent), pname=p("moe.latent_down.w")) \
-            if latent else h
+        u = _linear(h, int(latent), pname=p("moe.latent_down.w"),
+                    rows=rows) if latent else h
         y, counts, logits = layers.moe_routed_ffn(
             u, h if ffn.get("route_from", "raw") == "normed" else x_in,
             ffn["experts"], ffn["top_k"], ffn["width"],
@@ -1047,7 +1096,7 @@ def _ffn(x, x_in, hidden, intermediate, ffn, p, rms_norm_eps, valid, taps,
                                    "topk_group", "gated") if k in ffn},
             **clamp)
         if latent:
-            y = _linear(y, hidden, pname=p("moe.latent_up.w"))
+            y = _linear(y, hidden, pname=p("moe.latent_up.w"), rows=rows)
         if int(ffn.get("n_group", 1)) > 1:
             counts, group_rows = counts
             taps.setdefault("group_rows", []).append(group_rows)
@@ -1059,10 +1108,11 @@ def _ffn(x, x_in, hidden, intermediate, ffn, p, rms_norm_eps, valid, taps,
             # expert-parallel group alike, so counted once
             shared = _swiglu(
                 h, hidden, int(ffn["shared_width"]),
-                p("moe.shared_gate_up.w"), p("moe.shared_down.w"), **clamp) \
+                p("moe.shared_gate_up.w"), p("moe.shared_down.w"),
+                rows=rows, **clamp) \
                 if ffn.get("gated", True) else _relu2_mlp(
                     h, hidden, int(ffn["shared_width"]),
-                    p("moe.shared_up.w"), p("moe.shared_down.w"))
+                    p("moe.shared_up.w"), p("moe.shared_down.w"), rows=rows)
             if float(ffn.get("shared_scale", 1.0)) != 1.0:
                 shared = layers.scale(shared,
                                       scale=float(ffn["shared_scale"]))
@@ -1161,9 +1211,19 @@ def build_llama_prefill(batch_size, seq_len, vocab_size=32000,
                         qk_norm=False, mask_block=None, tie_head=False,
                         norm="pre", norm_kind="rms", logit_scale=1.0,
                         embed_scale=1.0, residual_scale=1.0,
-                        attn_scale=None):
+                        attn_scale=None, stop_at_prompt=True):
     """Prefill entry point: one causal forward over the (padded) prompt
     that populates a decode cache in one shot.
+
+    In the paged mode, on a rung of :data:`DENSE_MIN_ROWS` rows or more,
+    every dense product (projections, dense and shared FFNs) stops at
+    the segment that holds the prompt's last token (``prompt_len``;
+    :func:`dense_rows_run` rows of the rung), and the rows behind are zero
+    where they were products of padding: nothing a real row reads, causal
+    attention, the scans' ``valid``, the convolutions' tails, the head's
+    one row and the pages written up to ``prompt_len`` depend on them.
+    ``stop_at_prompt=False`` builds the plain products at every rung (what
+    a test compares with).
 
     A model with layers that keep slot state (gated short convolutions,
     the gated delta rule: ``mixer`` of :data:`DEFAULT_LAYER`) takes one
@@ -1291,6 +1351,8 @@ def build_llama_prefill(batch_size, seq_len, vocab_size=32000,
     # from the pad tail: the paged path feeds their number, the others
     # have it as last_pos + 1
     valid = prompt_len
+    dense_rows = prompt_len if stop_at_prompt \
+        and seq_len >= DENSE_MIN_ROWS else None
     if valid is None and (has_state
                           or expert_layers(layer_pattern, num_layers)):
         valid = layers.cast(last_pos + 1, "int32")
@@ -1309,7 +1371,8 @@ def build_llama_prefill(batch_size, seq_len, vocab_size=32000,
                               mask_block=mask_block, norm=norm,
                               norm_kind=norm_kind,
                               residual_scale=residual_scale,
-                              attn_scale=attn_scale, **state)
+                              attn_scale=attn_scale, dense_rows=dense_rows,
+                              **state)
         if lspec["mixer"] is None:
             continue                 # an FFN alone leaves nothing behind
         if lspec["mixer"] != "attention":

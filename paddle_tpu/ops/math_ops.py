@@ -391,6 +391,128 @@ def _mul_lower(ctx: LowerContext, op: Operator):
     ctx.set_output(op, "Out", jnp.reshape(out, xs[:xd] + ys[yd:]))
 
 
+# Rows a segment of the products that stop at the valid rows
+# (``mul_valid_rows``, ``swiglu_valid_rows``).  Measured on a v5e at float32
+# "highest" (tools/dense_rows_microbench.py and the two cells whose
+# prefills the products set the pace of, 256 against 512: PERF.md §6 PR 64).
+VALID_ROW_SEGMENT = 256
+
+
+def _over_valid_rows(x, valid, segment, width, fn):
+    """``fn`` (rows ``[1, segment, K]`` -> ``[1, segment, width]``, a row's
+    result a function of that row alone) over the segments of x [1, S, K]
+    that hold one of its first ``valid`` (a traced int32 scalar) rows, zeros
+    behind them: a loop of dynamic trip count, so one body is compiled
+    whatever S.  Where S is no multiple of the segment the last one starts
+    early, at S - segment, and works some rows again."""
+    import jax
+    jnp = _jnp()
+    rows = x.shape[1]
+
+    def one(i, out):
+        start = jnp.minimum(i * segment, rows - segment)
+        part = fn(jax.lax.dynamic_slice_in_dim(x, start, segment, axis=1))
+        return jax.lax.dynamic_update_slice_in_dim(out, part, start, axis=1)
+
+    # (zeros that wait for x: XLA:TPU fills a buffer of plain zeros at the
+    # program's start, every loop's at once, and holds them all till their
+    # loops run: 2.3 GB in solar-open2-250b's rung 4096, PERF.md §6 PR 64)
+    zeros = jnp.broadcast_to(0 * x[0, 0, 0], (1, rows, width))
+    return jax.lax.fori_loop(
+        0, (jnp.clip(valid, 0, rows) + segment - 1) // segment, one, zeros)
+
+
+def _rows_dot(x, y):
+    """``mul``'s ``dot_general`` of rows x [1, R, K] by y [K, N]."""
+    import jax
+    return jax.lax.dot_general(
+        x, y, (((2,), (0,)), ((), ())),
+        preferred_element_type=_acc_dtype(x.dtype),
+        precision=_mm_precision(x.dtype)).astype(x.dtype)
+
+
+def valid_rows_product(x, y, valid, segment=VALID_ROW_SEGMENT):
+    """x [1, S, K] @ y [K, N] over the segments that hold one of the first
+    ``valid`` rows (:func:`_over_valid_rows`): the same ``dot_general`` as
+    ``mul``'s a segment."""
+    return _over_valid_rows(x, valid, segment, y.shape[1],
+                            lambda rows: _rows_dot(rows, y))
+
+
+def valid_rows_swiglu(x, gate_up, down, valid, segment=VALID_ROW_SEGMENT,
+                      limit=None):
+    """``(silu(x W_gate) * (x W_up)) W_down`` with gate | up fused, x
+    [1, S, K], over the segments that hold one of the first ``valid`` rows
+    (:func:`_over_valid_rows`): both products and what lies between them
+    a segment, so no ``[S, 2I]`` is ever held.  ``limit`` L: the gate held
+    under L and the up to [-L, L] first."""
+    import jax
+    jnp = _jnp()
+    width = down.shape[0]
+
+    def ffn(rows):
+        gu = _rows_dot(rows, gate_up)
+        gate, up = gu[..., :width], gu[..., width:]
+        if limit is not None:
+            gate = jnp.clip(gate, -3.0e38, limit)
+            up = jnp.clip(up, -limit, limit)
+        return _rows_dot(jax.nn.silu(gate) * up, down)
+
+    return _over_valid_rows(x, valid, segment, down.shape[1], ffn)
+
+
+def _valid_rows_infer(weights):
+    """Shape inference of an op over X [1, S, K] that stops at
+    ``ValidRows``: ``weights`` are its matrix slots in order, chained K ->
+    ... -> N (a slot ``(name, 2)``: the next one reads half its columns)."""
+    def infer(op: Operator, block: Block):
+        x = in_var(op, block, "X")
+        shapes = [tuple(int(d) for d in in_var(op, block, w).shape)
+                  for w, _ in weights]
+        ok = len(x.shape) == 3 and int(x.shape[0]) == 1 \
+            and all(len(s) == 2 for s in shapes)
+        k = int(x.shape[2]) if ok else None
+        for (_w, split), shape in zip(weights, shapes):
+            ok = ok and shape[0] == k and shape[1] % split == 0
+            k = shape[1] // split if ok else None
+        if not ok:
+            raise InvalidArgumentError(
+                f"{op.type}: X [1, S, K] through "
+                f"{[w for w, _ in weights]}, got X{tuple(x.shape)} {shapes}")
+        if not 0 < int(op.attr("segment")) <= int(x.shape[1]):
+            raise InvalidArgumentError(
+                f"{op.type}: a segment of {op.attr('segment')} rows of "
+                f"{x.shape[1]}")
+        set_out(op, block, "Out", [1, x.shape[1], k], x.dtype)
+    return infer
+
+
+@register_op("mul_valid_rows", infer=_valid_rows_infer([("Y", 1)]),
+             grad=None)
+def _mul_valid_rows_lower(ctx: LowerContext, op: Operator):
+    """``mul`` of rows X [1, S, K] by Y [K, N] of which only the first
+    ``ValidRows[0]`` hold anything (:func:`valid_rows_product`).
+    Inference only."""
+    ctx.set_output(op, "Out", valid_rows_product(
+        ctx.get_input(op, "X"), ctx.get_input(op, "Y"),
+        ctx.get_input(op, "ValidRows")[0].astype("int32"),
+        int(op.attr("segment"))))
+
+
+@register_op("swiglu_valid_rows",
+             infer=_valid_rows_infer([("GateUp", 2), ("Down", 1)]),
+             grad=None)
+def _swiglu_valid_rows_lower(ctx: LowerContext, op: Operator):
+    """A SwiGLU (GateUp [K, 2I], Down [I, N]) of rows X [1, S, K] of which
+    only the first ``ValidRows[0]`` hold anything
+    (:func:`valid_rows_swiglu`).  Inference only."""
+    ctx.set_output(op, "Out", valid_rows_swiglu(
+        ctx.get_input(op, "X"), ctx.get_input(op, "GateUp"),
+        ctx.get_input(op, "Down"),
+        ctx.get_input(op, "ValidRows")[0].astype("int32"),
+        int(op.attr("segment")), op.attr("limit", None)))
+
+
 @register_op("dot", infer=lambda op, block: set_out(
     op, block, "Out", list(in_var(op, block, "X").shape[:-1]) or [1],
     in_var(op, block, "X").dtype))

@@ -161,7 +161,10 @@ expert ``moe_shared_expert_rows`` (row-layers it ran on),
 (block diffusion: slot-passes that decided positions / that only
 committed a block's K/V), ``serving_block_tokens_committed``,
 ``serving_slot_state_writes`` (prefills that wrote a slot's state),
-``serving_delta_state_steps``
+``serving_prefill_rows_run`` / ``serving_prefill_rows_skipped`` (a
+whole-prompt prefill's rung rows that its dense products multiplied / that
+lay in whole segments behind the prompt's end and were not:
+``models/llama.py`` ``dense_rows_run``), ``serving_delta_state_steps``
 (slot-layers whose delta state a decode step moved on),
 ``serving_ssm_state_steps`` (the same of a state-space layer's matrix);
 gauges (the cache's are listed in ``kv_cache.py``)
@@ -185,6 +188,7 @@ import numpy as np
 
 from .. import blackbox, costmodel, fault, telemetry
 from ..flags import flag_value
+from ..models.llama import dense_rows_run
 from ..monitor import stat_add
 from ..ops.gated_delta_ops import CHUNK as DELTA_CHUNK
 from ..ops.ssd_ops import CHUNK as SSD_CHUNK
@@ -739,6 +743,7 @@ class GenerationEngine:
                    "moe_tokens_routed": 0, "moe_pad_pairs_left_out": 0,
                    "moe_tokens_dropped": 0, "block_passes_denoise": 0,
                    "block_passes_commit": 0, "block_tokens_committed": 0,
+                   "prefill_rows_run": 0, "prefill_rows_skipped": 0,
                    "slot_state_writes": 0, "delta_state_steps": 0,
                    "ssm_state_steps": 0,
                    "moe_pairs_routed": 0, "moe_pairs_held": 0,
@@ -2125,11 +2130,18 @@ class GenerationEngine:
                 # the rows [c_kv | k_r] a latent layer's pool took
                 latent = {"latent_rows_written": n_rows} \
                     if self._latent_layers else {}
+                # the rung's rows its dense products multiply: on a long
+                # rung they stop at the prompt's last segment
+                rows_run = dense_rows_run(bucket, n_rows)
             outs = self._launch(
                 "generation/prefill", lambda: self._run_fetching(
                     self._prefill_exe, prog, fetches, feed),
-                parent=parent, tokens=n_rows, bucket=bucket, slot=slot.idx,
-                **state, **latent)
+                parent=parent, tokens=n_rows, bucket=bucket,
+                rows_run=rows_run, slot=slot.idx, **state, **latent)
+            self._count("prefill_rows_run", rows_run)
+            self._count("prefill_rows_skipped", bucket - rows_run)
+            stat_add("serving_prefill_rows_run", rows_run)
+            stat_add("serving_prefill_rows_skipped", bucket - rows_run)
             if state:
                 self._count("slot_state_writes")
                 stat_add("serving_slot_state_writes")

@@ -1172,6 +1172,11 @@ SKIP = {
        "trash row; the Pallas kernels in interpret mode; inside the "
        "engine against the benchmark reference's full forward)" for op in [
            "ssd_chunk", "ssd_step"]},
+    **{op: "tests/test_dense_rows.py (against the plain mul and the plain "
+       "SwiGLU: valid at, beside and inside a segment's edge, a rung that "
+       "is no multiple of the segment, zeros behind; inside "
+       "build_llama_prefill against the same program without it)" for op in [
+           "mul_valid_rows", "swiglu_valid_rows"]},
     "moe_routed_ffn":
         "tests/test_window_moe.py (routing, dropless counts and the "
         "grouped matmul vs a plain float64 loop; the op inside the "

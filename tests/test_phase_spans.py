@@ -166,8 +166,8 @@ def test_existing_spans_keep_start_end_and_attributes(traced):
     # (PR 36: the span that holds a launch also says whether the device
     # had run dry; the idle engine's first launch always finds it so)
     assert prefill.attrs == {
-        "tokens": len(PROMPT), "bucket": 16, "slot": prefill.attrs["slot"],
-        "drained": 1, "idle_known_ms": prefill.attrs["idle_known_ms"],
+        "tokens": len(PROMPT), "bucket": 16, "rows_run": 16,
+        "slot": prefill.attrs["slot"], "drained": 1, "idle_known_ms": prefill.attrs["idle_known_ms"],
         "idle_slack_ms": prefill.attrs["idle_slack_ms"]}
     assert prepare.attrs == {"slot": prefill.attrs["slot"], "bucket": 16}
     # the prefill span opens at the executor call and closes at

@@ -1,5 +1,5 @@
 #!/usr/bin/env python
-"""Hash the decode program and two prefill (or chunk) rungs of serving
+"""Hash the decode program and every prefill (or chunk) rung of serving
 cells as a tree lowers them on the CPU, at published widths, from shapes
 alone.
 
@@ -28,8 +28,10 @@ store's round trip (``jax.export``, serialise, deserialise,
 each executable's optimised HLO (``as_text()`` less its ``metadata`` and
 with every value and computation named by where it first appears; a
 second hash is of its lines sorted and no operand named, for two schedules
-of the same operations) with its ``cost_analysis`` flops and
-``memory_analysis`` bytes: the stored module compiles to the executable the
+of the same operations) with its ``cost_analysis`` flops,
+``memory_analysis`` bytes and the seconds ``lower`` and ``compile`` took
+(PR 64; on this host's shared cores, so two trees are compared in one
+sitting): the stored module compiles to the executable the
 step compiled to where the two lines of a program agree.  A minute or two a
 program; no chip, nothing runs.  Default cells: ``mistral7b-chat``,
 ``smallthinker21b-mixedlen``, ``olmo-hybrid7b-longdoc``.  A training cell (PR 62: ``bert-base-seq512``,
@@ -44,6 +46,7 @@ import json
 import os
 import re
 import sys
+import time
 
 CELLS = ["gigachat35-ragturns", "command-a-plus-ragdocs",
          "solar-open2-agentturns", "smallthinker21b-mixedlen",
@@ -128,8 +131,11 @@ def main(argv) -> int:
         return "\n".join(lines)
 
     def executable(fn, args):
-        """An executable's line: its optimised HLO's hash, flops, bytes."""
+        """An executable's line (its optimised HLO's hash, flops, bytes) and
+        the seconds its ``lower`` and ``compile`` took."""
+        t0 = time.monotonic()
         exe = fn.lower(*args).compile()
+        took = time.monotonic() - t0
         text = canonical(exe.as_text())
         if dump:
             dumped.append(os.path.join(dump, "%d.txt" % len(dumped)))
@@ -145,7 +151,7 @@ def main(argv) -> int:
             hashlib.sha256(lines.encode()).hexdigest()[:16],
             cost.get("flops", 0.0), cost.get("bytes accessed", 0.0),
             mem.argument_size_in_bytes, mem.output_size_in_bytes,
-            mem.temp_size_in_bytes, mem.alias_size_in_bytes)
+            mem.temp_size_in_bytes, mem.alias_size_in_bytes), took
 
     def lowered(build, shapes):
         main, startup = pt.Program(), pt.Program()
@@ -190,15 +196,16 @@ def main(argv) -> int:
 
         from paddle_tpu import program_store
 
-        direct = executable(jitted, args)
+        direct, direct_s = executable(jitted, args)
         blob = program_store.export_step(jitted, args, ("tpu",)).serialize()
-        stored = executable(program_store.wrapped(
+        stored, stored_s = executable(program_store.wrapped(
             export.deserialize(blob), donate_argnums, **jit_kwargs), args)
         same = "same" if direct == stored else (
             "same operations, some scheduled in another order"
             if direct.split()[1:] == stored.split()[1:] else "DIFFERENT")
-        return "%s\n    direct %s\n    stored %s (module %d bytes)" % (
-            same, direct, stored, len(blob))
+        return ("%s\n    direct %s in %.1f s\n    stored %s in %.1f s "
+                "(module %d bytes)") % (same, direct, direct_s, stored,
+                                        stored_s, len(blob))
 
     def train_step(name):
         """The training cell's step over a mesh of its described chips, as
@@ -241,7 +248,7 @@ def main(argv) -> int:
             slots, seq, name="llama", **paged, **model), shapes), flush=True)
         rungs = sorted(e["prefill_buckets"])
         one = ((1, np_slot), "int32")
-        for b in rungs[-1:] if compiled else (rungs[0], rungs[-1]):
+        for b in rungs[-1:] if compiled else rungs:
             if chunk:
                 shapes = {"chunk_ids": ((1, b), "int64"),
                           "base": ((1,), "int32"), "block_table": one,
