@@ -69,7 +69,11 @@ random seeded weights:
   engine on the same weights whose program has the plain products: the
   first token's logits and a decode step's (the pages written) within the
   tolerance, and the rows counted (``prefill_rows_run`` 768 + 2048,
-  ``prefill_rows_skipped`` 1280).
+  ``prefill_rows_skipped`` 1280).  Then (PR 65) a rung of 1024 rows at
+  Mistral's layer shapes (hidden 4096 / 14336: under 2048 rows the rule
+  takes the fused SwiGLU and the products of a weight of 4096 rows or
+  more) at prompts of 593 and 1024 tokens, the same way (768 + 1024 run,
+  256 skipped).
 
 * **the program store** (PR 60) — first of all, before this process
   touches the chip: two child processes, one after the other, each build
@@ -1847,14 +1851,21 @@ STORE_STATS = ("program_store_hits", "program_store_misses",
 
 
 DENSE = dict(hidden=2048, heads=16, kv_heads=8, ffn=8192, vocab=4096,
-             rung=2048, prompts=(600, 2048), steps=1)
+             rung=2048, prompts=(600, 2048), steps=1, rows=(768 + 2048, 1280))
+# (PR 65) a short rung at Mistral's layer shapes: the single products'
+# weights have the 4096 rows from which a rung under 2048 rows takes them
+DENSE_SHORT = dict(hidden=4096, heads=32, kv_heads=8, ffn=14336, vocab=4096,
+                   rung=1024, prompts=(593, 1024), steps=1,
+                   rows=(768 + 1024, 256))
 
 
 def dense_rows_phase(cfg=DENSE):
     """What a prefill whose dense products stop at the prompt's end adds
     (PR 64): a 2048-row rung at ``valid`` 600 (three of eight segments run)
     and 2048 (all eight) against the same program with the plain products
-    on the same weights, and the engine's account of the rows."""
+    on the same weights, and the engine's account of the rows
+    (``cfg["rows"]``: run, skipped).  ``DENSE_SHORT`` (PR 65): a 1024-row
+    rung at 593 (three of four) and 1024."""
     import functools
 
     from paddle_tpu.models.llama import build_llama_prefill
@@ -1898,7 +1909,7 @@ def dense_rows_phase(cfg=DENSE):
         counters = gen.stats()["counters"]
         run, skipped = (counters["prefill_rows_run"],
                         counters["prefill_rows_skipped"])
-        check((run, skipped) == (768 + 2048, 1280),
+        check((run, skipped) == cfg["rows"],
               f"rows counted: {run} run, {skipped} skipped")
     finally:
         gen.close()
@@ -2107,6 +2118,7 @@ def main():
 
     t0 = time.perf_counter()
     dense_rows_phase()
+    dense_rows_phase(DENSE_SHORT)
     say(f"dense products that stop at the prompt's end done "
         f"[{time.perf_counter() - t0:.1f} s]")
 

@@ -298,29 +298,65 @@ def _linear(x, size, pname=None, name=None, rows=None):
     """``x W`` over the last dim of x [B, S, K].  ``rows`` ([1] int32, in
     a whole-prompt prefill of :func:`dense_rows_run`'s rungs): how many of
     the S rows hold a token; the product stops at the segment that holds
-    the last of them and the rows behind are zero."""
-    if rows is not None:
+    the last of them and the rows behind are zero, where
+    :func:`dense_rows_segment` takes a product of K weight rows in a rung
+    of S."""
+    if rows is not None and dense_rows_segment(x.shape[1], x.shape[2]):
         return layers.fc_valid_rows(x, size, rows, param_attr=pname,
                                     name=name)
     return layers.fc(x, size, num_flatten_dims=2, bias_attr=False,
                      param_attr=pname, name=name)
 
 
-# A whole-prompt prefill's dense products stop at the prompt's end in rungs
-# of at least this many rows: a shorter rung's program stays the plain one
-# (a decode gap that waits out a prefill waits out a short rung's).
-DENSE_MIN_ROWS = 2048
+# Where a whole-prompt prefill's dense products stop at the prompt's end,
+# by the rule of ISSUE 65 on tools/dense_rows_microbench.py's table (a v5e,
+# float32 "highest"; PERF.md section 6, PR 64 and PR 65): a (rung, segment)
+# is taken where the segmented form costs no more than 5 % over the whole
+# one at a full rung at every shape measured, and saves at least half a
+# segment's share of the rows one segment short of it.
+#  * 256-row segments in rungs of 2048 rows and more, every product (PR 64:
+#    the fused SwiGLU -2.1 to +2.4 % at a full rung, -26 % at three quarters).
+#  * 256-row segments in rungs of 1024 to 2047 rows: the fused SwiGLU at
+#    every width measured (-2.2 to +5.0 % at a full rung of 1024, 0.75 to
+#    1.06 of a segment's share saved one short), and a single product whose
+#    weight has DENSE_MIN_K rows or more (-1.6 to +4.8 %, 0.82 to 1.02): a
+#    loop's extra work (its [segment, N] result is copied into the rung's
+#    buffer, which is filled with zeros first) goes as N and a product's as
+#    K * N, so a narrow K loses (+8.4 to +16.9 % at K 1536 and 2048) and
+#    stays the plain product.
+#  * Nothing under 1024 rows: a rung of 512 is two segments and its single
+#    products read +8 to +18 % at a full rung; 128-row segments read +2 to
+#    +51 % at every rung (Mistral's SwiGLU +7 to +10 %): twice the weight
+#    reads, half the rows to spread a loop's fixed 0.2 ms over.
+DENSE_MIN_ROWS = 1024
+DENSE_ALL_ROWS = 2048
+DENSE_MIN_K = 4096
+
+
+def dense_rows_segment(seq_len, k=None):
+    """Rows a segment of a dense product of a whole-prompt prefill rung of
+    ``seq_len`` rows (the table above): ``ops/math_ops.py``
+    ``VALID_ROW_SEGMENT`` from :data:`DENSE_MIN_ROWS` rows up, for a single
+    product of ``k`` weight rows under :data:`DENSE_ALL_ROWS` only where k
+    is at least :data:`DENSE_MIN_K` (None: a fused SwiGLU, or the rung as a
+    whole); None where the product is the plain one."""
+    from ..ops.math_ops import VALID_ROW_SEGMENT
+
+    if seq_len < DENSE_MIN_ROWS or (
+            seq_len < DENSE_ALL_ROWS and k is not None and k < DENSE_MIN_K):
+        return None
+    return VALID_ROW_SEGMENT
 
 
 def dense_rows_run(seq_len, prompt_len):
     """Rows of a whole-prompt prefill rung of ``seq_len`` rows that its
     dense products multiply at a prompt of ``prompt_len`` tokens: whole
-    segments (``ops/math_ops.py`` ``VALID_ROW_SEGMENT`` rows) up to the one
-    that holds the last token, in a rung of at least
-    :data:`DENSE_MIN_ROWS` rows; else every row."""
-    from ..ops.math_ops import VALID_ROW_SEGMENT as segment
-
-    if seq_len < DENSE_MIN_ROWS:
+    segments (:func:`dense_rows_segment` rows) up to the one that holds the
+    last token; every row of a rung the rule leaves plain.  (In a rung
+    under :data:`DENSE_ALL_ROWS` the products of a narrow weight run every
+    row all the same.)"""
+    segment = dense_rows_segment(seq_len)
+    if segment is None:
         return seq_len
     return min(seq_len, segment * -(-prompt_len // segment))
 
@@ -1216,10 +1252,12 @@ def build_llama_prefill(batch_size, seq_len, vocab_size=32000,
     that populates a decode cache in one shot.
 
     In the paged mode, on a rung of :data:`DENSE_MIN_ROWS` rows or more,
-    every dense product (projections, dense and shared FFNs) stops at
-    the segment that holds the prompt's last token (``prompt_len``;
-    :func:`dense_rows_run` rows of the rung), and the rows behind are zero
-    where they were products of padding: nothing a real row reads, causal
+    every dense product that :func:`dense_rows_segment` takes (projections,
+    dense and shared FFNs; all of them from :data:`DENSE_ALL_ROWS` rows up)
+    stops at the segment that holds the prompt's last token
+    (``prompt_len``; :func:`dense_rows_run` rows of the rung), and the rows
+    behind are zero where they were products of padding: nothing a real
+    row reads, causal
     attention, the scans' ``valid``, the convolutions' tails, the head's
     one row and the pages written up to ``prompt_len`` depend on them.
     ``stop_at_prompt=False`` builds the plain products at every rung (what
@@ -1352,7 +1390,7 @@ def build_llama_prefill(batch_size, seq_len, vocab_size=32000,
     # have it as last_pos + 1
     valid = prompt_len
     dense_rows = prompt_len if stop_at_prompt \
-        and seq_len >= DENSE_MIN_ROWS else None
+        and dense_rows_segment(seq_len) else None
     if valid is None and (has_state
                           or expert_layers(layer_pattern, num_layers)):
         valid = layers.cast(last_pos + 1, "int32")

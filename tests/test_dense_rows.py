@@ -1,9 +1,11 @@
 """A whole-prompt prefill rung's dense products stop at the prompt's end
-(ISSUE 64): op ``mul_valid_rows`` against the plain ``mul``,
-``build_llama_prefill`` with the mechanism on against the same program with
-it off (every mixer kind, a dense and a shared-expert FFN), the programs
-that must stay the parent's (a pinned hash of one small program of each
-kind), and the engine's account of the rows.
+(ISSUE 64; ISSUE 65: in the rungs of 1024 to 2047 rows too, the fused SwiGLU
+and the products of a wide weight): op ``mul_valid_rows`` against the plain
+``mul``, ``build_llama_prefill`` with the mechanism on against the same
+program with it off (every mixer kind, a dense and a shared-expert FFN,
+under the rule of the long rungs, of the short ones and the one that
+ships), the programs that must stay the parent's (a pinned hash of one
+small program of each kind), and the engine's account of the rows.
 """
 import contextlib
 import functools
@@ -26,11 +28,32 @@ llama = importlib.import_module("paddle_tpu.models.llama")
 SEG = 16           # the segment the small programs here are built with
 
 
-@pytest.fixture
-def small_segment(monkeypatch):
-    """Segments of 16 rows in rungs of 64 or more."""
+def _small_rule(monkeypatch, all_rows=4 * SEG):
+    """Segments of 16 rows in rungs of 64 or more; every product from
+    ``all_rows`` rows up, under that the fused SwiGLU and the single
+    products of a weight of 32 rows or more (``hidden`` here)."""
     monkeypatch.setattr(math_ops, "VALID_ROW_SEGMENT", SEG)
     monkeypatch.setattr(llama, "DENSE_MIN_ROWS", 4 * SEG)
+    monkeypatch.setattr(llama, "DENSE_ALL_ROWS", all_rows)
+    monkeypatch.setattr(llama, "DENSE_MIN_K", 32)
+
+
+@pytest.fixture
+def small_segment(monkeypatch):
+    """The rule of the long rungs at the small size: every product."""
+    _small_rule(monkeypatch)
+
+
+@pytest.fixture
+def rule(request, monkeypatch):
+    """``every_product``: :func:`small_segment`'s.  ``wide_k_only``: the
+    rule of the short rungs at that size (the latent products, 16 weight
+    rows, stay plain).  ``ships``: the constants as they are."""
+    if request.param == "every_product":
+        _small_rule(monkeypatch)
+    elif request.param == "wide_k_only":
+        _small_rule(monkeypatch, all_rows=1 << 20)
+    return request.param
 
 
 # ---------------------------------------------------------------------------
@@ -203,24 +226,41 @@ def _ops(main):
     return [op.type for op in main.global_block().ops]
 
 
+# (rule, rung, prompt): the small segment at every edge of a prompt's last
+# segment, and the rule that ships at its shortest rung (Mistral's two
+# prompts of ``chat-steady`` that land there short of it, and a full one)
+CASES = [(r, 4 * SEG + 8, p) for r in ("every_product", "wide_k_only")
+         for p in (1, SEG + 3, 3 * SEG, 4 * SEG + 5)] \
+    + [("ships", 1024, p) for p in (593, 1024)]
+
+
 @pytest.mark.parametrize("kind", sorted(MODELS))
-@pytest.mark.parametrize("prompt", [1, SEG + 3, 3 * SEG, 4 * SEG + 5])
+@pytest.mark.parametrize("rule,rung,prompt", CASES, indirect=["rule"],
+                         ids=["%s-%d-%d" % c for c in CASES])
 def test_prefill_that_stops_at_the_prompt_leaves_what_the_plain_one_does(
-        small_segment, kind, prompt):
+        rule, rung, prompt, kind):
     """Logits of the last real row and everything written for the slot
     (pages but the trash page, the slot's state rows) within 1e-6 of
     their range of the same program with the mechanism off (XLA:CPU orders
     a product's accumulation by its shape)."""
-    model, rung = MODELS[kind], 4 * SEG + 8
+    model = MODELS[kind]
     on = _prefill(model, rung)
     off = _prefill(model, rung, stop_at_prompt=False)
-    # (the one ``mul`` left is the head's, on the one gathered row)
-    assert _ops(on[0]).count("mul") == 1
+    stopped = _ops(on[0]).count("mul_valid_rows") \
+        + 2 * _ops(on[0]).count("swiglu_valid_rows")
     # (a SwiGLU's two products are one op where they stop at the prompt)
-    assert _ops(on[0]).count("mul_valid_rows") \
-        + 2 * _ops(on[0]).count("swiglu_valid_rows") \
-        == _ops(off[0]).count("mul") - 1 > 0
+    assert stopped + _ops(on[0]).count("mul") == _ops(off[0]).count("mul")
     assert "mul_valid_rows" not in _ops(off[0])
+    assert "swiglu_valid_rows" in _ops(on[0])
+    if rule == "every_product":
+        # (the one ``mul`` left is the head's, on the one gathered row)
+        assert _ops(on[0]).count("mul") == 1
+    elif rule == "ships":
+        # (hidden 32: no single product has a weight of 4096 rows)
+        assert "mul_valid_rows" not in _ops(on[0])
+    else:
+        assert "mul_valid_rows" in _ops(on[0])
+        assert (_ops(on[0]).count("mul") > 1) == (kind == "latent")
     scope, exe = pt.Scope(), pt.Executor()
     exe.run(on[1], scope=scope)
     np_slot = rung // PAGE
@@ -266,11 +306,28 @@ def test_the_unpaged_prefill_and_the_switch_build_the_plain_products(
 @pytest.mark.parametrize("rung,prompt,run", [
     (2048, 600, 768), (2048, 2048, 2048), (2048, 1, 256),
     (3712, 3585, 3712), (3712, 3584, 3584), (3712, 1024, 1024),
-    (1024, 513, 1024), (1536, 1, 1536), (6144, 5878, 5888)])
+    (6144, 5878, 5888), (1536, 1, 256),
+    # ``chat-steady``'s prompts on their rungs: three on rung 1024, and
+    # the rungs under it run every row
+    (1024, 593, 768), (1024, 755, 768), (1024, 1024, 1024),
+    (512, 325, 512), (256, 133, 256), (128, 87, 128)])
 def test_dense_rows_run_at_the_constant_that_ships(rung, prompt, run):
     assert math_ops.VALID_ROW_SEGMENT == 256
-    assert llama.DENSE_MIN_ROWS == 2048
+    assert (llama.DENSE_MIN_ROWS, llama.DENSE_ALL_ROWS,
+            llama.DENSE_MIN_K) == (1024, 2048, 4096)
     assert llama.dense_rows_run(rung, prompt) == run
+
+
+@pytest.mark.parametrize("rung,k,segment", [
+    (1024, None, 256), (1024, 4096, 256), (1024, 14336, 256),
+    (1024, 4095, None), (2047, 2048, None), (2047, None, 256),
+    (2048, 128, 256), (8192, 64, 256), (1023, None, None),
+    (512, 14336, None), (256, None, None)])
+def test_which_products_of_which_rungs_the_rule_that_ships_takes(
+        rung, k, segment):
+    """A rung's fused SwiGLU (k None) and its single products of k weight
+    rows: 256-row segments, or the plain product."""
+    assert llama.dense_rows_segment(rung, k) == segment
 
 
 # ---------------------------------------------------------------------------
@@ -304,14 +361,17 @@ HYBRID = dict(BASE, num_layers=3, layer_pattern=[
     MODELS["gated_delta"]["layer_pattern"][0],
     {"attn_gate": True, "ffn": SHARED}])
 PAGED = dict(num_pages=2 * 16 + 1, page_tokens=8)
-# Program JSON of the parent (5216b6b), by this file's ``_hash``
+
+
+def _paged(rung):
+    return dict(name="llama", cache_slots=2, max_seq_len=rung,
+                num_pages=2 * (rung // 8) + 1, page_tokens=8)
+
+
+# Program JSON of PR 64's parent (5216b6b), by this file's ``_hash``
 PARENTS = {
-    "prefill_1024": ("23b40df02da605fc", lambda: llama.build_llama_prefill(
-        1, 1024, name="llama", cache_slots=2, max_seq_len=1024,
-        num_pages=2 * 128 + 1, page_tokens=8, **HYBRID)),
-    "prefill_1536_latent": ("3f6f9c214dd538a8", lambda: llama.build_llama_prefill(
-        1, 1536, name="llama", cache_slots=2, max_seq_len=1536,
-        num_pages=2 * 192 + 1, page_tokens=8, **MODELS["latent"])),
+    "prefill_512": ("a4b64cd9683877a7", lambda: llama.build_llama_prefill(
+        1, 512, **_paged(512), **HYBRID)),
     "prefill_4096_unpaged": ("dddd73f2bd408743", lambda: llama.build_llama_prefill(
         1, 4096, name="llama", **HYBRID)),
     "prefill_block_causal": ("ecfef0c42f5ec100", lambda: llama.build_llama_prefill(
@@ -333,33 +393,64 @@ PARENTS = {
 }
 
 
+# Rungs of 2048 and more are PR 64's programs: Program JSON of PR 65's
+# parent (4b90ba5), which has the mechanism there
+LONG_PARENTS = {
+    "prefill_2048": ("d4561b0b44d312ec", lambda: llama.build_llama_prefill(
+        1, 2048, **_paged(2048), **HYBRID)),
+    "prefill_3712_latent": ("8deb905e56394d82", lambda: llama.build_llama_prefill(
+        1, 3712, **_paged(3712), **MODELS["latent"])),
+}
+
+
+def _ops_of(build):
+    main, startup = pt.Program(), pt.Program()
+    with _names_from_zero(), pt.program_guard(main, startup):
+        build()
+    return _ops(main)
+
+
 @pytest.mark.parametrize("kind", sorted(PARENTS))
 def test_programs_under_the_rule_are_the_parents(kind):
-    """Rungs under 2048 rows, every decode, block, chunk and verify
+    """Rungs under 1024 rows, every decode, block, chunk and verify
     program and whatever has no ``prompt_len`` (the unpaged prefill, the
     full forward, training) come out as they did before the mechanism."""
     pinned, build = PARENTS[kind]
     assert _hash(build) == pinned
 
 
-def test_a_long_rung_is_no_longer_the_parents_program():
-    paged = dict(name="llama", cache_slots=2, max_seq_len=2048,
-                 num_pages=2 * 256 + 1, page_tokens=8, **BASE)
-    on = _hash(lambda: llama.build_llama_prefill(1, 2048, **paged))
+@pytest.mark.parametrize("kind", sorted(LONG_PARENTS))
+def test_rungs_of_2048_and_more_are_pr_64s_programs(kind):
+    """The rule of the short rungs (ISSUE 65) leaves the long ones'
+    programs as they were: every product, 256-row segments."""
+    pinned, build = LONG_PARENTS[kind]
+    assert "mul_valid_rows" in _ops_of(build)
+    assert _hash(build) == pinned
+
+
+@pytest.mark.parametrize("rung,plain", [(2048, "1fbd2bc474694a9a"),
+                                        (1024, "a5d525a1c8bfdee9")])
+def test_a_long_rung_is_no_longer_the_parents_program(rung, plain):
+    """``plain``: the rung's program before the mechanism reached it (2048:
+    PR 64's parent, 1024: PR 65's), which ``stop_at_prompt=False`` still
+    builds."""
+    on = _hash(lambda: llama.build_llama_prefill(1, rung, **_paged(rung),
+                                                 **BASE))
     off = _hash(lambda: llama.build_llama_prefill(
-        1, 2048, stop_at_prompt=False, **paged))
-    assert off == "1fbd2bc474694a9a" and on != off
+        1, rung, stop_at_prompt=False, **_paged(rung), **BASE))
+    assert off == plain and on != off
 
 
 # ---------------------------------------------------------------------------
 # the engine's account
 # ---------------------------------------------------------------------------
 
-def test_engine_counts_the_rows_a_prefill_ran_and_skipped():
+@pytest.mark.parametrize("rung", [2048, 1024])
+def test_engine_counts_the_rows_a_prefill_ran_and_skipped(rung):
     from paddle_tpu.serving import GenerationEngine
 
-    kw = dict(num_slots=2, max_seq_len=2112, max_new_tokens=2,
-              prefill_buckets=[64, 2048], page_tokens=64, prefill_chunk=0,
+    kw = dict(num_slots=2, max_seq_len=rung + 64, max_new_tokens=2,
+              prefill_buckets=[64, rung], page_tokens=64, prefill_chunk=0,
               prefix_reuse=False, speculate=False, attn_impl="xla", seed=0,
               keep_logits=True, deadline_ms=600000.0)
     old = pt.get_flags(["FLAGS_telemetry"])
@@ -370,8 +461,11 @@ def test_engine_counts_the_rows_a_prefill_ran_and_skipped():
                                                 stop_at_prompt=False)
     try:
         on, off = ([op.type for op in e._prefill_prog_for(
-            2048)[0].global_block().ops] for e in (eng, plain))
-        assert "mul_valid_rows" in on and "mul_valid_rows" not in off
+            rung)[0].global_block().ops] for e in (eng, plain))
+        # (rung 1024 at hidden 32: the SwiGLU alone stops at the prompt)
+        assert "swiglu_valid_rows" in on and "swiglu_valid_rows" not in off
+        assert ("mul_valid_rows" in on) == (rung == 2048)
+        assert "mul_valid_rows" not in off
         run0 = stat_get("serving_prefill_rows_run")
         skip0 = stat_get("serving_prefill_rows_skipped")
         rng = np.random.default_rng(64)
@@ -381,17 +475,18 @@ def test_engine_counts_the_rows_a_prefill_ran_and_skipped():
         got = [eng.submit(p).result(timeout=600) for p in (long, short)]
         want = [plain.submit(p).result(timeout=600) for p in (long, short)]
         c = eng.stats()["counters"]
-        # 600 tokens on rung 2048: three segments of 256 run, five are
-        # skipped; rung 64 is under the rule: every row runs
+        # 600 tokens on rung 2048 (1024): three segments of 256 run, five
+        # (one) are skipped; rung 64 is under the rule: every row runs
         assert (c["prefill_rows_run"], c["prefill_rows_skipped"]) \
-            == (768 + 64, 1280)
+            == (768 + 64, rung - 768)
         assert stat_get("serving_prefill_rows_run") - run0 >= 768 + 64
-        assert stat_get("serving_prefill_rows_skipped") - skip0 >= 1280
+        assert stat_get("serving_prefill_rows_skipped") - skip0 \
+            >= rung - 768
         spans = {s.attrs["bucket"]: s.attrs["rows_run"]
                  for s in telemetry.get_spans()
                  if s.name == "generation/prefill"
                  and "rows_run" in s.attrs}
-        assert spans == {2048: 768, 64: 64}
+        assert spans == {rung: 768, 64: 64}
         for g, w in zip(got, want):
             assert g["tokens"] == w["tokens"]
             np.testing.assert_allclose(np.asarray(g["logits"]),

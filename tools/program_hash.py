@@ -40,6 +40,10 @@ builds it over a mesh of the cell's chips: the step's own ``jit`` beside the
 stored call under the same shardings, donation and compiler options.
 ``--dump DIR`` (after ``--compiled``) also writes every executable's text as
 it is hashed, ``DIR/<n>.txt`` in the order of the lines printed, for a diff.
+``--rungs R,R`` (PR 65; after ``--compiled`` and ``--dump``) compiles those
+of each cell's rungs that it has instead of the widest, the step's own
+``jit`` alone and no decode program: what a PR that changes some rungs
+compares on two trees (temporaries, seconds of ``lower`` + ``compile``).
 """
 import hashlib
 import json
@@ -65,6 +69,10 @@ def main(argv) -> int:
         os.makedirs(dump, exist_ok=True)
         del argv[1:3]
     dumped = []
+    only = None
+    if argv[1:2] == ["--rungs"]:
+        only = {int(r) for r in argv[2].split(",")}
+        del argv[1:3]
     tree = os.path.abspath(argv[1])
     cells = argv[2:] or (COMPILED_CELLS if compiled else CELLS)
     sys.path[:0] = [os.path.join(tree, "benchmark"), tree]
@@ -159,7 +167,8 @@ def main(argv) -> int:
         with pt.program_guard(main, startup):
             out = build()
         feeds, fetches = out[0], out[1]
-        names = [fetches[n].name for n in ("next_token", "expert_counts")
+        names = [fetches[n].name for n in ("next_token", "tokens",
+                                           "rows_written", "expert_counts")
                  if n in fetches]
         block = main.global_block()
 
@@ -197,6 +206,8 @@ def main(argv) -> int:
         from paddle_tpu import program_store
 
         direct, direct_s = executable(jitted, args)
+        if only:
+            return "direct %s in %.1f s" % (direct, direct_s)
         blob = program_store.export_step(jitted, args, ("tpu",)).serialize()
         stored, stored_s = executable(program_store.wrapped(
             export.deserialize(blob), donate_argnums, **jit_kwargs), args)
@@ -230,6 +241,10 @@ def main(argv) -> int:
             continue
         cfg, e = cell.cfg, cell.mix["engine"]
         model = cell.builder().model_args(cfg)
+        # (block diffusion: what ``GenerationEngine._blk_args`` hands on)
+        bd = model.pop("block_diffusion", None)
+        blk = {"block": bd["block"], "mask_id": bd["mask_id"]} if bd else {}
+        mask = {"mask_block": bd["block"]} if bd else {}
         slots, pt_, seq = e["num_slots"], e["page_tokens"], e["max_seq_len"]
         np_slot = seq // pt_
         pages = slots * np_slot + 1
@@ -241,14 +256,20 @@ def main(argv) -> int:
         paged = dict(num_pages=pages, page_tokens=pt_,
                      num_window_pages=wpages)
         table = ((slots, np_slot), "int32")
-        shapes = {"tokens": ((slots, 1), "int64"),
+        rows = bd["block"] if bd else 1
+        shapes = {"tokens": ((slots, rows), "int64"),
                   "positions": ((slots,), "int32"), "block_tables": table,
-                  "live": ((slots,), "int32"), "block_tables_window": table}
-        print(name, "decode", lowered(lambda: llama.build_llama_decode(
-            slots, seq, name="llama", **paged, **model), shapes), flush=True)
+                  "live": ((slots,), "int32"), "block_tables_window": table,
+                  "masked": ((slots, rows), "int32"),
+                  "quota": ((slots,), "int32"), "fresh": ((slots,), "int32")}
+        if not only:
+            print(name, "decode", lowered(lambda: llama.build_llama_decode(
+                slots, seq, name="llama", **paged, **blk, **model), shapes),
+                flush=True)
         rungs = sorted(e["prefill_buckets"])
         one = ((1, np_slot), "int32")
-        for b in rungs[-1:] if compiled else rungs:
+        for b in [r for r in rungs if r in only] if only \
+                else rungs[-1:] if compiled else rungs:
             if chunk:
                 shapes = {"chunk_ids": ((1, b), "int64"),
                           "base": ((1,), "int32"), "block_table": one,
@@ -267,7 +288,7 @@ def main(argv) -> int:
                           "slot": ((1,), "int32")}
                 got = lowered(lambda: llama.build_llama_prefill(
                     1, b, name="llama", cache_slots=slots, max_seq_len=seq,
-                    paged=True, **paged, **model), shapes)
+                    paged=True, **paged, **mask, **model), shapes)
             print(name, "chunk" if chunk else "prefill", b, got, flush=True)
     return 0
 
