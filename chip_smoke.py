@@ -1850,6 +1850,148 @@ STORE_STATS = ("program_store_hits", "program_store_misses",
                "attention_lowered_paged_decode_reference")
 
 
+SHORTCUT = dict(hidden=6144, heads=8, q_rank=1536, latent=512, nope=128,
+                rope=64, v=128, ffn=12288, experts=768, zero=256, held=8,
+                width=2048, top_k=12, factor=6.0, rows=(32, 512), vocab=2048,
+                chunk=512, max_seq=1024, prompt=700, steps=3)
+
+
+def shortcut_phase(cfg=SHORTCUT):
+    """What a decoder of shortcut-connected layers under a router with
+    identity experts adds (PR 66), at longcat-flash-chat's published widths
+    for one chip of its 64-chip group: the expert branch (a 768-wide
+    softmax router with a selection bias whose last 256 outputs are
+    identity experts, 12 picks a token weighed 6 p unnormalised, 8 of the
+    512 real experts of 2048 held over 6144) at a decode step's 32 rows and
+    a chunk's 512 against a written-out sum, the held products on the
+    Pallas grouped matmul inside the scoped VMEM; and one round trip
+    through a two-slot ``GenerationEngine`` of ONE published layer (two
+    latent sublayers of 8 heads with the inner norms scaled 2 and 3.4641,
+    two dense SwiGLUs of 12288, the branch carried past the second
+    sublayer): a 700-token prompt in two chunks over latent pages and three
+    absorbed decode steps against the uncached forward, with what the
+    router's picks were."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.monitor import stat_get
+    from paddle_tpu.parallel import moe
+    from paddle_tpu.serving import GenerationEngine
+
+    hid, E, Z, held = cfg["hidden"], cfg["experts"], cfg["zero"], cfg["held"]
+    I, k, factor = cfg["width"], cfg["top_k"], cfg["factor"]
+    key = jax.random.key(66)
+
+    def draw(i, *shape):
+        return jax.random.normal(jax.random.fold_in(key, i), shape)
+
+    router = draw(1, hid, E) * hid ** -0.5
+    bias = 0.001 * draw(2, E)
+    gate_up = draw(3, held, hid, 2 * I) * hid ** -0.5
+    down = draw(4, held, I, hid) * I ** -0.5
+    for rows in cfg["rows"]:
+        u = draw(10 + rows, rows, hid)
+        counted = {c: stat_get(c) for c in (
+            "grouped_matmul_lowered_pallas",
+            "grouped_matmul_lowered_ragged_dot")}
+        got, counts, _ = jax.jit(lambda *t: moe.moe_routed_tokens(
+            *t[:5], top_k=k, activation="silu", score="softmax",
+            expert_bias=t[5], norm_topk=False, route_scale=factor,
+            held_first=0, zero_experts=Z,
+            precision=jax.lax.Precision.HIGHEST))(
+                u, u, router, gate_up, down, bias)
+
+        @jax.jit
+        def written_out(u, router, gate_up, down, bias):
+            p = jax.nn.softmax(jnp.dot(u, router, precision="highest"), -1)
+            _, picks = jax.lax.top_k(p + bias, k)
+            dense = jnp.zeros_like(p).at[
+                jnp.arange(u.shape[0])[:, None], picks].set(
+                    factor * jnp.take_along_axis(p, picks, -1))
+
+            def one(e, acc):
+                gu = jnp.dot(u, gate_up[e], precision="highest")
+                y = jnp.dot(jax.nn.silu(gu[:, :I]) * gu[:, I:], down[e],
+                            precision="highest")
+                return acc + dense[:, e, None] * y
+
+            real = jax.lax.fori_loop(0, held, one, jnp.zeros_like(u))
+            return real + dense[:, E - Z:].sum(-1, keepdims=True) * u, dense
+
+        want, dense = written_out(u, router, gate_up, down, bias)
+        rel = float(jnp.abs(got - want).max() / jnp.abs(want).max())
+        grew = {c: stat_get(c) - v for c, v in counted.items()}
+        n_zero, n_held = int(counts[E - Z:].sum()), int(counts[:held].sum())
+        check(rel <= TOL and int(counts.sum()) == rows * k
+              and n_zero == int((dense[:, E - Z:] > 0).sum()),
+              f"the expert branch over {rows} rows off the written-out sum "
+              f"by {rel:.4g}, {int(counts.sum())} pairs placed")
+        check(grew == {"grouped_matmul_lowered_pallas": 2,
+                       "grouped_matmul_lowered_ragged_dot": 0},
+              f"the held experts' two products lowered to {grew}")
+        say(f"shortcut branch: {rows} rows x {k} picks over {E} outputs, "
+            f"{n_zero} identity, {n_held} held of {held} real experts of "
+            f"{I} over {hid}, within {rel:.4g} of the written-out sum; "
+            f"both products on the Pallas kernel")
+    del router, gate_up, down, u, got, want, dense
+
+    mla = {"q_rank": cfg["q_rank"], "kv_rank": cfg["latent"],
+           "nope_dim": cfg["nope"], "rope_dim": cfg["rope"],
+           "v_dim": cfg["v"], "interleave": True,
+           "q_norm_scale": (hid / cfg["q_rank"]) ** 0.5,
+           "kv_norm_scale": (hid / cfg["latent"]) ** 0.5}
+    experts = {"experts": E, "zero_experts": Z, "held": (0, held),
+               "top_k": k, "width": I, "activation": "silu",
+               "route_from": "normed", "score": "softmax",
+               "expert_bias": True, "norm_topk": False,
+               "route_scale": factor}
+    sub = {"mla": mla, "ffn": "dense"}
+    model = dict(vocab_size=cfg["vocab"], hidden=hid, num_layers=2,
+                 num_heads=cfg["heads"], num_kv_heads=cfg["heads"],
+                 intermediate=cfg["ffn"], rms_norm_eps=1e-5, rope_base=1e7,
+                 layer_pattern=[dict(sub, branch=experts),
+                                dict(sub, join=True)])
+    names = ("attention_lowered_latent_chunk",
+             "attention_lowered_latent_chunk_reference",
+             "attention_lowered_latent_decode")
+    before = [stat_get(n) for n in names]
+    gen = GenerationEngine(
+        model, num_slots=2, max_seq_len=cfg["max_seq"], eos_id=-1,
+        page_tokens=16, prefill_chunk=cfg["chunk"],
+        prefill_buckets=[cfg["chunk"] // 2, cfg["chunk"]], prefix_reuse=False,
+        speculate=False, keep_logits=True, deadline_ms=600000)
+    try:
+        gen.scope.set_var(f"{gen.name}.blk0.moe.expert_bias", bias)
+        prompt = np.random.default_rng(66).integers(
+            1, cfg["vocab"], cfg["prompt"]).tolist()
+        res = gen.generate(prompt, cfg["steps"], timeout=600)
+        counters = gen.stats()["counters"]
+        want = _forward_logits(gen, model, prompt + res["tokens"][:-1],
+                               cfg["max_seq"])[cfg["prompt"] - 1:]
+    finally:
+        gen.close()
+    got = np.stack(res["logits"])
+    grew = [stat_get(n) - b for n, b in zip(names, before)]
+    rel = float(np.abs(got - want).max() / np.abs(want).max())
+    routed, zero = counters["moe_pairs_routed"], counters["moe_pairs_zero"]
+    check(counters["prefill_chunks"] == 2
+          and counters["moe_tokens_dropped"] == 0
+          and 0 < counters["moe_pairs_held"] < routed - zero < routed,
+          f"the shortcut layer placed {routed} pairs: "
+          f"{counters['moe_pairs_held']} held, {zero} identity")
+    # two chunk rungs and the step, one latent op a SUBLAYER each
+    check(grew == [4, 0, 2], f"latent programs lowered "
+          f"{dict(zip(names, grew))}")
+    check(bool(np.isfinite(got).all()) and rel <= 2.0 ** -10,
+          f"the shortcut layer's chunks and steps off the uncached forward "
+          f"by {rel:.4g}")
+    say(f"shortcut layer: a {cfg['prompt']}-token prompt in 2 chunks over "
+        f"two latent caches and {cfg['steps']} steps, the branch carried "
+        f"past the second sublayer, within {rel:.4g} of the uncached "
+        f"forward; of {routed} routed pairs {counters['moe_pairs_held']} "
+        f"held, {zero} identity")
+
+
 DENSE = dict(hidden=2048, heads=16, kv_heads=8, ffn=8192, vocab=4096,
              rung=2048, prompts=(600, 2048), steps=1, rows=(768 + 2048, 1280))
 # (PR 65) a short rung at Mistral's layer shapes: the single products'
@@ -2115,6 +2257,12 @@ def main():
     single_sublayer_phase()
     say(f"grouped state-space kernels, latent experts of two matrices and "
         f"single-sublayer layers done [{time.perf_counter() - t0:.1f} s]")
+
+    t0 = time.perf_counter()
+    shortcut_phase()
+    say(f"shortcut-connected layer and identity experts done "
+        f"[{time.perf_counter() - t0:.1f} s]")
+    gc.collect()
 
     t0 = time.perf_counter()
     dense_rows_phase()

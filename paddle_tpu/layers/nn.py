@@ -1466,7 +1466,7 @@ def moe_routed_ffn(x, router_x, num_experts, top_k, d_ff,
                    keep_router_logits=False, score="softmax",
                    expert_bias=False, norm_topk=True, route_scale=1.0,
                    held=None, limit=None, n_group=1, topk_group=1,
-                   gated=True):
+                   gated=True, zero_experts=0, scope=None):
     """Dropless top-k mixture of gated experts without bias
     (ops/moe_ops.py ``moe_routed_ffn``): each token of ``x`` [B, S, H]
     goes to the ``top_k`` experts its row of ``router_x`` [B, S, H]
@@ -1495,6 +1495,13 @@ def moe_routed_ffn(x, router_x, num_experts, top_k, d_ff,
     matrix; the first stack is then ``.up.w`` [E, H, d_ff].  ``router_x``
     may be wider than ``x`` (a router over the full row beside experts
     that work in a latent one): ``.router.w`` is [its width, E].
+    ``zero_experts`` Z: the LAST Z of the ``num_experts`` router outputs are
+    identity ("zero-computation") experts: no weights, a pick adds its
+    routing weight times ``x`` (``parallel/moe.py`` ``moe_routed_tokens``);
+    the real experts are the first ``num_experts - Z``, ``held`` lies among
+    them, and with ``expert_bias`` a softmax router chooses by ``softmax +
+    bias`` and weighs by the unbiased softmax.  ``scope``: a named scope
+    around the layer's operations, in the compiled module's metadata.
     ``name`` prefixes the parameters ``.router.w`` [H, E], ``.gate_up.w``
     [E, H, 2 d_ff] and ``.down.w`` [E, d_ff, H].  Returns ``(out,
     expert_count [E] int32, router_logits or None)``."""
@@ -1502,12 +1509,16 @@ def moe_routed_ffn(x, router_x, num_experts, top_k, d_ff,
 
     helper = LayerHelper("moe_routed_ffn", name=name)
     h, e, i = int(x.shape[-1]), int(num_experts), int(d_ff)
-    here = e
+    zero = int(zero_experts)
+    if not 0 <= zero < e or (zero and int(n_group) > 1):
+        raise ValueError(f"moe_routed_ffn: {zero} identity experts among "
+                         f"{e} router outputs in {n_group} group(s)")
+    here = e - zero
     if held is not None:
         first, here = int(held[0]), int(held[1])
-        if not 0 <= first < first + here <= e:
+        if not 0 <= first < first + here <= e - zero:
             raise ValueError(f"moe_routed_ffn holds experts {first} .. "
-                             f"{first + here - 1} of {e}")
+                             f"{first + here - 1} of {e - zero}")
     p = (lambda s: f"{name}.{s}") if name else (lambda s: None)
     router_w = helper.create_parameter(
         p("router.w"), [int(router_x.shape[-1]), e], x.dtype)
@@ -1534,6 +1545,10 @@ def moe_routed_ffn(x, router_x, num_experts, top_k, d_ff,
         attrs["held_first"] = first
     if limit is not None:
         attrs["limit"] = float(limit)
+    if zero:
+        attrs["zero_experts"] = zero
+    if scope:
+        attrs["scope"] = str(scope)
     if expert_bias:
         inputs["ExpertBias"] = [helper.create_parameter(
             p("expert_bias"), [e], "float32", is_bias=True)]

@@ -96,9 +96,33 @@ def _program_build(kind, bucket_at=None):
 #           router reads the full row, ``u = h W_down`` [R] goes through
 #           the dispatch, the experts ([R, I] and [I, R]) and the combine,
 #           and their weighted sum comes up again, ``r W_up``, once a row;
-#           the shared expert stays at full width.  Or None: the layer has
+#           the shared expert stays at full width.  "zero_experts": Z
+#           makes the LAST Z of the router's E outputs identity
+#           ("zero-computation") experts: scored and picked as any other
+#           (one of the token's top_k places, its routing weight), no
+#           weights, no matmul, the row gets ``(sum of their weights) * u``
+#           added once; the real experts are 0 .. E - Z - 1 and "held" lies
+#           among them; with "expert_bias" a "softmax" router chooses by
+#           ``softmax + bias`` and weighs by the unbiased softmax over all
+#           E.  Or None: the layer has
 #           NO second half (a mixer alone: ``x = x + mixer(norm(x))``, one
 #           norm ``.ln1``, one residual add)
+#   branch: None, or such a dict of routed experts BESIDE a dense ``ffn``
+#           (shortcut-connected experts; ``norm`` "pre" only): the experts
+#           read ``u = norm(x; .ln2)``, the rows the dense FFN reads, and
+#           what they give, ``s = MoE(u)``, does NOT join the stream here:
+#           it is carried past this layer's FFN and the next layers' mixers,
+#           cache writes and FFNs to the first layer with
+#   join:   True: ``x = x + mixer(..); x = x + ffn(norm(x)) + s``, the
+#           carried branch joins after this layer's FFN (under
+#           ``residual_scale`` like any sublayer's output).  A published
+#           layer of two attention sublayers, two dense FFNs and one expert
+#           branch is two pattern layers, the first with "branch", the second
+#           with "join"; a branch that never joins, a join with nothing
+#           carried and a second branch before the first has joined are
+#           refused.  The branch's parameters are the routed FFN's
+#           (``.moe.*`` of the layer it leaves) and it counts as that
+#           layer's expert layer (``expert_layers``)
 #   mixer:  "attention" (q, k, v, RoPE, pages) or a dict {"kind": "conv",
 #           "L_cache": L, "bias": False}: a gated short convolution,
 #           ``[B, C, u] = split3(h W_in)``, ``y = (C * conv_L(B * u))
@@ -139,8 +163,10 @@ def _program_build(kind, bucket_at=None):
 #           (default (dn + dr) ** -0.5, times YaRN's ``mscale_all_dim``
 #           factor squared where "yarn" has that key: ``_mla_scale``),
 #           "interleave": rotate pairs (2i, 2i + 1), "yarn": None or
-#           ``layers.rope``'s dict}: queries
-#           through a low-rank pair with a norm between, ONE latent
+#           ``layers.rope``'s dict, "q_norm_scale" / "kv_norm_scale": what
+#           ``norm(h W_qa)`` and ``norm(c_kv)`` are multiplied by (default
+#           1; ``k_r`` is not; the cached row holds the scaled ``c_kv``)}:
+#           queries through a low-rank pair with a norm between, ONE latent
 #           ``c_kv`` [C] and one rotated key ``k_r`` [dr] a token shared
 #           by all heads, which are all that is cached (``cache_spec``
 #           kind "latent_pages"); the model's ``num_kv_heads`` and
@@ -157,7 +183,7 @@ def _program_build(kind, bucket_at=None):
 DEFAULT_LAYER = {"window": None, "rope": True, "ffn": "dense",
                  "attn_precision": None, "mixer": "attention",
                  "attn_gate": False, "mla": None, "swiglu_limit": None,
-                 "rope_interleave": False}
+                 "rope_interleave": False, "branch": None, "join": False}
 
 
 def layer_spec(layer_pattern, i):
@@ -288,10 +314,25 @@ def _cache_vars(block, spec, layer):
         stop_gradient=True) for e in spec if e["layer"] == layer)
 
 
+def routed_ffn(layer):
+    """A pattern layer's routed experts: its ``ffn`` where that is a dict,
+    else its ``branch`` (None: the layer routes nothing)."""
+    ffn = layer["ffn"]
+    return ffn if ffn not in ("dense", None) else layer.get("branch")
+
+
 def expert_layers(layer_pattern, num_layers):
-    """Indices of the layers whose FFN is routed experts."""
+    """Indices of the layers that route over experts: as their FFN, or as
+    a branch beside it."""
     return [i for i in range(num_layers)
-            if layer_spec(layer_pattern, i)["ffn"] not in ("dense", None)]
+            if routed_ffn(layer_spec(layer_pattern, i)) is not None]
+
+
+def _all_joined(carry):
+    """A model's layers are through: a branch still carried never joined."""
+    if carry:
+        raise ValueError("an expert branch left the stream and no later "
+                         "layer has 'join': True")
 
 
 def _linear(x, size, pname=None, name=None, rows=None):
@@ -701,8 +742,13 @@ def llama_block(x, hidden, num_heads, num_kv_heads, seq_len, head_dim,
                 qk_norm=False, mask_block=None, block=False,
                 conv_state=None, slot=None, live=None, norm="pre",
                 norm_kind="rms", chunk_pages=False, residual_scale=1.0,
-                attn_scale=None, dense_rows=None):
+                attn_scale=None, dense_rows=None, carry=None):
     """One decoder layer. x: [B, S, H].
+
+    ``carry``: the dict a model's layers share for what leaves the stream
+    at one layer and joins it at a later one (the pattern's ``branch`` /
+    ``join``): a layer with a branch puts its experts' output there, the
+    layer that joins takes it out.
 
     ``dense_rows`` ([1] int32; a whole-prompt prefill of a long rung,
     B = 1): the rows that hold a token, at which every dense product of
@@ -807,7 +853,9 @@ def llama_block(x, hidden, num_heads, num_kv_heads, seq_len, head_dim,
                     valid=valid, taps=taps, norm=norm,
                     limit=layer.get("swiglu_limit"), norm_kind=norm_kind,
                     h=h if norm == "parallel" else None,
-                    residual_scale=residual_scale, rows=dense_rows)
+                    residual_scale=residual_scale, rows=dense_rows,
+                    branch=layer.get("branch"), join=layer.get("join"),
+                    carry=carry)
     if layer["mixer"] is None or layer["ffn"] is None:
         if norm != "pre" or (layer["mixer"] is None
                              and layer["ffn"] is None):
@@ -995,8 +1043,10 @@ def _mla_mixer(h, seq_len, hidden, num_heads, layer, p, eps, rope_base,
                yarn=mla.get("yarn"),
                offset=positions if kv_cache is not None else None)
 
-    def normed(t, pname):
-        return layers.rms_norm(t, epsilon=eps, param_attr=p(pname))
+    def normed(t, pname, scale=1.0):
+        t = layers.rms_norm(t, epsilon=eps, param_attr=p(pname))
+        return t if float(scale) == 1.0 else layers.scale(
+            t, scale=float(scale))
 
     def cut(t, axis, lo, hi):
         return layers.slice(t, axes=[axis], starts=[lo], ends=[hi])
@@ -1006,13 +1056,14 @@ def _mla_mixer(h, seq_len, hidden, num_heads, layer, p, eps, rope_base,
                                 [0, 2, 1, 3])               # [B, n, S, d]
 
     q = heads(_linear(normed(_linear(h, rank_q, pname=p("q_a.w"), rows=rows),
-                             "q_a_norm"),
+                             "q_a_norm", mla.get("q_norm_scale", 1.0)),
                       num_heads * (dn + dr), pname=p("q_b.w"), rows=rows),
               num_heads, dn + dr)
     q_nope = cut(q, 3, 0, dn)
     q_rope = layers.rope(cut(q, 3, dn, dn + dr), **rot)
     kv_a = _linear(h, rank_kv + dr, pname=p("kv_a.w"), rows=rows)
-    c_kv = normed(cut(kv_a, 2, 0, rank_kv), "kv_a_norm")     # [B, S, C]
+    c_kv = normed(cut(kv_a, 2, 0, rank_kv), "kv_a_norm",
+                  mla.get("kv_norm_scale", 1.0))             # [B, S, C]
     k_r = layers.rope(heads(cut(kv_a, 2, rank_kv, rank_kv + dr), 1, dr),
                       **rot)                                 # [B, 1, S, dr]
     row = None
@@ -1093,7 +1144,8 @@ def _relu2_mlp(h, hidden, width, up_name, down_name, rows=None):
 
 def _ffn(x, x_in, hidden, intermediate, ffn, p, rms_norm_eps, valid, taps,
          norm="pre", limit=None, norm_kind="rms", h=None,
-         residual_scale=1.0, rows=None):
+         residual_scale=1.0, rows=None, branch=None, join=False,
+         carry=None):
     """The layer's second half on the post-mixer stream x: norm, dense
     SwiGLU or routed experts (``x_in``: the layer's raw input, which some
     routers read), residual.  ``norm``: where the norms sit
@@ -1105,58 +1157,90 @@ def _ffn(x, x_in, hidden, intermediate, ffn, p, rms_norm_eps, valid, taps,
     multiplied by as it joins the stream; ``rows``: :func:`_linear`'s, for
     the dense, the shared and the latent products (the routed experts
     leave padded rows out by ``valid``).  ``ffn`` None: the layer has no
-    second half and x is handed back."""
+    second half and x is handed back.  ``branch``: routed experts beside a
+    dense ``ffn``, which read the same normed rows and whose output goes
+    into ``carry`` and not onto the stream; ``join``: what ``carry`` holds
+    joins the stream behind this FFN's output (the pattern's keys)."""
+    if (branch or join) and (carry is None or norm != "pre"
+                             or ffn != "dense"):
+        raise ValueError(
+            f"an expert branch leaves and joins beside a dense FFN under "
+            f"norm 'pre', in a model that carries it (ffn {ffn!r}, norm "
+            f"{norm!r})")
     if ffn is None:
         return x
     pre, post = _norm_modes(norm)
     if h is None:
         h = _norm(x, rms_norm_eps, p("ln2"), norm_kind) if pre else x
     clamp = {} if limit is None else {"limit": float(limit)}
+    if branch:
+        if "branch" in carry:
+            raise ValueError("a second expert branch leaves the stream "
+                             "before the first has joined")
+        carry["branch"] = _routed(h, x_in, hidden, branch, p, valid, taps,
+                                  clamp, rows, scope="shortcut_branch")
     if ffn == "dense":
         y = _swiglu(h, hidden, intermediate, p("gate_up.w"), p("ffn_out.w"),
                     rows=rows, **clamp)
     else:
-        taps = taps if taps is not None else {}
-        # (a latent layer's experts read and write rows of that width:
-        # down before the dispatch, up after the combine, once a row)
-        latent = ffn.get("latent")
-        u = _linear(h, int(latent), pname=p("moe.latent_down.w"),
-                    rows=rows) if latent else h
-        y, counts, logits = layers.moe_routed_ffn(
-            u, h if ffn.get("route_from", "raw") == "normed" else x_in,
-            ffn["experts"], ffn["top_k"], ffn["width"],
-            activation=ffn.get("activation", "relu"), valid=valid,
-            name=p("moe"), keep_router_logits=bool(taps.get("keep_logits")),
-            **{k: ffn[k] for k in ("score", "expert_bias", "norm_topk",
-                                   "route_scale", "held", "n_group",
-                                   "topk_group", "gated") if k in ffn},
-            **clamp)
-        if latent:
-            y = _linear(y, hidden, pname=p("moe.latent_up.w"), rows=rows)
-        if int(ffn.get("n_group", 1)) > 1:
-            counts, group_rows = counts
-            taps.setdefault("group_rows", []).append(group_rows)
-        taps.setdefault("counts", []).append(counts)
-        if logits is not None:
-            taps.setdefault("logits", []).append(logits)
-        if ffn.get("shared_width"):
-            # every row, whatever it was routed to; on every chip of an
-            # expert-parallel group alike, so counted once
-            shared = _swiglu(
-                h, hidden, int(ffn["shared_width"]),
-                p("moe.shared_gate_up.w"), p("moe.shared_down.w"),
-                rows=rows, **clamp) \
-                if ffn.get("gated", True) else _relu2_mlp(
-                    h, hidden, int(ffn["shared_width"]),
-                    p("moe.shared_up.w"), p("moe.shared_down.w"), rows=rows)
-            if float(ffn.get("shared_scale", 1.0)) != 1.0:
-                shared = layers.scale(shared,
-                                      scale=float(ffn["shared_scale"]))
-            y = layers.elementwise_add(y, shared)
+        y = _routed(h, x_in, hidden, ffn, p, valid, taps, clamp, rows)
     if post:
         y = _norm(y, rms_norm_eps, p("ln2_post" if pre else "ln2"),
                   norm_kind)
-    return _residual(x, y, residual_scale)
+    x = _residual(x, y, residual_scale)
+    if join:
+        if "branch" not in carry:
+            raise ValueError("a layer with 'join' and no branch carried to "
+                             "it")
+        x = _residual(x, carry.pop("branch"), residual_scale)
+    return x
+
+
+def _routed(h, x_in, hidden, ffn, p, valid, taps, clamp, rows, scope=None):
+    """The routed experts ``ffn`` (a pattern dict) on normed rows ``h``,
+    with their shared expert where they have one: what :func:`_ffn` adds to
+    the stream, or carries as a branch (``scope``: the name its operations
+    carry in the compiled module's metadata).  ``taps`` gets the layer's
+    counts and router logits; the other arguments are :func:`_ffn`'s."""
+    taps = taps if taps is not None else {}
+    # (a latent layer's experts read and write rows of that width:
+    # down before the dispatch, up after the combine, once a row)
+    latent = ffn.get("latent")
+    u = _linear(h, int(latent), pname=p("moe.latent_down.w"),
+                rows=rows) if latent else h
+    y, counts, logits = layers.moe_routed_ffn(
+        u, h if ffn.get("route_from", "raw") == "normed" else x_in,
+        ffn["experts"], ffn["top_k"], ffn["width"],
+        activation=ffn.get("activation", "relu"), valid=valid,
+        name=p("moe"), keep_router_logits=bool(taps.get("keep_logits")),
+        **{k: ffn[k] for k in ("score", "expert_bias", "norm_topk",
+                               "route_scale", "held", "n_group",
+                               "topk_group", "gated", "zero_experts")
+           if k in ffn},
+        **({"scope": scope} if scope else {}), **clamp)
+    if latent:
+        y = _linear(y, hidden, pname=p("moe.latent_up.w"), rows=rows)
+    if int(ffn.get("n_group", 1)) > 1:
+        counts, group_rows = counts
+        taps.setdefault("group_rows", []).append(group_rows)
+    taps.setdefault("counts", []).append(counts)
+    if logits is not None:
+        taps.setdefault("logits", []).append(logits)
+    if ffn.get("shared_width"):
+        # every row, whatever it was routed to; on every chip of an
+        # expert-parallel group alike, so counted once
+        shared = _swiglu(
+            h, hidden, int(ffn["shared_width"]),
+            p("moe.shared_gate_up.w"), p("moe.shared_down.w"),
+            rows=rows, **clamp) \
+            if ffn.get("gated", True) else _relu2_mlp(
+                h, hidden, int(ffn["shared_width"]),
+                p("moe.shared_up.w"), p("moe.shared_down.w"), rows=rows)
+        if float(ffn.get("shared_scale", 1.0)) != 1.0:
+            shared = layers.scale(shared,
+                                  scale=float(ffn["shared_scale"]))
+        y = layers.elementwise_add(y, shared)
+    return y
 
 
 def llama(input_ids, vocab_size=32000, hidden=4096, num_layers=32,
@@ -1181,6 +1265,7 @@ def llama(input_ids, vocab_size=32000, hidden=4096, num_layers=32,
     head_dim = head_dim or hidden // num_heads
     p = (lambda s: f"{name}.{s}") if name else (lambda s: None)
     x = _embed(input_ids, vocab_size, hidden, p("embed"), embed_scale)
+    carry = {}
     for i in range(num_layers):
         x = llama_block(x, hidden, num_heads, num_kv_heads, seq_len,
                         head_dim, intermediate,
@@ -1190,7 +1275,8 @@ def llama(input_ids, vocab_size=32000, hidden=4096, num_layers=32,
                         layer=layer_spec(layer_pattern, i),
                         qk_norm=qk_norm, mask_block=mask_block, norm=norm,
                         norm_kind=norm_kind, residual_scale=residual_scale,
-                        attn_scale=attn_scale)
+                        attn_scale=attn_scale, carry=carry)
+    _all_joined(carry)
     x = _norm(x, rms_norm_eps, p("ln_f"), norm_kind)
     return _head(x, vocab_size, name, tie_head, logit_scale)
 
@@ -1395,6 +1481,7 @@ def build_llama_prefill(batch_size, seq_len, vocab_size=32000,
                           or expert_layers(layer_pattern, num_layers)):
         valid = layers.cast(last_pos + 1, "int32")
     block = default_main_program().global_block()
+    carry = {}
     for i in range(num_layers):
         caches = _cache_vars(block, spec, i)
         lspec = layer_spec(layer_pattern, i)
@@ -1410,7 +1497,7 @@ def build_llama_prefill(batch_size, seq_len, vocab_size=32000,
                               norm_kind=norm_kind,
                               residual_scale=residual_scale,
                               attn_scale=attn_scale, dense_rows=dense_rows,
-                              **state)
+                              carry=carry, **state)
         if lspec["mixer"] is None:
             continue                 # an FFN alone leaves nothing behind
         if lspec["mixer"] != "attention":
@@ -1433,6 +1520,7 @@ def build_llama_prefill(batch_size, seq_len, vocab_size=32000,
                     prompt_len, whole_pages=seq_len % page_tokens == 0)
         else:
             kvs.append((i, {"latent": k} if v is None else {"k": k, "v": v}))
+    _all_joined(carry)
     if mask_block is not None:
         # (kept router logits are every row's, [B, L_moe, S, E]: no row
         # is yielded, so none is picked)
@@ -1592,6 +1680,7 @@ def build_llama_decode(num_slots, max_seq_len, vocab_size=32000,
     cache_names = [e["name"] for e in spec if e["kind"] != "slot_state"]
     x = _embed(tokens, vocab_size, hidden, f"{name}.embed", embed_scale)
     taps = {"keep_logits": keep_router_logits}
+    carry = {}
     for i in range(num_layers):
         caches = _cache_vars(gblock, spec, i)
         lspec = layer_spec(layer_pattern, i)
@@ -1610,7 +1699,8 @@ def build_llama_decode(num_slots, max_seq_len, vocab_size=32000,
                         layer=lspec, valid=n_rows, taps=taps,
                         qk_norm=qk_norm, block=bool(block), norm=norm,
                         norm_kind=norm_kind, residual_scale=residual_scale,
-                        attn_scale=attn_scale, **cache)
+                        attn_scale=attn_scale, carry=carry, **cache)
+    _all_joined(carry)
     x = _norm(x, rms_norm_eps, f"{name}.ln_f", norm_kind)
     logits = _head(x, vocab_size, name, tie_head,
                    logit_scale)                              # [slots,1,V]
@@ -1696,6 +1786,7 @@ def _chunk_forward(chunk_len, max_seq_len, num_pages, page_tokens,
     cache_names = [e["name"] for e in spec]
     x = _embed(chunk_ids, vocab_size, hidden, f"{name}.embed", embed_scale)
     taps = {"keep_logits": keep_router_logits}
+    carry = {}
     for i in range(num_layers):
         # rope offset = base per row; the attention's validity mask
         # (j <= base + t) is exactly causal-over-prefix-plus-chunk
@@ -1709,7 +1800,9 @@ def _chunk_forward(chunk_len, max_seq_len, num_pages, page_tokens,
                         layer=layer_spec(layer_pattern, i), valid=ck_len,
                         taps=taps, qk_norm=qk_norm, norm=norm,
                         norm_kind=norm_kind, chunk_pages=page_aligned,
-                        residual_scale=residual_scale, attn_scale=attn_scale)
+                        residual_scale=residual_scale, attn_scale=attn_scale,
+                        carry=carry)
+    _all_joined(carry)
     return feeds, x, cache_names, taps
 
 

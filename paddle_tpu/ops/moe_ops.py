@@ -11,6 +11,8 @@ context, the same pattern as flash_attention:
 """
 from __future__ import annotations
 
+import contextlib
+
 from .registry import in_var, register_op, set_out
 
 
@@ -76,7 +78,14 @@ def _moe_routed_ffn(ctx, op):
     one).  Attribute ``limit``: the experts' SwiGLU clamp.  Attributes
     ``n_group`` / ``topk_group``: group-limited selection
     (``route_top_k``); the output GroupRows [n_group] int32 then counts the
-    valid rows that kept each group.  Inference only."""
+    valid rows that kept each group.  Attribute ``zero_experts`` Z: the
+    last Z of RouterW's E outputs are identity experts (no weights; a pick
+    adds its routing weight times X), ExpertBias then moves a softmax
+    router's choice too, and ExpertCount's last Z count their picks.
+    Attribute ``scope``: a ``jax.named_scope`` around the whole layer: the
+    compiled module's ``op_name`` metadata carries it (the profiler's
+    event names on a TPU do not: PERF.md section 6, PR 66).  Inference
+    only."""
     import jax
     import jax.numpy as jnp
 
@@ -94,21 +103,26 @@ def _moe_routed_ffn(ctx, op):
     n_group = int(op.attr("n_group", 1))
     topk_group = int(op.attr("topk_group", 1))
     router_x = ctx.get_input(op, "RouterX")
-    out, counts, logits = moe_routed_tokens(
-        x.reshape(-1, shape[-1]), router_x.reshape(-1, router_x.shape[-1]),
-        ctx.get_input(op, "RouterW"), ctx.get_input(op, "GateUpW"),
-        ctx.get_input(op, "DownW"), top_k=int(op.attr("top_k")),
-        activation=op.attr("activation", "relu"), valid=valid,
-        precision=_mm_precision(x.dtype),
-        score=op.attr("score", "softmax"),
-        expert_bias=ctx.get_input(op, "ExpertBias")
-        if op.single_input("ExpertBias") else None,
-        norm_topk=bool(op.attr("norm_topk", True)),
-        route_scale=float(op.attr("route_scale", 1.0)),
-        held_first=op.attr("held_first", None),
-        limit=op.attr("limit", None),
-        mesh_devices=ctx.mesh.devices.size if ctx.mesh is not None else 1,
-        n_group=n_group, topk_group=topk_group)
+    scope = op.attr("scope", None)
+    with jax.named_scope(scope) if scope else contextlib.nullcontext():
+        out, counts, logits = moe_routed_tokens(
+            x.reshape(-1, shape[-1]),
+            router_x.reshape(-1, router_x.shape[-1]),
+            ctx.get_input(op, "RouterW"), ctx.get_input(op, "GateUpW"),
+            ctx.get_input(op, "DownW"), top_k=int(op.attr("top_k")),
+            activation=op.attr("activation", "relu"), valid=valid,
+            precision=_mm_precision(x.dtype),
+            score=op.attr("score", "softmax"),
+            expert_bias=ctx.get_input(op, "ExpertBias")
+            if op.single_input("ExpertBias") else None,
+            norm_topk=bool(op.attr("norm_topk", True)),
+            route_scale=float(op.attr("route_scale", 1.0)),
+            held_first=op.attr("held_first", None),
+            limit=op.attr("limit", None),
+            mesh_devices=ctx.mesh.devices.size if ctx.mesh is not None
+            else 1,
+            n_group=n_group, topk_group=topk_group,
+            zero_experts=int(op.attr("zero_experts", 0)))
     ctx.set_output(op, "Out", out.reshape(shape))
     ctx.set_output(op, "ExpertCount", counts)
     if op.output("GroupRows"):

@@ -48,7 +48,7 @@ def tiles(m, k, n, scoped=False):
     if m < ROW_BLOCK:
         return None
     tn = next((t for t in (n, *range(n - n % LANES, 0, -LANES))
-               if n % t == 0 and k * t * 4 <= block_bytes(scoped)), None)
+               if n % t == 0 and block_fits(k, t, scoped)), None)
     return tn and (ROW_BLOCK, tn)
 
 
@@ -224,3 +224,33 @@ def grouped_matmul_epilogue(rows, weights, group_sizes, row_scale=None, *,
         name="grouped_matmul_ragged-dot",
         interpret=interpret,
     )(offsets, group, block, *operands)
+
+
+# ---------------------------------------------------------------------------
+# PR 66: what a block of weights takes of the scoped VMEM beside itself.
+# Down here so that every call above stays on its lines.
+# ---------------------------------------------------------------------------
+
+SCOPED_VMEM_BYTES = 16 << 20   # what XLA:TPU gives an operation by default
+COMPILER_TEMP_COLUMNS = 256
+
+
+def block_fits(k, tn, scoped):
+    """Whether a group's ``[k, tn]`` block of weights may be a call's:
+    under :func:`block_bytes`, and for a ``scoped`` call inside the default
+    scoped VMEM with what Mosaic lays beside the two copies the pipeline
+    holds: from :data:`COMPILER_TEMP_COLUMNS` columns a block up, a ``[k,
+    256]`` float32 temporary of its own (compile-only readings for a v5e,
+    PR 66: inside a decode program ``[6144, 256]`` blocks asked for 18.14
+    MiB, 12 of blocks and 6.14 beside them, and were refused; ``[5120,
+    256]``, the widest an accepted cell runs, 15.14; ``[6144, 128]`` and
+    ``[12288, 128]`` nothing beside the blocks), and a quarter MiB of
+    slack.  Every block the accepted cells run fits as it did."""
+    block = k * tn * 4
+    if block > block_bytes(scoped):
+        return False
+    if not scoped:
+        return True
+    temp = k * COMPILER_TEMP_COLUMNS * 4 if tn >= COMPILER_TEMP_COLUMNS else 0
+    return 2 * block + temp + (1 << 18) <= SCOPED_VMEM_BYTES
+

@@ -182,7 +182,13 @@ def route_top_k(router_x, router_w, top_k: int, score: str = "softmax",
     and only the ``topk_group`` best groups (:func:`group_keep`) keep
     their scores, the others read 0; the ``top_k`` largest of what is left
     are the token's experts, weighted by their ``s`` (with ``norm_topk``
-    divided by their sum plus 1e-20)."""
+    divided by their sum plus 1e-20).
+
+    ``score`` "softmax" WITH ``expert_bias``: :func:`_softmax_biased`, the
+    k largest of ``softmax(logits) + expert_bias`` weighted by their
+    unbiased softmax over all E.  Where the last Z of the router's outputs
+    are identity experts (:func:`moe_routed_tokens`' ``zero_experts``) they
+    are scored and picked as any other: a pick ``e >= E - Z`` is one."""
     import jax
     import jax.numpy as jnp
 
@@ -199,6 +205,9 @@ def route_top_k(router_x, router_w, top_k: int, score: str = "softmax",
         weights, experts = jax.lax.top_k(jnp.where(keep, s, 0.0), top_k)
         if norm_topk:
             weights = weights / (weights.sum(axis=-1, keepdims=True) + 1e-20)
+    elif score == "softmax" and expert_bias is not None:
+        experts, weights = _softmax_biased(logits, expert_bias, top_k,
+                                           norm_topk)
     elif score == "softmax":
         top, experts = jax.lax.top_k(logits, top_k)
         weights = jax.nn.softmax(top, axis=-1) if norm_topk else jnp.exp(
@@ -372,7 +381,7 @@ def moe_routed_tokens(x, router_x, router_w, w_gate_up, w_down, *,
                       expert_bias=None, norm_topk: bool = True,
                       route_scale: float = 1.0, held_first=None,
                       limit=None, mesh_devices: int = 1, n_group: int = 1,
-                      topk_group: int = 1):
+                      topk_group: int = 1, zero_experts: int = 0):
     """Dropless top-k mixture of gated experts over flat tokens.
 
     x [N, H] is the experts' input, router_x [N, H] what the router reads;
@@ -402,7 +411,16 @@ def moe_routed_tokens(x, router_x, router_w, w_gate_up, w_down, *,
     pairs alone, so ``counts.sum() == valid.sum() * k`` proves no token
     was dropped.  Under ``held_first`` they are the pairs routed to each
     of the E experts, and their slice over the held experts is what the
-    matmuls ran with."""
+    matmuls ran with.
+
+    ``zero_experts`` Z: the LAST Z of the router's E outputs are identity
+    ("zero-computation") experts, which have no weights and return their
+    input: such a pick takes one of the token's ``top_k`` places and its
+    routing weight, reaches no dispatch and no product, and the row gets
+    ``(sum of its identity picks' weights) * x`` (:func:`_zero_term`) added
+    once.  The real experts are ``0 .. E - Z - 1`` (``held_first``'s range
+    lies among them; without it all of them are held, at index 0), and
+    ``counts`` stay [E]: the last Z are the identity picks."""
     import jax
     import jax.numpy as jnp
 
@@ -412,6 +430,8 @@ def moe_routed_tokens(x, router_x, router_w, w_gate_up, w_down, *,
     logits, experts, weights = route_top_k(
         router_x, router_w, top_k, score, expert_bias, norm_topk,
         route_scale, n_group, topk_group)
+    if zero_experts and held_first is None:
+        held_first = 0
     if held_first is not None:
         held = w_gate_up.shape[0]
         pair_valid = jnp.ones((N, 1), bool) if valid is None \
@@ -421,6 +441,9 @@ def moe_routed_tokens(x, router_x, router_w, w_gate_up, w_down, *,
         out = _held_share(x, jnp.where(here, experts - held_first, held),
                           weights, w_gate_up, w_down, activation, precision,
                           limit, mesh_devices, E)
+        if zero_experts:
+            out = out + _zero_term(x, experts >= E - zero_experts, weights,
+                                   pair_valid)
         counts = jnp.zeros((E,), jnp.int32).at[experts.reshape(-1)].add(
             jnp.broadcast_to(pair_valid, experts.shape).reshape(-1)
             .astype(jnp.int32))
@@ -675,3 +698,37 @@ def _acted(h, inter, activation, limit=None):
     [M, 2 inter] or [M, inter]: the same epilogue, as a pass of its own."""
     return _after(h, None,
                   **_first_epilogue(h.shape[1], inter, activation, limit))
+
+
+# ---------------------------------------------------------------------------
+# PR 66: a router whose last outputs are identity experts, chosen by softmax
+# plus a selection bias.  Down here for PR 57's reason.
+# ---------------------------------------------------------------------------
+
+def _softmax_biased(logits, expert_bias, top_k, norm_topk):
+    """:func:`route_top_k`'s third form: ``p = softmax(logits)`` over all E;
+    the ``top_k`` largest of ``p + expert_bias`` are chosen (ties to the
+    lower index); the weights are their UNBIASED ``p``, with ``norm_topk``
+    divided by their sum plus 1e-20.  Returns ``(experts, weights)``."""
+    import jax
+    import jax.numpy as jnp
+
+    p = jax.nn.softmax(logits, axis=-1)
+    _, experts = jax.lax.top_k(p + expert_bias.astype(jnp.float32), top_k)
+    weights = jnp.take_along_axis(p, experts, axis=-1)
+    if norm_topk:
+        weights = weights / (weights.sum(axis=-1, keepdims=True) + 1e-20)
+    return experts, weights
+
+
+def _zero_term(x, zero, weights, pair_valid):
+    """What a row's identity picks add: ``(sum of their weights) * x`` in
+    float32, once a row.  ``zero`` [N, k] marks the picks, ``pair_valid``
+    [N, 1] the real rows: a row behind it is selected to 0, never
+    multiplied."""
+    import jax.numpy as jnp
+
+    w = jnp.where(zero, weights.astype(jnp.float32), 0.0) \
+        .sum(axis=-1, keepdims=True)
+    return jnp.where(pair_valid, w * x.astype(jnp.float32), 0.0) \
+        .astype(x.dtype)

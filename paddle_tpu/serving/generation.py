@@ -155,7 +155,9 @@ pattern's ``held``) ``moe_pairs_routed`` / ``moe_pairs_held`` (the
 token-expert pairs the router placed over all its experts, and those
 whose expert is held here and went through the matmuls; under
 group-limited selection ``moe_rows_group_held``, the row-layers whose kept
-groups include the one held here), with a shared
+groups include the one held here; where the router's last outputs are
+identity experts, the pattern's ``zero_experts``, ``moe_pairs_zero``, the
+pairs they took, which no chip multiplies), with a shared
 expert ``moe_shared_expert_rows`` (row-layers it ran on),
 ``serving_block_passes_denoise`` / ``serving_block_passes_commit``
 (block diffusion: slot-passes that decided positions / that only
@@ -541,7 +543,7 @@ class GenerationEngine:
         import paddle_tpu as pt
         from ..compile_cache import ensure_compile_cache
         from ..models.llama import (build_llama_prefill, expert_layers,
-                                    layer_spec)
+                                    layer_spec, routed_ffn)
 
         ensure_compile_cache()
         self.model = dict(model)
@@ -639,13 +641,16 @@ class GenerationEngine:
         self._window_layers = kv.layers_of("window_pages")
         # attention layers whose pages hold one latent row a token
         self._latent_layers = kv.layers_of("latent_pages")
-        routed = [specs[i]["ffn"] for i in expert_layers(
+        routed = [routed_ffn(specs[i]) for i in expert_layers(
             self.model.get("layer_pattern"), self.model["num_layers"])]
         self._moe_top_k = routed[0]["top_k"] if routed else 0
         # one chip's share of an expert-parallel group: the range of the
         # router's experts held here (None: all), and a shared expert
         self._moe_held = routed[0].get("held") if routed else None
         self._moe_shared = bool(routed and routed[0].get("shared_width"))
+        # the router's last outputs that are identity experts (0: none)
+        self._moe_zero = int(routed[0].get("zero_experts", 0)) if routed \
+            else 0
         # group-limited selection: the router's groups (1: none)
         self._moe_groups = int(routed[0].get("n_group", 1)) if routed else 1
         self._build_fn_prefill = build_llama_prefill
@@ -747,6 +752,7 @@ class GenerationEngine:
                    "slot_state_writes": 0, "delta_state_steps": 0,
                    "ssm_state_steps": 0,
                    "moe_pairs_routed": 0, "moe_pairs_held": 0,
+                   "moe_pairs_zero": 0,
                    "moe_rows_group_held": 0, "moe_shared_expert_rows": 0}
         self._n_lock = threading.Lock()
         # per-bucket manifest-flops cache for usage attribution: the
@@ -2274,7 +2280,8 @@ class GenerationEngine:
                     # programs: each read the held experts it touched)
                     span.attrs.update({
                         k: sum(b[k] for b in booked) for k in (
-                            "pairs_routed", "pairs_held", "rows_group_held",
+                            "pairs_routed", "pairs_held", "pairs_absent",
+                            "pairs_zero", "rows_group_held",
                             "pad_pairs_left_out", "experts_held_touched")
                         if k in booked[0]})
         finally:
@@ -2307,9 +2314,11 @@ class GenerationEngine:
             stat_add("moe_tokens_dropped", dropped)
             logger.error("expert routing lost %d token-expert pairs",
                          dropped)
-        touched = float((counts > 0).sum(axis=1).mean())
-        mean = counts.mean(axis=1)
-        load = float((counts.max(axis=1) / np.maximum(mean, 1e-9)).mean())
+        # (an identity expert has no weights to touch or to load)
+        real = counts[:, :counts.shape[1] - self._moe_zero]
+        touched = float((real > 0).sum(axis=1).mean())
+        mean = real.mean(axis=1)
+        load = float((real.max(axis=1) / np.maximum(mean, 1e-9)).mean())
         if telemetry.enabled():
             telemetry.gauge_set("moe_experts_touched", touched)
             telemetry.gauge_set("moe_expert_load_max_over_mean", load)
@@ -2333,6 +2342,14 @@ class GenerationEngine:
             attrs.update(pairs_routed=routed, pairs_held=held,
                          experts_held_touched=round(
                              float((here > 0).sum(axis=1).mean()), 3))
+            if self._moe_zero:
+                # the routed pairs three ways: multiplied here, an expert
+                # of another chip's, an identity expert's (no product)
+                zero = routed - int(real.sum())
+                self._count("moe_pairs_zero", zero)
+                stat_add("moe_pairs_zero", zero)
+                attrs.update(pairs_zero=zero,
+                             pairs_absent=routed - held - zero)
             per = counts.shape[1] // self._moe_groups
             if group_rows is not None and first // per == (first + n - 1) \
                     // per:

@@ -13,7 +13,10 @@ three entries for what no cell had (the state-space kernels): a group of
 their own, put on the collected module here.  The cell PR 63 added did
 the same, with two entries for what no cell had (the grouped kernel's
 share of its roofline over every program that calls it, the rows a touched
-held expert multiplies) and one reader.  No JAX is imported and no engine started.
+held expert multiplies) and one reader.  The cell PR 66 added did the
+same, with two entries (the identity picks' share of the routed picks, the
+carried expert branch's share of the device's time) and no reader.  No JAX
+is imported and no engine started.
 """
 import importlib.util
 import json
@@ -67,7 +70,11 @@ manifest.GROUPS["state space"] = SSM
 LATENT_EXPERTS = ["expert_kernel_roofline.pool",
                   "moe_rows_per_held_expert.pool"]
 manifest.GROUPS["experts in a latent row"] = LATENT_EXPERTS
-AFTER_SETUP = len(SSM) + len(LATENT_EXPERTS)   # entries behind `.setup`
+# PR 66 likewise, two entries that only its cell reports
+IDENTITY = ["moe_zero_pairs_pct.pool", "shortcut_branch_share_pct.pool"]
+manifest.GROUPS["identity experts"] = IDENTITY
+# entries behind `.setup`
+AFTER_SETUP = len(SSM) + len(LATENT_EXPERTS) + len(IDENTITY)
 
 # the benchmark's own checks, collected here under their own names
 globals().update({name: fn for name, fn in vars(manifest).items()
@@ -162,6 +169,12 @@ NEMO_ROW = ("served_tokens_per_s", [
     "closed loop", "experts", "experts, a share held",
     "whole-prompt prefill", "step on its span", "paged decode kernel",
     "slot state", "state space", "experts in a latent row"], 40)
+# the cell PR 66 added: families joined (the latent expert cell's group of
+# rows a held expert multiplies among them), and the group of its two entries
+LONGCAT = "longcat-flash-agentchat"
+LONGCAT_ROW = ("served_tokens_per_s", [
+    "closed loop", "experts", "experts, a share held", "step on its span",
+    "latent pages", "chunked prefill", "identity experts"], 43)
 JOINED = {DSV2: {"config": "deepseek-v2", "mix": "docqa-pool",
                  "reduced": ["num_hidden_layers", "n_routed_experts",
                              "vocab_size"],
@@ -176,7 +189,13 @@ JOINED = {DSV2: {"config": "deepseek-v2", "mix": "docqa-pool",
                  "reduced": ["num_hidden_layers", "hybrid_override_pattern",
                              "n_routed_experts", "vocab_size",
                              "num_nextn_predict_layers"],
-                 "driver": "serve_share", "rungs": [256, 256, 512]}}
+                 "driver": "serve_share", "rungs": [256, 256, 512]},
+          LONGCAT: {"config": "longcat-flash-chat",
+                    "mix": "agentchat-pool",
+                    "reduced": ["num_layers", "num_attention_heads",
+                                "n_routed_experts", "vocab_size"],
+                    "driver": "serve_chunks", "rungs": [256, 512, 256],
+                    "chunk": 512}}
 CELLS_AT_PR54 = 11
 
 
@@ -185,7 +204,7 @@ def _json(*parts):
         return json.load(f)
 
 
-def test_the_benchmark_has_twelve_configurations_and_fourteen_cells():
+def test_the_benchmark_has_thirteen_configurations_and_fifteen_cells():
     spec = manifest.SPEC
     assert [c["name"] for c in spec["configs"]] == [
         "bert-base-mlm", "mistral-7b-v0.1", "smallthinker-21b-a3b",
@@ -197,7 +216,7 @@ def test_the_benchmark_has_twelve_configurations_and_fourteen_cells():
         "bert-base-seq512-dp4", "smallthinker21b-mixedlen",
         "sdar30b-blockgen", "lfm2-24b-longanswer"] + list(ADDED) \
         + list(JOINED)
-    assert (len(spec["configs"]), len(manifest.CELLS)) == (12, 14)
+    assert (len(spec["configs"]), len(manifest.CELLS)) == (13, 15)
     assert [w["name"] for w in spec["workloads"] if w["chips"] == 4] \
         == ["bert-base-seq512-dp4"]
     for cell, joined in JOINED.items():
@@ -206,10 +225,10 @@ def test_the_benchmark_has_twelve_configurations_and_fourteen_cells():
             == (1, joined["config"], joined["mix"])
     assert spec["workloads"][-1] == new
     # a cell that joins families adds no entry; PR 59's brought three for
-    # what no cell had, at the end, and PR 63's two behind them
-    assert len(manifest.ENTRIES) == 97 + AFTER_SETUP == 102
+    # what no cell had, at the end, PR 63's two and PR 66's two behind them
+    assert len(manifest.ENTRIES) == 97 + AFTER_SETUP == 104
     assert [m["name"] for m in manifest.ENTRIES][-AFTER_SETUP:] \
-        == SSM + LATENT_EXPERTS
+        == SSM + LATENT_EXPERTS + IDENTITY
     used = {w["config"] for w in spec["workloads"]}
     assert used == {c["name"] for c in spec["configs"]}
     for c in spec["configs"]:
@@ -541,7 +560,8 @@ def test_the_joined_cell_reports_its_groups_and_forty_values(monkeypatch):
     for name in manifest.entries_of(DSV2):
         lists = manifest.BY_NAME[name]["workloads"]
         assert lists[lists.index(DSV2) + 1:] in (
-            [], [GRANITE], [NEMO], [GRANITE, NEMO]), name
+            [], [GRANITE], [NEMO], [GRANITE, NEMO], [LONGCAT],
+            [NEMO, LONGCAT], [GRANITE, NEMO, LONGCAT]), name
     assert DSV2 not in manifest.TABLE         # (a ``benchmark`` PR's to add)
 
 
@@ -655,8 +675,8 @@ def test_the_fixture_is_the_whole_parent_and_52_names_went():
     went = {r["old"] for r in at_pr54} - set(manifest.BY_NAME)
     came = set(manifest.BY_NAME) - {r["old"] for r in at_pr54}
     assert (len(went), len(came), len(manifest.ENTRIES)) \
-        == (52, 22 + AFTER_SETUP, 102)
-    assert came >= set(SSM + LATENT_EXPERTS)
+        == (52, 22 + AFTER_SETUP, 104)
+    assert came >= set(SSM + LATENT_EXPERTS + IDENTITY)
     for r in at_pr54:
         assert set(r["cells"]) \
             <= set(manifest.BY_NAME[r["new"]]["workloads"]), r
@@ -680,7 +700,8 @@ def test_the_state_space_cell_reports_its_groups_and_thirty_four_values():
         manifest.GROUPS = kept
     for name in manifest.entries_of(GRANITE):
         lists = manifest.BY_NAME[name]["workloads"]
-        assert lists[lists.index(GRANITE) + 1:] in ([], [NEMO]), name
+        assert lists[lists.index(GRANITE) + 1:] in (
+            [], [NEMO], [LONGCAT], [NEMO, LONGCAT]), name
     assert GRANITE not in manifest.TABLE       # (a ``benchmark`` PR's to add)
     for name in SSM:
         assert manifest.BY_NAME[name] == {
@@ -756,7 +777,7 @@ def test_the_state_space_configuration_cuts_depth_and_no_width():
               if c["name"] == joined["config"]]
     assert entry["reduced"] == cfg["reduced"] == joined["reduced"]
     assert entry["source"] == cfg["source"]
-    assert manifest.SPEC["configs"][-2] == entry
+    assert manifest.SPEC["configs"][-3] == entry
     assert (cfg["hidden_size"], cfg["num_attention_heads"],
             cfg["num_key_value_heads"], cfg["intermediate_size"],
             cfg["shared_intermediate_size"], cfg["mamba_n_heads"],
@@ -808,7 +829,8 @@ def test_the_latent_expert_cell_reports_its_groups_and_forty_values():
     finally:
         manifest.GROUPS = kept
     for name in manifest.entries_of(NEMO):
-        assert manifest.BY_NAME[name]["workloads"][-1] == NEMO, name
+        lists = manifest.BY_NAME[name]["workloads"]
+        assert lists[lists.index(NEMO) + 1:] in ([], [LONGCAT]), name
     assert NEMO not in manifest.TABLE          # (a ``benchmark`` PR's to add)
     kernel, rows = (manifest.BY_NAME[n] for n in LATENT_EXPERTS)
     assert kernel == {
@@ -818,7 +840,7 @@ def test_the_latent_expert_cell_reports_its_groups_and_forty_values():
     assert rows == {
         "name": LATENT_EXPERTS[1], "unit": "rows", "better": "higher",
         "source": "program_span", "layer": "expert path",
-        "moves": "served_tokens_per_s", "workloads": [NEMO]}
+        "moves": "served_tokens_per_s", "workloads": [NEMO, LONGCAT]}
     for name in ("delta_step_roofline.pool", "mla_kernel_share_pct.pool",
                  "prefill_chunk_roofline.pool", manifest.TOUCHED,
                  "attention_kernel_share_pct.pool"):
@@ -905,7 +927,7 @@ def test_the_latent_expert_configuration_cuts_what_it_says_and_no_width():
               if c["name"] == joined["config"]]
     assert entry["reduced"] == cfg["reduced"] == joined["reduced"]
     assert entry["source"] == cfg["source"]
-    assert manifest.SPEC["configs"][-1] == entry
+    assert manifest.SPEC["configs"][-2] == entry
     # every width as published
     assert (cfg["hidden_size"], cfg["num_attention_heads"],
             cfg["num_key_value_heads"], cfg["head_dim"],
@@ -1073,3 +1095,116 @@ def test_the_exposed_share_counts_asynchronous_fusions(ops, exposed_s, want):
     # the accepted reader sees the synchronous part alone
     assert _reader("collective_exposed").read({"trace": trace}) == (
         pytest.approx(100.0 * exposed_s / 1.4467) if exposed_s else None)
+
+
+# -- PR 66: a cell that joined families and brought two entries --------------
+
+def test_the_shortcut_cell_reports_its_groups_and_forty_three_values(
+        monkeypatch):
+    """Its row: ``deepseek-v2-docqa``'s forty (the 22 of the closed loop,
+    the experts' two, a held share's two, the step on its span, four of the
+    five of latent pages, the five of chunked prefill, the four of the
+    start-up account), the rows a touched held expert multiplies and its
+    own two.  No group of slot state, of the paged decode kernel or of a
+    whole-prompt prefill."""
+    groups = _groups_less_not_run()
+    groups["experts, a share held"] = groups["experts, a share held"] \
+        + [LATENT_EXPERTS[1]]
+    monkeypatch.setattr(manifest, "GROUPS", groups)
+    assert manifest.check_cell(LONGCAT, LONGCAT_ROW) == LONGCAT_ROW[2] == 43
+    for name in manifest.entries_of(LONGCAT):
+        assert manifest.BY_NAME[name]["workloads"][-1] == LONGCAT, name
+    assert LONGCAT not in manifest.TABLE       # (a ``benchmark`` PR's to add)
+    zero, branch = (manifest.BY_NAME[n] for n in IDENTITY)
+    assert zero == {
+        "name": IDENTITY[0], "unit": "%", "better": "higher",
+        "source": "program_span", "layer": "expert path",
+        "moves": "served_tokens_per_s", "workloads": [LONGCAT]}
+    assert branch == {
+        "name": IDENTITY[1], "unit": "%", "better": "lower",
+        "source": "device_trace", "layer": "kernels",
+        "moves": "served_tokens_per_s", "workloads": [LONGCAT]}
+    assert set(manifest.entries_of(LONGCAT)) \
+        == set(manifest.entries_of(DSV2)) | set(IDENTITY) \
+        | {LATENT_EXPERTS[1]}
+    for name in (DSV2_NOT_RUN, "delta_step_roofline.pool",
+                 "paged_kernel_roofline.pool", "prefill_roofline.pool",
+                 "ssm_step_roofline.pool", "expert_kernel_roofline.pool",
+                 manifest.TOUCHED):
+        assert LONGCAT not in manifest.BY_NAME[name]["workloads"], name
+    manifest.check_cell_loads(LONGCAT)
+
+
+@pytest.mark.parametrize("name,reader,key,reads", [
+    ("decode_step_roofline.pool", "roofline_span", "attrs",
+     ["experts_held_touched", "latent_positions"]),
+    ("decode_step_roofline.pool", "roofline_span", "fn",
+     "ops_bytes_longcat_flash.decode_step_bytes"),
+    ("mla_decode_bytes_roofline.pool", "roofline_kernel", "fn",
+     "ops_bytes_longcat_flash.mla_decode_bytes"),
+    ("mla_decode_flops_roofline.pool", "roofline_kernel", "fn",
+     "ops_bytes_longcat_flash.mla_decode_flops"),
+    ("mla_kernel_share_pct.pool", "trace_op_share", "pattern",
+     "^%?mla_(decode|chunk)_attention"),
+    ("prefill_chunk_roofline.pool", "roofline_chunks", "fn",
+     "ops_bytes_longcat_flash.chunk_flops"),
+    ("chunk_attention_roofline.pool", "roofline_kernel_prefill", "fn",
+     "ops_bytes_longcat_flash.chunk_attention_flops"),
+    ("chunk_attention_roofline.pool", "roofline_kernel_prefill", "pattern",
+     "^%?mla_chunk_attention"),
+    ("chunk_attention_share_pct.pool", "trace_op_share", "pattern",
+     "^%?mla_chunk_attention"),
+    ("moe_held_touched_pct.pool", "span_attr_mean", "per",
+     "n_routed_experts"),
+    ("moe_rows_per_held_expert.pool", "span_attr_ratio", "scale", 0.25),
+    ("latent_fill_pct.pool", "span_attr_mean", "scale", 100 / (32 * 1280)),
+    ("moe_zero_pairs_pct.pool", "span_attr_ratio", "num", "pairs_zero"),
+    ("moe_zero_pairs_pct.pool", "span_attr_ratio", "den", ["pairs_routed"]),
+    ("moe_zero_pairs_pct.pool", "span_attr_ratio", "span",
+     "generation/decode_step"),
+    ("shortcut_branch_share_pct.pool", "trace_op_share", "pattern",
+     "^%?while[.\\d]* = \\(.*f32\\[8,6144,4096\\]|[fs]32\\[\\d+,768\\]"),
+])
+def test_the_shortcut_cells_arguments_come_from_its_own_files(
+        name, reader, key, reads):
+    got_reader, args = manifest.check_arguments(name, LONGCAT)
+    assert got_reader == reader and args[key] == reads
+
+
+def test_the_shortcut_configuration_cuts_what_it_says_and_no_width():
+    joined = JOINED[LONGCAT]
+    cfg = _json("configs", joined["config"] + ".json")
+    entry = manifest.SPEC["configs"][-1]
+    assert entry["name"] == joined["config"]
+    assert entry["reduced"] == cfg["reduced"] == joined["reduced"]
+    assert entry["source"] == cfg["source"] \
+        == "https://huggingface.co/meituan-longcat/LongCat-Flash-Chat/" \
+           "blob/main/config.json"
+    # every published width, the router's 768 outputs and its 12 picks
+    assert (cfg["hidden_size"], cfg["ffn_hidden_size"],
+            cfg["expert_ffn_hidden_size"], cfg["kv_lora_rank"],
+            cfg["q_lora_rank"], cfg["qk_rope_head_dim"], cfg["v_head_dim"],
+            cfg["qk_nope_head_dim"], cfg["moe_topk"],
+            cfg["zero_expert_num"], cfg["routed_scaling_factor"],
+            cfg["rms_norm_eps"], cfg["rope_theta"],
+            cfg["max_position_embeddings"]) \
+        == (6144, 12288, 2048, 512, 1536, 64, 128, 128, 12, 256, 6, 1e-5,
+            10000000, 131072)
+    assert cfg["expert_share"]["router_experts"] == 768 \
+        == cfg["published"]["n_routed_experts"] + cfg["zero_expert_num"]
+    assert cfg["published"] == {"num_layers": 28, "num_attention_heads": 64,
+                                "n_routed_experts": 512,
+                                "vocab_size": 131072}
+    assert [cfg[k] for k in joined["reduced"]] == [4, 8, 8, 16384]
+    # the floors of the guide, and the deployment's 64 chips
+    assert cfg["vocab_size"] * 8 == 131072 \
+        and cfg["num_attention_heads"] * 8 == 64 \
+        and cfg["n_routed_experts"] * 64 == 512
+    for key in ("assumed", "as_run", "deployment", "check_tolerance",
+                "rehearse", "builder", "expert_share", "per_layer_args"):
+        assert key in cfg
+    assert len(cfg["check_tolerance"]["why"]) > 200
+    assert "64" in cfg["deployment"]
+    assert os.path.exists(os.path.join(
+        BENCH, "builders", cfg["builder"] + ".py"))
+

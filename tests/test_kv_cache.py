@@ -150,7 +150,8 @@ CONFIGS = {    # configuration: the kinds of cache its model has
     "command-a-plus-05-2026": {"pages", "window_pages"},
     "deepseek-v2": {"latent_pages"},
     "granite-4.0-h-micro": {"pages", "slot_state"},
-    "nemotron3-super-120b-a12b": {"pages", "slot_state"}}
+    "nemotron3-super-120b-a12b": {"pages", "slot_state"},
+    "longcat-flash-chat": {"latent_pages"}}
 
 
 def test_every_serving_configuration_is_in_the_list():

@@ -1180,7 +1180,11 @@ SKIP = {
     "moe_routed_ffn":
         "tests/test_window_moe.py (routing, dropless counts and the "
         "grouped matmul vs a plain float64 loop; the op inside the "
-        "engine vs the uncached forward and the benchmark's reference)",
+        "engine vs the uncached forward and the benchmark's reference); "
+        "its attribute zero_experts (identity experts under a softmax "
+        "router with a selection bias): tests/test_longcat_flash.py (a "
+        "written-out loop, identity-only and real-only routers, rows "
+        "behind valid, the shares of a router of E + Z)",
     "masked_select": "dynamic shape; covered via layers.masked_select "
                      "usage in tests/test_models.py",
     "unique": "dynamic shape; lowering returns padded/size pair",
