@@ -93,6 +93,8 @@ def reference_check(run, cfg, scope, seq, seed) -> bool:
     rel_loss = abs(got_loss - want_loss) / abs(want_loss)
     ok = bool(np.isfinite(got_logits).all() and rel <= tol
               and rel_loss <= tol)
+    run.check = {"tolerance": tol, "rel_logits": rel, "rel_loss": rel_loss,
+                 "finite": bool(np.isfinite(got_logits).all())}
     run.say(f"reference check: MLM logits {got_logits.shape} off the "
             f"float32 reference by {rel:.4g} of its range, loss "
             f"{got_loss:.5f} against {want_loss:.5f} ({rel_loss:.3g}); "

@@ -74,6 +74,136 @@ def resolve(dotted: str):
 
 ARGS_KEY = "per_layer_args"
 
+# -- what the program ran in, observed ---------------------------------------
+# The benchmark passes the program no dtype and is told none: it reads the
+# arrays of every engine the builder makes (``Cell.builder``), by class.
+ADMITTED = ("float32", "bfloat16")   # dtypes a configuration may admit
+CLASSES = ("weights", "pages", "state")
+ROUTER_LIMIT = "router_off_limit_share_of_router_range"
+ITEMSIZE = {"float32": 4, "bfloat16": 2, "float16": 2, "float8_e4m3fn": 1}
+
+
+def kept_patterns(cfg: dict) -> list:
+    """Array-name patterns (``fnmatch``, on the name behind the engine's
+    prefix) that the configuration's ``as_run.bfloat16.keeps_float32``
+    lists: what a bfloat16 program keeps in float32."""
+    keeps = cfg.get("as_run", {}).get("bfloat16", {}).get("keeps_float32", [])
+    return [p for entry in keeps for p in entry.get("arrays", [])]
+
+
+def observe_engine(gen, cfg: dict) -> dict:
+    """The dtypes of the device arrays of engine ``gen``, by class, from
+    its scope: a walk over names and dtypes, no fetch, no compile.
+
+    * ``weights``: floating arrays of two or more dimensions under the
+      engine's prefix that are no cache and match no kept pattern;
+    * ``pages``: ``gen.cache_names`` (K/V, window and latent pools);
+    * ``state``: ``gen.state_names`` (convolution rows, delta matrices,
+      state-space state).
+
+    A class reads the one dtype all its arrays have, ``"mixed: a, b"``
+    where they differ, None where the engine has none.
+    ``kept_not_float32`` names (behind the prefix) the arrays that match a
+    kept pattern, and the vectors (norm weights, biases, a head's
+    constants), that are not float32."""
+    import fnmatch
+
+    import numpy as np
+
+    pages = set(gen.cache_names)
+    state = set(getattr(gen, "state_names", ()))
+    patterns = kept_patterns(cfg)
+    prefix = gen.name + "."
+    seen = {c: set() for c in CLASSES}
+    astray = []
+    for n in gen.scope.local_var_names():
+        v = gen.scope.find_var(n)
+        if getattr(v, "dtype", None) is None:
+            continue
+        dtype = str(np.dtype(v.dtype))
+        if "float" not in dtype:
+            continue
+        if n in pages or n in state:
+            seen["pages" if n in pages else "state"].add(dtype)
+        elif n.startswith(prefix):
+            short = n[len(prefix):]
+            if v.ndim >= 2 and not any(fnmatch.fnmatchcase(short, p)
+                                       for p in patterns):
+                seen["weights"].add(dtype)
+            elif dtype != "float32":
+                astray.append(f"{short} ({dtype})")
+
+    def one(found):
+        found = sorted(found)
+        return None if not found else found[0] if len(found) == 1 \
+            else "mixed: " + ", ".join(found)
+
+    return dict({c: one(seen[c]) for c in CLASSES},
+                kept_not_float32=sorted(astray))
+
+
+def item_sizes(ctx: dict):
+    """Bytes an item by class (``ops_bytes.ItemSizes``) for the roofline
+    readers: what the run observed on its engines (``ctx["as_run_observed"]``
+    where a context brings its own, else the run's cell).  A run always
+    has an observation by the time a reader runs; a context with neither
+    is a test's hand-made one and reads as a float32 program does.  No
+    file's ``as_run.dtype`` is read: nothing states an item size."""
+    from ops_bytes import ItemSizes, sizes_of
+
+    seen = ctx.get("as_run_observed")
+    if seen is None:
+        cell = getattr(ctx.get("run"), "cell", None)
+        seen = getattr(cell, "observed", None)
+    if seen is None:
+        return sizes_of(ITEMSIZE["float32"])
+    return ItemSizes(**{c: ITEMSIZE[seen[c] or "float32"] for c in CLASSES})
+
+
+def said_limit(limit) -> str:
+    """`` (limit x)`` for a check's line, nothing where there is none."""
+    return "" if limit is None else f" (limit {limit:.4g})"
+
+
+def tolerance_entry(cfg: dict, dtype: str):
+    """The entry of the configuration's ``check_tolerance`` that a program
+    whose weights are ``dtype`` is held to, or None where the file admits
+    no such program.  The keys at the top of ``check_tolerance`` are the
+    ``float32`` entry (tests outside ``benchmark/`` read them there); the
+    ``bfloat16`` entry lies under that key."""
+    tol = cfg["check_tolerance"]
+    if dtype == "float32":
+        return {k: v for k, v in tol.items() if k not in ADMITTED}
+    entry = tol.get(dtype) if dtype in ADMITTED else None
+    return entry if isinstance(entry, dict) and "share_of_range" in entry \
+        else None
+
+
+def held_to(cfg: dict, observed: dict):
+    """``(entry, problems)``: the tolerance entry that what was observed
+    is held to, and why the program is not one the configuration admits
+    (each problem names the array or the class).  A float32 program is
+    all float32.  A bfloat16 program has every array that
+    ``keeps_float32`` lists, and every vector, in float32, and its pages
+    and state in what ``as_run.bfloat16`` states."""
+    dtype = observed["weights"]
+    admitted = cfg["as_run"].get("dtypes_admitted", ["float32"])
+    entry = tolerance_entry(cfg, dtype) if dtype in admitted else None
+    if entry is None:
+        return None, [f"weights are {dtype}: the configuration admits "
+                      f"{', '.join(admitted)} and its check_tolerance "
+                      f"has no entry for that"]
+    stated = cfg["as_run"].get(dtype, {}) if dtype != "float32" else {}
+    problems = [f"array {name}: kept float32 beside {dtype} weights by "
+                f"keeps_float32 (every vector is)"
+                for name in observed["kept_not_float32"]]
+    for c in ("pages", "state"):
+        want = stated.get(c, "float32")
+        if observed[c] not in (None, want):
+            problems.append(f"{c} are {observed[c]}, stated {want} "
+                            f"beside {dtype} weights")
+    return entry, problems
+
 
 class Cell:
     """One entry of ``workloads`` with its files resolved."""
@@ -90,9 +220,22 @@ class Cell:
         self.config_name = self.entry["config"]
         self.cfg = load_json("configs", self.config_name + ".json")
         self.mix = traffic.load_mix(self.entry["traffic"])
+        self.observed = None          # by class, once an engine is built
+        self.observed_problems = []
         if rehearse:
-            # toy sizes for the CPU, from the files themselves
-            self.cfg.update(self.cfg.get("rehearse", {}))
+            # toy sizes for the CPU, from the files themselves (a toy
+            # tolerance is the float32 entry's; the others stay the file's,
+            # and so does what ``as_run`` states of the admitted dtypes)
+            toy = dict(self.cfg.get("rehearse", {}))
+            tol = toy.pop("check_tolerance", {})
+            if "as_run" in toy:
+                toy["as_run"] = dict(
+                    {k: v for k, v in self.cfg["as_run"].items()
+                     if k in ("dtypes_admitted",) + ADMITTED},
+                    **toy["as_run"])
+            self.cfg.update(toy)
+            self.cfg["check_tolerance"] = dict(self.cfg["check_tolerance"],
+                                               **tol)
             toy = self.mix.get("rehearse", {})
             self.mix.update({k: v for k, v in toy.items() if k != "engine"})
             self.mix.setdefault("engine", {}).update(toy.get("engine", {}))
@@ -117,14 +260,90 @@ class Cell:
         return load_module("reference", self.config_name)
 
     def builder(self):
-        return load_module("builders", self.cfg["builder"])
+        """The configuration's builder, with every engine it makes
+        observed on the way out (``note_engine``): nothing is passed to
+        the program and nothing asked of it."""
+        return _Observing(load_module("builders", self.cfg["builder"]), self)
+
+    def note_engine(self, gen):
+        """Read what ``gen`` runs in (``observe_engine``) and hold the
+        cell to the entry of ``check_tolerance`` for it: from here on
+        ``cfg["check_tolerance"]`` IS that entry (the references read the
+        near-tie margin there) and ``tolerance`` its number.  What makes
+        the program one the configuration does not admit goes to
+        ``observed_problems``; the drivers' verdict has it
+        (``Cell.admitted``)."""
+        seen = observe_engine(gen, self.cfg)
+        entry, problems = held_to(self.cfg, seen)
+        del seen["kept_not_float32"]
+        if self.observed is not None and seen != self.observed:
+            problems.append(f"engines of one run differ: {self.observed} "
+                            f"then {seen}")
+        self.observed = seen
+        self.observed_problems += [p for p in problems
+                                   if p not in self.observed_problems]
+        if entry is not None:
+            self.hold_to(seen["weights"])
+
+    def hold_to(self, dtype: str, margin: float = None) -> bool:
+        """Make ``cfg["check_tolerance"]`` the file's entry for weights of
+        ``dtype`` (with the other entries under their keys still), or
+        leave it and say False where the file has none.  ``margin``:
+        another near-tie margin, for a control's sweep."""
+        whole = self.cfg.setdefault("check_tolerance_file",
+                                    self.cfg["check_tolerance"])
+        entry = tolerance_entry({"check_tolerance": whole}, dtype)
+        if entry is None:
+            return False
+        entry = dict(entry, **{d: whole[d] for d in ADMITTED if d in whole})
+        if margin is not None:
+            entry["near_tie_margin_share_of_router_range"] = float(margin)
+        self.cfg["check_tolerance"] = entry
+        return True
+
+    @property
+    def admitted(self) -> bool:
+        """Every engine built so far ran in dtypes the configuration
+        admits, with what it keeps in float32 in float32."""
+        return not self.observed_problems
 
     @property
     def tolerance(self) -> float:
         """How far the program may be off its plain reference, as a share
         of the reference's largest magnitude; the configuration's file
-        gives the number and the reason."""
+        gives the number and the reason, by the dtype of the weights the
+        program was observed to run in (float32 until an engine is
+        built)."""
         return float(self.cfg["check_tolerance"]["share_of_range"])
+
+    @property
+    def router_tolerance(self):
+        """How far the program's router scores may lie off the
+        reference's on a compared row, as a share of the row's range of
+        scores, where the entry the cell is held to states it (the
+        ``bfloat16`` entries of the routed configurations, whose near-tie
+        margins are wide); None where it states none (every ``float32``
+        entry: its narrow margin judges the picks themselves)."""
+        limit = self.cfg["check_tolerance"].get(ROUTER_LIMIT)
+        return None if limit is None else float(limit)
+
+
+class _Observing:
+    """A builder's module whose ``engine`` notes what it built."""
+
+    def __init__(self, module, cell):
+        self.__dict__.update(_module=module, _cell=cell)
+
+    def __getattr__(self, name):
+        return getattr(self._module, name)
+
+    def __setattr__(self, name, value):
+        setattr(self._module, name, value)
+
+    def engine(self, *args, **kwargs):
+        gen = self._module.engine(*args, **kwargs)
+        self._cell.note_engine(gen)
+        return gen
 
 
 def peaks_for(kind: str) -> dict:
@@ -149,6 +368,9 @@ class Run:
         self._lock = threading.Lock()
         self.trace = None
         self.largest_temp_bytes = 0
+        # what the drivers compared for ``correct``, each number beside
+        # its limit, by short names: the result line's last key, ``check``
+        self.check = None
         self.workdir = tempfile.mkdtemp(prefix="bench_")
 
     # -- devices ------------------------------------------------------------
@@ -318,13 +540,18 @@ class Run:
                end_to_end: dict, ctx: dict) -> int:
         """Print the contract's line, last.  ``end_to_end`` holds every
         end-to-end reading the driver took, by metric name."""
+        for problem in self.cell.observed_problems:
+            self.say(f"as run: {problem}: NOT correct")
+        correct = bool(correct) and self.cell.admitted
         if self.rehearse:
             print(json.dumps({"rehearsal": True, "cell": self.cell.name,
                               "correct": bool(correct),
+                              "as_run_observed": self.cell.observed,
                               "attempted": int(attempted),
                               "failed": int(failed),
                               "largest_temp_bytes": self.largest_temp_bytes,
-                              "counts": ctx.get("counts", {})}),
+                              "counts": ctx.get("counts", {}),
+                              "check": self.said_check(correct)}),
                   flush=True)
             return 0
         device = self.device = self.device_block()
@@ -339,11 +566,30 @@ class Run:
                                       "unit": m["unit"]}
         line = {"correct": bool(correct), "attempted": int(attempted),
                 "failed": int(failed), "metrics": metrics, "device": device}
+        if self.cell.observed is not None:
+            # the dtypes of the engine's weights, pages and slot state
+            line["as_run_observed"] = self.cell.observed
         if self.trace_on:
             line["breakdown"] = {"device_ops": self.trace["device_ops"],
                                  "idle_gaps": self.trace["idle_gaps"]}
+        line["check"] = self.said_check(correct)
         print(json.dumps(line), flush=True)
         return 0
+
+    def said_check(self, correct: bool) -> dict:
+        """What was compared for ``correct`` (the drivers' ``self.check``
+        and what ``as_run`` refused), each number beside its limit: the
+        last lines on standard error and the result line's last key, so
+        that a run that reads not correct says by which number in what
+        the driver's record keeps of it."""
+        check = dict(self.check or {}, correct=bool(correct))
+        if self.cell.observed_problems:
+            check["as_run_problems"] = self.cell.observed_problems
+        for name, value in check.items():
+            print(f"[bench {self.cell.name}] check: {name} "
+                  f"{json.dumps(value)}", file=sys.stderr)
+        sys.stderr.flush()
+        return check
 
     def cleanup(self):
         shutil.rmtree(self.workdir, ignore_errors=True)

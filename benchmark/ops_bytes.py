@@ -7,6 +7,8 @@ published keys, so no PR that changes the program can move them.
 """
 from __future__ import annotations
 
+from typing import NamedTuple
+
 
 # -- BERT MLM training -------------------------------------------------------
 
@@ -29,6 +31,32 @@ def bert_train_flops_per_token(cfg: dict, seq: int, n_pred: int) -> float:
     return bert_train_flops_per_sequence(cfg, seq, n_pred) / seq
 
 
+# -- item sizes, by the class of array ---------------------------------------
+
+class ItemSizes(NamedTuple):
+    """Bytes an item of the arrays a serving program holds, by class, as
+    the harness observed them on the engine that ran
+    (``harness.observe_engine``): ``weights`` (the matrices), ``pages``
+    (K/V, window and latent pools), ``state`` (slot state: convolution
+    rows, delta matrices, state-space state); ``kept`` is what a
+    configuration's ``as_run.bfloat16.keeps_float32`` keeps in float32
+    whatever the weights are in (norm weights, the router and its bias,
+    the convolution's taps, the decay's constants and projections, and
+    what a recurrence's kernel reads a token): 4."""
+    weights: int
+    pages: int
+    state: int
+    kept: int = 4
+
+
+def sizes_of(itemsize) -> ItemSizes:
+    """``itemsize`` as ``ItemSizes``; a plain number is every class alike
+    (4: a float32 program)."""
+    if isinstance(itemsize, ItemSizes):
+        return itemsize
+    return ItemSizes(itemsize, itemsize, itemsize, itemsize)
+
+
 # -- Mistral (grouped-query decoder) serving --------------------------------
 
 def mistral_layer_params(cfg: dict) -> int:
@@ -40,14 +68,15 @@ def mistral_layer_params(cfg: dict) -> int:
     return qkv + h * h + 2 * h * i + i * h
 
 
-def mistral_weight_bytes(cfg: dict, itemsize: int) -> int:
+def mistral_weight_bytes(cfg: dict, itemsize) -> int:
     """Bytes of every weight a decode step reads: all layers' matrices and
-    norms, the final norm and the untied head.  The embedding table is
-    read one row per token and is left out."""
-    h = cfg["hidden_size"]
-    per_layer = mistral_layer_params(cfg) + 2 * h
-    return itemsize * (cfg["num_hidden_layers"] * per_layer + h
-                       + h * cfg["vocab_size"])
+    norms (kept float32), the final norm and the untied head.  The
+    embedding table is read one row per token and is left out."""
+    sz = sizes_of(itemsize)
+    h, layers = cfg["hidden_size"], cfg["num_hidden_layers"]
+    return sz.weights * (layers * mistral_layer_params(cfg)
+                         + h * cfg["vocab_size"]) \
+        + sz.kept * (layers * 2 * h + h)
 
 
 def mistral_kv_bytes_per_token(cfg: dict, itemsize: int) -> int:
@@ -70,12 +99,13 @@ def mistral_prefill_flops(cfg: dict, n_tokens: int) -> float:
 
 
 def mistral_decode_step_bytes(cfg: dict, live_kv_tokens: float,
-                              itemsize: int) -> float:
+                              itemsize) -> float:
     """Bytes one decode step must read: the weights once, and K and V of
     every live position.  Bytes-bound: a decode step does about two FLOPs
-    per weight byte per sequence."""
-    return mistral_weight_bytes(cfg, itemsize) \
-        + live_kv_tokens * mistral_kv_bytes_per_token(cfg, itemsize)
+    per weight byte per sequence.  ``itemsize``: ``ItemSizes`` (or a
+    number for every class alike)."""
+    return mistral_weight_bytes(cfg, itemsize) + live_kv_tokens \
+        * mistral_kv_bytes_per_token(cfg, sizes_of(itemsize).pages)
 
 
 def mistral_decode_step_flops(cfg: dict, n_seqs: int,

@@ -12,6 +12,8 @@ a fault of the count.
 """
 from __future__ import annotations
 
+from ops_bytes import sizes_of
+
 
 def window_layer_count(cfg: dict) -> int:
     return sum(kind == "sliding_attention"
@@ -54,19 +56,19 @@ def pair_flops(cfg: dict) -> int:
 
 def paged_kernel_bytes(cfg: dict, live_positions: float,
                        live_positions_window: float,
-                       itemsize: int) -> float:
+                       itemsize) -> float:
     """Bytes the paged decode kernels of one step must read: K and V of
     the positions the live slots attend, whole contexts in the full layer
     and what lies inside the window in the sliding layers."""
     n_window = window_layer_count(cfg)
-    return kv_bytes_per_position(cfg, itemsize) * (
+    return kv_bytes_per_position(cfg, sizes_of(itemsize).pages) * (
         (cfg["num_hidden_layers"] - n_window) * live_positions
         + n_window * live_positions_window)
 
 
 def decode_step_bytes(cfg: dict, experts_held_touched: float,
                       live_positions: float, live_positions_window: float,
-                      itemsize: int) -> float:
+                      itemsize) -> float:
     """Bytes one decode step over the grid must move: every layer's
     attention matrices and its one norm; the router over all its experts,
     the held experts that got a row (``experts_held_touched``, the mean
@@ -79,8 +81,12 @@ def decode_step_bytes(cfg: dict, experts_held_touched: float,
         h + attention_params(cfg) + router_params(cfg)
         + expert_params(cfg) * (experts_held_touched
                                 + cfg["num_shared_experts"]))
-    return itemsize * weights + paged_kernel_bytes(
-        cfg, live_positions, live_positions_window, itemsize)
+    sz = sizes_of(itemsize)
+    # kept float32: the norms and the router
+    kept = h + cfg["num_hidden_layers"] * (h + router_params(cfg))
+    return sz.weights * (weights - kept) + sz.kept * kept \
+        + paged_kernel_bytes(cfg, live_positions, live_positions_window,
+                             sz)
 
 
 def chunk_pairs(cfg: dict, n_tokens: int, base: int):

@@ -14,6 +14,8 @@ the count.
 """
 from __future__ import annotations
 
+from ops_bytes import sizes_of
+
 
 def mla_mixer_params(cfg: dict) -> int:
     """The two low-rank pairs with their norms and the output projection:
@@ -56,11 +58,11 @@ def latent_row_bytes(cfg: dict, itemsize: int) -> int:
 
 
 def mla_decode_bytes(cfg: dict, latent_positions: float,
-                     itemsize: int) -> float:
+                     itemsize) -> float:
     """Bytes the latent decode kernels of one step must read: one row a
     cached position the live slots attend, once, in every layer."""
-    return latent_row_bytes(cfg, itemsize) * cfg["num_hidden_layers"] \
-        * latent_positions
+    return latent_row_bytes(cfg, sizes_of(itemsize).pages) \
+        * cfg["num_hidden_layers"] * latent_positions
 
 
 def mla_decode_flops(cfg: dict, latent_positions: float,
@@ -74,7 +76,7 @@ def mla_decode_flops(cfg: dict, latent_positions: float,
 
 
 def decode_step_bytes(cfg: dict, experts_held_touched: float,
-                      latent_positions: float, itemsize: int) -> float:
+                      latent_positions: float, itemsize) -> float:
     """Bytes one decode step over the grid must move: every layer's
     latent attention matrices and its two norms; the leading layer's dense
     SwiGLU; an expert layer's router over all its experts, the held
@@ -83,16 +85,21 @@ def decode_step_bytes(cfg: dict, experts_held_touched: float,
     untied head over the vocabulary slice, once (the rows the embedding
     reads are left out); a latent row a live position in every layer."""
     h = cfg["hidden_size"]
+    sz = sizes_of(itemsize)
     weights = h + h * cfg["vocab_size"]
+    # kept float32: norms (the two low-rank pairs' too) and the router
+    kept = h
     for i in range(cfg["num_hidden_layers"]):
         weights += 2 * h + mla_mixer_params(cfg)
+        kept += 2 * h + cfg["q_lora_rank"] + cfg["kv_lora_rank"]
         if i < cfg["first_k_dense_replace"]:
             weights += dense_params(cfg)
         else:
             weights += router_params(cfg) + expert_params(cfg) * (
                 experts_held_touched + cfg["n_shared_experts"])
-    return itemsize * weights \
-        + mla_decode_bytes(cfg, latent_positions, itemsize)
+            kept += router_params(cfg)
+    return sz.weights * (weights - kept) + sz.kept * kept \
+        + mla_decode_bytes(cfg, latent_positions, sz)
 
 
 def pair_flops(cfg: dict) -> int:

@@ -15,6 +15,8 @@ of the count.
 """
 from __future__ import annotations
 
+from ops_bytes import sizes_of
+
 
 def layer_kinds(cfg: dict) -> list:
     return ["mla" if i in cfg["full_attention_layers"] else "delta"
@@ -101,10 +103,11 @@ def conv_state_bytes_per_slot(cfg: dict, itemsize: int) -> int:
 
 
 def mla_decode_bytes(cfg: dict, latent_positions: float,
-                     itemsize: int) -> float:
+                     itemsize) -> float:
     """Bytes the latent decode kernels of one step must read: one row a
     cached position the live slots attend, once, in every latent layer."""
-    return latent_row_bytes(cfg, itemsize) * n_mla(cfg) * latent_positions
+    return latent_row_bytes(cfg, sizes_of(itemsize).pages) * n_mla(cfg) \
+        * latent_positions
 
 
 def mla_decode_flops(cfg: dict, latent_positions: float,
@@ -128,27 +131,31 @@ def mla_prefill_flops(cfg: dict, n_tokens: float, itemsize: int = 4) -> float:
         + cfg["v_head_dim"]) * n * (n + 1) / 2 * n_mla(cfg)
 
 
-def delta_step_bytes(cfg: dict, state_slots: float, itemsize: int) -> float:
+def delta_step_bytes(cfg: dict, state_slots: float, itemsize) -> float:
     """The delta state of every delta layer, read once and written once,
     for the slots the step advanced."""
-    return 2 * delta_state_bytes_per_slot(cfg, itemsize) * n_delta(cfg) \
+    return 2 * delta_state_bytes_per_slot(cfg, sizes_of(itemsize).state) \
+        * n_delta(cfg) \
         * state_slots
 
 
-def delta_chunk_bytes(cfg: dict, scan_tokens: float, itemsize: int) -> float:
+def delta_chunk_bytes(cfg: dict, scan_tokens: float, itemsize) -> float:
     """Bytes the delta rule of one prefill must move in every delta layer:
     q, k (a row a VALUE head, as the ops take them), v, the log decay and
     beta of every real token read, its output written, and the state it
-    leaves written once."""
+    leaves written once.  What the rule reads and writes a token is
+    float32 whatever the weights are in (kept); the state is the
+    state's."""
+    sz = sizes_of(itemsize)
     heads, dk, dv, _ = delta_dims(cfg)
     per_token = heads * (2 * dk + 2 * dv + 2)
-    return itemsize * n_delta(cfg) * (per_token * scan_tokens
-                                      + heads * dk * dv)
+    return n_delta(cfg) * (sz.kept * per_token * scan_tokens
+                           + sz.state * heads * dk * dv)
 
 
 def decode_step_bytes(cfg: dict, experts_held_touched: float,
                       latent_positions: float, state_slots: float,
-                      itemsize: int) -> float:
+                      itemsize) -> float:
     """Bytes one decode step over the grid must move: every layer's mixer
     and its four norms; a leading layer's dense SwiGLU; an expert layer's
     router over all its experts with its bias, the held experts that got
@@ -158,18 +165,28 @@ def decode_step_bytes(cfg: dict, experts_held_touched: float,
     live position in the latent layers; and both states of every delta
     layer, read and written, for the ``state_slots`` slots advanced."""
     h = cfg["hidden_size"]
+    sz = sizes_of(itemsize)
+    heads, _, dv, channels = delta_dims(cfg)
     weights = h + h * cfg["vocab_size"] + state_slots * h
+    # kept float32: norms (the two low-rank pairs' too), the router and its
+    # bias, a | b, the taps, the decay's constants, the head norm's weight
+    kept = h
     for i, kind in enumerate(layer_kinds(cfg)):
         weights += 4 * h + mixer_params(cfg, kind)
+        kept += 4 * h + (
+            cfg["q_lora_rank"] + cfg["kv_lora_rank"] if kind == "mla"
+            else h * 2 * heads + channels * cfg["linear_conv_kernel_dim"]
+            + 2 * heads + dv)
         if i < cfg["first_k_dense_replace"]:
             weights += dense_params(cfg)
         else:
             weights += router_params(cfg) + expert_params(cfg) * (
                 experts_held_touched + cfg["n_shared_experts"])
-    state = 2 * conv_state_bytes_per_slot(cfg, itemsize) * n_delta(cfg) \
-        * state_slots + delta_step_bytes(cfg, state_slots, itemsize)
-    return itemsize * weights + state \
-        + mla_decode_bytes(cfg, latent_positions, itemsize)
+            kept += router_params(cfg)
+    state = 2 * conv_state_bytes_per_slot(cfg, sz.state) * n_delta(cfg) \
+        * state_slots + delta_step_bytes(cfg, state_slots, sz)
+    return sz.weights * (weights - kept) + sz.kept * kept + state \
+        + mla_decode_bytes(cfg, latent_positions, sz)
 
 
 def prefill_flops(cfg: dict, n_tokens: int) -> float:

@@ -11,6 +11,8 @@ share over 100% is a fault of the count.
 """
 from __future__ import annotations
 
+from ops_bytes import sizes_of
+
 
 def layer_kinds(cfg: dict) -> list:
     return list(cfg["layer_types"][:cfg["num_hidden_layers"]])
@@ -71,31 +73,35 @@ def conv_state_bytes_per_slot(cfg: dict, itemsize: int) -> int:
 
 
 def paged_kernel_bytes(cfg: dict, live_positions: float,
-                       itemsize: int) -> float:
+                       itemsize) -> float:
     """Bytes the paged decode kernels of one step must read: K and V of
     the positions the live slots attend, in every attention layer."""
-    return kv_bytes_per_position(cfg, itemsize) \
+    return kv_bytes_per_position(cfg, sizes_of(itemsize).pages) \
         * (len(layer_kinds(cfg)) - n_mamba(cfg)) * live_positions
 
 
-def ssm_step_bytes(cfg: dict, state_slots: float, itemsize: int) -> float:
+def ssm_step_bytes(cfg: dict, state_slots: float, itemsize) -> float:
     """Bytes the state steps of one decode step must move: the matrix
     state of every state-space layer, read once and written once, for the
     ``state_slots`` slots the step advanced."""
-    return 2 * ssm_state_bytes_per_slot(cfg, itemsize) * n_mamba(cfg) \
-        * state_slots
+    return 2 * ssm_state_bytes_per_slot(cfg, sizes_of(itemsize).state) \
+        * n_mamba(cfg) * state_slots
 
 
-def ssm_chunk_bytes(cfg: dict, scan_tokens: float, itemsize: int) -> float:
+def ssm_chunk_bytes(cfg: dict, scan_tokens: float, itemsize) -> float:
     """Bytes the recurrence of one prefill must move in every state-space
     layer: x, B, C and dt of every real token read, its output written,
     and the state it leaves written once (it starts from none).  The same
     tokens' operations (``ssm_chunk_flops``) take less of the chip than
-    these bytes do, so the bytes are the scan kernel's floor."""
+    these bytes do, so the bytes are the scan kernel's floor.  What the
+    recurrence reads and writes a token is float32 whatever the weights
+    are in (kept: the convolution's result, dt); the state is the
+    state's."""
+    sz = sizes_of(itemsize)
     heads, p, n, _ = ssm_dims(cfg)
     per_token = 2 * heads * p + 2 * n + heads
-    return itemsize * n_mamba(cfg) * (per_token * scan_tokens
-                                      + heads * p * n)
+    return n_mamba(cfg) * (sz.kept * per_token * scan_tokens
+                           + sz.state * heads * p * n)
 
 
 def ssm_chunk_flops(cfg: dict, scan_tokens: float) -> float:
@@ -106,7 +112,7 @@ def ssm_chunk_flops(cfg: dict, scan_tokens: float) -> float:
 
 
 def decode_step_bytes(cfg: dict, live_positions: float, state_slots: float,
-                      itemsize: int) -> float:
+                      itemsize) -> float:
     """Bytes one decode step over the grid must move: every layer's mixer,
     its SwiGLU and its two norms; the final norm and the tied table, once
     (the head reads it whole; the slots' embedding rows are among its
@@ -115,15 +121,22 @@ def decode_step_bytes(cfg: dict, live_positions: float, state_slots: float,
     and both states of every state-space layer, read and written, for the
     ``state_slots`` slots the step advanced."""
     h = cfg["hidden_size"]
+    sz = sizes_of(itemsize)
+    heads, p, _, channels = ssm_dims(cfg)
     weights = h + h * cfg["vocab_size"]
+    # kept float32: norms, the taps and their bias, the three constants a
+    # head, the gated norm's weight
+    kept = h
     for kind in layer_kinds(cfg):
         weights += 2 * h + dense_params(cfg)
         weights += mamba_mixer_params(cfg) if kind == "mamba" \
             else attention_mixer_params(cfg)
-    state = 2 * conv_state_bytes_per_slot(cfg, itemsize) * n_mamba(cfg) \
-        * state_slots + ssm_step_bytes(cfg, state_slots, itemsize)
-    return itemsize * weights + state \
-        + paged_kernel_bytes(cfg, live_positions, itemsize)
+        kept += 2 * h + (channels * (cfg["mamba_d_conv"] + 1) + 3 * heads
+                         + heads * p if kind == "mamba" else 0)
+    state = 2 * conv_state_bytes_per_slot(cfg, sz.state) * n_mamba(cfg) \
+        * state_slots + ssm_step_bytes(cfg, state_slots, sz)
+    return sz.weights * (weights - kept) + sz.kept * kept + state \
+        + paged_kernel_bytes(cfg, live_positions, sz)
 
 
 def prefill_flops(cfg: dict, n_tokens: int) -> float:

@@ -9,6 +9,8 @@ count.
 """
 from __future__ import annotations
 
+from ops_bytes import sizes_of
+
 
 def layer_kinds(cfg: dict) -> list:
     """``(mixer, dense)`` of every layer that is run."""
@@ -59,16 +61,17 @@ def state_bytes_per_slot(cfg: dict, itemsize: int) -> int:
 
 
 def paged_kernel_bytes(cfg: dict, live_positions: float,
-                       itemsize: int) -> float:
+                       itemsize) -> float:
     """Bytes the paged decode kernels of one step must read: K and V of
     the positions the live slots attend, in every attention layer."""
     n_attn = sum(kind != "conv" for kind, _ in layer_kinds(cfg))
-    return kv_bytes_per_position(cfg, itemsize) * n_attn * live_positions
+    return kv_bytes_per_position(cfg, sizes_of(itemsize).pages) * n_attn \
+        * live_positions
 
 
 def decode_step_bytes(cfg: dict, experts_touched: float,
                       live_positions: float, state_slots: float,
-                      itemsize: int) -> float:
+                      itemsize) -> float:
     """Bytes one decode step over the grid must move: every layer's mixer
     and its two norms; the leading dense SwiGLU; in every expert layer the
     router, its bias and the experts that got a row (``experts_touched``,
@@ -79,17 +82,22 @@ def decode_step_bytes(cfg: dict, experts_touched: float,
     and the state of every conv layer, read and written, for the
     ``state_slots`` slots the step advanced."""
     h = cfg["hidden_size"]
+    sz = sizes_of(itemsize)
     weights = h + h * cfg["vocab_size"]
+    kept = h            # kept float32: norms, taps, the router and its bias
     n_conv = 0
     for kind, dense in layer_kinds(cfg):
         weights += 2 * h + (conv_mixer_params(cfg) if kind == "conv"
                             else attention_mixer_params(cfg))
         weights += dense_params(cfg) if dense else \
             router_params(cfg) + experts_touched * expert_params(cfg)
+        kept += 2 * h + (h * cfg["conv_L_cache"] if kind == "conv"
+                         else 2 * head_dim(cfg)) \
+            + (0 if dense else router_params(cfg))
         n_conv += kind == "conv"
-    state = 2 * state_bytes_per_slot(cfg, itemsize) * n_conv * state_slots
-    return itemsize * weights + state \
-        + paged_kernel_bytes(cfg, live_positions, itemsize)
+    state = 2 * state_bytes_per_slot(cfg, sz.state) * n_conv * state_slots
+    return sz.weights * (weights - kept) + sz.kept * kept + state \
+        + paged_kernel_bytes(cfg, live_positions, sz)
 
 
 def prefill_flops(cfg: dict, n_tokens: int) -> float:

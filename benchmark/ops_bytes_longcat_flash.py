@@ -17,6 +17,8 @@ the count.
 """
 from __future__ import annotations
 
+from ops_bytes import sizes_of
+
 SUBLAYERS = 2            # latent sublayers (and dense SwiGLUs) a layer
 
 
@@ -78,11 +80,11 @@ def latent_row_bytes(cfg: dict, itemsize: int) -> int:
 
 
 def mla_decode_bytes(cfg: dict, latent_positions: float,
-                     itemsize: int) -> float:
+                     itemsize) -> float:
     """Bytes the latent decode kernels of one step must read: one row a
     cached position the live slots attend, once, in every sublayer."""
-    return latent_row_bytes(cfg, itemsize) * SUBLAYERS * cfg["num_layers"] \
-        * latent_positions
+    return latent_row_bytes(cfg, sizes_of(itemsize).pages) * SUBLAYERS \
+        * cfg["num_layers"] * latent_positions
 
 
 def mla_decode_flops(cfg: dict, latent_positions: float,
@@ -96,7 +98,7 @@ def mla_decode_flops(cfg: dict, latent_positions: float,
 
 
 def decode_step_bytes(cfg: dict, experts_held_touched: float,
-                      latent_positions: float, itemsize: int) -> float:
+                      latent_positions: float, itemsize) -> float:
     """Bytes one decode step over the grid must move: every layer's
     weights outside its routed experts and the held experts that got a row
     (``experts_held_touched``, the mean over the expert layers); the final
@@ -107,8 +109,13 @@ def decode_step_bytes(cfg: dict, experts_held_touched: float,
     weights = h + h * cfg["vocab_size"] + cfg["num_layers"] * (
         layer_params_outside_experts(cfg)
         + expert_params(cfg) * experts_held_touched)
-    return itemsize * weights \
-        + mla_decode_bytes(cfg, latent_positions, itemsize)
+    sz = sizes_of(itemsize)
+    # kept float32: norms (the low-rank pairs' too), the router, its bias
+    kept = h + cfg["num_layers"] * (
+        SUBLAYERS * (2 * h + cfg["q_lora_rank"] + cfg["kv_lora_rank"])
+        + router_params(cfg))
+    return sz.weights * (weights - kept) + sz.kept * kept \
+        + mla_decode_bytes(cfg, latent_positions, sz)
 
 
 def pair_flops(cfg: dict) -> int:

@@ -8,6 +8,8 @@ share over 100% is a fault of the count.
 """
 from __future__ import annotations
 
+from ops_bytes import sizes_of
+
 
 def _dims(cfg: dict):
     h, d = cfg["hidden_size"], cfg["head_dim"]
@@ -38,9 +40,9 @@ def kv_bytes_per_position(cfg: dict, itemsize: int) -> int:
 
 def decode_step_bytes(cfg: dict, experts_touched: float,
                       positions_full: float, positions_window: float,
-                      itemsize: int) -> float:
+                      itemsize) -> float:
     """Bytes one decode step must read: every layer's attention matrices,
-    router and norms; the experts that got a token (``experts_touched``,
+    router and norms (the router and the norms kept float32); the experts that got a token (``experts_touched``,
     the mean over the layers); the final norm and the untied head; K and
     V of the live positions, whole contexts in the full layers
     (``positions_full``) and what lies inside the window in the window
@@ -52,9 +54,11 @@ def decode_step_bytes(cfg: dict, experts_touched: float,
     weights = layers * (attention_params(cfg) + 2 * h
                         + experts_touched * expert_params(cfg)) \
         + h + h * cfg["vocab_size"]
-    kv = kv_bytes_per_position(cfg, itemsize) * (
+    sz = sizes_of(itemsize)
+    kept = layers * (2 * h + h * cfg["moe_num_primary_experts"]) + h
+    kv = kv_bytes_per_position(cfg, sz.pages) * (
         (layers - n_window) * positions_full + n_window * positions_window)
-    return itemsize * weights + kv
+    return sz.weights * (weights - kept) + sz.kept * kept + kv
 
 
 def prefill_flops(cfg: dict, n_tokens: int) -> float:

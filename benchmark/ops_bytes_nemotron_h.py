@@ -14,6 +14,8 @@ fault of the count.
 """
 from __future__ import annotations
 
+from ops_bytes import sizes_of
+
 
 def layer_kinds(cfg: dict) -> list:
     """One letter a layer: ``M`` a state-space mixer, ``*`` attention,
@@ -91,31 +93,35 @@ def conv_state_bytes_per_slot(cfg: dict, itemsize: int) -> int:
 
 
 def paged_kernel_bytes(cfg: dict, live_positions: float,
-                       itemsize: int) -> float:
+                       itemsize) -> float:
     """Bytes the paged decode kernels of one step must read: K and V of
     the positions the live slots attend, in every attention layer."""
-    return kv_bytes_per_position(cfg, itemsize) * n_of(cfg, "*") \
+    return kv_bytes_per_position(cfg, sizes_of(itemsize).pages) \
+        * n_of(cfg, "*") \
         * live_positions
 
 
-def ssm_step_bytes(cfg: dict, state_slots: float, itemsize: int) -> float:
+def ssm_step_bytes(cfg: dict, state_slots: float, itemsize) -> float:
     """Bytes the state steps of one decode step must move: the matrix
     state of every state-space layer, read once and written once, for the
     ``state_slots`` slots the step advanced (B and C of the groups are
     under a thousandth of it)."""
-    return 2 * ssm_state_bytes_per_slot(cfg, itemsize) * n_of(cfg, "M") \
+    return 2 * ssm_state_bytes_per_slot(cfg, sizes_of(itemsize).state) \
+        * n_of(cfg, "M") \
         * state_slots
 
 
-def ssm_chunk_bytes(cfg: dict, scan_tokens: float, itemsize: int) -> float:
+def ssm_chunk_bytes(cfg: dict, scan_tokens: float, itemsize) -> float:
     """Bytes the recurrence of one prefill must move in every state-space
     layer: x, dt and every group's B and C of every real token read, its
     output written, and the state it leaves written once (it starts from
-    none)."""
+    none).  What the recurrence reads and writes a token is float32
+    whatever the weights are in (kept); the state is the state's."""
+    sz = sizes_of(itemsize)
     heads, p, n, g, _ = ssm_dims(cfg)
     per_token = 2 * heads * p + 2 * g * n + heads
-    return itemsize * n_of(cfg, "M") * (per_token * scan_tokens
-                                        + heads * p * n)
+    return n_of(cfg, "M") * (sz.kept * per_token * scan_tokens
+                             + sz.state * heads * p * n)
 
 
 def ssm_chunk_flops(cfg: dict, scan_tokens: float) -> float:
@@ -126,20 +132,23 @@ def ssm_chunk_flops(cfg: dict, scan_tokens: float) -> float:
 
 
 def expert_kernel_bytes(cfg: dict, experts_held_touched: float,
-                        pairs_held: float, itemsize: int) -> float:
+                        pairs_held: float, itemsize) -> float:
     """Bytes the grouped products of one decode step must move: the two
     matrices of every held expert that got a row (``experts_held_touched``,
     the mean over the expert layers), in every expert layer, and the held
     pairs' rows (``pairs_held``, summed over the layers) into and out of
     both products: latent in and width out, width in and latent out."""
     rows = 2 * (cfg["moe_latent_size"] + cfg["moe_intermediate_size"])
-    return itemsize * (expert_params(cfg) * experts_held_touched
-                       * n_of(cfg, "E") + rows * pairs_held)
+    # (the rows are activations that enter and leave a product of the
+    # weights' dtype: the weights' size)
+    return sizes_of(itemsize).weights * (
+        expert_params(cfg) * experts_held_touched * n_of(cfg, "E")
+        + rows * pairs_held)
 
 
 def decode_step_bytes(cfg: dict, experts_held_touched: float,
                       live_positions: float, state_slots: float,
-                      itemsize: int) -> float:
+                      itemsize) -> float:
     """Bytes one decode step over the grid must move: every layer's one
     norm and its one sublayer (a state-space mixer; attention; or the
     router, the latent pair, the full-width shared expert and the held
@@ -150,16 +159,25 @@ def decode_step_bytes(cfg: dict, experts_held_touched: float,
     the attention layers; and both states of every state-space layer, read
     and written, for the ``state_slots`` slots the step advanced."""
     h = cfg["hidden_size"]
+    sz = sizes_of(itemsize)
+    heads, p, _, _, channels = ssm_dims(cfg)
     weights = h + h * cfg["vocab_size"] + state_slots * h
+    # kept float32: norms; the taps and their bias, the three constants a
+    # head and the gated norm's weight; the router and its bias
+    kept = h
     for kind in layer_kinds(cfg):
         weights += h + {
             "M": mamba_params(cfg), "*": attention_params(cfg),
             "E": expert_layer_fixed_params(cfg)
             + expert_params(cfg) * experts_held_touched}[kind]
-    state = 2 * conv_state_bytes_per_slot(cfg, itemsize) * n_of(cfg, "M") \
-        * state_slots + ssm_step_bytes(cfg, state_slots, itemsize)
-    return itemsize * weights + state \
-        + paged_kernel_bytes(cfg, live_positions, itemsize)
+        kept += h + {
+            "M": channels * (cfg["conv_kernel"] + 1) + 3 * heads
+            + heads * p, "*": 0,
+            "E": (h + 1) * cfg["expert_share"]["router_experts"]}[kind]
+    state = 2 * conv_state_bytes_per_slot(cfg, sz.state) * n_of(cfg, "M") \
+        * state_slots + ssm_step_bytes(cfg, state_slots, sz)
+    return sz.weights * (weights - kept) + sz.kept * kept + state \
+        + paged_kernel_bytes(cfg, live_positions, sz)
 
 
 def prefill_flops(cfg: dict, n_tokens: int) -> float:

@@ -11,6 +11,8 @@ share over 100% is a fault of the count.
 """
 from __future__ import annotations
 
+from ops_bytes import sizes_of
+
 
 def layer_kinds(cfg: dict) -> list:
     return list(cfg["layer_types"][:cfg["num_hidden_layers"]])
@@ -67,34 +69,37 @@ def conv_state_bytes_per_slot(cfg: dict, itemsize: int) -> int:
 
 
 def paged_kernel_bytes(cfg: dict, live_positions: float,
-                       itemsize: int) -> float:
+                       itemsize) -> float:
     """Bytes the paged decode kernels of one step must read: K and V of
     the positions the live slots attend, in every full-attention layer."""
-    return kv_bytes_per_position(cfg, itemsize) \
+    return kv_bytes_per_position(cfg, sizes_of(itemsize).pages) \
         * (len(layer_kinds(cfg)) - n_linear(cfg)) * live_positions
 
 
-def delta_step_bytes(cfg: dict, state_slots: float, itemsize: int) -> float:
+def delta_step_bytes(cfg: dict, state_slots: float, itemsize) -> float:
     """Bytes the delta-state steps of one decode step must move: the
     state of every linear layer, read once and written once, for the
     ``state_slots`` slots the step advanced."""
-    return 2 * delta_state_bytes_per_slot(cfg, itemsize) * n_linear(cfg) \
-        * state_slots
+    return 2 * delta_state_bytes_per_slot(cfg, sizes_of(itemsize).state) \
+        * n_linear(cfg) * state_slots
 
 
-def delta_chunk_bytes(cfg: dict, scan_tokens: float, itemsize: int) -> float:
+def delta_chunk_bytes(cfg: dict, scan_tokens: float, itemsize) -> float:
     """Bytes the delta rule of one prefill must move in every linear
     layer: q, k, v, the log decay and beta of every real token read, its
     output written, and the state it leaves written once (it starts from
-    none)."""
+    none).  What the rule reads and writes a token is float32 whatever
+    the weights are in (kept: the convolution's result, the L2 norms, the
+    decay); the state is the state's."""
+    sz = sizes_of(itemsize)
     heads, dk, dv, _ = delta_dims(cfg)
     per_token = heads * (2 * dk + 2 * dv + 2)
-    return itemsize * n_linear(cfg) * (per_token * scan_tokens
-                                       + heads * dk * dv)
+    return n_linear(cfg) * (sz.kept * per_token * scan_tokens
+                            + sz.state * heads * dk * dv)
 
 
 def decode_step_bytes(cfg: dict, live_positions: float, state_slots: float,
-                      itemsize: int) -> float:
+                      itemsize) -> float:
     """Bytes one decode step over the grid must move: every layer's mixer,
     its SwiGLU and its norms (a full layer's two QK-norm weights, a linear
     layer's decay constants and output-norm weight among them); the final
@@ -105,16 +110,24 @@ def decode_step_bytes(cfg: dict, live_positions: float, state_slots: float,
     the step advanced."""
     h = cfg["hidden_size"]
     heads, _, dv, _ = delta_dims(cfg)
+    sz = sizes_of(itemsize)
+    channels = delta_dims(cfg)[3]
     weights = h + h * cfg["vocab_size"] + state_slots * h
+    # kept float32: norms, a | b, the taps, the decay's constants
+    kept = h
     for kind in layer_kinds(cfg):
         weights += 2 * h + dense_params(cfg)
         weights += linear_mixer_params(cfg) + 2 * heads + dv \
             if kind == "linear_attention" \
             else attention_mixer_params(cfg) + 2 * h
-    state = 2 * conv_state_bytes_per_slot(cfg, itemsize) * n_linear(cfg) \
-        * state_slots + delta_step_bytes(cfg, state_slots, itemsize)
-    return itemsize * weights + state \
-        + paged_kernel_bytes(cfg, live_positions, itemsize)
+        kept += 2 * h + (h * 2 * heads
+                         + channels * cfg["linear_conv_kernel_dim"]
+                         + 2 * heads + dv if kind == "linear_attention"
+                         else 2 * h)
+    state = 2 * conv_state_bytes_per_slot(cfg, sz.state) * n_linear(cfg) \
+        * state_slots + delta_step_bytes(cfg, state_slots, sz)
+    return sz.weights * (weights - kept) + sz.kept * kept + state \
+        + paged_kernel_bytes(cfg, live_positions, sz)
 
 
 def prefill_flops(cfg: dict, n_tokens: int) -> float:

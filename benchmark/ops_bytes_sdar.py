@@ -8,6 +8,8 @@ exceeds what the program does: a share over 100% is a fault of the count.
 """
 from __future__ import annotations
 
+from ops_bytes import sizes_of
+
 
 def attention_params(cfg: dict) -> int:
     """One layer's attention matrices (fused QKV, output) and its router:
@@ -33,7 +35,7 @@ def kv_bytes_per_position(cfg: dict, itemsize: int) -> int:
 
 
 def pass_bytes(cfg: dict, experts_touched: float, live_positions: float,
-               rows: float, itemsize: int) -> float:
+               rows: float, itemsize) -> float:
     """Bytes one pass over the grid must read: every layer's attention
     matrices, router and norms; the experts that got a row
     (``experts_touched``, the mean over the layers); the final norm and
@@ -46,8 +48,11 @@ def pass_bytes(cfg: dict, experts_touched: float, live_positions: float,
     weights = layers * (attention_params(cfg) + norm_params(cfg)
                         + experts_touched * expert_params(cfg)) \
         + h + h * cfg["vocab_size"] + rows * h
-    kv = kv_bytes_per_position(cfg, itemsize) * layers * live_positions
-    return itemsize * weights + kv
+    sz = sizes_of(itemsize)
+    # kept float32: the norms and the router
+    kept = layers * (norm_params(cfg) + h * cfg["num_experts"]) + h
+    kv = kv_bytes_per_position(cfg, sz.pages) * layers * live_positions
+    return sz.weights * (weights - kept) + sz.kept * kept + kv
 
 
 def prefill_flops(cfg: dict, n_tokens: int) -> float:

@@ -14,6 +14,8 @@ a fault of the count.
 """
 from __future__ import annotations
 
+from ops_bytes import sizes_of
+
 
 def layer_kinds(cfg: dict) -> list:
     return ["attention" if i in cfg["gqa_layers"] else "kda"
@@ -85,34 +87,38 @@ def conv_state_bytes_per_slot(cfg: dict, itemsize: int) -> int:
 
 
 def paged_kernel_bytes(cfg: dict, live_positions: float,
-                       itemsize: int) -> float:
+                       itemsize) -> float:
     """Bytes the paged decode kernels of one step must read: K and V of
     the positions the live slots attend, in every attention layer."""
-    return kv_bytes_per_position(cfg, itemsize) \
+    return kv_bytes_per_position(cfg, sizes_of(itemsize).pages) \
         * (len(layer_kinds(cfg)) - n_kda(cfg)) * live_positions
 
 
-def kda_step_bytes(cfg: dict, state_slots: float, itemsize: int) -> float:
+def kda_step_bytes(cfg: dict, state_slots: float, itemsize) -> float:
     """Bytes the delta-state steps of one decode step must move: the
     state of every KDA layer, read once and written once, for the
     ``state_slots`` slots the step advanced."""
-    return 2 * kda_state_bytes_per_slot(cfg, itemsize) * n_kda(cfg) \
-        * state_slots
+    return 2 * kda_state_bytes_per_slot(cfg, sizes_of(itemsize).state) \
+        * n_kda(cfg) * state_slots
 
 
-def kda_chunk_bytes(cfg: dict, scan_tokens: float, itemsize: int) -> float:
+def kda_chunk_bytes(cfg: dict, scan_tokens: float, itemsize) -> float:
     """Bytes the delta rule of one prefill must move in every KDA layer:
     q, k, v and the log decay (a value a key channel) of every real token
     and its beta read, its output written, and the state it leaves
-    written once (it starts from none)."""
+    written once (it starts from none).  What the rule reads and writes
+    a token is float32 whatever the weights are in (kept); the state is
+    the state's."""
+    sz = sizes_of(itemsize)
     heads, d, _ = kda_dims(cfg)
     per_token = heads * (5 * d + 1)
-    return itemsize * n_kda(cfg) * (per_token * scan_tokens + heads * d * d)
+    return n_kda(cfg) * (sz.kept * per_token * scan_tokens
+                         + sz.state * heads * d * d)
 
 
 def decode_step_bytes(cfg: dict, experts_held_touched: float,
                       live_positions: float, state_slots: float,
-                      itemsize: int) -> float:
+                      itemsize) -> float:
     """Bytes one decode step over the grid must move: every layer's mixer
     and its two norms; the router over all its experts with its bias, the
     held experts that got a row (``experts_held_touched``, the mean over
@@ -123,16 +129,26 @@ def decode_step_bytes(cfg: dict, experts_held_touched: float,
     every KDA layer, read and written, for the ``state_slots`` slots the
     step advanced."""
     h = cfg["hidden_size"]
+    sz = sizes_of(itemsize)
+    low = cfg["assumed"]["low_rank"]
+    heads, d, channels = kda_dims(cfg)
+    taps = cfg["linear_attn_config"]["short_conv_kernel_size"]
     weights = h + h * cfg["vocab_size"] + state_slots * h
+    # kept float32: norms, the router and its bias, the decay's low-rank
+    # pair, beta, the taps, A_log, dt_bias and the head norm's weight
+    kept = h
     for kind in layer_kinds(cfg):
         weights += 2 * h + (kda_mixer_params(cfg) if kind == "kda"
                             else attention_mixer_params(cfg))
         weights += router_params(cfg) + expert_params(cfg) * (
             experts_held_touched + cfg["n_shared_experts"])
-    state = 2 * conv_state_bytes_per_slot(cfg, itemsize) * n_kda(cfg) \
-        * state_slots + kda_step_bytes(cfg, state_slots, itemsize)
-    return itemsize * weights + state \
-        + paged_kernel_bytes(cfg, live_positions, itemsize)
+        kept += 2 * h + router_params(cfg) + (
+            h * low + low * heads * d + h * heads + channels * taps
+            + heads + heads * d + d if kind == "kda" else 0)
+    state = 2 * conv_state_bytes_per_slot(cfg, sz.state) * n_kda(cfg) \
+        * state_slots + kda_step_bytes(cfg, state_slots, sz)
+    return sz.weights * (weights - kept) + sz.kept * kept + state \
+        + paged_kernel_bytes(cfg, live_positions, sz)
 
 
 def prefill_flops(cfg: dict, n_tokens: int) -> float:

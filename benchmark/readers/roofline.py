@@ -4,8 +4,9 @@
 The metric's file names the arithmetic: ``fn`` is ``<module>.<function>``
 of a module under ``benchmark/`` that gives the operations or bytes the
 work needs, ``peak`` the key of ``peaks.json`` they are held against, and
-``per`` how work and device time are paired.  The item size is that of
-the configuration's ``as_run.dtype``.
+``per`` how work and device time are paired.  The item sizes are by class (weights, pages,
+slot state), as the harness observed them on the engine that ran
+(``harness.item_sizes``).
 
 * ``per: decode_step``: ``fn(cfg, live_kv_tokens, itemsize)`` for one
   step (the live positions are the measured window's mean of the
@@ -19,9 +20,7 @@ the configuration's ``as_run.dtype``.
 """
 import bisect
 
-import numpy as np
-
-from harness import load_module, resolve
+from harness import item_sizes, load_module, resolve
 
 split = load_module("readers", "module_time").split
 
@@ -38,7 +37,7 @@ def read(ctx, per, fn, peak):
         if not live or not decode:
             return None
         tokens = gen.page_tokens * sum(live) / len(live)
-        itemsize = np.dtype(cfg["as_run"]["dtype"]).itemsize
+        itemsize = item_sizes(ctx)
         took_s = sum(e - s for s, e in decode) / len(decode)
         return 100.0 * need(cfg, tokens, itemsize) / peak / took_s
     if per == "prefill":

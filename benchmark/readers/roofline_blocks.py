@@ -5,9 +5,7 @@ that got a row, the positions its live slots attended, its live rows)
 over the peak, over the pass module's mean device time
 (``module_time.split``: the module that ran most often).  A program
 without such spans (one token a step) gives nothing to read."""
-import numpy as np
-
-from harness import load_module, resolve
+from harness import item_sizes, load_module, resolve
 
 split = load_module("readers", "module_time").split
 ATTRS = ("experts_touched", "live_positions", "rows")
@@ -25,7 +23,7 @@ def read(ctx, fn, peak, span="generation/decode_step"):
     if not steps or not passes:
         return None
     mean = [sum(a[k] for a in steps) / len(steps) for k in ATTRS]
-    itemsize = np.dtype(cfg["as_run"]["dtype"]).itemsize
+    itemsize = item_sizes(ctx)
     took_s = sum(e - s for s, e in passes) / len(passes)
     return 100.0 * resolve(fn)(cfg, *mean, itemsize) \
         / run.peaks[peak] / took_s

@@ -9,9 +9,7 @@ as its output's shape).  Nothing matching, or no such spans: nothing to
 read."""
 import re
 
-import numpy as np
-
-from harness import load_module, resolve
+from harness import item_sizes, load_module, resolve
 
 split = load_module("readers", "module_time").split
 step_means = load_module("readers", "roofline_span").step_means
@@ -29,6 +27,6 @@ def read(ctx, fn, peak, attrs, pattern, span="generation/decode_step"):
                  if rx.search(t["op_text"][name]))
     if means is None or not decode or took_s <= 0:
         return None
-    itemsize = np.dtype(cfg["as_run"]["dtype"]).itemsize
+    itemsize = item_sizes(ctx)
     return 100.0 * len(decode) * resolve(fn)(cfg, *means, itemsize) \
         / run.peaks[peak] / took_s

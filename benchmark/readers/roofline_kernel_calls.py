@@ -15,9 +15,7 @@ Spans without an attribute (an earlier commit's prefill spans), no
 trace, or nothing matching: nothing to read."""
 import re
 
-import numpy as np
-
-from harness import load_module, resolve
+from harness import item_sizes, load_module, resolve
 
 split = load_module("readers", "module_time").split
 step_means = load_module("readers", "roofline_span").step_means
@@ -33,7 +31,7 @@ def read(ctx, fn, peak, attrs, pattern, span="generation/decode_step",
     rx = re.compile(pattern)
     took_s = sum(sec for name, sec in t["op_seconds"].items()
                  if rx.search(t["op_text"][name]))
-    itemsize = np.dtype(cfg["as_run"]["dtype"]).itemsize
+    itemsize = item_sizes(ctx)
     needed = 0.0
     for runs, name in ((decode, span), (prefill, prefill_span)):
         if not runs:

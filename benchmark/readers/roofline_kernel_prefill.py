@@ -9,9 +9,7 @@ no trace, or nothing matching: nothing to read."""
 import bisect
 import re
 
-import numpy as np
-
-from harness import load_module, resolve
+from harness import item_sizes, load_module, resolve
 
 split = load_module("readers", "module_time").split
 
@@ -30,7 +28,7 @@ def read(ctx, fn, peak, attr, pattern, span="generation/prefill"):
                  if rx.search(t["op_text"][name]))
     if not spans or not prefill or took_s <= 0:
         return None
-    itemsize = np.dtype(cfg["as_run"]["dtype"]).itemsize
+    itemsize = item_sizes(ctx)
     starts = [s for s, _ in spans]
     needed = 0.0
     for s, _ in prefill:

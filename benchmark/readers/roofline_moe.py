@@ -4,9 +4,7 @@ itemsize)`` for the mean decode step of the traced seconds (the
 ``experts_touched``, ``live_positions`` and ``live_positions_window``
 attributes of its ``generation/decode_step`` spans) over the peak, over
 the decode module's mean device time (``module_time.split``)."""
-import numpy as np
-
-from harness import load_module, resolve
+from harness import item_sizes, load_module, resolve
 
 split = load_module("readers", "module_time").split
 ATTRS = ("experts_touched", "live_positions", "live_positions_window")
@@ -24,7 +22,7 @@ def read(ctx, fn, peak, span="generation/decode_step"):
     if not steps or not decode:
         return None
     mean = [sum(a[k] for a in steps) / len(steps) for k in ATTRS]
-    itemsize = np.dtype(cfg["as_run"]["dtype"]).itemsize
+    itemsize = item_sizes(ctx)
     took_s = sum(e - s for s, e in decode) / len(decode)
     return 100.0 * resolve(fn)(cfg, *mean, itemsize) \
         / run.peaks[peak] / took_s

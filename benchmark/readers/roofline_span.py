@@ -5,9 +5,7 @@ of its ``generation/decode_step`` spans, in that order), over the peak,
 over the decode module's mean device time (``module_time.split``: the
 module that ran most often).  A program whose spans lack an attribute (an
 earlier commit's) gives nothing to read."""
-import numpy as np
-
-from harness import load_module, resolve
+from harness import item_sizes, load_module, resolve
 
 split = load_module("readers", "module_time").split
 
@@ -33,7 +31,7 @@ def read(ctx, fn, peak, attrs, span="generation/decode_step"):
     means = step_means(ctx, attrs, span)
     if means is None or not decode:
         return None
-    itemsize = np.dtype(cfg["as_run"]["dtype"]).itemsize
+    itemsize = item_sizes(ctx)
     took_s = sum(e - s for s, e in decode) / len(decode)
     return 100.0 * resolve(fn)(cfg, *means, itemsize) \
         / run.peaks[peak] / took_s

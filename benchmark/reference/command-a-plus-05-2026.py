@@ -72,6 +72,17 @@ def held_range(cfg: dict) -> tuple:
     ``cfg["expert_share"]["router_experts"]``."""
     return int(cfg["expert_share"]["first"]), int(cfg["num_experts"])
 
+# Roundings that a control puts in (``benchmark/tests/standins.py``): the
+# plain reference leaves ``ROUND`` None, every ``_at`` is then the identity
+# and adds nothing to the lowered module.  ``where`` is "residual" (the
+# stream after a sublayer), "pages" (K and V as a program writes them to
+# its pages) or "product" (an activation that enters a product).
+ROUND = None
+
+
+def _at(where, x):
+    return x if ROUND is None else ROUND(where, x)
+
 
 def params_from_scope(scope, cfg: dict, name: str = "llama") -> dict:
     """The program's weights, by the names ``models/llama.py`` gives them,
@@ -119,6 +130,7 @@ def attention_rows(q, k, v, first, window):
     head g reads KV head g // (H // Hkv); one KV head's group at a time."""
     heads, r, d = q.shape
     kv_heads, n, _ = k.shape
+    q = _at("product", q)
     rep = heads // kv_heads
     i = first + jnp.arange(r)[:, None]
     j = jnp.arange(n)[None, :]
@@ -132,7 +144,8 @@ def attention_rows(q, k, v, first, window):
         vg = jax.lax.dynamic_index_in_dim(v, g, 0, False)
         s = jnp.einsum("hqd,kd->hqk", qg, kg) \
             / float(np.sqrt(d))              # weak: keeps q's precision
-        p = jax.nn.softmax(jnp.where(keep[None], s, -jnp.inf), -1)
+        p = _at("product",
+                jax.nn.softmax(jnp.where(keep[None], s, -jnp.inf), -1))
         return jnp.einsum("hqk,kd->hqd", p, vg)
 
     out = jax.lax.map(group, jnp.arange(kv_heads))     # [Hkv, rep, r, d]
@@ -143,7 +156,7 @@ def swiglu(h, gate_up, down):
     """W_2(silu(W_1 h) * (W_3 h)) with gate | up side by side."""
     width = down.shape[0]
     gu = h @ gate_up.astype(h.dtype)
-    return (jax.nn.silu(gu[:, :width]) * gu[:, width:]) \
+    return _at("product", jax.nn.silu(gu[:, :width]) * gu[:, width:]) \
         @ down.astype(h.dtype)
 
 
@@ -232,6 +245,7 @@ def ffn(h, p, cfg, held, rows=None, program_logits=None, shared=True,
     if weights is None:
         logits = h @ p["router"].astype(h.dtype)
         weights, report = route(logits, cfg, rows, program_logits)
+        h = _at("product", h)           # (the router read it whole)
     first, count = held
     if p["gate_up"].shape[0] != count:
         raise ValueError(f"{p['gate_up'].shape[0]} expert matrices for a "
@@ -265,18 +279,21 @@ def forward(params: dict, token_ids, cfg: dict, rows=None,
         rows = jnp.asarray(rows)
     reports, routers = [], []
     with jax.default_matmul_precision("highest"):
-        x = params["embed"].astype(dtype)[jnp.pad(ids, (0, pad))]
+        x = _at("residual",
+                params["embed"].astype(dtype)[jnp.pad(ids, (0, pad))])
         for i, p in enumerate(params["layers"]):
             sliding = cfg["layer_types"][i] == "sliding_attention"
             window = int(cfg["sliding_window"]) if sliding else None
             h = layer_norm(x, p["ln"], eps)
+            logits = h @ p["router"].astype(dtype)
+            h = _at("product", h)       # (the router read it whole)
             wq = heads * d
             kv = (h @ p["qkv"][:, wq:].astype(dtype)).reshape(
                 n + pad, 2 * kv_heads, d).transpose(1, 0, 2)
             k, v = kv[:kv_heads], kv[kv_heads:]
             if sliding:
                 k = rope_interleaved(k, float(cfg["rope_theta"]))
-            logits = h @ p["router"].astype(dtype)
+            k, v = _at("pages", k), _at("pages", v)
             weights, report = route(
                 logits, cfg, rows,
                 None if program_router is None else program_router[:, i])
@@ -290,19 +307,19 @@ def forward(params: dict, token_ids, cfg: dict, rows=None,
                 if sliding:
                     q = rope_interleaved(q, float(cfg["rope_theta"]), first)
                 a = attention_rows(q, k, v, first, window)
-                a = a.transpose(1, 0, 2).reshape(block, wq) \
+                a = _at("product", a.transpose(1, 0, 2).reshape(block, wq)) \
                     @ p["wo"].astype(dtype)
                 y, _, _ = ffn(hb, p, cfg, held, weights=jax.lax.
                               dynamic_slice_in_dim(weights, first, block, 0))
                 return a + y
 
             y = jax.lax.map(rows_of, jnp.arange((n + pad) // block))
-            x = x + y.reshape(n + pad, -1)
+            x = _at("residual", x + y.reshape(n + pad, -1))
             if keep_router:
                 routers.append(logits[rows])
             if report is not None:
                 reports.append(report)
-        x = layer_norm(x, params["ln_f"], eps)[:n]
+        x = _at("product", layer_norm(x, params["ln_f"], eps)[:n])
         if rows is not None:
             x = x[rows]
         out = x @ params["embed"].astype(dtype).T

@@ -80,6 +80,17 @@ def held_range(cfg: dict) -> tuple:
     ``cfg["expert_share"]["router_experts"]``."""
     return int(cfg["expert_share"]["first"]), int(cfg["n_routed_experts"])
 
+# Roundings that a control puts in (``benchmark/tests/standins.py``): the
+# plain reference leaves ``ROUND`` None, every ``_at`` is then the identity
+# and adds nothing to the lowered module.  ``where`` is "residual" (the
+# stream after a sublayer), "pages" (K and V as a program writes them to
+# its pages) or "product" (an activation that enters a product).
+ROUND = None
+
+
+def _at(where, x):
+    return x if ROUND is None else ROUND(where, x)
+
 
 def params_from_scope(scope, cfg: dict, name: str = "llama") -> dict:
     """The program's weights, by the names ``models/llama.py`` gives them,
@@ -196,12 +207,15 @@ def attention_rows(q, c_kv, k_r, w_kvb, first, cfg):
 
     def heads_of(g):
         wg = jax.lax.dynamic_slice_in_dim(w, g * group, group, 1)
-        kv = jnp.einsum("nc,chd->hnd", c_kv, wg)          # [g, n, dn + dv]
-        qg = jax.lax.dynamic_slice_in_dim(q, g * group, group, 1)
+        kv = _at("product",
+                 jnp.einsum("nc,chd->hnd", c_kv, wg))     # [g, n, dn + dv]
+        qg = _at("product",
+                 jax.lax.dynamic_slice_in_dim(q, g * group, group, 1))
         s = (jnp.einsum("qhd,hkd->hqk", qg[..., :dn], kv[..., :dn])
              + jnp.einsum("qhd,kd->hqk", qg[..., dn:], k_r)) \
             * scale                          # weak: keeps q's precision
-        p = jax.nn.softmax(jnp.where(keep[None], s, -jnp.inf), -1)
+        p = _at("product",
+                jax.nn.softmax(jnp.where(keep[None], s, -jnp.inf), -1))
         return jnp.einsum("hqk,hkd->hqd", p, kv[..., dn:])
 
     out = jax.lax.map(heads_of, jnp.arange(heads // group))
@@ -216,7 +230,7 @@ def swiglu(h, gate_up, down):
     """W_2(silu(W_1 h) * (W_3 h)) with gate | up side by side."""
     width = down.shape[0]
     gu = h @ gate_up.astype(h.dtype)
-    return (jax.nn.silu(gu[:, :width]) * gu[:, width:]) \
+    return _at("product", jax.nn.silu(gu[:, :width]) * gu[:, width:]) \
         @ down.astype(h.dtype)
 
 
@@ -331,6 +345,7 @@ def ffn(h, p, cfg, held, rows=None, program_logits=None, shared=True,
     if weights is None:
         logits = h @ p["router"].astype(h.dtype)
         weights, report = route(logits, cfg, rows, program_logits, grouped)
+        h = _at("product", h)           # (the router read it whole)
     first, count = held
     if p["gate_up"].shape[0] != count:
         raise ValueError(f"{p['gate_up'].shape[0]} expert matrices for a "
@@ -364,19 +379,23 @@ def forward(params: dict, token_ids, cfg: dict, rows=None,
         rows = jnp.asarray(rows)
     reports, routers = [], []
     with jax.default_matmul_precision("highest"):
-        x = params["embed"].astype(dtype)[jnp.pad(ids, (0, pad))]
+        x = _at("residual",
+                params["embed"].astype(dtype)[jnp.pad(ids, (0, pad))])
         cos, sin = _tables(cfg, 0, n + pad, dtype)
         moe_at = -len(params["dense"])
         for p in params["dense"] + params["layers"]:
-            h = _norm(x, p["ln1"], eps)
+            h = _at("product", _norm(x, p["ln1"], eps))
             kv_a = h @ p["kv_a"].astype(dtype)
-            c_kv = _norm(kv_a[:, :c], p["kv_a_norm"], eps)
-            k_r = _rotate_pairs(kv_a[:, c:], cos, sin)       # [n, dr]
+            # the latent row as a program writes it to its pages
+            c_kv = _at("pages", _norm(kv_a[:, :c], p["kv_a_norm"], eps))
+            k_r = _at("pages",
+                      _rotate_pairs(kv_a[:, c:], cos, sin))  # [n, dr]
 
             def attend(b, h=h, p=p, c_kv=c_kv, k_r=k_r):
                 first = b * block
                 hb = jax.lax.dynamic_slice_in_dim(h, first, block, 0)
-                c_q = _norm(hb @ p["q_a"].astype(dtype), p["q_a_norm"], eps)
+                c_q = _at("product", _norm(hb @ p["q_a"].astype(dtype),
+                                           p["q_a_norm"], eps))
                 q = (c_q @ p["q_b"].astype(dtype)).reshape(
                     block, heads, dn + dr)
                 cb, sb = (jax.lax.dynamic_slice_in_dim(t, first, block, 0)
@@ -384,13 +403,13 @@ def forward(params: dict, token_ids, cfg: dict, rows=None,
                 q = jnp.concatenate(
                     [q[..., :dn], _rotate_pairs(q[..., dn:], cb, sb)], -1)
                 a = attention_rows(q, c_kv, k_r, p["kv_b"], first, cfg)
-                return a @ p["wo"].astype(dtype)
+                return _at("product", a) @ p["wo"].astype(dtype)
 
             y = jax.lax.map(attend, jnp.arange((n + pad) // block))
-            x = x + y.reshape(n + pad, -1)
+            x = _at("residual", x + y.reshape(n + pad, -1))
             h = _norm(x, p["ln2"], eps)
             if moe_at < 0:
-                def dense(b, h=h, p=p):
+                def dense(b, h=_at("product", h), p=p):
                     hb = jax.lax.dynamic_slice_in_dim(h, b * block, block, 0)
                     return swiglu(hb, p["gate_up"], p["down"])
 
@@ -402,7 +421,7 @@ def forward(params: dict, token_ids, cfg: dict, rows=None,
                     None if program_router is None
                     else program_router[:, moe_at], grouped)
 
-                def experts(b, h=h, p=p, weights=weights):
+                def experts(b, h=_at("product", h), p=p, weights=weights):
                     first = b * block
                     hb = jax.lax.dynamic_slice_in_dim(h, first, block, 0)
                     return ffn(hb, p, cfg, held, weights=jax.lax.
@@ -415,8 +434,8 @@ def forward(params: dict, token_ids, cfg: dict, rows=None,
                 if report is not None:
                     reports.append(report)
             moe_at += 1
-            x = x + y.reshape(n + pad, -1)
-        x = _norm(x, params["ln_f"], eps)[:n]
+            x = _at("residual", x + y.reshape(n + pad, -1))
+        x = _at("product", _norm(x, params["ln_f"], eps)[:n])
         if rows is not None:
             x = x[rows]
         out = x @ params["head"].astype(dtype)

@@ -86,6 +86,17 @@ def held_range(cfg: dict) -> tuple:
     ``cfg["expert_share"]["router_experts"]``."""
     return int(cfg["expert_share"]["first"]), int(cfg["n_routed_experts"])
 
+# Roundings that a control puts in (``benchmark/tests/standins.py``): the
+# plain reference leaves ``ROUND`` None, every ``_at`` is then the identity
+# and adds nothing to the lowered module.  ``where`` is "residual" (the
+# stream after a sublayer), "pages" (K and V as a program writes them to
+# its pages) or "product" (an activation that enters a product).
+ROUND = None
+
+
+def _at(where, x):
+    return x if ROUND is None else ROUND(where, x)
+
 
 def params_from_scope(scope, cfg: dict, name: str = "llama") -> dict:
     """The program's weights, by the names ``models/llama.py`` gives them,
@@ -178,13 +189,16 @@ def mla_scale(cfg: dict) -> float:
 def _attention(q, k, v, scale):
     """q, k [H, n, dk] and v [H, n, dv], causal, in blocks of queries."""
     n = q.shape[1]
+    # (the expanded K and V are products' results, not page rows)
+    q, k, v = _at("product", q), _at("product", k), _at("product", v)
     j = jnp.arange(n)[None, :]
     out = []
     for start in range(0, n, Q_BLOCK):
         i = jnp.arange(start, min(start + Q_BLOCK, n))[:, None]
         s = jnp.einsum("hqd,hkd->hqk", q[:, start:start + Q_BLOCK], k) \
             * scale                           # weak: keeps q's precision
-        p = jax.nn.softmax(jnp.where((j <= i)[None], s, -jnp.inf), -1)
+        p = _at("product",
+                jax.nn.softmax(jnp.where((j <= i)[None], s, -jnp.inf), -1))
         out.append(jnp.einsum("hqk,hkd->hqd", p, v))
     return jnp.concatenate(out, axis=1)
 
@@ -199,11 +213,14 @@ def _mla(h, p, cfg, eps):
     angles = jnp.arange(n, dtype=jnp.float32)[:, None] \
         * jnp.asarray(yarn_frequencies(cfg), jnp.float32)[None, :]
     cos, sin = jnp.cos(angles).astype(dtype), jnp.sin(angles).astype(dtype)
-    c_q = _norm(h @ p["q_a"].astype(dtype), p["q_a_norm"], eps)
+    h = _at("product", h)
+    c_q = _at("product", _norm(h @ p["q_a"].astype(dtype), p["q_a_norm"],
+                               eps))
     q = (c_q @ p["q_b"].astype(dtype)).reshape(n, heads, dn + dr)
     kv_a = h @ p["kv_a"].astype(dtype)
-    c_kv = _norm(kv_a[:, :c], p["kv_a_norm"], eps)
-    k_r = _rotate_pairs(kv_a[:, c:], cos, sin)               # [n, dr]
+    # the latent row as a program writes it to its pages: [c_kv | k_r]
+    c_kv = _at("pages", _norm(kv_a[:, :c], p["kv_a_norm"], eps))
+    k_r = _at("pages", _rotate_pairs(kv_a[:, c:], cos, sin))  # [n, dr]
     q = jnp.concatenate([q[..., :dn], _rotate_pairs(q[..., dn:], cos, sin)],
                         axis=-1)
     kv = (c_kv @ p["kv_b"].astype(dtype)).reshape(n, heads, dn + dv)
@@ -215,7 +232,7 @@ def _mla(h, p, cfg, eps):
     a = a.transpose(1, 0, 2).reshape(n, heads * dv)
     if cfg["gated_attention"]:
         a = a * jax.nn.sigmoid(h @ p["gate"].astype(dtype))
-    return a @ p["wo"].astype(dtype)
+    return _at("product", a) @ p["wo"].astype(dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -254,13 +271,15 @@ def _delta(h, p, cfg, eps):
     n = h.shape[0]
     hk, hv = cfg["linear_num_key_heads"], cfg["linear_num_value_heads"]
     dk, dv = cfg["linear_key_head_dim"], cfg["linear_value_head_dim"]
+    # (the decay and beta read the normed rows whole: kept products)
+    whole, h = h, _at("product", h)
     c = jax.nn.silu(_short_conv(h @ p["qkv"].astype(dtype), p["conv"]))
     q = _l2(c[:, :hk * dk].reshape(n, hk, dk)) * (dk ** -0.5)
     k = _l2(c[:, hk * dk:2 * hk * dk].reshape(n, hk, dk))
     v = c[:, 2 * hk * dk:].reshape(n, hv, dv)
     # key head j serves value heads (hv / hk) j ..
     q, k = jnp.repeat(q, hv // hk, axis=1), jnp.repeat(k, hv // hk, axis=1)
-    ab = h @ p["ab"].astype(dtype)
+    ab = whole @ p["ab"].astype(dtype)
     g = -jnp.exp(p["a_log"].astype(dtype)) \
         * jax.nn.softplus(ab[:, :hv] + p["dt_bias"].astype(dtype))
     beta = jax.nn.sigmoid(ab[:, hv:])
@@ -268,7 +287,7 @@ def _delta(h, p, cfg, eps):
     gate = (h @ p["z"].astype(dtype)).reshape(n, hv, dv)
     o = _norm(o, p["o_norm"], cfg["linear_attn_o_norm_eps"]) \
         * (float(cfg["linear_sigmoid_gate_scale"]) * jax.nn.sigmoid(gate))
-    return o.reshape(n, hv * dv) @ p["wo"].astype(dtype)
+    return _at("product", o.reshape(n, hv * dv)) @ p["wo"].astype(dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -281,7 +300,7 @@ def _swiglu(h, gate_up, down, limit):
     gate, up = gu[:, :inter], gu[:, inter:]
     if limit is not None:
         gate, up = jnp.minimum(gate, limit), jnp.clip(up, -limit, limit)
-    return (jax.nn.silu(gate) * up) @ down.astype(h.dtype)
+    return _at("product", jax.nn.silu(gate) * up) @ down.astype(h.dtype)
 
 
 def _choose(score, top_k, rows, prog_score, margin_share):
@@ -354,6 +373,7 @@ def ffn(h, p, cfg, held, rows=None, program_logits=None, shared=True):
     dtype = h.dtype
     limit = cfg["swiglu_limit"]
     logits = h @ p["router"].astype(dtype)
+    h = _at("product", h)               # (the router read it whole)
     weights, report = route(logits, p["bias"], cfg, rows, program_logits)
     first, count = held
     if p["gate_up"].shape[0] != count:
@@ -383,16 +403,17 @@ def forward(params: dict, token_ids, cfg: dict, rows=None,
         rows = jnp.asarray(rows)
     reports, routers = [], []
     with jax.default_matmul_precision("highest"):
-        x = params["embed"].astype(dtype)[ids]
+        x = _at("residual", params["embed"].astype(dtype)[ids])
         moe_at = -len(params["dense"])
         for p in params["dense"] + params["layers"]:
             h = _norm(x, p["ln1"], eps)
             y = _mla(h, p, cfg, eps) if "kv_a" in p \
                 else _delta(h, p, cfg, eps)
-            x = x + _norm(y, p["ln1_post"], eps)
+            x = _at("residual", x + _norm(y, p["ln1_post"], eps))
             h = _norm(x, p["ln2"], eps)
             if moe_at < 0:
-                y = _swiglu(h, p["gate_up"], p["down"], cfg["swiglu_limit"])
+                y = _swiglu(_at("product", h), p["gate_up"], p["down"],
+                            cfg["swiglu_limit"])
             else:
                 y, logits, report = ffn(
                     h, p, cfg, held, rows,
@@ -403,8 +424,8 @@ def forward(params: dict, token_ids, cfg: dict, rows=None,
                 if report is not None:
                     reports.append(report)
             moe_at += 1
-            x = x + _norm(y, p["ln2_post"], eps)
-        x = _norm(x, params["ln_f"], eps)
+            x = _at("residual", x + _norm(y, p["ln2_post"], eps))
+        x = _at("product", _norm(x, params["ln_f"], eps))
         if rows is not None:
             x = x[rows]
         out = x @ params["head"].astype(dtype)

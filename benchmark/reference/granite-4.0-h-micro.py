@@ -46,6 +46,17 @@ import jax.numpy as jnp
 
 Q_BLOCK = 512
 
+# Roundings that a control puts in (``benchmark/tests/standins.py``): the
+# plain reference leaves ``ROUND`` None, every ``_at`` is then the identity
+# and adds nothing to the lowered module.  ``where`` is "residual" (the
+# stream after a sublayer), "pages" (K and V as a program writes them to
+# its pages) or "product" (an activation that enters a product).
+ROUND = None
+
+
+def _at(where, x):
+    return x if ROUND is None else ROUND(where, x)
+
 
 def layer_kinds(cfg: dict) -> list:
     return list(cfg["layer_types"][:cfg["num_hidden_layers"]])
@@ -84,13 +95,15 @@ def _rms_norm(x, w, eps):
 def _attention(q, k, v, scale):
     """q, k, v [H, n, d], causal, in blocks of queries."""
     n = q.shape[1]
+    q, k, v = _at("product", q), _at("pages", k), _at("pages", v)
     j = jnp.arange(n)[None, :]
     out = []
     for start in range(0, n, Q_BLOCK):
         i = jnp.arange(start, min(start + Q_BLOCK, n))[:, None]
         s = jnp.einsum("hqd,hkd->hqk", q[:, start:start + Q_BLOCK], k) \
             * scale                          # weak: keeps q's precision
-        p = jax.nn.softmax(jnp.where((j <= i)[None], s, -jnp.inf), -1)
+        p = _at("product",
+                jax.nn.softmax(jnp.where((j <= i)[None], s, -jnp.inf), -1))
         out.append(jnp.einsum("hqk,hkd->hqd", p, v))
     return jnp.concatenate(out, axis=1)
 
@@ -120,7 +133,8 @@ def selective_scan(x, dt, a, bm, cm):
 def _swiglu(h, gate_up, down):
     inter = down.shape[0]
     gu = h @ gate_up.astype(h.dtype)
-    return (jax.nn.silu(gu[:, :inter]) * gu[:, inter:]) @ down.astype(h.dtype)
+    return _at("product", jax.nn.silu(gu[:, :inter]) * gu[:, inter:]) \
+        @ down.astype(h.dtype)
 
 
 def _mamba(h, p, cfg, eps):
@@ -139,7 +153,7 @@ def _mamba(h, p, cfg, eps):
     y = selective_scan(x, dt, a, bm, cm) \
         + p["d"].astype(dtype)[:, None] * x
     y = _rms_norm(y.reshape(n, inner) * jax.nn.silu(z), p["y_norm"], eps)
-    return y @ p["wo"].astype(dtype)
+    return _at("product", y) @ p["wo"].astype(dtype)
 
 
 def _full_attention(h, p, cfg, eps):
@@ -158,7 +172,8 @@ def _full_attention(h, p, cfg, eps):
     # query head g reads KV head g // (heads / kv)
     k, v = (jnp.repeat(t, heads // kv, axis=0) for t in (k, v))
     y = _attention(q, k, v, cfg["attention_multiplier"])
-    return y.transpose(1, 0, 2).reshape(n, heads * d) @ p["wo"].astype(dtype)
+    return _at("product", y.transpose(1, 0, 2).reshape(n, heads * d)) \
+        @ p["wo"].astype(dtype)
 
 
 def forward(params: dict, token_ids, cfg: dict, rows=None,
@@ -170,13 +185,15 @@ def forward(params: dict, token_ids, cfg: dict, rows=None,
     ids = jnp.asarray(token_ids, jnp.int32)
     with jax.default_matmul_precision("highest"):
         table = params["embed"].astype(dtype)
-        x = table[ids] * cfg["embedding_multiplier"]
+        x = _at("residual", table[ids] * cfg["embedding_multiplier"])
         for p, kind in zip(params["layers"], layer_kinds(cfg)):
             mixer = _mamba if kind == "mamba" else _full_attention
-            x = x + res * mixer(_rms_norm(x, p["ln1"], eps), p, cfg, eps)
-            x = x + res * _swiglu(_rms_norm(x, p["ln2"], eps), p["gate_up"],
-                                  p["down"])
-        x = _rms_norm(x, params["ln_f"], eps)
+            x = _at("residual", x + res * mixer(
+                _at("product", _rms_norm(x, p["ln1"], eps)), p, cfg, eps))
+            x = _at("residual", x + res * _swiglu(
+                _at("product", _rms_norm(x, p["ln2"], eps)), p["gate_up"],
+                p["down"]))
+        x = _at("product", _rms_norm(x, params["ln_f"], eps))
         if rows is not None:
             x = x[jnp.asarray(rows)]
         return (x @ table.T) / cfg["logits_scaling"]

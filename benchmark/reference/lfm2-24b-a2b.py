@@ -57,6 +57,17 @@ def layer_kinds(cfg: dict) -> list:
             for i, kind in enumerate(
                 cfg["layer_types"][:cfg["num_hidden_layers"]])]
 
+# Roundings that a control puts in (``benchmark/tests/standins.py``): the
+# plain reference leaves ``ROUND`` None, every ``_at`` is then the identity
+# and adds nothing to the lowered module.  ``where`` is "residual" (the
+# stream after a sublayer), "pages" (K and V as a program writes them to
+# its pages) or "product" (an activation that enters a product).
+ROUND = None
+
+
+def _at(where, x):
+    return x if ROUND is None else ROUND(where, x)
+
 
 def params_from_scope(scope, cfg: dict, name: str = "llama") -> dict:
     """The program's weights, by the names ``models/llama.py`` gives them,
@@ -109,6 +120,7 @@ def _attention(q, k, v):
     head g // (H // Hkv).  In blocks of queries."""
     heads, n, d = q.shape
     rep = heads // k.shape[0]
+    q, k, v = _at("product", q), _at("pages", k), _at("pages", v)
     k, v = jnp.repeat(k, rep, axis=0), jnp.repeat(v, rep, axis=0)
     j = jnp.arange(n)[None, :]
     out = []
@@ -116,7 +128,8 @@ def _attention(q, k, v):
         i = jnp.arange(start, min(start + Q_BLOCK, n))[:, None]
         s = jnp.einsum("hqd,hkd->hqk", q[:, start:start + Q_BLOCK], k) \
             / float(np.sqrt(d))              # weak: keeps q's precision
-        p = jax.nn.softmax(jnp.where((j <= i)[None], s, -jnp.inf), -1)
+        p = _at("product",
+                jax.nn.softmax(jnp.where((j <= i)[None], s, -jnp.inf), -1))
         out.append(jnp.einsum("hqk,hkd->hqd", p, v))
     return jnp.concatenate(out, axis=1)
 
@@ -161,7 +174,8 @@ def _choose(score, top_k, rows, prog_score, margin_share):
 def _swiglu(h, gate_up, down):
     inter = down.shape[0]
     gu = h @ gate_up.astype(h.dtype)
-    return (jax.nn.silu(gu[:, :inter]) * gu[:, inter:]) @ down.astype(h.dtype)
+    return _at("product", jax.nn.silu(gu[:, :inter]) * gu[:, inter:]) \
+        @ down.astype(h.dtype)
 
 
 def _experts(h, weights, gate_up, down):
@@ -213,14 +227,15 @@ def forward(params: dict, token_ids, cfg: dict, rows=None,
     reports, routers = [], []
     moe = 0
     with jax.default_matmul_precision("highest"):
-        x = params["embed"].astype(dtype)[ids]
+        x = _at("residual", params["embed"].astype(dtype)[ids])
         for p, (kind, dense) in zip(params["layers"], layer_kinds(cfg)):
-            h = _rms_norm(x, p["ln1"], eps)
+            h = _at("product", _rms_norm(x, p["ln1"], eps))
             if kind == "conv":
                 bcu = h @ p["w_in"].astype(dtype)
                 z = bcu[:, :hidden] * bcu[:, 2 * hidden:]
                 c = _short_conv(z, p["conv"])
-                y = (bcu[:, hidden:2 * hidden] * c) @ p["w_out"].astype(dtype)
+                y = _at("product", bcu[:, hidden:2 * hidden] * c) \
+                    @ p["w_out"].astype(dtype)
             else:
                 qkv = h @ p["qkv"].astype(dtype)
                 q = qkv[:, :heads * d].reshape(n, heads, d).transpose(1, 0, 2)
@@ -230,14 +245,16 @@ def forward(params: dict, token_ids, cfg: dict, rows=None,
                     .transpose(1, 0, 2)
                 q = _rope(_rms_norm(q, p["q_norm"], eps), theta)
                 k = _rope(_rms_norm(k, p["k_norm"], eps), theta)
-                y = _attention(q, k, v).transpose(1, 0, 2) \
-                    .reshape(n, heads * d) @ p["wo"].astype(dtype)
-            x = x + y
+                y = _at("product", _attention(q, k, v).transpose(1, 0, 2)
+                        .reshape(n, heads * d)) @ p["wo"].astype(dtype)
+            x = _at("residual", x + y)
             g = _rms_norm(x, p["ln2"], eps)
             if dense:
-                x = x + _swiglu(g, p["gate_up"], p["down"])
+                x = _at("residual", x + _swiglu(_at("product", g),
+                                                p["gate_up"], p["down"]))
                 continue
             logits = g @ p["router"].astype(dtype)
+            g = _at("product", g)       # (the router read it whole)
             if keep_router:
                 routers.append(logits[rows])
             weights, report = route(
@@ -246,8 +263,9 @@ def forward(params: dict, token_ids, cfg: dict, rows=None,
             moe += 1
             if report is not None:
                 reports.append(report)
-            x = x + _experts(g, weights, p["gate_up"], p["down"])
-        x = _rms_norm(x, params["ln_f"], eps)
+            x = _at("residual",
+                    x + _experts(g, weights, p["gate_up"], p["down"]))
+        x = _at("product", _rms_norm(x, params["ln_f"], eps))
         if rows is not None:
             x = x[rows]
         out = x @ params["embed"].astype(dtype).T

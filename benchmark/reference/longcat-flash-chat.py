@@ -81,6 +81,17 @@ def real_experts(cfg: dict) -> int:
     return int(cfg["expert_share"]["router_experts"]) \
         - int(cfg["zero_expert_num"])
 
+# Roundings that a control puts in (``benchmark/tests/standins.py``): the
+# plain reference leaves ``ROUND`` None, every ``_at`` is then the identity
+# and adds nothing to the lowered module.  ``where`` is "residual" (the
+# stream after a sublayer), "pages" (K and V as a program writes them to
+# its pages) or "product" (an activation that enters a product).
+ROUND = None
+
+
+def _at(where, x):
+    return x if ROUND is None else ROUND(where, x)
+
 
 def params_from_scope(scope, cfg: dict, name: str = "llama") -> dict:
     """The program's weights, by the names ``models/llama.py`` gives them,
@@ -170,21 +181,25 @@ def mla(h, p, cfg, cos, sin):
     n, heads = h.shape[0], p["wo"].shape[0] // dv
     q_scale, kv_scale = lora_scales(cfg)
     dtype = h.dtype
-    c_q = _norm(h @ p["q_a"].astype(dtype), p["q_a_norm"], eps) * q_scale
-    q = (c_q @ p["q_b"].astype(dtype)).reshape(n, heads, dn + dr)
-    q_rope = _rotate_pairs(q[..., dn:], cos, sin)
+    h = _at("product", h)
+    c_q = _at("product", _norm(h @ p["q_a"].astype(dtype), p["q_a_norm"],
+                               eps) * q_scale)
+    q = _at("product",
+            (c_q @ p["q_b"].astype(dtype)).reshape(n, heads, dn + dr))
+    q_rope = _at("product", _rotate_pairs(q[..., dn:], cos, sin))
     kv_a = h @ p["kv_a"].astype(dtype)
-    c_kv = _norm(kv_a[:, :c], p["kv_a_norm"], eps) * kv_scale
-    k_r = _rotate_pairs(kv_a[:, c:], cos, sin)                # [n, dr]
-    kv = (c_kv @ p["kv_b"].astype(dtype)).reshape(n, heads, dn + dv)
+    # the latent row as a program writes it to its pages: [c_kv | k_r]
+    c_kv = _at("pages", _norm(kv_a[:, :c], p["kv_a_norm"], eps) * kv_scale)
+    k_r = _at("pages", _rotate_pairs(kv_a[:, c:], cos, sin))  # [n, dr]
+    kv = _at("product",
+             (c_kv @ p["kv_b"].astype(dtype)).reshape(n, heads, dn + dv))
     s = (jnp.einsum("qhd,khd->hqk", q[..., :dn], kv[..., :dn])
          + jnp.einsum("qhd,kd->hqk", q_rope, k_r)) \
         * (dn + dr) ** -0.5                  # weak: keeps q's precision
     keep = jnp.arange(n)[None, :] <= jnp.arange(n)[:, None]
-    a = jnp.einsum("hqk,khd->qhd",
-                   jax.nn.softmax(jnp.where(keep[None], s, -jnp.inf), -1),
-                   kv[..., dn:])
-    return a.reshape(n, heads * dv) @ p["wo"].astype(dtype)
+    a = jnp.einsum("hqk,khd->qhd", _at("product", jax.nn.softmax(
+        jnp.where(keep[None], s, -jnp.inf), -1)), kv[..., dn:])
+    return _at("product", a.reshape(n, heads * dv)) @ p["wo"].astype(dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -195,7 +210,7 @@ def swiglu(h, gate_up, down):
     """W_2(silu(W_1 h) * (W_3 h)) with gate | up side by side."""
     width = down.shape[0]
     gu = h @ gate_up.astype(h.dtype)
-    return (jax.nn.silu(gu[:, :width]) * gu[:, width:]) \
+    return _at("product", jax.nn.silu(gu[:, :width]) * gu[:, width:]) \
         @ down.astype(h.dtype)
 
 
@@ -273,6 +288,7 @@ def moe(u, p, cfg, held, rows=None, program_logits=None, identity=True):
     False leaves the identity picks' term out (the shares of a layer count
     it once)."""
     logits = u @ p["router"].astype(u.dtype)
+    u = _at("product", u)               # (the router read it whole)
     weights, report = route(logits, p["bias"], cfg, rows, program_logits)
     first, count = held
     if p["gate_up"].shape[0] != count or first + count > real_experts(cfg):
@@ -295,18 +311,21 @@ def layer(x, p, cfg, cos, sin, held, rows=None, program_logits=None,
         raise ValueError(f"join is one of {JOINS}")
     eps = cfg["rms_norm_eps"]
     first, second = p["sub"]
-    a = x + mla(_norm(x, first["ln_in"], eps), first, cfg, cos, sin)
+    a = _at("residual",
+            x + mla(_norm(x, first["ln_in"], eps), first, cfg, cos, sin))
     u = _norm(a, first["ln_post"], eps)
     s, logits, report = moe(u, p, cfg, held, rows, program_logits)
-    b = a + swiglu(u, first["gate_up"], first["down"])
+    b = a + swiglu(_at("product", u), first["gate_up"], first["down"])
     if join == "before_second_sublayer":
         b = b + s
-    c = b + mla(_norm(b, second["ln_in"], eps), second, cfg, cos, sin)
-    y = c + swiglu(_norm(c, second["ln_post"], eps), second["gate_up"],
-                   second["down"])
+    b = _at("residual", b)
+    c = _at("residual",
+            b + mla(_norm(b, second["ln_in"], eps), second, cfg, cos, sin))
+    y = c + swiglu(_at("product", _norm(c, second["ln_post"], eps)),
+                   second["gate_up"], second["down"])
     if join == "after_second_ffn":
         y = y + s
-    return y, logits, report
+    return _at("residual", y), logits, report
 
 
 def forward(params: dict, token_ids, cfg: dict, rows=None,
@@ -326,7 +345,7 @@ def forward(params: dict, token_ids, cfg: dict, rows=None,
         rows = jnp.asarray(rows)
     reports, routers = [], []
     with jax.default_matmul_precision("highest"):
-        x = params["embed"].astype(dtype)[ids]
+        x = _at("residual", params["embed"].astype(dtype)[ids])
         cos, sin = _tables(cfg, ids.shape[0], dtype)
         for i, p in enumerate(params["layers"]):
             x, logits, report = layer(
@@ -337,7 +356,7 @@ def forward(params: dict, token_ids, cfg: dict, rows=None,
                 routers.append(logits[rows])
             if report is not None:
                 reports.append(report)
-        x = _norm(x, params["ln_f"], cfg["rms_norm_eps"])
+        x = _at("product", _norm(x, params["ln_f"], cfg["rms_norm_eps"]))
         if rows is not None:
             x = x[rows]
         out = x @ params["head"].astype(dtype)

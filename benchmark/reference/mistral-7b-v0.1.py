@@ -17,6 +17,17 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+# Roundings that a control puts in (``benchmark/tests/standins.py``): the
+# plain reference leaves ``ROUND`` None, every ``_at`` is then the identity
+# and adds nothing to the lowered module.  ``where`` is "residual" (the
+# stream after a sublayer), "pages" (K and V as a program writes them to
+# its pages) or "product" (an activation that enters a product).
+ROUND = None
+
+
+def _at(where, x):
+    return x if ROUND is None else ROUND(where, x)
+
 
 def params_from_scope(scope, cfg: dict, name: str = "llama") -> dict:
     """The program's weights, by the names ``models/llama.py`` gives them.
@@ -75,25 +86,29 @@ def forward(params: dict, token_ids, cfg: dict, rows=None):
     ids = jnp.asarray(token_ids, jnp.int32)
     n = ids.shape[0]
     with jax.default_matmul_precision("highest"):
-        x = params["embed"][ids]
+        x = _at("residual", params["embed"][ids])
         mask = jnp.tril(jnp.ones((n, n), bool))
         for p in params["layers"]:
-            h = _rms_norm(x, p["ln1"], eps)
+            h = _at("product", _rms_norm(x, p["ln1"], eps))
             q = (h @ p["wq"]).reshape(n, heads, d).transpose(1, 0, 2)
             k = (h @ p["wk"]).reshape(n, kv, d).transpose(1, 0, 2)
             v = (h @ p["wv"]).reshape(n, kv, d).transpose(1, 0, 2)
-            q, k = _rope(q, theta), _rope(k, theta)
+            q, k = _at("product", _rope(q, theta)), _rope(k, theta)
+            k, v = _at("pages", k), _at("pages", v)
             # query head g reads KV head g // (heads // kv)
             k = jnp.repeat(k, heads // kv, axis=0)
             v = jnp.repeat(v, heads // kv, axis=0)
             s = jnp.einsum("hqd,hkd->hqk", q, k) / np.sqrt(d)
             s = jnp.where(mask[None], s, -jnp.inf)
-            a = jnp.einsum("hqk,hkd->hqd", jax.nn.softmax(s, -1), v)
-            x = x + a.transpose(1, 0, 2).reshape(n, heads * d) @ p["wo"]
-            h = _rms_norm(x, p["ln2"], eps)
-            x = x + (jax.nn.silu(h @ p["w_gate"]) * (h @ p["w_up"])) \
-                @ p["w_down"]
-        x = _rms_norm(x, params["ln_f"], eps)
+            a = jnp.einsum("hqk,hkd->hqd",
+                           _at("product", jax.nn.softmax(s, -1)), v)
+            a = _at("product", a.transpose(1, 0, 2).reshape(n, heads * d))
+            x = _at("residual", x + a @ p["wo"])
+            h = _at("product", _rms_norm(x, p["ln2"], eps))
+            x = _at("residual", x + _at(
+                "product", jax.nn.silu(h @ p["w_gate"]) * (h @ p["w_up"]))
+                @ p["w_down"])
+        x = _at("product", _rms_norm(x, params["ln_f"], eps))
         if rows is not None:
             x = x[jnp.asarray(rows)]
         return x @ params["head"]

@@ -75,6 +75,17 @@ def held_range(cfg: dict) -> tuple:
     ``cfg["expert_share"]["router_experts"]``."""
     return int(cfg["expert_share"]["first"]), int(cfg["n_routed_experts"])
 
+# Roundings that a control puts in (``benchmark/tests/standins.py``): the
+# plain reference leaves ``ROUND`` None, every ``_at`` is then the identity
+# and adds nothing to the lowered module.  ``where`` is "residual" (the
+# stream after a sublayer), "pages" (K and V as a program writes them to
+# its pages) or "product" (an activation that enters a product).
+ROUND = None
+
+
+def _at(where, x):
+    return x if ROUND is None else ROUND(where, x)
+
 
 def params_from_scope(scope, cfg: dict, name: str = "llama") -> dict:
     """The program's weights, by the names ``models/llama.py`` gives them,
@@ -118,13 +129,15 @@ def _rms_norm(x, w, eps, group=None):
 def _attention(q, k, v, scale):
     """q, k, v [H, n, d], causal, in blocks of queries."""
     n = q.shape[1]
+    q, k, v = _at("product", q), _at("pages", k), _at("pages", v)
     j = jnp.arange(n)[None, :]
     out = []
     for start in range(0, n, Q_BLOCK):
         i = jnp.arange(start, min(start + Q_BLOCK, n))[:, None]
         s = jnp.einsum("hqd,hkd->hqk", q[:, start:start + Q_BLOCK], k) \
             * scale                          # weak: keeps q's precision
-        p = jax.nn.softmax(jnp.where((j <= i)[None], s, -jnp.inf), -1)
+        p = _at("product",
+                jax.nn.softmax(jnp.where((j <= i)[None], s, -jnp.inf), -1))
         out.append(jnp.einsum("hqk,hkd->hqd", p, v))
     return jnp.concatenate(out, axis=1)
 
@@ -161,7 +174,7 @@ def _mamba(h, p, cfg, eps):
     heads, hp = cfg["mamba_num_heads"], cfg["mamba_head_dim"]
     state, groups = cfg["ssm_state_size"], cfg["n_groups"]
     inner = heads * hp
-    zxd = h @ p["w_in"].astype(dtype)
+    zxd = _at("product", h) @ p["w_in"].astype(dtype)
     z, dt = zxd[:, :inner], zxd[:, -heads:]
     xbc = jax.nn.silu(_short_conv(zxd[:, inner:-heads], p["conv"],
                                   p["conv_b"]))
@@ -174,7 +187,7 @@ def _mamba(h, p, cfg, eps):
         + p["d"].astype(dtype)[:, None] * x
     y = _rms_norm(y.reshape(n, inner) * jax.nn.silu(z), p["y_norm"], eps,
                   group=inner // groups)
-    return y @ p["wo"].astype(dtype)
+    return _at("product", y) @ p["wo"].astype(dtype)
 
 
 def _full_attention(h, p, cfg):
@@ -182,7 +195,7 @@ def _full_attention(h, p, cfg):
     n = h.shape[0]
     heads, kv = cfg["num_attention_heads"], cfg["num_key_value_heads"]
     d = cfg["head_dim"]
-    qkv = h @ p["qkv"].astype(dtype)
+    qkv = _at("product", h) @ p["qkv"].astype(dtype)
 
     def split(t, m):
         return t.reshape(n, m, d).transpose(1, 0, 2)
@@ -193,11 +206,12 @@ def _full_attention(h, p, cfg):
     # query head g reads KV head g // (heads / kv)
     k, v = (jnp.repeat(t, heads // kv, axis=0) for t in (k, v))
     y = _attention(q, k, v, d ** -0.5)
-    return y.transpose(1, 0, 2).reshape(n, heads * d) @ p["wo"].astype(dtype)
+    return _at("product", y.transpose(1, 0, 2).reshape(n, heads * d)) \
+        @ p["wo"].astype(dtype)
 
 
 def _relu2_mlp(h, up, down):
-    return jnp.square(jax.nn.relu(h @ up.astype(h.dtype))) \
+    return _at("product", jnp.square(jax.nn.relu(h @ up.astype(h.dtype)))) \
         @ down.astype(h.dtype)
 
 
@@ -270,14 +284,15 @@ def latent_moe(h, p, cfg, held, rows=None, program_logits=None,
     leaves the shared expert out (the shares of a layer count it once)."""
     dtype = h.dtype
     logits = h @ p["router"].astype(dtype)
+    h = _at("product", h)               # (the router read it whole)
     weights, report = route(logits, p["bias"], cfg, rows, program_logits)
     first, count = held
     if p["up"].shape[0] != count:
         raise ValueError(f"{p['up'].shape[0]} expert matrices for a share "
                          f"of {count}")
-    u = h @ p["lat_down"].astype(dtype)
+    u = _at("product", h @ p["lat_down"].astype(dtype))
     r = held_experts(u, weights, p["up"], p["down"], first)
-    y = r @ p["lat_up"].astype(dtype)
+    y = _at("product", r) @ p["lat_up"].astype(dtype)
     if shared and cfg["n_shared_experts"]:
         y = y + _relu2_mlp(h, p["shared_up"], p["shared_down"])
     return y, logits, report
@@ -301,7 +316,7 @@ def forward(params: dict, token_ids, cfg: dict, rows=None,
         rows = jnp.asarray(rows)
     reports, routers = [], []
     with jax.default_matmul_precision("highest"):
-        x = params["embed"].astype(dtype)[ids]
+        x = _at("residual", params["embed"].astype(dtype)[ids])
         for p, kind in zip(params["blocks"], layer_kinds(cfg)):
             h = _rms_norm(x, p["ln"], eps)
             if kind == "M":
@@ -317,8 +332,8 @@ def forward(params: dict, token_ids, cfg: dict, rows=None,
                     routers.append(logits[rows])
                 if report is not None:
                     reports.append(report)
-            x = x + y
-        x = _rms_norm(x, params["ln_f"], eps)
+            x = _at("residual", x + y)
+        x = _at("product", _rms_norm(x, params["ln_f"], eps))
         if rows is not None:
             x = x[rows]
         out = x @ params["head"].astype(dtype)

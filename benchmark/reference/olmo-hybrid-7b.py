@@ -49,6 +49,17 @@ Q_BLOCK = 512
 def layer_kinds(cfg: dict) -> list:
     return list(cfg["layer_types"][:cfg["num_hidden_layers"]])
 
+# Roundings that a control puts in (``benchmark/tests/standins.py``): the
+# plain reference leaves ``ROUND`` None, every ``_at`` is then the identity
+# and adds nothing to the lowered module.  ``where`` is "residual" (the
+# stream after a sublayer), "pages" (K and V as a program writes them to
+# its pages) or "product" (an activation that enters a product).
+ROUND = None
+
+
+def _at(where, x):
+    return x if ROUND is None else ROUND(where, x)
+
 
 def params_from_scope(scope, cfg: dict, name: str = "llama") -> dict:
     """The program's weights, by the names ``models/llama.py`` gives them,
@@ -87,13 +98,15 @@ def _rms_norm(x, w, eps):
 def _attention(q, k, v):
     """q, k, v [H, n, d], causal, in blocks of queries."""
     _, n, d = q.shape
+    q, k, v = _at("product", q), _at("pages", k), _at("pages", v)
     j = jnp.arange(n)[None, :]
     out = []
     for start in range(0, n, Q_BLOCK):
         i = jnp.arange(start, min(start + Q_BLOCK, n))[:, None]
         s = jnp.einsum("hqd,hkd->hqk", q[:, start:start + Q_BLOCK], k) \
             / float(np.sqrt(d))              # weak: keeps q's precision
-        p = jax.nn.softmax(jnp.where((j <= i)[None], s, -jnp.inf), -1)
+        p = _at("product",
+                jax.nn.softmax(jnp.where((j <= i)[None], s, -jnp.inf), -1))
         out.append(jnp.einsum("hqk,hkd->hqd", p, v))
     return jnp.concatenate(out, axis=1)
 
@@ -127,7 +140,8 @@ def delta_rule(q, k, v, g, beta):
 def _swiglu(h, gate_up, down):
     inter = down.shape[0]
     gu = h @ gate_up.astype(h.dtype)
-    return (jax.nn.silu(gu[:, :inter]) * gu[:, inter:]) @ down.astype(h.dtype)
+    return _at("product", jax.nn.silu(gu[:, :inter]) * gu[:, inter:]) \
+        @ down.astype(h.dtype)
 
 
 def _linear_attention(x, p, cfg, eps):
@@ -148,7 +162,7 @@ def _linear_attention(x, p, cfg, eps):
     o = delta_rule(q, k, v, g, beta)
     gate = (x @ p["gate"].astype(dtype)).reshape(n, heads, dv)
     o = _rms_norm(o, p["o_norm"], eps) * jax.nn.silu(gate)
-    return o.reshape(n, heads * dv) @ p["wo"].astype(dtype)
+    return _at("product", o.reshape(n, heads * dv)) @ p["wo"].astype(dtype)
 
 
 def _full_attention(x, p, cfg, eps):
@@ -164,7 +178,8 @@ def _full_attention(x, p, cfg, eps):
         return t.reshape(n, heads, d).transpose(1, 0, 2)
 
     y = _attention(split(q), split(k), split(qkv[:, 2 * hidden:]))
-    return y.transpose(1, 0, 2).reshape(n, hidden) @ p["wo"].astype(dtype)
+    return _at("product", y.transpose(1, 0, 2).reshape(n, hidden)) \
+        @ p["wo"].astype(dtype)
 
 
 def forward(params: dict, token_ids, cfg: dict, rows=None,
@@ -175,14 +190,16 @@ def forward(params: dict, token_ids, cfg: dict, rows=None,
     eps = cfg["rms_norm_eps"]
     ids = jnp.asarray(token_ids, jnp.int32)
     with jax.default_matmul_precision("highest"):
-        x = params["embed"].astype(dtype)[ids]
+        x = _at("residual", params["embed"].astype(dtype)[ids])
         for p, kind in zip(params["layers"], layer_kinds(cfg)):
             mixer = _linear_attention if kind == "linear_attention" \
                 else _full_attention
-            x = x + _rms_norm(mixer(x, p, cfg, eps), p["ln1"], eps)
-            x = x + _rms_norm(_swiglu(x, p["gate_up"], p["down"]),
-                              p["ln2"], eps)
-        x = _rms_norm(x, params["ln_f"], eps)
+            x = _at("residual",
+                    x + _rms_norm(mixer(x, p, cfg, eps), p["ln1"], eps))
+            x = _at("residual",
+                    x + _rms_norm(_swiglu(x, p["gate_up"], p["down"]),
+                                  p["ln2"], eps))
+        x = _at("product", _rms_norm(x, params["ln_f"], eps))
         if rows is not None:
             x = x[jnp.asarray(rows)]
         return x @ params["head"].astype(dtype)

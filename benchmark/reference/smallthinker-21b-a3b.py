@@ -36,6 +36,17 @@ import numpy as np
 
 Q_BLOCK = 512
 
+# Roundings that a control puts in (``benchmark/tests/standins.py``): the
+# plain reference leaves ``ROUND`` None, every ``_at`` is then the identity
+# and adds nothing to the lowered module.  ``where`` is "residual" (the
+# stream after a sublayer), "pages" (K and V as a program writes them to
+# its pages) or "product" (an activation that enters a product).
+ROUND = None
+
+
+def _at(where, x):
+    return x if ROUND is None else ROUND(where, x)
+
 
 def params_from_scope(scope, cfg: dict, name: str = "llama") -> dict:
     """The program's weights, by the names ``models/llama.py`` gives them,
@@ -75,6 +86,7 @@ def _attention(q, k, v, window):
     In blocks of queries: a block's scores are [H, Q_BLOCK, n]."""
     heads, n, d = q.shape
     rep = heads // k.shape[0]
+    q, k, v = _at("product", q), _at("pages", k), _at("pages", v)
     k, v = jnp.repeat(k, rep, axis=0), jnp.repeat(v, rep, axis=0)
     j = jnp.arange(n)[None, :]
     out = []
@@ -85,7 +97,8 @@ def _attention(q, k, v, window):
             keep = keep & (j > i - window)
         s = jnp.einsum("hqd,hkd->hqk", q[:, start:start + Q_BLOCK], k) \
             / float(np.sqrt(d))              # weak: keeps q's precision
-        p = jax.nn.softmax(jnp.where(keep[None], s, -jnp.inf), -1)
+        p = _at("product",
+                jax.nn.softmax(jnp.where(keep[None], s, -jnp.inf), -1))
         out.append(jnp.einsum("hqk,hkd->hqd", p, v))
     return jnp.concatenate(out, axis=1)
 
@@ -131,7 +144,7 @@ def _experts(h, chosen, weights, gate_up, down):
 
     def one(e, acc):
         gu = h @ jax.lax.dynamic_index_in_dim(gate_up, e, 0, False)
-        y = (jnp.maximum(gu[:, :inter], 0) * gu[:, inter:]) \
+        y = _at("product", jnp.maximum(gu[:, :inter], 0) * gu[:, inter:]) \
             @ jax.lax.dynamic_index_in_dim(down, e, 0, False)
         w = jnp.where(chosen[:, e], weights[:, e], 0.0)
         return acc + w[:, None] * y
@@ -169,7 +182,7 @@ def forward(params: dict, token_ids, cfg: dict, rows=None,
                                         jnp.float32), ids, rows[0])
     routers = []
     with jax.default_matmul_precision("highest"):
-        x = params["embed"][ids]
+        x = _at("residual", params["embed"][ids])
         for i, p in enumerate(params["layers"]):
             window = cfg["sliding_window_size"] \
                 if cfg["sliding_window_layout"][i] else None
@@ -182,7 +195,7 @@ def forward(params: dict, token_ids, cfg: dict, rows=None,
             # softmax over the chosen logits alone
             weights = jax.nn.softmax(
                 jnp.where(chosen, logits, -jnp.inf), -1)
-            h = _rms_norm(x, p["ln1"], eps)
+            h = _at("product", _rms_norm(x, p["ln1"], eps))
             qkv = h @ p["qkv"]
             q = qkv[:, :heads * d].reshape(n, heads, d).transpose(1, 0, 2)
             k = qkv[:, heads * d:(heads + kv) * d] \
@@ -192,10 +205,12 @@ def forward(params: dict, token_ids, cfg: dict, rows=None,
             if cfg["rope_layout"][i]:
                 q, k = _rope(q, theta), _rope(k, theta)
             a = _attention(q, k, v, window)
-            x = x + a.transpose(1, 0, 2).reshape(n, heads * d) @ p["wo"]
-            h = _rms_norm(x, p["ln2"], eps)
-            x = x + _experts(h, chosen, weights, p["gate_up"], p["down"])
-        x = _rms_norm(x, params["ln_f"], eps)
+            a = _at("product", a.transpose(1, 0, 2).reshape(n, heads * d))
+            x = _at("residual", x + a @ p["wo"])
+            h = _at("product", _rms_norm(x, p["ln2"], eps))
+            x = _at("residual", x + _experts(h, chosen, weights,
+                                             p["gate_up"], p["down"]))
+        x = _at("product", _rms_norm(x, params["ln_f"], eps))
         if rows is not None:
             x = x[jnp.asarray(rows)]
         out = x @ params["head"]

@@ -78,6 +78,17 @@ def held_range(cfg: dict) -> tuple:
     ``cfg["expert_share"]["router_experts"]``."""
     return int(cfg["expert_share"]["first"]), int(cfg["n_routed_experts"])
 
+# Roundings that a control puts in (``benchmark/tests/standins.py``): the
+# plain reference leaves ``ROUND`` None, every ``_at`` is then the identity
+# and adds nothing to the lowered module.  ``where`` is "residual" (the
+# stream after a sublayer), "pages" (K and V as a program writes them to
+# its pages) or "product" (an activation that enters a product).
+ROUND = None
+
+
+def _at(where, x):
+    return x if ROUND is None else ROUND(where, x)
+
 
 def params_from_scope(scope, cfg: dict, name: str = "llama") -> dict:
     """The program's weights, by the names ``models/llama.py`` gives them,
@@ -121,6 +132,7 @@ def _attention(q, k, v):
     head j // (H // Hkv).  In blocks of queries."""
     heads, n, d = q.shape
     rep = heads // k.shape[0]
+    q, k, v = _at("product", q), _at("pages", k), _at("pages", v)
     k, v = jnp.repeat(k, rep, axis=0), jnp.repeat(v, rep, axis=0)
     j = jnp.arange(n)[None, :]
     out = []
@@ -128,7 +140,8 @@ def _attention(q, k, v):
         i = jnp.arange(start, min(start + Q_BLOCK, n))[:, None]
         s = jnp.einsum("hqd,hkd->hqk", q[:, start:start + Q_BLOCK], k) \
             / float(np.sqrt(d))              # weak: keeps q's precision
-        p = jax.nn.softmax(jnp.where((j <= i)[None], s, -jnp.inf), -1)
+        p = _at("product",
+                jax.nn.softmax(jnp.where((j <= i)[None], s, -jnp.inf), -1))
         out.append(jnp.einsum("hqk,hkd->hqd", p, v))
     return jnp.concatenate(out, axis=1)
 
@@ -163,7 +176,8 @@ def delta_rule(q, k, v, g, beta):
 def _swiglu(h, gate_up, down):
     inter = down.shape[0]
     gu = h @ gate_up.astype(h.dtype)
-    return (jax.nn.silu(gu[:, :inter]) * gu[:, inter:]) @ down.astype(h.dtype)
+    return _at("product", jax.nn.silu(gu[:, :inter]) * gu[:, inter:]) \
+        @ down.astype(h.dtype)
 
 
 def _kda(h, p, cfg, eps):
@@ -171,22 +185,24 @@ def _kda(h, p, cfg, eps):
     n = h.shape[0]
     lin = cfg["linear_attn_config"]
     heads, d = lin["num_heads"], lin["head_dim"]
+    # (beta and the decay read the normed rows whole: kept products)
+    whole, h = h, _at("product", h)
     c = jax.nn.silu(_short_conv(h @ p["qkv"].astype(dtype), p["conv"]))
     q = _l2(c[:, :heads * d].reshape(n, heads, d)) * (d ** -0.5)
     k = _l2(c[:, heads * d:2 * heads * d].reshape(n, heads, d))
     v = c[:, 2 * heads * d:].reshape(n, heads, d)
-    beta = jax.nn.sigmoid(h @ p["wb"].astype(dtype))
+    beta = jax.nn.sigmoid(whole @ p["wb"].astype(dtype))
     if cfg["kda_allow_neg_eigval"]:
         beta = beta * 2.0
-    f = (h @ p["f_down"].astype(dtype)) @ p["f_up"].astype(dtype)
+    f = (whole @ p["f_down"].astype(dtype)) @ p["f_up"].astype(dtype)
     g = -jnp.exp(p["a_log"].astype(dtype))[None, :, None] \
         * jax.nn.softplus(f + p["dt_bias"].astype(dtype)) \
         .reshape(n, heads, d)
     o = delta_rule(q, k, v, g, beta)
-    gate = ((h @ p["g_down"].astype(dtype)) @ p["g_up"].astype(dtype)) \
-        .reshape(n, heads, d)
+    gate = (_at("product", h @ p["g_down"].astype(dtype))
+            @ p["g_up"].astype(dtype)).reshape(n, heads, d)
     o = _rms_norm(o, p["o_norm"], eps) * jax.nn.sigmoid(gate)
-    return o.reshape(n, heads * d) @ p["wo"].astype(dtype)
+    return _at("product", o.reshape(n, heads * d)) @ p["wo"].astype(dtype)
 
 
 def _gated_attention(h, p, cfg):
@@ -194,6 +210,7 @@ def _gated_attention(h, p, cfg):
     n = h.shape[0]
     heads, kv, d = cfg["num_attention_heads"], cfg["num_key_value_heads"], \
         cfg["head_dim"]
+    h = _at("product", h)
     qkv = h @ p["qkv"].astype(dtype)
 
     def split(t, m):
@@ -205,7 +222,7 @@ def _gated_attention(h, p, cfg):
     a = a.transpose(1, 0, 2).reshape(n, heads * d)
     if cfg["use_gqa_gate"]:
         a = a * jax.nn.sigmoid(h @ p["gate"].astype(dtype))
-    return a @ p["wo"].astype(dtype)
+    return _at("product", a) @ p["wo"].astype(dtype)
 
 
 def _choose(score, top_k, rows, prog_score, margin_share):
@@ -277,6 +294,7 @@ def ffn(h, p, cfg, held, rows=None, program_logits=None, shared=True):
     once)."""
     dtype = h.dtype
     logits = h @ p["router"].astype(dtype)
+    h = _at("product", h)               # (the router read it whole)
     weights, report = route(logits, p["bias"], cfg, rows, program_logits)
     first, count = held
     if p["gate_up"].shape[0] != count:
@@ -305,21 +323,21 @@ def forward(params: dict, token_ids, cfg: dict, rows=None,
         rows = jnp.asarray(rows)
     reports, routers = [], []
     with jax.default_matmul_precision("highest"):
-        x = params["embed"].astype(dtype)[ids]
+        x = _at("residual", params["embed"].astype(dtype)[ids])
         for i, (p, kind) in enumerate(zip(params["layers"],
                                           layer_kinds(cfg))):
             h = _rms_norm(x, p["ln1"], eps)
-            x = x + (_kda(h, p, cfg, eps) if kind == "kda"
-                     else _gated_attention(h, p, cfg))
+            x = _at("residual", x + (_kda(h, p, cfg, eps) if kind == "kda"
+                                     else _gated_attention(h, p, cfg)))
             y, logits, report = ffn(
                 _rms_norm(x, p["ln2"], eps), p, cfg, held, rows,
                 None if program_router is None else program_router[:, i])
-            x = x + y
+            x = _at("residual", x + y)
             if keep_router:
                 routers.append(logits[rows])
             if report is not None:
                 reports.append(report)
-        x = _rms_norm(x, params["ln_f"], eps)
+        x = _at("product", _rms_norm(x, params["ln_f"], eps))
         if rows is not None:
             x = x[rows]
         out = x @ params["head"].astype(dtype)

@@ -20,20 +20,56 @@ from harness import HERE, quantile, seeded_weights, share_over
 CHECK_NEW_TOKENS = 9      # one from the prefill, eight cached decode steps
 
 
+def jitted_forward(ref, cfg):
+    import jax
+
+    return jax.jit(lambda p, ids, rows: ref.forward(p, ids, cfg, rows))
+
+
+def check_request(forward, params, tol, pad, prompt, res, cfg=None):
+    """What decides ``correct`` for one compared request: ``(fine,
+    {"rel": share of the reference's range})``.  ``res`` is the engine's
+    result under ``keep_logits``; ``forward(params, ids, rows)`` the plain
+    reference's, jitted.  A check engine that keeps its router logits
+    leaves them where the reference finds them itself
+    (``builders/smallthinker_engine.py``); a stand-in's result
+    (``tests/standins.py``) brings them in ``res`` and names ``cfg``, and
+    they lie there for this call."""
+    n = len(prompt)
+    got = np.stack(res["logits"])
+    ids = np.zeros((pad,), "int32")
+    seq = list(prompt) + list(res["tokens"])
+    ids[:len(seq)] = seq
+    rows = np.arange(n - 1, n - 1 + CHECK_NEW_TOKENS)
+    offered = cfg is not None and "router_logits" in res
+    if offered:
+        cfg["_program_router"] = {"ids": [int(t) for t in seq],
+                                  "first_row": n - 1,
+                                  "logits": np.stack(res["router_logits"])}
+    try:
+        want = np.asarray(forward(params, ids, rows))
+    finally:
+        if offered:
+            del cfg["_program_router"]
+    rel = float(np.abs(got - want).max() / np.abs(want).max())
+    fine = len(res["tokens"]) == CHECK_NEW_TOKENS \
+        and got.shape == want.shape and bool(np.isfinite(got).all()) \
+        and rel <= tol
+    return bool(fine), {"rel": rel}
+
+
 def reference_check(run, cfg, mix, seed):
     """A check-only engine (two slots, ``keep_logits`` on) makes the
     weights, which are then redrawn from the seed; for one short and one
     long prompt its paged prefill and eight cached decode steps must give the logits of the plain
     reference's full forward over prompt plus generated tokens.  Returns
     ``(ok, scope)``: the timed engine is built on the same weights."""
-    import jax
-
-    tol = run.cell.tolerance
     lens = list(mix["reference_prompts"])
     buckets = sorted({min(b for b in mix["engine"]["prefill_buckets"]
                           if b >= n) for n in lens})
     gen = run.cell.builder().engine(cfg, mix, num_slots=2, keep_logits=True,
                                     buckets=buckets)
+    tol = run.cell.tolerance      # of what that engine ran in
     seeded_weights(gen.scope,
                    [n for n in gen.scope.local_var_names()
                     if n.startswith(gen.name + ".")
@@ -41,24 +77,20 @@ def reference_check(run, cfg, mix, seed):
     ref = run.cell.reference()
     params = ref.params_from_scope(gen.scope, cfg, gen.name)
     pad = -(-(max(lens) + CHECK_NEW_TOKENS) // 128) * 128
-    forward = jax.jit(lambda p, ids, rows: ref.forward(p, ids, cfg, rows))
+    forward = jitted_forward(ref, cfg)
     ok = True
+    readings = run.check = {"tolerance": tol, "rel": {}}
     try:
         gen.warmup()
         for j, n in enumerate(lens):
             prompt = traffic.token_ids(seed, 900000 + j, n,
                                        cfg["vocab_size"])
             res = gen.generate(prompt, CHECK_NEW_TOKENS, timeout=600)
-            got = np.stack(res["logits"])
-            ids = np.zeros((pad,), "int32")
-            seq = prompt + res["tokens"]
-            ids[:len(seq)] = seq
-            rows = np.arange(n - 1, n - 1 + CHECK_NEW_TOKENS)
-            want = np.asarray(forward(params, ids, rows))
-            rel = float(np.abs(got - want).max() / np.abs(want).max())
-            ok = ok and len(res["tokens"]) == CHECK_NEW_TOKENS \
-                and got.shape == want.shape \
-                and bool(np.isfinite(got).all()) and rel <= tol
+            fine, got = check_request(forward, params, tol, pad, prompt,
+                                      res)
+            ok, rel = ok and fine, got["rel"]
+            # (a line is JSON: logits that are not finite read null)
+            readings["rel"][str(n)] = rel if np.isfinite(rel) else None
             run.say(f"reference check: prompt {n}, paged prefill + "
                     f"{CHECK_NEW_TOKENS - 1} cached decode steps off the "
                     f"float32 reference's full forward by {rel:.4g} of "
@@ -385,6 +417,9 @@ def run_cell(run) -> int:
                        s.name == "generation/decode_step" for s in spans)},
     }
     e2e = dict(sm, setup_s=setup_s)
+    # (beside the set-up check's readings: answers of the window that
+    # came whole but not as the summary line said; limit 0)
+    run.check = dict(run.check or {}, window_answers_wrong=len(wrong))
     return run.finish(correct=served.correct and not wrong,
                       attempted=attempted, failed=failed, end_to_end=e2e,
                       ctx=ctx)

@@ -31,6 +31,8 @@ from __future__ import annotations
 
 import numpy as np
 
+import harness
+
 import serve
 import traffic
 from harness import seeded_weights
@@ -75,7 +77,7 @@ def check_plan(cfg, mix, seed):
 
 
 def check_request(forward, params, B, tol, pad, prompt, n_new, res,
-                  compared=True):
+                  compared=True, router_tol=None):
     """What decides ``correct`` for one request.  ``res`` is the engine's
     result under ``keep_logits``: ``tokens``, ``finish`` and ``passes``,
     each pass's ``base``, ``tokens`` / ``masked`` [B] (the block as the
@@ -84,7 +86,9 @@ def check_request(forward, params, B, tol, pad, prompt, n_new, res,
     pass, where the engine says); ``router_logits`` of the result itself
     are the prefill's, ``[[L, bucket, E]]`` (where the engine says).
     ``forward(params, ids, masked, rows, program_router, router_covers)``
-    is the plain reference's, jitted.  Returns ``(fine, readings)``."""
+    is the plain reference's, jitted.  ``router_tol``: how far the
+    program's router logits may lie off the reference's, where the entry
+    states it (``Cell.router_tolerance``).  Returns ``(fine, readings)``."""
     n = len(prompt)
     seq = list(prompt) + list(res["tokens"])
     commits = [p for p in res["passes"] if not p["quota"]]
@@ -126,11 +130,13 @@ def check_request(forward, params, B, tol, pad, prompt, n_new, res,
         rel = float(np.abs(logits - want).max() / np.abs(want).max())
         kind = "denoise" if p["quota"] else "commit"
         got[kind] = max(got[kind], rel)
-        got["router_off"] = max(got["router_off"], float(report[:, 0].max()))
+        off = float(report[:, 0].max())
+        got["router_off"] = max(got["router_off"], off)
         got["near_ties"] = max(got["near_ties"], int(report[:, 2].sum()))
         got["taken"] = max(got["taken"], int(report[:, 3].sum()))
         fine = fine and logits.shape == want.shape \
-            and bool(np.isfinite(logits).all()) and rel <= tol
+            and bool(np.isfinite(logits).all()) and rel <= tol \
+            and (router_tol is None or off <= router_tol)
     return bool(fine), got
 
 
@@ -194,11 +200,12 @@ def served_plan(builder, cfg, mix, scope, plan):
 def reference_check(run, cfg, mix, seed):
     import gc
 
-    tol = run.cell.tolerance
     B = int(cfg["assumed"]["generation"]["block_length"])
     slots = int(mix["engine"]["num_slots"])
     plan = check_plan(cfg, mix, seed)
     scope = seeded_scope(run.cell.builder(), cfg, mix, seed)
+    tol = run.cell.tolerance      # of what that engine ran in
+    router_tol = run.cell.router_tolerance
     results, stats = served_plan(run.cell.builder(), cfg, mix, scope, plan)
     # the closed engine still holds its pool, in a cycle: without this
     # the timed engine's pool may come to lie beside it (1.6 GB)
@@ -207,10 +214,13 @@ def reference_check(run, cfg, mix, seed):
     params = ref.params_from_scope(scope, cfg)
     forward, pad = jitted_forward(ref, cfg), check_pad(cfg, mix)
     ok = True
+    readings = run.check = {"tolerance": tol, "router_tolerance": router_tol,
+                            "denoise": {}, "commit": {}, "router_off": {},
+                            "near_ties": {}, "taken": {}}
     fillers = [0, 0]                 # requests, of them not fine
     for (prompt, n_new, compared), res in zip(plan, results):
         fine, got = check_request(forward, params, B, tol, pad, prompt,
-                                  n_new, res, compared)
+                                  n_new, res, compared, router_tol)
         res.clear()                  # a pass's logits are the whole grid's
         ok = ok and fine
         if not compared:
@@ -218,14 +228,20 @@ def reference_check(run, cfg, mix, seed):
             fillers[1] += not fine
             continue
         n = len(prompt)
+        for what in ("denoise", "commit", "router_off", "near_ties",
+                     "taken"):
+            # (a line is JSON: logits that are not finite read null)
+            readings[what][str(n)] = \
+                got[what] if np.isfinite(got[what]) else None
         run.say(f"reference check: prompt {n} (tail {n % B}), paged "
                 f"block-causal prefill + {got['passes']} passes of "
                 f"{mix['check_blocks']} blocks off the float32 "
                 f"reference's full forward by {got['denoise']:.4g} "
                 f"(denoising) / {got['commit']:.4g} (commit) of its range "
                 f"(tolerance {tol:.4g}); router logits off by at most "
-                f"{got['router_off']:.3g} of a row's range over the rows "
-                f"a pass reads, at most {got['near_ties']} row-layers of "
+                f"{got['router_off']:.3g} of a row's range"
+                f"{harness.said_limit(router_tol)} "
+                f"over the rows a pass reads, at most {got['near_ties']} row-layers of "
                 f"them a near tie, {got['taken']} taking the program's "
                 f"choice; "
                 f"{min(got['riders'])}-{max(got['riders'])} of "
@@ -236,6 +252,7 @@ def reference_check(run, cfg, mix, seed):
             f"{fillers[0]} fillers ({fillers[1]} of them not exactly "
             f"their tokens), {passes / max(stats['decode_steps'], 1):.1f} "
             f"slots live a pass over {stats['decode_steps']} passes")
+    readings["fillers_not_their_tokens"] = fillers[1]      # limit 0
     del params, forward
     return ok, scope
 

@@ -30,6 +30,8 @@ from __future__ import annotations
 
 import numpy as np
 
+import harness
+
 import serve
 import serve_blocks
 import serve_delta
@@ -172,11 +174,12 @@ def chunks_held(plan, results, times, launches, chunk):
 def reference_check(run, cfg, mix, seed):
     import gc
 
-    tol = run.cell.tolerance
     builder = run.cell.builder()
     chunk = int(mix["engine"]["prefill_chunk"])
     plan = check_plan(cfg, mix, seed)
     scope = serve_blocks.seeded_scope(builder, cfg, mix, seed)
+    tol = run.cell.tolerance      # of what that engine ran in
+    router_tol = run.cell.router_tolerance
     results, times, stats, launches = served_plan(builder, cfg, mix, scope,
                                                   plan)
     # (as serve_state: the closed engine's pools must be gone before the
@@ -189,7 +192,8 @@ def reference_check(run, cfg, mix, seed):
     ok = all(len(r["tokens"]) == n_new and r["finish"] == "length"
              for (_, n_new, _), r in zip(plan, results))
     margin = cfg["check_tolerance"]["near_tie_margin_share_of_router_range"]
-    readings = {"tolerance": tol, "near_tie_margin": margin, "rel": {},
+    readings = {"tolerance": tol, "near_tie_margin": margin,
+                "router_tolerance": router_tol, "rel": {},
                 "router_off": {}, "near_ties": {}, "taken": {},
                 "exact_tokens": ok}
     if not ok:
@@ -200,7 +204,7 @@ def reference_check(run, cfg, mix, seed):
         if not isinstance(kind, int):
             continue
         fine, got = serve_state.check_request(forward, params, tol, pad,
-                                              prompt, res)
+                                              prompt, res, router_tol)
         routers.append(got.pop("router"))
         for what, v in got.items():
             # (a line is JSON: logits that are not finite read null)
@@ -213,7 +217,9 @@ def reference_check(run, cfg, mix, seed):
                 f"off the float32 reference's single forward by "
                 f"{got['rel']:.4g} of its range (tolerance {tol:.4g}); "
                 f"router scores off by at most {got['router_off']:.3g} of "
-                f"a row's range, {got['near_ties']} row-layers a near tie, "
+                f"a row's range"
+                f"{harness.said_limit(router_tol)}, "
+                f"{got['near_ties']} row-layers a near tie, "
                 f"{got['taken']} taking the program's choice"
                 + ("" if fine else ": NOT correct"))
     held, notes = serve_state.plan_held(plan, results, times)

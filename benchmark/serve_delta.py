@@ -15,19 +15,16 @@ model has neither.
   prefill and eight cached decode steps) against the plain reference's
   full forward over prompt plus generated tokens, and nothing else: there
   is no discrete choice, so no near-tie rule.
-* ``served_plan``: every request's ``(claimed, first token, finished)``
-  on the engine's clock, not on a stamp the harness took before
-  ``submit`` turned a 5000-token list into an array.
+* ``served_plan``: ``serve_state``'s, which since PR 67 tells every
+  request's ``(claimed, first token, finished)`` on the engine's clock as
+  this driver's own did from PR 41 on.
 * ``reference_check``: the verdict, put in ``serve``'s place by name.
-* ``run_cell``: the check's readings, each beside its limit, under a last
-  key ``check`` of the result line, so that a run that reads NOT correct
-  says by which of them on the line itself.
+* ``run_cell``: the check's readings, each beside its limit, go to
+  ``run.check``, which the harness prints under a last key ``check`` of
+  the result line, so that a run that reads NOT correct says by which of
+  them on the line itself.
 """
 from __future__ import annotations
-
-import contextlib
-import io
-import json
 
 import numpy as np
 
@@ -66,51 +63,18 @@ def check_request(forward, params, tol, pad, prompt, res):
     return bool(fine), {"rel": rel}
 
 
-def served_plan(builder, cfg, mix, scope, plan):
-    """``serve_state.served_plan`` with each request's ``(claimed, first
-    token, finished)`` on ONE clock, the engine's.  ``serve_state``'s adds
-    the engine's milliseconds, which count from ITS stamp of the
-    submission, to a stamp the harness took before the call: a request's
-    times are early by what ``submit`` did in between, the conversion of
-    the prompt's list (0.3-0.5 ms for the 5000-token prompt, 0.05 ms for
-    a filler) and whatever the scheduler thread kept of the interpreter.
-    ``plan_held`` asks whether a slot's earlier tenant had finished when
-    the compared request claimed it, two stamps of one thread that read
-    0.1-0.45 ms apart on those clocks (my chip run, PR 41): a submit that
-    takes half a millisecond longer reads "the plan did NOT hold" on a
-    plan that held.  The engine hands ``on_token`` its
-    own stamp of every token and counts ``ttft_ms`` from the same one, so
-    the first token's stamp less ``ttft_ms`` is its stamp of the
-    submission."""
-    rungs = mix["engine"]["prefill_buckets"]
-    buckets = sorted({min(b for b in rungs if b >= len(p))
-                      for p, _, _ in plan})
-    gen = builder.engine(cfg, mix, scope=scope, keep_logits=True,
-                         buckets=buckets)
-    try:
-        gen.warmup()
-        stamps, futures = [[] for _ in plan], []
-        for (prompt, n_new, kind), at in zip(plan, stamps):
-            futures.append(gen.submit(
-                prompt, n_new, keep_logits=isinstance(kind, int),
-                on_token=lambda _, t, at=at: at.append(t)))
-        results = [f.result(600) for f in futures]
-        sent = [at[0] - r["ttft_ms"] / 1e3 for at, r in zip(stamps, results)]
-        times = [(t + r["queue_wait_ms"] / 1e3, t + r["ttft_ms"] / 1e3,
-                  t + r["total_ms"] / 1e3) for t, r in zip(sent, results)]
-        return results, times, gen.stats()["counters"]
-    finally:
-        gen.close()
-        scope.erase(list(gen.cache_names) + list(gen.state_names))
+# every request's ``(claimed, first token, finished)`` on the engine's
+# clock: first told so here (PR 41), ``serve_state``'s own since PR 67
+served_plan = serve_state.served_plan
 
 
 def reference_check(run, cfg, mix, seed):
     import gc
 
-    tol = run.cell.tolerance
     builder = run.cell.builder()
     plan = serve_state.check_plan(cfg, mix, seed)
     scope = seeded_scope(builder, cfg, mix, seed)
+    tol = run.cell.tolerance      # of what that engine ran in
     results, times, stats = served_plan(builder, cfg, mix, scope, plan)
     # (as serve_state: the closed engine's pool must be gone before the
     # timed engine's is made)
@@ -152,20 +116,7 @@ def reference_check(run, cfg, mix, seed):
 
 def run_cell(run) -> int:
     # ``serve.Served`` looks its set-up check up by name when it is
-    # built: the one thing this driver puts in its place
+    # built: the one thing this driver puts in its place.  (``run.check``
+    # goes out as the result line's last key: ``harness.Run.finish``.)
     serve.reference_check = reference_check
-    finish = run.finish
-
-    def finish_with_check(**said):
-        """The harness's result line with what the check read under a
-        last key; whatever else it printed, as it printed it."""
-        out = io.StringIO()
-        with contextlib.redirect_stdout(out):
-            rc = finish(**said)
-        *before, line = out.getvalue().splitlines()
-        print("\n".join(before + [json.dumps(
-            dict(json.loads(line), check=run.check))]), flush=True)
-        return rc
-
-    run.finish = finish_with_check
     return serve.run_cell(run)

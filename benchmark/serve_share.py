@@ -23,6 +23,8 @@ from __future__ import annotations
 
 import numpy as np
 
+import harness
+
 import serve
 import serve_blocks
 import serve_delta
@@ -41,10 +43,11 @@ def seeded_scope(builder, cfg, mix, seed):
 def reference_check(run, cfg, mix, seed):
     import gc
 
-    tol = run.cell.tolerance
     builder = run.cell.builder()
     plan = serve_state.check_plan(cfg, mix, seed)
     scope = seeded_scope(builder, cfg, mix, seed)
+    tol = run.cell.tolerance      # of what that engine ran in
+    router_tol = run.cell.router_tolerance
     results, times, stats = serve_delta.served_plan(builder, cfg, mix,
                                                     scope, plan)
     # (as serve_state: the closed engine's pool must be gone before the
@@ -57,7 +60,8 @@ def reference_check(run, cfg, mix, seed):
     ok = all(len(r["tokens"]) == n_new and r["finish"] == "length"
              for (_, n_new, _), r in zip(plan, results))
     margin = cfg["check_tolerance"]["near_tie_margin_share_of_router_range"]
-    readings = {"tolerance": tol, "near_tie_margin": margin, "rel": {},
+    readings = {"tolerance": tol, "near_tie_margin": margin,
+                "router_tolerance": router_tol, "rel": {},
                 "router_off": {}, "near_ties": {}, "taken": {},
                 "exact_tokens": ok}
     if not ok:
@@ -68,7 +72,7 @@ def reference_check(run, cfg, mix, seed):
         if not isinstance(kind, int):
             continue
         fine, got = serve_state.check_request(forward, params, tol, pad,
-                                              prompt, res)
+                                              prompt, res, router_tol)
         routers.append(got.pop("router"))
         for what, v in got.items():
             # (a line is JSON: logits that are not finite read null)
@@ -80,7 +84,8 @@ def reference_check(run, cfg, mix, seed):
                 f"cached decode steps off the float32 reference's full "
                 f"forward by {got['rel']:.4g} of its range (tolerance "
                 f"{tol:.4g}); router scores off by at most "
-                f"{got['router_off']:.3g} of a row's range, "
+                f"{got['router_off']:.3g} of a row's range"
+                f"{harness.said_limit(router_tol)}, "
                 f"{got['near_ties']} row-layers a near tie, "
                 f"{got['taken']} taking the program's choice"
                 + ("" if fine else ": NOT correct"))

@@ -32,6 +32,8 @@ from __future__ import annotations
 
 import numpy as np
 
+import harness
+
 import serve
 import serve_blocks
 import traffic
@@ -113,11 +115,13 @@ def plan_held(plan, results, times):
     return held, notes
 
 
-def check_request(forward, params, tol, pad, prompt, res):
+def check_request(forward, params, tol, pad, prompt, res, router_tol=None):
     """What decides ``correct`` for one compared request.  ``res`` is the
     engine's result under ``keep_logits``: ``logits`` and
     ``router_logits`` hold one row a generated token.  ``forward(params,
     ids, rows, program_router)`` is the plain reference's, jitted.
+    ``router_tol``: how far the program's router scores may lie off the
+    reference's, where the entry states it (``Cell.router_tolerance``).
     Returns ``(fine, readings)``."""
     n = len(prompt)
     got = np.stack(res["logits"])                            # [9, V]
@@ -129,9 +133,10 @@ def check_request(forward, params, tol, pad, prompt, res):
     want, report = forward(params, ids, rows, prog)
     want, report = np.asarray(want), np.asarray(report)
     rel = float(np.abs(got - want).max() / np.abs(want).max())
+    off = float(report[:, 0].max())
     fine = got.shape == want.shape and bool(np.isfinite(got).all()) \
-        and rel <= tol
-    return bool(fine), {"rel": rel, "router_off": float(report[:, 0].max()),
+        and rel <= tol and (router_tol is None or off <= router_tol)
+    return bool(fine), {"rel": rel, "router_off": off,
                         "near_ties": int(report[:, 2].sum()),
                         "taken": int(report[:, 3].sum()), "router": prog}
 
@@ -174,10 +179,25 @@ def served_plan(builder, cfg, mix, scope, plan):
     """The plan's requests through a check engine of the mix's size on
     the weights in ``scope``: their results (logits kept for the
     compared ones alone), each one's ``(claimed, first token, finished)``
-    on the host clock, and the engine's counters.  The engine is closed and its pool
-    and state out of the scope when this returns."""
-    import time
+    and the engine's counters.  The engine is closed and its pool and
+    state out of the scope when this returns.
 
+    The times are told on ONE clock, the engine's.  Until PR 67 this
+    function added the engine's milliseconds, which count from ITS stamp
+    of the submission, to a stamp the harness took before the call: a
+    request's times were early by what ``submit`` did in between, the
+    conversion of the prompt's list (0.3-0.5 ms for a 5000-token prompt,
+    0.05 ms for a filler) and whatever the scheduler thread kept of the
+    interpreter.  ``plan_held`` asks whether a slot's earlier tenant had
+    finished when the compared request claimed it, two stamps of one
+    thread that read 0.1-0.45 ms apart (my chip run, PR 41): a submit
+    that took half a millisecond longer read "the plan did NOT hold" on a
+    plan that held, and the driver's check of PR 67 drew such a run
+    (``lfm2-24b-longanswer``, seed 257746178: ``correct`` false there,
+    true twice on the same seed on my chip runs).  The engine hands
+    ``on_token`` its own stamp of every token and counts ``ttft_ms`` from
+    the same one, so the first token's stamp less ``ttft_ms`` is its stamp
+    of the submission."""
     rungs = mix["engine"]["prefill_buckets"]
     buckets = sorted({min(b for b in rungs if b >= len(p))
                       for p, _, _ in plan})
@@ -185,12 +205,13 @@ def served_plan(builder, cfg, mix, scope, plan):
                          buckets=buckets)
     try:
         gen.warmup()
-        sent, futures = [], []
-        for prompt, n_new, kind in plan:
-            sent.append(time.monotonic())
-            futures.append(gen.submit(prompt, n_new,
-                                      keep_logits=isinstance(kind, int)))
+        stamps, futures = [[] for _ in plan], []
+        for (prompt, n_new, kind), at in zip(plan, stamps):
+            futures.append(gen.submit(
+                prompt, n_new, keep_logits=isinstance(kind, int),
+                on_token=lambda _, t, at=at: at.append(t)))
         results = [f.result(600) for f in futures]
+        sent = [at[0] - r["ttft_ms"] / 1e3 for at, r in zip(stamps, results)]
         times = [(t + r["queue_wait_ms"] / 1e3, t + r["ttft_ms"] / 1e3,
                   t + r["total_ms"] / 1e3) for t, r in zip(sent, results)]
         return results, times, gen.stats()["counters"]
@@ -202,10 +223,11 @@ def served_plan(builder, cfg, mix, scope, plan):
 def reference_check(run, cfg, mix, seed):
     import gc
 
-    tol = run.cell.tolerance
     builder = run.cell.builder()
     plan = check_plan(cfg, mix, seed)
     scope = seeded_scope(builder, cfg, mix, seed)
+    tol = run.cell.tolerance      # of what that engine ran in
+    router_tol = run.cell.router_tolerance
     results, times, stats = served_plan(builder, cfg, mix, scope, plan)
     # the closed engine still holds its pool, in a cycle: without this
     # the timed engine's pool may come to lie beside it
@@ -215,6 +237,11 @@ def reference_check(run, cfg, mix, seed):
     forward, pad = jitted_forward(ref, cfg), check_pad(mix)
     ok = all(len(r["tokens"]) == n_new and r["finish"] == "length"
              for (_, n_new, _), r in zip(plan, results))
+    margin = cfg["check_tolerance"]["near_tie_margin_share_of_router_range"]
+    readings = {"tolerance": tol, "near_tie_margin": margin,
+                "router_tolerance": router_tol, "rel": {},
+                "router_off": {}, "near_ties": {}, "taken": {},
+                "exact_tokens": ok}
     if not ok:
         run.say("reference check: a request did not get exactly its "
                 "tokens: NOT correct")
@@ -222,15 +249,21 @@ def reference_check(run, cfg, mix, seed):
     for (prompt, _, kind), res in zip(plan, results):
         if not isinstance(kind, int):
             continue
-        fine, got = check_request(forward, params, tol, pad, prompt, res)
-        routers.append(got["router"])
+        fine, got = check_request(forward, params, tol, pad, prompt, res,
+                                  router_tol)
+        routers.append(got.pop("router"))
+        for what, v in got.items():
+            # (a line is JSON: logits that are not finite read null)
+            readings[what][str(len(prompt))] = \
+                v if np.isfinite(v) else None
         ok = ok and fine
         run.say(f"reference check: prompt {len(prompt)} in reused slot "
                 f"{res['slot']}, paged prefill + {CHECK_NEW_TOKENS - 1} "
                 f"cached decode steps off the float32 reference's full "
                 f"forward by {got['rel']:.4g} of its range (tolerance "
                 f"{tol:.4g}); router scores off by at most "
-                f"{got['router_off']:.3g} of a row's range, "
+                f"{got['router_off']:.3g} of a row's range"
+                f"{harness.said_limit(router_tol)}, "
                 f"{got['near_ties']} row-layers a near tie, "
                 f"{got['taken']} taking the program's choice"
                 + ("" if fine else ": NOT correct"))
@@ -246,6 +279,7 @@ def reference_check(run, cfg, mix, seed):
             f"the expert bias moved the choice of "
             f"{100 * bias_moved_share(np.concatenate(routers), biases, cfg['num_experts_per_tok']):.1f}% "
             f"of the compared row-layers")
+    run.check = dict(readings, plan_held=held)
     del params, forward
     return ok and held, scope
 

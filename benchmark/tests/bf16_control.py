@@ -8,6 +8,7 @@ bfloat16 is the nearest precision below the float32 the configuration
 states, so at least one prompt must come out over ``share_of_range``.
 
     python3 benchmark/tests/bf16_control.py [--seed N] [--rehearse]
+        [--standin stated|throughout|fp8 [--entry float32|bfloat16]]
 
 prints one line per reference prompt; without ``--rehearse`` it is the
 published widths and needs the chip.
@@ -79,6 +80,14 @@ def readings(cell, seed: int) -> list:
 def main(argv=None) -> int:
     import harness
 
+    argv = sys.argv[1:] if argv is None else list(argv)
+    if "--standin" in argv:
+        # 'stated', 'fp8' or this file's own reading through the shared
+        # stand-ins, judged by the bfloat16 entry (standins.py)
+        sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
+        import standins
+
+        return standins.control(argv, "smallthinker21b-mixedlen")
     ap = argparse.ArgumentParser()
     ap.add_argument("--workload", default="smallthinker21b-mixedlen")
     ap.add_argument("--seed", type=int, default=2800000003)

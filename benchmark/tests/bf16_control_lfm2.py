@@ -10,6 +10,7 @@ nearest precision below the float32 the configuration states, so the
 comparison must come out NOT fine on at least one prompt.
 
     python3 benchmark/tests/bf16_control_lfm2.py [--seed N] [--rehearse]
+        [--standin stated|throughout|fp8 [--entry float32|bfloat16]]
 
 prints one line per reference prompt; without ``--rehearse`` it is the
 published widths and needs the chip.
@@ -71,6 +72,14 @@ def readings(cell, seed: int) -> list:
 def main(argv=None) -> int:
     import harness
 
+    argv = sys.argv[1:] if argv is None else list(argv)
+    if "--standin" in argv:
+        # 'stated', 'fp8' or this file's own reading through the shared
+        # stand-ins, judged by the bfloat16 entry (standins.py)
+        sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
+        import standins
+
+        return standins.control(argv, "lfm2-24b-longanswer")
     ap = argparse.ArgumentParser()
     ap.add_argument("--workload", default="lfm2-24b-longanswer")
     ap.add_argument("--seed", type=int, default=3400000003)

@@ -309,6 +309,45 @@ def test_the_check_catches_a_slot_that_is_not_reset(fault, monkeypatch):
     assert "prefills wrote a slot's state" in said[-1]
 
 
+def test_a_slow_submit_does_not_decide_correct(monkeypatch):
+    """The run the driver's check of PR 67 refused (seed 257746178,
+    ``correct`` false on logits 2e-6 to 7e-6 of the range off): a compared
+    request whose ``submit`` takes 20 ms.  ``serve_state.reference_check``
+    reads the plan on the engine's clock and stays correct, and its
+    readings go to ``run.check`` for the result line's last key."""
+    import time
+
+    import harness
+    import serve_state
+    from paddle_tpu.serving import GenerationEngine
+
+    cell = harness.Cell(CELL, rehearse=True)
+    real = GenerationEngine.submit
+
+    def slow(self, prompt, *args, **kw):
+        if kw.get("keep_logits"):
+            time.sleep(0.02)
+        return real(self, prompt, *args, **kw)
+
+    monkeypatch.setattr(GenerationEngine, "submit", slow)
+    said = []
+
+    class Run:
+        pass
+
+    run = Run()
+    run.cell, run.say = cell, said.append
+    ok, _ = serve_state.reference_check(run, cell.cfg, cell.mix, 257746178)
+    assert ok, said
+    assert not any("NOT" in line for line in said)
+    check = run.check
+    assert check["plan_held"] and check["exact_tokens"]
+    assert check["tolerance"] == cell.tolerance
+    assert len(check["rel"]) == 2
+    assert all(0 <= r <= check["tolerance"] for r in check["rel"].values())
+    assert set(check["router_off"]) == set(check["rel"])
+
+
 def test_bfloat16_throughout_fails_the_check():
     """The check's control (``bf16_control_lfm2.py``): the reference
     computed in bfloat16 throughout goes through the cell's own

@@ -114,6 +114,14 @@ GROUPS = {
     "routed step": ["decode_step_roofline.mix"],
     "block diffusion": ["tokens_per_pass.blk", "commit_pass_share_pct.blk",
                         "block_step_roofline.blk"],
+    # what one later cell alone reports (PR 59, PR 63, PR 66; tier-1 puts
+    # the same three on the collected module, which is the same again)
+    "state space": ["ssm_step_roofline.pool", "ssm_chunk_roofline.pool",
+                    "ssm_kernel_share_pct.pool"],
+    "experts in a latent row": ["expert_kernel_roofline.pool",
+                                "moe_rows_per_held_expert.pool"],
+    "identity experts": ["moe_zero_pairs_pct.pool",
+                         "shortcut_branch_share_pct.pool"],
 }
 # the entries whose reader takes arguments from the cell's own files
 FAMILIES = [name for group in (
@@ -406,7 +414,8 @@ def test_the_fixture_is_the_whole_parent_and_52_names_went():
     assert len({r["old"] for r in AT_PR54}) == 127
     went = {r["old"] for r in AT_PR54} - set(BY_NAME)
     came = set(BY_NAME) - {r["old"] for r in AT_PR54}
-    assert (len(went), len(came), len(ENTRIES)) == (52, 22, 97)
+    # (22 came and 97 stood at PR 55; PR 59, 63 and 66 brought 3 + 2 + 2)
+    assert (len(went), len(came), len(ENTRIES)) == (52, 29, 104)
     for r in AT_PR54:
         assert set(r["cells"]) <= set(BY_NAME[r["new"]]["workloads"]), r
 
@@ -506,3 +515,18 @@ def test_the_files_kept_for_tier_1_are_the_parents():
         assert (spec["reader"], spec["args"]) == (row["reader"], row["args"])
         for cell in row["cells"]:
             assert resolved(row["new"], cell) == (row["reader"], row["args"])
+
+
+# -- what a serving program ran in (PR 67) ---------------------------------------
+#
+# ``as_run_checks.py`` holds the checks of the two admitted dtypes, the
+# observation and the readers' item sizes; they are taken in here as this
+# file's own so that tier-1, which collects this file's checks by name
+# (``tests/test_benchmark_manifest.py``), collects them too.  JAX is
+# imported inside those that build an engine, not by this module.
+
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
+import as_run_checks  # noqa: E402
+
+globals().update({name: fn for name, fn in vars(as_run_checks).items()
+                  if name.startswith("test_as_run_")})

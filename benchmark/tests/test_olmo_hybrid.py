@@ -312,13 +312,42 @@ def test_the_check_catches_a_state_fault(fault, monkeypatch):
     assert "prefills wrote a slot's state" in said[-1]
 
 
+def stamped_before_submit(builder, cfg, mix, scope, plan):
+    """``serve_state.served_plan`` as it was until PR 67: the engine's
+    milliseconds added to a stamp the harness took BEFORE ``submit``.
+    Kept here as the reading that fails: a plan that held reads as not
+    held where a compared request's ``submit`` is slow."""
+    import time
+
+    rungs = mix["engine"]["prefill_buckets"]
+    buckets = sorted({min(b for b in rungs if b >= len(p))
+                      for p, _, _ in plan})
+    gen = builder.engine(cfg, mix, scope=scope, keep_logits=True,
+                         buckets=buckets)
+    try:
+        gen.warmup()
+        sent, futures = [], []
+        for prompt, n_new, kind in plan:
+            sent.append(time.monotonic())
+            futures.append(gen.submit(prompt, n_new,
+                                      keep_logits=isinstance(kind, int)))
+        results = [f.result(600) for f in futures]
+        times = [(t + r["queue_wait_ms"] / 1e3, t + r["ttft_ms"] / 1e3,
+                  t + r["total_ms"] / 1e3) for t, r in zip(sent, results)]
+        return results, times, gen.stats()["counters"]
+    finally:
+        gen.close()
+        scope.erase(list(gen.cache_names) + list(gen.state_names))
+
+
 def test_the_plan_is_read_on_the_engines_clock(monkeypatch):
     """A compared request whose ``submit`` takes 20 ms (a long prompt's
     list turned into an array, the scheduler thread holding the
     interpreter) still reads as landing in a slot its earlier tenant had
-    left: ``serve_delta.served_plan`` tells every time on the engine's
-    clock.  ``serve_state.served_plan``'s times, which start at a stamp
-    taken before the call, read the same plan as NOT held."""
+    left: ``serve_state.served_plan`` (``serve_delta.served_plan`` is the
+    same function since PR 67) tells every time on the engine's clock.
+    Times that start at a stamp taken before the call, as
+    ``serve_state``'s did until then, read the same plan as NOT held."""
     import time
 
     import harness
@@ -326,6 +355,7 @@ def test_the_plan_is_read_on_the_engines_clock(monkeypatch):
     import serve_state
     from paddle_tpu.serving import GenerationEngine
 
+    assert serve_delta.served_plan is serve_state.served_plan
     cell = harness.Cell(CELL, rehearse=True)
     real = GenerationEngine.submit
 
@@ -339,8 +369,8 @@ def test_the_plan_is_read_on_the_engines_clock(monkeypatch):
     plan = serve_state.check_plan(cell.cfg, cell.mix, 4100000033)
     scope = serve_delta.seeded_scope(builder, cell.cfg, cell.mix,
                                      4100000033)
-    for served, holds in ((serve_delta.served_plan, True),
-                          (serve_state.served_plan, False)):
+    for served, holds in ((serve_state.served_plan, True),
+                          (stamped_before_submit, False)):
         results, times, _ = served(builder, cell.cfg, cell.mix, scope, plan)
         held, notes = serve_state.plan_held(plan, results, times)
         assert held == holds, notes

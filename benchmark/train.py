@@ -119,6 +119,9 @@ def run_cell(run) -> int:
                          for x in losses])
     failed = int((~np.isfinite(values[n_warm:n_warm + steps])).sum())
     correct = correct and bool(np.isfinite(values).all())
+    # (beside the set-up check's readings; limit 0)
+    run.check = dict(run.check or {},
+                     losses_not_finite=int((~np.isfinite(values)).sum()))
     tokens_per_s_chip = steps * batch * seq / window / n
     run.say(f"window {window:.3f} s, {steps} steps of {batch} x {seq} on "
             f"{n} chip(s), loss {values[n_warm]:.4f} -> "
