@@ -613,7 +613,10 @@ def serve_phase(cfg=SERVE, on_chip=True):
         prefill_buckets=cfg["prefill_buckets"], max_new_tokens=new,
         page_tokens=cfg["page_tokens"], prefill_chunk=0,
         prefix_reuse=False, attn_impl="auto", keep_logits=True, seed=0,
-        deadline_ms=600000.0)
+        deadline_ms=600000.0,
+        # (held to the float32 uncached forward at TOL; what the engine's
+        # own rule picks for this model, bfloat16, is ``dtype_phase``'s)
+        dtype="float32")
     engine = ServingEngine(predictor, workers=1, max_batch=8,
                            max_delay_ms=2.0, deadline_ms=600000.0,
                            warmup_shapes={"x": (cfg["mlp"]["feat"],)})
@@ -2019,7 +2022,9 @@ def dense_rows_phase(cfg=DENSE):
     kw = dict(num_slots=2, max_seq_len=cfg["rung"] + 64,
               prefill_buckets=[cfg["rung"]], page_tokens=16,
               prefill_chunk=0, prefix_reuse=False, speculate=False,
-              keep_logits=True, eos_id=-1)
+              keep_logits=True, eos_id=-1,
+              # (the float32 table's rungs and segments: ``cfg["rows"]``)
+              dtype="float32")
     gen = GenerationEngine(model, **kw)
     plain = GenerationEngine(model, scope=gen.scope.new_scope(), **kw)
     plain._build_fn_prefill = functools.partial(build_llama_prefill,
@@ -2062,6 +2067,119 @@ def dense_rows_phase(cfg=DENSE):
         "plain: " + ", ".join(
             f"{n}: {took['stops', n]:.3f} | {took['plain', n]:.3f} s"
             for n in cfg["prompts"]))
+
+
+# (PR 68) Mistral-7B's published widths at a toy depth, one rung, and the
+# limit its cells hold a bfloat16 program to
+DTYPE = dict(config="mistral-7b-v0.1", layers=2, rung=1024, prompts=(48, 700),
+             steps=8)
+
+
+def dtype_phase(cfg=DTYPE):
+    """What the engine's rule serves a dense decoder in (PR 68): the toy-
+    depth Mistral at published widths as the rule builds it (bfloat16) and
+    with float32 stated, on the same (rounded) weights: the counters of the
+    programs built, the attention kernels both took (no reference
+    formulation), the classes of arrays the benchmark would observe, and
+    the bfloat16 program's reading through the cell's comparison (its
+    logits at a prefill and eight cached steps against the plain float32
+    reference on the same weights, as a share of the reference's range)
+    beside the two stand-ins' (``benchmark/tests/standins.py``: ``stated``,
+    ``throughout``) and the float32 program's, at the file's limits."""
+    import jax.numpy as jnp
+
+    from paddle_tpu.serving import GenerationEngine
+
+    bench = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                         "benchmark")
+    sys.path[:0] = [p for p in (bench, os.path.join(bench, "tests"))
+                    if p not in sys.path]
+    import harness
+    import standins
+
+    whole = harness.load_json("configs", cfg["config"] + ".json")
+    conf = dict(whole, num_hidden_layers=cfg["layers"])
+    ref = harness.load_module("reference", cfg["config"])
+    model = harness.load_module("builders", whole["builder"]).model_args(conf)
+    kw = dict(num_slots=2, max_seq_len=cfg["rung"] + 64,
+              prefill_buckets=[cfg["rung"]], page_tokens=16, prefill_chunk=0,
+              prefix_reuse=False, speculate=False, keep_logits=True,
+              eos_id=-1, seed=0)
+    paths0 = attention_paths()
+    low = GenerationEngine(model, **kw)
+    f32 = GenerationEngine(model, dtype="float32", **kw)
+    try:
+        for eng in (low, f32):
+            eng.warmup()
+        built = {eng.dtype: {k: v for k, v in eng.stats()[
+            "counters"].items() if k.startswith("programs_built_")}
+            for eng in (low, f32)}
+        check(low.dtype == "bfloat16" and built == {
+            "bfloat16": {"programs_built_bfloat16": 2,
+                         "programs_built_float32": 0},
+            "float32": {"programs_built_bfloat16": 0,
+                        "programs_built_float32": 2}},
+            f"the rule builds bfloat16, float32 where stated: {built}")
+        paths = paths_since(paths0)
+        check(paths.get("pallas", 0) >= 2 * cfg["layers"]
+              and paths.get("paged_decode", 0) >= 2 * cfg["layers"]
+              and not any("reference" in k or "blockwise" in k
+                          for k in paths),
+              f"both dtypes took the kernels, no reference: {paths}")
+        seen = harness.observe_engine(low, conf)
+        entry, problems = harness.held_to(whole, seen)
+        check(seen == {"weights": "bfloat16", "pages": "bfloat16",
+                       "state": None, "kept_not_float32": []}
+              and not problems
+              and entry is whole["check_tolerance"]["bfloat16"],
+              f"the benchmark would observe {seen}")
+        # the same (rounded) weights under the float32 program
+        for n in low._weight_names():
+            f32.scope.set_var(n, jnp.asarray(low.scope.find_var(n),
+                                             jnp.float32))
+        params = ref.params_from_scope(f32.scope, conf)
+        limit, limit32 = entry["share_of_range"], \
+            whole["check_tolerance"]["share_of_range"]
+        steps, read = cfg["steps"], {}
+        rng = np.random.default_rng(68)
+        for n in cfg["prompts"]:
+            prompt = rng.integers(1, conf["vocab_size"], n).tolist()
+            res = low.generate(prompt, steps + 1, timeout=600)
+            ids = np.zeros((cfg["rung"] + 64,), "int32")
+            seq = prompt + res["tokens"][:-1]
+            ids[:len(seq)] = seq
+            rows = np.arange(n - 1, n + steps)
+            want = np.asarray(ref.forward(params, ids, conf, rows))
+            span = float(want.max() - want.min())
+
+            def rel(got):
+                return float(np.abs(np.asarray(got, "float32")
+                                    - want).max() / span)
+
+            read["program", n] = rel(np.stack(res["logits"]))
+            # (teacher-forced on the same tokens: the float32 program's
+            # own answer where its tokens are the bfloat16 program's)
+            res32 = f32.generate(prompt, steps + 1, timeout=600)
+            if res32["tokens"] == res["tokens"]:
+                read["float32 program", n] = rel(np.stack(res32["logits"]))
+            for kind in ("stated", "throughout"):
+                read[kind, n] = rel(standins.lowered(ref, conf, kind)(
+                    params, ids, rows)[0])
+            check(np.isfinite(read["program", n])
+                  and read["program", n] <= limit,
+                  f"prompt {n}: the bfloat16 program lies "
+                  f"{read['program', n]:.4g} of the range off the float32 "
+                  f"reference (limit {limit:.4g})")
+            check(read.get(("float32 program", n), 0.0) <= limit32,
+                  f"prompt {n}: the float32 program within its own limit "
+                  f"{limit32:.4g}")
+    finally:
+        low.close()
+        f32.close()
+    say("serving dtype: " + "; ".join(
+        f"{what} {n}: {v:.4g}" for (what, n), v in sorted(read.items()))
+        + f" (limits: bfloat16 {limit:.4g}, float32 {limit32:.4g}); "
+        f"programs built {built}; attention {paths}")
 
 
 def store_child(cfg=STORE):
@@ -2268,6 +2386,12 @@ def main():
     dense_rows_phase()
     dense_rows_phase(DENSE_SHORT)
     say(f"dense products that stop at the prompt's end done "
+        f"[{time.perf_counter() - t0:.1f} s]")
+    gc.collect()
+
+    t0 = time.perf_counter()
+    dtype_phase()
+    say(f"the dense decoder in bfloat16 beside float32 done "
         f"[{time.perf_counter() - t0:.1f} s]")
 
     say(f"set-up (compile-dominated: kernel check + first train step + "

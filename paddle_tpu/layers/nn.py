@@ -44,20 +44,24 @@ __all__ = [
 
 
 def fc(input, size, num_flatten_dims=1, param_attr=None, bias_attr=None,
-       act=None, name=None):
+       act=None, name=None, out_dtype=None):
     """Fully-connected layer (reference layers/nn.py:295 `fc`): flattens
-    input to 2-D at num_flatten_dims, matmuls against a [in, size] weight."""
+    input to 2-D at num_flatten_dims, matmuls against a [in, size] weight.
+    ``out_dtype`` "float32" on two-byte rows: the product's float32 sum
+    itself comes back, not rounded to the rows' dtype (op ``mul``)."""
     helper = LayerHelper("fc", name=name)
     inputs = input if isinstance(input, (list, tuple)) else [input]
     mul_results = []
     for x in inputs:
         in_features = int(np.prod(x.shape[num_flatten_dims:]))
         w = helper.create_parameter(param_attr, [in_features, size], x.dtype)
-        out = helper.create_variable_for_type_inference(x.dtype)
+        out = helper.create_variable_for_type_inference(out_dtype or x.dtype)
+        attrs = {"x_num_col_dims": num_flatten_dims, "y_num_col_dims": 1}
+        # (an attr only where asked for: the other programs' text stays)
+        if out_dtype:
+            attrs["out_dtype"] = out_dtype
         helper.append_op("mul", inputs={"X": [x], "Y": [w]},
-                         outputs={"Out": [out]},
-                         attrs={"x_num_col_dims": num_flatten_dims,
-                                "y_num_col_dims": 1})
+                         outputs={"Out": [out]}, attrs=attrs)
         mul_results.append(out)
     if len(mul_results) == 1:
         pre_bias = mul_results[0]
@@ -78,12 +82,15 @@ def fc(input, size, num_flatten_dims=1, param_attr=None, bias_attr=None,
     return helper.append_activation(pre_act, act)
 
 
-def fc_valid_rows(input, size, valid_rows, param_attr=None, name=None):
+def fc_valid_rows(input, size, valid_rows, param_attr=None, name=None,
+                  segment=None):
     """``fc(input, size, num_flatten_dims=2, bias_attr=False)`` on rows
     ``input`` [1, S, K] of which only the first ``valid_rows[0]`` hold
     anything (a prompt padded to its rung): the product runs over the
     segments of rows that hold one of them and the rows behind are zero
-    (op ``mul_valid_rows``).  The weight is ``fc``'s, under its name."""
+    (op ``mul_valid_rows``), ``segment`` rows at a time (None:
+    ``ops/math_ops.py`` ``VALID_ROW_SEGMENT``).  The weight is ``fc``'s,
+    under its name."""
     from ..ops.math_ops import VALID_ROW_SEGMENT
 
     helper = LayerHelper("fc", name=name)
@@ -94,19 +101,20 @@ def fc_valid_rows(input, size, valid_rows, param_attr=None, name=None):
                      inputs={"X": [input], "Y": [w],
                              "ValidRows": [valid_rows]},
                      outputs={"Out": [out]},
-                     attrs={"segment": VALID_ROW_SEGMENT})
+                     attrs={"segment": int(segment or VALID_ROW_SEGMENT)})
     return out
 
 
 def swiglu_valid_rows(input, width, size, valid_rows, gate_up_attr=None,
-                      down_attr=None, limit=None, name=None):
+                      down_attr=None, limit=None, name=None, segment=None):
     """``fc(silu(gate) * up, size)`` with ``gate | up = fc(input, 2 *
     width)`` (no biases; with ``limit`` L the gate held under L and the up
     to [-L, L]) on rows ``input`` [1, S, K] of which only the first
     ``valid_rows[0]`` hold anything: both products run a segment of rows
     at a time over the segments that hold one of them, the rows behind are
-    zero and no [S, 2 * width] is held (op ``swiglu_valid_rows``).  The
-    weights are the two ``fc``s', under their names."""
+    zero and no [S, 2 * width] is held (op ``swiglu_valid_rows``;
+    ``segment``: :func:`fc_valid_rows`'s).  The weights are the two
+    ``fc``s', under their names."""
     from ..ops.math_ops import VALID_ROW_SEGMENT
 
     helper = LayerHelper("fc", name=name)
@@ -114,7 +122,7 @@ def swiglu_valid_rows(input, width, size, valid_rows, gate_up_attr=None,
         gate_up_attr, [int(input.shape[2]), 2 * width], input.dtype)
     down = helper.create_parameter(down_attr, [width, size], input.dtype)
     out = helper.create_variable_for_type_inference(input.dtype)
-    attrs = {"segment": VALID_ROW_SEGMENT}
+    attrs = {"segment": int(segment or VALID_ROW_SEGMENT)}
     if limit is not None:
         attrs["limit"] = float(limit)
     helper.append_op("swiglu_valid_rows",
@@ -460,13 +468,17 @@ def log_softmax(input, axis=-1, name=None):
     return out
 
 
-def matmul(x, y, transpose_x=False, transpose_y=False, alpha=1.0, name=None):
+def matmul(x, y, transpose_x=False, transpose_y=False, alpha=1.0, name=None,
+           out_dtype=None):
+    """``out_dtype``: :func:`fc`'s."""
     helper = LayerHelper("matmul", name=name)
-    out = helper.create_variable_for_type_inference(x.dtype)
+    out = helper.create_variable_for_type_inference(out_dtype or x.dtype)
+    attrs = {"transpose_X": transpose_x, "transpose_Y": transpose_y,
+             "alpha": alpha}
+    if out_dtype:
+        attrs["out_dtype"] = out_dtype
     helper.append_op("matmul", inputs={"X": [x], "Y": [y]},
-                     outputs={"Out": [out]},
-                     attrs={"transpose_X": transpose_x,
-                            "transpose_Y": transpose_y, "alpha": alpha})
+                     outputs={"Out": [out]}, attrs=attrs)
     return out
 
 
@@ -590,7 +602,8 @@ def pad(x, paddings, pad_value=0.0, name=None):
 def flash_attention(q, k, v, bias=None, causal=False, scale=None,
                     seq_parallel_mode="ring", impl="auto", layout="bhsd",
                     dropout_prob=0.0, is_test=False, name=None,
-                    window=None, mask_block=None, precision=None):
+                    window=None, mask_block=None, precision=None,
+                    softmax_float32=False):
     """Fused multi-head attention; q/k/v: [B, H, S, D] (layout "bhsd")
     or [B, S, H, D] (layout "bshd", impl="xla" only).
 
@@ -611,6 +624,12 @@ def flash_attention(q, k, v, bias=None, causal=False, scale=None,
     times values) at the backend's default, under which a TPU rounds
     float32 operands to bfloat16; "highest" feeds them whole, whatever
     the mask (forward only, no padding bias).
+    softmax_float32: with two-byte q, k, v under impl="xla", the scores
+    are the first product's float32 sum, the softmax runs on them in
+    float32 and the probabilities are rounded where they enter the second
+    product (what the kernels and the blockwise formulation do whatever
+    they are given; without it that formulation's scores and softmax are
+    the operands' dtype, as a mixed-precision training step has them).
     """
     helper = LayerHelper("flash_attention", name=name)
     out = helper.create_variable_for_type_inference(q.dtype)
@@ -625,6 +644,8 @@ def flash_attention(q, k, v, bias=None, causal=False, scale=None,
         attrs["mask_block"] = int(mask_block)
     if precision is not None:
         attrs["precision"] = str(precision)
+    if softmax_float32:
+        attrs["softmax_float32"] = True
     inputs = {"Q": [q], "K": [k], "V": [v]}
     if bias is not None:
         inputs["Bias"] = [bias]

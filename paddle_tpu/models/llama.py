@@ -193,6 +193,42 @@ def layer_spec(layer_pattern, i):
     return dict(DEFAULT_LAYER, **layer_pattern[i % len(layer_pattern)])
 
 
+# What a serving program is declared in (``serving_dtype``): bfloat16 where
+# every layer of the model is of a kind whose bfloat16 form exists, and the
+# kinds that have one are listed HERE and nowhere else: a pattern key and
+# the values of it that do.  A key left out (rope, rope_interleave) changes
+# no kind.  The next kind to get a bfloat16 form (ROADMAP S4) adds its value
+# and its kernels; a value that is a dict (a routed FFN, a mixer with state)
+# is matched whole, so naming one router's experts admits no other's.
+BFLOAT16_LAYER_KINDS = {
+    "mixer": ("attention",),     # q, k, v over K/V pages ...
+    "window": (None,),           # ... in the full pool alone,
+    "mla": (None,),              # ... whole heads, not a latent row
+    "ffn": ("dense",),           # a dense SwiGLU
+    "branch": (None,), "join": (False,),
+    "attn_gate": (False,), "swiglu_limit": (None,),
+    "attn_precision": (None,),
+}
+
+
+def serving_dtype(model):
+    """The dtype a serving engine's programs are declared in, from the
+    model it is handed (the engine's ``model`` dict): ``"bfloat16"`` where
+    every layer is of :data:`BFLOAT16_LAYER_KINDS`, generation is one token
+    a step (no ``block_diffusion``) and the norms are RMS norms (their
+    weight is float32 whatever the row's dtype; a LayerNorm's is created in
+    the row's); ``"float32"`` otherwise."""
+    if model.get("block_diffusion") or model.get("norm_kind", "rms") != "rms":
+        return "float32"
+    pattern = model.get("layer_pattern")
+    for i in range(len(pattern) if pattern else 1):
+        layer = layer_spec(pattern, i)
+        if any(layer[key] not in kinds
+               for key, kinds in BFLOAT16_LAYER_KINDS.items()):
+            return "float32"
+    return "bfloat16"
+
+
 def state_layers(layer_pattern, num_layers):
     """Indices of the layers that keep slot state, not pages: those whose
     mixer is a gated short convolution, the gated delta rule or a
@@ -235,11 +271,13 @@ def window_layers(layer_pattern, num_layers):
 
 def cache_spec(name, num_layers, layer_pattern=None, *, num_slots,
                num_pages, page_tokens, num_kv_heads, head_dim, hidden,
-               num_window_pages=None):
+               num_window_pages=None, dtype="float32"):
     """What a decoder keeps between steps, layer by layer: the one
     description the program builders declare their persistable state
     from and the serving engine allocates from.  A list of ``{"name",
-    "layer", "kind", "shape"}``, kinds:
+    "layer", "kind", "shape", "dtype"}``: the page pools are ``dtype``
+    (the program's, :func:`serving_dtype`), slot state is float32 whatever
+    the program's.  Kinds:
 
     * ``"pages"`` / ``"window_pages"``: an attention layer's K and V page
       pools (two entries, K first), ``ops/decode_ops.py`` ``pool_shape``
@@ -283,7 +321,8 @@ def cache_spec(name, num_layers, layer_pattern=None, *, num_slots,
                 shapes = {"conv_state": [int(mixer["conv"]) - 1, channels],
                           "delta_state": [heads, dk, dv]}
             spec += [{"name": f"{name}.{what}_{i}", "layer": i,
-                      "kind": "slot_state", "shape": [num_slots + 1] + shape}
+                      "kind": "slot_state", "shape": [num_slots + 1] + shape,
+                      "dtype": "float32"}
                      for what, shape in shapes.items()]
             continue
         mla = layer_spec(layer_pattern, i)["mla"]
@@ -295,13 +334,13 @@ def cache_spec(name, num_layers, layer_pattern=None, *, num_slots,
                          "kind": "latent_pages",
                          "shape": latent_pool_shape(
                              num_pages, page_tokens, int(mla["kv_rank"]),
-                             int(mla["rope_dim"]))})
+                             int(mla["rope_dim"])), "dtype": dtype})
             continue
         kind = "window_pages" if i in windowed else "pages"
         shape = pool_shape(num_window_pages if i in windowed else num_pages,
                            num_kv_heads, page_tokens, head_dim)
         spec += [{"name": f"{name}.pool_{kv}_{i}", "layer": i, "kind": kind,
-                  "shape": shape} for kv in ("k", "v")]
+                  "shape": shape, "dtype": dtype} for kv in ("k", "v")]
     return spec
 
 
@@ -310,8 +349,9 @@ def _cache_vars(block, spec, layer):
     entries of :func:`cache_spec` (K and V pools, the one latent pool,
     or the layer's one or two states)."""
     return tuple(block.create_var(
-        name=e["name"], persistable=True, shape=e["shape"], dtype="float32",
-        stop_gradient=True) for e in spec if e["layer"] == layer)
+        name=e["name"], persistable=True, shape=e["shape"],
+        dtype=e["dtype"], stop_gradient=True)
+        for e in spec if e["layer"] == layer)
 
 
 def routed_ffn(layer):
@@ -342,9 +382,11 @@ def _linear(x, size, pname=None, name=None, rows=None):
     the last of them and the rows behind are zero, where
     :func:`dense_rows_segment` takes a product of K weight rows in a rung
     of S."""
-    if rows is not None and dense_rows_segment(x.shape[1], x.shape[2]):
+    segment = rows is not None and dense_rows_segment(
+        x.shape[1], x.shape[2], x.dtype)
+    if segment:
         return layers.fc_valid_rows(x, size, rows, param_attr=pname,
-                                    name=name)
+                                    name=name, segment=segment)
     return layers.fc(x, size, num_flatten_dims=2, bias_attr=False,
                      param_attr=pname, name=name)
 
@@ -372,31 +414,66 @@ def _linear(x, size, pname=None, name=None, rows=None):
 DENSE_MIN_ROWS = 1024
 DENSE_ALL_ROWS = 2048
 DENSE_MIN_K = 4096
+# The same rule on the same tool's table at bfloat16 operands (one MXU pass,
+# float32 sums; ``--dtype bfloat16``, a v5e, Mistral's shapes; PERF.md
+# section 6, PR 68): a product is five to six times shorter and a loop
+# turn's fixed cost and its [segment, N] copy are what they were.
+#  * 512-row segments, the fused SwiGLU alone: +2.6 % at a full rung of 2048
+#    and 0.89 of a segment's share saved one short (at 1024-row segments
+#    +4.1 % and 0.93, coarser; at 256-row ones +41 to +46 %: a turn reads
+#    the weights again for half the rows).  No single product: q | k | v,
+#    the attention's output and the FFN's halves read +7 to +23 % at a
+#    full rung at every segment.
+#  * From 2048 rows: a rung of 1024 is two such segments (+2.3 %, 0.92) and
+#    a prompt that takes it is longer than the rung before it, so both
+#    would run.
+#  * A rung that is no whole number of such segments is cut into the fewest
+#    EQUAL segments of at most 512 rows where those are whole sublane tiles
+#    of 16 rows (3712 rows: eight of 464), so that no row is worked twice:
+#    in 512-row segments the eighth turn works 384 rows again and a full
+#    rung of 3712 reads +13 %, in eight of 464 the table's last reading.
+#    Where no such cut exists, 512-row segments if the last one works no
+#    more than 5 % of the rung again (``overshoot``), else the plain SwiGLU.
+DENSE_ROWS_BFLOAT16 = {"segment": 512, "min_rows": 2048, "tile": 16,
+                       "overshoot": 0.05}
 
 
-def dense_rows_segment(seq_len, k=None):
+def dense_rows_segment(seq_len, k=None, dtype="float32"):
     """Rows a segment of a dense product of a whole-prompt prefill rung of
-    ``seq_len`` rows (the table above): ``ops/math_ops.py``
-    ``VALID_ROW_SEGMENT`` from :data:`DENSE_MIN_ROWS` rows up, for a single
-    product of ``k`` weight rows under :data:`DENSE_ALL_ROWS` only where k
-    is at least :data:`DENSE_MIN_K` (None: a fused SwiGLU, or the rung as a
-    whole); None where the product is the plain one."""
+    ``seq_len`` rows of ``dtype`` (the tables above; float32's is
+    ``ops/math_ops.py`` ``VALID_ROW_SEGMENT`` from :data:`DENSE_MIN_ROWS`
+    rows up, for a single product of ``k`` weight rows under
+    :data:`DENSE_ALL_ROWS` only where k is at least :data:`DENSE_MIN_K`;
+    None: a fused SwiGLU, or the rung as a whole); None where the product
+    is the plain one."""
     from ..ops.math_ops import VALID_ROW_SEGMENT
 
+    if dtype != "float32":
+        low = DENSE_ROWS_BFLOAT16
+        if k is not None or seq_len < low["min_rows"]:
+            return None
+        segment = low["segment"]
+        turns = -(-seq_len // segment)
+        if seq_len % turns == 0 and seq_len // turns % low["tile"] == 0:
+            return seq_len // turns
+        if segment * turns > (1 + low["overshoot"]) * seq_len:
+            return None
+        return segment
     if seq_len < DENSE_MIN_ROWS or (
             seq_len < DENSE_ALL_ROWS and k is not None and k < DENSE_MIN_K):
         return None
     return VALID_ROW_SEGMENT
 
 
-def dense_rows_run(seq_len, prompt_len):
-    """Rows of a whole-prompt prefill rung of ``seq_len`` rows that its
-    dense products multiply at a prompt of ``prompt_len`` tokens: whole
-    segments (:func:`dense_rows_segment` rows) up to the one that holds the
-    last token; every row of a rung the rule leaves plain.  (In a rung
-    under :data:`DENSE_ALL_ROWS` the products of a narrow weight run every
-    row all the same.)"""
-    segment = dense_rows_segment(seq_len)
+def dense_rows_run(seq_len, prompt_len, dtype="float32"):
+    """Rows of a whole-prompt prefill rung of ``seq_len`` rows of ``dtype``
+    that its dense products multiply at a prompt of ``prompt_len`` tokens:
+    whole segments (:func:`dense_rows_segment` rows) up to the one that
+    holds the last token; every row of a rung the rule leaves plain.  (In a
+    rung under :data:`DENSE_ALL_ROWS` the products of a narrow weight run
+    every row all the same, and so do all single products of a bfloat16
+    rung.)"""
+    segment = dense_rows_segment(seq_len, dtype=dtype)
     if segment is None:
         return seq_len
     return min(seq_len, segment * -(-prompt_len // segment))
@@ -435,15 +512,19 @@ def _head(x, vocab_size, name, tie_head=False, logit_scale=1.0):
     """The LM head over normed rows x [B, S, H] -> [B, S, V]: its own
     matrix ``.head.w`` [H, V], or with ``tie_head`` the embedding table
     ``.embed`` [V, H] itself, read transposed (one parameter, not two);
-    times ``logit_scale`` where that is not 1."""
+    times ``logit_scale`` where that is not 1.  The logits of two-byte
+    rows are the product's float32 accumulator itself, not rounded to the
+    rows' dtype: the sampler and a reference check read them."""
+    whole = {} if x.dtype == "float32" else {"out_dtype": "float32"}
     if not tie_head:
-        logits = _linear(x, vocab_size, pname=f"{name}.head.w" if name
-                         else None)
+        logits = layers.fc(x, vocab_size, num_flatten_dims=2,
+                           bias_attr=False, param_attr=f"{name}.head.w"
+                           if name else None, **whole)
     else:
         from ..framework.core import default_main_program
 
         table = default_main_program().global_block().var(f"{name}.embed")
-        logits = layers.matmul(x, table, transpose_y=True)
+        logits = layers.matmul(x, table, transpose_y=True, **whole)
     if float(logit_scale) != 1.0:
         logits = layers.scale(logits, scale=float(logit_scale))
     return logits
@@ -726,9 +807,13 @@ def _residual(x, y, scale=1.0):
     return layers.elementwise_add(x, y)
 
 
-def _embed(ids, vocab_size, hidden, pname, scale=1.0):
-    """The token rows, times ``scale`` where that is not 1."""
-    x = layers.embedding(ids, size=[vocab_size, hidden], param_attr=pname)
+def _embed(ids, vocab_size, hidden, pname, scale=1.0, dtype="float32"):
+    """The token rows, times ``scale`` where that is not 1.  ``dtype``: the
+    table's, and with it the residual stream's and every matrix's behind
+    it (a layer creates its weights in its input's dtype; norm weights are
+    float32 whatever the row's)."""
+    x = layers.embedding(ids, size=[vocab_size, hidden], param_attr=pname,
+                         dtype=dtype)
     if float(scale) != 1.0:
         x = layers.scale(x, scale=float(scale))
     return x
@@ -968,6 +1053,10 @@ def llama_block(x, hidden, num_heads, num_kv_heads, seq_len, head_dim,
             win = dict(win, mask_block=mask_block)
         if layer.get("attn_precision") is not None:
             win = dict(win, precision=layer["attn_precision"])
+        if x.dtype != "float32":
+            # (the kernels keep their softmax state float32 whatever the
+            # operands; the einsum formulation has to be told)
+            win = dict(win, softmax_float32=True)
         attn = layers.flash_attention(q, k, v, causal=True,
                                       impl=attn_impl, **win)
     attn = layers.transpose(attn, [0, 2, 1, 3])
@@ -1122,7 +1211,8 @@ def _swiglu(h, hidden, width, gate_up_name, down_name, limit=None,
     if rows is not None:
         return layers.swiglu_valid_rows(
             h, width, hidden, rows, gate_up_attr=gate_up_name,
-            down_attr=down_name, limit=limit)
+            down_attr=down_name, limit=limit,
+            segment=dense_rows_segment(h.shape[1], dtype=h.dtype))
     gate_up = _linear(h, 2 * width, pname=gate_up_name)
     gate = layers.slice(gate_up, axes=[2], starts=[0], ends=[width])
     up = layers.slice(gate_up, axes=[2], starts=[width], ends=[2 * width])
@@ -1249,7 +1339,7 @@ def llama(input_ids, vocab_size=32000, hidden=4096, num_layers=32,
           rms_norm_eps=1e-6, rope_base=10000.0, layer_pattern=None,
           qk_norm=False, mask_block=None, tie_head=False, norm="pre",
           norm_kind="rms", logit_scale=1.0, embed_scale=1.0,
-          residual_scale=1.0, attn_scale=None):
+          residual_scale=1.0, attn_scale=None, dtype="float32"):
     """Returns logits [B, S, V]. input_ids: [B, S] int64.
 
     ``head_dim`` defaults to ``hidden // num_heads`` (a model may
@@ -1257,14 +1347,16 @@ def llama(input_ids, vocab_size=32000, hidden=4096, num_layers=32,
     ``layer_pattern`` is described at :data:`DEFAULT_LAYER`, ``qk_norm``
     ``norm``, ``norm_kind`` and ``mask_block`` at :func:`llama_block`;
     ``tie_head`` makes the head's product read the embedding table (needs
-    ``name``) and ``logit_scale`` multiplies the logits; ``embed_scale``
+    ``name``) and ``logit_scale`` multiplies the logits; ``dtype`` is
+    :func:`build_llama_prefill`'s; ``embed_scale``
     multiplies the embedding's rows, ``residual_scale`` and ``attn_scale``
     are :func:`llama_block`'s.  The defaults build exactly the program
     they always did."""
     num_kv_heads = num_kv_heads or num_heads
     head_dim = head_dim or hidden // num_heads
     p = (lambda s: f"{name}.{s}") if name else (lambda s: None)
-    x = _embed(input_ids, vocab_size, hidden, p("embed"), embed_scale)
+    x = _embed(input_ids, vocab_size, hidden, p("embed"), embed_scale,
+               dtype)
     carry = {}
     for i in range(num_layers):
         x = llama_block(x, hidden, num_heads, num_kv_heads, seq_len,
@@ -1333,9 +1425,18 @@ def build_llama_prefill(batch_size, seq_len, vocab_size=32000,
                         qk_norm=False, mask_block=None, tie_head=False,
                         norm="pre", norm_kind="rms", logit_scale=1.0,
                         embed_scale=1.0, residual_scale=1.0,
-                        attn_scale=None, stop_at_prompt=True):
+                        attn_scale=None, stop_at_prompt=True,
+                        dtype="float32"):
     """Prefill entry point: one causal forward over the (padded) prompt
     that populates a decode cache in one shot.
+
+    ``dtype`` (every program builder's; :func:`serving_dtype` is the
+    engine's choice of it): what the embedding table, every matrix, the
+    residual stream and the page pools are declared in.  At "bfloat16"
+    every product's operands are two bytes and its sum float32, the norms'
+    weights and sums, the rotary tables and the softmax state float32, and
+    the logits leave the program float32; "float32" builds the program the
+    builders always built.
 
     In the paged mode, on a rung of :data:`DENSE_MIN_ROWS` rows or more,
     every dense product that :func:`dense_rows_segment` takes (projections,
@@ -1467,8 +1568,10 @@ def build_llama_prefill(batch_size, seq_len, vocab_size=32000,
                           num_slots=cache_slots, num_pages=num_pages,
                           page_tokens=page_tokens,
                           num_kv_heads=num_kv_heads, head_dim=head_dim,
-                          hidden=hidden, num_window_pages=num_window_pages)
-    x = _embed(input_ids, vocab_size, hidden, f"{name}.embed", embed_scale)
+                          hidden=hidden, num_window_pages=num_window_pages,
+                          dtype=dtype)
+    x = _embed(input_ids, vocab_size, hidden, f"{name}.embed", embed_scale,
+               dtype)
     kvs = []
     taps = {"keep_logits": keep_router_logits}
     # the expert layers and those that keep slot state tell real rows
@@ -1476,7 +1579,7 @@ def build_llama_prefill(batch_size, seq_len, vocab_size=32000,
     # have it as last_pos + 1
     valid = prompt_len
     dense_rows = prompt_len if stop_at_prompt \
-        and dense_rows_segment(seq_len) else None
+        and dense_rows_segment(seq_len, dtype=dtype) else None
     if valid is None and (has_state
                           or expert_layers(layer_pattern, num_layers)):
         valid = layers.cast(last_pos + 1, "int32")
@@ -1565,8 +1668,9 @@ def build_llama_decode(num_slots, max_seq_len, vocab_size=32000,
                        qk_norm=False, block=None, mask_id=None,
                        tie_head=False, norm="pre", norm_kind="rms",
                        logit_scale=1.0, embed_scale=1.0, residual_scale=1.0,
-                       attn_scale=None):
-    """Cached decode step over a fixed slot grid.
+                       attn_scale=None, dtype="float32"):
+    """Cached decode step over a fixed slot grid (``dtype``:
+    :func:`build_llama_prefill`'s).
 
     A layer that keeps slot state (``mixer`` of :data:`DEFAULT_LAYER`)
     has no pools: a gated short convolution's ``<name>.conv_state_<i>``
@@ -1676,9 +1780,11 @@ def build_llama_decode(num_slots, max_seq_len, vocab_size=32000,
     spec = cache_spec(name, num_layers, layer_pattern, num_slots=num_slots,
                       num_pages=num_pages, page_tokens=page_tokens,
                       num_kv_heads=num_kv_heads, head_dim=head_dim,
-                      hidden=hidden, num_window_pages=num_window_pages)
+                      hidden=hidden, num_window_pages=num_window_pages,
+                      dtype=dtype)
     cache_names = [e["name"] for e in spec if e["kind"] != "slot_state"]
-    x = _embed(tokens, vocab_size, hidden, f"{name}.embed", embed_scale)
+    x = _embed(tokens, vocab_size, hidden, f"{name}.embed", embed_scale,
+               dtype)
     taps = {"keep_logits": keep_router_logits}
     carry = {}
     for i in range(num_layers):
@@ -1727,7 +1833,8 @@ def _chunk_forward(chunk_len, max_seq_len, num_pages, page_tokens,
                    tie_head=False, norm="pre", norm_kind="rms",
                    logit_scale=1.0, num_window_pages=None,
                    page_aligned=False, keep_router_logits=False,
-                   embed_scale=1.0, residual_scale=1.0, attn_scale=None):
+                   embed_scale=1.0, residual_scale=1.0, attn_scale=None,
+                   dtype="float32"):
     """The forward that the chunk and the verify programs share: C new
     tokens at ``base`` attend the slot's pages plus themselves causally.
     Returns ``(feed_names, x [1, C, H] before the final norm,
@@ -1782,9 +1889,11 @@ def _chunk_forward(chunk_len, max_seq_len, num_pages, page_tokens,
     spec = cache_spec(name, num_layers, layer_pattern, num_slots=0,
                       num_pages=num_pages, page_tokens=page_tokens,
                       num_kv_heads=num_kv_heads, head_dim=head_dim,
-                      hidden=hidden, num_window_pages=num_window_pages)
+                      hidden=hidden, num_window_pages=num_window_pages,
+                      dtype=dtype)
     cache_names = [e["name"] for e in spec]
-    x = _embed(chunk_ids, vocab_size, hidden, f"{name}.embed", embed_scale)
+    x = _embed(chunk_ids, vocab_size, hidden, f"{name}.embed", embed_scale,
+               dtype)
     taps = {"keep_logits": keep_router_logits}
     carry = {}
     for i in range(num_layers):

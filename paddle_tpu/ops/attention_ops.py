@@ -441,7 +441,12 @@ def _flash_attention(ctx, op):
         scale = sm_scale if sm_scale is not None else 1.0 / (d ** 0.5)
         eq = ("bqhd,bkhd->bhqk" if layout == "bshd"
               else "bhqd,bhkd->bhqk")
-        s = jnp.einsum(eq, q, k, **prec) * scale
+        # attr softmax_float32 (two-byte q, k, v of a serving program):
+        # the scores are the product's float32 sum, the softmax float32,
+        # the probabilities rounded where they enter the second product
+        whole = {"preferred_element_type": jnp.float32} \
+            if op.attr("softmax_float32", False) else {}
+        s = jnp.einsum(eq, q, k, **prec, **whole) * scale
         if bias is not None:
             s = s + bias[:, None, None, :].astype(s.dtype)
         if causal:
@@ -463,7 +468,11 @@ def _flash_attention(ctx, op):
             p = jnp.where(keep, p / (1.0 - prob), 0.0).astype(p.dtype)
         eo = ("bhqk,bkhd->bqhd" if layout == "bshd"
               else "bhqk,bhkd->bhqd")
-        out = jnp.einsum(eo, p, v, **prec)
+        if whole:
+            out = jnp.einsum(eo, p.astype(v.dtype), v, **prec,
+                             **whole).astype(q.dtype)
+        else:
+            out = jnp.einsum(eo, p, v, **prec)
         _lowered("xla", window=window)
         ctx.set_output(op, "Out", out)
         _bind_statistic(ctx, op, q.shape[:3])

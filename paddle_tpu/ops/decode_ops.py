@@ -260,9 +260,16 @@ def _attend_cache(q, k, v, pos, scale=None, window=None, block=False):
     validity rule ``j <= pos[b] + t`` (and, under a sliding ``window``,
     ``j > pos[b] + t - window``): the einsum formulation.  With ``block``
     the T rows are one block whose rows all see the whole block: ``j <=
-    pos[b] + T - 1`` for every row."""
+    pos[b] + T - 1`` for every row.  Two-byte q, k, v (a bfloat16 serving
+    program's rows and pages): the scores are the first product's float32
+    sum, the softmax runs on them in float32, the probabilities are
+    rounded where they enter the second product, whose sum is float32
+    too; float32 operands lower exactly as they always did."""
     import jax
     import jax.numpy as jnp
+
+    whole = {} if q.dtype == jnp.float32 \
+        else {"preferred_element_type": jnp.float32}
 
     B, H, T, D = q.shape
     Hkv, S = k.shape[1], k.shape[2]
@@ -281,10 +288,10 @@ def _attend_cache(q, k, v, pos, scale=None, window=None, block=False):
         # row keeps the generic row-consistent GEMM path; the clone's
         # scores are sliced away before the softmax.
         s = jnp.einsum("bhqd,bhkd->bhqk",
-                       jnp.concatenate([q, q], axis=2), k)[:, :, :1]
+                       jnp.concatenate([q, q], axis=2), k, **whole)[:, :, :1]
         s = s * scale
     else:
-        s = jnp.einsum("bhqd,bhkd->bhqk", q, k) * scale
+        s = jnp.einsum("bhqd,bhkd->bhqk", q, k, **whole) * scale
     # validity mask: same -1e30 constant as flash_attention impl="xla";
     # exp underflows to exact 0 for masked columns, so softmax sums and
     # the PV contraction are bit-identical to the shorter uncached row
@@ -295,8 +302,8 @@ def _attend_cache(q, k, v, pos, scale=None, window=None, block=False):
     if window is not None:
         keep = keep & (j > limit - int(window))
     s = jnp.where(keep, s, jnp.asarray(-1e30, s.dtype))
-    p = jax.nn.softmax(s, axis=-1)
-    return jnp.einsum("bhqk,bhkd->bhqd", p, v).astype(q.dtype)
+    p = jax.nn.softmax(s, axis=-1).astype(v.dtype)
+    return jnp.einsum("bhqk,bhkd->bhqd", p, v, **whole).astype(q.dtype)
 
 
 @register_op("cached_attention", infer=_cached_attn_infer, grad=None)
@@ -351,7 +358,8 @@ def _chunk_attention(ctx, op):
     on_tpu = jax.default_backend() == "tpu"
     n_mesh = ctx.mesh.devices.size if ctx.mesh is not None else 1
     if on_tpu and n_mesh == 1 \
-            and chunk_attention_supported(q.shape, k.shape):
+            and chunk_attention_supported(q.shape, k.shape,
+                                          q.dtype.itemsize):
         out = chunk_attention(q, k, v, pos, window=window, sm_scale=scale)
         _lowered("chunk_pallas")
     else:
@@ -361,8 +369,9 @@ def _chunk_attention(ctx, op):
             reason = (f"chunk_attention under a {n_mesh}-device mesh"
                       if n_mesh > 1 else
                       f"chunk_attention with Q {q.shape} over a view "
-                      f"{k.shape} (kernel needs one slot, head_dim % 128 "
-                      f"== 0, rows % 8 == 0, columns % 128 == 0)")
+                      f"{k.shape} of {q.dtype} (kernel needs one slot, "
+                      f"head_dim % 128 == 0, whole sublane tiles of rows: "
+                      f"8 of float32, 16 of bfloat16, columns % 128 == 0)")
         _lowered("chunk_reference", reason)
     ctx.set_output(op, "Out", out)
 
@@ -413,7 +422,8 @@ def _paged_decode_attention(ctx, op):
             "sliding window (each row would admit its own columns)")
     on_tpu = jax.default_backend() == "tpu"
     n_mesh = ctx.mesh.devices.size if ctx.mesh is not None else 1
-    fits = paged_attention.supported(q.shape, pool_k.shape, window)
+    fits = paged_attention.supported(q.shape, pool_k.shape, window,
+                                     pool_k.dtype.itemsize)
     D = q.shape[3]
     if on_tpu and n_mesh == 1 and fits:
         kw = {} if window is None else {"window": int(window)}
@@ -432,9 +442,11 @@ def _paged_decode_attention(ctx, op):
             reason = (f"paged_decode_attention under a {n_mesh}-device "
                       f"mesh" if n_mesh > 1 else
                       f"paged_decode_attention with Q {q.shape} over "
-                      f"pages {pool_k.shape[1:]} (kernel needs head_dim "
-                      f"% 128 == 0 or heads of 64 packed two a row, "
-                      f"page_tokens % 8 == 0, at most "
+                      f"pages {pool_k.shape[1:]} of {pool_k.dtype} (kernel "
+                      f"needs head_dim % 128 == 0 or heads of 64 packed "
+                      f"two a row, pages of whole sublane tiles: "
+                      f"page_tokens % 8 == 0 at float32, % 16 at "
+                      f"bfloat16, at most "
                       f"{paged_attention.MAX_GROUP_ROWS} query rows a "
                       f"KV head)")
         _lowered("paged_decode_reference", reason, window=window)

@@ -306,7 +306,8 @@ def _matmul_infer(op: Operator, block: Block):
     x, y = in_var(op, block, "X"), in_var(op, block, "Y")
     tx = op.attr("trans_x", op.attr("transpose_X", False))
     ty = op.attr("trans_y", op.attr("transpose_Y", False))
-    set_out(op, block, "Out", _matmul_shape(x.shape, y.shape, tx, ty), x.dtype)
+    set_out(op, block, "Out", _matmul_shape(x.shape, y.shape, tx, ty),
+            op.attr("out_dtype", None) or x.dtype)
 
 
 def _matmul_lower(ctx: LowerContext, op: Operator):
@@ -321,7 +322,7 @@ def _matmul_lower(ctx: LowerContext, op: Operator):
     # On the MXU, accumulate matmuls in f32 even for bf16 operands.
     out = jnp.matmul(x, y, preferred_element_type=_acc_dtype(x.dtype),
                      precision=_mm_precision(x.dtype))
-    out = out.astype(x.dtype)
+    out = out.astype(_out_dtype(op, x))
     alpha = op.attr("alpha", 1.0)
     if alpha != 1.0:
         out = out * alpha
@@ -333,6 +334,14 @@ def _acc_dtype(dtype):
     if dtype in (jnp.bfloat16, np.float16):
         return jnp.float32
     return dtype
+
+
+def _out_dtype(op, x):
+    """What ``mul`` / ``matmul`` hand on: X's dtype, or with the attr
+    ``out_dtype`` (an LM head over two-byte rows: "float32") that one, the
+    float32 sum not rounded on the way."""
+    out = op.attr("out_dtype", None)
+    return x.dtype if out is None else dtype_to_np(out)
 
 
 def _mm_precision(dtype):
@@ -359,7 +368,7 @@ def _mul_infer(op: Operator, block: Block):
     xd = op.attr("x_num_col_dims", 1)
     yd = op.attr("y_num_col_dims", 1)
     out = list(x.shape[:xd]) + list(y.shape[yd:])
-    set_out(op, block, "Out", out, x.dtype)
+    set_out(op, block, "Out", out, op.attr("out_dtype", None) or x.dtype)
 
 
 @register_op("mul", infer=_mul_infer)
@@ -381,13 +390,13 @@ def _mul_lower(ctx: LowerContext, op: Operator):
         out = jax.lax.dot_general(
             x, y, dn, preferred_element_type=_acc_dtype(x.dtype),
             precision=_mm_precision(x.dtype))
-        ctx.set_output(op, "Out", out.astype(x.dtype))
+        ctx.set_output(op, "Out", out.astype(_out_dtype(op, x)))
         return
     x2 = jnp.reshape(x, (int(np.prod(xs[:xd])), -1))
     y2 = jnp.reshape(y, (int(np.prod(ys[:yd])), -1))
     out = jnp.matmul(x2, y2, preferred_element_type=_acc_dtype(x2.dtype),
                      precision=_mm_precision(x2.dtype))
-    out = out.astype(x2.dtype)
+    out = out.astype(_out_dtype(op, x2))
     ctx.set_output(op, "Out", jnp.reshape(out, xs[:xd] + ys[yd:]))
 
 

@@ -37,6 +37,12 @@ DEFAULT_BLOCK_K = 512
 NEG_INF = -1e30
 
 
+def sublane_rows(itemsize):
+    """Rows a sublane tile of items of that many bytes: 8 of float32, 16
+    of bfloat16."""
+    return 32 // itemsize
+
+
 def _fit_block(block, size, compiled=False):
     """Largest halving of `block` that divides `size`.  For a compiled
     (non-interpret) kernel several blocks must each span whole 128-lane
@@ -162,17 +168,23 @@ def _fa_fwd_kernel(q_ref, k_ref, v_ref, *rest, block_k, causal, scale,
     else:
         o_ref, lse_ref = rest
     qi = pl.program_id(1)
-    q = q_ref[0].astype(jnp.float32) * scale          # [Bq, D]
-    bq, d = q.shape
-    nk = seq_k // block_k
     # None: the MXU's default (float32 operands rounded to bf16)
     prec = {} if precision is None \
         else {"precision": jax.lax.Precision(precision)}
+    # At the default the MXU takes its operands as bfloat16 whatever they
+    # were, so two-byte q, k, v go in as they lie (the scaled q and the
+    # probabilities rounded where they enter their product: the values the
+    # MXU made of their float32 forms) and nothing is widened on the way;
+    # the sums and the softmax state are float32 either way
+    operand = q_ref.dtype if precision is None else jnp.float32
+    q = (q_ref[0].astype(jnp.float32) * scale).astype(operand)   # [Bq, D]
+    bq, d = q.shape
+    nk = seq_k // block_k
 
     def body(j, carry):
         m, l, acc = carry
-        kb = k_ref[0, pl.ds(j * block_k, block_k), :].astype(jnp.float32)
-        vb = v_ref[0, pl.ds(j * block_k, block_k), :].astype(jnp.float32)
+        kb = k_ref[0, pl.ds(j * block_k, block_k), :].astype(operand)
+        vb = v_ref[0, pl.ds(j * block_k, block_k), :].astype(operand)
         s = jax.lax.dot_general(
             q, kb, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32, **prec)  # [Bq, Bk]
@@ -199,7 +211,8 @@ def _fa_fwd_kernel(q_ref, k_ref, v_ref, *rest, block_k, causal, scale,
         corr = jnp.exp(m - m_new)
         l_new = l * corr + p.sum(-1, keepdims=True)
         acc_new = acc * corr + jnp.dot(
-            p, vb, preferred_element_type=jnp.float32, **prec)
+            p.astype(operand), vb, preferred_element_type=jnp.float32,
+            **prec)
         return m_new, l_new, acc_new
 
     m0 = jnp.full((bq, 1), NEG_INF, jnp.float32)
@@ -593,14 +606,15 @@ def flash_attention_bias(q, k, v, bias, causal=False, sm_scale=None,
 # whole.  And query head ``g`` reads KV head ``g // rep`` by indexing: no
 # key or value is repeated.  Forward only; no statistic comes back.
 
-def chunk_attention_supported(q_shape, kv_shape):
+def chunk_attention_supported(q_shape, kv_shape, itemsize=4):
     """Whether the compiled chunk kernel takes these shapes: one slot,
-    whole lane tiles of ``D``, whole sublane tiles of rows, key blocks of
-    whole 128-lane tiles that divide the view."""
+    whole lane tiles of ``D``, whole sublane tiles of rows (8 of four-byte
+    items, 16 of two-byte ones), key blocks of whole 128-lane tiles that
+    divide the view."""
     B, H, C, D = q_shape
     _, Hkv, S, _ = kv_shape
-    return (B == 1 and D % 128 == 0 and C % 8 == 0 and H % Hkv == 0
-            and S % 128 == 0 and kv_shape[3] == D)
+    return (B == 1 and D % 128 == 0 and C % sublane_rows(itemsize) == 0
+            and H % Hkv == 0 and S % 128 == 0 and kv_shape[3] == D)
 
 
 def _chunk_kernel(base_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem, *,
@@ -612,7 +626,12 @@ def _chunk_kernel(base_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem, *,
 
     h, qi = pl.program_id(0), pl.program_id(1)
     kv_head = h // rep
-    q = q_ref[0].astype(jnp.float32) * scale          # [Bq, D]
+    # (two-byte operands as they lie, at the MXU's default: the forward
+    # kernel's rule; "highest" is for float32 operands, which it keeps whole)
+    operand = jnp.float32
+    if q_ref.dtype != jnp.float32:
+        operand, precision = q_ref.dtype, None
+    q = (q_ref[0].astype(jnp.float32) * scale).astype(operand)   # [Bq, D]
     bq, d = q.shape
     row0 = base_ref[0] + qi * bq        # this block's first row's position
     prec = {} if precision is None \
@@ -643,8 +662,8 @@ def _chunk_kernel(base_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem, *,
 
         for c in copies(j, buf):
             c.wait()
-        kb = kbuf[buf].astype(jnp.float32)
-        vb = vbuf[buf].astype(jnp.float32)
+        kb = kbuf[buf].astype(operand)
+        vb = vbuf[buf].astype(operand)
         s = jax.lax.dot_general(
             q, kb, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32, **prec)  # [Bq, Bk]
@@ -664,7 +683,8 @@ def _chunk_kernel(base_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem, *,
         corr = jnp.exp(m - m_new)
         l_new = l * corr + p.sum(-1, keepdims=True)
         acc_new = acc * corr + jnp.dot(
-            p, vb, preferred_element_type=jnp.float32, **prec)
+            p.astype(operand), vb, preferred_element_type=jnp.float32,
+            **prec)
         return m_new, l_new, acc_new
 
     m0 = jnp.full((bq, 1), NEG_INF, jnp.float32)

@@ -30,8 +30,15 @@ HBM through the block table, and nothing else but Q and the output:
   garbage (NaN included) cannot reach the output through ``0 * x``.
 
 Shapes the compiled kernel takes: ``D`` a multiple of 128 (lanes) and
-``pt`` a multiple of 8 (float32 sublanes); ``supported()`` says so and
+``pt`` whole sublane tiles (8 rows of float32, 16 of bfloat16: a page is
+copied into rows ``i * pt ..`` of the buffer); ``supported()`` says so and
 the op lowers anything else to the reference formulation.
+
+**Two-byte pools.**  The buffers are the pools' dtype and go into both
+contractions as they lie, with q (the op hands it over in the pools'
+dtype) and the probabilities rounded where they enter ``p @ v``: one MXU
+pass each at the default precision, float32 sums, and the softmax state
+float32 as ever.
 
 **Heads of 64.**  A TPU pads a minor dim of 64 to the 128 lanes, in HBM
 too, so such a model's pool is kept ``[P, Hkv / 2, pt, 128]``: KV heads
@@ -55,12 +62,25 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .flash_attention import NEG_INF  # the -1e30 mask constant
+from .flash_attention import sublane_rows
 
 # positions fetched and contracted per loop turn.  On a TPU v5e at Hkv 8,
 # D 128, float32, page 16 (PERF.md §6, PR 25): 128 / 256 / 512 take 137 /
 # 150 / 186 us a layer over 32 short chat contexts and 243 / 235 / 239 us
-# over 8 long ones; a smaller granule wastes less on a slot's masked tail
+# over 8 long ones; a smaller granule wastes less on a slot's masked tail.
+# Over bfloat16 pages (tools/attn_dtype_microbench.py, PERF.md §6, PR 68: 32
+# chat contexts of 100-1,400 positions | 8 long ones of 1,000-3,700) a page
+# copy is half the bytes and a turn's fixed cost what it was: 313 / 275 /
+# 257 | 247 / 247 / 270 us a layer (float32 in the same run: 370 / 385 /
+# 391 | 296 / 285 / 290), so two-byte pools take 256
 GRANULE_POSITIONS = 128
+GRANULE_POSITIONS_TWO_BYTE = 256
+
+
+def granule_positions(dtype):
+    """Positions a loop turn over pools of ``dtype`` (the table above)."""
+    return GRANULE_POSITIONS if jnp.dtype(dtype).itemsize >= 4 \
+        else GRANULE_POSITIONS_TWO_BYTE
 # float32 operands go through the MXU whole (Mosaic's fp32 contraction),
 # not rounded to bf16 as at default precision: 6e-7 of the range against
 # a "highest" reference where default reads 4e-3 to 7e-3 (the einsum
@@ -69,22 +89,29 @@ GRANULE_POSITIONS = 128
 PRECISION = jax.lax.Precision.HIGHEST
 
 
+def _precision(dtype):
+    """Of the kernel's two contractions: :data:`PRECISION` on float32
+    operands, the default (None) on two-byte ones, which are what the MXU
+    multiplies."""
+    return PRECISION if dtype == jnp.float32 else None
+
+
 # query rows of one KV head the kernel holds at once: ``rep`` query heads
 # times the rows of a block
 MAX_GROUP_ROWS = 256
 
 
-def supported(q_shape, pool_shape, window=None):
+def supported(q_shape, pool_shape, window=None, itemsize=4):
     """Whether the compiled kernel takes these shapes: whole lane tiles
-    of ``D``, whole float32 sublane tiles of ``pt``, and the query rows
-    of a slot all admitting the same columns (one token, or a block of
-    rows without a sliding window) with a KV head's group of them in
-    ``MAX_GROUP_ROWS``."""
+    of ``D``, whole sublane tiles of ``pt`` (of the pools' ``itemsize``),
+    and the query rows of a slot all admitting the same columns (one
+    token, or a block of rows without a sliding window) with a KV head's
+    group of them in ``MAX_GROUP_ROWS``."""
     _, H, T, D = q_shape
     _, Hkv, pt, pool_d = pool_shape
     pack = pool_d // D          # KV heads a pool row (2: heads of 64)
     return (pool_d % 128 == 0 and pack in (1, 2) and pack * D == pool_d
-            and pt % 8 == 0 and H % (Hkv * pack) == 0
+            and pt % sublane_rows(itemsize) == 0 and H % (Hkv * pack) == 0
             and (T == 1 or window is None)
             and (H // Hkv) * T <= MAX_GROUP_ROWS)
 
@@ -193,7 +220,7 @@ def _kernel(bt_ref, pos_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem,
         v = vbuf[buf]
         s = jnp.einsum("hrd,hkd->hrk", q, k,
                        preferred_element_type=jnp.float32,
-                       precision=PRECISION) * scale
+                       precision=_precision(k.dtype)) * scale
         col = g * T + jax.lax.broadcasted_iota(jnp.int32, (1, 1, T), 2)
         keep = col <= pos
         if window is not None:
@@ -204,8 +231,9 @@ def _kernel(bt_ref, pos_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem,
         p = jnp.exp(s - m_new)
         l = alpha * l + p.sum(axis=-1, keepdims=True)
         acc = alpha * acc + jnp.einsum(
-            "hrk,hkd->hrd", p, v, preferred_element_type=jnp.float32,
-            precision=PRECISION)
+            "hrk,hkd->hrd", p.astype(v.dtype), v,
+            preferred_element_type=jnp.float32,
+            precision=_precision(v.dtype))
         return m_new, l, acc
 
     init = (jnp.full((Hkv, R, 1), -jnp.inf, jnp.float32),
@@ -220,7 +248,7 @@ def _kernel(bt_ref, pos_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem,
                                     "window"))
 def paged_decode_attention(q, pool_k, pool_v, block_table, positions,
                            scale=None, interpret=False,
-                           granule=GRANULE_POSITIONS, window=None):
+                           granule=None, window=None):
     """``q`` [B, H, T, D] over pools ``[P, Hkv, pt, D]`` through
     ``block_table`` [B, NP] int32; ``positions`` [B] int32 is the last
     column each slot admits: every one of its ``T`` query rows attends
@@ -230,7 +258,8 @@ def paged_decode_attention(q, pool_k, pool_v, block_table, positions,
     ``base + T - 1``.  The rows of a KV head's group are then ``rep x
     T`` and the page walk is the one-token step's.  ``granule`` is the
     number of positions fetched and contracted per loop turn, rounded
-    to whole pages.  ``window`` (one row only) adds the lower bound ``j
+    to whole pages (None: :func:`granule_positions` of the pools' dtype).
+    ``window`` (one row only) adds the lower bound ``j
     > positions[b] - window``: the granule loop starts at the window's
     first page and nothing left of it is fetched.  Pools ``[P, Hkv / 2,
     pt, 2 D]`` hold two KV heads a row (this module's docstring).
@@ -247,7 +276,10 @@ def paged_decode_attention(q, pool_k, pool_v, block_table, positions,
                          f"pool rows of {D}")
     packed = D != head_d
     rep = (H // Hkv) * T              # query rows that share a pool row
-    R = -(-rep // 8) * 8                             # whole sublane tiles
+    tile = sublane_rows(q.dtype.itemsize)
+    R = -(-rep // tile) * tile                       # whole sublane tiles
+    if granule is None:
+        granule = granule_positions(pool_k.dtype)
     G = max(1, min(granule // pt, NP))
     scale = scale if scale is not None else 1.0 / (head_d ** 0.5)
 

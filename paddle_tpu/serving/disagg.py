@@ -34,9 +34,10 @@ transport later slots into.  Two implementations ship:
 * :class:`HostBytesTransport` — the serialization path the HTTP
   ``POST /adopt`` hop and a future RDMA/TCP transport share:
   :meth:`KVSegment.to_bytes` / :meth:`KVSegment.from_bytes` frame a
-  little-endian float32 payload behind a JSON header (magic +
-  version + fingerprint), so a decode replica in another process
-  adopts exactly what the prefill replica exported.
+  little-endian payload in the page pools' dtype (float32, or the
+  bfloat16 of a program served in it; the header says which) behind a
+  JSON header (magic + version + fingerprint), so a decode replica in
+  another process adopts exactly what the prefill replica exported.
 
 **Fingerprint contract.**  ``config_fingerprint`` hashes the model
 size dict, the page geometry (``page_tokens`` / ``max_seq_len``), the
@@ -77,6 +78,7 @@ import numpy as np
 from .. import telemetry
 from ..flags import flag_value
 from .engine import OverloadedError, RequestFailed, ServingFuture
+from .kv_cache import ITEMSIZE
 
 __all__ = ["KVSegment", "SegmentMismatch", "SegmentTransport",
            "DeviceTransport", "HostBytesTransport", "DisaggPair",
@@ -87,6 +89,36 @@ SEGMENT_MAGIC = b"PTKVSEG1"
 # HTTP content type for a serialized segment (the router recognizes a
 # prefill replica's export reply by it)
 SEGMENT_CONTENT_TYPE = "application/x-paddletpu-kvsegment"
+# a page block's items on the wire, by the pools' dtype: numpy has no
+# bfloat16 of its own, so those bytes travel as the uint16 they are
+_WIRE = {"float32": "<f4", "bfloat16": "<u2"}
+
+
+def _pages_dtype(pages) -> str:
+    """``"float32"`` or ``"bfloat16"``: what a page block (numpy or jax)
+    holds."""
+    name = str(pages.dtype)
+    if name not in _WIRE:
+        raise ValueError(f"a KV segment carries float32 or bfloat16 pages, "
+                         f"got {name}")
+    return name
+
+
+def _to_wire(pages, dtype: str) -> bytes:
+    """A page block's bytes, little-endian C-order, bit for bit."""
+    return np.ascontiguousarray(np.asarray(pages)).view(
+        _WIRE[dtype]).tobytes()
+
+
+def _from_wire(buf, dtype: str, count: int, offset: int, shape):
+    """The block :func:`_to_wire` wrote, in its own dtype again."""
+    arr = np.frombuffer(buf, _WIRE[dtype], count=count,
+                        offset=offset).reshape(shape)
+    if dtype == "float32":
+        return arr
+    import ml_dtypes
+
+    return arr.view(ml_dtypes.bfloat16)
 
 
 class SegmentMismatch(ValueError):
@@ -113,7 +145,8 @@ class KVSegment:
     """One sequence's populated KV pages, detached from any pool.
 
     ``layers`` — one ``(k_pages, v_pages)`` pair per model layer, each
-    ``[n_pages, n_kv, page_tokens, D]`` in LOGICAL page order (index j
+    ``[n_pages, n_kv, page_tokens, D]`` of the exporting engine's pools'
+    dtype (:attr:`dtype`) in LOGICAL page order (index j
     holds tokens ``[j*page_tokens, (j+1)*page_tokens)``); the physical
     page ids of the source pool are deliberately NOT part of the
     segment — the adopter scatters into whatever pages its own pool
@@ -151,10 +184,16 @@ class KVSegment:
         return int(self.layers[0][0].shape[0]) if self.layers else 0
 
     @property
+    def dtype(self) -> str:
+        """``"float32"`` or ``"bfloat16"``: the page blocks' dtype."""
+        return _pages_dtype(self.layers[0][0]) if self.layers else "float32"
+
+    @property
     def nbytes(self) -> int:
         """Payload bytes (K/V page blocks + optional logits) — the
         number a transport actually moves."""
-        total = sum(int(np.prod(k.shape)) * 4 + int(np.prod(v.shape)) * 4
+        item = ITEMSIZE[self.dtype]
+        total = sum((int(np.prod(k.shape)) + int(np.prod(v.shape))) * item
                     for k, v in self.layers)
         if self.logits is not None:
             total += int(np.prod(np.asarray(self.logits).shape)) * 4
@@ -163,11 +202,12 @@ class KVSegment:
     # -- serialization (the host-bytes / cross-host path) -------------------
     def to_bytes(self) -> bytes:
         """``MAGIC | u32 header_len | header JSON | payload``: payload
-        is every layer's K then V page block as little-endian float32
-        C-order, then the optional logits block.  Self-describing —
-        :meth:`from_bytes` needs nothing but the buffer."""
-        k0 = np.asarray(self.layers[0][0])
-        n_pages, n_kv, pt, d = k0.shape
+        is every layer's K then V page block, little-endian C-order in
+        the pools' dtype (the header's ``dtype``), then the optional
+        logits block (float32).  Self-describing — :meth:`from_bytes`
+        needs nothing but the buffer."""
+        dtype = self.dtype
+        n_pages, n_kv, pt, d = self.layers[0][0].shape
         logits = None if self.logits is None \
             else np.ascontiguousarray(np.asarray(self.logits, "<f4"))
         header = {
@@ -175,7 +215,7 @@ class KVSegment:
             "prompt_len": self.prompt_len, "position": self.position,
             "tokens": self.tokens, "page_tokens": self.page_tokens,
             "n_layers": self.n_layers, "n_pages": int(n_pages),
-            "n_kv": int(n_kv), "head_dim": int(d),
+            "n_kv": int(n_kv), "head_dim": int(d), "dtype": dtype,
             "trace_id": self.trace_id,
             "logits_shape": list(logits.shape)
             if logits is not None else None,
@@ -183,10 +223,7 @@ class KVSegment:
         hb = json.dumps(header, sort_keys=True).encode()
         parts = [SEGMENT_MAGIC, struct.pack("<I", len(hb)), hb]
         for k, v in self.layers:
-            parts.append(np.ascontiguousarray(
-                np.asarray(k, "<f4")).tobytes())
-            parts.append(np.ascontiguousarray(
-                np.asarray(v, "<f4")).tobytes())
+            parts += [_to_wire(k, dtype), _to_wire(v, dtype)]
         if logits is not None:
             parts.append(logits.tobytes())
         return b"".join(parts)
@@ -210,7 +247,12 @@ class KVSegment:
                              f"speaks {SEGMENT_VERSION})")
         shape = (header["n_pages"], header["n_kv"],
                  header["page_tokens"], header["head_dim"])
-        block = int(np.prod(shape)) * 4
+        # (a segment written before the header named it is float32)
+        dtype = header.get("dtype", "float32")
+        if dtype not in _WIRE:
+            raise ValueError(f"KV segment of unknown dtype {dtype!r}")
+        items = int(np.prod(shape))
+        block = items * ITEMSIZE[dtype]
         expect = off + header["n_layers"] * 2 * block
         if header.get("logits_shape"):
             expect += int(np.prod(header["logits_shape"])) * 4
@@ -220,11 +262,9 @@ class KVSegment:
                              f"{len(buf)}")
         layers = []
         for _ in range(header["n_layers"]):
-            k = np.frombuffer(buf, "<f4", count=block // 4,
-                              offset=off).reshape(shape)
+            k = _from_wire(buf, dtype, items, off, shape)
             off += block
-            v = np.frombuffer(buf, "<f4", count=block // 4,
-                              offset=off).reshape(shape)
+            v = _from_wire(buf, dtype, items, off, shape)
             off += block
             layers.append((k, v))
         logits = None
@@ -247,8 +287,8 @@ class KVSegment:
 class SegmentTransport:
     """The handoff seam: ``send`` delivers a segment to wherever the
     adopting engine will read it from.  Implementations must preserve
-    the payload bit-exactly (float32 in, the same float32 out) — the
-    round trip is part of the exactness contract the tests pin."""
+    the payload bit-exactly (the pools' dtype in, the same bits out) —
+    the round trip is part of the exactness contract the tests pin."""
 
     def send(self, segment: KVSegment) -> KVSegment:
         raise NotImplementedError

@@ -168,7 +168,9 @@ whole-prompt prefill's rung rows that its dense products multiplied / that
 lay in whole segments behind the prompt's end and were not:
 ``models/llama.py`` ``dense_rows_run``), ``serving_delta_state_steps``
 (slot-layers whose delta state a decode step moved on),
-``serving_ssm_state_steps`` (the same of a state-space layer's matrix);
+``serving_ssm_state_steps`` (the same of a state-space layer's matrix),
+``programs_built_float32`` / ``programs_built_bfloat16`` (the programs the
+engine built, by the dtype they are declared in: ``dtype``, below);
 gauges (the cache's are listed in ``kv_cache.py``)
 ``serving_spec_acceptance_rate``, ``serving_slot_occupancy``,
 ``moe_experts_touched``, ``moe_expert_load_max_over_mean``; histograms
@@ -525,6 +527,14 @@ class GenerationEngine:
     executor.Scope` whose weights use the same ``name`` prefix (the
     engine then shares them zero-copy); omitted, the engine seeds its
     own random weights (bench / loadgen).
+    ``dtype``: what every program of the engine is declared in (the
+    embedding table, every matrix, the residual stream, the page pools;
+    ``models/llama.py`` ``build_llama_prefill``).  None: the engine's own
+    rule on the model it is handed (``models/llama.py``
+    ``serving_dtype``: bfloat16 where every layer is of a kind whose
+    bfloat16 form exists, float32 otherwise); ``"float32"`` /
+    ``"bfloat16"`` state it.  ``stats()["counters"]`` counts the programs
+    built as ``programs_built_float32`` / ``programs_built_bfloat16``.
 
     In-process API: :meth:`submit` (future) / :meth:`generate`
     (blocking).  The HTTP front end exposes ``POST /generate`` over the
@@ -539,14 +549,24 @@ class GenerationEngine:
                  mesh=None, shard_rules=None, paged=None,
                  page_tokens=None, num_pages=None, prefill_chunk=None,
                  prefix_reuse=None, role=None, speculate=None,
-                 spec_tokens=None, spec_ngram=None, num_window_pages=None):
+                 spec_tokens=None, spec_ngram=None, num_window_pages=None,
+                 dtype=None):
         import paddle_tpu as pt
         from ..compile_cache import ensure_compile_cache
         from ..models.llama import (build_llama_prefill, expert_layers,
-                                    layer_spec, routed_ffn)
+                                    layer_spec, routed_ffn, serving_dtype)
 
         ensure_compile_cache()
         self.model = dict(model)
+        self.dtype = str(dtype) if dtype is not None \
+            else serving_dtype(self.model)
+        if self.dtype not in ("float32", "bfloat16"):
+            raise ValueError(f"dtype is None, 'float32' or 'bfloat16', got "
+                             f"{dtype!r}")
+        # what a builder is handed beyond the model: nothing where the
+        # program is the float32 one the builders always built
+        self._dtype_args = {} if self.dtype == "float32" \
+            else {"dtype": self.dtype}
         # block diffusion (the class docstring): B positions a step, T
         # denoising passes a block; 0 = one token a step
         bd = self.model.pop("block_diffusion", None) or {}
@@ -609,7 +629,8 @@ class GenerationEngine:
             max_seq_len=self.max_seq_len, page_tokens=page_tokens,
             num_pages=num_pages, num_window_pages=num_window_pages,
             prefill_chunk=self.prefill_chunk,
-            prefix_reuse=self.prefix_reuse, count=self._count)
+            prefix_reuse=self.prefix_reuse, count=self._count,
+            dtype=self.dtype)
         # what the programs are built from and others read of it, set
         # once (``kv_live_bytes`` moves: a property)
         for attr in ("page_tokens", "pages_per_slot", "num_pages",
@@ -696,6 +717,33 @@ class GenerationEngine:
         self._adopt_scatter = None  # donated jit, built on first adopt
         self._prefill_rr = 0  # chunked-prefill round-robin cursor
 
+        # (before the first program is built: a build is counted)
+        self._n = {"requests": 0, "shed": 0, "served": 0, "prefills": 0,
+                   "decode_steps": 0, "decode_steps_ahead": 0,
+                   "decode_joiners_ahead": 0,
+                   "decode_rows_discarded": 0, "generated_tokens": 0,
+                   "prefill_tokens": 0, "slot_reclaims": 0,
+                   "failed": 0, "prefix_hits": 0,
+                   "prefix_tokens_saved": 0, "prefill_chunks": 0,
+                   "page_evictions": 0, "pool_stalls": 0,
+                   "segments_exported": 0, "segments_adopted": 0,
+                   "adopt_rejects": 0, "spec_drafts": 0,
+                   "spec_tokens_proposed": 0,
+                   "spec_tokens_accepted": 0, "spec_rollbacks": 0,
+                   "window_pages_released": 0,
+                   "window_pages_released_in_prefill": 0,
+                   "moe_tokens_routed": 0, "moe_pad_pairs_left_out": 0,
+                   "moe_tokens_dropped": 0, "block_passes_denoise": 0,
+                   "block_passes_commit": 0, "block_tokens_committed": 0,
+                   "prefill_rows_run": 0, "prefill_rows_skipped": 0,
+                   "slot_state_writes": 0, "delta_state_steps": 0,
+                   "ssm_state_steps": 0,
+                   "moe_pairs_routed": 0, "moe_pairs_held": 0,
+                   "moe_pairs_zero": 0,
+                   "moe_rows_group_held": 0, "moe_shared_expert_rows": 0,
+                   "programs_built_float32": 0, "programs_built_bfloat16": 0}
+        self._n_lock = threading.Lock()
+
         # programs + executors: decode gets its own executor so its
         # compile-cache entry (and cost/memory manifest) is isolated —
         # cache_info()["entries"][0] IS the decode step
@@ -731,30 +779,6 @@ class GenerationEngine:
         self._pending_swap = None
         self.weights_version = 1
 
-        self._n = {"requests": 0, "shed": 0, "served": 0, "prefills": 0,
-                   "decode_steps": 0, "decode_steps_ahead": 0,
-                   "decode_joiners_ahead": 0,
-                   "decode_rows_discarded": 0, "generated_tokens": 0,
-                   "prefill_tokens": 0, "slot_reclaims": 0,
-                   "failed": 0, "prefix_hits": 0,
-                   "prefix_tokens_saved": 0, "prefill_chunks": 0,
-                   "page_evictions": 0, "pool_stalls": 0,
-                   "segments_exported": 0, "segments_adopted": 0,
-                   "adopt_rejects": 0, "spec_drafts": 0,
-                   "spec_tokens_proposed": 0,
-                   "spec_tokens_accepted": 0, "spec_rollbacks": 0,
-                   "window_pages_released": 0,
-                   "window_pages_released_in_prefill": 0,
-                   "moe_tokens_routed": 0, "moe_pad_pairs_left_out": 0,
-                   "moe_tokens_dropped": 0, "block_passes_denoise": 0,
-                   "block_passes_commit": 0, "block_tokens_committed": 0,
-                   "prefill_rows_run": 0, "prefill_rows_skipped": 0,
-                   "slot_state_writes": 0, "delta_state_steps": 0,
-                   "ssm_state_steps": 0,
-                   "moe_pairs_routed": 0, "moe_pairs_held": 0,
-                   "moe_pairs_zero": 0,
-                   "moe_rows_group_held": 0, "moe_shared_expert_rows": 0}
-        self._n_lock = threading.Lock()
         # per-bucket manifest-flops cache for usage attribution: the
         # executor cache walk is paid once per bucket, not per dispatch
         self._usage_flops: Dict[int, int] = {}
@@ -805,7 +829,8 @@ class GenerationEngine:
                 num_pages=self.num_pages, page_tokens=self.page_tokens,
                 num_window_pages=self.num_window_pages or None,
                 keep_router_logits=self.keep_logits, **self._blk_args(),
-                **self.model)
+                **self._dtype_args, **self.model)
+        self._built()
         self._decode_prog = main
         self._decode_feeds = feeds
         self._decode_fetches = fetches
@@ -814,6 +839,10 @@ class GenerationEngine:
             # engine-owned weights: the decode program references every
             # parameter, so one startup run initializes the full set
             self._prefill_exe.run(startup, scope=self.scope)
+
+    def _built(self):
+        """One more program built in the engine's dtype."""
+        self._count(f"programs_built_{self.dtype}")
 
     def _blk_args(self, prefill: bool = False) -> dict:
         """What block diffusion adds to a program builder's arguments;
@@ -908,7 +937,9 @@ class GenerationEngine:
                     page_tokens=self.page_tokens,
                     num_window_pages=self.num_window_pages or None,
                     keep_router_logits=self.keep_logits,
-                    **self._blk_args(prefill=True), **self.model)
+                    **self._blk_args(prefill=True), **self._dtype_args,
+                    **self.model)
+            self._built()
             entry = self._prefill_progs[bucket] = (main, fetches)
         return entry
 
@@ -936,7 +967,9 @@ class GenerationEngine:
                     self.page_tokens, name=self.name,
                     num_window_pages=self.num_window_pages or None,
                     page_aligned=aligned,
-                    keep_router_logits=self.keep_logits, **self.model)
+                    keep_router_logits=self.keep_logits,
+                    **self._dtype_args, **self.model)
+            self._built()
             entry = self._chunk_progs[bucket] = (main, fetches)
         return entry
 
@@ -978,7 +1011,9 @@ class GenerationEngine:
             with pt.program_guard(main, startup):
                 _feeds, fetches, _names = build_llama_verify(
                     bucket, self.max_seq_len, self.num_pages,
-                    self.page_tokens, name=self.name, **self.model)
+                    self.page_tokens, name=self.name, **self._dtype_args,
+                    **self.model)
+            self._built()
             entry = self._verify_progs[bucket] = (main, fetches)
         return entry
 
@@ -1430,9 +1465,11 @@ class GenerationEngine:
         a segment exported here adopts bit-exactly there."""
         if self._fingerprint is None:
             from .disagg import config_fingerprint
+            # (the programs' dtype with the model's sizes, where it is
+            # not float32: pages of another dtype mean something else)
             self._fingerprint = config_fingerprint(
-                self.model, self.page_tokens, self.max_seq_len,
-                self.name, self._seed)
+                dict(self.model, **self._dtype_args), self.page_tokens,
+                self.max_seq_len, self.name, self._seed)
         return self._fingerprint
 
     def _check_segment(self, seg):
@@ -2138,7 +2175,7 @@ class GenerationEngine:
                     if self._latent_layers else {}
                 # the rung's rows its dense products multiply: on a long
                 # rung they stop at the prompt's last segment
-                rows_run = dense_rows_run(bucket, n_rows)
+                rows_run = dense_rows_run(bucket, n_rows, self.dtype)
             outs = self._launch(
                 "generation/prefill", lambda: self._run_fetching(
                     self._prefill_exe, prog, fetches, feed),

@@ -249,6 +249,10 @@ REFUSALS = {
 }
 
 
+# bytes an item of the dtypes a cache entry is declared in (``cache_spec``)
+ITEMSIZE = {"float32": 4, "bfloat16": 2}
+
+
 class KVCache:
     """Every layer's cache of one engine: the page configuration, the
     pools and the prefix index, the per-slot page accounting, and (after
@@ -261,9 +265,13 @@ class KVCache:
     def __init__(self, model: Dict, name: str = "llama", *, num_slots: int,
                  max_seq_len: int, page_tokens=None, num_pages=None,
                  num_window_pages=None, prefill_chunk: int = 0,
-                 prefix_reuse: bool = False, count=None):
+                 prefix_reuse: bool = False, count=None,
+                 dtype: str = "float32"):
         from ..models.llama import cache_spec, layer_spec, window_layers
 
+        # the page pools' dtype: the engine's programs' (slot state is
+        # float32 whatever they are, ``cache_spec``)
+        self.dtype = str(dtype)
         self.num_slots, self.max_seq_len = int(num_slots), int(max_seq_len)
         self.prefill_chunk = int(prefill_chunk)
         self.prefix_reuse = bool(prefix_reuse)
@@ -326,13 +334,13 @@ class KVCache:
             num_kv_heads=self._n_kv,
             head_dim=model.get("head_dim") or model["hidden"] // heads,
             hidden=model["hidden"],
-            num_window_pages=self.num_window_pages or None)
+            num_window_pages=self.num_window_pages or None, dtype=self.dtype)
         self.kinds = {e["kind"] for e in self.spec}
         self.state_names = [e["name"] for e in self.spec
                             if e["kind"] == "slot_state"]
 
         def nbytes(*kinds, of=0):
-            return sum(int(np.prod(e["shape"][of:])) * 4
+            return sum(int(np.prod(e["shape"][of:])) * ITEMSIZE[e["dtype"]]
                        for e in self.spec if e["kind"] in kinds)
 
         # capacity the pools take (trash pages included), and the
@@ -417,7 +425,7 @@ class KVCache:
                 # the prefill scatter donate all pools in one call, and
                 # XLA rejects donating the same buffer twice (device_put
                 # also allocates a fresh buffer per call)
-                zeros = jnp.zeros(tuple(entry["shape"]), jnp.float32)
+                zeros = jnp.zeros(tuple(entry["shape"]), entry["dtype"])
                 # (slot state is replicated under a mesh: it is small,
                 # and its rows are slots, not heads)
                 pool = jax.device_put(zeros, cache_sh) \

@@ -80,6 +80,51 @@ AFTER_SETUP = len(SSM) + len(LATENT_EXPERTS) + len(IDENTITY)
 globals().update({name: fn for name, fn in vars(manifest).items()
                   if name.startswith("test_")})
 
+# PR 68: the engine's own rule serves Mistral's rehearsal engine in bfloat16
+# (``models/llama.py`` ``serving_dtype``), and the benchmark's check of the
+# same name holds every rehearsal engine to float32 in all three classes
+# (``benchmark/tests/as_run_checks.py``, a `benchmark` PR's to edit).  The
+# check is taken here under its own name with its own cases, expecting of
+# each configuration what the rule builds.
+_as_run = manifest.as_run_checks
+BFLOAT16_BY_THE_RULE = ["mistral-7b-v0.1"]
+
+
+@pytest.mark.parametrize("config", list(_as_run.FLOAT32))
+def test_as_run_the_keep_list_matches_the_engines_arrays(config):
+    """Every pattern of ``keeps_float32`` matches an array of the engine a
+    rehearsal-size builder makes; the engine is observed in the dtype the
+    program's rule builds (Mistral: bfloat16 weights and pages, no state;
+    the others float32 in all three classes) and held to that entry."""
+    import fnmatch
+
+    harness = _as_run.harness
+    cell, gen = _as_run._engine_read_only(config)
+    names = [n[len(gen.name) + 1:] for n in gen.scope.local_var_names()
+             if n.startswith(gen.name + ".")]
+    for pattern in harness.kept_patterns(cell.cfg):
+        assert any(fnmatch.fnmatchcase(n, pattern) for n in names), pattern
+    low = config in BFLOAT16_BY_THE_RULE
+    dtype = "bfloat16" if low else "float32"
+    assert gen.dtype == dtype
+    assert cell.observed == {
+        "weights": dtype, "pages": dtype,
+        "state": "float32" if gen.state_names else None}
+    assert cell.admitted and not cell.observed_problems
+    share, margin, _ = _as_run.FLOAT32[config]
+    tol = cell.cfg["check_tolerance"]
+    whole = harness.load_json("configs", config + ".json")
+    if low:
+        # held to the file's bfloat16 entry (the toy widths' own, where
+        # the rehearsal group has one)
+        entry = whole.get("rehearse", {}).get("check_tolerance", {}).get(
+            "bfloat16") or whole["check_tolerance"]["bfloat16"]
+        assert cell.tolerance == entry["share_of_range"] > share
+    elif "check_tolerance" not in cell.cfg.get("rehearse", {}):
+        assert cell.tolerance == share \
+            and tol.get(_as_run.MARGIN) == margin
+
+
 # the cells that model_config PRs added since the merge (PR 41, 43, 47, 51),
 # in the order they were added: the cell's configuration and mix, its own
 # entries, the shared families that list it beside ``POOL``, what its

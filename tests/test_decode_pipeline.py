@@ -44,7 +44,10 @@ def _engine(kind, **kw):
     return GenerationEngine(
         WINDOW_MODEL if kind == "window" else MODEL, num_slots=3,
         max_seq_len=64, page_tokens=8, prefill_chunk=0,
-        prefix_reuse=False, speculate=False, attn_impl="xla", seed=0, **kw)
+        prefix_reuse=False, speculate=False, attn_impl="xla", seed=0,
+        # (held to the float32 uncached forward; the engine's own choice
+        # for MODEL, bfloat16, is held in tests/test_serving_dtype.py)
+        dtype="float32", **kw)
 
 
 @pytest.fixture(scope="module", autouse=True)
@@ -584,7 +587,7 @@ def test_weight_swap_settles_the_step_in_flight_first(paged):
                              attn_impl="xla", seed=7,
                              page_tokens=8, prefix_reuse=False,
                              prefill_chunk=0, speculate=False,
-                             name="donor")
+                             name="donor", dtype="float32")
     try:
         new = {n.replace("donor", paged.name, 1):
                np.asarray(donor.scope.find_var(n))

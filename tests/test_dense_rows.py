@@ -452,7 +452,9 @@ def test_engine_counts_the_rows_a_prefill_ran_and_skipped(rung):
     kw = dict(num_slots=2, max_seq_len=rung + 64, max_new_tokens=2,
               prefill_buckets=[64, rung], page_tokens=64, prefill_chunk=0,
               prefix_reuse=False, speculate=False, attn_impl="xla", seed=0,
-              keep_logits=True, deadline_ms=600000.0)
+              keep_logits=True, deadline_ms=600000.0,
+              # (the float32 table's rungs and segments: the fixture's)
+              dtype="float32")
     old = pt.get_flags(["FLAGS_telemetry"])
     pt.set_flags({"FLAGS_telemetry": True})
     eng = GenerationEngine(BASE, **kw)

@@ -52,7 +52,11 @@ def gen_engine():
     eng = GenerationEngine(MODEL, num_slots=3, max_seq_len=48,
                            max_new_tokens=8, keep_logits=True,
                            attn_impl="xla", seed=0, queue_cap=64,
-                           deadline_ms=600000.0, prefix_reuse=False)
+                           deadline_ms=600000.0, prefix_reuse=False,
+                           # (held to the float32 uncached forward; the
+                           # engine's own choice for MODEL, bfloat16, is
+                           # held in tests/test_serving_dtype.py)
+                           dtype="float32")
     yield eng
     eng.close()
 

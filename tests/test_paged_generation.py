@@ -52,7 +52,11 @@ def _paged(scope=None, **kw):
     base = dict(num_slots=3, max_seq_len=96, max_new_tokens=8,
                 keep_logits=True, attn_impl="xla", seed=0,
                 queue_cap=64, deadline_ms=600000.0,
-                page_tokens=PAGE, prefill_chunk=0, prefix_reuse=False)
+                page_tokens=PAGE, prefill_chunk=0, prefix_reuse=False,
+                # (held to the float32 uncached forward: the program the
+                # engine's rule would pick, bfloat16, is held to it at a
+                # tolerance in tests/test_serving_dtype.py)
+                dtype="float32")
     base.update(kw)
     return GenerationEngine(MODEL, scope=scope, **base)
 
@@ -425,16 +429,27 @@ def test_whole_prompt_prefills_first_come_first_served(plain_ref):
     """Unchunked, slots claimed in one pass prefill in the order their
     requests arrived: one whole prompt an iteration, oldest first (the
     round-robin cursor is for slices of chunked prompts)."""
+    from paddle_tpu import telemetry
+
+    old = pt.get_flags(["FLAGS_telemetry"])
+    pt.set_flags({"FLAGS_telemetry": True})
     eng = _paged(plain_ref.scope.new_scope(), autostart=False)
     try:
         rng = np.random.default_rng(3)
         futures = [eng.submit(rng.integers(1, 61, 20).tolist(), 2)
                    for _ in range(3)]
+        telemetry.clear_spans()
         eng.start()                      # all three claimed at once
-        ttft = [f.result(300)["ttft_ms"] for f in futures]
-        assert ttft == sorted(ttft)
+        slots = [f.result(300)["slot"] for f in futures]
+        # the engine's own record, not three host-clock readings that
+        # differ by less than the clock's jitter: the prefill launches in
+        # the order they were made, by the slot each filled
+        launched = [s.attrs["slot"] for s in telemetry.get_spans()
+                    if s.name == "generation/prefill"]
+        assert sorted(slots) == [0, 1, 2] and launched == slots
     finally:
         eng.close()
+        pt.set_flags(old)
 
 
 def test_pool_exhaustion_cache_full(plain_ref):

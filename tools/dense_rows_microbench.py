@@ -35,9 +35,17 @@ the chip's work, and a configuration none of whose cells has a rung that
 long (``LONGEST_RUNG``) does not multiply its shapes there: both are timed,
 printed, and left out of the rule.
 
-``python tools/dense_rows_microbench.py [--short]`` (chip only, six to
-fifteen minutes): each the median of ``--reps`` runs after a warm-up.
-Writes ``chiprun_out/dense_rows_microbench[_short].json``.
+``--dtype bfloat16`` (PR 68) times the same at bfloat16 operands, as op
+``mul`` multiplies them: one MXU pass at the default precision, float32
+sums, the result rounded to bfloat16; segments of 256, 512 and 1024 rows
+(``--short``: 256 and 512), and the rule's verdicts by the bfloat16 table's
+``min_k``.  ``--only WORD`` keeps the shapes whose name starts with it
+(``--only mistral``: the one configuration served in bfloat16 at PR 68).
+
+``python tools/dense_rows_microbench.py [--short] [--dtype bfloat16]``
+(chip only, six to fifteen minutes): each the median of ``--reps`` runs
+after a warm-up.  Writes
+``chiprun_out/dense_rows_microbench[_short][_bfloat16].json``.
 """
 from __future__ import annotations
 
@@ -53,7 +61,11 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 SHAPES = [("mistral gate|up", 4096, 28672, (2048, 3712)),
           ("mistral down", 14336, 4096, (2048, 3712)),
           ("olmo gate|up", 3840, 22016, (2048, 6144)),
-          ("olmo down", 11008, 3840, (2048, 6144))]
+          ("olmo down", 11008, 3840, (2048, 6144)),
+          # (PR 68) the mixer's two projections, single products of the
+          # rungs' programs as the FFN's are not (the SwiGLU is fused)
+          ("mistral q|k|v", 4096, 6144, (2048, 3712)),
+          ("mistral attn out", 4096, 4096, (2048, 3712))]
 FFNS = [("mistral swiglu", 4096, 14336, (2048, 3712)),
         ("olmo swiglu", 3840, 11008, (2048, 6144))]
 SEGMENTS = (256, 512)
@@ -115,9 +127,22 @@ def main(argv=None) -> int:
                     help="the SwiGLUs alone, not the single products")
     ap.add_argument("--short", action="store_true",
                     help="rungs 256, 512, 1024 at segments 128 and 256")
+    ap.add_argument("--dtype", choices=("float32", "bfloat16"),
+                    default="float32", help="the operands' dtype")
+    ap.add_argument("--only", default="",
+                    help="the shapes whose name starts with this word")
+    ap.add_argument("--segments", default="",
+                    help="these segments (rows, comma separated) instead "
+                    "of the mode's own")
     args = ap.parse_args(argv)
     shapes, ffns, segments = (SHORT_SHAPES, SHORT_FFNS, SHORT_SEGMENTS) \
         if args.short else (SHAPES, FFNS, SEGMENTS)
+    if args.dtype == "bfloat16":
+        segments = (256, 512) if args.short else (256, 512, 1024)
+    if args.segments:
+        segments = tuple(int(s) for s in args.segments.split(","))
+    shapes, ffns = ([c for c in cases if c[0].startswith(args.only)]
+                    for cases in (shapes, ffns))
 
     import jax
     import jax.numpy as jnp
@@ -129,11 +154,18 @@ def main(argv=None) -> int:
     from paddle_tpu.ops.math_ops import valid_rows_product, valid_rows_swiglu
 
     rng = np.random.default_rng(64)
-    out = {"device": jax.devices()[0].device_kind, "rows": []}
+    dtype = jnp.dtype(args.dtype)
+    out = {"device": jax.devices()[0].device_kind, "dtype": args.dtype,
+           "rows": []}
 
     def dot(x, w):
-        return jax.lax.dot_general(x, w, (((2,), (0,)), ((), ())),
-                                   precision=jax.lax.Precision.HIGHEST)
+        # (op ``mul``'s product: "highest" on float32 operands, one pass
+        # on bfloat16 ones, a float32 sum rounded to the operands' dtype)
+        return jax.lax.dot_general(
+            x, w, (((2,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+            precision=jax.lax.Precision.HIGHEST
+            if dtype == jnp.float32 else None).astype(dtype)
 
     def swiglu(x, w):
         gu = dot(x, w[0])
@@ -141,7 +173,7 @@ def main(argv=None) -> int:
         return dot(jax.nn.silu(gu[..., :width]) * gu[..., width:], w[1])
 
     def draw(k, n):
-        return jnp.asarray(rng.normal(size=(k, n)) * k ** -0.5, jnp.float32)
+        return jnp.asarray(rng.normal(size=(k, n)) * k ** -0.5, dtype)
 
     # (a case draws its matrices when its turn comes: 0.5-0.9 GB each)
     cases = [(what, k, rungs, lambda k=k, n=n: draw(k, n), dot,
@@ -155,7 +187,7 @@ def main(argv=None) -> int:
         w = matrices()
         whole = jax.jit(whole_fn)
         for rung in rungs:
-            x = jnp.asarray(rng.normal(size=(1, rung, k)), jnp.float32)
+            x = jnp.asarray(rng.normal(size=(1, rung, k)), dtype)
             whole_ms, whole_first = timed(lambda: whole(x, w), args.reps)
             want = whole(x, w)
             row = {"product": what, "k": k, "rung": rung,
@@ -166,13 +198,17 @@ def main(argv=None) -> int:
             for segment in (s for s in segments if s <= rung):
                 seg = jax.jit(lambda x, w, v, segment=segment:
                               seg_fn(x, w, v, segment))
+                # (the quarters, and what the rule reads: the whole rung
+                # and one segment short of it)
                 for valid in (range(segment, rung + 1, segment) if args.short
-                              else (rung * q // 4 for q in (1, 2, 3, 4))):
+                              else sorted({rung * q // 4 for q in (1, 2, 3, 4)}
+                                          | {rung - segment})):
                     v = jnp.asarray(valid, jnp.int32)
                     ms, first = timed(lambda: seg(x, w, v), args.reps)
                     got = seg(x, w, v)
-                    off = float(jnp.abs(got[:, :valid]
-                                        - want[:, :valid]).max())
+                    off = float(jnp.abs(
+                        got[:, :valid].astype(jnp.float32)
+                        - want[:, :valid].astype(jnp.float32)).max())
                     run = min(rung, -(-valid // segment) * segment)
                     behind = float(jnp.abs(got[:, run:]).max()) \
                         if run < rung else 0.0
@@ -187,10 +223,11 @@ def main(argv=None) -> int:
             out["rows"].append(row)
             del x, want
         del w
-    if args.short:
-        out["rule"] = rule(out["rows"])
+    out["rule"] = rule(out["rows"])
     os.makedirs("chiprun_out", exist_ok=True)
-    name = "dense_rows_microbench%s.json" % ("_short" if args.short else "")
+    name = "dense_rows_microbench%s%s.json" % (
+        "_short" if args.short else "",
+        "" if args.dtype == "float32" else "_" + args.dtype)
     with open(os.path.join("chiprun_out", name), "w") as f:
         json.dump(out, f, indent=1)
     return 0
@@ -203,8 +240,14 @@ def rule(rows):
     it saves at ``valid`` one segment short of the rung as a share of that
     segment's share of the rows, each with the product it was read at;
     ``taken``: ``over`` <= 5 % and ``saved`` >= half (a rung of one segment
-    has nothing to skip)."""
-    from paddle_tpu.models.llama import DENSE_MIN_K
+    has nothing to skip).  The families part at the float32 table's
+    ``DENSE_MIN_K`` at either dtype (the bfloat16 row takes no single
+    product)."""
+    import importlib
+
+    # (``paddle_tpu.models`` exports a function of the module's name)
+    llama = importlib.import_module("paddle_tpu.models.llama")
+    DENSE_MIN_K = llama.DENSE_MIN_K
 
     def family(r):
         return "fused SwiGLU" if "swiglu" in r["product"] else \
@@ -220,6 +263,8 @@ def rule(rows):
             if r["rung"] != rung or family(r) != fam \
                     or r["whole_ms"] < HOST_BOUND_MS \
                     or LONGEST_RUNG.get(r["product"].split()[0], rung) < rung:
+                continue
+            if f"seg{segment}_valid{rung}" not in r:
                 continue
             full = r[f"seg{segment}_valid{rung}"]["ms"] / r["whole_ms"] - 1
             over = max(over, (full, r["product"]))

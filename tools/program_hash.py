@@ -175,7 +175,8 @@ def main(argv) -> int:
         def spec(shape, dtype):
             dtype = {"int64": "int32", "float64": "float32"}.get(
                 str(dtype), str(dtype))
-            return jax.ShapeDtypeStruct(tuple(shape), np.dtype(dtype),
+            # (jax.numpy.dtype: numpy alone does not know "bfloat16")
+            return jax.ShapeDtypeStruct(tuple(shape), jax.numpy.dtype(dtype),
                                         sharding=chip)
 
         def state(ns):
@@ -241,6 +242,11 @@ def main(argv) -> int:
             continue
         cfg, e = cell.cfg, cell.mix["engine"]
         model = cell.builder().model_args(cfg)
+        # (PR 68) the dtype the tree's engine would declare the programs
+        # in, where the tree has a rule (``GenerationEngine._dtype_args``)
+        rule = getattr(llama, "serving_dtype", None)
+        if rule is not None and rule(model) != "float32":
+            model["dtype"] = rule(model)
         # (block diffusion: what ``GenerationEngine._blk_args`` hands on)
         bd = model.pop("block_diffusion", None)
         blk = {"block": bd["block"], "mask_id": bd["mask_id"]} if bd else {}
